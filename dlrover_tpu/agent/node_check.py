@@ -4,18 +4,28 @@ Capability ref: ``dlrover/trainer/torch/node_check/nvidia_gpu.py:24`` +
 ``utils.py:58-196`` (``matmul`` stress + ``bm_allgather`` timed) and the
 agent driver ``training.py:828-977`` (``NodeCheckElasticAgent``).
 
-TPU redesign: probes run *in the agent's own process* on the local chips (no
+TPU redesign: one probe process drives all local chips (no
 fork-per-device), measuring (a) bf16 matmul sustained TFLOPs on every local
 chip — catches degraded/thermally-limited chips, and (b) psum all-reduce
 bandwidth across local chips over ICI — catches bad ICI links.  Elapsed time
 is reported to the master's NetworkCheckRendezvousManager, which runs the
 pairwise bisection (SURVEY.md §3.5).
+
+A chip belongs to one process at a time, and the agent goes on to spawn the
+trainer: the probes therefore run in a short-lived child
+(``python -m dlrover_tpu.agent.node_check``) that has exited, and released
+the chip, before the trainer starts.  The agent itself never initialises a
+JAX backend.
 """
 
 from __future__ import annotations
 
+import json
+import os
+import subprocess
+import sys
 import time
-from typing import Tuple
+from typing import Optional, Tuple
 
 from dlrover_tpu.common.log import default_logger as logger
 
@@ -111,15 +121,15 @@ def probe_result_digest(matrix_dim: int = 512, iters: int = 4) -> str:
     return f"{zlib.crc32(out.tobytes()) & 0xFFFFFFFF:08x}"
 
 
-def golden_replay_check(client, node_rank: int) -> bool:
+def golden_replay_check(client, node_rank: int, digest: str) -> bool:
     """Record the golden probe digest at first join; compare on re-join.
 
-    The golden value lives in the master's kv store (it survives master
-    restarts through the state store), keyed by node rank.  A mismatch is
+    ``digest`` is this host's :func:`probe_result_digest`.  The golden
+    value lives in the master's kv store (it survives master restarts
+    through the state store), keyed by node rank.  A mismatch is
     reported like a failed bisection round — the master's verdict then
     excludes this host the same way a bad ICI link would be.
     """
-    digest = probe_result_digest()
     key = f"node_check_golden/{node_rank}"
     golden = client.kv_get(key)
     if not golden:
@@ -144,8 +154,12 @@ def run_probe_payload(matrix_dim: int = 4096) -> Tuple[bool, float]:
     """The full per-host probe: returns (healthy, elapsed_seconds)."""
     import jax
 
+    from dlrover_tpu.common import faults
+
     t0 = time.monotonic()
     try:
+        # Seam: the TPU runtime failing at init is this probe failing.
+        faults.fire("backend.init")
         tflops = []
         for device in jax.local_devices():
             tflops.append(matmul_probe(matrix_dim, device=device))
@@ -159,6 +173,57 @@ def run_probe_payload(matrix_dim: int = 4096) -> Tuple[bool, float]:
     except Exception as e:
         logger.error("node check probe failed: %s", e)
         return False, time.monotonic() - t0
+
+
+def probe_in_child(
+    with_digest: bool = False, timeout: float = 600.0
+) -> Tuple[bool, float, Optional[str]]:
+    """Run the probe payload in a child process that holds the chip alone
+    and has exited when this returns: ``(healthy, elapsed_s, digest)``.
+
+    ``digest`` is the golden-replay digest when asked for and the child
+    got that far, else None.  A child that crashes, hangs past ``timeout``
+    or prints no verdict is an unhealthy host, not an agent failure.
+    """
+    cmd = [sys.executable, "-m", "dlrover_tpu.agent.node_check"]
+    if with_digest:
+        cmd.append("--digest")
+    package_root = os.path.dirname(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    )
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.pathsep.join(
+        p for p in (package_root, env.get("PYTHONPATH", "")) if p
+    )
+    t0 = time.monotonic()
+    try:
+        out = subprocess.run(
+            cmd, env=env, capture_output=True, text=True, timeout=timeout
+        )
+        verdict = json.loads(out.stdout.strip().splitlines()[-1])
+        return (
+            bool(verdict["healthy"]), float(verdict["elapsed"]),
+            verdict.get("digest"),
+        )
+    except subprocess.TimeoutExpired:
+        logger.error("node check child exceeded %.0fs", timeout)
+    except (IndexError, KeyError, ValueError):
+        logger.error(
+            "node check child gave no verdict (rc=%d): %s",
+            out.returncode, out.stderr.strip()[-2000:],
+        )
+    return False, time.monotonic() - t0, None
+
+
+def _child_main(argv=None) -> int:
+    """The probe child: one JSON verdict as the last line of stdout."""
+    want_digest = "--digest" in (sys.argv[1:] if argv is None else argv)
+    healthy, elapsed = run_probe_payload()
+    verdict = {"healthy": healthy, "elapsed": elapsed}
+    if want_digest:
+        verdict["digest"] = probe_result_digest()
+    print(json.dumps(verdict), flush=True)
+    return 0
 
 
 def run_network_check(
@@ -186,14 +251,19 @@ def run_network_check(
             if state.world:
                 break
             time.sleep(0.5)
-        healthy, elapsed = run_probe_payload()
-        if check_round == 0:
-            # Golden-batch replay rides the first round only: one seeded
-            # matmul digest compared against the value recorded at the
-            # job's first join.  A mismatch fails this round exactly like
-            # a failed probe, feeding the master's bisection the suspect.
+        # Golden-batch replay rides the first round only: one seeded
+        # matmul digest compared against the value recorded at the job's
+        # first join.  A mismatch fails this round exactly like a failed
+        # probe, feeding the master's bisection the suspect.
+        healthy, elapsed, digest = probe_in_child(
+            with_digest=check_round == 0
+        )
+        if digest is not None:
             try:
-                healthy = golden_replay_check(client, node_rank) and healthy
+                healthy = (
+                    golden_replay_check(client, node_rank, digest)
+                    and healthy
+                )
             except Exception as e:  # noqa: BLE001 - probe is best-effort
                 logger.warning("golden replay check skipped: %s", e)
         local_healthy = local_healthy and healthy
@@ -209,3 +279,7 @@ def run_network_check(
         time.sleep(1.0)
     logger.warning("network-check verdict timed out; using local result")
     return local_healthy
+
+
+if __name__ == "__main__":
+    sys.exit(_child_main())

@@ -95,7 +95,7 @@ class ElasticLaunchConfig:
     live_relayout: bool = False
     # Device-init watchdog (VERDICT r4 #2b): a freshly started trainer
     # that produces no first step report within this bound is stuck below
-    # Python (wedged device relay, hung PJRT init) — a failure mode the
+    # Python (hung PJRT / device init) — a failure mode the
     # generic heartbeat can NEVER catch, because the agent process itself
     # stays healthy and keeps heartbeating while the trainer hangs at
     # backend init.  0 disables.  Generous default: first-compile of a
@@ -504,7 +504,12 @@ class ElasticAgent:
             self._log_pump = None
 
     def _restart_workers(self):
-        """ref ``_restart_workers:687``: in-place process restart, no new pod."""
+        """ref ``_restart_workers:687``: in-place process restart, no new pod.
+
+        The chip passes from the old trainer to the new: ``_stop_workers``
+        returns only when the old process has been reaped (a trainer that
+        died on its own was reaped by the ``poll()`` that saw it), and a
+        chip belongs to one process at a time."""
         # A LIVE trainer being torn down (membership change, hang
         # remediation) gets its stacks collected first — where it was
         # stuck is exactly what the post-incident diagnosis needs.

@@ -100,10 +100,10 @@ def _select_shards(leaf) -> Tuple[Tuple[int, ...], str, List[Tuple[Tuple, Any]]]
             # single-host restore still works (harmless duplicate on disk).
             shard = leaf.addressable_shards[0]
             shards.append((_slices_to_index(shard.index, leaf.shape), shard.data))
-        return tuple(leaf.shape), np.dtype(leaf.dtype).str, shards
+        return tuple(leaf.shape), np.dtype(leaf.dtype).name, shards
     block = np.asarray(leaf)
     index = tuple((0, d) for d in block.shape)
-    return tuple(block.shape), block.dtype.str, [(index, block)]
+    return tuple(block.shape), block.dtype.name, [(index, block)]
 
 
 def pack_pytree(
@@ -297,7 +297,10 @@ def assemble_tensor(
 ) -> np.ndarray:
     """Reassemble a full tensor from shard records via ``block_loader(record)``
     (returns flat uint8).  Requires the records to cover the global shape."""
-    dtype = np.dtype(meta.dtype)
+    # The dtype's *name*: ``.str`` of bfloat16 (and every other ml_dtypes
+    # type) is the opaque ``<V2``, which restores as raw void bytes.
+    # ``jnp.dtype`` also reads the ``<f4`` spelling of older checkpoints.
+    dtype = jax.numpy.dtype(meta.dtype)
     out = np.empty(meta.global_shape, dtype=dtype)
     for record in meta.shards:
         block = (

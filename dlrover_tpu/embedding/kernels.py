@@ -7,8 +7,11 @@ back in.  Both directions run through exactly two compiled programs:
 
 - on TPU, a Pallas kernel using ``PrefetchScalarGridSpec`` scalar
   prefetch — the slot indices arrive before the kernel body runs, so each
-  grid step DMAs one ``(1, dim)`` row block straight between HBM and the
-  output without materializing a one-hot or a full-table copy;
+  grid step DMAs one row block straight between HBM and the output
+  without materializing a one-hot or a full-table copy.  The kernels see
+  ``[N, 1, dim]`` views (a free bitcast) so that a one-row block spans the
+  array's whole last two dims: Mosaic refuses a ``(1, dim)`` block of an
+  ``(N, dim)`` array;
 - everywhere else (the CPU tier-1 lane), a pure ``jnp.take`` /
   ``.at[].set`` body with the IDENTICAL contract — same shapes, same
   duplicate-slot semantics, same trace counters — so the fallback tests
@@ -30,12 +33,8 @@ import os
 import jax
 import jax.numpy as jnp
 
-try:  # pallas ships with jax, but guard exotic builds
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover - pallas always present in-image
-    pl = None
-    pltpu = None
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 ENV_MODE = "DLROVER_TPU_EMBED_PALLAS"
 
@@ -56,7 +55,7 @@ def kernel_mode() -> str:
     forced = os.environ.get(ENV_MODE, "").strip().lower()
     if forced in ("interpret", "pallas", "jnp"):
         return forced
-    if pl is not None and jax.devices()[0].platform == "tpu":
+    if jax.devices()[0].platform == "tpu":
         return "pallas"
     return "jnp"
 
@@ -77,42 +76,46 @@ def _scatter_kernel(slots_ref, rows_ref, cache_ref, out_ref):
 def _pallas_gather(cache: jax.Array, slots: jax.Array,
                    interpret: bool) -> jax.Array:
     n, dim = int(slots.shape[0]), int(cache.shape[1])
+    row = (1, 1, dim)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n,),
-        in_specs=[pl.BlockSpec((1, dim), lambda i, slots: (slots[i], 0))],
-        out_specs=pl.BlockSpec((1, dim), lambda i, slots: (i, 0)),
+        in_specs=[pl.BlockSpec(row, lambda i, slots: (slots[i], 0, 0))],
+        out_specs=pl.BlockSpec(row, lambda i, slots: (i, 0, 0)),
     )
     return pl.pallas_call(
         _gather_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n, dim), cache.dtype),
+        out_shape=jax.ShapeDtypeStruct((n, 1, dim), cache.dtype),
         interpret=interpret,
-    )(slots, cache)
+    )(slots, cache.reshape(-1, 1, dim)).reshape(n, dim)
 
 
 def _pallas_scatter(cache: jax.Array, slots: jax.Array,
                     rows: jax.Array, interpret: bool) -> jax.Array:
     n, dim = int(slots.shape[0]), int(cache.shape[1])
+    row = (1, 1, dim)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n,),
         in_specs=[
-            pl.BlockSpec((1, dim), lambda i, slots: (i, 0)),         # rows
-            pl.BlockSpec((1, dim), lambda i, slots: (slots[i], 0)),  # cache
+            pl.BlockSpec(row, lambda i, slots: (i, 0, 0)),         # rows
+            pl.BlockSpec(row, lambda i, slots: (slots[i], 0, 0)),  # cache
         ],
-        out_specs=pl.BlockSpec((1, dim), lambda i, slots: (slots[i], 0)),
+        out_specs=pl.BlockSpec(row, lambda i, slots: (slots[i], 0, 0)),
     )
     return pl.pallas_call(
         _scatter_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(cache.shape, cache.dtype),
+        out_shape=jax.ShapeDtypeStruct((cache.shape[0], 1, dim), cache.dtype),
         # Alias the cache operand (index 2: after the scalar-prefetch
         # slots and the rows) onto the output: untouched rows keep their
         # HBM contents in place instead of round-tripping the whole table.
         input_output_aliases={2: 0},
         interpret=interpret,
-    )(slots, rows, cache)
+    )(
+        slots, rows.reshape(n, 1, dim), cache.reshape(-1, 1, dim)
+    ).reshape(cache.shape)
 
 
 # -- jitted entry points -------------------------------------------------------

@@ -2,14 +2,17 @@
 
 The Python face of ``native/kv_store.cc`` (capability ref
 ``tfplus/tfplus/kv_variable/kernels/kv_variable.h`` — see the .cc header).
-The shared library is compiled with g++ on first use and cached next to the
-source; a NumPy fallback implements the identical contract when no compiler
-is available (CI safety net — the native path is the product).
+The shared library is compiled with g++ on first use into the checkout's
+``.jax_cache`` directory (ignored by git) under a name that carries a hash
+of the source, so a binary built from another source is never loaded; a
+NumPy fallback implements the identical contract when no compiler is
+available (CI safety net — the native path is the product).
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -17,11 +20,12 @@ from typing import Dict, Optional, Tuple
 
 import numpy as np
 
+from dlrover_tpu.common import faults
 from dlrover_tpu.common.log import default_logger as logger
+from dlrover_tpu.runtime.compile_cache import default_cache_dir
 
-_NATIVE_DIR = os.path.join(os.path.dirname(__file__), "native")
-_SRC = os.path.join(_NATIVE_DIR, "kv_store.cc")
-_LIB = os.path.join(_NATIVE_DIR, "libkvstore.so")
+_SRC = os.path.join(os.path.dirname(__file__), "native", "kv_store.cc")
+_BUILD_DIR = default_cache_dir()
 _build_lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _lib_failed = False
@@ -33,6 +37,29 @@ _MAX_BUILD_ATTEMPTS = 2
 _build_attempts = 0
 
 
+def _build_native() -> str:
+    """Path of the library built from ``kv_store.cc`` as it is now,
+    compiling it first if that exact source has not been built yet."""
+    # Seams: an unreadable source or a failed install of the binary is a
+    # failed build, which degrades to the NumPy store below.
+    faults.fire("storage.read", path=os.path.basename(_SRC))
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    lib_path = os.path.join(_BUILD_DIR, f"libkvstore-{digest}.so")
+    if not os.path.exists(lib_path):
+        os.makedirs(_BUILD_DIR, exist_ok=True)
+        # Build beside the final name and rename: another process never
+        # loads a half-written library.
+        tmp_path = f"{lib_path}.{os.getpid()}.tmp"
+        subprocess.run(
+            ["g++", "-O2", "-shared", "-fPIC", "-o", tmp_path, _SRC],
+            check=True, capture_output=True, text=True,
+        )
+        faults.fire("storage.write", path=os.path.basename(lib_path))
+        os.replace(tmp_path, lib_path)
+    return lib_path
+
+
 def _load_native() -> Optional[ctypes.CDLL]:
     global _lib, _lib_failed, _build_attempts
     if _lib is not None or _lib_failed:
@@ -42,15 +69,10 @@ def _load_native() -> Optional[ctypes.CDLL]:
             return _lib
         _build_attempts += 1
         try:
-            if not os.path.exists(_LIB) or (
-                os.path.getmtime(_LIB) < os.path.getmtime(_SRC)
-            ):
-                subprocess.run(
-                    ["g++", "-O2", "-shared", "-fPIC", "-o", _LIB, _SRC],
-                    check=True, capture_output=True, text=True,
-                )
-            lib = ctypes.CDLL(_LIB)
-        except (OSError, subprocess.CalledProcessError) as e:
+            lib = ctypes.CDLL(_build_native())
+        except (
+            OSError, subprocess.CalledProcessError, faults.FaultInjected
+        ) as e:
             if _build_attempts >= _MAX_BUILD_ATTEMPTS:
                 _lib_failed = True
                 logger.warning(

@@ -37,6 +37,7 @@ from dlrover_tpu.runtime.mesh import (
     TENSOR_AXIS,
     current_mesh,
     mesh_axis_size,
+    shard_local,
     shard_map_compat,
 )
 
@@ -158,6 +159,29 @@ def cached_attention(
     probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
     out = jnp.einsum("bhgqk,bkhd->bqhgd", probs, v)
     return out.reshape(b, sq, hq, d)
+
+
+def _flash_local(q, k, v, segment_ids, *, block_q, block_kv):
+    """Causal flash attention on each device's own batch rows and heads
+    (:func:`shard_local`): q/k/v stay sharded as the active rule table
+    lays out ``[batch, -, act_heads, kv]``, the sequence is whole."""
+    from dlrover_tpu.ops import flash_attention as fa
+
+    qkv_spec = nn.logical_to_mesh_axes((lr.BATCH, None, lr.ACT_HEADS, lr.KV))
+    args, specs = [q, k, v], [qkv_spec] * 3
+    if segment_ids is not None:
+        args.append(segment_ids)
+        specs.append(nn.logical_to_mesh_axes((lr.BATCH, None)))
+
+    def local(q, k, v, seg=None):
+        return fa.mha(
+            q, k, v, causal=True, segment_ids=seg,
+            block_q=block_q, block_kv=block_kv,
+        )
+
+    return shard_local(
+        local, in_specs=tuple(specs), out_specs=qkv_spec
+    )(*args)
 
 
 class Attention(nn.Module):
@@ -293,11 +317,8 @@ class Attention(nn.Module):
                 # logits against the mostly-empty pool.  Narrower chunks
                 # fall back to XLA: the kernel's 16-sublane tile floor
                 # means a narrow bucket would be pure pad.
-                from dlrover_tpu.ops import flash_attention as fa
-
-                out = fa.mha(
-                    q, k.astype(self.dtype), v.astype(self.dtype),
-                    causal=True,
+                out = _flash_local(
+                    q, k.astype(self.dtype), v.astype(self.dtype), None,
                     block_q=self.flash_block_q,
                     block_kv=self.flash_block_kv,
                 )
@@ -315,39 +336,42 @@ class Attention(nn.Module):
             v = nn.with_logical_constraint(v, spec)
             out = ring_attention(q, k, v, causal=True, segment_ids=segment_ids)
             out = nn.with_logical_constraint(out, spec)
-        else:
-            if self.attention_impl == "flash":
-                from dlrover_tpu.ops import flash_attention as fa
-
-                def attn_fn(q, k, v, seg):
-                    return fa.mha(
-                        q, k, v,
-                        causal=True,
-                        segment_ids=seg,
-                        block_q=self.flash_block_q,
-                        block_kv=self.flash_block_kv,
-                    )
-            elif self.attention_impl == "xla":
-                def attn_fn(q, k, v, seg):
-                    return xla_attention(
-                        q, k, v, causal=True, segment_ids=seg
-                    )
-            else:
-                raise ValueError(
-                    f"unknown attention_impl {self.attention_impl!r}"
-                )
-
+        elif self.attention_impl in ("flash", "xla"):
+            flash = self.attention_impl == "flash"
+            blocks = dict(
+                block_q=self.flash_block_q, block_kv=self.flash_block_kv
+            )
             if mesh_axis_size(SEQ_AXIS) > 1:
                 # Ulysses SP: explicit seq<->heads all-to-alls (see
                 # ulysses_attention docstring for why not annotations).
+                # attn_fn runs inside its shard_map: already device-local.
+                def attn_fn(q, k, v, seg):
+                    if flash:
+                        from dlrover_tpu.ops import flash_attention as fa
+
+                        return fa.mha(
+                            q, k, v, causal=True, segment_ids=seg, **blocks
+                        )
+                    return xla_attention(
+                        q, k, v, causal=True, segment_ids=seg
+                    )
+
                 out = ulysses_attention(attn_fn, q, k, v, segment_ids)
+            elif flash:
+                out = _flash_local(q, k, v, segment_ids, **blocks)
             else:
                 attn_spec = (lr.BATCH, None, lr.ACT_HEADS, lr.KV)
                 q = nn.with_logical_constraint(q, attn_spec)
                 k = nn.with_logical_constraint(k, attn_spec)
                 v = nn.with_logical_constraint(v, attn_spec)
-                out = attn_fn(q, k, v, segment_ids)
+                out = xla_attention(
+                    q, k, v, causal=True, segment_ids=segment_ids
+                )
                 out = nn.with_logical_constraint(out, attn_spec)
+        else:
+            raise ValueError(
+                f"unknown attention_impl {self.attention_impl!r}"
+            )
         out = layers.DenseGeneral(
             features,
             axis=(-2, -1),

@@ -368,49 +368,75 @@ class MoEMlp(nn.Module):
     # -- dropless grouped-GEMM dispatch ---------------------------------------
 
     def _grouped_forward(self, x, router_logits, wi, wg, wo):
+        from jax.sharding import PartitionSpec as P
+
         from dlrover_tpu.ops.grouped_matmul import grouped_matmul
+        from dlrover_tpu.runtime.mesh import shard_local
 
         b, s, d = x.shape
         e, k = self.num_experts, self.top_k
         block = self.gmm_block_rows
-        n = b * s * k
-        # Static row budget: every token-choice plus at most one partial
-        # block of padding per expert, rounded to whole kernel blocks.
-        n_pad = ((n + block - 1) // block + e) * block
 
-        x_flat = x.reshape(b * s, d).astype(self.dtype)
+        # Routing and its statistics are global and plain XLA; only the
+        # sort + grouped GEMMs below run per device.
         gate_vals, gate_idx, aux_loss = _gate(router_logits, k)
-        experts_flat = gate_idx.reshape(n)                   # [N]
-        gates_flat = gate_vals.reshape(n).astype(self.dtype)
-        token_of_choice = jnp.arange(n, dtype=jnp.int32) // k
-
-        # Stable sort by expert: each expert's choices become one
-        # consecutive ragged group.
-        order = jnp.argsort(experts_flat, stable=True)
-        expert_sorted = experts_flat[order]
-        src_token = token_of_choice[order]
-        counts = jnp.zeros((e,), jnp.int32).at[experts_flat].add(1)
+        counts = jnp.zeros((e,), jnp.int32).at[gate_idx.reshape(-1)].add(1)
         # Dropless: routed == total, so drop_fraction books as exactly 0.
         self._sow_router_stats(
-            _router_entropy(router_logits), routed=counts, total=n
+            _router_entropy(router_logits), routed=counts, total=b * s * k
         )
-        padded = ((counts + block - 1) // block) * block     # group sizes
-        group_starts = jnp.cumsum(padded) - padded
-        count_starts = jnp.cumsum(counts) - counts
-        rank = jnp.arange(n, dtype=jnp.int32) - count_starts[expert_sorted]
-        dest = group_starts[expert_sorted] + rank            # [N] row slots
 
-        rows = jnp.zeros((n_pad, d), self.dtype).at[dest].set(
-            x_flat[src_token]
-        )
-        h = grouped_matmul(rows, wi, padded, block)
-        if wg is not None:
-            g = grouped_matmul(rows, wg, padded, block)
-            h = nn.silu(g) * h
-        else:
-            h = nn.gelu(h)
-        out_rows = grouped_matmul(h, wo, padded, block)
+        def local(x, gate_vals, gate_idx, *weights):
+            """Sort this device's token-choices by expert and run each
+            expert's ragged row group through the grouped GEMMs."""
+            wi, wo = weights[0], weights[-1]
+            wg = weights[1] if len(weights) == 3 else None
+            b, s, d = x.shape
+            n = b * s * k
+            # Static row budget: every token-choice plus at most one
+            # partial block of padding per expert, in whole kernel blocks.
+            n_pad = ((n + block - 1) // block + e) * block
 
-        weighted = out_rows[dest] * gates_flat[order][:, None]
-        out = jnp.zeros((b * s, d), self.dtype).at[src_token].add(weighted)
-        return out.reshape(b, s, d), aux_loss.astype(jnp.float32)
+            x_flat = x.reshape(b * s, d).astype(self.dtype)
+            experts_flat = gate_idx.reshape(n)                   # [N]
+            gates_flat = gate_vals.reshape(n).astype(self.dtype)
+            token_of_choice = jnp.arange(n, dtype=jnp.int32) // k
+
+            # Stable sort by expert: each expert's choices become one
+            # consecutive ragged group.
+            order = jnp.argsort(experts_flat, stable=True)
+            expert_sorted = experts_flat[order]
+            src_token = token_of_choice[order]
+            counts = jnp.zeros((e,), jnp.int32).at[experts_flat].add(1)
+            padded = ((counts + block - 1) // block) * block     # group sizes
+            group_starts = jnp.cumsum(padded) - padded
+            count_starts = jnp.cumsum(counts) - counts
+            rank = jnp.arange(n, dtype=jnp.int32) - count_starts[expert_sorted]
+            dest = group_starts[expert_sorted] + rank            # [N] row slots
+
+            rows = jnp.zeros((n_pad, d), self.dtype).at[dest].set(
+                x_flat[src_token]
+            )
+            h = grouped_matmul(rows, wi, padded, block)
+            if wg is not None:
+                g = grouped_matmul(rows, wg, padded, block)
+                h = nn.silu(g) * h
+            else:
+                h = nn.gelu(h)
+            out_rows = grouped_matmul(h, wo, padded, block)
+
+            weighted = out_rows[dest] * gates_flat[order][:, None]
+            out = jnp.zeros((b * s, d), self.dtype).at[src_token].add(weighted)
+            return out.reshape(b, s, d)
+
+        # Tokens stay split over their batch and sequence axes (an MLP is
+        # token-wise); the embed dim and the expert weights are whole on
+        # every device, so peers on a tensor axis repeat each other's work.
+        tokens = nn.logical_to_mesh_axes((lr.BATCH, lr.ACT_SEQ, None))
+        weights = [wi] + ([wg] if wg is not None else []) + [wo]
+        out = shard_local(
+            local,
+            in_specs=(tokens,) * 3 + (P(),) * len(weights),
+            out_specs=tokens,
+        )(x, gate_vals, gate_idx, *weights)
+        return out, aux_loss.astype(jnp.float32)

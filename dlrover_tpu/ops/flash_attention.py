@@ -29,16 +29,14 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from dlrover_tpu.ops import backend
+
 NEG_INF = -1e30
 _LANE = 128
 # Row statistics (lse/delta) ride [.., S, _STAT] arrays: 8 lanes (one f32
 # sublane tile) instead of 128 cuts their HBM footprint/traffic 16x — at
 # bench shapes that is ~200 MB of pure padding per layer per tensor.
 _STAT = 8
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _pad_to(x, size, axis, value=0):
@@ -178,7 +176,7 @@ def _flash_fwd(
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
         out_shape=out_shape,
-        interpret=_interpret(),
+        interpret=backend.interpret(),
     )(seg_q, seg_kv, q, k, v)
     return o, lse[..., 0]
 
@@ -433,7 +431,7 @@ def _flash_bwd_fused(
             jax.ShapeDtypeStruct((b, hq, skv, d), k.dtype),
             jax.ShapeDtypeStruct((b, hq, skv, d), v.dtype),
         ],
-        interpret=_interpret(),
+        interpret=backend.interpret(),
     )(seg_q, seg_kv, q, k, v, do, lse_l, o)
     if group > 1:
         dk = dk.reshape(b, hkv, group, skv, d).sum(axis=2).astype(k.dtype)
@@ -489,7 +487,7 @@ def _flash_bwd(
         ),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         out_shape=jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
-        interpret=_interpret(),
+        interpret=backend.interpret(),
     )(*common_in)
 
     # dk/dv: one pass per q-head; accumulated per kv head afterwards (GQA).
@@ -539,7 +537,7 @@ def _flash_bwd(
             jax.ShapeDtypeStruct((b, hq, skv, d), k.dtype),
             jax.ShapeDtypeStruct((b, hq, skv, d), v.dtype),
         ],
-        interpret=_interpret(),
+        interpret=backend.interpret(),
     )(*common_in)
     if group > 1:
         dk = dk.reshape(b, hkv, group, skv, d).sum(axis=2).astype(k.dtype)

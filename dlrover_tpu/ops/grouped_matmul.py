@@ -23,15 +23,44 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+from dlrover_tpu.ops import backend
 
 
-def _gmm_kernel(expert_of_block, x_ref, w_ref, out_ref):
-    out_ref[:] = jax.lax.dot(
+# VMEM one kernel may spend on its expert-weight tiles (double-buffered
+# blocks plus f32 accumulators); the row blocks of x / dy / out take the
+# rest of v5e's 16 MiB scoped VMEM.  A whole [K, M] expert block overflows
+# it at MoE widths (K=1600, M=3200), so the kernels tile K and M.
+_TILE_BYTES = 8 * 2**20
+
+
+def _lane_tile(dim: int, limit: int) -> int:
+    """Widest divisor of ``dim`` that is a multiple of 128 lanes and at
+    most ``limit`` (at least one lane group); ``dim`` itself when it is
+    not lane-aligned — such a dim can only be a block's full extent."""
+    if dim % 128:
+        return dim
+    lanes = dim // 128
+    fits = [
+        t for t in range(1, lanes + 1)
+        if lanes % t == 0 and t * 128 <= limit
+    ]
+    return 128 * max(fits, default=1)
+
+
+def _gmm_kernel(expert_of_block, x_ref, w_ref, out_ref, acc_ref):
+    kk = pl.program_id(2)
+
+    @pl.when(kk == 0)
+    def _():
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+
+    acc_ref[:] += jax.lax.dot(
         x_ref[:], w_ref[0], preferred_element_type=jnp.float32
-    ).astype(out_ref.dtype)
+    )
+
+    @pl.when(kk == pl.num_programs(2) - 1)
+    def _():
+        out_ref[:] = acc_ref[:].astype(out_ref.dtype)
 
 
 def _expert_of_block(group_sizes, num_blocks, block_rows):
@@ -61,44 +90,51 @@ def _gmm_fwd_impl(x, w, group_sizes, block_rows):
     num_blocks = n // block_rows
     expert_of_block = _expert_of_block(group_sizes, num_blocks, block_rows)
 
+    # Output columns outermost, contraction innermost.  While the whole K
+    # fits (tk == k) an expert's [K, tm] strip stays resident across its
+    # consecutive row blocks; K is split only when M cannot be.
+    tile_elems = _TILE_BYTES // (2 * jnp.dtype(w.dtype).itemsize)
+    tm = _lane_tile(m, tile_elems // k)
+    tk = _lane_tile(k, tile_elems // tm)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(num_blocks,),
+        grid=(m // tm, num_blocks, k // tk),
         in_specs=[
             pl.BlockSpec(
-                (block_rows, k), lambda i, eob: (i, 0),
+                (block_rows, tk), lambda j, i, kk, eob: (i, kk),
                 memory_space=pltpu.VMEM,
             ),
             pl.BlockSpec(
-                (1, k, m), lambda i, eob: (eob[i], 0, 0),
+                (1, tk, tm), lambda j, i, kk, eob: (eob[i], kk, j),
                 memory_space=pltpu.VMEM,
             ),
         ],
         out_specs=pl.BlockSpec(
-            (block_rows, m), lambda i, eob: (i, 0),
+            (block_rows, tm), lambda j, i, kk, eob: (i, j),
             memory_space=pltpu.VMEM,
         ),
+        scratch_shapes=[pltpu.VMEM((block_rows, tm), jnp.float32)],
     )
     return pl.pallas_call(
         _gmm_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n, m), x.dtype),
-        interpret=_interpret(),
+        interpret=backend.interpret(),
     )(expert_of_block, x, w)
 
 
 def _gmm_dw_kernel(eob_ref, x_ref, dy_ref, dw_ref, acc_ref):
-    """Accumulate x_block^T @ dy_block into the owning expert's dw.
+    """Accumulate x_block^T @ dy_block into the owning expert's dw tile.
 
     Row blocks of one expert are consecutive (rows sorted by expert), so the
-    expert's output block stays resident across its run of grid steps; the
+    expert's output tile stays resident across its run of grid steps; the
     accumulator resets at each expert boundary.
     """
-    i = pl.program_id(0)
+    i = pl.program_id(2)
+    last_i = pl.num_programs(2) - 1
     first = jnp.logical_or(i == 0, eob_ref[i] != eob_ref[jnp.maximum(i - 1, 0)])
     last = jnp.logical_or(
-        i == pl.num_programs(0) - 1,
-        eob_ref[i] != eob_ref[jnp.minimum(i + 1, pl.num_programs(0) - 1)],
+        i == last_i, eob_ref[i] != eob_ref[jnp.minimum(i + 1, last_i)]
     )
 
     @pl.when(first)
@@ -131,30 +167,36 @@ def _gmm_bwd(block_rows, residuals, dy):
     ).astype(x.dtype)
     # dw: per-expert accumulation over that expert's row blocks.
     eob = _expert_of_block(group_sizes, num_blocks, block_rows)
+    # Tiles of dw outermost, row blocks innermost: the f32 accumulator and
+    # the double-buffered output block hold one [tk, tm] tile across an
+    # expert's consecutive row blocks.
+    tile_elems = _TILE_BYTES // (4 + 2 * jnp.dtype(w.dtype).itemsize)
+    tm = _lane_tile(m, tile_elems // k)
+    tk = _lane_tile(k, tile_elems // tm)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(num_blocks,),
+        grid=(k // tk, m // tm, num_blocks),
         in_specs=[
             pl.BlockSpec(
-                (block_rows, k), lambda i, eob: (i, 0),
+                (block_rows, tk), lambda a, j, i, eob: (i, a),
                 memory_space=pltpu.VMEM,
             ),
             pl.BlockSpec(
-                (block_rows, m), lambda i, eob: (i, 0),
+                (block_rows, tm), lambda a, j, i, eob: (i, j),
                 memory_space=pltpu.VMEM,
             ),
         ],
         out_specs=pl.BlockSpec(
-            (1, k, m), lambda i, eob: (eob[i], 0, 0),
+            (1, tk, tm), lambda a, j, i, eob: (eob[i], a, j),
             memory_space=pltpu.VMEM,
         ),
-        scratch_shapes=[pltpu.VMEM((k, m), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((tk, tm), jnp.float32)],
     )
     dw = pl.pallas_call(
         _gmm_dw_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((e, k, m), w.dtype),
-        interpret=_interpret(),
+        interpret=backend.interpret(),
     )(eob, x, dy)
     # Experts with no rows are never visited; their dw block is undefined.
     dw = jnp.where((group_sizes > 0)[:, None, None], dw, 0.0).astype(w.dtype)
@@ -165,9 +207,19 @@ grouped_matmul.defvjp(_gmm_fwd, _gmm_bwd)
 
 
 def grouped_matmul_ref(x, w, group_sizes):
-    """XLA reference used in tests and as the CPU fallback."""
-    offsets = jnp.cumsum(group_sizes)
-    experts = jnp.searchsorted(
-        offsets, jnp.arange(x.shape[0]), side="right"
-    )
-    return jnp.einsum("nk,nkm->nm", x, w[experts])
+    """Plain XLA reference: one masked dense matmul per expert.
+
+    E times the kernel's FLOPs but only [N, K] of extra memory, so it
+    stands beside the kernel at MoE widths too (gathering ``w[experts]``
+    per row would take N*K*M elements).  Rows past ``sum(group_sizes)``
+    belong to no expert and come out zero.
+    """
+    ends = jnp.cumsum(group_sizes)
+    rows = jnp.arange(x.shape[0])[:, None]
+    out = jnp.zeros((x.shape[0], w.shape[2]), jnp.float32)
+    for e in range(w.shape[0]):
+        mine = (rows >= ends[e] - group_sizes[e]) & (rows < ends[e])
+        out = out + jnp.dot(
+            jnp.where(mine, x, 0), w[e], preferred_element_type=jnp.float32
+        )
+    return out.astype(x.dtype)

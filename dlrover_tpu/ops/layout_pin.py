@@ -22,9 +22,7 @@ from __future__ import annotations
 import jax
 from jax.experimental import pallas as pl
 
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+from dlrover_tpu.ops import backend
 
 
 def _identity_kernel(x_ref, o_ref):
@@ -32,7 +30,7 @@ def _identity_kernel(x_ref, o_ref):
 
 
 def _pin_call(x: jax.Array) -> jax.Array:
-    if x.ndim < 2 or _interpret():
+    if x.ndim < 2 or backend.interpret():
         # CPU/interpret: layouts don't exist; keep the graph clean.
         return x
     *lead, s, f = x.shape

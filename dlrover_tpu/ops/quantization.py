@@ -23,15 +23,13 @@ import numpy as np
 import optax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec
+
+from dlrover_tpu.ops import backend
+from dlrover_tpu.runtime.mesh import shard_local
 
 BLOCK = 256  # values per quantization block
 _ROWS = 8    # fp32 sublane tile height
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 _ROW_TILE = 512  # rows per kernel grid step (keeps VMEM well under limit)
 
 
@@ -85,7 +83,7 @@ def quantize(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
             jax.ShapeDtypeStruct((rows, cols), jnp.int8),
             jax.ShapeDtypeStruct((rows, 128), jnp.float32),
         ],
-        interpret=_interpret(),
+        interpret=backend.interpret(),
     )(x2)
 
 
@@ -106,7 +104,7 @@ def dequantize(
         out_specs=pl.BlockSpec((tile, cols), lambda i: (i, 0),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct(q.shape, jnp.float32),
-        interpret=_interpret(),
+        interpret=backend.interpret(),
     )(q, scales)
     n = int(np.prod(shape)) if shape else 1
     return out.reshape(-1)[:n].reshape(shape)
@@ -150,6 +148,16 @@ def _q8_adam_kernel(
         jnp.int8
     )
     new_vs_ref[:] = jnp.broadcast_to(v_scale, new_vs_ref.shape)
+
+
+def _whole_leaf(kernel_call):
+    """A quantized-Adam kernel call under a mesh (``shard_local``): the
+    moments are replicated [rows, BLOCK] views of the flattened leaf, so
+    every device updates the whole leaf and a sharded gradient is gathered
+    at the boundary."""
+    return shard_local(
+        kernel_call, in_specs=PartitionSpec(), out_specs=PartitionSpec()
+    )
 
 
 class _QMoment(NamedTuple):
@@ -229,7 +237,7 @@ def q8_adam(
             narrow = lambda: pl.BlockSpec(
                 (tile, 128), lambda i: (i, 0), memory_space=pltpu.VMEM
             )
-            upd2, nmq, nms, nvq, nvs = pl.pallas_call(
+            upd2, nmq, nms, nvq, nvs = _whole_leaf(pl.pallas_call(
                 _q8_adam_kernel,
                 grid=grid,
                 in_specs=[
@@ -244,8 +252,8 @@ def q8_adam(
                     jax.ShapeDtypeStruct((rows, cols), jnp.int8),
                     jax.ShapeDtypeStruct((rows, 128), jnp.float32),
                 ],
-                interpret=_interpret(),
-            )(hyper, g2, p2, m.q, m.scales, v.q, v.scales)
+                interpret=backend.interpret(),
+            ))(hyper, g2, p2, m.q, m.scales, v.q, v.scales)
             upd = upd2.reshape(-1)[: g.size].reshape(p.shape).astype(p.dtype)
             return upd, _QMoment(nmq, nms), _QMoment(nvq, nvs)
 
@@ -281,11 +289,17 @@ def q8_adam(
 _SCALE_LANES = 8
 
 
-def _pack_nibbles_signed(x_int):
-    """[R, BLOCK] int32 in [-7,7] -> [R, BLOCK/2] int8 (lo|hi<<4)."""
-    pairs = x_int.reshape(x_int.shape[0], BLOCK // 2, 2)
-    lo = pairs[..., 0] & 0xF
-    hi = pairs[..., 1] & 0xF
+# Byte j of a packed row holds element j in its low nibble and element
+# j + BLOCK/2 in its high one: both halves are lane-aligned [R, 128] slices,
+# so pack and unpack are shifts and masks on same-shape vectors.  (Pairing
+# neighbours needs a trailing-axis reshape that Mosaic cannot lay out.)
+_HALF = BLOCK // 2
+
+
+def _pack_nibbles(x_int):
+    """[R, BLOCK] int32 nibble values -> [R, BLOCK/2] int8 (lo | hi<<4)."""
+    lo = x_int[:, :_HALF] & 0xF
+    hi = x_int[:, _HALF:] & 0xF
     return (lo | (hi << 4)).astype(jnp.int8)
 
 
@@ -294,16 +308,14 @@ def _unpack_nibbles_signed(packed):
     p = packed.astype(jnp.int32)
     lo = (p << 28) >> 28           # arithmetic shifts sign-extend
     hi = (p << 24) >> 28
-    out = jnp.stack([lo, hi], axis=-1)
-    return out.reshape(packed.shape[0], BLOCK).astype(jnp.float32)
+    return jnp.concatenate([lo, hi], axis=1).astype(jnp.float32)
 
 
 def _unpack_nibbles_unsigned(packed):
     p = packed.astype(jnp.int32) & 0xFF
-    lo = p & 0xF
-    hi = (p >> 4) & 0xF
-    out = jnp.stack([lo, hi], axis=-1)
-    return out.reshape(packed.shape[0], BLOCK).astype(jnp.float32)
+    return jnp.concatenate(
+        [p & 0xF, (p >> 4) & 0xF], axis=1
+    ).astype(jnp.float32)
 
 
 def _q4_adam_kernel(
@@ -335,14 +347,14 @@ def _q4_adam_kernel(
     m_q = (
         jnp.sign(m) * jnp.clip(jnp.round(7.0 * m_n), 0, 7)
     ).astype(jnp.int32)
-    new_mq_ref[:] = _pack_nibbles_signed(m_q)
+    new_mq_ref[:] = _pack_nibbles(m_q)
     new_ms_ref[:] = jnp.broadcast_to(m_scale, new_ms_ref.shape)
 
     v_absmax = jnp.max(v, axis=1, keepdims=True)
     v_scale = jnp.where(v_absmax == 0.0, 1.0, v_absmax)
     v_n = jnp.sqrt(jnp.sqrt(v / v_scale))
     v_q = jnp.clip(jnp.round(15.0 * v_n), 0, 15).astype(jnp.int32)
-    new_vq_ref[:] = _pack_nibbles_signed(v_q)  # [0,15] fits the nibble
+    new_vq_ref[:] = _pack_nibbles(v_q)
     new_vs_ref[:] = jnp.broadcast_to(v_scale, new_vs_ref.shape)
 
 
@@ -423,7 +435,7 @@ def q4_adam(
                 (tile, _SCALE_LANES), lambda i: (i, 0),
                 memory_space=pltpu.VMEM
             )
-            upd2, nmq, nms, nvq, nvs = pl.pallas_call(
+            upd2, nmq, nms, nvq, nvs = _whole_leaf(pl.pallas_call(
                 _q4_adam_kernel,
                 grid=grid,
                 in_specs=[
@@ -438,8 +450,8 @@ def q4_adam(
                     jax.ShapeDtypeStruct((rows, cols // 2), jnp.int8),
                     jax.ShapeDtypeStruct((rows, _SCALE_LANES), jnp.float32),
                 ],
-                interpret=_interpret(),
-            )(hyper, g2, p2, m.q, m.scales, v.q, v.scales)
+                interpret=backend.interpret(),
+            ))(hyper, g2, p2, m.q, m.scales, v.q, v.scales)
             upd = upd2.reshape(-1)[: g.size].reshape(p.shape).astype(p.dtype)
             return upd, _QMoment(nmq, nms), _QMoment(nvq, nvs)
 

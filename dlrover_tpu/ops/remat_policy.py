@@ -12,9 +12,9 @@ host<->HBM DMA instead: the named activations are ``device_put`` to
 This module is the registry that turns the ad-hoc remat strings into
 :class:`RematPolicy` objects carrying
 
-* the jax checkpoint policy (``jax_policy``), with a capability probe and
-  a silent save-only fallback on backends without ``pinned_host`` memory
-  (CPU tests exercise the fallback path end to end);
+* the jax checkpoint policy (``jax_policy``); an offload policy raises on
+  a backend without ``pinned_host`` memory (the CPU backend of the tests
+  has it, so they run the real offload path);
 * the accounting metadata ``auto/tune.py`` prices candidates with
   (HBM-resident activation bytes, recompute fraction, offloaded bytes).
 
@@ -40,8 +40,6 @@ import dataclasses
 from typing import Callable, Dict, Optional, Sequence, Tuple, Union
 
 import jax
-
-from dlrover_tpu.common.log import default_logger as logger
 
 OFFLOAD_SRC = "device"
 OFFLOAD_DST = "pinned_host"
@@ -216,19 +214,9 @@ def validate(name: str, attention_impl: str = "xla") -> RematPolicy:
 
 
 def host_offload_supported(device=None) -> bool:
-    """True when the backend exposes a ``pinned_host`` memory kind AND the
-    installed jax has the names+offload checkpoint policy."""
-    if not hasattr(jax.checkpoint_policies, "save_and_offload_only_these_names"):
-        return False
-    try:
-        device = device if device is not None else jax.devices()[0]
-        kinds = {m.kind for m in device.addressable_memories()}
-    except Exception:  # noqa: BLE001 - conservative: no probe, no offload
-        return False
-    return OFFLOAD_DST in kinds
-
-
-_fallback_warned: set = set()
+    """True when the backend exposes a ``pinned_host`` memory kind."""
+    device = device if device is not None else jax.devices()[0]
+    return OFFLOAD_DST in {m.kind for m in device.addressable_memories()}
 
 
 def jax_policy(
@@ -236,10 +224,9 @@ def jax_policy(
 ) -> Optional[Callable]:
     """The ``jax.ad_checkpoint.checkpoint`` policy callable for a name.
 
-    Offload policies degrade to the equivalent save-only policy (same
-    names, kept in HBM) on backends without ``pinned_host`` memory — a
-    logged warning, never a crash, so the same config runs on CPU test
-    meshes and TPU slices.
+    An offload policy on a backend without ``pinned_host`` memory raises:
+    keeping the names in HBM instead would run another memory plan under
+    the offload policy's name.
     """
     policy = resolve(policy)
     if policy.builtin:
@@ -248,22 +235,17 @@ def jax_policy(
         return None  # "none": no checkpointing at all
     cp = jax.checkpoint_policies
     if policy.offload_names:
-        if host_offload_supported():
-            return cp.save_and_offload_only_these_names(
-                names_which_can_be_saved=list(policy.saved_names),
-                names_which_can_be_offloaded=list(policy.offload_names),
-                offload_src=OFFLOAD_SRC,
-                offload_dst=OFFLOAD_DST,
+        if not host_offload_supported():
+            raise ValueError(
+                f"remat policy {policy.name!r} offloads "
+                f"{list(policy.offload_names)} to {OFFLOAD_DST!r} memory, "
+                f"which {jax.devices()[0].device_kind!r} does not expose; "
+                "pick a save-only policy"
             )
-        if policy.name not in _fallback_warned:
-            _fallback_warned.add(policy.name)
-            logger.warning(
-                "remat policy %r: backend has no %r memory kind; falling "
-                "back to the save-only equivalent (names %s kept in HBM)",
-                policy.name, OFFLOAD_DST,
-                list(policy.saved_names + policy.offload_names),
-            )
-        return cp.save_only_these_names(
-            *policy.saved_names, *policy.offload_names
+        return cp.save_and_offload_only_these_names(
+            names_which_can_be_saved=list(policy.saved_names),
+            names_which_can_be_offloaded=list(policy.offload_names),
+            offload_src=OFFLOAD_SRC,
+            offload_dst=OFFLOAD_DST,
         )
     return cp.save_only_these_names(*policy.saved_names)

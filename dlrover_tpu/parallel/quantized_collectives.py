@@ -37,17 +37,6 @@ import jax.numpy as jnp
 RING_MIN_BYTES = 1 << 20
 
 
-def _axis_size(axis_name: str) -> int:
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    # older jax: the mesh axis size is a trace-time constant
-    return (
-        jax.core.get_axis_env().axis_size(axis_name)
-        if hasattr(jax.core, "get_axis_env")
-        else int(jax.lax.psum(1, axis_name))
-    )
-
-
 def axis_crosses_dcn(mesh, axis_name: str) -> bool:
     """Whether the mesh axis spans TPU slices (so its wire is DCN).
 
@@ -160,7 +149,7 @@ def quantized_all_reduce(
     neighbor hops — see :func:`select_reduce_algo`); the broadcast phase
     is an all-gather either way.
     """
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if n == 1:
         return x
     orig_shape, orig_dtype = x.shape, x.dtype
@@ -206,7 +195,7 @@ def quantized_reduce_scatter(
     on a full-precision all-gather, so quantization noise never touches
     the master weights.
     """
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if n == 1:
         return x
     if x.shape[dim] % n:
@@ -281,7 +270,7 @@ def quantized_all_gather(
     produce bit-identical results — the split only trades launch latency
     against per-hop bandwidth, same as :func:`select_reduce_algo`.
     """
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if n == 1:
         return x
     orig_dtype = x.dtype
@@ -373,7 +362,7 @@ _qa2a.defvjp(_qa2a_fwd, _qa2a_bwd)
 
 
 def _qa2a_impl(x, axis_name, split_axis, concat_axis, block):
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if n == 1:
         return x
     if x.shape[split_axis] % n:

@@ -5,11 +5,13 @@ construction, identical to the one the previous world ran whenever the
 (config, mesh-shape) pair is unchanged — the dominant goodput tax the
 Flash-Checkpoint story leaves on the table.  Two layers remove it:
 
-1. **Persistent XLA compilation cache** (cross-process): ``enable()``
-   points ``jax.config.jax_compilation_cache_dir`` at a directory keyed
-   under the job workdir, so a restarted process re-traces but skips the
-   XLA compile.  Knob: ``DLROVER_TPU_COMPILE_CACHE`` (or an explicit
-   checkpoint-workdir-derived path).
+1. **Persistent XLA compilation cache** (cross-process): a restarted
+   process re-traces but skips the XLA compile.  One rule places it: where
+   ``JAX_COMPILATION_CACHE_DIR`` is set, jax's own handling of that
+   variable is left alone and nothing here names another directory;
+   otherwise the cache is ``<checkout>/.jax_cache`` — a fixed path, because
+   the path is part of every entry's key and a directory that moves never
+   hits.
 2. **In-process ShardedTrain memo** (same-process restarts — e.g. a
    trainer rebuilt after a resize back to a previously-seen mesh shape):
    ``train_cache_key`` names the compiled program by everything that
@@ -19,15 +21,14 @@ Flash-Checkpoint story leaves on the table.  Two layers remove it:
 
 from __future__ import annotations
 
+import collections
 import os
-from typing import Optional
+from typing import Dict, Optional
 
 from dlrover_tpu.common.log import default_logger as logger
 
-# Env knob: set to a directory to enable the persistent XLA compile cache
-# for every trainer in the job (the agent exports it to workers so a
-# restarted worker lands on the same cache).
-ENV_COMPILE_CACHE = "DLROVER_TPU_COMPILE_CACHE"
+# jax's own variable: read by jax at import, never written here.
+ENV_JAX_CACHE_DIR = "JAX_COMPILATION_CACHE_DIR"
 
 # Opt-in override for the CPU-backend gate in ``maybe_enable``: on the CPU
 # backend, a process that *hits* cache entries another process wrote gets a
@@ -40,29 +41,54 @@ ENV_COMPILE_CACHE_CPU_OK = "DLROVER_TPU_COMPILE_CACHE_CPU_OK"
 
 _enabled_dir: Optional[str] = None
 
+# Persistent-cache traffic of this process, counted from jax's monitoring
+# events: a hit is an executable read back, a miss is one compiled and
+# written.  What a restarted trainer reports to prove it compiled nothing.
+_EVENT_NAMES = {
+    "/jax/compilation_cache/cache_hits": "hits",
+    "/jax/compilation_cache/cache_misses": "misses",
+}
+_event_counts: collections.Counter = collections.Counter()
 
-def cache_dir_for(workdir: str) -> str:
-    """The compile-cache directory keyed under a job workdir."""
-    return os.path.join(workdir, "compile_cache")
+
+def _count_event(event: str, **_):
+    name = _EVENT_NAMES.get(event)
+    if name is not None:
+        _event_counts[name] += 1
 
 
-def enable(cache_dir: str) -> str:
-    """Point jax's persistent compilation cache at ``cache_dir``.
+def stats() -> Dict[str, int]:
+    """``{"hits", "misses"}`` of the persistent cache since ``enable()``."""
+    return {name: _event_counts[name] for name in _EVENT_NAMES.values()}
 
-    Idempotent; thresholds are dropped to zero so even the small CPU-mesh
-    test programs populate the cache (the default min-compile-time gate
-    would skip them and hide cache bugs until a real TPU run).
+
+def default_cache_dir() -> str:
+    """``<checkout>/.jax_cache``: fixed, derived from where this package
+    lies, ignored by git.  Also where the embedding store builds its
+    native library."""
+    package = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    return os.path.join(os.path.dirname(package), ".jax_cache")
+
+
+def enable() -> str:
+    """Turn jax's persistent compilation cache on and return its directory.
+
+    Idempotent.  Thresholds are dropped to zero so that every program is
+    cached, the small ones a restart would otherwise recompile included.
     """
     global _enabled_dir
-    cache_dir = os.path.abspath(cache_dir)
-    if _enabled_dir == cache_dir:
-        return cache_dir
-    os.makedirs(cache_dir, exist_ok=True)
+    if _enabled_dir is not None:
+        return _enabled_dir
     import jax
 
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    cache_dir = os.environ.get(ENV_JAX_CACHE_DIR, "")
+    if not cache_dir:
+        cache_dir = default_cache_dir()
+        os.makedirs(cache_dir, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.monitoring.register_event_listener(_count_event)
     _enabled_dir = cache_dir
     logger.info("persistent compilation cache enabled at %s", cache_dir)
     return cache_dir
@@ -72,44 +98,29 @@ def enabled_dir() -> Optional[str]:
     return _enabled_dir
 
 
-def _cpu_backend() -> bool:
-    try:
-        import jax
+def maybe_enable() -> Optional[str]:
+    """``enable()`` unless the backend is the CPU.
 
-        return jax.default_backend() == "cpu"
-    except Exception:  # noqa: BLE001 - no backend => nothing to protect
-        return False
-
-
-def maybe_enable(explicit_dir: str = "", workdir: str = "") -> Optional[str]:
-    """Resolve + enable the cache dir: explicit > env knob > workdir-derived.
-
-    Returns the enabled directory, or None when no source names one (the
-    cache stays off — tests and ad-hoc runs must not write to CWD), or when
-    the backend is CPU: XLA's persisted CPU executables do not survive
-    cross-process reuse (deserialization yields crashing or silently wrong
-    programs), and an elastic restart is precisely a second process reading
-    the first one's entries.  ``ENV_COMPILE_CACHE_CPU_OK=1`` overrides for
-    single-process cache-plumbing tests; ``enable()`` itself stays ungated.
+    XLA's persisted CPU executables do not survive cross-process reuse
+    (deserialization yields crashing or silently wrong programs), and an
+    elastic restart is precisely a second process reading the first one's
+    entries.  ``ENV_COMPILE_CACHE_CPU_OK=1`` overrides for single-process
+    cache-plumbing tests; ``enable()`` itself stays ungated.  Initialises
+    the backend: call it from the process that owns the chip.
     """
-    cache_dir = (
-        explicit_dir
-        or os.environ.get(ENV_COMPILE_CACHE, "")
-        or (cache_dir_for(workdir) if workdir else "")
-    )
-    if not cache_dir:
-        return None
+    import jax
+
     if (
         os.environ.get(ENV_COMPILE_CACHE_CPU_OK, "") != "1"
-        and _cpu_backend()
+        and jax.default_backend() == "cpu"
     ):
-        logger.warning(
+        logger.info(
             "persistent compile cache disabled on the CPU backend "
             "(cross-process executable reuse is unsound there; set %s=1 "
             "to force)", ENV_COMPILE_CACHE_CPU_OK,
         )
         return None
-    return enable(cache_dir)
+    return enable()
 
 
 def train_cache_key(

@@ -129,8 +129,12 @@ def build_mesh(
                 shape, devices=devices, allow_split_physical_axes=True
             )
         except (ValueError, NotImplementedError) as e:
-            # CPU fallback (tests) and odd topologies: plain reshape.
-            logger.debug("create_device_mesh failed (%s); using reshape", e)
+            # No topology-aware assignment for this shape: neighbours on
+            # an axis may then not be ICI neighbours.
+            logger.warning(
+                "create_device_mesh failed (%s: %s); reshaping "
+                "jax.devices() order into the mesh", type(e).__name__, e,
+            )
             device_array = np.asarray(devices).reshape(shape)
     mesh = Mesh(device_array, MESH_AXES)
     logger.info(
@@ -175,45 +179,43 @@ def _smallest_prime_factor(n: int) -> int:
 
 
 def current_mesh():
-    """The ambient mesh, or None: the abstract mesh on jax >= 0.5
-    (``jax.set_mesh``), else the physical context mesh (``with mesh:``)
-    that older jax's thread resources track."""
-    get_abstract = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abstract is not None:
-        mesh = get_abstract()
-        if mesh is not None and not mesh.empty:
-            return mesh
-    from jax._src import mesh as mesh_lib
-
-    physical = mesh_lib.thread_resources.env.physical_mesh
-    if physical is not None and not physical.empty:
-        return physical
-    return None
+    """The ambient mesh (``jax.set_mesh``), or None."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
 
 
 def activate_mesh(mesh):
-    """Context manager making ``mesh`` ambient for tracing and execution:
-    ``jax.set_mesh`` where it exists, else the Mesh context manager (the
-    same scope on older jax)."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+    """Context manager making ``mesh`` ambient for tracing and execution."""
+    return jax.set_mesh(mesh)
 
 
 def shard_map_compat(fn, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` with replication/vma checking off, tolerant of
-    the ``jax.experimental.shard_map`` era (``check_rep``) and the
-    top-level ``jax.shard_map`` era (``check_vma``)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(
+    """``jax.shard_map`` with replication (vma) checking off."""
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False,
+        check_vma=False,
+    )
+
+
+def shard_local(fn, *, in_specs, out_specs):
+    """``fn`` run on each device's local block of its operands.
+
+    Every Pallas kernel reachable under a mesh goes through here: a Mosaic
+    kernel is a custom call the SPMD partitioner refuses to split ("Mosaic
+    kernels cannot be automatically partitioned"), so the kernel must see
+    its local block and the partitioner must never meet it.  Interpret mode
+    lowers a kernel to plain HLO that GSPMD does partition, which is how
+    the CPU-mesh tests passed without this.  ``in_specs``/``out_specs``
+    name the axes the operands may stay sharded on; an operand sharded on
+    an axis its spec omits is gathered at the boundary.  With no ambient
+    mesh, or a mesh of one device, ``fn`` is returned as it is and the
+    lowered program is unchanged.
+    """
+    mesh = current_mesh()
+    if mesh is None or mesh.size == 1:
+        return fn
+    return shard_map_compat(
+        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs
     )
 
 

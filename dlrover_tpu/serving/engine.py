@@ -62,6 +62,7 @@ from dlrover_tpu.common.log import default_logger as logger
 from dlrover_tpu.common.retry import RetryPolicy
 from dlrover_tpu.models.transformer import TransformerConfig
 from dlrover_tpu.rl.generation import SamplingParams
+from dlrover_tpu.runtime import compile_cache
 from dlrover_tpu.serving.bucketing import make_buckets, pad_to_bucket, \
     pick_bucket
 from dlrover_tpu.serving.decode import get_programs, get_spec_programs
@@ -179,6 +180,9 @@ class ServingEngine:
             raise ValueError(f"role must be one of {ROLES}, got {role!r}")
         if buckets is None:
             buckets = make_buckets(max(1, config.max_seq_len // 2))
+        # A restarted replica re-traces its programs but reads their
+        # executables back from the persistent cache.
+        compile_cache.maybe_enable()
         self._base_config = config
         self.role = role
         self.tp: Optional[ServeTPMesh] = (

@@ -77,9 +77,6 @@ class TrainerConfig:
     # AOT lower().compile() the step at construction and report the wall
     # time to the master's goodput ledger (event "compile").
     warmup_compile: bool = False
-    # Persistent XLA compilation cache directory; "" resolves the
-    # DLROVER_TPU_COMPILE_CACHE env knob, then checkpoint_dir/compile_cache.
-    compile_cache_dir: str = ""
     # -- microbatch engine --------------------------------------------------
     # Gradient accumulation: split the global batch into N microbatches
     # and lax.scan the fwd+bwd, accumulating grads on device
@@ -253,9 +250,7 @@ class ElasticTrainer:
         self._fit_max_steps = 0
         # Restart-fast compile, layer 1: persistent XLA cache so a restarted
         # process re-traces but skips compilation.
-        compile_cache.maybe_enable(
-            config.compile_cache_dir, workdir=config.checkpoint_dir
-        )
+        compile_cache.maybe_enable()
         # Microbatch engine: resolve the effective grad_accum for THIS
         # world from the configured reference pairing (config.grad_accum @
         # grad_accum_ref_world, default: the current world), snapped to a
@@ -311,13 +306,20 @@ class ElasticTrainer:
         )
         self.train = self._build_train()
         if config.warmup_compile:
+            before = compile_cache.stats()
             compile_s = self.train.aot_compile()
+            after = compile_cache.stats()
             # 0.0 means the build cache handed back an already-compiled
-            # program — a zero-cost restart, recorded as a cache hit.
+            # program — a zero-cost restart, recorded as a cache hit.  The
+            # persistent_* counts say what the step program did to the
+            # cross-process cache: a restarted trainer hits, never misses.
             detail = {
                 "seconds": round(compile_s, 6),
                 "restart": renv.restart_count() > 0,
                 "cached": compile_s == 0.0,
+                "persistent_hits": after["hits"] - before["hits"],
+                "persistent_misses": after["misses"] - before["misses"],
+                "kernel_calls": self.train.kernel_calls,
             }
             logger.info("compile warmup: %s", detail)
             telemetry.event("compile", duration_s=compile_s, **detail)

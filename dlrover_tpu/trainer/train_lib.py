@@ -278,6 +278,10 @@ class ShardedTrain:
     # utils/memory_profile), captured by aot_compile where the backend
     # provides it — the compiler-side half of the HBM accounting plane.
     memory_analysis: Optional[Dict[str, int]] = None
+    # Compiled Pallas kernels (``tpu_custom_call``) in the AOT step's
+    # optimized program: 0 wherever the kernels ran in interpret mode, so
+    # a chip run can tell that the step it timed holds the kernel it names.
+    kernel_calls: Optional[int] = None
 
     def init(self, rng: jax.Array) -> TrainState:
         with use_mesh(self.mesh):
@@ -313,9 +317,7 @@ class ShardedTrain:
             return 0.0
         t0 = time.perf_counter()
         with use_mesh(self.mesh):
-            abstract_state = jax.eval_shape(
-                self.init_fn, jax.random.PRNGKey(0)
-            )
+            abstract_state = jax.eval_shape(self.init_fn, _ABSTRACT_KEY)
             self._aot_step = self.step_fn.lower(
                 abstract_state, self.batch_avals
             ).compile()
@@ -324,7 +326,17 @@ class ShardedTrain:
         self.memory_analysis = memory_profile.compiled_memory_analysis(
             self._aot_step
         )
-        return time.perf_counter() - t0
+        seconds = time.perf_counter() - t0
+        self.kernel_calls = self._aot_step.as_text().count(
+            'custom_call_target="tpu_custom_call"'
+        )
+        return seconds
+
+
+# Shape of a ``jax.random.PRNGKey``: eval_shape needs no real key, and
+# building one inside the mesh context would run a program on the mesh's
+# devices before anything is compiled for them.
+_ABSTRACT_KEY = jax.ShapeDtypeStruct((2,), jnp.uint32)
 
 
 def _sanitize_boxes(tree):
@@ -536,7 +548,7 @@ def build_sharded_train(
         return _make_state(params, optimizer.init(params))
 
     with use_mesh(mesh), nn.logical_axis_rules(rules):
-        abstract_state = jax.eval_shape(_init_boxed, jax.random.PRNGKey(0))
+        abstract_state = jax.eval_shape(_init_boxed, _ABSTRACT_KEY)
         abstract_state = _sanitize_boxes(abstract_state)
         logical_specs = nn.get_partition_spec(abstract_state)
         state_shardings = nn.logical_to_mesh_sharding(

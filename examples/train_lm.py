@@ -28,10 +28,21 @@ def parse_args():
     p.add_argument("--batch-size", type=int, default=8,
                    help="GLOBAL batch size (constant across elasticity)")
     p.add_argument("--seq-len", type=int, default=128)
-    p.add_argument("--vocab", type=int, default=1024)
-    p.add_argument("--layers", type=int, default=2)
-    p.add_argument("--d-model", type=int, default=128)
-    p.add_argument("--heads", type=int, default=4)
+    p.add_argument("--size", default="",
+                   help="a GPT-2 size of models/gpt2.py (124m, 355m, 774m, "
+                        "1.5b) at its published widths; --layers/--d-model/"
+                        "--heads/--vocab then override single fields. "
+                        "Default: a toy model (2 layers, d_model 128)")
+    p.add_argument("--vocab", type=int, default=None)
+    p.add_argument("--layers", type=int, default=None)
+    p.add_argument("--d-model", type=int, default=None)
+    p.add_argument("--heads", type=int, default=None)
+    p.add_argument("--param-dtype", default="float32",
+                   choices=("float32", "bfloat16"),
+                   help="dtype the parameters are stored in")
+    p.add_argument("--report-every", type=int, default=5,
+                   help="log the loss and report to the master every N "
+                        "steps")
     p.add_argument("--checkpoint-dir", default="")
     p.add_argument("--ckpt-every", type=int, default=10)
     p.add_argument("--dataset-size", type=int, default=100000)
@@ -57,10 +68,6 @@ def parse_args():
     p.add_argument("--warmup-compile", action="store_true",
                    help="AOT-compile the step at startup and report the "
                         "wall time to the master's goodput ledger")
-    p.add_argument("--compile-cache-dir", default="",
-                   help="persistent XLA compilation cache dir (default: "
-                        "$DLROVER_TPU_COMPILE_CACHE, else derived from "
-                        "--checkpoint-dir; restarts skip recompiling)")
     p.add_argument("--grad-accum", type=int, default=1,
                    help="microbatches per step: split the global batch "
                         "into N sequential microbatches and accumulate "
@@ -158,6 +165,7 @@ def parse_args():
 def main():
     args = parse_args()
     import jax
+    import jax.numpy as jnp
 
     from dlrover_tpu.common.log import default_logger as logger
     from dlrover_tpu.data.loader import (
@@ -176,14 +184,23 @@ def main():
     client = renv.master_client()
 
     model_kw = dict(
-        num_layers=args.layers,
-        d_model=args.d_model,
-        num_heads=args.heads,
-        vocab_size=args.vocab,
         max_seq_len=args.seq_len,
         remat=args.remat,
         attention_impl=args.attention_impl,
+        param_dtype=getattr(jnp, args.param_dtype),
     )
+    # Without --size: the toy model.  With it: that size's own widths.
+    toy = {} if args.size else dict(
+        num_layers=2, d_model=128, num_heads=4, vocab_size=1024
+    )
+    for field, given in (
+        ("num_layers", args.layers), ("d_model", args.d_model),
+        ("num_heads", args.heads), ("vocab_size", args.vocab),
+    ):
+        if given is not None:
+            model_kw[field] = given
+        elif field in toy:
+            model_kw[field] = toy[field]
     if args.flash_block_q:
         model_kw["flash_block_q"] = args.flash_block_q
     if args.flash_block_kv:
@@ -195,7 +212,7 @@ def main():
             capacity_factor=args.moe_capacity_factor,
             moe_dispatch=args.moe_dispatch,
         )
-    cfg = gpt2_config("124m", **model_kw)
+    cfg = gpt2_config(args.size or "124m", **model_kw)
     trainer = ElasticTrainer(
         cfg,
         TrainerConfig(
@@ -205,11 +222,11 @@ def main():
             learning_rate=1e-3,
             checkpoint_dir=args.checkpoint_dir,
             ckpt_every=args.ckpt_every,
+            report_every=args.report_every,
             auto_tune=args.auto_tune,
             metrics_lag=args.metrics_lag,
             prefetch_to_device=args.prefetch,
             warmup_compile=args.warmup_compile,
-            compile_cache_dir=args.compile_cache_dir,
             grad_accum=args.grad_accum,
             accum_dtype=args.accum_dtype,
             reduce_quant=args.reduce_quant,
@@ -246,7 +263,7 @@ def main():
     else:
         loader_source = None
     loader = ElasticDataLoader(
-        synthetic_lm_sample_fn(args.vocab, args.seq_len),
+        synthetic_lm_sample_fn(cfg.vocab_size, args.seq_len),
         batch_size=local_batch,
         source=loader_source,
     )

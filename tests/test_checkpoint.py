@@ -69,6 +69,8 @@ def test_shm_handler_roundtrip():
         "w": jnp.arange(12, dtype=jnp.float32).reshape(3, 4),
         "b": np.ones(5, np.int32),
         "nested": {"s": jnp.float32(2.5)},
+        # bf16 params (the 1.5B model's): numpy spells the dtype "<V2".
+        "h": jnp.arange(6, dtype=jnp.bfloat16),
     }
     meta = handler.save_state_dict(state, step=7, extra={"note": "x"})
     assert meta.step == 7
@@ -85,6 +87,9 @@ def test_shm_handler_roundtrip():
     np.testing.assert_array_equal(
         w, np.arange(12, dtype=np.float32).reshape(3, 4)
     )
+    h = [a for p, a in flat.items() if "'h'" in "".join(p)][0]
+    assert h.dtype == jnp.bfloat16
+    np.testing.assert_array_equal(h.astype(np.float32), np.arange(6))
     handler.close(unlink=True)
     reader.close()
 

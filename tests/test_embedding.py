@@ -153,8 +153,8 @@ def test_native_build_retries_once_before_latching(monkeypatch, tmp_path):
             )
         return real_run(cmd, **kw)
 
-    # Fresh module state pointed at a lib path that forces a build.
-    monkeypatch.setattr(store, "_LIB", str(tmp_path / "libkvstore.so"))
+    # Fresh module state pointed at a build dir that forces a build.
+    monkeypatch.setattr(store, "_BUILD_DIR", str(tmp_path))
     monkeypatch.setattr(store, "_lib", None)
     monkeypatch.setattr(store, "_lib_failed", False)
     monkeypatch.setattr(store, "_build_attempts", 0)
@@ -175,7 +175,7 @@ def test_native_build_latches_after_two_failures(monkeypatch, tmp_path):
     def always_fail(cmd, **kw):
         raise real_subprocess.CalledProcessError(1, cmd, stderr="boom")
 
-    monkeypatch.setattr(store, "_LIB", str(tmp_path / "libkvstore.so"))
+    monkeypatch.setattr(store, "_BUILD_DIR", str(tmp_path))
     monkeypatch.setattr(store, "_lib", None)
     monkeypatch.setattr(store, "_lib_failed", False)
     monkeypatch.setattr(store, "_build_attempts", 0)

@@ -110,7 +110,7 @@ def test_q4_adam_state_is_1_25_bytes_per_param():
     # nibble round-trip sanity
     import numpy as np2
     vals = jnp.asarray(np2.arange(-7, 8).repeat(18)[:qz.BLOCK], jnp.int32)
-    packed = qz._pack_nibbles_signed(vals[None, :])
+    packed = qz._pack_nibbles(vals[None, :])
     un = qz._unpack_nibbles_signed(packed)
     np.testing.assert_array_equal(un[0], np2.asarray(vals, np2.float32))
 
@@ -138,8 +138,15 @@ def test_grouped_matmul_fwd(rng):
     np.testing.assert_allclose(out, ref, atol=1e-4, rtol=1e-4)
 
 
-def test_grouped_matmul_grads(rng):
-    e, k, m = 3, 64, 64
+@pytest.mark.parametrize("k,m,tile_bytes", [
+    (64, 64, None),          # one tile per expert block
+    (256, 384, 256 * 1024),  # fwd/dx tile M then K, dw tiles M
+    (384, 200, 256 * 1024),  # M off the lane grid: only K can be split
+])
+def test_grouped_matmul_grads(rng, monkeypatch, k, m, tile_bytes):
+    if tile_bytes is not None:
+        monkeypatch.setattr(gmm, "_TILE_BYTES", tile_bytes)
+    e = 3
     sizes = jnp.asarray([128, 256, 128], jnp.int32)
     n = int(sizes.sum())
     x = jnp.asarray(rng.normal(size=(n, k)), jnp.float32)

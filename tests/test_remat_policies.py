@@ -13,7 +13,6 @@ memory, and (c) that the named saveables actually exist in the jaxpr.
 from __future__ import annotations
 
 import functools
-import logging
 
 import jax
 import jax.numpy as jnp
@@ -134,28 +133,18 @@ def test_config_accepts_selective_offload_strings():
         gpt2_config("124m", remat="offlaod")
 
 
-def test_offload_falls_back_to_save_only_without_pinned_host(monkeypatch):
-    """Satellite: on a backend with no pinned_host memory kind the offload
-    policy must degrade to the save-only equivalent with a logged warning
-    — not crash (CPU test meshes are exactly this backend)."""
+def test_offload_without_pinned_host_raises(monkeypatch):
+    """On a backend with no pinned_host memory kind an offload policy is
+    refused with a sentence — keeping the names in HBM instead would run
+    another memory plan under the offload policy's name.  Where the kind
+    exists (this CPU backend has it) the policy's gradients are those of
+    the un-rematerialized step."""
     monkeypatch.setattr(rp, "host_offload_supported", lambda device=None: False)
-    rp._fallback_warned.clear()
-    records = []
-
-    class _Capture(logging.Handler):
-        def emit(self, record):
-            records.append(record.getMessage())
-
-    handler = _Capture()
-    logging.getLogger("dlrover_tpu").addHandler(handler)
-    try:
-        policy = rp.jax_policy("offload")
-    finally:
-        logging.getLogger("dlrover_tpu").removeHandler(handler)
-    assert policy is not None
-    assert any("pinned_host" in m and "save-only" in m for m in records)
-    # The degraded policy is the save-only twin: grads match a policy that
-    # saves the same names in HBM.
+    with pytest.raises(ValueError, match="pinned_host"):
+        rp.jax_policy("offload")
+    assert rp.jax_policy("flash_res") is not None  # save-only: unaffected
+    monkeypatch.undo()
+    assert rp.host_offload_supported()
     l_off, g_off = _loss_and_grads("offload", "xla")
     l_ref, g_ref = _loss_and_grads("none", "xla")
     np.testing.assert_allclose(float(l_off), float(l_ref), rtol=2e-3)
@@ -166,16 +155,6 @@ def test_offload_falls_back_to_save_only_without_pinned_host(monkeypatch):
             np.asarray(a, np.float64), np.asarray(b, np.float64),
             rtol=2e-3, atol=1e-5,
         )
-    # Warned once, not per trace.
-    rp._fallback_warned.clear()
-    records.clear()
-    logging.getLogger("dlrover_tpu").addHandler(handler)
-    try:
-        rp.jax_policy("offload")
-        rp.jax_policy("offload")
-    finally:
-        logging.getLogger("dlrover_tpu").removeHandler(handler)
-    assert len([m for m in records if "falling" in m or "save-only" in m]) == 1
 
 
 def test_named_saveables_present_in_jaxpr():

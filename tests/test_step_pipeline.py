@@ -360,24 +360,34 @@ def test_warmup_compile_reports_goodput_event(monkeypatch):
 def test_persistent_compile_cache_configured(tmp_path, monkeypatch):
     from dlrover_tpu.runtime import compile_cache
 
-    monkeypatch.delenv(compile_cache.ENV_COMPILE_CACHE, raising=False)
-    # No explicit dir, no env knob, no workdir: the cache stays off.
-    assert compile_cache.maybe_enable("", workdir="") is None
-    cache_dir = str(tmp_path / "cc")
-    enabled = compile_cache.enable(cache_dir)
-    assert os.path.isdir(enabled)
-    assert jax.config.jax_compilation_cache_dir == enabled
-    assert compile_cache.enable(cache_dir) == enabled  # idempotent
-    # Resolution order: explicit > env > workdir-derived.
-    assert compile_cache.cache_dir_for("/w") == "/w/compile_cache"
+    # Record what enable() would set instead of setting it: a cache left on
+    # for the rest of the session would hand later CPU processes this one's
+    # executables, which is the unsound reuse the CPU gate exists against.
+    written = {}
+    monkeypatch.setattr(jax.config, "update", written.__setitem__)
+    monkeypatch.setattr(
+        jax.monitoring, "register_event_listener", lambda fn: None
+    )
+    monkeypatch.setattr(compile_cache, "_enabled_dir", None)
+    monkeypatch.setenv(compile_cache.ENV_JAX_CACHE_DIR, str(tmp_path))
     # On the CPU backend maybe_enable declines (cross-process reuse of
     # persisted CPU executables crashes a resumed trainer); the dedicated
     # opt-in env lets single-process plumbing tests through.
     monkeypatch.delenv(compile_cache.ENV_COMPILE_CACHE_CPU_OK, raising=False)
-    assert compile_cache.maybe_enable("", workdir=str(tmp_path)) is None
+    assert compile_cache.maybe_enable() is None
+    assert compile_cache.enabled_dir() is None and not written
     monkeypatch.setenv(compile_cache.ENV_COMPILE_CACHE_CPU_OK, "1")
-    via_workdir = compile_cache.maybe_enable("", workdir=str(tmp_path))
-    assert via_workdir == os.path.join(str(tmp_path), "compile_cache")
+    enabled = compile_cache.maybe_enable()
+    assert enabled == str(tmp_path) == compile_cache.enabled_dir()
+    # Every program is cached, however small or quick to compile.
+    assert written == {
+        "jax_persistent_cache_min_entry_size_bytes": -1,
+        "jax_persistent_cache_min_compile_time_secs": 0,
+    }
+    # Idempotent: the first resolution holds for the process.
+    monkeypatch.delenv(compile_cache.ENV_JAX_CACHE_DIR)
+    assert compile_cache.enable() == enabled
+    assert set(compile_cache.stats()) == {"hits", "misses"}
 
 
 def test_train_cache_key_sensitivity():

@@ -46,6 +46,11 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
+from dlrover_tpu.utils.devices import (  # noqa: E402
+    device_fields,
+    virtual_cpu_devices,
+)
+
 #: Shape-model agreement for the registry's own accounting (leg 1) and
 #: the zero1 modeled-vs-measured comparison: the only tolerated slack is
 #: replicated scalar leaves (optimizer step counters) the shard model
@@ -132,20 +137,6 @@ def evaluate_memory_gate(result):
     }
     failed = sorted(name for name, held in checks.items() if not held)
     return not failed, failed
-
-
-def _force_cpu_mesh(n_devices: int):
-    """Virtual n-device CPU world, set before jax import (the bench is
-    about bytes accounting, which the CPU backend's shardings preserve)."""
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    if "cpu" in os.environ["JAX_PLATFORMS"]:
-        flags = " ".join(
-            f for f in os.environ.get("XLA_FLAGS", "").split()
-            if "force_host_platform_device_count" not in f
-        )
-        os.environ["XLA_FLAGS"] = (
-            f"{flags} --xla_force_host_platform_device_count={n_devices}"
-        ).strip()
 
 
 def _model_config(args):
@@ -443,7 +434,7 @@ def run_postmortem_leg(args, tmpdir):
 
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
-    _force_cpu_mesh(4)
+    virtual_cpu_devices(4)
 
     import tempfile
 
@@ -465,6 +456,7 @@ def main(argv=None) -> int:
         }
     ok, failed = evaluate_memory_gate(result)
     result["ok"] = ok
+    result["device"] = device_fields()
     result["failed_checks"] = failed
     z = result["zero1"]["legs"]
     kv = {leg["tp"]: leg["measured_kv_b"]

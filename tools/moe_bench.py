@@ -40,6 +40,11 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
+from dlrover_tpu.utils.devices import (  # noqa: E402
+    device_fields,
+    virtual_cpu_devices,
+)
+
 
 def build_parser() -> argparse.ArgumentParser:
     p = argparse.ArgumentParser(description=__doc__.splitlines()[0])
@@ -99,20 +104,6 @@ def evaluate_moe_gate(result):
     }
     failed = sorted(name for name, held in checks.items() if not held)
     return not failed, failed
-
-
-def _force_cpu_mesh(n_devices: int):
-    """Virtual n-device CPU world, set before jax import (the bench is
-    about dispatch structure, which the CPU backend preserves)."""
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    if "cpu" in os.environ["JAX_PLATFORMS"]:
-        flags = " ".join(
-            f for f in os.environ.get("XLA_FLAGS", "").split()
-            if "force_host_platform_device_count" not in f
-        )
-        os.environ["XLA_FLAGS"] = (
-            f"{flags} --xla_force_host_platform_device_count={n_devices}"
-        ).strip()
 
 
 def _config(args, moe: bool):
@@ -297,7 +288,7 @@ def main(argv=None) -> int:
         raise SystemExit(
             f"--experts {args.experts} must divide by --expert {args.expert}"
         )
-    _force_cpu_mesh(args.data * args.expert)
+    virtual_cpu_devices(args.data * args.expert)
     os.environ.setdefault("DLROVER_TPU_JOB", "moe_bench")
 
     from dlrover_tpu.parallel.quantized_collectives import a2a_wire_bytes
@@ -337,6 +328,7 @@ def main(argv=None) -> int:
     }
     ok, failed = evaluate_moe_gate(result)
     result["ok"] = ok
+    result["device"] = device_fields()
     result["failed_checks"] = failed
     result["headline"] = {
         "tokens_per_s_moe": round(moe["tokens_per_s"], 2),

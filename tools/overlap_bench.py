@@ -44,6 +44,11 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
+from dlrover_tpu.utils.devices import (  # noqa: E402
+    device_fields,
+    virtual_cpu_devices,
+)
+
 #: Documented ZeRO-1 parity tolerances (tests/test_zero1.py PARAM_RTOL /
 #: PARAM_ATOL, atol doubled for the extra grad-accum reassociation the
 #: scan-interior reduce-scatter introduces): the parity score is
@@ -107,20 +112,6 @@ def evaluate_overlap_gate(result):
     }
     failed = sorted(name for name, held in checks.items() if not held)
     return not failed, failed
-
-
-def _force_cpu_mesh(n_devices: int):
-    """Virtual n-device CPU world, set before jax import (the bench is
-    about schedule structure, which the CPU backend preserves)."""
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    if "cpu" in os.environ["JAX_PLATFORMS"]:
-        flags = " ".join(
-            f for f in os.environ.get("XLA_FLAGS", "").split()
-            if "force_host_platform_device_count" not in f
-        )
-        os.environ["XLA_FLAGS"] = (
-            f"{flags} --xla_force_host_platform_device_count={n_devices}"
-        ).strip()
 
 
 def _build(args, overlap: bool):
@@ -282,7 +273,7 @@ def run_parity(args):
 
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
-    _force_cpu_mesh(args.data * args.fsdp)
+    virtual_cpu_devices(args.data * args.fsdp)
 
     serialized = _measure_build(args, overlap=False)
     overlapped = _measure_build(args, overlap=True)
@@ -314,6 +305,7 @@ def main(argv=None) -> int:
     }
     ok, failed = evaluate_overlap_gate(result)
     result["ok"] = ok
+    result["device"] = device_fields()
     result["failed_checks"] = failed
     result["headline"] = {
         "hidden_fraction_serialized": round(

@@ -31,7 +31,8 @@ def run(remat: str, batch: int, steps: int, opt_name: str, trace: str | None,
     from dlrover_tpu.parallel import rules as lr
     from dlrover_tpu.runtime.mesh import ParallelConfig, build_mesh
     from dlrover_tpu.trainer import train_lib
-    from bench import chip_peak_tflops, flops_per_token
+    from bench import flops_per_token
+    from dlrover_tpu.auto.tune import chip_specs
 
     config = gpt2_config(
         "1.5b", max_seq_len=SEQ_LEN, param_dtype=jnp.bfloat16,
@@ -72,7 +73,7 @@ def run(remat: str, batch: int, steps: int, opt_name: str, trace: str | None,
 
     tok_s = batch * SEQ_LEN / dt
     ftok = flops_per_token(config)
-    peak = chip_peak_tflops()
+    peak = chip_specs()[0] / 1e12
     mfu = tok_s * ftok / 1e12 / peak
     base = REFERENCE_HFU * peak * 1e12 / ftok
     mem = jax.devices()[0].memory_stats() or {}

@@ -59,8 +59,9 @@ speculation targets:
 
     python tools/serve_bench.py --tp-drill --d-model 32 --vocab 64
 
-Runs on CPU (JAX_PLATFORMS=cpu) by default: the comparison is about
-scheduling, not the chip — both legs run the same compiled programs.
+Runs on the platform jax finds (set ``JAX_PLATFORMS=cpu`` for the CPU: the
+comparison is about scheduling — both legs run the same compiled
+programs) and names it in the ``device`` block of what it writes.
 """
 
 from __future__ import annotations
@@ -73,6 +74,11 @@ import time
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
+
+from dlrover_tpu.utils.devices import (  # noqa: E402
+    device_fields,
+    virtual_cpu_devices,
+)
 
 
 def build_model(args):
@@ -593,6 +599,7 @@ def run_tp_drill(args, out_path: str) -> int:
         ),
         "value": round(value, 3),
         "unit": "x tokens/s",
+        "device": device_fields(),
         "detail": {"ok": ok, "failed_checks": failed_checks, **drill},
     }
     with open(out_path, "w") as f:
@@ -816,6 +823,7 @@ def run_fleet_drill(args, out_path: str) -> int:
             "metric": "requests lost to a mid-flight replica death",
             "value": len(lost),
             "unit": "requests",
+            "device": device_fields(),
             "detail": {"ok": ok, "failed_checks": failed_checks, **drill},
         }
         with open(out_path, "w") as f:
@@ -895,11 +903,8 @@ def main() -> int:
                     help="speculative acceptance rate the gate requires")
     args = ap.parse_args()
 
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     if args.tp_drill:
-        os.environ.setdefault(
-            "XLA_FLAGS", "--xla_force_host_platform_device_count=8"
-        )
+        virtual_cpu_devices(8)
         return run_tp_drill(args, args.out or "SERVE_TP.json")
     if args.fleet_drill:
         return run_fleet_drill(args, args.out or "SERVE_FLEET.json")
@@ -936,6 +941,7 @@ def main() -> int:
         "metric": "continuous-batching speedup over static batching",
         "value": round(speedup, 3),
         "unit": "x tokens/s",
+        "device": device_fields(),
         "detail": {
             "ok": ok,
             "failed_checks": failed_checks,

@@ -513,16 +513,22 @@ class ElasticAgent:
         # A LIVE trainer being torn down (membership change, hang
         # remediation) gets its stacks collected first — where it was
         # stuck is exactly what the post-incident diagnosis needs.
-        stacks = self.dump_trainer_stacks(timeout_s=2.0)
+        upcoming = self._restart_count + 1
+        with self.telemetry.span("restart.stacks", restart_count=upcoming):
+            stacks = self.dump_trainer_stacks(timeout_s=2.0)
         if stacks:
             logger.info(
                 "trainer stacks at restart:\n%s",
                 "\n".join(stacks.splitlines()[:60]),
             )
-        self._restart_count += 1
-        self.telemetry.event("restart", restart_count=self._restart_count)
-        self._stop_workers()
-        self._start_workers()
+        self._restart_count = upcoming
+        self.telemetry.event("restart", restart_count=upcoming)
+        with self.telemetry.span("restart.stop", restart_count=upcoming):
+            self._stop_workers()
+        # Rendezvous and the new trainer's Popen; the trainer's own
+        # ``startup.runtime`` begins where the OS starts that process.
+        with self.telemetry.span("restart.spawn", restart_count=upcoming):
+            self._start_workers()
 
     def _membership_changed(self) -> bool:
         """ref ``_membership_changed:694``: nodes waiting to join (scale-up)
@@ -547,6 +553,7 @@ class ElasticAgent:
             self.config.checkpoint_dir,
             host_index=self.node_id,
             num_hosts=num_hosts,
+            recorder=self.telemetry,
         )
         self._saver.start()
         AsyncCheckpointSaver.register_signal_handlers()
@@ -742,24 +749,31 @@ class ElasticAgent:
                 "process_exit", code=code,
                 restart_count=self._restart_count,
             )
-            self._save_ckpt_to_storage()
-            tail = self._tail_log(30)
-            error = f"exit code {code}"
-            if tail:
-                error += f"\n--- trainer log tail ---\n{tail}"
-            try:
-                action = self.client.report_failure(
-                    error,
-                    exit_code=code,
-                    level="process",
-                    restart_count=self._restart_count,
-                )
-            except ConnectionError:
-                action = (
-                    "restart"
-                    if self._restart_count < self.config.max_restarts
-                    else "stop"
-                )
+            # Everything from here to the new trainer's first step shares
+            # the identifier of the restart it leads to.
+            upcoming = self._restart_count + 1
+            with self.telemetry.span("failure.save", restart_count=upcoming):
+                self._save_ckpt_to_storage()
+            with self.telemetry.span(
+                "failure.report", restart_count=upcoming
+            ):
+                tail = self._tail_log(30)
+                error = f"exit code {code}"
+                if tail:
+                    error += f"\n--- trainer log tail ---\n{tail}"
+                try:
+                    action = self.client.report_failure(
+                        error,
+                        exit_code=code,
+                        level="process",
+                        restart_count=self._restart_count,
+                    )
+                except ConnectionError:
+                    action = (
+                        "restart"
+                        if self._restart_count < self.config.max_restarts
+                        else "stop"
+                    )
             if action == "restart" and (
                 self._restart_count < self.config.max_restarts
             ):

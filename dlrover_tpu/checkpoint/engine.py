@@ -33,6 +33,7 @@ from typing import Any, Callable, Dict, Optional
 import jax
 import numpy as np
 
+from dlrover_tpu.common import telemetry
 from dlrover_tpu.common.log import default_logger as logger
 from dlrover_tpu.common.multi_process import (
     SharedDict,
@@ -271,6 +272,38 @@ class StorageStepReader:
         """Read + verify one complete world group and reshard it into this
         world; None when any host's bytes fail verification (the caller's
         walk then tries the next candidate group / an older step)."""
+        # Storage to host blocks, verification included.
+        with telemetry.span("restore.read", **{"from": "storage"}) as span:
+            read = self._read_step_group(step, expected, host_files)
+            if read is not None and span is not None:
+                span.attrs["bytes"] = sum(
+                    len(d) for d in read[2].values()
+                )
+        if read is None:
+            return None
+        merged, ref_meta, _ = read
+        booked = getattr(ref_meta, "world_size", 0)
+        if booked and booked != expected:
+            logger.warning(
+                "step %d: meta books world %d but filenames say %d "
+                "(shard records drive reassembly; continuing)",
+                step, booked, expected,
+            )
+        if expected != self.num_hosts:
+            logger.info(
+                "cross-world restore: step %d saved by %d hosts -> "
+                "resharded into world of %d hosts",
+                step, expected, self.num_hosts,
+            )
+        else:
+            logger.info("restored step %d from %s", step, self.checkpoint_dir)
+        return self._materialize(merged, ref_meta, shardings, treedef)
+
+    def _read_step_group(
+        self, step: int, expected: int, host_files: Dict[int, str]
+    ):
+        """``(tensors by path, a host's meta, data by host)`` of one world
+        group, every byte verified; None when anything fails."""
         metas: Dict[int, CheckpointMeta] = {}
         datas: Dict[int, bytes] = {}
         for host in host_files:
@@ -329,22 +362,7 @@ class StorageStepReader:
                 )
 
             merged[path] = assemble_tensor(combined, block_loader)
-        booked = getattr(ref_meta, "world_size", 0)
-        if booked and booked != expected:
-            logger.warning(
-                "step %d: meta books world %d but filenames say %d "
-                "(shard records drive reassembly; continuing)",
-                step, booked, expected,
-            )
-        if expected != self.num_hosts:
-            logger.info(
-                "cross-world restore: step %d saved by %d hosts -> "
-                "resharded into world of %d hosts",
-                step, expected, self.num_hosts,
-            )
-        else:
-            logger.info("restored step %d from %s", step, self.checkpoint_dir)
-        return self._materialize(merged, ref_meta, shardings, treedef)
+        return merged, ref_meta, datas
 
     def _verify_host_digest(
         self, step: int, host: int, num_hosts: int, raw: bytes, data: bytes
@@ -426,7 +444,9 @@ class StorageStepReader:
         # (trainer knob booking: grad_accum/reference world, rng, config)
         # without widening every load path's (step, state) return.
         self.last_restored_extra = dict(getattr(meta, "extra", None) or {})
-        return materialize_records(arrays, meta, shardings, treedef)
+        # Host blocks to device arrays under the target shardings.
+        with telemetry.span("restore.place"):
+            return materialize_records(arrays, meta, shardings, treedef)
 
 
 class CheckpointEngine(StorageStepReader):
@@ -481,6 +501,7 @@ class CheckpointEngine(StorageStepReader):
             logger.info(
                 "step %d: shm busy (saver persisting); skip memory save", step
             )
+            telemetry.event("checkpoint.skip", step=step, reason="shm_busy")
             return False
         try:
             t0 = time.monotonic()
@@ -549,12 +570,18 @@ class CheckpointEngine(StorageStepReader):
                 return -1, None
             if shm_ok and shm_step == step:
                 logger.info("restoring step %d from shm", step)
-                arrays = {
-                    t.path: assemble_tensor(
-                        t, lambda r: self._shm.load_block(meta, r)
-                    )
-                    for t in meta.tensors
-                }
+                with telemetry.span(
+                    "restore.read", **{"from": "shm"},
+                    bytes=sum(
+                        r.nbytes for t in meta.tensors for r in t.shards
+                    ),
+                ):
+                    arrays = {
+                        t.path: assemble_tensor(
+                            t, lambda r: self._shm.load_block(meta, r)
+                        )
+                        for t in meta.tensors
+                    }
                 result = self._materialize(arrays, meta, shardings, treedef)
             else:
                 result = self._load_step_from_storage(step, shardings, treedef)

@@ -18,7 +18,7 @@ import time
 import zlib
 from typing import Optional
 
-from dlrover_tpu.common import faults
+from dlrover_tpu.common import faults, telemetry
 from dlrover_tpu.common.log import default_logger as logger
 from dlrover_tpu.common.multi_process import (
     SharedDict,
@@ -56,7 +56,13 @@ class AsyncCheckpointSaver:
         num_hosts: int = 1,
         deletion_strategy: Optional[CheckpointDeletionStrategy] = None,
         commit_timeout: float = 600.0,
+        recorder: Optional[telemetry.TelemetryRecorder] = None,
     ):
+        # Whose timeline lane the persist lands in: the agent hands over
+        # its own recorder; a trainer's in-process saver uses the process's.
+        self._telemetry = (
+            telemetry.recorder() if recorder is None else recorder
+        )
         self.checkpoint_dir = checkpoint_dir
         self.storage = storage or get_checkpoint_storage()
         self.layout = CheckpointDirLayout(checkpoint_dir)
@@ -250,92 +256,109 @@ class AsyncCheckpointSaver:
         # overwrite the arena mid-persist (it skips the save instead).
         if not self._lock.acquire(blocking=True):
             return False
-        try:
-            meta = self._shm.load_meta()
-            if meta is None or meta.step != step:
-                actual = None if meta is None else meta.step
-                logger.warning(
-                    "shm holds step %s, wanted %d; persisting what exists",
-                    actual, step,
-                )
-                if meta is None:
-                    return False
-                step = meta.step
-            t0 = time.monotonic()
-            step_dir = self.layout.step_dir(step)
-            self.storage.safe_makedirs(step_dir)
-            # Keyed by world generation: a re-persist of the same step under
-            # a NEW world must clean this saver's own previous-world files.
-            clean_key = (step, world_gen)
-            if clean_key not in self._cleaned_steps:
-                self._clean_stale_host_files(step, num_hosts, world_hosts)
-                self._cleaned_steps.add(clean_key)
-            faults.fire("saver.persist", step=step)
-            # Integrity chain: stamp a crc32 into every shard record (and a
-            # whole-file digest sidecar) while the bytes are still in shm —
-            # restore re-computes both, so a bit-flip or truncation anywhere
-            # between here and the restoring host is caught, and the step
-            # degrades to an older verified one instead of feeding the
-            # model torn tensors.  crc cost is off the training path (this
-            # is the async saver thread).
-            data = bytes(self._shm.raw_data(meta))
-            for tensor in meta.tensors:
-                for record in tensor.shards:
-                    record.crc32 = zlib.crc32(
-                        memoryview(data)[
-                            record.offset:record.offset + record.nbytes
-                        ]
+        with self._telemetry.span("persist", step=step) as persist:
+            try:
+                meta = self._shm.load_meta()
+                if meta is None or meta.step != step:
+                    actual = None if meta is None else meta.step
+                    logger.warning(
+                        "shm holds step %s, wanted %d; persisting what "
+                        "exists", actual, step,
                     )
-            # World booking for cross-world restore: the meta records which
-            # world persisted it, so a restoring world of a different size
-            # can pick the authoritative group in a mixed step dir and
-            # reshard instead of rejecting the step.
-            meta.world_size = num_hosts
-            meta.world_hosts = (
-                tuple(world_hosts) if world_hosts else (self.host_index,)
-            )
-            meta_bytes = pickle.dumps(meta)
-            self.storage.write(
-                meta_bytes,
-                self.layout.meta_path(step, self.host_index, num_hosts),
-            )
-            self.storage.write(
-                data,
-                self.layout.data_path(step, self.host_index, num_hosts),
-            )
-            self.storage.write(
-                digest_stamp(
-                    zlib.crc32(meta_bytes), zlib.crc32(data), len(data)
-                ),
-                self.layout.digest_path(step, self.host_index, num_hosts),
-            )
-            # The done marker is world-stamped: the commit barrier only
-            # counts markers carrying the sealed world's size, so a stale
-            # done file left by a previous world's persist of the same step
-            # (same host id, different world) can never satisfy the barrier.
-            # It is written LAST: meta/data/digest are all durable before
-            # the step can count toward the commit barrier.
-            self.storage.write(
-                self._done_stamp(num_hosts),
-                self.layout.done_path(step, self.host_index),
-            )
-            logger.info(
-                "host %d persisted step %d in %.2fs",
-                self.host_index, step, time.monotonic() - t0,
-            )
-        finally:
-            self._lock.release()
-        with self._state_lock:
-            self._persisted_step = step
-        self._status.set("persisted_step", step)
-        if is_committer:
-            self.commit_checkpoint(
-                step,
-                expected_hosts=world_hosts,
-                num_hosts=num_hosts,
-                timeout=commit_timeout,
-                world_gen=world_gen,
-            )
+                    if meta is None:
+                        return False
+                    step = meta.step
+                    if persist is not None:
+                        persist.attrs.update(step=step, id=f"step:{step}")
+                t0 = time.monotonic()
+                step_dir = self.layout.step_dir(step)
+                self.storage.safe_makedirs(step_dir)
+                # Keyed by world generation: a re-persist of the same step
+                # under a NEW world must clean this saver's own
+                # previous-world files.
+                clean_key = (step, world_gen)
+                if clean_key not in self._cleaned_steps:
+                    self._clean_stale_host_files(step, num_hosts, world_hosts)
+                    self._cleaned_steps.add(clean_key)
+                faults.fire("saver.persist", step=step)
+                # Integrity chain: stamp a crc32 into every shard record
+                # (and a whole-file digest sidecar) while the bytes are
+                # still in shm — restore re-computes both, so a bit-flip or
+                # truncation anywhere between here and the restoring host
+                # is caught, and the step degrades to an older verified one
+                # instead of feeding the model torn tensors.  crc cost is
+                # off the training path (this is the async saver thread).
+                with self._telemetry.span("persist.copy"):
+                    data = bytes(self._shm.raw_data(meta))
+                if persist is not None:
+                    persist.attrs["bytes"] = len(data)
+                with self._telemetry.span("persist.crc"):
+                    for tensor in meta.tensors:
+                        for record in tensor.shards:
+                            record.crc32 = zlib.crc32(
+                                memoryview(data)[
+                                    record.offset:record.offset + record.nbytes
+                                ]
+                            )
+                # World booking for cross-world restore: the meta records
+                # which world persisted it, so a restoring world of a
+                # different size can pick the authoritative group in a
+                # mixed step dir and reshard instead of rejecting the step.
+                meta.world_size = num_hosts
+                meta.world_hosts = (
+                    tuple(world_hosts) if world_hosts else (self.host_index,)
+                )
+                meta_bytes = pickle.dumps(meta)
+                with self._telemetry.span("persist.write"):
+                    self.storage.write(
+                        meta_bytes,
+                        self.layout.meta_path(step, self.host_index, num_hosts),
+                    )
+                    self.storage.write(
+                        data,
+                        self.layout.data_path(step, self.host_index, num_hosts),
+                    )
+                with self._telemetry.span("persist.crc"):
+                    digest = digest_stamp(
+                        zlib.crc32(meta_bytes), zlib.crc32(data), len(data)
+                    )
+                with self._telemetry.span("persist.write"):
+                    self.storage.write(
+                        digest,
+                        self.layout.digest_path(
+                            step, self.host_index, num_hosts
+                        ),
+                    )
+                    # The done marker is world-stamped: the commit barrier
+                    # only counts markers carrying the sealed world's size,
+                    # so a stale done file left by a previous world's
+                    # persist of the same step (same host id, different
+                    # world) can never satisfy the barrier.  It is written
+                    # LAST: meta/data/digest are all durable before the
+                    # step can count toward the commit barrier.
+                    self.storage.write(
+                        self._done_stamp(num_hosts),
+                        self.layout.done_path(step, self.host_index),
+                    )
+                logger.info(
+                    "host %d persisted step %d in %.2fs",
+                    self.host_index, step, time.monotonic() - t0,
+                )
+            finally:
+                self._lock.release()
+            with self._state_lock:
+                self._persisted_step = step
+            self._status.set("persisted_step", step)
+            self._telemetry.event("persisted", step=step, bytes=len(data))
+            if is_committer:
+                with self._telemetry.span("persist.commit"):
+                    self.commit_checkpoint(
+                        step,
+                        expected_hosts=world_hosts,
+                        num_hosts=num_hosts,
+                        timeout=commit_timeout,
+                        world_gen=world_gen,
+                    )
         return True
 
     def set_world(self, world_hosts: list):

@@ -28,6 +28,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import numpy as np
 
+from dlrover_tpu.common import telemetry
 from dlrover_tpu.common.log import default_logger as logger
 from dlrover_tpu.common.multi_process import SharedMemory, attach_or_none
 
@@ -121,42 +122,56 @@ def pack_pytree(
     selected = [
         (path, _select_shards(leaf)) for path, leaf in leaves_with_paths
     ]
-    for _, (_, _, shards) in selected:
-        for _, block in shards:
-            if isinstance(block, jax.Array):
-                try:
-                    block.copy_to_host_async()
-                except Exception as e:
-                    # Purely a prefetch optimization — np.asarray below
-                    # still materializes the block synchronously — but a
-                    # backend that rejects async copies is worth one line.
-                    logger.debug("copy_to_host_async unavailable: %s", e)
     tensors: List[TensorMeta] = []
     blocks: List[np.ndarray] = []
     offset = 0
-    for path, (global_shape, dtype, shards) in selected:
-        shards = [(index, np.asarray(block)) for index, block in shards]
-        records = []
-        for index, block in shards:
-            block = np.ascontiguousarray(block)
-            records.append(
-                ShardRecord(
-                    index=index,
-                    offset=offset,
-                    nbytes=block.nbytes,
-                    shape=tuple(block.shape),
+    # From the first copy launched to the last shard materialised on the
+    # host: what the device-to-host link (and the device, if it still has
+    # work in flight) makes the save wait for.
+    with telemetry.span("checkpoint.d2h", step=step) as span:
+        t0 = time.monotonic()
+        for _, (_, _, shards) in selected:
+            for _, block in shards:
+                if isinstance(block, jax.Array):
+                    try:
+                        block.copy_to_host_async()
+                    except Exception as e:
+                        # Purely a prefetch optimization — np.asarray
+                        # below still materializes the block synchronously
+                        # — but a backend that rejects async copies is
+                        # worth one line.
+                        logger.debug(
+                            "copy_to_host_async unavailable: %s", e
+                        )
+        launched = time.monotonic()
+        for path, (global_shape, dtype, shards) in selected:
+            shards = [(index, np.asarray(block)) for index, block in shards]
+            records = []
+            for index, block in shards:
+                block = np.ascontiguousarray(block)
+                records.append(
+                    ShardRecord(
+                        index=index,
+                        offset=offset,
+                        nbytes=block.nbytes,
+                        shape=tuple(block.shape),
+                    )
+                )
+                blocks.append(block)
+                offset += block.nbytes
+            tensors.append(
+                TensorMeta(
+                    path=tuple(jax.tree_util.keystr([k]) for k in path),
+                    global_shape=global_shape,
+                    dtype=dtype,
+                    shards=records,
                 )
             )
-            blocks.append(block)
-            offset += block.nbytes
-        tensors.append(
-            TensorMeta(
-                path=tuple(jax.tree_util.keystr([k]) for k in path),
-                global_shape=global_shape,
-                dtype=dtype,
-                shards=records,
-            )
-        )
+        if span is not None:
+            span.attrs["bytes"] = offset
+            span.attrs["shards"] = len(blocks)
+            # Seconds the launches took; the rest is materialisation.
+            span.attrs["launch_s"] = launched - t0
     meta = CheckpointMeta(
         step=step,
         created_at=time.time(),
@@ -192,36 +207,53 @@ class SharedMemoryHandler:
         # data + meta, then publish the header *last*.  A trainer SIGKILLed
         # mid-copy leaves meta_len == 0, which readers treat as "no
         # checkpoint" instead of committing torn tensor bytes.
-        buf[: _HEADER.size] = _HEADER.pack(0)
-        blocks = iter(blocks)
-        for tensor in meta.tensors:
-            for record in tensor.shards:
-                start = data_offset + record.offset
-                dst = np.frombuffer(
-                    buf, dtype=np.uint8, count=record.nbytes, offset=start
-                )
-                dst[:] = next(blocks).reshape(-1).view(np.uint8)
-        buf[_HEADER.size : data_offset] = meta_bytes
-        buf[: _HEADER.size] = _HEADER.pack(len(meta_bytes))
+        with telemetry.span(
+            "checkpoint.shm_write", step=step, bytes=total - data_offset
+        ):
+            buf[: _HEADER.size] = _HEADER.pack(0)
+            blocks = iter(blocks)
+            for tensor in meta.tensors:
+                for record in tensor.shards:
+                    start = data_offset + record.offset
+                    dst = np.frombuffer(
+                        buf, dtype=np.uint8, count=record.nbytes,
+                        offset=start,
+                    )
+                    dst[:] = next(blocks).reshape(-1).view(np.uint8)
+            buf[_HEADER.size : data_offset] = meta_bytes
+            buf[: _HEADER.size] = _HEADER.pack(len(meta_bytes))
         return meta
 
     def _ensure_capacity(self, total: int):
-        if self._shm is not None and self._shm.size < total:
+        if self._shm is not None and self._shm.size >= total:
+            return
+        # Only a save that creates, grows or re-attaches the arena comes
+        # here.  A new mapping's pages are not touched yet: the first write
+        # into them (``checkpoint.shm_write``) pays for that.
+        with telemetry.span("checkpoint.arena", bytes=total) as span:
+            created = self._open_arena(total)
+            if span is not None:
+                span.attrs["created"] = created
+
+    def _open_arena(self, total: int) -> bool:
+        """Attach the arena if one of this name is large enough, else
+        (re)create it; returns whether it was created."""
+        if self._shm is not None:
             self._shm.close()
             self._shm.unlink()
             self._shm = None
-        if self._shm is None:
-            # Round up so small step-to-step growth doesn't recreate.
-            size = max(total, 1 << 20)
-            size = 1 << (size - 1).bit_length()
-            existing = attach_or_none(self.name)
-            if existing is not None:
-                if existing.size >= total:
-                    self._shm = existing
-                    return
-                existing.close()
-                existing.unlink()
-            self._shm = SharedMemory(self.name, create=True, size=size)
+        # Round up so small step-to-step growth doesn't recreate.
+        size = max(total, 1 << 20)
+        size = 1 << (size - 1).bit_length()
+        existing = attach_or_none(self.name)
+        if existing is not None:
+            if existing.size >= total:
+                self._shm = existing
+                return False
+            existing.close()
+            existing.unlink()
+        self._shm = SharedMemory(self.name, create=True, size=size)
+        return True
 
     # -- reader side (agent or restarted trainer) -----------------------------
 

@@ -20,6 +20,18 @@ Design constraints:
   event also carries a wall-clock timestamp derived from one (wall, mono)
   anchor taken at construction, so streams from different hosts merge on
   wall time without per-event ``time.time()`` skew.
+* **Spans nest and group** — an open span knows the span open on the same
+  thread when it started (``attrs["parent"]``, its name) and carries a
+  shared identifier (``attrs["id"]``): ``step:<n>`` for everything done
+  for one training step or one save of that step, ``restart:<n>`` for
+  everything done for one resume.  The identifier is taken from the
+  span's own ``step`` / ``restart_count`` attribute, else inherited from
+  its parent, so self time is a span's duration less its children's.
+* **One clock with the device trace** — a process that has jax calls
+  :func:`install_trace_annotations` once; from then on an open span is
+  also a ``jax.profiler.TraceAnnotation`` named ``dlrover:<name>``, a host
+  row in whatever profiler session is on (next to nothing with none on).
+  This module itself never imports jax: master and agent record without.
 
 Knobs (also surfaced in README):
 
@@ -54,6 +66,11 @@ _FALSY = ("0", "false", "off", "no")
 # site instead.
 RESERVED_ATTRS = frozenset({"name", "duration_s", "t_mono"})
 
+#: Prefix of a span's row in a profiler trace (the one annotation
+#: namespace of the program; ``utils/device_profile`` filters on it).
+TRACE_PREFIX = "dlrover:"
+DEFAULT_TAP_SIZE = 65536
+
 
 def _check_attrs(attrs: Dict[str, Any]):
     bad = RESERVED_ATTRS.intersection(attrs)
@@ -85,7 +102,7 @@ class _Span:
     append under the lock.
     """
 
-    __slots__ = ("_recorder", "name", "attrs", "_t0")
+    __slots__ = ("_recorder", "name", "attrs", "_t0", "_annotation")
 
     def __init__(self, recorder: "TelemetryRecorder", name: str,
                  attrs: Dict[str, Any]):
@@ -93,17 +110,74 @@ class _Span:
         self.name = name
         self.attrs = attrs
         self._t0 = 0.0
+        self._annotation = None
 
     def __enter__(self) -> "_Span":
+        recorder = self._recorder
+        stack = recorder._open_spans()
+        _relate(self.attrs, stack)
+        stack.append(self)
+        annotate = recorder._annotate
+        if annotate is not None:
+            self._annotation = annotate(TRACE_PREFIX + self.name)
+            self._annotation.__enter__()
         self._t0 = time.monotonic()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         duration = time.monotonic() - self._t0
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+        stack = self._recorder._open_spans()
+        if stack and stack[-1] is self:
+            stack.pop()
         if exc_type is not None:
             self.attrs["error"] = exc_type.__name__
         self._recorder._record("span", self.name, self._t0, duration,
                                self.attrs)
+        return False
+
+
+def _relate(attrs: Dict[str, Any], stack: List["_Span"]):
+    """Stamp ``parent`` (the innermost span open on this thread) and the
+    shared ``id`` into ``attrs``: the span's own step or restart, else the
+    parent's."""
+    parent = stack[-1] if stack else None
+    if parent is not None:
+        attrs["parent"] = parent.name
+    if "step" in attrs:
+        attrs["id"] = f"step:{attrs['step']}"
+    elif "restart_count" in attrs:
+        attrs["id"] = f"restart:{attrs['restart_count']}"
+    elif parent is not None and "id" in parent.attrs:
+        attrs["id"] = parent.attrs["id"]
+
+
+class Tap:
+    """A same-process reader's hold on the stream: everything recorded
+    between ``open_tap()`` and ``close()`` stays here (bounded, oldest
+    dropped first) whatever ``ship()``/``drain()`` do to the ring, until
+    the reader ``take()``s it."""
+
+    def __init__(self, recorder: "TelemetryRecorder", size: int):
+        self._recorder = recorder
+        self._events: Deque[WireEvent] = deque(maxlen=max(1, size))
+
+    def take(self) -> List[WireEvent]:
+        """Remove and return what the tap holds."""
+        with self._recorder._lock:
+            out = list(self._events)
+            self._events.clear()
+        return out
+
+    def close(self):
+        self._recorder._close_tap(self)
+
+    def __enter__(self) -> "Tap":
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        self.close()
         return False
 
 
@@ -129,6 +203,12 @@ class TelemetryRecorder:
         self.dropped = 0  # events overwritten before a drain shipped them
         self._anchor_wall = time.time()
         self._anchor_mono = time.monotonic()
+        # Open spans of each thread, innermost last.
+        self._local = threading.local()
+        # ``name -> context manager`` naming an open span in a profiler
+        # trace; None (master, agent, tests) records on the ring alone.
+        self._annotate = None
+        self._taps: Tuple[Tap, ...] = ()
 
     # -- configuration --------------------------------------------------------
 
@@ -150,6 +230,30 @@ class TelemetryRecorder:
     def ring_size(self) -> int:
         return self._ring.maxlen or 0
 
+    def annotate_with(self, factory):
+        """``factory(name)`` returns the context manager that marks an open
+        span in a profiler trace (``jax.profiler.TraceAnnotation``)."""
+        self._annotate = factory
+
+    def open_tap(self, size: int = DEFAULT_TAP_SIZE) -> Tap:
+        """Hold a copy of everything recorded from now on for a reader in
+        this process; see :class:`Tap`."""
+        tap = Tap(self, size)
+        with self._lock:
+            self._taps += (tap,)
+        return tap
+
+    def _close_tap(self, tap: Tap):
+        with self._lock:
+            self._taps = tuple(t for t in self._taps if t is not tap)
+
+    def _open_spans(self) -> List[_Span]:
+        try:
+            return self._local.stack
+        except AttributeError:
+            stack = self._local.stack = []
+            return stack
+
     # -- recording ------------------------------------------------------------
 
     def _wall(self, mono: float) -> float:
@@ -160,19 +264,20 @@ class TelemetryRecorder:
         if not self.enabled:
             return
         attrs.setdefault("src", self.source)
+        wire = (name, kind, self._wall(t_mono), duration_s, attrs)
         with self._lock:
             if len(self._ring) == self._ring.maxlen:
                 self.dropped += 1
-            self._ring.append(
-                (name, kind, self._wall(t_mono), duration_s, attrs)
-            )
+            self._ring.append(wire)
+            for tap in self._taps:
+                tap._events.append(wire)
 
     def span(self, name: str, /, **attrs):
-        """Context manager timing a code region.  Nesting works naturally
-        (each span records independently on exit); mutate ``.attrs`` inside
-        the block to attach results discovered mid-span.  Attrs named after
-        the reserved parameters (``RESERVED_ATTRS``) are rejected with
-        ``ValueError``.
+        """Context manager timing a code region.  Spans nest: each records
+        on exit with its ``parent`` and shared ``id`` (module docstring);
+        mutate ``.attrs`` inside the block to attach results discovered
+        mid-span.  Attrs named after the reserved parameters
+        (``RESERVED_ATTRS``) are rejected with ``ValueError``.
         """
         if attrs:
             _check_attrs(attrs)
@@ -185,10 +290,11 @@ class TelemetryRecorder:
         """Record an instant (or externally-timed) occurrence.
 
         ``t_mono`` backdates the event to a caller-captured
-        ``time.monotonic()`` reading — how modeled sub-phases (e.g. the
-        microbatch engine's accumulate/reduce/update breakdown, which the
-        host cannot observe inside one XLA program) are placed *inside*
-        their enclosing measured span on the Chrome trace.
+        ``time.monotonic()`` reading — how a phase that was timed elsewhere
+        (a capture window's measured device phases, a process's start as
+        the OS booked it) lands where it happened on the Chrome trace.
+        The event's ``parent`` and ``id`` are those of the span open on
+        this thread when it is recorded.
 
         ``duration_s`` and ``t_mono`` are the timing channel, never attrs;
         an attrs dict naming them (or ``name`` — see ``RESERVED_ATTRS``)
@@ -207,6 +313,7 @@ class TelemetryRecorder:
             )
         if not self.enabled:
             return
+        _relate(attrs, self._open_spans())
         self._record("event" if duration_s == 0.0 else "span",
                      name, time.monotonic() if t_mono is None else t_mono,
                      duration_s, attrs)
@@ -307,3 +414,25 @@ def event(name: str, /, duration_s: float = 0.0,
 
 def configure(**kwargs):
     _RECORDER.configure(**kwargs)
+
+
+def install_trace_annotations():
+    """Make the process-wide recorder's open spans rows of the profiler's
+    trace.  Called once by a process that runs jax (the trainer, at
+    start-up); the import stays inside so that master and agent, which
+    import this module, never load jax."""
+    import jax
+
+    _RECORDER.annotate_with(jax.profiler.TraceAnnotation)
+
+
+def process_start_mono() -> Optional[float]:
+    """This process's start as the OS booked it, on ``time.monotonic``'s
+    clock (Linux: both count from boot), or None where /proc cannot say."""
+    try:
+        with open("/proc/self/stat") as f:
+            fields = f.read().rsplit(")", 1)[1].split()
+        start = int(fields[19]) / os.sysconf("SC_CLK_TCK")
+    except (OSError, ValueError, IndexError):
+        return None
+    return start if 0.0 <= start <= time.monotonic() else None

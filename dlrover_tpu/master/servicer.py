@@ -35,6 +35,7 @@ _COUNTER_KINDS: Dict[str, str] = {
     "replica.death": "replica_deaths",
     "process_exit": "worker_exits",
     "worker_start": "worker_starts",
+    "checkpoint.skip": "checkpoint_skipped",
 }
 
 

@@ -53,6 +53,8 @@ class JobTimeline:
         self._step_order: Deque[int] = deque()
         # Lifecycle counters folded out of agent event streams.
         self._restart_counts: Counter = Counter()
+        # node -> newest step its saver reported durable ("persisted").
+        self._persisted_steps: Dict[int, int] = {}
         # Free-form master-side counters (telemetry drops, perf
         # regressions): bump() feeds them, render_metrics exposes them.
         self._counters: Counter = Counter()
@@ -81,6 +83,11 @@ class JobTimeline:
                     )
                 elif name == "restart":
                     self._restart_counts[int(node_id)] += 1
+                elif name == "persisted" and "step" in attrs:
+                    self._persisted_steps[int(node_id)] = max(
+                        self._persisted_steps.get(int(node_id), -1),
+                        int(attrs["step"]),
+                    )
 
     def record(self, node_id: int, name: str, kind: str = "event",
                t_wall: float = 0.0, duration_s: float = 0.0,
@@ -106,6 +113,7 @@ class JobTimeline:
         with self._lock:
             self._events.pop(node_id, None)
             self._restart_counts.pop(node_id, None)
+            self._persisted_steps.pop(node_id, None)
             for per_node in self._step_durations.values():
                 per_node.pop(node_id, None)
 
@@ -421,6 +429,8 @@ class JobTimeline:
             replica_deaths = self._counters.get("replica_deaths", 0)
             worker_exits = self._counters.get("worker_exits", 0)
             worker_starts = self._counters.get("worker_starts", 0)
+            checkpoint_skipped = self._counters.get("checkpoint_skipped", 0)
+            persisted_steps = dict(self._persisted_steps)
         gauge("dlrover_telemetry_dropped_total", dropped,
               "events the node telemetry rings overwrote before a drain")
         gauge("dlrover_perf_regressions_total", regressions,
@@ -435,6 +445,18 @@ class JobTimeline:
               "training worker process exits the agent observed")
         gauge("dlrover_worker_starts_total", worker_starts,
               "training worker process launches the agent performed")
+        gauge("dlrover_checkpoint_skipped_total", checkpoint_skipped,
+              "saves the trainers skipped (arena busy with a persist, or "
+              "a non-finite state)")
+        if persisted_steps:
+            lines.append(
+                "# HELP dlrover_persisted_step newest step the node's "
+                "saver has made durable"
+            )
+            lines.append("# TYPE dlrover_persisted_step gauge")
+            for node_id in sorted(persisted_steps):
+                gauge("dlrover_persisted_step", persisted_steps[node_id],
+                      labels=f'{{node="{node_id}"}}')
         stats = self.step_stats()
         if stats:
             lines.append(

@@ -39,6 +39,27 @@ from dlrover_tpu.trainer import state_digest, train_lib
 from dlrover_tpu.utils.profiler import pipeline_counters
 
 
+# End-of-source sentinel of ``ElasticTrainer._waited``.
+_NO_BATCH = object()
+
+_PROCESS_START_BOOKED = False
+
+
+def _book_process_start(restart: int):
+    """``startup.runtime``: from this process's start, as the OS booked
+    it, to its devices being listed — interpreter, imports and the
+    accelerator runtime's start-up.  Once per process."""
+    global _PROCESS_START_BOOKED
+    started = telemetry.process_start_mono()
+    if _PROCESS_START_BOOKED or started is None:
+        return
+    _PROCESS_START_BOOKED = True
+    telemetry.event(
+        "startup.runtime", duration_s=time.monotonic() - started,
+        t_mono=started, restart_count=restart,
+    )
+
+
 @dataclasses.dataclass
 class TrainerConfig:
     global_batch_size: int = 8
@@ -191,6 +212,14 @@ class ElasticTrainer:
         self.config = config
         self.callbacks = list(callbacks or [])
         self.client = client if client is not None else renv.master_client()
+        # From here on an open span is a ``dlrover:<name>`` row of whatever
+        # profiler session is on.  The start-up spans share the resume's
+        # identifier, so the agent's failure path and this trainer's
+        # start-up read as one interval on the job timeline.
+        telemetry.install_trace_annotations()
+        restart = renv.restart_count()
+        jax.devices()
+        _book_process_start(restart)
         if config.auto_tune:
             from dlrover_tpu.auto import auto_tune
 
@@ -206,7 +235,9 @@ class ElasticTrainer:
             logger.info("auto_tune picked %s", tuned.best.describe())
         self.model_config = model_config
         self.parallel = parallel or ParallelConfig(data=-1)
-        self.mesh = build_mesh(self.parallel)
+        with telemetry.span("startup.mesh", restart_count=restart):
+            self.mesh = build_mesh(self.parallel)
+        t_build = time.monotonic()
         self.model = TransformerLM(model_config)
         self.optimizer = optimizer or train_lib.make_optimizer(
             config.optimizer, learning_rate=config.learning_rate,
@@ -305,6 +336,11 @@ class ElasticTrainer:
             config.reuse_compiled and optimizer is None and rules is None
         )
         self.train = self._build_train()
+        # Model, optimizer and ``build_sharded_train``, up to ``compile``.
+        telemetry.event(
+            "startup.build", duration_s=time.monotonic() - t_build,
+            t_mono=t_build, restart_count=restart,
+        )
         if config.warmup_compile:
             before = compile_cache.stats()
             compile_s = self.train.aot_compile()
@@ -322,10 +358,15 @@ class ElasticTrainer:
                 "kernel_calls": self.train.kernel_calls,
             }
             logger.info("compile warmup: %s", detail)
-            telemetry.event("compile", duration_s=compile_s, **detail)
+            telemetry.event(
+                "compile", duration_s=compile_s,
+                t_mono=time.monotonic() - compile_s,
+                restart_count=restart, **detail,
+            )
             if self.client is not None:
                 self.client.report_event("compile", json.dumps(detail))
-        self.state = self.train.init(jax.random.PRNGKey(0))
+        with telemetry.span("startup.init", restart_count=restart):
+            self.state = self.train.init(jax.random.PRNGKey(0))
         # Classified HBM accounting: None when off, so _report pays one
         # attribute read and nothing else (the same off-path contract as
         # the device profiler above).
@@ -354,13 +395,14 @@ class ElasticTrainer:
             self._ckpt = Checkpointer(
                 config.checkpoint_dir, local_saver=not renv.under_agent()
             )
-            with telemetry.span("restore"):
+            with telemetry.span("restore", restart_count=restart):
                 restored_step, restored = self._ckpt.load_checkpoint(
                     shardings=self.train.state_shardings,
                     state_template=self.state,
                 )
+                if restored is not None:
+                    self.state = self.train.adopt(restored)
             if restored is not None:
-                self.state = self.train.adopt(restored)
                 self.step = restored_step
                 # A restored step is NOT a step this world has committed:
                 # shm restores (and another world's uncommitted files) are
@@ -372,6 +414,19 @@ class ElasticTrainer:
                     "resumed from checkpoint at step %d", restored_step
                 )
                 self._adopt_checkpoint_accum(self._ckpt.last_extra)
+
+    # -- read-only views (measurement harnesses, tools) -------------------------
+
+    @property
+    def checkpointing(self) -> bool:
+        """Whether this trainer saves and restores (``checkpoint_dir``)."""
+        return self._ckpt is not None
+
+    @property
+    def logical_axis_rules(self):
+        """The logical-axis -> mesh-axis rule table the model is sharded
+        by (``parallel/rules.py``)."""
+        return self._rules
 
     # -- microbatch engine -----------------------------------------------------
 
@@ -758,34 +813,13 @@ class ElasticTrainer:
         prof = self._device_profiler
         capturing = prof is not None and prof.arm(self.step + 1)
         t_span = time.monotonic()
+        # The span is also the step's ``dlrover:step`` row in a profiler
+        # trace: a host-side row, not a traced op, so the compiled step
+        # program is untouched (no-retrace contract holds).
         with telemetry.span("step", step=self.step + 1):
-            if capturing:
-                # The annotation marks the step in the device trace; it is
-                # a host-side profiler row, not a traced op — the compiled
-                # step program is untouched (no-retrace contract holds).
-                with prof.annotation("step"):
-                    metrics = self._dispatch_step(batch)
-            else:
-                metrics = self._dispatch_step(batch)
+            metrics = self._dispatch_step(batch)
         if capturing:
             self._finish_capture(t_span)
-        if (
-            self.train.grad_accum > 1 or self.train.zero1
-        ) and telemetry.recorder().enabled:
-            # The accumulate/reduce/update phases live inside one XLA
-            # program, invisible to the host — emit the cost-model
-            # breakdown as sub-spans backdated into the measured step span
-            # (source="modeled") so the job timeline shows the overlap.
-            wall = time.monotonic() - t_span
-            for row in train_lib.microbatch_phase_plan(
-                self.train.grad_accum, self.train.reduce_quant, wall,
-                zero1=self.train.zero1, overlap=self.train.overlap,
-            ):
-                telemetry.event(
-                    row["phase"], duration_s=row["dur"],
-                    t_mono=t_span + row["t0"], step=self.step,
-                    micro=row["micro"], source="modeled",
-                )
         self._last_metrics = metrics
         return metrics
 
@@ -940,7 +974,7 @@ class ElasticTrainer:
         ``train_step`` (whose ``shard_batch`` then passes it through)."""
         if self.config.prefetch_to_device <= 0:
             self._prefetcher = None
-            return loader
+            return self._waited(loader)
         from dlrover_tpu.data.loader import DevicePrefetcher
 
         # The handle is kept for apply_world_change's drain; place_fn
@@ -951,7 +985,18 @@ class ElasticTrainer:
             lambda batch: train_lib.shard_batch(batch, self.train),
             depth=self.config.prefetch_to_device,
         )
-        return self._prefetcher
+        return self._waited(self._prefetcher)
+
+    def _waited(self, source: Iterable) -> Iterable:
+        """``source``'s batches, with the wait for each one spanned
+        (``data_wait``), whichever loader or prefetcher is underneath."""
+        it = iter(source)
+        while True:
+            with telemetry.span("data_wait", step=self.step + 1):
+                batch = next(it, _NO_BATCH)
+            if batch is _NO_BATCH:
+                return
+            yield batch
 
     # -- deferred metrics ------------------------------------------------------
 
@@ -1292,22 +1337,31 @@ class ElasticTrainer:
     # -- checkpoint -----------------------------------------------------------
 
     def save_checkpoint(self):
-        # Checkpoint barrier: drain deferred metrics first, so (a) every
-        # step committed by this save has already been reported/attributed
-        # and (b) _healthy_to_save reads host floats, not device arrays.
-        self._flush_metrics()
         if self._ckpt is None:
-            return
-        if self._healthy_to_save() is False:
-            logger.error(
-                "skipping checkpoint at step %d: state holds non-finite "
-                "values; waiting for the master's restart remediation",
-                self.step,
-            )
+            self._flush_metrics()
             return
         from dlrover_tpu.checkpoint import StorageType
 
+        # The span is everything the training loop is blocked for: the
+        # drain of what the device still had in flight, then the save.
         with telemetry.span("checkpoint", step=self.step):
+            with telemetry.span("checkpoint.drain"):
+                # Checkpoint barrier: drain deferred metrics first, so (a)
+                # every step committed by this save has already been
+                # reported/attributed and (b) _healthy_to_save reads host
+                # floats, not device arrays.
+                self._flush_metrics()
+                healthy = self._healthy_to_save()
+            if healthy is False:
+                logger.error(
+                    "skipping checkpoint at step %d: state holds "
+                    "non-finite values; waiting for the master's restart "
+                    "remediation", self.step,
+                )
+                telemetry.event(
+                    "checkpoint.skip", step=self.step, reason="non_finite"
+                )
+                return
             self._ckpt.save_checkpoint(
                 self.step, self.state, StorageType.DISK,
                 extra=self._accum_extra(),

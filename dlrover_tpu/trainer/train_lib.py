@@ -304,6 +304,11 @@ class ShardedTrain:
         with use_mesh(self.mesh):
             return self.eval_fn(state, batch)
 
+    def compiled_step_text(self) -> str:
+        """The compiled step program as text (its instructions with their
+        ``op_name`` metadata); empty before ``aot_compile``."""
+        return self._aot_step.as_text() if self._aot_step is not None else ""
+
     def aot_compile(self) -> float:
         """``lower().compile()`` the train step before the first batch.
 
@@ -1098,11 +1103,11 @@ def microbatch_phase_plan(
     ratio; update ~4%; the rest accumulates, split evenly over the N
     microbatches).  Rows are dicts ``{"phase", "micro", "t0", "dur"}``
     with times relative to step start — consumed by the trainer's
-    telemetry emission (attr ``source="modeled"``) and by
-    ``tools/trace_steps.py``'s per-microbatch table.
+    ``profile_every`` calibration (the modeled side of measured/modeled)
+    and by ``tools/trace_steps.py``'s per-microbatch table.
 
     ``zero1=True`` replaces the replicated reduce/update tail with the
-    sharded-update phases the trainer books as spans: ``reduce_scatter``
+    sharded-update phases: ``reduce_scatter``
     (half the all-reduce wire — est_comm_time's RS leg, where the int8
     format applies), ``shard_update`` (1/dp of the optimizer FLOPs) and
     ``allgather`` (the updated params riding back, full precision).  The

@@ -67,10 +67,6 @@ _COLLECTIVE_KEYS = (
 #: ``TfrtCpuExecutable::Execute``).
 _HLO_NAME = re.compile(r"^[a-z][a-z0-9._-]*$")
 
-#: Our own TraceAnnotation namespace — host-side rows, never device ops.
-ANNOTATION_PREFIX = "dlrover."
-
-
 def _is_collective(op_name: str) -> bool:
     return any(key in op_name for key in _COLLECTIVE_KEYS)
 
@@ -86,7 +82,8 @@ def _collective_leg(op_name: str) -> Optional[str]:
 
 
 def _is_device_op(name: str) -> bool:
-    if name.startswith(ANNOTATION_PREFIX):
+    # The program's own spans (``dlrover:<name>``): host rows, never ops.
+    if name.startswith(telemetry.TRACE_PREFIX):
         return False
     # Envelope rows (whole-program / while-loop spans) would double-count
     # the leaves; bare integers are XLA's anonymous envelope ids.
@@ -164,8 +161,8 @@ def parse_device_trace(path: str) -> Optional[DeviceWindow]:
     accelerator (``TPU``/``GPU``/``/device:``); a CPU run has none, so the
     parser falls back to the ``/host:CPU`` plane where XLA:CPU books its op
     rows, filtered to HLO-shaped names so host scaffolding
-    (``PjitFunction``, profiler internals, our own ``dlrover.*``
-    annotations) never counts as device time.
+    (``PjitFunction``, profiler internals, our own ``dlrover:*``
+    span rows) never counts as device time.
 
     Returns ``None`` when the trace is unreadable or holds no device ops —
     the degrade-to-no-rows contract: a malformed window must cost the step
@@ -326,13 +323,6 @@ class DeviceProfiler:
         self._window_dir = trace_dir
         return True
 
-    def annotation(self, name: str):
-        """A ``jax.profiler.TraceAnnotation`` in our namespace (host-side
-        marker rows; excluded from device-op accounting by prefix)."""
-        import jax
-
-        return jax.profiler.TraceAnnotation(ANNOTATION_PREFIX + name)
-
     def finish(self) -> Optional[DeviceWindow]:
         """Close the open window; parse it.  The caller must have blocked
         on the step's outputs first (the window only holds what the device
@@ -387,7 +377,7 @@ def emit_measured_phases(
     # Sequential layout inside the step span: compute first, collective
     # after — the real lanes overlap (that is what overlap_fraction
     # reports), but additive placement keeps the device track readable
-    # next to the modeled rows, which make the same presentation choice.
+    # (the modeled phase plan makes the same presentation choice).
     t = t_span
     for kind in ("compute", "collective"):
         seconds = window.seconds(kind)

@@ -26,7 +26,7 @@ import re
 import tempfile
 import threading
 import time
-from typing import Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Deque, Dict, List, Optional, Sequence, Tuple
 
 import jax
 
@@ -243,7 +243,13 @@ class StepPipelineCounters:
     pre-pipeline behavior); label ``"metrics-flush"`` is the ring's batched
     fetch covering ``steps``.  ``sync_block_count`` therefore must read 0 in
     pipelined mode — the tier-1 assertion ``tools/trace_steps.py`` wraps.
+
+    The event log is a window (the newest ``EVENT_WINDOW`` events, thousands
+    of steps); the totals in ``summary()`` are counters kept for the life
+    of the job.
     """
+
+    EVENT_WINDOW = 65536
 
     def __init__(self):
         self._lock = threading.Lock()
@@ -251,7 +257,10 @@ class StepPipelineCounters:
 
     def reset(self):
         with getattr(self, "_lock", threading.Lock()):
-            self.events: List[PipelineEvent] = []
+            self._events: Deque[PipelineEvent] = collections.deque(
+                maxlen=self.EVENT_WINDOW
+            )
+            self._block_counts: collections.Counter = collections.Counter()
             self.host_block_count = 0
             self.host_blocked_s = 0.0
             self.place_count = 0
@@ -263,31 +272,36 @@ class StepPipelineCounters:
             # dlrover_telemetry_dropped_total gauge).
             self.dropped_events = 0
 
+    @property
+    def events(self) -> List[PipelineEvent]:
+        """The event window, oldest first."""
+        with self._lock:
+            return list(self._events)
+
     @contextlib.contextmanager
     def host_block(self, label: str, steps: Sequence[int] = ()):
         t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            dt = time.perf_counter() - t0
-            with self._lock:
-                self.host_block_count += 1
-                self.host_blocked_s += dt
-                self.events.append(
-                    PipelineEvent("block", label, t0, dt, tuple(steps))
-                )
-            # Host blocks are the pipeline's stalls — fold them into the
-            # job timeline so metrics-flush/eval-fetch slices sit next to
-            # the trainer's step spans in the merged Perfetto trace.
-            _telemetry.event(
-                label, duration_s=dt, kind="block", steps=tuple(steps)
-            )
+        # Host blocks are the pipeline's stalls — fold them into the job
+        # timeline (and a profiler trace) so metrics-flush/eval-fetch
+        # slices sit inside the span that waited for them.
+        with _telemetry.span(label, kind="block", steps=tuple(steps)):
+            try:
+                yield
+            finally:
+                dt = time.perf_counter() - t0
+                with self._lock:
+                    self.host_block_count += 1
+                    self.host_blocked_s += dt
+                    self._block_counts[label] += 1
+                    self._events.append(
+                        PipelineEvent("block", label, t0, dt, tuple(steps))
+                    )
 
     def record_place(self, duration_s: float = 0.0, label: str = "h2d"):
         with self._lock:
             index = self.place_count
             self.place_count += 1
-            self.events.append(
+            self._events.append(
                 PipelineEvent("place", label, time.perf_counter(),
                               duration_s, (index,))
             )
@@ -305,7 +319,7 @@ class StepPipelineCounters:
         with self._lock:
             self.dispatch_count += 1
             self.dispatch_s += duration_s
-            self.events.append(
+            self._events.append(
                 PipelineEvent("dispatch", "step", time.perf_counter(),
                               duration_s, (step,))
             )
@@ -315,7 +329,7 @@ class StepPipelineCounters:
     def blocks(self, label: Optional[str] = None) -> List[PipelineEvent]:
         with self._lock:
             return [
-                e for e in self.events
+                e for e in self._events
                 if e.kind == "block" and (label is None or e.label == label)
             ]
 
@@ -330,7 +344,7 @@ class StepPipelineCounters:
         """One row per dispatched step: host dispatch time vs attributed
         blocking time — the timeline ``tools/trace_steps.py`` dumps."""
         with self._lock:
-            events = list(self.events)
+            events = list(self._events)
         rows: Dict[int, Dict] = {}
         for e in events:
             if e.kind == "dispatch":
@@ -355,14 +369,8 @@ class StepPipelineCounters:
             return {
                 "host_block_count": self.host_block_count,
                 "host_blocked_s": self.host_blocked_s,
-                "sync_block_count": len([
-                    e for e in self.events
-                    if e.kind == "block" and e.label == "metrics"
-                ]),
-                "flush_block_count": len([
-                    e for e in self.events
-                    if e.kind == "block" and e.label == "metrics-flush"
-                ]),
+                "sync_block_count": self._block_counts["metrics"],
+                "flush_block_count": self._block_counts["metrics-flush"],
                 "place_count": self.place_count,
                 "dispatch_count": self.dispatch_count,
                 "dispatch_s": self.dispatch_s,

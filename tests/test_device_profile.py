@@ -108,14 +108,14 @@ def test_parse_prefers_real_device_plane_over_host(tmp_path):
 
 def test_parse_cpu_fallback_filters_host_scaffolding(tmp_path):
     # No accelerator pid: fall back to the CPU plane, but only HLO-shaped
-    # rows count — host scaffolding, our own dlrover.* annotations, jit_*
+    # rows count — host scaffolding, our own dlrover:* span rows, jit_*
     # and anonymous while/digit envelopes are all rejected.
     events = [
         _meta(7, "/host:CPU"),
         _op(7, "PjitFunction(f)", 0, 999),
         _op(7, "$profiler.py:91 start_trace", 0, 999),
         _op(7, "TfrtCpuExecutable::Execute", 0, 999),
-        _op(7, "dlrover.step", 0, 999),
+        _op(7, "dlrover:step", 0, 999),
         _op(7, "jit_train_step", 0, 999),
         _op(7, "while.3", 0, 999),
         _op(7, "42", 0, 999),

@@ -77,9 +77,9 @@ def test_event_duration_selects_kind():
 
 
 def test_event_t_mono_backdates():
-    """Modeled sub-phases (microbatch accumulate/reduce/update) are
-    recorded after their enclosing step span closes but placed at
-    caller-captured times inside it."""
+    """Phases timed elsewhere (a capture window's measured device phases,
+    a process's start as the OS booked it) are recorded afterwards but
+    placed at caller-captured times."""
     r = _recorder()
     t0 = time.monotonic() - 2.5
     r.event("accumulate", duration_s=1.0, t_mono=t0, micro=0)
@@ -154,6 +154,17 @@ def test_disabled_mode_allocates_nothing_per_event():
     with r.span("c"):
         pass
     assert len(r) == 0 and r.drain() == []
+    # ... nor open a profiler row, nor keep a stack of open spans, nor feed
+    # a tap: with an annotation factory installed the answer is the same.
+    made = []
+    r.annotate_with(made.append)
+    with r.open_tap() as tap:
+        assert r.span("step", step=1) is telemetry._NULL_SPAN
+        with r.span("step", step=1):
+            with r.span("checkpoint.d2h"):
+                r.event("checkpoint.skip", step=1, reason="shm_busy")
+        assert tap.take() == []
+    assert made == [] and not hasattr(r._local, "stack")
 
 
 def test_env_knobs(monkeypatch):
@@ -768,3 +779,25 @@ def test_host_blocks_fold_into_module_recorder():
     flush = events[names.index("metrics-flush")]
     assert flush[1] == "span" and flush[4]["steps"] == (3, 4)
     assert flush[4]["kind"] == "block"
+
+
+def test_host_blocks_know_the_span_that_waited_for_them():
+    from dlrover_tpu.utils.profiler import pipeline_counters
+
+    recorder = telemetry.recorder()
+    was_enabled = recorder.enabled
+    recorder.configure(enabled=True)
+    try:
+        with recorder.open_tap() as tap:
+            with telemetry.span("checkpoint", step=8):
+                with telemetry.span("checkpoint.drain"):
+                    with pipeline_counters().host_block(
+                        "metrics-flush", steps=(7, 8)
+                    ):
+                        pass
+            events = tap.take()
+    finally:
+        recorder.configure(enabled=was_enabled)
+    (flush,) = [e for e in events if e[0] == "metrics-flush"]
+    assert flush[4]["parent"] == "checkpoint.drain"
+    assert flush[4]["id"] == "step:8"

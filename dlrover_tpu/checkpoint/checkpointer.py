@@ -30,9 +30,18 @@ class Checkpointer:
     Usage::
 
         ckpt = Checkpointer(checkpoint_dir, local_saver=True)
-        ckpt.save_checkpoint(step, state)                    # shm only, ~ms
+        ckpt.save_checkpoint(step, state)                    # shm only
         ckpt.save_checkpoint(step, state, StorageType.DISK)  # + async persist
         step, state = ckpt.load_checkpoint(train.state_shardings, treedef)
+
+    Either call returns once the state is in the shared-memory arena, so a
+    SIGKILL after it finds that step there; the persist runs behind the
+    training loop.  What the call blocks for is the device-to-host path:
+    measured on a TPU v5e at 3.37 GB of state (PERF.md §5), half a second
+    (6.7 GB/s) once the arena's pages are resident, some ten seconds for
+    a job's first save, which touches them for the first time, and 6-7 s
+    where a save falls back to the per-shard copy (0.5 GB/s; event
+    ``checkpoint.d2h_fallback``).
     """
 
     def __init__(

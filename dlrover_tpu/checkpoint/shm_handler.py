@@ -19,8 +19,12 @@ its global index so restore can reassemble under any new sharding.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
+import functools
+import os
 import pickle
+import resource
 import struct
 import time
 from typing import Any, Dict, List, Optional, Tuple
@@ -102,9 +106,87 @@ def _select_shards(leaf) -> Tuple[Tuple[int, ...], str, List[Tuple[Tuple, Any]]]
             shard = leaf.addressable_shards[0]
             shards.append((_slices_to_index(shard.index, leaf.shape), shard.data))
         return tuple(leaf.shape), np.dtype(leaf.dtype).name, shards
-    block = np.asarray(leaf)
-    index = tuple((0, d) for d in block.shape)
-    return tuple(block.shape), block.dtype.name, [(index, block)]
+    block = np.ascontiguousarray(leaf)
+    index = tuple((0, d) for d in np.shape(leaf))
+    return tuple(np.shape(leaf)), block.dtype.name, [(index, block)]
+
+
+def _plan_pytree(
+    state: Any, step: int, extra: Optional[Dict[str, Any]]
+) -> Tuple[CheckpointMeta, List[Any]]:
+    """The meta of a save and, in the order of its records, the block each
+    record's bytes come from (a single-device ``jax.Array`` or a host
+    array).  Nothing is copied: a record's size is its block's."""
+    tensors: List[TensorMeta] = []
+    sources: List[Any] = []
+    offset = 0
+    for path, leaf in jax.tree_util.tree_flatten_with_path(state)[0]:
+        global_shape, dtype, shards = _select_shards(leaf)
+        records = []
+        for index, block in shards:
+            records.append(
+                ShardRecord(
+                    index=index,
+                    offset=offset,
+                    nbytes=block.nbytes,
+                    # A scalar's block is stored as one element, (1,).
+                    shape=tuple(block.shape) or (1,),
+                )
+            )
+            sources.append(block)
+            offset += block.nbytes
+        tensors.append(
+            TensorMeta(
+                path=tuple(jax.tree_util.keystr([k]) for k in path),
+                global_shape=global_shape,
+                dtype=dtype,
+                shards=records,
+            )
+        )
+    meta = CheckpointMeta(
+        step=step,
+        created_at=time.time(),
+        tensors=tensors,
+        extra=dict(extra or {}),
+    )
+    return meta, sources
+
+
+def _minor_faults() -> int:
+    """Pages this process has touched for the first time, so far."""
+    return resource.getrusage(resource.RUSAGE_SELF).ru_minflt
+
+
+def _as_bytes(block: np.ndarray) -> np.ndarray:
+    return np.ascontiguousarray(block).reshape(-1).view(np.uint8)
+
+
+def _fetch_per_shard(sources: List[Any]) -> Tuple[List[np.ndarray], float]:
+    """Every block on the host, each device block by a copy of its own
+    into a host buffer the runtime allocates for it; also the seconds the
+    launches took (the rest is the wait for the copies).
+
+    Measured (TPU v5e, GPT-2 1.5B, 3.37 GB in 66 bf16 blocks; PERF.md §5):
+    0.50-0.55 GB/s, whether or not the 66 copies are launched together
+    and whether or not the host memory is recycled.  The runtime brings
+    each block from its tiled (for some shapes transposed) device layout
+    to row-major on the way, and that, not the link, is the time: the
+    same bytes flattened on the device first are in the arena in 0.5 s.
+    """
+    t0 = time.monotonic()
+    for block in sources:
+        if isinstance(block, jax.Array):
+            try:
+                # A shard's ``.data`` is a distinct jax.Array whose host
+                # cache the logical parent's copy does not warm.
+                block.copy_to_host_async()
+            except Exception as e:
+                # Purely a prefetch — np.asarray below still materializes
+                # the block — but a backend that rejects async copies is
+                # worth one line.
+                logger.debug("copy_to_host_async unavailable: %s", e)
+    launch_s = time.monotonic() - t0
+    return [np.ascontiguousarray(block) for block in sources], launch_s
 
 
 def pack_pytree(
@@ -112,117 +194,291 @@ def pack_pytree(
 ) -> Tuple[CheckpointMeta, List[np.ndarray]]:
     """Flatten ``state`` into (meta, ordered blocks). Pure — no shm I/O.
 
-    D2H cost model: every per-shard ``np.asarray`` is a blocking transfer, so
-    we first start ``copy_to_host_async`` on *every shard array* (not the
-    logical parent — a shard's ``.data`` is a distinct jax.Array whose host
-    cache the parent's copy does not warm), then materialize; all transfers
-    overlap and total time is max-transfer, not sum-of-round-trips.
+    Every block comes to the host by the per-shard copy
+    (``_fetch_per_shard``: 0.5 GB/s on a v5e).  A save into the arena
+    takes the staged path of ``SharedMemoryHandler.save_state_dict``.
     """
-    leaves_with_paths = jax.tree_util.tree_flatten_with_path(state)[0]
-    selected = [
-        (path, _select_shards(leaf)) for path, leaf in leaves_with_paths
-    ]
-    tensors: List[TensorMeta] = []
-    blocks: List[np.ndarray] = []
-    offset = 0
+    meta, sources = _plan_pytree(state, step, extra)
     # From the first copy launched to the last shard materialised on the
-    # host: what the device-to-host link (and the device, if it still has
-    # work in flight) makes the save wait for.
-    with telemetry.span("checkpoint.d2h", step=step) as span:
-        t0 = time.monotonic()
-        for _, (_, _, shards) in selected:
-            for _, block in shards:
-                if isinstance(block, jax.Array):
-                    try:
-                        block.copy_to_host_async()
-                    except Exception as e:
-                        # Purely a prefetch optimization — np.asarray
-                        # below still materializes the block synchronously
-                        # — but a backend that rejects async copies is
-                        # worth one line.
-                        logger.debug(
-                            "copy_to_host_async unavailable: %s", e
-                        )
-        launched = time.monotonic()
-        for path, (global_shape, dtype, shards) in selected:
-            shards = [(index, np.asarray(block)) for index, block in shards]
-            records = []
-            for index, block in shards:
-                block = np.ascontiguousarray(block)
-                records.append(
-                    ShardRecord(
-                        index=index,
-                        offset=offset,
-                        nbytes=block.nbytes,
-                        shape=tuple(block.shape),
-                    )
-                )
-                blocks.append(block)
-                offset += block.nbytes
-            tensors.append(
-                TensorMeta(
-                    path=tuple(jax.tree_util.keystr([k]) for k in path),
-                    global_shape=global_shape,
-                    dtype=dtype,
-                    shards=records,
-                )
-            )
+    # host: what the device-to-host path (and the device, if it still has
+    # work in flight) makes the caller wait for.
+    with telemetry.span(
+        "checkpoint.d2h", step=step, path="per_shard", groups=0
+    ) as span:
+        faults = _minor_faults()
+        blocks, launch_s = _fetch_per_shard(sources)
         if span is not None:
-            span.attrs["bytes"] = offset
+            span.attrs["bytes"] = sum(b.nbytes for b in blocks)
             span.attrs["shards"] = len(blocks)
-            # Seconds the launches took; the rest is materialisation.
-            span.attrs["launch_s"] = launched - t0
-    meta = CheckpointMeta(
-        step=step,
-        created_at=time.time(),
-        tensors=tensors,
-        extra=dict(extra or {}),
-    )
+            span.attrs["launch_s"] = launch_s
+            span.attrs["minflt"] = _minor_faults() - faults
     return meta, blocks
+
+
+# -- the staged device-to-host path ---------------------------------------------
+
+#: Bytes of one staged transfer: a run of a block's elements in row-major
+#: order, as a 1-D array made on the device.  Measured on a v5e (PERF.md
+#: §5; 3.37 GB, seconds a save): pieces of 8 and 16 MiB 0.49-0.54, of 32
+#: MiB 1.18-1.20, of 64 MiB 1.2-1.7, of 128 MiB 2.7.  The runtime hands
+#: each piece over in a host buffer it allocates for it; from 32 MiB on
+#: glibc maps such a buffer afresh, and first touches are what is slow.
+_PIECE_BYTES = 16 << 20
+#: Bytes launched ahead of the piece being copied into the arena: with one
+#: more program's pieces, all the host memory a save takes, whatever the
+#: size of the state (128 MiB: 0.61 s, 256 MiB: 0.49-0.52, 512 MiB: 0.58).
+_IN_FLIGHT_BYTES = 16 * _PIECE_BYTES
+#: Pieces one program makes.  Every program flattens its whole block into
+#: a temporary before it cuts, so with a program a piece a block of 1 GB
+#: is flattened sixty times over and the device, busy all through the
+#: save, is what the transfers wait for (0.96-0.98 s a save; 8 or 16 pieces
+#: a program: 0.49-0.52 s, a third of it transfers, the rest the copy
+#: into the arena).
+_GROUP_PIECES = 8
+#: A block smaller than this takes the per-shard copy: it is too small to
+#: pay for a program.
+_STAGED_MIN_BYTES = 4 << 20
+
+
+def _flat_pieces(block, start, *, sizes: Tuple[int, ...]):
+    """Consecutive runs of ``sizes`` elements of ``block`` in row-major
+    order, from element ``start``, each as a 1-D array: the bytes as a
+    host reader wants them, in a layout the runtime copies out without
+    touching.
+
+    XLA's TPU compiler flattens the whole block into a temporary (the
+    device has the HBM between steps) and compiles this in 0.2-0.6 s
+    whatever the shape.  A reshape of a run of rows alone needs no such
+    temporary, but takes the compiler up to 30 s where the minor dimension
+    is no multiple of 128; and pieces put together row by row in a loop on
+    the device come out right and cross at a third of the rate (PERF.md
+    §6, PR 25).
+    """
+    flat = block.reshape(-1)
+    pieces = []
+    for size in sizes:
+        pieces.append(jax.lax.dynamic_slice(flat, (start,), (size,)))
+        start = start + size
+    return tuple(pieces)
+
+
+def _program_bytes(compiled) -> int:
+    """HBM a compiled piece program needs beside its input: its pieces and
+    the flattened block (twice that for a block the TPU stores
+    transposed)."""
+    stats = compiled.memory_analysis()
+    return int(stats.temp_size_in_bytes + stats.output_size_in_bytes)
+
+
+def _free_device_bytes(device) -> Optional[int]:
+    """Free memory of ``device``, or None where the backend keeps no
+    count (the CPU's)."""
+    stats = device.memory_stats()
+    if not stats or "bytes_limit" not in stats:
+        return None
+    return int(stats["bytes_limit"] - stats["bytes_in_use"])
+
+
+@dataclasses.dataclass(frozen=True)
+class _StagedPlan:
+    """How the blocks of one shape, dtype and device cross."""
+
+    #: (first element, elements of each piece) of every program call
+    groups: List[Tuple[int, Tuple[int, ...]]]
+    programs: Dict[Tuple[int, ...], Any]  # sizes -> compiled _flat_pieces
+    device_bytes: int  # HBM the largest program needs beside its input
 
 
 class SharedMemoryHandler:
     """Owns one shm arena (per training process) and packs pytrees into it."""
 
     def __init__(self, name: str):
-        import os
-
         job = os.environ.get("DLROVER_TPU_JOB", "")
         tag = f"{job}_" if job else ""
         self.name = f"dlrover_tpu_ckpt_{tag}{name}".replace("/", "_")
         self._shm: Optional[SharedMemory] = None
+        # Staged plans by (shape, dtype, device): compiled at the first
+        # save that meets a block of that kind, and never again.
+        self._staged: Dict[Tuple, Optional[_StagedPlan]] = {}
+        #: Path the last save took and the bytes a second it moved from
+        #: the device into the arena.
+        self.last_d2h: Dict[str, Any] = {}
 
     # -- writer side (trainer) ------------------------------------------------
 
     def save_state_dict(
         self, state: Any, step: int, extra: Optional[Dict[str, Any]] = None
     ) -> CheckpointMeta:
-        meta, blocks = pack_pytree(state, step, extra)
+        """Write ``state`` into the arena; returns once every byte is
+        there and the header is published.
+
+        The device-to-host path, as measured on a v5e (PERF.md §5): the
+        runtime brings 1-D arrays of 16 MiB to the host faster than one
+        thread copies them on into the arena (3.37 GB in 0.5 s, both
+        included), and arrays in their device layout at 0.5 GB/s.  So
+        each large block is flattened on the device piece by piece
+        (``_flat_pieces``), pieces are in flight while the last one is
+        copied to its record's place in the arena, and the host memory a
+        save takes does not grow with the state.
+        Small blocks cross as they are, in the same pipeline; the
+        per-shard copy of everything stays as the counted fallback.
+        """
+        meta, sources = _plan_pytree(state, step, extra)
         meta_bytes = pickle.dumps(meta)
         data_offset = _HEADER.size + len(meta_bytes)
-        total = data_offset + sum(b.nbytes for b in blocks)
-        self._ensure_capacity(total)
+        size = sum(r.nbytes for t in meta.tensors for r in t.shards)
+        self._ensure_capacity(data_offset + size)
         buf = self._shm.buf
         # Crash-consistency ordering: invalidate the header first, then write
         # data + meta, then publish the header *last*.  A trainer SIGKILLed
         # mid-copy leaves meta_len == 0, which readers treat as "no
         # checkpoint" instead of committing torn tensor bytes.
-        with telemetry.span(
-            "checkpoint.shm_write", step=step, bytes=total - data_offset
-        ):
-            buf[: _HEADER.size] = _HEADER.pack(0)
-            blocks = iter(blocks)
-            for tensor in meta.tensors:
-                for record in tensor.shards:
-                    start = data_offset + record.offset
-                    dst = np.frombuffer(
-                        buf, dtype=np.uint8, count=record.nbytes,
-                        offset=start,
-                    )
-                    dst[:] = next(blocks).reshape(-1).view(np.uint8)
+        buf[: _HEADER.size] = _HEADER.pack(0)
+        arena = np.frombuffer(buf, dtype=np.uint8, count=size, offset=data_offset)
+        offsets = [r.offset for t in meta.tensors for r in t.shards]
+        # From the first launch to the last device byte in the arena (the
+        # per-shard path: on the host; ``checkpoint.shm_write`` copies).
+        with telemetry.span("checkpoint.d2h", step=step) as span:
+            t0 = time.monotonic()
+            faults = _minor_faults()
+            left, d2h = self._device_to_arena(sources, offsets, arena, step)
+            d2h.update(bytes=size, shards=len(sources),
+                       minflt=_minor_faults() - faults)
+            if span is not None:
+                span.attrs.update(d2h)
+            seconds = max(time.monotonic() - t0, 1e-9)
+            self.last_d2h = dict(d2h, gb_s=size / seconds / 1e9)
+        with telemetry.span("checkpoint.shm_write", step=step, bytes=size):
+            for offset, block in left:
+                flat = _as_bytes(block)
+                arena[offset : offset + flat.size] = flat
+            del arena
             buf[_HEADER.size : data_offset] = meta_bytes
             buf[: _HEADER.size] = _HEADER.pack(len(meta_bytes))
         return meta
+
+    def _device_to_arena(self, sources, offsets, arena, step):
+        """Bring every device block into ``arena``; returns the (offset,
+        host block) pairs still to be written there, and what the
+        ``checkpoint.d2h`` span says of the path taken."""
+        host = [
+            (offset, block) for offset, block in zip(offsets, sources)
+            if not isinstance(block, jax.Array)
+        ]
+        device = [
+            (offset, block) for offset, block in zip(offsets, sources)
+            if isinstance(block, jax.Array)
+        ]
+        try:
+            plans = [self._staged_plan(block) for _, block in device]
+            reason = self._refusal(device, plans)
+            if reason is None:
+                return host, self._stage(device, plans, arena)
+        except jax.errors.JaxRuntimeError as e:
+            # The arena may hold part of the state: its header says "no
+            # checkpoint" until the per-shard path has rewritten all.
+            logger.warning("step %d: staged save failed: %s", step, e)
+            reason = "device_error"
+        logger.warning(
+            "step %d: save falls back to the per-shard copy (%s)",
+            step, reason,
+        )
+        telemetry.event("checkpoint.d2h_fallback", step=step, reason=reason)
+        blocks, launch_s = _fetch_per_shard([b for _, b in device])
+        left = host + [(o, b) for (o, _), b in zip(device, blocks)]
+        return left, {"path": "per_shard", "groups": 0, "launch_s": launch_s}
+
+    def _staged_plan(self, block) -> Optional[_StagedPlan]:
+        """The plan for blocks like ``block`` (None: per-shard copy)."""
+        key = (block.shape, block.dtype.name, block.device.id)
+        if key in self._staged:
+            return self._staged[key]
+        plan = None
+        # (a flat block has nothing to undo)
+        if block.ndim >= 2 and block.nbytes >= _STAGED_MIN_BYTES:
+            most = _PIECE_BYTES // block.dtype.itemsize
+            sizes = [
+                min(most, block.size - first)
+                for first in range(0, block.size, most)
+            ]
+            groups, first = [], 0
+            for i in range(0, len(sizes), _GROUP_PIECES):
+                group = tuple(sizes[i : i + _GROUP_PIECES])
+                groups.append((first, group))
+                first += sum(group)
+            programs = {
+                group: jax.jit(
+                    functools.partial(_flat_pieces, sizes=group)
+                ).lower(block, np.int32(0)).compile()
+                for group in sorted({group for _, group in groups})
+            }
+            plan = _StagedPlan(
+                groups, programs,
+                max(_program_bytes(p) for p in programs.values()),
+            )
+        self._staged[key] = plan
+        return plan
+
+    @staticmethod
+    def _refusal(device, plans) -> Optional[str]:
+        """Why this save cannot take the staged path, or None."""
+        need: Dict[Any, int] = {}
+        for (_, block), plan in zip(device, plans):
+            if plan is not None:
+                need[block.device] = max(
+                    need.get(block.device, 0), plan.device_bytes
+                )
+        for dev, most in need.items():
+            free = _free_device_bytes(dev)
+            if free is not None and free < most + _IN_FLIGHT_BYTES:
+                return "hbm_headroom"
+        return None
+
+    def _stage(self, device, plans, arena):
+        """The pipeline: flatten the next piece on the device and launch
+        its copy while earlier ones cross, and copy each into the arena at
+        its record's offset as it arrives.  Returns the span's attrs."""
+        pending: collections.deque = collections.deque()
+        started = time.monotonic()
+        landing_s = copy_s = 0.0
+        groups = in_flight = 0
+
+        def land():
+            nonlocal landing_s, copy_s, in_flight
+            t0 = time.monotonic()
+            piece, offset = pending.popleft()
+            flat = _as_bytes(np.asarray(piece))
+            t1 = time.monotonic()
+            arena[offset : offset + flat.size] = flat
+            in_flight -= flat.size
+            copy_s += time.monotonic() - t1
+            landing_s += time.monotonic() - t0
+
+        def pieces_of(offset, block, plan):
+            if plan is None:
+                yield block, offset
+                return
+            for first, sizes in plan.groups:
+                at = offset + first * block.dtype.itemsize
+                for piece in plan.programs[sizes](block, np.int32(first)):
+                    yield piece, at
+                    at += piece.nbytes
+
+        for (offset, block), plan in zip(device, plans):
+            groups += len(plan.groups) if plan is not None else 0
+            for piece, at in pieces_of(offset, block, plan):
+                piece.copy_to_host_async()
+                pending.append((piece, at))
+                in_flight += piece.nbytes
+                while in_flight > _IN_FLIGHT_BYTES:
+                    land()
+        while pending:
+            land()
+        return {
+            "path": "staged", "groups": groups, "arena_copy_s": copy_s,
+            # what is neither the wait for a piece nor its copy
+            "launch_s": time.monotonic() - started - landing_s,
+        }
 
     def _ensure_capacity(self, total: int):
         if self._shm is not None and self._shm.size >= total:

@@ -36,6 +36,7 @@ _COUNTER_KINDS: Dict[str, str] = {
     "process_exit": "worker_exits",
     "worker_start": "worker_starts",
     "checkpoint.skip": "checkpoint_skipped",
+    "checkpoint.d2h_fallback": "checkpoint_d2h_fallback",
 }
 
 

@@ -430,6 +430,7 @@ class JobTimeline:
             worker_exits = self._counters.get("worker_exits", 0)
             worker_starts = self._counters.get("worker_starts", 0)
             checkpoint_skipped = self._counters.get("checkpoint_skipped", 0)
+            d2h_fallbacks = self._counters.get("checkpoint_d2h_fallback", 0)
             persisted_steps = dict(self._persisted_steps)
         gauge("dlrover_telemetry_dropped_total", dropped,
               "events the node telemetry rings overwrote before a drain")
@@ -448,6 +449,9 @@ class JobTimeline:
         gauge("dlrover_checkpoint_skipped_total", checkpoint_skipped,
               "saves the trainers skipped (arena busy with a persist, or "
               "a non-finite state)")
+        gauge("dlrover_checkpoint_d2h_fallback_total", d2h_fallbacks,
+              "saves that left the staged device-to-host path for the "
+              "per-shard copy (too little free HBM, or a device error)")
         if persisted_steps:
             lines.append(
                 "# HELP dlrover_persisted_step newest step the node's "

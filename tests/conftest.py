@@ -39,3 +39,28 @@ def cpu_child_env():
 @pytest.fixture(scope="session")
 def rng():
     return np.random.default_rng(0)
+
+
+@pytest.fixture
+def tap():
+    """Everything the process-wide recorder records during the test."""
+    from dlrover_tpu.common import telemetry
+
+    recorder = telemetry.recorder()
+    was_enabled = recorder.enabled
+    recorder.configure(enabled=True)
+    with recorder.open_tap() as held:
+        yield held
+    recorder.configure(enabled=was_enabled)
+
+
+@pytest.fixture
+def small_pieces(monkeypatch):
+    """The staged save path's sizes, cut so that test-size leaves are
+    staged: 4 KiB pieces, 8 KiB in flight, from 1 KiB a block."""
+    from dlrover_tpu.checkpoint import shm_handler
+
+    monkeypatch.setattr(shm_handler, "_STAGED_MIN_BYTES", 1024)
+    monkeypatch.setattr(shm_handler, "_PIECE_BYTES", 4096)
+    monkeypatch.setattr(shm_handler, "_IN_FLIGHT_BYTES", 8192)
+    return shm_handler

@@ -113,6 +113,219 @@ def test_shm_handler_sharded_array():
     handler.close(unlink=True)
 
 
+# -- the two device-to-host paths of a save -------------------------------------
+
+
+def _leaf(kind: str, dtype):
+    """A leaf of the named kind, its values distinct, and the number of
+    records a save stores for it."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    shape = {
+        "scalar": (), "flat": (300,), "ragged_2d": (100, 33),
+        "several_pieces": (6, 40, 50), "sharded_replicated": (64, 50),
+    }[kind]
+    values = np.arange(int(np.prod(shape)), dtype=np.int64).reshape(shape)
+    leaf = jnp.asarray(values % 251 - 125, dtype)
+    if kind != "sharded_replicated":
+        return leaf, 1
+    # Rows over "x", a second copy of every shard over "y": the save keeps
+    # the copy with replica_id 0 of each.
+    mesh = Mesh(np.asarray(jax.devices()[:4]).reshape(2, 2), ("x", "y"))
+    return jax.device_put(
+        leaf, NamedSharding(mesh, PartitionSpec("x", None))
+    ), 2
+
+
+def _named(events, name):
+    return [e for e in events if e[0] == name]
+
+
+@pytest.mark.parametrize("kind", [
+    "scalar", "flat", "ragged_2d", "several_pieces", "sharded_replicated",
+])
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32", "int32"])
+@pytest.mark.parametrize("path", ["staged", "per_shard"])
+def test_arena_round_trip_by_either_path(
+    path, dtype, kind, small_pieces, tap, monkeypatch
+):
+    """Whichever way the bytes leave the device, the arena holds each
+    record's bytes at its offset in ``meta.tensors`` order, the meta
+    describes them, and a restore is bitwise."""
+    leaf, records = _leaf(kind, jnp.dtype(dtype))
+    # (dict keys flatten in sorted order)
+    state = {"a": np.arange(3, dtype=np.int16), "b": leaf,
+             "c": jnp.ones((40, 50), jnp.float32)}
+    if path == "per_shard":
+        monkeypatch.setattr(small_pieces, "_free_device_bytes", lambda d: 0)
+    handler = SharedMemoryHandler(f"rt{os.getpid()}")
+    try:
+        meta = handler.save_state_dict(state, step=4, extra={"k": 1})
+        events = tap.take()
+        (d2h,) = _named(events, "checkpoint.d2h")
+        assert d2h[4]["path"] == path
+        fallbacks = _named(events, "checkpoint.d2h_fallback")
+        if path == "per_shard":
+            (fallback,) = fallbacks
+            assert fallback[1] == "event"
+            assert fallback[4] == dict(
+                fallback[4], step=4, reason="hbm_headroom"
+            )
+            assert d2h[4]["groups"] == 0
+        else:
+            assert not fallbacks
+            # "c" is one program's two pieces; a scalar or a flat leaf is
+            # not staged, a larger one is cut into pieces of at most 4 KiB,
+            # eight to a program
+            plans = [p for p in handler._staged.values() if p is not None]
+            staged_leaf = kind not in ("scalar", "flat")
+            # (a plan a device: the two stored shards are on two)
+            assert len(plans) == 1 + records * staged_leaf
+            assert d2h[4]["groups"] == 1 + records * (
+                -(-leaf.nbytes // records // (8 * 4096))
+            ) * staged_leaf
+            assert all(
+                size * leaf.dtype.itemsize <= 4096
+                for plan in plans for _, sizes in plan.groups
+                for size in sizes
+            )
+        # meta: three tensors in tree order, records back to back
+        first, tensor, last = handler.load_meta().tensors
+        assert [t.path for t in meta.tensors] == [
+            first.path, tensor.path, last.path
+        ]
+        assert tensor.global_shape == leaf.shape and tensor.dtype == dtype
+        assert len(tensor.shards) == records
+        shards = [
+            np.asarray(s.data) for s in leaf.addressable_shards
+            if s.replica_id == 0
+        ]
+        offset = first.shards[0].nbytes
+        for record, shard in zip(tensor.shards, shards):
+            assert record.offset == offset
+            assert record.nbytes == shard.nbytes
+            assert record.shape == (shard.shape or (1,))
+            offset += record.nbytes
+        assert last.shards[0].offset == offset
+        # arena bytes
+        want = b"".join(
+            [np.arange(3, dtype=np.int16).tobytes()]
+            + [s.tobytes() for s in shards]
+            + [np.ones((40, 50), np.float32).tobytes()]
+        )
+        assert bytes(handler.raw_data(meta)) == want
+        # restore
+        out = assemble_tensor(tensor, lambda r: handler.load_block(meta, r))
+        assert out.dtype == leaf.dtype and out.shape == leaf.shape
+        assert out.tobytes() == np.asarray(leaf).tobytes()
+    finally:
+        handler.close(unlink=True)
+
+
+def test_staged_and_parent_layouts_restore_each_other(small_pieces, tap):
+    """The arena the parent commit wrote (header, pickled meta, then the
+    blocks of ``pack_pytree`` back to back) and the arena a staged save
+    writes are the same bytes: each restores under the other's reader."""
+    import pickle
+    import struct
+
+    from dlrover_tpu.checkpoint.shm_handler import pack_pytree
+
+    state = {
+        "w": jnp.asarray(
+            np.arange(6 * 40 * 50).reshape(6, 40, 50) % 97, jnp.bfloat16
+        ),
+        "b": jnp.arange(300, dtype=jnp.float32),
+        "step": jnp.int32(9),
+        "host": np.arange(5),
+    }
+    handler = SharedMemoryHandler(f"pc{os.getpid()}")
+    try:
+        meta = handler.save_state_dict(state, step=9)
+        (d2h,) = _named(tap.take(), "checkpoint.d2h")
+        assert d2h[4]["path"] == "staged" and d2h[4]["groups"] == 1
+        staged = bytes(handler.raw_data(meta))
+        # the parent's writer, as it was
+        parent_meta, blocks = pack_pytree(state, 9)
+        parent = b"".join(
+            np.ascontiguousarray(b).reshape(-1).view(np.uint8).tobytes()
+            for b in blocks
+        )
+        assert staged == parent
+        assert parent_meta.tensors == meta.tensors
+        # the parent's reader over the staged arena: header, meta, offsets
+        buf = handler._shm.buf
+        (meta_len,) = struct.unpack("<Q", bytes(buf[:8]))
+        read = pickle.loads(bytes(buf[8 : 8 + meta_len]))
+        assert read.tensors == parent_meta.tensors and read.step == 9
+        for tensor in read.tensors:
+            out = assemble_tensor(
+                tensor,
+                lambda r: np.frombuffer(
+                    buf, np.uint8, count=r.nbytes,
+                    offset=8 + meta_len + r.offset,
+                ),
+            )
+            name = tensor.path[0][2:-2]
+            assert out.tobytes() == np.asarray(state[name]).tobytes()
+            del out
+    finally:
+        handler.close(unlink=True)
+
+
+def test_second_save_compiles_nothing(small_pieces):
+    """The flatten programs of a state are compiled at its first save."""
+    compiles = []
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda name, secs, **_: compiles.append(name)
+        if name == "/jax/core/compile/backend_compile_duration" else None
+    )
+    state = lambda k: {  # noqa: E731
+        "w": jnp.full((6, 40, 50), k, jnp.bfloat16),
+        "v": jnp.full((100, 33), k, jnp.float32),
+    }
+    handler = SharedMemoryHandler(f"sc{os.getpid()}")
+    try:
+        first = state(1)
+        jax.block_until_ready(first)
+        handler.save_state_dict(first, step=1)
+        plans = dict(handler._staged)
+        assert sum(len(p.programs) for p in plans.values()) >= 2
+        second = state(2)
+        jax.block_until_ready(second)
+        before = len(compiles)
+        handler.save_state_dict(second, step=2)
+        assert len(compiles) == before
+        assert handler._staged == plans
+        assert handler.last_d2h["path"] == "staged"
+    finally:
+        handler.close(unlink=True)
+
+
+def test_a_device_error_in_the_pipeline_falls_back(small_pieces, tap,
+                                                   monkeypatch):
+    state = {"w": jnp.ones((6, 40, 50), jnp.bfloat16)}
+    handler = SharedMemoryHandler(f"de{os.getpid()}")
+
+    def broken(*a, **k):
+        raise jax.errors.JaxRuntimeError("RESOURCE_EXHAUSTED: no HBM")
+
+    monkeypatch.setattr(handler, "_stage", broken)
+    try:
+        meta = handler.save_state_dict(state, step=2)
+        events = tap.take()
+        (fallback,) = _named(events, "checkpoint.d2h_fallback")
+        assert fallback[4]["reason"] == "device_error"
+        (d2h,) = _named(events, "checkpoint.d2h")
+        assert d2h[4]["path"] == "per_shard"
+        out = assemble_tensor(
+            meta.tensors[0], lambda r: handler.load_block(meta, r)
+        )
+        assert out.tobytes() == np.asarray(state["w"]).tobytes()
+    finally:
+        handler.close(unlink=True)
+
+
 def test_checkpointer_memory_and_disk_cycle(tmp_path):
     ckpt_dir = str(tmp_path / "ckpt")
     ckpt = Checkpointer(ckpt_dir, host_index=0, num_hosts=1, local_saver=True)

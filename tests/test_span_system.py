@@ -51,17 +51,6 @@ def _own_sockets_and_arena(tmp_path, monkeypatch):
             os.unlink(os.path.join("/dev/shm", name))
 
 
-@pytest.fixture
-def tap():
-    """Everything the process-wide recorder records during the test."""
-    recorder = telemetry.recorder()
-    was_enabled = recorder.enabled
-    recorder.configure(enabled=True)
-    with recorder.open_tap() as held:
-        yield held
-    recorder.configure(enabled=was_enabled)
-
-
 def _trainer(tmp_path=None, **cfg):
     model_config = gpt2_config(
         "124m", num_layers=1, d_model=32, num_heads=2, vocab_size=64,
@@ -270,8 +259,27 @@ def test_pipeline_event_log_is_a_window_and_the_totals_are_not():
 # -- the save --------------------------------------------------------------------
 
 
-def test_a_save_is_split_where_the_work_happens(tmp_path, tap):
+def test_a_save_is_split_where_the_work_happens(tmp_path, tap, small_pieces):
+    compiles = []
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda name, secs, **_: compiles.append(name)
+        if name == "/jax/core/compile/backend_compile_duration" else None
+    )
     trainer = _trainer(tmp_path, ckpt_every=2, metrics_lag=3)
+    arena = trainer._ckpt._engine._shm
+    inner = trainer.save_checkpoint
+    staged, compiled_in = [], []
+
+    def save_then_wait_for_the_persist():
+        # The local saver holds the arena while it persists: a next save
+        # that finds it held is skipped ("shm busy"), whatever the load.
+        before = len(compiles)
+        inner()
+        compiled_in.append(len(compiles) - before)
+        staged.append(dict(arena._staged))
+        assert trainer._ckpt.wait(timeout=60)
+
+    trainer.save_checkpoint = save_then_wait_for_the_persist
     trainer.fit(_batches(6), max_steps=4)
     trainer.close()
     events = tap.take()
@@ -292,14 +300,22 @@ def test_a_save_is_split_where_the_work_happens(tmp_path, tap):
         assert sum(e[3] for e in children) <= save[3]
         (d2h,) = _named(children, "checkpoint.d2h")
         assert d2h[4]["bytes"] == size and d2h[4]["shards"] > 0
+        assert d2h[4]["path"] == "staged" and d2h[4]["groups"] > 0
+        assert d2h[4]["minflt"] >= 0
+        assert 0 <= d2h[4]["arena_copy_s"] <= d2h[3]
         (write,) = _named(children, "checkpoint.shm_write")
         assert write[4]["bytes"] == size
         # what the device still had in flight is read inside the drain
         assert _named(events, "metrics-flush", parent="checkpoint.drain",
                       id=group)
-    (arena,) = _named(events, "checkpoint.arena")
-    assert arena[4]["created"] is True and arena[4]["bytes"] > size
+    (arena_span,) = _named(events, "checkpoint.arena")
+    assert arena_span[4]["created"] is True and arena_span[4]["bytes"] > size
     assert not _named(events, "checkpoint.skip")
+    assert not _named(events, "checkpoint.d2h_fallback")
+    # The second save of the same state compiles nothing and plans nothing
+    # new: its programs and pieces are the first save's.
+    assert compiled_in[0] > 0 and compiled_in[1] == 0
+    assert staged[0] and staged[1] == staged[0]
 
 
 def test_a_persist_has_its_four_children_and_says_persisted(tmp_path, tap):
@@ -397,6 +413,8 @@ def test_skips_and_persisted_steps_reach_the_master_gauges():
          {"step": 14, "reason": "shm_busy"}),
         ("persisted", "event", 0.0, 0.0, {"step": 7}),
         ("persisted", "event", 0.0, 0.0, {"step": 14}),
+        ("checkpoint.d2h_fallback", "event", 0.0, 0.0,
+         {"step": 21, "reason": "hbm_headroom"}),
     )
     wire = pickle.dumps(msg.Envelope(
         node_id=2, payload=msg.TelemetryEvents(2, events),
@@ -404,6 +422,7 @@ def test_skips_and_persisted_steps_reach_the_master_gauges():
     assert servicer.report(msg.safe_loads(wire)).success
     text = timeline.render_metrics()
     assert "dlrover_checkpoint_skipped_total 1" in text
+    assert "dlrover_checkpoint_d2h_fallback_total 1" in text
     assert 'dlrover_persisted_step{node="2"} 14' in text
     assert "# HELP dlrover_persisted_step " in text
 

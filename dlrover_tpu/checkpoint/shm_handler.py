@@ -22,6 +22,7 @@ from __future__ import annotations
 import collections
 import dataclasses
 import functools
+import mmap
 import os
 import pickle
 import resource
@@ -327,7 +328,7 @@ class SharedMemoryHandler:
         meta_bytes = pickle.dumps(meta)
         data_offset = _HEADER.size + len(meta_bytes)
         size = sum(r.nbytes for t in meta.tensors for r in t.shards)
-        self._ensure_capacity(data_offset + size)
+        fresh = self._ensure_capacity(data_offset + size)
         buf = self._shm.buf
         # Crash-consistency ordering: invalidate the header first, then write
         # data + meta, then publish the header *last*.  A trainer SIGKILLed
@@ -355,7 +356,25 @@ class SharedMemoryHandler:
             del arena
             buf[_HEADER.size : data_offset] = meta_bytes
             buf[: _HEADER.size] = _HEADER.pack(len(meta_bytes))
+        if fresh:
+            self._settle(data_offset + size)
         return meta
+
+    def _settle(self, total: int):
+        """Read a byte of every page this save has just written for the
+        first time.
+
+        On the v5e machines a new mapping is at full speed only from its
+        third pass on: 3.37 GB are written in 8.2-10.0 s, then in 1.5-1.8 s,
+        then in 0.24 s, and the second pass costs 0.23 s where it reads one
+        byte a page (PERF.md section 6, PR 26).  Left alone it falls on the
+        job's next save, whose stall then swings with the host's memory
+        from run to run (1.32-1.82 s against 0.46-0.50 s for every later
+        save); here the save that made the mapping pays for all of it.
+        """
+        with telemetry.span("checkpoint.arena_settle", bytes=total):
+            pages = np.frombuffer(self._shm.buf, dtype=np.uint8, count=total)
+            int(pages[:: mmap.PAGESIZE].max())
 
     def _device_to_arena(self, sources, offsets, arena, step):
         """Bring every device block into ``arena``; returns the (offset,
@@ -480,16 +499,19 @@ class SharedMemoryHandler:
             "launch_s": time.monotonic() - started - landing_s,
         }
 
-    def _ensure_capacity(self, total: int):
+    def _ensure_capacity(self, total: int) -> bool:
+        """Whether this call mapped the arena anew."""
         if self._shm is not None and self._shm.size >= total:
-            return
+            return False
         # Only a save that creates, grows or re-attaches the arena comes
         # here.  A new mapping's pages are not touched yet: the first write
-        # into them (``checkpoint.shm_write``) pays for that.
+        # into them (``checkpoint.d2h``) pays for that, and ``_settle`` for
+        # their second pass.
         with telemetry.span("checkpoint.arena", bytes=total) as span:
             created = self._open_arena(total)
             if span is not None:
                 span.attrs["created"] = created
+        return True
 
     def _open_arena(self, total: int) -> bool:
         """Attach the arena if one of this name is large enough, else

@@ -290,6 +290,8 @@ class SpeedMonitor:
         experts: float = 0.0,
         top_k: float = 0.0,
         load: Any = "[]",
+        pad_share: float = 0.0,
+        max_expert_load: float = 0.0,
         **_ignored,
     ):
         """A trainer's router-health snapshot (its ``moe`` telemetry
@@ -310,10 +312,12 @@ class SpeedMonitor:
                 "experts": float(experts),
                 "top_k": float(top_k),
                 "load": [float(v) for v in load],
+                "pad_share": float(pad_share),
+                "max_expert_load": float(max_expert_load),
             }
 
     def moe_ledger(self) -> Dict[str, Any]:
-        """Router-health aggregate: entropy/drop average across reporters
+        """Router-health aggregate: entropy/drop/padding average over reporters
         (each books its own replica's gate view), expert geometry takes
         the max, and per-expert load averages elementwise across the
         reporters that carry the full-width vector."""
@@ -329,16 +333,18 @@ class SpeedMonitor:
                 sum(vec[i] for vec in loads) / len(loads)
                 for i in range(int(experts))
             ] if loads else []
+
+            def mean(key):
+                return sum(s[key] for s in stats) / n if n else 0.0
+
             return {
                 "moe_events": float(self._moe_events),
                 "reporters": float(n),
                 "step": max((s["step"] for s in stats), default=0.0),
-                "entropy": (
-                    sum(s["entropy"] for s in stats) / n if n else 0.0
-                ),
-                "drop_fraction": (
-                    sum(s["drop_fraction"] for s in stats) / n if n else 0.0
-                ),
+                "entropy": mean("entropy"),
+                "drop_fraction": mean("drop_fraction"),
+                "pad_share": mean("pad_share"),
+                "max_expert_load": mean("max_expert_load"),
                 "experts": experts,
                 "top_k": max((s["top_k"] for s in stats), default=0.0),
                 "load": load,

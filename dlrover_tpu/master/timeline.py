@@ -367,6 +367,12 @@ class JobTimeline:
                   moe["drop_fraction"],
                   "fraction of token-choices dropped at expert capacity "
                   "(0 on the dropless grouped path)")
+            gauge("dlrover_moe_pad_share", moe["pad_share"],
+                  "padding rows over the rows the expert matmuls run "
+                  "(capacity slots, or the grouped GEMMs' row budget)")
+            gauge("dlrover_moe_max_expert_load", moe["max_expert_load"],
+                  "busiest expert's routed rows over the mean expert's "
+                  "(1 = perfectly balanced)")
             gauge("dlrover_moe_experts", moe["experts"],
                   "expert count of the reported MoE model")
             gauge("dlrover_moe_top_k", moe["top_k"],

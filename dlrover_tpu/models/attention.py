@@ -184,6 +184,32 @@ def _flash_local(q, k, v, segment_ids, *, block_q, block_kv):
     )(*args)
 
 
+class QKNorm(nn.Module):
+    """RMSNorm over a whole projection ``[..., H, hd]``: all heads jointly,
+    one ``[H * hd]`` scale in the published (head-major) order, float32
+    accumulation (OLMoE / OLMo-2 ``q_norm`` and ``k_norm``)."""
+
+    epsilon: float = 1e-5
+    param_dtype: layers.Dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        heads, head_dim = x.shape[-2:]
+        scale = self.param(
+            "scale",
+            nn.with_logical_partitioning(
+                nn.initializers.ones_init(), (lr.NORM,)
+            ),
+            (heads * head_dim,),
+            self.param_dtype,
+        )
+        x32 = x.astype(jnp.float32)
+        var = jnp.mean(jnp.square(x32), axis=(-2, -1), keepdims=True)
+        y = x32 * jax.lax.rsqrt(var + self.epsilon)
+        scale = scale.astype(jnp.float32).reshape(heads, head_dim)
+        return (y * scale).astype(x.dtype)
+
+
 class Attention(nn.Module):
     """Causal self-attention block with RoPE/GQA and SP-aware shardings."""
 
@@ -197,6 +223,7 @@ class Attention(nn.Module):
     param_dtype: layers.Dtype = jnp.float32
     attention_impl: str = "xla"
     fused_qkv: bool = True
+    qk_norm: bool = False
     flash_block_q: int = 512
     flash_block_kv: int = 512
     # Autoregressive decoding: keep K/V in a "cache" collection of
@@ -265,6 +292,14 @@ class Attention(nn.Module):
                 save_name="qkv_proj",
                 name="value",
             )(x)
+
+        if self.qk_norm:
+            # Train, prefill and cached decode all pass here.  The norm is
+            # over all heads of a projection jointly, so the fused kernel's
+            # per-head [q | k | v] slices are normed over their two trailing
+            # axes and the one wide QKV matmul is kept.
+            q = QKNorm(param_dtype=self.param_dtype, name="q_norm")(q)
+            k = QKNorm(param_dtype=self.param_dtype, name="k_norm")(k)
 
         if self.use_rope:
             q, k = layers.rotary_embedding(q, k, positions, self.rope_theta)

@@ -25,25 +25,43 @@ from dlrover_tpu.models import layers
 from dlrover_tpu.parallel import rules as lr
 
 
-def _gate(logits: jax.Array, k: int):
-    """Shared top-k gate: (gate_vals, gate_idx, aux_loss)."""
+def _gate(logits: jax.Array, k: int, norm_topk_prob: bool = True,
+          aux_form: str = "top1"):
+    """Shared top-k gate: (gate_vals, gate_idx, aux_loss), float32.
+
+    ``norm_topk_prob`` renormalises the chosen gates to sum to one
+    (Mixtral); without it they are the chosen experts' softmax
+    probabilities as they are (OLMoE).  ``aux_form`` picks the
+    load-balancing loss: ``"top1"`` (Switch: share of first choices x mean
+    probability, x E^2/k) or ``"topk"`` (E x sum_e f_e x P_e, ``f_e`` the
+    share of tokens that chose ``e`` in any of their k slots)."""
     e = logits.shape[-1]
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     gate_vals, gate_idx = jax.lax.top_k(probs, k)            # [B,S,k]
-    # renormalize the chosen gates
-    gate_vals = gate_vals / jnp.clip(
-        jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9
-    )
-    # Load-balancing aux loss: mean prob * mean assignment per expert.
-    top1_onehot = jax.nn.one_hot(gate_idx[..., 0], e, dtype=jnp.float32)
-    density = jnp.mean(top1_onehot, axis=(0, 1))             # [E]
+    if norm_topk_prob:
+        gate_vals = gate_vals / jnp.clip(
+            jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9
+        )
     density_proxy = jnp.mean(probs, axis=(0, 1))             # [E]
-    aux_loss = jnp.sum(density * density_proxy) * (e ** 2) / k
+    if aux_form == "top1":
+        top1_onehot = jax.nn.one_hot(gate_idx[..., 0], e, dtype=jnp.float32)
+        density = jnp.mean(top1_onehot, axis=(0, 1))         # [E]
+        aux_loss = jnp.sum(density * density_proxy) * (e ** 2) / k
+    elif aux_form == "topk":
+        chosen = jax.nn.one_hot(gate_idx, e, dtype=jnp.float32).sum(axis=-2)
+        aux_loss = jnp.sum(
+            jnp.mean(chosen, axis=(0, 1)) * density_proxy
+        ) * e
+    else:
+        raise ValueError(
+            f"unknown MoE aux_form {aux_form!r}; expected 'top1' or 'topk'"
+        )
     return gate_vals, gate_idx, aux_loss
 
 
 def top_k_gating(
-    logits: jax.Array, k: int, capacity: int
+    logits: jax.Array, k: int, capacity: int, norm_topk_prob: bool = True,
+    aux_form: str = "top1",
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Top-k gating with per-expert capacity (Switch/GShard style).
 
@@ -53,7 +71,9 @@ def top_k_gating(
     auxiliary loss (ref ``topk_gating.py`` capability).
     """
     b, s, e = logits.shape
-    gate_vals, gate_idx, aux_loss = _gate(logits, k)
+    gate_vals, gate_idx, aux_loss = _gate(
+        logits, k, norm_topk_prob, aux_form
+    )
 
     # Assign capacity slots expert-by-expert in token order.  Slots taken by
     # earlier choice ranks offset later ranks (`prior`), so a token picked
@@ -82,6 +102,120 @@ def _router_entropy(router_logits: jax.Array) -> jax.Array:
     """Mean per-token entropy of the router distribution (nats)."""
     probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
     return jnp.mean(-jnp.sum(probs * jnp.log(probs + 1e-9), axis=-1))
+
+
+STATS_TAIL = 2  # [pad_share, max_expert_load] after the per-expert loads
+
+
+def split_stats(vec):
+    """``(entropy, drop_fraction, load[E], pad_share, max_expert_load)`` of
+    one ``moe_stats`` vector (:meth:`MoEMlp._sow_router_stats`)."""
+    return vec[0], vec[1], vec[2:-STATS_TAIL], vec[-2], vec[-1]
+
+
+# -- dropless dispatch: every move of rows is a gather -------------------------
+#
+# A (token, expert) pair has one row among the expert-grouped rows and each
+# token exactly k of them, so rows <- tokens, tokens <- rows and both their
+# transposes are gathers (plus a sum over k), never a scatter: a TPU gathers
+# rows at memory speed and scatters them one at a time.
+
+
+def _row_budget(pairs: int, block: int, experts: int) -> int:
+    """Rows the grouped GEMMs run for ``pairs`` routed pairs: every pair
+    plus at most one partial block of padding per expert, in whole blocks."""
+    return ((pairs + block - 1) // block + experts) * block
+
+
+def _dispatch_plan(gate_idx, experts: int, block: int, n_pad: int):
+    """Where each (token, choice) pair's row lives among the expert-grouped
+    rows, and which pair each row holds, from ``gate_idx`` ``[T, k]``.
+
+    ``padded`` [E]: rows of each expert's group (whole blocks);
+    ``dest`` [T, k]: the pair's row; ``row_pair`` [n_pad]: the row's pair
+    (flat ``token * k + choice``), ``T * k`` where the row is padding."""
+    t, k = gate_idx.shape
+    n = t * k
+    flat = gate_idx.reshape(n)
+    onehot = (flat[:, None] == jnp.arange(experts)[None, :]).astype(jnp.int32)
+    seen = jnp.cumsum(onehot, axis=0)                           # [N, E]
+    counts = seen[-1]
+    padded = ((counts + block - 1) // block) * block
+    group_ends = jnp.cumsum(padded)
+    group_starts = group_ends - padded
+    count_starts = jnp.cumsum(counts) - counts
+    # group start + rank of the pair within its expert's group, token order
+    dest = jnp.sum(onehot * (seen - 1 + group_starts[None, :]), axis=1)
+    # pairs in expert order: a stable sort keeps the token order per expert
+    _, order = jax.lax.sort(
+        (flat, jnp.arange(n, dtype=jnp.int32)), num_keys=1, is_stable=True
+    )
+    row = jnp.arange(n_pad, dtype=jnp.int32)
+    expert_of_row = jnp.minimum(
+        jnp.sum(row[:, None] >= group_ends[None, :], axis=1), experts - 1
+    )
+    rank = row - group_starts[expert_of_row]
+    row_pair = jnp.where(
+        rank < counts[expert_of_row],
+        order[jnp.clip(count_starts[expert_of_row] + rank, 0, n - 1)], n,
+    )
+    return {"padded": padded, "dest": dest.reshape(t, k), "row_pair": row_pair}
+
+
+def _zero_row(x):
+    """``x`` with one row of zeros after its last: what a padding row's
+    index (one past the end) gathers, so no pass has to mask them."""
+    return jnp.concatenate([x, jnp.zeros((1,) + x.shape[1:], x.dtype)])
+
+
+@jax.custom_vjp
+def _rows_of_tokens(x, plan):
+    """``rows[r] = x[token of row r]``, zero where the row is padding."""
+    k = plan["dest"].shape[1]
+    return _zero_row(x)[plan["row_pair"] // k]
+
+
+def _rows_of_tokens_fwd(x, plan):
+    return _rows_of_tokens(x, plan), plan
+
+
+def _rows_of_tokens_bwd(plan, d_rows):
+    # each token's k rows, summed: the scatter-add's transpose as a gather
+    d_x = d_rows[plan["dest"]].astype(jnp.float32).sum(axis=1)
+    return d_x.astype(d_rows.dtype), None
+
+
+_rows_of_tokens.defvjp(_rows_of_tokens_fwd, _rows_of_tokens_bwd)
+
+
+@jax.custom_vjp
+def _tokens_of_rows(out_rows, gates, plan):
+    """``out[t] = sum_j gates[t, j] * out_rows[row of pair (t, j)]``, the
+    gates and the sum in float32."""
+    picked = out_rows[plan["dest"]].astype(jnp.float32)         # [T, k, D]
+    return (picked * gates[..., None]).sum(axis=1).astype(out_rows.dtype)
+
+
+def _tokens_of_rows_fwd(out_rows, gates, plan):
+    return _tokens_of_rows(out_rows, gates, plan), (out_rows, gates, plan)
+
+
+def _tokens_of_rows_bwd(residuals, d_out):
+    out_rows, gates, plan = residuals
+    k = gates.shape[1]
+    pair = plan["row_pair"]
+    row_gate = _zero_row(gates.reshape(-1))[pair]
+    d_rows = (
+        _zero_row(d_out)[pair // k].astype(jnp.float32) * row_gate[:, None]
+    ).astype(out_rows.dtype)
+    d_gates = jnp.einsum(
+        "tkd,td->tk", out_rows[plan["dest"]].astype(jnp.float32),
+        d_out.astype(jnp.float32),
+    )
+    return d_rows, d_gates.astype(gates.dtype), None
+
+
+_tokens_of_rows.defvjp(_tokens_of_rows_fwd, _tokens_of_rows_bwd)
 
 
 class MoEMlp(nn.Module):
@@ -117,7 +251,8 @@ class MoEMlp(nn.Module):
       under expert parallelism.
 
     Router observability: every forward ``sow``s a ``moe_stats`` vector
-    ``[gate_entropy, drop_fraction, load_0..load_{E-1}]`` into the
+    ``[gate_entropy, drop_fraction, load_0..load_{E-1}, pad_share,
+    max_expert_load]`` (:func:`split_stats`) into the
     ``"intermediates"`` collection — a no-op (zero cost) unless the
     caller applies with ``mutable=["intermediates"]``, which is how the
     trainer harvests router health on the report cadence without
@@ -133,6 +268,8 @@ class MoEMlp(nn.Module):
     param_dtype: layers.Dtype = jnp.float32
     dispatch: str = "einsum"        # "einsum" | "a2a" | "a2a_int8" | "grouped"
     gmm_block_rows: int = 128
+    norm_topk_prob: bool = True     # see :func:`_gate`
+    aux_form: str = "top1"
 
     @nn.compact
     def __call__(self, x: jax.Array) -> Tuple[jax.Array, jax.Array]:
@@ -205,12 +342,14 @@ class MoEMlp(nn.Module):
         e = self.num_experts
         capacity = max(1, int(self.capacity_factor * s * self.top_k / e))
         dispatch, combine, aux_loss = top_k_gating(
-            router_logits, self.top_k, capacity
+            router_logits, self.top_k, capacity, self.norm_topk_prob,
+            self.aux_form,
         )
         self._sow_router_stats(
             _router_entropy(router_logits),
             routed=dispatch.sum(axis=(0, 1, 3)),
             total=b * s * self.top_k,
+            rows_run=b * e * capacity,
         )
         dispatch = dispatch.astype(self.dtype)
         combine = combine.astype(self.dtype)
@@ -264,6 +403,11 @@ class MoEMlp(nn.Module):
         e, k = self.num_experts, self.top_k
         capacity = max(1, int(self.capacity_factor * s * k / e))
         int8 = self.dispatch == "a2a_int8"
+        if self.aux_form != "top1":
+            raise ValueError(
+                "a2a dispatch computes the 'top1' load-balancing loss only "
+                f"(got aux_form={self.aux_form!r}); use dispatch='einsum'"
+            )
         for axis in ("seq", "tensor"):
             if mesh_axis_size(axis) > 1:
                 raise ValueError(
@@ -299,7 +443,9 @@ class MoEMlp(nn.Module):
             wo_loc = weights[-1]
             # Slot assignment is per (batch row, expert) — identical on a
             # batch chunk to what the full batch computes.
-            dispatch, combine, _ = top_k_gating(logits_loc, k, capacity)
+            dispatch, combine, _ = top_k_gating(
+                logits_loc, k, capacity, self.norm_topk_prob
+            )
             probs = jax.nn.softmax(logits_loc.astype(jnp.float32), axis=-1)
             # Exact global aux loss: pmean the densities BEFORE the
             # product (chunk means over equal chunks compose exactly).
@@ -350,19 +496,28 @@ class MoEMlp(nn.Module):
             body, mesh=current_mesh(), in_specs=in_specs,
             out_specs=(bspec, P(), P(), P()),
         )(*args)
-        self._sow_router_stats(entropy, routed, b * s * k)
+        self._sow_router_stats(entropy, routed, b * s * k, b * e * capacity)
         return out, aux.astype(jnp.float32)
 
-    def _sow_router_stats(self, entropy, routed, total):
-        """Book ``[entropy, drop_fraction, load_0..load_{E-1}]`` into the
-        ``"intermediates"`` collection (no-op unless mutable)."""
+    def _sow_router_stats(self, entropy, routed, total, rows_run):
+        """Book ``[entropy, drop_fraction, load_0..load_{E-1}, pad_share,
+        max_expert_load]`` into the ``"intermediates"`` collection (no-op
+        unless mutable).  ``routed`` are the (token, expert) pairs each
+        expert computes, ``total`` the pairs the router chose, ``rows_run``
+        the rows the expert matmuls run (capacity slots, or the grouped
+        GEMMs' padded row budget): what is not a routed pair is padding."""
         routed = routed.astype(jnp.float32)
         kept = routed.sum()
         drop = 1.0 - kept / max(1, total)
         load = routed / jnp.clip(kept, 1.0)
+        pad_share = 1.0 - kept / rows_run
+        max_load = load.max() * routed.shape[0]
         self.sow(
             "intermediates", "moe_stats",
-            jnp.concatenate([jnp.stack([entropy, drop]), load]),
+            jnp.concatenate([
+                jnp.stack([entropy, drop]), load,
+                jnp.stack([pad_share, max_load]),
+            ]),
         )
 
     # -- dropless grouped-GEMM dispatch ---------------------------------------
@@ -371,7 +526,7 @@ class MoEMlp(nn.Module):
         from jax.sharding import PartitionSpec as P
 
         from dlrover_tpu.ops.grouped_matmul import grouped_matmul
-        from dlrover_tpu.runtime.mesh import shard_local
+        from dlrover_tpu.runtime.mesh import mesh_axis_size, shard_local
 
         b, s, d = x.shape
         e, k = self.num_experts, self.top_k
@@ -379,60 +534,60 @@ class MoEMlp(nn.Module):
 
         # Routing and its statistics are global and plain XLA; only the
         # sort + grouped GEMMs below run per device.
-        gate_vals, gate_idx, aux_loss = _gate(router_logits, k)
-        counts = jnp.zeros((e,), jnp.int32).at[gate_idx.reshape(-1)].add(1)
-        # Dropless: routed == total, so drop_fraction books as exactly 0.
-        self._sow_router_stats(
-            _router_entropy(router_logits), routed=counts, total=b * s * k
-        )
-
-        def local(x, gate_vals, gate_idx, *weights):
-            """Sort this device's token-choices by expert and run each
-            expert's ragged row group through the grouped GEMMs."""
-            wi, wo = weights[0], weights[-1]
-            wg = weights[1] if len(weights) == 3 else None
-            b, s, d = x.shape
-            n = b * s * k
-            # Static row budget: every token-choice plus at most one
-            # partial block of padding per expert, in whole kernel blocks.
-            n_pad = ((n + block - 1) // block + e) * block
-
-            x_flat = x.reshape(b * s, d).astype(self.dtype)
-            experts_flat = gate_idx.reshape(n)                   # [N]
-            gates_flat = gate_vals.reshape(n).astype(self.dtype)
-            token_of_choice = jnp.arange(n, dtype=jnp.int32) // k
-
-            # Stable sort by expert: each expert's choices become one
-            # consecutive ragged group.
-            order = jnp.argsort(experts_flat, stable=True)
-            expert_sorted = experts_flat[order]
-            src_token = token_of_choice[order]
-            counts = jnp.zeros((e,), jnp.int32).at[experts_flat].add(1)
-            padded = ((counts + block - 1) // block) * block     # group sizes
-            group_starts = jnp.cumsum(padded) - padded
-            count_starts = jnp.cumsum(counts) - counts
-            rank = jnp.arange(n, dtype=jnp.int32) - count_starts[expert_sorted]
-            dest = group_starts[expert_sorted] + rank            # [N] row slots
-
-            rows = jnp.zeros((n_pad, d), self.dtype).at[dest].set(
-                x_flat[src_token]
+        with jax.named_scope("router"):
+            gate_vals, gate_idx, aux_loss = _gate(
+                router_logits, k, self.norm_topk_prob, self.aux_form
             )
-            h = grouped_matmul(rows, wi, padded, block)
-            if wg is not None:
-                g = grouped_matmul(rows, wg, padded, block)
-                h = nn.silu(g) * h
-            else:
-                h = nn.gelu(h)
-            out_rows = grouped_matmul(h, wo, padded, block)
-
-            weighted = out_rows[dest] * gates_flat[order][:, None]
-            out = jnp.zeros((b * s, d), self.dtype).at[src_token].add(weighted)
-            return out.reshape(b, s, d)
-
         # Tokens stay split over their batch and sequence axes (an MLP is
         # token-wise); the embed dim and the expert weights are whole on
         # every device, so peers on a tensor axis repeat each other's work.
         tokens = nn.logical_to_mesh_axes((lr.BATCH, lr.ACT_SEQ, None))
+        shards = 1
+        for axes in tokens:
+            for axis in (axes,) if isinstance(axes, str) else (axes or ()):
+                shards *= mesh_axis_size(axis)
+        # Dropless: routed == total, so drop_fraction books as exactly 0.
+        self._sow_router_stats(
+            _router_entropy(router_logits),
+            routed=jax.nn.one_hot(
+                gate_idx.reshape(-1), e, dtype=jnp.int32
+            ).sum(axis=0),
+            total=b * s * k,
+            rows_run=shards * _row_budget(b * s * k // shards, block, e),
+        )
+
+        def local(x, gate_vals, gate_idx, *weights):
+            """Group this device's (token, expert) pairs by expert and run
+            each expert's ragged row group through the grouped GEMMs."""
+            wi, wo = weights[0], weights[-1]
+            wg = weights[1] if len(weights) == 3 else None
+            b, s, d = x.shape
+            t = b * s
+            with jax.named_scope("sort"):
+                plan = _dispatch_plan(
+                    gate_idx.reshape(t, k), e, block,
+                    _row_budget(t * k, block, e),
+                )
+            with jax.named_scope("scatter"):
+                rows = _rows_of_tokens(
+                    x.reshape(t, d).astype(self.dtype), plan
+                )
+            with jax.named_scope("gmm_wi"):
+                h = grouped_matmul(rows, wi, plan["padded"], block)
+            if wg is not None:
+                with jax.named_scope("gmm_wg"):
+                    g = grouped_matmul(rows, wg, plan["padded"], block)
+                h = nn.silu(g) * h
+            else:
+                h = nn.gelu(h)
+            with jax.named_scope("gmm_wo"):
+                out_rows = grouped_matmul(h, wo, plan["padded"], block)
+            with jax.named_scope("combine"):
+                out = _tokens_of_rows(
+                    out_rows, gate_vals.reshape(t, k), plan
+                )
+            return out.reshape(b, s, d)
+
         weights = [wi] + ([wg] if wg is not None else []) + [wo]
         out = shard_local(
             local,

@@ -58,6 +58,16 @@ class TransformerConfig:
     moe_aux_weight: float = 0.01
     moe_dispatch: str = "einsum"   # "einsum" | "a2a" | "a2a_int8"
                                    # (EP-shardable) | "grouped" (EP=1 only)
+    # Renormalise the chosen gates to sum to one (Mixtral) or use the
+    # softmax probabilities of the chosen experts as they are (OLMoE).
+    norm_topk_prob: bool = True
+    # Load-balancing loss: "top1" (Switch: first choices only, E^2/k) or
+    # "topk" (E * sum_e f_e * P_e with f_e counted over all k choices, the
+    # form the Mixtral/OLMoE reference implementations train with).
+    moe_aux_form: str = "top1"
+    # RMSNorm over the whole q and k projections (all heads jointly, own
+    # scale each) before the head split and RoPE (OLMoE, OLMo-2).
+    qk_norm: bool = False
     # numerics / execution
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
@@ -275,6 +285,7 @@ class Block(nn.Module):
             param_dtype=cfg.param_dtype,
             attention_impl=cfg.attention_impl,
             fused_qkv=cfg.fused_qkv,
+            qk_norm=cfg.qk_norm,
             flash_block_q=cfg.flash_block_q,
             flash_block_kv=cfg.flash_block_kv,
             decode=cfg.decode,
@@ -300,6 +311,8 @@ class Block(nn.Module):
                 dtype=cfg.dtype,
                 param_dtype=cfg.param_dtype,
                 dispatch=cfg.moe_dispatch,
+                norm_topk_prob=cfg.norm_topk_prob,
+                aux_form=cfg.moe_aux_form,
                 name="moe",
             )(y)
             aux = aux + layer_aux

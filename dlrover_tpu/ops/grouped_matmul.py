@@ -198,7 +198,8 @@ def _gmm_bwd(block_rows, residuals, dy):
         out_shape=jax.ShapeDtypeStruct((e, k, m), w.dtype),
         interpret=backend.interpret(),
     )(eob, x, dy)
-    # Experts with no rows are never visited; their dw block is undefined.
+    # An expert with no rows is never visited and the kernel leaves its dw
+    # block unwritten: that expert's gradient is zero.
     dw = jnp.where((group_sizes > 0)[:, None, None], dw, 0.0).astype(w.dtype)
     return dx, dw, None
 

@@ -30,6 +30,7 @@ import optax
 from dlrover_tpu.common import faults, telemetry
 from dlrover_tpu.common.log import default_logger as logger
 from dlrover_tpu.common.retry import RetryError, RetryPolicy
+from dlrover_tpu.models import moe as moe_lib
 from dlrover_tpu.models.transformer import TransformerConfig, TransformerLM
 from dlrover_tpu.parallel import rules as lr
 from dlrover_tpu.runtime import compile_cache, env as renv
@@ -1268,8 +1269,8 @@ class ElasticTrainer:
             self._emit_memory_event(step)
         if self._pending_moe_stats:
             # Router-health fetch rides the report cadence (queued before
-            # the ring ships below).  Vector layout: [entropy,
-            # drop_fraction, load_0..load_{E-1}] (models/moe.py sow).
+            # the ring ships below).  Vector layout: models/moe.py
+            # ``split_stats``.
             pending, self._pending_moe_stats = self._pending_moe_stats, []
             with pipeline_counters().host_block(
                 "moe_stats", steps=tuple(s for s, _ in pending)
@@ -1279,15 +1280,18 @@ class ElasticTrainer:
                     for s, v in pending
                 ]
             for mstep, vec in pending:
+                entropy, drop, load, pad_share, max_load = (
+                    moe_lib.split_stats(vec)
+                )
                 telemetry.event(
                     "moe", step=mstep,
-                    entropy=float(vec[0]),
-                    drop_fraction=float(vec[1]),
-                    experts=int(vec.size - 2),
+                    entropy=float(entropy),
+                    drop_fraction=float(drop),
+                    experts=int(load.size),
                     top_k=int(getattr(self.model_config, "top_k", 0)),
-                    load=json.dumps(
-                        [round(float(v), 6) for v in vec[2:]]
-                    ),
+                    load=json.dumps([round(float(v), 6) for v in load]),
+                    pad_share=float(pad_share),
+                    max_expert_load=float(max_load),
                 )
         if self.client is not None:
             self.client.report_step(

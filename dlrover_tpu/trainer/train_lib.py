@@ -1234,8 +1234,9 @@ def build_moe_stats_fn(model, train: ShardedTrain):
     """Router-observability harvest: ``fn(state, placed_batch) -> [2+E]``.
 
     Re-applies the model forward with ``mutable=["intermediates"]`` so
-    every MoE layer's sown ``moe_stats`` vector ([gate entropy,
-    capacity-drop fraction, per-expert load]) materializes, then averages
+    every MoE layer's sown ``moe_stats`` vector (``moe.split_stats``: gate
+    entropy, drop fraction, per-expert load, pad share, busiest expert's
+    load) materializes, then averages
     over layers (and any scan/sow stacking).  A SEPARATE jitted program
     from the train step — the step never carries the mutable collection,
     so its trace (and the zero-retrace contract) is untouched; the

@@ -416,6 +416,46 @@ def test_reader_reattaches_after_arena_growth():
     reader.close()
 
 
+@pytest.mark.parametrize("second, settles_again", [
+    ("same", False),      # the mapping is the first save's
+    ("grown", True),      # unlink + create: a new mapping
+    ("attached", True),   # another handler maps the arena that is there
+])
+def test_the_save_that_maps_the_arena_settles_its_pages(
+    tap, second, settles_again
+):
+    """A mapping is read once, page by page, by the save that made it and
+    wrote it first, so that no later save pays for its second pass."""
+    name = f"st{os.getpid()}{second}"
+    small = {"w": np.arange(5000, dtype=np.float32)}
+    writer = SharedMemoryHandler(name)
+    other = SharedMemoryHandler(name)
+    try:
+        meta = writer.save_state_dict(small, step=1)
+        (settle,) = _named(tap.take(), "checkpoint.arena_settle")
+        # header, meta and every byte of the state
+        assert settle[4]["bytes"] > small["w"].nbytes
+        assert writer.load_block(meta, meta.tensors[0].shards[0]).tobytes() \
+            == small["w"].tobytes()
+        if second == "grown":
+            state = {"w": np.ones(1 << 19, np.float32)}
+            saver = writer
+        else:
+            state = {"w": small["w"] + 1}
+            saver = other if second == "attached" else writer
+        meta = saver.save_state_dict(state, step=2)
+        events = tap.take()
+        assert len(_named(events, "checkpoint.arena_settle")) == int(
+            settles_again
+        )
+        assert len(_named(events, "checkpoint.arena")) == int(settles_again)
+        assert saver.load_block(meta, meta.tensors[0].shards[0]).tobytes() \
+            == state["w"].tobytes()
+    finally:
+        other.close()
+        writer.close(unlink=True)
+
+
 def test_torn_write_is_invisible():
     """A crash mid-save must not leave a valid-looking checkpoint: the
     header is zeroed during the write and only published at the end."""

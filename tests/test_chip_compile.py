@@ -145,6 +145,11 @@ CASES = [
      [((40960, 1600), BF16), ((8, 1600, 3200), BF16), ((8,), I32)], {}, 3),
     ("grouped_matmul_wo", _grouped_matmul,
      [((40960, 3200), BF16), ((8, 3200, 1600), BF16), ((8,), I32)], {}, 3),
+    # OLMoE-1B-7B: 64 groups, 131072 routed rows + 64 blocks of budget
+    ("grouped_matmul_olmoe_wi", _grouped_matmul,
+     [((139264, 2048), BF16), ((64, 2048, 1024), BF16), ((64,), I32)], {}, 3),
+    ("grouped_matmul_olmoe_wo", _grouped_matmul,
+     [((139264, 1024), BF16), ((64, 1024, 2048), BF16), ((64,), I32)], {}, 3),
     ("quantize_dequantize", _quant_roundtrip, [LEAF], {}, 2),
     ("q8_adam", lambda: _adam_update("q8_adam"), [LEAF, LEAF], {}, 1),
     ("q4_adam", lambda: _adam_update("q4_adam"), [LEAF, LEAF], {}, 1),

@@ -4,7 +4,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from dlrover_tpu.models.moe import MoEMlp, _router_entropy, top_k_gating
+from dlrover_tpu.models.moe import (
+    STATS_TAIL,
+    MoEMlp,
+    _router_entropy,
+    split_stats,
+    top_k_gating,
+)
 
 
 def test_top2_no_slot_collision():
@@ -69,11 +75,15 @@ def test_router_stats_sown_as_intermediates():
     )
     (vec,) = jax.tree_util.tree_leaves(inter)
     vec = np.asarray(vec, np.float64).ravel()
-    assert vec.shape == (2 + 4,)
-    entropy, drop, load = vec[0], vec[1], vec[2:]
+    assert vec.shape == (2 + 4 + STATS_TAIL,)
+    entropy, drop, load, pad_share, max_load = split_stats(vec)
     assert 0.0 <= entropy <= np.log(4) + 1e-6
     assert 0.0 <= drop <= 1.0
     np.testing.assert_allclose(load.sum(), 1.0, atol=1e-6)
+    # 2 sequences x 4 experts x capacity 16 slots hold the 64 chosen pairs
+    # less the dropped ones; the busiest expert's share over the mean's.
+    np.testing.assert_allclose(pad_share, 1 - 64 * (1 - drop) / 128, atol=1e-6)
+    np.testing.assert_allclose(max_load, load.max() * 4, rtol=1e-6)
     # The plain apply returns no intermediates: sow was a no-op.
     plain = layer.apply(params, x)
     assert isinstance(plain, tuple) and len(plain) == 2
@@ -90,6 +100,8 @@ def test_router_stats_grouped_is_dropless():
     (vec,) = jax.tree_util.tree_leaves(inter)
     vec = np.asarray(vec, np.float64).ravel()
     assert vec[1] == 0.0  # dropless: nothing hit a capacity wall
+    # 64 pairs in a budget of (64 / 8 + 4 experts) blocks of 8 rows
+    np.testing.assert_allclose(split_stats(vec)[3], 1 - 64 / 96, atol=1e-6)
 
     einsum_layer = _stats_layer("einsum", capacity_factor=0.25)
     _, inter = einsum_layer.apply(params, x, mutable=["intermediates"])

@@ -16,6 +16,7 @@ import pytest
 import trace_asserts
 
 from dlrover_tpu.models.llama import moe_llama_config
+from dlrover_tpu.models import moe as moe_lib
 from dlrover_tpu.models.moe import MoEMlp
 from dlrover_tpu.models.transformer import TransformerLM
 from dlrover_tpu.parallel import rules as lr
@@ -217,7 +218,7 @@ def test_moe_steady_state_no_retrace():
 @pytest.mark.slow
 def test_moe_stats_harvest():
     """build_moe_stats_fn reads the sown router stats off the live state:
-    [entropy, drop_fraction, load_0..load_{E-1}] with sane ranges.
+    ``moe.split_stats`` layout, with sane ranges.
     Slow-marked: the layer-level sow contract is witnessed in tier-1 by
     test_moe.py::test_router_stats_sown_as_intermediates."""
     config = _moe_config("a2a")
@@ -234,8 +235,8 @@ def test_moe_stats_harvest():
     stats_fn = train_lib.build_moe_stats_fn(model, train)
     vec = np.asarray(jax.device_get(stats_fn(state, batch)), np.float64)
     e = config.num_experts
-    assert vec.shape == (2 + e,)
-    entropy, drop, load = vec[0], vec[1], vec[2:]
+    assert vec.shape == (2 + e + moe_lib.STATS_TAIL,)
+    entropy, drop, load = moe_lib.split_stats(vec)[:3]
     assert 0.0 <= entropy <= np.log(e) + 1e-6
     assert 0.0 <= drop <= 1.0
     assert np.all(load >= 0.0)

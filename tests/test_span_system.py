@@ -294,8 +294,9 @@ def test_a_save_is_split_where_the_work_happens(tmp_path, tap, small_pieces):
         ]
         names = sorted(e[0] for e in children)
         first = save[4]["step"] == 2
+        # The save that maps the arena also settles its pages.
         assert names == (
-            ["checkpoint.arena"] if first else []
+            ["checkpoint.arena", "checkpoint.arena_settle"] if first else []
         ) + ["checkpoint.d2h", "checkpoint.drain", "checkpoint.shm_write"]
         assert sum(e[3] for e in children) <= save[3]
         (d2h,) = _named(children, "checkpoint.d2h")
@@ -310,6 +311,8 @@ def test_a_save_is_split_where_the_work_happens(tmp_path, tap, small_pieces):
                       id=group)
     (arena_span,) = _named(events, "checkpoint.arena")
     assert arena_span[4]["created"] is True and arena_span[4]["bytes"] > size
+    (settle,) = _named(events, "checkpoint.arena_settle")
+    assert settle[4]["bytes"] == arena_span[4]["bytes"]
     assert not _named(events, "checkpoint.skip")
     assert not _named(events, "checkpoint.d2h_fallback")
     # The second save of the same state compiles nothing and plans nothing

@@ -375,6 +375,7 @@ def test_moe_event_routes_through_servicer_into_gauges():
         "step": 40, "entropy": 1.15, "drop_fraction": 0.03,
         "experts": 4, "top_k": 2,
         "load": "[0.26, 0.25, 0.25, 0.24]",
+        "pad_share": 0.059, "max_expert_load": 1.04,
         "unknown_future_attr": 1,  # trainers may grow the event
     }
     wire = pickle.dumps(msg.Envelope(
@@ -399,6 +400,8 @@ def test_moe_event_routes_through_servicer_into_gauges():
     assert metrics["dlrover_moe_capacity_drop_fraction"] == (
         pytest.approx(0.03)
     )
+    assert metrics["dlrover_moe_pad_share"] == pytest.approx(0.059)
+    assert metrics["dlrover_moe_max_expert_load"] == pytest.approx(1.04)
     assert metrics["dlrover_moe_experts"] == 4
     assert metrics["dlrover_moe_top_k"] == 2
     assert metrics["dlrover_moe_reporters"] == 1

@@ -62,13 +62,17 @@ def _gate(logits: jax.Array, k: int, norm_topk_prob: bool = True,
 def top_k_gating(
     logits: jax.Array, k: int, capacity: int, norm_topk_prob: bool = True,
     aux_form: str = "top1",
-) -> Tuple[jax.Array, jax.Array, jax.Array]:
+) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """Top-k gating with per-expert capacity (Switch/GShard style).
 
-    Returns ``(dispatch, combine, aux_loss)`` with
+    Returns ``(dispatch, combine, aux_loss, routed)`` with
     ``dispatch: [B, S, E, C]`` bool-ish one-hot of (expert, slot) per token,
-    ``combine: [B, S, E, C]`` gate-weighted dispatch, and the load-balancing
-    auxiliary loss (ref ``topk_gating.py`` capability).
+    ``combine: [B, S, E, C]`` gate-weighted dispatch, the load-balancing
+    auxiliary loss (ref ``topk_gating.py`` capability), and ``routed: [E]``
+    the (token, choice) pairs each expert keeps: ``dispatch`` summed over
+    all but its expert axis, read off the slot counters instead (the train
+    step books it every step, and ``dispatch`` is a gigabyte at Mixtral's
+    shapes).
     """
     b, s, e = logits.shape
     gate_vals, gate_idx, aux_loss = _gate(
@@ -95,7 +99,7 @@ def top_k_gating(
         d = onehot[..., None] * slot[..., None, :]            # [B,S,E,C]
         dispatch = dispatch + d
         combine = combine + d * gate_vals[..., choice][..., None, None]
-    return dispatch, combine, aux_loss
+    return dispatch, combine, aux_loss, prior.sum(axis=(0, 1))
 
 
 def _router_entropy(router_logits: jax.Array) -> jax.Array:
@@ -254,9 +258,10 @@ class MoEMlp(nn.Module):
     ``[gate_entropy, drop_fraction, load_0..load_{E-1}, pad_share,
     max_expert_load]`` (:func:`split_stats`) into the
     ``"intermediates"`` collection — a no-op (zero cost) unless the
-    caller applies with ``mutable=["intermediates"]``, which is how the
-    trainer harvests router health on the report cadence without
-    touching the compiled step.
+    caller applies with ``mutable=["intermediates"]``.  The train step is
+    that caller (``train_lib._forward_sums``): the vector leaves the step
+    program as ``metrics["moe_stats"]``, and the trainer reads it on the
+    report cadence.  Serving, RL and the references apply without it.
     """
 
     num_experts: int
@@ -341,13 +346,13 @@ class MoEMlp(nn.Module):
         b, s, d = x.shape
         e = self.num_experts
         capacity = max(1, int(self.capacity_factor * s * self.top_k / e))
-        dispatch, combine, aux_loss = top_k_gating(
+        dispatch, combine, aux_loss, routed = top_k_gating(
             router_logits, self.top_k, capacity, self.norm_topk_prob,
             self.aux_form,
         )
         self._sow_router_stats(
             _router_entropy(router_logits),
-            routed=dispatch.sum(axis=(0, 1, 3)),
+            routed=routed,
             total=b * s * self.top_k,
             rows_run=b * e * capacity,
         )
@@ -443,7 +448,7 @@ class MoEMlp(nn.Module):
             wo_loc = weights[-1]
             # Slot assignment is per (batch row, expert) — identical on a
             # batch chunk to what the full batch computes.
-            dispatch, combine, _ = top_k_gating(
+            dispatch, combine, _, routed = top_k_gating(
                 logits_loc, k, capacity, self.norm_topk_prob
             )
             probs = jax.nn.softmax(logits_loc.astype(jnp.float32), axis=-1)
@@ -463,9 +468,7 @@ class MoEMlp(nn.Module):
                 jnp.mean(-jnp.sum(probs * jnp.log(probs + 1e-9), -1)),
                 batch_axes,
             )
-            routed = jax.lax.psum(
-                dispatch.sum(axis=(0, 1, 3)), batch_axes
-            )
+            routed = jax.lax.psum(routed, batch_axes)
             dispatch = dispatch.astype(self.dtype)
             combine = combine.astype(self.dtype)
             # Local dispatch to ALL experts: [E, b_chunk, C, D].
@@ -502,10 +505,11 @@ class MoEMlp(nn.Module):
     def _sow_router_stats(self, entropy, routed, total, rows_run):
         """Book ``[entropy, drop_fraction, load_0..load_{E-1}, pad_share,
         max_expert_load]`` into the ``"intermediates"`` collection (no-op
-        unless mutable).  ``routed`` are the (token, expert) pairs each
-        expert computes, ``total`` the pairs the router chose, ``rows_run``
-        the rows the expert matmuls run (capacity slots, or the grouped
-        GEMMs' padded row budget): what is not a routed pair is padding."""
+        unless mutable; the train step is the caller that makes it so).
+        ``routed`` are the (token, expert) pairs each expert computes,
+        ``total`` the pairs the router chose, ``rows_run`` the rows the
+        expert matmuls run (capacity slots, or the grouped GEMMs' padded
+        row budget): what is not a routed pair is padding."""
         routed = routed.astype(jnp.float32)
         kept = routed.sum()
         drop = 1.0 - kept / max(1, total)

@@ -400,8 +400,9 @@ class TransformerLM(nn.Module):
             stack = nn.scan(
                 block_cls,
                 # "intermediates" carries the MoE router stats each layer
-                # sows — stacked on a leading layer axis when harvested
-                # with mutable=["intermediates"], absent otherwise.
+                # sows — stacked on a leading layer axis where the caller
+                # applies with mutable=["intermediates"] (the train step
+                # does), absent otherwise.
                 variable_axes={"params": 0, "cache": 0, "intermediates": 0},
                 split_rngs={"params": True},
                 in_axes=nn.broadcast,

@@ -272,12 +272,6 @@ class ElasticTrainer:
         self._digest_fn = None
         self._digest_train = None
         self._pending_digests: List[Tuple[int, Any]] = []
-        # MoE router observability: lazily-built stats program (same
-        # rebuild-on-new-train rule as the digest) and (step, device
-        # vector) pairs fetched + shipped on the report cadence.
-        self._moe_stats_fn = None
-        self._moe_stats_train = None
-        self._pending_moe_stats: List[Tuple[int, Any]] = []
         self._on_step: Optional[Callable[[int, Dict], None]] = None
         self._fit_max_steps = 0
         # Restart-fast compile, layer 1: persistent XLA cache so a restarted
@@ -851,36 +845,7 @@ class ElasticTrainer:
             # Booked inside the step span: the digest dispatch is part
             # of the step's host-observed cost at its check cadence.
             self._sdc_check()
-        if (
-            getattr(self.model_config, "num_experts", 0)
-            and self.step % self.config.report_every == 0
-        ):
-            self._moe_stats_check(placed)
         return metrics
-
-    def _moe_stats_check(self, placed):
-        """Dispatch the router-stats harvest (entropy / load /
-        capacity-drop) on the report cadence; the fetch + telemetry ship
-        ride ``_report``, off the step's critical path.  Best-effort: a
-        model the harvest cannot re-apply (exotic remat policies) logs
-        once and disables itself rather than costing the step loop."""
-        if self._moe_stats_fn is False:
-            return
-        try:
-            if (
-                self._moe_stats_fn is None
-                or self._moe_stats_train is not self.train
-            ):
-                self._moe_stats_fn = train_lib.build_moe_stats_fn(
-                    self.model, self.train
-                )
-                self._moe_stats_train = self.train
-            self._pending_moe_stats.append(
-                (self.step, self._moe_stats_fn(self.state, placed))
-            )
-        except Exception as e:  # noqa: BLE001 — observability must not kill
-            logger.warning("moe stats harvest failed (disabled): %s", e)
-            self._moe_stats_fn = False
 
     # -- device-time capture ---------------------------------------------------
 
@@ -1016,9 +981,16 @@ class ElasticTrainer:
         ring, self._metrics_ring = self._metrics_ring, []
         steps = tuple(step for step, _ in ring)
         with pipeline_counters().host_block("metrics-flush", steps=steps):
-            fetched = jax.device_get([metrics for _, metrics in ring])
-        for (step, _), host in zip(ring, fetched):
+            fetched = jax.device_get([
+                {k: v for k, v in metrics.items() if k != "moe_stats"}
+                for _, metrics in ring
+            ])
+        for (step, device), host in zip(ring, fetched):
             host = {k: float(np.asarray(v)) for k, v in host.items()}
+            if "moe_stats" in device:
+                # An MoE step's router vector stays on the device: only
+                # a report reads it (``_report``).
+                host["moe_stats"] = device["moe_stats"]
             self._last_metrics = host
             if self._on_step is not None:
                 self._on_step(step, host)
@@ -1267,32 +1239,27 @@ class ElasticTrainer:
             # drain RPC.  Off path (memory_report=False) this branch is
             # the one attribute read.
             self._emit_memory_event(step)
-        if self._pending_moe_stats:
-            # Router-health fetch rides the report cadence (queued before
-            # the ring ships below).  Vector layout: models/moe.py
-            # ``split_stats``.
-            pending, self._pending_moe_stats = self._pending_moe_stats, []
-            with pipeline_counters().host_block(
-                "moe_stats", steps=tuple(s for s, _ in pending)
-            ):
-                pending = [
-                    (s, np.asarray(jax.device_get(v), np.float64))
-                    for s, v in pending
-                ]
-            for mstep, vec in pending:
-                entropy, drop, load, pad_share, max_load = (
-                    moe_lib.split_stats(vec)
-                )
-                telemetry.event(
-                    "moe", step=mstep,
-                    entropy=float(entropy),
-                    drop_fraction=float(drop),
-                    experts=int(load.size),
-                    top_k=int(getattr(self.model_config, "top_k", 0)),
-                    load=json.dumps([round(float(v), 6) for v in load]),
-                    pad_share=float(pad_share),
-                    max_expert_load=float(max_load),
-                )
+        moe_stats = metrics.get("moe_stats")
+        if moe_stats is not None and step % cfg.report_every == 0:
+            # Router health on the report cadence (queued before the ring
+            # ships below): the vector this step's program returned, so
+            # of the parameters the step routed with, ready when its loss
+            # is.  Layout: models/moe.py ``split_stats``.
+            with pipeline_counters().host_block("moe_stats", steps=(step,)):
+                vec = np.asarray(jax.device_get(moe_stats), np.float64)
+            entropy, drop, load, pad_share, max_load = (
+                moe_lib.split_stats(vec)
+            )
+            telemetry.event(
+                "moe", step=step,
+                entropy=float(entropy),
+                drop_fraction=float(drop),
+                experts=int(load.size),
+                top_k=int(getattr(self.model_config, "top_k", 0)),
+                load=json.dumps([round(float(v), 6) for v in load]),
+                pad_share=float(pad_share),
+                max_expert_load=float(max_load),
+            )
         if self.client is not None:
             self.client.report_step(
                 step,

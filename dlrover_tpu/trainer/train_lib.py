@@ -411,6 +411,22 @@ def _batch_shard_count(mesh: Mesh, batch_spec_entry) -> int:
     return out
 
 
+def _mean_moe_stats(sown) -> Optional[jax.Array]:
+    """One float32 ``moe_stats`` vector (``moe.split_stats``) out of the
+    ``"intermediates"`` a forward pass sowed: the mean over the MoE layers
+    and over whatever axes the layer scan and the sow stack.  ``None``
+    where no layer sowed one."""
+    vectors = [
+        leaf.reshape(-1, leaf.shape[-1])
+        for path, leaf in jax.tree_util.tree_leaves_with_path(sown)
+        if any(getattr(key, "key", None) == "moe_stats" for key in path)
+    ]
+    if not vectors:
+        return None
+    stats = jnp.concatenate(vectors, axis=0).astype(jnp.float32)
+    return jax.lax.stop_gradient(jnp.mean(stats, axis=0))
+
+
 def build_sharded_train(
     model: nn.Module,
     optimizer: optax.GradientTransformation,
@@ -649,20 +665,36 @@ def build_sharded_train(
             " [int8]" if allgather_quant == "int8" else "",
         )
 
+    # A model with routers hands each layer's ``moe_stats`` vector out of
+    # the step that computes it; a dense model's step is applied as ever.
+    has_experts = bool(
+        getattr(getattr(model, "config", None), "num_experts", 0)
+    )
+
     def _forward_sums(params, apply_fn, inputs, targets, weights):
-        """One forward pass -> (weighted CE sum, token count, aux loss)."""
-        if ce_chunks:
-            hidden, aux = apply_fn(
-                {"params": params}, inputs, return_hidden=True
+        """One forward pass -> (weighted CE sum, token count, aux loss,
+        router statistics).  The last is the layers' sown ``moe_stats``
+        vectors (``moe.split_stats``) averaged over layers and whatever
+        axes the scan and the sow stack, under ``stop_gradient``; ``None``
+        for a model that sows none."""
+        kwargs = {"return_hidden": True} if ce_chunks else {}
+        variables = {"params": params}
+        if has_experts:
+            (out, aux), sown = apply_fn(
+                variables, inputs, mutable=["intermediates"], **kwargs
             )
+            moe_stats = _mean_moe_stats(sown)
+        else:
+            out, aux = apply_fn(variables, inputs, **kwargs)
+            moe_stats = None
+        if ce_chunks:
             ce, total_weight = chunked_cross_entropy_loss(
-                hidden, output_head(params), targets, weights,
+                out, output_head(params), targets, weights,
                 num_chunks=ce_chunks,
             )
         else:
-            logits, aux = apply_fn({"params": params}, inputs)
-            ce, total_weight = cross_entropy_loss(logits, targets, weights)
-        return ce * total_weight, total_weight, aux
+            ce, total_weight = cross_entropy_loss(out, targets, weights)
+        return ce * total_weight, total_weight, aux, moe_stats
 
     def _q_reduce_scatter_leaf(leaf, z_sharding, full_sharding):
         """Route one gradient leaf's DP reduce through the int8 wire as a
@@ -817,16 +849,16 @@ def build_sharded_train(
         TRACE_COUNTS["train_step"] += 1
 
         def loss_fn(params):
-            ce_sum, total_weight, aux = _forward_sums(
+            ce_sum, total_weight, aux, moe_stats = _forward_sums(
                 params, state.apply_fn, batch["inputs"], batch["targets"],
                 batch["weights"],
             )
             ce = ce_sum / total_weight
-            return ce + aux, (ce, aux, total_weight)
+            return ce + aux, (ce, aux, total_weight, moe_stats)
 
-        grads, (ce, aux, total_weight) = jax.grad(loss_fn, has_aux=True)(
-            state.params
-        )
+        grads, (ce, aux, total_weight, moe_stats) = jax.grad(
+            loss_fn, has_aux=True
+        )(state.params)
         if overlap_active:
             # Per-bucket reduce-scatter waves directly off the backward:
             # each leaf's scatter depends only on that leaf's gradient, so
@@ -841,6 +873,8 @@ def build_sharded_train(
             "grad_norm": optax.global_norm(grads),
             "step": new_state.step,
         }
+        if moe_stats is not None:
+            metrics["moe_stats"] = moe_stats
         return new_state, metrics
 
     def _accum_train_step(state: TrainState, batch: Dict[str, jax.Array]):
@@ -871,13 +905,15 @@ def build_sharded_train(
         )
 
         def micro_loss(params, mb):
-            ce_sum, _w, aux = _forward_sums(
+            ce_sum, _w, aux, moe_stats = _forward_sums(
                 params, state.apply_fn, mb["inputs"], mb["targets"],
                 mb["weights"],
             )
             # aux (model-internal regularizers) is a per-microbatch mean:
             # average it over N so its gradient scale matches full-batch.
-            return ce_sum / w_total + aux / grad_accum, (ce_sum, aux)
+            return ce_sum / w_total + aux / grad_accum, (
+                ce_sum, aux, moe_stats
+            )
 
         params_shardings = state_shardings.params
         # Overlap: the accumulator lives in the 1/dp zero1 shard layout
@@ -900,17 +936,19 @@ def build_sharded_train(
 
         def accum(carry, mb):
             gacc, ce_acc, aux_acc = carry
-            g, (ce_sum, aux) = jax.grad(micro_loss, has_aux=True)(
-                state.params, mb
-            )
+            g, (ce_sum, aux, moe_stats) = jax.grad(
+                micro_loss, has_aux=True
+            )(state.params, mb)
             if overlap_active:
                 g = _scatter_grads(g)
             gacc = pin(jax.tree.map(
                 lambda a, gi: a + gi.astype(a.dtype), gacc, g
             ))
-            return (gacc, ce_acc + ce_sum, aux_acc + aux), None
+            # The microbatches' router statistics stack as the scan's
+            # output (``None``, and no output, for a dense model).
+            return (gacc, ce_acc + ce_sum, aux_acc + aux), moe_stats
 
-        (grads, ce_sum, aux_sum), _ = jax.lax.scan(
+        (grads, ce_sum, aux_sum), moe_stats = jax.lax.scan(
             accum, (grads0, jnp.zeros((), jnp.float32),
                     jnp.zeros((), jnp.float32)), xs
         )
@@ -951,6 +989,8 @@ def build_sharded_train(
             "grad_norm": optax.global_norm(grads),
             "step": new_state.step,
         }
+        if moe_stats is not None:
+            metrics["moe_stats"] = moe_stats.mean(axis=0)
         return new_state, metrics
 
     if grad_accum > 1:
@@ -1228,34 +1268,3 @@ def shard_batch(
 
         pipeline_counters().record_place(time.perf_counter() - t0)
     return out
-
-
-def build_moe_stats_fn(model, train: ShardedTrain):
-    """Router-observability harvest: ``fn(state, placed_batch) -> [2+E]``.
-
-    Re-applies the model forward with ``mutable=["intermediates"]`` so
-    every MoE layer's sown ``moe_stats`` vector (``moe.split_stats``: gate
-    entropy, drop fraction, per-expert load, pad share, busiest expert's
-    load) materializes, then averages
-    over layers (and any scan/sow stacking).  A SEPARATE jitted program
-    from the train step — the step never carries the mutable collection,
-    so its trace (and the zero-retrace contract) is untouched; the
-    trainer runs this on the report cadence only, like the SDC digest.
-    """
-
-    @jax.jit
-    def stats(params, tokens):
-        _, inter = model.apply(
-            {"params": params}, tokens, mutable=["intermediates"]
-        )
-        leaves = jax.tree_util.tree_leaves(inter)
-        stacked = jnp.concatenate(
-            [leaf.reshape(-1, leaf.shape[-1]) for leaf in leaves], axis=0
-        )
-        return jnp.mean(stacked, axis=0)
-
-    def run(state, batch):
-        with use_mesh(train.mesh):
-            return stats(state.params, batch["inputs"])
-
-    return run

@@ -19,26 +19,30 @@ def test_top2_no_slot_collision():
     logits = jnp.array(
         [[[2.0, 1.0], [2.0, 1.0], [1.0, 2.0], [1.0, 2.0]]]
     )  # [1, 4, 2]
-    dispatch, combine, _ = top_k_gating(logits, k=2, capacity=4)
+    dispatch, combine, _, routed = top_k_gating(logits, k=2, capacity=4)
     occupancy = np.asarray(dispatch.sum(axis=1))  # [1, E, C]
     assert occupancy.max() <= 1.0 + 1e-6, occupancy
     # every token got both choices dispatched (capacity is ample)
     assert float(dispatch.sum()) == 8.0
+    np.testing.assert_array_equal(routed, [4.0, 4.0])
 
 
 def test_capacity_drops_overflow():
     logits = jnp.zeros((1, 8, 2))  # all tokens identical -> same expert order
-    dispatch, _, _ = top_k_gating(logits, k=1, capacity=3)
+    dispatch, _, _, routed = top_k_gating(logits, k=1, capacity=3)
     occupancy = np.asarray(dispatch.sum(axis=1))
     assert occupancy.max() <= 1.0 + 1e-6
     # only `capacity` tokens make it in
     assert float(dispatch.sum()) == 3.0
+    np.testing.assert_array_equal(routed, [3.0, 0.0])
 
 
 def test_combine_weights_normalized():
     rng = np.random.default_rng(0)
     logits = jnp.asarray(rng.normal(size=(2, 16, 4)).astype(np.float32))
-    dispatch, combine, aux = top_k_gating(logits, k=2, capacity=16)
+    dispatch, combine, aux, routed = top_k_gating(logits, k=2, capacity=16)
+    # the slot counters count what ``dispatch`` holds, expert by expert
+    np.testing.assert_array_equal(routed, dispatch.sum(axis=(0, 1, 3)))
     # combine weights per token sum to ~1 where both choices kept
     token_mass = np.asarray(combine.sum(axis=(2, 3)))
     assert token_mass.max() <= 1.0 + 1e-5

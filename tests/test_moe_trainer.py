@@ -5,10 +5,13 @@ GSPMD einsum reference (layer-level fp32 is BITWISE — the explicit
 exchange is a re-transport of the same math, not an approximation),
 composition with the microbatch/ZeRO-1/overlap engines through
 ``build_sharded_train``, expert-axis param sharding, the grouped-dispatch
-EP>1 guard, router-stats harvest, cache-key coverage of the MoE knobs,
+EP>1 guard, the router statistics a step hands out, cache-key coverage of the MoE knobs,
 and the zero-retrace steady state.
 """
 
+import json
+
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -215,32 +218,198 @@ def test_moe_steady_state_no_retrace():
     assert np.isfinite(float(metrics["loss"]))
 
 
-@pytest.mark.slow
-def test_moe_stats_harvest():
-    """build_moe_stats_fn reads the sown router stats off the live state:
-    ``moe.split_stats`` layout, with sane ranges.
-    Slow-marked: the layer-level sow contract is witnessed in tier-1 by
-    test_moe.py::test_router_stats_sown_as_intermediates."""
-    config = _moe_config("a2a")
-    mesh = build_mesh(EP_MESH)
-    model = TransformerLM(config)
-    opt = train_lib.make_optimizer("sgd", learning_rate=1e-2)
-    train = train_lib.build_sharded_train(
-        model, opt, mesh, lr.DEFAULT_RULES,
-        global_batch_size=16, seq_len=16,
+# -- the step hands out the router's numbers ----------------------------------
+
+ONE_CHIP = ParallelConfig(data=-1)
+
+
+def _sown_stats(model, params, tokens):
+    """The layers' ``moe_stats`` vectors of one plain forward, reduced as
+    the step reduces them: the mean over layers and stacking axes."""
+    _, sown = model.apply(
+        {"params": params}, tokens, mutable=["intermediates"]
     )
-    state = train.init(jax.random.PRNGKey(0))
-    batch = train_lib.shard_batch(_batches(1)[0], train)
-    state, _ = train.step(state, batch)
-    stats_fn = train_lib.build_moe_stats_fn(model, train)
-    vec = np.asarray(jax.device_get(stats_fn(state, batch)), np.float64)
+    leaves = jax.tree_util.tree_leaves(sown)
+    stacked = jnp.concatenate(
+        [leaf.reshape(-1, leaf.shape[-1]) for leaf in leaves], axis=0
+    )
+    return np.asarray(jnp.mean(stacked, axis=0), np.float64)
+
+
+def _build(config, parallel=ONE_CHIP, **build_kw):
+    model = TransformerLM(config)
+    train = train_lib.build_sharded_train(
+        model, train_lib.make_optimizer("sgd", learning_rate=1e-2),
+        build_mesh(parallel), lr.DEFAULT_RULES,
+        global_batch_size=16, seq_len=16, donate_state=False, **build_kw,
+    )
+    return model, train, train.init(jax.random.PRNGKey(0))
+
+
+@pytest.mark.parametrize(
+    "dispatch,grad_accum", [("einsum", 1), ("grouped", 1), ("einsum", 2)],
+    ids=["einsum", "grouped", "einsum-accum2"],
+)
+def test_step_metrics_carry_the_router_stats_of_its_input_params(
+    dispatch, grad_accum
+):
+    """``metrics["moe_stats"]`` is what a plain forward with the
+    collection mutable sows on the step's INPUT parameters and batch
+    (under ``grad_accum`` the mean over the microbatches), in
+    ``split_stats``' ranges."""
+    config = _moe_config(dispatch, dtype=jnp.float32)
+    model, train, state = _build(config, grad_accum=grad_accum)
+    raw = _batches(1)[0]
+    # Under the step's mesh and rules: the grouped path's row budget (and
+    # with it ``pad_share``) is per shard of the tokens.
+    with train_lib.use_mesh(train.mesh), nn.logical_axis_rules(train.rules):
+        want = np.mean([
+            _sown_stats(model, state.params, jnp.asarray(rows))
+            for rows in np.split(raw["inputs"], grad_accum)
+        ], axis=0)
+    _, metrics = train.step(state, train_lib.shard_batch(raw, train))
+    got = metrics["moe_stats"]
     e = config.num_experts
-    assert vec.shape == (2 + e + moe_lib.STATS_TAIL,)
-    entropy, drop, load = moe_lib.split_stats(vec)[:3]
+    assert got.shape == (2 + e + moe_lib.STATS_TAIL,)
+    assert got.dtype == jnp.float32
+    got = np.asarray(got, np.float64)
+    np.testing.assert_allclose(got, want, atol=1e-6)
+    entropy, drop, load, pad_share, max_load = moe_lib.split_stats(got)
     assert 0.0 <= entropy <= np.log(e) + 1e-6
-    assert 0.0 <= drop <= 1.0
+    assert 0.0 <= drop <= 1.0 and 0.0 <= pad_share < 1.0
     assert np.all(load >= 0.0)
     np.testing.assert_allclose(load.sum(), 1.0, atol=1e-5)
+    # A mean over layers of each layer's busiest expert: no less than the
+    # busiest of the layers' mean loads.
+    assert load.max() * e - 1e-5 <= max_load <= e
+
+
+@pytest.mark.parametrize("dispatch", ["einsum", "grouped"])
+def test_the_extra_output_leaves_the_step_s_mathematics_alone(dispatch):
+    """Loss, gradient norm and the updated parameters of an MoE step are
+    those of ``jax.grad`` over the same loss with no collection mutable
+    (the vector is an output under ``stop_gradient``, nothing more)."""
+    import optax
+
+    config = _moe_config(dispatch, dtype=jnp.float32)
+    model, train, state = _build(config)
+    raw = _batches(1)[0]
+    batch = train_lib.shard_batch(raw, train)
+
+    def plain_loss(params):
+        logits, aux = model.apply({"params": params}, batch["inputs"])
+        ce, _ = train_lib.cross_entropy_loss(
+            logits, batch["targets"], batch["weights"]
+        )
+        return ce + aux, ce
+
+    @jax.jit
+    def plain_step(params, opt_state):
+        grads, ce = jax.grad(plain_loss, has_aux=True)(params)
+        updates, _ = train.tx.update(grads, opt_state, params)
+        return (
+            optax.apply_updates(params, updates), ce,
+            optax.global_norm(grads),
+        )
+
+    with train_lib.use_mesh(train.mesh):
+        want_params, want_loss, want_norm = plain_step(
+            state.params, state.opt_state
+        )
+    new_state, metrics = train.step(state, batch)
+    np.testing.assert_allclose(
+        float(metrics["loss"]), float(want_loss), rtol=1e-6
+    )
+    np.testing.assert_allclose(
+        float(metrics["grad_norm"]), float(want_norm), rtol=1e-6
+    )
+    jax.tree.map(
+        lambda got, want: np.testing.assert_allclose(
+            np.asarray(got), np.asarray(want), rtol=1e-6, atol=1e-7
+        ),
+        new_state.params, want_params,
+    )
+
+
+def test_a_dense_step_has_no_router_output():
+    """No experts, no new output: a dense model's step hands back the
+    five scalars it always has, and nothing named ``moe_stats``."""
+    from dlrover_tpu.models.gpt2 import gpt2_config
+
+    config = gpt2_config(
+        "124m", num_layers=2, d_model=64, num_heads=2, vocab_size=256,
+        max_seq_len=64,
+    )
+    for grad_accum in (1, 2):
+        _, train, state = _build(config, grad_accum=grad_accum)
+        batch = train_lib.shard_batch(_batches(1)[0], train)
+        with train_lib.use_mesh(train.mesh):
+            _, metrics = jax.eval_shape(train.step_fn, state, batch)
+        assert {
+            k: (v.shape, v.dtype.name) for k, v in metrics.items()
+        } == {
+            "loss": ((), "float32"), "aux_loss": ((), "float32"),
+            "tokens": ((), "float32"), "grad_norm": ((), "float32"),
+            "step": ((), "int32"),
+        }
+
+
+MOE_EVENT_ATTRS = {
+    "step", "entropy", "drop_fraction", "experts", "top_k", "load",
+    "pad_share", "max_expert_load",
+}
+
+
+@pytest.mark.parametrize("metrics_lag", [0, 4])
+def test_fit_books_one_moe_event_per_report_from_the_step_itself(
+    metrics_lag, monkeypatch, tmp_path
+):
+    """Ten steps at ``report_every=5``: exactly two ``moe`` events, of
+    steps 5 and 10, every attribute present, one trace of the step
+    program and no second program beside it."""
+    from dlrover_tpu.common import telemetry
+    from dlrover_tpu.trainer.elastic_trainer import (
+        ElasticTrainer,
+        TrainerConfig,
+    )
+
+    monkeypatch.setenv("DLROVER_TPU_JOB", f"moe_{tmp_path.name}")
+    monkeypatch.setenv("DLROVER_TPU_SOCKET_DIR", str(tmp_path / "socks"))
+    config = _moe_config("grouped")
+    train_lib.reset_build_cache()
+    train_lib.reset_trace_counts()
+    trainer = ElasticTrainer(
+        config,
+        TrainerConfig(
+            global_batch_size=16, seq_len=16, learning_rate=1e-2,
+            ckpt_every=1000, report_every=5, metrics_lag=metrics_lag,
+        ),
+        client=None,
+    )
+    seen = []
+    with telemetry.recorder().open_tap() as tap:
+        trainer.fit(
+            _batches(10), max_steps=10,
+            on_step=lambda step, metrics: seen.append(step),
+        )
+        events = [
+            e for e in tap.take() if e[0] == "moe" and e[1] == "event"
+        ]
+    assert seen == list(range(1, 11))
+    assert [e[4]["step"] for e in events] == [5, 10]
+    e_count = config.num_experts
+    for event in events:
+        attrs = event[4]
+        assert MOE_EVENT_ATTRS <= set(attrs)
+        assert attrs["experts"] == e_count and attrs["top_k"] == config.top_k
+        load = json.loads(attrs["load"])
+        assert len(load) == e_count and abs(sum(load) - 1.0) < 1e-4
+        assert 0.0 <= attrs["entropy"] <= np.log(e_count) + 1e-6
+        assert attrs["drop_fraction"] == 0.0  # dropless
+        assert 1.0 <= attrs["max_expert_load"] <= e_count
+    assert train_lib.trace_count("train_step") == 1
+    # No second program, nor any state for one, on the trainer.
+    assert not [name for name in vars(trainer) if "moe_stats" in name]
 
 
 def test_train_cache_key_covers_moe_knobs():

@@ -88,10 +88,6 @@ def recompute_flops_per_token(config, remat: str) -> float:
         # saved mlp_out additionally skips the wo forward recompute
         "branch_out": qkv + wi + attn_fwd,
         "dots": attn_fwd,
-        # offload keeps qkv_proj/attn_out/mlp_wo resident (pinned host):
-        # no matmul recompute at all — its cost is DMA, not FLOPs, so HFU
-        # accounting sees only the attention-forward replay inside flash.
-        "offload": attn_fwd,
     }.get(remat, qkv + wi + wo + attn_fwd)
     return per_layer * config.num_layers
 

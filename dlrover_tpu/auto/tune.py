@@ -45,24 +45,28 @@ from dlrover_tpu.runtime.mesh import ParallelConfig
 # it; ``bench.py`` reads the same rows.  A device that is not here is an
 # error, never a default: a utilisation against a guessed peak means
 # nothing.  Columns: peak bf16 FLOP/s, HBM B/s, HBM bytes, ICI B/s per
-# link and direction, sustained host<->HBM DMA B/s (one direction).
+# link and direction.
 # Sources: Google Cloud documentation, "TPU v5e" / "TPU v5p" / "TPU v4"
-# system architecture pages, for the first four columns.  The host-DMA
-# column is an assumption nobody has measured on these chips — it is THE
-# number the offload-vs-recompute trade hinges on, kept conservative
-# (PROFILE.md "Remat policies").  The "cpu" row is not a device's peak: it
+# system architecture pages.  The "cpu" row is not a device's peak: it
 # only makes the model's ranking meaningful (relative, not absolute) on
 # the virtual CPU mesh of the tests.
 _CHIP_SPECS = {
-    "tpu v5 lite": (197e12, 819e9, 16e9, 4.5e10, 15e9),   # v5e
-    "tpu v5e": (197e12, 819e9, 16e9, 4.5e10, 15e9),
-    "tpu v5p": (459e12, 2765e9, 95e9, 9e10, 32e9),
-    "tpu v4": (275e12, 1228e9, 32e9, 9e10, 32e9),
-    "cpu": (1e12, 100e9, 8e9, 1e10, 10e9),
+    "tpu v5 lite": (197e12, 819e9, 16e9, 4.5e10),   # v5e
+    "tpu v5e": (197e12, 819e9, 16e9, 4.5e10),
+    "tpu v5p": (459e12, 2765e9, 95e9, 9e10),
+    "tpu v4": (275e12, 1228e9, 32e9, 9e10),
+    "cpu": (1e12, 100e9, 8e9, 1e10),
 }
 
+# Host-to-device B/s for a step's batch: an ASSUMPTION, measured on no
+# chip.  Its one reader, ``est_h2d_time``, sits under a max() with the
+# compute time, which a batch's 12 bytes a token never reach.
+_HOST_TO_DEVICE_BW = 15e9
 
-def _spec_row(device=None) -> Tuple[float, float, float, float, float]:
+
+def chip_specs(device=None) -> Tuple[float, float, float, float]:
+    """(peak bf16 FLOP/s, HBM B/s, HBM bytes, ICI B/s) of ``device``
+    (default: the first device); raises for a kind not in the table."""
     device = device or jax.devices()[0]
     kind = device.device_kind.lower()
     if kind not in _CHIP_SPECS:
@@ -72,16 +76,6 @@ def _spec_row(device=None) -> Tuple[float, float, float, float, float]:
             "auto/tune.py _CHIP_SPECS"
         )
     return _CHIP_SPECS[kind]
-
-
-def chip_specs(device=None) -> Tuple[float, float, float, float]:
-    """(peak bf16 FLOP/s, HBM B/s, HBM bytes, ICI B/s) of ``device``
-    (default: the first device); raises for a kind not in the table."""
-    return _spec_row(device)[:4]
-
-
-def host_dma_bandwidth(device=None) -> float:
-    return _spec_row(device)[4]
 
 
 @dataclasses.dataclass
@@ -99,12 +93,10 @@ class Candidate:
     fused_ln: bool = False                   # Pallas one-pass LN backward
     est_step_time: float = math.inf
     est_hbm_gb: float = math.inf
-    # Accounting components the remat choice trades against each other
-    # (ops/remat_policy.py): backward recompute time vs host<->HBM DMA
-    # time for offloaded activations.  Exposed so tests (and operators
+    # Backward recompute time, the accounting component the remat choice
+    # moves (ops/remat_policy.py).  Exposed so tests (and operators
     # reading the candidate table) can see WHY a policy won.
     est_recompute_time: float = 0.0
-    est_dma_time: float = 0.0
     # Input-pipeline H2D time for the local batch slice.  With the device
     # prefetcher (data.loader.DevicePrefetcher) this OVERLAPS compute, so
     # it enters the step estimate under the same max() as compute/HBM
@@ -241,16 +233,13 @@ def enumerate_candidates(
     """
     heads = config.num_heads
     seq_len = seq_len or config.max_seq_len
-    if search_kernels:
+    if search_kernels and config.attention_impl == "flash":
         # The remat policy is a searchable kernel-class knob like flash
-        # blocks / CE chunking: widen with host offload (and the flash
-        # residual policies where the flash names exist) so the chip
-        # arbitrates the recompute-vs-DMA trade empirically.
-        extra = ["offload"]
-        if config.attention_impl == "flash":
-            extra += ["flash_only", "flash_res"]
+        # blocks / CE chunking: widen with the flash residual policies
+        # where the flash names exist.
         remat_policies = tuple(remat_policies) + tuple(
-            r for r in extra if r not in remat_policies
+            r for r in ("flash_only", "flash_res")
+            if r not in remat_policies
         )
     # Validate up front, identically on every host: a policy without a
     # broadcast code raising only on the hosts whose measured best uses it
@@ -344,16 +333,6 @@ def _estimate(
         tokens_local * config.num_layers * config.d_model * 2 * act_mult
         / max(p.tensor, 1) / max(p.pipe, 1)
     )
-    # Host-offloaded activations (offload-family policies): zero HBM
-    # residency, but every byte crosses the host DMA link twice per step
-    # (park at forward, fetch at backward).  Priced at the policy's
-    # intended semantics even where the local backend would fall back to
-    # save-only — the plan is for the target chip, not the test mesh.
-    offload_b = (
-        tokens_local * config.num_layers * config.d_model * 2
-        * policy.offload_bytes_per_token_layer
-        / max(p.tensor, 1) / max(p.pipe, 1)
-    )
     # transient working set (attention + MLP blocks)
     work_b = tokens_local * config.resolved_d_ff * 2 * 4 / max(p.tensor, 1)
     # Logits working set: unchunked CE materializes [tokens, vocab] fp32
@@ -378,14 +357,11 @@ def _estimate(
     mxu_eff = 0.55  # measured sustained efficiency at bench shapes
     t_compute = flops_dev / (peak_flops * mxu_eff)
     # Backward recompute is SERIAL extra compute (the replay runs before
-    # the grads that need it), and the backward fetch of offloaded
-    # activations is serial DMA the same way — both are additive terms, so
-    # the offload-vs-save trade reduces to est_dma_time vs the recompute
-    # time the offload avoids.  Forward FLOPs are 1/3 of ftok.
+    # the grads that need it), so it is an additive term.  Forward FLOPs
+    # are 1/3 of ftok.
     t_recompute = (
         flops_dev * policy.recompute_fraction / 3 / (peak_flops * mxu_eff)
     )
-    t_dma = 2 * offload_b / host_dma_bandwidth()
     # Flash block sizes: measured relative attention-kernel cost on v5e at
     # seq 1024 (PROFILE.md round 3 table; one-kv-block is fastest because
     # the fused single-pass backward engages).  Attention is ~20% of the
@@ -488,13 +464,12 @@ def _estimate(
     # copy with the previous step's compute, so it shares the roofline
     # max() with compute/HBM instead of adding to the critical path; a
     # shape is only penalized when it is genuinely input-bound.
-    t_h2d = tokens_local * 12 / host_dma_bandwidth()
+    t_h2d = tokens_local * 12 / _HOST_TO_DEVICE_BW
     cand.est_recompute_time = t_recompute
-    cand.est_dma_time = t_dma
     cand.est_h2d_time = t_h2d
     cand.est_comm_time = t_ici * bubble
     cand.est_step_time = (
-        max(t_compute, t_hbm, t_h2d) + t_recompute + t_dma + t_ici
+        max(t_compute, t_hbm, t_h2d) + t_recompute + t_ici
     ) * bubble
 
 
@@ -791,47 +766,22 @@ def _knob_neighbors(
     return out
 
 
-_REMAT_CODES = {"none": 0, "full": 1, "dots": 2, "attn_out": 3,
-                "branch_out": 4, "flash_only": 5, "flash_res": 6,
-                "dots_no_batch": 7, "offload": 8}
-_CODE_TO_REMAT = {v: k for k, v in _REMAT_CODES.items()}
-# Selective offload policies ("offload:<names>") encode as a bitmask over
-# remat_policy.OFFLOADABLE_NAMES above this base — an open set of names
-# needs no per-name registry entry to broadcast.
-_OFFLOAD_CODE_BASE = 100
-
-
 def _encode_remat(name: str) -> int:
-    if name in _REMAT_CODES:
-        return _REMAT_CODES[name]
-    policy = remat_policy_lib.resolve(name)  # ValueError on garbage
-    if policy.offload_names:
-        bits = 0
-        for i, n in enumerate(remat_policy_lib.OFFLOADABLE_NAMES):
-            if n in policy.offload_names:
-                bits |= 1 << i
-        return _OFFLOAD_CODE_BASE + bits
-    raise ValueError(
-        f"remat policy {name!r} has no broadcast code; add it to "
-        "_REMAT_CODES"
+    """A policy's broadcast code: its place in the registry's sorted
+    names, the same on every host of one version."""
+    return remat_policy_lib.available().index(
+        remat_policy_lib.resolve(name).name  # ValueError on garbage
     )
 
 
 def _decode_remat(code: int) -> str:
-    if code in _CODE_TO_REMAT:
-        return _CODE_TO_REMAT[code]
-    if code >= _OFFLOAD_CODE_BASE:
-        bits = code - _OFFLOAD_CODE_BASE
-        names = [
-            n for i, n in enumerate(remat_policy_lib.OFFLOADABLE_NAMES)
-            if bits & (1 << i)
-        ]
-        if names:
-            return remat_policy_lib.offload_policy_name(names)
-    raise ValueError(
-        f"broadcast remat code {code} unknown to this host "
-        "(version skew between hosts?)"
-    )
+    names = remat_policy_lib.available()
+    if not 0 <= code < len(names):
+        raise ValueError(
+            f"broadcast remat code {code} unknown to this host "
+            "(version skew between hosts?)"
+        )
+    return names[code]
 
 
 def _broadcast_choice(best: Candidate, ranked: List[Candidate]) -> Candidate:

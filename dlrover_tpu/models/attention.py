@@ -222,7 +222,6 @@ class Attention(nn.Module):
     dtype: layers.Dtype = jnp.bfloat16
     param_dtype: layers.Dtype = jnp.float32
     attention_impl: str = "xla"
-    fused_qkv: bool = True
     qk_norm: bool = False
     flash_block_q: int = 512
     flash_block_kv: int = 512
@@ -243,7 +242,10 @@ class Attention(nn.Module):
         if positions is None:
             positions = jnp.arange(x.shape[1])[None, :]
 
-        if self.fused_qkv and self.num_kv_heads == self.num_heads:
+        if self.num_kv_heads == self.num_heads:
+            # This is the whole rule: without GQA the three projections are
+            # equally wide and run as one kernel (param ``qkv``), with GQA
+            # they stay three (``query``/``key``/``value``).
             # One [d, H, 3*hd] matmul instead of three [d, H, hd] ones: the
             # wider N dim keeps the MXU tiled efficiently (measured 37% ->
             # ~75% MFU on v5e at GPT-2 1.5B shapes).  The split is on the
@@ -255,10 +257,6 @@ class Attention(nn.Module):
                 use_bias=self.use_bias,
                 dtype=self.dtype,
                 param_dtype=self.param_dtype,
-                # Remat saveable: under offload-family policies the fused
-                # projection output moves to pinned host memory instead of
-                # being recomputed in the backward.
-                save_name="qkv_proj",
                 name="qkv",
             )(x)
             q = qkv[..., : self.head_dim]
@@ -271,7 +269,6 @@ class Attention(nn.Module):
                 use_bias=self.use_bias,
                 dtype=self.dtype,
                 param_dtype=self.param_dtype,
-                save_name="qkv_proj",
                 name="query",
             )(x)
             k = layers.DenseGeneral(
@@ -280,7 +277,6 @@ class Attention(nn.Module):
                 use_bias=self.use_bias,
                 dtype=self.dtype,
                 param_dtype=self.param_dtype,
-                save_name="qkv_proj",
                 name="key",
             )(x)
             v = layers.DenseGeneral(
@@ -289,7 +285,6 @@ class Attention(nn.Module):
                 use_bias=self.use_bias,
                 dtype=self.dtype,
                 param_dtype=self.param_dtype,
-                save_name="qkv_proj",
                 name="value",
             )(x)
 

@@ -10,11 +10,10 @@ module class, so the same model code runs under any strategy.
 
 from __future__ import annotations
 
-from typing import Any, Callable, Iterable, Optional, Tuple, Union
+from typing import Any, Callable, Iterable, Tuple, Union
 
 import flax.linen as nn
 import jax
-import jax.ad_checkpoint
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec
 
@@ -51,16 +50,6 @@ class DenseGeneral(nn.Module):
     dtype: Dtype = jnp.bfloat16
     param_dtype: Dtype = jnp.float32
     kernel_init: Initializer = default_kernel_init
-    # Store the kernel with (features..., in...) dims instead of
-    # (in..., features...): same math via swapped contraction dims, but a
-    # different operand orientation for XLA's emitter choice (measured on
-    # the wo matmul, PROFILE.md round 4).  kernel_axes follow the STORED
-    # order.  Checkpoint-format change where enabled.
-    transpose_kernel: bool = False
-    # Tag the output as a named remat saveable
-    # (jax.ad_checkpoint.checkpoint_name) so ops/remat_policy.py policies
-    # can save or host-offload it individually.
-    save_name: Optional[str] = None
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
@@ -69,10 +58,7 @@ class DenseGeneral(nn.Module):
         )
         axis = _normalize_axes(self.axis, x.ndim)
         in_shape = tuple(x.shape[a] for a in axis)
-        if self.transpose_kernel:
-            kernel_shape = features + in_shape
-        else:
-            kernel_shape = in_shape + features
+        kernel_shape = in_shape + features
         assert len(self.kernel_axes) == len(kernel_shape), (
             f"kernel_axes {self.kernel_axes} must name every dim of "
             f"{kernel_shape}"
@@ -85,14 +71,8 @@ class DenseGeneral(nn.Module):
         )
         kernel = kernel.astype(self.dtype)
         x = x.astype(self.dtype)
-        if self.transpose_kernel:
-            contract = tuple(
-                range(len(features), len(features) + len(axis))
-            )
-        else:
-            contract = tuple(range(len(axis)))
         out = jax.lax.dot_general(
-            x, kernel, ((axis, contract), ((), ()))
+            x, kernel, ((axis, tuple(range(len(axis)))), ((), ()))
         )
         if self.use_bias:
             bias = self.param(
@@ -104,8 +84,6 @@ class DenseGeneral(nn.Module):
                 self.param_dtype,
             )
             out = out + bias.astype(self.dtype)
-        if self.save_name:
-            out = jax.ad_checkpoint.checkpoint_name(out, self.save_name)
         return out
 
 

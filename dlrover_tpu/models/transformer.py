@@ -30,9 +30,7 @@ from dlrover_tpu.models import layers
 from dlrover_tpu.models.attention import Attention
 from dlrover_tpu.models.moe import MoEMlp
 from dlrover_tpu.ops import remat_policy as remat_policies
-from dlrover_tpu.ops.layout_pin import pin_layout
 from dlrover_tpu.parallel import rules as lr
-from dlrover_tpu.runtime.mesh import shard_local
 
 
 @dataclasses.dataclass(frozen=True)
@@ -72,31 +70,16 @@ class TransformerConfig:
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
     attention_impl: str = "xla"    # "xla" | "flash"
-    # One [d,H,3*hd] matmul when no GQA.  NOTE: flips the attention param
-    # tree from query/key/value to qkv — a checkpoint format change;
-    # set False to restore pre-round-3 checkpoints.
-    fused_qkv: bool = True
     flash_block_q: int = 1024      # measured fastest on v5e at seq 1024
     flash_block_kv: int = 1024
-    # Layout firewall around the attention block: the flash kernel's fixed
-    # operand layouts otherwise flip the whole layer seq-minor and the MLP
-    # matmuls lower to ~40%-MXU windowed emitters (see ops/layout_pin.py).
-    pin_attn_layouts: bool = False
-    # Store the MLP wo kernel transposed [d_model, d_ff] (emitter
-    # experiment, PROFILE.md r4).  Checkpoint-format change when True.
-    wo_transposed: bool = False
     # One-pass Pallas LayerNorm backward (ops/fused_norm.py): attacks the
     # 6.4 ms/layer LN-bwd sink.  Numerics-tested; on-chip speedup not
     # measured — off until a trace prices it.
     fused_ln: bool = False
-    remat: str = "none"            # a registered ops/remat_policy.py name
-                                   # ("none", "dots", "dots_no_batch",
-                                   # "full", "attn_out", "branch_out",
-                                   # "flash_res", "flash_only" — flash impl
-                                   # only — "offload") or a selective
-                                   # "offload:<name>[,<name>...]" list
+    remat: str = "none"            # a name registered in
+                                   # ops/remat_policy.py (the flash_* ones
+                                   # need attention_impl="flash")
     scan_layers: bool = True
-    scan_unroll: int = 1           # layers per scan iteration (XLA overlap)
     logits_dtype: Any = jnp.float32
     logit_scale: float = 1.0       # µP output multiplier (optimizers/mup.py)
     # Pipeline parallelism (see parallel/pipeline.py): stages must divide
@@ -207,7 +190,6 @@ class Mlp(nn.Module):
     use_bias: bool
     dtype: Any
     param_dtype: Any
-    wo_transposed: bool = False
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
@@ -234,27 +216,12 @@ class Mlp(nn.Module):
             h = nn.gelu(h)
         return layers.DenseGeneral(
             d,
-            kernel_axes=(
-                (lr.EMBED, lr.MLP) if self.wo_transposed
-                else (lr.MLP, lr.EMBED)
-            ),
+            kernel_axes=(lr.MLP, lr.EMBED),
             use_bias=self.use_bias,
             dtype=self.dtype,
             param_dtype=self.param_dtype,
-            transpose_kernel=self.wo_transposed,
-            # Remat saveable: offload-family policies park the wo output
-            # in pinned host memory so the backward skips the d_ff-wide
-            # recompute chain (wi (+wg) + activation + wo).
-            save_name="mlp_wo",
             name="wo",
         )(h)
-
-
-def _pin_local(x: jax.Array) -> jax.Array:
-    """:func:`pin_layout` on each device's block of a residual-stream
-    activation (the identity kernel needs no operand whole)."""
-    spec = nn.logical_to_mesh_axes((lr.BATCH, lr.ACT_SEQ, lr.ACT_EMBED))
-    return shard_local(pin_layout, in_specs=(spec,), out_specs=spec)(x)
 
 
 class Block(nn.Module):
@@ -270,8 +237,6 @@ class Block(nn.Module):
         cfg = self.config
         x, aux = carry
         x = nn.with_logical_constraint(x, (lr.BATCH, lr.ACT_SEQ, lr.ACT_EMBED))
-        if cfg.pin_attn_layouts:
-            x = _pin_local(x)
         y = layers.make_norm(cfg.norm, cfg.dtype, cfg.param_dtype, "ln_attn",
                      fused_backward=cfg.fused_ln)(x)
         y = Attention(
@@ -284,7 +249,6 @@ class Block(nn.Module):
             dtype=cfg.dtype,
             param_dtype=cfg.param_dtype,
             attention_impl=cfg.attention_impl,
-            fused_qkv=cfg.fused_qkv,
             qk_norm=cfg.qk_norm,
             flash_block_q=cfg.flash_block_q,
             flash_block_kv=cfg.flash_block_kv,
@@ -292,8 +256,6 @@ class Block(nn.Module):
             cache_len=cfg.max_seq_len,
             name="attn",
         )(y, positions, segment_ids)
-        if cfg.pin_attn_layouts:
-            y = _pin_local(y)
         # Named checkpoint: under the "attn_out" remat policy the backward
         # skips re-running the whole attention forward (the priciest part of
         # recompute) at b*s*d bf16 per layer of extra HBM.
@@ -323,7 +285,6 @@ class Block(nn.Module):
                 use_bias=cfg.use_bias,
                 dtype=cfg.dtype,
                 param_dtype=cfg.param_dtype,
-                wo_transposed=cfg.wo_transposed,
                 name="mlp",
             )(y)
         # Under the "branch_out" policy the backward rebuilds the residual
@@ -379,8 +340,8 @@ class TransformerLM(nn.Module):
         x = nn.with_logical_constraint(x, (lr.BATCH, lr.ACT_SEQ, lr.ACT_EMBED))
 
         block_cls = Block
-        # Registry lookup (ops/remat_policy.py): named save/offload sets,
-        # builtins, and the pinned-host fallback all resolve here.
+        # Registry lookup (ops/remat_policy.py): named save sets and
+        # builtins resolve here.
         policy = remat_policies.jax_policy(cfg.remat)
         if cfg.remat != "none":
             block_cls = nn.remat(
@@ -407,7 +368,6 @@ class TransformerLM(nn.Module):
                 split_rngs={"params": True},
                 in_axes=nn.broadcast,
                 length=cfg.num_layers,
-                unroll=cfg.scan_unroll,
                 metadata_params={nn.PARTITION_NAME: lr.LAYERS},
             )(cfg, name="blocks")
             (x, aux), _ = stack((x, aux0), positions, segment_ids)

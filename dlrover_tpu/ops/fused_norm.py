@@ -9,8 +9,7 @@ fp32 row statistics recomputed from the saved (mean, rstd) residuals.
 
 Status: numerics-verified (interpret mode, and compiled on the chip by
 ``chip_smoke.py``); the on-chip speedup is not measured — the flag default
-stays off until a trace prices it, per the same measure-first rule that
-retired ops/layout_pin.py.
+stays off until a trace prices it.
 
 Capability ref: the reference leans on apex/Triton fused layernorm
 kernels (``atorch/.../layers.py`` fused-norm paths); this is the Pallas

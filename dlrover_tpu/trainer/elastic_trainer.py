@@ -93,9 +93,6 @@ class TrainerConfig:
     # 0 = place each batch synchronously on the step's critical path.
     prefetch_to_device: int = 0
     # -- restart-fast compile ----------------------------------------------
-    # Reuse in-process compiled programs when (config, mesh-shape) repeats
-    # (train_lib build cache keyed by compile_cache.train_cache_key).
-    reuse_compiled: bool = True
     # AOT lower().compile() the step at construction and report the wall
     # time to the master's goodput ledger (event "compile").
     warmup_compile: bool = False
@@ -327,9 +324,7 @@ class ElasticTrainer:
         # Layer 2: in-process program reuse.  Only config-built pieces are
         # representable in the key — a caller-supplied optimizer or rule
         # set could close over anything, so either one opts out.
-        self._cacheable = (
-            config.reuse_compiled and optimizer is None and rules is None
-        )
+        self._cacheable = optimizer is None and rules is None
         self.train = self._build_train()
         # Model, optimizer and ``build_sharded_train``, up to ``compile``.
         telemetry.event(
@@ -598,8 +593,8 @@ class ElasticTrainer:
         only in grad_accum).  ``aot=True`` additionally lowers+compiles
         each step program now; with it a resize to a warmed world
         performs ZERO traces and ZERO compiles.  Needs the in-process
-        build cache (``reuse_compiled`` with default optimizer/rules) to
-        retain anything.  Returns ``{world: grad_accum}``."""
+        build cache (default optimizer and rules) to retain
+        anything.  Returns ``{world: grad_accum}``."""
         out: Dict[int, int] = {}
         for world in worlds:
             vm = self.vmesh.with_world(int(world))

@@ -52,8 +52,8 @@ def parse_args():
                    help="test hook: slow steps down (chaos windows)")
     p.add_argument("--remat", default="none",
                    help="remat policy (ops/remat_policy.py): none, full, "
-                        "attn_out, branch_out, flash_only, flash_res, "
-                        "offload, offload:<name>[,<name>...]")
+                        "dots, dots_no_batch, attn_out, branch_out, "
+                        "flash_only, flash_res")
     p.add_argument("--auto-tune", action="store_true",
                    help="search mesh/remat strategy before training "
                         "(auto_accelerate equivalent)")

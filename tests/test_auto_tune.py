@@ -5,6 +5,8 @@ Mirrors the reference's auto_accelerate tests
 search must produce a feasible, runnable strategy without hand-picking.
 """
 
+import dataclasses
+
 import jax
 import pytest
 
@@ -196,8 +198,6 @@ def test_interleave_knob_enumerated_and_materialized():
         c.interleave == 2 for c in cands6 if c.parallel.pipe == 2
     )
 
-    import dataclasses
-
     from dlrover_tpu.auto.tune import _estimate
 
     a = next(c for c in piped if c.interleave == 0 and c.microbatches == 2
@@ -209,92 +209,66 @@ def test_interleave_knob_enumerated_and_materialized():
     assert b.est_step_time != a.est_step_time  # the knob changes the model
 
 
-# ---- remat-policy accounting (ops/remat_policy.py tentpole) -------------
-
-_V5E_SPECS = (197e12, 819e9, 16e9, 4.5e10)
+# ---- remat policies in the search (ops/remat_policy.py) -----------------
 
 
-def _offload_vs_flash(monkeypatch, dma_bw):
-    """Estimate offload vs flash_only at bench-like compute-bound shapes
-    with the chip pinned to v5e and a controlled host-DMA bandwidth."""
-    from dlrover_tpu.auto import tune
-    from dlrover_tpu.runtime.mesh import ParallelConfig
-
-    monkeypatch.setattr(tune, "chip_specs", lambda device=None: _V5E_SPECS)
-    monkeypatch.setattr(
-        tune, "host_dma_bandwidth", lambda device=None: dma_bw
-    )
-    cfg = gpt2_config("1.5b", max_seq_len=1024, attention_impl="flash")
-    off = tune.Candidate(ParallelConfig(fsdp=8), "offload")
-    fla = tune.Candidate(ParallelConfig(fsdp=8), "flash_only")
-    for cand in (off, fla):
-        tune._estimate(cand, cfg, 16, 1024, "adamw", 8)
-        assert not cand.rejected, cand.rejected
-    return off, fla
-
-
-def test_offload_beats_flash_only_iff_dma_cheaper(monkeypatch):
-    """Acceptance: the ranking flips exactly with the modeled trade —
-    offload outranks flash_only iff its DMA time is below the recompute
-    time flash_only pays.  Both regimes, same shapes, only the host link
-    speed differs."""
-    # Fast host link (NVLink-class): DMA ~free, offload must win.
-    off, fla = _offload_vs_flash(monkeypatch, dma_bw=1e12)
-    assert off.est_dma_time < fla.est_recompute_time
-    assert off.est_step_time < fla.est_step_time
-    # Slow host link: the DMA serializes past the saved recompute.
-    off, fla = _offload_vs_flash(monkeypatch, dma_bw=3e9)
-    assert off.est_dma_time > fla.est_recompute_time
-    assert off.est_step_time > fla.est_step_time
-    # The iff in one expression: ordering tracks the component trade.
-    for bw in (1e12, 64e9, 15e9, 3e9):
-        off, fla = _offload_vs_flash(monkeypatch, dma_bw=bw)
-        assert (off.est_step_time < fla.est_step_time) == (
-            off.est_dma_time < fla.est_recompute_time
-        )
-
-
-def test_search_kernels_enumerates_offload_policy():
+def test_search_kernels_widens_with_flash_policies_only():
     from dlrover_tpu.auto import tune
 
     cfg = gpt2_config(
         "124m", num_layers=2, d_model=64, num_heads=4, vocab_size=512,
         max_seq_len=512, attention_impl="flash",
     )
+    flash_names = {"flash_only", "flash_res"}
     narrow = tune.enumerate_candidates(cfg, 8, seq_len=512)
-    assert not any(c.remat == "offload" for c in narrow)
+    assert not flash_names & {c.remat for c in narrow}
     wide = tune.enumerate_candidates(cfg, 8, search_kernels=True,
                                      seq_len=512)
-    assert any(c.remat == "offload" for c in wide)
-    # Selective policies are first-class searchable values too.
-    sel = tune.enumerate_candidates(
-        cfg, 8, remat_policies=("full", "offload:attn_out,mlp_wo"),
-        seq_len=512,
-    )
-    assert any(c.remat == "offload:attn_out,mlp_wo" for c in sel)
+    assert {c.remat for c in wide} == {c.remat for c in narrow} | flash_names
+    # Under xla the flash names exist nowhere: nothing is added.
+    xla = dataclasses.replace(cfg, attention_impl="xla")
+    assert {
+        c.remat for c in tune.enumerate_candidates(
+            xla, 8, search_kernels=True, seq_len=512)
+    } == {c.remat for c in tune.enumerate_candidates(xla, 8, seq_len=512)}
     with pytest.raises(ValueError, match="no broadcast encoding"):
         tune.enumerate_candidates(cfg, 8, remat_policies=("offlaod",))
 
 
 def test_remat_broadcast_codes_roundtrip():
     """Multihost agreement broadcasts the remat choice as an int — every
-    enumerable policy (selective offload sets included) must roundtrip."""
+    registered policy must roundtrip, and a code no policy has is the
+    loud version-skew error."""
     from dlrover_tpu.auto import tune
+    from dlrover_tpu.ops import remat_policy as rp
 
-    names = list(tune._REMAT_CODES) + [
-        "offload:qkv_proj", "offload:attn_out,mlp_wo",
-        "offload:qkv_proj,flash_out",
-    ]
-    for name in names:
+    for name in rp.available():
         assert tune._decode_remat(tune._encode_remat(name)) == name
-    # The default offload set folds back to the canonical alias...
-    code = tune._encode_remat("offload:qkv_proj,attn_out,mlp_wo")
-    assert tune._decode_remat(code) == "offload"
-    # ...and order never matters.
-    assert tune._encode_remat("offload:mlp_wo,attn_out") == \
-        tune._encode_remat("offload:attn_out,mlp_wo")
     with pytest.raises(ValueError):
         tune._encode_remat("no_such_policy")
+    for code in (-1, len(rp.available())):
+        with pytest.raises(ValueError, match="version skew"):
+            tune._decode_remat(code)
+
+
+def test_newly_registered_policy_broadcasts_with_no_edit(monkeypatch):
+    """The registry is the one home of the remat decision: a policy added
+    to it has a broadcast code and is enumerable with no edit to
+    auto/tune.py."""
+    from dlrover_tpu.auto import tune
+    from dlrover_tpu.ops import remat_policy as rp
+
+    monkeypatch.setattr(rp, "_REGISTRY", dict(rp._REGISTRY))
+    rp.register(rp.RematPolicy("mlp_only", saved_names=("mlp_out",)))
+    assert "mlp_only" in rp.available()
+    assert tune._decode_remat(tune._encode_remat("mlp_only")) == "mlp_only"
+    cfg = gpt2_config(
+        "124m", num_layers=2, d_model=64, num_heads=4, vocab_size=512,
+        max_seq_len=512,
+    )
+    cands = tune.enumerate_candidates(
+        cfg, 8, remat_policies=("full", "mlp_only"), seq_len=512)
+    assert any(c.remat == "mlp_only" for c in cands)
 
 
 def test_pick_grad_accum_prefers_smallest_fitting():

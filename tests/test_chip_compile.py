@@ -122,12 +122,6 @@ def _embed(name):
     return getattr(kernels, name)
 
 
-def _pin_layout():
-    from dlrover_tpu.ops.layout_pin import pin_layout
-
-    return jax.grad(lambda x: jnp.square(pin_layout(x).astype(F32)).sum())
-
-
 QKV_1P5B = ((16, 1024, 25, 64), BF16)      # GPT-2 1.5B, batch 16
 QKV_LONG = ((4, 2048, 32, 128), BF16)      # head_dim 128, two kv blocks
 LEAF = ((1600, 6400), F32)                 # the 1.5B MLP wi kernel
@@ -157,7 +151,6 @@ CASES = [
      [CACHE, ((4096,), I32)], {"mode": "pallas"}, 1),
     ("embed_scatter", lambda: _embed("_scatter"),
      [CACHE, ((4096,), I32), ((4096, 128), F32)], {"mode": "pallas"}, 1),
-    ("pin_layout", _pin_layout, [((16, 1024, 1600), BF16)], {}, 2),
 ]
 
 

@@ -6,6 +6,7 @@ import numpy as np
 import pytest
 
 from dlrover_tpu.models.gpt2 import gpt2_config
+from dlrover_tpu.models.transformer import TransformerConfig
 from dlrover_tpu.trainer.elastic_trainer import ElasticTrainer, TrainerConfig
 
 
@@ -34,6 +35,29 @@ def _loader(batches, batch, seq, vocab=256, seed=0):
     for _ in range(batches):
         toks = rng.integers(0, vocab, size=(batch, seq + 1), dtype=np.int32)
         yield {"inputs": toks[:, :-1], "targets": toks[:, 1:]}
+
+
+@pytest.mark.parametrize(
+    "config_cls,field,value",
+    [
+        pytest.param(TransformerConfig, "fused_qkv", True, id="fused_qkv"),
+        pytest.param(TransformerConfig, "pin_attn_layouts", False,
+                     id="pin_attn_layouts"),
+        pytest.param(TransformerConfig, "wo_transposed", False,
+                     id="wo_transposed"),
+        pytest.param(TransformerConfig, "scan_unroll", 1, id="scan_unroll"),
+        pytest.param(TrainerConfig, "reuse_compiled", True,
+                     id="reuse_compiled"),
+    ],
+)
+def test_config_naming_a_removed_field_fails_at_construction(
+    config_cls, field, value
+):
+    """A job spec or a benchmark file that still names a field PR 29
+    removed meets the error where ``Config(**spec)`` is built, even with
+    the value that used to be the default — not a silently ignored key."""
+    with pytest.raises(TypeError, match=field):
+        config_cls(**{field: value})
 
 
 def test_fit_trains_and_reports(tmp_path):

@@ -83,10 +83,17 @@ def _layer_forward(dispatch, params, x, mesh, num_experts=8):
     return np.asarray(jax.device_get(out)), float(aux)
 
 
-def test_a2a_layer_bitwise_matches_einsum():
+def test_a2a_layer_matches_einsum_to_float32_rounding():
     """fp32 layer forward: the explicit a2a exchange reproduces the GSPMD
-    einsum dispatch BITWISE — same routing, same expert matmuls, same
-    combine; only the transport changed."""
+    einsum dispatch — same routing, same expert matmuls, same combine;
+    only the transport changed.  Not bit for bit: the a2a body runs the
+    expert matmuls on each device's [E/ep, b_chunk*ep, C, .] block, the
+    einsum path on the whole [E, b, C, .] array, and the backend's dot
+    picks its accumulation order over the 32- and 64-term contractions
+    from the operand shapes (the same ``ebcf,efd->ebcd`` on 2 of the 16
+    batch rows differs from the rows of the whole by as much).  Both lie
+    4e-8 from a float64 evaluation; a token routed or combined wrongly
+    moves an output by its own size."""
     mesh = build_mesh(EP_MESH)
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.normal(size=(16, 8, 32)), jnp.float32)
@@ -98,8 +105,11 @@ def test_a2a_layer_bitwise_matches_einsum():
     params = layer.init(jax.random.PRNGKey(0), x)
     out_e, aux_e = _layer_forward("einsum", params, x, mesh)
     out_a, aux_a = _layer_forward("a2a", params, x, mesh)
-    np.testing.assert_array_equal(out_e, out_a)
-    assert aux_e == aux_a
+    eps = np.finfo(np.float32).eps
+    np.testing.assert_allclose(
+        out_a, out_e, rtol=0, atol=8 * eps * np.abs(out_e).max()
+    )
+    assert aux_a == pytest.approx(aux_e, rel=4 * eps)
 
 
 def test_a2a_int8_layer_close_to_einsum():

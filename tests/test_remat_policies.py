@@ -1,13 +1,13 @@
-"""Remat-policy tests: registry, parity across all policies, offload
-fallback, and recompute elision.
+"""Remat-policy tests: registry, parity across all policies, and
+recompute elision.
 
 The round-4 perf work (PROFILE.md) saves the flash kernel's own outputs
 (o, lse) as named remat targets so the backward replay drops the attention
 forward recompute; the remat-policy subsystem (ops/remat_policy.py)
-generalizes that into named, composable policies with host offload.
+generalizes that into named save-only policies.
 These tests pin down (a) gradient equivalence across every registered
-policy, (b) the save-only fallback on backends without pinned host
-memory, and (c) that the named saveables actually exist in the jaxpr.
+policy, (b) what the registry and the config refuse, and (c) that the
+named saveables actually exist in the jaxpr.
 """
 
 from __future__ import annotations
@@ -37,8 +37,8 @@ def _tiny(remat: str, impl: str = "flash"):
 @functools.lru_cache(maxsize=None)
 def _loss_and_grads(remat: str, impl: str = "flash"):
     # Cached: the parametrized parity sweep reuses the "none" reference
-    # (and the fallback test reuses "offload") instead of re-tracing the
-    # same jit per test — each trace is seconds of CPU compile time.
+    # instead of re-tracing the same jit per test — each trace is seconds
+    # of CPU compile time.
     model, cfg = _tiny(remat, impl)
     tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 64), 0,
                                 cfg.vocab_size)
@@ -66,9 +66,6 @@ def test_flash_policies_match_attn_out_grads(remat):
         )
 
 
-_ALL_POLICIES = sorted(rp.available()) + ["offload:attn_out,mlp_wo"]
-
-
 @pytest.mark.parametrize(
     "remat",
     [
@@ -78,13 +75,13 @@ _ALL_POLICIES = sorted(rp.available()) + ["offload:attn_out,mlp_wo"]
         # here.
         pytest.param(p, marks=pytest.mark.slow)
         if p in ("flash_only", "flash_res") else p
-        for p in _ALL_POLICIES
+        for p in rp.available()
     ],
 )
 def test_every_registered_policy_matches_none_grads(remat):
-    """Loss/grad parity for EVERY policy the registry knows (plus a
-    selective offload list) against the no-remat baseline — the same
-    harness as the pipeline parity tests, rtol 2e-3.
+    """Loss/grad parity for EVERY policy the registry knows against the
+    no-remat baseline — the same harness as the pipeline parity tests,
+    rtol 2e-3.
 
     Non-flash policies run under xla attention (the interpreted flash
     kernel dominates CPU compile time and adds nothing to a remat parity
@@ -104,63 +101,33 @@ def test_every_registered_policy_matches_none_grads(remat):
 
 
 def test_registry_resolves_and_canonicalizes():
-    # Selective lists canonicalize to a stable order...
-    assert rp.resolve("offload:mlp_wo,qkv_proj").name == (
-        "offload:qkv_proj,mlp_wo"
-    )
-    # ...and the default name set folds back to the plain alias.
-    assert rp.resolve("offload:mlp_wo,attn_out,qkv_proj").name == "offload"
-    offload = rp.resolve("offload")
-    assert offload.offload_names == ("qkv_proj", "attn_out", "mlp_wo")
-    assert offload.recompute_fraction == 0.0
-    assert offload.offload_bytes_per_token_layer == 5.0
-    with pytest.raises(ValueError, match="unknown offload target"):
-        rp.resolve("offload:nonsense")
+    assert len(rp.available()) == 8
+    for name in rp.available():
+        assert rp.resolve(name).name == name
     with pytest.raises(ValueError, match="remat must be one of"):
         rp.resolve("bogus_policy")
-    # Flash-name policies are rejected under non-flash impls, selective
-    # offload lists included.
+    # Flash-name policies are rejected under non-flash impls.
     with pytest.raises(ValueError, match="attention_impl='flash'"):
-        rp.validate("offload:flash_out", attention_impl="xla")
+        rp.validate("flash_res", attention_impl="xla")
     with pytest.raises(ValueError, match="attention_impl='flash'"):
         TransformerConfig(remat="flash_only", attention_impl="xla")
 
 
-def test_config_accepts_selective_offload_strings():
-    cfg = gpt2_config("124m", num_layers=2, remat="offload:attn_out,mlp_wo")
-    assert cfg.remat == "offload:attn_out,mlp_wo"
+@pytest.mark.parametrize(
+    "remat", ["offload", "offload:attn_out,mlp_wo", "offlaod"]
+)
+def test_config_refuses_removed_and_unknown_remat_strings(remat):
+    """The host-offload family went in PR 29 (the chip read its road at a
+    fiftieth of what the cost model assumed): both of its spellings meet
+    the registry's ordinary error, as any typo does."""
     with pytest.raises(ValueError, match="remat must be one of"):
-        gpt2_config("124m", remat="offlaod")
-
-
-def test_offload_without_pinned_host_raises(monkeypatch):
-    """On a backend with no pinned_host memory kind an offload policy is
-    refused with a sentence — keeping the names in HBM instead would run
-    another memory plan under the offload policy's name.  Where the kind
-    exists (this CPU backend has it) the policy's gradients are those of
-    the un-rematerialized step."""
-    monkeypatch.setattr(rp, "host_offload_supported", lambda device=None: False)
-    with pytest.raises(ValueError, match="pinned_host"):
-        rp.jax_policy("offload")
-    assert rp.jax_policy("flash_res") is not None  # save-only: unaffected
-    monkeypatch.undo()
-    assert rp.host_offload_supported()
-    l_off, g_off = _loss_and_grads("offload", "xla")
-    l_ref, g_ref = _loss_and_grads("none", "xla")
-    np.testing.assert_allclose(float(l_off), float(l_ref), rtol=2e-3)
-    for a, b in zip(
-        jax.tree_util.tree_leaves(g_off), jax.tree_util.tree_leaves(g_ref)
-    ):
-        np.testing.assert_allclose(
-            np.asarray(a, np.float64), np.asarray(b, np.float64),
-            rtol=2e-3, atol=1e-5,
-        )
+        gpt2_config("124m", num_layers=2, remat=remat)
 
 
 def test_named_saveables_present_in_jaxpr():
-    """qkv_proj / attn_out / mlp_out / mlp_wo must be tagged in the traced
-    program — otherwise offload/selective policies silently save nothing."""
-    model, cfg = _tiny("offload", "xla")
+    """attn_out / mlp_out must be tagged in the traced program — otherwise
+    the policies that name them silently save nothing."""
+    model, cfg = _tiny("branch_out", "xla")
     tokens = jnp.zeros((2, 64), jnp.int32)
     params = model.init(jax.random.PRNGKey(0), tokens)
 
@@ -169,7 +136,7 @@ def test_named_saveables_present_in_jaxpr():
         return jnp.mean(logits.astype(jnp.float32) ** 2) + aux
 
     txt = str(jax.make_jaxpr(jax.grad(loss))(params))
-    for name in ("qkv_proj", "attn_out", "mlp_out", "mlp_wo"):
+    for name in ("attn_out", "mlp_out"):
         assert name in txt, f"checkpoint_name {name!r} missing from jaxpr"
 
 

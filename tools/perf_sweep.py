@@ -24,8 +24,7 @@ REFERENCE_HFU = 0.656
 
 def run(remat: str, batch: int, steps: int, opt_name: str, trace: str | None,
         attention_impl: str = "flash", ce_chunks: int = 0,
-        block_q: int = 1024, block_kv: int = 1024,
-        scan_unroll: int = 1) -> None:
+        block_q: int = 1024, block_kv: int = 1024) -> None:
     from dlrover_tpu.models.gpt2 import gpt2_config
     from dlrover_tpu.models.transformer import TransformerLM
     from dlrover_tpu.parallel import rules as lr
@@ -38,7 +37,6 @@ def run(remat: str, batch: int, steps: int, opt_name: str, trace: str | None,
         "1.5b", max_seq_len=SEQ_LEN, param_dtype=jnp.bfloat16,
         remat=remat, attention_impl=attention_impl,
         flash_block_q=block_q, flash_block_kv=block_kv,
-        scan_unroll=scan_unroll,
     )
     model = TransformerLM(config)
     mesh = build_mesh(ParallelConfig(data=-1, fsdp=1))
@@ -98,5 +96,4 @@ if __name__ == "__main__":
         ce_chunks=int(kv.get("ce", 0)),
         block_q=int(kv.get("bq", 1024)),
         block_kv=int(kv.get("bkv", 1024)),
-        scan_unroll=int(kv.get("unroll", 1)),
     )

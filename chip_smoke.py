@@ -68,6 +68,7 @@ FLASH_BLOCK = 1024
 LAYERNORM = (BATCH, SEQ_LEN, 1600)
 RMSNORM = (4, 2048, 4096)
 GROUPED = (40960, 1600, 3200, (8192, 4096, 0, 10240, 2048, 6144, 5120, 5120))
+ROW_SUM = (17408, 2048, 8, 2048)       # rows, tokens, rows a token, width
 LEAF = (1600, 6400)
 EMBED_CACHE = (65536, 128, 4096)
 
@@ -420,6 +421,7 @@ def phase_kernels(args) -> int:
     from dlrover_tpu.ops import backend
     from dlrover_tpu.ops import flash_attention as fa
     from dlrover_tpu.ops import quantization as qz
+    from dlrover_tpu.ops import row_gather_sum
     from dlrover_tpu.ops.grouped_matmul import (
         grouped_matmul,
         grouped_matmul_ref,
@@ -500,6 +502,25 @@ def phase_kernels(args) -> int:
         out_and_grads(lambda x, w: grouped_matmul(x, w, sizes, 128)),
         out_and_grads(lambda x, w: grouped_matmul_ref(x, w, sizes)),
         (gct, gx, gw), BF16_TOL, 3,
+    )
+
+    # -- a token's k rows fetched and summed (the dropless combine), from
+    # rows the grouped GEMM hands out row-tiled ------------------------------
+    r, t, picks, width = ROW_SUM
+    even = jnp.full((8,), r // 8, jnp.int32)
+    rx, rw = normal((r, 512)), normal((8, 512, width), scale=512 ** -0.5)
+    picked = jax.random.randint(next(keys), (t, picks), 0, r)
+    gates = jax.random.uniform(next(keys), (t, picks), f32)
+    cases.check(
+        f"grouped_matmul row-tiled out + row_gather_sum R={r} T={t} "
+        f"k={picks} D={width} bf16",
+        lambda x, w, idx, g: row_gather_sum.gather_sum(
+            grouped_matmul(x, w, even, 128, True), idx, g
+        ),
+        lambda x, w, idx, g: (
+            grouped_matmul_ref(x, w, even)[idx].astype(f32) * g[..., None]
+        ).sum(1),
+        (rx, rw, picked, gates), BF16_TOL, 2,
     )
 
     # -- block quantization and the quantized Adam updates -------------------

@@ -15,6 +15,7 @@ matmuls at larger expert counts.
 
 from __future__ import annotations
 
+import functools
 from typing import Tuple
 
 import flax.linen as nn
@@ -22,6 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from dlrover_tpu.models import layers
+from dlrover_tpu.ops import row_gather_sum
 from dlrover_tpu.parallel import rules as lr
 
 
@@ -121,8 +123,24 @@ def split_stats(vec):
 #
 # A (token, expert) pair has one row among the expert-grouped rows and each
 # token exactly k of them, so rows <- tokens, tokens <- rows and both their
-# transposes are gathers (plus a sum over k), never a scatter: a TPU gathers
-# rows at memory speed and scatters them one at a time.
+# transposes are gathers (plus a sum over k), never a scatter.  The two
+# directions are not alike on a TPU (v5e, 139,264 rows of 2048 bf16):
+#
+# * rows <- tokens (``x[token of row]``, output in row order from a 64 MiB
+#   source) is fast as XLA writes it: 0.9-1.2 ms for 570 MB.
+# * tokens <- rows (a token's k rows, fetched and summed) is not.  XLA's
+#   gather lands ``[T, k, D]`` first, whose second-minor k = 8 pads to the
+#   16-row bf16 tile, converts it to float32 and reduces it in a second
+#   pass: 2.7 GB of traffic for 67 MB of output, 5.4 ms.  A choice-major
+#   ``[k * T, D]`` gather is no faster (5.4-8.7 ms): a row of a 2-D bf16
+#   array is sixteen strided 256-byte pieces of a (16, 128) tile.
+#
+# So the k-row fetch is fused (``ops/row_gather_sum.py``: one DMA a row out
+# of a ROW-TILED ``[R, D // 128, 128]`` array, summed in float32 in VMEM,
+# 2.3 ms, bound by the DMA issue rate), no ``[T, k, D]`` array exists, and
+# where that kernel fits the d_model-wide rows stay row-tiled from the
+# gather that makes them to the GEMMs, which reshape blocks in VMEM for
+# nothing; turning a plain ``[R, D]`` array row-tiled is a 1.8 ms copy.
 
 
 def _row_budget(pairs: int, block: int, experts: int) -> int:
@@ -172,21 +190,40 @@ def _zero_row(x):
     return jnp.concatenate([x, jnp.zeros((1,) + x.shape[1:], x.dtype)])
 
 
-@jax.custom_vjp
-def _rows_of_tokens(x, plan):
+def _rows_for(x, pair_token, tiled: bool):
+    """``x[pair_token]`` with a zero row for the index one past the end:
+    ``[R, D]``, or row-tiled ``[R, D // 128, 128]`` (a row is then whole
+    native tiles, which is what the fetch-and-sum kernel can DMA)."""
+    src = _zero_row(x)
+    return (row_gather_sum.row_tiled(src) if tiled else src)[pair_token]
+
+
+def _k_rows_summed(rows, dest, gates=None):
+    """``out[t] = sum_j gates[t, j] * rows[dest[t, j]]`` (no ``gates``: the
+    plain sum) as ``[T, D]``, the gates and the sum in float32.  Row-tiled
+    ``rows`` go through the kernel, which fetches and sums in one pass."""
+    if rows.ndim == 3:
+        return row_gather_sum.gather_sum(rows, dest, gates)
+    picked = rows[dest].astype(jnp.float32)                     # [T, k, D]
+    if gates is not None:
+        picked = picked * gates[..., None]
+    return picked.sum(axis=1).astype(rows.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _rows_of_tokens(x, plan, tiled=False):
     """``rows[r] = x[token of row r]``, zero where the row is padding."""
     k = plan["dest"].shape[1]
-    return _zero_row(x)[plan["row_pair"] // k]
+    return _rows_for(x, plan["row_pair"] // k, tiled)
 
 
-def _rows_of_tokens_fwd(x, plan):
-    return _rows_of_tokens(x, plan), plan
+def _rows_of_tokens_fwd(x, plan, tiled):
+    return _rows_of_tokens(x, plan, tiled), plan
 
 
-def _rows_of_tokens_bwd(plan, d_rows):
+def _rows_of_tokens_bwd(tiled, plan, d_rows):
     # each token's k rows, summed: the scatter-add's transpose as a gather
-    d_x = d_rows[plan["dest"]].astype(jnp.float32).sum(axis=1)
-    return d_x.astype(d_rows.dtype), None
+    return _k_rows_summed(d_rows, plan["dest"]), None
 
 
 _rows_of_tokens.defvjp(_rows_of_tokens_fwd, _rows_of_tokens_bwd)
@@ -195,9 +232,8 @@ _rows_of_tokens.defvjp(_rows_of_tokens_fwd, _rows_of_tokens_bwd)
 @jax.custom_vjp
 def _tokens_of_rows(out_rows, gates, plan):
     """``out[t] = sum_j gates[t, j] * out_rows[row of pair (t, j)]``, the
-    gates and the sum in float32."""
-    picked = out_rows[plan["dest"]].astype(jnp.float32)         # [T, k, D]
-    return (picked * gates[..., None]).sum(axis=1).astype(out_rows.dtype)
+    gates and the sum in float32; ``out_rows`` plain or row-tiled."""
+    return _k_rows_summed(out_rows, plan["dest"], gates)
 
 
 def _tokens_of_rows_fwd(out_rows, gates, plan):
@@ -208,15 +244,19 @@ def _tokens_of_rows_bwd(residuals, d_out):
     out_rows, gates, plan = residuals
     k = gates.shape[1]
     pair = plan["row_pair"]
-    row_gate = _zero_row(gates.reshape(-1))[pair]
-    d_rows = (
-        _zero_row(d_out)[pair // k].astype(jnp.float32) * row_gate[:, None]
-    ).astype(out_rows.dtype)
-    d_gates = jnp.einsum(
-        "tkd,td->tk", out_rows[plan["dest"]].astype(jnp.float32),
-        d_out.astype(jnp.float32),
+    within_row = tuple(range(1, out_rows.ndim))
+    row_gate = jnp.expand_dims(_zero_row(gates.reshape(-1))[pair], within_row)
+    # d_out in ROW order (the fast direction) serves both cotangents: a
+    # gate's is the dot of its row with its token's d_out, formed here and
+    # read back as k scalars a token, not from a second fetch of k rows.
+    d_out_rows = _rows_for(d_out, pair // k, out_rows.ndim == 3).astype(
+        jnp.float32
     )
-    return d_rows, d_gates.astype(gates.dtype), None
+    d_rows = (d_out_rows * row_gate).astype(out_rows.dtype)
+    row_dot = jnp.sum(
+        out_rows.astype(jnp.float32) * d_out_rows, axis=within_row
+    )
+    return d_rows, row_dot[plan["dest"]].astype(gates.dtype), None
 
 
 _tokens_of_rows.defvjp(_tokens_of_rows_fwd, _tokens_of_rows_bwd)
@@ -572,9 +612,12 @@ class MoEMlp(nn.Module):
                     gate_idx.reshape(t, k), e, block,
                     _row_budget(t * k, block, e),
                 )
+            # The d_model-wide rows live row-tiled between the gathers and
+            # the GEMMs wherever the fetch-and-sum kernel can read them.
+            tiled = row_gather_sum.kernel_fits(d, k, self.dtype)
             with jax.named_scope("scatter"):
                 rows = _rows_of_tokens(
-                    x.reshape(t, d).astype(self.dtype), plan
+                    x.reshape(t, d).astype(self.dtype), plan, tiled
                 )
             with jax.named_scope("gmm_wi"):
                 h = grouped_matmul(rows, wi, plan["padded"], block)
@@ -585,7 +628,9 @@ class MoEMlp(nn.Module):
             else:
                 h = nn.gelu(h)
             with jax.named_scope("gmm_wo"):
-                out_rows = grouped_matmul(h, wo, plan["padded"], block)
+                out_rows = grouped_matmul(
+                    h, wo, plan["padded"], block, tiled
+                )
             with jax.named_scope("combine"):
                 out = _tokens_of_rows(
                     out_rows, gate_vals.reshape(t, k), plan

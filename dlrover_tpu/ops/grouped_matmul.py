@@ -12,6 +12,12 @@ pattern for ragged work (PrefetchScalarGridSpec).
 
 Group sizes must be multiples of ``block_rows``; the MoE layer guarantees
 this by padding each expert's token group (capacity-style or to the block).
+
+Rows may come and go *row-tiled*, ``[N, K // 128, 128]`` for ``[N, K]``: in
+that view a row is whole native tiles, contiguous in HBM, which is the form
+``ops/row_gather_sum.py`` can DMA single rows from.  The kernels take such
+blocks and reshape them in VMEM, so no relayout pass over HBM is needed on
+either side of a GEMM.
 """
 
 from __future__ import annotations
@@ -24,6 +30,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from dlrover_tpu.ops import backend
+from dlrover_tpu.ops.row_gather_sum import LANES, tile_rows
 
 
 # VMEM one kernel may spend on its expert-weight tiles (double-buffered
@@ -33,18 +40,42 @@ from dlrover_tpu.ops import backend
 _TILE_BYTES = 8 * 2**20
 
 
-def _lane_tile(dim: int, limit: int) -> int:
-    """Widest divisor of ``dim`` that is a multiple of 128 lanes and at
-    most ``limit`` (at least one lane group); ``dim`` itself when it is
-    not lane-aligned — such a dim can only be a block's full extent."""
-    if dim % 128:
+def _lane_tile(dim: int, limit: int, quantum: int = LANES) -> int:
+    """Widest divisor of ``dim`` that is a multiple of ``quantum`` (128
+    lanes) and at most ``limit`` (at least one quantum); ``dim`` itself
+    when it is no multiple of it — such a dim can only be a block's full
+    extent."""
+    if dim % quantum:
         return dim
-    lanes = dim // 128
+    groups = dim // quantum
     fits = [
-        t for t in range(1, lanes + 1)
-        if lanes % t == 0 and t * 128 <= limit
+        t for t in range(1, groups + 1)
+        if groups % t == 0 and t * quantum <= limit
     ]
-    return 128 * max(fits, default=1)
+    return quantum * max(fits, default=1)
+
+
+def _quantum(tiled: bool, dtype) -> int:
+    """What a block's width is a multiple of: lanes, and for a row-tiled
+    array whole native tiles (its lane groups are the second-minor dim)."""
+    return LANES * tile_rows(dtype) if tiled else LANES
+
+
+def _row_block(tiled, block_rows, width, index):
+    """BlockSpec of ``block_rows`` rows by ``width`` columns at block
+    ``index(*grid) -> (i, j)``, of a plain or a row-tiled array."""
+    if not tiled:
+        return pl.BlockSpec((block_rows, width), index, memory_space=pltpu.VMEM)
+    return pl.BlockSpec(
+        (block_rows, width // LANES, LANES),
+        lambda *grid: index(*grid) + (0,), memory_space=pltpu.VMEM,
+    )
+
+
+def _plain(ref):
+    """A row block as ``[rows, width]``, whichever form it is held in."""
+    block = ref[...]
+    return block.reshape(block.shape[0], -1) if block.ndim == 3 else block
 
 
 def _gmm_kernel(expert_of_block, x_ref, w_ref, out_ref, acc_ref):
@@ -55,12 +86,12 @@ def _gmm_kernel(expert_of_block, x_ref, w_ref, out_ref, acc_ref):
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
     acc_ref[:] += jax.lax.dot(
-        x_ref[:], w_ref[0], preferred_element_type=jnp.float32
+        _plain(x_ref), w_ref[0], preferred_element_type=jnp.float32
     )
 
     @pl.when(kk == pl.num_programs(2) - 1)
     def _():
-        out_ref[:] = acc_ref[:].astype(out_ref.dtype)
+        out_ref[...] = acc_ref[:].astype(out_ref.dtype).reshape(out_ref.shape)
 
 
 def _expert_of_block(group_sizes, num_blocks, block_rows):
@@ -72,20 +103,23 @@ def _expert_of_block(group_sizes, num_blocks, block_rows):
     return jnp.minimum(eob, group_sizes.shape[0] - 1).astype(jnp.int32)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def grouped_matmul(
-    x: jax.Array,           # [N, K] rows sorted by expert
+    x: jax.Array,           # [N, K] rows sorted by expert, or row-tiled
     w: jax.Array,           # [E, K, M]
     group_sizes: jax.Array, # [E] int32, sum == N, multiples of block_rows
     block_rows: int = 128,
+    out_tiled: bool = False,
 ) -> jax.Array:
-    """Returns [N, M] where out[r] = x[r] @ w[expert_of_row(r)]."""
-    return _gmm_fwd_impl(x, w, group_sizes, block_rows)
+    """Returns [N, M] where out[r] = x[r] @ w[expert_of_row(r)]; with
+    ``out_tiled`` the same rows as ``[N, M // 128, 128]``.  ``dx`` comes
+    back in the form ``x`` came in."""
+    return _gmm_fwd_impl(x, w, group_sizes, block_rows, out_tiled)
 
 
-def _gmm_fwd_impl(x, w, group_sizes, block_rows):
-    n, k = x.shape
-    e, _, m = w.shape
+def _gmm_fwd_impl(x, w, group_sizes, block_rows, out_tiled):
+    n = x.shape[0]
+    e, k, m = w.shape
     assert n % block_rows == 0, f"N={n} not a multiple of {block_rows}"
     num_blocks = n // block_rows
     expert_of_block = _expert_of_block(group_sizes, num_blocks, block_rows)
@@ -94,31 +128,31 @@ def _gmm_fwd_impl(x, w, group_sizes, block_rows):
     # fits (tk == k) an expert's [K, tm] strip stays resident across its
     # consecutive row blocks; K is split only when M cannot be.
     tile_elems = _TILE_BYTES // (2 * jnp.dtype(w.dtype).itemsize)
-    tm = _lane_tile(m, tile_elems // k)
-    tk = _lane_tile(k, tile_elems // tm)
+    tm = _lane_tile(m, tile_elems // k, _quantum(out_tiled, x.dtype))
+    tk = _lane_tile(k, tile_elems // tm, _quantum(x.ndim == 3, x.dtype))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(m // tm, num_blocks, k // tk),
         in_specs=[
-            pl.BlockSpec(
-                (block_rows, tk), lambda j, i, kk, eob: (i, kk),
-                memory_space=pltpu.VMEM,
+            _row_block(
+                x.ndim == 3, block_rows, tk, lambda j, i, kk, eob: (i, kk)
             ),
             pl.BlockSpec(
                 (1, tk, tm), lambda j, i, kk, eob: (eob[i], kk, j),
                 memory_space=pltpu.VMEM,
             ),
         ],
-        out_specs=pl.BlockSpec(
-            (block_rows, tm), lambda j, i, kk, eob: (i, j),
-            memory_space=pltpu.VMEM,
+        out_specs=_row_block(
+            out_tiled, block_rows, tm, lambda j, i, kk, eob: (i, j)
         ),
         scratch_shapes=[pltpu.VMEM((block_rows, tm), jnp.float32)],
     )
     return pl.pallas_call(
         _gmm_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n, m), x.dtype),
+        out_shape=jax.ShapeDtypeStruct(
+            (n, m // LANES, LANES) if out_tiled else (n, m), x.dtype
+        ),
         interpret=backend.interpret(),
     )(expert_of_block, x, w)
 
@@ -142,7 +176,7 @@ def _gmm_dw_kernel(eob_ref, x_ref, dy_ref, dw_ref, acc_ref):
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
     acc_ref[:] += jax.lax.dot_general(
-        x_ref[:], dy_ref[:], (((0,), (0,)), ((), ())),
+        _plain(x_ref), _plain(dy_ref), (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
 
@@ -151,19 +185,19 @@ def _gmm_dw_kernel(eob_ref, x_ref, dy_ref, dw_ref, acc_ref):
         dw_ref[0] = acc_ref[:].astype(dw_ref.dtype)
 
 
-def _gmm_fwd(x, w, group_sizes, block_rows):
-    out = _gmm_fwd_impl(x, w, group_sizes, block_rows)
+def _gmm_fwd(x, w, group_sizes, block_rows, out_tiled):
+    out = _gmm_fwd_impl(x, w, group_sizes, block_rows, out_tiled)
     return out, (x, w, group_sizes)
 
 
-def _gmm_bwd(block_rows, residuals, dy):
+def _gmm_bwd(block_rows, out_tiled, residuals, dy):
     x, w, group_sizes = residuals
-    n, k = x.shape
-    e, _, m = w.shape
+    n = x.shape[0]
+    e, k, m = w.shape
     num_blocks = n // block_rows
-    # dx: grouped matmul against w^T.
+    # dx: grouped matmul against w^T, in the form x came in.
     dx = _gmm_fwd_impl(
-        dy, jnp.swapaxes(w, 1, 2), group_sizes, block_rows
+        dy, jnp.swapaxes(w, 1, 2), group_sizes, block_rows, x.ndim == 3
     ).astype(x.dtype)
     # dw: per-expert accumulation over that expert's row blocks.
     eob = _expert_of_block(group_sizes, num_blocks, block_rows)
@@ -171,20 +205,16 @@ def _gmm_bwd(block_rows, residuals, dy):
     # the double-buffered output block hold one [tk, tm] tile across an
     # expert's consecutive row blocks.
     tile_elems = _TILE_BYTES // (4 + 2 * jnp.dtype(w.dtype).itemsize)
-    tm = _lane_tile(m, tile_elems // k)
-    tk = _lane_tile(k, tile_elems // tm)
+    tm = _lane_tile(m, tile_elems // k, _quantum(out_tiled, x.dtype))
+    tk = _lane_tile(k, tile_elems // tm, _quantum(x.ndim == 3, x.dtype))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(k // tk, m // tm, num_blocks),
         in_specs=[
-            pl.BlockSpec(
-                (block_rows, tk), lambda a, j, i, eob: (i, a),
-                memory_space=pltpu.VMEM,
+            _row_block(
+                x.ndim == 3, block_rows, tk, lambda a, j, i, eob: (i, a)
             ),
-            pl.BlockSpec(
-                (block_rows, tm), lambda a, j, i, eob: (i, j),
-                memory_space=pltpu.VMEM,
-            ),
+            _row_block(out_tiled, block_rows, tm, lambda a, j, i, eob: (i, j)),
         ],
         out_specs=pl.BlockSpec(
             (1, tk, tm), lambda a, j, i, eob: (eob[i], a, j),

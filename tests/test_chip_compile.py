@@ -89,14 +89,20 @@ def _norm_grad(name, with_bias):
     return jax.grad(loss, argnums=tuple(range(2 + with_bias)))
 
 
-def _grouped_matmul():
+def _grouped_matmul(out_tiled=False):
     from dlrover_tpu.ops.grouped_matmul import grouped_matmul
 
     def loss(x, w, sizes):
-        return grouped_matmul(x, w, sizes, 128).astype(F32).sum()
+        return grouped_matmul(x, w, sizes, 128, out_tiled).astype(F32).sum()
 
     # value_and_grad keeps the forward kernel live beside dx and dw.
     return jax.value_and_grad(loss, argnums=(0, 1))
+
+
+def _row_gather_sum():
+    from dlrover_tpu.ops.row_gather_sum import gather_sum
+
+    return gather_sum
 
 
 def _quant_roundtrip():
@@ -144,6 +150,26 @@ CASES = [
      [((139264, 2048), BF16), ((64, 2048, 1024), BF16), ((64,), I32)], {}, 3),
     ("grouped_matmul_olmoe_wo", _grouped_matmul,
      [((139264, 1024), BF16), ((64, 1024, 2048), BF16), ((64,), I32)], {}, 3),
+    # the same with the d_model-wide rows row-tiled on their way in and out
+    ("grouped_matmul_olmoe_wi_rows_tiled", _grouped_matmul,
+     [((139264, 16, 128), BF16), ((64, 2048, 1024), BF16), ((64,), I32)],
+     {}, 3),
+    ("grouped_matmul_olmoe_wo_out_tiled", lambda: _grouped_matmul(True),
+     [((139264, 1024), BF16), ((64, 1024, 2048), BF16), ((64,), I32)], {}, 3),
+    # Mixtral under `grouped` (6 x 4096 tokens, 2 a token): K and M are
+    # tiled, and a row-tiled side is tiled in whole native tiles
+    ("grouped_matmul_mixtral_wi_rows_tiled", _grouped_matmul,
+     [((50176, 32, 128), BF16), ((8, 4096, 14336), BF16), ((8,), I32)],
+     {}, 3),
+    ("grouped_matmul_mixtral_wo_out_tiled", lambda: _grouped_matmul(True),
+     [((50176, 14336), BF16), ((8, 14336, 4096), BF16), ((8,), I32)], {}, 3),
+    # a token's 8 of the 139264 rows fetched and summed, OLMoE's combine
+    ("row_gather_sum_olmoe_weighted", _row_gather_sum,
+     [((139264, 16, 128), BF16), ((16384, 8), I32), ((16384, 8), F32)], {}, 1),
+    ("row_gather_sum_olmoe_plain", _row_gather_sum,
+     [((139264, 16, 128), BF16), ((16384, 8), I32)], {}, 1),
+    ("row_gather_sum_from_plain_rows", _row_gather_sum,
+     [((139264, 2048), BF16), ((16384, 8), I32)], {}, 1),
     ("quantize_dequantize", _quant_roundtrip, [LEAF], {}, 2),
     ("q8_adam", lambda: _adam_update("q8_adam"), [LEAF, LEAF], {}, 1),
     ("q4_adam", lambda: _adam_update("q4_adam"), [LEAF, LEAF], {}, 1),

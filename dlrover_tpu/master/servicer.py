@@ -395,6 +395,17 @@ class MasterServicer:
                         "unparseable moe event from %d: %r",
                         node, attrs,
                     )
+            elif self.speed_monitor is not None and name == "linear_attn":
+                # Linear-attention health snapshot (mean decay, mean
+                # write strength, the recurrent state's largest entry):
+                # feeds the ledger behind the dlrover_linear_attn_* gauges.
+                try:
+                    self.speed_monitor.record_linear_attn(node, **attrs)
+                except (TypeError, ValueError):
+                    logger.warning(
+                        "unparseable linear_attn event from %d: %r",
+                        node, attrs,
+                    )
             elif self.speed_monitor is not None and name == "embed":
                 # Embedding-plane stats snapshot: feeds the embed ledger
                 # behind the dlrover_embed_* gauges (rows owned, cache

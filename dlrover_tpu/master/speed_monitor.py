@@ -99,6 +99,10 @@ class SpeedMonitor:
         # load) — the ``dlrover_moe_*`` gauges read the aggregate.
         self._moe_stats: Dict[int, Dict[str, Any]] = {}
         self._moe_events = 0
+        # "linear_attn" telemetry events: each reporter's newest snapshot
+        # of its gated-delta-rule layers — the ``dlrover_linear_attn_*``
+        # gauges read the aggregate.
+        self._linear_attn_stats: Dict[int, Dict[str, float]] = {}
 
     def collect_global_step(
         self, step: int, timestamp: Optional[float] = None, tokens: int = 0
@@ -315,6 +319,58 @@ class SpeedMonitor:
                 "pad_share": float(pad_share),
                 "max_expert_load": float(max_expert_load),
             }
+
+    def record_linear_attn(
+        self,
+        node_id: int = 0,
+        *,
+        step: float = 0.0,
+        layers: float = 0.0,
+        chunk: float = 0.0,
+        mean_alpha: float = 0.0,
+        mean_beta: float = 0.0,
+        state_absmax: float = 0.0,
+        **_ignored,
+    ):
+        """A trainer's linear-attention snapshot (its ``linear_attn``
+        telemetry event).  Newest-wins per reporting node; unknown attrs
+        are ignored so the trainer can grow the event."""
+        with self._lock:
+            self._linear_attn_stats[node_id] = {
+                "step": float(step),
+                "layers": float(layers),
+                "chunk": float(chunk),
+                "mean_alpha": float(mean_alpha),
+                "mean_beta": float(mean_beta),
+                "state_absmax": float(state_absmax),
+            }
+
+    def linear_attn_ledger(self) -> Dict[str, float]:
+        """Aggregate over reporters: the means average (each books its own
+        replica's batch), the state's largest entry and the geometry take
+        the max (a non-finite entry on any replica must show)."""
+        with self._lock:
+            stats = list(self._linear_attn_stats.values())
+        n = len(stats)
+
+        def mean(key):
+            return sum(s[key] for s in stats) / n if n else 0.0
+
+        def most(key):
+            values = [s[key] for s in stats]
+            if any(v != v for v in values):
+                return float("nan")
+            return max(values, default=0.0)
+
+        return {
+            "reporters": float(n),
+            "step": most("step"),
+            "layers": most("layers"),
+            "chunk": most("chunk"),
+            "mean_alpha": mean("mean_alpha"),
+            "mean_beta": mean("mean_beta"),
+            "state_absmax": most("state_absmax"),
+        }
 
     def moe_ledger(self) -> Dict[str, Any]:
         """Router-health aggregate: entropy/drop/padding average over reporters

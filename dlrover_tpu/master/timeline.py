@@ -391,6 +391,23 @@ class JobTimeline:
                           labels=f'{{expert="{i}"}}')
             else:
                 gauge("dlrover_moe_expert_load", 0)
+            linear = speed_monitor.linear_attn_ledger()
+            gauge("dlrover_linear_attn_layers", linear["layers"],
+                  "gated-delta-rule layers of the reported model")
+            gauge("dlrover_linear_attn_chunk", linear["chunk"],
+                  "tokens a chunk of the chunked delta rule holds")
+            gauge("dlrover_linear_attn_mean_alpha", linear["mean_alpha"],
+                  "mean state decay exp(g) over tokens, heads and layers "
+                  "(mean of reporters; 1 = nothing forgotten)")
+            gauge("dlrover_linear_attn_mean_beta", linear["mean_beta"],
+                  "mean write strength beta (0..1, or 0..2 where negative "
+                  "eigenvalues are allowed)")
+            gauge("dlrover_linear_attn_state_absmax",
+                  linear["state_absmax"],
+                  "largest |S| entry of a recurrent state at any chunk "
+                  "boundary (max of reporters; NaN/Inf = diverged)")
+            gauge("dlrover_linear_attn_reporters", linear["reporters"],
+                  "trainers that have reported linear-attention snapshots")
             sdc = speed_monitor.sdc_ledger()
             gauge("dlrover_sdc_checks_total", sdc["checks"],
                   "cross-replica state-digest votes performed")

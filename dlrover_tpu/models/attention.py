@@ -223,6 +223,7 @@ class Attention(nn.Module):
     param_dtype: layers.Dtype = jnp.float32
     attention_impl: str = "xla"
     qk_norm: bool = False
+    norm_eps: float = 1e-5
     flash_block_q: int = 512
     flash_block_kv: int = 512
     # Autoregressive decoding: keep K/V in a "cache" collection of
@@ -293,8 +294,12 @@ class Attention(nn.Module):
             # over all heads of a projection jointly, so the fused kernel's
             # per-head [q | k | v] slices are normed over their two trailing
             # axes and the one wide QKV matmul is kept.
-            q = QKNorm(param_dtype=self.param_dtype, name="q_norm")(q)
-            k = QKNorm(param_dtype=self.param_dtype, name="k_norm")(k)
+            q = QKNorm(
+                self.norm_eps, param_dtype=self.param_dtype, name="q_norm"
+            )(q)
+            k = QKNorm(
+                self.norm_eps, param_dtype=self.param_dtype, name="k_norm"
+            )(k)
 
         if self.use_rope:
             q, k = layers.rotary_embedding(q, k, positions, self.rope_theta)

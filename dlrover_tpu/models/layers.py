@@ -234,13 +234,14 @@ class LayerNorm(nn.Module):
 
 
 def make_norm(kind: str, dtype: Dtype, param_dtype: Dtype, name: str,
-              fused_backward: bool = False) -> nn.Module:
+              fused_backward: bool = False,
+              epsilon: float = 1e-5) -> nn.Module:
     if kind == "rmsnorm":
         return RMSNorm(dtype=dtype, param_dtype=param_dtype, name=name,
-                       fused_backward=fused_backward)
+                       fused_backward=fused_backward, epsilon=epsilon)
     if kind == "layernorm":
         return LayerNorm(dtype=dtype, param_dtype=param_dtype, name=name,
-                         fused_backward=fused_backward)
+                         fused_backward=fused_backward, epsilon=epsilon)
     raise ValueError(f"unknown norm kind {kind!r}")
 
 

@@ -1,0 +1,249 @@
+"""The gated-delta-rule mixer: a linear-attention layer with a recurrent
+state in place of a KV cache (Gated DeltaNet, Yang et al.,
+arXiv:2412.06464), as Olmo-Hybrid's ``linear_attention`` layers run it.
+
+With ``n`` the mixer's input, ``H`` heads, key heads of ``dk`` and value
+heads of ``dv``::
+
+    q, k, v, z = W_q n, W_k n, W_v n, W_g n              (no bias)
+    q, k, v <- SiLU(causal depthwise conv, ``taps`` taps, own taps a channel)
+    per head:  q <- q / ||q|| * dk^-1/2,   k <- k / ||k||
+    beta = sigmoid(W_b n)     (x 2 with ``allow_neg_eigval``: 0 < beta < 2)
+    g    = -exp(A_log) * softplus(W_a n + dt_bias)       (float32, < 0)
+    o    = gated delta rule over (q, k, v, g, beta)       (ops/gated_delta_rule)
+    y    = RMSNorm_dv(o; [dv] scale shared by the heads) * SiLU(z)
+    out  = W_o y
+
+The four wide projections run as ONE ``[d, H, 2 dk + 2 dv]`` matmul
+(param ``qkvg``; a head's row is ``[q | k | v | z]``), as the attention
+layer's ``qkv`` does and for the same reason; the split is on the per-head
+axis, which no strategy shards, so the heads shard as attention's do.  The
+convolution is depthwise, so it runs on the ``[q | k | v]`` slice as it
+lies.  ``W_a`` and ``W_b`` are one ``[d, H, 2]`` matmul in float32
+accumulation (param ``ab``).
+
+Each forward ``sow``s ``linear_attn_stats`` = ``[mean alpha, mean beta,
+largest |S| entry at a chunk boundary]`` (:func:`split_stats`) into
+``"intermediates"``: a no-op unless the caller applies with that
+collection mutable, as the train step does.
+
+The block names this module ``linear_attn``, so its ``named_scope``s reach
+the compiled text as ``linear_attn/qkv``, ``/conv``, ``/gates``,
+``/delta_rule``, ``/out_norm`` and (the output projection's own name)
+``/wo``, forward and transposed ops alike, where the benchmark reads them.
+"""
+
+from __future__ import annotations
+
+import math
+from typing import Any
+
+import flax.linen as nn
+import jax
+import jax.ad_checkpoint
+import jax.numpy as jnp
+
+from dlrover_tpu.models import layers
+from dlrover_tpu.ops.gated_delta_rule import gated_delta_rule
+from dlrover_tpu.parallel import rules as lr
+
+F32 = jnp.float32
+STATS_NAME = "linear_attn_stats"
+L2_EPS = 1e-6
+
+
+def split_stats(vec):
+    """``(mean_alpha, mean_beta, state_absmax)`` of one stats vector."""
+    return vec[0], vec[1], vec[2]
+
+
+def fold_stats(stacked: jax.Array) -> jax.Array:
+    """One vector out of the layers' (and microbatches') ``[n, 3]``: the
+    means of the means, the largest of the largest."""
+    return jnp.concatenate(
+        [stacked[:, :2].mean(axis=0), stacked[:, 2:].max(axis=0)]
+    )
+
+
+def _a_log_init(key, shape, dtype):
+    # Gated DeltaNet: A ~ U(0, 16), kept as its logarithm
+    return jnp.log(
+        jax.random.uniform(key, shape, F32, 1e-4, 16.0)
+    ).astype(dtype)
+
+
+def _dt_bias_init(key, shape, dtype):
+    # Gated DeltaNet (and Mamba-2): dt log-uniform in [1e-3, 1e-1], kept
+    # as the inverse of softplus so that softplus(dt_bias) = dt
+    dt = jnp.exp(
+        jax.random.uniform(key, shape, F32)
+        * (math.log(0.1) - math.log(0.001)) + math.log(0.001)
+    )
+    dt = jnp.maximum(dt, 1e-4)
+    return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
+
+
+def _conv_init(key, shape, dtype):
+    # torch's Conv1d default for a depthwise filter: U(+-1/sqrt(taps))
+    bound = 1.0 / math.sqrt(shape[0])
+    return jax.random.uniform(key, shape, F32, -bound, bound).astype(dtype)
+
+
+def _shifted_sum(x: jax.Array, taps: jax.Array, before: bool) -> jax.Array:
+    """``sum_j taps[j] * x[t - (K - 1) + j]`` (``before``: a tap reads
+    tokens behind t, zeros before the start) or ``sum_j taps[j] *
+    x[t + (K - 1) - j]`` (tokens ahead of t, zeros past the end)."""
+    k, s = taps.shape[0], x.shape[1]
+    rest = [(0, 0)] * (x.ndim - 2)
+    pad = jnp.pad(x, [(0, 0), (k - 1, 0) if before else (0, k - 1)] + rest)
+    return sum(
+        pad[:, (j if before else k - 1 - j):][:, :s] * taps[j]
+        for j in range(k)
+    )
+
+
+@jax.custom_vjp
+def causal_depthwise_conv(x: jax.Array, taps: jax.Array) -> jax.Array:
+    """``y[t] = sum_j taps[j] * x[t - (K - 1) + j]`` per channel, zeros
+    before the sequence starts.  ``x`` [B, S, ...], ``taps`` [K, ...]:
+    K shifted multiply-adds, no convolution primitive.  The VJP is
+    written out (the same K shifts the other way, and K dot products for
+    the taps): autodiff's transposed slices each landed as a padded copy
+    of the cotangent before they were summed."""
+    return _shifted_sum(x, taps, before=True)
+
+
+def _conv_fwd(x, taps):
+    return _shifted_sum(x, taps, before=True), (x, taps)
+
+
+def _conv_bwd(res, dy):
+    x, taps = res
+    k, s = taps.shape[0], x.shape[1]
+    dx = _shifted_sum(dy, taps, before=False)
+    pad = jnp.pad(x, [(0, 0), (k - 1, 0)] + [(0, 0)] * (x.ndim - 2))
+    d_taps = jnp.stack([
+        jnp.sum(
+            pad[:, j: j + s].astype(F32) * dy.astype(F32), axis=(0, 1)
+        )
+        for j in range(k)
+    ])
+    return dx.astype(x.dtype), d_taps.astype(taps.dtype)
+
+
+causal_depthwise_conv.defvjp(_conv_fwd, _conv_bwd)
+
+
+def l2_normalise(x: jax.Array) -> jax.Array:
+    x32 = x.astype(F32)
+    return x32 * jax.lax.rsqrt(
+        jnp.sum(x32 * x32, axis=-1, keepdims=True) + L2_EPS
+    )
+
+
+class GatedDeltaNet(nn.Module):
+    num_heads: int
+    key_dim: int
+    value_dim: int
+    conv_taps: int = 4
+    allow_neg_eigval: bool = False
+    norm_eps: float = 1e-5
+    chunk: int = 128
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        h, dk, dv = self.num_heads, self.key_dim, self.value_dim
+        features = x.shape[-1]
+        proj_init = nn.initializers.normal(stddev=0.02)
+        with jax.named_scope("qkv"):
+            qkvg = layers.DenseGeneral(
+                (h, 2 * dk + 2 * dv),
+                kernel_axes=(lr.EMBED, lr.HEADS, lr.KV),
+                dtype=self.dtype, param_dtype=self.param_dtype,
+                kernel_init=proj_init, name="qkvg",
+            )(x)
+        z = qkvg[..., 2 * dk + dv:]
+        with jax.named_scope("conv"):
+            taps = self.param(
+                "conv_kernel",
+                nn.with_logical_partitioning(
+                    _conv_init, (None, lr.HEADS, lr.KV)
+                ),
+                (self.conv_taps, h, 2 * dk + dv), self.param_dtype,
+            )
+            qkv = nn.silu(causal_depthwise_conv(
+                qkvg[..., : 2 * dk + dv], taps.astype(self.dtype)
+            ))
+            q = (l2_normalise(qkv[..., :dk]) * dk ** -0.5).astype(self.dtype)
+            k = l2_normalise(qkv[..., dk: 2 * dk]).astype(self.dtype)
+            v = qkv[..., 2 * dk:]
+        with jax.named_scope("gates"):
+            ab_kernel = self.param(
+                "ab_kernel",
+                nn.with_logical_partitioning(
+                    proj_init, (lr.EMBED, lr.HEADS, None)
+                ),
+                (features, h, 2), self.param_dtype,
+            )
+            # 2 H numbers that set every decay: float32 whatever the
+            # parameters' dtype, as Gated DeltaNet keeps them
+            a_log = self.param(
+                "A_log",
+                nn.with_logical_partitioning(_a_log_init, (lr.HEADS,)),
+                (h,), F32,
+            )
+            dt_bias = self.param(
+                "dt_bias",
+                nn.with_logical_partitioning(_dt_bias_init, (lr.HEADS,)),
+                (h,), F32,
+            )
+            ab = jnp.einsum(
+                "bsd,dhc->bshc", x.astype(self.dtype),
+                ab_kernel.astype(self.dtype), preferred_element_type=F32,
+            )
+            g = -jnp.exp(a_log.astype(F32)) * jax.nn.softplus(
+                ab[..., 0] + dt_bias.astype(F32)
+            )
+            beta = jax.nn.sigmoid(ab[..., 1])
+            if self.allow_neg_eigval:
+                beta = 2.0 * beta
+        spec = (lr.BATCH, None, lr.ACT_HEADS, lr.KV)
+        q, k, v = (nn.with_logical_constraint(a, spec) for a in (q, k, v))
+        with jax.named_scope("delta_rule"):
+            o, state_absmax = gated_delta_rule(
+                q, k, v, g, beta, chunk=self.chunk
+            )
+        # The one activation of the mixer the layer's remat keeps
+        # (ops/remat_policy.py): with it the backward rebuilds the chunk
+        # tensors once, not the outputs as well.
+        o = jax.ad_checkpoint.checkpoint_name(o, "delta_out")
+        o = nn.with_logical_constraint(o, spec)
+        self.sow(
+            "intermediates", STATS_NAME,
+            jax.lax.stop_gradient(jnp.stack([
+                jnp.exp(g).mean(), beta.mean(), state_absmax,
+            ])),
+        )
+        with jax.named_scope("out_norm"):
+            scale = self.param(
+                "out_norm_scale",
+                nn.with_logical_partitioning(
+                    nn.initializers.ones_init(), (lr.NORM,)
+                ),
+                (dv,), self.param_dtype,
+            )
+            o32 = o.astype(F32)
+            y = o32 * jax.lax.rsqrt(
+                jnp.mean(o32 * o32, axis=-1, keepdims=True) + self.norm_eps
+            )
+            y = (y * scale.astype(F32) * nn.silu(z.astype(F32))).astype(
+                self.dtype
+            )
+        return layers.DenseGeneral(
+            features, axis=(-2, -1),
+            kernel_axes=(lr.HEADS, lr.KV, lr.EMBED),
+            dtype=self.dtype, param_dtype=self.param_dtype,
+            kernel_init=proj_init, name="wo",
+        )(y)

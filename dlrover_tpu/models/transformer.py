@@ -28,9 +28,15 @@ import jax.numpy as jnp
 
 from dlrover_tpu.models import layers
 from dlrover_tpu.models.attention import Attention
+from dlrover_tpu.models.linear_attention import GatedDeltaNet
 from dlrover_tpu.models.moe import MoEMlp
 from dlrover_tpu.ops import remat_policy as remat_policies
 from dlrover_tpu.parallel import rules as lr
+
+
+FULL_ATTENTION = "full_attention"
+LINEAR_ATTENTION = "linear_attention"
+LAYER_KINDS = (FULL_ATTENTION, LINEAR_ATTENTION)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -66,6 +72,26 @@ class TransformerConfig:
     # RMSNorm over the whole q and k projections (all heads jointly, own
     # scale each) before the head split and RoPE (OLMoE, OLMo-2).
     qk_norm: bool = False
+    # One period of layer kinds ("full_attention" | "linear_attention"),
+    # repeated num_layers / len(layer_pattern) times; empty = every layer
+    # full attention.  The trunk scans over PERIODS: a period applies its
+    # blocks in order, each under its own name (``linear_0`` .. ``full_3``)
+    # with its own parameters stacked over the periods.
+    layer_pattern: Tuple[str, ...] = ()
+    # The gated-delta-rule mixer of the "linear_attention" layers
+    # (models/linear_attention.py): heads (0 -> num_heads), key and value
+    # head sizes, taps of the short convolution, and whether beta is
+    # doubled so that the state's transition may have negative eigenvalues.
+    linear_num_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel: int = 4
+    linear_allow_neg_eigval: bool = False
+    # "pre": x + f(Norm(x)) (GPT-2, Llama, Mixtral, OLMoE); "post":
+    # x + Norm(f(x)), each branch's OUTPUT normalised before the residual
+    # add (OLMo 2 and later).
+    norm_placement: str = "pre"
+    norm_eps: float = 1e-5         # every RMSNorm / LayerNorm / QK-norm
     # numerics / execution
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
@@ -112,7 +138,27 @@ class TransformerConfig:
     def resolved_head_dim(self) -> int:
         return self.head_dim or self.d_model // self.num_heads
 
+    @property
+    def num_scan_units(self) -> int:
+        """What the trunk scans (and the pipeline stacks) over: periods of
+        the layer pattern, or single layers without one."""
+        return self.num_layers // max(1, len(self.layer_pattern))
+
+    @property
+    def num_linear_layers(self) -> int:
+        return self.num_scan_units * self.layer_pattern.count(
+            LINEAR_ATTENTION
+        )
+
+    def layer_kind(self, layer: int) -> str:
+        if not self.layer_pattern:
+            return FULL_ATTENTION
+        return self.layer_pattern[layer % len(self.layer_pattern)]
+
     def __post_init__(self):
+        # a JSON list arrives as a list; the config must stay hashable
+        object.__setattr__(self, "layer_pattern", tuple(self.layer_pattern))
+        self._check_pattern()
         if self.attention_impl not in ("xla", "flash", "ring"):
             raise ValueError(
                 f"attention_impl must be 'xla', 'flash' or 'ring', got "
@@ -142,10 +188,11 @@ class TransformerConfig:
                     "pipeline_interleave > 1 requires pipeline_stages > 1"
                 )
             chunks = self.pipeline_stages * self.pipeline_interleave
-            if self.num_layers % chunks:
+            if self.num_scan_units % chunks:
                 raise ValueError(
-                    f"num_layers {self.num_layers} not divisible by "
-                    f"stages*interleave {chunks}"
+                    f"num_layers {self.num_layers} ({self.num_scan_units} "
+                    f"scanned units) not divisible by stages*interleave "
+                    f"{chunks}"
                 )
             micro = self.num_microbatches or self.pipeline_stages
             if micro < self.pipeline_stages:
@@ -155,6 +202,53 @@ class TransformerConfig:
                     "microbatch re-enters stage 0 only after lap L-1 "
                     "cleared the ring"
                 )
+
+    def _check_pattern(self):
+        pattern = self.layer_pattern
+        unknown = sorted(set(pattern) - set(LAYER_KINDS))
+        if unknown:
+            raise ValueError(
+                f"layer_pattern kinds must be among {list(LAYER_KINDS)}, "
+                f"got {unknown}"
+            )
+        if self.norm_placement not in ("pre", "post"):
+            raise ValueError(
+                f"norm_placement must be 'pre' or 'post', got "
+                f"{self.norm_placement!r}"
+            )
+        if not pattern:
+            return
+        if self.num_layers % len(pattern):
+            raise ValueError(
+                f"num_layers {self.num_layers} is no whole number of "
+                f"periods of the {len(pattern)}-layer pattern {pattern}"
+            )
+        if self.num_scan_units % self.pipeline_stages:
+            raise ValueError(
+                f"pipeline_stages {self.pipeline_stages} does not divide "
+                f"the {self.num_scan_units} periods of the "
+                f"{len(pattern)}-layer pattern ({self.num_layers} layers): "
+                "a stage holds whole periods"
+            )
+        if LINEAR_ATTENTION in pattern:
+            if not (self.linear_key_head_dim and self.linear_value_head_dim):
+                raise ValueError(
+                    "a linear_attention layer needs linear_key_head_dim and "
+                    f"linear_value_head_dim, got {self.linear_key_head_dim} "
+                    f"and {self.linear_value_head_dim}"
+                )
+            if self.decode:
+                raise ValueError(
+                    "decode=True with a linear_attention layer: the layer's "
+                    "recurrent state [H, dv, dk] and its convolution's last "
+                    "taps have no place beside the KV cache yet "
+                    "(serving/decode.py, serving/engine.py); this model "
+                    "trains only"
+                )
+
+    @property
+    def resolved_linear_heads(self) -> int:
+        return self.linear_num_heads or self.num_heads
 
     @property
     def resolved_d_ff(self) -> int:
@@ -181,7 +275,22 @@ class TransformerConfig:
             ff = (3 if self.activation == "swiglu" else 2) * d * self.resolved_d_ff
         embed = v * d + (0 if self.position != "learned" else self.max_seq_len * d)
         head = 0 if self.tie_embeddings else v * d
-        return l * (attn + ff) + embed + head
+        linear = self.num_linear_layers
+        return (
+            (l - linear) * attn + linear * self._linear_mixer_params()
+            + l * ff + embed + head
+        )
+
+    def _linear_mixer_params(self) -> int:
+        """q, k, v, gate and output projections, the two gate
+        projections, the convolution's taps, A_log, dt_bias and the output
+        norm's scale (models/linear_attention.py)."""
+        d, h = self.d_model, self.resolved_linear_heads
+        dk, dv = self.linear_key_head_dim, self.linear_value_head_dim
+        return (
+            2 * d * h * dk + 3 * d * h * dv + 2 * d * h
+            + self.linear_conv_kernel * h * (2 * dk + dv) + 2 * h + dv
+        )
 
 
 class Mlp(nn.Module):
@@ -225,7 +334,11 @@ class Mlp(nn.Module):
 
 
 class Block(nn.Module):
+    """One layer: a token mixer of ``kind`` (softmax attention, or the
+    gated delta rule) and an MLP, each on its residual branch."""
+
     config: TransformerConfig
+    kind: str = FULL_ATTENTION
 
     @nn.compact
     def __call__(
@@ -237,32 +350,54 @@ class Block(nn.Module):
         cfg = self.config
         x, aux = carry
         x = nn.with_logical_constraint(x, (lr.BATCH, lr.ACT_SEQ, lr.ACT_EMBED))
-        y = layers.make_norm(cfg.norm, cfg.dtype, cfg.param_dtype, "ln_attn",
-                     fused_backward=cfg.fused_ln)(x)
-        y = Attention(
-            num_heads=cfg.num_heads,
-            num_kv_heads=cfg.resolved_kv_heads,
-            head_dim=cfg.resolved_head_dim,
-            use_rope=cfg.position == "rope",
-            rope_theta=cfg.rope_theta,
-            use_bias=cfg.use_bias,
-            dtype=cfg.dtype,
-            param_dtype=cfg.param_dtype,
-            attention_impl=cfg.attention_impl,
-            qk_norm=cfg.qk_norm,
-            flash_block_q=cfg.flash_block_q,
-            flash_block_kv=cfg.flash_block_kv,
-            decode=cfg.decode,
-            cache_len=cfg.max_seq_len,
-            name="attn",
-        )(y, positions, segment_ids)
+        post = cfg.norm_placement == "post"
+
+        def norm(name, y):
+            return layers.make_norm(
+                cfg.norm, cfg.dtype, cfg.param_dtype, name,
+                fused_backward=cfg.fused_ln, epsilon=cfg.norm_eps,
+            )(y)
+
+        y = x if post else norm("ln_attn", x)
+        if self.kind == LINEAR_ATTENTION:
+            y = GatedDeltaNet(
+                num_heads=cfg.resolved_linear_heads,
+                key_dim=cfg.linear_key_head_dim,
+                value_dim=cfg.linear_value_head_dim,
+                conv_taps=cfg.linear_conv_kernel,
+                allow_neg_eigval=cfg.linear_allow_neg_eigval,
+                norm_eps=cfg.norm_eps,
+                dtype=cfg.dtype,
+                param_dtype=cfg.param_dtype,
+                name="linear_attn",
+            )(y)
+        else:
+            y = Attention(
+                num_heads=cfg.num_heads,
+                num_kv_heads=cfg.resolved_kv_heads,
+                head_dim=cfg.resolved_head_dim,
+                use_rope=cfg.position == "rope",
+                rope_theta=cfg.rope_theta,
+                use_bias=cfg.use_bias,
+                dtype=cfg.dtype,
+                param_dtype=cfg.param_dtype,
+                attention_impl=cfg.attention_impl,
+                qk_norm=cfg.qk_norm,
+                norm_eps=cfg.norm_eps,
+                flash_block_q=cfg.flash_block_q,
+                flash_block_kv=cfg.flash_block_kv,
+                decode=cfg.decode,
+                cache_len=cfg.max_seq_len,
+                name="attn",
+            )(y, positions, segment_ids)
+        if post:
+            y = norm("ln_attn", y)
         # Named checkpoint: under the "attn_out" remat policy the backward
         # skips re-running the whole attention forward (the priciest part of
         # recompute) at b*s*d bf16 per layer of extra HBM.
         y = jax.ad_checkpoint.checkpoint_name(y, "attn_out")
         x = x + y
-        y = layers.make_norm(cfg.norm, cfg.dtype, cfg.param_dtype, "ln_mlp",
-                     fused_backward=cfg.fused_ln)(x)
+        y = x if post else norm("ln_mlp", x)
         if cfg.num_experts:
             y, layer_aux = MoEMlp(
                 num_experts=cfg.num_experts,
@@ -287,6 +422,8 @@ class Block(nn.Module):
                 param_dtype=cfg.param_dtype,
                 name="mlp",
             )(y)
+        if post:
+            y = norm("ln_mlp", y)
         # Under the "branch_out" policy the backward rebuilds the residual
         # stream from saved branch outputs instead of re-running the wo
         # matmul (b*s*d bf16 per layer of extra HBM each).
@@ -294,6 +431,45 @@ class Block(nn.Module):
         x = x + y
         x = nn.with_logical_constraint(x, (lr.BATCH, lr.ACT_SEQ, lr.ACT_EMBED))
         return (x, aux), None
+
+
+def block_class(cfg: TransformerConfig, prevent_cse: bool):
+    """``Block``, under the config's remat policy (registry lookup,
+    ops/remat_policy.py: named save sets and builtins resolve there).
+    A block that IS a scan's body needs no barrier against CSE (the loop
+    is one); a block among others does, or the compiler may rebuild every
+    block of the body before the first cotangent arrives."""
+    if cfg.remat == "none":
+        return Block
+    return nn.remat(
+        Block,
+        policy=remat_policies.jax_policy(cfg.remat),
+        prevent_cse=prevent_cse,
+        static_argnums=(),
+    )
+
+
+def slot_name(position: int, kind: str) -> str:
+    """A block's name inside a period: ``linear_0`` .. ``full_3``."""
+    return f"{kind.split('_')[0]}_{position}"
+
+
+class Period(nn.Module):
+    """One period of ``config.layer_pattern``: its blocks in order, each
+    (under the remat policy) with its kind and its own name.  The scanned
+    unit of a patterned trunk, with ``Block``'s signature."""
+
+    config: TransformerConfig
+
+    @nn.compact
+    def __call__(self, carry, positions=None, segment_ids=None):
+        cfg = self.config
+        block_cls = block_class(cfg, prevent_cse=True)
+        for i, kind in enumerate(cfg.layer_pattern):
+            carry, _ = block_cls(cfg, kind, name=slot_name(i, kind))(
+                carry, positions, segment_ids
+            )
+        return carry, None
 
 
 class TransformerLM(nn.Module):
@@ -339,47 +515,44 @@ class TransformerLM(nn.Module):
             x = x + pos_table.astype(cfg.dtype)[positions]
         x = nn.with_logical_constraint(x, (lr.BATCH, lr.ACT_SEQ, lr.ACT_EMBED))
 
-        block_cls = Block
-        # Registry lookup (ops/remat_policy.py): named save sets and
-        # builtins resolve here.
-        policy = remat_policies.jax_policy(cfg.remat)
-        if cfg.remat != "none":
-            block_cls = nn.remat(
-                Block,
-                policy=policy,
-                prevent_cse=not cfg.scan_layers,
-                static_argnums=(),
-            )
+        block_cls = block_class(cfg, prevent_cse=not cfg.scan_layers)
+        # What is stacked and scanned: single layers, or whole periods of
+        # the layer pattern (each slot of a period then has its own
+        # parameters, stacked over the periods).
+        unit_cls = Period if cfg.layer_pattern else block_cls
         aux0 = jnp.zeros((), jnp.float32)
         if cfg.pipeline_stages > 1:
             from dlrover_tpu.parallel.pipeline import PipelinedBlocks
 
-            x, aux = PipelinedBlocks(cfg, block_cls, name="blocks")(
+            x, aux = PipelinedBlocks(cfg, unit_cls, name="blocks")(
                 x, aux0, positions, segment_ids
             )
         elif cfg.scan_layers:
             stack = nn.scan(
-                block_cls,
-                # "intermediates" carries the MoE router stats each layer
-                # sows — stacked on a leading layer axis where the caller
-                # applies with mutable=["intermediates"] (the train step
-                # does), absent otherwise.
+                unit_cls,
+                # "intermediates" carries the stats the layers sow (MoE
+                # router, linear attention) — stacked on a leading axis
+                # where the caller applies with mutable=["intermediates"]
+                # (the train step does), absent otherwise.
                 variable_axes={"params": 0, "cache": 0, "intermediates": 0},
                 split_rngs={"params": True},
                 in_axes=nn.broadcast,
-                length=cfg.num_layers,
+                length=cfg.num_scan_units,
                 metadata_params={nn.PARTITION_NAME: lr.LAYERS},
             )(cfg, name="blocks")
             (x, aux), _ = stack((x, aux0), positions, segment_ids)
         else:
             carry = (x, aux0)
             for i in range(cfg.num_layers):
-                carry, _ = block_cls(cfg, name=f"block_{i}")(
-                    carry, positions, segment_ids
-                )
+                carry, _ = block_cls(
+                    cfg, cfg.layer_kind(i), name=f"block_{i}"
+                )(carry, positions, segment_ids)
             x, aux = carry
 
-        x = layers.make_norm(cfg.norm, cfg.dtype, cfg.param_dtype, "ln_final")(x)
+        x = layers.make_norm(
+            cfg.norm, cfg.dtype, cfg.param_dtype, "ln_final",
+            epsilon=cfg.norm_eps,
+        )(x)
         if return_hidden:
             # Caller computes the loss head itself (chunked CE path) — the
             # [B, S, V] logits tensor is never materialized.  The µP logit

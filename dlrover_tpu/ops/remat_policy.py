@@ -17,7 +17,9 @@ objects carrying
 The saveable names are emitted by the model code via
 ``jax.ad_checkpoint.checkpoint_name``: ``attn_out`` / ``mlp_out``
 (transformer.py Block), ``flash_out`` / ``flash_lse``
-(ops/flash_attention.py custom_vjp fwd — flash impl only).
+(ops/flash_attention.py custom_vjp fwd — flash impl only), ``delta_out``
+(models/linear_attention.py: the gated delta rule's output; a model
+without such a layer emits no such name, and its step is what it was).
 """
 
 from __future__ import annotations
@@ -89,8 +91,10 @@ register(RematPolicy(
     "flash_res", saved_names=("attn_out", "flash_out", "flash_lse"),
     hbm_act_per_token_layer=3.05, recompute_fraction=0.55,
 ))
+# The mixers' kernels' outputs and nothing else: the flash kernel's, and
+# where a layer pattern has gated-delta-rule layers, the rule's.
 register(RematPolicy(
-    "flash_only", saved_names=("flash_out", "flash_lse"),
+    "flash_only", saved_names=("flash_out", "flash_lse", "delta_out"),
     hbm_act_per_token_layer=2.05, recompute_fraction=0.7,
 ))
 

@@ -59,10 +59,13 @@ from dlrover_tpu.parallel import rules as lr
 
 
 class PipelinedBlocks(nn.Module):
-    """Run ``num_layers`` Blocks as ``pipeline_stages`` pipelined stages.
+    """Run the trunk's scanned units as ``pipeline_stages`` pipelined
+    stages.
 
-    ``block_cls`` is the (possibly remat-wrapped) per-layer module class with
-    the ``carry=(x, aux), (positions, segment_ids) -> (carry, None)``
+    ``block_cls`` is the module class the trunk stacks: the (possibly
+    remat-wrapped) layer, or one period of the layer pattern
+    (``config.num_scan_units`` of them make the trunk), with the
+    ``carry=(x, aux), (positions, segment_ids) -> (carry, None)``
     signature shared with the non-pipelined scan path.
     """
 
@@ -80,10 +83,10 @@ class PipelinedBlocks(nn.Module):
         cfg = self.config
         stages = cfg.pipeline_stages
         num_micro = cfg.num_microbatches or stages
-        if cfg.num_layers % stages:
+        if cfg.num_scan_units % stages:
             raise ValueError(
-                f"num_layers {cfg.num_layers} not divisible by "
-                f"pipeline_stages {stages}"
+                f"num_layers {cfg.num_layers} ({cfg.num_scan_units} "
+                f"scanned units) not divisible by pipeline_stages {stages}"
             )
         if cfg.num_experts:
             raise NotImplementedError(
@@ -95,7 +98,7 @@ class PipelinedBlocks(nn.Module):
             raise ValueError(
                 f"batch {batch} not divisible by num_microbatches {num_micro}"
             )
-        per_stage = cfg.num_layers // stages
+        per_stage = cfg.num_scan_units // stages
         mb_size = batch // num_micro
 
         # One stage = a scan over its per_stage layers (params [per_stage,...]
@@ -278,7 +281,7 @@ class PipelinedBlocks(nn.Module):
         stages = cfg.pipeline_stages
         v = cfg.pipeline_interleave
         num_micro = cfg.num_microbatches or stages
-        per_chunk = cfg.num_layers // (stages * v)
+        per_chunk = cfg.num_scan_units // (stages * v)
         batch = x.shape[0]
         mb_size = batch // num_micro
         total_ticks = v * num_micro + stages - 1

@@ -30,6 +30,7 @@ import optax
 from dlrover_tpu.common import faults, telemetry
 from dlrover_tpu.common.log import default_logger as logger
 from dlrover_tpu.common.retry import RetryError, RetryPolicy
+from dlrover_tpu.models import linear_attention
 from dlrover_tpu.models import moe as moe_lib
 from dlrover_tpu.models.transformer import TransformerConfig, TransformerLM
 from dlrover_tpu.parallel import rules as lr
@@ -42,6 +43,9 @@ from dlrover_tpu.utils.profiler import pipeline_counters
 
 # End-of-source sentinel of ``ElasticTrainer._waited``.
 _NO_BATCH = object()
+# The step metrics that are vectors, not scalars: they stay on the device
+# until a report reads them.
+_STATS_KEYS = ("moe_stats", linear_attention.STATS_NAME)
 
 _PROCESS_START_BOOKED = False
 
@@ -977,15 +981,17 @@ class ElasticTrainer:
         steps = tuple(step for step, _ in ring)
         with pipeline_counters().host_block("metrics-flush", steps=steps):
             fetched = jax.device_get([
-                {k: v for k, v in metrics.items() if k != "moe_stats"}
+                {k: v for k, v in metrics.items() if k not in _STATS_KEYS}
                 for _, metrics in ring
             ])
         for (step, device), host in zip(ring, fetched):
             host = {k: float(np.asarray(v)) for k, v in host.items()}
-            if "moe_stats" in device:
-                # An MoE step's router vector stays on the device: only
-                # a report reads it (``_report``).
-                host["moe_stats"] = device["moe_stats"]
+            for key in _STATS_KEYS:
+                if key in device:
+                    # A step's stats vectors (MoE router, linear
+                    # attention) stay on the device: only a report reads
+                    # them (``_report``).
+                    host[key] = device[key]
             self._last_metrics = host
             if self._on_step is not None:
                 self._on_step(step, host)
@@ -1219,9 +1225,12 @@ class ElasticTrainer:
         logger.info(
             "step %d loss %.4f lr %.3g", step, loss, self.current_lr(step)
         )
+        state_absmax = self._report_linear_attn(metrics, step)
         anomalies = ()
         if self.numeric_monitor is not None:
-            found = self.numeric_monitor.check(step, loss, grad_norm)
+            found = self.numeric_monitor.check(
+                step, loss, grad_norm, state_absmax=state_absmax
+            )
             if found:
                 for a in found:
                     logger.error("numeric anomaly: %s", a.encode())
@@ -1284,6 +1293,30 @@ class ElasticTrainer:
         from dlrover_tpu.agent.monitor import write_device_metrics
 
         write_device_metrics()
+
+    def _report_linear_attn(self, metrics, step: int) -> Optional[float]:
+        """Linear-attention health on the report cadence: the vector this
+        step's program returned (``linear_attention.split_stats``), as a
+        ``linear_attn`` event.  Returns its ``state_absmax`` for the
+        numeric check, ``None`` where the step handed none out or no
+        report is due."""
+        stats = metrics.get(linear_attention.STATS_NAME)
+        if stats is None or step % self.config.report_every:
+            return None
+        with pipeline_counters().host_block(
+            "linear_attn_stats", steps=(step,)
+        ):
+            vec = np.asarray(jax.device_get(stats), np.float64)
+        alpha, beta, absmax = linear_attention.split_stats(vec)
+        telemetry.event(
+            "linear_attn", step=step,
+            layers=self.model_config.num_linear_layers,
+            chunk=linear_attention.GatedDeltaNet.chunk,
+            mean_alpha=float(alpha),
+            mean_beta=float(beta),
+            state_absmax=float(absmax),
+        )
+        return float(absmax)
 
     def _emit_memory_event(self, step: int):
         """One flat-attr ``memory`` event: allocator truth + classified

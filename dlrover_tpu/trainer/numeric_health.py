@@ -36,7 +36,8 @@ class Anomaly:
 class NumericHealthMonitor:
     """Rolling-window anomaly detector over (loss, grad_norm) series.
 
-    * **nan** — loss or grad_norm is NaN/Inf: always an anomaly.
+    * **nan** — loss, grad_norm or a linear-attention state's largest
+      entry is NaN/Inf: always an anomaly.
     * **loss_spike** — loss exceeds ``mean + spike_sigma * std`` of the
       window AND ``spike_ratio x`` the window mean (the sigma test alone
       misfires on converged, near-zero-variance losses).
@@ -65,15 +66,23 @@ class NumericHealthMonitor:
         self.anomalies: List[Anomaly] = []
 
     def check(self, step: int, loss: float,
-              grad_norm: Optional[float] = None) -> List[Anomaly]:
-        """Feed one step's scalars; returns anomalies found at this step."""
+              grad_norm: Optional[float] = None,
+              state_absmax: Optional[float] = None) -> List[Anomaly]:
+        """Feed one step's scalars; returns anomalies found at this step.
+        ``state_absmax`` is the largest entry of a linear-attention
+        layer's recurrent state where the step reports one: not finite,
+        it is the same anomaly as a loss that is not."""
         found: List[Anomaly] = []
-        if not math.isfinite(loss) or (
-            grad_norm is not None and not math.isfinite(grad_norm)
+        if not all(
+            math.isfinite(v) for v in (loss, grad_norm, state_absmax)
+            if v is not None
         ):
             found.append(Anomaly(
                 "nan", step,
-                f"loss={loss} grad_norm={grad_norm}",
+                f"loss={loss} grad_norm={grad_norm}" + (
+                    "" if state_absmax is None
+                    else f" state_absmax={state_absmax}"
+                ),
             ))
             # Poisoned values must not enter the rolling statistics.
             self.anomalies.extend(found)

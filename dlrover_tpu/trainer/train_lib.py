@@ -24,6 +24,7 @@ from flax.training import train_state as flax_train_state
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from dlrover_tpu.common.log import default_logger as logger
+from dlrover_tpu.models import linear_attention
 from dlrover_tpu.parallel import rules as lr
 
 
@@ -411,20 +412,36 @@ def _batch_shard_count(mesh: Mesh, batch_spec_entry) -> int:
     return out
 
 
-def _mean_moe_stats(sown) -> Optional[jax.Array]:
-    """One float32 ``moe_stats`` vector (``moe.split_stats``) out of the
-    ``"intermediates"`` a forward pass sowed: the mean over the MoE layers
-    and over whatever axes the layer scan and the sow stack.  ``None``
+def _sown_vectors(sown, name: str) -> Optional[jax.Array]:
+    """The float32 vectors the layers sowed under ``name`` into the
+    ``"intermediates"`` of a forward pass, as ``[n, width]`` over the
+    layers and whatever axes the layer scan and the sow stack; ``None``
     where no layer sowed one."""
     vectors = [
         leaf.reshape(-1, leaf.shape[-1])
         for path, leaf in jax.tree_util.tree_leaves_with_path(sown)
-        if any(getattr(key, "key", None) == "moe_stats" for key in path)
+        if any(getattr(key, "key", None) == name for key in path)
     ]
     if not vectors:
         return None
-    stats = jnp.concatenate(vectors, axis=0).astype(jnp.float32)
-    return jax.lax.stop_gradient(jnp.mean(stats, axis=0))
+    return jax.lax.stop_gradient(
+        jnp.concatenate(vectors, axis=0).astype(jnp.float32)
+    )
+
+
+def _layer_stats(sown) -> Dict[str, jax.Array]:
+    """What a step hands out of the layers' sown vectors, by metric name:
+    ``moe_stats`` (mean over the layers) and ``linear_attn_stats``
+    (``linear_attention.fold_stats``: means, and the largest state
+    entry).  Empty for a model whose layers sow neither."""
+    out = {}
+    moe = _sown_vectors(sown, "moe_stats")
+    if moe is not None:
+        out["moe_stats"] = jnp.mean(moe, axis=0)
+    linear = _sown_vectors(sown, linear_attention.STATS_NAME)
+    if linear is not None:
+        out[linear_attention.STATS_NAME] = linear_attention.fold_stats(linear)
+    return out
 
 
 def build_sharded_train(
@@ -665,28 +682,31 @@ def build_sharded_train(
             " [int8]" if allgather_quant == "int8" else "",
         )
 
-    # A model with routers hands each layer's ``moe_stats`` vector out of
-    # the step that computes it; a dense model's step is applied as ever.
-    has_experts = bool(
-        getattr(getattr(model, "config", None), "num_experts", 0)
+    # A model with routers or linear-attention layers hands each layer's
+    # sown stats vector out of the step that computes it; any other
+    # model's step is applied as ever.
+    model_config = getattr(model, "config", None)
+    sows_stats = bool(
+        getattr(model_config, "num_experts", 0)
+        or "linear_attention" in getattr(model_config, "layer_pattern", ())
     )
 
     def _forward_sums(params, apply_fn, inputs, targets, weights):
         """One forward pass -> (weighted CE sum, token count, aux loss,
-        router statistics).  The last is the layers' sown ``moe_stats``
-        vectors (``moe.split_stats``) averaged over layers and whatever
-        axes the scan and the sow stack, under ``stop_gradient``; ``None``
-        for a model that sows none."""
+        layer statistics).  The last is ``_layer_stats`` of what the
+        layers sowed (``moe_stats``, ``linear_attn_stats``), folded over
+        layers and whatever axes the scan and the sow stack, under
+        ``stop_gradient``; empty for a model that sows none."""
         kwargs = {"return_hidden": True} if ce_chunks else {}
         variables = {"params": params}
-        if has_experts:
+        if sows_stats:
             (out, aux), sown = apply_fn(
                 variables, inputs, mutable=["intermediates"], **kwargs
             )
-            moe_stats = _mean_moe_stats(sown)
+            stats = _layer_stats(sown)
         else:
             out, aux = apply_fn(variables, inputs, **kwargs)
-            moe_stats = None
+            stats = {}
         if ce_chunks:
             ce, total_weight = chunked_cross_entropy_loss(
                 out, output_head(params), targets, weights,
@@ -694,7 +714,7 @@ def build_sharded_train(
             )
         else:
             ce, total_weight = cross_entropy_loss(out, targets, weights)
-        return ce * total_weight, total_weight, aux, moe_stats
+        return ce * total_weight, total_weight, aux, stats
 
     def _q_reduce_scatter_leaf(leaf, z_sharding, full_sharding):
         """Route one gradient leaf's DP reduce through the int8 wire as a
@@ -849,14 +869,14 @@ def build_sharded_train(
         TRACE_COUNTS["train_step"] += 1
 
         def loss_fn(params):
-            ce_sum, total_weight, aux, moe_stats = _forward_sums(
+            ce_sum, total_weight, aux, stats = _forward_sums(
                 params, state.apply_fn, batch["inputs"], batch["targets"],
                 batch["weights"],
             )
             ce = ce_sum / total_weight
-            return ce + aux, (ce, aux, total_weight, moe_stats)
+            return ce + aux, (ce, aux, total_weight, stats)
 
-        grads, (ce, aux, total_weight, moe_stats) = jax.grad(
+        grads, (ce, aux, total_weight, stats) = jax.grad(
             loss_fn, has_aux=True
         )(state.params)
         if overlap_active:
@@ -873,8 +893,7 @@ def build_sharded_train(
             "grad_norm": optax.global_norm(grads),
             "step": new_state.step,
         }
-        if moe_stats is not None:
-            metrics["moe_stats"] = moe_stats
+        metrics.update(stats)
         return new_state, metrics
 
     def _accum_train_step(state: TrainState, batch: Dict[str, jax.Array]):
@@ -905,14 +924,14 @@ def build_sharded_train(
         )
 
         def micro_loss(params, mb):
-            ce_sum, _w, aux, moe_stats = _forward_sums(
+            ce_sum, _w, aux, stats = _forward_sums(
                 params, state.apply_fn, mb["inputs"], mb["targets"],
                 mb["weights"],
             )
             # aux (model-internal regularizers) is a per-microbatch mean:
             # average it over N so its gradient scale matches full-batch.
             return ce_sum / w_total + aux / grad_accum, (
-                ce_sum, aux, moe_stats
+                ce_sum, aux, stats
             )
 
         params_shardings = state_shardings.params
@@ -936,7 +955,7 @@ def build_sharded_train(
 
         def accum(carry, mb):
             gacc, ce_acc, aux_acc = carry
-            g, (ce_sum, aux, moe_stats) = jax.grad(
+            g, (ce_sum, aux, stats) = jax.grad(
                 micro_loss, has_aux=True
             )(state.params, mb)
             if overlap_active:
@@ -944,11 +963,11 @@ def build_sharded_train(
             gacc = pin(jax.tree.map(
                 lambda a, gi: a + gi.astype(a.dtype), gacc, g
             ))
-            # The microbatches' router statistics stack as the scan's
-            # output (``None``, and no output, for a dense model).
-            return (gacc, ce_acc + ce_sum, aux_acc + aux), moe_stats
+            # The microbatches' layer statistics stack as the scan's
+            # output (empty, and no output, for a model that sows none).
+            return (gacc, ce_acc + ce_sum, aux_acc + aux), stats
 
-        (grads, ce_sum, aux_sum), moe_stats = jax.lax.scan(
+        (grads, ce_sum, aux_sum), stats = jax.lax.scan(
             accum, (grads0, jnp.zeros((), jnp.float32),
                     jnp.zeros((), jnp.float32)), xs
         )
@@ -989,8 +1008,14 @@ def build_sharded_train(
             "grad_norm": optax.global_norm(grads),
             "step": new_state.step,
         }
-        if moe_stats is not None:
-            metrics["moe_stats"] = moe_stats.mean(axis=0)
+        if "moe_stats" in stats:
+            metrics["moe_stats"] = stats["moe_stats"].mean(axis=0)
+        if linear_attention.STATS_NAME in stats:
+            metrics[linear_attention.STATS_NAME] = (
+                linear_attention.fold_stats(
+                    stats[linear_attention.STATS_NAME]
+                )
+            )
         return new_state, metrics
 
     if grad_accum > 1:

@@ -46,6 +46,7 @@ import jax.numpy as jnp
 from dlrover_tpu.models import layers
 from dlrover_tpu.ops.gated_delta_rule import gated_delta_rule
 from dlrover_tpu.parallel import rules as lr
+from dlrover_tpu.runtime.mesh import shard_local
 
 F32 = jnp.float32
 STATS_NAME = "linear_attn_stats"
@@ -141,6 +142,27 @@ def l2_normalise(x: jax.Array) -> jax.Array:
     )
 
 
+def _delta_rule_local(q, k, v, g, beta, *, chunk):
+    """The rule on each device's own batch rows and heads
+    (:func:`shard_local`, as attention's ``_flash_local``): a head's state
+    is its own and the sequence is whole, so nothing crosses devices but
+    the largest ``|S|``, which each device reports for itself."""
+    qkv_spec = nn.logical_to_mesh_axes((lr.BATCH, None, lr.ACT_HEADS, lr.KV))
+    gate_spec = nn.logical_to_mesh_axes((lr.BATCH, None, lr.ACT_HEADS))
+
+    def local(q, k, v, g, beta):
+        o, state_absmax = gated_delta_rule(q, k, v, g, beta, chunk=chunk)
+        return o, state_absmax[None, None]
+
+    o, state_absmax = shard_local(
+        local, in_specs=(qkv_spec,) * 3 + (gate_spec,) * 2,
+        out_specs=(
+            qkv_spec, nn.logical_to_mesh_axes((lr.BATCH, lr.ACT_HEADS)),
+        ),
+    )(q, k, v, g, beta)
+    return o, state_absmax.max()
+
+
 class GatedDeltaNet(nn.Module):
     num_heads: int
     key_dim: int
@@ -212,12 +234,12 @@ class GatedDeltaNet(nn.Module):
         spec = (lr.BATCH, None, lr.ACT_HEADS, lr.KV)
         q, k, v = (nn.with_logical_constraint(a, spec) for a in (q, k, v))
         with jax.named_scope("delta_rule"):
-            o, state_absmax = gated_delta_rule(
+            o, state_absmax = _delta_rule_local(
                 q, k, v, g, beta, chunk=self.chunk
             )
         # The one activation of the mixer the layer's remat keeps
-        # (ops/remat_policy.py): with it the backward rebuilds the chunk
-        # tensors once, not the outputs as well.
+        # (ops/remat_policy.py): the backward runs the rule's forward
+        # kernel again for its chunk-start states, not for the outputs.
         o = jax.ad_checkpoint.checkpoint_name(o, "delta_out")
         o = nn.with_logical_constraint(o, spec)
         self.sow(

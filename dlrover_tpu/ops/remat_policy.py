@@ -20,6 +20,13 @@ The saveable names are emitted by the model code via
 (ops/flash_attention.py custom_vjp fwd — flash impl only), ``delta_out``
 (models/linear_attention.py: the gated delta rule's output; a model
 without such a layer emits no such name, and its step is what it was).
+
+What ``flash_only`` keeps of a linear layer is that output alone.  The
+rule's backward kernel also reads the state each chunk starts from, and
+the forward kernel writes those again under the remat: keeping them too
+(no second forward) was the slower step on the chip, because the compiler
+then made room by recomputing two projections, and keeping neither let it
+pick slower layouts around the rule (PERF.md §6, PR 32).
 """
 
 from __future__ import annotations

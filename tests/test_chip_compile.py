@@ -105,6 +105,15 @@ def _row_gather_sum():
     return gather_sum
 
 
+def _delta_rule():
+    from dlrover_tpu.ops.gated_delta_rule import gated_delta_rule
+
+    def loss(q, k, v, g, beta):
+        return gated_delta_rule(q, k, v, g, beta)[0].astype(F32).sum()
+
+    return jax.grad(loss, argnums=(0, 1, 2, 3, 4))
+
+
 def _quant_roundtrip():
     from dlrover_tpu.ops import quantization as qz
 
@@ -170,6 +179,11 @@ CASES = [
      [((139264, 16, 128), BF16), ((16384, 8), I32)], {}, 1),
     ("row_gather_sum_from_plain_rows", _row_gather_sum,
      [((139264, 2048), BF16), ((16384, 8), I32)], {}, 1),
+    # Olmo-Hybrid-7B's linear layers: 2 x 8192 tokens, 30 heads of 96 / 192,
+    # the forward kernel and the backward kernel
+    ("delta_rule_olmo_hybrid", _delta_rule,
+     [((2, 8192, 30, 96), BF16)] * 2 + [((2, 8192, 30, 192), BF16)]
+     + [((2, 8192, 30), F32)] * 2, {}, 2),
     ("quantize_dequantize", _quant_roundtrip, [LEAF], {}, 2),
     ("q8_adam", lambda: _adam_update("q8_adam"), [LEAF, LEAF], {}, 1),
     ("q4_adam", lambda: _adam_update("q4_adam"), [LEAF, LEAF], {}, 1),

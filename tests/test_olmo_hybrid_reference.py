@@ -3,6 +3,9 @@
 CPU: the chunked delta rule against the token-by-token recurrence, the
 patterned trunk against the reference's loop over layers.
 
+The rule runs as its Pallas kernels in interpret mode
+(``ops/backend.interpret``), forward and backward.
+
 Seeded weights; every norm scale is moved off one.  Tolerances, all
 float32 against float32: the chunked form orders its sums differently
 from the recurrence and builds ``(I + A)^-1`` by products, which moves an
@@ -130,6 +133,95 @@ def test_state_absmax_is_the_largest_boundary_state():
     np.testing.assert_allclose(float(got), top, rtol=1e-5)
 
 
+def rule_grads(fn, args, do):
+    return jax.grad(
+        lambda *a: (fn(*a).astype(jnp.float32) * do).sum(),
+        argnums=(0, 1, 2, 3, 4),
+    )(*args)
+
+
+# bfloat16 operands: W, V', M and the start states are rounded to 8 bits
+# inside a chunk, which moves a gradient by 0.3-1.2% of its largest entry
+# (read here, both lengths); a dropped term moves it by tens of percent.
+KERNEL_RTOL = {jnp.float32: 2e-5, jnp.bfloat16: 3e-2}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("length,cotangent", [
+    (128, "all"), (384, "all"), (300, "all"), (384, "last_chunk"),
+])
+def test_kernel_gradients_at_the_published_head_widths(
+    length, cotangent, dtype
+):
+    """dk 96, dv 192, chunk 128: one chunk, three, and a length that is
+    no whole number of chunks; every gradient against autodiff of the
+    token-by-token recurrence on the same (rounded) operands.  With the
+    cotangent on the last chunk alone, all that reaches the first chunk's
+    tokens has crossed two chunk boundaries as the state's cotangent."""
+    args, do = rule_inputs(length, length, True, heads=2, dk=96, dv=192)
+    args = tuple(a.astype(dtype) for a in args[:3]) + args[3:]
+    if cotangent == "last_chunk":
+        do = do * (jnp.arange(length) >= 256)[None, :, None, None]
+    in_f32 = tuple(a.astype(jnp.float32) for a in args)
+    want_o = reference.delta_rule_recurrence(*in_f32)
+    got_o, _ = gated_delta_rule(*args)
+    assert got_o.dtype == dtype
+    rtol = KERNEL_RTOL[dtype]
+    assert float(jnp.abs(got_o - want_o).max()) <= rtol * float(
+        jnp.abs(want_o).max()
+    )
+    got = rule_grads(lambda *a: gated_delta_rule(*a)[0], args, do)
+    want = rule_grads(reference.delta_rule_recurrence, in_f32, do)
+    for name, g, w in zip("q k v g beta".split(), got, want):
+        assert g.dtype == (jnp.float32 if name in ("g", "beta") else dtype)
+        first = jnp.abs(w[:, :128]).max()
+        # (q_t reaches no output but its own token's)
+        assert float(first) > 0 or (name, cotangent) == ("q", "last_chunk")
+        assert float(jnp.abs(g - w).max()) <= rtol * float(
+            jnp.abs(w).max()
+        ), name
+        # and the first chunk's own gradients, by their own size
+        assert float(jnp.abs(g[:, :128] - w[:, :128]).max()) <= (
+            rtol * float(first)
+        ), name
+
+
+def test_alike_keys_in_bfloat16_stay_with_the_recurrence():
+    """The inverse's three-pass products (bfloat16 operands take that
+    path; float32 operands multiply exactly) on keys at cosine 0.9 and
+    beta 1.98: within the rounding of the operands, where one pass or
+    the product of powers is not."""
+    args, _ = rule_inputs(9, 256, True, heads=2, dk=96, dv=192)
+    q, k, v, g, beta = args
+    k = k + 0.3 * jnp.ones_like(k[:1, :1, :1])
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    cos = jnp.einsum("bshk,bthk->bhst", k, k)
+    assert float(cos.min()) > 0.6
+    args = tuple(a.astype(jnp.bfloat16) for a in (q, k, v)) + (
+        0.05 * g, 0.99 * jnp.full_like(beta, 2.0),
+    )
+    want = reference.delta_rule_recurrence(
+        *(a.astype(jnp.float32) for a in args)
+    )
+    got, _ = gated_delta_rule(*args)
+    assert float(jnp.abs(got - want).max()) <= 3e-2 * float(
+        jnp.abs(want).max()
+    )
+
+
+@pytest.mark.parametrize("chunk,dtype,message", [
+    (96, jnp.float32, "power of two"),
+    (8, jnp.bfloat16, "16 rows of a bfloat16 tile, got 8"),
+])
+def test_a_chunk_the_kernel_cannot_tile_raises_with_the_numbers(
+    chunk, dtype, message
+):
+    args, _ = rule_inputs(1, 64, True)
+    args = tuple(a.astype(dtype) for a in args[:3]) + args[3:]
+    with pytest.raises(ValueError, match=message):
+        gated_delta_rule(*args, chunk=chunk)
+
+
 # -- the model -----------------------------------------------------------------
 
 
@@ -230,9 +322,16 @@ def test_the_unrolled_trunk_is_the_scanned_one(params, tokens):
     assert nll_gap(cfg, unrolled, tokens) <= NLL_ATOL
 
 
-def test_the_train_step_s_first_loss_is_the_reference_s(params, tokens):
+@pytest.mark.parametrize("parallel,devices", [
+    (dict(data=1), 1), (dict(data=2, tensor=2), 4),
+])
+def test_the_train_step_s_first_loss_is_the_reference_s(
+    parallel, devices, params, tokens
+):
     """The normal path: ``build_sharded_train``'s compiled step, under the
-    policy the cell runs (the rule's and the flash kernels' outputs kept)."""
+    policy the cell runs (the rule's and the flash kernels' outputs kept);
+    on one device, and with the batch over ``data`` and the heads over
+    ``tensor``, where each device's kernels see its own rows and heads."""
     from dlrover_tpu.models import linear_attention
     from dlrover_tpu.parallel import rules as lr
     from dlrover_tpu.runtime.mesh import ParallelConfig, build_mesh
@@ -242,13 +341,16 @@ def test_the_train_step_s_first_loss_is_the_reference_s(params, tokens):
     train = train_lib.build_sharded_train(
         TransformerLM(cfg),
         train_lib.make_optimizer("adafactor", learning_rate=1e-3),
-        build_mesh(ParallelConfig(data=1), devices=jax.devices()[:1]),
+        build_mesh(
+            ParallelConfig(**parallel), devices=jax.devices()[:devices]
+        ),
         lr.DEFAULT_RULES, global_batch_size=BATCH, seq_len=SEQ,
     )
     state = train.init(jax.random.PRNGKey(0))
     state = state.replace(params=jax.tree.map(
-        lambda new, old: jnp.array(new, old.dtype, copy=True), params,
-        state.params,
+        lambda new, old: jax.device_put(
+            jnp.array(new, old.dtype, copy=True), old.sharding
+        ), params, state.params,
     ))
     batch = {"inputs": np.asarray(tokens[0]), "targets": np.asarray(tokens[1])}
     _, metrics = train.step(state, train_lib.shard_batch(batch, train))

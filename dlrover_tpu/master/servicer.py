@@ -395,6 +395,15 @@ class MasterServicer:
                         "unparseable moe event from %d: %r",
                         node, attrs,
                     )
+            elif self.speed_monitor is not None and name == "mtp":
+                # The multi-token-prediction module's loss: behind the
+                # dlrover_mtp_loss gauge.
+                try:
+                    self.speed_monitor.record_mtp(node, **attrs)
+                except (TypeError, ValueError):
+                    logger.warning(
+                        "unparseable mtp event from %d: %r", node, attrs,
+                    )
             elif self.speed_monitor is not None and name == "linear_attn":
                 # Linear-attention health snapshot (mean decay, mean
                 # write strength, the recurrent state's largest entry):

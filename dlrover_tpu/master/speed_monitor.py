@@ -99,6 +99,8 @@ class SpeedMonitor:
         # load) — the ``dlrover_moe_*`` gauges read the aggregate.
         self._moe_stats: Dict[int, Dict[str, Any]] = {}
         self._moe_events = 0
+        # "mtp" events: each reporter's newest multi-token-prediction loss.
+        self._mtp_loss: Dict[int, float] = {}
         # "linear_attn" telemetry events: each reporter's newest snapshot
         # of its gated-delta-rule layers — the ``dlrover_linear_attn_*``
         # gauges read the aggregate.
@@ -296,10 +298,15 @@ class SpeedMonitor:
         load: Any = "[]",
         pad_share: float = 0.0,
         max_expert_load: float = 0.0,
+        held: float = 0.0,
+        pairs_here: float = 1.0,
+        bias_absmax: float = 0.0,
         **_ignored,
     ):
         """A trainer's router-health snapshot (its ``moe`` telemetry
-        event).  Newest-wins per reporting node; ``load`` arrives as a
+        event).  ``held`` of the ``experts`` live on the reporter's chip
+        (0: all), which computed ``pairs_here`` of the routed pairs;
+        ``bias_absmax`` is a bias-corrected router's largest bias.  Newest-wins per reporting node; ``load`` arrives as a
         JSON array string of per-expert load fractions (wire attrs stay
         scalar-ish); unknown attrs are ignored so the trainer can grow
         the event without breaking older masters."""
@@ -318,7 +325,23 @@ class SpeedMonitor:
                 "load": [float(v) for v in load],
                 "pad_share": float(pad_share),
                 "max_expert_load": float(max_expert_load),
+                "held": float(held or experts),
+                "pairs_here": float(pairs_here),
+                "bias_absmax": float(bias_absmax),
             }
+
+    def record_mtp(self, node_id: int = 0, *, step: float = 0.0,
+                   mtp_loss: float = 0.0, **_ignored):
+        """A trainer's multi-token-prediction loss (its ``mtp`` event);
+        newest wins per reporting node."""
+        with self._lock:
+            self._mtp_loss[node_id] = float(mtp_loss)
+
+    def mtp_loss(self) -> float:
+        """Mean over reporters of the newest MTP loss; 0 with none."""
+        with self._lock:
+            values = list(self._mtp_loss.values())
+        return sum(values) / len(values) if values else 0.0
 
     def record_linear_attn(
         self,
@@ -404,6 +427,11 @@ class SpeedMonitor:
                 "experts": experts,
                 "top_k": max((s["top_k"] for s in stats), default=0.0),
                 "load": load,
+                "held": max((s["held"] for s in stats), default=0.0),
+                "pairs_here": mean("pairs_here") if n else 1.0,
+                "bias_absmax": max(
+                    (s["bias_absmax"] for s in stats), default=0.0
+                ),
             }
 
     def embed_ledger(self) -> Dict[str, float]:

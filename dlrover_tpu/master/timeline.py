@@ -379,6 +379,18 @@ class JobTimeline:
                   "router choices per token (top-k)")
             gauge("dlrover_moe_reporters", moe["reporters"],
                   "trainers that have reported router-health snapshots")
+            gauge("dlrover_moe_experts_held", moe["held"],
+                  "experts of a layer that live on a reporter's chip "
+                  "(= dlrover_moe_experts where none is told a share)")
+            gauge("dlrover_moe_pairs_here", moe["pairs_here"],
+                  "share of the routed token-choices a reporter's own "
+                  "experts computed (mean of reporters; 1 without a share)")
+            gauge("dlrover_moe_router_bias_absmax", moe["bias_absmax"],
+                  "largest |bias| of a bias-corrected router (max of "
+                  "reporters; 0 where the router has none)")
+            gauge("dlrover_mtp_loss", speed_monitor.mtp_loss(),
+                  "multi-token-prediction module's cross-entropy (mean of "
+                  "reporters' newest; 0 where the model has no module)")
             lines.append(
                 "# HELP dlrover_moe_expert_load fraction of kept "
                 "token-choices routed to each expert (mean of reporters; "

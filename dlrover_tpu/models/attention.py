@@ -16,6 +16,11 @@ TPU-first design notes:
     (einsum softmax, XLA-fused) or ``"flash"`` (Pallas flash-attention
     kernel).  Ring-attention context parallelism lives in
     ``dlrover_tpu.parallel.ring_attention`` and wraps either impl.
+  * Two modules share that math: :class:`Attention` (one ``head_dim`` for
+    q, k and v; GQA; QK-norm) and :class:`LatentAttention` (the
+    DeepSeek-V2/V3 family's multi-head latent attention: q through a
+    low-rank latent, k and v rebuilt from a shared latent row, one rotary
+    key for all heads, keys wider than values).
 """
 
 from __future__ import annotations
@@ -25,6 +30,7 @@ from typing import Callable, Optional
 
 import flax.linen as nn
 import jax
+import jax.ad_checkpoint
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
@@ -113,6 +119,7 @@ def xla_attention(
     scale = d ** -0.5
     # GQA via broadcast, not jnp.repeat: grouping q keeps K/V (and their
     # remat recompute) at H_kv width instead of inflating HBM by `group`x.
+    # (``v`` may have another width than ``q`` and ``k``: the output's.)
     qg = q.reshape(b, sq, hkv, group, d)
     logits = jnp.einsum(
         "bqhgd,bkhd->bhgqk", qg, k, preferred_element_type=jnp.float32
@@ -131,7 +138,7 @@ def xla_attention(
         logits = jnp.where(mask, logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
     out = jnp.einsum("bhgqk,bkhd->bqhgd", probs, v)
-    return out.reshape(b, sq, hq, d)
+    return out.reshape(b, sq, hq, v.shape[-1])
 
 
 def cached_attention(
@@ -417,3 +424,125 @@ class Attention(nn.Module):
             name="out",
         )(out)
         return out
+
+
+class LatentAttention(nn.Module):
+    """Multi-head latent attention (DeepSeek-V2/V3 family), training form.
+
+    ``n`` is the normed residual stream ``[B, S, d]``::
+
+        c_q = RMSNorm(n W_qa)                       # q_lora_rank
+        [q_nope | q_pe]_h = c_q W_qb                # H heads, nope + rope
+        [c_kv | k_pe] = n W_kva                     # kv_lora_rank | rope
+        [k_nope | v]_h = RMSNorm(c_kv) W_kvb        # H heads, nope | v
+        q_pe, k_pe <- RoPE(theta); ONE k_pe for all heads
+        k_h = [k_nope_h | k_pe]; softmax(q_h k_h / sqrt(nope + rope)) v_h
+        y = concat_h(o_h) W_o                       # H * v -> d
+
+    Training keeps no cache: k and v are materialised per head and go
+    through the attention kernel as ``H`` heads with keys ``nope + rope``
+    wide and values ``v_head_dim`` wide (``ops/flash_attention.py`` takes
+    the two widths; v is not padded).  The rotary halves follow the
+    program's rotate-half convention on the LAST ``rope`` columns of a
+    head's q and of the ``kv_a`` row (``rope_interleave`` in the published
+    config is a fixed permutation of those columns).
+
+    There is no decode path: a latent cache would hold the normed
+    ``c_kv`` row and the rotated ``k_pe`` (``kv_lora_rank + rope`` numbers
+    a token, not ``2 H hd``) in ``serving/decode.py``'s cache pool, with
+    ``W_kvb`` absorbed into the query and output sides; nothing there can
+    hold it yet, so ``TransformerConfig`` refuses ``decode=True``.
+    """
+
+    num_heads: int
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    rope_theta: float = 10000.0
+    norm_eps: float = 1e-6
+    dtype: layers.Dtype = jnp.bfloat16
+    param_dtype: layers.Dtype = jnp.float32
+    attention_impl: str = "xla"
+    flash_block_q: int = 512
+    flash_block_kv: int = 512
+
+    @nn.compact
+    def __call__(
+        self,
+        x: jax.Array,
+        positions: Optional[jax.Array] = None,
+        segment_ids: Optional[jax.Array] = None,
+    ) -> jax.Array:
+        if self.attention_impl not in ("flash", "xla"):
+            raise ValueError(
+                "latent attention runs under attention_impl 'flash' or "
+                f"'xla', got {self.attention_impl!r}"
+            )
+        features = x.shape[-1]
+        nope, rope = self.qk_nope_head_dim, self.qk_rope_head_dim
+        if positions is None:
+            positions = jnp.arange(x.shape[1])[None, :]
+
+        def dense(width, axes, name):
+            return layers.DenseGeneral(
+                width, kernel_axes=axes, use_bias=False, dtype=self.dtype,
+                param_dtype=self.param_dtype, name=name,
+            )
+
+        def norm(name):
+            return layers.make_norm(
+                "rmsnorm", self.dtype, self.param_dtype, name,
+                epsilon=self.norm_eps,
+            )
+
+        c_q = norm("q_norm")(
+            dense(self.q_lora_rank, (lr.EMBED, lr.LATENT), "q_a")(x)
+        )
+        q = dense(
+            (self.num_heads, nope + rope), (lr.LATENT, lr.HEADS, lr.KV), "q_b"
+        )(c_q)
+        kv_row = dense(
+            self.kv_lora_rank + rope, (lr.EMBED, lr.LATENT), "kv_a"
+        )(x)
+        c_kv = norm("kv_norm")(kv_row[..., : self.kv_lora_rank])
+        kv = dense(
+            (self.num_heads, nope + self.v_head_dim),
+            (lr.LATENT, lr.HEADS, lr.KV), "kv_b",
+        )(c_kv)
+        with jax.named_scope("rope"):
+            q_pe, k_pe = layers.rotary_embedding(
+                q[..., nope:], kv_row[..., None, self.kv_lora_rank:],
+                positions, self.rope_theta,
+            )
+            q = jnp.concatenate([q[..., :nope], q_pe], axis=-1)
+            k = jnp.concatenate([
+                kv[..., :nope],
+                jnp.broadcast_to(k_pe, (*kv.shape[:-1], rope)),
+            ], axis=-1)
+            v = kv[..., nope:]
+        # Names for a remat policy that would KEEP the per-head keys and
+        # values (``flash_only`` does not: the backward rebuilds them from
+        # the latent row, which lost nothing measurable on the chip and
+        # saves 2 x B x S x H x (192 + 128) bytes a layer; PERF.md §6).
+        k = jax.ad_checkpoint.checkpoint_name(k, "latent_k")
+        v = jax.ad_checkpoint.checkpoint_name(v, "latent_v")
+
+        if self.attention_impl == "flash":
+            out = _flash_local(
+                q, k, v, segment_ids, block_q=self.flash_block_q,
+                block_kv=self.flash_block_kv,
+            )
+        else:
+            attn_spec = (lr.BATCH, None, lr.ACT_HEADS, lr.KV)
+            q = nn.with_logical_constraint(q, attn_spec)
+            k = nn.with_logical_constraint(k, attn_spec)
+            v = nn.with_logical_constraint(v, attn_spec)
+            out = xla_attention(q, k, v, causal=True, segment_ids=segment_ids)
+            out = nn.with_logical_constraint(out, attn_spec)
+        return layers.DenseGeneral(
+            features, axis=(-2, -1),
+            kernel_axes=(lr.HEADS, lr.KV, lr.EMBED), use_bias=False,
+            dtype=self.dtype, param_dtype=self.param_dtype, name="wo",
+        )(out)

@@ -28,7 +28,8 @@ from dlrover_tpu.parallel import rules as lr
 
 
 def _gate(logits: jax.Array, k: int, norm_topk_prob: bool = True,
-          aux_form: str = "top1"):
+          aux_form: str = "top1", scoring: str = "softmax", bias=None,
+          scale: float = 1.0):
     """Shared top-k gate: (gate_vals, gate_idx, aux_loss), float32.
 
     ``norm_topk_prob`` renormalises the chosen gates to sum to one
@@ -36,7 +37,31 @@ def _gate(logits: jax.Array, k: int, norm_topk_prob: bool = True,
     probabilities as they are (OLMoE).  ``aux_form`` picks the
     load-balancing loss: ``"top1"`` (Switch: share of first choices x mean
     probability, x E^2/k) or ``"topk"`` (E x sum_e f_e x P_e, ``f_e`` the
-    share of tokens that chose ``e`` in any of their k slots)."""
+    share of tokens that chose ``e`` in any of their k slots).
+
+    ``scoring="sigmoid"`` is the DeepSeek-V3 family's router
+    (``topk_method: noaux_tc``): ``s = sigmoid(logits)``; the k experts are
+    chosen on ``s + bias`` (``bias`` picks, it never weighs, and takes no
+    gradient); the gates are the chosen ``s``, renormalised under
+    ``norm_topk_prob``, times ``scale``; there is no auxiliary loss (the
+    bias is moved by :func:`bias_update` after each step instead)."""
+    if scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+        pick = scores if bias is None else scores + jax.lax.stop_gradient(
+            bias.astype(jnp.float32)
+        )
+        _, gate_idx = jax.lax.top_k(pick, k)
+        gate_vals = jnp.take_along_axis(scores, gate_idx, axis=-1)
+        if norm_topk_prob:
+            gate_vals = gate_vals / (
+                jnp.sum(gate_vals, axis=-1, keepdims=True) + 1e-20
+            )
+        return gate_vals * scale, gate_idx, jnp.zeros((), jnp.float32)
+    if scoring != "softmax":
+        raise ValueError(
+            f"unknown router scoring {scoring!r}; expected 'softmax' or "
+            "'sigmoid'"
+        )
     e = logits.shape[-1]
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     gate_vals, gate_idx = jax.lax.top_k(probs, k)            # [B,S,k]
@@ -59,6 +84,16 @@ def _gate(logits: jax.Array, k: int, norm_topk_prob: bool = True,
             f"unknown MoE aux_form {aux_form!r}; expected 'top1' or 'topk'"
         )
     return gate_vals, gate_idx, aux_loss
+
+
+def bias_update(bias: jax.Array, load: jax.Array, rate: float) -> jax.Array:
+    """The auxiliary-loss-free balancing rule (DeepSeek-V3): after a step,
+    ``b_e += rate * sign(mean load - load_e)`` from that step's own
+    per-expert loads (any positive multiple of the counts: only the order
+    against the mean matters).  ``bias`` and ``load`` are ``[..., E]``."""
+    load = load.astype(jnp.float32)
+    step = jnp.sign(load.mean(axis=-1, keepdims=True) - load)
+    return (bias.astype(jnp.float32) + rate * step).astype(bias.dtype)
 
 
 def top_k_gating(
@@ -104,10 +139,22 @@ def top_k_gating(
     return dispatch, combine, aux_loss, prior.sum(axis=(0, 1))
 
 
-def _router_entropy(router_logits: jax.Array) -> jax.Array:
-    """Mean per-token entropy of the router distribution (nats)."""
-    probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
+def _router_entropy(router_logits: jax.Array,
+                    scoring: str = "softmax") -> jax.Array:
+    """Mean per-token entropy of the router distribution (nats): the
+    softmax, or a sigmoid router's scores normalised over the experts."""
+    if scoring == "sigmoid":
+        scores = jax.nn.sigmoid(router_logits.astype(jnp.float32))
+        probs = scores / scores.sum(axis=-1, keepdims=True)
+    else:
+        probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
     return jnp.mean(-jnp.sum(probs * jnp.log(probs + 1e-9), axis=-1))
+
+
+# What a layer told its share of the experts sows beside ``moe_stats``:
+# ``[pairs_here, bias_absmax]``, the share of the routed pairs this chip
+# computed and the router bias's largest entry.
+SHARE_STATS_NAME = "moe_share_stats"
 
 
 STATS_TAIL = 2  # [pad_share, max_expert_load] after the per-expert loads
@@ -115,8 +162,17 @@ STATS_TAIL = 2  # [pad_share, max_expert_load] after the per-expert loads
 
 def split_stats(vec):
     """``(entropy, drop_fraction, load[E], pad_share, max_expert_load)`` of
-    one ``moe_stats`` vector (:meth:`MoEMlp._sow_router_stats`)."""
-    return vec[0], vec[1], vec[2:-STATS_TAIL], vec[-2], vec[-1]
+    one ``moe_stats`` vector (:meth:`MoEMlp._sow_router_stats`), or of a
+    stack of them along leading axes."""
+    return (vec[..., 0], vec[..., 1], vec[..., 2:-STATS_TAIL], vec[..., -2],
+            vec[..., -1])
+
+
+def fold_share_stats(rows):
+    """``[n, 2]`` ``moe_share_stats`` vectors (layers, microbatches) as
+    one: the mean share of the routed pairs computed here, the largest
+    router-bias entry."""
+    return jnp.stack([rows[:, 0].mean(), rows[:, 1].max()])
 
 
 # -- dropless dispatch: every move of rows is a gather -------------------------
@@ -149,25 +205,62 @@ def _row_budget(pairs: int, block: int, experts: int) -> int:
     return ((pairs + block - 1) // block + experts) * block
 
 
-def _dispatch_plan(gate_idx, experts: int, block: int, n_pad: int):
+def _share_row_budget(pairs: int, block: int, held: int, total: int,
+                      multiple: float) -> int:
+    """Rows set aside where ``held`` of ``total`` experts live here:
+    ``multiple`` x the expected share of the ``pairs`` the router chose,
+    one block of padding an expert, and one block more that no pair is
+    ever given (its rows stay zero: where the pairs routed elsewhere, and
+    any beyond this budget, point).  The GEMMs skip the row blocks after
+    the last live one, so the slack costs memory and no GEMM time."""
+    expected = int(multiple * pairs * held / total)
+    return _row_budget(expected, block, held) + block
+
+
+def _dispatch_plan(gate_idx, experts: int, block: int, n_pad: int,
+                   first: int = 0, total: int = 0):
     """Where each (token, choice) pair's row lives among the expert-grouped
     rows, and which pair each row holds, from ``gate_idx`` ``[T, k]``.
 
+    The plan is over the ``experts`` held here, ``first .. first +
+    experts - 1`` of the ``total`` the router chose among (all of them by
+    default).  A pair routed to an expert that lives elsewhere has no row
+    here; nor has a pair past the budget (``n_pad`` rows less the zero
+    block): both point at the last row, which no pair is given and the
+    GEMMs leave zero.
+
     ``padded`` [E]: rows of each expert's group (whole blocks);
     ``dest`` [T, k]: the pair's row; ``row_pair`` [n_pad]: the row's pair
-    (flat ``token * k + choice``), ``T * k`` where the row is padding."""
+    (flat ``token * k + choice``), ``T * k`` where the row is padding;
+    under a share also ``kept`` []: the pairs that have a row, and
+    ``here`` []: the pairs routed to an expert held here."""
     t, k = gate_idx.shape
     n = t * k
+    share = bool(total) and experts < total
     flat = gate_idx.reshape(n)
+    if share:
+        # experts elsewhere sort after every expert held here
+        flat = flat - first
+        flat = jnp.where((flat >= 0) & (flat < experts), flat, experts)
     onehot = (flat[:, None] == jnp.arange(experts)[None, :]).astype(jnp.int32)
     seen = jnp.cumsum(onehot, axis=0)                           # [N, E]
     counts = seen[-1]
     padded = ((counts + block - 1) // block) * block
     group_ends = jnp.cumsum(padded)
     group_starts = group_ends - padded
+    kept = counts
+    if share:
+        # a group ends where the budget does, less the zero block
+        limit = n_pad - block
+        padded = jnp.clip(limit - group_starts, 0, padded)
+        kept = jnp.minimum(counts, padded)
+        group_ends = group_starts + padded
     count_starts = jnp.cumsum(counts) - counts
     # group start + rank of the pair within its expert's group, token order
     dest = jnp.sum(onehot * (seen - 1 + group_starts[None, :]), axis=1)
+    if share:
+        placed = jnp.sum(onehot * (seen <= kept[None, :]), axis=1) > 0
+        dest = jnp.where(placed, dest, n_pad - 1)
     # pairs in expert order: a stable sort keeps the token order per expert
     _, order = jax.lax.sort(
         (flat, jnp.arange(n, dtype=jnp.int32)), num_keys=1, is_stable=True
@@ -177,11 +270,17 @@ def _dispatch_plan(gate_idx, experts: int, block: int, n_pad: int):
         jnp.sum(row[:, None] >= group_ends[None, :], axis=1), experts - 1
     )
     rank = row - group_starts[expert_of_row]
+    live = rank < kept[expert_of_row]
+    if share:
+        live = live & (rank >= 0)
     row_pair = jnp.where(
-        rank < counts[expert_of_row],
+        live,
         order[jnp.clip(count_starts[expert_of_row] + rank, 0, n - 1)], n,
     )
-    return {"padded": padded, "dest": dest.reshape(t, k), "row_pair": row_pair}
+    plan = {"padded": padded, "dest": dest.reshape(t, k), "row_pair": row_pair}
+    if share:
+        plan.update(kept=kept.sum(), here=counts.sum())
+    return plan
 
 
 def _zero_row(x):
@@ -294,6 +393,25 @@ class MoEMlp(nn.Module):
       silently computing with the wrong experts; use an a2a/einsum mode
       under expert parallelism.
 
+    A chip's share of the experts (``experts_held`` < ``num_experts``,
+    ``"grouped"`` only): the layer holds experts ``first_expert ..
+    first_expert + experts_held - 1`` of a layer whose other experts live
+    on further chips.  The router keeps its ``num_experts`` outputs and its
+    ``top_k`` a token; the layer computes the part of the result its own
+    experts give for the pairs routed to them, and what the absent
+    experts would add is left out (on one chip the layer runs without its
+    exchange; nothing stands in for the absent chips).  The dispatch plan
+    is over the held experts, the row budget a stated multiple of the
+    expected share (:func:`_share_row_budget`) whose dead row blocks the
+    GEMMs skip, and a pair beyond it is dropped and counted in
+    ``drop_fraction``, never silently.
+
+    ``scoring="sigmoid"`` with ``router_bias`` is the DeepSeek-V3 router
+    (:func:`_gate`); the bias ``router_bias`` ``[num_experts]`` is a
+    parameter no gradient reaches, moved by :func:`bias_update` in the
+    train step.  ``shared_d_ff`` adds a shared expert (a plain SwiGLU MLP
+    of that width, ``shared``) every token passes through.
+
     Router observability: every forward ``sow``s a ``moe_stats`` vector
     ``[gate_entropy, drop_fraction, load_0..load_{E-1}, pad_share,
     max_expert_load]`` (:func:`split_stats`) into the
@@ -315,14 +433,34 @@ class MoEMlp(nn.Module):
     gmm_block_rows: int = 128
     norm_topk_prob: bool = True     # see :func:`_gate`
     aux_form: str = "top1"
+    scoring: str = "softmax"        # "softmax" | "sigmoid"
+    router_bias: bool = False       # choose on score + bias (sigmoid only)
+    routed_scale: float = 1.0       # the gates' factor (sigmoid only)
+    experts_held: int = 0           # 0 -> all num_experts live here
+    first_expert: int = 0
+    shared_d_ff: int = 0            # 0 -> no shared expert
+    row_budget_multiple: float = 1.25
+
+    @property
+    def held(self) -> int:
+        return self.experts_held or self.num_experts
 
     @nn.compact
     def __call__(self, x: jax.Array) -> Tuple[jax.Array, jax.Array]:
         b, s, d = x.shape
-        e = self.num_experts
+        e = self.held
+        check_share(
+            self.num_experts, self.experts_held, self.first_expert,
+            self.dispatch,
+        )
+        if self.router_bias and self.scoring != "sigmoid":
+            raise ValueError(
+                "router_bias corrects a sigmoid router's choice "
+                f"(scoring='sigmoid'), got scoring={self.scoring!r}"
+            )
 
         router_logits = layers.DenseGeneral(
-            e,
+            self.num_experts,
             kernel_axes=(lr.EMBED, None),
             dtype=jnp.float32,
             param_dtype=self.param_dtype,
@@ -354,9 +492,36 @@ class MoEMlp(nn.Module):
                 self.param_dtype,
             ).astype(self.dtype)
 
+        bias = None
+        if self.router_bias:
+            bias = self.param(
+                "router_bias",
+                nn.with_logical_partitioning(
+                    nn.initializers.zeros_init(), (None,)
+                ),
+                (self.num_experts,), jnp.float32,
+            )
+        shared = None
+        if self.shared_d_ff:
+            from dlrover_tpu.models.transformer import Mlp
+
+            shared = Mlp(
+                d_ff=self.shared_d_ff, activation=self.activation,
+                use_bias=False, dtype=self.dtype,
+                param_dtype=self.param_dtype, name="shared",
+            )(x)
+        out, aux = self._routed(x, router_logits, bias, wi, wg, wo)
+        return (out if shared is None else out + shared), aux
+
+    def _routed(self, x, router_logits, bias, wi, wg, wo):
         from dlrover_tpu.runtime.mesh import EXPERT_AXIS, mesh_axis_size
 
         ep = mesh_axis_size(EXPERT_AXIS)
+        if self.dispatch != "grouped" and self.scoring != "softmax":
+            raise ValueError(
+                f"scoring={self.scoring!r} is routed by dispatch='grouped' "
+                f"only, got dispatch={self.dispatch!r}"
+            )
         if self.dispatch == "grouped":
             if ep > 1:
                 raise ValueError(
@@ -368,7 +533,7 @@ class MoEMlp(nn.Module):
                     "'einsum', 'a2a', or 'a2a_int8' under expert "
                     "parallelism (see PROFILE.md round 19)."
                 )
-            return self._grouped_forward(x, router_logits, wi, wg, wo)
+            return self._grouped_forward(x, router_logits, bias, wi, wg, wo)
         if self.dispatch not in ("einsum", "a2a", "a2a_int8"):
             raise ValueError(
                 f"unknown MoE dispatch {self.dispatch!r}; expected one of "
@@ -542,19 +707,34 @@ class MoEMlp(nn.Module):
         self._sow_router_stats(entropy, routed, b * s * k, b * e * capacity)
         return out, aux.astype(jnp.float32)
 
-    def _sow_router_stats(self, entropy, routed, total, rows_run):
+    def _sow_router_stats(self, entropy, routed, total, rows_run, kept=None):
         """Book ``[entropy, drop_fraction, load_0..load_{E-1}, pad_share,
         max_expert_load]`` into the ``"intermediates"`` collection (no-op
         unless mutable; the train step is the caller that makes it so).
-        ``routed`` are the (token, expert) pairs each expert computes,
-        ``total`` the pairs the router chose, ``rows_run`` the rows the
-        expert matmuls run (capacity slots, or the grouped GEMMs' padded
-        row budget): what is not a routed pair is padding."""
+        ``routed`` are the (token, expert) pairs each expert computes
+        (under a share: that the router chose for each of ALL the experts),
+        ``total`` the pairs the router chose (under a share: for the
+        experts held here), ``rows_run`` the rows the expert matmuls run
+        (capacity slots, the grouped GEMMs' row budget, or under a share
+        their live row blocks): what is
+        not a routed pair is padding.  ``kept`` are the pairs computed
+        here where that is not ``routed``'s sum."""
         routed = routed.astype(jnp.float32)
-        kept = routed.sum()
-        drop = 1.0 - kept / max(1, total)
-        load = routed / jnp.clip(kept, 1.0)
-        pad_share = 1.0 - kept / rows_run
+        chosen = routed.sum()
+        if kept is None:
+            kept = chosen
+        if isinstance(total, int):
+            def share_missing(whole):
+                return 1.0 - kept / max(1, whole)
+        else:
+            # counted on the device: the difference first, so that nothing
+            # missing reads exactly 0 (a TPU's x / x need not be 1)
+            def share_missing(whole):
+                return (whole - kept) / jnp.maximum(whole, 1.0)
+
+        drop = share_missing(total)
+        load = routed / jnp.clip(chosen, 1.0)
+        pad_share = share_missing(rows_run)
         max_load = load.max() * routed.shape[0]
         self.sow(
             "intermediates", "moe_stats",
@@ -566,39 +746,58 @@ class MoEMlp(nn.Module):
 
     # -- dropless grouped-GEMM dispatch ---------------------------------------
 
-    def _grouped_forward(self, x, router_logits, wi, wg, wo):
+    def _grouped_forward(self, x, router_logits, bias, wi, wg, wo):
         from jax.sharding import PartitionSpec as P
 
         from dlrover_tpu.ops.grouped_matmul import grouped_matmul
         from dlrover_tpu.runtime.mesh import mesh_axis_size, shard_local
 
         b, s, d = x.shape
-        e, k = self.num_experts, self.top_k
+        e, k = self.held, self.top_k
+        total, first = self.num_experts, self.first_expert
+        share = e < total
         block = self.gmm_block_rows
 
         # Routing and its statistics are global and plain XLA; only the
         # sort + grouped GEMMs below run per device.
         with jax.named_scope("router"):
             gate_vals, gate_idx, aux_loss = _gate(
-                router_logits, k, self.norm_topk_prob, self.aux_form
+                router_logits, k, self.norm_topk_prob, self.aux_form,
+                self.scoring, bias, self.routed_scale,
             )
         # Tokens stay split over their batch and sequence axes (an MLP is
         # token-wise); the embed dim and the expert weights are whole on
         # every device, so peers on a tensor axis repeat each other's work.
         tokens = nn.logical_to_mesh_axes((lr.BATCH, lr.ACT_SEQ, None))
-        shards = 1
-        for axes in tokens:
-            for axis in (axes,) if isinstance(axes, str) else (axes or ()):
-                shards *= mesh_axis_size(axis)
-        # Dropless: routed == total, so drop_fraction books as exactly 0.
-        self._sow_router_stats(
-            _router_entropy(router_logits),
-            routed=jax.nn.one_hot(
-                gate_idx.reshape(-1), e, dtype=jnp.int32
-            ).sum(axis=0),
-            total=b * s * k,
-            rows_run=shards * _row_budget(b * s * k // shards, block, e),
+        token_axes = tuple(
+            axis for axes in tokens
+            for axis in ((axes,) if isinstance(axes, str) else (axes or ()))
         )
+
+        def budget(pairs):
+            if share:
+                return _share_row_budget(
+                    pairs, block, e, total, self.row_budget_multiple
+                )
+            return _row_budget(pairs, block, e)
+
+        def routed():
+            """The pairs the router chose for each of ALL the experts."""
+            return jax.nn.one_hot(
+                gate_idx.reshape(-1), total, dtype=jnp.int32
+            ).sum(axis=0)
+
+        if not share:
+            shards = 1
+            for axis in token_axes:
+                shards *= mesh_axis_size(axis)
+            # Dropless: routed == total, so drop_fraction books as exactly 0.
+            self._sow_router_stats(
+                _router_entropy(router_logits, self.scoring),
+                routed=routed(),
+                total=b * s * k,
+                rows_run=shards * budget(b * s * k // shards),
+            )
 
         def local(x, gate_vals, gate_idx, *weights):
             """Group this device's (token, expert) pairs by expert and run
@@ -609,8 +808,8 @@ class MoEMlp(nn.Module):
             t = b * s
             with jax.named_scope("sort"):
                 plan = _dispatch_plan(
-                    gate_idx.reshape(t, k), e, block,
-                    _row_budget(t * k, block, e),
+                    gate_idx.reshape(t, k), e, block, budget(t * k),
+                    first, total,
                 )
             # The d_model-wide rows live row-tiled between the gathers and
             # the GEMMs wherever the fetch-and-sum kernel can read them.
@@ -619,28 +818,92 @@ class MoEMlp(nn.Module):
                 rows = _rows_of_tokens(
                     x.reshape(t, d).astype(self.dtype), plan, tiled
                 )
+            # A share's budget is a multiple of the expected rows: the
+            # GEMMs skip its dead blocks.  With every expert held the slack
+            # is a block an expert at most, and skipping costs more than it
+            # saves (ops/grouped_matmul.py).
             with jax.named_scope("gmm_wi"):
-                h = grouped_matmul(rows, wi, plan["padded"], block)
+                h = grouped_matmul(
+                    rows, wi, plan["padded"], block, False, share
+                )
             if wg is not None:
                 with jax.named_scope("gmm_wg"):
-                    g = grouped_matmul(rows, wg, plan["padded"], block)
+                    g = grouped_matmul(
+                        rows, wg, plan["padded"], block, False, share
+                    )
                 h = nn.silu(g) * h
             else:
                 h = nn.gelu(h)
             with jax.named_scope("gmm_wo"):
                 out_rows = grouped_matmul(
-                    h, wo, plan["padded"], block, tiled
+                    h, wo, plan["padded"], block, tiled, share
                 )
             with jax.named_scope("combine"):
                 out = _tokens_of_rows(
                     out_rows, gate_vals.reshape(t, k), plan
                 )
-            return out.reshape(b, s, d)
+            if not share:
+                return out.reshape(b, s, d)
+            # this device's [pairs with a row, pairs routed to an expert
+            # held here, rows its GEMMs run: the live blocks]
+            counted = jnp.stack(
+                [plan["kept"], plan["here"], plan["padded"].sum()]
+            ).astype(jnp.float32)
+            return out.reshape(b, s, d), counted[None]
 
         weights = [wi] + ([wg] if wg is not None else []) + [wo]
         out = shard_local(
             local,
             in_specs=(tokens,) * 3 + (P(),) * len(weights),
-            out_specs=tokens,
+            out_specs=(tokens, P(token_axes or None, None)) if share
+            else tokens,
         )(x, gate_vals, gate_idx, *weights)
+        here = b * s * k
+        if share:
+            # A pair past the row budget is dropped, and counted; the
+            # rows run are the live blocks, the budget's slack is skipped.
+            out, counted = out
+            kept, here, rows_run = jax.lax.stop_gradient(counted.sum(axis=0))
+            self._sow_router_stats(
+                _router_entropy(router_logits, self.scoring),
+                routed=routed(), total=here, rows_run=rows_run, kept=kept,
+            )
+        if share or bias is not None:
+            self.sow(
+                "intermediates", SHARE_STATS_NAME,
+                jnp.stack([
+                    here / (b * s * k),
+                    jnp.zeros((), jnp.float32) if bias is None
+                    else jnp.abs(jax.lax.stop_gradient(bias)).max(),
+                ]),
+            )
         return out, aux_loss.astype(jnp.float32)
+
+
+def check_share(num_experts: int, held: int, first: int, dispatch: str):
+    """A share of the experts is whole: ``held`` divides ``num_experts``,
+    ``first`` is a multiple of it, and only the grouped dispatch can be
+    told one."""
+    if not held or held == num_experts:
+        if first:
+            raise ValueError(
+                f"first_expert {first} without a share of the experts "
+                "(experts_held)"
+            )
+        return
+    if held < 0 or num_experts % held or first % held or not (
+        0 <= first < num_experts
+    ):
+        raise ValueError(
+            f"experts_held {held} must divide num_experts {num_experts} "
+            f"and first_expert {first} be a multiple of it below "
+            f"{num_experts}: a chip holds one of num_experts / held equal "
+            "shares"
+        )
+    if dispatch != "grouped":
+        raise ValueError(
+            f"a share of the experts ({held} of {num_experts}) is computed "
+            f"by dispatch='grouped' only, got dispatch={dispatch!r}: the "
+            "capacity dispatches build their [B, S, E, C] tensors over "
+            "every expert"
+        )

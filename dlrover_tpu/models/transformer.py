@@ -4,7 +4,13 @@ One model covers the reference's example/benchmark families (nanoGPT GPT-2,
 Llama2 — ref ``examples/pytorch/nanogpt/train.py``,
 ``atorch/examples/llama2/``): config flags pick learned-position+LayerNorm+GELU
 (GPT-2) or RoPE+RMSNorm+SwiGLU+GQA (Llama), and ``num_experts > 0`` switches
-the MLP to expert-parallel MoE.
+the MLP to expert-parallel MoE.  The DeepSeek-V3 family's parts are flags
+too: latent attention (``kv_lora_rank``), a sigmoid router with a
+bias-corrected choice, a shared expert, a chip's share of the experts
+(``experts_held``), leading dense layers before the scanned expert layers
+(``first_k_dense``) and a multi-token-prediction module (``mtp_depth``)
+whose output leaves the model only where the caller hands it the next
+tokens (the train step does).
 
 TPU-first structure:
   * layers are ``nn.scan``-stacked: one trace regardless of depth (fast
@@ -27,9 +33,9 @@ import jax.ad_checkpoint
 import jax.numpy as jnp
 
 from dlrover_tpu.models import layers
-from dlrover_tpu.models.attention import Attention
+from dlrover_tpu.models.attention import Attention, LatentAttention
 from dlrover_tpu.models.linear_attention import GatedDeltaNet
-from dlrover_tpu.models.moe import MoEMlp
+from dlrover_tpu.models.moe import MoEMlp, check_share
 from dlrover_tpu.ops import remat_policy as remat_policies
 from dlrover_tpu.parallel import rules as lr
 
@@ -69,9 +75,47 @@ class TransformerConfig:
     # "topk" (E * sum_e f_e * P_e with f_e counted over all k choices, the
     # form the Mixtral/OLMoE reference implementations train with).
     moe_aux_form: str = "top1"
+    # The DeepSeek-V3 family's router and expert layer (models/moe.py):
+    # "sigmoid" scores with the k experts chosen on score + bias
+    # (``router_bias``; the bias is a parameter no gradient reaches, moved
+    # by ``router_bias_rate`` x sign(mean load - load) after each step) and
+    # the gates scaled by ``routed_scaling_factor``; ``num_shared_experts``
+    # shared experts of the routed experts' width every token passes
+    # through; ``experts_held`` (0 = all) of the ``num_experts`` the router
+    # chooses among live on this chip, from ``first_expert`` on, and the
+    # rows set aside are ``moe_row_budget`` x the expected share;
+    # ``moe_d_ff`` is an expert's width where dense layers have ``d_ff``.
+    router_scoring: str = "softmax"
+    router_bias: bool = False
+    router_bias_rate: float = 0.001
+    routed_scaling_factor: float = 1.0
+    num_shared_experts: int = 0
+    experts_held: int = 0
+    first_expert: int = 0
+    moe_row_budget: float = 1.25
+    moe_d_ff: int = 0
+    # Layers before the scanned trunk whose MLP is dense (``d_ff`` wide)
+    # though the trunk's is sparse: ``dense_0`` .. of ``num_layers``.
+    first_k_dense: int = 0
+    # Multi-token prediction (DeepSeek-V3 §2.2): one module (depth 1) that
+    # predicts token i+2 from the trunk's hidden state i and the embedding
+    # of token i+1 through a layer of its own and the SHARED head; the
+    # train step adds ``mtp_weight`` x its cross-entropy to the loss.
+    mtp_depth: int = 0
+    mtp_weight: float = 0.3
     # RMSNorm over the whole q and k projections (all heads jointly, own
     # scale each) before the head split and RoPE (OLMoE, OLMo-2).
     qk_norm: bool = False
+    # Latent attention (models/attention.py ``LatentAttention``), on where
+    # ``kv_lora_rank`` is set: q through a ``q_lora_rank`` latent, k and v
+    # rebuilt from a ``kv_lora_rank`` latent, ``qk_rope_head_dim`` rotary
+    # columns shared by all heads; keys ``qk_nope_head_dim +
+    # qk_rope_head_dim`` wide, values ``v_head_dim``.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     # One period of layer kinds ("full_attention" | "linear_attention"),
     # repeated num_layers / len(layer_pattern) times; empty = every layer
     # full attention.  The trunk scans over PERIODS: a period applies its
@@ -142,7 +186,21 @@ class TransformerConfig:
     def num_scan_units(self) -> int:
         """What the trunk scans (and the pipeline stacks) over: periods of
         the layer pattern, or single layers without one."""
-        return self.num_layers // max(1, len(self.layer_pattern))
+        return (self.num_layers - self.first_k_dense) // max(
+            1, len(self.layer_pattern)
+        )
+
+    @property
+    def latent_attention(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def resolved_moe_d_ff(self) -> int:
+        return self.moe_d_ff or self.resolved_d_ff
+
+    @property
+    def resolved_experts_held(self) -> int:
+        return self.experts_held or self.num_experts
 
     @property
     def num_linear_layers(self) -> int:
@@ -159,6 +217,7 @@ class TransformerConfig:
         # a JSON list arrives as a list; the config must stay hashable
         object.__setattr__(self, "layer_pattern", tuple(self.layer_pattern))
         self._check_pattern()
+        self._check_family()
         if self.attention_impl not in ("xla", "flash", "ring"):
             raise ValueError(
                 f"attention_impl must be 'xla', 'flash' or 'ring', got "
@@ -246,6 +305,98 @@ class TransformerConfig:
                     "trains only"
                 )
 
+    def _check_family(self):
+        """The DeepSeek-V3 family's fields: whole shares, a trunk left
+        after the dense prefix, latent attention's five sizes together."""
+        if self.num_experts:
+            check_share(
+                self.num_experts, self.experts_held, self.first_expert,
+                self.moe_dispatch,
+            )
+        elif self.experts_held or self.num_shared_experts or self.router_bias:
+            raise ValueError(
+                "experts_held, num_shared_experts and router_bias describe "
+                "an expert layer: set num_experts"
+            )
+        if self.router_scoring not in ("softmax", "sigmoid"):
+            raise ValueError(
+                "router_scoring must be 'softmax' or 'sigmoid', got "
+                f"{self.router_scoring!r}"
+            )
+        if self.router_bias and self.router_scoring != "sigmoid":
+            raise ValueError(
+                "router_bias corrects a sigmoid router's choice; got "
+                f"router_scoring={self.router_scoring!r}"
+            )
+        if self.router_scoring == "sigmoid" and self.moe_dispatch != "grouped":
+            raise ValueError(
+                "router_scoring='sigmoid' is routed by moe_dispatch="
+                f"'grouped' only, got {self.moe_dispatch!r}"
+            )
+        if not 0 <= self.first_k_dense < max(1, self.num_layers):
+            raise ValueError(
+                f"first_k_dense {self.first_k_dense} must leave a trunk of "
+                f"the {self.num_layers} layers"
+            )
+        if self.first_k_dense and self.layer_pattern:
+            raise ValueError(
+                "first_k_dense puts dense layers before a trunk of layers of "
+                "one kind: it takes no layer_pattern"
+            )
+        if self.first_k_dense and (
+            self.num_layers - self.first_k_dense
+        ) % self.pipeline_stages:
+            raise ValueError(
+                f"pipeline_stages {self.pipeline_stages} does not divide "
+                f"the {self.num_layers - self.first_k_dense} layers after "
+                f"the {self.first_k_dense} dense one(s), which run ahead "
+                "of the first stage"
+            )
+        if self.mtp_depth not in (0, 1):
+            raise ValueError(
+                f"mtp_depth must be 0 or 1 (one module), got {self.mtp_depth}"
+            )
+        if self.mtp_depth and self.layer_pattern:
+            raise ValueError(
+                "mtp_depth with a layer_pattern: the module's layer has no "
+                "kind to take"
+            )
+        latent = (
+            self.q_lora_rank, self.kv_lora_rank, self.qk_nope_head_dim,
+            self.qk_rope_head_dim, self.v_head_dim,
+        )
+        if any(latent) and not all(latent):
+            raise ValueError(
+                "latent attention needs q_lora_rank, kv_lora_rank, "
+                "qk_nope_head_dim, qk_rope_head_dim and v_head_dim "
+                f"together, got {latent}"
+            )
+        if not self.latent_attention:
+            return
+        if self.position != "rope":
+            raise ValueError(
+                "latent attention rotates qk_rope_head_dim columns: it "
+                f"needs position='rope', got {self.position!r}"
+            )
+        if self.qk_rope_head_dim % 2:
+            raise ValueError(
+                f"qk_rope_head_dim {self.qk_rope_head_dim} must be even"
+            )
+        if self.attention_impl == "ring" or self.qk_norm:
+            raise ValueError(
+                "latent attention runs under attention_impl 'flash' or "
+                "'xla' and has its own latent norms (no qk_norm)"
+            )
+        if self.decode:
+            raise ValueError(
+                "decode=True with latent attention: its cache would hold "
+                "the normed kv latent and the one rotated key row "
+                f"({self.kv_lora_rank} + {self.qk_rope_head_dim} numbers a "
+                "token) with W_kvb absorbed into the query and output "
+                "sides, and serving/decode.py's cache pool holds [H_kv, hd] "
+                "keys and values only; this model trains only"
+            )
+
     @property
     def resolved_linear_heads(self) -> int:
         return self.linear_num_heads or self.num_heads
@@ -261,24 +412,46 @@ class TransformerConfig:
         return 4 * self.d_model
 
     def num_params(self) -> int:
-        """Approximate parameter count (for MFU/HFU accounting)."""
+        """Approximate parameter count (for MFU/HFU accounting): the
+        parameters HELD, so a chip's share of the experts counts
+        ``experts_held`` of them; latent attention by its five projections
+        and two latent norms; the shared experts, the dense prefix and the
+        MTP module (a layer, ``eh_proj`` and its three norms) where set; a
+        layer's own two norms and the final one are left out, as ever."""
         d, v, l = self.d_model, self.vocab_size, self.num_layers
-        h = self.resolved_head_dim * self.num_heads
-        hkv = self.resolved_head_dim * self.resolved_kv_heads
-        attn = d * h + 2 * d * hkv + h * d
-        if self.num_experts:
-            ff = self.num_experts * (
-                (3 if self.activation == "swiglu" else 2)
-                * d * self.resolved_d_ff
-            ) + d * self.num_experts
+        swiglu = 3 if self.activation == "swiglu" else 2
+        if self.latent_attention:
+            h, qk = self.num_heads, self.qk_nope_head_dim + self.qk_rope_head_dim
+            attn = (
+                d * self.q_lora_rank + self.q_lora_rank * h * qk
+                + d * (self.kv_lora_rank + self.qk_rope_head_dim)
+                + self.kv_lora_rank * h
+                * (self.qk_nope_head_dim + self.v_head_dim)
+                + h * self.v_head_dim * d
+                + self.q_lora_rank + self.kv_lora_rank
+            )
         else:
-            ff = (3 if self.activation == "swiglu" else 2) * d * self.resolved_d_ff
+            h = self.resolved_head_dim * self.num_heads
+            hkv = self.resolved_head_dim * self.resolved_kv_heads
+            attn = d * h + 2 * d * hkv + h * d
+        dense_ff = swiglu * d * self.resolved_d_ff
+        if self.num_experts:
+            one = swiglu * d * self.resolved_moe_d_ff
+            ff = (
+                (self.resolved_experts_held + self.num_shared_experts) * one
+                + d * self.num_experts
+                + (self.num_experts if self.router_bias else 0)
+            )
+        else:
+            ff = dense_ff
         embed = v * d + (0 if self.position != "learned" else self.max_seq_len * d)
         head = 0 if self.tie_embeddings else v * d
         linear = self.num_linear_layers
+        dense = self.first_k_dense
+        mtp = self.mtp_depth * (attn + ff + 2 * d * d + 3 * d)
         return (
             (l - linear) * attn + linear * self._linear_mixer_params()
-            + l * ff + embed + head
+            + (l - dense) * ff + dense * dense_ff + embed + head + mtp
         )
 
     def _linear_mixer_params(self) -> int:
@@ -334,11 +507,14 @@ class Mlp(nn.Module):
 
 
 class Block(nn.Module):
-    """One layer: a token mixer of ``kind`` (softmax attention, or the
-    gated delta rule) and an MLP, each on its residual branch."""
+    """One layer: a token mixer of ``kind`` (softmax attention, plain or
+    latent, or the gated delta rule) and an MLP, each on its residual
+    branch.  ``dense_mlp`` makes the MLP the dense one (``d_ff`` wide)
+    though the model's trunk is sparse: a leading dense layer."""
 
     config: TransformerConfig
     kind: str = FULL_ATTENTION
+    dense_mlp: bool = False
 
     @nn.compact
     def __call__(
@@ -371,6 +547,23 @@ class Block(nn.Module):
                 param_dtype=cfg.param_dtype,
                 name="linear_attn",
             )(y)
+        elif cfg.latent_attention:
+            y = LatentAttention(
+                num_heads=cfg.num_heads,
+                q_lora_rank=cfg.q_lora_rank,
+                kv_lora_rank=cfg.kv_lora_rank,
+                qk_nope_head_dim=cfg.qk_nope_head_dim,
+                qk_rope_head_dim=cfg.qk_rope_head_dim,
+                v_head_dim=cfg.v_head_dim,
+                rope_theta=cfg.rope_theta,
+                norm_eps=cfg.norm_eps,
+                dtype=cfg.dtype,
+                param_dtype=cfg.param_dtype,
+                attention_impl=cfg.attention_impl,
+                flash_block_q=cfg.flash_block_q,
+                flash_block_kv=cfg.flash_block_kv,
+                name="attn",
+            )(y, positions, segment_ids)
         else:
             y = Attention(
                 num_heads=cfg.num_heads,
@@ -398,10 +591,10 @@ class Block(nn.Module):
         y = jax.ad_checkpoint.checkpoint_name(y, "attn_out")
         x = x + y
         y = x if post else norm("ln_mlp", x)
-        if cfg.num_experts:
+        if cfg.num_experts and not self.dense_mlp:
             y, layer_aux = MoEMlp(
                 num_experts=cfg.num_experts,
-                d_ff=cfg.resolved_d_ff,
+                d_ff=cfg.resolved_moe_d_ff,
                 top_k=cfg.top_k,
                 capacity_factor=cfg.capacity_factor,
                 activation=cfg.activation,
@@ -410,6 +603,13 @@ class Block(nn.Module):
                 dispatch=cfg.moe_dispatch,
                 norm_topk_prob=cfg.norm_topk_prob,
                 aux_form=cfg.moe_aux_form,
+                scoring=cfg.router_scoring,
+                router_bias=cfg.router_bias,
+                routed_scale=cfg.routed_scaling_factor,
+                experts_held=cfg.experts_held,
+                first_expert=cfg.first_expert,
+                shared_d_ff=cfg.num_shared_experts * cfg.resolved_moe_d_ff,
+                row_budget_multiple=cfg.moe_row_budget,
                 name="moe",
             )(y)
             aux = aux + layer_aux
@@ -472,8 +672,56 @@ class Period(nn.Module):
         return carry, None
 
 
+class MTPModule(nn.Module):
+    """The multi-token-prediction module, depth 1 (DeepSeek-V3 §2.2)::
+
+        h'_i = [RMSNorm(h_i) ; RMSNorm(Emb(t_{i+1}))] W_eh      # 2d -> d
+        one more layer of the trunk's kind (its own attention, router,
+        shared and held experts), then a norm of its own
+
+    ``h`` is the trunk's output BEFORE its final norm, the embedding the
+    model's own; the caller puts the model's shared head on the result,
+    which predicts token ``i + 2``.  Returns ``(hidden, aux)``."""
+
+    config: TransformerConfig
+
+    @nn.compact
+    def __call__(self, hidden, next_embed, positions, segment_ids):
+        cfg = self.config
+
+        def norm(name, y):
+            return layers.make_norm(
+                cfg.norm, cfg.dtype, cfg.param_dtype, name,
+                epsilon=cfg.norm_eps,
+            )(y)
+
+        x = layers.DenseGeneral(
+            cfg.d_model,
+            kernel_axes=(None, lr.EMBED),
+            use_bias=False,
+            dtype=cfg.dtype,
+            param_dtype=cfg.param_dtype,
+            name="proj",
+        )(jnp.concatenate(
+            [norm("hnorm", hidden), norm("enorm", next_embed)], axis=-1
+        ))
+        (x, aux), _ = block_class(cfg, prevent_cse=True)(
+            cfg, FULL_ATTENTION, name="block"
+        )((x, jnp.zeros((), jnp.float32)), positions, segment_ids)
+        return norm("norm", x), aux
+
+
 class TransformerLM(nn.Module):
-    """Decoder-only LM.  ``__call__(tokens) -> (logits, aux_loss)``."""
+    """Decoder-only LM.  ``__call__(tokens) -> (logits, aux_loss)``.
+
+    The trunk is ``first_k_dense`` leading dense layers (``dense_<i>``,
+    applied one by one; under pipelining they run ahead of the stage ring,
+    on the whole batch, as the first stage's input) and then the scanned
+    units (``blocks``).  With ``mtp_depth`` and ``next_tokens`` (the token
+    after each of ``tokens``: the train step's targets) a third value is
+    returned, the MTP module's logits (its normed hidden state under
+    ``return_hidden``), which predict the token after ``next_tokens``;
+    without ``next_tokens`` the call is the two values it always was."""
 
     config: TransformerConfig
 
@@ -484,7 +732,8 @@ class TransformerLM(nn.Module):
         positions: Optional[jax.Array] = None,
         segment_ids: Optional[jax.Array] = None,
         return_hidden: bool = False,
-    ) -> Tuple[jax.Array, jax.Array]:
+        next_tokens: Optional[jax.Array] = None,
+    ) -> Tuple[jax.Array, ...]:
         cfg = self.config
         if cfg.position == "learned" and tokens.shape[1] > cfg.max_seq_len:
             # XLA gather would silently clamp overflow positions to the last
@@ -521,6 +770,14 @@ class TransformerLM(nn.Module):
         # parameters, stacked over the periods).
         unit_cls = Period if cfg.layer_pattern else block_cls
         aux0 = jnp.zeros((), jnp.float32)
+        if cfg.first_k_dense:
+            prefix_cls = block_class(cfg, prevent_cse=True)
+            carry = (x, aux0)
+            for i in range(cfg.first_k_dense):
+                carry, _ = prefix_cls(
+                    cfg, FULL_ATTENTION, True, name=f"dense_{i}"
+                )(carry, positions, segment_ids)
+            x, aux0 = carry
         if cfg.pipeline_stages > 1:
             from dlrover_tpu.parallel.pipeline import PipelinedBlocks
 
@@ -543,11 +800,24 @@ class TransformerLM(nn.Module):
             (x, aux), _ = stack((x, aux0), positions, segment_ids)
         else:
             carry = (x, aux0)
-            for i in range(cfg.num_layers):
+            for i in range(cfg.first_k_dense, cfg.num_layers):
                 carry, _ = block_cls(
                     cfg, cfg.layer_kind(i), name=f"block_{i}"
                 )(carry, positions, segment_ids)
             x, aux = carry
+
+        mtp_hidden = None
+        if cfg.mtp_depth and (
+            next_tokens is not None or self.is_initializing()
+        ):
+            # the module's parameters are made at init whoever calls
+            mtp_hidden, mtp_aux = MTPModule(cfg, name="mtp")(
+                x, embed(tokens if next_tokens is None else next_tokens),
+                positions, segment_ids,
+            )
+            aux = aux + mtp_aux
+            if next_tokens is None:
+                mtp_hidden = None
 
         x = layers.make_norm(
             cfg.norm, cfg.dtype, cfg.param_dtype, "ln_final",
@@ -560,21 +830,33 @@ class TransformerLM(nn.Module):
             # the same scaled logits as the materialized path.
             if cfg.logit_scale != 1.0:
                 x = x * cfg.logit_scale
+                if mtp_hidden is not None:
+                    mtp_hidden = mtp_hidden * cfg.logit_scale
+            if mtp_hidden is not None:
+                return x, aux * cfg.moe_aux_weight, mtp_hidden
             return x, aux * cfg.moe_aux_weight
         if cfg.tie_embeddings:
-            logits = embed.attend(x)
+            head = embed.attend
         else:
-            logits = layers.DenseGeneral(
+            head = layers.DenseGeneral(
                 cfg.vocab_size,
                 kernel_axes=(lr.EMBED, lr.VOCAB),
                 use_bias=False,
                 dtype=cfg.dtype,
                 param_dtype=cfg.param_dtype,
                 name="lm_head",
-            )(x)
-        logits = nn.with_logical_constraint(
-            logits, (lr.BATCH, lr.ACT_SEQ, lr.VOCAB)
-        )
-        if cfg.logit_scale != 1.0:
-            logits = logits * cfg.logit_scale
-        return logits.astype(cfg.logits_dtype), aux * cfg.moe_aux_weight
+            )
+
+        def logits_of(hidden):
+            logits = nn.with_logical_constraint(
+                head(hidden), (lr.BATCH, lr.ACT_SEQ, lr.VOCAB)
+            )
+            if cfg.logit_scale != 1.0:
+                logits = logits * cfg.logit_scale
+            return logits.astype(cfg.logits_dtype)
+
+        if mtp_hidden is not None:
+            with jax.named_scope("mtp/head"):
+                mtp_logits = logits_of(mtp_hidden)
+            return logits_of(x), aux * cfg.moe_aux_weight, mtp_logits
+        return logits_of(x), aux * cfg.moe_aux_weight

@@ -1,4 +1,5 @@
-"""Pallas TPU flash attention: fwd + bwd, causal/segment masks, GQA.
+"""Pallas TPU flash attention: fwd + bwd, causal/segment masks, GQA, and
+values of another width than the keys (``d_qk != d_v``).
 
 Capability ref: the reference's flash-attention integration layer
 (``atorch/atorch/modules/transformer/layers.py:1278-1640``: FA wrappers with
@@ -128,9 +129,12 @@ def _fwd_kernel(
 def _flash_fwd(
     q, k, v, seg_q, seg_kv, *, causal, scale, block_q, block_kv
 ):
-    """q [B,Hq,S,D], k/v [B,Hkv,S,D], seg [B,S] -> (o [B,Hq,S,D], lse)."""
+    """q [B,Hq,S,D], k [B,Hkv,S,D], v [B,Hkv,S,Dv], seg [B,S] ->
+    (o [B,Hq,S,Dv], lse).  ``Dv`` may differ from ``D`` (latent attention:
+    keys of 192, values of 128); the scores contract over ``D``, the
+    accumulator and the output are ``Dv`` wide."""
     b, hq, sq, d = q.shape
-    hkv, skv = k.shape[1], k.shape[2]
+    hkv, skv, d_v = k.shape[1], k.shape[2], v.shape[3]
     group = hq // hkv
     nq, nk = sq // block_q, skv // block_kv
 
@@ -140,7 +144,7 @@ def _flash_fwd(
         block_q=block_q, block_kv=block_kv,
     )
     out_shape = [
-        jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
+        jax.ShapeDtypeStruct((b, hq, sq, d_v), q.dtype),
         jax.ShapeDtypeStruct((b, hq, sq, _STAT), jnp.float32),
     ]
     o, lse = pl.pallas_call(
@@ -157,13 +161,13 @@ def _flash_fwd(
                 lambda ib, ih, iq, ik, g=group: (ib, ih // g, ik, 0),
             ),
             pl.BlockSpec(
-                (1, 1, block_kv, d),
+                (1, 1, block_kv, d_v),
                 lambda ib, ih, iq, ik, g=group: (ib, ih // g, ik, 0),
             ),
         ],
         out_specs=[
             pl.BlockSpec(
-                (1, 1, block_q, d), lambda ib, ih, iq, ik: (ib, ih, iq, 0)
+                (1, 1, block_q, d_v), lambda ib, ih, iq, ik: (ib, ih, iq, 0)
             ),
             pl.BlockSpec(
                 (1, 1, block_q, _STAT),
@@ -173,7 +177,7 @@ def _flash_fwd(
         scratch_shapes=[
             pltpu.VMEM((block_q, _LANE), jnp.float32),
             pltpu.VMEM((block_q, _LANE), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, d_v), jnp.float32),
         ],
         out_shape=out_shape,
         interpret=backend.interpret(),
@@ -375,7 +379,7 @@ def _flash_bwd_fused(
     *, causal, scale, block_q, block_kv
 ):
     b, hq, sq, d = q.shape
-    hkv, skv = k.shape[1], k.shape[2]
+    hkv, skv, d_v = k.shape[1], k.shape[2], v.shape[3]
     group = hq // hkv
     nq, nk = sq // block_q, skv // block_kv
 
@@ -398,17 +402,17 @@ def _flash_bwd_fused(
                 lambda ib, ih, ik, iq, g=group: (ib, ih // g, ik, 0),
             ),
             pl.BlockSpec(
-                (1, 1, block_kv, d),
+                (1, 1, block_kv, d_v),
                 lambda ib, ih, ik, iq, g=group: (ib, ih // g, ik, 0),
             ),
             pl.BlockSpec(
-                (1, 1, block_q, d), lambda ib, ih, ik, iq: (ib, ih, iq, 0)
+                (1, 1, block_q, d_v), lambda ib, ih, ik, iq: (ib, ih, iq, 0)
             ),
             pl.BlockSpec(
                 (1, 1, block_q, _STAT), lambda ib, ih, ik, iq: (ib, ih, iq, 0)
             ),
             pl.BlockSpec(
-                (1, 1, block_q, d), lambda ib, ih, ik, iq: (ib, ih, iq, 0)
+                (1, 1, block_q, d_v), lambda ib, ih, ik, iq: (ib, ih, iq, 0)
             ),
         ],
         out_specs=[
@@ -419,23 +423,23 @@ def _flash_bwd_fused(
                 (1, 1, block_kv, d), lambda ib, ih, ik, iq: (ib, ih, ik, 0)
             ),
             pl.BlockSpec(
-                (1, 1, block_kv, d), lambda ib, ih, ik, iq: (ib, ih, ik, 0)
+                (1, 1, block_kv, d_v), lambda ib, ih, ik, iq: (ib, ih, ik, 0)
             ),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_kv, d), jnp.float32),
-            pltpu.VMEM((block_kv, d), jnp.float32),
+            pltpu.VMEM((block_kv, d_v), jnp.float32),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
             jax.ShapeDtypeStruct((b, hq, skv, d), k.dtype),
-            jax.ShapeDtypeStruct((b, hq, skv, d), v.dtype),
+            jax.ShapeDtypeStruct((b, hq, skv, d_v), v.dtype),
         ],
         interpret=backend.interpret(),
     )(seg_q, seg_kv, q, k, v, do, lse_l, o)
     if group > 1:
         dk = dk.reshape(b, hkv, group, skv, d).sum(axis=2).astype(k.dtype)
-        dv = dv.reshape(b, hkv, group, skv, d).sum(axis=2).astype(v.dtype)
+        dv = dv.reshape(b, hkv, group, skv, d_v).sum(axis=2).astype(v.dtype)
     return dq, dk, dv
 
 
@@ -444,7 +448,7 @@ def _flash_bwd(
     *, causal, scale, block_q, block_kv
 ):
     b, hq, sq, d = q.shape
-    hkv, skv = k.shape[1], k.shape[2]
+    hkv, skv, d_v = k.shape[1], k.shape[2], v.shape[3]
     group = hq // hkv
     nq, nk = sq // block_q, skv // block_kv
 
@@ -471,15 +475,15 @@ def _flash_bwd(
                 lambda ib, ih, iq, ik, g=group: (ib, ih // g, ik, 0),
             ),
             pl.BlockSpec(
-                (1, 1, block_kv, d),
+                (1, 1, block_kv, d_v),
                 lambda ib, ih, iq, ik, g=group: (ib, ih // g, ik, 0),
             ),
             pl.BlockSpec(
-                (1, 1, block_q, d), lambda ib, ih, iq, ik: (ib, ih, iq, 0)
+                (1, 1, block_q, d_v), lambda ib, ih, iq, ik: (ib, ih, iq, 0)
             ),
             lane_spec_q,
             pl.BlockSpec(
-                (1, 1, block_q, d), lambda ib, ih, iq, ik: (ib, ih, iq, 0)
+                (1, 1, block_q, d_v), lambda ib, ih, iq, ik: (ib, ih, iq, 0)
             ),
         ],
         out_specs=pl.BlockSpec(
@@ -508,17 +512,17 @@ def _flash_bwd(
                 lambda ib, ih, ik, iq, g=group: (ib, ih // g, ik, 0),
             ),
             pl.BlockSpec(
-                (1, 1, block_kv, d),
+                (1, 1, block_kv, d_v),
                 lambda ib, ih, ik, iq, g=group: (ib, ih // g, ik, 0),
             ),
             pl.BlockSpec(
-                (1, 1, block_q, d), lambda ib, ih, ik, iq: (ib, ih, iq, 0)
+                (1, 1, block_q, d_v), lambda ib, ih, ik, iq: (ib, ih, iq, 0)
             ),
             pl.BlockSpec(
                 (1, 1, block_q, _STAT), lambda ib, ih, ik, iq: (ib, ih, iq, 0)
             ),
             pl.BlockSpec(
-                (1, 1, block_q, d), lambda ib, ih, ik, iq: (ib, ih, iq, 0)
+                (1, 1, block_q, d_v), lambda ib, ih, ik, iq: (ib, ih, iq, 0)
             ),
         ],
         out_specs=[
@@ -526,22 +530,22 @@ def _flash_bwd(
                 (1, 1, block_kv, d), lambda ib, ih, ik, iq: (ib, ih, ik, 0)
             ),
             pl.BlockSpec(
-                (1, 1, block_kv, d), lambda ib, ih, ik, iq: (ib, ih, ik, 0)
+                (1, 1, block_kv, d_v), lambda ib, ih, ik, iq: (ib, ih, ik, 0)
             ),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_kv, d), jnp.float32),
-            pltpu.VMEM((block_kv, d), jnp.float32),
+            pltpu.VMEM((block_kv, d_v), jnp.float32),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, hq, skv, d), k.dtype),
-            jax.ShapeDtypeStruct((b, hq, skv, d), v.dtype),
+            jax.ShapeDtypeStruct((b, hq, skv, d_v), v.dtype),
         ],
         interpret=backend.interpret(),
     )(*common_in)
     if group > 1:
         dk = dk.reshape(b, hkv, group, skv, d).sum(axis=2).astype(k.dtype)
-        dv = dv.reshape(b, hkv, group, skv, d).sum(axis=2).astype(v.dtype)
+        dv = dv.reshape(b, hkv, group, skv, d_v).sum(axis=2).astype(v.dtype)
     return dq, dk, dv
 
 
@@ -604,6 +608,10 @@ def mha(
     scale: Optional[float] = None,
 ) -> jax.Array:
     """Flash attention on [B, S, H, D] tensors (layout of models/attention).
+
+    ``v`` may be narrower or wider than ``q`` and ``k`` (``[B, S, H, Dv]``,
+    latent attention's 192-wide keys and 128-wide values): the output is
+    ``Dv`` wide and the default ``scale`` is ``D ** -0.5``, the keys'.
 
     ``segment_ids`` [B, S] activates packed-sequence masking: token i attends
     token j only if segment_ids[i] == segment_ids[j] (and j <= i when

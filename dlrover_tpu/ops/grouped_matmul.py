@@ -12,6 +12,17 @@ pattern for ragged work (PrefetchScalarGridSpec).
 
 Group sizes must be multiples of ``block_rows``; the MoE layer guarantees
 this by padding each expert's token group (capacity-style or to the block).
+``x`` may have more rows than ``sum(group_sizes)`` (the caller's static
+row budget).  As a rule those rows meet the last expert's weights: they
+hold zeros, so the work is inert, and where the slack is a few blocks in a
+thousand (every expert held: one block an expert at most) that is the
+fastest form.  With ``skip_dead`` the row blocks after the last live one
+are DEAD instead: they do no matmul, fetch nothing new (their index maps
+stay on the last live block) and come out zero.  That costs every block a
+guard and a little scalar work (3% of the GEMM time at OLMoE's shapes, on
+the chip: PERF.md section 6, PR 33) and pays where the slack is large by
+construction: a chip's share of the experts, whose budget is a multiple of
+the expected rows.
 
 Rows may come and go *row-tiled*, ``[N, K // 128, 128]`` for ``[N, K]``: in
 that view a row is whole native tiles, contiguous in HBM, which is the form
@@ -78,19 +89,28 @@ def _plain(ref):
     return block.reshape(block.shape[0], -1) if block.ndim == 3 else block
 
 
-def _gmm_kernel(expert_of_block, x_ref, w_ref, out_ref, acc_ref):
+def _gmm_kernel(*refs, skip_dead):
+    live_blocks = refs[1] if skip_dead else None
+    x_ref, w_ref, out_ref, acc_ref = refs[-4:]
     kk = pl.program_id(2)
 
     @pl.when(kk == 0)
     def _():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    acc_ref[:] += jax.lax.dot(
-        _plain(x_ref), w_ref[0], preferred_element_type=jnp.float32
-    )
+    def accumulate():
+        acc_ref[:] += jax.lax.dot(
+            _plain(x_ref), w_ref[0], preferred_element_type=jnp.float32
+        )
+
+    if skip_dead:
+        pl.when(pl.program_id(1) < live_blocks[0])(accumulate)
+    else:
+        accumulate()
 
     @pl.when(kk == pl.num_programs(2) - 1)
     def _():
+        # a dead block writes the zeros its accumulator still holds
         out_ref[...] = acc_ref[:].astype(out_ref.dtype).reshape(out_ref.shape)
 
 
@@ -103,26 +123,50 @@ def _expert_of_block(group_sizes, num_blocks, block_rows):
     return jnp.minimum(eob, group_sizes.shape[0] - 1).astype(jnp.int32)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _block_plan(group_sizes, num_blocks, block_rows, skip_dead):
+    """The kernels' prefetched scalars: ``(expert_of_block,)``, or with
+    ``skip_dead`` ``(expert_of_block, live_blocks [1])``: how many row
+    blocks hold rows of a group, the dead blocks after them taking the
+    last live block's expert so that no index map moves on them."""
+    eob = _expert_of_block(group_sizes, num_blocks, block_rows)
+    if not skip_dead:
+        return (eob,)
+    live = (jnp.sum(group_sizes) // block_rows).astype(jnp.int32)
+    last = eob[jnp.maximum(live - 1, 0)]
+    eob = jnp.where(jnp.arange(num_blocks) < live, eob, last)
+    return eob, live.reshape(1)
+
+
+def _live(i, scalars):
+    """The row block the index maps give for grid row ``i``: itself, or
+    under ``skip_dead`` (a second scalar) the last live one where ``i`` is
+    dead."""
+    if len(scalars) == 1:
+        return i
+    return jnp.minimum(i, jnp.maximum(scalars[1][0] - 1, 0))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def grouped_matmul(
     x: jax.Array,           # [N, K] rows sorted by expert, or row-tiled
     w: jax.Array,           # [E, K, M]
-    group_sizes: jax.Array, # [E] int32, sum == N, multiples of block_rows
+    group_sizes: jax.Array, # [E] int32, sum <= N, multiples of block_rows
     block_rows: int = 128,
     out_tiled: bool = False,
+    skip_dead: bool = False,
 ) -> jax.Array:
     """Returns [N, M] where out[r] = x[r] @ w[expert_of_row(r)]; with
     ``out_tiled`` the same rows as ``[N, M // 128, 128]``.  ``dx`` comes
-    back in the form ``x`` came in."""
-    return _gmm_fwd_impl(x, w, group_sizes, block_rows, out_tiled)
+    back in the form ``x`` came in.  ``skip_dead``: the module docstring."""
+    return _gmm_fwd_impl(x, w, group_sizes, block_rows, out_tiled, skip_dead)
 
 
-def _gmm_fwd_impl(x, w, group_sizes, block_rows, out_tiled):
+def _gmm_fwd_impl(x, w, group_sizes, block_rows, out_tiled, skip_dead):
     n = x.shape[0]
     e, k, m = w.shape
     assert n % block_rows == 0, f"N={n} not a multiple of {block_rows}"
     num_blocks = n // block_rows
-    expert_of_block = _expert_of_block(group_sizes, num_blocks, block_rows)
+    scalars = _block_plan(group_sizes, num_blocks, block_rows, skip_dead)
 
     # Output columns outermost, contraction innermost.  While the whole K
     # fits (tk == k) an expert's [K, tm] strip stays resident across its
@@ -130,40 +174,50 @@ def _gmm_fwd_impl(x, w, group_sizes, block_rows, out_tiled):
     tile_elems = _TILE_BYTES // (2 * jnp.dtype(w.dtype).itemsize)
     tm = _lane_tile(m, tile_elems // k, _quantum(out_tiled, x.dtype))
     tk = _lane_tile(k, tile_elems // tm, _quantum(x.ndim == 3, x.dtype))
+    last_k = k // tk - 1
+
+    def k_of(i, kk, s):
+        # a dead block stays on the contraction tile the last live one ended on
+        return kk if len(s) == 1 else jnp.where(i < s[1][0], kk, last_k)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=len(scalars),
         grid=(m // tm, num_blocks, k // tk),
         in_specs=[
             _row_block(
-                x.ndim == 3, block_rows, tk, lambda j, i, kk, eob: (i, kk)
+                x.ndim == 3, block_rows, tk,
+                lambda j, i, kk, *s: (_live(i, s), k_of(i, kk, s)),
             ),
             pl.BlockSpec(
-                (1, tk, tm), lambda j, i, kk, eob: (eob[i], kk, j),
+                (1, tk, tm), lambda j, i, kk, *s: (s[0][i], k_of(i, kk, s), j),
                 memory_space=pltpu.VMEM,
             ),
         ],
         out_specs=_row_block(
-            out_tiled, block_rows, tm, lambda j, i, kk, eob: (i, j)
+            out_tiled, block_rows, tm, lambda j, i, kk, *s: (i, j)
         ),
         scratch_shapes=[pltpu.VMEM((block_rows, tm), jnp.float32)],
     )
     return pl.pallas_call(
-        _gmm_kernel,
+        functools.partial(_gmm_kernel, skip_dead=skip_dead),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(
             (n, m // LANES, LANES) if out_tiled else (n, m), x.dtype
         ),
         interpret=backend.interpret(),
-    )(expert_of_block, x, w)
+    )(*scalars, x, w)
 
 
-def _gmm_dw_kernel(eob_ref, x_ref, dy_ref, dw_ref, acc_ref):
+def _gmm_dw_kernel(*refs, skip_dead):
     """Accumulate x_block^T @ dy_block into the owning expert's dw tile.
 
     Row blocks of one expert are consecutive (rows sorted by expert), so the
     expert's output tile stays resident across its run of grid steps; the
-    accumulator resets at each expert boundary.
+    accumulator resets at each expert boundary.  Under ``skip_dead`` the
+    dead blocks carry the last live block's expert and add nothing.
     """
+    eob_ref = refs[0]
+    x_ref, dy_ref, dw_ref, acc_ref = refs[-4:]
     i = pl.program_id(2)
     last_i = pl.num_programs(2) - 1
     first = jnp.logical_or(i == 0, eob_ref[i] != eob_ref[jnp.maximum(i - 1, 0)])
@@ -175,32 +229,39 @@ def _gmm_dw_kernel(eob_ref, x_ref, dy_ref, dw_ref, acc_ref):
     def _():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    acc_ref[:] += jax.lax.dot_general(
-        _plain(x_ref), _plain(dy_ref), (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    def accumulate():
+        acc_ref[:] += jax.lax.dot_general(
+            _plain(x_ref), _plain(dy_ref), (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+
+    if skip_dead:
+        pl.when(i < refs[1][0])(accumulate)
+    else:
+        accumulate()
 
     @pl.when(last)
     def _():
         dw_ref[0] = acc_ref[:].astype(dw_ref.dtype)
 
 
-def _gmm_fwd(x, w, group_sizes, block_rows, out_tiled):
-    out = _gmm_fwd_impl(x, w, group_sizes, block_rows, out_tiled)
+def _gmm_fwd(x, w, group_sizes, block_rows, out_tiled, skip_dead):
+    out = _gmm_fwd_impl(x, w, group_sizes, block_rows, out_tiled, skip_dead)
     return out, (x, w, group_sizes)
 
 
-def _gmm_bwd(block_rows, out_tiled, residuals, dy):
+def _gmm_bwd(block_rows, out_tiled, skip_dead, residuals, dy):
     x, w, group_sizes = residuals
     n = x.shape[0]
     e, k, m = w.shape
     num_blocks = n // block_rows
     # dx: grouped matmul against w^T, in the form x came in.
     dx = _gmm_fwd_impl(
-        dy, jnp.swapaxes(w, 1, 2), group_sizes, block_rows, x.ndim == 3
+        dy, jnp.swapaxes(w, 1, 2), group_sizes, block_rows, x.ndim == 3,
+        skip_dead,
     ).astype(x.dtype)
     # dw: per-expert accumulation over that expert's row blocks.
-    eob = _expert_of_block(group_sizes, num_blocks, block_rows)
+    scalars = _block_plan(group_sizes, num_blocks, block_rows, skip_dead)
     # Tiles of dw outermost, row blocks innermost: the f32 accumulator and
     # the double-buffered output block hold one [tk, tm] tile across an
     # expert's consecutive row blocks.
@@ -208,28 +269,33 @@ def _gmm_bwd(block_rows, out_tiled, residuals, dy):
     tm = _lane_tile(m, tile_elems // k, _quantum(out_tiled, x.dtype))
     tk = _lane_tile(k, tile_elems // tm, _quantum(x.ndim == 3, x.dtype))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=len(scalars),
         grid=(k // tk, m // tm, num_blocks),
         in_specs=[
             _row_block(
-                x.ndim == 3, block_rows, tk, lambda a, j, i, eob: (i, a)
+                x.ndim == 3, block_rows, tk,
+                lambda a, j, i, *s: (_live(i, s), a),
             ),
-            _row_block(out_tiled, block_rows, tm, lambda a, j, i, eob: (i, j)),
+            _row_block(
+                out_tiled, block_rows, tm,
+                lambda a, j, i, *s: (_live(i, s), j),
+            ),
         ],
         out_specs=pl.BlockSpec(
-            (1, tk, tm), lambda a, j, i, eob: (eob[i], a, j),
+            (1, tk, tm), lambda a, j, i, *s: (s[0][i], a, j),
             memory_space=pltpu.VMEM,
         ),
         scratch_shapes=[pltpu.VMEM((tk, tm), jnp.float32)],
     )
     dw = pl.pallas_call(
-        _gmm_dw_kernel,
+        functools.partial(_gmm_dw_kernel, skip_dead=skip_dead),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((e, k, m), w.dtype),
         interpret=backend.interpret(),
-    )(eob, x, dy)
-    # An expert with no rows is never visited and the kernel leaves its dw
-    # block unwritten: that expert's gradient is zero.
+    )(*scalars, x, dy)
+    # An expert with no rows is never visited (or only by dead blocks) and
+    # the kernel leaves its dw block unwritten: that expert's gradient is
+    # zero.
     dw = jnp.where((group_sizes > 0)[:, None, None], dw, 0.0).astype(w.dtype)
     return dx, dw, None
 

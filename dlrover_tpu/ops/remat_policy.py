@@ -19,7 +19,10 @@ The saveable names are emitted by the model code via
 (transformer.py Block), ``flash_out`` / ``flash_lse``
 (ops/flash_attention.py custom_vjp fwd — flash impl only), ``delta_out``
 (models/linear_attention.py: the gated delta rule's output; a model
-without such a layer emits no such name, and its step is what it was).
+without such a layer emits no such name, and its step is what it was),
+``latent_k`` / ``latent_v`` (models/attention.py ``LatentAttention``: the
+per-head keys and values rebuilt from the latent row; NO registered policy
+keeps them).
 
 What ``flash_only`` keeps of a linear layer is that output alone.  The
 rule's backward kernel also reads the state each chunk starts from, and
@@ -27,6 +30,13 @@ the forward kernel writes those again under the remat: keeping them too
 (no second forward) was the slower step on the chip, because the compiler
 then made room by recomputing two projections, and keeping neither let it
 pick slower layouts around the rule (PERF.md §6, PR 32).
+
+What ``flash_only`` keeps of a latent-attention layer is the flash
+kernel's output and log-sum-exp rows, as of any attention layer.  The
+per-head k and v (``[B, S, H, 192 + 128]``, 335 MB a layer at 2 x 8192
+tokens) are rebuilt in the backward from the 576-wide latent row: keeping
+them too moved the step by -0.05% on the chip (PERF.md §6, PR 33), and the
+memory buys a layer instead.
 """
 
 from __future__ import annotations

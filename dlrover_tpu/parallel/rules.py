@@ -49,6 +49,7 @@ EMBED = "embed"            # param embedding dim (FSDP shard dim)
 MLP = "mlp"                # param MLP hidden dim (TP col split)
 HEADS = "heads"            # param attention heads dim (TP split)
 KV = "kv"                  # param per-head dim
+LATENT = "latent"          # latent attention's low-rank dim (q 1536, kv 512+64)
 VOCAB = "vocab"            # param vocab dim (TP vocab split)
 EXPERT = "expert"          # param expert dim (EP shard dim)
 LAYERS = "layers"          # scanned layer dim (within one pipeline stage)
@@ -82,6 +83,9 @@ def make_rules(
         (BATCH, (DATA_AXIS, FSDP_AXIS)),
         (ACT_EMBED, TENSOR_AXIS),
         (KV, None),
+        # A latent is whole on every device: its down-projection contracts
+        # the (fsdp-sharded) embed dim, its up-projection splits by heads.
+        (LATENT, None),
         (NORM, None),
         (GATHERED, None),
     ]

@@ -45,7 +45,9 @@ from dlrover_tpu.utils.profiler import pipeline_counters
 _NO_BATCH = object()
 # The step metrics that are vectors, not scalars: they stay on the device
 # until a report reads them.
-_STATS_KEYS = ("moe_stats", linear_attention.STATS_NAME)
+_STATS_KEYS = (
+    "moe_stats", moe_lib.SHARE_STATS_NAME, linear_attention.STATS_NAME,
+)
 
 _PROCESS_START_BOOKED = False
 
@@ -1249,10 +1251,18 @@ class ElasticTrainer:
             # ships below): the vector this step's program returned, so
             # of the parameters the step routed with, ready when its loss
             # is.  Layout: models/moe.py ``split_stats``.
+            # A layer told its share of the experts (or a router bias)
+            # hands [pairs_here, bias_absmax] out beside the vector; any
+            # other computes every pair it routes and has no bias.
+            fetch = [moe_stats, metrics.get(moe_lib.SHARE_STATS_NAME)]
             with pipeline_counters().host_block("moe_stats", steps=(step,)):
-                vec = np.asarray(jax.device_get(moe_stats), np.float64)
+                vec, share = jax.device_get(fetch)
+            vec = np.asarray(vec, np.float64)
             entropy, drop, load, pad_share, max_load = (
                 moe_lib.split_stats(vec)
+            )
+            pairs_here, bias_absmax = (
+                (1.0, 0.0) if share is None else np.asarray(share, np.float64)
             )
             telemetry.event(
                 "moe", step=step,
@@ -1263,6 +1273,17 @@ class ElasticTrainer:
                 load=json.dumps([round(float(v), 6) for v in load]),
                 pad_share=float(pad_share),
                 max_expert_load=float(max_load),
+                experts_total=int(load.size),
+                held=int(self.model_config.resolved_experts_held),
+                pairs_here=float(pairs_here),
+                bias_absmax=float(bias_absmax),
+            )
+        if "mtp_loss" in metrics and step % cfg.report_every == 0:
+            # The multi-token-prediction module's own cross-entropy (token
+            # i + 2 from position i), beside the main loss it trains with.
+            telemetry.event(
+                "mtp", step=step, mtp_loss=float(metrics["mtp_loss"]),
+                weight=float(self.model_config.mtp_weight),
             )
         if self.client is not None:
             self.client.report_step(

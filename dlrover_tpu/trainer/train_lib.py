@@ -25,6 +25,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from dlrover_tpu.common.log import default_logger as logger
 from dlrover_tpu.models import linear_attention
+from dlrover_tpu.models import moe as moe_lib
 from dlrover_tpu.parallel import rules as lr
 
 
@@ -429,19 +430,69 @@ def _sown_vectors(sown, name: str) -> Optional[jax.Array]:
     )
 
 
-def _layer_stats(sown) -> Dict[str, jax.Array]:
+ROUTER_LOADS = "router_loads"
+
+
+def _router_loads(sown) -> Dict[Tuple[str, ...], jax.Array]:
+    """Each expert layer's own per-expert loads ``[..., E]`` (the ``load``
+    of its ``moe_stats``), by the module path that sowed them: the same
+    path holds the layer's ``router_bias`` in ``params``."""
+    out = {}
+
+    def walk(node, path):
+        if not isinstance(node, dict):
+            return
+        if "moe_stats" in node:
+            out[path] = moe_lib.split_stats(
+                jax.lax.stop_gradient(node["moe_stats"][0])
+            )[2]
+        for key, child in node.items():
+            walk(child, path + (key,))
+
+    walk(dict(sown).get("intermediates", {}), ())
+    return out
+
+
+def _layer_stats(sown, router_loads: bool = False) -> Dict[str, Any]:
     """What a step hands out of the layers' sown vectors, by metric name:
-    ``moe_stats`` (mean over the layers) and ``linear_attn_stats``
-    (``linear_attention.fold_stats``: means, and the largest state
-    entry).  Empty for a model whose layers sow neither."""
+    ``moe_stats`` (mean over the layers), ``moe_share_stats`` (mean share
+    of the routed pairs computed here, largest router-bias entry) and
+    ``linear_attn_stats`` (``linear_attention.fold_stats``: means, and the
+    largest state entry).  Empty for a model whose layers sow none.
+    ``router_loads`` adds each expert layer's own loads (``ROUTER_LOADS``,
+    by module path) for the router-bias rule."""
     out = {}
     moe = _sown_vectors(sown, "moe_stats")
     if moe is not None:
         out["moe_stats"] = jnp.mean(moe, axis=0)
+    share = _sown_vectors(sown, moe_lib.SHARE_STATS_NAME)
+    if share is not None:
+        out[moe_lib.SHARE_STATS_NAME] = moe_lib.fold_share_stats(share)
     linear = _sown_vectors(sown, linear_attention.STATS_NAME)
     if linear is not None:
         out[linear_attention.STATS_NAME] = linear_attention.fold_stats(linear)
+    if router_loads:
+        out[ROUTER_LOADS] = _router_loads(sown)
     return out
+
+
+def move_router_bias(old_params, new_params, loads, rate: float):
+    """``new_params`` with every ``router_bias`` set to the OLD one moved
+    by the balancing rule (``moe.bias_update``) on that layer's own loads
+    of this step: whatever the optimizer made of the leaf (its gradient is
+    zero, but weight decay or a parameter-scaled update need not be) is
+    discarded.  ``loads``: ``ROUTER_LOADS`` of :func:`_layer_stats`."""
+    def moved(old, new, path, load):
+        if not path:
+            return dict(new, router_bias=moe_lib.bias_update(
+                old["router_bias"], load, rate
+            ))
+        key = path[0]
+        return dict(new, **{key: moved(old[key], new[key], path[1:], load)})
+
+    for path, load in loads.items():
+        new_params = moved(old_params, new_params, path, load)
+    return new_params
 
 
 def build_sharded_train(
@@ -690,30 +741,58 @@ def build_sharded_train(
         getattr(model_config, "num_experts", 0)
         or "linear_attention" in getattr(model_config, "layer_pattern", ())
     )
+    # The DeepSeek-V3 family: a multi-token-prediction module whose
+    # cross-entropy joins the loss, and router biases the step moves.
+    mtp_weight = (
+        float(getattr(model_config, "mtp_weight", 0.0))
+        if getattr(model_config, "mtp_depth", 0) else 0.0
+    )
+    bias_rate = (
+        float(getattr(model_config, "router_bias_rate", 0.0))
+        if getattr(model_config, "router_bias", False) else 0.0
+    )
 
     def _forward_sums(params, apply_fn, inputs, targets, weights):
         """One forward pass -> (weighted CE sum, token count, aux loss,
         layer statistics).  The last is ``_layer_stats`` of what the
         layers sowed (``moe_stats``, ``linear_attn_stats``), folded over
         layers and whatever axes the scan and the sow stack, under
-        ``stop_gradient``; empty for a model that sows none."""
+        ``stop_gradient``; empty for a model that sows none.  A model with
+        an MTP module is handed the targets as the next tokens, and the
+        weighted sum of its cross-entropy (token ``i + 2`` from position
+        ``i``, the last position masked) rides the statistics as
+        ``mtp_ce_sum``."""
         kwargs = {"return_hidden": True} if ce_chunks else {}
+        if mtp_weight:
+            kwargs["next_tokens"] = targets
         variables = {"params": params}
         if sows_stats:
-            (out, aux), sown = apply_fn(
+            outs, sown = apply_fn(
                 variables, inputs, mutable=["intermediates"], **kwargs
             )
-            stats = _layer_stats(sown)
+            stats = _layer_stats(sown, router_loads=bool(bias_rate))
         else:
-            out, aux = apply_fn(variables, inputs, **kwargs)
+            outs = apply_fn(variables, inputs, **kwargs)
             stats = {}
-        if ce_chunks:
-            ce, total_weight = chunked_cross_entropy_loss(
-                out, output_head(params), targets, weights,
-                num_chunks=ce_chunks,
+        out, aux = outs[:2]
+
+        def ce_of(out, targets, weights):
+            if ce_chunks:
+                return chunked_cross_entropy_loss(
+                    out, output_head(params), targets, weights,
+                    num_chunks=ce_chunks,
+                )
+            return cross_entropy_loss(out, targets, weights)
+
+        ce, total_weight = ce_of(out, targets, weights)
+        if mtp_weight:
+            # position i predicts targets[i + 1]; the last has none
+            mtp_ce, mtp_total = ce_of(
+                outs[2], jnp.roll(targets, -1, axis=1),
+                jnp.pad(weights[:, 1:], ((0, 0), (0, 1))),
             )
-        else:
-            ce, total_weight = cross_entropy_loss(out, targets, weights)
+            stats["mtp_ce_sum"] = mtp_ce * mtp_total
+            stats["mtp_tokens"] = mtp_total
         return ce * total_weight, total_weight, aux, stats
 
     def _q_reduce_scatter_leaf(leaf, z_sharding, full_sharding):
@@ -865,6 +944,27 @@ def build_sharded_train(
             opt_state=new_opt_state,
         )
 
+    def _family_update(state, new_state, stats):
+        """After the optimizer: each router bias moved by the balancing
+        rule on this step's own loads (no gradient reaches it)."""
+        if not bias_rate:
+            return new_state
+        return new_state.replace(params=move_router_bias(
+            state.params, new_state.params, stats[ROUTER_LOADS], bias_rate
+        ))
+
+    def _family_metrics(stats):
+        """The step's metrics out of ``_forward_sums``' statistics: the
+        layers' vectors as they are, the MTP module's cross-entropy as
+        ``mtp_loss``; the routers' loads stay inside the step."""
+        out = {
+            k: v for k, v in stats.items()
+            if k not in (ROUTER_LOADS, "mtp_ce_sum", "mtp_tokens")
+        }
+        if "mtp_ce_sum" in stats:
+            out["mtp_loss"] = stats["mtp_ce_sum"] / stats["mtp_tokens"]
+        return out
+
     def _train_step(state: TrainState, batch: Dict[str, jax.Array]):
         TRACE_COUNTS["train_step"] += 1
 
@@ -874,7 +974,12 @@ def build_sharded_train(
                 batch["weights"],
             )
             ce = ce_sum / total_weight
-            return ce + aux, (ce, aux, total_weight, stats)
+            loss = ce + aux
+            if mtp_weight:
+                loss = loss + mtp_weight * (
+                    stats["mtp_ce_sum"] / stats["mtp_tokens"]
+                )
+            return loss, (ce, aux, total_weight, stats)
 
         grads, (ce, aux, total_weight, stats) = jax.grad(
             loss_fn, has_aux=True
@@ -886,6 +991,7 @@ def build_sharded_train(
             # still back-propagating.
             grads = _scatter_grads(grads)
         new_state = _apply_update(state, grads, scattered=overlap_active)
+        new_state = _family_update(state, new_state, stats)
         metrics = {
             "loss": ce,
             "aux_loss": aux,
@@ -893,7 +999,7 @@ def build_sharded_train(
             "grad_norm": optax.global_norm(grads),
             "step": new_state.step,
         }
-        metrics.update(stats)
+        metrics.update(_family_metrics(stats))
         return new_state, metrics
 
     def _accum_train_step(state: TrainState, batch: Dict[str, jax.Array]):
@@ -923,6 +1029,11 @@ def build_sharded_train(
             batch["weights"].astype(jnp.float32).sum(), 1.0
         )
 
+        # the MTP module's own count: every position but a row's last
+        mtp_total = jnp.maximum(
+            batch["weights"][:, 1:].astype(jnp.float32).sum(), 1.0
+        )
+
         def micro_loss(params, mb):
             ce_sum, _w, aux, stats = _forward_sums(
                 params, state.apply_fn, mb["inputs"], mb["targets"],
@@ -930,9 +1041,10 @@ def build_sharded_train(
             )
             # aux (model-internal regularizers) is a per-microbatch mean:
             # average it over N so its gradient scale matches full-batch.
-            return ce_sum / w_total + aux / grad_accum, (
-                ce_sum, aux, stats
-            )
+            loss = ce_sum / w_total + aux / grad_accum
+            if mtp_weight:
+                loss = loss + mtp_weight * stats["mtp_ce_sum"] / mtp_total
+            return loss, (ce_sum, aux, stats)
 
         params_shardings = state_shardings.params
         # Overlap: the accumulator lives in the 1/dp zero1 shard layout
@@ -1001,6 +1113,12 @@ def build_sharded_train(
             lambda g, p: g.astype(p.dtype), grads, state.params
         )
         new_state = _apply_update(state, grads, scattered=overlap_active)
+        if ROUTER_LOADS in stats:
+            # the step's loads are the microbatches' together
+            stats[ROUTER_LOADS] = jax.tree.map(
+                lambda load: load.mean(axis=0), stats[ROUTER_LOADS]
+            )
+        new_state = _family_update(state, new_state, stats)
         metrics = {
             "loss": ce_sum / w_total,
             "aux_loss": aux_sum / grad_accum,
@@ -1010,6 +1128,12 @@ def build_sharded_train(
         }
         if "moe_stats" in stats:
             metrics["moe_stats"] = stats["moe_stats"].mean(axis=0)
+        if moe_lib.SHARE_STATS_NAME in stats:
+            metrics[moe_lib.SHARE_STATS_NAME] = moe_lib.fold_share_stats(
+                stats[moe_lib.SHARE_STATS_NAME]
+            )
+        if "mtp_ce_sum" in stats:
+            metrics["mtp_loss"] = stats["mtp_ce_sum"].sum() / mtp_total
         if linear_attention.STATS_NAME in stats:
             metrics[linear_attention.STATS_NAME] = (
                 linear_attention.fold_stats(

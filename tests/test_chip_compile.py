@@ -89,11 +89,13 @@ def _norm_grad(name, with_bias):
     return jax.grad(loss, argnums=tuple(range(2 + with_bias)))
 
 
-def _grouped_matmul(out_tiled=False):
+def _grouped_matmul(out_tiled=False, skip_dead=False):
     from dlrover_tpu.ops.grouped_matmul import grouped_matmul
 
     def loss(x, w, sizes):
-        return grouped_matmul(x, w, sizes, 128, out_tiled).astype(F32).sum()
+        return grouped_matmul(
+            x, w, sizes, 128, out_tiled, skip_dead
+        ).astype(F32).sum()
 
     # value_and_grad keeps the forward kernel live beside dx and dw.
     return jax.value_and_grad(loss, argnums=(0, 1))
@@ -146,6 +148,10 @@ CACHE = ((65536, 128), F32)
 CASES = [
     ("flash_fwd_fused_bwd", lambda: _flash(1024), [QKV_1P5B] * 3, {}, 2),
     ("flash_split_bwd", lambda: _flash(1024), [QKV_LONG] * 3, {}, 3),
+    # JoyAI-LLM-Flash's latent attention: 2 x 8192 tokens, 32 heads, keys
+    # 192 wide (1.5 lane groups) and values 128; eight kv blocks
+    ("flash_latent_192_128", lambda: _flash(1024),
+     [((2, 8192, 32, 192), BF16)] * 2 + [((2, 8192, 32, 128), BF16)], {}, 3),
     ("fused_layernorm", lambda: _norm_grad("fused_layernorm", True),
      [((16, 1024, 1600), BF16), ((1600,), F32), ((1600,), F32)], {}, 1),
     ("fused_rmsnorm", lambda: _norm_grad("fused_rmsnorm", False),
@@ -172,6 +178,17 @@ CASES = [
      {}, 3),
     ("grouped_matmul_mixtral_wo_out_tiled", lambda: _grouped_matmul(True),
      [((50176, 14336), BF16), ((8, 14336, 4096), BF16), ((8,), I32)], {}, 3),
+    # JoyAI-LLM-Flash's share: 32 held experts of 768, a budget of 24,704
+    # rows (1.25 x the expected 16,384 + a block an expert + the zero
+    # block) whose dead blocks the kernels skip; rows tiled in and out
+    ("grouped_matmul_share_wi_rows_tiled",
+     lambda: _grouped_matmul(False, True),
+     [((24704, 16, 128), BF16), ((32, 2048, 768), BF16), ((32,), I32)],
+     {}, 3),
+    ("grouped_matmul_share_wo_out_tiled", lambda: _grouped_matmul(True, True),
+     [((24704, 768), BF16), ((32, 768, 2048), BF16), ((32,), I32)], {}, 3),
+    ("row_gather_sum_share_weighted", _row_gather_sum,
+     [((24704, 16, 128), BF16), ((16384, 8), I32), ((16384, 8), F32)], {}, 1),
     # a token's 8 of the 139264 rows fetched and summed, OLMoE's combine
     ("row_gather_sum_olmoe_weighted", _row_gather_sum,
      [((139264, 16, 128), BF16), ((16384, 8), I32), ((16384, 8), F32)], {}, 1),

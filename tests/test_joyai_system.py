@@ -1,0 +1,360 @@
+"""JoyAI-LLM-Flash's parts through the rest of the system, one small CPU
+test each: the train step's loss, metrics and router-bias rule against the
+reference, the microbatch engine, a Flash Checkpoint save and restore of
+the bias, the dense prefix under a two-stage pipeline, the ``moe`` and
+``mtp`` events with their gauges."""
+
+import dataclasses
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from dlrover_tpu.models import moe as moe_lib
+from dlrover_tpu.models.joyai_llm_flash import joyai_llm_flash_config
+from dlrover_tpu.models.references import joyai_llm_flash as ref
+from dlrover_tpu.models.transformer import TransformerLM
+from dlrover_tpu.parallel import rules as lr
+from dlrover_tpu.runtime.mesh import ParallelConfig, build_mesh
+from dlrover_tpu.trainer import train_lib
+
+SEQ, BATCH, VOCAB = 32, 8, 256
+
+SMALL = dict(
+    vocab_size=VOCAB, num_layers=3, d_model=64, num_heads=4, d_ff=96,
+    max_seq_len=SEQ, q_lora_rank=48, kv_lora_rank=32, qk_nope_head_dim=16,
+    qk_rope_head_dim=8, v_head_dim=16, num_experts=16, top_k=4, moe_d_ff=32,
+    experts_held=4, first_expert=4, moe_row_budget=2.0, rope_theta=1e4,
+    dtype=jnp.float32, param_dtype=jnp.float32,
+)
+
+
+def config(**overrides):
+    return joyai_llm_flash_config(**{**SMALL, **overrides})
+
+
+def batches(n, seed=0):
+    rng = np.random.default_rng(seed)
+    rows = rng.integers(0, VOCAB, (n, BATCH, SEQ + 1), dtype=np.int32)
+    return [{"inputs": r[:, :-1], "targets": r[:, 1:]} for r in rows]
+
+
+def build(cfg=None, devices=1, parallel=None, optimizer="sgd", **kw):
+    mesh = build_mesh(
+        parallel or ParallelConfig(data=-1), devices=jax.devices()[:devices]
+    )
+    return train_lib.build_sharded_train(
+        TransformerLM(cfg or config()),
+        train_lib.make_optimizer(optimizer, learning_rate=1e-2),
+        mesh, lr.DEFAULT_RULES, global_batch_size=BATCH, seq_len=SEQ, **kw,
+    )
+
+
+def biases(params):
+    return {
+        "/".join(k.key for k in path): np.asarray(leaf)
+        for path, leaf in jax.tree_util.tree_leaves_with_path(params)
+        if path[-1].key == "router_bias"
+    }
+
+
+def test_the_step_trains_the_sum_reports_the_parts_and_moves_the_bias():
+    """One step against the reference: ``loss`` is the main cross-entropy,
+    ``mtp_loss`` the module's, the gradient is of their weighted sum, and
+    each layer's bias moves by the rule on that layer's own counts."""
+    cfg = config()
+    train = build(cfg)
+    state = train.init(jax.random.PRNGKey(0))
+    params = jax.tree.map(np.asarray, state.params)
+    batch = batches(1)[0]
+    with jax.default_matmul_precision("highest"):
+        new_state, metrics = train.step(
+            state, train_lib.shard_batch(batch, train)
+        )
+    fields = dataclasses.asdict(cfg)
+    inputs, targets = jnp.asarray(batch["inputs"]), jnp.asarray(
+        batch["targets"]
+    )
+    want = ref.forward(fields, params, inputs, targets)
+    assert float(metrics["loss"]) == pytest.approx(
+        float(want["nll"].mean()), abs=1e-4
+    )
+    assert float(metrics["mtp_loss"]) == pytest.approx(
+        float(want["mtp_nll"].mean()), abs=1e-4
+    )
+    assert float(metrics["aux_loss"]) == 0.0
+    _, grads = ref.loss_and_grads(fields, params, inputs, targets)
+    # plain SGD, clipped at global norm 1: the update is the gradient's
+    # direction, the reference's
+    norm = float(metrics["grad_norm"])
+    want_norm = float(jnp.sqrt(sum(
+        jnp.sum(g * g) for g in jax.tree.leaves(grads)
+    )))
+    assert norm == pytest.approx(want_norm, rel=1e-4)
+    moved = biases(new_state.params)
+    assert sorted(moved) == [
+        "blocks/moe/router_bias", "mtp/block/moe/router_bias"
+    ]
+    trunk = np.stack([np.asarray(c) for c in want["counts"][:2]])
+    np.testing.assert_allclose(
+        moved["blocks/moe/router_bias"],
+        np.stack([
+            np.asarray(ref.bias_rule(jnp.zeros(16), c, cfg.router_bias_rate))
+            for c in trunk
+        ]), atol=1e-7,
+    )
+    np.testing.assert_allclose(
+        moved["mtp/block/moe/router_bias"],
+        ref.bias_rule(jnp.zeros(16), want["counts"][2], cfg.router_bias_rate),
+        atol=1e-7,
+    )
+    share = np.asarray(metrics[moe_lib.SHARE_STATS_NAME])
+    here = np.mean([float(c[4:8].sum() / c.sum()) for c in want["counts"]])
+    assert share[0] == pytest.approx(here, rel=1e-5) and share[1] == 0.0
+    _, drop, load, _, _ = moe_lib.split_stats(np.asarray(metrics["moe_stats"]))
+    assert drop == 0.0 and load.shape == (16,)
+
+
+@pytest.mark.parametrize("optimizer", ["adafactor", "adamw"])
+def test_no_optimizer_moves_the_bias_only_the_rule_does(optimizer):
+    train = build(optimizer=optimizer)
+    state = train.init(jax.random.PRNGKey(0))
+    for i, batch in enumerate(batches(3), start=1):
+        state, metrics = train.step(
+            state, train_lib.shard_batch(batch, train)
+        )
+        for name, bias in biases(state.params).items():
+            # every entry has moved by whole steps of the rate, each way
+            steps = bias / 0.001
+            np.testing.assert_allclose(steps, np.rint(steps), atol=1e-3)
+            assert np.abs(steps).max() <= i + 1e-3, name
+        assert float(np.asarray(metrics[moe_lib.SHARE_STATS_NAME])[1]) == (
+            pytest.approx(0.001 * (i - 1), abs=1e-6)
+        )
+
+
+def test_the_microbatch_engine_trains_the_same_step():
+    one, two = build(), build(grad_accum=2)
+    batch = batches(1)[0]
+    results = []
+    for train in (one, two):
+        state = train.init(jax.random.PRNGKey(0))
+        state, metrics = train.step(
+            state, train_lib.shard_batch(batch, train)
+        )
+        results.append((state, metrics))
+    (a_state, a), (b_state, b) = results
+    for key in ("loss", "mtp_loss", "grad_norm"):
+        assert float(a[key]) == pytest.approx(float(b[key]), rel=2e-4), key
+    np.testing.assert_allclose(
+        np.asarray(a[moe_lib.SHARE_STATS_NAME]),
+        np.asarray(b[moe_lib.SHARE_STATS_NAME]), rtol=1e-5,
+    )
+    # the step's loads are the microbatches' together: one rule, one move
+    for name, bias in biases(a_state.params).items():
+        np.testing.assert_allclose(
+            bias, biases(b_state.params)[name], atol=1e-7, err_msg=name
+        )
+
+
+def digest(state):
+    from dlrover_tpu.trainer import state_digest
+
+    return int(state_digest._digest_tree(state))
+
+
+@pytest.mark.skipif(len(jax.devices()) < 2, reason="needs two host devices")
+def test_a_flash_checkpoint_keeps_the_router_bias(small_pieces):
+    """``b`` is train state no gradient moves: saved through the staged
+    path with the rest of it and restored from the arena alone."""
+    from dlrover_tpu.checkpoint import engine as ckpt_engine
+    from dlrover_tpu.checkpoint.shm_handler import (
+        SharedMemoryHandler,
+        assemble_tensor,
+    )
+
+    train = build(
+        devices=2, parallel=ParallelConfig(data=1, fsdp=2),
+        optimizer="adafactor",
+    )
+    state = train.init(jax.random.PRNGKey(0))
+    for batch in batches(3):
+        state, _ = train.step(state, train_lib.shard_batch(batch, train))
+    saved, saved_bias = digest(state), biases(state.params)
+    assert all(np.abs(b).max() > 0 for b in saved_bias.values())
+    name = f"joyai{os.getpid()}"
+    writer = SharedMemoryHandler(name)
+    try:
+        writer.save_state_dict(state, step=3)
+        writer.close()                       # the process is gone
+        reader = SharedMemoryHandler(name)
+        meta = reader.load_meta()
+        assert meta.step == 3
+        assert [t.path for t in meta.tensors if "router_bias" in str(t.path)]
+        arrays = {
+            t.path: assemble_tensor(t, lambda r: reader.load_block(meta, r))
+            for t in meta.tensors
+        }
+        restored = ckpt_engine.materialize_records(
+            arrays, meta, train.state_shardings,
+            jax.tree_util.tree_structure(state),
+        )
+        assert digest(restored) == saved
+        for key, bias in biases(restored.params).items():
+            np.testing.assert_array_equal(bias, saved_bias[key])
+        batch = train_lib.shard_batch(batches(4)[3], train)
+        _, a = train.step(restored, batch)
+        assert np.isfinite(float(a["loss"]))
+    finally:
+        SharedMemoryHandler(name).close(unlink=True)
+
+
+@pytest.mark.skipif(len(jax.devices()) < 4, reason="needs four host devices")
+def test_the_dense_layer_runs_ahead_of_a_two_stage_pipeline():
+    """A dense trunk with latent attention and one leading layer outside
+    the stack: two pipeline stages over a real ``pipe`` axis give the loss
+    the plain scan gives on the same weights."""
+    dense = dict(
+        num_experts=0, experts_held=0, first_expert=0, router_bias=False,
+        router_scoring="softmax", num_shared_experts=0, moe_dispatch="einsum",
+        mtp_depth=0, num_layers=5, first_k_dense=1,
+    )
+    tokens = batches(1)[0]
+    losses, params1 = {}, None
+    for pp in (1, 2):
+        cfg = config(
+            pipeline_stages=pp, num_microbatches=2 if pp > 1 else 0, **dense
+        )
+        assert cfg.num_scan_units == 4
+        train = build(
+            cfg, devices=2 * pp,
+            parallel=ParallelConfig(data=2, pipe=pp), optimizer="sgd",
+        )
+        state = train.init(jax.random.PRNGKey(0))
+        if pp == 1:
+            params1 = jax.tree.map(np.asarray, state.params)
+        else:
+            # the first stage's input is the dense layer's output: the
+            # layer lives outside the stage-stacked weights, whole
+            assert set(state.params) == set(params1)
+            stacked = jax.tree.map(
+                lambda leaf: leaf.reshape(2, 2, *leaf.shape[1:]),
+                params1["blocks"],
+            )
+            piped = dict(
+                params1, blocks={"ticks": {"stages": {"layers": stacked}}}
+            )
+            assert jax.tree.structure(piped) == jax.tree.structure(
+                jax.tree.map(np.asarray, state.params)
+            )
+            state = state.replace(params=jax.device_put(
+                piped, train.state_shardings.params
+            ))
+            spec = state.params["blocks"]["ticks"]["stages"]["layers"][
+                "attn"
+            ]["q_b"]["kernel"].sharding.spec
+            assert spec[0] == "pipe", spec
+            assert "pipe" not in str(
+                state.params["dense_0"]["attn"]["q_b"]["kernel"].sharding.spec
+            )
+        _, metrics = train.step(state, train_lib.shard_batch(tokens, train))
+        losses[pp] = float(metrics["loss"])
+    assert losses[2] == pytest.approx(losses[1], rel=1e-4)
+    with pytest.raises(NotImplementedError, match="num_experts=0"):
+        TransformerLM(config(pipeline_stages=2, num_layers=5)).init(
+            jax.random.PRNGKey(0), jnp.zeros((2, SEQ), jnp.int32)
+        )
+
+
+@pytest.mark.parametrize("metrics_lag", [0, 4])
+def test_fit_books_the_share_and_the_mtp_loss_on_the_report_cadence(
+    metrics_lag, monkeypatch, tmp_path
+):
+    from dlrover_tpu.common import telemetry
+    from dlrover_tpu.trainer.elastic_trainer import (
+        ElasticTrainer,
+        TrainerConfig,
+    )
+
+    monkeypatch.setenv("DLROVER_TPU_JOB", f"joy_{tmp_path.name}")
+    monkeypatch.setenv("DLROVER_TPU_SOCKET_DIR", str(tmp_path / "socks"))
+    train_lib.reset_build_cache()
+    train_lib.reset_trace_counts()
+    trainer = ElasticTrainer(
+        config(),
+        TrainerConfig(
+            global_batch_size=BATCH, seq_len=SEQ, learning_rate=1e-2,
+            optimizer="adafactor", ckpt_every=1000, report_every=5,
+            metrics_lag=metrics_lag,
+        ),
+        client=None,
+    )
+    seen = {}
+    with telemetry.recorder().open_tap() as tap:
+        trainer.fit(
+            batches(10), max_steps=10,
+            on_step=lambda step, metrics: seen.update({step: metrics}),
+        )
+        events = [e for e in tap.take() if e[1] == "event"]
+    moe = [e[4] for e in events if e[0] == "moe"]
+    mtp = [e[4] for e in events if e[0] == "mtp"]
+    assert [e["step"] for e in moe] == [5, 10] == [e["step"] for e in mtp]
+    for event in moe:
+        share = np.asarray(seen[event["step"]][moe_lib.SHARE_STATS_NAME])
+        assert event["experts"] == event["experts_total"] == 16
+        assert event["held"] == 4 and event["top_k"] == 4
+        assert event["pairs_here"] == pytest.approx(float(share[0]))
+        assert 0.1 < event["pairs_here"] < 0.4
+        assert event["bias_absmax"] == pytest.approx(float(share[1]))
+        assert event["bias_absmax"] == pytest.approx(
+            0.001 * (event["step"] - 1), abs=1e-6
+        )
+        assert event["drop_fraction"] == 0.0
+        assert len(__import__("json").loads(event["load"])) == 16
+    for event in mtp:
+        assert event["mtp_loss"] == pytest.approx(
+            seen[event["step"]]["mtp_loss"]
+        )
+        assert event["weight"] == pytest.approx(0.3)
+        assert 4.0 < event["mtp_loss"] < 7.0
+    assert train_lib.trace_count("train_step") == 1
+
+
+def test_the_master_renders_the_share_the_bias_and_the_mtp_loss_as_gauges():
+    from dlrover_tpu.master.speed_monitor import SpeedMonitor
+    from dlrover_tpu.master.timeline import JobTimeline
+
+    monitor = SpeedMonitor()
+    common = dict(entropy=5.5, drop_fraction=0.0, experts=256, top_k=8,
+                  load="[]", pad_share=0.1, max_expert_load=1.2)
+    monitor.record_moe(0, step=5, held=32, pairs_here=0.124,
+                       bias_absmax=0.004, later_attr="ignored", **common)
+    monitor.record_moe(1, step=5, held=32, pairs_here=0.126,
+                       bias_absmax=0.006, **common)
+    monitor.record_mtp(0, step=5, mtp_loss=10.0, weight=0.3)
+    monitor.record_mtp(1, step=5, mtp_loss=10.5)
+    ledger = monitor.moe_ledger()
+    assert ledger["held"] == 32 and ledger["experts"] == 256
+    assert ledger["pairs_here"] == pytest.approx(0.125)
+    assert ledger["bias_absmax"] == 0.006         # the largest replica's
+    assert monitor.mtp_loss() == pytest.approx(10.25)
+    text = JobTimeline().render_metrics(speed_monitor=monitor)
+    for name, value in (
+        ("dlrover_moe_experts_held", "32"),
+        ("dlrover_moe_pairs_here", "0.125"),
+        ("dlrover_moe_router_bias_absmax", "0.006"),
+        ("dlrover_mtp_loss", "10.25"),
+    ):
+        assert f"# TYPE {name} gauge" in text
+        assert any(
+            line.startswith(name + " ") and line.split()[1].startswith(value)
+            for line in text.splitlines()
+        ), name
+    # an older trainer's event (no share told) reads as every expert held
+    older = SpeedMonitor()
+    older.record_moe(0, step=1, **common)
+    assert older.moe_ledger()["held"] == 256
+    assert older.moe_ledger()["pairs_here"] == 1.0
+    assert older.mtp_loss() == 0.0

@@ -319,7 +319,7 @@ def test_moe_row_move_ms_is_the_scatter_and_combine_scopes_kernels_or_not():
     assert scope_ms.read(dict(evidence, trace=other), spec["params"]) is None
 
 
-def test_the_manifest_lists_the_reading_for_the_olmoe_cell_only():
+def test_the_manifest_lists_the_reading_for_the_dropless_cells_only():
     from benchmark import build
 
     entry = [
@@ -330,5 +330,7 @@ def test_the_manifest_lists_the_reading_for_the_olmoe_cell_only():
         "name": "moe_row_move_ms", "unit": "ms", "better": "lower",
         "source": "device_trace", "layer": "step program",
         "moves": "tokens_per_s_chip",
-        "workloads": ["olmoe-1b-7b.train_steady"],
+        "workloads": [
+            "olmoe-1b-7b.train_steady", "joyai-llm-flash.train_steady",
+        ],
     }]

@@ -14,6 +14,24 @@ steps.  Causal blocks above the diagonal are skipped via ``@pl.when`` — for
 long sequences that halves the FLOPs, which is exactly the regime the
 north-star benchmark (long-context goodput) cares about.
 
+Backward: ONE pass (``_bwd_fused_kernel``: s, the mask, p, dp and ds once
+a block feed dq, dk and dv; 5 matmuls a block) wherever its dq accumulator
+fits VMEM, else the split pair (``_bwd_dq_kernel`` then ``_bwd_dkv_kernel``,
+7 matmuls, every score block computed twice).  The choice is a function of
+the shapes, :func:`backward_path`.  The one pass sweeps the kv blocks on the
+outer grid axis, so a dq block is revisited non-consecutively, which an
+output block may not be and a scratch may.  With one kv block there is
+nothing to accumulate.  With several, a float32 scratch ``[S_q, d]`` holds
+the whole sequence's dq of one (batch, head) and the dq output block is the
+whole sequence, resident until the head changes: ``S_q * lanes(d) * (4 + 2 *
+itemsize)`` bytes, 16 MiB at 8192 x 192 in bf16 (192 occupies 256 lanes), 8
+at 8192 x 128, beside 23.5 / 21.5 MiB of blocks and temporaries at blocks of
+1024.  The kernel asks for what :func:`_fused_bwd_vmem_bytes` counts as its
+``vmem_limit_bytes``, and is chosen while that is within ``_VMEM_CAP`` = 64
+MiB (half a v5e core's VMEM): in bf16 at blocks of 1024 up to 43008 tokens
+at head 128 and 20480 at 192 / 128; longer sequences keep the split pair.
+No float32 dq crosses HBM either way.
+
 Padding: sequence lengths are padded to the block size by the wrapper; the
 pad region is masked via an implicit segment id (pad tokens attend nowhere).
 """
@@ -26,7 +44,6 @@ from typing import Optional
 import jax
 import jax.ad_checkpoint
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -38,6 +55,9 @@ _LANE = 128
 # sublane tile) instead of 128 cuts their HBM footprint/traffic 16x — at
 # bench shapes that is ~200 MB of pure padding per layer per tensor.
 _STAT = 8
+# The most VMEM the one-pass backward may ask for: half of a v5e core's
+# 128 MiB (a kernel that asks for nothing gets 16 MiB).
+_VMEM_CAP = 64 << 20
 
 
 def _pad_to(x, size, axis, value=0):
@@ -47,6 +67,18 @@ def _pad_to(x, size, axis, value=0):
     widths = [(0, 0)] * x.ndim
     widths[axis] = (0, pad)
     return jnp.pad(x, widths, constant_values=value)
+
+
+def _blocks_and_padding(sq, skv, block_q, block_kv):
+    """Blocks clamped to the (pow2-padded) sequence, floor of 16 so that the
+    sublane tile stays valid for bf16 when the whole sequence is one block,
+    and both lengths padded to whole blocks."""
+    block_q = min(block_q, max(16, 1 << (sq - 1).bit_length()))
+    block_kv = min(block_kv, max(16, 1 << (skv - 1).bit_length()))
+    return (
+        block_q, block_kv,
+        -(-sq // block_q) * block_q, -(-skv // block_kv) * block_kv,
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -319,19 +351,27 @@ def _bwd_dkv_kernel(
 
 def _bwd_fused_kernel(
     seg_q_ref, seg_kv_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref,
-    dq_ref, dk_ref, dv_ref, dk_acc_ref, dv_acc_ref,
-    *, causal: bool, scale: float, block_q: int, block_kv: int,
+    dq_ref, dk_ref, dv_ref, dk_acc_ref, dv_acc_ref, *dq_acc,
+    causal: bool, scale: float, block_q: int, block_kv: int,
 ):
     """One-pass backward: s/p computed once feed dq, dk AND dv.
 
-    Single-kv-block fast path (``nk == 1`` — the bench shape): every dq
-    output block is visited exactly once, dk/dv accumulate in VMEM scratch
-    across the inner q sweep.  The split dq/dkv kernels each recomputed
+    Grid (batch, head, kv block, q block): dk/dv accumulate in VMEM scratch
+    across the inner q sweep.  The split dq/dkv kernels each recompute
     s = q k^T and the softmax from lse (7 S^2 D matmul units + 2 exp sweeps
     per pair); fused it is 5 + 1, a ~25% cut of backward kernel FLOPs.
-    With nk > 1 dq blocks would be revisited non-consecutively, which
-    Pallas TPU's output pipelining does not guarantee to reload — the
-    wrapper dispatches to the split kernels instead for those shapes.
+
+    A dq block takes one term from every live kv block, and the kv axis is
+    the OUTER one here, so its visits are not consecutive.  An output block
+    may not be revisited that way (Pallas TPU writes it back when its index
+    changes and does not reload it), a scratch may: with several kv blocks
+    ``dq_ref`` is the whole sequence of one (batch, head), resident until
+    the head changes, and ``dq_acc`` one float32 scratch of the same extent.
+    A q block's rows are assigned at kv block 0 (live for every q block),
+    added to at the others in ascending kv order, which is the order and
+    the precision of ``_bwd_dq_kernel``'s scratch, and cast into ``dq_ref``
+    at the q block's last live kv block.  With ONE kv block (``dq_acc``
+    empty) every dq block is visited once and written straight out.
     """
     ik, iq = pl.program_id(2), pl.program_id(3)
     nq = pl.num_programs(3)
@@ -363,15 +403,55 @@ def _bwd_fused_kernel(
             ds, q_ref[0, 0], (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        # nk == 1 (enforced by the dispatcher): one visit per dq block.
-        dq_ref[0, 0] = jax.lax.dot(
+        dq = jax.lax.dot(
             ds, k_ref[0, 0], preferred_element_type=jnp.float32
-        ).astype(dq_ref.dtype)
+        )
+        if not dq_acc:
+            dq_ref[0, 0] = dq.astype(dq_ref.dtype)
+            return
+        (dq_acc_ref,) = dq_acc
+        rows = pl.ds(pl.multiple_of(q_start, block_q), block_q)
+
+        @pl.when(ik == 0)
+        def _first():
+            dq_acc_ref[rows, :] = dq
+
+        @pl.when(ik > 0)
+        def _later():
+            dq_acc_ref[rows, :] += dq
+
+        last = pl.num_programs(2) - 1
+        if causal:
+            last = jnp.minimum(last, (q_start + block_q - 1) // block_kv)
+
+        @pl.when(ik == last)
+        def _write():
+            dq_ref[0, 0, rows, :] = dq_acc_ref[rows, :].astype(dq_ref.dtype)
 
     @pl.when(iq == nq - 1)
     def _finalize_kv():
         dk_ref[0, 0] = dk_acc_ref[:].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_acc_ref[:].astype(dv_ref.dtype)
+
+
+def _fused_bwd_vmem_bytes(sq, d, d_v, block_q, block_kv, dtype) -> int:
+    """VMEM the one-pass backward asks for at several kv blocks, from its
+    shapes alone (a last dimension occupies whole 128-lane tiles): the
+    pipeline's two buffers of every block in and out, the lse block at 128
+    lanes, the dk / dv accumulators, four float32 ``[block_q, block_kv]``
+    temporaries, and dq over the WHOLE q sequence: the float32 scratch and
+    two buffers of the output.  At 8192 x 192 / 128 in bf16 and blocks of
+    1024: 6 + 1.5 + 16 + 16 = 39.5 MiB, where the v5e's compiler refuses
+    less than 30.5 (it keeps two temporaries, not four); at 8192 x 128,
+    29.5 for 21.5."""
+    item = jnp.dtype(dtype).itemsize
+    d, d_v = (-(-width // _LANE) * _LANE for width in (d, d_v))
+    q_side = block_q * (d + 2 * d_v)        # q, do, o
+    kv_side = block_kv * (d + d_v)          # k, v in; dk, dv out
+    blocks = 2 * item * (q_side + 2 * kv_side) + 2 * 4 * block_q * _LANE
+    temporaries = 4 * 4 * block_q * block_kv
+    dq = sq * d * (4 + 2 * item)
+    return blocks + 4 * kv_side + temporaries + dq
 
 
 def _flash_bwd_fused(
@@ -385,6 +465,42 @@ def _flash_bwd_fused(
 
     lse_l = jnp.broadcast_to(lse[..., None], (*lse.shape, _STAT))
 
+    def q_block(ik, iq):
+        return iq
+
+    def q_rows(ib, ih, ik, iq):
+        return (ib, ih, q_block(ik, iq), 0)
+
+    dq_spec = pl.BlockSpec((1, 1, block_q, d), q_rows)
+    scratch = [
+        pltpu.VMEM((block_kv, d), jnp.float32),
+        pltpu.VMEM((block_kv, d_v), jnp.float32),
+    ]
+    one_pass = {}
+    if nk > 1:
+        dq_spec = pl.BlockSpec(
+            (1, 1, sq, d), lambda ib, ih, ik, iq: (ib, ih, 0, 0)
+        )
+        scratch.append(pltpu.VMEM((sq, d), jnp.float32))
+        one_pass = dict(
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=(
+                    "parallel", "parallel", "arbitrary", "arbitrary"
+                ),
+                vmem_limit_bytes=_fused_bwd_vmem_bytes(
+                    sq, d, d_v, block_q, block_kv, q.dtype
+                ),
+            ),
+            name="flash_bwd_one_pass",
+        )
+        if causal:
+            # A step above the diagonal computes nothing: let it name the
+            # q-side blocks of the kv block's first live step, which the
+            # pipeline then fetches once and not for every dead step
+            # (JoyAI's layer 35.0 -> 31.2 ms on a v5e, PERF.md §6 PR 34).
+            def q_block(ik, iq):
+                return jnp.maximum(iq, (ik * block_kv) // block_q)
+
     dq, dk, dv = pl.pallas_call(
         functools.partial(
             _bwd_fused_kernel, causal=causal, scale=scale,
@@ -392,11 +508,12 @@ def _flash_bwd_fused(
         ),
         grid=(b, hq, nk, nq),
         in_specs=[
-            pl.BlockSpec((1, 1, block_q), lambda ib, ih, ik, iq: (ib, 0, iq)),
-            pl.BlockSpec((1, 1, block_kv), lambda ib, ih, ik, iq: (ib, 0, ik)),
             pl.BlockSpec(
-                (1, 1, block_q, d), lambda ib, ih, ik, iq: (ib, ih, iq, 0)
+                (1, 1, block_q),
+                lambda ib, ih, ik, iq: (ib, 0, q_block(ik, iq)),
             ),
+            pl.BlockSpec((1, 1, block_kv), lambda ib, ih, ik, iq: (ib, 0, ik)),
+            pl.BlockSpec((1, 1, block_q, d), q_rows),
             pl.BlockSpec(
                 (1, 1, block_kv, d),
                 lambda ib, ih, ik, iq, g=group: (ib, ih // g, ik, 0),
@@ -405,20 +522,12 @@ def _flash_bwd_fused(
                 (1, 1, block_kv, d_v),
                 lambda ib, ih, ik, iq, g=group: (ib, ih // g, ik, 0),
             ),
-            pl.BlockSpec(
-                (1, 1, block_q, d_v), lambda ib, ih, ik, iq: (ib, ih, iq, 0)
-            ),
-            pl.BlockSpec(
-                (1, 1, block_q, _STAT), lambda ib, ih, ik, iq: (ib, ih, iq, 0)
-            ),
-            pl.BlockSpec(
-                (1, 1, block_q, d_v), lambda ib, ih, ik, iq: (ib, ih, iq, 0)
-            ),
+            pl.BlockSpec((1, 1, block_q, d_v), q_rows),
+            pl.BlockSpec((1, 1, block_q, _STAT), q_rows),
+            pl.BlockSpec((1, 1, block_q, d_v), q_rows),
         ],
         out_specs=[
-            pl.BlockSpec(
-                (1, 1, block_q, d), lambda ib, ih, ik, iq: (ib, ih, iq, 0)
-            ),
+            dq_spec,
             pl.BlockSpec(
                 (1, 1, block_kv, d), lambda ib, ih, ik, iq: (ib, ih, ik, 0)
             ),
@@ -426,16 +535,14 @@ def _flash_bwd_fused(
                 (1, 1, block_kv, d_v), lambda ib, ih, ik, iq: (ib, ih, ik, 0)
             ),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((block_kv, d), jnp.float32),
-            pltpu.VMEM((block_kv, d_v), jnp.float32),
-        ],
+        scratch_shapes=scratch,
         out_shape=[
             jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
             jax.ShapeDtypeStruct((b, hq, skv, d), k.dtype),
             jax.ShapeDtypeStruct((b, hq, skv, d_v), v.dtype),
         ],
         interpret=backend.interpret(),
+        **one_pass,
     )(seg_q, seg_kv, q, k, v, do, lse_l, o)
     if group > 1:
         dk = dk.reshape(b, hkv, group, skv, d).sum(axis=2).astype(k.dtype)
@@ -554,6 +661,21 @@ def _flash_bwd(
 # ---------------------------------------------------------------------------
 
 
+def backward_path(sq, skv, d, d_v, block_q, block_kv, dtype) -> str:
+    """``"fused"`` or ``"split"``: the backward :func:`mha` runs at these
+    sizes, given as its caller gives them (clamping and padding them twice
+    changes nothing, so the dispatch asks with the padded ones).  One kv
+    block needs no dq scratch and is always fused; several are while
+    :func:`_fused_bwd_vmem_bytes` is within ``_VMEM_CAP``."""
+    block_q, block_kv, sq, skv = _blocks_and_padding(
+        sq, skv, block_q, block_kv
+    )
+    if skv == block_kv:
+        return "fused"
+    need = _fused_bwd_vmem_bytes(sq, d, d_v, block_q, block_kv, dtype)
+    return "fused" if need <= _VMEM_CAP else "split"
+
+
 @functools.partial(
     jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8)
 )
@@ -581,11 +703,11 @@ def _flash_core_fwd(q, k, v, seg_q, seg_kv, causal, scale, block_q, block_kv):
 
 def _flash_core_bwd(causal, scale, block_q, block_kv, residuals, g):
     q, k, v, seg_q, seg_kv, o, lse = residuals
-    # Fused single-pass backward when the whole kv extent is one block
-    # (no dq output revisits); split dq/dkv kernels otherwise.
-    impl = (
-        _flash_bwd_fused if k.shape[2] == block_kv else _flash_bwd
+    path = backward_path(
+        q.shape[2], k.shape[2], q.shape[3], v.shape[3], block_q, block_kv,
+        q.dtype,
     )
+    impl = _flash_bwd_fused if path == "fused" else _flash_bwd
     dq, dk, dv = impl(
         q, k, v, seg_q, seg_kv, o, lse, g,
         causal=causal, scale=scale, block_q=block_q, block_kv=block_kv,
@@ -621,12 +743,9 @@ def mha(
     skv = k.shape[1]
     scale = d ** -0.5 if scale is None else scale
 
-    # Clamp blocks to the (pow2-padded) sequence; floor of 16 keeps the
-    # sublane tile valid for bf16 when the whole sequence is one block.
-    block_q = min(block_q, max(16, 1 << (sq - 1).bit_length()))
-    block_kv = min(block_kv, max(16, 1 << (skv - 1).bit_length()))
-    sq_p = int(np.ceil(sq / block_q)) * block_q
-    skv_p = int(np.ceil(skv / block_kv)) * block_kv
+    block_q, block_kv, sq_p, skv_p = _blocks_and_padding(
+        sq, skv, block_q, block_kv
+    )
 
     if segment_ids is None:
         seg_q = jnp.zeros((b, sq), jnp.int32)

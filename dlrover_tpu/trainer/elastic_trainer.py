@@ -352,6 +352,7 @@ class ElasticTrainer:
                 "persistent_hits": after["hits"] - before["hits"],
                 "persistent_misses": after["misses"] - before["misses"],
                 "kernel_calls": self.train.kernel_calls,
+                "flash_backward": self._flash_backward(),
             }
             logger.info("compile warmup: %s", detail)
             telemetry.event(
@@ -447,6 +448,27 @@ class ElasticTrainer:
         return self.vmesh.grad_accum_for(
             self._ref_accum, self.config.global_batch_size,
             self._dp_shards(),
+        )
+
+    def _flash_backward(self) -> str:
+        """Which flash-attention backward the step program holds: ``fused``
+        (one pass), ``split`` (dq, then dk / dv) or ``none`` (no flash
+        kernel).  Chosen at trace time from the shapes alone, so this asks
+        the function the dispatch asks; the sequence is whole inside
+        attention under every rule table (``models/attention.py``)."""
+        cfg = self.model_config
+        if cfg.attention_impl != "flash":
+            return "none"
+        from dlrover_tpu.ops import flash_attention
+
+        d = d_v = cfg.resolved_head_dim
+        if cfg.latent_attention:
+            d = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+            d_v = cfg.v_head_dim
+        seq = self.config.seq_len
+        return flash_attention.backward_path(
+            seq, seq, d, d_v, cfg.flash_block_q, cfg.flash_block_kv,
+            cfg.dtype,
         )
 
     def _build_train(

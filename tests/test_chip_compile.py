@@ -141,17 +141,36 @@ def _embed(name):
 
 QKV_1P5B = ((16, 1024, 25, 64), BF16)      # GPT-2 1.5B, batch 16
 QKV_LONG = ((4, 2048, 32, 128), BF16)      # head_dim 128, two kv blocks
+
+
+def _flash_longest(seq, d, d_v=None):
+    """One sequence of one head at the one-pass backward's bound."""
+    qk = ((1, seq, 1, d), BF16)
+    return [qk, qk, ((1, seq, 1, d_v or d), BF16)]
+
+
 LEAF = ((1600, 6400), F32)                 # the 1.5B MLP wi kernel
 CACHE = ((65536, 128), F32)
 
 # (id, builder, avals, static kwargs, kernels expected in the program)
 CASES = [
     ("flash_fwd_fused_bwd", lambda: _flash(1024), [QKV_1P5B] * 3, {}, 2),
-    ("flash_split_bwd", lambda: _flash(1024), [QKV_LONG] * 3, {}, 3),
+    # several kv blocks: the forward and ONE backward kernel, its dq in a
+    # whole-sequence VMEM scratch (PR 34; the split pair made three)
+    ("flash_fused_bwd_two_kv_blocks", lambda: _flash(1024),
+     [QKV_LONG] * 3, {}, 2),
     # JoyAI-LLM-Flash's latent attention: 2 x 8192 tokens, 32 heads, keys
     # 192 wide (1.5 lane groups) and values 128; eight kv blocks
     ("flash_latent_192_128", lambda: _flash(1024),
-     [((2, 8192, 32, 192), BF16)] * 2 + [((2, 8192, 32, 128), BF16)], {}, 3),
+     [((2, 8192, 32, 192), BF16)] * 2 + [((2, 8192, 32, 128), BF16)], {}, 2),
+    # the longest sequences `backward_path` calls fused (64 MiB of VMEM
+    # asked for), and the first beyond: the split pair, three kernels
+    ("flash_fused_bwd_longest_128", lambda: _flash(1024),
+     _flash_longest(43008, 128), {}, 2),
+    ("flash_fused_bwd_longest_192_128", lambda: _flash(1024),
+     _flash_longest(20480, 192, 128), {}, 2),
+    ("flash_split_bwd", lambda: _flash(1024),
+     _flash_longest(44032, 128), {}, 3),
     ("fused_layernorm", lambda: _norm_grad("fused_layernorm", True),
      [((16, 1024, 1600), BF16), ((1600,), F32), ((1600,), F32)], {}, 1),
     ("fused_rmsnorm", lambda: _norm_grad("fused_rmsnorm", False),

@@ -454,7 +454,12 @@ def test_the_published_widths_count_what_the_issue_counts():
 # left all four as they were: the flash kernels at ``d_qk == d_v``, the
 # grouped GEMMs with every expert held (no dead blocks skipped), the router
 # statistics without a share and the trunk without a dense prefix or an MTP
-# module lower to what they lowered to.
+# module lower to what they lowered to.  PR 34 (the one-pass flash backward at
+# several kv blocks) left three as they were: their tiny presets run ONE kv
+# block (64 tokens in a block of 64), which lowers to the parent's kernel.
+# ``olmo-hybrid-7b``'s preset sets blocks of 16 for its 64 tokens, four kv
+# blocks: its two full layers' backward is now one kernel with a [64, 16]
+# float32 dq scratch where it was two, so its text is recorded anew.
 LOWERED_AT_PARENT = {
     "gpt2-1.5b":
         "daebdfc3a4e5c684c9383ca48012bef11bbd46910f33798c49c7014a77cf9aa5",
@@ -463,7 +468,7 @@ LOWERED_AT_PARENT = {
     "olmoe-1b-7b":
         "cd528fd6aa74a2ab95d1cdf8fd40a4b2967b21cf41399c806e1919738cacc672",
     "olmo-hybrid-7b":
-        "8b2faaad982e40cb539e8ad215c87e7781f0d33a39a97b017bbf3394c86c358e",
+        "0ef3c02d42c8b79c81557dfa17d5e166eefd82a26f1cd9cc4db9709a548f7686",
 }
 
 
@@ -507,6 +512,51 @@ def test_earlier_models_keep_their_lowered_step_text(preset):
     # and none of them has met the DeepSeek-V3 family's parts
     for name in ("latent", "router_bias", "mtp", "moe_share_stats"):
         assert name not in text, name
+
+
+@pytest.mark.parametrize("preset,blocks,vmem_cap,path", [
+    ("gpt2-1.5b", 1, None, "fused"),        # one kv block: no dq scratch
+    ("olmo-hybrid-7b", 4, None, "fused"),   # several: dq in VMEM scratch
+    ("olmo-hybrid-7b", 4, 1 << 16, "split"),   # past the bound
+    ("gpt2-1.5b", 1, 1 << 16, "fused"),
+])
+def test_compile_event_names_the_flash_backward(
+    tap, monkeypatch, preset, blocks, vmem_cap, path
+):
+    """The path is a fact of the compiled step: the ``compile`` event names
+    it, from the function the dispatch asks (``xla`` attention: ``none``)."""
+    from benchmark import build
+    from dlrover_tpu.ops import flash_attention
+    from dlrover_tpu.trainer import train_lib
+    from dlrover_tpu.trainer.elastic_trainer import (
+        ElasticTrainer, TrainerConfig,
+    )
+
+    if vmem_cap is not None:
+        monkeypatch.setattr(flash_attention, "_VMEM_CAP", vmem_cap)
+    cfg = build.load_json(os.path.join(
+        REPO, "tests", "benchmark_suite", "presets", f"{preset}.json"
+    ))
+    seq = cfg["run"]["seq_len"]
+    model = build.transformer_config(build.model_group(cfg), seq)
+    assert model.attention_impl == "flash"
+    assert seq // min(seq, model.flash_block_kv) == blocks
+
+    def flash_backward(model):
+        train_lib.reset_build_cache()
+        tap.take()
+        ElasticTrainer(model, TrainerConfig(
+            global_batch_size=jax.device_count(), seq_len=seq,
+            optimizer="adafactor", warmup_compile=True, ckpt_every=1000,
+        ))
+        (event,) = [e for e in tap.take() if e[0] == "compile"]
+        return event[-1]["flash_backward"]
+
+    assert flash_backward(model) == path
+    if vmem_cap is None:
+        assert flash_backward(
+            dataclasses.replace(model, attention_impl="xla", remat="none")
+        ) == "none"
 
 
 def test_olmoe_keeps_its_tree_and_losses():

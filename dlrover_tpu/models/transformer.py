@@ -589,7 +589,8 @@ class Block(nn.Module):
         # skips re-running the whole attention forward (the priciest part of
         # recompute) at b*s*d bf16 per layer of extra HBM.
         y = jax.ad_checkpoint.checkpoint_name(y, "attn_out")
-        x = x + y
+        with jax.named_scope("residual"):
+            x = x + y
         y = x if post else norm("ln_mlp", x)
         if cfg.num_experts and not self.dense_mlp:
             y, layer_aux = MoEMlp(
@@ -628,7 +629,8 @@ class Block(nn.Module):
         # stream from saved branch outputs instead of re-running the wo
         # matmul (b*s*d bf16 per layer of extra HBM each).
         y = jax.ad_checkpoint.checkpoint_name(y, "mlp_out")
-        x = x + y
+        with jax.named_scope("residual"):
+            x = x + y
         x = nn.with_logical_constraint(x, (lr.BATCH, lr.ACT_SEQ, lr.ACT_EMBED))
         return (x, aux), None
 
@@ -761,7 +763,8 @@ class TransformerLM(nn.Module):
                 (cfg.max_seq_len, cfg.d_model),
                 cfg.param_dtype,
             )
-            x = x + pos_table.astype(cfg.dtype)[positions]
+            with jax.named_scope("pos_embed"):
+                x = x + pos_table.astype(cfg.dtype)[positions]
         x = nn.with_logical_constraint(x, (lr.BATCH, lr.ACT_SEQ, lr.ACT_EMBED))
 
         block_cls = block_class(cfg, prevent_cse=not cfg.scan_layers)

@@ -48,6 +48,28 @@ def reset_trace_counts():
     TRACE_COUNTS.clear()
 
 
+# The step program's own ``jax.named_scope``s: what the step does outside the
+# model's modules.  A scope reaches an instruction's ``op_name`` and nothing
+# else (the lowered text without locations is the same), and the device
+# trace's per-layer metrics read it there; an op differentiated inside the
+# loss function reads ``jvp(loss)`` and ``transpose(jvp(loss))``.  None of
+# the names holds a layer's (``attn``, ``moe``, ``mtp``, ``scatter``,
+# ``combine``, ``conv``), whose metrics must not pick them up.
+OPTIMIZER = "optimizer"
+OPTIMIZER_REDUCE = f"{OPTIMIZER}/reduce"  # ZeRO-1: gradients to their shards
+OPTIMIZER_UPDATE = f"{OPTIMIZER}/update"  # ``tx.update``: clip and the rule
+OPTIMIZER_APPLY = f"{OPTIMIZER}/apply"  # ``apply_updates``
+OPTIMIZER_GATHER = f"{OPTIMIZER}/gather"  # ZeRO-1: parameters re-replicated
+GRAD_NORM = "grad_norm"
+ROUTER_BIAS = "router_bias"
+LOSS = "loss"  # cross-entropy (the MTP term's too) and the scalars after it
+GRAD_ACCUM = "grad_accum"  # the microbatch reshapes and the carry's sum
+STEP_SCOPES = (
+    OPTIMIZER_REDUCE, OPTIMIZER_UPDATE, OPTIMIZER_APPLY, OPTIMIZER_GATHER,
+    GRAD_NORM, ROUTER_BIAS, LOSS, GRAD_ACCUM,
+)
+
+
 def use_mesh(mesh: Mesh):
     """Context entering the mesh for both tracing and execution."""
     from dlrover_tpu.runtime.mesh import activate_mesh
@@ -784,16 +806,17 @@ def build_sharded_train(
                 )
             return cross_entropy_loss(out, targets, weights)
 
-        ce, total_weight = ce_of(out, targets, weights)
-        if mtp_weight:
-            # position i predicts targets[i + 1]; the last has none
-            mtp_ce, mtp_total = ce_of(
-                outs[2], jnp.roll(targets, -1, axis=1),
-                jnp.pad(weights[:, 1:], ((0, 0), (0, 1))),
-            )
-            stats["mtp_ce_sum"] = mtp_ce * mtp_total
-            stats["mtp_tokens"] = mtp_total
-        return ce * total_weight, total_weight, aux, stats
+        with jax.named_scope(LOSS):
+            ce, total_weight = ce_of(out, targets, weights)
+            if mtp_weight:
+                # position i predicts targets[i + 1]; the last has none
+                mtp_ce, mtp_total = ce_of(
+                    outs[2], jnp.roll(targets, -1, axis=1),
+                    jnp.pad(weights[:, 1:], ((0, 0), (0, 1))),
+                )
+                stats["mtp_ce_sum"] = mtp_ce * mtp_total
+                stats["mtp_tokens"] = mtp_total
+            return ce * total_weight, total_weight, aux, stats
 
     def _q_reduce_scatter_leaf(leaf, z_sharding, full_sharding):
         """Route one gradient leaf's DP reduce through the int8 wire as a
@@ -853,9 +876,10 @@ def build_sharded_train(
 
         def _scatter_grads(grads):
             """Per-bucket reduce-scatter waves over the whole grad tree."""
-            return overlap_lib.scheduled_leaf_map(
-                _rs_grad_leaf, grads, overlap_plan
-            )
+            with jax.named_scope(OPTIMIZER_REDUCE):
+                return overlap_lib.scheduled_leaf_map(
+                    _rs_grad_leaf, grads, overlap_plan
+                )
 
         def _ag_param_leaf(i, p):
             """Re-replicate one updated param leaf (optionally int8)."""
@@ -897,9 +921,10 @@ def build_sharded_train(
             )
 
     def _apply_update(state: TrainState, grads, scattered: bool = False):
-        """Optimizer update: replicated (``apply_gradients``) or ZeRO-1.
+        """Optimizer update: replicated or ZeRO-1, each part under its
+        ``STEP_SCOPES`` name.
 
-        The zero1 path is ``apply_gradients`` with three sharding pins
+        The zero1 path is the replicated one with three sharding pins
         around it: grads pinned to the update shards (GSPMD lowers the DP
         sum into a reduce-scatter — or the quantized collective runs it
         explicitly), params pinned likewise (a free local slice of the
@@ -911,37 +936,43 @@ def build_sharded_train(
         per-bucket reduce-scatter (``parallel.overlap``), and the
         re-replication then rides the per-bucket staircase.
         """
-        if not zero1_active:
-            return state.apply_gradients(grads=grads)
         pin = jax.lax.with_sharding_constraint
-        if scattered:
-            # Already reduce-scattered inside the scan; re-pinning the
-            # shard layout is free and keeps the update shard-local.
-            grads = jax.tree.map(pin, grads, zero1_param_shardings)
-        elif reduce_quant == "int8":
-            grads = jax.tree.map(
-                _q_reduce_scatter_leaf, grads, zero1_param_shardings,
-                state_shardings.params,
+        params = state.params
+        if zero1_active:
+            with jax.named_scope(OPTIMIZER_REDUCE):
+                if reduce_quant == "int8" and not scattered:
+                    grads = jax.tree.map(
+                        _q_reduce_scatter_leaf, grads, zero1_param_shardings,
+                        state_shardings.params,
+                    )
+                else:
+                    # ``scattered``: already reduce-scattered inside the
+                    # scan; re-pinning the shard layout is free and keeps
+                    # the update shard-local.
+                    grads = jax.tree.map(pin, grads, zero1_param_shardings)
+                params = jax.tree.map(pin, params, zero1_param_shardings)
+        # ``TrainState.apply_gradients`` taken apart, so that its two halves
+        # carry their names.  A pin is no instruction: the collective the
+        # compiler puts there carries the name of the op whose result it
+        # moves (the gather reads ``optimizer/apply``).
+        with jax.named_scope(OPTIMIZER_UPDATE):
+            updates, new_opt_state = state.tx.update(
+                grads, state.opt_state, params
             )
-        else:
-            grads = jax.tree.map(pin, grads, zero1_param_shardings)
-        params_sharded = jax.tree.map(
-            pin, state.params, zero1_param_shardings
-        )
-        updates, new_opt_state = state.tx.update(
-            grads, state.opt_state, params_sharded
-        )
-        new_params = optax.apply_updates(params_sharded, updates)
-        if overlap_active:
-            new_params = _replicate_params(new_params)
-        else:
-            new_params = jax.tree.map(
-                pin, new_params, state_shardings.params
-            )
+        with jax.named_scope(OPTIMIZER_APPLY):
+            new_params = optax.apply_updates(params, updates)
+        if zero1_active:
+            with jax.named_scope(OPTIMIZER_GATHER):
+                if overlap_active:
+                    new_params = _replicate_params(new_params)
+                else:
+                    new_params = jax.tree.map(
+                        pin, new_params, state_shardings.params
+                    )
+        with jax.named_scope(OPTIMIZER_APPLY):
+            step = state.step + 1
         return state.replace(
-            step=state.step + 1,
-            params=new_params,
-            opt_state=new_opt_state,
+            step=step, params=new_params, opt_state=new_opt_state
         )
 
     def _family_update(state, new_state, stats):
@@ -949,9 +980,15 @@ def build_sharded_train(
         rule on this step's own loads (no gradient reaches it)."""
         if not bias_rate:
             return new_state
-        return new_state.replace(params=move_router_bias(
-            state.params, new_state.params, stats[ROUTER_LOADS], bias_rate
-        ))
+        with jax.named_scope(ROUTER_BIAS):
+            return new_state.replace(params=move_router_bias(
+                state.params, new_state.params, stats[ROUTER_LOADS],
+                bias_rate,
+            ))
+
+    def _grad_norm(grads):
+        with jax.named_scope(GRAD_NORM):
+            return optax.global_norm(grads)
 
     def _family_metrics(stats):
         """The step's metrics out of ``_forward_sums``' statistics: the
@@ -973,12 +1010,13 @@ def build_sharded_train(
                 params, state.apply_fn, batch["inputs"], batch["targets"],
                 batch["weights"],
             )
-            ce = ce_sum / total_weight
-            loss = ce + aux
-            if mtp_weight:
-                loss = loss + mtp_weight * (
-                    stats["mtp_ce_sum"] / stats["mtp_tokens"]
-                )
+            with jax.named_scope(LOSS):
+                ce = ce_sum / total_weight
+                loss = ce + aux
+                if mtp_weight:
+                    loss = loss + mtp_weight * (
+                        stats["mtp_ce_sum"] / stats["mtp_tokens"]
+                    )
             return loss, (ce, aux, total_weight, stats)
 
         grads, (ce, aux, total_weight, stats) = jax.grad(
@@ -996,7 +1034,7 @@ def build_sharded_train(
             "loss": ce,
             "aux_loss": aux,
             "tokens": total_weight,
-            "grad_norm": optax.global_norm(grads),
+            "grad_norm": _grad_norm(grads),
             "step": new_state.step,
         }
         metrics.update(_family_metrics(stats))
@@ -1019,20 +1057,21 @@ def build_sharded_train(
             arr = arr.reshape(grad_accum, micro, *arr.shape[1:])
             return jax.lax.with_sharding_constraint(arr, micro_sharding)
 
-        xs = {k: to_micro(k) for k in ("inputs", "targets", "weights")}
+        with jax.named_scope(GRAD_ACCUM):
+            xs = {k: to_micro(k) for k in ("inputs", "targets", "weights")}
         # The GLOBAL token count: known before the scan (weights are an
         # input), it normalizes every microbatch's CE-sum gradient so the
         # accumulated total equals the full-batch mean-CE gradient exactly
         # — not a mean-of-means, which would drift whenever microbatches
         # carry unequal token counts.
-        w_total = jnp.maximum(
-            batch["weights"].astype(jnp.float32).sum(), 1.0
-        )
-
-        # the MTP module's own count: every position but a row's last
-        mtp_total = jnp.maximum(
-            batch["weights"][:, 1:].astype(jnp.float32).sum(), 1.0
-        )
+        with jax.named_scope(LOSS):
+            w_total = jnp.maximum(
+                batch["weights"].astype(jnp.float32).sum(), 1.0
+            )
+            # the MTP module's own count: every position but a row's last
+            mtp_total = jnp.maximum(
+                batch["weights"][:, 1:].astype(jnp.float32).sum(), 1.0
+            )
 
         def micro_loss(params, mb):
             ce_sum, _w, aux, stats = _forward_sums(
@@ -1041,9 +1080,12 @@ def build_sharded_train(
             )
             # aux (model-internal regularizers) is a per-microbatch mean:
             # average it over N so its gradient scale matches full-batch.
-            loss = ce_sum / w_total + aux / grad_accum
-            if mtp_weight:
-                loss = loss + mtp_weight * stats["mtp_ce_sum"] / mtp_total
+            with jax.named_scope(LOSS):
+                loss = ce_sum / w_total + aux / grad_accum
+                if mtp_weight:
+                    loss = loss + (
+                        mtp_weight * stats["mtp_ce_sum"] / mtp_total
+                    )
             return loss, (ce_sum, aux, stats)
 
         params_shardings = state_shardings.params
@@ -1061,9 +1103,10 @@ def build_sharded_train(
                 jax.lax.with_sharding_constraint, tree, accum_shardings
             )
 
-        grads0 = pin(jax.tree.map(
-            lambda p: jnp.zeros(p.shape, accum_jdt), state.params
-        ))
+        with jax.named_scope(GRAD_ACCUM):
+            grads0 = pin(jax.tree.map(
+                lambda p: jnp.zeros(p.shape, accum_jdt), state.params
+            ))
 
         def accum(carry, mb):
             gacc, ce_acc, aux_acc = carry
@@ -1072,9 +1115,10 @@ def build_sharded_train(
             )(state.params, mb)
             if overlap_active:
                 g = _scatter_grads(g)
-            gacc = pin(jax.tree.map(
-                lambda a, gi: a + gi.astype(a.dtype), gacc, g
-            ))
+            with jax.named_scope(GRAD_ACCUM):
+                gacc = pin(jax.tree.map(
+                    lambda a, gi: a + gi.astype(a.dtype), gacc, g
+                ))
             # The microbatches' layer statistics stack as the scan's
             # output (empty, and no output, for a model that sows none).
             return (gacc, ce_acc + ce_sum, aux_acc + aux), stats
@@ -1106,12 +1150,14 @@ def build_sharded_train(
                 )
                 return fn(leaf)
 
-            grads = jax.tree.map(q_reduce, grads, params_shardings)
+            with jax.named_scope(OPTIMIZER_REDUCE):
+                grads = jax.tree.map(q_reduce, grads, params_shardings)
         # Hand the optimizer grads in the params' dtype (bf16 accumulation
         # is a wire/HBM format, not an update format).
-        grads = jax.tree.map(
-            lambda g, p: g.astype(p.dtype), grads, state.params
-        )
+        with jax.named_scope(GRAD_ACCUM):
+            grads = jax.tree.map(
+                lambda g, p: g.astype(p.dtype), grads, state.params
+            )
         new_state = _apply_update(state, grads, scattered=overlap_active)
         if ROUTER_LOADS in stats:
             # the step's loads are the microbatches' together
@@ -1119,11 +1165,13 @@ def build_sharded_train(
                 lambda load: load.mean(axis=0), stats[ROUTER_LOADS]
             )
         new_state = _family_update(state, new_state, stats)
+        with jax.named_scope(LOSS):
+            loss, aux_loss = ce_sum / w_total, aux_sum / grad_accum
         metrics = {
-            "loss": ce_sum / w_total,
-            "aux_loss": aux_sum / grad_accum,
+            "loss": loss,
+            "aux_loss": aux_loss,
             "tokens": w_total,
-            "grad_norm": optax.global_norm(grads),
+            "grad_norm": _grad_norm(grads),
             "step": new_state.step,
         }
         if "moe_stats" in stats:
@@ -1159,17 +1207,19 @@ def build_sharded_train(
             hidden, aux = state.apply_fn(
                 {"params": state.params}, batch["inputs"], return_hidden=True
             )
-            ce, total_weight = chunked_cross_entropy_loss(
-                hidden, output_head(state.params), batch["targets"],
-                batch["weights"], num_chunks=ce_chunks,
-            )
+            with jax.named_scope(LOSS):
+                ce, total_weight = chunked_cross_entropy_loss(
+                    hidden, output_head(state.params), batch["targets"],
+                    batch["weights"], num_chunks=ce_chunks,
+                )
         else:
             logits, aux = state.apply_fn(
                 {"params": state.params}, batch["inputs"]
             )
-            ce, total_weight = cross_entropy_loss(
-                logits, batch["targets"], batch["weights"]
-            )
+            with jax.named_scope(LOSS):
+                ce, total_weight = cross_entropy_loss(
+                    logits, batch["targets"], batch["weights"]
+                )
         return {"loss": ce, "aux_loss": aux, "tokens": total_weight}
 
     init_jit = jax.jit(

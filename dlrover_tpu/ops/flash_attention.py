@@ -10,9 +10,49 @@ style), so activation memory is O(S * D) instead of O(S^2).
 
 Block layout: grid (batch, q_heads, q_blocks, kv_blocks) with the kv axis
 innermost so the running (m, l, acc) state lives in VMEM scratch across kv
-steps.  Causal blocks above the diagonal are skipped via ``@pl.when`` — for
-long sequences that halves the FLOPs, which is exactly the regime the
-north-star benchmark (long-context goodput) cares about.
+steps.  With ONE kv block there is no state: a tile's rows are normalised
+and written straight out (the state's ``[rows, 1]`` column arithmetic, its
+stores and the finalize cost GPT-2's forward 0.4 of 1.65 ms a layer).
+
+A block's class.  Under a causal mask a ``(q block, kv block)`` pair is one
+of three things, a function of the shapes alone (:func:`block_classes`
+counts them, ``_block_class`` tells them apart inside a kernel), and every
+kernel here, forward, one-pass backward and the split pair, does only its
+class's work:
+
+* **dead** (the q block's last row precedes the kv block's first column):
+  nothing is computed (``pl.when``) and nothing is fetched.  A grid step
+  still happens, so its index maps name a block that is already there: the
+  kv-inner kernels (forward, ``_bwd_dq_kernel``) keep k, v and the kv
+  segment ids on the q block's LAST LIVE kv block (``_last_live_kv``), the
+  q-inner ones (``_bwd_fused_kernel``, ``_bwd_dkv_kernel``) keep q, do, o,
+  lse and the q segment ids on the kv block's FIRST LIVE q block
+  (``_first_live_q``), and the pipeline copies nothing for an index that
+  did not change.
+* **interior** (every row sees every column): no causal mask is built, no
+  iota, no compare.
+* **diagonal** (the rest).  Where ``block_q == block_kv`` the block is
+  worked as ROW STRIPS that end at the diagonal: strip ``r`` of
+  ``_strip_rows`` rows takes ``q[r h : (r + 1) h]`` against the first
+  ``(r + 1) h`` rows of k and v, static slices of blocks already in VMEM,
+  and updates only its own rows of m, l, acc (forward) or of dq, and the
+  first ``(r + 1) h`` rows of the dk / dv accumulators (backward).  Four
+  strips do 10 of a block's 16 sub-tiles.  A row sees the same columns as
+  in the masked square, so every sum holds the same terms.  Blocks of other
+  shapes (``block_q != block_kv``, or too small for two strips of whole
+  128-lane tiles) keep the masked square.
+
+Non-causal attention has interior blocks only.
+
+The segment compare is built only where ids were given or a length was
+padded (``segments``, a static fact of the call :func:`mha` works out);
+then every class keeps it, interior blocks too, and all-masked rows are
+guarded.  Without it a row always sees column 0, so its running maximum is
+finite after the first kv block and the guards go as well.  The segment
+operands stay in every kernel's signature, unread.  ``scale`` rides the
+``[rows, d]`` q tile where that is exact, a power of two (head 64), and
+the ``[rows, cols]`` score tile otherwise: rounding ``q * scale`` to
+bfloat16 would move the scores.
 
 Backward: ONE pass (``_bwd_fused_kernel``: s, the mask, p, dp and ds once
 a block feed dq, dk and dv; 5 matmuls a block) wherever its dq accumulator
@@ -39,7 +79,8 @@ pad region is masked via an implicit segment id (pad tokens attend nowhere).
 from __future__ import annotations
 
 import functools
-from typing import Optional
+import math
+from typing import NamedTuple, Optional
 
 import jax
 import jax.ad_checkpoint
@@ -58,6 +99,8 @@ _STAT = 8
 # The most VMEM the one-pass backward may ask for: half of a v5e core's
 # 128 MiB (a kernel that asks for nothing gets 16 MiB).
 _VMEM_CAP = 64 << 20
+# Most rows of a diagonal block's strip (chosen on a v5e, PERF.md §6 PR 36).
+_STRIP = 256
 
 
 def _pad_to(x, size, axis, value=0):
@@ -82,84 +125,231 @@ def _blocks_and_padding(sq, skv, block_q, block_kv):
 
 
 # ---------------------------------------------------------------------------
+# a block's class
+# ---------------------------------------------------------------------------
+
+
+class BlockClasses(NamedTuple):
+    """How many blocks of each class one (batch, head) holds, and the rows
+    of a diagonal block's strips (0: the masked square)."""
+
+    dead: int
+    interior: int
+    diagonal: int
+    strip: int
+
+
+def _block_class(iq, ik, block_q, block_kv, causal):
+    """``(dead, interior)`` of block ``(iq, ik)``, Python or traced; a block
+    that is neither is diagonal."""
+    if not causal:
+        return False, True
+    q_start, kv_start = iq * block_q, ik * block_kv
+    return (
+        q_start + block_q - 1 < kv_start,
+        kv_start + block_kv - 1 <= q_start,
+    )
+
+
+def _strip_rows(block_q, block_kv) -> int:
+    """Rows of a diagonal block's strips, 0 to keep the masked square.  A
+    strip's width lands on the score tile's lanes and its height on q's
+    sublanes, so it is whole 128-lane tiles, and a block holds at least
+    two."""
+    rows = min(_STRIP, block_q // 2)
+    if block_q != block_kv or rows % _LANE or block_q % rows:
+        return 0
+    return rows
+
+
+def block_classes(sq, skv, block_q, block_kv, causal) -> BlockClasses:
+    """The classes of an attention of these sizes, given as :func:`mha`'s
+    caller gives them (clamped and padded here as there)."""
+    block_q, block_kv, sq, skv = _blocks_and_padding(
+        sq, skv, block_q, block_kv
+    )
+    nq, nk = sq // block_q, skv // block_kv
+    if not causal:
+        return BlockClasses(0, nq * nk, 0, 0)
+    dead = interior = 0
+    for iq in range(nq):
+        for ik in range(nk):
+            is_dead, is_interior = _block_class(iq, ik, block_q, block_kv, True)
+            dead += is_dead
+            interior += is_interior
+    diagonal = nq * nk - dead - interior
+    strip = _strip_rows(block_q, block_kv) if diagonal else 0
+    return BlockClasses(dead, interior, diagonal, strip)
+
+
+def _last_live_kv(iq, ik, block_q, block_kv, causal):
+    """The kv block a kv-inner grid step names: its own, or on a dead step
+    the q block's last live one, which is already in VMEM."""
+    if not causal:
+        return ik
+    return jnp.minimum(ik, (iq * block_q + block_q - 1) // block_kv)
+
+
+def _first_live_q(iq, ik, nq, block_q, block_kv, causal):
+    """The q block a q-inner grid step names: its own, or on a dead step
+    the kv block's first live one, which the pipeline then fetches once and
+    not for every dead step (JoyAI's layer 35.0 -> 31.2 ms on a v5e,
+    PERF.md §6 PR 34).  A kv block past the last q row (more keys than
+    queries) has no live q block: its steps stay on the last of the
+    ``nq``."""
+    if not causal:
+        return iq
+    return jnp.maximum(iq, jnp.minimum((ik * block_kv) // block_q, nq - 1))
+
+
+def _tiles(block_q, block_kv, strip):
+    """Static ``(rows, cols)`` slices that cover a block's live part: the
+    whole block, or the strips of a diagonal one."""
+    if not strip:
+        return [(slice(0, block_q), slice(0, block_kv))]
+    return [
+        (slice(r * strip, (r + 1) * strip), slice(0, (r + 1) * strip))
+        for r in range(block_q // strip)
+    ]
+
+
+def _for_live_class(iq, ik, compute, *, causal, block_q, block_kv, classes):
+    """Run ``compute(causal_offset, tiles)`` as block ``(iq, ik)``'s class
+    asks: not at all (dead), over the whole block with no causal mask
+    (interior, ``causal_offset`` None), or over a diagonal block's tiles,
+    where row ``i`` of the block sees column ``j`` while ``i + causal_offset
+    >= j``.  A class the shapes do not hold gets no branch."""
+    whole = _tiles(block_q, block_kv, 0)
+    if not causal:
+        compute(None, whole)
+        return
+    dead, interior = _block_class(iq, ik, block_q, block_kv, True)
+    if classes.interior:
+        pl.when(interior)(lambda: compute(None, whole))
+    if classes.diagonal:
+        # Blocks of one size meet the diagonal where iq == ik: a static
+        # offset, so the mask is a constant (JoyAI's step 1738.5 -> 1735.6
+        # ms on a v5e against the traced difference, PERF.md §6 PR 36).
+        offset = 0 if block_q == block_kv else iq * block_q - ik * block_kv
+        pl.when(jnp.logical_not(jnp.logical_or(dead, interior)))(
+            lambda: compute(
+                offset, _tiles(block_q, block_kv, classes.strip)
+            )
+        )
+
+
+def _masked(x, fill, rows, cols, causal_offset, seg_q_ref, seg_kv_ref,
+            segments):
+    """``x``, the scores or probabilities of tile ``(rows, cols)`` of a
+    block, with ``fill`` where a row may not see a column; ``x`` itself
+    where nothing is masked."""
+    mask = None
+    if causal_offset is not None:
+        shape = (rows.stop - rows.start, cols.stop - cols.start)
+        row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        mask = row + (causal_offset + rows.start - cols.start) >= col
+    if segments:
+        seg = seg_q_ref[0, 0, rows][:, None] == seg_kv_ref[0, 0, cols][None, :]
+        mask = seg if mask is None else jnp.logical_and(mask, seg)
+    return x if mask is None else jnp.where(mask, x, fill)
+
+
+def _scores(q, k, scale):
+    """``(q k^T) * scale`` in float32: matmul inputs stay bf16 (MXU native
+    rate), accumulation is fp32 via preferred_element_type — the standard
+    flash-attention numerics.  A power of two scales any float exactly, so
+    it rides the narrow q tile and not the score tile."""
+    exact = scale > 0 and math.frexp(scale)[0] == 0.5
+    s = jax.lax.dot_general(
+        q * scale if exact else q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    return s if exact else s * scale
+
+
+# ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
 
 
 def _fwd_kernel(
     seg_q_ref, seg_kv_ref, q_ref, k_ref, v_ref,
-    o_ref, lse_ref,
-    m_ref, l_ref, acc_ref,
-    *, causal: bool, scale: float, block_q: int, block_kv: int,
+    o_ref, lse_ref, *state,
+    causal: bool, scale: float, block_q: int, block_kv: int,
+    classes: BlockClasses, segments: bool,
 ):
+    """``state`` is the running (m, l, acc) scratch of several kv blocks.
+    With ONE kv block it is empty: every row is visited once, and its tile
+    is normalised and written straight out."""
     iq, ik = pl.program_id(2), pl.program_id(3)
     nk = pl.num_programs(3)
 
-    @pl.when(ik == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    q_start = iq * block_q
-    kv_start = ik * block_kv
-    # Whole-block causal skip: the earliest q row can't see this kv block.
-    run = (not causal) or (q_start + block_q - 1 >= kv_start)
-
-    @pl.when(run)
-    def _compute():
-        # Matmul inputs stay bf16 (MXU native rate); accumulation is fp32 via
-        # preferred_element_type — the standard flash-attention numerics.
-        q = q_ref[0, 0]  # [block_q, d]
-        k = k_ref[0, 0]  # [block_kv, d]
-        v = v_ref[0, 0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale  # [block_q, block_kv]
-
-        mask = None
-        if causal:
-            rows = q_start + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_kv), 0
-            )
-            cols = kv_start + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_kv), 1
-            )
-            mask = rows >= cols
-        seg_q = seg_q_ref[0, 0]  # [block_q]
-        seg_kv = seg_kv_ref[0, 0]  # [block_kv]
-        seg = seg_q[:, None] == seg_kv[None, :]
-        mask = seg if mask is None else jnp.logical_and(mask, seg)
-        s = jnp.where(mask, s, NEG_INF)
-
-        m_prev = m_ref[:, 0][:, None]  # [block_q, 1]
-        m_cur = jnp.max(s, axis=1)[:, None]
-        m_new = jnp.maximum(m_prev, m_cur)
-        # All-masked rows keep m at NEG_INF; freeze them to avoid inf-inf.
-        p = jnp.exp(s - m_new)
-        p = jnp.where(m_new == NEG_INF, 0.0, p)
-        correction = jnp.exp(m_prev - m_new)
-        correction = jnp.where(m_prev == NEG_INF, 0.0, correction)
-        l_new = correction * l_ref[:, 0][:, None] + jnp.sum(p, axis=1)[:, None]
-        acc_ref[:] = acc_ref[:] * correction + jax.lax.dot(
-            p.astype(v.dtype), v, preferred_element_type=jnp.float32
-        )
-        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
-
-    @pl.when(ik == nk - 1)
-    def _finalize():
-        l = l_ref[:, 0][:, None]
+    def _write(rows, m, l, acc):
         safe_l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_ref[:] / safe_l).astype(o_ref.dtype)
-        m = m_ref[:, 0][:, None]
+        o_ref[0, 0, rows, :] = (acc / safe_l).astype(o_ref.dtype)
         lse = jnp.where(l == 0.0, NEG_INF, m + jnp.log(safe_l))
-        lse_ref[0, 0] = jnp.broadcast_to(lse, lse_ref.shape[2:])
+        lse_ref[0, 0, rows, :] = jnp.broadcast_to(lse, (lse.shape[0], _STAT))
+
+    if state:
+        m_ref, l_ref, acc_ref = state
+
+        @pl.when(ik == 0)
+        def _init():
+            m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+            l_ref[:] = jnp.zeros_like(l_ref)
+            acc_ref[:] = jnp.zeros_like(acc_ref)
+
+    def _compute(causal_offset, tiles):
+        for rows, cols in tiles:
+            v = v_ref[0, 0, cols, :]
+            s = _scores(q_ref[0, 0, rows, :], k_ref[0, 0, cols, :], scale)
+            s = _masked(
+                s, NEG_INF, rows, cols, causal_offset, seg_q_ref, seg_kv_ref,
+                segments,
+            )
+
+            m_new = jnp.max(s, axis=1)[:, None]  # [rows, 1]
+            if state:
+                m_prev = m_ref[rows, 0][:, None]
+                m_new = jnp.maximum(m_prev, m_new)
+            p = jnp.exp(s - m_new)
+            if segments:
+                # All-masked rows keep m at NEG_INF; freeze them to avoid
+                # inf-inf.  Without segments column 0 is live for every row.
+                p = jnp.where(m_new == NEG_INF, 0.0, p)
+            l_new = jnp.sum(p, axis=1)[:, None]
+            pv = jax.lax.dot(
+                p.astype(v.dtype), v, preferred_element_type=jnp.float32
+            )
+            if not state:
+                _write(rows, m_new, l_new, pv)
+                continue
+            correction = jnp.exp(m_prev - m_new)
+            if segments:
+                correction = jnp.where(m_prev == NEG_INF, 0.0, correction)
+            l_new += correction * l_ref[rows, 0][:, None]
+            acc_ref[rows, :] = acc_ref[rows, :] * correction + pv
+            m_ref[rows, :] = jnp.broadcast_to(m_new, (m_new.shape[0], _LANE))
+            l_ref[rows, :] = jnp.broadcast_to(l_new, (l_new.shape[0], _LANE))
+
+    _for_live_class(
+        iq, ik, _compute, causal=causal, block_q=block_q, block_kv=block_kv,
+        classes=classes,
+    )
+
+    if state:
+        @pl.when(ik == nk - 1)
+        def _finalize():
+            _write(
+                slice(0, block_q), m_ref[:, 0][:, None], l_ref[:, 0][:, None],
+                acc_ref[:],
+            )
 
 
 def _flash_fwd(
-    q, k, v, seg_q, seg_kv, *, causal, scale, block_q, block_kv
+    q, k, v, seg_q, seg_kv, *, causal, scale, block_q, block_kv,
+    segments,
 ):
     """q [B,Hq,S,D], k [B,Hkv,S,D], v [B,Hkv,S,Dv], seg [B,S] ->
     (o [B,Hq,S,Dv], lse).  ``Dv`` may differ from ``D`` (latent attention:
@@ -170,10 +360,18 @@ def _flash_fwd(
     group = hq // hkv
     nq, nk = sq // block_q, skv // block_kv
 
+    def kv_block(iq, ik):
+        return _last_live_kv(iq, ik, block_q, block_kv, causal)
+
+    def kv_rows(ib, ih, iq, ik):
+        return (ib, ih // group, kv_block(iq, ik), 0)
+
     grid = (b, hq, nq, nk)
     kernel = functools.partial(
         _fwd_kernel, causal=causal, scale=scale,
         block_q=block_q, block_kv=block_kv,
+        classes=block_classes(sq, skv, block_q, block_kv, causal),
+        segments=segments,
     )
     out_shape = [
         jax.ShapeDtypeStruct((b, hq, sq, d_v), q.dtype),
@@ -184,18 +382,15 @@ def _flash_fwd(
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, block_q), lambda ib, ih, iq, ik: (ib, 0, iq)),
-            pl.BlockSpec((1, 1, block_kv), lambda ib, ih, iq, ik: (ib, 0, ik)),
+            pl.BlockSpec(
+                (1, 1, block_kv),
+                lambda ib, ih, iq, ik: (ib, 0, kv_block(iq, ik)),
+            ),
             pl.BlockSpec(
                 (1, 1, block_q, d), lambda ib, ih, iq, ik: (ib, ih, iq, 0)
             ),
-            pl.BlockSpec(
-                (1, 1, block_kv, d),
-                lambda ib, ih, iq, ik, g=group: (ib, ih // g, ik, 0),
-            ),
-            pl.BlockSpec(
-                (1, 1, block_kv, d_v),
-                lambda ib, ih, iq, ik, g=group: (ib, ih // g, ik, 0),
-            ),
+            pl.BlockSpec((1, 1, block_kv, d), kv_rows),
+            pl.BlockSpec((1, 1, block_kv, d_v), kv_rows),
         ],
         out_specs=[
             pl.BlockSpec(
@@ -206,7 +401,7 @@ def _flash_fwd(
                 lambda ib, ih, iq, ik: (ib, ih, iq, 0),
             ),
         ],
-        scratch_shapes=[
+        scratch_shapes=[] if nk == 1 else [
             pltpu.VMEM((block_q, _LANE), jnp.float32),
             pltpu.VMEM((block_q, _LANE), jnp.float32),
             pltpu.VMEM((block_q, d_v), jnp.float32),
@@ -222,65 +417,50 @@ def _flash_fwd(
 # ---------------------------------------------------------------------------
 
 
-def _block_mask(causal, q_start, kv_start, seg_q_ref, seg_kv_ref,
-                block_q, block_kv):
-    mask = None
-    if causal:
-        rows = q_start + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_kv), 0
-        )
-        cols = kv_start + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_kv), 1
-        )
-        mask = rows >= cols
-    seg = seg_q_ref[0, 0][:, None] == seg_kv_ref[0, 0][None, :]
-    return seg if mask is None else jnp.logical_and(mask, seg)
-
-
 def _recompute_p_ds(
     q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref,
     seg_q_ref, seg_kv_ref,
-    *, causal, scale, q_start, kv_start, block_q, block_kv,
+    *, rows, cols, causal_offset, scale, segments,
 ):
-    """Shared backward block math: probabilities p and score-grads ds.
+    """Shared backward math of one tile of a block (``_tiles``: the whole
+    block, or a diagonal block's strip): probabilities p, score-grads ds,
+    and the tile's q, k and do as dk, dq and dv contract them.
 
     The softmax recompute from lse and its masking MUST be identical across
-    the dq / dkv / fused kernels — one traced helper keeps them in sync.
+    the dq / dkv / fused kernels — one traced helper keeps them in sync,
+    so that they sum the same terms in the same order.
 
     ``delta = rowsum(o * do)`` is computed IN-KERNEL from the o block (the
     head dim is whole per block, so the row sum is exact) instead of in a
     separate XLA fusion — that fusion plus the padded [B,H,S,STAT] delta
     array cost ~1 ms/layer of pure HBM traffic at bench shapes.
     """
-    q = q_ref[0, 0]
-    k = k_ref[0, 0]
-    v = v_ref[0, 0]
-    do = do_ref[0, 0]
-    lse = lse_ref[0, 0][:, 0][:, None]
+    k = k_ref[0, 0, cols, :]
+    v = v_ref[0, 0, cols, :]
+    do = do_ref[0, 0, rows, :]
+    lse = lse_ref[0, 0, rows, :][:, 0][:, None]
     delta = jnp.sum(
-        o_ref[0, 0].astype(jnp.float32) * do.astype(jnp.float32),
+        o_ref[0, 0, rows, :].astype(jnp.float32) * do.astype(jnp.float32),
         axis=1, keepdims=True,
     )
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ) * scale
-    mask = _block_mask(
-        causal, q_start, kv_start, seg_q_ref, seg_kv_ref, block_q, block_kv
+    q = q_ref[0, 0, rows, :]
+    p = _masked(
+        jnp.exp(_scores(q, k, scale) - lse), 0.0,
+        rows, cols, causal_offset, seg_q_ref, seg_kv_ref, segments,
     )
-    p = jnp.where(mask, jnp.exp(s - lse), 0.0)
     dp = jax.lax.dot_general(
         do, v, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
-    ds = (p * (dp - delta) * scale).astype(q.dtype)
-    return p, ds
+    ds = p * (dp - delta) * scale
+    return p, ds.astype(q.dtype), q, k, do
 
 
 def _bwd_dq_kernel(
     seg_q_ref, seg_kv_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref,
     dq_ref, dq_acc_ref,
     *, causal: bool, scale: float, block_q: int, block_kv: int,
+    classes: BlockClasses, segments: bool,
 ):
     iq, ik = pl.program_id(2), pl.program_id(3)
     nk = pl.num_programs(3)
@@ -289,30 +469,44 @@ def _bwd_dq_kernel(
     def _init():
         dq_acc_ref[:] = jnp.zeros_like(dq_acc_ref)
 
-    q_start, kv_start = iq * block_q, ik * block_kv
-    run = (not causal) or (q_start + block_q - 1 >= kv_start)
+    def _compute(causal_offset, tiles):
+        for rows, cols in tiles:
+            _, ds, _, k, _ = _recompute_p_ds(
+                q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref,
+                seg_q_ref, seg_kv_ref,
+                rows=rows, cols=cols, causal_offset=causal_offset,
+                scale=scale, segments=segments,
+            )
+            dq_acc_ref[rows, :] += jax.lax.dot(
+                ds, k, preferred_element_type=jnp.float32
+            )
 
-    @pl.when(run)
-    def _compute():
-        _, ds = _recompute_p_ds(
-            q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref,
-            seg_q_ref, seg_kv_ref,
-            causal=causal, scale=scale, q_start=q_start, kv_start=kv_start,
-            block_q=block_q, block_kv=block_kv,
-        )
-        dq_acc_ref[:] += jax.lax.dot(
-            ds, k_ref[0, 0], preferred_element_type=jnp.float32
-        )
+    _for_live_class(
+        iq, ik, _compute, causal=causal, block_q=block_q, block_kv=block_kv,
+        classes=classes,
+    )
 
     @pl.when(ik == nk - 1)
     def _finalize():
         dq_ref[0, 0] = dq_acc_ref[:].astype(dq_ref.dtype)
 
 
+def _add_dk_dv(dk_acc_ref, dv_acc_ref, cols, p, ds, q, do):
+    dv_acc_ref[cols, :] += jax.lax.dot_general(
+        p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    dk_acc_ref[cols, :] += jax.lax.dot_general(
+        ds, q, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+
+
 def _bwd_dkv_kernel(
     seg_q_ref, seg_kv_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref,
     dk_ref, dv_ref, dk_acc_ref, dv_acc_ref,
     *, causal: bool, scale: float, block_q: int, block_kv: int,
+    classes: BlockClasses, segments: bool,
 ):
     ik, iq = pl.program_id(2), pl.program_id(3)  # note: kv outer, q inner
     nq = pl.num_programs(3)
@@ -322,26 +516,20 @@ def _bwd_dkv_kernel(
         dk_acc_ref[:] = jnp.zeros_like(dk_acc_ref)
         dv_acc_ref[:] = jnp.zeros_like(dv_acc_ref)
 
-    q_start, kv_start = iq * block_q, ik * block_kv
-    run = (not causal) or (q_start + block_q - 1 >= kv_start)
+    def _compute(causal_offset, tiles):
+        for rows, cols in tiles:
+            p, ds, q, _, do = _recompute_p_ds(
+                q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref,
+                seg_q_ref, seg_kv_ref,
+                rows=rows, cols=cols, causal_offset=causal_offset,
+                scale=scale, segments=segments,
+            )
+            _add_dk_dv(dk_acc_ref, dv_acc_ref, cols, p, ds, q, do)
 
-    @pl.when(run)
-    def _compute():
-        p, ds = _recompute_p_ds(
-            q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref,
-            seg_q_ref, seg_kv_ref,
-            causal=causal, scale=scale, q_start=q_start, kv_start=kv_start,
-            block_q=block_q, block_kv=block_kv,
-        )
-        do = do_ref[0, 0]
-        dv_acc_ref[:] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dk_acc_ref[:] += jax.lax.dot_general(
-            ds, q_ref[0, 0], (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+    _for_live_class(
+        iq, ik, _compute, causal=causal, block_q=block_q, block_kv=block_kv,
+        classes=classes,
+    )
 
     @pl.when(iq == nq - 1)
     def _finalize():
@@ -353,6 +541,7 @@ def _bwd_fused_kernel(
     seg_q_ref, seg_kv_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref,
     dq_ref, dk_ref, dv_ref, dk_acc_ref, dv_acc_ref, *dq_acc,
     causal: bool, scale: float, block_q: int, block_kv: int,
+    classes: BlockClasses, segments: bool,
 ):
     """One-pass backward: s/p computed once feed dq, dk AND dv.
 
@@ -381,44 +570,36 @@ def _bwd_fused_kernel(
         dk_acc_ref[:] = jnp.zeros_like(dk_acc_ref)
         dv_acc_ref[:] = jnp.zeros_like(dv_acc_ref)
 
-    q_start, kv_start = iq * block_q, ik * block_kv
-    # ik == 0 always runs under causal (kv_start 0), so the dq init below
-    # is guaranteed to execute for every q block.
-    run = (not causal) or (q_start + block_q - 1 >= kv_start)
+    q_start = iq * block_q
 
-    @pl.when(run)
-    def _compute():
-        p, ds = _recompute_p_ds(
-            q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref,
-            seg_q_ref, seg_kv_ref,
-            causal=causal, scale=scale, q_start=q_start, kv_start=kv_start,
-            block_q=block_q, block_kv=block_kv,
-        )
-        do = do_ref[0, 0]
-        dv_acc_ref[:] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dk_acc_ref[:] += jax.lax.dot_general(
-            ds, q_ref[0, 0], (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dq = jax.lax.dot(
-            ds, k_ref[0, 0], preferred_element_type=jnp.float32
-        )
-        if not dq_acc:
-            dq_ref[0, 0] = dq.astype(dq_ref.dtype)
-            return
-        (dq_acc_ref,) = dq_acc
-        rows = pl.ds(pl.multiple_of(q_start, block_q), block_q)
+    # ik == 0 always runs under causal (kv_start 0), so the dq init below
+    # is guaranteed to execute for every row of every q block.
+    def _compute(causal_offset, tiles):
+        for rows, cols in tiles:
+            p, ds, q, k, do = _recompute_p_ds(
+                q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref,
+                seg_q_ref, seg_kv_ref,
+                rows=rows, cols=cols, causal_offset=causal_offset,
+                scale=scale, segments=segments,
+            )
+            _add_dk_dv(dk_acc_ref, dv_acc_ref, cols, p, ds, q, do)
+            dq = jax.lax.dot(ds, k, preferred_element_type=jnp.float32)
+            if not dq_acc:
+                dq_ref[0, 0, rows, :] = dq.astype(dq_ref.dtype)
+                continue
+            _add_dq(dq, rows, *dq_acc)
+
+    def _add_dq(dq, rows, dq_acc_ref):
+        height = rows.stop - rows.start
+        at = pl.ds(pl.multiple_of(q_start + rows.start, height), height)
 
         @pl.when(ik == 0)
         def _first():
-            dq_acc_ref[rows, :] = dq
+            dq_acc_ref[at, :] = dq
 
         @pl.when(ik > 0)
         def _later():
-            dq_acc_ref[rows, :] += dq
+            dq_acc_ref[at, :] += dq
 
         last = pl.num_programs(2) - 1
         if causal:
@@ -426,7 +607,12 @@ def _bwd_fused_kernel(
 
         @pl.when(ik == last)
         def _write():
-            dq_ref[0, 0, rows, :] = dq_acc_ref[rows, :].astype(dq_ref.dtype)
+            dq_ref[0, 0, at, :] = dq_acc_ref[at, :].astype(dq_ref.dtype)
+
+    _for_live_class(
+        iq, ik, _compute, causal=causal, block_q=block_q, block_kv=block_kv,
+        classes=classes,
+    )
 
     @pl.when(iq == nq - 1)
     def _finalize_kv():
@@ -456,7 +642,7 @@ def _fused_bwd_vmem_bytes(sq, d, d_v, block_q, block_kv, dtype) -> int:
 
 def _flash_bwd_fused(
     q, k, v, seg_q, seg_kv, o, lse, do,
-    *, causal, scale, block_q, block_kv
+    *, causal, scale, block_q, block_kv, segments,
 ):
     b, hq, sq, d = q.shape
     hkv, skv, d_v = k.shape[1], k.shape[2], v.shape[3]
@@ -466,10 +652,13 @@ def _flash_bwd_fused(
     lse_l = jnp.broadcast_to(lse[..., None], (*lse.shape, _STAT))
 
     def q_block(ik, iq):
-        return iq
+        return _first_live_q(iq, ik, nq, block_q, block_kv, causal)
 
     def q_rows(ib, ih, ik, iq):
         return (ib, ih, q_block(ik, iq), 0)
+
+    def kv_rows(ib, ih, ik, iq):
+        return (ib, ih // group, ik, 0)
 
     dq_spec = pl.BlockSpec((1, 1, block_q, d), q_rows)
     scratch = [
@@ -493,18 +682,13 @@ def _flash_bwd_fused(
             ),
             name="flash_bwd_one_pass",
         )
-        if causal:
-            # A step above the diagonal computes nothing: let it name the
-            # q-side blocks of the kv block's first live step, which the
-            # pipeline then fetches once and not for every dead step
-            # (JoyAI's layer 35.0 -> 31.2 ms on a v5e, PERF.md §6 PR 34).
-            def q_block(ik, iq):
-                return jnp.maximum(iq, (ik * block_kv) // block_q)
 
     dq, dk, dv = pl.pallas_call(
         functools.partial(
             _bwd_fused_kernel, causal=causal, scale=scale,
             block_q=block_q, block_kv=block_kv,
+            classes=block_classes(sq, skv, block_q, block_kv, causal),
+            segments=segments,
         ),
         grid=(b, hq, nk, nq),
         in_specs=[
@@ -514,14 +698,8 @@ def _flash_bwd_fused(
             ),
             pl.BlockSpec((1, 1, block_kv), lambda ib, ih, ik, iq: (ib, 0, ik)),
             pl.BlockSpec((1, 1, block_q, d), q_rows),
-            pl.BlockSpec(
-                (1, 1, block_kv, d),
-                lambda ib, ih, ik, iq, g=group: (ib, ih // g, ik, 0),
-            ),
-            pl.BlockSpec(
-                (1, 1, block_kv, d_v),
-                lambda ib, ih, ik, iq, g=group: (ib, ih // g, ik, 0),
-            ),
+            pl.BlockSpec((1, 1, block_kv, d), kv_rows),
+            pl.BlockSpec((1, 1, block_kv, d_v), kv_rows),
             pl.BlockSpec((1, 1, block_q, d_v), q_rows),
             pl.BlockSpec((1, 1, block_q, _STAT), q_rows),
             pl.BlockSpec((1, 1, block_q, d_v), q_rows),
@@ -552,7 +730,7 @@ def _flash_bwd_fused(
 
 def _flash_bwd(
     q, k, v, seg_q, seg_kv, o, lse, do,
-    *, causal, scale, block_q, block_kv
+    *, causal, scale, block_q, block_kv, segments,
 ):
     b, hq, sq, d = q.shape
     hkv, skv, d_v = k.shape[1], k.shape[2], v.shape[3]
@@ -562,75 +740,70 @@ def _flash_bwd(
     lse_l = jnp.broadcast_to(lse[..., None], (*lse.shape, _STAT))
 
     common_in = [seg_q, seg_kv, q, k, v, do, lse_l, o]
-    lane_spec_q = pl.BlockSpec(
-        (1, 1, block_q, _STAT), lambda ib, ih, iq, ik: (ib, ih, iq, 0)
+    static = dict(
+        causal=causal, scale=scale, block_q=block_q, block_kv=block_kv,
+        classes=block_classes(sq, skv, block_q, block_kv, causal),
+        segments=segments,
     )
+
+    # dq: kv inner, so a dead step parks the kv side (as the forward's).
+    def kv_block(iq, ik):
+        return _last_live_kv(iq, ik, block_q, block_kv, causal)
+
+    def q_rows(ib, ih, iq, ik):
+        return (ib, ih, iq, 0)
+
+    def parked_kv_rows(ib, ih, iq, ik):
+        return (ib, ih // group, kv_block(iq, ik), 0)
+
     dq = pl.pallas_call(
-        functools.partial(
-            _bwd_dq_kernel, causal=causal, scale=scale,
-            block_q=block_q, block_kv=block_kv,
-        ),
+        functools.partial(_bwd_dq_kernel, **static),
         grid=(b, hq, nq, nk),
         in_specs=[
             pl.BlockSpec((1, 1, block_q), lambda ib, ih, iq, ik: (ib, 0, iq)),
-            pl.BlockSpec((1, 1, block_kv), lambda ib, ih, iq, ik: (ib, 0, ik)),
             pl.BlockSpec(
-                (1, 1, block_q, d), lambda ib, ih, iq, ik: (ib, ih, iq, 0)
+                (1, 1, block_kv),
+                lambda ib, ih, iq, ik: (ib, 0, kv_block(iq, ik)),
             ),
-            pl.BlockSpec(
-                (1, 1, block_kv, d),
-                lambda ib, ih, iq, ik, g=group: (ib, ih // g, ik, 0),
-            ),
-            pl.BlockSpec(
-                (1, 1, block_kv, d_v),
-                lambda ib, ih, iq, ik, g=group: (ib, ih // g, ik, 0),
-            ),
-            pl.BlockSpec(
-                (1, 1, block_q, d_v), lambda ib, ih, iq, ik: (ib, ih, iq, 0)
-            ),
-            lane_spec_q,
-            pl.BlockSpec(
-                (1, 1, block_q, d_v), lambda ib, ih, iq, ik: (ib, ih, iq, 0)
-            ),
+            pl.BlockSpec((1, 1, block_q, d), q_rows),
+            pl.BlockSpec((1, 1, block_kv, d), parked_kv_rows),
+            pl.BlockSpec((1, 1, block_kv, d_v), parked_kv_rows),
+            pl.BlockSpec((1, 1, block_q, d_v), q_rows),
+            pl.BlockSpec((1, 1, block_q, _STAT), q_rows),
+            pl.BlockSpec((1, 1, block_q, d_v), q_rows),
         ],
-        out_specs=pl.BlockSpec(
-            (1, 1, block_q, d), lambda ib, ih, iq, ik: (ib, ih, iq, 0)
-        ),
+        out_specs=pl.BlockSpec((1, 1, block_q, d), q_rows),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         out_shape=jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
         interpret=backend.interpret(),
     )(*common_in)
 
     # dk/dv: one pass per q-head; accumulated per kv head afterwards (GQA).
+    # q inner, so a dead step parks the q side (as the one pass's).
+    def q_block(ik, iq):
+        return _first_live_q(iq, ik, nq, block_q, block_kv, causal)
+
+    def parked_q_rows(ib, ih, ik, iq):
+        return (ib, ih, q_block(ik, iq), 0)
+
+    def kv_rows(ib, ih, ik, iq):
+        return (ib, ih // group, ik, 0)
+
     dk, dv = pl.pallas_call(
-        functools.partial(
-            _bwd_dkv_kernel, causal=causal, scale=scale,
-            block_q=block_q, block_kv=block_kv,
-        ),
+        functools.partial(_bwd_dkv_kernel, **static),
         grid=(b, hq, nk, nq),
         in_specs=[
-            pl.BlockSpec((1, 1, block_q), lambda ib, ih, ik, iq: (ib, 0, iq)),
+            pl.BlockSpec(
+                (1, 1, block_q),
+                lambda ib, ih, ik, iq: (ib, 0, q_block(ik, iq)),
+            ),
             pl.BlockSpec((1, 1, block_kv), lambda ib, ih, ik, iq: (ib, 0, ik)),
-            pl.BlockSpec(
-                (1, 1, block_q, d), lambda ib, ih, ik, iq: (ib, ih, iq, 0)
-            ),
-            pl.BlockSpec(
-                (1, 1, block_kv, d),
-                lambda ib, ih, ik, iq, g=group: (ib, ih // g, ik, 0),
-            ),
-            pl.BlockSpec(
-                (1, 1, block_kv, d_v),
-                lambda ib, ih, ik, iq, g=group: (ib, ih // g, ik, 0),
-            ),
-            pl.BlockSpec(
-                (1, 1, block_q, d_v), lambda ib, ih, ik, iq: (ib, ih, iq, 0)
-            ),
-            pl.BlockSpec(
-                (1, 1, block_q, _STAT), lambda ib, ih, ik, iq: (ib, ih, iq, 0)
-            ),
-            pl.BlockSpec(
-                (1, 1, block_q, d_v), lambda ib, ih, ik, iq: (ib, ih, iq, 0)
-            ),
+            pl.BlockSpec((1, 1, block_q, d), parked_q_rows),
+            pl.BlockSpec((1, 1, block_kv, d), kv_rows),
+            pl.BlockSpec((1, 1, block_kv, d_v), kv_rows),
+            pl.BlockSpec((1, 1, block_q, d_v), parked_q_rows),
+            pl.BlockSpec((1, 1, block_q, _STAT), parked_q_rows),
+            pl.BlockSpec((1, 1, block_q, d_v), parked_q_rows),
         ],
         out_specs=[
             pl.BlockSpec(
@@ -677,20 +850,24 @@ def backward_path(sq, skv, d, d_v, block_q, block_kv, dtype) -> str:
 
 
 @functools.partial(
-    jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8)
+    jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9)
 )
-def _flash_core(q, k, v, seg_q, seg_kv, causal, scale, block_q, block_kv):
+def _flash_core(
+    q, k, v, seg_q, seg_kv, causal, scale, block_q, block_kv, segments
+):
     o, _ = _flash_fwd(
-        q, k, v, seg_q, seg_kv,
-        causal=causal, scale=scale, block_q=block_q, block_kv=block_kv,
+        q, k, v, seg_q, seg_kv, causal=causal, scale=scale,
+        block_q=block_q, block_kv=block_kv, segments=segments,
     )
     return o
 
 
-def _flash_core_fwd(q, k, v, seg_q, seg_kv, causal, scale, block_q, block_kv):
+def _flash_core_fwd(
+    q, k, v, seg_q, seg_kv, causal, scale, block_q, block_kv, segments
+):
     o, lse = _flash_fwd(
-        q, k, v, seg_q, seg_kv,
-        causal=causal, scale=scale, block_q=block_q, block_kv=block_kv,
+        q, k, v, seg_q, seg_kv, causal=causal, scale=scale,
+        block_q=block_q, block_kv=block_kv, segments=segments,
     )
     # Named remat saveables: under the "flash_res" policy (models/transformer)
     # the first forward saves o+lse and the backward replay DCEs the whole
@@ -701,7 +878,9 @@ def _flash_core_fwd(q, k, v, seg_q, seg_kv, causal, scale, block_q, block_kv):
     return o, (q, k, v, seg_q, seg_kv, o, lse)
 
 
-def _flash_core_bwd(causal, scale, block_q, block_kv, residuals, g):
+def _flash_core_bwd(
+    causal, scale, block_q, block_kv, segments, residuals, g
+):
     q, k, v, seg_q, seg_kv, o, lse = residuals
     path = backward_path(
         q.shape[2], k.shape[2], q.shape[3], v.shape[3], block_q, block_kv,
@@ -709,8 +888,8 @@ def _flash_core_bwd(causal, scale, block_q, block_kv, residuals, g):
     )
     impl = _flash_bwd_fused if path == "fused" else _flash_bwd
     dq, dk, dv = impl(
-        q, k, v, seg_q, seg_kv, o, lse, g,
-        causal=causal, scale=scale, block_q=block_q, block_kv=block_kv,
+        q, k, v, seg_q, seg_kv, o, lse, g, causal=causal, scale=scale,
+        block_q=block_q, block_kv=block_kv, segments=segments,
     )
     return dq, dk, dv, None, None
 
@@ -760,8 +939,10 @@ def mha(
     kt = _pad_to(k.transpose(0, 2, 1, 3), skv_p, 2)
     vt = _pad_to(v.transpose(0, 2, 1, 3), skv_p, 2)
 
+    # Nothing to compare where no ids were given and no length was padded.
+    segments = segment_ids is not None or (sq_p, skv_p) != (sq, skv)
     o = _flash_core(
         qt, kt, vt, seg_q[:, None, :], seg_kv[:, None, :],
-        causal, scale, block_q, block_kv,
+        causal, scale, block_q, block_kv, segments,
     )
     return o[:, :, :sq].transpose(0, 2, 1, 3)

@@ -352,7 +352,7 @@ class ElasticTrainer:
                 "persistent_hits": after["hits"] - before["hits"],
                 "persistent_misses": after["misses"] - before["misses"],
                 "kernel_calls": self.train.kernel_calls,
-                "flash_backward": self._flash_backward(),
+                **self._flash_facts(),
             }
             logger.info("compile warmup: %s", detail)
             telemetry.event(
@@ -450,15 +450,19 @@ class ElasticTrainer:
             self._dp_shards(),
         )
 
-    def _flash_backward(self) -> str:
-        """Which flash-attention backward the step program holds: ``fused``
-        (one pass), ``split`` (dq, then dk / dv) or ``none`` (no flash
-        kernel).  Chosen at trace time from the shapes alone, so this asks
-        the function the dispatch asks; the sequence is whole inside
-        attention under every rule table (``models/attention.py``)."""
+    def _flash_facts(self) -> Dict[str, Any]:
+        """What the step program's flash-attention kernels are, for the
+        ``compile`` event: ``flash_backward``, the backward it holds
+        (``fused``: one pass, ``split``: dq, then dk / dv, ``none``: no
+        flash kernel), and ``flash_blocks``, how many causal blocks of
+        each class one (batch, head) holds and the rows of a diagonal
+        block's strips (0: the masked square).  Both are chosen at trace
+        time from the shapes alone, so this asks the functions the dispatch
+        asks; the sequence is whole inside attention under every rule table
+        (``models/attention.py``)."""
         cfg = self.model_config
         if cfg.attention_impl != "flash":
-            return "none"
+            return {"flash_backward": "none", "flash_blocks": None}
         from dlrover_tpu.ops import flash_attention
 
         d = d_v = cfg.resolved_head_dim
@@ -466,10 +470,15 @@ class ElasticTrainer:
             d = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
             d_v = cfg.v_head_dim
         seq = self.config.seq_len
-        return flash_attention.backward_path(
-            seq, seq, d, d_v, cfg.flash_block_q, cfg.flash_block_kv,
-            cfg.dtype,
-        )
+        blocks = (cfg.flash_block_q, cfg.flash_block_kv)
+        return {
+            "flash_backward": flash_attention.backward_path(
+                seq, seq, d, d_v, *blocks, cfg.dtype
+            ),
+            "flash_blocks": flash_attention.block_classes(
+                seq, seq, *blocks, causal=True
+            )._asdict(),
+        }
 
     def _build_train(
         self, grad_accum: Optional[int] = None

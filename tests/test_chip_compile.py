@@ -71,8 +71,11 @@ BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
 def _flash(block):
     from dlrover_tpu.ops import flash_attention as fa
 
-    def loss(q, k, v):
-        out = fa.mha(q, k, v, causal=True, block_q=block, block_kv=block)
+    def loss(q, k, v, ids=None):
+        out = fa.mha(
+            q, k, v, causal=True, segment_ids=ids,
+            block_q=block, block_kv=block,
+        )
         return out.astype(F32).sum()
 
     return jax.grad(loss, argnums=(0, 1, 2))
@@ -171,6 +174,13 @@ CASES = [
      _flash_longest(20480, 192, 128), {}, 2),
     ("flash_split_bwd", lambda: _flash(1024),
      _flash_longest(44032, 128), {}, 3),
+    # the strips of a diagonal block slice the segment ids too: packed
+    # documents at JoyAI's widths, and a length padded to whole blocks
+    ("flash_strips_with_ids", lambda: _flash(1024),
+     [((2, 8192, 32, 192), BF16)] * 2 + [((2, 8192, 32, 128), BF16),
+                                         ((2, 8192), I32)], {}, 2),
+    ("flash_strips_padded", lambda: _flash(1024),
+     [((4, 4000, 16, 128), BF16)] * 3, {}, 2),
     ("fused_layernorm", lambda: _norm_grad("fused_layernorm", True),
      [((16, 1024, 1600), BF16), ((1600,), F32), ((1600,), F32)], {}, 1),
     ("fused_rmsnorm", lambda: _norm_grad("fused_rmsnorm", False),

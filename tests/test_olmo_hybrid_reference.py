@@ -449,7 +449,11 @@ def test_the_published_widths_count_what_the_issue_counts():
 # -- the models the program already ran are what they were ---------------------
 
 # sha256 of the lowered step text (StableHLO; CPU; the benchmark's tiny
-# presets; ``@name_<n>`` counters normalised) at the parent commit f9ad26b.
+# presets; ``@name_<n>`` counters normalised) at the parent commit 04ce0df
+# with PR 36's flash kernels, which every one of the four runs: no segment
+# compare and no all-masked-row guards without ids or padding, an exact
+# ``scale`` on the q tile, the forward of ONE kv block written straight out
+# (all four were recorded anew; the other modules lower to what they did).
 # A PR that changes these models' step on purpose records them anew.  PR 33
 # left all four as they were: the flash kernels at ``d_qk == d_v``, the
 # grouped GEMMs with every expert held (no dead blocks skipped), the router
@@ -462,13 +466,13 @@ def test_the_published_widths_count_what_the_issue_counts():
 # float32 dq scratch where it was two, so its text is recorded anew.
 LOWERED_AT_PARENT = {
     "gpt2-1.5b":
-        "daebdfc3a4e5c684c9383ca48012bef11bbd46910f33798c49c7014a77cf9aa5",
+        "3fb5f6338781894bc6418780c92ff0224b12abbaddadeef5d7c79a740c9f4f92",
     "mixtral-8x7b":
-        "7b7ac706f25d2c06a31008bf192870a146dd3ba2b995a3c4b3b8b051c8763c06",
+        "a610499e04164995118fff59e041ffb9f8a82901625a1fddb1ebe83edd4790bb",
     "olmoe-1b-7b":
-        "cd528fd6aa74a2ab95d1cdf8fd40a4b2967b21cf41399c806e1919738cacc672",
+        "0d7f87bc882696205ed45766b521452c70b9eab6848047132852914d5623fd02",
     "olmo-hybrid-7b":
-        "0ef3c02d42c8b79c81557dfa17d5e166eefd82a26f1cd9cc4db9709a548f7686",
+        "f0d80527a4cb2792ec44b3c973ef822f3582b8ba5d6d058e9850cd56c1ed6ab5",
 }
 
 
@@ -514,17 +518,20 @@ def test_earlier_models_keep_their_lowered_step_text(preset):
         assert name not in text, name
 
 
-@pytest.mark.parametrize("preset,blocks,vmem_cap,path", [
-    ("gpt2-1.5b", 1, None, "fused"),        # one kv block: no dq scratch
-    ("olmo-hybrid-7b", 4, None, "fused"),   # several: dq in VMEM scratch
-    ("olmo-hybrid-7b", 4, 1 << 16, "split"),   # past the bound
-    ("gpt2-1.5b", 1, 1 << 16, "fused"),
+@pytest.mark.parametrize("preset,blocks,vmem_cap,path,classes", [
+    # one kv block: no dq scratch, and one diagonal block a (batch, head)
+    ("gpt2-1.5b", 1, None, "fused", (0, 0, 1)),
+    # several: dq in VMEM scratch; 6 dead, 6 interior, 4 diagonal
+    ("olmo-hybrid-7b", 4, None, "fused", (6, 6, 4)),
+    ("olmo-hybrid-7b", 4, 1 << 16, "split", (6, 6, 4)),   # past the bound
+    ("gpt2-1.5b", 1, 1 << 16, "fused", (0, 0, 1)),
 ])
 def test_compile_event_names_the_flash_backward(
-    tap, monkeypatch, preset, blocks, vmem_cap, path
+    tap, monkeypatch, preset, blocks, vmem_cap, path, classes
 ):
-    """The path is a fact of the compiled step: the ``compile`` event names
-    it, from the function the dispatch asks (``xla`` attention: ``none``)."""
+    """The path and the blocks' classes are facts of the compiled step: the
+    ``compile`` event names them, from the functions the dispatch asks
+    (``xla`` attention: ``none``, and no blocks)."""
     from benchmark import build
     from dlrover_tpu.ops import flash_attention
     from dlrover_tpu.trainer import train_lib
@@ -542,7 +549,7 @@ def test_compile_event_names_the_flash_backward(
     assert model.attention_impl == "flash"
     assert seq // min(seq, model.flash_block_kv) == blocks
 
-    def flash_backward(model):
+    def flash_facts(model):
         train_lib.reset_build_cache()
         tap.take()
         ElasticTrainer(model, TrainerConfig(
@@ -550,13 +557,18 @@ def test_compile_event_names_the_flash_backward(
             optimizer="adafactor", warmup_compile=True, ckpt_every=1000,
         ))
         (event,) = [e for e in tap.take() if e[0] == "compile"]
-        return event[-1]["flash_backward"]
+        return event[-1]["flash_backward"], event[-1]["flash_blocks"]
 
-    assert flash_backward(model) == path
+    strip = flash_attention.block_classes(
+        seq, seq, model.flash_block_q, model.flash_block_kv, True
+    ).strip
+    assert flash_facts(model) == (path, dict(zip(
+        ("dead", "interior", "diagonal", "strip"), (*classes, strip)
+    )))
     if vmem_cap is None:
-        assert flash_backward(
+        assert flash_facts(
             dataclasses.replace(model, attention_impl="xla", remat="none")
-        ) == "none"
+        ) == ("none", None)
 
 
 def test_olmoe_keeps_its_tree_and_losses():

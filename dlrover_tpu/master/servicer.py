@@ -415,6 +415,16 @@ class MasterServicer:
                         "unparseable linear_attn event from %d: %r",
                         node, attrs,
                     )
+            elif self.speed_monitor is not None and name == "ssm":
+                # State-space health snapshot (mean decay, mean step, the
+                # recurrent state's largest entry): feeds the ledger behind
+                # the dlrover_ssm_* gauges.
+                try:
+                    self.speed_monitor.record_ssm(node, **attrs)
+                except (TypeError, ValueError):
+                    logger.warning(
+                        "unparseable ssm event from %d: %r", node, attrs,
+                    )
             elif self.speed_monitor is not None and name == "embed":
                 # Embedding-plane stats snapshot: feeds the embed ledger
                 # behind the dlrover_embed_* gauges (rows owned, cache

@@ -105,6 +105,9 @@ class SpeedMonitor:
         # of its gated-delta-rule layers — the ``dlrover_linear_attn_*``
         # gauges read the aggregate.
         self._linear_attn_stats: Dict[int, Dict[str, float]] = {}
+        # "ssm" telemetry events: the same for a model's state-space
+        # (Mamba-2) layers — the ``dlrover_ssm_*`` gauges.
+        self._ssm_stats: Dict[int, Dict[str, float]] = {}
 
     def collect_global_step(
         self, step: int, timestamp: Optional[float] = None, tokens: int = 0
@@ -372,8 +375,41 @@ class SpeedMonitor:
         """Aggregate over reporters: the means average (each books its own
         replica's batch), the state's largest entry and the geometry take
         the max (a non-finite entry on any replica must show)."""
+        return self._state_ledger(
+            self._linear_attn_stats, ("mean_alpha", "mean_beta")
+        )
+
+    def record_ssm(
+        self,
+        node_id: int = 0,
+        *,
+        step: float = 0.0,
+        layers: float = 0.0,
+        chunk: float = 0.0,
+        mean_decay: float = 0.0,
+        mean_dt: float = 0.0,
+        state_absmax: float = 0.0,
+        **_ignored,
+    ):
+        """A trainer's state-space snapshot (its ``ssm`` telemetry event).
+        Newest-wins per reporting node; unknown attrs are ignored."""
         with self._lock:
-            stats = list(self._linear_attn_stats.values())
+            self._ssm_stats[node_id] = {
+                "step": float(step),
+                "layers": float(layers),
+                "chunk": float(chunk),
+                "mean_decay": float(mean_decay),
+                "mean_dt": float(mean_dt),
+                "state_absmax": float(state_absmax),
+            }
+
+    def ssm_ledger(self) -> Dict[str, float]:
+        """:meth:`linear_attn_ledger`'s aggregate of the ``ssm`` events."""
+        return self._state_ledger(self._ssm_stats, ("mean_decay", "mean_dt"))
+
+    def _state_ledger(self, store, mean_keys) -> Dict[str, float]:
+        with self._lock:
+            stats = list(store.values())
         n = len(stats)
 
         def mean(key):
@@ -385,15 +421,15 @@ class SpeedMonitor:
                 return float("nan")
             return max(values, default=0.0)
 
-        return {
+        out = {
             "reporters": float(n),
             "step": most("step"),
             "layers": most("layers"),
             "chunk": most("chunk"),
-            "mean_alpha": mean("mean_alpha"),
-            "mean_beta": mean("mean_beta"),
             "state_absmax": most("state_absmax"),
         }
+        out.update({key: mean(key) for key in mean_keys})
+        return out
 
     def moe_ledger(self) -> Dict[str, Any]:
         """Router-health aggregate: entropy/drop/padding average over reporters

@@ -420,6 +420,21 @@ class JobTimeline:
                   "boundary (max of reporters; NaN/Inf = diverged)")
             gauge("dlrover_linear_attn_reporters", linear["reporters"],
                   "trainers that have reported linear-attention snapshots")
+            ssm = speed_monitor.ssm_ledger()
+            gauge("dlrover_ssm_layers", ssm["layers"],
+                  "state-space (Mamba-2) layers of the reported model")
+            gauge("dlrover_ssm_chunk", ssm["chunk"],
+                  "tokens a chunk of the chunked scan holds")
+            gauge("dlrover_ssm_mean_decay", ssm["mean_decay"],
+                  "mean state decay exp(dt A) over tokens, heads and "
+                  "layers (mean of reporters; 1 = nothing forgotten)")
+            gauge("dlrover_ssm_mean_dt", ssm["mean_dt"],
+                  "mean step dt after its softplus")
+            gauge("dlrover_ssm_state_absmax", ssm["state_absmax"],
+                  "largest |S| entry of a state-space state at any chunk "
+                  "boundary (max of reporters; NaN/Inf = diverged)")
+            gauge("dlrover_ssm_reporters", ssm["reporters"],
+                  "trainers that have reported state-space snapshots")
             sdc = speed_monitor.sdc_ledger()
             gauge("dlrover_sdc_checks_total", sdc["checks"],
                   "cross-replica state-digest votes performed")

@@ -84,7 +84,7 @@ def _dt_bias_init(key, shape, dtype):
     return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
 
 
-def _conv_init(key, shape, dtype):
+def conv_init(key, shape, dtype):
     # torch's Conv1d default for a depthwise filter: U(+-1/sqrt(taps))
     bound = 1.0 / math.sqrt(shape[0])
     return jax.random.uniform(key, shape, F32, -bound, bound).astype(dtype)
@@ -191,7 +191,7 @@ class GatedDeltaNet(nn.Module):
             taps = self.param(
                 "conv_kernel",
                 nn.with_logical_partitioning(
-                    _conv_init, (None, lr.HEADS, lr.KV)
+                    conv_init, (None, lr.HEADS, lr.KV)
                 ),
                 (self.conv_taps, h, 2 * dk + dv), self.param_dtype,
             )

@@ -1,0 +1,226 @@
+"""The Mamba-2 mixer: a state-space layer whose recurrent state stands in
+place of a KV cache (Dao & Gu, arXiv:2405.21060 §6-7), as Nemotron-H's
+``M`` layers run it.
+
+With ``n`` the mixer's input, ``H`` heads of ``P``, a state of ``N`` a
+head, ``G`` groups of ``H / G`` heads that share ``B`` and ``C``, and the
+inner width ``H P`` (its own number, not ``expand x hidden``)::
+
+    [z | xBC | dt] = n W_in                 (H P | H P + 2 G N | H; no bias)
+    xBC = SiLU(causal depthwise conv(xBC), ``taps`` taps, + b_conv)
+    x, B, C = xBC split (H P | G N | G N)
+    dt = softplus(dt + dt_bias)             (float32, a head; no clamp)
+    A  = -exp(A_log)                        (one scalar a head)
+    per head:  S_t = exp(dt_t A) S_{t-1} + dt_t x_t B_t^T
+               y_t = S_t C_t + D x_t        (ops/ssd.py)
+    y   = RMSNorm_groups(y * SiLU(z))       (gate first; the mean square
+                                             over each group's H P / G
+                                             columns, one [H P] scale)
+    out = y W_out
+
+The projection is ONE ``[d, 2 H P + 2 G N + H]`` matmul (param
+``in_proj``), its ``dt`` columns leave it in the activations' dtype and
+are float32 from the softplus on.  The convolution is
+``linear_attention.causal_depthwise_conv`` over all ``H P + 2 G N``
+channels, with a bias.
+
+Each forward ``sow``s ``ssm_stats`` = ``[mean exp(dt A), mean dt, largest
+|S| entry at a chunk boundary]`` (``linear_attention.split_stats`` reads
+it) into ``"intermediates"``: a no-op unless the caller applies with that
+collection mutable, as the train step does.
+
+The block names this module ``ssm``, so its scopes reach the compiled text
+as ``ssm/in_proj``, ``/conv``, ``/dt``, ``/scan``, ``/out_norm`` and
+``/out_proj``, forward and transposed ops alike, where the benchmark reads
+them.
+"""
+
+from __future__ import annotations
+
+import math
+from typing import Any
+
+import flax.linen as nn
+import jax
+import jax.ad_checkpoint
+import jax.numpy as jnp
+
+from dlrover_tpu.models import layers
+from dlrover_tpu.models.linear_attention import (
+    causal_depthwise_conv,
+    conv_init,
+)
+from dlrover_tpu.ops.ssd import ssd
+from dlrover_tpu.parallel import rules as lr
+from dlrover_tpu.runtime.mesh import shard_local
+
+F32 = jnp.float32
+STATS_NAME = "ssm_stats"
+
+
+def _a_log_init(key, shape, dtype):
+    # Mamba-2: A = 1 .. H, kept as its logarithm
+    del key
+    return jnp.log(jnp.arange(1, shape[0] + 1, dtype=F32)).astype(dtype)
+
+
+def dt_bias_init(dt_min: float, dt_max: float, dt_floor: float):
+    """``dt`` log-uniform in ``[dt_min, dt_max]``, floored at ``dt_floor``,
+    kept as the inverse of softplus so that softplus(dt_bias) = dt."""
+    def init(key, shape, dtype):
+        dt = jnp.exp(
+            jax.random.uniform(key, shape, F32)
+            * (math.log(dt_max) - math.log(dt_min)) + math.log(dt_min)
+        )
+        dt = jnp.maximum(dt, dt_floor)
+        return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
+
+    return init
+
+
+def _ssd_local(x, dt, a_head, b, c, d, *, chunk, impl):
+    """The scan on each device's own batch rows (:func:`shard_local`, as
+    attention's ``_flash_local``): ``B`` and ``C`` are a group's, so the
+    heads stay whole, and nothing crosses devices but the largest ``|S|``,
+    which each device reports for itself."""
+    rows = nn.logical_to_mesh_axes((lr.BATCH, None, None, None))
+    per_token = nn.logical_to_mesh_axes((lr.BATCH, None, None))
+    whole = nn.logical_to_mesh_axes((None,))
+
+    def local(x, dt, a_head, b, c, d):
+        y, state_absmax = ssd(x, dt, a_head, b, c, d, chunk=chunk, impl=impl)
+        return y, state_absmax[None]
+
+    y, state_absmax = shard_local(
+        local, in_specs=(rows, per_token, whole, rows, rows, whole),
+        out_specs=(rows, nn.logical_to_mesh_axes((lr.BATCH,))),
+    )(x, dt, a_head, b, c, d)
+    return y, state_absmax.max()
+
+
+class Mamba2(nn.Module):
+    num_heads: int
+    head_dim: int
+    state_size: int
+    num_groups: int
+    conv_taps: int = 4
+    chunk: int = 128
+    dt_min: float = 0.001
+    dt_max: float = 0.1
+    dt_floor: float = 1e-4
+    norm_eps: float = 1e-5
+    impl: str = "xla"              # ops/ssd.py: "xla" | "kernel"
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        h, p, n, g = (
+            self.num_heads, self.head_dim, self.state_size, self.num_groups
+        )
+        inner, bc = h * p, g * n
+        features = x.shape[-1]
+        batch, s = x.shape[:2]
+        proj = layers.DenseGeneral(
+            2 * inner + 2 * bc + h,
+            kernel_axes=(lr.EMBED, lr.SSM_INNER),
+            dtype=self.dtype, param_dtype=self.param_dtype,
+            name="in_proj",
+        )(x)
+        z = proj[..., :inner]
+        with jax.named_scope("conv"):
+            taps = self.param(
+                "conv_kernel",
+                nn.with_logical_partitioning(
+                    conv_init, (None, lr.SSM_INNER)
+                ),
+                (self.conv_taps, inner + 2 * bc), self.param_dtype,
+            )
+            bias = self.param(
+                "conv_bias",
+                nn.with_logical_partitioning(
+                    # torch's Conv1d default: the taps' own bound
+                    lambda key, shape, dtype: conv_init(
+                        key, (self.conv_taps,) + shape, dtype
+                    )[0],
+                    (lr.SSM_INNER,),
+                ),
+                (inner + 2 * bc,), self.param_dtype,
+            )
+            xbc = nn.silu(
+                causal_depthwise_conv(
+                    proj[..., inner: 2 * inner + 2 * bc],
+                    taps.astype(self.dtype),
+                ) + bias.astype(self.dtype)
+            )
+            x_in = xbc[..., :inner].reshape(batch, s, h, p)
+            b = xbc[..., inner: inner + bc].reshape(batch, s, g, n)
+            c = xbc[..., inner + bc:].reshape(batch, s, g, n)
+        with jax.named_scope("dt"):
+            # 3 H numbers that set every decay and step: float32 whatever
+            # the parameters' dtype, as Mamba-2 keeps them
+            a_log = self.param(
+                "A_log",
+                nn.with_logical_partitioning(_a_log_init, (lr.SSM_HEADS,)),
+                (h,), F32,
+            )
+            dt_bias = self.param(
+                "dt_bias",
+                nn.with_logical_partitioning(
+                    dt_bias_init(self.dt_min, self.dt_max, self.dt_floor),
+                    (lr.SSM_HEADS,),
+                ),
+                (h,), F32,
+            )
+            d_skip = self.param(
+                "D",
+                nn.with_logical_partitioning(
+                    nn.initializers.ones_init(), (lr.SSM_HEADS,)
+                ),
+                (h,), F32,
+            )
+            dt = jax.nn.softplus(
+                proj[..., 2 * inner + 2 * bc:].astype(F32)
+                + dt_bias.astype(F32)
+            )
+            a_head = -jnp.exp(a_log.astype(F32))
+        with jax.named_scope("scan"):
+            y, state_absmax = _ssd_local(
+                x_in, dt, a_head, b, c, d_skip, chunk=self.chunk,
+                impl=self.impl,
+            )
+        # The one activation of the mixer the layer's remat keeps
+        # (ops/remat_policy.py): the backward runs the forward kernel again
+        # for its chunk-start states.
+        y = jax.ad_checkpoint.checkpoint_name(y, "ssd_out")
+        self.sow(
+            "intermediates", STATS_NAME,
+            jax.lax.stop_gradient(jnp.stack([
+                jnp.exp(dt * a_head).mean(), dt.mean(), state_absmax,
+            ])),
+        )
+        with jax.named_scope("out_norm"):
+            scale = self.param(
+                "out_norm_scale",
+                nn.with_logical_partitioning(
+                    nn.initializers.ones_init(), (lr.SSM_INNER,)
+                ),
+                (inner,), self.param_dtype,
+            )
+            gated = (
+                y.reshape(batch, s, inner).astype(F32)
+                * nn.silu(z.astype(F32))
+            ).reshape(batch, s, g, inner // g)
+            normed = gated * jax.lax.rsqrt(
+                jnp.mean(gated * gated, axis=-1, keepdims=True)
+                + self.norm_eps
+            )
+            y = (
+                normed.reshape(batch, s, inner) * scale.astype(F32)
+            ).astype(self.dtype)
+        return layers.DenseGeneral(
+            features,
+            kernel_axes=(lr.SSM_INNER, lr.EMBED),
+            dtype=self.dtype, param_dtype=self.param_dtype,
+            name="out_proj",
+        )(y)

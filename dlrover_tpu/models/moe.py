@@ -27,6 +27,14 @@ from dlrover_tpu.ops import row_gather_sum
 from dlrover_tpu.parallel import rules as lr
 
 
+def ungated(h: jax.Array, activation: str) -> jax.Array:
+    """An MLP's hidden activation where no gate multiplies it: ``gelu``, or
+    ``relu2``, the rectifier squared (Nemotron-H's experts and MLPs)."""
+    if activation == "relu2":
+        return jnp.square(nn.relu(h))
+    return nn.gelu(h)
+
+
 def _gate(logits: jax.Array, k: int, norm_topk_prob: bool = True,
           aux_form: str = "top1", scoring: str = "softmax", bias=None,
           scale: float = 1.0):
@@ -409,8 +417,10 @@ class MoEMlp(nn.Module):
     ``scoring="sigmoid"`` with ``router_bias`` is the DeepSeek-V3 router
     (:func:`_gate`); the bias ``router_bias`` ``[num_experts]`` is a
     parameter no gradient reaches, moved by :func:`bias_update` in the
-    train step.  ``shared_d_ff`` adds a shared expert (a plain SwiGLU MLP
-    of that width, ``shared``) every token passes through.
+    train step.  ``shared_d_ff`` adds a shared expert (a plain MLP of the
+    layer's ``activation`` and that width, ``shared``) every token passes
+    through.  Without a gate (``activation`` ``"gelu"`` or ``"relu2"``) an
+    expert is two matrices and the grouped path two grouped GEMMs.
 
     Router observability: every forward ``sow``s a ``moe_stats`` vector
     ``[gate_entropy, drop_fraction, load_0..load_{E-1}, pad_share,
@@ -575,7 +585,7 @@ class MoEMlp(nn.Module):
             g = jnp.einsum("ebcd,edf->ebcf", expert_in, wg)
             h = nn.silu(g) * h
         else:
-            h = nn.gelu(h)
+            h = ungated(h, self.activation)
         expert_out = jnp.einsum("ebcf,efd->ebcd", h, wo)
         expert_out = nn.with_logical_constraint(
             expert_out, (lr.EXPERT, lr.BATCH, None, lr.ACT_EMBED)
@@ -688,7 +698,7 @@ class MoEMlp(nn.Module):
                 g = jnp.einsum("ebcd,edf->ebcf", expert_in, wg_loc)
                 h = nn.silu(g) * h
             else:
-                h = nn.gelu(h)
+                h = ungated(h, self.activation)
             expert_out = jnp.einsum("ebcf,efd->ebcd", h, wo_loc)
             # Combine leg home: the exact inverse exchange.
             expert_out = wire(expert_out, 1, 0)    # [E, b_chunk, C, D]
@@ -833,7 +843,7 @@ class MoEMlp(nn.Module):
                     )
                 h = nn.silu(g) * h
             else:
-                h = nn.gelu(h)
+                h = ungated(h, self.activation)
             with jax.named_scope("gmm_wo"):
                 out_rows = grouped_matmul(
                     h, wo, plan["padded"], block, tiled, share

@@ -10,7 +10,10 @@ bias-corrected choice, a shared expert, a chip's share of the experts
 (``experts_held``), leading dense layers before the scanned expert layers
 (``first_k_dense``) and a multi-token-prediction module (``mtp_depth``)
 whose output leaves the model only where the caller hands it the next
-tokens (the train step does).
+tokens (the train step does).  Nemotron-H's layers are kinds of a layer pattern:
+a layer that is ONE residual branch (a Mamba-2 state-space mixer, an
+attention without rotation, an expert layer of ungated ``relu2`` experts
+with a shared expert of its own width, or a dense MLP alone).
 
 TPU-first structure:
   * layers are ``nn.scan``-stacked: one trace regardless of depth (fast
@@ -25,6 +28,7 @@ TPU-first structure:
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Optional, Tuple
 
 import flax.linen as nn
@@ -35,14 +39,25 @@ import jax.numpy as jnp
 from dlrover_tpu.models import layers
 from dlrover_tpu.models.attention import Attention, LatentAttention
 from dlrover_tpu.models.linear_attention import GatedDeltaNet
-from dlrover_tpu.models.moe import MoEMlp, check_share
+from dlrover_tpu.models.mamba2 import Mamba2
+from dlrover_tpu.models.moe import MoEMlp, check_share, ungated
 from dlrover_tpu.ops import remat_policy as remat_policies
+from dlrover_tpu.ops import ssd
 from dlrover_tpu.parallel import rules as lr
 
 
 FULL_ATTENTION = "full_attention"
 LINEAR_ATTENTION = "linear_attention"
-LAYER_KINDS = (FULL_ATTENTION, LINEAR_ATTENTION)
+# Layers of TWO residual branches: a mixer, then an MLP (``Block``).
+TWO_BRANCH_KINDS = (FULL_ATTENTION, LINEAR_ATTENTION)
+# Layers that are ONE residual branch, ``x + f(Norm(x))`` (``BranchBlock``):
+# a state-space mixer, an attention, an expert layer, a dense MLP.
+SSM = "ssm"
+ATTENTION = "attention"
+EXPERTS = "experts"
+MLP = "mlp"
+BRANCH_KINDS = (SSM, ATTENTION, EXPERTS, MLP)
+LAYER_KINDS = TWO_BRANCH_KINDS + BRANCH_KINDS
 
 
 @dataclasses.dataclass(frozen=True)
@@ -55,12 +70,20 @@ class TransformerConfig:
     head_dim: int = 0              # 0 -> d_model // num_heads
     d_ff: int = 0                  # 0 -> 4*d_model (gelu) or 8/3*d_model (swiglu)
     max_seq_len: int = 1024
-    position: str = "learned"      # "learned" (GPT-2) | "rope" (Llama)
+    position: str = "learned"      # "learned" (GPT-2) | "rope" (Llama) |
+                                   # "none" (Nemotron-H: the state-space
+                                   # layers give the order)
     norm: str = "layernorm"        # "layernorm" | "rmsnorm"
-    activation: str = "gelu"       # "gelu" | "swiglu"
+    activation: str = "gelu"       # "gelu" | "swiglu" | "relu2" (ungated,
+                                   # the rectifier squared)
     rope_theta: float = 10000.0
     use_bias: bool = True          # GPT-2 uses biases, Llama does not
     tie_embeddings: bool = True
+    # The embedding table's initial std (0 -> the program's 0.02).  Every
+    # branch reads the residual stream through a norm, so what this sets
+    # is how far the token's own row outweighs the branches' outputs at
+    # init: see ``benchmark/configs/nemotron-3-nano-30b-a3b.json``.
+    embed_init_std: float = 0.0
     # MoE
     num_experts: int = 0
     top_k: int = 2
@@ -94,6 +117,9 @@ class TransformerConfig:
     first_expert: int = 0
     moe_row_budget: float = 1.25
     moe_d_ff: int = 0
+    # The shared expert's own width (0 -> ``num_shared_experts`` x the
+    # routed experts' width, the DeepSeek-V3 family's).
+    shared_expert_d_ff: int = 0
     # Layers before the scanned trunk whose MLP is dense (``d_ff`` wide)
     # though the trunk's is sparse: ``dense_0`` .. of ``num_layers``.
     first_k_dense: int = 0
@@ -116,12 +142,29 @@ class TransformerConfig:
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
-    # One period of layer kinds ("full_attention" | "linear_attention"),
+    # One period of layer kinds (``LAYER_KINDS``: "full_attention" and
+    # "linear_attention" are a mixer AND an MLP; "ssm", "attention",
+    # "experts" and "mlp" are that part alone on one residual branch),
     # repeated num_layers / len(layer_pattern) times; empty = every layer
     # full attention.  The trunk scans over PERIODS: a period applies its
-    # blocks in order, each under its own name (``linear_0`` .. ``full_3``)
-    # with its own parameters stacked over the periods.
+    # blocks in order, each under its own name (``linear_0`` .. ``full_3``,
+    # ``experts_0`` .. ``attention_8``) with its own parameters stacked
+    # over the periods.
     layer_pattern: Tuple[str, ...] = ()
+    # The Mamba-2 mixer of the "ssm" layers (models/mamba2.py): heads, a
+    # head's width and state size, the groups that share B and C, taps of
+    # the short convolution, the scan's chunk, the range ``dt`` is drawn
+    # from at init, and how the scan runs (ops/ssd.py: "xla" | "kernel").
+    ssm_num_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state_size: int = 0
+    ssm_groups: int = 1
+    ssm_conv_kernel: int = 4
+    ssm_chunk: int = 128
+    ssm_dt_min: float = 0.001
+    ssm_dt_max: float = 0.1
+    ssm_dt_floor: float = 1e-4
+    ssm_impl: str = "xla"
     # The gated-delta-rule mixer of the "linear_attention" layers
     # (models/linear_attention.py): heads (0 -> num_heads), key and value
     # head sizes, taps of the short convolution, and whether beta is
@@ -203,10 +246,22 @@ class TransformerConfig:
         return self.experts_held or self.num_experts
 
     @property
-    def num_linear_layers(self) -> int:
-        return self.num_scan_units * self.layer_pattern.count(
-            LINEAR_ATTENTION
+    def resolved_shared_d_ff(self) -> int:
+        return self.shared_expert_d_ff or (
+            self.num_shared_experts * self.resolved_moe_d_ff
         )
+
+    def num_layers_of(self, kind: str) -> int:
+        """Layers of ``kind`` in the scanned trunk."""
+        return self.num_scan_units * self.layer_pattern.count(kind)
+
+    @property
+    def num_linear_layers(self) -> int:
+        return self.num_layers_of(LINEAR_ATTENTION)
+
+    @property
+    def num_ssm_layers(self) -> int:
+        return self.num_layers_of(SSM)
 
     def layer_kind(self, layer: int) -> str:
         if not self.layer_pattern:
@@ -289,6 +344,12 @@ class TransformerConfig:
                 f"{len(pattern)}-layer pattern ({self.num_layers} layers): "
                 "a stage holds whole periods"
             )
+        if SSM in pattern:
+            self._check_ssm()
+        if EXPERTS in pattern and not self.num_experts:
+            raise ValueError(
+                "an 'experts' layer needs num_experts (and moe_d_ff, top_k)"
+            )
         if LINEAR_ATTENTION in pattern:
             if not (self.linear_key_head_dim and self.linear_value_head_dim):
                 raise ValueError(
@@ -305,6 +366,37 @@ class TransformerConfig:
                     "trains only"
                 )
 
+    def _check_ssm(self):
+        h, p, n, g = (
+            self.ssm_num_heads, self.ssm_head_dim, self.ssm_state_size,
+            self.ssm_groups,
+        )
+        if not (h and p and n) or g < 1 or h % g:
+            raise ValueError(
+                "an ssm layer needs ssm_num_heads, ssm_head_dim and "
+                "ssm_state_size, and ssm_groups dividing the heads, got "
+                f"{h}, {p}, {n}, {g}"
+            )
+        if self.ssm_impl not in ssd.IMPLS:
+            raise ValueError(
+                f"ssm_impl must be one of {ssd.IMPLS}, got {self.ssm_impl!r}"
+            )
+        if self.ssm_impl == "kernel" and not ssd.kernel_fits(h, p, g):
+            raise ValueError(
+                f"ssm_impl='kernel' lays heads side by side in {ssd.LANES}"
+                f"-lane tiles: ssm_head_dim {p} must divide {ssd.LANES} and "
+                f"a group's {h}/{g} heads be whole tiles wide; "
+                "ssm_impl='xla' takes any sizes"
+            )
+        if self.decode:
+            raise ValueError(
+                "decode=True with an ssm layer: the layer's recurrent "
+                f"state [H, P, N] ({h} x {p} x {n}) and its convolution's "
+                f"last {self.ssm_conv_kernel - 1} rows have no place beside "
+                "the KV cache yet (serving/decode.py, serving/engine.py); "
+                "this model trains only"
+            )
+
     def _check_family(self):
         """The DeepSeek-V3 family's fields: whole shares, a trunk left
         after the dense prefix, latent attention's five sizes together."""
@@ -317,6 +409,16 @@ class TransformerConfig:
             raise ValueError(
                 "experts_held, num_shared_experts and router_bias describe "
                 "an expert layer: set num_experts"
+            )
+        if self.position not in ("learned", "rope", "none"):
+            raise ValueError(
+                "position must be 'learned', 'rope' or 'none', got "
+                f"{self.position!r}"
+            )
+        if self.activation not in ("gelu", "swiglu", "relu2"):
+            raise ValueError(
+                "activation must be 'gelu', 'swiglu' or 'relu2', got "
+                f"{self.activation!r}"
             )
         if self.router_scoring not in ("softmax", "sigmoid"):
             raise ValueError(
@@ -417,7 +519,8 @@ class TransformerConfig:
         ``experts_held`` of them; latent attention by its five projections
         and two latent norms; the shared experts, the dense prefix and the
         MTP module (a layer, ``eh_proj`` and its three norms) where set; a
-        layer's own two norms and the final one are left out, as ever."""
+        one-branch layer by its one part; a layer's own norms and the
+        final one are left out, as ever."""
         d, v, l = self.d_model, self.vocab_size, self.num_layers
         swiglu = 3 if self.activation == "swiglu" else 2
         if self.latent_attention:
@@ -438,7 +541,8 @@ class TransformerConfig:
         if self.num_experts:
             one = swiglu * d * self.resolved_moe_d_ff
             ff = (
-                (self.resolved_experts_held + self.num_shared_experts) * one
+                self.resolved_experts_held * one
+                + swiglu * d * self.resolved_shared_d_ff
                 + d * self.num_experts
                 + (self.num_experts if self.router_bias else 0)
             )
@@ -449,9 +553,28 @@ class TransformerConfig:
         linear = self.num_linear_layers
         dense = self.first_k_dense
         mtp = self.mtp_depth * (attn + ff + 2 * d * d + 3 * d)
+        # layers that are one branch: none of them is a mixer AND an MLP
+        ssm, alone, experts, mlp = (
+            self.num_layers_of(kind) for kind in BRANCH_KINDS
+        )
+        two = l - ssm - alone - experts - mlp
         return (
-            (l - linear) * attn + linear * self._linear_mixer_params()
-            + (l - dense) * ff + dense * dense_ff + embed + head + mtp
+            (two - linear + alone) * attn
+            + linear * self._linear_mixer_params()
+            + ssm * self._ssm_mixer_params()
+            + (two - dense + experts) * ff + (dense + mlp) * dense_ff
+            + embed + head + mtp
+        )
+
+    def _ssm_mixer_params(self) -> int:
+        """The input and output projections, the convolution's taps and
+        bias, A_log, D, dt_bias and the gated norm's scale
+        (models/mamba2.py)."""
+        h, inner = self.ssm_num_heads, self.ssm_num_heads * self.ssm_head_dim
+        bc = self.ssm_groups * self.ssm_state_size
+        return (
+            self.d_model * (2 * inner + 2 * bc + h) + inner * self.d_model
+            + (self.ssm_conv_kernel + 1) * (inner + 2 * bc) + 3 * h + inner
         )
 
     def _linear_mixer_params(self) -> int:
@@ -495,7 +618,7 @@ class Mlp(nn.Module):
             )(x)
             h = nn.silu(g) * h
         else:
-            h = nn.gelu(h)
+            h = ungated(h, self.activation)
         return layers.DenseGeneral(
             d,
             kernel_axes=(lr.MLP, lr.EMBED),
@@ -504,6 +627,86 @@ class Mlp(nn.Module):
             param_dtype=self.param_dtype,
             name="wo",
         )(h)
+
+
+def _norm(cfg: TransformerConfig, name: str):
+    return layers.make_norm(
+        cfg.norm, cfg.dtype, cfg.param_dtype, name,
+        fused_backward=cfg.fused_ln, epsilon=cfg.norm_eps,
+    )
+
+
+def _attention(cfg: TransformerConfig):
+    """The config's softmax attention, latent or plain, named ``attn``."""
+    if cfg.latent_attention:
+        return LatentAttention(
+            num_heads=cfg.num_heads,
+            q_lora_rank=cfg.q_lora_rank,
+            kv_lora_rank=cfg.kv_lora_rank,
+            qk_nope_head_dim=cfg.qk_nope_head_dim,
+            qk_rope_head_dim=cfg.qk_rope_head_dim,
+            v_head_dim=cfg.v_head_dim,
+            rope_theta=cfg.rope_theta,
+            norm_eps=cfg.norm_eps,
+            dtype=cfg.dtype,
+            param_dtype=cfg.param_dtype,
+            attention_impl=cfg.attention_impl,
+            flash_block_q=cfg.flash_block_q,
+            flash_block_kv=cfg.flash_block_kv,
+            name="attn",
+        )
+    return Attention(
+        num_heads=cfg.num_heads,
+        num_kv_heads=cfg.resolved_kv_heads,
+        head_dim=cfg.resolved_head_dim,
+        use_rope=cfg.position == "rope",
+        rope_theta=cfg.rope_theta,
+        use_bias=cfg.use_bias,
+        dtype=cfg.dtype,
+        param_dtype=cfg.param_dtype,
+        attention_impl=cfg.attention_impl,
+        qk_norm=cfg.qk_norm,
+        norm_eps=cfg.norm_eps,
+        flash_block_q=cfg.flash_block_q,
+        flash_block_kv=cfg.flash_block_kv,
+        decode=cfg.decode,
+        cache_len=cfg.max_seq_len,
+        name="attn",
+    )
+
+
+def _experts(cfg: TransformerConfig):
+    return MoEMlp(
+        num_experts=cfg.num_experts,
+        d_ff=cfg.resolved_moe_d_ff,
+        top_k=cfg.top_k,
+        capacity_factor=cfg.capacity_factor,
+        activation=cfg.activation,
+        dtype=cfg.dtype,
+        param_dtype=cfg.param_dtype,
+        dispatch=cfg.moe_dispatch,
+        norm_topk_prob=cfg.norm_topk_prob,
+        aux_form=cfg.moe_aux_form,
+        scoring=cfg.router_scoring,
+        router_bias=cfg.router_bias,
+        routed_scale=cfg.routed_scaling_factor,
+        experts_held=cfg.experts_held,
+        first_expert=cfg.first_expert,
+        shared_d_ff=cfg.resolved_shared_d_ff,
+        row_budget_multiple=cfg.moe_row_budget,
+        name="moe",
+    )
+
+
+def _dense_mlp(cfg: TransformerConfig):
+    return Mlp(
+        d_ff=cfg.resolved_d_ff,
+        activation=cfg.activation,
+        use_bias=cfg.use_bias,
+        dtype=cfg.dtype,
+        param_dtype=cfg.param_dtype,
+        name="mlp",
+    )
 
 
 class Block(nn.Module):
@@ -529,10 +732,7 @@ class Block(nn.Module):
         post = cfg.norm_placement == "post"
 
         def norm(name, y):
-            return layers.make_norm(
-                cfg.norm, cfg.dtype, cfg.param_dtype, name,
-                fused_backward=cfg.fused_ln, epsilon=cfg.norm_eps,
-            )(y)
+            return _norm(cfg, name)(y)
 
         y = x if post else norm("ln_attn", x)
         if self.kind == LINEAR_ATTENTION:
@@ -547,42 +747,8 @@ class Block(nn.Module):
                 param_dtype=cfg.param_dtype,
                 name="linear_attn",
             )(y)
-        elif cfg.latent_attention:
-            y = LatentAttention(
-                num_heads=cfg.num_heads,
-                q_lora_rank=cfg.q_lora_rank,
-                kv_lora_rank=cfg.kv_lora_rank,
-                qk_nope_head_dim=cfg.qk_nope_head_dim,
-                qk_rope_head_dim=cfg.qk_rope_head_dim,
-                v_head_dim=cfg.v_head_dim,
-                rope_theta=cfg.rope_theta,
-                norm_eps=cfg.norm_eps,
-                dtype=cfg.dtype,
-                param_dtype=cfg.param_dtype,
-                attention_impl=cfg.attention_impl,
-                flash_block_q=cfg.flash_block_q,
-                flash_block_kv=cfg.flash_block_kv,
-                name="attn",
-            )(y, positions, segment_ids)
         else:
-            y = Attention(
-                num_heads=cfg.num_heads,
-                num_kv_heads=cfg.resolved_kv_heads,
-                head_dim=cfg.resolved_head_dim,
-                use_rope=cfg.position == "rope",
-                rope_theta=cfg.rope_theta,
-                use_bias=cfg.use_bias,
-                dtype=cfg.dtype,
-                param_dtype=cfg.param_dtype,
-                attention_impl=cfg.attention_impl,
-                qk_norm=cfg.qk_norm,
-                norm_eps=cfg.norm_eps,
-                flash_block_q=cfg.flash_block_q,
-                flash_block_kv=cfg.flash_block_kv,
-                decode=cfg.decode,
-                cache_len=cfg.max_seq_len,
-                name="attn",
-            )(y, positions, segment_ids)
+            y = _attention(cfg)(y, positions, segment_ids)
         if post:
             y = norm("ln_attn", y)
         # Named checkpoint: under the "attn_out" remat policy the backward
@@ -593,36 +759,10 @@ class Block(nn.Module):
             x = x + y
         y = x if post else norm("ln_mlp", x)
         if cfg.num_experts and not self.dense_mlp:
-            y, layer_aux = MoEMlp(
-                num_experts=cfg.num_experts,
-                d_ff=cfg.resolved_moe_d_ff,
-                top_k=cfg.top_k,
-                capacity_factor=cfg.capacity_factor,
-                activation=cfg.activation,
-                dtype=cfg.dtype,
-                param_dtype=cfg.param_dtype,
-                dispatch=cfg.moe_dispatch,
-                norm_topk_prob=cfg.norm_topk_prob,
-                aux_form=cfg.moe_aux_form,
-                scoring=cfg.router_scoring,
-                router_bias=cfg.router_bias,
-                routed_scale=cfg.routed_scaling_factor,
-                experts_held=cfg.experts_held,
-                first_expert=cfg.first_expert,
-                shared_d_ff=cfg.num_shared_experts * cfg.resolved_moe_d_ff,
-                row_budget_multiple=cfg.moe_row_budget,
-                name="moe",
-            )(y)
+            y, layer_aux = _experts(cfg)(y)
             aux = aux + layer_aux
         else:
-            y = Mlp(
-                d_ff=cfg.resolved_d_ff,
-                activation=cfg.activation,
-                use_bias=cfg.use_bias,
-                dtype=cfg.dtype,
-                param_dtype=cfg.param_dtype,
-                name="mlp",
-            )(y)
+            y = _dense_mlp(cfg)(y)
         if post:
             y = norm("ln_mlp", y)
         # Under the "branch_out" policy the backward rebuilds the residual
@@ -635,16 +775,85 @@ class Block(nn.Module):
         return (x, aux), None
 
 
-def block_class(cfg: TransformerConfig, prevent_cse: bool):
-    """``Block``, under the config's remat policy (registry lookup,
+class BranchBlock(nn.Module):
+    """One layer that is ONE residual branch, ``x + f(Norm(x))`` (under
+    ``norm_placement="post"``: ``x + Norm(f(x))``), with one norm ``ln``:
+    ``f`` is ``kind``'s part alone, a Mamba-2 mixer (``ssm``), the config's
+    attention (``attn``), its expert layer (``moe``) or its dense MLP
+    (``mlp``).  ``Block``'s signature, so a period mixes both."""
+
+    config: TransformerConfig
+    kind: str = SSM
+
+    @nn.compact
+    def __call__(
+        self,
+        carry: Tuple[jax.Array, jax.Array],
+        positions: Optional[jax.Array] = None,
+        segment_ids: Optional[jax.Array] = None,
+    ) -> Tuple[Tuple[jax.Array, jax.Array], None]:
+        cfg = self.config
+        x, aux = carry
+        x = nn.with_logical_constraint(x, (lr.BATCH, lr.ACT_SEQ, lr.ACT_EMBED))
+        post = cfg.norm_placement == "post"
+        norm = _norm(cfg, "ln")
+        y = x if post else norm(x)
+        if self.kind == SSM:
+            y = Mamba2(
+                num_heads=cfg.ssm_num_heads,
+                head_dim=cfg.ssm_head_dim,
+                state_size=cfg.ssm_state_size,
+                num_groups=cfg.ssm_groups,
+                conv_taps=cfg.ssm_conv_kernel,
+                chunk=cfg.ssm_chunk,
+                dt_min=cfg.ssm_dt_min,
+                dt_max=cfg.ssm_dt_max,
+                dt_floor=cfg.ssm_dt_floor,
+                norm_eps=cfg.norm_eps,
+                impl=cfg.ssm_impl,
+                dtype=cfg.dtype,
+                param_dtype=cfg.param_dtype,
+                name="ssm",
+            )(y)
+        elif self.kind == ATTENTION:
+            y = _attention(cfg)(y, positions, segment_ids)
+        elif self.kind == EXPERTS:
+            y, layer_aux = _experts(cfg)(y)
+            aux = aux + layer_aux
+        else:
+            y = _dense_mlp(cfg)(y)
+        if post:
+            y = norm(y)
+        # the names Block's two branches carry, so that a policy which
+        # keeps a mixer's or an MLP's output keeps this layer's
+        y = jax.ad_checkpoint.checkpoint_name(
+            y, "attn_out" if self.kind in (SSM, ATTENTION) else "mlp_out"
+        )
+        with jax.named_scope("residual"):
+            x = x + y
+        x = nn.with_logical_constraint(x, (lr.BATCH, lr.ACT_SEQ, lr.ACT_EMBED))
+        return (x, aux), None
+
+
+def block_class(
+    cfg: TransformerConfig, prevent_cse: bool, kind: str = FULL_ATTENTION
+):
+    """``kind``'s block class (``Block``, or ``BranchBlock`` for a layer
+    that is one branch), under the config's remat policy (registry lookup,
     ops/remat_policy.py: named save sets and builtins resolve there).
     A block that IS a scan's body needs no barrier against CSE (the loop
     is one); a block among others does, or the compiler may rebuild every
     block of the body before the first cotangent arrives."""
+    return _block_class(cfg, prevent_cse, kind in BRANCH_KINDS)
+
+
+@functools.lru_cache(maxsize=64)
+def _block_class(cfg: TransformerConfig, prevent_cse: bool, branch: bool):
+    cls = BranchBlock if branch else Block
     if cfg.remat == "none":
-        return Block
+        return cls
     return nn.remat(
-        Block,
+        cls,
         policy=remat_policies.jax_policy(cfg.remat),
         prevent_cse=prevent_cse,
         static_argnums=(),
@@ -652,7 +861,8 @@ def block_class(cfg: TransformerConfig, prevent_cse: bool):
 
 
 def slot_name(position: int, kind: str) -> str:
-    """A block's name inside a period: ``linear_0`` .. ``full_3``."""
+    """A block's name inside a period: ``linear_0`` .. ``full_3``,
+    ``experts_0`` .. ``attention_8``."""
     return f"{kind.split('_')[0]}_{position}"
 
 
@@ -666,11 +876,10 @@ class Period(nn.Module):
     @nn.compact
     def __call__(self, carry, positions=None, segment_ids=None):
         cfg = self.config
-        block_cls = block_class(cfg, prevent_cse=True)
         for i, kind in enumerate(cfg.layer_pattern):
-            carry, _ = block_cls(cfg, kind, name=slot_name(i, kind))(
-                carry, positions, segment_ids
-            )
+            carry, _ = block_class(cfg, prevent_cse=True, kind=kind)(
+                cfg, kind, name=slot_name(i, kind)
+            )(carry, positions, segment_ids)
         return carry, None
 
 
@@ -751,6 +960,10 @@ class TransformerLM(nn.Module):
             features=cfg.d_model,
             dtype=cfg.dtype,
             param_dtype=cfg.param_dtype,
+            embedding_init=(
+                nn.initializers.normal(stddev=cfg.embed_init_std)
+                if cfg.embed_init_std else layers.default_embed_init
+            ),
             name="embed",
         )
         x = embed(tokens)
@@ -804,8 +1017,9 @@ class TransformerLM(nn.Module):
         else:
             carry = (x, aux0)
             for i in range(cfg.first_k_dense, cfg.num_layers):
-                carry, _ = block_cls(
-                    cfg, cfg.layer_kind(i), name=f"block_{i}"
+                kind = cfg.layer_kind(i)
+                carry, _ = block_class(cfg, prevent_cse=True, kind=kind)(
+                    cfg, kind, name=f"block_{i}"
                 )(carry, positions, segment_ids)
             x, aux = carry
 

@@ -20,6 +20,8 @@ The saveable names are emitted by the model code via
 (ops/flash_attention.py custom_vjp fwd — flash impl only), ``delta_out``
 (models/linear_attention.py: the gated delta rule's output; a model
 without such a layer emits no such name, and its step is what it was),
+``ssd_out`` (models/mamba2.py: the state-space scan's output, likewise
+only where a model has such a layer),
 ``latent_k`` / ``latent_v`` (models/attention.py ``LatentAttention``: the
 per-head keys and values rebuilt from the latent row; NO registered policy
 keeps them).
@@ -30,6 +32,13 @@ the forward kernel writes those again under the remat: keeping them too
 (no second forward) was the slower step on the chip, because the compiler
 then made room by recomputing two projections, and keeping neither let it
 pick slower layouts around the rule (PERF.md §6, PR 32).
+
+What ``flash_only`` keeps of a state-space (Mamba-2) layer is likewise the
+scan's output alone (``ssd_out``, ``[B, S, H P]``): the scan's forward
+kernel runs a second time in the backward for the chunk-start states
+(``[B G, S / 128, N, 512]``, 134 MB a layer at 2 x 8192 tokens).  Keeping
+those too was refused by the chip's compiler at the benchmark's depth
+("Used 16.06G of 15.75G hbm"; PERF.md §6, PR 37).
 
 What ``flash_only`` keeps of a latent-attention layer is the flash
 kernel's output and log-sum-exp rows, as of any attention layer.  The
@@ -109,9 +118,11 @@ register(RematPolicy(
     hbm_act_per_token_layer=3.05, recompute_fraction=0.55,
 ))
 # The mixers' kernels' outputs and nothing else: the flash kernel's, and
-# where a layer pattern has gated-delta-rule layers, the rule's.
+# where a layer pattern has gated-delta-rule or state-space layers, the
+# rule's and the scan's.
 register(RematPolicy(
-    "flash_only", saved_names=("flash_out", "flash_lse", "delta_out"),
+    "flash_only",
+    saved_names=("flash_out", "flash_lse", "delta_out", "ssd_out"),
     hbm_act_per_token_layer=2.05, recompute_fraction=0.7,
 ))
 

@@ -50,6 +50,8 @@ MLP = "mlp"                # param MLP hidden dim (TP col split)
 HEADS = "heads"            # param attention heads dim (TP split)
 KV = "kv"                  # param per-head dim
 LATENT = "latent"          # latent attention's low-rank dim (q 1536, kv 512+64)
+SSM_INNER = "ssm_inner"    # a state-space mixer's fused columns [z|x|B|C|dt]
+SSM_HEADS = "ssm_heads"    # its per-head scalars (A_log, D, dt_bias)
 VOCAB = "vocab"            # param vocab dim (TP vocab split)
 EXPERT = "expert"          # param expert dim (EP shard dim)
 LAYERS = "layers"          # scanned layer dim (within one pipeline stage)
@@ -86,6 +88,14 @@ def make_rules(
         # A latent is whole on every device: its down-projection contracts
         # the (fsdp-sharded) embed dim, its up-projection splits by heads.
         (LATENT, None),
+        # A state-space mixer's heads read the B and C of their GROUP, and
+        # its one input projection lays z, x, B, C and dt side by side: no
+        # even split of those columns keeps a head with its group, so the
+        # columns, the convolution's channels and the per-head scalars are
+        # whole on every device (its projections still split on embed), and
+        # the scan runs on each device's own batch rows.
+        (SSM_INNER, None),
+        (SSM_HEADS, None),
         (NORM, None),
         (GATHERED, None),
     ]

@@ -31,6 +31,7 @@ from dlrover_tpu.common import faults, telemetry
 from dlrover_tpu.common.log import default_logger as logger
 from dlrover_tpu.common.retry import RetryError, RetryPolicy
 from dlrover_tpu.models import linear_attention
+from dlrover_tpu.models import mamba2
 from dlrover_tpu.models import moe as moe_lib
 from dlrover_tpu.models.transformer import TransformerConfig, TransformerLM
 from dlrover_tpu.parallel import rules as lr
@@ -47,6 +48,7 @@ _NO_BATCH = object()
 # until a report reads them.
 _STATS_KEYS = (
     "moe_stats", moe_lib.SHARE_STATS_NAME, linear_attention.STATS_NAME,
+    mamba2.STATS_NAME,
 )
 
 _PROCESS_START_BOOKED = False
@@ -353,6 +355,7 @@ class ElasticTrainer:
                 "persistent_misses": after["misses"] - before["misses"],
                 "kernel_calls": self.train.kernel_calls,
                 **self._flash_facts(),
+                "ssm_scan": self._ssm_scan(),
             }
             logger.info("compile warmup: %s", detail)
             telemetry.event(
@@ -449,6 +452,13 @@ class ElasticTrainer:
             self._ref_accum, self.config.global_batch_size,
             self._dp_shards(),
         )
+
+    def _ssm_scan(self) -> str:
+        """How the step program's state-space scan runs, for the
+        ``compile`` event: ``kernel`` / ``xla`` (``ops/ssd.py``), ``none``
+        for a model without such a layer."""
+        cfg = self.model_config
+        return cfg.ssm_impl if cfg.num_ssm_layers else "none"
 
     def _flash_facts(self) -> Dict[str, Any]:
         """What the step program's flash-attention kernels are, for the
@@ -1258,7 +1268,17 @@ class ElasticTrainer:
         logger.info(
             "step %d loss %.4f lr %.3g", step, loss, self.current_lr(step)
         )
-        state_absmax = self._report_linear_attn(metrics, step)
+        # the largest recurrent-state entry of either kind of layer; one
+        # that is not a number stays so
+        absmaxes = [
+            v for v in (
+                self._report_linear_attn(metrics, step),
+                self._report_ssm(metrics, step),
+            ) if v is not None
+        ]
+        state_absmax = None if not absmaxes else (
+            float("nan") if any(v != v for v in absmaxes) else max(absmaxes)
+        )
         anomalies = ()
         if self.numeric_monitor is not None:
             found = self.numeric_monitor.check(
@@ -1348,27 +1368,52 @@ class ElasticTrainer:
 
     def _report_linear_attn(self, metrics, step: int) -> Optional[float]:
         """Linear-attention health on the report cadence: the vector this
-        step's program returned (``linear_attention.split_stats``), as a
-        ``linear_attn`` event.  Returns its ``state_absmax`` for the
-        numeric check, ``None`` where the step handed none out or no
-        report is due."""
-        stats = metrics.get(linear_attention.STATS_NAME)
-        if stats is None or step % self.config.report_every:
+        step's program returned, as a ``linear_attn`` event.  Returns its
+        ``state_absmax`` for the numeric check, ``None`` where the step
+        handed none out or no report is due."""
+        read = self._state_stats(
+            metrics, step, linear_attention.STATS_NAME,
+            ("mean_alpha", "mean_beta"),
+        )
+        if read is None:
             return None
-        with pipeline_counters().host_block(
-            "linear_attn_stats", steps=(step,)
-        ):
-            vec = np.asarray(jax.device_get(stats), np.float64)
-        alpha, beta, absmax = linear_attention.split_stats(vec)
         telemetry.event(
             "linear_attn", step=step,
             layers=self.model_config.num_linear_layers,
-            chunk=linear_attention.GatedDeltaNet.chunk,
-            mean_alpha=float(alpha),
-            mean_beta=float(beta),
-            state_absmax=float(absmax),
+            chunk=linear_attention.GatedDeltaNet.chunk, **read,
         )
-        return float(absmax)
+        return read["state_absmax"]
+
+    def _report_ssm(self, metrics, step: int) -> Optional[float]:
+        """The same for the state-space layers, as an ``ssm`` event (their
+        vector is laid out as the linear layers')."""
+        read = self._state_stats(
+            metrics, step, mamba2.STATS_NAME, ("mean_decay", "mean_dt")
+        )
+        if read is None:
+            return None
+        telemetry.event(
+            "ssm", step=step, layers=self.model_config.num_ssm_layers,
+            chunk=self.model_config.ssm_chunk, **read,
+        )
+        return read["state_absmax"]
+
+    def _state_stats(
+        self, metrics, step: int, stats_name: str, means
+    ) -> Optional[Dict[str, float]]:
+        """A recurrent mixer's sown vector of this step, if a report is due
+        (``linear_attention.split_stats``: two means, named ``means``, and
+        ``state_absmax``, the largest state entry)."""
+        stats = metrics.get(stats_name)
+        if stats is None or step % self.config.report_every:
+            return None
+        with pipeline_counters().host_block(stats_name, steps=(step,)):
+            vec = np.asarray(jax.device_get(stats), np.float64)
+        first, second, absmax = linear_attention.split_stats(vec)
+        return {
+            means[0]: float(first), means[1]: float(second),
+            "state_absmax": float(absmax),
+        }
 
     def _emit_memory_event(self, step: int):
         """One flat-attr ``memory`` event: allocator truth + classified

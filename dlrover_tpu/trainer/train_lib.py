@@ -25,6 +25,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from dlrover_tpu.common.log import default_logger as logger
 from dlrover_tpu.models import linear_attention
+from dlrover_tpu.models import mamba2
 from dlrover_tpu.models import moe as moe_lib
 from dlrover_tpu.parallel import rules as lr
 
@@ -453,6 +454,9 @@ def _sown_vectors(sown, name: str) -> Optional[jax.Array]:
 
 
 ROUTER_LOADS = "router_loads"
+# The recurrent mixers' sown vectors, one layout ([mean decay, mean write
+# strength or step, largest state entry]) and one fold.
+_STATE_STATS = (linear_attention.STATS_NAME, mamba2.STATS_NAME)
 
 
 def _router_loads(sown) -> Dict[Tuple[str, ...], jax.Array]:
@@ -479,8 +483,9 @@ def _layer_stats(sown, router_loads: bool = False) -> Dict[str, Any]:
     """What a step hands out of the layers' sown vectors, by metric name:
     ``moe_stats`` (mean over the layers), ``moe_share_stats`` (mean share
     of the routed pairs computed here, largest router-bias entry) and
-    ``linear_attn_stats`` (``linear_attention.fold_stats``: means, and the
-    largest state entry).  Empty for a model whose layers sow none.
+    ``linear_attn_stats`` and ``ssm_stats`` (``linear_attention.fold_stats``:
+    means, and the largest state entry).  Empty for a model whose layers sow
+    none.
     ``router_loads`` adds each expert layer's own loads (``ROUTER_LOADS``,
     by module path) for the router-bias rule."""
     out = {}
@@ -490,9 +495,10 @@ def _layer_stats(sown, router_loads: bool = False) -> Dict[str, Any]:
     share = _sown_vectors(sown, moe_lib.SHARE_STATS_NAME)
     if share is not None:
         out[moe_lib.SHARE_STATS_NAME] = moe_lib.fold_share_stats(share)
-    linear = _sown_vectors(sown, linear_attention.STATS_NAME)
-    if linear is not None:
-        out[linear_attention.STATS_NAME] = linear_attention.fold_stats(linear)
+    for name in _STATE_STATS:
+        vectors = _sown_vectors(sown, name)
+        if vectors is not None:
+            out[name] = linear_attention.fold_stats(vectors)
     if router_loads:
         out[ROUTER_LOADS] = _router_loads(sown)
     return out
@@ -755,13 +761,14 @@ def build_sharded_train(
             " [int8]" if allgather_quant == "int8" else "",
         )
 
-    # A model with routers or linear-attention layers hands each layer's
-    # sown stats vector out of the step that computes it; any other
-    # model's step is applied as ever.
+    # A model with routers, linear-attention or state-space layers hands
+    # each layer's sown stats vector out of the step that computes it; any
+    # other model's step is applied as ever.
     model_config = getattr(model, "config", None)
     sows_stats = bool(
         getattr(model_config, "num_experts", 0)
-        or "linear_attention" in getattr(model_config, "layer_pattern", ())
+        or {"linear_attention", "ssm"}
+        & set(getattr(model_config, "layer_pattern", ()))
     )
     # The DeepSeek-V3 family: a multi-token-prediction module whose
     # cross-entropy joins the loss, and router biases the step moves.
@@ -777,7 +784,8 @@ def build_sharded_train(
     def _forward_sums(params, apply_fn, inputs, targets, weights):
         """One forward pass -> (weighted CE sum, token count, aux loss,
         layer statistics).  The last is ``_layer_stats`` of what the
-        layers sowed (``moe_stats``, ``linear_attn_stats``), folded over
+        layers sowed (``moe_stats``, ``linear_attn_stats``, ``ssm_stats``),
+        folded over
         layers and whatever axes the scan and the sow stack, under
         ``stop_gradient``; empty for a model that sows none.  A model with
         an MTP module is handed the targets as the next tokens, and the
@@ -1182,12 +1190,9 @@ def build_sharded_train(
             )
         if "mtp_ce_sum" in stats:
             metrics["mtp_loss"] = stats["mtp_ce_sum"].sum() / mtp_total
-        if linear_attention.STATS_NAME in stats:
-            metrics[linear_attention.STATS_NAME] = (
-                linear_attention.fold_stats(
-                    stats[linear_attention.STATS_NAME]
-                )
-            )
+        for name in _STATE_STATS:
+            if name in stats:
+                metrics[name] = linear_attention.fold_stats(stats[name])
         return new_state, metrics
 
     if grad_accum > 1:

@@ -119,6 +119,15 @@ def _delta_rule():
     return jax.grad(loss, argnums=(0, 1, 2, 3, 4))
 
 
+def _ssd():
+    from dlrover_tpu.ops.ssd import ssd
+
+    def loss(x, dt, a, b, c, d):
+        return ssd(x, dt, a, b, c, d, impl="kernel")[0].astype(F32).sum()
+
+    return jax.grad(loss, argnums=(0, 1, 2, 3, 4, 5))
+
+
 def _quant_roundtrip():
     from dlrover_tpu.ops import quantization as qz
 
@@ -230,6 +239,11 @@ CASES = [
     ("delta_rule_olmo_hybrid", _delta_rule,
      [((2, 8192, 30, 96), BF16)] * 2 + [((2, 8192, 30, 192), BF16)]
      + [((2, 8192, 30), F32)] * 2, {}, 2),
+    # Nemotron-3-Nano's Mamba-2 layers: 2 x 8192 tokens, 64 heads of 64, a
+    # 128-wide state, 8 groups: the forward kernel and the backward kernel
+    ("ssd_nemotron_h", _ssd,
+     [((2, 8192, 64, 64), BF16), ((2, 8192, 64), F32), ((64,), F32)]
+     + [((2, 8192, 8, 128), BF16)] * 2 + [((64,), F32)], {}, 2),
     ("quantize_dequantize", _quant_roundtrip, [LEAF], {}, 2),
     ("q8_adam", lambda: _adam_update("q8_adam"), [LEAF, LEAF], {}, 1),
     ("q4_adam", lambda: _adam_update("q4_adam"), [LEAF, LEAF], {}, 1),

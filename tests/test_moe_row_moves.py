@@ -332,5 +332,6 @@ def test_the_manifest_lists_the_reading_for_the_dropless_cells_only():
         "moves": "tokens_per_s_chip",
         "workloads": [
             "olmoe-1b-7b.train_steady", "joyai-llm-flash.train_steady",
+            "nemotron-3-nano-30b-a3b.train_steady",
         ],
     }]

@@ -18,8 +18,14 @@ The four wide projections run as ONE ``[d, H, 2 dk + 2 dv]`` matmul
 (param ``qkvg``; a head's row is ``[q | k | v | z]``), as the attention
 layer's ``qkv`` does and for the same reason; the split is on the per-head
 axis, which no strategy shards, so the heads shard as attention's do.  The
-convolution is depthwise, so it runs on the ``[q | k | v]`` slice as it
-lies.  ``W_a`` and ``W_b`` are one ``[d, H, 2]`` matmul in float32
+convolution is depthwise, so it runs on the ``[q | k | v]`` columns as
+they lie in that row: :func:`causal_depthwise_conv` is the convolution,
+the SiLU and the two L2 norms in one call, one Pallas pass forward and one
+backward (``ops/short_conv.py``) where the tokens are whole lane tiles and
+the widths whole row tiles, as at the published widths, and K shifted
+multiply-adds with XLA's own norms anywhere else (:func:`conv_path` says
+which; Mamba-2's mixer calls the same function with a bias and no norm).
+``W_a`` and ``W_b`` are one ``[d, H, 2]`` matmul in float32
 accumulation (param ``ab``).
 
 Each forward ``sow``s ``linear_attn_stats`` = ``[mean alpha, mean beta,
@@ -36,21 +42,23 @@ the compiled text as ``linear_attn/qkv``, ``/conv``, ``/gates``,
 from __future__ import annotations
 
 import math
-from typing import Any
+from typing import Any, Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.ad_checkpoint
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from dlrover_tpu.models import layers
+from dlrover_tpu.ops import short_conv
 from dlrover_tpu.ops.gated_delta_rule import gated_delta_rule
 from dlrover_tpu.parallel import rules as lr
 from dlrover_tpu.runtime.mesh import shard_local
 
 F32 = jnp.float32
 STATS_NAME = "linear_attn_stats"
-L2_EPS = 1e-6
+L2_EPS = short_conv.L2_EPS
 
 
 def split_stats(vec):
@@ -104,7 +112,7 @@ def _shifted_sum(x: jax.Array, taps: jax.Array, before: bool) -> jax.Array:
 
 
 @jax.custom_vjp
-def causal_depthwise_conv(x: jax.Array, taps: jax.Array) -> jax.Array:
+def _conv_xla(x: jax.Array, taps: jax.Array) -> jax.Array:
     """``y[t] = sum_j taps[j] * x[t - (K - 1) + j]`` per channel, zeros
     before the sequence starts.  ``x`` [B, S, ...], ``taps`` [K, ...]:
     K shifted multiply-adds, no convolution primitive.  The VJP is
@@ -132,7 +140,7 @@ def _conv_bwd(res, dy):
     return dx.astype(x.dtype), d_taps.astype(taps.dtype)
 
 
-causal_depthwise_conv.defvjp(_conv_fwd, _conv_bwd)
+_conv_xla.defvjp(_conv_fwd, _conv_bwd)
 
 
 def l2_normalise(x: jax.Array) -> jax.Array:
@@ -140,6 +148,73 @@ def l2_normalise(x: jax.Array) -> jax.Array:
     return x32 * jax.lax.rsqrt(
         jnp.sum(x32 * x32, axis=-1, keepdims=True) + L2_EPS
     )
+
+
+def _l2_scaled(y: jax.Array, scale: Optional[float]) -> jax.Array:
+    """``scale`` times the L2-normalised ``y`` in its dtype; ``y`` itself
+    for no scale."""
+    if scale is None:
+        return y
+    normed = l2_normalise(y)
+    return (normed if scale == 1.0 else normed * scale).astype(y.dtype)
+
+
+def short_conv_path(
+    x_shape, taps_shape, offset=0, splits=None, l2_scales=None,
+) -> str:
+    """``kernel`` where :func:`causal_depthwise_conv` runs ``ops/short_conv``
+    on such an input, ``xla`` where it runs the written-out form."""
+    tiled = short_conv.plan(x_shape, taps_shape, offset, splits, l2_scales)
+    return "xla" if tiled is None else "kernel"
+
+
+def causal_depthwise_conv(
+    x: jax.Array, taps: jax.Array, bias: Optional[jax.Array] = None, *,
+    offset: int = 0, splits: Optional[Tuple[int, ...]] = None,
+    l2_scales: Optional[Tuple[Optional[float], ...]] = None,
+):
+    """``SiLU(conv(x[..., offset: offset + C]) + bias)`` with ``conv`` the
+    causal depthwise convolution ``sum_j taps[j] * x[t - (K - 1) + j]`` per
+    channel (zeros before the sequence starts) and ``C = taps.shape[-1]``.
+    ``x`` [B, S, ..., W], ``taps`` [K, ..., C], ``bias`` [..., C] or None;
+    with ``splits`` the result comes as the tuple of those widths of its
+    last axis, the one whose ``l2_scales`` is a number L2-normalised over
+    its width and multiplied by it.
+
+    Chosen from the input's shape: tokens whole lane tiles and channels and
+    offset whole row tiles (``ops/short_conv.plan``) run as one Pallas pass
+    forward and one backward, which read ``x`` in place; anything else as K
+    shifted multiply-adds in XLA, the form the kernels are held to."""
+    channels = taps.shape[-1]
+    path = short_conv_path(x.shape, taps.shape, offset, splits, l2_scales)
+    if path == "xla":
+        y = _conv_xla(x[..., offset: offset + channels], taps)
+        if bias is not None:
+            y = y + bias
+        y = nn.silu(y)
+        if not splits:
+            return y
+        edges = [sum(splits[:n]) for n in range(len(splits) + 1)]
+        return tuple(
+            _l2_scaled(y[..., lo: hi], scale)
+            for lo, hi, scale in zip(
+                edges, edges[1:], l2_scales or (None,) * len(splits)
+            )
+        )
+    # a Mosaic kernel sees its device's block: batch rows may stay sharded,
+    # the tokens and the channels are whole
+    rows = nn.logical_to_mesh_axes((lr.BATCH,) + (None,) * (x.ndim - 1))
+    args, specs = (x, taps), (rows, P())
+    if bias is not None:
+        args, specs = args + (bias,), specs + (P(),)
+
+    def local(x, taps, bias=None):
+        return short_conv.short_conv(x, taps, bias, offset, splits, l2_scales)
+
+    return shard_local(
+        local, in_specs=specs,
+        out_specs=(rows,) * len(splits) if splits else rows,
+    )(*args)
 
 
 def _delta_rule_local(q, k, v, g, beta, *, chunk):
@@ -161,6 +236,18 @@ def _delta_rule_local(q, k, v, g, beta, *, chunk):
         ),
     )(q, k, v, g, beta)
     return o, state_absmax.max()
+
+
+def conv_path(
+    seq: int, num_heads: int, key_dim: int, value_dim: int, taps: int,
+) -> str:
+    """How :class:`GatedDeltaNet` of these widths runs its convolution on
+    ``seq`` tokens: what ``causal_depthwise_conv`` answers for its call."""
+    return short_conv_path(
+        (1, seq, num_heads, 2 * key_dim + 2 * value_dim),
+        (taps, num_heads, 2 * key_dim + value_dim),
+        0, (key_dim, key_dim, value_dim), (key_dim ** -0.5, 1.0, None),
+    )
 
 
 class GatedDeltaNet(nn.Module):
@@ -195,12 +282,11 @@ class GatedDeltaNet(nn.Module):
                 ),
                 (self.conv_taps, h, 2 * dk + dv), self.param_dtype,
             )
-            qkv = nn.silu(causal_depthwise_conv(
-                qkvg[..., : 2 * dk + dv], taps.astype(self.dtype)
-            ))
-            q = (l2_normalise(qkv[..., :dk]) * dk ** -0.5).astype(self.dtype)
-            k = l2_normalise(qkv[..., dk: 2 * dk]).astype(self.dtype)
-            v = qkv[..., 2 * dk:]
+            # q and k leave L2-normalised, q times dk ** -0.5 besides
+            q, k, v = causal_depthwise_conv(
+                qkvg, taps.astype(self.dtype), splits=(dk, dk, dv),
+                l2_scales=(dk ** -0.5, 1.0, None),
+            )
         with jax.named_scope("gates"):
             ab_kernel = self.param(
                 "ab_kernel",

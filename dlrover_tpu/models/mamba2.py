@@ -20,9 +20,14 @@ inner width ``H P`` (its own number, not ``expand x hidden``)::
 
 The projection is ONE ``[d, 2 H P + 2 G N + H]`` matmul (param
 ``in_proj``), its ``dt`` columns leave it in the activations' dtype and
-are float32 from the softplus on.  The convolution is
-``linear_attention.causal_depthwise_conv`` over all ``H P + 2 G N``
-channels, with a bias.
+are float32 from the softplus on.  The convolution, its bias and the SiLU
+are ONE call of ``linear_attention.causal_depthwise_conv`` over all ``H P
++ 2 G N`` channels, which reads them where they lie in the projection
+(columns ``H P`` onwards) and returns ``x``, ``B`` and ``C`` apart: one
+Pallas pass forward and one backward (``ops/short_conv.py``) where the
+tokens are whole lane tiles and the widths whole row tiles, as at the
+published widths; K shifted multiply-adds in XLA anywhere else
+(:func:`conv_path` says which).
 
 Each forward ``sow``s ``ssm_stats`` = ``[mean exp(dt A), mean dt, largest
 |S| entry at a chunk boundary]`` (``linear_attention.split_stats`` reads
@@ -49,6 +54,7 @@ from dlrover_tpu.models import layers
 from dlrover_tpu.models.linear_attention import (
     causal_depthwise_conv,
     conv_init,
+    short_conv_path,
 )
 from dlrover_tpu.ops.ssd import ssd
 from dlrover_tpu.parallel import rules as lr
@@ -96,6 +102,19 @@ def _ssd_local(x, dt, a_head, b, c, d, *, chunk, impl):
         out_specs=(rows, nn.logical_to_mesh_axes((lr.BATCH,))),
     )(x, dt, a_head, b, c, d)
     return y, state_absmax.max()
+
+
+def conv_path(
+    seq: int, num_heads: int, head_dim: int, state_size: int,
+    num_groups: int, taps: int,
+) -> str:
+    """How :class:`Mamba2` of these widths runs its convolution on ``seq``
+    tokens: what ``causal_depthwise_conv`` answers for its call."""
+    inner, bc = num_heads * head_dim, num_groups * state_size
+    return short_conv_path(
+        (1, seq, 2 * inner + 2 * bc + num_heads), (taps, inner + 2 * bc),
+        inner, (inner, bc, bc),
+    )
 
 
 class Mamba2(nn.Module):
@@ -147,15 +166,13 @@ class Mamba2(nn.Module):
                 ),
                 (inner + 2 * bc,), self.param_dtype,
             )
-            xbc = nn.silu(
-                causal_depthwise_conv(
-                    proj[..., inner: 2 * inner + 2 * bc],
-                    taps.astype(self.dtype),
-                ) + bias.astype(self.dtype)
+            x_in, b, c = causal_depthwise_conv(
+                proj, taps.astype(self.dtype), bias.astype(self.dtype),
+                offset=inner, splits=(inner, bc, bc),
             )
-            x_in = xbc[..., :inner].reshape(batch, s, h, p)
-            b = xbc[..., inner: inner + bc].reshape(batch, s, g, n)
-            c = xbc[..., inner + bc:].reshape(batch, s, g, n)
+            x_in = x_in.reshape(batch, s, h, p)
+            b = b.reshape(batch, s, g, n)
+            c = c.reshape(batch, s, g, n)
         with jax.named_scope("dt"):
             # 3 H numbers that set every decay and step: float32 whatever
             # the parameters' dtype, as Mamba-2 keeps them

@@ -40,6 +40,12 @@ kernel runs a second time in the backward for the chunk-start states
 those too was refused by the chip's compiler at the benchmark's depth
 ("Used 16.06G of 15.75G hbm"; PERF.md §6, PR 37).
 
+Neither layer's short convolution is kept: it runs a second time in the
+backward, and the backward rebuilds its pre-activation a third time from
+the projection's output (``ops/short_conv.py``: one Pallas pass each way
+where the shape tiles, PERF.md §6, PR 38), which costs a pass where a kept
+copy costs 201 / 377 MB a layer that neither step has.
+
 What ``flash_only`` keeps of a latent-attention layer is the flash
 kernel's output and log-sum-exp rows, as of any attention layer.  The
 per-head k and v (``[B, S, H, 192 + 128]``, 335 MB a layer at 2 x 8192

@@ -356,6 +356,7 @@ class ElasticTrainer:
                 "kernel_calls": self.train.kernel_calls,
                 **self._flash_facts(),
                 "ssm_scan": self._ssm_scan(),
+                "short_conv": self._short_conv(),
             }
             logger.info("compile warmup: %s", detail)
             telemetry.event(
@@ -459,6 +460,31 @@ class ElasticTrainer:
         for a model without such a layer."""
         cfg = self.model_config
         return cfg.ssm_impl if cfg.num_ssm_layers else "none"
+
+    def _short_conv(self) -> str:
+        """How the step program's short convolutions run, for the
+        ``compile`` event: ``kernel`` (``ops/short_conv.py``) / ``xla``
+        (the written-out form), ``none`` for a model without a state-space
+        or linear-attention layer.  Chosen at trace time from the shapes
+        alone, so this asks the function the dispatch asks."""
+        cfg = self.model_config
+        seq = self.config.seq_len
+        paths = set()
+        if cfg.num_ssm_layers:
+            from dlrover_tpu.models import mamba2
+
+            paths.add(mamba2.conv_path(
+                seq, cfg.ssm_num_heads, cfg.ssm_head_dim,
+                cfg.ssm_state_size, cfg.ssm_groups, cfg.ssm_conv_kernel,
+            ))
+        if cfg.num_linear_layers:
+            from dlrover_tpu.models import linear_attention
+
+            paths.add(linear_attention.conv_path(
+                seq, cfg.resolved_linear_heads, cfg.linear_key_head_dim,
+                cfg.linear_value_head_dim, cfg.linear_conv_kernel,
+            ))
+        return "+".join(sorted(paths)) or "none"
 
     def _flash_facts(self) -> Dict[str, Any]:
         """What the step program's flash-attention kernels are, for the

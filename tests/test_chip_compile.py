@@ -128,6 +128,20 @@ def _ssd():
     return jax.grad(loss, argnums=(0, 1, 2, 3, 4, 5))
 
 
+def _short_conv(offset, splits, l2_scales, has_bias):
+    from dlrover_tpu.ops.short_conv import short_conv
+
+    def loss(x, taps, bias=None):
+        outs = short_conv(x, taps, bias, offset, splits, l2_scales)
+        return sum(y.astype(F32).sum() for y in outs)
+
+    # the backward rebuilds what it needs from x: the value keeps the
+    # forward kernel in the program
+    return jax.value_and_grad(
+        loss, argnums=(0, 1, 2) if has_bias else (0, 1)
+    )
+
+
 def _quant_roundtrip():
     from dlrover_tpu.ops import quantization as qz
 
@@ -244,6 +258,16 @@ CASES = [
     ("ssd_nemotron_h", _ssd,
      [((2, 8192, 64, 64), BF16), ((2, 8192, 64), F32), ((64,), F32)]
      + [((2, 8192, 8, 128), BF16)] * 2 + [((64,), F32)], {}, 2),
+    # the short convolutions at the cells' 2 x 8192 tokens, the forward
+    # kernel and the backward kernel: Nemotron's 6,144 channels from column
+    # 4,096 of in_proj's 10,304 with a bias, x | B | C written apart; the
+    # hybrid's 384 of each of 30 heads' 576, q and k L2-normalised
+    ("short_conv_nemotron_h",
+     lambda: _short_conv(4096, (4096, 1024, 1024), None, True),
+     [((2, 8192, 10304), BF16), ((4, 6144), BF16), ((6144,), BF16)], {}, 2),
+    ("short_conv_olmo_hybrid",
+     lambda: _short_conv(0, (96, 96, 192), (96 ** -0.5, 1.0, None), False),
+     [((2, 8192, 30, 576), BF16), ((4, 30, 384), BF16)], {}, 2),
     ("quantize_dequantize", _quant_roundtrip, [LEAF], {}, 2),
     ("q8_adam", lambda: _adam_update("q8_adam"), [LEAF, LEAF], {}, 1),
     ("q4_adam", lambda: _adam_update("q4_adam"), [LEAF, LEAF], {}, 1),

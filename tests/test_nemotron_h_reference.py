@@ -154,7 +154,39 @@ def test_token_nll_matches_the_reference(case, params, tokens):
 @pytest.mark.parametrize("case", ["xla", "kernels_a_share"])
 def test_loss_and_every_gradient_match_the_reference(case, params, tokens):
     cfg = config(**CASES[case])
-    ours = held(params, cfg)
+    loss_and_every_gradient_match(cfg, held(params, cfg), tokens)
+
+
+def test_loss_and_every_gradient_match_where_the_conv_kernel_runs():
+    """64 tokens are half a lane tile, so the cases above take the
+    convolution's XLA form; 256 tokens of the same x | B | C = 256 | 32 |
+    32 channels from column 256 are two token tiles of
+    ``ops/short_conv.py``'s kernels (the tokens on the lanes, 32 channels a
+    tile, three outputs)."""
+    from dlrover_tpu.models import mamba2
+    from dlrover_tpu.ops import short_conv
+
+    seq = 256
+    rows = jax.random.randint(jax.random.PRNGKey(2), (BATCH, seq + 1), 0, VOCAB)
+    tokens = rows[:, :-1], rows[:, 1:]
+    assert mamba2.conv_path(SEQ, 4, 64, 16, 2, 4) == "xla"
+    assert mamba2.conv_path(seq, 4, 64, 16, 2, 4) == "kernel"
+    calls = []
+    kernel = short_conv.short_conv
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(short_conv, "_TILE_TOKENS", 128)
+        patch.setattr(
+            short_conv, "short_conv",
+            lambda *a: calls.append(a[0].shape) or kernel(*a),
+        )
+        for case in ("xla", "kernels_a_share"):
+            cfg = config(max_seq_len=seq, **CASES[case])
+            ours = held(init(config(max_seq_len=seq), tokens[0]), cfg)
+            loss_and_every_gradient_match(cfg, ours, tokens)
+    assert calls and set(calls) == {(BATCH, seq, 580)}
+
+
+def loss_and_every_gradient_match(cfg, ours, tokens):
     got, got_grads = jax.value_and_grad(program_loss, argnums=1)(
         cfg, ours, *tokens
     )

@@ -234,9 +234,13 @@ def tokens():
 
 @pytest.fixture(scope="module")
 def params(tokens):
+    return init(config(), tokens)
+
+
+def init(cfg, tokens):
     """The program's own init, then every norm scale moved off one."""
     tree = nn.meta.unbox(
-        TransformerLM(config()).init(jax.random.PRNGKey(5), tokens[0])
+        TransformerLM(cfg).init(jax.random.PRNGKey(5), tokens[0])
     )["params"]
     rng = np.random.default_rng(7)
 
@@ -295,6 +299,42 @@ def test_loss_and_every_gradient_match_the_reference(
     cfg = config(attention_impl=attention_impl, remat=(
         "flash_only" if attention_impl == "flash" else "none"
     ))
+    loss_and_every_gradient_match(cfg, params, tokens)
+
+
+def test_loss_and_every_gradient_match_where_the_conv_kernel_runs():
+    """Heads of 12 | 12 | 24 columns are no whole row tiles and 144 tokens
+    no whole lane tile, so the cases above take the convolution's XLA form;
+    256 tokens of heads of 16 | 16 | 32 are two token tiles of
+    ``ops/short_conv.py``'s kernels (the tokens on the lanes; q and k one
+    row tile each, normalised inside; v two)."""
+    from dlrover_tpu.models import linear_attention
+    from dlrover_tpu.ops import short_conv
+
+    seq = 256
+    rng = np.random.default_rng(13)
+    rows = jnp.asarray(rng.integers(0, VOCAB, (BATCH, seq + 1)), jnp.int32)
+    tokens = rows[:, :-1], rows[:, 1:]
+    assert linear_attention.conv_path(seq, 4, 12, 24, 4) == "xla"
+    assert linear_attention.conv_path(SEQ, 4, 16, 32, 4) == "xla"
+    assert linear_attention.conv_path(seq, 4, 16, 32, 4) == "kernel"
+    calls = []
+    kernel = short_conv.short_conv
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(short_conv, "_TILE_TOKENS", 128)
+        patch.setattr(
+            short_conv, "short_conv",
+            lambda *a: calls.append(a[0].shape) or kernel(*a),
+        )
+        cfg = config(
+            attention_impl="flash", remat="flash_only", max_seq_len=seq,
+            linear_key_head_dim=16, linear_value_head_dim=32,
+        )
+        loss_and_every_gradient_match(cfg, init(cfg, tokens), tokens)
+    assert calls and set(calls) == {(BATCH, seq, 4, 96)}
+
+
+def loss_and_every_gradient_match(cfg, params, tokens):
     got, got_grads = jax.value_and_grad(program_loss, argnums=1)(
         cfg, params, *tokens
     )

@@ -106,8 +106,10 @@ def xla_attention(
     *,
     causal: bool = True,
     segment_ids: Optional[jax.Array] = None,
+    scale: Optional[float] = None,
 ) -> jax.Array:
-    """Reference einsum attention; fp32 softmax; shapes [B, S, H, D].
+    """Reference einsum attention; fp32 softmax; shapes [B, S, H, D];
+    the scores times ``scale`` (default ``D ** -0.5``).
 
     Supports GQA (H_kv dividing H_q) and packed-sequence masks via
     ``segment_ids`` — the capability match for the reference's GLM/pack mask
@@ -116,7 +118,7 @@ def xla_attention(
     b, sq, hq, d = q.shape
     hkv = k.shape[2]
     group = hq // hkv
-    scale = d ** -0.5
+    scale = d ** -0.5 if scale is None else scale
     # GQA via broadcast, not jnp.repeat: grouping q keeps K/V (and their
     # remat recompute) at H_kv width instead of inflating HBM by `group`x.
     # (``v`` may have another width than ``q`` and ``k``: the output's.)
@@ -146,6 +148,7 @@ def cached_attention(
     k: jax.Array,
     v: jax.Array,
     q_positions: jax.Array,
+    scale: Optional[float] = None,
 ) -> jax.Array:
     """Decode attention: queries at absolute ``q_positions`` [B, T]
     against the full KV cache [B, L, H_kv, D]; cache slots past a query's
@@ -153,7 +156,7 @@ def cached_attention(
     b, sq, hq, d = q.shape
     cache_len, hkv = k.shape[1], k.shape[2]
     group = hq // hkv
-    scale = d ** -0.5
+    scale = d ** -0.5 if scale is None else scale
     qg = q.reshape(b, sq, hkv, group, d)
     logits = jnp.einsum(
         "bqhgd,bkhd->bhgqk", qg, k, preferred_element_type=jnp.float32
@@ -168,7 +171,7 @@ def cached_attention(
     return out.reshape(b, sq, hq, d)
 
 
-def _flash_local(q, k, v, segment_ids, *, block_q, block_kv):
+def _flash_local(q, k, v, segment_ids, *, block_q, block_kv, scale=None):
     """Causal flash attention on each device's own batch rows and heads
     (:func:`shard_local`): q/k/v stay sharded as the active rule table
     lays out ``[batch, -, act_heads, kv]``, the sequence is whole."""
@@ -182,7 +185,7 @@ def _flash_local(q, k, v, segment_ids, *, block_q, block_kv):
 
     def local(q, k, v, seg=None):
         return fa.mha(
-            q, k, v, causal=True, segment_ids=seg,
+            q, k, v, causal=True, segment_ids=seg, scale=scale,
             block_q=block_q, block_kv=block_kv,
         )
 
@@ -233,6 +236,10 @@ class Attention(nn.Module):
     norm_eps: float = 1e-5
     flash_block_q: int = 512
     flash_block_kv: int = 512
+    # What multiplies the scores before the softmax on every path (flash,
+    # einsum, ring, cached); 0 -> ``head_dim ** -0.5``.  Granite's
+    # ``attention_multiplier`` is 1/128 at heads of 128.
+    scale: float = 0.0
     # Autoregressive decoding: keep K/V in a "cache" collection of
     # ``cache_len`` slots and attend incoming queries (prefill chunk or
     # single decode token) against it.
@@ -247,6 +254,7 @@ class Attention(nn.Module):
         segment_ids: Optional[jax.Array] = None,
     ) -> jax.Array:
         features = x.shape[-1]
+        scale = self.scale or None
         if positions is None:
             positions = jnp.arange(x.shape[1])[None, :]
 
@@ -362,11 +370,11 @@ class Attention(nn.Module):
                 out = _flash_local(
                     q, k.astype(self.dtype), v.astype(self.dtype), None,
                     block_q=self.flash_block_q,
-                    block_kv=self.flash_block_kv,
+                    block_kv=self.flash_block_kv, scale=scale,
                 )
             else:
                 out = cached_attention(
-                    q, cached_k.value, cached_v.value, q_positions
+                    q, cached_k.value, cached_v.value, q_positions, scale
                 )
         elif self.attention_impl == "ring":
             # Ring CP: sequence stays sharded; K/V stream around the ring.
@@ -376,12 +384,15 @@ class Attention(nn.Module):
             q = nn.with_logical_constraint(q, spec)
             k = nn.with_logical_constraint(k, spec)
             v = nn.with_logical_constraint(v, spec)
-            out = ring_attention(q, k, v, causal=True, segment_ids=segment_ids)
+            out = ring_attention(
+                q, k, v, causal=True, segment_ids=segment_ids, scale=scale
+            )
             out = nn.with_logical_constraint(out, spec)
         elif self.attention_impl in ("flash", "xla"):
             flash = self.attention_impl == "flash"
             blocks = dict(
-                block_q=self.flash_block_q, block_kv=self.flash_block_kv
+                block_q=self.flash_block_q, block_kv=self.flash_block_kv,
+                scale=scale,
             )
             if mesh_axis_size(SEQ_AXIS) > 1:
                 # Ulysses SP: explicit seq<->heads all-to-alls (see
@@ -395,7 +406,7 @@ class Attention(nn.Module):
                             q, k, v, causal=True, segment_ids=seg, **blocks
                         )
                     return xla_attention(
-                        q, k, v, causal=True, segment_ids=seg
+                        q, k, v, causal=True, segment_ids=seg, scale=scale
                     )
 
                 out = ulysses_attention(attn_fn, q, k, v, segment_ids)
@@ -407,7 +418,8 @@ class Attention(nn.Module):
                 k = nn.with_logical_constraint(k, attn_spec)
                 v = nn.with_logical_constraint(v, attn_spec)
                 out = xla_attention(
-                    q, k, v, causal=True, segment_ids=segment_ids
+                    q, k, v, causal=True, segment_ids=segment_ids,
+                    scale=scale,
                 )
                 out = nn.with_logical_constraint(out, attn_spec)
         else:
@@ -467,6 +479,7 @@ class LatentAttention(nn.Module):
     attention_impl: str = "xla"
     flash_block_q: int = 512
     flash_block_kv: int = 512
+    scale: float = 0.0             # 0 -> (nope + rope) ** -0.5
 
     @nn.compact
     def __call__(
@@ -532,14 +545,17 @@ class LatentAttention(nn.Module):
         if self.attention_impl == "flash":
             out = _flash_local(
                 q, k, v, segment_ids, block_q=self.flash_block_q,
-                block_kv=self.flash_block_kv,
+                block_kv=self.flash_block_kv, scale=self.scale or None,
             )
         else:
             attn_spec = (lr.BATCH, None, lr.ACT_HEADS, lr.KV)
             q = nn.with_logical_constraint(q, attn_spec)
             k = nn.with_logical_constraint(k, attn_spec)
             v = nn.with_logical_constraint(v, attn_spec)
-            out = xla_attention(q, k, v, causal=True, segment_ids=segment_ids)
+            out = xla_attention(
+                q, k, v, causal=True, segment_ids=segment_ids,
+                scale=self.scale or None,
+            )
             out = nn.with_logical_constraint(out, attn_spec)
         return layers.DenseGeneral(
             features, axis=(-2, -1),

@@ -4,7 +4,9 @@ place of a KV cache (Dao & Gu, arXiv:2405.21060 §6-7), as Nemotron-H's
 
 With ``n`` the mixer's input, ``H`` heads of ``P``, a state of ``N`` a
 head, ``G`` groups of ``H / G`` heads that share ``B`` and ``C``, and the
-inner width ``H P`` (its own number, not ``expand x hidden``)::
+inner width ``H P`` (its own number, not ``expand x hidden``; Nemotron-H:
+64 heads in 8 groups, Granite-4.0-H: 128 heads in ONE, which the scan's
+kernels cut into tiles of heads, ``ops/ssd.py`` ``heads_per_step``)::
 
     [z | xBC | dt] = n W_in                 (H P | H P + 2 G N | H; no bias)
     xBC = SiLU(causal depthwise conv(xBC), ``taps`` taps, + b_conv)
@@ -128,6 +130,9 @@ class Mamba2(nn.Module):
     dt_max: float = 0.1
     dt_floor: float = 1e-4
     norm_eps: float = 1e-5
+    # ``out_proj``'s initial scale over lecun normal's (Mamba's reference
+    # code rescales it by ``(residual branches) ** -0.5``)
+    out_init_scale: float = 1.0
     impl: str = "xla"              # ops/ssd.py: "xla" | "kernel"
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
@@ -239,5 +244,9 @@ class Mamba2(nn.Module):
             features,
             kernel_axes=(lr.SSM_INNER, lr.EMBED),
             dtype=self.dtype, param_dtype=self.param_dtype,
+            # lecun normal at scale 1
+            kernel_init=nn.initializers.variance_scaling(
+                self.out_init_scale ** 2, "fan_in", "truncated_normal"
+            ),
             name="out_proj",
         )(y)

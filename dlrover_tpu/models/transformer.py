@@ -13,7 +13,13 @@ whose output leaves the model only where the caller hands it the next
 tokens (the train step does).  Nemotron-H's layers are kinds of a layer pattern:
 a layer that is ONE residual branch (a Mamba-2 state-space mixer, an
 attention without rotation, an expert layer of ungated ``relu2`` experts
-with a shared expert of its own width, or a dense MLP alone).
+with a shared expert of its own width, or a dense MLP alone).  Granite-4.0-H's
+layer, a mixer AND an expert layer on two pre-norm branches, runs as two
+such layers (``ssm`` | ``attention``, then ``experts``), and its four scalar
+multipliers are fields with neutral defaults: ``embed_scale`` on the
+embedding's output, ``attention_scale`` on the scores, ``residual_scale`` on
+a branch's output before its residual add (every block class), and
+``logit_scale`` on the logits.
 
 TPU-first structure:
   * layers are ``nn.scan``-stacked: one trace regardless of depth (fast
@@ -84,6 +90,13 @@ class TransformerConfig:
     # is how far the token's own row outweighs the branches' outputs at
     # init: see ``benchmark/configs/nemotron-3-nano-30b-a3b.json``.
     embed_init_std: float = 0.0
+    # Granite's scalar multipliers (with ``logit_scale`` below, which is
+    # 1 / ``logits_scaling``): on the embedding's output, on the attention
+    # scores before the softmax (0 -> ``head_dim ** -0.5``) and on every
+    # branch's output before its residual add.  The defaults change nothing.
+    embed_scale: float = 1.0
+    attention_scale: float = 0.0
+    residual_scale: float = 1.0
     # MoE
     num_experts: int = 0
     top_k: int = 2
@@ -164,6 +177,12 @@ class TransformerConfig:
     ssm_dt_min: float = 0.001
     ssm_dt_max: float = 0.1
     ssm_dt_floor: float = 1e-4
+    # ``out_proj``'s initial scale over lecun normal's: the mixer's output
+    # at init is largely one vector for every late token of a sequence (a
+    # running sum of SiLU'd, so positive-mean, inputs), and Mamba's
+    # reference code rescales it by ``(residual branches) ** -0.5``; see
+    # ``benchmark/configs/granite-4.0-h-small.json``.
+    ssm_out_init_scale: float = 1.0
     ssm_impl: str = "xla"
     # The gated-delta-rule mixer of the "linear_attention" layers
     # (models/linear_attention.py): heads (0 -> num_heads), key and value
@@ -194,7 +213,8 @@ class TransformerConfig:
                                    # need attention_impl="flash")
     scan_layers: bool = True
     logits_dtype: Any = jnp.float32
-    logit_scale: float = 1.0       # µP output multiplier (optimizers/mup.py)
+    logit_scale: float = 1.0       # µP output multiplier (optimizers/mup.py);
+                                   # Granite's 1 / logits_scaling
     # Pipeline parallelism (see parallel/pipeline.py): stages must divide
     # num_layers; microbatches default to the stage count.
     pipeline_stages: int = 1
@@ -262,6 +282,15 @@ class TransformerConfig:
     @property
     def num_ssm_layers(self) -> int:
         return self.num_layers_of(SSM)
+
+    @property
+    def ssm_heads_per_step(self) -> int:
+        """Heads one grid step of the scan kernels holds at these sizes
+        (``ops/ssd.py``); 0 where the kernels do not hold them."""
+        return ssd.heads_per_step(
+            self.ssm_num_heads, self.ssm_head_dim, self.ssm_groups,
+            self.ssm_state_size, self.ssm_chunk, self.dtype,
+        )
 
     def layer_kind(self, layer: int) -> str:
         if not self.layer_pattern:
@@ -381,12 +410,13 @@ class TransformerConfig:
             raise ValueError(
                 f"ssm_impl must be one of {ssd.IMPLS}, got {self.ssm_impl!r}"
             )
-        if self.ssm_impl == "kernel" and not ssd.kernel_fits(h, p, g):
+        if self.ssm_impl == "kernel" and not self.ssm_heads_per_step:
             raise ValueError(
                 f"ssm_impl='kernel' lays heads side by side in {ssd.LANES}"
-                f"-lane tiles: ssm_head_dim {p} must divide {ssd.LANES} and "
-                f"a group's {h}/{g} heads be whole tiles wide; "
-                "ssm_impl='xla' takes any sizes"
+                f"-lane tiles: ssm_head_dim {p} must divide {ssd.LANES}, "
+                f"a group's {h}/{g} heads be whole tiles wide and one tile "
+                f"with its chunk of {self.ssm_chunk} fit VMEM "
+                "(ops/ssd.py heads_per_step); ssm_impl='xla' takes any sizes"
             )
         if self.decode:
             raise ValueError(
@@ -653,6 +683,7 @@ def _attention(cfg: TransformerConfig):
             attention_impl=cfg.attention_impl,
             flash_block_q=cfg.flash_block_q,
             flash_block_kv=cfg.flash_block_kv,
+            scale=cfg.attention_scale,
             name="attn",
         )
     return Attention(
@@ -669,6 +700,7 @@ def _attention(cfg: TransformerConfig):
         norm_eps=cfg.norm_eps,
         flash_block_q=cfg.flash_block_q,
         flash_block_kv=cfg.flash_block_kv,
+        scale=cfg.attention_scale,
         decode=cfg.decode,
         cache_len=cfg.max_seq_len,
         name="attn",
@@ -707,6 +739,12 @@ def _dense_mlp(cfg: TransformerConfig):
         param_dtype=cfg.param_dtype,
         name="mlp",
     )
+
+
+def _add_branch(cfg: TransformerConfig, x: jax.Array, y: jax.Array):
+    """``x + residual_scale * y``: a branch joins the residual stream."""
+    with jax.named_scope("residual"):
+        return x + (y if cfg.residual_scale == 1.0 else y * cfg.residual_scale)
 
 
 class Block(nn.Module):
@@ -755,8 +793,7 @@ class Block(nn.Module):
         # skips re-running the whole attention forward (the priciest part of
         # recompute) at b*s*d bf16 per layer of extra HBM.
         y = jax.ad_checkpoint.checkpoint_name(y, "attn_out")
-        with jax.named_scope("residual"):
-            x = x + y
+        x = _add_branch(cfg, x, y)
         y = x if post else norm("ln_mlp", x)
         if cfg.num_experts and not self.dense_mlp:
             y, layer_aux = _experts(cfg)(y)
@@ -769,8 +806,7 @@ class Block(nn.Module):
         # stream from saved branch outputs instead of re-running the wo
         # matmul (b*s*d bf16 per layer of extra HBM each).
         y = jax.ad_checkpoint.checkpoint_name(y, "mlp_out")
-        with jax.named_scope("residual"):
-            x = x + y
+        x = _add_branch(cfg, x, y)
         x = nn.with_logical_constraint(x, (lr.BATCH, lr.ACT_SEQ, lr.ACT_EMBED))
         return (x, aux), None
 
@@ -810,6 +846,7 @@ class BranchBlock(nn.Module):
                 dt_max=cfg.ssm_dt_max,
                 dt_floor=cfg.ssm_dt_floor,
                 norm_eps=cfg.norm_eps,
+                out_init_scale=cfg.ssm_out_init_scale,
                 impl=cfg.ssm_impl,
                 dtype=cfg.dtype,
                 param_dtype=cfg.param_dtype,
@@ -829,8 +866,7 @@ class BranchBlock(nn.Module):
         y = jax.ad_checkpoint.checkpoint_name(
             y, "attn_out" if self.kind in (SSM, ATTENTION) else "mlp_out"
         )
-        with jax.named_scope("residual"):
-            x = x + y
+        x = _add_branch(cfg, x, y)
         x = nn.with_logical_constraint(x, (lr.BATCH, lr.ACT_SEQ, lr.ACT_EMBED))
         return (x, aux), None
 
@@ -966,7 +1002,11 @@ class TransformerLM(nn.Module):
             ),
             name="embed",
         )
-        x = embed(tokens)
+        def embedded(ids):
+            rows = embed(ids)
+            return rows if cfg.embed_scale == 1.0 else rows * cfg.embed_scale
+
+        x = embedded(tokens)
         if cfg.position == "learned":
             pos_table = self.param(
                 "pos_embedding",
@@ -1029,7 +1069,7 @@ class TransformerLM(nn.Module):
         ):
             # the module's parameters are made at init whoever calls
             mtp_hidden, mtp_aux = MTPModule(cfg, name="mtp")(
-                x, embed(tokens if next_tokens is None else next_tokens),
+                x, embedded(tokens if next_tokens is None else next_tokens),
                 positions, segment_ids,
             )
             aux = aux + mtp_aux

@@ -6,7 +6,10 @@ gather lands the picked rows as ``[T, k, D]`` first and reduces them in a
 second pass; this kernel never holds such an array.  For a chunk of tokens
 it starts one DMA per picked row from HBM into a VMEM slot, and while the
 next chunk's rows are in flight it multiplies and sums the chunk that has
-arrived.
+arrived.  A chunk is whole native tiles of tokens, as many as fill a slot
+of about 1 MiB and never fewer than one tile (:func:`_chunk_tokens`: the
+slot's bytes follow from ``k``, ``D`` and the dtype); :func:`kernel_fits`
+says which ``(D, k, dtype)`` the kernel holds.
 
 A row of a 2-D bf16 array is sixteen strided 256-byte pieces under the
 (16, 128) tiling, and Mosaic refuses to slice one row out of it; the kernel
@@ -28,11 +31,16 @@ from dlrover_tpu.ops import backend
 
 LANES = 128
 # Tokens one grid step writes, and the bytes of picked rows one VMEM slot
-# holds (two slots: one is summed while the other fills).  The kernel is
-# bound by the rate at which DMAs can be issued (19 ns a row on a v5e), not
-# by their latency: slots of 0.25 to 4 MiB run alike.
+# aims to hold (two slots: one is summed while the other fills).  The
+# kernel is bound by the rate at which DMAs can be issued (19 ns a row on a
+# v5e), not by their latency: slots of 0.25 to 4 MiB run alike.  A slot
+# holds whole tiles of tokens, so where ONE tile of tokens' k rows is more
+# than the aim (10 rows of 4096 bfloat16 a token: 1.25 MiB) the slot is
+# that tile, as long as the two slots, the summed chunk and the grid step's
+# double-buffered output block stay within the VMEM plan.
 _BLOCK_TOKENS = 512
 _SLOT_BYTES = 1 << 20
+_VMEM_BUDGET = 12 << 20
 # DMAs started per turn of the issue loop (its body is unrolled this far).
 _ISSUE_UNROLL = 16
 
@@ -48,14 +56,19 @@ def row_tiled(rows):
 
 
 def _chunk_tokens(d: int, k: int, dtype) -> int:
-    """Tokens whose rows fill a slot: whole tiles of output rows."""
-    tokens = _SLOT_BYTES // (k * d * jnp.dtype(dtype).itemsize)
-    return min(_BLOCK_TOKENS, tokens // tile_rows(dtype) * tile_rows(dtype))
+    """Tokens whose rows fill a slot: whole tiles of output rows, at least
+    one; 0 where even one tile of tokens overflows the VMEM plan."""
+    size, tile = jnp.dtype(dtype).itemsize, tile_rows(dtype)
+    tokens = _SLOT_BYTES // (k * d * size)
+    chunk = min(_BLOCK_TOKENS, max(tile, tokens // tile * tile))
+    planned = (2 * chunk * k + chunk + 2 * _BLOCK_TOKENS) * d * size
+    return chunk if planned <= _VMEM_BUDGET else 0
 
 
 def kernel_fits(d: int, k: int, dtype) -> bool:
     """Whether a row of ``d`` elements is whole native tiles in the view the
-    kernel DMAs from, and a tile of tokens' ``k`` rows each fit a slot."""
+    kernel DMAs from, and a tile of tokens' ``k`` rows each fit the slots
+    the VMEM plan leaves room for."""
     return d % (LANES * tile_rows(dtype)) == 0 and _chunk_tokens(d, k, dtype) > 0
 
 

@@ -19,17 +19,24 @@ chunk and ``S`` the state the chunk starts from::
     Y  = ((C B^T) * L) (dt * X) + exp(b) * (C S^T) + D X
     S' = exp(b_Q) S + (dt * exp(b_Q - b) * X)^T B
 
-``C B^T`` is a group's, computed once for its heads.
+``C B^T`` is a group's, computed once for the heads of a grid step.
 
 **What lives where** (``impl="kernel"``).  One kernel runs the forward and
-one the backward.  Their grids are (batch, group of heads, chunk); the
-chunk axis is sequential and the float32 state of the group's heads (the
+one the backward.  Their grids are (batch, tile of heads, chunk): a TILE is
+:func:`heads_per_step` heads of one group, a whole group where it is small
+enough (Nemotron-H's 8 heads) and a part of it where it is not
+(Granite-4.0-H's one group of 128 heads runs as 16 tiles of 8), and tile
+``t`` reads the ``B`` and ``C`` of group ``t // tiles_per_group``.  The
+chunk axis is sequential and the float32 state of the tile's heads (the
 backward: its cotangent) stays in VMEM scratch as ``S^T`` ``[N, heads x
 P]`` from one chunk to the next.  ``x`` and ``y`` are read and written IN
 PLACE as ``[B, S, H P]`` (a grid step takes the ``[Q, heads x P]`` columns
-of its group), ``B`` and ``C`` as ``[B, S, G N]``: no transpose is made for
+of its tile), ``B`` and ``C`` as ``[B, S, G N]``: no transpose is made for
 the kernels but of the two per-token scalars ``dt`` and ``a`` (``[B, S,
-H]`` float32, 1/64 of ``x``).  Heads narrower than the 128 lanes lie side
+H]`` float32, 1/64 of ``x``).  Where a group is several tiles, ``C B^T`` is
+formed once a tile, and the backward writes each tile's part of ``dB`` and
+``dC`` in float32 (``[B, S, tiles x N]``), summed over a group's tiles
+outside the kernel.  Heads narrower than the 128 lanes lie side
 by side in a lane tile: the products that do not depend on the head's
 decay (``C S^T``, ``B^T X``, ``B dS'``) run once a tile, the two that do
 (``M_h X``, ``M_h^T dY``) once a head on the whole tile, the head's lanes
@@ -274,23 +281,51 @@ def _bwd_kernel(
     db_ref[0] = (d_b + _dot(d_scores_cd, cm, _TN)).astype(db_ref.dtype)
 
 
-def _specs(groups, heads, width, state, chunk, n, reverse):
-    """Block specs of one grid step (batch, group, chunk): the group's
-    columns of x-like, B-like, per-token and D-like operands."""
+def _grid(x, dt, b, groups, head_dim):
+    """The grid ``(batch, tiles, n)`` of these operands and a grid step's
+    sizes."""
+    batch, _, total = x.shape
+    n, chunk = dt.shape[1], dt.shape[-1]
+    state = b.shape[-1] // groups
+    heads = heads_per_step(
+        total // head_dim, head_dim, groups, state, chunk, x.dtype
+    )
+    width = heads * head_dim
+    tiles = total // width
+    return types.SimpleNamespace(
+        grid=(batch, tiles, n), tiles=tiles, per_group=tiles // groups,
+        heads=heads, width=width, state=state, chunk=chunk, n=n,
+    )
+
+
+def _specs(g, reverse):
+    """Block specs of one grid step (batch, tile of heads, chunk) of the
+    grid ``g`` (:func:`_grid`): the tile's columns of x-like and D-like
+    operands and its per-token rows, its group's columns of B and C
+    (``bc``), and a tile's own columns of a B-like output (``bc_part``: the
+    backward's partial dB, dC where a group is several tiles)."""
     def at(c):
-        return n - 1 - c if reverse else c
+        return g.n - 1 - c if reverse else c
+
+    def group(t):
+        return t if g.per_group == 1 else t // g.per_group
 
     return types.SimpleNamespace(
-        x=pl.BlockSpec((1, chunk, width), lambda i, g, c: (i, at(c), g)),
-        bc=pl.BlockSpec((1, chunk, state), lambda i, g, c: (i, at(c), g)),
-        token=pl.BlockSpec(
-            (heads, 1, 1, chunk),
-            lambda i, g, c: (i * groups + g, at(c), 0, 0),
+        x=pl.BlockSpec((1, g.chunk, g.width), lambda i, t, c: (i, at(c), t)),
+        bc=pl.BlockSpec(
+            (1, g.chunk, g.state), lambda i, t, c: (i, at(c), group(t))
         ),
-        d=pl.BlockSpec((1, width), lambda i, g, c: (0, g)),
+        bc_part=pl.BlockSpec(
+            (1, g.chunk, g.state), lambda i, t, c: (i, at(c), t)
+        ),
+        token=pl.BlockSpec(
+            (g.heads, 1, 1, g.chunk),
+            lambda i, t, c: (i * g.tiles + t, at(c), 0, 0),
+        ),
+        d=pl.BlockSpec((1, g.width), lambda i, t, c: (0, t)),
         start=pl.BlockSpec(
-            (1, 1, state, width),
-            lambda i, g, c: (i * groups + g, at(c), 0, 0),
+            (1, 1, g.state, g.width),
+            lambda i, t, c: (i * g.tiles + t, at(c), 0, 0),
         ),
     )
 
@@ -299,34 +334,32 @@ def _specs(groups, heads, width, state, chunk, n, reverse):
 def _forward(x, dt, a, b, c, d, *, groups, head_dim):
     """``x`` [B, S, H P]; ``dt``, ``a`` [B H, n, 1, Q] float32; ``b``, ``c``
     [B, S, G N]; ``d`` [1, H P] float32 (a head's ``D`` on its lanes).
-    Returns ``y`` [B, S, H P], the chunks' start states ``S^T`` [B G, n, N,
-    heads P] (both in ``x``'s dtype) and each (batch, group)'s largest
-    ``|S|`` at a chunk's end [B G] (float32).  (Jitted, as the backward is,
+    Returns ``y`` [B, S, H P], the chunks' start states ``S^T`` [B tiles, n,
+    N, heads P] (both in ``x``'s dtype) and each (batch, tile)'s largest
+    ``|S|`` at a chunk's end [B tiles] (float32).  (Jitted, as the backward is,
     so that a step which runs the scan in several slots, forward, recomputed
     and transposed, traces and lowers each kernel body once.)"""
-    batch, _, total = x.shape
-    n, chunk = dt.shape[1], dt.shape[-1]
-    width, state = total // groups, b.shape[-1] // groups
-    heads = width // head_dim
-    sp = _specs(groups, heads, width, state, chunk, n, False)
+    g = _grid(x, dt, b, groups, head_dim)
+    batch, tiles = g.grid[:2]
+    sp = _specs(g, False)
     y, starts, top = pl.pallas_call(
-        functools.partial(_fwd_kernel, heads=heads, head_dim=head_dim),
-        grid=(batch, groups, n),
+        functools.partial(_fwd_kernel, heads=g.heads, head_dim=head_dim),
+        grid=g.grid,
         in_specs=[sp.x, sp.token, sp.token, sp.bc, sp.bc, sp.d],
         out_specs=[
             sp.x, sp.start,
             pl.BlockSpec(
-                (1, 1, LANES), lambda i, g, c: (i * groups + g, 0, 0)
+                (1, 1, LANES), lambda i, t, c: (i * tiles + t, 0, 0)
             ),
         ],
         out_shape=[
             jax.ShapeDtypeStruct(x.shape, x.dtype),
             jax.ShapeDtypeStruct(
-                (batch * groups, n, state, width), x.dtype
+                (batch * tiles, g.n, g.state, g.width), x.dtype
             ),
-            jax.ShapeDtypeStruct((batch * groups, 1, LANES), F32),
+            jax.ShapeDtypeStruct((batch * tiles, 1, LANES), F32),
         ],
-        scratch_shapes=[pltpu.VMEM((state, width), F32)],
+        scratch_shapes=[pltpu.VMEM((g.state, g.width), F32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
@@ -338,32 +371,48 @@ def _forward(x, dt, a, b, c, d, *, groups, head_dim):
 
 @functools.partial(jax.jit, static_argnames=("groups", "head_dim"))
 def _backward(x, dt, a, b, c, d, starts, dy, *, groups, head_dim):
-    batch, _, total = x.shape
-    n, chunk = dt.shape[1], dt.shape[-1]
-    width, state = total // groups, b.shape[-1] // groups
-    heads = width // head_dim
-    sp = _specs(groups, heads, width, state, chunk, n, True)
-    return pl.pallas_call(
-        functools.partial(_bwd_kernel, heads=heads, head_dim=head_dim),
-        grid=(batch, groups, n),
+    g = _grid(x, dt, b, groups, head_dim)
+    batch, tiles = g.grid[:2]
+    sp = _specs(g, True)
+    # a group of several tiles: each writes its own part of dB and dC
+    split = g.per_group > 1
+    part = jax.ShapeDtypeStruct(
+        (batch, x.shape[1], tiles * g.state), F32
+    )
+    dx, ddt, da, db, dc = pl.pallas_call(
+        functools.partial(_bwd_kernel, heads=g.heads, head_dim=head_dim),
+        grid=g.grid,
         in_specs=[
             sp.x, sp.token, sp.token, sp.bc, sp.bc, sp.d, sp.start, sp.x,
         ],
-        out_specs=[sp.x, sp.token, sp.token, sp.bc, sp.bc],
+        out_specs=[sp.x, sp.token, sp.token] + (
+            [sp.bc_part] * 2 if split else [sp.bc] * 2
+        ),
         out_shape=[
             jax.ShapeDtypeStruct(x.shape, x.dtype),
             jax.ShapeDtypeStruct(dt.shape, F32),
             jax.ShapeDtypeStruct(a.shape, F32),
-            jax.ShapeDtypeStruct(b.shape, b.dtype),
-            jax.ShapeDtypeStruct(c.shape, c.dtype),
-        ],
-        scratch_shapes=[pltpu.VMEM((state, width), F32)],
+        ] + (
+            [part, part] if split else [
+                jax.ShapeDtypeStruct(b.shape, b.dtype),
+                jax.ShapeDtypeStruct(c.shape, c.dtype),
+            ]
+        ),
+        scratch_shapes=[pltpu.VMEM((g.state, g.width), F32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=backend.interpret(),
         name="ssd_bwd",
     )(x, dt, a, b, c, d, starts, dy)
+    if split:
+        def over_tiles(parts, like):
+            return parts.reshape(
+                *parts.shape[:2], groups, g.per_group, g.state
+            ).sum(axis=3).reshape(like.shape).astype(like.dtype)
+
+        db, dc = over_tiles(db, b), over_tiles(dc, c)
+    return dx, ddt, da, db, dc
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
@@ -393,13 +442,67 @@ def _scan_bwd(groups, head_dim, res, cts):
 _scan.defvjp(_scan_fwd, _scan_bwd)
 
 
-def kernel_fits(heads: int, head_dim: int, groups: int) -> bool:
-    """Whether the kernels' lane layout holds these sizes: heads that
-    divide a 128-lane tile, a group's heads whole tiles wide."""
-    return (
-        heads % groups == 0 and LANES % head_dim == 0
-        and (heads // groups * head_dim) % LANES == 0
+# What a grid step may plan to hold in VMEM (a v5e's scoped default is
+# 16 MiB, the blocks double-buffered), and the heads one kernel body
+# unrolls: every head is a ``[Q, Q]`` decay and two products written out
+# in the body, and bodies of more than 8 heads have compiled for minutes.
+_VMEM_BUDGET = 12 << 20
+_MAX_HEADS = 8
+
+
+def step_vmem_bytes(
+    heads: int, head_dim: int, state: int, chunk: int, dtype
+) -> int:
+    """VMEM a grid step of ``heads`` heads plans for, by the backward
+    kernel (the larger): the double-buffered blocks (x, dy, dx tiles, the
+    chunk's start state, B, C, dB, dC, and the per-token rows of dt, a,
+    ddt, da, each padded to 8 sublanes), the float32 state scratch, and
+    the body's float32 terms: ``C B^T`` and its cotangent, four ``[Q, Q]``
+    a head of the lane tile in hand, a dozen ``[Q, 128]`` tiles."""
+    size = jnp.dtype(dtype).itemsize
+    width = heads * head_dim
+    blocks = 2 * (
+        3 * chunk * width * size + state * width * size
+        + 2 * chunk * state * size + 2 * chunk * state * 4
+        + 4 * heads * 8 * chunk * 4
     )
+    terms = (
+        (2 + 4 * (LANES // head_dim)) * chunk * chunk + 12 * chunk * LANES
+    ) * 4
+    return blocks + state * width * 4 + terms
+
+
+def heads_per_step(
+    heads: int, head_dim: int, groups: int, state: int = 128,
+    chunk: int = 128, dtype=jnp.bfloat16,
+) -> int:
+    """Heads one grid step of the kernels holds (the grid's second axis is
+    ``heads // heads_per_step`` tiles): the most heads of ONE group, in
+    whole 128-lane tiles, that :func:`step_vmem_bytes` puts within the
+    VMEM budget and a body may unroll; 0 where the lane layout does not
+    hold the sizes (``head_dim`` must divide 128, a group be whole lane
+    tiles wide) or not even one lane tile fits."""
+    if heads % groups or LANES % head_dim:
+        return 0
+    per_group, per_tile = heads // groups, LANES // head_dim
+    if per_group % per_tile:
+        return 0
+    most = max(per_tile, min(per_group, _MAX_HEADS))
+    fitting = [
+        n for n in range(per_tile, most + 1, per_tile)
+        if per_group % n == 0
+        and step_vmem_bytes(n, head_dim, state, chunk, dtype) <= _VMEM_BUDGET
+    ]
+    return max(fitting, default=0)
+
+
+def kernel_fits(
+    heads: int, head_dim: int, groups: int, state: int = 128,
+    chunk: int = 128, dtype=jnp.bfloat16,
+) -> bool:
+    """Whether the kernels hold these sizes: :func:`heads_per_step` finds
+    a tile of heads."""
+    return heads_per_step(heads, head_dim, groups, state, chunk, dtype) > 0
 
 
 def _ssd_kernel(x, dt, a, b, c, d, chunk):
@@ -510,12 +613,15 @@ def ssd(
             f"x, b, c must share a dtype, got {cd}, {b.dtype}, {c.dtype}"
         )
     heads, head_dim, groups = x.shape[2], x.shape[3], b.shape[2]
-    if impl == "kernel" and not kernel_fits(heads, head_dim, groups):
+    if impl == "kernel" and not kernel_fits(
+        heads, head_dim, groups, b.shape[3], chunk, cd
+    ):
         raise ValueError(
             f"the kernels lay heads side by side in {LANES}-lane tiles: "
-            f"head_dim {head_dim} must divide {LANES} and a group's "
-            f"{heads}/{groups} heads be whole tiles wide; impl='xla' takes "
-            "any sizes"
+            f"head_dim {head_dim} must divide {LANES}, a group's "
+            f"{heads}/{groups} heads be whole tiles wide and one tile's "
+            f"chunk of {chunk} with its state of {b.shape[3]} fit VMEM "
+            "(heads_per_step); impl='xla' takes any sizes"
         )
     s = x.shape[1]
     pad = -s % chunk

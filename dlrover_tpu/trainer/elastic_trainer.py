@@ -356,7 +356,9 @@ class ElasticTrainer:
                 "kernel_calls": self.train.kernel_calls,
                 **self._flash_facts(),
                 "ssm_scan": self._ssm_scan(),
+                "ssm_heads_per_step": self._ssm_heads_per_step(),
                 "short_conv": self._short_conv(),
+                "row_moves": self._row_moves(),
             }
             logger.info("compile warmup: %s", detail)
             telemetry.event(
@@ -460,6 +462,29 @@ class ElasticTrainer:
         for a model without such a layer."""
         cfg = self.model_config
         return cfg.ssm_impl if cfg.num_ssm_layers else "none"
+
+    def _ssm_heads_per_step(self) -> Optional[int]:
+        """How the scan kernels' grid is cut, beside ``ssm_scan``: the
+        heads one grid step holds (``ops/ssd.py`` ``heads_per_step``, which
+        the kernels ask; a group of more heads runs as several tiles),
+        ``None`` where no kernel runs the scan."""
+        if self._ssm_scan() != "kernel":
+            return None
+        return self.model_config.ssm_heads_per_step
+
+    def _row_moves(self) -> str:
+        """Which path a token's ``top_k`` rows take through the dropless
+        dispatch's combine and the scatter's transpose, for the ``compile``
+        event: ``kernel`` (``ops/row_gather_sum.py``: fetched and summed in
+        one pass) / ``xla`` (a gather, then a reduction), ``none`` for a
+        model without grouped experts.  Asks the function the layer asks."""
+        cfg = self.model_config
+        if not cfg.num_experts or cfg.moe_dispatch != "grouped":
+            return "none"
+        from dlrover_tpu.ops import row_gather_sum
+
+        fits = row_gather_sum.kernel_fits(cfg.d_model, cfg.top_k, cfg.dtype)
+        return "kernel" if fits else "xla"
 
     def _short_conv(self) -> str:
         """How the step program's short convolutions run, for the
@@ -1420,7 +1445,9 @@ class ElasticTrainer:
             return None
         telemetry.event(
             "ssm", step=step, layers=self.model_config.num_ssm_layers,
-            chunk=self.model_config.ssm_chunk, **read,
+            chunk=self.model_config.ssm_chunk,
+            heads=self.model_config.ssm_num_heads,
+            groups=self.model_config.ssm_groups, **read,
         )
         return read["state_absmax"]
 

@@ -119,11 +119,13 @@ def _delta_rule():
     return jax.grad(loss, argnums=(0, 1, 2, 3, 4))
 
 
-def _ssd():
+def _ssd(chunk=128):
     from dlrover_tpu.ops.ssd import ssd
 
     def loss(x, dt, a, b, c, d):
-        return ssd(x, dt, a, b, c, d, impl="kernel")[0].astype(F32).sum()
+        return ssd(
+            x, dt, a, b, c, d, chunk=chunk, impl="kernel"
+        )[0].astype(F32).sum()
 
     return jax.grad(loss, argnums=(0, 1, 2, 3, 4, 5))
 
@@ -258,6 +260,29 @@ CASES = [
     ("ssd_nemotron_h", _ssd,
      [((2, 8192, 64, 64), BF16), ((2, 8192, 64), F32), ((64,), F32)]
      + [((2, 8192, 8, 128), BF16)] * 2 + [((64,), F32)], {}, 2),
+    # Granite-4.0-H-Small's: 128 heads of 64 in ONE group, sixteen tiles of
+    # 8 heads a (batch, chunk), at both chunks the configuration names
+    ("ssd_granite_one_group", _ssd,
+     [((2, 8192, 128, 64), BF16), ((2, 8192, 128), F32), ((128,), F32)]
+     + [((2, 8192, 1, 128), BF16)] * 2 + [((128,), F32)], {}, 2),
+    ("ssd_granite_one_group_chunk_256", lambda: _ssd(256),
+     [((2, 8192, 128, 64), BF16), ((2, 8192, 128), F32), ((128,), F32)]
+     + [((2, 8192, 1, 128), BF16)] * 2 + [((128,), F32)], {}, 2),
+    # its share of the experts: ten rows of 4,096 a token fetched and
+    # summed out of the 26,880 rows set aside for 9 of 72 experts, and the
+    # grouped GEMMs over them
+    ("row_gather_sum_granite_weighted", _row_gather_sum,
+     [((26880, 32, 128), BF16), ((16384, 10), I32), ((16384, 10), F32)],
+     {}, 1),
+    ("row_gather_sum_granite_plain", _row_gather_sum,
+     [((26880, 32, 128), BF16), ((16384, 10), I32)], {}, 1),
+    ("grouped_matmul_granite_wi_rows_tiled",
+     lambda: _grouped_matmul(False, True),
+     [((26880, 32, 128), BF16), ((9, 4096, 768), BF16), ((9,), I32)],
+     {}, 3),
+    ("grouped_matmul_granite_wo_out_tiled",
+     lambda: _grouped_matmul(True, True),
+     [((26880, 768), BF16), ((9, 768, 4096), BF16), ((9,), I32)], {}, 3),
     # the short convolutions at the cells' 2 x 8192 tokens, the forward
     # kernel and the backward kernel: Nemotron's 6,144 channels from column
     # 4,096 of in_proj's 10,304 with a bias, x | B | C written apart; the
@@ -265,6 +290,10 @@ CASES = [
     ("short_conv_nemotron_h",
      lambda: _short_conv(4096, (4096, 1024, 1024), None, True),
      [((2, 8192, 10304), BF16), ((4, 6144), BF16), ((6144,), BF16)], {}, 2),
+    # Granite's 8,448 channels from column 8,192 of in_proj's 16,768
+    ("short_conv_granite",
+     lambda: _short_conv(8192, (8192, 128, 128), None, True),
+     [((2, 8192, 16768), BF16), ((4, 8448), BF16), ((8448,), BF16)], {}, 2),
     ("short_conv_olmo_hybrid",
      lambda: _short_conv(0, (96, 96, 192), (96 ** -0.5, 1.0, None), False),
      [((2, 8192, 30, 576), BF16), ((4, 30, 384), BF16)], {}, 2),

@@ -185,6 +185,8 @@ def test_the_kernel_sums_bfloat16_rows_in_float32():
 @pytest.mark.parametrize("d,k,dtype,fits", [
     (2048, 8, jnp.bfloat16, True), (1024, 8, F32, True),
     (4096, 2, jnp.bfloat16, True),
+    # Granite-4.0-H-Small: one tile of tokens' ten rows is 1.25 MiB, the slot
+    (4096, 10, jnp.bfloat16, True),
     (1024, 8, jnp.bfloat16, False),    # half a bfloat16 tile a row
     (1600, 2, jnp.bfloat16, False), (64, 2, F32, False), (48, 2, F32, False),
     (2048, 64, jnp.bfloat16, False),   # a tile of tokens overflows a slot
@@ -198,6 +200,42 @@ def test_the_kernel_takes_rows_of_whole_tiles_only(d, k, dtype, fits):
             row_gather_sum.gather_sum(
                 jnp.zeros((8, d), dtype), jnp.zeros((4, k), jnp.int32)
             )
+
+
+@pytest.mark.parametrize("d,k,dtype,chunk", [
+    # the shapes that fitted a 1 MiB slot keep the chunk they had
+    (2048, 8, jnp.bfloat16, 32),       # OLMoE, JoyAI-LLM-Flash
+    (4096, 2, jnp.bfloat16, 64),       # Mixtral
+    (1024, 8, F32, 32),
+    (2048, 1, jnp.bfloat16, 256),
+    # one native tile of tokens where ten rows of 4,096 overflow it
+    (4096, 10, jnp.bfloat16, 16),
+    (4096, 8, jnp.bfloat16, 16),
+    (2048, 64, jnp.bfloat16, 0),       # past the VMEM plan
+])
+def test_the_slot_follows_from_k_d_and_the_dtype(d, k, dtype, chunk):
+    from dlrover_tpu.ops import row_gather_sum
+
+    assert row_gather_sum._chunk_tokens(d, k, dtype) == chunk
+
+
+def test_ten_rows_of_4096_a_token_are_fetched_and_summed():
+    """Granite's combine at its own row width and k, a few tokens past one
+    chunk of 16: the kernel under the TPU interpreter is the definition."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from dlrover_tpu.ops import row_gather_sum
+
+    case = _case(37, 10, 4096, experts=12, idle_expert=0)
+    rows = case["rows"].astype(jnp.bfloat16)
+    dest = case["plan"]["dest"]
+    want = np.asarray(_definition(rows, dest, case["gates"]), np.float32)
+    got = row_gather_sum.gather_sum(
+        rows, dest, case["gates"], interpret=pltpu.InterpretParams()
+    )
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), want, rtol=2 ** -7, atol=1e-6
+    )
 
 
 # -- row-tiled rows through the GEMMs and the layer ---------------------------
@@ -333,5 +371,6 @@ def test_the_manifest_lists_the_reading_for_the_dropless_cells_only():
         "workloads": [
             "olmoe-1b-7b.train_steady", "joyai-llm-flash.train_steady",
             "nemotron-3-nano-30b-a3b.train_steady",
+            "granite-4.0-h-small.train_steady",
         ],
     }]

@@ -151,3 +151,83 @@ def test_the_kernel_refuses_sizes_its_lanes_do_not_hold():
     mixed = dict(args, b=args["b"].astype(jnp.bfloat16))
     with pytest.raises(ValueError, match="share a dtype"):
         ssd_lib.ssd(**mixed, chunk=16, impl="xla")
+
+
+# One group of many heads, cut into tiles of heads_per_step heads
+# (Granite-4.0-H's layout: 128 heads in ONE group): (heads, head_dim,
+# groups) -> tiles a group
+TILED = [
+    (32, 64, 1, 4),        # one group of 32: four tiles of 8 heads
+    (32, 64, 2, 2),        # two groups of 16: two tiles each
+    (16, 128, 1, 2),       # heads a lane tile wide
+]
+
+
+@pytest.mark.parametrize("heads,head_dim,groups,tiles", TILED)
+def test_a_group_of_many_heads_runs_as_tiles(heads, head_dim, groups, tiles):
+    """Forward and all six gradients of a group wider than a grid step,
+    against the recurrence one token at a time: every tile reads its
+    group's B and C, and dB, dC are the sum over the group's tiles."""
+    per_step = ssd_lib.heads_per_step(heads, head_dim, groups, 16, 16, F32)
+    assert heads // groups // per_step == tiles
+    args, weight = inputs(
+        batch=2, s=40, heads=heads, head_dim=head_dim, groups=groups
+    )
+    names = sorted(args)
+
+    def through(fn):
+        def loss(*values):
+            return (fn(**dict(zip(names, values))) * weight).sum()
+
+        return jax.grad(loss, argnums=tuple(range(len(names))))(
+            *(args[n] for n in names)
+        )
+
+    y, _ = ssd_lib.ssd(**args, chunk=16, impl="kernel")
+    np.testing.assert_allclose(y, recurrence(**args), atol=TOL, rtol=TOL)
+    got = through(lambda **kw: ssd_lib.ssd(**kw, chunk=16, impl="kernel")[0])
+    want = through(recurrence)
+    for name, g, w in zip(names, got, want):
+        scale = float(jnp.abs(w).max())
+        np.testing.assert_allclose(
+            g, w, atol=TOL * scale, rtol=TOL, err_msg=name
+        )
+
+
+@pytest.mark.parametrize("shape,want", [
+    # Nemotron-H: a group of 8 heads is one grid step, as before the tiles
+    (dict(heads=64, head_dim=64, groups=8, state=128, chunk=128), 8),
+    # Granite-4.0-H: ONE group of 128 heads, sixteen tiles of 8
+    (dict(heads=128, head_dim=64, groups=1, state=128, chunk=128), 8),
+    (dict(heads=128, head_dim=64, groups=1, state=128, chunk=256), 8),
+    (dict(heads=4, head_dim=64, groups=2, state=16, chunk=16), 2),
+    (dict(heads=8, head_dim=128, groups=1, state=128, chunk=128), 8),
+    # not even one lane tile of a 1024-token chunk fits
+    (dict(heads=128, head_dim=64, groups=1, state=128, chunk=1024), 0),
+    (dict(heads=4, head_dim=48, groups=2, state=16, chunk=16), 0),
+])
+def test_the_tile_of_heads_follows_from_the_shapes(shape, want):
+    assert ssd_lib.heads_per_step(**shape) == want
+    assert ssd_lib.kernel_fits(**shape) is (want > 0)
+    if want:
+        assert ssd_lib.step_vmem_bytes(
+            want, shape["head_dim"], shape["state"], shape["chunk"],
+            jnp.bfloat16,
+        ) <= ssd_lib._VMEM_BUDGET
+
+
+def test_nemotrons_kernels_are_lowered_as_before_the_tiles():
+    """A group that is one grid step takes the index maps it had: B and C
+    by the grid's own second index, dB and dC written whole in the
+    operands' dtype (no partial sums)."""
+    args, weight = inputs(batch=1, s=32, heads=4, head_dim=64, groups=2)
+
+    def loss(x, b):
+        y, _ = ssd_lib.ssd(**dict(args, x=x, b=b), chunk=16, impl="kernel")
+        return (y * weight[:1, :32]).sum()
+
+    text = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(
+        args["x"], args["b"]
+    ))
+    assert "ssd_fwd" in text and "ssd_bwd" in text
+    assert " div " not in text.split("ssd_bwd")[1].split("reduce_sum")[0]

@@ -36,9 +36,12 @@ pick slower layouts around the rule (PERF.md §6, PR 32).
 What ``flash_only`` keeps of a state-space (Mamba-2) layer is likewise the
 scan's output alone (``ssd_out``, ``[B, S, H P]``): the scan's forward
 kernel runs a second time in the backward for the chunk-start states
-(``[B G, S / 128, N, 512]``, 134 MB a layer at 2 x 8192 tokens).  Keeping
-those too was refused by the chip's compiler at the benchmark's depth
-("Used 16.06G of 15.75G hbm"; PERF.md §6, PR 37).
+(``[B tiles, S / 128, N, 512]``, 134 MB a layer at 2 x 8192 tokens).
+Keeping those too was refused by the chip's compiler at the benchmark's
+depth ("Used 16.06G of 15.75G hbm"; PERF.md §6, PR 37).  The backward
+kernel adds up ``dD`` and a group's ``dB``, ``dC`` itself: nothing of the
+scan's cotangents is reduced by XLA but ``dD`` over the batch and a head's
+lanes.
 
 Neither layer's short convolution is kept: it runs a second time in the
 backward, and the backward rebuilds its pre-activation a third time from

@@ -22,29 +22,48 @@ chunk and ``S`` the state the chunk starts from::
 ``C B^T`` is a group's, computed once for the heads of a grid step.
 
 **What lives where** (``impl="kernel"``).  One kernel runs the forward and
-one the backward.  Their grids are (batch, tile of heads, chunk): a TILE is
-:func:`heads_per_step` heads of one group, a whole group where it is small
-enough (Nemotron-H's 8 heads) and a part of it where it is not
-(Granite-4.0-H's one group of 128 heads runs as 16 tiles of 8), and tile
-``t`` reads the ``B`` and ``C`` of group ``t // tiles_per_group``.  The
-chunk axis is sequential and the float32 state of the tile's heads (the
-backward: its cotangent) stays in VMEM scratch as ``S^T`` ``[N, heads x
-P]`` from one chunk to the next.  ``x`` and ``y`` are read and written IN
-PLACE as ``[B, S, H P]`` (a grid step takes the ``[Q, heads x P]`` columns
-of its tile), ``B`` and ``C`` as ``[B, S, G N]``: no transpose is made for
-the kernels but of the two per-token scalars ``dt`` and ``a`` (``[B, S,
-H]`` float32, 1/64 of ``x``).  Where a group is several tiles, ``C B^T`` is
-formed once a tile, and the backward writes each tile's part of ``dB`` and
-``dC`` in float32 (``[B, S, tiles x N]``), summed over a group's tiles
-outside the kernel.  Heads narrower than the 128 lanes lie side
-by side in a lane tile: the products that do not depend on the head's
-decay (``C S^T``, ``B^T X``, ``B dS'``) run once a tile, the two that do
-(``M_h X``, ``M_h^T dY``) once a head on the whole tile, the head's lanes
-selected after.  HBM sees x, B, C, dt, a, D in, y, each chunk's START state
-in the operands' dtype and the largest ``|S|`` out; backward: the same
-inputs, the start states and ``dy`` in, dx, dB, dC, ddt, da out.  No
-``[Q, Q]`` tensor reaches HBM.  ``dD`` is one reduction of ``dy x`` outside
-the kernel.
+one the backward.  Their grids are (batch, group, chunk, tile of the
+group's heads): a TILE is :func:`heads_per_step` heads of one group, a
+whole group where it is small enough (Nemotron-H's 8 heads: the last axis
+is 1) and a part of it where it is not (Granite-4.0-H's one group of 128
+heads runs as 16 tiles of 8).  The chunk axis is sequential, the tile axis
+inside it, and the float32 state of ALL the group's tiles (the backward:
+its cotangent) stays in VMEM scratch as ``S^T`` ``[tiles, N, heads x P]``
+from one chunk to the next.  ``x`` and ``y`` are read and written IN PLACE
+as ``[B, S, H P]`` (a grid step takes the ``[Q, heads x P]`` columns of
+its tile), ``B`` and ``C`` TRANSPOSED, as ``[B, G N, S]`` (a block is
+``B^T`` ``[N, Q]``: what a convolution kernel that keeps the tokens on the
+lanes hands over, so its transpose and this one cancel and XLA copies
+neither; ``dB`` and ``dC`` go back the same way).  A group's block is the
+same for all its tiles, so it is fetched once a (batch, chunk); ``C B^T``
+and the token-major ``B``, ``C`` are formed at the group's first tile into
+scratch (:func:`_at_first`), and the backward adds the tiles' parts of
+``dB^T`` and ``dC^T`` in float32 scratch and writes them once, at the last
+tile, in the operands' dtype.  No other transpose is made for the kernels
+but of the two per-token scalars: ``dt`` and the running sums ``b`` of
+``a`` inside each chunk come as rows ``[B, n, H, Q]`` float32 (1/64 of
+``x``; ``b`` from one product with the triangle outside the kernels, whose
+transpose turns the backward's ``db`` into ``da``).
+
+What depends on a head's decay is built ONCE A GRID STEP from those rows:
+``exp(b)``, ``exp(b_Q - b)`` on ``[heads, Q]``; a head's column of ``b``
+for its ``[Q, Q]`` decay is its row laid on every sublane and transposed,
+and a lane tile's ``[Q, 128]`` factors likewise (no masked lane reduction
+and no ``[Q, 1]`` column anywhere).  Heads narrower than the 128 lanes lie
+side by side in a lane tile: the products that do not depend on the head's
+decay (``C S^T``, ``B dS'``) run once a tile, those that do (``M_h X``,
+``(B^T * to_end_h) X``, ``M_h^T dY``) once a head on the whole tile, the
+head's lanes selected after.  The backward builds ``M^T`` directly, so
+``M^T dY`` needs no transpose and the sum of ``dM M`` over a row of ``M``
+is a sum down the sublanes; the sum over a column is ``<dt X, M^T dY>``
+with the same rounded operands, a head's lanes of a transposed tile; and
+``d b_Q = <dS', S'>``.  ``dD``'s ``sum_t dy x`` is added up in the backward
+kernel, in a float32 block that stays in VMEM over a (batch, group)'s
+chunks; the batch and a head's P lanes are summed outside.  HBM sees x,
+dt, b, B, C, D in, y, each chunk's START state in the operands' dtype and
+the largest ``|S|`` a lane out; backward: the same inputs, the start
+states and ``dy`` in, dx, dB, dC, ddt, db, dD out.  No ``[Q, Q]`` tensor
+reaches HBM.
 
 **Precision.**  Products take operands in the inputs' dtype and accumulate
 in float32; ``dt``, ``a``, its running sums, every ``exp`` and the state
@@ -74,10 +93,9 @@ F32 = jnp.float32
 LANES = 128
 IMPLS = ("xla", "kernel")
 
-# dot_general's dimension numbers: x y, x y^T, x^T y
+# dot_general's dimension numbers: x y, x y^T
 _NN = (((1,), (0,)), ((), ()))
 _NT = (((1,), (1,)), ((), ()))
-_TN = (((0,), (0,)), ((), ()))
 
 
 def _dot(x, y, dims=_NN):
@@ -89,40 +107,91 @@ def _dot(x, y, dims=_NN):
     )
 
 
-def _to_col(x_row, eye):
-    """[1, Q] -> [Q, 1], through the diagonal of a [Q, Q]."""
-    return jnp.sum(jnp.where(eye, x_row, 0.0), axis=1, keepdims=True)
-
-
-def _to_row(x_col, eye):
-    return jnp.sum(jnp.where(eye, x_col, 0.0), axis=0, keepdims=True)
-
-
-def _masks(q):
+def _triangles(q):
+    """``upper`` [i, t] true where ``i <= t`` (it masks ``M^T``) and its
+    transpose ``lower`` (it masks ``M``)."""
     row = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
     col = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
-    return col <= row, col == row
+    return row <= col, col <= row
 
 
-def _head_terms(a_row, dt_row, lower, eye):
-    """What one head's chunk builds from its ``a`` and ``dt`` rows [1, Q]
-    (float32), forward and backward alike.  A vector indexed by the token
-    comes as a column [Q, 1] where it scales rows."""
-    q = a_row.shape[-1]
-    b_col = jnp.sum(jnp.where(lower, a_row, 0.0), axis=1, keepdims=True)
-    total = b_col[q - 1:, :]                              # b_Q  [1, 1]
-    # exp(b_t - b_i) where i <= t; the other half would overflow
-    decay = jnp.exp(
-        jnp.where(lower, b_col - _to_row(b_col, eye), -jnp.inf)
-    )
+def _when(condition):
+    """``pl.when`` that a condition known while tracing (a group of one
+    tile: every tile is its group's first and last) settles there."""
+    if isinstance(condition, bool):
+        return (lambda body: body()) if condition else (lambda body: None)
+    return pl.when(condition)
+
+
+def _at_first(first, ref, make):
+    """What ``make()`` gives, made once a group: at the group's first tile
+    into the scratch ``ref`` and read from there by every tile; a group of
+    one tile makes it in place."""
+    if first is True:
+        return make()
+
+    @pl.when(first)
+    def _():
+        ref[...] = make()
+
+    return ref[...]
+
+
+def _step_terms(sums_ref, dt_ref):
+    """What a grid step's heads build from the running sums ``b`` of ``a``
+    and from ``dt``, forward and backward alike, ONCE for the tile of heads
+    and as ROWS ``[heads, Q]`` (float32): one ``exp`` each on ``heads x Q``
+    numbers."""
+    b, dt = sums_ref[0, 0], dt_ref[0, 0]
+    to_end = jnp.exp(b[:, b.shape[1] - 1:] - b)           # exp(b_Q - b)
     return types.SimpleNamespace(
-        decay=decay, dt=_to_col(dt_row, eye), from_start=jnp.exp(b_col),
-        to_end=jnp.exp(total - b_col), whole=jnp.exp(total),
+        b=b, dt=dt, from_start=jnp.exp(b), to_end=to_end,
     )
+
+
+def _down(row, q):
+    """A head's row [1, Q] as the column it is, on every lane: [Q, Q]
+    whose entry [t, i] is ``row[t]`` (a transpose of the row laid on
+    every sublane: the unit that transposes is far cheaper than a lane
+    reduction or a lane broadcast a head)."""
+    return jnp.broadcast_to(row, (q, row.shape[1])).T
+
+
+def _spread(rows, first, count, head_dim):
+    """One lane tile [Q, 128] out of the rows [heads, Q] of its ``count``
+    heads: head ``first + j``'s value of token ``t`` at ``[t, j head_dim
+    : (j + 1) head_dim]``."""
+    q = rows.shape[1]
+    return jnp.concatenate([
+        jnp.broadcast_to(rows[first + j:first + j + 1], (head_dim, q))
+        for j in range(count)
+    ], axis=0).T
+
+
+def _head_rows(tile, count, head_dim):
+    """Each head's own sum over its lanes of ``tile`` [Q, 128], as ROWS
+    [1, Q]: the tile transposed, a head's lanes are sublanes and their sum
+    is plain adds."""
+    tall = tile.T                                         # [128, Q]
+    return [
+        jnp.sum(
+            tall[j * head_dim:(j + 1) * head_dim], axis=0, keepdims=True
+        )
+        for j in range(count)
+    ]
+
+
+def _stack(rows, like):
+    """``[heads, Q]`` out of each head's [1, Q] (or [1, 1])."""
+    sub = jax.lax.broadcasted_iota(jnp.int32, like.shape, 0)
+    out = jnp.zeros(like.shape, F32)
+    for j, r in enumerate(rows):
+        out = jnp.where(sub == j, r, out)
+    return out
 
 
 def _by_lane(values, lane_head):
-    """One lane tile out of its heads' columns (or scalars): lanes of head
+    """One lane tile out of its heads' tiles (or rows): lanes of head
     ``j`` take ``values[j]``."""
     out = values[0]
     for j in range(1, len(values)):
@@ -130,313 +199,388 @@ def _by_lane(values, lane_head):
     return out
 
 
-def _tile_terms(a_ref, dt_ref, first, count, lower, eye, lane_head):
-    heads = [
-        _head_terms(a_ref[first + j, 0], dt_ref[first + j, 0], lower, eye)
-        for j in range(count)
-    ]
-    return heads, types.SimpleNamespace(**{
-        name: _by_lane([getattr(h, name) for h in heads], lane_head)
-        for name in ("dt", "from_start", "to_end", "whole")
-    })
-
-
 def _fwd_kernel(
-    x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, y_ref, start_ref, top_ref,
-    state, *, heads, head_dim,
+    x_ref, dt_ref, sums_ref, b_ref, c_ref, d_ref, y_ref, top_ref, start_ref,
+    state, scores_ref, c_tok, *, heads, head_dim, per_group,
 ):
-    @pl.when(pl.program_id(2) == 0)
-    def _():
-        state[...] = jnp.zeros_like(state)
-        top_ref[...] = jnp.zeros_like(top_ref)
+    chunk, tile_of_group = pl.program_id(2), pl.program_id(3)
+    first = True if per_group == 1 else tile_of_group == 0
 
-    bm, cm = b_ref[0], c_ref[0]                           # [Q, N]
-    cd = bm.dtype
-    lower, eye = _masks(bm.shape[0])
-    scores = _dot(cm, bm, _NT)                            # C B^T, the group's
+    @pl.when(chunk == 0)
+    def _():
+        state[tile_of_group] = jnp.zeros(state.shape[1:], F32)
+
+        @_when(first)
+        def _():
+            top_ref[...] = jnp.zeros_like(top_ref)
+
+    bm_t = b_ref[0]                                       # B^T  [N, Q]
+    cd = bm_t.dtype
+    q = bm_t.shape[1]
+    _, lower = _triangles(q)
+
+    cm = _at_first(first, c_tok, lambda: c_ref[0].T)      # C  [Q, N]
+    # C B^T, the group's
+    scores = _at_first(first, scores_ref, lambda: _dot(cm, bm_t))
+    bm_t = bm_t.astype(F32)
+    terms = _step_terms(sums_ref, dt_ref)
+    to_end_dt = terms.to_end * terms.dt
+    whole = terms.from_start[:, q - 1:]                   # exp(b_Q)  [heads, 1]
     per_tile = LANES // head_dim
     lane_head = jax.lax.broadcasted_iota(
         jnp.int32, (1, LANES), 1
     ) // head_dim
-    top = jnp.zeros((1, 1), F32)
+    top = jnp.zeros((1, LANES), F32)
     for tile in range(heads // per_tile):
         lanes = pl.ds(tile * LANES, LANES)
-        each, t = _tile_terms(
-            a_ref, dt_ref, tile * per_tile, per_tile, lower, eye, lane_head
-        )
-        x = x_ref[0, :, lanes].astype(F32)                # [Q, 128]
-        x_dt = x * t.dt
-        x_dt_cd = x_dt.astype(cd)
-        start = state[:, lanes]                           # S^T  [N, 128]
+        here = range(tile * per_tile, (tile + 1) * per_tile)
+        from_start = _spread(terms.from_start, here[0], per_tile, head_dim)
+        x_cd = x_ref[0, :, lanes]                         # [Q, 128]
+        x = x_cd.astype(F32)
+        start = state[tile_of_group, :, lanes]            # S^T  [N, 128]
         start_cd = start.astype(cd)
         start_ref[0, 0, :, lanes] = start_cd
-        within = _by_lane(
-            [_dot((scores * h.decay).astype(cd), x_dt_cd) for h in each],
-            lane_head,
+        within, added = [], []
+        for h in here:
+            # what the chunk adds to the state: (B^T * dt to_end) X, the
+            # head's row on B^T's lanes (no column of it is needed)
+            added.append(_dot(
+                (bm_t * to_end_dt[h:h + 1]).astype(cd), x_cd
+            ))
+            # exp(b_t - b_i) where i <= t; the other half would overflow
+            decay = jnp.exp(jnp.where(
+                lower, _down(terms.b[h:h + 1], q) - terms.b[h:h + 1],
+                -jnp.inf,
+            ))
+            within.append(_dot(
+                (scores * decay * terms.dt[h:h + 1]).astype(cd), x_cd
+            ))
+        y = (
+            _by_lane(within, lane_head)
+            + from_start * _dot(cm, start_cd) + d_ref[:, lanes] * x
         )
-        y = within + t.from_start * _dot(cm, start_cd) + d_ref[:, lanes] * x
         y_ref[0, :, lanes] = y.astype(y_ref.dtype)
-        end = t.whole * start + _dot(bm, (x_dt * t.to_end).astype(cd), _TN)
-        state[:, lanes] = end
-        top = jnp.maximum(top, jnp.max(
-            jnp.max(jnp.abs(end), axis=1, keepdims=True), axis=0,
-            keepdims=True,
-        ))
+        end = _by_lane(
+            [whole[h:h + 1] for h in here], lane_head
+        ) * start + _by_lane(added, lane_head)
+        state[tile_of_group, :, lanes] = end
+        top = jnp.maximum(top, jnp.max(jnp.abs(end), axis=0, keepdims=True))
     top_ref[0] = jnp.maximum(top_ref[0], top)
 
 
 def _bwd_kernel(
-    x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, start_ref, dy_ref,
-    dx_ref, ddt_ref, da_ref, db_ref, dc_ref, d_state, *, heads, head_dim,
+    x_ref, dt_ref, sums_ref, b_ref, c_ref, d_ref, start_ref, dy_ref,
+    dx_ref, ddt_ref, dsums_ref, db_ref, dc_ref, dd_ref,
+    d_state, scores_ref, d_scores, d_b, d_c, b_tok, c_tok, *, heads, head_dim,
+    per_group,
 ):
     """One chunk, walked last to first.  ``d_state`` holds the cotangent
-    of the chunk's END state on entry and of its start state on exit."""
-    @pl.when(pl.program_id(2) == 0)
-    def _():
-        d_state[...] = jnp.zeros_like(d_state)
+    of the chunk's END state on entry and of its start state on exit.
+    The ``[Q, Q]`` terms are built TRANSPOSED (``[i, t]``): ``M^T dY``
+    needs no transpose then, and the sums over ``i`` that ``da`` needs are
+    sums down the sublanes."""
+    chunk, tile_of_group = pl.program_id(2), pl.program_id(3)
+    first = True if per_group == 1 else tile_of_group == 0
+    last = True if per_group == 1 else tile_of_group == per_group - 1
 
-    bm, cm = b_ref[0], c_ref[0]
-    cd = bm.dtype
-    lower, eye = _masks(bm.shape[0])
-    scores = _dot(cm, bm, _NT)
+    @pl.when(chunk == 0)
+    def _():
+        d_state[tile_of_group] = jnp.zeros(d_state.shape[1:], F32)
+        dd_ref[0, tile_of_group] = jnp.zeros(dd_ref.shape[2:], F32)
+
+    bm_t, cm_t = b_ref[0], c_ref[0]                       # B^T, C^T  [N, Q]
+    cd = bm_t.dtype
+    q = bm_t.shape[1]
+    upper, _ = _triangles(q)
+
+    bm = _at_first(first, b_tok, lambda: bm_t.T)          # B, C  [Q, N]
+    cm = _at_first(first, c_tok, lambda: cm_t.T)
+    # (C B^T)^T
+    scores_t = _at_first(first, scores_ref, lambda: _dot(bm, cm_t))
+
+    @_when(first)
+    def _():
+        d_scores[...] = jnp.zeros_like(d_scores)
+        d_b[...] = jnp.zeros_like(d_b)
+        d_c[...] = jnp.zeros_like(d_c)
+
+    terms = _step_terms(sums_ref, dt_ref)
     per_tile = LANES // head_dim
     lane_head = jax.lax.broadcasted_iota(
         jnp.int32, (1, LANES), 1
     ) // head_dim
-    d_scores = jnp.zeros_like(scores)
-    d_b = jnp.zeros(bm.shape, F32)
-    d_c = jnp.zeros(cm.shape, F32)
-
-    def head_sums(v, axis0=False):
-        """Each head's own sum over its lanes of ``v`` [., 128]: columns
-        [., 1] (``axis0``: over the rows too, [1, 1])."""
-        out = []
-        for j in range(per_tile):
-            s = jnp.sum(
-                jnp.where(lane_head == j, v, 0.0), axis=1, keepdims=True
-            )
-            out.append(jnp.sum(s, axis=0, keepdims=True) if axis0 else s)
-        return out
-
+    d_dt, d_sums, d_total = [], [], []
     for tile in range(heads // per_tile):
         lanes = pl.ds(tile * LANES, LANES)
-        first = tile * per_tile
-        each, t = _tile_terms(
-            a_ref, dt_ref, first, per_tile, lower, eye, lane_head
-        )
+        here = range(tile * per_tile, (tile + 1) * per_tile)
+        sums = _spread(terms.b, here[0], per_tile, head_dim)
+        dt = _spread(terms.dt, here[0], per_tile, head_dim)
+        from_start = jnp.exp(sums)
+        to_end = jnp.exp(sums[q - 1:] - sums)
         x = x_ref[0, :, lanes].astype(F32)
         dy_cd = dy_ref[0, :, lanes]
         dy = dy_cd.astype(F32)
-        x_dt = x * t.dt
-        x_dt_cd = x_dt.astype(cd)
-        x_end_cd = (x_dt * t.to_end).astype(cd)
+        x_dt = x * dt
+        # dt X as the products see it
+        x_dt_seen = x_dt.astype(cd).astype(F32)
+        x_end = x_dt * to_end
+        x_end_cd = x_end.astype(cd)
         start_cd = start_ref[0, 0, :, lanes]              # S^T  [N, 128]
-        d_end = d_state[:, lanes]
+        d_end = d_state[tile_of_group, :, lanes]
         d_end_cd = d_end.astype(cd)
         # Y = M (dt X) + from_start * (C S^T) + D X
         # S' = whole S + (dt to_end X)^T B
         c_start = _dot(cm, start_cd)                      # [Q, 128]
         b_d_end = _dot(bm, d_end_cd)                      # [Q, 128]
-        dy_start_cd = (dy * t.from_start).astype(cd)
-        d_c = d_c + _dot(dy_start_cd, start_cd, _NT)
-        d_b = d_b + _dot(x_end_cd, d_end_cd, _NT)
-        d_state[:, lanes] = t.whole * d_end + _dot(cm, dy_start_cd, _TN)
-        d_x_dt = _by_lane(
-            [_dot((scores * h.decay).astype(cd), dy_cd, _TN) for h in each],
-            lane_head,
-        ) + t.to_end * b_d_end
-        d_from_start = head_sums(dy * c_start)
-        d_to_end = head_sums(x_dt * b_d_end)
-        d_whole = head_sums(d_end * start_cd.astype(F32), axis0=True)
-        d_dt = head_sums(d_x_dt * x)
-        for j, h in enumerate(each):
-            own = lane_head == j
-            d_within = jnp.where(lower, _dot(
-                jnp.where(own, dy, 0.0).astype(cd), x_dt_cd, _NT
+        dy_start = dy * from_start
+        dy_start_cd = dy_start.astype(cd)
+        d_c[...] += _dot(start_cd, dy_start_cd, _NT)      # dC^T  [N, Q]
+        d_b[...] += _dot(d_end_cd, x_end_cd, _NT)
+        whole = from_start[q - 1:]                        # exp(b_Q)  [1, 128]
+        d_state[tile_of_group, :, lanes] = whole * d_end + _dot(
+            cm_t, dy_start_cd
+        )
+        d_x_dt, d_rows = [], []
+        for j, h in enumerate(here):
+            # [i, t]: exp(b_t - b_i) where i <= t, M^T beside it
+            decay_t = jnp.exp(jnp.where(
+                upper, terms.b[h:h + 1] - _down(terms.b[h:h + 1], q),
+                -jnp.inf,
+            ))
+            m_t = (scores_t * decay_t).astype(cd)
+            d_x_dt.append(_dot(m_t, dy_cd))
+            d_m_t = jnp.where(upper, _dot(
+                jnp.where(lane_head == j, x_dt_seen, 0.0).astype(cd), dy_cd,
+                _NT,
             ), 0.0)
-            d_scores = d_scores + d_within * h.decay
-            # decay[t, i] = exp(b_t - b_i), from_start = exp(b),
-            # to_end = exp(b_Q - b), whole = exp(b_Q)
-            d_decay = d_within * scores * h.decay
-            d_b_end = d_to_end[j] * h.to_end
-            d_b_col = (
-                jnp.sum(d_decay, axis=1, keepdims=True)
-                + d_from_start[j] * h.from_start - d_b_end
-                - _to_col(jnp.sum(d_decay, axis=0, keepdims=True), eye)
-            )
-            d_total = (
-                jnp.sum(d_b_end, axis=0, keepdims=True)
-                + d_whole[j] * h.whole
-            )
-            # b = cumsum(a):  da_t = the sum of db_j over j >= t; b_Q holds all
-            da_ref[first + j, 0] = d_total + jnp.sum(
-                jnp.where(lower, d_b_col, 0.0), axis=0, keepdims=True
-            )
-            ddt_ref[first + j, 0] = _to_row(d_dt[j], eye)
+            d_scores[...] += d_m_t * decay_t
+            # b_t moves row t of M: the sum over i of dM M, down the
+            # sublanes here
+            d_rows.append(jnp.sum(
+                d_m_t * m_t.astype(F32), axis=0, keepdims=True
+            ))
+        within = _by_lane(d_x_dt, lane_head)              # M^T dY
+        across = to_end * b_d_end
+        d_x_dt = within + across
         dx_ref[0, :, lanes] = (
-            d_x_dt * t.dt + d_ref[:, lanes] * dy
+            d_x_dt * dt + d_ref[:, lanes] * dy
         ).astype(dx_ref.dtype)
-    d_scores_cd = d_scores.astype(cd)
-    dc_ref[0] = (d_c + _dot(d_scores_cd, bm)).astype(dc_ref.dtype)
-    db_ref[0] = (d_b + _dot(d_scores_cd, cm, _TN)).astype(db_ref.dtype)
+        dd_ref[0, tile_of_group, :, lanes] += jnp.sum(
+            dy * x, axis=0, keepdims=True
+        )
+        d_dt += _head_rows(d_x_dt * x, per_tile, head_dim)
+        # b_i moves column i of M and to_end the other way: the sum over
+        # t of dM M is <dt X, M^T dY> with the SAME rounded M and dt X as
+        # the row sums above, so that what cancels between them cancels
+        d_sums += [
+            rows + start - cols for rows, start, cols in zip(
+                d_rows,
+                _head_rows(dy_start * c_start, per_tile, head_dim),
+                _head_rows(
+                    within * x_dt_seen + across * x_dt, per_tile, head_dim
+                ),
+            )
+        ]
+        # b_Q = the whole chunk's sum scales S' and what each token adds
+        # to it: d b_Q = <dS', S'>, a head's lanes of it
+        ends = whole * jnp.sum(
+            d_end * start_cd.astype(F32), axis=0, keepdims=True
+        ) + jnp.sum(x_end * b_d_end, axis=0, keepdims=True)
+        d_total += [
+            jnp.sum(
+                jnp.where(lane_head == j, ends, 0.0), axis=1, keepdims=True
+            )
+            for j in range(per_tile)
+        ]
+    # from_start = exp(b), decay[t, i] = exp(b_t - b_i), to_end =
+    # exp(b_Q - b): the chunk's last sum b_Q holds what moves them all
+    token = jax.lax.broadcasted_iota(jnp.int32, terms.b.shape, 1)
+    dsums_ref[0, 0] = _stack(d_sums, terms.b) + jnp.where(
+        token == q - 1, _stack(d_total, terms.b), 0.0
+    )
+    ddt_ref[0, 0] = _stack(d_dt, terms.b)
+
+    @_when(last)
+    def _():
+        d_scores_cd = d_scores[...].astype(cd)            # [i, t]
+        dc_ref[0] = (
+            d_c[...] + _dot(bm_t, d_scores_cd)
+        ).astype(dc_ref.dtype)
+        db_ref[0] = (
+            d_b[...] + _dot(cm_t, d_scores_cd, _NT)
+        ).astype(db_ref.dtype)
 
 
 def _grid(x, dt, b, groups, head_dim):
-    """The grid ``(batch, tiles, n)`` of these operands and a grid step's
-    sizes."""
+    """The grid ``(batch, groups, n, tiles a group)`` of these operands
+    and a grid step's sizes."""
     batch, _, total = x.shape
     n, chunk = dt.shape[1], dt.shape[-1]
-    state = b.shape[-1] // groups
+    state = b.shape[1] // groups
     heads = heads_per_step(
         total // head_dim, head_dim, groups, state, chunk, x.dtype
     )
     width = heads * head_dim
     tiles = total // width
+    per_group = tiles // groups
     return types.SimpleNamespace(
-        grid=(batch, tiles, n), tiles=tiles, per_group=tiles // groups,
-        heads=heads, width=width, state=state, chunk=chunk, n=n,
+        grid=(batch, groups, n, per_group), batch=batch, groups=groups,
+        tiles=tiles, per_group=per_group, heads=heads, width=width,
+        state=state, chunk=chunk, n=n,
     )
 
 
 def _specs(g, reverse):
-    """Block specs of one grid step (batch, tile of heads, chunk) of the
-    grid ``g`` (:func:`_grid`): the tile's columns of x-like and D-like
-    operands and its per-token rows, its group's columns of B and C
-    (``bc``), and a tile's own columns of a B-like output (``bc_part``: the
-    backward's partial dB, dC where a group is several tiles)."""
+    """Block specs of one grid step (batch, group, chunk, tile of the
+    group's heads) of the grid ``g`` (:func:`_grid`): the tile's columns of
+    x-like and D-like operands and its per-token rows, its group's rows
+    of B^T and C^T (``bc``: the same block for all the group's tiles, so
+    fetched once a (batch, chunk) and, as an output, written once)."""
     def at(c):
         return g.n - 1 - c if reverse else c
 
-    def group(t):
-        return t if g.per_group == 1 else t // g.per_group
+    def tile(j, t):
+        return j if g.per_group == 1 else j * g.per_group + t
 
     return types.SimpleNamespace(
-        x=pl.BlockSpec((1, g.chunk, g.width), lambda i, t, c: (i, at(c), t)),
-        bc=pl.BlockSpec(
-            (1, g.chunk, g.state), lambda i, t, c: (i, at(c), group(t))
+        x=pl.BlockSpec(
+            (1, g.chunk, g.width), lambda i, j, c, t: (i, at(c), tile(j, t))
         ),
-        bc_part=pl.BlockSpec(
-            (1, g.chunk, g.state), lambda i, t, c: (i, at(c), t)
+        bc=pl.BlockSpec(
+            (1, g.state, g.chunk), lambda i, j, c, t: (i, j, at(c))
         ),
         token=pl.BlockSpec(
-            (g.heads, 1, 1, g.chunk),
-            lambda i, t, c: (i * g.tiles + t, at(c), 0, 0),
+            (1, 1, g.heads, g.chunk),
+            lambda i, j, c, t: (i, at(c), tile(j, t), 0),
         ),
-        d=pl.BlockSpec((1, g.width), lambda i, t, c: (0, t)),
+        d=pl.BlockSpec((1, g.width), lambda i, j, c, t: (0, tile(j, t))),
         start=pl.BlockSpec(
             (1, 1, g.state, g.width),
-            lambda i, t, c: (i * g.tiles + t, at(c), 0, 0),
+            lambda i, j, c, t: (i * g.tiles + tile(j, t), at(c), 0, 0),
         ),
     )
 
 
+_SEMANTICS = ("parallel", "parallel", "arbitrary", "arbitrary")
+
+
 @functools.partial(jax.jit, static_argnames=("groups", "head_dim"))
-def _forward(x, dt, a, b, c, d, *, groups, head_dim):
-    """``x`` [B, S, H P]; ``dt``, ``a`` [B H, n, 1, Q] float32; ``b``, ``c``
-    [B, S, G N]; ``d`` [1, H P] float32 (a head's ``D`` on its lanes).
-    Returns ``y`` [B, S, H P], the chunks' start states ``S^T`` [B tiles, n,
-    N, heads P] (both in ``x``'s dtype) and each (batch, tile)'s largest
-    ``|S|`` at a chunk's end [B tiles] (float32).  (Jitted, as the backward is,
-    so that a step which runs the scan in several slots, forward, recomputed
-    and transposed, traces and lowers each kernel body once.)"""
+def _forward(x, dt, sums, b, c, d, *, groups, head_dim):
+    """``x`` [B, S, H P]; ``dt`` and ``sums`` (the running sums of ``a``
+    inside each chunk) [B, n, H, Q] float32; ``b``, ``c`` [B, G N, S];
+    ``d`` [1, H P] float32 (a head's ``D`` on its lanes).  Returns ``y``
+    [B, S, H P], each (batch, group)'s largest ``|S|`` at a chunk's end, a
+    lane's own [B G, 128] (float32) and the chunks' start states ``S^T``
+    [B tiles, n, N, heads P] in ``x``'s dtype (what the backward reads).  (Jitted, as the backward is, so that a step which runs the scan
+    in several slots, forward, recomputed and transposed, traces and lowers
+    each kernel body once.)"""
     g = _grid(x, dt, b, groups, head_dim)
-    batch, tiles = g.grid[:2]
     sp = _specs(g, False)
-    y, starts, top = pl.pallas_call(
-        functools.partial(_fwd_kernel, heads=g.heads, head_dim=head_dim),
+    y, top, starts = pl.pallas_call(
+        functools.partial(
+            _fwd_kernel, heads=g.heads, head_dim=head_dim,
+            per_group=g.per_group,
+        ),
         grid=g.grid,
         in_specs=[sp.x, sp.token, sp.token, sp.bc, sp.bc, sp.d],
         out_specs=[
-            sp.x, sp.start,
+            sp.x,
             pl.BlockSpec(
-                (1, 1, LANES), lambda i, t, c: (i * tiles + t, 0, 0)
+                (1, 1, LANES), lambda i, j, c, t: (i * groups + j, 0, 0)
             ),
+            sp.start,
         ],
         out_shape=[
             jax.ShapeDtypeStruct(x.shape, x.dtype),
+            jax.ShapeDtypeStruct((g.batch * groups, 1, LANES), F32),
             jax.ShapeDtypeStruct(
-                (batch * tiles, g.n, g.state, g.width), x.dtype
+                (g.batch * g.tiles, g.n, g.state, g.width), x.dtype
             ),
-            jax.ShapeDtypeStruct((batch * tiles, 1, LANES), F32),
         ],
-        scratch_shapes=[pltpu.VMEM((g.state, g.width), F32)],
+        scratch_shapes=[
+            pltpu.VMEM((g.per_group, g.state, g.width), F32),
+            pltpu.VMEM((g.chunk, g.chunk), F32),
+            pltpu.VMEM((g.chunk, g.state), c.dtype),
+        ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=_SEMANTICS,
         ),
         interpret=backend.interpret(),
         name="ssd_fwd",
-    )(x, dt, a, b, c, d)
-    return y, starts, top[:, 0, 0]
+    )(x, dt, sums, b, c, d)
+    return y, top[:, 0], starts
 
 
 @functools.partial(jax.jit, static_argnames=("groups", "head_dim"))
-def _backward(x, dt, a, b, c, d, starts, dy, *, groups, head_dim):
+def _backward(x, dt, sums, b, c, d, starts, dy, *, groups, head_dim):
+    """The six cotangents; ``dD`` as each batch row's ``sum_t dy x`` on the
+    lanes [B G, tiles a group, 1, heads P] (float32)."""
     g = _grid(x, dt, b, groups, head_dim)
-    batch, tiles = g.grid[:2]
     sp = _specs(g, True)
-    # a group of several tiles: each writes its own part of dB and dC
-    split = g.per_group > 1
-    part = jax.ShapeDtypeStruct(
-        (batch, x.shape[1], tiles * g.state), F32
-    )
-    dx, ddt, da, db, dc = pl.pallas_call(
-        functools.partial(_bwd_kernel, heads=g.heads, head_dim=head_dim),
+    return pl.pallas_call(
+        functools.partial(
+            _bwd_kernel, heads=g.heads, head_dim=head_dim,
+            per_group=g.per_group,
+        ),
         grid=g.grid,
         in_specs=[
             sp.x, sp.token, sp.token, sp.bc, sp.bc, sp.d, sp.start, sp.x,
         ],
-        out_specs=[sp.x, sp.token, sp.token] + (
-            [sp.bc_part] * 2 if split else [sp.bc] * 2
-        ),
+        out_specs=[
+            sp.x, sp.token, sp.token, sp.bc, sp.bc,
+            pl.BlockSpec(
+                (1, g.per_group, 1, g.width),
+                lambda i, j, c, t: (i * groups + j, 0, 0, 0),
+            ),
+        ],
         out_shape=[
             jax.ShapeDtypeStruct(x.shape, x.dtype),
             jax.ShapeDtypeStruct(dt.shape, F32),
-            jax.ShapeDtypeStruct(a.shape, F32),
-        ] + (
-            [part, part] if split else [
-                jax.ShapeDtypeStruct(b.shape, b.dtype),
-                jax.ShapeDtypeStruct(c.shape, c.dtype),
-            ]
-        ),
-        scratch_shapes=[pltpu.VMEM((g.state, g.width), F32)],
+            jax.ShapeDtypeStruct(sums.shape, F32),
+            jax.ShapeDtypeStruct(b.shape, b.dtype),
+            jax.ShapeDtypeStruct(c.shape, c.dtype),
+            jax.ShapeDtypeStruct(
+                (g.batch * groups, g.per_group, 1, g.width), F32
+            ),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((g.per_group, g.state, g.width), F32),
+            pltpu.VMEM((g.chunk, g.chunk), F32),
+            pltpu.VMEM((g.chunk, g.chunk), F32),
+            pltpu.VMEM((g.state, g.chunk), F32),
+            pltpu.VMEM((g.state, g.chunk), F32),
+            pltpu.VMEM((g.chunk, g.state), b.dtype),
+            pltpu.VMEM((g.chunk, g.state), c.dtype),
+        ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=_SEMANTICS,
         ),
         interpret=backend.interpret(),
         name="ssd_bwd",
-    )(x, dt, a, b, c, d, starts, dy)
-    if split:
-        def over_tiles(parts, like):
-            return parts.reshape(
-                *parts.shape[:2], groups, g.per_group, g.state
-            ).sum(axis=3).reshape(like.shape).astype(like.dtype)
-
-        db, dc = over_tiles(db, b), over_tiles(dc, c)
-    return dx, ddt, da, db, dc
+    )(x, dt, sums, b, c, d, starts, dy)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
-def _scan(x, dt, a, b, c, d, groups, head_dim):
-    return _scan_fwd(x, dt, a, b, c, d, groups, head_dim)[0]
+def _scan(x, dt, sums, b, c, d, groups, head_dim):
+    return _scan_fwd(x, dt, sums, b, c, d, groups, head_dim)[0]
 
 
-def _scan_fwd(x, dt, a, b, c, d, groups, head_dim):
-    y, starts, top = _forward(
-        x, dt, a, b, c, d, groups=groups, head_dim=head_dim
+def _scan_fwd(x, dt, sums, b, c, d, groups, head_dim):
+    y, top, starts = _forward(
+        x, dt, sums, b, c, d, groups=groups, head_dim=head_dim
     )
-    return (y, top), (x, dt, a, b, c, d, starts)
+    return (y, top), (x, dt, sums, b, c, d, starts)
 
 
 def _scan_bwd(groups, head_dim, res, cts):
     dy, _ = cts          # the largest |S| is a reading, not a result
-    x, dt, a, b, c, d, starts = res
-    dx, ddt, da, db, dc = _backward(
-        x, dt, a, b, c, d, starts, dy, groups=groups, head_dim=head_dim
+    x, dt, sums, b, c, d, starts = res
+    dx, ddt, dsums, db, dc, dd = _backward(
+        x, dt, sums, b, c, d, starts, dy, groups=groups, head_dim=head_dim
     )
-    dd = jnp.sum(
-        dy.astype(F32) * x.astype(F32), axis=(0, 1)
-    )[None].astype(d.dtype)
-    return dx, ddt, da, db, dc, dd
+    # over the batch here; _ssd_kernel's repeat of D sums a head's lanes
+    dd = dd.reshape(x.shape[0], -1).sum(axis=0)[None].astype(d.dtype)
+    return dx, ddt, dsums, db, dc, dd
 
 
 _scan.defvjp(_scan_fwd, _scan_bwd)
@@ -451,25 +595,33 @@ _MAX_HEADS = 8
 
 
 def step_vmem_bytes(
-    heads: int, head_dim: int, state: int, chunk: int, dtype
+    heads: int, head_dim: int, state: int, chunk: int, dtype,
+    tiles_per_group: int = 1,
 ) -> int:
     """VMEM a grid step of ``heads`` heads plans for, by the backward
-    kernel (the larger): the double-buffered blocks (x, dy, dx tiles, the
-    chunk's start state, B, C, dB, dC, and the per-token rows of dt, a,
-    ddt, da, each padded to 8 sublanes), the float32 state scratch, and
-    the body's float32 terms: ``C B^T`` and its cotangent, four ``[Q, Q]``
-    a head of the lane tile in hand, a dozen ``[Q, 128]`` tiles."""
+    kernel (the larger), where a group is ``tiles_per_group`` such steps:
+    the double-buffered blocks (x, dy, dx tiles, the chunk's start state,
+    B, C, dB, dC, the rows of dt, a, ddt, da, ``D`` and the group's ``dD``
+    block, a row padded to 8 sublanes), the scratch (float32: the state of
+    ALL the group's tiles, ``(C B^T)^T`` and its cotangent, the group's dB
+    and dC sums; the token-major B and C), and the body's float32 terms: the two triangles, four
+    ``[Q, Q]`` a head of the lane tile in hand, a dozen ``[Q, 128]``
+    tiles."""
     size = jnp.dtype(dtype).itemsize
     width = heads * head_dim
     blocks = 2 * (
         3 * chunk * width * size + state * width * size
-        + 2 * chunk * state * size + 2 * chunk * state * 4
-        + 4 * heads * 8 * chunk * 4
+        + 4 * chunk * state * size + 4 * max(heads, 8) * chunk * 4
+        + (1 + tiles_per_group) * 8 * width * 4
     )
+    scratch = (
+        tiles_per_group * state * width + 2 * chunk * chunk
+        + 2 * chunk * state
+    ) * 4 + 2 * chunk * state * size
     terms = (
         (2 + 4 * (LANES // head_dim)) * chunk * chunk + 12 * chunk * LANES
     ) * 4
-    return blocks + state * width * 4 + terms
+    return blocks + scratch + terms
 
 
 def heads_per_step(
@@ -490,8 +642,9 @@ def heads_per_step(
     most = max(per_tile, min(per_group, _MAX_HEADS))
     fitting = [
         n for n in range(per_tile, most + 1, per_tile)
-        if per_group % n == 0
-        and step_vmem_bytes(n, head_dim, state, chunk, dtype) <= _VMEM_BUDGET
+        if per_group % n == 0 and step_vmem_bytes(
+            n, head_dim, state, chunk, dtype, per_group // n
+        ) <= _VMEM_BUDGET
     ]
     return max(fitting, default=0)
 
@@ -511,13 +664,26 @@ def _ssd_kernel(x, dt, a, b, c, d, chunk):
     n = s // chunk
 
     def per_token(v):
-        """[B, S, H] float32 -> [B H, n, 1, Q]"""
-        return jnp.moveaxis(v, 2, 1).reshape(batch * heads, n, 1, chunk)
+        """[B, S, H] float32 -> [B, n, H, Q]: a chunk's tokens on the lanes"""
+        return jnp.swapaxes(v.reshape(batch, n, chunk, heads), 2, 3)
 
+    def by_channel(v):
+        """[B, S, G, N] -> [B, G N, S]: a token's state vector down the
+        sublanes, as a convolution kernel that keeps the tokens on the
+        lanes (``ops/short_conv.py``) hands it over: its transpose and
+        this one cancel, and no copy of B or C is made"""
+        return jnp.swapaxes(v.reshape(batch, s, groups * state), 1, 2)
+
+    # b_t, the running sum of a inside its chunk: one product with the
+    # triangle for all heads and chunks (its transpose, the sums from a
+    # token to the chunk's end, turns the kernel's db into da)
+    sums = jnp.matmul(
+        per_token(a), jnp.triu(jnp.ones((chunk, chunk), F32)),
+        precision=jax.lax.Precision.HIGHEST,
+    )
     y, top = _scan(
-        x.reshape(batch, s, heads * head_dim), per_token(dt), per_token(a),
-        b.reshape(batch, s, groups * state),
-        c.reshape(batch, s, groups * state),
+        x.reshape(batch, s, heads * head_dim), per_token(dt), sums,
+        by_channel(b), by_channel(c),
         jnp.repeat(d.astype(F32), head_dim)[None], groups, head_dim,
     )
     return y.reshape(x.shape), jnp.max(top)

@@ -357,6 +357,7 @@ class ElasticTrainer:
                 **self._flash_facts(),
                 "ssm_scan": self._ssm_scan(),
                 "ssm_heads_per_step": self._ssm_heads_per_step(),
+                "ssm_tiles_per_group": self._ssm_tiles_per_group(),
                 "short_conv": self._short_conv(),
                 "row_moves": self._row_moves(),
             }
@@ -466,11 +467,25 @@ class ElasticTrainer:
     def _ssm_heads_per_step(self) -> Optional[int]:
         """How the scan kernels' grid is cut, beside ``ssm_scan``: the
         heads one grid step holds (``ops/ssd.py`` ``heads_per_step``, which
-        the kernels ask; a group of more heads runs as several tiles),
-        ``None`` where no kernel runs the scan."""
+        the kernels ask; a group of more heads runs as several tiles:
+        ``ssm_tiles_per_group``), ``None`` where no kernel runs the
+        scan."""
         if self._ssm_scan() != "kernel":
             return None
         return self.model_config.ssm_heads_per_step
+
+    def _ssm_tiles_per_group(self) -> Optional[int]:
+        """Beside ``ssm_heads_per_step``: the grid steps that share one
+        group's B and C (the kernels' innermost grid axis: Nemotron-H 1,
+        Granite-4.0-H 16; where it is more than 1 the group's state stays
+        in VMEM for all of them, ``C B^T`` is formed at the first and dB,
+        dC are written at the last), ``None`` where no kernel runs the
+        scan."""
+        per_step = self._ssm_heads_per_step()
+        if not per_step:
+            return None
+        cfg = self.model_config
+        return cfg.ssm_num_heads // cfg.ssm_groups // per_step
 
     def _row_moves(self) -> str:
         """Which path a token's ``top_k`` rows take through the dropless

@@ -310,12 +310,15 @@ def test_the_shares_add_up_to_the_uncut_layer(held_here, total):
 # tests/test_olmo_hybrid_reference.py does not hold, at the parent commit
 # fed8b01 (its ``lowered_step_text``): JoyAI-LLM-Flash's (latent attention,
 # the sigmoid router, a share) and Nemotron's, whose text holds its scan
-# kernels' grids and index maps (one tile a group).
+# kernels' grids and index maps (one tile a group).  Nemotron's is the text
+# since PR 40, which rewrote the scan kernels' bodies (``ops/ssd.py``: at
+# fed8b01 it read 1b8a7fa8...d4e7b3); whoever edits those kernels next
+# re-pins it, and JoyAI's says that nothing else in the step moved.
 LOWERED_AT_PARENT = {
     "joyai-llm-flash":
         "680dda303fa8cbf13fc776cde453e40c8602a628464f5231c931c2402835c48d",
     "nemotron-3-nano-30b-a3b":
-        "1b8a7fa8a5648ce23ccf60fd5f26de5293d24f514a4eef3fa53c833257d4e7b3",
+        "1ac0af4f4c3def1f692d608f179378d391e7055c6795817117ad3f15e16b47b4",
 }
 
 
@@ -408,6 +411,7 @@ def test_fit_books_the_scan_cut_and_the_row_moves(monkeypatch, tmp_path):
     (compiled,) = [e for e in taken if e[0] == "compile"]
     assert compiled[-1]["ssm_scan"] == "kernel"
     assert compiled[-1]["ssm_heads_per_step"] == 8
+    assert compiled[-1]["ssm_tiles_per_group"] == 2
     assert compiled[-1]["row_moves"] == "xla"      # rows of 64 are no tile
     (ssm,) = [e for e in taken if e[0] == "ssm" and e[1] == "event"]
     assert ssm[4]["heads"] == 16 and ssm[4]["groups"] == 1
@@ -416,32 +420,36 @@ def test_fit_books_the_scan_cut_and_the_row_moves(monkeypatch, tmp_path):
     assert moe[4]["drop_fraction"] == 0.0
 
 
-@pytest.mark.parametrize("model,scan,heads,rows", [
-    # the published widths: the fetch-and-sum takes ten rows of 4,096
-    (lambda: granite_moe_hybrid_config(ssm_impl="kernel"), "kernel", 8,
+@pytest.mark.parametrize("model,scan,heads,tiles,rows", [
+    # the published widths: the fetch-and-sum takes ten rows of 4,096;
+    # ONE group of 128 heads is sixteen grid steps of 8
+    (lambda: granite_moe_hybrid_config(ssm_impl="kernel"), "kernel", 8, 16,
      "kernel"),
     (lambda: granite_moe_hybrid_config(ssm_impl="kernel", ssm_chunk=128),
-     "kernel", 8, "kernel"),
-    (lambda: granite_moe_hybrid_config(), "xla", None, "kernel"),
+     "kernel", 8, 16, "kernel"),
+    (lambda: granite_moe_hybrid_config(), "xla", None, None, "kernel"),
     # Nemotron-3-Nano's rows of 2,688 are no whole native tiles: its
-    # row moves are XLA's gather and reduction
-    (lambda: nemotron_h_config(ssm_impl="kernel"), "kernel", 8, "xla"),
-    (lambda: TransformerConfig(), "none", None, "none"),
+    # row moves are XLA's gather and reduction; a group of 8 heads is one
+    # grid step
+    (lambda: nemotron_h_config(ssm_impl="kernel"), "kernel", 8, 1, "xla"),
+    (lambda: TransformerConfig(), "none", None, None, "none"),
     (lambda: TransformerConfig(num_experts=8, moe_dispatch="einsum"),
-     "none", None, "none"),
+     "none", None, None, "none"),
 ])
 def test_the_compile_event_asks_what_the_dispatch_asks(
-    model, scan, heads, rows
+    model, scan, heads, tiles, rows
 ):
     from dlrover_tpu.trainer.elastic_trainer import ElasticTrainer
 
     class Stub:
         model_config = model()
         _ssm_scan = ElasticTrainer._ssm_scan
+        _ssm_heads_per_step = ElasticTrainer._ssm_heads_per_step
 
     stub = Stub()
     assert ElasticTrainer._ssm_scan(stub) == scan
     assert ElasticTrainer._ssm_heads_per_step(stub) == heads
+    assert ElasticTrainer._ssm_tiles_per_group(stub) == tiles
     assert ElasticTrainer._row_moves(stub) == rows
 
 

@@ -212,22 +212,141 @@ def test_the_tile_of_heads_follows_from_the_shapes(shape, want):
     if want:
         assert ssd_lib.step_vmem_bytes(
             want, shape["head_dim"], shape["state"], shape["chunk"],
-            jnp.bfloat16,
+            jnp.bfloat16, shape["heads"] // shape["groups"] // want,
         ) <= ssd_lib._VMEM_BUDGET
 
 
 def test_nemotrons_kernels_are_lowered_as_before_the_tiles():
-    """A group that is one grid step takes the index maps it had: B and C
-    by the grid's own second index, dB and dC written whole in the
-    operands' dtype (no partial sums)."""
+    """A group that is one grid step: B^T and C^T by the grid's own index
+    (the group's rows as x's columns are the tile's, no arithmetic on it),
+    dB and dC written whole by the kernel in the operands' dtype (no
+    partial sums over tiles for XLA to add), and no ``dy x`` product
+    outside the kernel."""
     args, weight = inputs(batch=1, s=32, heads=4, head_dim=64, groups=2)
+    x2 = args["x"].reshape(1, 32, 256)
+    b2 = jnp.swapaxes(args["b"].reshape(1, 32, 32), 1, 2)   # [B, G N, S]
+    dt4 = jnp.zeros((1, 2, 4, 16), F32)
+    g = ssd_lib._grid(x2, dt4, b2, 2, 64)
+    assert g.grid == (1, 2, 2, 1) and g.per_group == 1
+    for reverse, chunk in ((False, 1), (True, 0)):
+        sp = ssd_lib._specs(g, reverse)
+        assert sp.x.index_map(0, 1, 1, 0) == (0, chunk, 1)
+        assert sp.bc.index_map(0, 1, 1, 0) == (0, 1, chunk)
 
     def loss(x, b):
         y, _ = ssd_lib.ssd(**dict(args, x=x, b=b), chunk=16, impl="kernel")
         return (y * weight[:1, :32]).sum()
 
-    text = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(
         args["x"], args["b"]
-    ))
+    )
+    text = str(jaxpr)
     assert "ssd_fwd" in text and "ssd_bwd" in text
-    assert " div " not in text.split("ssd_bwd")[1].split("reduce_sum")[0]
+    after = text.split("ssd_bwd")[-1]
+    assert " div " not in after and " mul " not in after
+    backward = [
+        eqn for eqn in _flat(jaxpr.jaxpr)
+        if eqn.primitive.name == "pallas_call"
+        and "ssd_bwd" in str(eqn.params.get("name", eqn.params))
+    ]
+    assert len(backward) == 1
+    shapes = [(v.aval.shape, v.aval.dtype) for v in backward[0].outvars]
+    assert shapes[3] == (b2.shape, b2.dtype) == shapes[4]
+    assert shapes[5] == ((2, 1, 1, 128), F32)             # dD on the lanes
+
+
+def _flat(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _flat(sub)
+
+
+def through(fn, args, weight):
+    names = sorted(args)
+
+    def loss(*values):
+        y = fn(**dict(zip(names, values)))
+        return (y.astype(F32) * weight).sum()
+
+    return names, jax.grad(loss, argnums=tuple(range(len(names))))(
+        *(args[n] for n in names)
+    )
+
+
+# A grid step at its real sizes (8 heads of 64 side by side in four lane
+# tiles, a state of 128, a chunk of 128, two chunks, batch 2): one group
+# that is one tile, and one group of two tiles
+@pytest.mark.parametrize("dtype", [F32, jnp.bfloat16])
+@pytest.mark.parametrize("heads,tiles", [(8, 1), (16, 2)])
+def test_a_grid_step_at_its_real_sizes_matches_the_chunked_form(
+    heads, tiles, dtype
+):
+    assert heads // ssd_lib.heads_per_step(heads, 64, 1, 128, 128, dtype) \
+        == tiles
+    args, weight = inputs(
+        batch=2, s=256, heads=heads, head_dim=64, groups=1, state=128,
+        dtype=dtype,
+    )
+    args["dt"] = args["dt"] * 0.1              # a state that lives 128 tokens
+    got, want = {}, {}
+    for impl, out in (("kernel", got), ("xla", want)):
+        out["y"] = ssd_lib.ssd(**args, chunk=128, impl=impl)[0]
+        names, grads = through(
+            lambda **kw: ssd_lib.ssd(**kw, chunk=128, impl=impl)[0],
+            args, weight,
+        )
+        out.update(zip(names, grads))
+    if dtype == F32:
+        for name in got:
+            scale = float(jnp.abs(want[name]).max())
+            np.testing.assert_allclose(
+                got[name], want[name], atol=5 * TOL * scale, rtol=5 * TOL,
+                err_msg=name,
+            )
+        return
+    # bfloat16: both forms round their products; each stays within
+    # bfloat16's rounding of the float32 chunked form on the same inputs
+    exact = {k: v.astype(F32) for k, v in args.items()}
+    true = {"y": ssd_lib.ssd(**exact, chunk=128, impl="xla")[0]}
+    names, grads = through(
+        lambda **kw: ssd_lib.ssd(**kw, chunk=128, impl="xla")[0],
+        exact, weight,
+    )
+    true.update(zip(names, grads))
+    for name, t in true.items():
+        norm = float(jnp.linalg.norm(t))
+        errs = [
+            float(jnp.linalg.norm(out[name].astype(F32) - t)) / norm
+            for out in (got, want)
+        ]
+        assert errs[0] < max(2 * errs[1], 1e-5), (name, errs)
+        assert errs[0] < 0.02, (name, errs)
+
+
+@pytest.mark.parametrize("heads,groups", [(4, 2), (32, 1)])
+def test_dd_is_the_sum_of_dy_x_for_a_head_whose_d_is_not_one(heads, groups):
+    """``y`` holds ``D_h x``: ``dD_h`` is the sum of ``dy x`` over the
+    head's tokens and lanes whatever ``D`` is (the backward kernel adds it
+    up chunk by chunk, the batch and a head's lanes are summed outside),
+    and ``dx`` moves by ``D dy``."""
+    args, weight = inputs(batch=2, s=40, heads=heads, groups=groups)
+    args["d"] = jnp.linspace(-1.5, 2.5, heads)
+
+    def grads(d):
+        def loss(x, d):
+            y, _ = ssd_lib.ssd(**dict(args, x=x, d=d), chunk=16,
+                               impl="kernel")
+            return (y * weight).sum()
+
+        return jax.grad(loss, argnums=(0, 1))(args["x"], d)
+
+    dx, dd = grads(args["d"])
+    want = (weight * args["x"]).sum(axis=(0, 1, 3))
+    np.testing.assert_allclose(
+        dd, want, atol=TOL * float(jnp.abs(want).max()), rtol=TOL
+    )
+    dx_none, _ = grads(jnp.zeros((heads,)))
+    np.testing.assert_allclose(
+        dx - dx_none, args["d"][:, None] * weight, atol=5 * TOL, rtol=TOL
+    )

@@ -205,6 +205,15 @@ def fold_share_stats(rows):
 # where that kernel fits the d_model-wide rows stay row-tiled from the
 # gather that makes them to the GEMMs, which reshape blocks in VMEM for
 # nothing; turning a plain ``[R, D]`` array row-tiled is a 1.8 ms copy.
+#
+# Under a share of the experts a pair routed elsewhere (or past the budget)
+# has no row here and its ``dest`` names the last row, which stays zero.
+# XLA's gather fetches that row like any other; the kernel, bound by the
+# DMAs it issues, is instead handed the pairs that have a row (the plan's
+# ``live``: ``row_gather_sum.live_pairs`` of ``dest``, made once a layer
+# for the combine and for the scatter's transpose) and fetches and adds
+# those alone: an eighth of the DMAs, 0.5 ms a call where every pair costs
+# 2.6.  With every expert held no list is made and the call is as it was.
 
 
 def _row_budget(pairs: int, block: int, experts: int) -> int:
@@ -241,7 +250,9 @@ def _dispatch_plan(gate_idx, experts: int, block: int, n_pad: int,
     ``dest`` [T, k]: the pair's row; ``row_pair`` [n_pad]: the row's pair
     (flat ``token * k + choice``), ``T * k`` where the row is padding;
     under a share also ``kept`` []: the pairs that have a row, and
-    ``here`` []: the pairs routed to an expert held here."""
+    ``here`` []: the pairs routed to an expert held here.  (Where its rows
+    go through the fetch-and-sum kernel the layer adds ``live``: the list
+    of the pairs that have a row, in the form that kernel reads.)"""
     t, k = gate_idx.shape
     n = t * k
     share = bool(total) and experts < total
@@ -305,12 +316,14 @@ def _rows_for(x, pair_token, tiled: bool):
     return (row_gather_sum.row_tiled(src) if tiled else src)[pair_token]
 
 
-def _k_rows_summed(rows, dest, gates=None):
+def _k_rows_summed(rows, dest, gates=None, live=None):
     """``out[t] = sum_j gates[t, j] * rows[dest[t, j]]`` (no ``gates``: the
     plain sum) as ``[T, D]``, the gates and the sum in float32.  Row-tiled
-    ``rows`` go through the kernel, which fetches and sums in one pass."""
+    ``rows`` go through the kernel, which fetches and sums in one pass:
+    every pair, or given a share's ``live`` list only those that have a row
+    here (the others name the zero row)."""
     if rows.ndim == 3:
-        return row_gather_sum.gather_sum(rows, dest, gates)
+        return row_gather_sum.gather_sum(rows, dest, gates, live=live)
     picked = rows[dest].astype(jnp.float32)                     # [T, k, D]
     if gates is not None:
         picked = picked * gates[..., None]
@@ -330,7 +343,7 @@ def _rows_of_tokens_fwd(x, plan, tiled):
 
 def _rows_of_tokens_bwd(tiled, plan, d_rows):
     # each token's k rows, summed: the scatter-add's transpose as a gather
-    return _k_rows_summed(d_rows, plan["dest"]), None
+    return _k_rows_summed(d_rows, plan["dest"], live=plan.get("live")), None
 
 
 _rows_of_tokens.defvjp(_rows_of_tokens_fwd, _rows_of_tokens_bwd)
@@ -340,7 +353,7 @@ _rows_of_tokens.defvjp(_rows_of_tokens_fwd, _rows_of_tokens_bwd)
 def _tokens_of_rows(out_rows, gates, plan):
     """``out[t] = sum_j gates[t, j] * out_rows[row of pair (t, j)]``, the
     gates and the sum in float32; ``out_rows`` plain or row-tiled."""
-    return _k_rows_summed(out_rows, plan["dest"], gates)
+    return _k_rows_summed(out_rows, plan["dest"], gates, plan.get("live"))
 
 
 def _tokens_of_rows_fwd(out_rows, gates, plan):
@@ -816,14 +829,21 @@ class MoEMlp(nn.Module):
             wg = weights[1] if len(weights) == 3 else None
             b, s, d = x.shape
             t = b * s
+            n_pad = budget(t * k)
             with jax.named_scope("sort"):
                 plan = _dispatch_plan(
-                    gate_idx.reshape(t, k), e, block, budget(t * k),
-                    first, total,
+                    gate_idx.reshape(t, k), e, block, n_pad, first, total,
                 )
             # The d_model-wide rows live row-tiled between the gathers and
             # the GEMMs wherever the fetch-and-sum kernel can read them.
             tiled = row_gather_sum.kernel_fits(d, k, self.dtype)
+            if share and tiled:
+                # most of a token's pairs have no row here: the kernel is
+                # handed the ones that have, once for its two calls
+                with jax.named_scope("sort"):
+                    plan["live"] = row_gather_sum.live_pairs(
+                        plan["dest"], n_pad - 1, d, self.dtype
+                    )
             with jax.named_scope("scatter"):
                 rows = _rows_of_tokens(
                     x.reshape(t, d).astype(self.dtype), plan, tiled

@@ -491,15 +491,19 @@ class ElasticTrainer:
         """Which path a token's ``top_k`` rows take through the dropless
         dispatch's combine and the scatter's transpose, for the ``compile``
         event: ``kernel`` (``ops/row_gather_sum.py``: fetched and summed in
-        one pass) / ``xla`` (a gather, then a reduction), ``none`` for a
-        model without grouped experts.  Asks the function the layer asks."""
+        one pass) / ``kernel_live`` (the same under a share of the experts:
+        only the pairs that have a row here are fetched and added) /
+        ``xla`` (a gather, then a reduction), ``none`` for a model without
+        grouped experts.  Asks the function the layer asks."""
         cfg = self.model_config
         if not cfg.num_experts or cfg.moe_dispatch != "grouped":
             return "none"
         from dlrover_tpu.ops import row_gather_sum
 
-        fits = row_gather_sum.kernel_fits(cfg.d_model, cfg.top_k, cfg.dtype)
-        return "kernel" if fits else "xla"
+        if not row_gather_sum.kernel_fits(cfg.d_model, cfg.top_k, cfg.dtype):
+            return "xla"
+        share = cfg.resolved_experts_held < cfg.num_experts
+        return "kernel_live" if share else "kernel"
 
     def _short_conv(self) -> str:
         """How the step program's short convolutions run, for the
@@ -1381,6 +1385,16 @@ class ElasticTrainer:
             pairs_here, bias_absmax = (
                 (1.0, 0.0) if share is None else np.asarray(share, np.float64)
             )
+            # Of a token's top_k row fetches, the share that is issued: all
+            # of them, but where the live-only kernel runs those of the
+            # pairs the plan kept (routed here, less the dropped ones).
+            # From the two means the step already returns: the layers' mean
+            # of kept / pairs to the digit while no layer drops a pair,
+            # the product of two means (not the mean of the layers'
+            # products) once one does.
+            row_fetch_share = 1.0
+            if self._row_moves() == "kernel_live":
+                row_fetch_share = float(pairs_here) * (1.0 - float(drop))
             telemetry.event(
                 "moe", step=step,
                 entropy=float(entropy),
@@ -1394,6 +1408,7 @@ class ElasticTrainer:
                 held=int(self.model_config.resolved_experts_held),
                 pairs_here=float(pairs_here),
                 bias_absmax=float(bias_absmax),
+                row_fetch_share=row_fetch_share,
             )
         if "mtp_loss" in metrics and step % cfg.report_every == 0:
             # The multi-token-prediction module's own cross-entropy (token

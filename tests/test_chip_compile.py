@@ -110,6 +110,19 @@ def _row_gather_sum():
     return gather_sum
 
 
+def _row_gather_sum_live():
+    """Under a share's plan: the last row is the zero row, and the pairs
+    that name it are neither fetched nor added."""
+    from dlrover_tpu.ops.row_gather_sum import gather_sum, live_pairs
+
+    def fn(rows, index, *gates):
+        zero_row, d = rows.shape[0] - 1, rows.shape[1] * rows.shape[2]
+        live = live_pairs(index, zero_row, d, rows.dtype)
+        return gather_sum(rows, index, *gates, live=live)
+
+    return fn
+
+
 def _delta_rule():
     from dlrover_tpu.ops.gated_delta_rule import gated_delta_rule
 
@@ -243,6 +256,11 @@ CASES = [
      [((24704, 768), BF16), ((32, 768, 2048), BF16), ((32,), I32)], {}, 3),
     ("row_gather_sum_share_weighted", _row_gather_sum,
      [((24704, 16, 128), BF16), ((16384, 8), I32), ((16384, 8), F32)], {}, 1),
+    # the same handed the live pairs (an eighth of them in the cell)
+    ("row_gather_sum_share_live_weighted", _row_gather_sum_live,
+     [((24704, 16, 128), BF16), ((16384, 8), I32), ((16384, 8), F32)], {}, 1),
+    ("row_gather_sum_share_live_plain", _row_gather_sum_live,
+     [((24704, 16, 128), BF16), ((16384, 8), I32)], {}, 1),
     # a token's 8 of the 139264 rows fetched and summed, OLMoE's combine
     ("row_gather_sum_olmoe_weighted", _row_gather_sum,
      [((139264, 16, 128), BF16), ((16384, 8), I32), ((16384, 8), F32)], {}, 1),
@@ -275,6 +293,13 @@ CASES = [
      [((26880, 32, 128), BF16), ((16384, 10), I32), ((16384, 10), F32)],
      {}, 1),
     ("row_gather_sum_granite_plain", _row_gather_sum,
+     [((26880, 32, 128), BF16), ((16384, 10), I32)], {}, 1),
+    # (grid steps of 256 tokens: their indices are no whole SMEM tiles, and
+    # one SMEM block spans two steps)
+    ("row_gather_sum_granite_live_weighted", _row_gather_sum_live,
+     [((26880, 32, 128), BF16), ((16384, 10), I32), ((16384, 10), F32)],
+     {}, 1),
+    ("row_gather_sum_granite_live_plain", _row_gather_sum_live,
      [((26880, 32, 128), BF16), ((16384, 10), I32)], {}, 1),
     ("grouped_matmul_granite_wi_rows_tiled",
      lambda: _grouped_matmul(False, True),

@@ -418,6 +418,8 @@ def test_fit_books_the_scan_cut_and_the_row_moves(monkeypatch, tmp_path):
     assert ssm[4]["layers"] == 2 and ssm[4]["chunk"] == 16
     (moe,) = [e for e in taken if e[0] == "moe" and e[1] == "event"]
     assert moe[4]["drop_fraction"] == 0.0
+    # XLA's gather fetches every one of a token's rows, the zero row too
+    assert moe[4]["row_fetch_share"] == 1.0 > moe[4]["pairs_here"]
 
 
 @pytest.mark.parametrize("model,scan,heads,tiles,rows", [
@@ -428,10 +430,25 @@ def test_fit_books_the_scan_cut_and_the_row_moves(monkeypatch, tmp_path):
     (lambda: granite_moe_hybrid_config(ssm_impl="kernel", ssm_chunk=128),
      "kernel", 8, 16, "kernel"),
     (lambda: granite_moe_hybrid_config(), "xla", None, None, "kernel"),
+    # the cell's cut: 9 of the 72 experts here, so most of a token's ten
+    # pairs have no row here and the kernel is handed those that have
+    (lambda: granite_moe_hybrid_config(
+        ssm_impl="kernel", num_layers=20, experts_held=9, vocab_size=12544,
+    ), "kernel", 8, 16, "kernel_live"),
+    (lambda: TransformerConfig(
+        d_model=2048, num_heads=16, num_experts=256, experts_held=32,
+        top_k=8, moe_dispatch="grouped",
+    ), "none", None, None, "kernel_live"),
+    (lambda: TransformerConfig(
+        d_model=2048, num_heads=16, num_experts=64, top_k=8,
+        moe_dispatch="grouped",
+    ), "none", None, None, "kernel"),
     # Nemotron-3-Nano's rows of 2,688 are no whole native tiles: its
     # row moves are XLA's gather and reduction; a group of 8 heads is one
     # grid step
     (lambda: nemotron_h_config(ssm_impl="kernel"), "kernel", 8, 1, "xla"),
+    (lambda: nemotron_h_config(ssm_impl="kernel", experts_held=16),
+     "kernel", 8, 1, "xla"),
     (lambda: TransformerConfig(), "none", None, None, "none"),
     (lambda: TransformerConfig(num_experts=8, moe_dispatch="einsum"),
      "none", None, None, "none"),

@@ -282,6 +282,15 @@ def test_fit_books_the_share_and_the_mtp_loss_on_the_report_cadence(
     monkeypatch.setenv("DLROVER_TPU_SOCKET_DIR", str(tmp_path / "socks"))
     train_lib.reset_build_cache()
     train_lib.reset_trace_counts()
+    # rows of 64 go through XLA's gather (``row_moves: xla``); the second
+    # case reports as a trainer whose rows fit the live-only kernel does
+    assert ElasticTrainer._row_moves(
+        type("Stub", (), {"model_config": config()})()
+    ) == "xla"
+    if metrics_lag:
+        monkeypatch.setattr(
+            ElasticTrainer, "_row_moves", lambda self: "kernel_live"
+        )
     trainer = ElasticTrainer(
         config(),
         TrainerConfig(
@@ -312,6 +321,11 @@ def test_fit_books_the_share_and_the_mtp_loss_on_the_report_cadence(
             0.001 * (event["step"] - 1), abs=1e-6
         )
         assert event["drop_fraction"] == 0.0
+        # of a token's four row fetches: every one through XLA's gather,
+        # those of the pairs kept here through the live-only kernel
+        assert event["row_fetch_share"] == (
+            event["pairs_here"] if metrics_lag else 1.0
+        )
         assert len(__import__("json").loads(event["load"])) == 16
     for event in mtp:
         assert event["mtp_loss"] == pytest.approx(

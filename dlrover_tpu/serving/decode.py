@@ -546,11 +546,14 @@ class SpecPrograms:
             ).astype(jnp.int32)
             return (mutated["cache"], nxt, pos + 1), nxt
 
+        # One step more than is proposed: it writes the draft's K/V for
+        # pγ, so a fully accepted round leaves no hole for the next to
+        # attend over; its own proposal is discarded.
         (pool, _, _), proposed = jax.lax.scan(
             body, (draft_pool, tokens, positions), None,
-            length=self.spec_tokens,
+            length=self.spec_tokens + 1,
         )
-        return pool, jnp.transpose(proposed)  # [S, γ]
+        return pool, jnp.transpose(proposed[:-1])  # [S, γ]
 
     def _verify_impl(self, params, pool, chunk, positions, rng, temps,
                      topks):

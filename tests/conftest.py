@@ -54,6 +54,18 @@ def tap():
     recorder.configure(enabled=was_enabled)
 
 
+@pytest.fixture(scope="module")
+def one_step_program():
+    """For cases of one module that run the same step program under
+    different trainers: it is traced and compiled by the first of them and
+    the build cache hands it to the rest, so ``trace_count("train_step")``
+    reads 1 after each."""
+    from dlrover_tpu.trainer import train_lib
+
+    train_lib.reset_build_cache()
+    train_lib.reset_trace_counts()
+
+
 @pytest.fixture
 def small_pieces(monkeypatch):
     """The staged save path's sizes, cut so that test-size leaves are

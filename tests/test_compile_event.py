@@ -1,0 +1,102 @@
+"""What the ``compile`` event says of the compiled step's kernels, read off
+``ElasticTrainer``s of the benchmark's tiny presets: the flash backward's
+path and the classes of its blocks, the form the short convolutions took.
+A preset's trainer is built and compiled once, whichever case asks first,
+and its event kept."""
+
+import dataclasses
+import functools
+import os
+
+import jax
+import pytest
+
+from dlrover_tpu.common import telemetry
+from dlrover_tpu.ops import flash_attention
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def preset_model(preset, seq=None):
+    """(the preset's model at ``seq`` tokens or its own, that length)."""
+    from benchmark import build
+
+    cfg = build.load_json(os.path.join(
+        REPO, "tests", "benchmark_suite", "presets", f"{preset}.json"
+    ))
+    seq = seq or cfg["run"]["seq_len"]
+    return build.transformer_config(build.model_group(cfg), seq), seq
+
+
+@functools.cache
+def compile_event(preset, seq=None, vmem_cap=None, xla_attention=False):
+    """The attributes of the ``compile`` event of the preset's trainer,
+    built with the flash kernels' VMEM bound at ``vmem_cap`` where given."""
+    from dlrover_tpu.trainer import train_lib
+    from dlrover_tpu.trainer.elastic_trainer import (
+        ElasticTrainer, TrainerConfig,
+    )
+
+    model, seq = preset_model(preset, seq)
+    if xla_attention:
+        model = dataclasses.replace(model, attention_impl="xla", remat="none")
+    recorder = telemetry.recorder()
+    was_enabled = recorder.enabled
+    recorder.configure(enabled=True)
+    with pytest.MonkeyPatch.context() as patch, recorder.open_tap() as tap:
+        if vmem_cap is not None:
+            patch.setattr(flash_attention, "_VMEM_CAP", vmem_cap)
+        train_lib.reset_build_cache()
+        ElasticTrainer(model, TrainerConfig(
+            global_batch_size=jax.device_count(), seq_len=seq,
+            optimizer="adafactor", warmup_compile=True, ckpt_every=1000,
+        ))
+        (event,) = [e for e in tap.take() if e[0] == "compile"]
+    recorder.configure(enabled=was_enabled)
+    return event[-1]
+
+
+@pytest.mark.parametrize("preset,blocks,vmem_cap,path,classes", [
+    # one kv block: no dq scratch, and one diagonal block a (batch, head)
+    ("gpt2-1.5b", 1, None, "fused", (0, 0, 1)),
+    # several: dq in VMEM scratch; 6 dead, 6 interior, 4 diagonal
+    ("olmo-hybrid-7b", 4, None, "fused", (6, 6, 4)),
+    ("olmo-hybrid-7b", 4, 1 << 16, "split", (6, 6, 4)),   # past the bound
+    ("gpt2-1.5b", 1, 1 << 16, "fused", (0, 0, 1)),
+])
+def test_compile_event_names_the_flash_backward(
+    preset, blocks, vmem_cap, path, classes
+):
+    """The path and the blocks' classes are facts of the compiled step: the
+    ``compile`` event names them, from the functions the dispatch asks
+    (``xla`` attention: ``none``, and no blocks)."""
+    model, seq = preset_model(preset)
+    assert model.attention_impl == "flash"
+    assert seq // min(seq, model.flash_block_kv) == blocks
+
+    def flash_facts(**kw):
+        event = compile_event(preset, vmem_cap=vmem_cap, **kw)
+        return event["flash_backward"], event["flash_blocks"]
+
+    strip = flash_attention.block_classes(
+        seq, seq, model.flash_block_q, model.flash_block_kv, True
+    ).strip
+    assert flash_facts() == (path, dict(zip(
+        ("dead", "interior", "diagonal", "strip"), (*classes, strip)
+    )))
+    if vmem_cap is None:
+        assert flash_facts(xla_attention=True) == ("none", None)
+
+
+@pytest.mark.parametrize("preset,seq,path", [
+    # Nemotron-like: the tiny preset's layers on one whole lane tile of
+    # tokens (x | B | C = 256 | 32 | 32 channels: whole row tiles)
+    ("nemotron-3-nano-30b-a3b", 128, "kernel"),
+    ("nemotron-3-nano-30b-a3b", None, "xla"),      # the preset's 64 tokens
+    ("olmo-hybrid-7b", None, "xla"),
+    ("gpt2-1.5b", None, "none"),
+])
+def test_compile_event_names_the_short_conv(preset, seq, path):
+    """Beside ``test_compile_event_names_the_flash_backward``: which form
+    the step's convolutions took is a fact of the compiled step."""
+    assert compile_event(preset, seq)["short_conv"] == path

@@ -64,8 +64,8 @@ def test_grads_match_xla(rng, hq, hkv):
     def loss_ref(q, k, v):
         return jnp.sum(xla_attention(q, k, v, causal=True) ** 2)
 
-    g_flash = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-    g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    g_flash = jax.jit(jax.grad(loss_flash, argnums=(0, 1, 2)))(q, k, v)
+    g_ref = jax.jit(jax.grad(loss_ref, argnums=(0, 1, 2)))(q, k, v)
     for gf, gr, name in zip(g_flash, g_ref, "qkv"):
         np.testing.assert_allclose(
             gf, gr, atol=5e-4, rtol=5e-4, err_msg=f"d{name}"
@@ -87,7 +87,8 @@ def test_grads_with_segments(rng):
         return jnp.sum(xla_attention(q, k, v, causal=True, segment_ids=seg))
 
     np.testing.assert_allclose(
-        jax.grad(loss_flash)(q), jax.grad(loss_ref)(q), atol=5e-4, rtol=5e-4
+        jax.jit(jax.grad(loss_flash))(q), jax.jit(jax.grad(loss_ref))(q),
+        atol=5e-4, rtol=5e-4,
     )
 
 
@@ -106,7 +107,8 @@ def test_grads_fused_with_segments(rng):
         return jnp.sum(xla_attention(q, k, v, causal=True, segment_ids=seg))
 
     np.testing.assert_allclose(
-        jax.grad(loss_flash)(q), jax.grad(loss_ref)(q), atol=5e-4, rtol=5e-4
+        jax.jit(jax.grad(loss_flash))(q), jax.jit(jax.grad(loss_ref))(q),
+        atol=5e-4, rtol=5e-4,
     )
 
 
@@ -167,9 +169,10 @@ def _assert_grads_match_xla(
             out = attend(q, k, v)
             return jnp.sum(out ** 2), out
 
-        return jax.value_and_grad(
+        # one program a side: op by op, each side compiles a hundred
+        return jax.jit(jax.value_and_grad(
             sum_of_squares, argnums=(0, 1, 2), has_aux=True
-        )
+        ))
 
     ((_, out), g_flash), ((_, ref), g_ref) = (
         loss(attend)(q, k, v) for attend in (

@@ -2,9 +2,11 @@
 reference, on the CPU at a small size with seeded weights, and each new
 mechanism on its own: the flash kernels at ``d_qk != d_v``, the sigmoid
 router and its bias, a chip's share of the experts and its row budget, the
-grouped GEMM's dead blocks, the dense prefix, the MTP module."""
+grouped GEMM's dead blocks, the dense prefix, the MTP module.  What the
+configuration refuses and counts is ``tests/test_joyai_system.py``'s."""
 
 import dataclasses
+import functools
 
 import flax.linen as nn
 import jax
@@ -12,11 +14,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import reference_harness as harness
 from dlrover_tpu.models import moe as moe_lib
 from dlrover_tpu.models.attention import xla_attention
 from dlrover_tpu.models.joyai_llm_flash import joyai_llm_flash_config
 from dlrover_tpu.models.references import joyai_llm_flash as ref
-from dlrover_tpu.models.transformer import TransformerConfig, TransformerLM
+from dlrover_tpu.models.transformer import TransformerLM
 from dlrover_tpu.ops import flash_attention as fa
 from dlrover_tpu.ops import grouped_matmul as gmm
 
@@ -30,38 +33,6 @@ SMALL = dict(
     dtype=jnp.float32, param_dtype=jnp.float32,
 )
 
-
-def config(**overrides):
-    return joyai_llm_flash_config(**{**SMALL, **overrides})
-
-
-def tokens(seed=1, batch=BATCH):
-    rows = jax.random.randint(
-        jax.random.PRNGKey(seed), (batch, SEQ + 1), 0, VOCAB
-    )
-    return rows[:, :-1], rows[:, 1:]
-
-
-def init(cfg, inputs, bias_scale=0.05):
-    """Seeded weights, with router biases that are not zero so that the
-    choice on ``s + b`` differs from the choice on ``s``."""
-    params = nn.meta.unbox(
-        TransformerLM(cfg).init(jax.random.PRNGKey(0), inputs)
-    )["params"]
-
-    def leaf(path, a):
-        if path[-1].key != "router_bias":
-            return a
-        return bias_scale * jax.random.normal(jax.random.PRNGKey(3), a.shape)
-
-    return jax.tree_util.tree_map_with_path(leaf, params)
-
-
-def nll(logits, targets):
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-    return -jnp.take_along_axis(logp, targets[..., None], -1)[..., 0]
-
-
 # -- the whole model against the reference ------------------------------------
 
 # Tolerance: both sides are float32 under matmul precision "highest"; they
@@ -71,6 +42,37 @@ def nll(logits, targets):
 # hundredth of what any left-out term moves (a gate's scale, the shared
 # expert, the rotary key, the MTP term: 1e-2 or more a token).
 TOL = 1e-4
+# b picks, it never weighs: no gradient reaches it
+CHECK = harness.Harness(
+    ref, loss_atol=TOL, grad_atol=TOL, grad_rtol=0.0,
+    no_gradient=("router_bias",), may_be_zero=("ln_",),
+)
+
+
+def config(**overrides):
+    return joyai_llm_flash_config(**{**SMALL, **overrides})
+
+
+def move(name, leaf, draw):
+    """Router biases that are not zero, so that the choice on ``s + b``
+    differs from the choice on ``s``."""
+    if "router_bias" in name:
+        return 0.05 * draw(leaf.shape)
+    return leaf
+
+
+@functools.cache
+def tokens():
+    return harness.tokens(1, BATCH, SEQ, VOCAB)
+
+
+@functools.cache
+def weights(experts_held=4):
+    """Seeded weights of a share of ``experts_held`` experts (0: of all);
+    which share it is changes no shape."""
+    cfg = config(experts_held=experts_held, first_expert=0)
+    return harness.init(cfg, tokens()[0], move=move)
+
 
 CASES = {
     "share": {},
@@ -83,52 +85,27 @@ CASES = {
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_program_matches_the_reference_in_float32(case):
     cfg = config(**CASES[case])
-    model = TransformerLM(cfg)
-    inputs, targets = tokens()
-    params = init(cfg, inputs)
-    fields = dataclasses.asdict(cfg)
-
-    def program_loss(params):
-        logits, aux, mtp = model.apply(
-            {"params": params}, inputs, next_tokens=targets
-        )
-        main, extra = nll(logits, targets), nll(mtp[:, :-1], targets[:, 1:])
-        return main.mean() + aux + cfg.mtp_weight * extra.mean(), (main, extra)
-
-    with jax.default_matmul_precision("highest"):
-        (loss, (main, extra)), grads = jax.value_and_grad(
-            program_loss, has_aux=True
-        )(params)
-    want = ref.forward(fields, params, inputs, targets)
+    params = weights(cfg.experts_held)
+    _, (main, _, extra), _ = CHECK.loss_and_grads(cfg, params, tokens())
+    want = ref.forward(dataclasses.asdict(cfg), params, *tokens())
     np.testing.assert_allclose(main, want["nll"], atol=TOL)
     np.testing.assert_allclose(extra, want["mtp_nll"], atol=TOL)
-    want_loss, want_grads = ref.loss_and_grads(fields, params, inputs, targets)
-    assert float(loss) == pytest.approx(float(want_loss), abs=TOL)
-    flat, _ = jax.tree_util.tree_flatten_with_path(grads)
-    want_flat = jax.tree.leaves(want_grads)
-    assert len(flat) == len(want_flat)
-    for (path, got), want_leaf in zip(flat, want_flat):
-        name = "/".join(k.key for k in path)
-        np.testing.assert_allclose(got, want_leaf, atol=TOL, err_msg=name)
-        if name.endswith("router_bias"):
-            # b picks, it never weighs: no gradient reaches it
-            assert not np.asarray(got).any(), name
-        elif "ln_" not in name:
-            assert np.asarray(got).any(), name
+    CHECK.loss_and_every_gradient_match(cfg, params, tokens())
     # the plain call is the two values it always was
-    plain = model.apply({"params": params}, inputs)
+    plain = jax.jit(
+        lambda p, i: TransformerLM(cfg).apply({"params": p}, i)
+    )(params, tokens()[0])
     assert len(plain) == 2
-    np.testing.assert_allclose(nll(plain[0], targets), main, atol=1e-6)
+    np.testing.assert_allclose(
+        harness.token_nll(plain[0], tokens()[1]), main, atol=1e-6
+    )
 
 
 def test_the_reference_computed_lower_is_another_result():
-    cfg = config()
-    inputs, targets = tokens()
-    params = init(cfg, inputs)
-    fields = dataclasses.asdict(cfg)
-    exact = ref.token_nll(fields, params, inputs, targets)
+    fields = dataclasses.asdict(config())
+    exact = ref.token_nll(fields, weights(), *tokens())
     for lowered, least in (("router", TOL), ("all", 100 * TOL)):
-        other = ref.token_nll(fields, params, inputs, targets, lowered)
+        other = ref.token_nll(fields, weights(), *tokens(), lowered)
         assert float(jnp.abs(other - exact).mean()) > least, lowered
 
 
@@ -157,34 +134,17 @@ def test_the_shares_add_up_to_the_uncut_layer():
         routed_scaling_factor=2.5,
     )
     with jax.default_matmul_precision("highest"):
-        want, _ = ref.expert_layer(fields, n, whole)
         shared = ref.swiglu(n, whole["shared"])
-        got = shared
-        seen = 0.0
-        for first in range(0, total, held):
-            layer = moe_lib.MoEMlp(
-                num_experts=total, d_ff=width, top_k=4, dispatch="grouped",
-                scoring="sigmoid", router_bias=True, routed_scale=2.5,
-                experts_held=held, first_expert=first, shared_d_ff=width,
-                row_budget_multiple=4.0, dtype=jnp.float32, gmm_block_rows=8,
-            )
-            part = dict(
-                whole,
-                **{k: whole[k][first:first + held] for k in ("wi", "wg", "wo")},
-            )
-            (out, _), sown = layer.apply(
-                {"params": part}, n, mutable=["intermediates"]
-            )
-            stats = sown["intermediates"]
-            assert float(moe_lib.split_stats(stats["moe_stats"][0])[1]) == 0.0
-            seen += float(stats[moe_lib.SHARE_STATS_NAME][0][0])
-            # what every chip computes alike is counted once
-            got = got + (out - shared)
-            # and the share's own reference is the same partial sum
-            ours, _ = ref.routed_part(dict(fields, first_expert=first), n, part)
-            np.testing.assert_allclose(out - shared, ours, atol=TOL)
-    np.testing.assert_allclose(got, want, atol=TOL)
-    assert seen == pytest.approx(1.0)
+    harness.shares_add_up(
+        ref, fields, n, whole, held,
+        lambda first: moe_lib.MoEMlp(
+            num_experts=total, d_ff=width, top_k=4, dispatch="grouped",
+            scoring="sigmoid", router_bias=True, routed_scale=2.5,
+            experts_held=held, first_expert=first, shared_d_ff=width,
+            row_budget_multiple=4.0, dtype=jnp.float32, gmm_block_rows=8,
+        ),
+        shared, TOL,
+    )
 
 
 # -- the flash kernels at d_qk != d_v ----------------------------------------
@@ -288,7 +248,9 @@ def test_a_pair_past_the_row_budget_is_dropped_and_counted():
     d, total, held = 32, 8, 2
     n = jax.random.normal(jax.random.PRNGKey(0), (2, 32, d))
     layer = expert_layer(held, 1.0)
-    params = nn.meta.unbox(layer.init(jax.random.PRNGKey(1), n))["params"]
+    params = nn.meta.unbox(jax.jit(layer.init)(jax.random.PRNGKey(1), n))[
+        "params"
+    ]
     kernel = jnp.zeros((d, total)).at[:, :held].set(
         jnp.abs(params["router"]["kernel"][:, :held]) + 1.0
     )
@@ -297,9 +259,9 @@ def test_a_pair_past_the_row_budget_is_dropped_and_counted():
     pairs = 2 * 32 * 2
     results = {}
     for multiple in (1.0, 4.0):
-        (out, _), sown = expert_layer(held, multiple).apply(
-            {"params": params}, n, mutable=["intermediates"]
-        )
+        (out, _), sown = jax.jit(lambda p, n: expert_layer(
+            held, multiple
+        ).apply({"params": p}, n, mutable=["intermediates"]))(params, n)
         stats = sown["intermediates"]
         _, drop, load, pad, _ = moe_lib.split_stats(stats["moe_stats"][0])
         here = float(stats[moe_lib.SHARE_STATS_NAME][0][0])
@@ -373,84 +335,25 @@ def test_dead_row_blocks_cost_no_matmul_and_come_out_zero(skip_dead):
     assert live.tolist() == [3] and eob.tolist() == [0, 0, 2, 2, 2, 2]
 
 
-# -- the configuration's checks and counts ------------------------------------
-
-
-@pytest.mark.parametrize("overrides,message", [
-    (dict(experts_held=5), "must divide num_experts"),
-    (dict(first_expert=2), "multiple of it"),
-    (dict(moe_dispatch="einsum"), "dispatch='grouped' only"),
-    (dict(experts_held=0, first_expert=0, moe_dispatch="einsum"),
-     "routed by moe_dispatch='grouped' only"),
-    (dict(decode=True), "trains only"),
-    (dict(v_head_dim=0), "together"),
-    (dict(position="learned"), "position='rope'"),
-    (dict(first_k_dense=3), "must leave a trunk"),
-    (dict(num_experts=0, experts_held=0, first_expert=0, router_bias=False,
-          router_scoring="softmax", moe_dispatch="einsum"),
-     "describe an expert layer"),
-    (dict(layer_pattern=("full_attention",), num_layers=3),
-     "takes no layer_pattern"),
-    (dict(mtp_depth=2), "mtp_depth must be 0 or 1"),
-    (dict(router_scoring="softmax"), "router_bias corrects a sigmoid"),
-    (dict(pipeline_stages=3, first_k_dense=1, num_layers=5),
-     "does not divide"),
-])
-def test_bad_combinations_of_the_new_fields_raise(overrides, message):
-    with pytest.raises(ValueError, match=message):
-        config(**overrides)
-
-
-def test_num_params_counts_what_is_held():
-    cfg = config()
-    inputs, _ = tokens()
-    params = init(cfg, inputs)
-    held = sum(leaf.size for leaf in jax.tree.leaves(params))
-    norms = sum(
-        leaf.size for path, leaf in jax.tree_util.tree_leaves_with_path(params)
-        if path[-2].key in ("ln_attn", "ln_mlp", "ln_final")
-    )
-    # the layer norms are the approximation num_params() always made
-    assert cfg.num_params() == held - norms
-    whole = config(experts_held=0, first_expert=0)
-    one_expert = 3 * 64 * 32
-    assert whole.num_params() - cfg.num_params() == 3 * 12 * one_expert
-    # the published model, every expert held: 48.9 B and the MTP module
-    published = joyai_llm_flash_config()
-    assert published.num_params() == pytest.approx(50.16e9, rel=2e-3)
-    assert joyai_llm_flash_config(mtp_depth=0).num_params() == pytest.approx(
-        48.94e9, rel=2e-3
-    )
-
-
-def test_latent_attention_names_its_scopes_and_shares_one_rotary_key():
-    cfg = config(num_layers=2, mtp_depth=0)
-    inputs, _ = tokens()
-    params = init(cfg, inputs)
-    text = jax.jit(
-        lambda p, t: TransformerLM(cfg).apply({"params": p}, t)[0]
-    ).lower(params, inputs).as_text(debug_info=True)
-    for scope in ("attn/q_a", "attn/q_b", "attn/kv_a", "attn/kv_b",
-                  "attn/rope", "attn/wo", "moe/shared", "moe/router"):
-        assert scope in text, scope
-    kv_a = params["dense_0"]["attn"]["kv_a"]["kernel"]
-    assert kv_a.shape == (64, 32 + 8)    # one 8-wide rotary key, not 4
-
-
 def test_trunk_without_the_scan_has_the_same_losses():
     cfg = config()
     inputs, targets = tokens()
-    params = init(cfg, inputs)
+    params = weights()
     listed = {k: v for k, v in params.items() if k != "blocks"}
     for i in range(2):
         listed[f"block_{i + 1}"] = jax.tree.map(
             lambda a: a[i], params["blocks"]
         )
     loose = dataclasses.replace(cfg, scan_layers=False)
-    got = TransformerLM(loose).apply({"params": listed}, inputs)[0]
-    want = TransformerLM(cfg).apply({"params": params}, inputs)[0]
-    np.testing.assert_allclose(got, want, atol=1e-5)
+
+    def logits(cfg, params):
+        return jax.jit(
+            lambda p, i: TransformerLM(cfg).apply({"params": p}, i)[0]
+        )(params, inputs)
+
+    want = logits(cfg, params)
+    np.testing.assert_allclose(logits(loose, listed), want, atol=1e-5)
     np.testing.assert_allclose(
         ref.token_nll(dataclasses.asdict(loose), listed, inputs, targets),
-        nll(want, targets), atol=TOL,
+        harness.token_nll(want, targets), atol=TOL,
     )

@@ -1,17 +1,21 @@
 """JoyAI-LLM-Flash's parts through the rest of the system, one small CPU
 test each: the train step's loss, metrics and router-bias rule against the
-reference, the microbatch engine, a Flash Checkpoint save and restore of
-the bias, the dense prefix under a two-stage pipeline, the ``moe`` and
-``mtp`` events with their gauges."""
+reference, the microbatch engine, the ``moe`` and
+``mtp`` events; the parameter count and the latent attention's scopes (at
+the size of ``tests/test_joyai_reference.py``: ``numerics``).  (What the
+configuration refuses and the master's gauges are
+``tests/test_joyai_config.py``'s; the bias under the optimizers and a
+checkpoint, and the pipeline's dense prefix ``tests/test_joyai_state.py``'s.)"""
 
 import dataclasses
-import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import reference_harness as harness
+import test_joyai_reference as numerics
 from dlrover_tpu.models import moe as moe_lib
 from dlrover_tpu.models.joyai_llm_flash import joyai_llm_flash_config
 from dlrover_tpu.models.references import joyai_llm_flash as ref
@@ -117,24 +121,6 @@ def test_the_step_trains_the_sum_reports_the_parts_and_moves_the_bias():
     assert drop == 0.0 and load.shape == (16,)
 
 
-@pytest.mark.parametrize("optimizer", ["adafactor", "adamw"])
-def test_no_optimizer_moves_the_bias_only_the_rule_does(optimizer):
-    train = build(optimizer=optimizer)
-    state = train.init(jax.random.PRNGKey(0))
-    for i, batch in enumerate(batches(3), start=1):
-        state, metrics = train.step(
-            state, train_lib.shard_batch(batch, train)
-        )
-        for name, bias in biases(state.params).items():
-            # every entry has moved by whole steps of the rate, each way
-            steps = bias / 0.001
-            np.testing.assert_allclose(steps, np.rint(steps), atol=1e-3)
-            assert np.abs(steps).max() <= i + 1e-3, name
-        assert float(np.asarray(metrics[moe_lib.SHARE_STATS_NAME])[1]) == (
-            pytest.approx(0.001 * (i - 1), abs=1e-6)
-        )
-
-
 def test_the_microbatch_engine_trains_the_same_step():
     one, two = build(), build(grad_accum=2)
     batch = batches(1)[0]
@@ -159,118 +145,9 @@ def test_the_microbatch_engine_trains_the_same_step():
         )
 
 
-def digest(state):
-    from dlrover_tpu.trainer import state_digest
-
-    return int(state_digest._digest_tree(state))
-
-
-@pytest.mark.skipif(len(jax.devices()) < 2, reason="needs two host devices")
-def test_a_flash_checkpoint_keeps_the_router_bias(small_pieces):
-    """``b`` is train state no gradient moves: saved through the staged
-    path with the rest of it and restored from the arena alone."""
-    from dlrover_tpu.checkpoint import engine as ckpt_engine
-    from dlrover_tpu.checkpoint.shm_handler import (
-        SharedMemoryHandler,
-        assemble_tensor,
-    )
-
-    train = build(
-        devices=2, parallel=ParallelConfig(data=1, fsdp=2),
-        optimizer="adafactor",
-    )
-    state = train.init(jax.random.PRNGKey(0))
-    for batch in batches(3):
-        state, _ = train.step(state, train_lib.shard_batch(batch, train))
-    saved, saved_bias = digest(state), biases(state.params)
-    assert all(np.abs(b).max() > 0 for b in saved_bias.values())
-    name = f"joyai{os.getpid()}"
-    writer = SharedMemoryHandler(name)
-    try:
-        writer.save_state_dict(state, step=3)
-        writer.close()                       # the process is gone
-        reader = SharedMemoryHandler(name)
-        meta = reader.load_meta()
-        assert meta.step == 3
-        assert [t.path for t in meta.tensors if "router_bias" in str(t.path)]
-        arrays = {
-            t.path: assemble_tensor(t, lambda r: reader.load_block(meta, r))
-            for t in meta.tensors
-        }
-        restored = ckpt_engine.materialize_records(
-            arrays, meta, train.state_shardings,
-            jax.tree_util.tree_structure(state),
-        )
-        assert digest(restored) == saved
-        for key, bias in biases(restored.params).items():
-            np.testing.assert_array_equal(bias, saved_bias[key])
-        batch = train_lib.shard_batch(batches(4)[3], train)
-        _, a = train.step(restored, batch)
-        assert np.isfinite(float(a["loss"]))
-    finally:
-        SharedMemoryHandler(name).close(unlink=True)
-
-
-@pytest.mark.skipif(len(jax.devices()) < 4, reason="needs four host devices")
-def test_the_dense_layer_runs_ahead_of_a_two_stage_pipeline():
-    """A dense trunk with latent attention and one leading layer outside
-    the stack: two pipeline stages over a real ``pipe`` axis give the loss
-    the plain scan gives on the same weights."""
-    dense = dict(
-        num_experts=0, experts_held=0, first_expert=0, router_bias=False,
-        router_scoring="softmax", num_shared_experts=0, moe_dispatch="einsum",
-        mtp_depth=0, num_layers=5, first_k_dense=1,
-    )
-    tokens = batches(1)[0]
-    losses, params1 = {}, None
-    for pp in (1, 2):
-        cfg = config(
-            pipeline_stages=pp, num_microbatches=2 if pp > 1 else 0, **dense
-        )
-        assert cfg.num_scan_units == 4
-        train = build(
-            cfg, devices=2 * pp,
-            parallel=ParallelConfig(data=2, pipe=pp), optimizer="sgd",
-        )
-        state = train.init(jax.random.PRNGKey(0))
-        if pp == 1:
-            params1 = jax.tree.map(np.asarray, state.params)
-        else:
-            # the first stage's input is the dense layer's output: the
-            # layer lives outside the stage-stacked weights, whole
-            assert set(state.params) == set(params1)
-            stacked = jax.tree.map(
-                lambda leaf: leaf.reshape(2, 2, *leaf.shape[1:]),
-                params1["blocks"],
-            )
-            piped = dict(
-                params1, blocks={"ticks": {"stages": {"layers": stacked}}}
-            )
-            assert jax.tree.structure(piped) == jax.tree.structure(
-                jax.tree.map(np.asarray, state.params)
-            )
-            state = state.replace(params=jax.device_put(
-                piped, train.state_shardings.params
-            ))
-            spec = state.params["blocks"]["ticks"]["stages"]["layers"][
-                "attn"
-            ]["q_b"]["kernel"].sharding.spec
-            assert spec[0] == "pipe", spec
-            assert "pipe" not in str(
-                state.params["dense_0"]["attn"]["q_b"]["kernel"].sharding.spec
-            )
-        _, metrics = train.step(state, train_lib.shard_batch(tokens, train))
-        losses[pp] = float(metrics["loss"])
-    assert losses[2] == pytest.approx(losses[1], rel=1e-4)
-    with pytest.raises(NotImplementedError, match="num_experts=0"):
-        TransformerLM(config(pipeline_stages=2, num_layers=5)).init(
-            jax.random.PRNGKey(0), jnp.zeros((2, SEQ), jnp.int32)
-        )
-
-
 @pytest.mark.parametrize("metrics_lag", [0, 4])
 def test_fit_books_the_share_and_the_mtp_loss_on_the_report_cadence(
-    metrics_lag, monkeypatch, tmp_path
+    metrics_lag, monkeypatch, tmp_path, one_step_program
 ):
     from dlrover_tpu.common import telemetry
     from dlrover_tpu.trainer.elastic_trainer import (
@@ -280,8 +157,6 @@ def test_fit_books_the_share_and_the_mtp_loss_on_the_report_cadence(
 
     monkeypatch.setenv("DLROVER_TPU_JOB", f"joy_{tmp_path.name}")
     monkeypatch.setenv("DLROVER_TPU_SOCKET_DIR", str(tmp_path / "socks"))
-    train_lib.reset_build_cache()
-    train_lib.reset_trace_counts()
     # rows of 64 go through XLA's gather (``row_moves: xla``); the second
     # case reports as a trainer whose rows fit the live-only kernel does
     assert ElasticTrainer._row_moves(
@@ -336,39 +211,36 @@ def test_fit_books_the_share_and_the_mtp_loss_on_the_report_cadence(
     assert train_lib.trace_count("train_step") == 1
 
 
-def test_the_master_renders_the_share_the_bias_and_the_mtp_loss_as_gauges():
-    from dlrover_tpu.master.speed_monitor import SpeedMonitor
-    from dlrover_tpu.master.timeline import JobTimeline
+def test_num_params_counts_what_is_held():
+    cfg = config()
+    params = numerics.weights()
+    held = sum(leaf.size for leaf in jax.tree.leaves(params))
+    norms = sum(
+        leaf.size for path, leaf in jax.tree_util.tree_leaves_with_path(params)
+        if path[-2].key in ("ln_attn", "ln_mlp", "ln_final")
+    )
+    # the layer norms are the approximation num_params() always made
+    assert cfg.num_params() == held - norms
+    whole = config(experts_held=0, first_expert=0)
+    one_expert = 3 * 64 * 32
+    assert whole.num_params() - cfg.num_params() == 3 * 12 * one_expert
+    # the published model, every expert held: 48.9 B and the MTP module
+    published = joyai_llm_flash_config()
+    assert published.num_params() == pytest.approx(50.16e9, rel=2e-3)
+    assert joyai_llm_flash_config(mtp_depth=0).num_params() == pytest.approx(
+        48.94e9, rel=2e-3
+    )
 
-    monitor = SpeedMonitor()
-    common = dict(entropy=5.5, drop_fraction=0.0, experts=256, top_k=8,
-                  load="[]", pad_share=0.1, max_expert_load=1.2)
-    monitor.record_moe(0, step=5, held=32, pairs_here=0.124,
-                       bias_absmax=0.004, later_attr="ignored", **common)
-    monitor.record_moe(1, step=5, held=32, pairs_here=0.126,
-                       bias_absmax=0.006, **common)
-    monitor.record_mtp(0, step=5, mtp_loss=10.0, weight=0.3)
-    monitor.record_mtp(1, step=5, mtp_loss=10.5)
-    ledger = monitor.moe_ledger()
-    assert ledger["held"] == 32 and ledger["experts"] == 256
-    assert ledger["pairs_here"] == pytest.approx(0.125)
-    assert ledger["bias_absmax"] == 0.006         # the largest replica's
-    assert monitor.mtp_loss() == pytest.approx(10.25)
-    text = JobTimeline().render_metrics(speed_monitor=monitor)
-    for name, value in (
-        ("dlrover_moe_experts_held", "32"),
-        ("dlrover_moe_pairs_here", "0.125"),
-        ("dlrover_moe_router_bias_absmax", "0.006"),
-        ("dlrover_mtp_loss", "10.25"),
-    ):
-        assert f"# TYPE {name} gauge" in text
-        assert any(
-            line.startswith(name + " ") and line.split()[1].startswith(value)
-            for line in text.splitlines()
-        ), name
-    # an older trainer's event (no share told) reads as every expert held
-    older = SpeedMonitor()
-    older.record_moe(0, step=1, **common)
-    assert older.moe_ledger()["held"] == 256
-    assert older.moe_ledger()["pairs_here"] == 1.0
-    assert older.mtp_loss() == 0.0
+
+def test_latent_attention_names_its_scopes_and_shares_one_rotary_key():
+    cfg = config(num_layers=2, mtp_depth=0)
+    inputs, _ = numerics.tokens()
+    params = harness.init(cfg, inputs)
+    text = jax.jit(
+        lambda p, t: TransformerLM(cfg).apply({"params": p}, t)[0]
+    ).lower(params, inputs).as_text(debug_info=True)
+    for scope in ("attn/q_a", "attn/q_b", "attn/kv_a", "attn/kv_b",
+                  "attn/rope", "attn/wo", "moe/shared", "moe/router"):
+        assert scope in text, scope
+    kv_a = params["dense_0"]["attn"]["kv_a"]["kernel"]
+    assert kv_a.shape == (64, 32 + 8)    # one 8-wide rotary key, not 4

@@ -532,12 +532,16 @@ def test_a_layer_whose_rows_are_whole_tiles_runs_them_row_tiled(
         out, aux = layer.apply(p, x)
         return jnp.sum(out ** 2) + aux
 
-    tiled = jax.value_and_grad(loss, argnums=(0, 1))(params, x)
+    def value_and_grads():
+        # traced anew each time: what is patched above and below is seen
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1)))(params, x)
+
+    tiled = value_and_grads()
     # combine's forward and the transpose of rows-of-tokens
     n_pad = moe._share_row_budget(48, 8, 2, 4, 2.0) if held else 80
     assert forms == [((n_pad, 8, 128), bool(held))] * 2
     monkeypatch.setattr(row_gather_sum, "kernel_fits", lambda *a: False)
-    plain = jax.value_and_grad(loss, argnums=(0, 1))(params, x)
+    plain = value_and_grads()
     assert len(forms) == 2
     # Holding all, the old case, at the old tolerance.  Holding half, the
     # router kernel's gradient ``[1024, 4]`` (entries up to 171, each a sum

@@ -9,6 +9,7 @@ EP>1 guard, the router statistics a step hands out, cache-key coverage of the Mo
 and the zero-retrace steady state.
 """
 
+import functools
 import json
 
 import flax.linen as nn
@@ -236,9 +237,9 @@ ONE_CHIP = ParallelConfig(data=-1)
 def _sown_stats(model, params, tokens):
     """The layers' ``moe_stats`` vectors of one plain forward, reduced as
     the step reduces them: the mean over layers and stacking axes."""
-    _, sown = model.apply(
-        {"params": params}, tokens, mutable=["intermediates"]
-    )
+    _, sown = jax.jit(lambda p, t: model.apply(
+        {"params": p}, t, mutable=["intermediates"]
+    ))(params, tokens)
     leaves = jax.tree_util.tree_leaves(sown)
     stacked = jnp.concatenate(
         [leaf.reshape(-1, leaf.shape[-1]) for leaf in leaves], axis=0
@@ -246,7 +247,11 @@ def _sown_stats(model, params, tokens):
     return np.asarray(jnp.mean(stacked, axis=0), np.float64)
 
 
+@functools.cache
 def _build(config, parallel=ONE_CHIP, **build_kw):
+    """(model, train, its initial state), built once a configuration: two
+    cases ask for the plain einsum and grouped steps each, and the state is
+    not donated."""
     model = TransformerLM(config)
     train = train_lib.build_sharded_train(
         model, train_lib.make_optimizer("sgd", learning_rate=1e-2),
@@ -372,7 +377,7 @@ MOE_EVENT_ATTRS = {
 
 @pytest.mark.parametrize("metrics_lag", [0, 4])
 def test_fit_books_one_moe_event_per_report_from_the_step_itself(
-    metrics_lag, monkeypatch, tmp_path
+    metrics_lag, monkeypatch, tmp_path, one_step_program
 ):
     """Ten steps at ``report_every=5``: exactly two ``moe`` events, of
     steps 5 and 10, every attribute present, one trace of the step
@@ -386,8 +391,6 @@ def test_fit_books_one_moe_event_per_report_from_the_step_itself(
     monkeypatch.setenv("DLROVER_TPU_JOB", f"moe_{tmp_path.name}")
     monkeypatch.setenv("DLROVER_TPU_SOCKET_DIR", str(tmp_path / "socks"))
     config = _moe_config("grouped")
-    train_lib.reset_build_cache()
-    train_lib.reset_trace_counts()
     trainer = ElasticTrainer(
         config,
         TrainerConfig(

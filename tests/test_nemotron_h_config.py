@@ -1,0 +1,132 @@
+"""Nemotron-H without a model built: what the configuration refuses and
+counts, which scan a trainer names, and what the master does with the
+``ssm`` event.  (Many cases and no compile: a file is one worker's, and the
+driver's workers take the files with the most cases first.)"""
+
+import numpy as np
+import pytest
+
+from dlrover_tpu.models import nemotron_h
+from dlrover_tpu.models.nemotron_h import nemotron_h_config
+from dlrover_tpu.models.transformer import (
+    ATTENTION, EXPERTS, TransformerConfig,
+)
+from test_nemotron_h_reference import config
+
+
+def test_decode_with_an_ssm_layer_raises_naming_what_is_missing():
+    with pytest.raises(ValueError, match=r"recurrent state \[H, P, N\]"):
+        config(decode=True)
+    with pytest.raises(ValueError, match="serving/decode.py"):
+        config(decode=True)
+
+
+@pytest.mark.parametrize("overrides,message", [
+    (dict(ssm_num_heads=0), "an ssm layer needs"),
+    (dict(ssm_groups=3), "ssm_groups dividing the heads"),
+    (dict(ssm_impl="pallas"), "ssm_impl must be one of"),
+    (dict(ssm_impl="kernel", ssm_head_dim=48), "side by side"),
+    (dict(num_experts=0, router_scoring="softmax", router_bias=False,
+          num_shared_experts=0, moe_dispatch="einsum"),
+     "an 'experts' layer needs num_experts"),
+    (dict(layer_pattern=("ssm", "mamba")), "layer_pattern kinds"),
+    (dict(num_layers=10), "no whole number of periods"),
+    (dict(mtp_depth=1), "mtp_depth with a layer_pattern"),
+    (dict(first_k_dense=1), "first_k_dense"),
+    (dict(position="alibi"), "position must be"),
+    (dict(activation="relu"), "activation must be"),
+])
+def test_bad_combinations_of_the_new_fields_raise(overrides, message):
+    with pytest.raises(ValueError, match=message):
+        config(**overrides)
+
+
+def test_the_published_widths_count_what_the_issue_counts():
+    """ISSUE 37's arithmetic, from the program's own shapes (a layer's own
+    norm and the final one are left out of ``num_params``, as ever)."""
+    cfg = nemotron_h_config(
+        num_layers=18, experts_held=16, vocab_size=16384
+    )
+    assert cfg._ssm_mixer_params() == 38_744_896 - 2688
+    assert cfg.num_ssm_layers == 8
+    assert cfg.num_layers_of(EXPERTS) == 8
+    assert cfg.num_layers_of(ATTENTION) == 2
+    attn = 2 * 2688 * 4096 + 2 * 2688 * 256
+    assert attn == 23_399_040 - 2688
+    experts = 16 * 2 * 2688 * 1856 + 2 * 2688 * 3712 + 2688 * 128 + 128
+    assert experts == 179_948_288 - 2688
+    assert cfg.num_params() == (
+        8 * (38_744_896 - 2688) + 2 * attn + 8 * experts + 2 * 16384 * 2688
+    )
+    assert cfg.num_params() == 1_884_426_624 - 19 * 2688
+    whole = nemotron_h_config(
+        num_layers=52, layer_pattern=nemotron_h.kinds(
+            nemotron_h.PUBLISHED_PATTERN
+        ),
+    )
+    assert 31.5e9 < whole.num_params() < 31.7e9
+    letters = nemotron_h.PUBLISHED_PATTERN
+    assert (letters.count("M"), letters.count("E"), letters.count("*")) == (
+        23, 23, 6
+    )
+    # the run taken: published layers 34-42, the only whole run at 4 : 4 : 1
+    assert letters[34:43] == nemotron_h.PERIOD
+    runs = [len(run) + 1 for run in letters.split("*")[:-1]]
+    # ... and the last nine layers close with an expert layer, not an ``*``
+    assert runs == [6, 7, 7, 7, 7, 9] and letters.split("*")[-1] == "EMEMEMEME"
+
+
+def test_a_model_without_such_a_layer_names_no_scan():
+    from dlrover_tpu.trainer.elastic_trainer import ElasticTrainer
+
+    stub = type("T", (), {"model_config": TransformerConfig()})()
+    assert ElasticTrainer._ssm_scan(stub) == "none"
+    stub.model_config = config()
+    assert ElasticTrainer._ssm_scan(stub) == "xla"
+
+
+def test_the_master_renders_the_events_as_gauges():
+    from dlrover_tpu.master.speed_monitor import SpeedMonitor
+    from dlrover_tpu.master.timeline import JobTimeline
+
+    monitor = SpeedMonitor()
+    monitor.record_ssm(
+        0, step=5, layers=8, chunk=128, mean_decay=0.8, mean_dt=0.02,
+        state_absmax=2.5, later_attr="ignored",
+    )
+    monitor.record_ssm(
+        1, step=5, layers=8, chunk=128, mean_decay=0.6, mean_dt=0.04,
+        state_absmax=7.5,
+    )
+    ledger = monitor.ssm_ledger()
+    assert ledger["reporters"] == 2 and ledger["layers"] == 8
+    assert ledger["mean_decay"] == pytest.approx(0.7)
+    assert ledger["state_absmax"] == 7.5          # the worst replica's
+    text = JobTimeline().render_metrics(speed_monitor=monitor)
+    for name, value in (
+        ("dlrover_ssm_layers", "8"),
+        ("dlrover_ssm_chunk", "128"),
+        ("dlrover_ssm_mean_decay", "0.7"),
+        ("dlrover_ssm_mean_dt", "0.03"),
+        ("dlrover_ssm_state_absmax", "7.5"),
+        ("dlrover_ssm_reporters", "2"),
+    ):
+        assert f"# TYPE {name} gauge" in text
+        assert any(
+            line.startswith(name + " ") and line.split()[1].startswith(value)
+            for line in text.splitlines()
+        ), name
+    # a state that diverged on one replica shows as such, and the linear
+    # layers' ledger is its own
+    monitor.record_ssm(1, step=10, state_absmax=float("nan"))
+    assert np.isnan(monitor.ssm_ledger()["state_absmax"])
+    assert monitor.linear_attn_ledger()["reporters"] == 0
+
+
+def test_the_servicer_routes_the_event_to_the_ledger():
+    import inspect
+
+    from dlrover_tpu.master import servicer
+
+    source = inspect.getsource(servicer)
+    assert 'name == "ssm"' in source and "record_ssm(node, **attrs)" in source

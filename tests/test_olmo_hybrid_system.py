@@ -1,8 +1,12 @@
 """The layer pattern through the rest of the system, one small CPU test
 each: ZeRO-1 on four host devices, a Flash Checkpoint save and restore of
-the patterned tree, a live relayout 4 -> 2, and the ``linear_attn`` event
-with its gauges."""
+the patterned tree, a live relayout 4 -> 2, the ``linear_attn`` event;
+and, at the size of ``tests/test_olmo_hybrid_reference.py`` (``numerics``),
+the train step's first loss.  (What the configuration refuses and the
+master's gauges are ``tests/test_olmo_hybrid_config.py``'s; the earlier
+models' pinned steps ``tests/test_lowered_steps.py``'s.)"""
 
+import functools
 import os
 
 import jax
@@ -16,6 +20,8 @@ from dlrover_tpu.models.transformer import TransformerLM
 from dlrover_tpu.parallel import rules as lr
 from dlrover_tpu.runtime.mesh import ParallelConfig, build_mesh
 from dlrover_tpu.trainer import train_lib
+import test_olmo_hybrid_reference as numerics
+from test_olmo_hybrid_reference import params, tokens  # noqa: F401
 
 pytestmark = pytest.mark.skipif(
     len(jax.devices()) < 4, reason="needs four host devices"
@@ -41,11 +47,19 @@ def batches(n, seed=0):
 
 
 def build(devices, parallel, **kw):
+    """Built once for all the cases that ask for the same one (three ask
+    for the ZeRO-1 step on four devices): the state is each case's own."""
+    return _built(devices, parallel, tuple(sorted(kw.items())))
+
+
+@functools.cache
+def _built(devices, parallel, kw):
     mesh = build_mesh(parallel, devices=jax.devices()[:devices])
     return train_lib.build_sharded_train(
         TransformerLM(config()),
         train_lib.make_optimizer("adafactor", learning_rate=1e-2),
-        mesh, lr.DEFAULT_RULES, global_batch_size=BATCH, seq_len=SEQ, **kw,
+        mesh, lr.DEFAULT_RULES, global_batch_size=BATCH, seq_len=SEQ,
+        **dict(kw),
     )
 
 
@@ -149,7 +163,7 @@ def test_relayout_state_four_to_two_keeps_every_leaf():
 
 @pytest.mark.parametrize("metrics_lag", [0, 4])
 def test_fit_books_one_linear_attn_event_per_report_from_the_step_itself(
-    metrics_lag, monkeypatch, tmp_path
+    metrics_lag, monkeypatch, tmp_path, one_step_program
 ):
     """Ten steps at ``report_every=5``: exactly two ``linear_attn`` events,
     of steps 5 and 10, carrying the step's own numbers; one trace of the
@@ -162,8 +176,6 @@ def test_fit_books_one_linear_attn_event_per_report_from_the_step_itself(
 
     monkeypatch.setenv("DLROVER_TPU_JOB", f"la_{tmp_path.name}")
     monkeypatch.setenv("DLROVER_TPU_SOCKET_DIR", str(tmp_path / "socks"))
-    train_lib.reset_build_cache()
-    train_lib.reset_trace_counts()
     trainer = ElasticTrainer(
         config(),
         TrainerConfig(
@@ -201,48 +213,45 @@ def test_fit_books_one_linear_attn_event_per_report_from_the_step_itself(
     assert train_lib.trace_count("train_step") == 1
 
 
-def test_the_master_renders_the_events_as_gauges():
-    from dlrover_tpu.master.speed_monitor import SpeedMonitor
-    from dlrover_tpu.master.timeline import JobTimeline
+# -- at the reference's size --------------------------------------------------
 
-    monitor = SpeedMonitor()
-    monitor.record_linear_attn(
-        0, step=5, layers=6, chunk=64, mean_alpha=0.8, mean_beta=1.0,
-        state_absmax=2.5, later_attr="ignored",
+
+@pytest.mark.parametrize("parallel,devices", [
+    (dict(data=1), 1), (dict(data=2, tensor=2), 4),
+])
+def test_the_train_step_s_first_loss_is_the_reference_s(
+    parallel, devices, params, tokens
+):
+    """The normal path: ``build_sharded_train``'s compiled step, under the
+    policy the cell runs (the rule's and the flash kernels' outputs kept);
+    on one device, and with the batch over ``data`` and the heads over
+    ``tensor``, where each device's kernels see its own rows and heads."""
+    from dlrover_tpu.models import linear_attention
+    from dlrover_tpu.parallel import rules as lr
+    from dlrover_tpu.runtime.mesh import ParallelConfig, build_mesh
+    from dlrover_tpu.trainer import train_lib
+
+    cfg = numerics.config(attention_impl="flash", remat="flash_only")
+    train = train_lib.build_sharded_train(
+        TransformerLM(cfg),
+        train_lib.make_optimizer("adafactor", learning_rate=1e-3),
+        build_mesh(
+            ParallelConfig(**parallel), devices=jax.devices()[:devices]
+        ),
+        lr.DEFAULT_RULES, global_batch_size=numerics.BATCH,
+        seq_len=numerics.SEQ,
     )
-    monitor.record_linear_attn(
-        1, step=5, layers=6, chunk=64, mean_alpha=0.6, mean_beta=1.2,
-        state_absmax=7.5,
+    state = train.init(jax.random.PRNGKey(0))
+    state = state.replace(params=jax.tree.map(
+        lambda new, old: jax.device_put(
+            jnp.array(new, old.dtype, copy=True), old.sharding
+        ), params, state.params,
+    ))
+    batch = {"inputs": np.asarray(tokens[0]), "targets": np.asarray(tokens[1])}
+    _, metrics = train.step(state, train_lib.shard_batch(batch, train))
+    want = numerics.reference.token_nll(cfg, params, *tokens).mean()
+    assert abs(float(metrics["loss"]) - float(want)) <= numerics.LOSS_ATOL
+    alpha, beta, absmax = linear_attention.split_stats(
+        np.asarray(metrics[linear_attention.STATS_NAME])
     )
-    ledger = monitor.linear_attn_ledger()
-    assert ledger["reporters"] == 2 and ledger["layers"] == 6
-    assert ledger["mean_alpha"] == pytest.approx(0.7)
-    assert ledger["state_absmax"] == 7.5          # the worst replica's
-    text = JobTimeline().render_metrics(speed_monitor=monitor)
-    for name, value in (
-        ("dlrover_linear_attn_layers", "6"),
-        ("dlrover_linear_attn_chunk", "64"),
-        ("dlrover_linear_attn_mean_beta", "1.1"),
-        ("dlrover_linear_attn_state_absmax", "7.5"),
-        ("dlrover_linear_attn_reporters", "2"),
-    ):
-        assert f"# TYPE {name} gauge" in text
-        assert any(
-            line.startswith(name + " ") and line.split()[1].startswith(value)
-            for line in text.splitlines()
-        ), name
-    # a state that diverged on one replica shows as such
-    monitor.record_linear_attn(1, step=10, state_absmax=float("nan"))
-    assert np.isnan(monitor.linear_attn_ledger()["state_absmax"])
-
-
-def test_a_state_that_is_not_finite_is_the_anomaly_a_loss_would_be():
-    from dlrover_tpu.trainer.numeric_health import NumericHealthMonitor
-
-    monitor = NumericHealthMonitor()
-    assert monitor.check(1, 5.0, 1.0, state_absmax=3.0) == []
-    (found,) = monitor.check(2, 5.0, 1.0, state_absmax=float("inf"))
-    assert found.kind == "nan" and "state_absmax=inf" in found.detail
-    # a poisoned reading stays out of the rolling statistics
-    assert len(monitor._losses) == 1
-    assert monitor.check(3, 5.0, 1.0) == []
+    assert 0 < alpha < 1 and 0 < beta < 2 and 0 < absmax < 100

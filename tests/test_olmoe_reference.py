@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import reference_harness as harness
 from dlrover_tpu.models import moe as moe_lib
 from dlrover_tpu.models.olmoe import olmoe_config
 from dlrover_tpu.models.references import olmoe as reference
@@ -45,60 +46,33 @@ def config(**overrides):
     return olmoe_config(**base)
 
 
+CHECK = harness.Harness(
+    reference, loss_atol=LOSS_ATOL, grad_atol=GRAD_ATOL,
+    grad_rtol=GRAD_RTOL,
+)
+
+
+def move(name, leaf, draw):
+    """Every ``scale`` moved off one and the router sharpened so that the
+    eight probabilities differ."""
+    if name.endswith("['scale']"):
+        return leaf * (1 + 0.3 * draw(leaf.shape))
+    if "['router']" in name:
+        return leaf * 4
+    return leaf
+
+
 @pytest.fixture(scope="module")
 def tokens():
-    rng = np.random.default_rng(11)
-    rows = jnp.asarray(rng.integers(0, VOCAB, (BATCH, SEQ + 1)), jnp.int32)
-    return rows[:, :-1], rows[:, 1:]
+    return harness.tokens(11, BATCH, SEQ, VOCAB)
 
 
 @pytest.fixture(scope="module")
 def params(tokens):
-    """The program's own init, then every ``scale`` moved off one and the
-    router sharpened so that the eight probabilities differ."""
-    tree = nn.meta.unbox(
-        TransformerLM(config()).init(jax.random.PRNGKey(5), tokens[0])
-    )["params"]
-    rng = np.random.default_rng(7)
-
-    def move(path, leaf):
-        names = [getattr(k, "key", "") for k in path]
-        if names[-1] == "scale":
-            return leaf * jnp.asarray(
-                1 + 0.3 * rng.standard_normal(leaf.shape), leaf.dtype
-            )
-        if "router" in names:
-            return leaf * 4
-        return leaf
-
-    return jax.tree_util.tree_map_with_path(move, tree)
-
-
-def program_nll(cfg, params, inputs, targets):
-    logits, aux = TransformerLM(cfg).apply({"params": params}, inputs)
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-    nll = -jnp.take_along_axis(logp, targets[..., None], -1)[..., 0]
-    return nll, aux
-
-
-def program_loss(cfg, params, inputs, targets):
-    nll, aux = program_nll(cfg, params, inputs, targets)
-    return nll.mean() + aux
-
-
-def nll_gap(cfg, params, tokens):
-    got, _ = program_nll(cfg, params, *tokens)
-    want = reference.token_nll(cfg, params, *tokens)
-    return float(jnp.abs(got - want).max())
+    return harness.init(config(), tokens[0], seed=5, move=move)
 
 
 IMPLS = pytest.mark.parametrize("attention_impl", ["xla", "flash"])
-
-
-@IMPLS
-def test_token_nll_matches_the_reference(attention_impl, params, tokens):
-    cfg = config(attention_impl=attention_impl)
-    assert nll_gap(cfg, params, tokens) <= NLL_ATOL
 
 
 @IMPLS
@@ -108,21 +82,16 @@ def test_loss_and_every_gradient_match_the_reference(
     cfg = config(attention_impl=attention_impl, remat=(
         "flash_only" if attention_impl == "flash" else "none"
     ))
-    got, got_grads = jax.value_and_grad(program_loss, argnums=1)(
-        cfg, params, *tokens
-    )
-    want, want_grads = reference.loss_and_grads(cfg, params, *tokens)
-    assert abs(float(got) - float(want)) <= LOSS_ATOL
+    CHECK.loss_and_every_gradient_match(cfg, params, tokens)
     # the auxiliary term is in both: without it the losses part by this
-    aux = program_nll(cfg, params, *tokens)[1]
+    aux = CHECK.loss_and_grads(cfg, params, tokens)[1][1]
     assert float(aux) > 100 * LOSS_ATOL
-    flat_got = jax.tree_util.tree_leaves_with_path(got_grads)
-    flat_want = jax.tree_util.tree_leaves(want_grads)
-    assert len(flat_got) == len(flat_want)
-    for (path, g), w in zip(flat_got, flat_want):
-        bound = GRAD_ATOL + GRAD_RTOL * float(jnp.abs(w).max())
-        assert float(jnp.abs(g - w).max()) <= bound, jax.tree_util.keystr(path)
-        assert float(jnp.abs(w).max()) > 0, jax.tree_util.keystr(path)
+
+
+@IMPLS
+def test_token_nll_matches_the_reference(attention_impl, params, tokens):
+    cfg = config(attention_impl=attention_impl)
+    assert CHECK.nll_gap(cfg, params, tokens) <= NLL_ATOL
 
 
 def test_the_train_step_s_first_loss_is_the_reference_s(params, tokens):
@@ -172,16 +141,23 @@ def test_the_check_is_sharp(undo, params, tokens, monkeypatch):
     fails the comparison the tests above make."""
     if undo == "top1_balance_loss":
         cfg = config(moe_aux_form="top1")
-        got = program_loss(cfg, params, *tokens)
+        nll, aux, _ = CHECK.outputs(cfg, params, tokens)
         want = reference.loss(cfg, params, *tokens)
-        assert abs(float(got) - float(want)) > 100 * LOSS_ATOL
+        assert abs(float(nll.mean() + aux) - float(want)) > 100 * LOSS_ATOL
+        return
+    if undo == "one_dropped_pair":
+        # the plan is patched under a configuration the other cases run:
+        # traced here and now, not taken from what they kept
+        cfg = _drop_one_pair(monkeypatch)
+        got = harness.program_nll(cfg, params, *tokens)
+        want = reference.token_nll(cfg, params, *tokens)
+        assert float(jnp.abs(got - want).max()) > 10 * NLL_ATOL
         return
     cfg = {
-        "renormalised_gates": lambda: config(norm_topk_prob=True),
-        "no_qk_norm": lambda: config(qk_norm=False),
-        "one_dropped_pair": lambda: _drop_one_pair(monkeypatch),
-    }[undo]()
-    assert nll_gap(cfg, params, tokens) > 10 * NLL_ATOL
+        "renormalised_gates": config(norm_topk_prob=True),
+        "no_qk_norm": config(qk_norm=False),
+    }[undo]
+    assert CHECK.nll_gap(cfg, params, tokens) > 10 * NLL_ATOL
 
 
 def test_prefill_then_cached_decode_agree_with_the_full_forward(
@@ -190,16 +166,23 @@ def test_prefill_then_cached_decode_agree_with_the_full_forward(
     inputs = tokens[0]
     want, _ = reference.forward(config(), params, inputs)
     decoder = TransformerLM(config(decode=True))
+
+    @jax.jit
+    def decode(variables, ids, positions):
+        return decoder.apply(
+            variables, ids, positions=positions, mutable=["cache"]
+        )
+
     prefill = 24
-    (got, _), state = decoder.apply(
+    (got, _), state = decode(
         {"params": params}, inputs[:, :prefill],
-        positions=jnp.arange(prefill)[None, :], mutable=["cache"],
+        jnp.arange(prefill)[None, :],
     )
     np.testing.assert_allclose(got, want[:, :prefill], atol=NLL_ATOL)
     for i in range(prefill, SEQ):
-        (got, _), state = decoder.apply(
+        (got, _), state = decode(
             {"params": params, "cache": state["cache"]}, inputs[:, i:i + 1],
-            positions=jnp.full((BATCH, 1), i), mutable=["cache"],
+            jnp.full((BATCH, 1), i),
         )
         np.testing.assert_allclose(got[:, 0], want[:, i], atol=NLL_ATOL)
 
@@ -292,7 +275,9 @@ def test_earlier_models_keep_their_trees_and_losses(name):
         for path, _ in jax.tree_util.tree_leaves_with_path(tree["blocks"])
     )
     assert found == sorted(leaves)
-    got_nll, got_aux = program_nll(cfg, tree, rows[:, :-1], rows[:, 1:])
+    got_nll, got_aux, _ = harness.program_outputs(
+        cfg, tree, rows[:, :-1], rows[:, 1:]
+    )
     np.testing.assert_allclose(float(got_nll.mean()), nll, rtol=1e-6)
     np.testing.assert_allclose(float(got_aux), aux, rtol=1e-6)
 

@@ -235,14 +235,8 @@ def test_spec_self_draft_accepts_nearly_everything(setup):
     # accepted.  The max_new_tokens boundary cuts what is committed, not
     # what ``verify`` counts: a request's prefill emits 1 token, its first
     # round 4 more (5 of 8), its second is the last, so each of the 4
-    # requests proposes 2 x γ = 6 and accepts 6.
-    #
-    # FAILS since before PR 21 on a defect of the program, not of this
-    # expectation (ROADMAP D15): ``SpecPrograms._propose_impl`` feeds the
-    # draft t0, p1 .. p(γ-1), so after a fully accepted round the draft's
-    # cache has no K/V for pγ and its next proposals are made over that
-    # hole: 33 proposed, 22 accepted.  With the draft fed pγ too the
-    # counts below are met (PR 29, tried on a copy).
+    # requests proposes 2 x γ = 6 and accepts 6.  (The draft is fed pγ
+    # too, so a fully accepted round leaves its cache whole: ROADMAP D15.)
     assert stats["spec_proposed"] == 4 * 2 * 3
     assert stats["spec_accepted"] == stats["spec_proposed"]
 

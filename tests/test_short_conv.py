@@ -3,19 +3,16 @@ against the written-out XLA form they replace, at the two published channel
 layouts cut to three token tiles, and the choice between the two."""
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from dlrover_tpu.common import telemetry
 from dlrover_tpu.models import linear_attention as la
 from dlrover_tpu.models import mamba2
 from dlrover_tpu.ops import short_conv
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 F32 = jnp.float32
 
 # name: x's shape, taps' shape, a bias, the first column, the outputs'
@@ -261,45 +258,3 @@ def test_the_mixers_ask_for_their_own_layout():
     assert mamba2.conv_path(64, 4, 64, 16, 2, 4) == "xla"
     assert la.conv_path(64, 4, 12, 24, 4) == "xla"
     assert mamba2.conv_path(128, 4, 64, 16, 2, 4) == "kernel"
-
-
-@pytest.fixture
-def tap():
-    was_enabled = telemetry.recorder().enabled
-    telemetry.recorder().configure(enabled=True)
-    opened = telemetry.recorder().open_tap()
-    yield opened
-    opened.close()
-    telemetry.recorder().configure(enabled=was_enabled)
-
-
-@pytest.mark.parametrize("preset,seq,path", [
-    # Nemotron-like: the tiny preset's layers on one whole lane tile of
-    # tokens (x | B | C = 256 | 32 | 32 channels: whole row tiles)
-    ("nemotron-3-nano-30b-a3b", 128, "kernel"),
-    ("nemotron-3-nano-30b-a3b", None, "xla"),      # the preset's 64 tokens
-    ("olmo-hybrid-7b", None, "xla"),
-    ("gpt2-1.5b", None, "none"),
-])
-def test_compile_event_names_the_short_conv(tap, preset, seq, path):
-    """Beside ``test_compile_event_names_the_flash_backward``: which form
-    the step's convolutions took is a fact of the compiled step."""
-    from benchmark import build
-    from dlrover_tpu.trainer import train_lib
-    from dlrover_tpu.trainer.elastic_trainer import (
-        ElasticTrainer, TrainerConfig,
-    )
-
-    cfg = build.load_json(os.path.join(
-        REPO, "tests", "benchmark_suite", "presets", f"{preset}.json"
-    ))
-    seq = seq or cfg["run"]["seq_len"]
-    model = build.transformer_config(build.model_group(cfg), seq)
-    train_lib.reset_build_cache()
-    tap.take()
-    ElasticTrainer(model, TrainerConfig(
-        global_batch_size=jax.device_count(), seq_len=seq,
-        optimizer="adafactor", warmup_compile=True, ckpt_every=1000,
-    ))
-    (event,) = [e for e in tap.take() if e[0] == "compile"]
-    assert event[-1]["short_conv"] == path

@@ -2,6 +2,8 @@
 mode) and the chunked ``jax.numpy`` form against the recurrence one token at
 a time (the reference's ``ssm_recurrence``), forward and every gradient."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -27,6 +29,15 @@ def inputs(batch=2, s=40, heads=4, head_dim=64, groups=2, state=16, seed=0,
     ), jax.random.normal(k[6], (batch, s, heads, head_dim))
 
 
+def ssd(chunk, impl, **args):
+    """``ssd_lib.ssd`` as one program: op by op its chunked form compiles a
+    hundred pieces a shape."""
+    return jax.jit(
+        functools.partial(ssd_lib.ssd, chunk=chunk, impl=impl)
+    )(**args)
+
+
+@jax.jit
 def recurrence(x, dt, a_head, b, c, d):
     share = x.shape[2] // b.shape[2]
     with jax.default_matmul_precision("highest"):
@@ -34,6 +45,22 @@ def recurrence(x, dt, a_head, b, c, d):
             x, dt, a_head, jnp.repeat(b, share, axis=2),
             jnp.repeat(c, share, axis=2), d,
         )
+
+
+def through(fn, args, weight):
+    """``fn``'s output and the gradient of ``sum(y * weight)`` to every
+    argument, as one program (op by op, the kernel pair in interpret mode
+    and the chunked form each compile their hundred pieces twice)."""
+    names = sorted(args)
+
+    def loss(*values):
+        y = fn(**dict(zip(names, values)))
+        return (y.astype(F32) * weight).sum(), y
+
+    (_, y), grads = jax.jit(jax.value_and_grad(
+        loss, argnums=tuple(range(len(names))), has_aux=True
+    ))(*(args[n] for n in names))
+    return y, names, grads
 
 
 # (sequence, chunk): one chunk, a length that is several chunks, one that
@@ -45,7 +72,7 @@ SHAPES = [(16, 16), (64, 16), (40, 16), (24, 8), (8, 16)]
 @pytest.mark.parametrize("s,chunk", SHAPES)
 def test_forward_matches_the_recurrence(impl, s, chunk):
     args, _ = inputs(s=s)
-    y, top = ssd_lib.ssd(**args, chunk=chunk, impl=impl)
+    y, top = ssd(chunk, impl, **args)
     np.testing.assert_allclose(y, recurrence(**args), atol=TOL, rtol=TOL)
     assert np.isfinite(float(top)) and float(top) > 0
 
@@ -54,18 +81,11 @@ def test_forward_matches_the_recurrence(impl, s, chunk):
 @pytest.mark.parametrize("s,chunk", [(64, 16), (40, 16), (24, 8)])
 def test_gradients_match_the_recurrence(impl, s, chunk):
     args, weight = inputs(s=s)
-    names = sorted(args)
-
-    def through(fn):
-        def loss(*values):
-            return (fn(**dict(zip(names, values))) * weight).sum()
-
-        return jax.grad(loss, argnums=tuple(range(len(names))))(
-            *(args[n] for n in names)
-        )
-
-    got = through(lambda **kw: ssd_lib.ssd(**kw, chunk=chunk, impl=impl)[0])
-    want = through(recurrence)
+    _, names, got = through(
+        lambda **kw: ssd(chunk, impl, **kw)[0],
+        args, weight,
+    )
+    _, _, want = through(recurrence, args, weight)
     for name, g, w in zip(names, got, want):
         scale = float(jnp.abs(w).max())
         np.testing.assert_allclose(
@@ -78,11 +98,11 @@ def test_the_state_carries_across_a_chunk_boundary():
     sequence at the boundary and starting again from zero is another
     result, and the kernel's is the uncut recurrence's."""
     args, _ = inputs(s=32)
-    y, _ = ssd_lib.ssd(**args, chunk=16, impl="kernel")
+    y, _ = ssd(16, "kernel", **args)
     tail = {
         k: (v[:, 16:] if v.ndim > 1 else v) for k, v in args.items()
     }
-    fresh, _ = ssd_lib.ssd(**tail, chunk=16, impl="kernel")
+    fresh, _ = ssd(16, "kernel", **tail)
     assert float(jnp.abs(y[:, 16:] - fresh).max()) > 1e-2
     np.testing.assert_allclose(y, recurrence(**args), atol=TOL, rtol=TOL)
 
@@ -91,12 +111,12 @@ def test_heads_of_a_group_share_b_and_c():
     """Head ``h`` reads group ``h // (H / G)``: with each group's rows
     repeated for its heads and G = H, the result is the same."""
     args, _ = inputs(heads=4, groups=2)
-    y, _ = ssd_lib.ssd(**args, chunk=16, impl="xla")
+    y, _ = ssd(16, "xla", **args)
     own = dict(
         args, b=jnp.repeat(args["b"], 2, axis=2),
         c=jnp.repeat(args["c"], 2, axis=2),
     )
-    y_own, _ = ssd_lib.ssd(**own, chunk=16, impl="xla")
+    y_own, _ = ssd(16, "xla", **own)
     np.testing.assert_allclose(y, y_own, atol=TOL, rtol=TOL)
 
 
@@ -108,7 +128,7 @@ def test_the_largest_state_entry_is_a_reading_not_a_result():
 
     assert float(jnp.abs(jax.grad(top)(args["x"])).max()) == 0.0
     tops = [
-        float(ssd_lib.ssd(**args, chunk=16, impl=impl)[1])
+        float(ssd(16, impl, **args)[1])
         for impl in ssd_lib.IMPLS
     ]
     assert tops[0] == pytest.approx(tops[1], rel=1e-5)
@@ -123,7 +143,7 @@ def test_bfloat16_operands_keep_float32_decay_and_state():
         k: v.astype(F32) for k, v in args.items()
     })
     for impl in ssd_lib.IMPLS:
-        y, _ = ssd_lib.ssd(**args, chunk=16, impl=impl)
+        y, _ = ssd(16, impl, **args)
         assert y.dtype == jnp.bfloat16
         err = float(jnp.abs(y.astype(F32) - want).mean())
         assert err < 0.02 * float(jnp.abs(want).mean()), (impl, err)
@@ -145,12 +165,12 @@ def test_the_kernel_refuses_sizes_its_lanes_do_not_hold():
     assert not ssd_lib.kernel_fits(4, 32, 2)       # a group is 64 lanes wide
     args, _ = inputs(heads=4, head_dim=32, groups=2)
     with pytest.raises(ValueError, match="side by side"):
-        ssd_lib.ssd(**args, chunk=16, impl="kernel")
-    y, _ = ssd_lib.ssd(**args, chunk=16, impl="xla")
+        ssd(16, "kernel", **args)
+    y, _ = ssd(16, "xla", **args)
     np.testing.assert_allclose(y, recurrence(**args), atol=TOL, rtol=TOL)
     mixed = dict(args, b=args["b"].astype(jnp.bfloat16))
     with pytest.raises(ValueError, match="share a dtype"):
-        ssd_lib.ssd(**mixed, chunk=16, impl="xla")
+        ssd(16, "xla", **mixed)
 
 
 # One group of many heads, cut into tiles of heads_per_step heads
@@ -173,20 +193,12 @@ def test_a_group_of_many_heads_runs_as_tiles(heads, head_dim, groups, tiles):
     args, weight = inputs(
         batch=2, s=40, heads=heads, head_dim=head_dim, groups=groups
     )
-    names = sorted(args)
-
-    def through(fn):
-        def loss(*values):
-            return (fn(**dict(zip(names, values))) * weight).sum()
-
-        return jax.grad(loss, argnums=tuple(range(len(names))))(
-            *(args[n] for n in names)
-        )
-
-    y, _ = ssd_lib.ssd(**args, chunk=16, impl="kernel")
-    np.testing.assert_allclose(y, recurrence(**args), atol=TOL, rtol=TOL)
-    got = through(lambda **kw: ssd_lib.ssd(**kw, chunk=16, impl="kernel")[0])
-    want = through(recurrence)
+    y, names, got = through(
+        lambda **kw: ssd(16, "kernel", **kw)[0],
+        args, weight,
+    )
+    y_want, _, want = through(recurrence, args, weight)
+    np.testing.assert_allclose(y, y_want, atol=TOL, rtol=TOL)
     for name, g, w in zip(names, got, want):
         scale = float(jnp.abs(w).max())
         np.testing.assert_allclose(
@@ -262,18 +274,6 @@ def _flat(jaxpr):
             yield from _flat(sub)
 
 
-def through(fn, args, weight):
-    names = sorted(args)
-
-    def loss(*values):
-        y = fn(**dict(zip(names, values)))
-        return (y.astype(F32) * weight).sum()
-
-    return names, jax.grad(loss, argnums=tuple(range(len(names))))(
-        *(args[n] for n in names)
-    )
-
-
 # A grid step at its real sizes (8 heads of 64 side by side in four lane
 # tiles, a state of 128, a chunk of 128, two chunks, batch 2): one group
 # that is one tile, and one group of two tiles
@@ -291,9 +291,8 @@ def test_a_grid_step_at_its_real_sizes_matches_the_chunked_form(
     args["dt"] = args["dt"] * 0.1              # a state that lives 128 tokens
     got, want = {}, {}
     for impl, out in (("kernel", got), ("xla", want)):
-        out["y"] = ssd_lib.ssd(**args, chunk=128, impl=impl)[0]
-        names, grads = through(
-            lambda **kw: ssd_lib.ssd(**kw, chunk=128, impl=impl)[0],
+        out["y"], names, grads = through(
+            lambda **kw: ssd(128, impl, **kw)[0],
             args, weight,
         )
         out.update(zip(names, grads))
@@ -308,9 +307,9 @@ def test_a_grid_step_at_its_real_sizes_matches_the_chunked_form(
     # bfloat16: both forms round their products; each stays within
     # bfloat16's rounding of the float32 chunked form on the same inputs
     exact = {k: v.astype(F32) for k, v in args.items()}
-    true = {"y": ssd_lib.ssd(**exact, chunk=128, impl="xla")[0]}
-    names, grads = through(
-        lambda **kw: ssd_lib.ssd(**kw, chunk=128, impl="xla")[0],
+    true = {}
+    true["y"], names, grads = through(
+        lambda **kw: ssd(128, "xla", **kw)[0],
         exact, weight,
     )
     true.update(zip(names, grads))

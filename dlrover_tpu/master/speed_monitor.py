@@ -304,12 +304,17 @@ class SpeedMonitor:
         held: float = 0.0,
         pairs_here: float = 1.0,
         bias_absmax: float = 0.0,
+        tokens_here: float = 1.0,
+        groups: float = 1.0,
         **_ignored,
     ):
         """A trainer's router-health snapshot (its ``moe`` telemetry
         event).  ``held`` of the ``experts`` live on the reporter's chip
         (0: all), which computed ``pairs_here`` of the routed pairs;
-        ``bias_absmax`` is a bias-corrected router's largest bias.  Newest-wins per reporting node; ``load`` arrives as a
+        ``bias_absmax`` is a bias-corrected router's largest bias;
+        ``tokens_here`` the share of the tokens with a pair here and
+        ``groups`` the groups a group-limited choice cuts the experts into
+        (1: no limit).  Newest-wins per reporting node; ``load`` arrives as a
         JSON array string of per-expert load fractions (wire attrs stay
         scalar-ish); unknown attrs are ignored so the trainer can grow
         the event without breaking older masters."""
@@ -331,6 +336,8 @@ class SpeedMonitor:
                 "held": float(held or experts),
                 "pairs_here": float(pairs_here),
                 "bias_absmax": float(bias_absmax),
+                "tokens_here": float(tokens_here),
+                "groups": float(groups),
             }
 
     def record_mtp(self, node_id: int = 0, *, step: float = 0.0,
@@ -356,11 +363,14 @@ class SpeedMonitor:
         mean_alpha: float = 0.0,
         mean_beta: float = 0.0,
         state_absmax: float = 0.0,
+        min_alpha: float = 1.0,
         **_ignored,
     ):
         """A trainer's linear-attention snapshot (its ``linear_attn``
-        telemetry event).  Newest-wins per reporting node; unknown attrs
-        are ignored so the trainer can grow the event."""
+        telemetry event; ``min_alpha`` is a per-channel rule's smallest
+        mean decay of a channel, 1 where the event has none).  Newest-wins
+        per reporting node; unknown attrs are ignored so the trainer can
+        grow the event."""
         with self._lock:
             self._linear_attn_stats[node_id] = {
                 "step": float(step),
@@ -369,15 +379,23 @@ class SpeedMonitor:
                 "mean_alpha": float(mean_alpha),
                 "mean_beta": float(mean_beta),
                 "state_absmax": float(state_absmax),
+                "min_alpha": float(min_alpha),
             }
 
     def linear_attn_ledger(self) -> Dict[str, float]:
         """Aggregate over reporters: the means average (each books its own
         replica's batch), the state's largest entry and the geometry take
-        the max (a non-finite entry on any replica must show)."""
-        return self._state_ledger(
+        the max (a non-finite entry on any replica must show), the
+        smallest channel decay the min."""
+        out = self._state_ledger(
             self._linear_attn_stats, ("mean_alpha", "mean_beta")
         )
+        with self._lock:
+            out["min_alpha"] = min(
+                (s["min_alpha"] for s in self._linear_attn_stats.values()),
+                default=1.0,
+            )
+        return out
 
     def record_ssm(
         self,
@@ -468,6 +486,8 @@ class SpeedMonitor:
                 "bias_absmax": max(
                     (s["bias_absmax"] for s in stats), default=0.0
                 ),
+                "tokens_here": mean("tokens_here") if n else 1.0,
+                "groups": max((s["groups"] for s in stats), default=1.0),
             }
 
     def embed_ledger(self) -> Dict[str, float]:

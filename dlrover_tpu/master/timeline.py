@@ -385,6 +385,13 @@ class JobTimeline:
             gauge("dlrover_moe_pairs_here", moe["pairs_here"],
                   "share of the routed token-choices a reporter's own "
                   "experts computed (mean of reporters; 1 without a share)")
+            gauge("dlrover_moe_tokens_here", moe["tokens_here"],
+                  "share of the tokens with at least one routed pair on a "
+                  "reporter's chip: the rows an exchange would send it "
+                  "(mean of reporters; 1 without a share)")
+            gauge("dlrover_moe_router_groups", moe["groups"],
+                  "groups a group-limited router cuts the experts into "
+                  "(1: no limit)")
             gauge("dlrover_moe_router_bias_absmax", moe["bias_absmax"],
                   "largest |bias| of a bias-corrected router (max of "
                   "reporters; 0 where the router has none)")
@@ -418,6 +425,9 @@ class JobTimeline:
                   linear["state_absmax"],
                   "largest |S| entry of a recurrent state at any chunk "
                   "boundary (max of reporters; NaN/Inf = diverged)")
+            gauge("dlrover_linear_attn_min_alpha", linear["min_alpha"],
+                  "smallest mean decay of one channel of a per-channel "
+                  "rule (min of reporters; 1 where no layer has one)")
             gauge("dlrover_linear_attn_reporters", linear["reporters"],
                   "trainers that have reported linear-attention snapshots")
             ssm = speed_monitor.ssm_ledger()

@@ -451,6 +451,13 @@ class LatentAttention(nn.Module):
         k_h = [k_nope_h | k_pe]; softmax(q_h k_h / sqrt(nope + rope)) v_h
         y = concat_h(o_h) W_o                       # H * v -> d
 
+    ``q_lora_rank`` 0 (Ling-3.0-flash: published ``null``) takes q straight
+    from the stream, ``[q_nope | q_pe]_h = n W_q`` with no latent and no q
+    norm (the projection keeps the name ``q_b``: it is the one that makes
+    the heads' queries).  ``gate="head_wise"`` multiplies each head's
+    output by ``sigmoid(n W_gate)_h`` before ``W_o`` (Gated Attention,
+    arXiv:2505.06708; ``W_gate`` ``[d, H]``, scope ``attn/gate``).
+
     Training keeps no cache: k and v are materialised per head and go
     through the attention kernel as ``H`` heads with keys ``nope + rope``
     wide and values ``v_head_dim`` wide (``ops/flash_attention.py`` takes
@@ -480,6 +487,7 @@ class LatentAttention(nn.Module):
     flash_block_q: int = 512
     flash_block_kv: int = 512
     scale: float = 0.0             # 0 -> (nope + rope) ** -0.5
+    gate: str = ""                 # "" | "head_wise"
 
     @nn.compact
     def __call__(
@@ -492,6 +500,10 @@ class LatentAttention(nn.Module):
             raise ValueError(
                 "latent attention runs under attention_impl 'flash' or "
                 f"'xla', got {self.attention_impl!r}"
+            )
+        if self.gate not in ("", "head_wise"):
+            raise ValueError(
+                f"gate must be '' or 'head_wise', got {self.gate!r}"
             )
         features = x.shape[-1]
         nope, rope = self.qk_nope_head_dim, self.qk_rope_head_dim
@@ -510,12 +522,19 @@ class LatentAttention(nn.Module):
                 epsilon=self.norm_eps,
             )
 
-        c_q = norm("q_norm")(
-            dense(self.q_lora_rank, (lr.EMBED, lr.LATENT), "q_a")(x)
-        )
-        q = dense(
-            (self.num_heads, nope + rope), (lr.LATENT, lr.HEADS, lr.KV), "q_b"
-        )(c_q)
+        if self.q_lora_rank:
+            c_q = norm("q_norm")(
+                dense(self.q_lora_rank, (lr.EMBED, lr.LATENT), "q_a")(x)
+            )
+            q = dense(
+                (self.num_heads, nope + rope), (lr.LATENT, lr.HEADS, lr.KV),
+                "q_b",
+            )(c_q)
+        else:
+            q = dense(
+                (self.num_heads, nope + rope), (lr.EMBED, lr.HEADS, lr.KV),
+                "q_b",
+            )(x)
         kv_row = dense(
             self.kv_lora_rank + rope, (lr.EMBED, lr.LATENT), "kv_a"
         )(x)
@@ -557,6 +576,13 @@ class LatentAttention(nn.Module):
                 scale=self.scale or None,
             )
             out = nn.with_logical_constraint(out, attn_spec)
+        if self.gate:
+            head_gate = dense(self.num_heads, (lr.EMBED, lr.HEADS), "gate")(x)
+            with jax.named_scope("gate"):
+                out = (
+                    out.astype(jnp.float32)
+                    * jax.nn.sigmoid(head_gate.astype(jnp.float32))[..., None]
+                ).astype(self.dtype)
         return layers.DenseGeneral(
             features, axis=(-2, -1),
             kernel_axes=(lr.HEADS, lr.KV, lr.EMBED), use_bias=False,

@@ -33,6 +33,23 @@ largest |S| entry at a chunk boundary]`` (:func:`split_stats`) into
 ``"intermediates"``: a no-op unless the caller applies with that
 collection mutable, as the train step does.
 
+:class:`KimiDeltaAttention` is Kimi Linear's mixer (arXiv:2510.26692) as
+Ling-3.0-flash runs it: the same convolution and norms, then::
+
+    beta = sigmoid(W_b n)                                 (never doubled)
+    g    = bound * sigmoid(exp(A_log_h) * (W_f n + dt_bias))   [H, dk]
+           (the safe gate: ``bound`` = ``kda_lower_bound`` -5 < g < 0;
+           W_f full rank [d, H, dk], float32 accumulation)
+    o    = the rule with a decay PER CHANNEL over (q, k, v, g, beta)
+           (ops/kda: S_t = (I - beta k k^T) Diag(exp g) S_{t-1} + beta k v^T)
+    y    = RMSNorm_dv(o; one [dv] scale) * sigmoid(W_g n)
+    out  = W_o y
+
+Its scopes are ``linear_attn/qkv``, ``/conv``, ``/gates`` (the decay
+projection, beta and the safe gate), ``/kda``, ``/out_norm`` and ``/wo``;
+its sown vector has a fourth entry, the smallest mean decay of a channel
+(``min_alpha``: a channel that forgets everything reads near exp(bound)).
+
 The block names this module ``linear_attn``, so its ``named_scope``s reach
 the compiled text as ``linear_attn/qkv``, ``/conv``, ``/gates``,
 ``/delta_rule``, ``/out_norm`` and (the output projection's own name)
@@ -68,10 +85,12 @@ def split_stats(vec):
 
 def fold_stats(stacked: jax.Array) -> jax.Array:
     """One vector out of the layers' (and microbatches') ``[n, 3]``: the
-    means of the means, the largest of the largest."""
-    return jnp.concatenate(
-        [stacked[:, :2].mean(axis=0), stacked[:, 2:].max(axis=0)]
-    )
+    means of the means, the largest of the largest; of a KDA layer's
+    ``[n, 4]`` also the smallest of the smallest decays."""
+    folded = [stacked[:, :2].mean(axis=0), stacked[:, 2:3].max(axis=0)]
+    if stacked.shape[1] > 3:
+        folded.append(stacked[:, 3:].min(axis=0))
+    return jnp.concatenate(folded)
 
 
 def _a_log_init(key, shape, dtype):
@@ -217,20 +236,24 @@ def causal_depthwise_conv(
     )(*args)
 
 
-def _delta_rule_local(q, k, v, g, beta, *, chunk):
+def _delta_rule_local(q, k, v, g, beta, *, chunk, rule=gated_delta_rule):
     """The rule on each device's own batch rows and heads
     (:func:`shard_local`, as attention's ``_flash_local``): a head's state
     is its own and the sequence is whole, so nothing crosses devices but
-    the largest ``|S|``, which each device reports for itself."""
+    the largest ``|S|``, which each device reports for itself.  ``rule`` is
+    the scalar rule (``g`` one decay a head) or ``ops/kda.kda`` (``g`` laid
+    out as the keys are)."""
     qkv_spec = nn.logical_to_mesh_axes((lr.BATCH, None, lr.ACT_HEADS, lr.KV))
     gate_spec = nn.logical_to_mesh_axes((lr.BATCH, None, lr.ACT_HEADS))
 
     def local(q, k, v, g, beta):
-        o, state_absmax = gated_delta_rule(q, k, v, g, beta, chunk=chunk)
+        o, state_absmax = rule(q, k, v, g, beta, chunk=chunk)
         return o, state_absmax[None, None]
 
     o, state_absmax = shard_local(
-        local, in_specs=(qkv_spec,) * 3 + (gate_spec,) * 2,
+        local,
+        in_specs=(qkv_spec,) * 3
+        + (qkv_spec if g.ndim == q.ndim else gate_spec, gate_spec),
         out_specs=(
             qkv_spec, nn.logical_to_mesh_axes((lr.BATCH, lr.ACT_HEADS)),
         ),
@@ -240,11 +263,14 @@ def _delta_rule_local(q, k, v, g, beta, *, chunk):
 
 def conv_path(
     seq: int, num_heads: int, key_dim: int, value_dim: int, taps: int,
+    gate_in_row: bool = True,
 ) -> str:
     """How :class:`GatedDeltaNet` of these widths runs its convolution on
-    ``seq`` tokens: what ``causal_depthwise_conv`` answers for its call."""
+    ``seq`` tokens: what ``causal_depthwise_conv`` answers for its call
+    (``gate_in_row`` False: :class:`KimiDeltaAttention`, whose projection's
+    row is ``[q | k | v]`` alone)."""
     return short_conv_path(
-        (1, seq, num_heads, 2 * key_dim + 2 * value_dim),
+        (1, seq, num_heads, 2 * key_dim + (1 + gate_in_row) * value_dim),
         (taps, num_heads, 2 * key_dim + value_dim),
         0, (key_dim, key_dim, value_dim), (key_dim ** -0.5, 1.0, None),
     )
@@ -349,6 +375,138 @@ class GatedDeltaNet(nn.Module):
             y = (y * scale.astype(F32) * nn.silu(z.astype(F32))).astype(
                 self.dtype
             )
+        return layers.DenseGeneral(
+            features, axis=(-2, -1),
+            kernel_axes=(lr.HEADS, lr.KV, lr.EMBED),
+            dtype=self.dtype, param_dtype=self.param_dtype,
+            kernel_init=proj_init, name="wo",
+        )(y)
+
+
+class KimiDeltaAttention(nn.Module):
+    """Kimi Delta Attention (the module's text): parameters ``qkv``
+    ``[d, H, 2 dk + dv]``, ``conv_kernel``, ``f_kernel`` ``[d, H, dk]`` (the
+    decay projection, full rank), ``b_kernel`` ``[d, H]``, ``A_log`` ``[H]``,
+    ``dt_bias`` ``[H, dk]``, ``g_proj`` ``[d, H, dv]`` (the output gate),
+    ``out_norm_scale`` ``[dv]``, ``wo``."""
+
+    num_heads: int
+    key_dim: int
+    value_dim: int
+    conv_taps: int = 4
+    decay_bound: float = -5.0
+    norm_eps: float = 1e-5
+    chunk: int = 128               # ops/kda.py CHUNK
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        h, dk, dv = self.num_heads, self.key_dim, self.value_dim
+        features = x.shape[-1]
+        proj_init = nn.initializers.normal(stddev=0.02)
+        with jax.named_scope("qkv"):
+            qkv = layers.DenseGeneral(
+                (h, 2 * dk + dv),
+                kernel_axes=(lr.EMBED, lr.HEADS, lr.KV),
+                dtype=self.dtype, param_dtype=self.param_dtype,
+                kernel_init=proj_init, name="qkv",
+            )(x)
+        with jax.named_scope("conv"):
+            taps = self.param(
+                "conv_kernel",
+                nn.with_logical_partitioning(
+                    conv_init, (None, lr.HEADS, lr.KV)
+                ),
+                (self.conv_taps, h, 2 * dk + dv), self.param_dtype,
+            )
+            q, k, v = causal_depthwise_conv(
+                qkv, taps.astype(self.dtype), splits=(dk, dk, dv),
+                l2_scales=(dk ** -0.5, 1.0, None),
+            )
+        with jax.named_scope("gates"):
+            f_kernel = self.param(
+                "f_kernel",
+                nn.with_logical_partitioning(
+                    proj_init, (lr.EMBED, lr.HEADS, lr.KV)
+                ),
+                (features, h, dk), self.param_dtype,
+            )
+            b_kernel = self.param(
+                "b_kernel",
+                nn.with_logical_partitioning(proj_init, (lr.EMBED, lr.HEADS)),
+                (features, h), self.param_dtype,
+            )
+            a_log = self.param(
+                "A_log",
+                nn.with_logical_partitioning(_a_log_init, (lr.HEADS,)),
+                (h,), F32,
+            )
+            dt_bias = self.param(
+                "dt_bias",
+                nn.with_logical_partitioning(
+                    _dt_bias_init, (lr.HEADS, lr.KV)
+                ),
+                (h, dk), F32,
+            )
+            xc = x.astype(self.dtype)
+            f = jnp.einsum(
+                "bsd,dhk->bshk", xc, f_kernel.astype(self.dtype),
+                preferred_element_type=F32,
+            )
+            # the safe gate: bounded below, so a sub-chunk's total decay
+            # stays inside float32 (ops/kda.py)
+            g = self.decay_bound * jax.nn.sigmoid(
+                jnp.exp(a_log.astype(F32))[:, None]
+                * (f + dt_bias.astype(F32))
+            )
+            beta = jax.nn.sigmoid(jnp.einsum(
+                "bsd,dh->bsh", xc, b_kernel.astype(self.dtype),
+                preferred_element_type=F32,
+            ))
+        spec = (lr.BATCH, None, lr.ACT_HEADS, lr.KV)
+        q, k, v, g = (
+            nn.with_logical_constraint(a, spec) for a in (q, k, v, g)
+        )
+        with jax.named_scope("kda"):
+            # imported where a model has such a layer, and by no other
+            from dlrover_tpu.ops import kda as kda_ops
+
+            o, state_absmax = _delta_rule_local(
+                q, k, v, g, beta, chunk=self.chunk, rule=kda_ops.kda
+            )
+        # kept by ``flash_only`` as the scalar rule's output is
+        # (ops/remat_policy.py)
+        o = jax.ad_checkpoint.checkpoint_name(o, "kda_out")
+        o = nn.with_logical_constraint(o, spec)
+        alpha = jnp.exp(g)
+        self.sow(
+            "intermediates", STATS_NAME,
+            jax.lax.stop_gradient(jnp.stack([
+                alpha.mean(), beta.mean(), state_absmax,
+                alpha.mean(axis=(0, 1)).min(),
+            ])),
+        )
+        with jax.named_scope("out_norm"):
+            scale = self.param(
+                "out_norm_scale",
+                nn.with_logical_partitioning(
+                    nn.initializers.ones_init(), (lr.NORM,)
+                ),
+                (dv,), self.param_dtype,
+            )
+            gate = layers.DenseGeneral(
+                (h, dv), kernel_axes=(lr.EMBED, lr.HEADS, lr.KV),
+                dtype=self.dtype, param_dtype=self.param_dtype,
+                kernel_init=proj_init, name="g_proj",
+            )(x)
+            o32 = o.astype(F32)
+            y = o32 * jax.lax.rsqrt(
+                jnp.mean(o32 * o32, axis=-1, keepdims=True) + self.norm_eps
+            )
+            y = (
+                y * scale.astype(F32) * jax.nn.sigmoid(gate.astype(F32))
+            ).astype(self.dtype)
         return layers.DenseGeneral(
             features, axis=(-2, -1),
             kernel_axes=(lr.HEADS, lr.KV, lr.EMBED),

@@ -35,9 +35,22 @@ def ungated(h: jax.Array, activation: str) -> jax.Array:
     return nn.gelu(h)
 
 
+def group_limited(pick: jax.Array, groups: int, topk_group: int) -> jax.Array:
+    """``pick`` [..., E] with every expert outside a token's ``topk_group``
+    best groups at -inf (DeepSeek-V3 §2.1.2's node-limited choice): the E
+    experts are ``groups`` runs of E / groups consecutive ones, a group's
+    score is the sum of its two largest entries."""
+    e = pick.shape[-1]
+    grouped = pick.reshape(*pick.shape[:-1], groups, e // groups)
+    score = jax.lax.top_k(grouped, 2)[0].sum(axis=-1)         # [..., G]
+    _, best = jax.lax.top_k(score, topk_group)
+    keep = jax.nn.one_hot(best, groups, dtype=jnp.bool_).any(axis=-2)
+    return jnp.where(keep[..., None], grouped, -jnp.inf).reshape(pick.shape)
+
+
 def _gate(logits: jax.Array, k: int, norm_topk_prob: bool = True,
           aux_form: str = "top1", scoring: str = "softmax", bias=None,
-          scale: float = 1.0):
+          scale: float = 1.0, groups: int = 1, topk_group: int = 1):
     """Shared top-k gate: (gate_vals, gate_idx, aux_loss), float32.
 
     ``norm_topk_prob`` renormalises the chosen gates to sum to one
@@ -52,12 +65,22 @@ def _gate(logits: jax.Array, k: int, norm_topk_prob: bool = True,
     chosen on ``s + bias`` (``bias`` picks, it never weighs, and takes no
     gradient); the gates are the chosen ``s``, renormalised under
     ``norm_topk_prob``, times ``scale``; there is no auxiliary loss (the
-    bias is moved by :func:`bias_update` after each step instead)."""
+    bias is moved by :func:`bias_update` after each step instead).  With
+    ``groups`` > 1 the choice is group-limited (:func:`group_limited`, on
+    ``s + bias`` too): the k come from a token's ``topk_group`` best
+    groups."""
+    if groups > 1 and scoring != "sigmoid":
+        raise ValueError(
+            "a group-limited choice is the sigmoid router's, got "
+            f"scoring={scoring!r}"
+        )
     if scoring == "sigmoid":
         scores = jax.nn.sigmoid(logits.astype(jnp.float32))
         pick = scores if bias is None else scores + jax.lax.stop_gradient(
             bias.astype(jnp.float32)
         )
+        if groups > 1:
+            pick = group_limited(pick, groups, topk_group)
         _, gate_idx = jax.lax.top_k(pick, k)
         gate_vals = jnp.take_along_axis(scores, gate_idx, axis=-1)
         if norm_topk_prob:
@@ -161,7 +184,12 @@ def _router_entropy(router_logits: jax.Array,
 
 # What a layer told its share of the experts sows beside ``moe_stats``:
 # ``[pairs_here, bias_absmax]``, the share of the routed pairs this chip
-# computed and the router bias's largest entry.
+# computed and the router bias's largest entry; under a group-limited
+# choice a third entry, ``tokens_here``, the share of the tokens with at
+# least one pair here (the rows an exchange would have to send this chip:
+# fewer than a binomial share of every token's k would give, because a
+# token whose best groups leave this chip's experts out sends nothing;
+# without a limit it is that binomial share and is not sown).
 SHARE_STATS_NAME = "moe_share_stats"
 
 
@@ -179,8 +207,12 @@ def split_stats(vec):
 def fold_share_stats(rows):
     """``[n, 2]`` ``moe_share_stats`` vectors (layers, microbatches) as
     one: the mean share of the routed pairs computed here, the largest
-    router-bias entry."""
-    return jnp.stack([rows[:, 0].mean(), rows[:, 1].max()])
+    router-bias entry; of ``[n, 3]`` also the mean share of the tokens
+    with a pair here."""
+    folded = [rows[:, 0].mean(), rows[:, 1].max()]
+    if rows.shape[1] > 2:
+        folded.append(rows[:, 2].mean())
+    return jnp.stack(folded)
 
 
 # -- dropless dispatch: every move of rows is a gather -------------------------
@@ -432,8 +464,13 @@ class MoEMlp(nn.Module):
     parameter no gradient reaches, moved by :func:`bias_update` in the
     train step.  ``shared_d_ff`` adds a shared expert (a plain MLP of the
     layer's ``activation`` and that width, ``shared``) every token passes
-    through.  Without a gate (``activation`` ``"gelu"`` or ``"relu2"``) an
-    expert is two matrices and the grouped path two grouped GEMMs.
+    through.  ``router_groups`` > 1 makes the choice group-limited
+    (``router_topk_groups`` of the groups a token, :func:`group_limited`);
+    under a share the pairs that land here are then no binomial thinning of
+    every token's k (a token whose best groups leave this chip's out sends
+    nothing), which ``tokens_here`` reads.  Without a gate (``activation``
+    ``"gelu"`` or ``"relu2"``) an expert is two matrices and the grouped
+    path two grouped GEMMs.
 
     Router observability: every forward ``sow``s a ``moe_stats`` vector
     ``[gate_entropy, drop_fraction, load_0..load_{E-1}, pad_share,
@@ -463,6 +500,8 @@ class MoEMlp(nn.Module):
     first_expert: int = 0
     shared_d_ff: int = 0            # 0 -> no shared expert
     row_budget_multiple: float = 1.25
+    router_groups: int = 1          # > 1: a group-limited choice
+    router_topk_groups: int = 1
 
     @property
     def held(self) -> int:
@@ -786,7 +825,8 @@ class MoEMlp(nn.Module):
         with jax.named_scope("router"):
             gate_vals, gate_idx, aux_loss = _gate(
                 router_logits, k, self.norm_topk_prob, self.aux_form,
-                self.scoring, bias, self.routed_scale,
+                self.scoring, bias, self.routed_scale, self.router_groups,
+                self.router_topk_groups,
             )
         # Tokens stay split over their batch and sequence axes (an MLP is
         # token-wise); the embed dim and the expert weights are whole on
@@ -899,13 +939,18 @@ class MoEMlp(nn.Module):
                 routed=routed(), total=here, rows_run=rows_run, kept=kept,
             )
         if share or bias is not None:
+            share_stats = [
+                here / (b * s * k),
+                jnp.zeros((), jnp.float32) if bias is None
+                else jnp.abs(jax.lax.stop_gradient(bias)).max(),
+            ]
+            if self.router_groups > 1:
+                held_here = (gate_idx >= first) & (gate_idx < first + e)
+                share_stats.append(
+                    held_here.any(axis=-1).mean(dtype=jnp.float32)
+                )
             self.sow(
-                "intermediates", SHARE_STATS_NAME,
-                jnp.stack([
-                    here / (b * s * k),
-                    jnp.zeros((), jnp.float32) if bias is None
-                    else jnp.abs(jax.lax.stop_gradient(bias)).max(),
-                ]),
+                "intermediates", SHARE_STATS_NAME, jnp.stack(share_stats)
             )
         return out, aux_loss.astype(jnp.float32)
 

@@ -19,7 +19,13 @@ such layers (``ssm`` | ``attention``, then ``experts``), and its four scalar
 multipliers are fields with neutral defaults: ``embed_scale`` on the
 embedding's output, ``attention_scale`` on the scores, ``residual_scale`` on
 a branch's output before its residual add (every block class), and
-``logit_scale`` on the logits.
+``logit_scale`` on the logits.  Ling-3.0-flash's are flags again: the
+linear layers' rule (``linear_rule="kda"``: a decay per channel under a
+bounded gate), latent attention without a q latent (``q_lora_rank`` 0) and
+with a head-wise output gate (``attention_gate``), a group-limited router
+(``router_groups`` / ``router_topk_groups``), and ``first_k_dense`` leading
+layers ahead of a PATTERNED trunk whose two-branch blocks carry the expert
+layer.
 
 TPU-first structure:
   * layers are ``nn.scan``-stacked: one trace regardless of depth (fast
@@ -44,7 +50,10 @@ import jax.numpy as jnp
 
 from dlrover_tpu.models import layers
 from dlrover_tpu.models.attention import Attention, LatentAttention
-from dlrover_tpu.models.linear_attention import GatedDeltaNet
+from dlrover_tpu.models.linear_attention import (
+    GatedDeltaNet,
+    KimiDeltaAttention,
+)
 from dlrover_tpu.models.mamba2 import Mamba2
 from dlrover_tpu.models.moe import MoEMlp, check_share, ungated
 from dlrover_tpu.ops import remat_policy as remat_policies
@@ -130,11 +139,19 @@ class TransformerConfig:
     first_expert: int = 0
     moe_row_budget: float = 1.25
     moe_d_ff: int = 0
+    # A group-limited choice (DeepSeek-V3 §2.1.2, models/moe.py
+    # ``group_limited``): the experts are ``router_groups`` runs of
+    # consecutive ones and a token's ``top_k`` come from its
+    # ``router_topk_groups`` best groups.  1 and 1: no limit.
+    router_groups: int = 1
+    router_topk_groups: int = 1
     # The shared expert's own width (0 -> ``num_shared_experts`` x the
     # routed experts' width, the DeepSeek-V3 family's).
     shared_expert_d_ff: int = 0
     # Layers before the scanned trunk whose MLP is dense (``d_ff`` wide)
-    # though the trunk's is sparse: ``dense_0`` .. of ``num_layers``.
+    # though the trunk's is sparse: ``dense_0`` .. of ``num_layers``.  Ahead
+    # of a patterned trunk their mixers are the pattern's continued
+    # backwards (``layer_kind``), and the pattern is of two-branch kinds.
     first_k_dense: int = 0
     # Multi-token prediction (DeepSeek-V3 §2.2): one module (depth 1) that
     # predicts token i+2 from the trunk's hidden state i and the embedding
@@ -150,11 +167,15 @@ class TransformerConfig:
     # rebuilt from a ``kv_lora_rank`` latent, ``qk_rope_head_dim`` rotary
     # columns shared by all heads; keys ``qk_nope_head_dim +
     # qk_rope_head_dim`` wide, values ``v_head_dim``.
+    # ``q_lora_rank`` 0 beside the other four: q straight from the stream
+    # (no latent, no q norm).  ``attention_gate`` "head_wise": each head's
+    # output times ``sigmoid(n W_gate)_h`` before ``wo`` (latent only).
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
+    attention_gate: str = ""
     # One period of layer kinds (``LAYER_KINDS``: "full_attention" and
     # "linear_attention" are a mixer AND an MLP; "ssm", "attention",
     # "experts" and "mlp" are that part alone on one residual branch),
@@ -193,6 +214,11 @@ class TransformerConfig:
     linear_value_head_dim: int = 0
     linear_conv_kernel: int = 4
     linear_allow_neg_eigval: bool = False
+    # The linear layers' rule: "delta" (Gated DeltaNet: one decay a head,
+    # ``GatedDeltaNet``) or "kda" (Kimi Delta Attention: a decay a channel,
+    # ``g = linear_decay_bound x sigmoid(..)``, ``KimiDeltaAttention``).
+    linear_rule: str = "delta"
+    linear_decay_bound: float = -5.0
     # "pre": x + f(Norm(x)) (GPT-2, Llama, Mixtral, OLMoE); "post":
     # x + Norm(f(x)), each branch's OUTPUT normalised before the residual
     # add (OLMo 2 and later).
@@ -277,7 +303,11 @@ class TransformerConfig:
 
     @property
     def num_linear_layers(self) -> int:
-        return self.num_layers_of(LINEAR_ATTENTION)
+        """The trunk's, and those of a dense prefix ahead of it."""
+        return self.num_layers_of(LINEAR_ATTENTION) + sum(
+            self.layer_kind(i) == LINEAR_ATTENTION
+            for i in range(self.first_k_dense)
+        )
 
     @property
     def num_ssm_layers(self) -> int:
@@ -293,9 +323,13 @@ class TransformerConfig:
         )
 
     def layer_kind(self, layer: int) -> str:
+        """Layer ``layer``'s kind: the trunk starts a period after the
+        dense prefix, whose layers continue the pattern backwards."""
         if not self.layer_pattern:
             return FULL_ATTENTION
-        return self.layer_pattern[layer % len(self.layer_pattern)]
+        return self.layer_pattern[
+            (layer - self.first_k_dense) % len(self.layer_pattern)
+        ]
 
     def __post_init__(self):
         # a JSON list arrives as a list; the config must stay hashable
@@ -361,10 +395,13 @@ class TransformerConfig:
             )
         if not pattern:
             return
-        if self.num_layers % len(pattern):
+        if (self.num_layers - self.first_k_dense) % len(pattern):
             raise ValueError(
                 f"num_layers {self.num_layers} is no whole number of "
                 f"periods of the {len(pattern)}-layer pattern {pattern}"
+                + (f" after the first_k_dense = {self.first_k_dense} "
+                   "layer(s) of the dense prefix" if self.first_k_dense
+                   else "")
             )
         if self.num_scan_units % self.pipeline_stages:
             raise ValueError(
@@ -385,6 +422,20 @@ class TransformerConfig:
                     "a linear_attention layer needs linear_key_head_dim and "
                     f"linear_value_head_dim, got {self.linear_key_head_dim} "
                     f"and {self.linear_value_head_dim}"
+                )
+            if self.linear_rule not in ("delta", "kda"):
+                raise ValueError(
+                    "linear_rule must be 'delta' or 'kda', got "
+                    f"{self.linear_rule!r}"
+                )
+            if self.linear_rule == "kda" and not (
+                -88.0 / 16 < self.linear_decay_bound < 0
+            ):
+                raise ValueError(
+                    "linear_decay_bound bounds a token's log decay from "
+                    "below so that a 16-token sub-chunk's stays inside "
+                    "float32 (ops/kda.py): it must lie in (-5.5, 0), got "
+                    f"{self.linear_decay_bound}"
                 )
             if self.decode:
                 raise ValueError(
@@ -470,10 +521,31 @@ class TransformerConfig:
                 f"first_k_dense {self.first_k_dense} must leave a trunk of "
                 f"the {self.num_layers} layers"
             )
-        if self.first_k_dense and self.layer_pattern:
+        if self.first_k_dense and set(self.layer_pattern) - set(
+            TWO_BRANCH_KINDS
+        ):
             raise ValueError(
-                "first_k_dense puts dense layers before a trunk of layers of "
-                "one kind: it takes no layer_pattern"
+                "first_k_dense puts layers with a dense MLP before the "
+                "trunk: beside a layer_pattern every kind must be a mixer "
+                f"AND an MLP ({list(TWO_BRANCH_KINDS)}), got "
+                f"{list(self.layer_pattern)}"
+            )
+        if self.router_groups < 1 or self.router_topk_groups < 1 or (
+            self.router_groups > 1 and (
+                self.router_scoring != "sigmoid"
+                or self.num_experts % self.router_groups
+                or self.router_topk_groups > self.router_groups
+                or self.top_k > self.router_topk_groups
+                * (self.num_experts // self.router_groups)
+                or self.num_experts // self.router_groups < 2
+            )
+        ):
+            raise ValueError(
+                "a group-limited choice is a sigmoid router's: "
+                f"router_groups {self.router_groups} must divide num_experts "
+                f"{self.num_experts} into groups of two or more, and "
+                f"router_topk_groups {self.router_topk_groups} of them hold "
+                f"at least top_k {self.top_k} experts"
             )
         if self.first_k_dense and (
             self.num_layers - self.first_k_dense
@@ -494,14 +566,21 @@ class TransformerConfig:
                 "kind to take"
             )
         latent = (
-            self.q_lora_rank, self.kv_lora_rank, self.qk_nope_head_dim,
+            self.kv_lora_rank, self.qk_nope_head_dim,
             self.qk_rope_head_dim, self.v_head_dim,
         )
-        if any(latent) and not all(latent):
+        if any(latent + (self.q_lora_rank,)) and not all(latent):
             raise ValueError(
-                "latent attention needs q_lora_rank, kv_lora_rank, "
-                "qk_nope_head_dim, qk_rope_head_dim and v_head_dim "
-                f"together, got {latent}"
+                "latent attention needs kv_lora_rank, qk_nope_head_dim, "
+                "qk_rope_head_dim and v_head_dim together (q_lora_rank 0: q "
+                f"straight from the stream), got {latent}"
+            )
+        if self.attention_gate not in ("", "head_wise") or (
+            self.attention_gate and not self.latent_attention
+        ):
+            raise ValueError(
+                "attention_gate is '' or 'head_wise', and latent "
+                f"attention's, got {self.attention_gate!r}"
             )
         if not self.latent_attention:
             return
@@ -556,12 +635,16 @@ class TransformerConfig:
         if self.latent_attention:
             h, qk = self.num_heads, self.qk_nope_head_dim + self.qk_rope_head_dim
             attn = (
-                d * self.q_lora_rank + self.q_lora_rank * h * qk
-                + d * (self.kv_lora_rank + self.qk_rope_head_dim)
+                (
+                    d * self.q_lora_rank + self.q_lora_rank * h * qk
+                    + self.q_lora_rank
+                ) if self.q_lora_rank else d * h * qk
+            ) + (
+                d * (self.kv_lora_rank + self.qk_rope_head_dim)
                 + self.kv_lora_rank * h
                 * (self.qk_nope_head_dim + self.v_head_dim)
-                + h * self.v_head_dim * d
-                + self.q_lora_rank + self.kv_lora_rank
+                + h * self.v_head_dim * d + self.kv_lora_rank
+                + (d * h if self.attention_gate else 0)
             )
         else:
             h = self.resolved_head_dim * self.num_heads
@@ -610,9 +693,16 @@ class TransformerConfig:
     def _linear_mixer_params(self) -> int:
         """q, k, v, gate and output projections, the two gate
         projections, the convolution's taps, A_log, dt_bias and the output
-        norm's scale (models/linear_attention.py)."""
+        norm's scale (models/linear_attention.py); under ``kda`` the decay
+        projection is full rank ([d, H dk]) and dt_bias a channel's."""
         d, h = self.d_model, self.resolved_linear_heads
         dk, dv = self.linear_key_head_dim, self.linear_value_head_dim
+        if self.linear_rule == "kda":
+            return (
+                2 * d * h * dk + 3 * d * h * dv + d * h * dk + d * h
+                + self.linear_conv_kernel * h * (2 * dk + dv)
+                + h + h * dk + dv
+            )
         return (
             2 * d * h * dk + 3 * d * h * dv + 2 * d * h
             + self.linear_conv_kernel * h * (2 * dk + dv) + 2 * h + dv
@@ -684,6 +774,7 @@ def _attention(cfg: TransformerConfig):
             flash_block_q=cfg.flash_block_q,
             flash_block_kv=cfg.flash_block_kv,
             scale=cfg.attention_scale,
+            gate=cfg.attention_gate,
             name="attn",
         )
     return Attention(
@@ -726,6 +817,8 @@ def _experts(cfg: TransformerConfig):
         first_expert=cfg.first_expert,
         shared_d_ff=cfg.resolved_shared_d_ff,
         row_budget_multiple=cfg.moe_row_budget,
+        router_groups=cfg.router_groups,
+        router_topk_groups=cfg.router_topk_groups,
         name="moe",
     )
 
@@ -773,7 +866,19 @@ class Block(nn.Module):
             return _norm(cfg, name)(y)
 
         y = x if post else norm("ln_attn", x)
-        if self.kind == LINEAR_ATTENTION:
+        if self.kind == LINEAR_ATTENTION and cfg.linear_rule == "kda":
+            y = KimiDeltaAttention(
+                num_heads=cfg.resolved_linear_heads,
+                key_dim=cfg.linear_key_head_dim,
+                value_dim=cfg.linear_value_head_dim,
+                conv_taps=cfg.linear_conv_kernel,
+                decay_bound=cfg.linear_decay_bound,
+                norm_eps=cfg.norm_eps,
+                dtype=cfg.dtype,
+                param_dtype=cfg.param_dtype,
+                name="linear_attn",
+            )(y)
+        elif self.kind == LINEAR_ATTENTION:
             y = GatedDeltaNet(
                 num_heads=cfg.resolved_linear_heads,
                 key_dim=cfg.linear_key_head_dim,
@@ -1031,7 +1136,7 @@ class TransformerLM(nn.Module):
             carry = (x, aux0)
             for i in range(cfg.first_k_dense):
                 carry, _ = prefix_cls(
-                    cfg, FULL_ATTENTION, True, name=f"dense_{i}"
+                    cfg, cfg.layer_kind(i), True, name=f"dense_{i}"
                 )(carry, positions, segment_ids)
             x, aux0 = carry
         if cfg.pipeline_stages > 1:

@@ -1,0 +1,502 @@
+"""Kimi Delta Attention's rule (Kimi Linear, arXiv:2510.26692): the gated
+delta rule with a decay PER CHANNEL of the key, chunk-parallel, as Pallas
+kernels and as the same mathematics in ``jax.numpy``.
+
+Per head, with keys ``k_t`` in R^dk, values ``v_t`` in R^dv, a write
+strength ``beta_t`` and a log-decay VECTOR ``g_t`` in R^dk, ``g_t <= 0``
+(``a_t = exp(g_t)``), the rule keeps a state ``S`` in R^{dk x dv}::
+
+    S_t = (I - beta_t k_t k_t^T) Diag(a_t) S_{t-1} + beta_t k_t v_t^T
+    o_t = S_t^T q_t
+
+``ops/gated_delta_rule.py`` is this with one decay a head, and its chunk
+form rests on that: ``exp(b_t - b_i)`` is one number a pair of tokens, so
+the decay multiplies ``k_t . k_i`` AFTER the product.  Here it sits inside
+the sum over channels.  With ``G_t`` the running sum of ``g`` inside a
+chunk of ``C`` tokens and ``S`` the state the chunk starts from::
+
+    A'[t, i] = sum_c k_t[c] k_i[c] exp(G_t[c] - G_i[c])
+    A[t, i]  = beta_t A'[t, i]  for i < t, else 0;   T = (I + A)^-1
+    W = T (beta * K * exp(G))        U_v = T (beta * V)
+    U = U_v - W S                                  (the chunk's writes)
+    O = (Q * exp(G)) S + M U     M[t, i] = sum_c q_t[c] k_i[c]
+                                           exp(G_t[c] - G_i[c]),  i <= t
+    S' = Diag(exp(G_C)) S + (K * exp(G_C - G))^T U
+
+**The bound that makes it a product.**  ``A'`` and ``M`` are matrix
+products only once the exponent is split, ``(k_t exp(G_t - r)) . (k_i
+exp(r - G_i))``, and ``exp(r - G_i)`` overflows float32 unless the total
+decay between ``r`` and ``i`` is bounded.  The published gate is bounded
+below (``kda_lower_bound`` -5: ``-5 < g < 0``), and a chunk is cut into
+sub-chunks of ``SUB`` = 16 tokens: 16 x 5 = 80 < log(3.4e38) = 88.7.  The
+rows of sub-chunk ``a`` take ``r_a``, the running sum at its eighth token:
+``exp(G_t - r_a)`` then lies in e^-40 .. e^35 for its rows, ``exp(r_a -
+G_i)`` is at most 1 for every earlier column and at most e^40 for a column
+of the same sub-chunk; later columns are masked before the exponential.
+(With ``r_a`` at the sub-chunk's start the last row's factor is e^-80,
+and q's entries, 1e-2 x that, fall under float32's smallest normal number
+and are flushed: the last token of every sub-chunk then read 0.3% off.)
+One ``[2 SUB, dk] x [dk, C]`` product a sub-chunk gives its band of ``A'``
+and ``M``.  A caller whose ``g`` goes below ``-88 / SUB`` a token gets
+infinities: the mixer's gate cannot.
+
+**What lives where.**  As the scalar rule's kernels: one forward and one
+backward kernel over (groups of heads, chunks), the chunk axis sequential,
+the float32 state (its cotangent) in VMEM scratch from chunk to chunk,
+kept transposed ``[dv, dk]`` so that a decay scales its columns.  A grid
+step reads a chunk's q, k ``[C, dk]``, v ``[C, dv]``, g ``[C, dk]``
+(float32) and beta ``[C]`` and builds the running sums (log2 C shifted
+adds, exact), the scaled keys, ``A'``, ``T``, ``W``, ``U``, ``M`` in VMEM.
+HBM sees, forward: the inputs, ``o``, each chunk's start state in the
+operands' dtype, the largest ``|S|``; backward: those and ``do`` in, dq,
+dk, dv, dg, dbeta out.  The custom VJP keeps the inputs and the start
+states; under a layer's remat the forward kernel runs again for them.
+
+**Precision.**  Products take operands in the inputs' dtype and accumulate
+in float32; g, its running sums, ``T`` (built by halves in float32
+products of three bfloat16 passes, ``gated_delta_rule._unit_lower_inverse``)
+and the state are float32.  With float32 operands every product is float32.
+
+:func:`kda` picks by shape (:func:`plan`): the kernels where both head
+widths are whole lane tiles, the chunked ``jax.numpy`` form (the same
+chunk mathematics under ``vmap`` and ``lax.scan``, differentiated by JAX)
+anywhere else.  The chunk is 128 tokens and a grid step holds four heads,
+chosen on the chip at 2 x 8192 tokens and 32 heads of 128 / 128 (PERF.md
+§6): forward 10.4 ms and forward with backward 23.8 ms a layer, against
+15.1 and 33.8 at chunks of 64 (whose 64 x 64 products fill a quarter of
+the matrix unit, though ``T``'s 2 (log2 C - 1) float32 products grow with
+C^3) and 11.4 and 25.3 at one head a step.
+"""
+
+from __future__ import annotations
+
+import functools
+import types
+from typing import Tuple
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from dlrover_tpu.ops import backend
+from dlrover_tpu.ops.gated_delta_rule import (
+    _NT, _TN, _dot, _halves, _mm_f32, _to_col, _to_row,
+    _unit_lower_inverse,
+)
+from dlrover_tpu.ops.row_gather_sum import tile_rows
+
+F32 = jnp.float32
+SUB = 16            # tokens a sub-chunk: SUB x the gate's bound < log(f32 max)
+_MID = SUB // 2 - 1  # the token of a sub-chunk whose running sum is its r_a
+CHUNK = 128
+LANES = 128
+_HEADS_PER_STEP = 4
+_TOP_LANES = 128
+
+
+def _cumsum_rows(x, tok, roll):
+    """Running sum down the rows of ``x`` [C, w], exact: log2(C) shifted
+    adds.  ``roll(x, n)`` moves row r to row r + n."""
+    shift = 1
+    while shift < x.shape[0]:
+        x = x + jnp.where(tok >= shift, roll(x, shift), 0.0)
+        shift *= 2
+    return x
+
+
+def _chunk_tensors(q, k, v, g, beta_row, start, roll):
+    """What a chunk builds from its own tokens and the state it starts
+    from, forward and backward alike.  ``q``, ``k`` [C, dk], ``v`` [C, dv],
+    ``g`` [C, dk] float32, ``beta_row`` [1, C] float32, ``start`` [dv, dk]
+    in the operands' dtype."""
+    cd = v.dtype
+    c, dk = k.shape
+    row = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    lower, strict, eye = col <= row, col < row, col == row
+    tok = jax.lax.broadcasted_iota(jnp.int32, (c, dk), 0)
+    run = _cumsum_rows(g, tok, roll)                       # G  [C, dk]
+    total = run[c - 1:, :]                                 # G_C  [1, dk]
+    # r_a: the running sum in the middle of sub-chunk a
+    refs = [
+        run[a * SUB + _MID: a * SUB + _MID + 1, :] for a in range(c // SUB)
+    ]
+    own_ref = refs[0]
+    for a in range(1, c // SUB):
+        own_ref = jnp.where(tok >= a * SUB, refs[a], own_ref)
+    e_row = jnp.exp(run - own_ref)                  # e^-40 .. e^35
+    q32, k32 = q.astype(F32), k.astype(F32)
+    k_r, q_r = (k32 * e_row).astype(cd), (q32 * e_row).astype(cd)
+    e_cols, k_cols, bands_kk, bands_qk = [], [], [], []
+    for a in range(c // SUB):
+        lo, hi = a * SUB, (a + 1) * SUB
+        # columns past the sub-chunk are masked BEFORE the exponential
+        e_col = jnp.exp(jnp.where(tok < hi, refs[a] - run, -jnp.inf))
+        k_c = (k32 * e_col).astype(cd)
+        band = _dot(jnp.concatenate([k_r[lo:hi], q_r[lo:hi]], axis=0),
+                    k_c, _NT)                              # [2 SUB, C]
+        e_cols.append(e_col)
+        k_cols.append(k_c)
+        bands_kk.append(band[:SUB])
+        bands_qk.append(band[SUB:])
+    kk = jnp.where(strict, jnp.concatenate(bands_kk, axis=0), 0.0)   # A'
+    qk = jnp.where(lower, jnp.concatenate(bands_qk, axis=0), 0.0)    # M
+    beta_col = _to_col(beta_row, eye)
+    t = _unit_lower_inverse(kk * beta_col, row, col, exact=cd == F32)
+    gamma, e_end = jnp.exp(run), jnp.exp(total - run)
+    k_g, q_g = (k32 * gamma).astype(cd), (q32 * gamma).astype(cd)
+    t_b = (t * beta_row).astype(cd)
+    w = _dot(t_b, k_g).astype(cd)
+    return types.SimpleNamespace(
+        lower=lower, strict=strict, eye=eye, tok=tok, q32=q32, k32=k32,
+        e_row=e_row, k_r=k_r, q_r=q_r, e_cols=e_cols,
+        k_cols=k_cols, kk=kk, within=qk.astype(cd), beta_col=beta_col, t=t,
+        gamma=gamma, e_end=e_end, gamma_end=jnp.exp(total), k_g=k_g,
+        q_g=q_g, t_b=t_b, w=w,
+        writes=(_dot(t_b, v) - _dot(w, start, _NT)).astype(cd),      # U
+        k_end=(k32 * e_end).astype(cd),
+    )
+
+
+def _chunk_forward(x, state, start):
+    """``(o [C, dv] float32, the chunk's end state [dv, dk] float32)``."""
+    o = _dot(x.q_g, start, _NT) + _dot(x.within, x.writes)
+    return o, state * x.gamma_end + _dot(x.writes, x.k_end, _TN)
+
+
+def _absmax(a):
+    return jnp.max(
+        jnp.max(jnp.abs(a), axis=1, keepdims=True), axis=0, keepdims=True
+    )
+
+
+def _roll_rows(x, n):
+    return pltpu.roll(x, n, 0)
+
+
+def _fwd_kernel(
+    q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, start_ref, top_ref, state,
+    *, heads,
+):
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        state[...] = jnp.zeros_like(state)
+        top_ref[...] = jnp.zeros_like(top_ref)
+
+    for h in range(heads):
+        v = v_ref[h]
+        start = state[h].astype(v.dtype)                   # [dv, dk]
+        start_ref[h, 0] = start
+        x = _chunk_tensors(
+            q_ref[h], k_ref[h], v, g_ref[h], beta_ref[h, 0], start,
+            _roll_rows,
+        )
+        o, end = _chunk_forward(x, state[h], start)
+        state[h] = end
+        top_ref[h] = jnp.maximum(top_ref[h], _absmax(end))
+        o_ref[h] = o.astype(o_ref.dtype)
+
+
+def _bwd_kernel(
+    q_ref, k_ref, v_ref, g_ref, beta_ref, start_ref, do_ref,
+    dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, d_state,
+    *, heads,
+):
+    """One chunk, walked last to first.  ``d_state`` holds the cotangent
+    of the chunk's END state on entry and of its start state on exit."""
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        d_state[...] = jnp.zeros_like(d_state)
+
+    for h in range(heads):
+        v, do = v_ref[h], do_ref[h]
+        cd = v.dtype
+        exact = cd == F32
+        beta_row = beta_ref[h, 0]
+        start = start_ref[h, 0]                            # [dv, dk]
+        x = _chunk_tensors(
+            q_ref[h], k_ref[h], v, g_ref[h], beta_row, start, _roll_rows
+        )
+        c = v.shape[0]
+        q32, k32, tok = x.q32, x.k32, x.tok
+        d_end = d_state[h]
+        d_end_cd = d_end.astype(cd)
+
+        # O = q_g S^T + M U;  S' = gamma_C S + U^T k_end
+        d_u = _dot(x.within, do, _TN) + _dot(x.k_end, d_end_cd, _NT)
+        d_u_cd = d_u.astype(cd)
+        d_qk = jnp.where(x.lower, _dot(do, x.writes, _NT), 0.0)
+        d_q_g = _dot(do, start)
+        d_k_end = _dot(x.writes, d_end_cd)
+        d_gamma_end = jnp.sum(
+            d_end * start.astype(F32), axis=0, keepdims=True
+        )
+        # U = t_b V - W S^T
+        d_state[h] = (
+            d_end * x.gamma_end + _dot(do, x.q_g, _TN)
+            - _dot(d_u_cd, x.w, _TN)
+        )
+        d_w = (-_dot(d_u_cd, start)).astype(cd)
+        # W = t_b k_g with t_b = T * beta (columns)
+        d_t_b = _dot(d_w, x.k_g, _NT) + _dot(d_u_cd, v, _NT)
+        d_k_g = _dot(x.t_b, d_w, _TN)
+        dv_ref[h] = _dot(x.t_b, d_u_cd, _TN).astype(dv_ref.dtype)
+        d_beta_row = jnp.sum(d_t_b * x.t, axis=0, keepdims=True)
+        # T = (I + A)^-1:  dA = -T^T dT T^T
+        t_halves = _halves(x.t, exact)
+        d_a = _mm_f32(
+            _halves(
+                _mm_f32(t_halves, _halves(d_t_b * beta_row, exact), _TN),
+                exact,
+            ),
+            t_halves, _NT,
+        )
+        d_a = jnp.where(x.strict, -d_a, 0.0)
+        # A = beta_t A'
+        d_kk = d_a * x.beta_col
+        d_beta_col = jnp.sum(d_a * x.kk, axis=1, keepdims=True)
+        # the bands: [k_r; q_r]_a k_c_a^T
+        d_kk_cd, d_qk_cd = d_kk.astype(cd), d_qk.astype(cd)
+        d_run = jnp.zeros_like(x.gamma)
+        d_k = jnp.zeros_like(k32)
+        d_rows_k, d_rows_q = [], []
+        for a in range(c // SUB):
+            lo, hi = a * SUB, (a + 1) * SUB
+            d_band = jnp.concatenate([d_kk_cd[lo:hi], d_qk_cd[lo:hi]], axis=0)
+            d_rows = _dot(d_band, x.k_cols[a])             # [2 SUB, dk]
+            d_rows_k.append(d_rows[:SUB])
+            d_rows_q.append(d_rows[SUB:])
+            d_k_c = _dot(
+                d_band,
+                jnp.concatenate([x.k_r[lo:hi], x.q_r[lo:hi]], axis=0), _TN,
+            ) * x.e_cols[a]                                # [C, dk]
+            d_k = d_k + d_k_c
+            # e_col = exp(r_a - G): -z to G, z's sum to the row r_a reads
+            z = d_k_c * k32
+            d_run = d_run - z + jnp.where(
+                tok == lo + _MID, jnp.sum(z, axis=0, keepdims=True), 0.0
+            )
+        # k_r = k e_row, q_r = q e_row with e_row = exp(G - r_own)
+        d_k_r = jnp.concatenate(d_rows_k, axis=0) * x.e_row
+        d_q_r = jnp.concatenate(d_rows_q, axis=0) * x.e_row
+        z = d_k_r * k32 + d_q_r * q32
+        d_run = d_run + z
+        for a in range(c // SUB):
+            mine = (tok >= a * SUB) & (tok < (a + 1) * SUB)
+            d_run = d_run - jnp.where(
+                tok == a * SUB + _MID,
+                jnp.sum(jnp.where(mine, z, 0.0), axis=0, keepdims=True), 0.0,
+            )
+        # k_g = k gamma, q_g = q gamma, k_end = k e_end
+        d_k_g, d_q_g = d_k_g * x.gamma, d_q_g * x.gamma
+        d_k_end = d_k_end * x.e_end
+        z_end = d_k_end * k32
+        d_run = d_run + d_k_g * k32 + d_q_g * q32 - z_end
+        d_total = (
+            jnp.sum(z_end, axis=0, keepdims=True) + d_gamma_end * x.gamma_end
+        )
+        d_run = d_run + jnp.where(tok == c - 1, d_total, 0.0)
+        # G = cumsum(g):  dg_t = the sum of dG_j over j >= t
+        dg_ref[h] = (
+            jnp.sum(d_run, axis=0, keepdims=True)
+            - _cumsum_rows(d_run, tok, _roll_rows) + d_run
+        )
+        dbeta_ref[h, 0] = d_beta_row + _to_row(d_beta_col, x.eye)
+        dq_ref[h] = (d_q_r + d_q_g).astype(dq_ref.dtype)
+        dk_ref[h] = (d_k + d_k_r + d_k_g + d_k_end).astype(dk_ref.dtype)
+
+
+def _heads_per_step(heads: int) -> int:
+    return max(n for n in range(1, _HEADS_PER_STEP + 1) if heads % n == 0)
+
+
+def _specs(group, chunk, widths, index):
+    return [pl.BlockSpec((group, chunk, width), index) for width in widths]
+
+
+@jax.jit
+def _forward(q, k, v, g, beta):
+    """``q, k`` [BH, S, dk], ``v`` [BH, S, dv], ``g`` [BH, S, dk] float32,
+    ``beta`` [BH, N, 1, C] float32.  Returns ``o`` [BH, S, dv], the chunks'
+    start states [BH, N, dv, dk] (both in ``v``'s dtype) and each head's
+    largest ``|S|`` at a chunk's end [BH]."""
+    heads, s, dk = q.shape
+    dv = v.shape[-1]
+    n, chunk = beta.shape[1], beta.shape[-1]
+    group = _heads_per_step(heads)
+
+    def tokens(i, c):
+        return (i, c, 0)
+
+    def scalars(i, c):
+        return (i, c, 0, 0)
+
+    o, starts, top = pl.pallas_call(
+        functools.partial(_fwd_kernel, heads=group),
+        grid=(heads // group, n),
+        in_specs=_specs(group, chunk, (dk, dk, dv, dk), tokens) + [
+            pl.BlockSpec((group, 1, 1, chunk), scalars),
+        ],
+        out_specs=[
+            pl.BlockSpec((group, chunk, dv), tokens),
+            pl.BlockSpec((group, 1, dv, dk), scalars),
+            pl.BlockSpec((group, 1, _TOP_LANES), lambda i, c: (i, 0, 0)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((heads, s, dv), v.dtype),
+            jax.ShapeDtypeStruct((heads, n, dv, dk), v.dtype),
+            jax.ShapeDtypeStruct((heads, 1, _TOP_LANES), F32),
+        ],
+        scratch_shapes=[pltpu.VMEM((group, dv, dk), F32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+        ),
+        interpret=backend.interpret(),
+        name="kda_fwd",
+    )(q, k, v, g, beta)
+    return o, starts, top[:, 0, 0]
+
+
+@jax.jit
+def _backward(q, k, v, g, beta, starts, do):
+    heads, s, dk = q.shape
+    dv = v.shape[-1]
+    n, chunk = beta.shape[1], beta.shape[-1]
+    group = _heads_per_step(heads)
+
+    def tokens(i, c):
+        return (i, n - 1 - c, 0)
+
+    def scalars(i, c):
+        return (i, n - 1 - c, 0, 0)
+
+    per_token = pl.BlockSpec((group, 1, 1, chunk), scalars)
+    return pl.pallas_call(
+        functools.partial(_bwd_kernel, heads=group),
+        grid=(heads // group, n),
+        in_specs=_specs(group, chunk, (dk, dk, dv, dk), tokens) + [
+            per_token, pl.BlockSpec((group, 1, dv, dk), scalars),
+        ] + _specs(group, chunk, (dv,), tokens),
+        out_specs=_specs(group, chunk, (dk, dk, dv, dk), tokens) + [
+            per_token,
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct(k.shape, k.dtype),
+            jax.ShapeDtypeStruct(v.shape, v.dtype),
+            jax.ShapeDtypeStruct(g.shape, F32),
+            jax.ShapeDtypeStruct(beta.shape, F32),
+        ],
+        scratch_shapes=[pltpu.VMEM((group, dv, dk), F32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+        ),
+        interpret=backend.interpret(),
+        name="kda_bwd",
+    )(q, k, v, g, beta, starts, do)
+
+
+def _rule_fwd(q, k, v, g, beta):
+    o, starts, top = _forward(q, k, v, g, beta)
+    return (o, top), (q, k, v, g, beta, starts)
+
+
+@jax.custom_vjp
+def _rule(q, k, v, g, beta):
+    return _rule_fwd(q, k, v, g, beta)[0]
+
+
+def _rule_bwd(res, cts):
+    do, _ = cts          # the largest |S| is a reading, not a result
+    return tuple(_backward(*res, do))
+
+
+_rule.defvjp(_rule_fwd, _rule_bwd)
+
+
+def _rule_xla(q, k, v, g, beta):
+    """:func:`_rule` in ``jax.numpy``: the chunk's mathematics under
+    ``vmap`` over the heads and ``lax.scan`` over the chunks."""
+    heads, s, dk = q.shape
+    dv = v.shape[-1]
+    n, chunk = beta.shape[1], beta.shape[-1]
+
+    def chunks(a):
+        return jnp.moveaxis(a.reshape(heads, n, chunk, a.shape[-1]), 1, 0)
+
+    def roll(x, shift):
+        return jnp.roll(x, shift, axis=0)
+
+    def one_head(state, q, k, v, g, beta_row):
+        start = state.astype(v.dtype)
+        x = _chunk_tensors(q, k, v, g, beta_row, start, roll)
+        o, end = _chunk_forward(x, state, start)
+        return o.astype(v.dtype), end
+
+    def step(carry, xs):
+        state, top = carry
+        o, end = jax.vmap(one_head)(state, *xs)
+        return (end, jnp.maximum(top, jnp.abs(end).max(axis=(1, 2)))), o
+
+    (_, top), o = jax.lax.scan(
+        step,
+        (jnp.zeros((heads, dv, dk), F32), jnp.zeros((heads,), F32)),
+        (chunks(q), chunks(k), chunks(v), chunks(g), jnp.moveaxis(beta, 1, 0)),
+    )
+    return jnp.moveaxis(o, 0, 1).reshape(heads, s, dv), top
+
+
+def plan(key_dim: int, value_dim: int) -> str:
+    """``kernel`` where :func:`kda` runs the Pallas kernels on heads of
+    these widths (both whole lane tiles), ``xla`` where it runs the chunked
+    ``jax.numpy`` form."""
+    return "xla" if key_dim % LANES or value_dim % LANES else "kernel"
+
+
+def kda(
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    g: jax.Array,
+    beta: jax.Array,
+    chunk: int = CHUNK,
+) -> Tuple[jax.Array, jax.Array]:
+    """``q, k`` [B, S, H, dk] (already normalised and scaled), ``v``
+    [B, S, H, dv], ``g`` [B, S, H, dk] (log decay a channel, above
+    ``-88 / SUB`` a token: see the module's text) and ``beta`` [B, S, H].
+    Returns the outputs [B, S, H, dv] in ``v``'s dtype and, under
+    ``stop_gradient``, the largest ``|S|`` entry at any chunk boundary.
+
+    A sequence that is no whole number of chunks is padded with tokens
+    that neither write (``beta`` 0) nor decay (``g`` 0).  ``chunk`` is a
+    power of two, whole sub-chunks and whole tiles of the operands'
+    dtype."""
+    cd = v.dtype
+    if chunk & (chunk - 1) or chunk % SUB or chunk % tile_rows(cd):
+        raise ValueError(
+            f"chunk must be a power of two, a multiple of the {SUB}-token "
+            f"sub-chunk and of the {tile_rows(cd)} rows of a "
+            f"{jnp.dtype(cd).name} tile, got {chunk}"
+        )
+    if q.dtype != cd or k.dtype != cd:
+        raise ValueError(
+            f"q, k, v must share a dtype, got {q.dtype}, {k.dtype}, {cd}"
+        )
+    b, s, h, dk = q.shape
+    pad = -s % chunk
+    n = (s + pad) // chunk
+
+    def heads_first(a):
+        """[B, S, H, ...] -> [B * H, S + pad, ...]"""
+        a = jnp.pad(a, [(0, 0), (0, pad)] + [(0, 0)] * (a.ndim - 2))
+        return jnp.moveaxis(a, 2, 1).reshape(b * h, s + pad, *a.shape[3:])
+
+    rule = _rule if plan(dk, v.shape[-1]) == "kernel" else _rule_xla
+    o, top = rule(
+        heads_first(q), heads_first(k), heads_first(v),
+        heads_first(g.astype(F32)),
+        heads_first(beta.astype(F32)).reshape(b * h, n, 1, chunk),
+    )
+    o = jnp.moveaxis(o.reshape(b, h, s + pad, -1), 1, 2)
+    return o[:, :s], jax.lax.stop_gradient(jnp.max(top))

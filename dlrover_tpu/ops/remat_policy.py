@@ -20,6 +20,8 @@ The saveable names are emitted by the model code via
 (ops/flash_attention.py custom_vjp fwd — flash impl only), ``delta_out``
 (models/linear_attention.py: the gated delta rule's output; a model
 without such a layer emits no such name, and its step is what it was),
+``kda_out`` (the same file's ``KimiDeltaAttention``: the per-channel
+rule's output, kept as the scalar rule's is and for the same reason),
 ``ssd_out`` (models/mamba2.py: the state-space scan's output, likewise
 only where a model has such a layer),
 ``latent_k`` / ``latent_v`` (models/attention.py ``LatentAttention``: the
@@ -131,7 +133,7 @@ register(RematPolicy(
 # rule's and the scan's.
 register(RematPolicy(
     "flash_only",
-    saved_names=("flash_out", "flash_lse", "delta_out", "ssd_out"),
+    saved_names=("flash_out", "flash_lse", "delta_out", "kda_out", "ssd_out"),
     hbm_act_per_token_layer=2.05, recompute_fraction=0.7,
 ))
 

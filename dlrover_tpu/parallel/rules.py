@@ -48,7 +48,8 @@ ACT_EMBED = "act_embed"    # activation embedding dim
 EMBED = "embed"            # param embedding dim (FSDP shard dim)
 MLP = "mlp"                # param MLP hidden dim (TP col split)
 HEADS = "heads"            # param attention heads dim (TP split)
-KV = "kv"                  # param per-head dim
+KV = "kv"                  # param per-head dim (a KDA head's dk decay
+                           # channels too: whole on every device)
 LATENT = "latent"          # latent attention's low-rank dim (q 1536, kv 512+64)
 SSM_INNER = "ssm_inner"    # a state-space mixer's fused columns [z|x|B|C|dt]
 SSM_HEADS = "ssm_heads"    # its per-head scalars (A_log, D, dt_bias)

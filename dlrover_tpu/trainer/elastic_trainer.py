@@ -360,6 +360,7 @@ class ElasticTrainer:
                 "ssm_tiles_per_group": self._ssm_tiles_per_group(),
                 "short_conv": self._short_conv(),
                 "row_moves": self._row_moves(),
+                "kda": self._kda(),
             }
             logger.info("compile warmup: %s", detail)
             telemetry.event(
@@ -487,6 +488,17 @@ class ElasticTrainer:
         cfg = self.model_config
         return cfg.ssm_num_heads // cfg.ssm_groups // per_step
 
+    def _kda(self) -> str:
+        """How the step program's per-channel delta rule runs, for the
+        ``compile`` event: ``kernel`` / ``xla`` (``ops/kda.py`` ``plan``,
+        which the rule asks), ``none`` for a model without a KDA layer."""
+        cfg = self.model_config
+        if not cfg.num_linear_layers or cfg.linear_rule != "kda":
+            return "none"
+        from dlrover_tpu.ops import kda
+
+        return kda.plan(cfg.linear_key_head_dim, cfg.linear_value_head_dim)
+
     def _row_moves(self) -> str:
         """Which path a token's ``top_k`` rows take through the dropless
         dispatch's combine and the scatter's transpose, for the ``compile``
@@ -527,6 +539,7 @@ class ElasticTrainer:
             paths.add(linear_attention.conv_path(
                 seq, cfg.resolved_linear_heads, cfg.linear_key_head_dim,
                 cfg.linear_value_head_dim, cfg.linear_conv_kernel,
+                gate_in_row=cfg.linear_rule != "kda",
             ))
         return "+".join(sorted(paths)) or "none"
 
@@ -1382,9 +1395,15 @@ class ElasticTrainer:
             entropy, drop, load, pad_share, max_load = (
                 moe_lib.split_stats(vec)
             )
-            pairs_here, bias_absmax = (
-                (1.0, 0.0) if share is None else np.asarray(share, np.float64)
+            share = (1.0, 0.0) if share is None else np.asarray(
+                share, np.float64
             )
+            pairs_here, bias_absmax = share[:2]
+            # a group-limited router's layers also count the tokens with a
+            # pair here (models/moe.py ``SHARE_STATS_NAME``)
+            grouped = {} if len(share) < 3 else {
+                "tokens_here": float(share[2])
+            }
             # Of a token's top_k row fetches, the share that is issued: all
             # of them, but where the live-only kernel runs those of the
             # pairs the plan kept (routed here, less the dropped ones).
@@ -1409,6 +1428,9 @@ class ElasticTrainer:
                 pairs_here=float(pairs_here),
                 bias_absmax=float(bias_absmax),
                 row_fetch_share=row_fetch_share,
+                groups=int(self.model_config.router_groups),
+                topk_group=int(self.model_config.router_topk_groups),
+                **grouped,
             )
         if "mtp_loss" in metrics and step % cfg.report_every == 0:
             # The multi-token-prediction module's own cross-entropy (token
@@ -1458,10 +1480,15 @@ class ElasticTrainer:
         )
         if read is None:
             return None
+        rule = self.model_config.linear_rule
+        mixer = (
+            linear_attention.KimiDeltaAttention if rule == "kda"
+            else linear_attention.GatedDeltaNet
+        )
         telemetry.event(
             "linear_attn", step=step,
             layers=self.model_config.num_linear_layers,
-            chunk=linear_attention.GatedDeltaNet.chunk, **read,
+            chunk=mixer.chunk, rule=rule, **read,
         )
         return read["state_absmax"]
 
@@ -1493,10 +1520,14 @@ class ElasticTrainer:
         with pipeline_counters().host_block(stats_name, steps=(step,)):
             vec = np.asarray(jax.device_get(stats), np.float64)
         first, second, absmax = linear_attention.split_stats(vec)
-        return {
+        read = {
             means[0]: float(first), means[1]: float(second),
             "state_absmax": float(absmax),
         }
+        if vec.size > 3:
+            # a per-channel rule's smallest mean decay of a channel
+            read["min_alpha"] = float(vec[3])
+        return read
 
     def _emit_memory_event(self, step: int):
         """One flat-attr ``memory`` event: allocator truth + classified

@@ -132,6 +132,15 @@ def _delta_rule():
     return jax.grad(loss, argnums=(0, 1, 2, 3, 4))
 
 
+def _kda():
+    from dlrover_tpu.ops.kda import kda
+
+    def loss(q, k, v, g, beta):
+        return kda(q, k, v, g, beta)[0].astype(F32).sum()
+
+    return jax.grad(loss, argnums=(0, 1, 2, 3, 4))
+
+
 def _ssd(chunk=128):
     from dlrover_tpu.ops.ssd import ssd
 
@@ -273,6 +282,11 @@ CASES = [
     ("delta_rule_olmo_hybrid", _delta_rule,
      [((2, 8192, 30, 96), BF16)] * 2 + [((2, 8192, 30, 192), BF16)]
      + [((2, 8192, 30), F32)] * 2, {}, 2),
+    # Ling-3.0-flash's KDA layers: 2 x 8192 tokens, 32 heads of 128 / 128, a
+    # float32 log decay a channel: the forward kernel and the backward kernel
+    ("kda_ling_flash", _kda,
+     [((2, 8192, 32, 128), BF16)] * 3 + [((2, 8192, 32, 128), F32)]
+     + [((2, 8192, 32), F32)], {}, 2),
     # Nemotron-3-Nano's Mamba-2 layers: 2 x 8192 tokens, 64 heads of 64, a
     # 128-wide state, 8 groups: the forward kernel and the backward kernel
     ("ssd_nemotron_h", _ssd,

@@ -21,8 +21,10 @@ from test_joyai_reference import config
     (dict(num_experts=0, experts_held=0, first_expert=0, router_bias=False,
           router_scoring="softmax", moe_dispatch="einsum"),
      "describe an expert layer"),
-    (dict(layer_pattern=("full_attention",), num_layers=3),
-     "takes no layer_pattern"),
+    # (since PR 44 a dense prefix goes ahead of a pattern of two-branch
+    # kinds; what is refused is a prefix beside one-branch kinds)
+    (dict(layer_pattern=("full_attention", "experts"), num_layers=3),
+     "every kind must be a mixer AND an MLP"),
     (dict(mtp_depth=2), "mtp_depth must be 0 or 1"),
     (dict(router_scoring="softmax"), "router_bias corrects a sigmoid"),
     (dict(pipeline_stages=3, first_k_dense=1, num_layers=5),

@@ -1,0 +1,152 @@
+"""Kimi Delta Attention's rule (``ops/kda.py``): the chunked ``jax.numpy``
+form and the Pallas kernels (in interpret mode, forward and backward)
+against the token-by-token recurrence of Ling-3.0-flash's plain reference:
+outputs and every gradient, with the log decay near its bound of -5, near 0
+and mixed, on sequences that are no whole chunk; the largest boundary
+state; the chunks it refuses.
+
+Tolerances, float32 against float32: the chunked form splits every decay
+``exp(G_t - G_i)`` into two factors around a sub-chunk's middle (each up to
+e^40, so a product carries the rounding of two exponentials of arguments up
+to 40: 40 x 6e-8 relative), orders its sums differently from the recurrence
+and builds ``(I + A)^-1`` by products: 2e-5 of the largest entry."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from dlrover_tpu.models.references import ling_flash as reference
+from dlrover_tpu.ops import kda as kda_lib
+
+BATCH, HEADS = 2, 2
+# where the gate's pre-activation is centred: every channel near the bound
+# of -5, spread over (-5, 0), every channel near 0
+NEAR = {"bound": 6.0, "mixed": 0.0, "zero": -6.0}
+
+
+def rule_inputs(seed, length, dk, dv, near):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 6)
+    shape = (BATCH, length, HEADS)
+    q = jax.random.normal(keys[0], shape + (dk,))
+    k = jax.random.normal(keys[1], shape + (dk,))
+    q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) * dk ** -0.5
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    v = jax.random.normal(keys[2], shape + (dv,))
+    g = -5.0 * jax.nn.sigmoid(
+        2.0 * jax.random.normal(keys[3], shape + (dk,)) + NEAR[near]
+    )
+    beta = jax.nn.sigmoid(jax.random.normal(keys[4], shape))
+    do = jax.random.normal(keys[5], shape + (dv,))
+    return (q, k, v, g, beta), do
+
+
+def rule_and_grads(rule, args, do):
+    with jax.default_matmul_precision("highest"):
+        return jax.jit(jax.value_and_grad(
+            lambda *a: (rule(*a) * do).sum(), argnums=(0, 1, 2, 3, 4),
+        ))(*args)[1], jax.jit(rule)(*args)
+
+
+# (dk, dv): the widths decide the path (``plan``)
+WIDTHS = {"xla": (16, 24), "kernel": (128, 128)}
+
+
+@pytest.mark.parametrize("near", sorted(NEAR))
+@pytest.mark.parametrize("length,chunk", [(80, 32), (64, 64), (40, 16)])
+@pytest.mark.parametrize("path", sorted(WIDTHS))
+def test_chunked_rule_is_the_recurrence(path, length, chunk, near):
+    """Outputs and every gradient; 80 and 40 are no multiples of their
+    chunks, and every case but (64, 64) crosses a chunk boundary."""
+    dk, dv = WIDTHS[path]
+    assert kda_lib.plan(dk, dv) == path
+    args, do = rule_inputs(length + chunk, length, dk, dv, near)
+    if near == "bound":
+        assert float(args[3].mean()) < -4.8
+    if near == "zero":
+        assert float(args[3].mean()) > -0.2
+    want_grads, want = rule_and_grads(reference.kda_recurrence, args, do)
+    got_grads, got = rule_and_grads(
+        lambda *a: kda_lib.kda(*a, chunk=chunk)[0], args, do
+    )
+    scale = float(jnp.abs(want).max())
+    assert float(jnp.abs(got - want).max()) <= 2e-5 * scale
+    for name, g, w in zip("q k v g beta".split(), got_grads, want_grads):
+        top = float(jnp.abs(w).max())
+        assert top > 0, name
+        assert float(jnp.abs(g - w).max()) <= 2e-5 * top + 1e-7, name
+
+
+def test_a_sub_chunk_at_the_bound_stays_finite_and_right():
+    """Every channel of every token at the bound itself: a sub-chunk's
+    total decay is 16 x 5 = 80, inside float32 only because the reference
+    sum sits in the sub-chunk's middle (factors e^-40 .. e^40)."""
+    (q, k, v, g, beta), _ = rule_inputs(3, 64, 128, 128, "mixed")
+    g = jnp.full_like(g, -5.0)
+    with jax.default_matmul_precision("highest"):
+        got = kda_lib.kda(q, k, v, g, beta)[0]
+        want = reference.kda_recurrence(q, k, v, g, beta)
+    assert bool(jnp.isfinite(got).all())
+    assert float(jnp.abs(got - want).max()) <= 2e-5 * float(
+        jnp.abs(want).max()
+    )
+
+
+@pytest.mark.parametrize("path", sorted(WIDTHS))
+def test_state_absmax_is_the_largest_boundary_state(path):
+    dk, dv = WIDTHS[path]
+    (q, k, v, g, beta), _ = rule_inputs(7, 64, dk, dv, "zero")
+    v = 3.0 * v
+    with jax.default_matmul_precision("highest"):
+        _, absmax = kda_lib.kda(q, k, v, g, beta, chunk=16)
+
+    def states(state, xs):
+        q_t, k_t, v_t, g_t, beta_t = xs
+        state = state * jnp.exp(g_t)[..., None]
+        read = jnp.einsum("bhk,bhkv->bhv", k_t, state)
+        state = state + beta_t[..., None, None] * k_t[..., None] * (
+            v_t - read
+        )[..., None, :]
+        return state, jnp.abs(state).max()
+
+    with jax.default_matmul_precision("highest"):
+        _, tops = jax.lax.scan(
+            states, jnp.zeros((BATCH, HEADS, dk, dv)),
+            tuple(jnp.moveaxis(a, 1, 0) for a in (q, k, v, g, beta)),
+        )
+    boundary = float(tops[15::16].max())
+    assert float(absmax) == pytest.approx(boundary, rel=1e-4)
+    assert float(absmax) > 0
+
+
+@pytest.mark.parametrize("chunk", [8, 24, 48])
+def test_a_chunk_that_is_no_whole_sub_chunks_raises_with_the_numbers(chunk):
+    (q, k, v, g, beta), _ = rule_inputs(0, 32, 16, 16, "mixed")
+    with pytest.raises(ValueError, match=f"16-token sub-chunk.*got {chunk}"):
+        kda_lib.kda(q, k, v, g, beta, chunk=chunk)
+
+
+def test_mixed_dtypes_raise():
+    (q, k, v, g, beta), _ = rule_inputs(0, 32, 16, 16, "mixed")
+    with pytest.raises(ValueError, match="share a dtype"):
+        kda_lib.kda(q.astype(jnp.bfloat16), k, v, g, beta)
+
+
+def test_bfloat16_operands_keep_a_float32_decay_and_state():
+    """The cell's precision: bfloat16 q, k, v, float32 g and state.  With
+    slow decay (where a state adds up hundreds of writes) the result lies
+    within bfloat16's rounding of the float32 one (read 4.1e-5 of a mean
+    entry of 1e-2), and the recurrence with a bfloat16 state does not
+    (9.5e-5); under fast decay both are the output's own rounding."""
+    (q, k, v, g, beta), _ = rule_inputs(5, 256, 128, 128, "zero")
+    low = tuple(a.astype(jnp.bfloat16) for a in (q, k, v))
+    exact = reference.kda_recurrence(
+        *(a.astype(jnp.float32) for a in low), g, beta
+    )
+    got = kda_lib.kda(*low, g, beta)[0].astype(jnp.float32)
+    lowered = reference.kda_recurrence(*low, g, beta, jnp.bfloat16).astype(
+        jnp.float32
+    )
+    err = float(jnp.abs(got - exact).mean())
+    assert err < 0.6 * float(jnp.abs(lowered - exact).mean())
+    assert err < 0.01 * float(jnp.abs(exact).mean())

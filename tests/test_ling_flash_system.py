@@ -1,0 +1,284 @@
+"""Ling-3.0-flash's parts through the rest of the system, one small CPU
+test each: the train step's first loss and its router-bias rule against
+the reference (512-less: 32 loads in 4 groups), the ``linear_attn``,
+``moe`` and ``compile`` events' new fields from the step's own sown stats,
+the scopes the benchmark reads, what ``flash_only`` keeps, and that a model
+without a KDA layer imports and traces none of this.  (Sizes and weights
+are ``tests/test_ling_flash_reference.py``'s: ``numerics``.)"""
+
+import dataclasses
+import json
+import os
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import reference_harness as harness
+import test_ling_flash_reference as numerics
+from dlrover_tpu.models import linear_attention
+from dlrover_tpu.models import moe as moe_lib
+from dlrover_tpu.models.references import ling_flash as ref
+from dlrover_tpu.models.transformer import TransformerLM
+from dlrover_tpu.ops import kda as kda_lib
+from dlrover_tpu.ops import remat_policy
+from dlrover_tpu.parallel import rules as lr
+from dlrover_tpu.runtime.mesh import ParallelConfig, build_mesh
+from dlrover_tpu.trainer import train_lib
+from test_ling_flash_reference import config, params, tokens  # noqa: F401
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+SEQ, BATCH, VOCAB = 32, 8, numerics.VOCAB
+
+
+def batches(n, seed=0):
+    rng = np.random.default_rng(seed)
+    rows = rng.integers(0, VOCAB, (n, BATCH, SEQ + 1), dtype=np.int32)
+    return [{"inputs": r[:, :-1], "targets": r[:, 1:]} for r in rows]
+
+
+def biases(tree):
+    return {
+        "/".join(k.key for k in path): np.asarray(leaf)
+        for path, leaf in jax.tree_util.tree_leaves_with_path(tree)
+        if path[-1].key == "router_bias"
+    }
+
+
+def test_the_train_step_s_first_loss_and_bias_move_are_the_reference_s(
+    params, tokens
+):
+    """The normal path: ``build_sharded_train``'s compiled step under the
+    policy the cell runs (the flash and KDA outputs kept).  Each expert
+    layer's bias moves by the unchanged rule on that layer's own counts
+    over ALL the experts, in their groups."""
+    cfg = config(attention_impl="flash", remat="flash_only",
+                 flash_block_q=8, flash_block_kv=8)
+    train = train_lib.build_sharded_train(
+        TransformerLM(cfg),
+        train_lib.make_optimizer("adafactor", learning_rate=1e-3),
+        build_mesh(ParallelConfig(data=1), devices=jax.devices()[:1]),
+        lr.DEFAULT_RULES, global_batch_size=numerics.BATCH,
+        seq_len=numerics.SEQ,
+    )
+    state = train.init(jax.random.PRNGKey(0))
+    state = state.replace(params=jax.tree.map(
+        lambda new, old: jax.device_put(
+            jnp.array(new, old.dtype, copy=True), old.sharding
+        ), params, state.params,
+    ))
+    before = biases(params)
+    batch = {"inputs": np.asarray(tokens[0]), "targets": np.asarray(tokens[1])}
+    with jax.default_matmul_precision("highest"):
+        new_state, metrics = train.step(
+            state, train_lib.shard_batch(batch, train)
+        )
+    want = ref.forward(dataclasses.asdict(cfg), params, *tokens)
+    assert abs(float(metrics["loss"]) - float(want["nll"].mean())) <= 1e-4
+    assert float(metrics["aux_loss"]) == 0.0
+    moved = biases(new_state.params)
+    assert sorted(moved) == [
+        "blocks/full_1/moe/router_bias", "blocks/linear_0/moe/router_bias",
+        "blocks/linear_2/moe/router_bias",
+    ]
+    # the trunk's layers in order: linear_0, full_1, linear_2
+    for slot, counts in zip(("linear_0", "full_1", "linear_2"), want["counts"]):
+        name = f"blocks/{slot}/moe/router_bias"
+        np.testing.assert_allclose(
+            moved[name][0],
+            ref.bias_rule(before[name][0], counts, cfg.router_bias_rate),
+            atol=1e-7, err_msg=name,
+        )
+    pairs, bias_absmax, tokens_here = np.asarray(
+        metrics[moe_lib.SHARE_STATS_NAME]
+    )
+    here = np.mean([float(c[8:16].sum() / c.sum()) for c in want["counts"]])
+    assert pairs == pytest.approx(here, rel=1e-5)
+    assert 0 < pairs < tokens_here <= 0.75 and bias_absmax > 0
+    alpha, beta, absmax = linear_attention.split_stats(
+        np.asarray(metrics[linear_attention.STATS_NAME])
+    )
+    min_alpha = float(np.asarray(metrics[linear_attention.STATS_NAME])[3])
+    assert 0 < min_alpha <= alpha <= 1 and 0 < beta < 1 and 0 < absmax < 100
+
+
+@pytest.mark.parametrize("metrics_lag", [0, 4])
+def test_fit_books_the_new_fields_from_the_step_itself(
+    metrics_lag, monkeypatch, tmp_path, one_step_program
+):
+    """Ten steps at ``report_every=5``: two ``linear_attn`` and two ``moe``
+    events carrying the step's own numbers, one ``compile`` event that
+    says how the rule runs; one trace of the step and no second forward."""
+    from dlrover_tpu.common import telemetry
+    from dlrover_tpu.trainer.elastic_trainer import (
+        ElasticTrainer,
+        TrainerConfig,
+    )
+
+    monkeypatch.setenv("DLROVER_TPU_JOB", f"ling_{tmp_path.name}")
+    monkeypatch.setenv("DLROVER_TPU_SOCKET_DIR", str(tmp_path / "socks"))
+    cfg = config(max_seq_len=SEQ)
+    seen = {}
+    with telemetry.recorder().open_tap() as tap:
+        trainer = ElasticTrainer(
+            cfg,
+            TrainerConfig(
+                global_batch_size=BATCH, seq_len=SEQ, learning_rate=1e-2,
+                optimizer="adafactor", ckpt_every=1000, report_every=5,
+                metrics_lag=metrics_lag, warmup_compile=True,
+            ),
+            client=None,
+        )
+        trainer.fit(
+            batches(10), max_steps=10,
+            on_step=lambda step, metrics: seen.update({step: metrics}),
+        )
+        taken = tap.take()
+    events = [e for e in taken if e[1] == "event"]
+    compiled = [e[-1] for e in taken if e[0] == "compile"]
+    if not metrics_lag:
+        # (the second case is handed the first's program: nothing compiles)
+        assert [e["kda"] for e in compiled] == ["xla"]
+        assert compiled[0]["short_conv"] == "xla"
+    linear = [e[4] for e in events if e[0] == "linear_attn"]
+    moe = [e[4] for e in events if e[0] == "moe"]
+    assert [e["step"] for e in linear] == [5, 10] == [e["step"] for e in moe]
+    for event in linear:
+        vec = np.asarray(
+            seen[event["step"]][linear_attention.STATS_NAME], np.float64
+        )
+        assert event["rule"] == "kda" and event["layers"] == 3
+        assert event["chunk"] == kda_lib.CHUNK == 128
+        assert event["mean_alpha"] == pytest.approx(float(vec[0]))
+        assert event["min_alpha"] == pytest.approx(float(vec[3]))
+        assert 0 < event["min_alpha"] <= event["mean_alpha"] <= 1
+        assert 0 < event["state_absmax"] < 1e3
+    for event in moe:
+        share = np.asarray(seen[event["step"]][moe_lib.SHARE_STATS_NAME])
+        assert event["groups"] == 4 and event["topk_group"] == 2
+        assert event["experts"] == 32 and event["held"] == 8
+        assert event["pairs_here"] == pytest.approx(float(share[0]))
+        assert event["tokens_here"] == pytest.approx(float(share[2]))
+        assert event["pairs_here"] < event["tokens_here"] <= 0.75
+        assert event["drop_fraction"] == 0.0
+        assert len(json.loads(event["load"])) == 32
+    assert train_lib.trace_count("train_step") == 1
+
+
+def _stub(cfg, seq=SEQ):
+    return type("Stub", (), {
+        "model_config": cfg, "config": type("C", (), {"seq_len": seq})(),
+    })()
+
+
+def test_the_compile_event_says_which_rule_runs():
+    from dlrover_tpu.models.olmo_hybrid import olmo_hybrid_config
+    from dlrover_tpu.models.transformer import TransformerConfig
+    from dlrover_tpu.trainer.elastic_trainer import ElasticTrainer
+
+    assert ElasticTrainer._kda(_stub(config())) == "xla"
+    wide = config(
+        linear_num_heads=2, linear_key_head_dim=128, linear_value_head_dim=128
+    )
+    assert ElasticTrainer._kda(_stub(wide)) == "kernel"
+    # the scalar rule's layers and a model without linear layers: none
+    hybrid = olmo_hybrid_config(
+        num_layers=4, d_model=32, num_heads=4, linear_num_heads=4,
+        linear_key_head_dim=8, linear_value_head_dim=16, vocab_size=128,
+    )
+    assert ElasticTrainer._kda(_stub(hybrid)) == "none"
+    assert ElasticTrainer._kda(_stub(TransformerConfig())) == "none"
+    # the convolution's row is [q | k | v] alone: at the published widths
+    # on 8192 tokens it runs as the kernel, as the hybrid's does
+    published = numerics.ling_flash_config(
+        num_layers=7, first_k_dense=1, experts_held=32
+    )
+    assert ElasticTrainer._short_conv(_stub(published, 8192)) == "kernel"
+    assert ElasticTrainer._kda(_stub(published, 8192)) == "kernel"
+    assert ElasticTrainer._row_moves(_stub(published, 8192)) == "xla"
+
+
+def test_the_scopes_the_benchmark_reads_reach_the_compiled_text(tokens):
+    cfg = config()
+    weights = numerics.share(cfg)
+    text = jax.jit(
+        lambda p, t: TransformerLM(cfg).apply({"params": p}, t)[0]
+    ).lower(weights, tokens[0]).as_text(debug_info=True)
+    for scope in (
+        "linear_attn/qkv", "linear_attn/conv", "linear_attn/gates",
+        "linear_attn/kda", "linear_attn/out_norm", "linear_attn/wo",
+        "attn/q_b", "attn/kv_a", "attn/kv_b", "attn/rope", "attn/gate",
+        "attn/wo", "moe/router", "moe/shared",
+    ):
+        assert scope in text, scope
+    assert "attn/q_a" not in text
+    assert "linear_attn/delta_rule" not in text
+    # the group limit is the router's work
+    assert "moe/router" in text and "top_k" in text
+
+
+def test_flash_only_keeps_the_rule_s_output_under_its_own_name():
+    kept = remat_policy.resolve("flash_only").saved_names
+    assert "kda_out" in kept and "delta_out" in kept
+    cfg = config(attention_impl="flash", remat="flash_only",
+                 flash_block_q=8, flash_block_kv=8)
+    weights = numerics.share(cfg)
+    inputs, targets = numerics.seeded()[0]
+
+    def loss(p):
+        logits, _ = TransformerLM(cfg).apply({"params": p}, inputs)
+        return harness.token_nll(logits, targets).mean()
+
+    text = str(jax.make_jaxpr(jax.grad(loss))(weights))
+    assert "name=kda_out" in text and "name=flash_out" in text
+
+
+def test_num_params_counts_what_is_held():
+    cfg = config()
+    weights = numerics.share(cfg)
+    held = sum(leaf.size for leaf in jax.tree.leaves(weights))
+    norms = sum(
+        leaf.size
+        for path, leaf in jax.tree_util.tree_leaves_with_path(weights)
+        if path[-2].key in ("ln_attn", "ln_mlp", "ln_final")
+    )
+    # the layer norms are the approximation num_params() always made
+    assert cfg.num_params() == held - norms
+    assert cfg.num_linear_layers == 3
+    assert [cfg.layer_kind(i) for i in range(4)] == [
+        "linear_attention", "linear_attention", "full_attention",
+        "linear_attention",
+    ]
+
+
+def test_a_model_without_a_kda_layer_imports_none_of_it():
+    """Nothing new on the other cells' set-up path: the trainer, a dense
+    model and the hybrid's scalar rule import neither ``ops/kda.py`` nor
+    the model file."""
+    code = (
+        "import sys\n"
+        "from dlrover_tpu.trainer import elastic_trainer\n"
+        "import benchmark.worker\n"
+        "from dlrover_tpu.models.olmo_hybrid import olmo_hybrid_config\n"
+        "from dlrover_tpu.models.transformer import TransformerLM\n"
+        "import jax, jax.numpy as jnp\n"
+        "cfg = olmo_hybrid_config(num_layers=4, d_model=32, num_heads=4,"
+        " linear_num_heads=4, linear_key_head_dim=8,"
+        " linear_value_head_dim=16, vocab_size=128, dtype=jnp.float32)\n"
+        "tokens = jnp.zeros((1, 16), jnp.int32)\n"
+        "m = TransformerLM(cfg)\n"
+        "m.apply(m.init(jax.random.PRNGKey(0), tokens), tokens)\n"
+        "bad = [n for n in sys.modules if n.endswith(('ops.kda',"
+        " 'models.ling_flash', 'references.ling_flash'))]\n"
+        "assert not bad, bad\n"
+        "print('clean')\n"
+    )
+    out = subprocess.run(
+        [sys.executable, "-c", code], cwd=REPO, capture_output=True,
+        text=True, timeout=300,
+        env={**os.environ, "JAX_PLATFORMS": "cpu", "PYTHONPATH": REPO},
+    )
+    assert out.returncode == 0 and "clean" in out.stdout, out.stderr[-2000:]

@@ -217,7 +217,8 @@ def test_the_scopes_the_benchmark_reads_reach_the_compiled_text(tokens):
     assert "attn/q_a" not in text
     assert "linear_attn/delta_rule" not in text
     # the group limit is the router's work
-    assert "moe/router" in text and "top_k" in text
+    assert "moe/router" in text and "argmax" in text
+    assert "top_k" not in text
 
 
 def test_flash_only_keeps_the_rule_s_output_under_its_own_name():

@@ -91,18 +91,21 @@ def test_earlier_models_keep_their_lowered_step_text(preset):
 
 
 # sha256 of the lowered step text of the two tiny presets that
-# the table above does not hold, at the parent commit
-# fed8b01 (its ``lowered_step_text``): JoyAI-LLM-Flash's (latent attention,
-# the sigmoid router, a share) and Nemotron's, whose text holds its scan
-# kernels' grids and index maps (one tile a group).  Nemotron's is the text
-# since PR 40, which rewrote the scan kernels' bodies (``ops/ssd.py``: at
-# fed8b01 it read 1b8a7fa8...d4e7b3); whoever edits those kernels next
-# re-pins it, and JoyAI's says that nothing else in the step moved.
+# the table above does not hold (its ``lowered_step_text``): JoyAI-LLM-Flash's
+# (latent attention, the sigmoid router, a share) and Nemotron's, whose text
+# holds its scan kernels' grids and index maps (one tile a group).  Both are
+# the texts since PR 45, which took the gather, its scatter and ``top_k``'s
+# sort out of the sigmoid router (``models/moe.py::_gate``): at the parent
+# 0ab4b77 they read 680dda30...835c48d (as at fed8b01: nothing else in the
+# step had moved) and 1ac0af4f...e16b47b4 (PR 40's scan kernels); the
+# presets that route by softmax or not at all, the four above and Granite's,
+# kept the parent's texts (CHANGES.md, PR 45).  Whoever edits the router or
+# the scan kernels next re-pins them.
 LATER_PRESETS_LOWERED = {
     "joyai-llm-flash":
-        "680dda303fa8cbf13fc776cde453e40c8602a628464f5231c931c2402835c48d",
+        "63af0acfb70a6311d09581ecbce514fc91fea6dfa3d15f6c04ad2fa9706c9955",
     "nemotron-3-nano-30b-a3b":
-        "1ac0af4f4c3def1f692d608f179378d391e7055c6795817117ad3f15e16b47b4",
+        "80d960d277046e5ad1b5448296088ed0d3696374b89f651e741461bf439bf378",
 }
 
 

@@ -396,9 +396,13 @@ def _k_rows_summed(rows, dest, gates=None, live=None):
     plain sum) as ``[T, D]``, the gates and the sum in float32.  Row-tiled
     ``rows`` go through the kernel, which fetches and sums in one pass:
     every pair, or given a share's ``live`` list only those that have a row
-    here (the others name the zero row)."""
+    here (the others name the zero row).  So do plain rows of whole lanes
+    that a pad makes whole tiles (``row_gather_sum.padded_width``), padded
+    at the kernel's door and the sums cut back."""
     if rows.ndim == 3:
         return row_gather_sum.gather_sum(rows, dest, gates, live=live)
+    if row_gather_sum.padded_width(rows.shape[1], dest.shape[1], rows.dtype):
+        return row_gather_sum.padded_gather_sum(rows, dest, gates, live=live)
     picked = rows[dest].astype(jnp.float32)                     # [T, k, D]
     if gates is not None:
         picked = picked * gates[..., None]
@@ -918,14 +922,20 @@ class MoEMlp(nn.Module):
                     gate_idx.reshape(t, k), e, block, n_pad, first, total,
                 )
             # The d_model-wide rows live row-tiled between the gathers and
-            # the GEMMs wherever the fetch-and-sum kernel can read them.
+            # the GEMMs wherever the fetch-and-sum kernel can read them;
+            # rows it reads only padded stay plain, and ``_k_rows_summed``
+            # pads them at the kernel's door.
             tiled = row_gather_sum.kernel_fits(d, k, self.dtype)
-            if share and tiled:
+            fetched = d if tiled else row_gather_sum.padded_width(
+                d, k, self.dtype
+            )
+            if share and fetched:
                 # most of a token's pairs have no row here: the kernel is
-                # handed the ones that have, once for its two calls
+                # handed the ones that have, once for its two calls (its
+                # grid step follows from the width it fetches)
                 with jax.named_scope("sort"):
                     plan["live"] = row_gather_sum.live_pairs(
-                        plan["dest"], n_pad - 1, d, self.dtype
+                        plan["dest"], n_pad - 1, fetched, self.dtype
                     )
             with jax.named_scope("scatter"):
                 rows = _rows_of_tokens(

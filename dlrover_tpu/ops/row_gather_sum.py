@@ -17,6 +17,16 @@ reads the row-contiguous view ``[R, D // 128, 128]``, in which a row is
 whole tiles.  ``rows`` may come in that form already, or as ``[R, D]``, in
 which case the reshape is a copy XLA makes first.
 
+A row of whole lanes that is no whole native tiles (21 lane tiles of
+bfloat16: 2,688 columns) has no such view: the array ``[R, 21, 128]`` lies
+in HBM with its 21 padded to 24 and Mosaic refuses one row of it.
+:func:`padded_width` says how wide the view of such a row is (the next
+multiple of 8 lane tiles), and :func:`padded_gather_sum` is the kernel's
+door for plain ``[R, D]`` rows of such a width: it pads a row with zero
+columns, runs the same kernels on the view and cuts the sums back to D.
+The zero columns add zeros to columns that are cut off; the float32 terms
+of the D columns and their order are the kernel's.
+
 Under a share of the experts most of a token's k indices name one row of
 zeros (seven of eight where an eighth of the experts live here), and the
 kernel above would start a DMA for every one: it is bound by the rate at
@@ -64,6 +74,17 @@ _VMEM_BUDGET = 12 << 20
 # DMAs started per turn of the issue loop (its body is unrolled this far);
 # the live-only kernel also waits and adds this many a turn.
 _ISSUE_UNROLL = 16
+# Lane tiles the view of a padded row is a multiple of: the second-minor
+# extent of the HBM tiling of a ``[R, tiles, 128]`` array, which is 8 for
+# 16-bit elements too (a bfloat16 array lies there as (8, 128)(2, 1)).
+_PAD_LANE_TILES = 8
+# A row is padded to at most this many times its width.  The pad, every
+# DMA and the cut move the padded width, so past twice the row they move
+# more zeros than row, and the padded call's gain over XLA's gather (which
+# lands a token's k rows in float32 and reads them again) has not been
+# measured there: 2,688 and 2,560 pad to 3,072 (1.14 and 1.2), and the
+# widths of toy models (128, 256, 384) keep the path they had.
+_PAD_MOST = 2
 
 
 def tile_rows(dtype) -> int:
@@ -131,11 +152,31 @@ def live_pairs(index, zero_row: int, d: int, dtype):
     return words.reshape(-1), live.sum(axis=1, dtype=jnp.int32)
 
 
+def _whole_tiles(d: int, dtype) -> bool:
+    return d % (LANES * tile_rows(dtype)) == 0
+
+
 def kernel_fits(d: int, k: int, dtype) -> bool:
     """Whether a row of ``d`` elements is whole native tiles in the view the
     kernel DMAs from, and a tile of tokens' ``k`` rows each fit the slots
     the VMEM plan leaves room for."""
-    return d % (LANES * tile_rows(dtype)) == 0 and _chunk_tokens(d, k, dtype) > 0
+    return _whole_tiles(d, dtype) and _chunk_tokens(d, k, dtype) > 0
+
+
+def padded_width(d: int, k: int, dtype) -> int:
+    """For a row of ``d`` elements that is whole lanes and no whole native
+    tiles (:func:`kernel_fits` is false for it), the width of the view
+    :func:`padded_gather_sum` hands the kernel: the next multiple of
+    ``_PAD_LANE_TILES`` lane tiles.  0 where the row is whole native tiles
+    (it needs no pad), is no whole number of lanes, would grow past
+    ``_PAD_MOST`` times its width, or a tile of tokens' ``k`` padded rows
+    overflows the VMEM plan."""
+    if d % LANES or _whole_tiles(d, dtype):
+        return 0
+    quantum = LANES * _PAD_LANE_TILES
+    width = -(-d // quantum) * quantum
+    fits = width <= _PAD_MOST * d and _chunk_tokens(width, k, dtype) > 0
+    return width if fits else 0
 
 
 def _kernel(*refs, k, chunk, weighted):
@@ -293,15 +334,40 @@ def gather_sum(rows, index, weights=None, *, live=None, interpret=None):
     row are neither fetched nor added: ``+ 0.0`` is all the sum loses.  (Jitted so that a step which uses it forward, recomputed and
     transposed traces the kernel body once per signature and not eight
     times.)"""
+    d = rows.size // rows.shape[0]
+    if not kernel_fits(d, index.shape[1], rows.dtype):
+        raise ValueError(
+            f"rows of {d} x {rows.dtype}, {index.shape[1]} a token, do not "
+            "fit the fetch-and-sum kernel; see kernel_fits"
+        )
+    return _fetch_and_sum(rows, index, weights, live, interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def padded_gather_sum(rows, index, weights=None, *, live=None, interpret=None):
+    """:func:`gather_sum` for plain ``[R, D]`` rows with a
+    :func:`padded_width`: padded with zero columns to that width, fetched
+    and summed in its view, and the ``[T, width]`` sums cut back to
+    ``[T, D]``.  ``live`` is :func:`live_pairs` for rows of the PADDED
+    width.  (Jitted as ``gather_sum`` is: one trace a signature of the pad,
+    the kernel body and the cut.)"""
+    d, k = rows.shape[1], index.shape[1]
+    width = padded_width(d, k, rows.dtype)
+    if not width:
+        raise ValueError(
+            f"rows of {d} x {rows.dtype}, {k} a token, have no padded form "
+            "the fetch-and-sum kernel takes; see padded_width"
+        )
+    padded = jnp.pad(rows, ((0, 0), (0, width - d)))
+    return _fetch_and_sum(padded, index, weights, live, interpret)[:, :d]
+
+
+def _fetch_and_sum(rows, index, weights, live, interpret):
+    """The call itself, for rows whose width the caller has checked."""
     r = rows.shape[0]
     d = rows.size // r
     tiles = d // LANES
     t, k = index.shape
-    if not kernel_fits(d, k, rows.dtype):
-        raise ValueError(
-            f"rows of {d} x {rows.dtype}, {k} a token, do not fit the "
-            "fetch-and-sum kernel; see kernel_fits"
-        )
     chunk = _chunk_tokens(d, k, rows.dtype)
     if live is None:
         block = min(_BLOCK_TOKENS // chunk, -(-t // chunk)) * chunk

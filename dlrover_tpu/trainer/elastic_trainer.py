@@ -505,17 +505,25 @@ class ElasticTrainer:
         event: ``kernel`` (``ops/row_gather_sum.py``: fetched and summed in
         one pass) / ``kernel_live`` (the same under a share of the experts:
         only the pairs that have a row here are fetched and added) /
+        ``kernel_padded`` and ``kernel_live_padded`` (the same two for rows
+        of whole lanes that are whole tiles only padded: plain between the
+        gathers and the GEMMs, padded at the kernel's door) /
         ``xla`` (a gather, then a reduction), ``none`` for a model without
-        grouped experts.  Asks the function the layer asks."""
+        grouped experts.  Asks the functions the layer asks."""
         cfg = self.model_config
         if not cfg.num_experts or cfg.moe_dispatch != "grouped":
             return "none"
         from dlrover_tpu.ops import row_gather_sum
 
-        if not row_gather_sum.kernel_fits(cfg.d_model, cfg.top_k, cfg.dtype):
+        row = (cfg.d_model, cfg.top_k, cfg.dtype)
+        if row_gather_sum.kernel_fits(*row):
+            padded = ""
+        elif row_gather_sum.padded_width(*row):
+            padded = "_padded"
+        else:
             return "xla"
         share = cfg.resolved_experts_held < cfg.num_experts
-        return "kernel_live" if share else "kernel"
+        return ("kernel_live" if share else "kernel") + padded
 
     def _short_conv(self) -> str:
         """How the step program's short convolutions run, for the
@@ -1412,7 +1420,7 @@ class ElasticTrainer:
             # the product of two means (not the mean of the layers'
             # products) once one does.
             row_fetch_share = 1.0
-            if self._row_moves() == "kernel_live":
+            if self._row_moves().startswith("kernel_live"):
                 row_fetch_share = float(pairs_here) * (1.0 - float(drop))
             telemetry.event(
                 "moe", step=step,

@@ -123,6 +123,22 @@ def _row_gather_sum_live():
     return fn
 
 
+def _row_gather_sum_padded(share):
+    """Plain rows of whole lanes that are no whole tiles, padded at the
+    kernel's door and the sums cut back; under a share the list is the one
+    made for the padded width."""
+    from dlrover_tpu.ops import row_gather_sum as rgs
+
+    def fn(rows, index, *gates):
+        live = None
+        if share:
+            width = rgs.padded_width(rows.shape[1], index.shape[1], rows.dtype)
+            live = rgs.live_pairs(index, rows.shape[0] - 1, width, rows.dtype)
+        return rgs.padded_gather_sum(rows, index, *gates, live=live)
+
+    return fn
+
+
 def _delta_rule():
     from dlrover_tpu.ops.gated_delta_rule import gated_delta_rule
 
@@ -315,6 +331,18 @@ CASES = [
      {}, 1),
     ("row_gather_sum_granite_live_plain", _row_gather_sum_live,
      [((26880, 32, 128), BF16), ((16384, 10), I32)], {}, 1),
+    # Nemotron-3-Nano's and Ling-3.0-flash's shares: rows of 2,688 and 2,560
+    # (21 and 20 lane tiles, which Mosaic refuses to slice) padded to 24 at
+    # the kernel's door, out of budgets of 17,536 and 14,464 rows
+    ("row_gather_sum_nemotron_padded_live_weighted",
+     lambda: _row_gather_sum_padded(True),
+     [((17536, 2688), BF16), ((16384, 6), I32), ((16384, 6), F32)], {}, 1),
+    ("row_gather_sum_ling_padded_live_plain",
+     lambda: _row_gather_sum_padded(True),
+     [((14464, 2560), BF16), ((16384, 8), I32)], {}, 1),
+    ("row_gather_sum_nemotron_padded_every_pair",
+     lambda: _row_gather_sum_padded(False),
+     [((17536, 2688), BF16), ((16384, 6), I32)], {}, 1),
     ("grouped_matmul_granite_wi_rows_tiled",
      lambda: _grouped_matmul(False, True),
      [((26880, 32, 128), BF16), ((9, 4096, 768), BF16), ((9,), I32)],

@@ -57,12 +57,13 @@ def test_the_published_widths_count_what_the_issue_counts():
         d_model=2048, num_heads=16, num_experts=64, top_k=8,
         moe_dispatch="grouped",
     ), "none", None, None, "kernel"),
-    # Nemotron-3-Nano's rows of 2,688 are no whole native tiles: its
-    # row moves are XLA's gather and reduction; a group of 8 heads is one
-    # grid step
-    (lambda: nemotron_h_config(ssm_impl="kernel"), "kernel", 8, 1, "xla"),
+    # Nemotron-3-Nano's rows of 2,688 are no whole native tiles: the
+    # fetch-and-sum takes them padded to 3,072 at its door (XLA's gather
+    # and reduction until PR 46); a group of 8 heads is one grid step
+    (lambda: nemotron_h_config(ssm_impl="kernel"), "kernel", 8, 1,
+     "kernel_padded"),
     (lambda: nemotron_h_config(ssm_impl="kernel", experts_held=16),
-     "kernel", 8, 1, "xla"),
+     "kernel", 8, 1, "kernel_live_padded"),
     (lambda: TransformerConfig(), "none", None, None, "none"),
     (lambda: TransformerConfig(num_experts=8, moe_dispatch="einsum"),
      "none", None, None, "none"),

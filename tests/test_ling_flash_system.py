@@ -198,7 +198,12 @@ def test_the_compile_event_says_which_rule_runs():
     )
     assert ElasticTrainer._short_conv(_stub(published, 8192)) == "kernel"
     assert ElasticTrainer._kda(_stub(published, 8192)) == "kernel"
-    assert ElasticTrainer._row_moves(_stub(published, 8192)) == "xla"
+    # rows of 2,560 are 20 lane tiles: padded to 24 at the fetch-and-sum
+    # kernel's door, and under the cell's share (32 of 512 experts) only
+    # the pairs that have a row here are fetched
+    assert ElasticTrainer._row_moves(_stub(published, 8192)) == (
+        "kernel_live_padded"
+    )
 
 
 def test_the_scopes_the_benchmark_reads_reach_the_compiled_text(tokens):

@@ -40,8 +40,12 @@ def _case(t, k, d, experts=4, block=8, idle_expert=None, seed=0):
     }
 
 
-# (tokens, k, D): k 1 / 2 / 8, D lane-aligned and not, T odd
-SHAPES = [(13, 1, 16), (37, 2, 48), (21, 2, 128), (9, 8, 256), (40, 8, 24)]
+# (tokens, k, D): k 1 / 2 / 8, D lane-aligned and not, T odd; the last is
+# whole lanes that a pad to 1024 makes a whole float32 tile: plain rows of
+# such a width go through the kernel, padded at its door (under the TPU
+# interpreter here)
+SHAPES = [(13, 1, 16), (37, 2, 48), (21, 2, 128), (9, 8, 256), (40, 8, 24),
+          (21, 2, 640)]
 
 
 @pytest.mark.parametrize("weighted", [False, True])
@@ -202,6 +206,37 @@ def test_the_kernel_takes_rows_of_whole_tiles_only(d, k, dtype, fits):
             )
 
 
+@pytest.mark.parametrize("d,k,dtype,width", [
+    (2688, 6, jnp.bfloat16, 3072),     # Nemotron-3-Nano: 21 lane tiles
+    (2560, 8, jnp.bfloat16, 3072),     # Ling-3.0-flash: 20
+    (640, 2, F32, 1024), (896, 1, F32, 1024), (1152, 2, F32, 2048),
+    (1024, 8, jnp.bfloat16, 1024),     # 8 lane tiles: a pad of no column
+    # whole native tiles need no pad: ``kernel_fits`` answers for them
+    (2048, 8, jnp.bfloat16, 0), (4096, 10, jnp.bfloat16, 0), (1024, 8, F32, 0),
+    (2048, 64, jnp.bfloat16, 0),
+    # no whole lanes
+    (1600, 2, jnp.bfloat16, 0), (64, 2, F32, 0), (48, 2, F32, 0),
+    # more pad than row
+    (128, 2, F32, 0), (384, 2, jnp.bfloat16, 0),
+    (2560, 8, F32, 0),                 # a tile of tokens' padded rows: 14 MiB
+])
+def test_a_row_of_whole_lanes_has_a_padded_width(d, k, dtype, width):
+    from dlrover_tpu.ops import row_gather_sum
+
+    assert row_gather_sum.padded_width(d, k, dtype) == width
+    # the two answers never both hold, and ``kernel_fits`` kept its own
+    assert not (width and row_gather_sum.kernel_fits(d, k, dtype))
+    rows, index = jnp.zeros((8, d), dtype), jnp.zeros((4, k), jnp.int32)
+    if width:
+        with pytest.raises(ValueError, match="kernel_fits"):
+            row_gather_sum.gather_sum(rows, index)
+        made = jax.eval_shape(row_gather_sum.padded_gather_sum, rows, index)
+        assert made.shape == (4, d) and made.dtype == dtype
+    else:
+        with pytest.raises(ValueError, match="padded_width"):
+            row_gather_sum.padded_gather_sum(rows, index)
+
+
 @pytest.mark.parametrize("d,k,dtype,chunk", [
     # the shapes that fitted a 1 MiB slot keep the chunk they had
     (2048, 8, jnp.bfloat16, 32),       # OLMoE, JoyAI-LLM-Flash
@@ -212,6 +247,9 @@ def test_the_kernel_takes_rows_of_whole_tiles_only(d, k, dtype, fits):
     (4096, 10, jnp.bfloat16, 16),
     (4096, 8, jnp.bfloat16, 16),
     (2048, 64, jnp.bfloat16, 0),       # past the VMEM plan
+    # Nemotron's and Ling's rows at their padded width, 24 lane tiles
+    (3072, 6, jnp.bfloat16, 16),
+    (3072, 8, jnp.bfloat16, 16),
 ])
 def test_the_slot_follows_from_k_d_and_the_dtype(d, k, dtype, chunk):
     from dlrover_tpu.ops import row_gather_sum
@@ -261,11 +299,13 @@ def _share_case(t, k, d, blocks, seed=0):
 
 
 @pytest.mark.parametrize("weighted", [False, True])
-@pytest.mark.parametrize("k,d", [(8, 2048), (10, 4096)])
+@pytest.mark.parametrize("k,d", [(8, 2048), (10, 4096), (6, 2688)])
 def test_the_live_only_kernel_is_the_full_fetch_bit_for_bit(
     k, d, weighted, monkeypatch
 ):
-    """JoyAI's and Granite's ``(k, d)`` with an eighth of the pairs live, in
+    """JoyAI's and Granite's ``(k, d)``, and Nemotron's through the padded
+    call (plain rows of 21 lane tiles, the list made for the 24 they are
+    padded to), with an eighth of the pairs live, in
     grid steps of 32 tokens so that a few tokens hold every case: a step
     whose pairs are all live, one with none, a ragged last one (T is no
     multiple of the step; the SMEM block spans several steps), tokens with
@@ -278,12 +318,18 @@ def test_the_live_only_kernel_is_the_full_fetch_bit_for_bit(
     t = 32 + 32 + 13
     rows, index, gates, live = _share_case(t, k, d, blocks=32)
     gates = gates if weighted else None
-    listed = row_gather_sum.live_pairs(index, rows.shape[0] - 1, d, rows.dtype)
-    kernel = pltpu.InterpretParams()
-    full = row_gather_sum.gather_sum(rows, index, gates, interpret=kernel)
-    got = row_gather_sum.gather_sum(
-        rows, index, gates, live=listed, interpret=kernel
+    width = row_gather_sum.padded_width(d, k, rows.dtype)
+    assert bool(width) is not row_gather_sum.kernel_fits(d, k, rows.dtype)
+    call = (
+        row_gather_sum.padded_gather_sum if width
+        else row_gather_sum.gather_sum
     )
+    listed = row_gather_sum.live_pairs(
+        index, rows.shape[0] - 1, width or d, rows.dtype
+    )
+    kernel = pltpu.InterpretParams()
+    full = call(rows, index, gates, interpret=kernel)
+    got = call(rows, index, gates, live=listed, interpret=kernel)
     assert got.shape == (t, d) and got.dtype == jnp.bfloat16
     np.testing.assert_array_equal(np.asarray(got), np.asarray(full))
     np.testing.assert_allclose(
@@ -378,12 +424,33 @@ def test_a_live_list_made_for_other_rows_is_refused():
     ):
         with pytest.raises(ValueError, match="not live_pairs of a 1024 x 8"):
             row_gather_sum.gather_sum(rows, index, live=live)
+    # the padded call's steps follow from the width it fetches, 3,072 for
+    # rows of 2,688: a list made for narrower rows (steps of 512 tokens,
+    # not 256) is another's too
+    plain, six = jnp.zeros((64, 2688), jnp.bfloat16), index[:, :6]
+    for width, fine in ((3072, True), (1024, False)):
+        live = row_gather_sum.live_pairs(six, 63, width, jnp.bfloat16)
+
+        def made():
+            return jax.eval_shape(
+                lambda *a: row_gather_sum.padded_gather_sum(*a, live=live),
+                plain, six,
+            )
+
+        if fine:
+            assert made().shape == (1024, 2688)
+        else:
+            with pytest.raises(ValueError, match="rows of 3072 x bfloat16"):
+                made()
 
 
 @pytest.mark.parametrize("d,k,dtype,block", [
     (2048, 8, jnp.bfloat16, 512),      # JoyAI: 4 MiB of float32 sums
     (4096, 10, jnp.bfloat16, 256),     # Granite: 8 MiB would not fit
     (1024, 8, F32, 512),
+    # padded to 24 lane tiles: 6 MiB of sums do not fit, 3 do; Nemotron's
+    # 256 x 6 indices are no whole SMEM tiles, one block spans two steps
+    (3072, 6, jnp.bfloat16, 256),
 ])
 def test_a_live_only_grid_step_is_as_many_tokens_as_its_sums_fit(
     d, k, dtype, block
@@ -402,15 +469,22 @@ def test_a_live_only_grid_step_is_as_many_tokens_as_its_sums_fit(
     ) * 3
 
 
-def test_a_shares_plan_moves_rows_as_the_xla_form_does():
+@pytest.mark.parametrize("d", [1024, 640])
+def test_a_shares_plan_moves_rows_as_the_xla_form_does(d, monkeypatch):
     """A plan over 2 of 8 experts whose budget is one pair short: through
     ``_tokens_of_rows`` and ``_rows_of_tokens`` with row-tiled rows (the
     live-only kernel) the outputs and every cotangent are those of plain
-    rows (XLA's gather and sum), and both calls were handed the list."""
+    rows (XLA's gather and sum), and both calls were handed the list.
+    Rows of 640 are whole tiles only padded to 1024: they stay plain, the
+    padded call is the kernel's door, and XLA's form is what they take when
+    told that no pad fits."""
     from dlrover_tpu.ops import row_gather_sum
 
     rng = np.random.default_rng(3)
-    t, k, d, held, total, block = 48, 2, 1024, 2, 8, 8
+    t, k, held, total, block = 48, 2, 2, 8, 8
+    width = row_gather_sum.padded_width(d, k, F32)
+    assert width == (1024 if d == 640 else 0)
+    door = "padded_gather_sum" if width else "gather_sum"
     gate_idx = jnp.asarray(np.stack([
         rng.choice(total, size=k, replace=False) for _ in range(t)
     ]), jnp.int32)
@@ -423,7 +497,9 @@ def test_a_shares_plan_moves_rows_as_the_xla_form_does():
     assert int(plan["here"]) == here > int(plan["kept"]) > 0
     dest = np.asarray(plan["dest"])
     assert (dest == n_pad - 1).sum() == t * k - int(plan["kept"])
-    plan["live"] = row_gather_sum.live_pairs(plan["dest"], n_pad - 1, d, F32)
+    plan["live"] = row_gather_sum.live_pairs(
+        plan["dest"], n_pad - 1, width or d, F32
+    )
     assert int(plan["live"][1].sum()) == int(plan["kept"])
 
     rows = jnp.asarray(rng.standard_normal((n_pad, d)), F32).at[-block:].set(0)
@@ -431,9 +507,10 @@ def test_a_shares_plan_moves_rows_as_the_xla_form_does():
     gates = jnp.asarray(rng.random((t, k)), F32)
     d_out = jnp.asarray(rng.standard_normal((t, d)), F32)
     handed = []
-    real = row_gather_sum.gather_sum
+    real = getattr(row_gather_sum, door)
 
     def seen(rows, index, weights=None, *, live=None):
+        assert rows.shape[1:] == ((d,) if width else (d // 128, 128))
         handed.append(live)
         return real(rows, index, weights, live=live)
 
@@ -452,14 +529,16 @@ def test_a_shares_plan_moves_rows_as_the_xla_form_does():
 
     import unittest.mock
 
-    with unittest.mock.patch.object(row_gather_sum, "gather_sum", seen):
-        tiled = moved(True)
+    with unittest.mock.patch.object(row_gather_sum, door, seen):
+        tiled = moved(not width)
     assert len(handed) == 2
     for words, count in handed:
         np.testing.assert_array_equal(words, plan["live"][0])
         np.testing.assert_array_equal(count, plan["live"][1])
+    monkeypatch.setattr(row_gather_sum, "padded_width", lambda *a: 0)
     for got, want in zip(tiled, moved(False)):
         np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    assert len(handed) == 2
 
 
 # -- row-tiled rows through the GEMMs and the layer ---------------------------
@@ -499,19 +578,25 @@ def test_grouped_matmul_takes_and_gives_row_tiled_rows(x_tiled, out_tiled):
     np.testing.assert_array_equal(dw, want_dw)
 
 
-@pytest.mark.parametrize("held", [0, 2])
+@pytest.mark.parametrize("held,d_model", [(0, 1024), (2, 1024), (2, 640)])
 def test_a_layer_whose_rows_are_whole_tiles_runs_them_row_tiled(
-    held, monkeypatch
+    held, d_model, monkeypatch
 ):
     """d_model 1024 in float32 is a whole tile a row: the layer's rows go
     row-tiled through the GEMMs and the kernel.  Same output and gradients
     as the plain form, which the same layer takes when told nothing fits.
     Holding 2 of its 4 experts the layer hands the kernel the pairs that
-    have a row here; holding all it hands none."""
+    have a row here; holding all it hands none.  d_model 640 is a whole
+    tile only padded to 1024: the rows stay plain through the gathers and
+    the GEMMs, the padded call takes them as they are, and under a share
+    its live list is the one made for the padded width."""
     from dlrover_tpu.ops import row_gather_sum
 
+    width = row_gather_sum.padded_width(d_model, 2, F32)
+    assert width == (1024 if d_model == 640 else 0)
+    door = "padded_gather_sum" if width else "gather_sum"
     x = jnp.asarray(
-        np.random.default_rng(5).standard_normal((2, 12, 1024)), F32
+        np.random.default_rng(5).standard_normal((2, 12, d_model)), F32
     )
     layer = moe.MoEMlp(
         num_experts=4, d_ff=128, top_k=2, activation="swiglu", dtype=F32,
@@ -520,13 +605,19 @@ def test_a_layer_whose_rows_are_whole_tiles_runs_them_row_tiled(
     )
     params = layer.init(jax.random.PRNGKey(5), x)
     forms = []
-    real = row_gather_sum.gather_sum
+    real = getattr(row_gather_sum, door)
 
-    def seen(rows, *args, live=None):
+    def seen(rows, index, *args, live=None):
+        if live is not None:
+            # the list of this index for rows of the width that is fetched
+            want = row_gather_sum.live_pairs(
+                index, rows.shape[0] - 1, width or d_model, F32
+            )
+            assert [a.shape for a in live] == [a.shape for a in want]
         forms.append((rows.shape, live is not None))
-        return real(rows, *args, live=live)
+        return real(rows, index, *args, live=live)
 
-    monkeypatch.setattr(row_gather_sum, "gather_sum", seen)
+    monkeypatch.setattr(row_gather_sum, door, seen)
 
     def loss(p, x):
         out, aux = layer.apply(p, x)
@@ -539,8 +630,10 @@ def test_a_layer_whose_rows_are_whole_tiles_runs_them_row_tiled(
     tiled = value_and_grads()
     # combine's forward and the transpose of rows-of-tokens
     n_pad = moe._share_row_budget(48, 8, 2, 4, 2.0) if held else 80
-    assert forms == [((n_pad, 8, 128), bool(held))] * 2
+    row = (d_model,) if width else (8, 128)
+    assert forms == [((n_pad,) + row, bool(held))] * 2
     monkeypatch.setattr(row_gather_sum, "kernel_fits", lambda *a: False)
+    monkeypatch.setattr(row_gather_sum, "padded_width", lambda *a: 0)
     plain = value_and_grads()
     assert len(forms) == 2
     # Holding all, the old case, at the old tolerance.  Holding half, the

@@ -85,6 +85,34 @@ def test_a_model_without_such_a_layer_names_no_scan():
     assert ElasticTrainer._ssm_scan(stub) == "xla"
 
 
+@pytest.mark.parametrize("overrides,rows", [
+    # the cell: 16 of 128 experts here, six rows of 2,688 a token.  21 lane
+    # tiles are no whole native tiles, so the rows stay plain between the
+    # gathers and the GEMMs and the fetch-and-sum kernel takes them padded
+    # to 24, only the pairs that have a row here
+    (dict(experts_held=16), "kernel_live_padded"),
+    (dict(), "kernel_padded"),
+    # float32 rows of 3,072: the slots and the output's 512-token blocks
+    # pass the kernel's 12 MiB VMEM plan, so XLA's gather keeps them
+    (dict(experts_held=16, dtype="float32"), "xla"),
+    # the tiny reference model's rows of 64 are no whole lanes
+    (None, "xla"),
+])
+def test_the_compile_event_names_the_row_moves(overrides, rows):
+    from dlrover_tpu.ops import row_gather_sum
+    from dlrover_tpu.trainer.elastic_trainer import ElasticTrainer
+
+    cfg = config() if overrides is None else nemotron_h_config(**overrides)
+    stub = type("T", (), {"model_config": cfg})()
+    assert ElasticTrainer._row_moves(stub) == rows
+    row = (cfg.d_model, cfg.top_k, cfg.dtype)
+    # rows of whole tiles go row-tiled THROUGH THE GEMMS: these never do
+    assert not row_gather_sum.kernel_fits(*row)
+    assert row_gather_sum.padded_width(*row) == (
+        3072 if rows.endswith("padded") else 0
+    )
+
+
 def test_the_master_renders_the_events_as_gauges():
     from dlrover_tpu.master.speed_monitor import SpeedMonitor
     from dlrover_tpu.master.timeline import JobTimeline

@@ -135,7 +135,11 @@ def gated_delta_rule_cost(
 ) -> Dict[str, float]:
     """FLOPs and HBM bytes the delta rule's calls of ONE training step
     need (forward + backward, every linear layer, ``sequences`` on this
-    chip): the module docstring has what is counted."""
+    chip), counted at ``RULE_CHUNK`` = 64 tokens a chunk while the program
+    runs 128 (its configuration's ``assumed.chunk``): the cost is the
+    algorithm's at a fixed chunk, so the program's larger chunks read as
+    what they are against one yardstick.  The module docstring has what is
+    counted."""
     layers = layer_counts(model)[LINEAR]
     g = _linear_heads(model)
     tokens = float(sequences) * seq_len

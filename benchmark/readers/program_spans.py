@@ -8,8 +8,12 @@ of the window's steps (by their ``step`` attribute).
 
 The spans are ``evidence["program_spans"]`` (wire events: name, kind,
 wall, seconds, attrs) where a run hands them over; else whatever the
-recorder of this process still holds.  A program without these spans (an
-older commit) gives nothing, and the metric is left out of the line.
+recorder of this process still holds.  Ahead of either go
+``evidence["startup_spans"]``, the trainer's start-up spans, which the
+worker keeps when it builds the trainer: the ring ships to the master with
+the first report, and a trainer that is a child of the launcher hands
+nothing else across.  A program without these spans (an older commit) gives
+nothing, and the metric is left out of the line.
 """
 
 import statistics
@@ -33,13 +37,17 @@ def window_steps(evidence):
 
 
 def spans_of(evidence):
+    kept = list(evidence.get("startup_spans") or [])
     if evidence.get("program_spans") is not None:
-        return evidence["program_spans"]
-    if not evidence.get("step_ids"):
-        return []  # no run to belong to
-    from dlrover_tpu.common import telemetry
+        rest = evidence["program_spans"]
+    elif not evidence.get("step_ids"):
+        rest = []  # no run to belong to
+    else:
+        from dlrover_tpu.common import telemetry
 
-    return telemetry.recorder().peek()
+        rest = telemetry.recorder().peek()
+    names = {e[0] for e in kept}
+    return kept + [e for e in rest if e[0] not in names]
 
 
 def read(evidence, params):

@@ -11,12 +11,74 @@ tokens over all its seconds, so a stall inside the window shows in it.
 The rate of the MEDIAN reading, which one slow step cannot move, and the
 worst reading over the median stand beside it, so that a run that reads
 low can be told apart: one stall, or every step slower.
+
+Set-up is what a job's owner waits for and the program decides: from the
+mesh built to the window's first instant, less what the benchmark puts
+there itself and no user runs: its reference check and, in the save cell,
+its wait for the first persist (``setup_parts``).
 """
 
 from __future__ import annotations
 
 import statistics
 from typing import Dict, List, Optional, Sequence
+
+
+def startup_to_mesh_s(spans: Sequence[Sequence]) -> float:
+    """Seconds from the first trainer's process start, as the OS booked it,
+    to its mesh built, as the per-layer metric of that name reads them from
+    the program's wire events (name, kind, wall, seconds, attrs): its
+    file's span names, through its reader.  A run whose program did not
+    record them has no mesh instant, and no other instant stands in for
+    it."""
+    from benchmark import layers
+    from benchmark.readers import program_spans
+
+    params = layers.spec("startup_to_mesh_s")["params"]
+    value = program_spans.read({"program_spans": spans}, params)
+    if value is None:
+        absent = [
+            name for name in params["names"]
+            if not any(e[0] == name and e[1] == "span" for e in spans)
+        ]
+        raise SystemExit(
+            f"benchmark: the program recorded no {absent} span, so the "
+            "instant its mesh was built is unknown; setup_s is read "
+            "from there and from nowhere else; nothing measured"
+        )
+    return value
+
+
+def setup_parts(
+    t0: float, window_open: float, spans: Sequence[Sequence],
+    reference_check_s: float, persist_wait_s: Optional[float] = None,
+) -> Dict[str, float]:
+    """``setup_s`` and what is taken out of it, for every scenario.
+
+    ``process_to_window_s`` runs from ``t0``, the start of the process the
+    command started, to the window's first instant.  Out of it go
+    ``startup_to_mesh_s`` (interpreter, imports and the accelerator
+    runtime's start, up to the mesh: the machine's, in two modes seconds
+    apart on equal programs), ``reference_check_s`` (the benchmark's own
+    check, timed around the whole call) and, in a scenario that has one,
+    ``persist_wait_s`` (the seconds the harness holds the trainer until the
+    agent has persisted the first save, so that the next save is a reading:
+    no job waits there).  What stays is ``setup_s``: build, init, trace,
+    lower, compile or cache read, seeding and the steps until the window
+    opens and, where the trainer is a child of the program's launcher, the
+    launcher's, master's and agent's start and the first save, which makes
+    the arena.  The parts add up by construction."""
+    process_to_window = window_open - t0
+    parts = {
+        "startup_to_mesh_s": startup_to_mesh_s(spans),
+        "reference_check_s": reference_check_s,
+    }
+    if persist_wait_s is not None:
+        parts["persist_wait_s"] = persist_wait_s
+    return {
+        "process_to_window_s": process_to_window, **parts,
+        "setup_s": process_to_window - sum(parts.values()),
+    }
 
 
 def window_open_index(

@@ -15,7 +15,14 @@ Phases of a run:
   shared-memory arena and is no reading, waits until the agent has
   persisted it (16 s, longer than the steps to the next save, which the
   engine would otherwise skip as "shm busy"), and trains on to the step
-  before the second save.  ``setup_s`` ends there.
+  before the second save.  ``setup_s`` ends there.  It starts where this
+  process does and leaves out three stretches (``readings.setup_parts``):
+  the trainer's own process start to its mesh built (its
+  ``startup.runtime`` and ``startup.mesh`` spans), the reference check,
+  and that wait for the first persist, which this benchmark puts there
+  and no job makes; the trainer's record hands all three over.  The
+  launcher's, master's and agent's start stay in it, and the first save,
+  blocked while it makes the cold arena: the program owns them.
 * the window opens at that step's end, as the second save begins.  Every
   save inside it is a reading: the seconds the training loop is blocked in
   ``save_checkpoint``, on the host clock, around the call.
@@ -46,7 +53,7 @@ import tempfile
 import time
 from typing import Any, Dict, List, Optional
 
-from benchmark import build, layers
+from benchmark import build, layers, readings
 
 RECORDS = "records.jsonl"
 LAUNCHER_LOG = "launcher.log"
@@ -156,7 +163,7 @@ def _first_trainer(w, records: str, log_path: str,
     kill_after = int(traffic["kill_after_steps"])
     w.build_trainer()
     w.seed_state()
-    reference = w.check_reference()
+    reference = w.timed_reference_check()
     _wrap_save(w, _digest_fn(w))
     state = {"open": None, "close": None, "kill": None}
 
@@ -179,7 +186,9 @@ def _first_trainer(w, records: str, log_path: str,
             return  # training on until the harness kills this process
         if state["open"] is None:
             if step == every + 1:
+                t_wait = time.monotonic()
                 _wait_for_persist(log_path, every)
+                state["persist_wait_s"] = time.monotonic() - t_wait
             if (step >= 2 * every and step % every == 0 and w.saves
                     and w.warm_index() is not None):
                 state["open"] = i
@@ -205,6 +214,13 @@ def _first_trainer(w, records: str, log_path: str,
 def _first_evidence(w, state, reference) -> Dict[str, Any]:
     evidence = w.evidence()
     evidence["reference"] = reference
+    # The first save makes the shared-memory arena: the seconds the loop
+    # was blocked in it stay IN ``setup_s`` (the program owns them) and are
+    # the part of it that wanders from run to run (PERF.md section 2).  The
+    # wait for its persist is the harness's and goes OUT; a window cannot
+    # open before that wait, so a record without it is a fault.
+    evidence["first_save_cold_s"] = w.saves[0]["stall_s"]
+    evidence["persist_wait_s"] = state["persist_wait_s"]
     evidence["window"] = {
         "open": state["open"], "close": state["close"],
         "saves": state.get("saves_in_window"),
@@ -404,6 +420,14 @@ def _assemble(ctx, kill, resumed, t_kill, wall_kill, log_lines, every):
         float(m.group(2)) for line in log_lines
         for m in [PERSIST_LINE.search(line)] if m
     ]
+    setup = readings.setup_parts(
+        ctx.t0, t_open, ev["startup_spans"], ev["reference_check_s"],
+        ev["persist_wait_s"],
+    )
+    ctx.say({
+        "setup": setup, "first_save_cold_s": ev["first_save_cold_s"],
+        "compile": ev["compile"],
+    })
     resume_s = resumed["t"] - t_kill
     respawn = None
     if resumed.get("restart_event_wall") is not None:
@@ -424,6 +448,11 @@ def _assemble(ctx, kill, resumed, t_kill, wall_kill, log_lines, every):
     evidence = dict(
         ev, model=build.model_group(ctx.config), persist_s=persists,
         window_save_stalls=stalls,
+        # Of the trainer's spans only ``startup_spans`` cross the records
+        # file, and this process's recorder holds none: a metric of any
+        # other span finds nothing here and is left out of the line.
+        program_spans=[],
+        process_to_window_s=setup["process_to_window_s"],
     )
     device = dict(ev["device"])
     device["memory_peak_bytes"] = max(
@@ -445,7 +474,7 @@ def _assemble(ctx, kill, resumed, t_kill, wall_kill, log_lines, every):
             # All the seconds the loop was blocked in the window's saves
             # over their number: one slow save shows in it.
             "save_stall_s": statistics.fmean(stalls) if stalls else None,
-            "setup_s": t_open - ctx.t0,
+            "setup_s": setup["setup_s"],
         },
         "per_layer": layers.compute(ctx.manifest, ctx.cell, evidence)
         if ctx.trace else {},

@@ -1,13 +1,20 @@
 """Steady training steps: the program's trainer, loader and prefetcher in
 this process, no saves.
 
-Set-up: build the trainer (the step program compiles, or comes from the
-compile cache), make the weights from the seed, check the program's forward
-against the plain reference, run warm steps.  The window opens at the end of
-a step, once ``warm_steps`` whole steps have followed the last compilation,
-and closes at the first step's end at or after ``--seconds``.  A traced run
-then traces ``trace_readings`` further readings, so that the measured window is
-the same with and without the profiler.
+Before the window: build the trainer (the step program compiles, or comes
+from the compile cache), make the weights from the seed, check the
+program's forward against the plain reference, run warm steps.  The window
+opens at the end of a step, once ``warm_steps`` whole steps have followed
+the last compilation, and closes at the first step's end at or after
+``--seconds``.  A traced run then traces ``trace_readings`` further readings,
+so that the measured window is the same with and without the profiler.
+
+``setup_s`` starts at the instant the trainer's mesh is built (the close of
+the program's ``startup.mesh`` span) and ends at the window's first
+instant, less the seconds of the benchmark's own reference check
+(``readings.setup_parts``).  What is taken out, process start to the mesh
+and the check, is on a line of its own in every run (``"setup"``) and among
+the per-layer metrics of a traced one.
 """
 
 from __future__ import annotations
@@ -18,15 +25,15 @@ from typing import Any, Dict
 from benchmark import build, layers, readings, worker as worker_lib
 
 
-def run(ctx) -> Dict[str, Any]:
-    w = worker_lib.Worker(
+def run(ctx, worker_class=worker_lib.Worker) -> Dict[str, Any]:
+    w = worker_class(
         ctx.config, ctx.traffic, ctx.chips, ctx.seed, ctx.seconds,
         ctx.trace, rehearsal=ctx.rehearsal,
         trace_dir=os.path.join(ctx.run_dir, "trace"),
     )
     w.build_trainer()
     w.seed_state()
-    reference = w.check_reference()
+    reference = w.timed_reference_check()
     ctx.say({"reference": reference})
     trace_readings = int(ctx.traffic.get("trace_readings", 2))
     state = {"open": None, "close": None, "wait_from": 0, "trace_end": None}
@@ -61,7 +68,11 @@ def run(ctx) -> Dict[str, Any]:
         w.step_ends, w.step_ids, w.compile_ends, w.losses, state["open"],
         state["close"], w.tokens_per_step, w.chips,
     )
-    setup_s = w.step_ends[state["open"]] - ctx.t0
+    setup = readings.setup_parts(
+        ctx.t0, w.step_ends[state["open"]], w.startup_spans,
+        w.reference_check_s,
+    )
+    ctx.say({"setup": setup})
     evidence = w.evidence()
     ctx.say({
         "readings_s": summary["readings"],
@@ -76,6 +87,7 @@ def run(ctx) -> Dict[str, Any]:
     })
     evidence.update(
         summary=summary,
+        process_to_window_s=setup["process_to_window_s"],
         window_data_waits=w.batches.waits[
             state["wait_from"]: state["wait_to"]
         ],
@@ -123,7 +135,7 @@ def run(ctx) -> Dict[str, Any]:
         "failed": summary["failed"],
         "end_to_end": {
             "tokens_per_s_chip": summary["tokens_per_s_chip"],
-            "setup_s": setup_s,
+            "setup_s": setup["setup_s"],
         },
         "per_layer": layers.compute(ctx.manifest, ctx.cell, evidence)
         if w.trace else {},

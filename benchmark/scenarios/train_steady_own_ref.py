@@ -4,21 +4,22 @@ The harness's ``Worker.check_reference`` calls ``benchmark.reference`` by
 name, which knows GPT-2 and Mixtral.  A configuration whose layer that
 reference does not describe names its own module under
 ``benchmark/references/`` (``reference_module`` in its file); this
-scenario's worker calls that one.  Everything else, set-up, window,
-readings and ``correct``, is ``train_steady``'s, line for line; the
-scenario and its traffic file fold back into ``train_steady`` once
-``worker.check_reference`` reads ``reference_module`` itself (PERF.md §7).
+scenario's worker calls that one.  Everything else is ``train_steady``'s
+own ``run`` with this worker: set-up, window, readings and ``correct``,
+and ``setup_s`` from the mesh built to the window's first instant less the
+reference check's seconds.  The scenario and its traffic file fold back
+into ``train_steady`` once ``worker.check_reference`` reads
+``reference_module`` itself (PERF.md §7).
 """
 
 from __future__ import annotations
 
 import importlib
-import os
 import time
 from typing import Any, Dict
 
-from benchmark import build, layers, readings, traffic as traffic_lib
-from benchmark import worker as worker_lib
+from benchmark import traffic as traffic_lib, worker as worker_lib
+from benchmark.scenarios import train_steady
 
 
 class Worker(worker_lib.Worker):
@@ -81,114 +82,4 @@ class Worker(worker_lib.Worker):
 
 
 def run(ctx) -> Dict[str, Any]:
-    w = Worker(
-        ctx.config, ctx.traffic, ctx.chips, ctx.seed, ctx.seconds,
-        ctx.trace, rehearsal=ctx.rehearsal,
-        trace_dir=os.path.join(ctx.run_dir, "trace"),
-    )
-    w.build_trainer()
-    w.seed_state()
-    reference = w.check_reference()
-    ctx.say({"reference": reference})
-    trace_readings = int(ctx.traffic.get("trace_readings", 2))
-    state = {"open": None, "close": None, "wait_from": 0, "trace_end": None}
-
-    def hook(step, metrics):
-        if not w.note_step(step, metrics):
-            return
-        i = len(w.step_ends) - 1
-        if state["open"] is None:
-            if w.warm_index() is not None:
-                state["open"] = i
-                state["wait_from"] = len(w.batches.waits)
-            return
-        if state["close"] is None:
-            if readings.window_close_index(
-                w.step_ends, state["open"], w.seconds
-            ) is None:
-                return
-            state["close"] = i
-            state["wait_to"] = len(w.batches.waits)
-            if not w.trace:
-                raise worker_lib.Done
-            w.start_trace()
-            state["trace_end"] = i + trace_readings
-            return
-        if i >= state["trace_end"]:
-            w.stop_trace()
-            raise worker_lib.Done
-
-    w.fit(hook)
-    summary = readings.summarize(
-        w.step_ends, w.step_ids, w.compile_ends, w.losses, state["open"],
-        state["close"], w.tokens_per_step, w.chips,
-    )
-    setup_s = w.step_ends[state["open"]] - ctx.t0
-    evidence = w.evidence()
-    ctx.say({
-        "readings_s": summary["readings"],
-        "steps_per_reading": summary["steps_per_reading"],
-        "losses": [w.losses[k] for k in sorted(w.losses)],
-        "window_steps": [w.step_ids[state["open"]], w.step_ids[state["close"]]],
-        "tokens_per_s_chip_median_step":
-            summary["tokens_per_s_chip_median_step"],
-        "compile": evidence["compile"],
-        "compile_events": len(w.compile_ends),
-        "pipeline_counters": evidence["pipeline_counters"],
-    })
-    evidence.update(
-        summary=summary,
-        window_data_waits=w.batches.waits[
-            state["wait_from"]: state["wait_to"]
-        ],
-        model=w.model,
-        # A rehearsal's device has no published peak: the readers that
-        # need one then find nothing to read.
-        peak=None if ctx.rehearsal else build.peak_for(
-            w.devices[0].device_kind
-        ),
-        step_module=ctx.traffic.get("step_module", ""),
-    )
-    device = evidence["device"]
-    breakdown = None
-    if w.trace:
-        from benchmark import trace_reduce
-
-        evidence["trace"] = w.extract_trace()
-        reduced = trace_reduce.reduce(
-            evidence["trace"], evidence["step_module"]
-        )
-        evidence["trace_reduced"] = reduced
-        device = dict(
-            device, busy_s=reduced["busy_s"], window_s=reduced["window_s"]
-        )
-        breakdown = {
-            "device_ops": reduced["device_ops"],
-            "idle_gaps": reduced["idle_gaps"],
-        }
-        ctx.say({"trace": {
-            k: v for k, v in reduced.items()
-            if k not in ("device_ops", "idle_gaps")
-        }})
-    correct = bool(
-        reference["ok"] and summary["ok"]
-        and worker_lib.all_finite(w.losses.values())
-        # The compiled step's own first loss (all sequences of the batch)
-        # must lie where the reference's loss on the first sequences does:
-        # both are means over thousands of tokens of one distribution.
-        and abs(w.losses[1] - reference["reference_loss"])
-        <= ctx.config["reference_tolerance"]["first_step_loss"]
-    )
-    return {
-        "correct": correct,
-        "attempted": summary["steps"],
-        "failed": summary["failed"],
-        "end_to_end": {
-            "tokens_per_s_chip": summary["tokens_per_s_chip"],
-            "setup_s": setup_s,
-        },
-        "per_layer": layers.compute(ctx.manifest, ctx.cell, evidence)
-        if w.trace else {},
-        "device": device,
-        "breakdown": breakdown,
-    }
+    return train_steady.run(ctx, Worker)

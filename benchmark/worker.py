@@ -146,6 +146,8 @@ class Worker:
         self.traced = False
         self.trainer = None
         self.compile_event = None
+        self.startup_spans: List[Any] = []
+        self.reference_check_s: Optional[float] = None
         self.batches: Optional[TimedBatches] = None
 
     # -- set-up ---------------------------------------------------------------
@@ -184,15 +186,18 @@ class Worker:
             trainer_config,
             parallel=ParallelConfig(**self.traffic.get("mesh", {"data": -1})),
         )
-        # The trainer's own ``compile`` event: the step program compiled,
-        # or read back from the compile cache.  Read here, before the
-        # first report ships the ring to the master.
+        # The trainer's own ``compile`` event (the step program compiled,
+        # or read back from the compile cache) and its start-up spans.
+        # Read here, before the first report ships the ring to the master.
         from dlrover_tpu.common import telemetry
 
+        events = telemetry.recorder().peek()
         self.compile_event = next(
-            (e for e in reversed(telemetry.recorder().peek())
-             if e[0] == "compile"), None,
+            (e for e in reversed(events) if e[0] == "compile"), None,
         )
+        self.startup_spans = [
+            e for e in events if e[0].startswith("startup.")
+        ]
         return self.trainer
 
     def _on_event(self, name, secs, **_):
@@ -228,6 +233,15 @@ class Worker:
         return self.batches
 
     # -- correctness against the plain reference ------------------------------
+
+    def timed_reference_check(self) -> Dict[str, Any]:
+        """``check_reference``, timed around the whole call: the rows'
+        making and the check's own compilations are the benchmark's too,
+        and ``setup_s`` counts none of it (``readings.setup_parts``)."""
+        t0 = time.monotonic()
+        reference = self.check_reference()
+        self.reference_check_s = time.monotonic() - t0
+        return reference
 
     def check_reference(self) -> Dict[str, Any]:
         """Per-token loss of the program's forward (its kernels, its dtype,
@@ -358,6 +372,8 @@ class Worker:
             "pipeline_counters": pipeline_counters().summary(),
             "compile_s": self.compile_event[3] if self.compile_event else None,
             "compile": self.compile_event[4] if self.compile_event else None,
+            "startup_spans": self.startup_spans,
+            "reference_check_s": self.reference_check_s,
             "device": device_info(self.devices),
             "tokens_per_step": self.tokens_per_step,
             "seq_len": self.seq_len,

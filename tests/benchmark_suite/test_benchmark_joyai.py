@@ -391,12 +391,16 @@ def test_the_cell_joins_the_lists_the_issue_names_and_no_cost_of_flops_py():
     own = {"latent_attn_ms", "latent_proj_ms", "latent_flash_roofline",
            "shared_expert_ms", "mtp_ms", "held_grouped_matmul_roofline",
            "latent_moe_step_mfu", "moe_pairs_here", "router_bias_absmax"}
-    assert joined == own | {
+    # membership only: the next metric or cell to join breaks nothing here
+    assert joined >= own | {
         "host_step_gap_ms", "step_s_worst_over_median",
         "tokens_per_s_chip_median_step", "data_wait_ms",
         "data_wait_span_ms", "step_device_ms", "device_idle_share",
         "peak_hbm_gib", "startup_to_mesh_s", "moe_row_move_ms",
         "moe_pad_share", "moe_max_expert_load",
+        # owed since PR 35, joined as a data change (PR 47)
+        "forward_ms", "recompute_ms", "backward_ms", "optimizer_ms",
+        "head_loss_ms", "step_unnamed_ms",
     }
     # not the metrics whose costs do not describe this model, nor the
     # dispatch's milliseconds, whose pattern would take in moe/shared/
@@ -404,10 +408,10 @@ def test_the_cell_joins_the_lists_the_issue_names_and_no_cost_of_flops_py():
                  "grouped_matmul_roofline", "moe_dispatch_ms"):
         assert CELL not in per_layer[name]["workloads"]
     for name in own:
-        assert per_layer[name]["workloads"] == [CELL]
+        assert CELL in per_layer[name]["workloads"]
         assert per_layer[name]["moves"] == "tokens_per_s_chip"
         assert layers.spec(name)["name"] == name
     e2e = {m["name"]: m for m in build.manifest()["end_to_end"]}
-    assert e2e["tokens_per_s_chip"]["workloads"][-1] == CELL
+    assert CELL in e2e["tokens_per_s_chip"]["workloads"]
     cell = {w["name"]: w for w in build.manifest()["workloads"]}[CELL]
     assert cell["traffic"] == "train_steady_own_ref" and cell["chips"] == 1

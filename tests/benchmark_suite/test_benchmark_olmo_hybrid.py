@@ -322,24 +322,24 @@ def test_delta_state_absmax_is_the_largest_of_the_windows_events():
 
 
 def test_the_cell_joins_the_lists_the_issue_names_and_no_roofline_of_flops_py():
+    """The cell's OWN metrics and the nine every steady cell has, by
+    membership: never a list's place in the file nor a closed set, so that
+    the next metric or cell to join breaks nothing here."""
     per_layer = {m["name"]: m for m in build.manifest()["per_layer"]}
     joined = {name for name, m in per_layer.items()
               if CELL in m.get("workloads", [])}
     own = {"delta_rule_roofline", "linear_attn_ms", "short_conv_ms",
            "pattern_flash_roofline", "pattern_step_mfu",
            "delta_state_absmax"}
-    assert joined == own | {
+    assert joined >= own | {
         "host_step_gap_ms", "step_s_worst_over_median",
         "tokens_per_s_chip_median_step", "data_wait_ms",
         "data_wait_span_ms", "step_device_ms", "device_idle_share",
         "peak_hbm_gib", "startup_to_mesh_s",
     }
     for name in own:
-        assert per_layer[name]["workloads"] == [CELL]
+        assert CELL in per_layer[name]["workloads"]
         assert per_layer[name]["moves"] == "tokens_per_s_chip"
         assert layers.spec(name)["name"] == name
-    names = list(per_layer)
-    assert names[-6:] == [
-        "delta_rule_roofline", "linear_attn_ms", "short_conv_ms",
-        "pattern_flash_roofline", "pattern_step_mfu", "delta_state_absmax",
-    ]
+    for name in ("step_mfu", "flash_roofline", "flash_attn_roofline"):
+        assert CELL not in per_layer[name]["workloads"]

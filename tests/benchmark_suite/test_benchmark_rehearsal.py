@@ -6,6 +6,7 @@ line says ``"rehearsal": true``.  What is checked is the control flow and
 the shape of the result line, never a speed.
 """
 
+import functools
 import json
 import os
 import subprocess
@@ -18,7 +19,10 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(
 LIMIT_S = 420
 
 
+@functools.lru_cache(maxsize=None)
 def run_cell(cell, trace, devices, rehearsal=True):
+    """One run of the command; a worker runs each (cell, trace) once and
+    every test that reads it shares the result."""
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
@@ -42,16 +46,16 @@ def manifest():
 CELLS = [(w["name"], w["chips"]) for w in manifest()["workloads"]]
 
 
-@pytest.mark.parametrize("cell,chips", CELLS)
-@pytest.mark.parametrize("trace", [0, 1])
-def test_rehearsal_prints_one_result_line(cell, chips, trace):
+def check_result_line(cell, chips, trace):
     proc = run_cell(cell, trace, chips)
     assert proc.returncode == 0, proc.stderr[-3000:]
     line = json.loads(proc.stdout.strip().splitlines()[-1])
     assert set(line) - {"breakdown"} == {
         "correct", "attempted", "failed", "metrics", "device", "rehearsal"
     }
-    assert line["correct"] is True and line["failed"] == 0
+    assert line["correct"] is True and line["failed"] == 0, (
+        proc.stdout[-3000:]
+    )
     assert line["attempted"] > 0
     assert line["device"]["platform"] == "cpu"
     assert line["device"]["count"] == chips
@@ -71,6 +75,14 @@ def test_rehearsal_prints_one_result_line(cell, chips, trace):
     assert any("readings_s" in n or "step_readings_s" in n for n in notes)
 
 
+@pytest.mark.parametrize("cell,chips", CELLS)
+def test_rehearsal_prints_one_result_line(cell, chips):
+    """The untraced run of every cell.  The traced runs are checked in
+    ``test_benchmark_program_spans.py``, the file that reads them for the
+    span metrics too: one worker runs each traced rehearsal once."""
+    check_result_line(cell, chips, 0)
+
+
 def test_measuring_without_a_tpu_exits_non_zero_and_prints_no_result():
     cell = CELLS[0][0]
     proc = run_cell(cell, 0, 1, rehearsal=False)
@@ -82,3 +94,4 @@ def test_measuring_without_a_tpu_exits_non_zero_and_prints_no_result():
 def test_an_unknown_workload_exits_non_zero():
     proc = run_cell("no-such.cell", 0, 1)
     assert proc.returncode != 0 and not proc.stdout.strip()
+

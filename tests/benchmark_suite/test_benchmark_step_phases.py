@@ -77,7 +77,7 @@ SIX = PHASES + ("head_loss_ms", "step_unnamed_ms")
 CELLS = [
     "gpt2-1.5b.train_steady", "mixtral-8x7b.train_steady",
     "gpt2-1.5b.train_steady_x4", "olmoe-1b-7b.train_steady",
-    "olmo-hybrid-7b.train_steady",
+    "olmo-hybrid-7b.train_steady", "joyai-llm-flash.train_steady",
 ]
 
 
@@ -147,8 +147,8 @@ def test_a_program_without_the_scopes_leaves_the_line_whole():
 
 @pytest.mark.parametrize("metric", SIX)
 def test_the_five_cells_report_it(metric):
-    """A superset check: a later cell (JoyAI's, once its own test lets it)
-    joins the lists as a data change, with no edit here."""
+    """A superset check: a later cell joins the lists as a data change,
+    with no edit here (JoyAI's did, PR 47)."""
     entry = {m["name"]: m for m in build.manifest()["per_layer"]}[metric]
     assert set(CELLS) <= set(entry["workloads"])
     assert (entry["unit"], entry["better"], entry["source"]) == (
@@ -156,3 +156,21 @@ def test_the_five_cells_report_it(metric):
     )
     assert entry["layer"] == "step program"
     assert entry["moves"] == "tokens_per_s_chip"
+
+
+@pytest.mark.parametrize("op_name,named", [
+    # a state-space mixer's scopes and the layer's own norm carry a
+    # layer's name (PR 47: the pattern knew no ``ssm`` and no bare ``ln``)
+    (FWD + BODY + "period/mamba_0/ssm/in_proj/dot_general", True),
+    (BWD + BODY + "checkpoint/period/mamba_0/ssm/scan/pallas_call", True),
+    (FWD + BODY + "period/mamba_0/ssm/dt/softplus", True),
+    (FWD + BODY + "period/mamba_0/ln/mul", True),
+    (FWD + BODY + "blocks/ln_attn/mul", True),
+    # a scan's own slices and an instruction with no op_name stay unnamed
+    (FWD + "while/body/dynamic_slice", False),
+    ("", False),
+])
+def test_step_unnamed_leaves_out_what_carries_a_layers_name(op_name, named):
+    pattern = layers.spec("step_unnamed_ms")["params"]["match"]
+    hit = re.search(pattern, f"fusion.1@{op_name}") is not None
+    assert hit == (not named)

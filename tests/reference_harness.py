@@ -14,10 +14,34 @@ Nemotron-H's tiny model); every comparison passes at the tolerances it had.
 
 A model's file gives its ``config``, its reference module, the leaves its
 init moves and its tolerances; nothing here names a model.
+
+The step programs are built here too, once a process.  ``built`` keys on
+the configuration (a frozen dataclass: every field), the batch and the
+sequence length, the number of devices and the mesh's axes, the optimizer
+and its rate, and whatever further options of ``build_sharded_train`` a
+case names; it builds under the key ``train_lib``'s own build cache names
+the program by, so the cache holds it and nothing stands beside it.
+``preset`` reads a tiny preset of the benchmark, ``lowered`` lowers a built
+step once for every case that reads its text, ``first_step`` runs one step
+on given weights, ``digest`` folds a state as the trainer does,
+``compile_event`` is what a trainer of the configuration says of its
+compiled step.  A file is one process: cases
+that read the same program belong in the same file (and when a file ends
+``conftest.py`` drops every program jax compiled: what ``built`` keeps
+compiles anew if a later file of the same worker asks for it).
+
+What ``built``, ``lowered`` and ``compile_event`` hand out is shared by
+every later case of the file: a case never mutates it (no attribute of the
+``ShardedTrain`` set, no entry of the event's dictionary changed, no
+``reset_build_cache()`` while another case still counts on a hit).  State
+is never shared: ``first_step`` and a case's own ``train.init`` make a new
+one each time, copied from the weights they are given, so the step's
+donation of its state takes nothing of another case's.
 """
 
 import dataclasses
 import functools
+import os
 
 import flax.linen as nn
 import jax
@@ -25,9 +49,141 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from dlrover_tpu.common import telemetry
 from dlrover_tpu.models import moe as moe_lib
-
 from dlrover_tpu.models.transformer import TransformerLM
+from dlrover_tpu.parallel import rules as lr
+from dlrover_tpu.runtime import compile_cache
+from dlrover_tpu.runtime.mesh import ParallelConfig, build_mesh
+from dlrover_tpu.trainer import train_lib
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+# -- the step programs, built once a process ----------------------------------
+
+
+@functools.cache
+def preset(name, seq=None):
+    """``(model, seq, sequences a chip)`` of the benchmark's tiny preset
+    ``name``, at ``seq`` tokens or its own."""
+    from benchmark import build
+
+    cfg = build.load_json(os.path.join(
+        REPO, "tests", "benchmark_suite", "presets", f"{name}.json"
+    ))
+    seq = seq or cfg["run"]["seq_len"]
+    return (
+        build.transformer_config(build.model_group(cfg), seq), seq,
+        cfg["run"]["sequences_per_chip"],
+    )
+
+
+def built(cfg, *, batch, seq, devices=1, parallel=None, optimizer="adafactor",
+          learning_rate=1e-3, **engine):
+    """``build_sharded_train``'s program of ``cfg`` on the first ``devices``
+    host devices (``parallel``: the mesh's axes, all of them ``data`` by
+    default), kept for the process; ``engine``: further options of
+    ``build_sharded_train``."""
+    return _built(
+        cfg, batch, seq, devices, tuple(sorted((parallel or {}).items())),
+        optimizer, learning_rate, tuple(sorted(engine.items())),
+    )
+
+
+@functools.cache
+def _built(cfg, batch, seq, devices, parallel, optimizer, learning_rate,
+           engine):
+    mesh = build_mesh(
+        ParallelConfig(**(dict(parallel) or {"data": -1})),
+        devices=jax.devices()[:devices],
+    )
+    engine = dict(engine)
+    key = compile_cache.train_cache_key(
+        cfg, mesh.devices.shape, global_batch_size=batch, seq_len=seq,
+        optimizer=f"{optimizer}/lr={learning_rate!r}", **engine,
+    )
+    return train_lib.build_sharded_train(
+        TransformerLM(cfg),
+        train_lib.make_optimizer(optimizer, learning_rate=learning_rate),
+        mesh, lr.DEFAULT_RULES, global_batch_size=batch, seq_len=seq,
+        cache_key=key, **engine,
+    )
+
+
+_LOWERED = {}
+
+
+def lowered(train):
+    """The step of ``train`` lowered for its own abstract state and batch:
+    one tracing for every case that reads its text or compiles it."""
+    # by identity (a ``ShardedTrain`` does not hash): the entry holds the
+    # program, so an id is never another's
+    if id(train) not in _LOWERED:
+        state = jax.eval_shape(train.init_fn, train_lib._ABSTRACT_KEY)
+        with train_lib.use_mesh(train.mesh):
+            _LOWERED[id(train)] = (
+                train, train.step_fn.lower(state, train.batch_avals)
+            )
+    return _LOWERED[id(train)][1]
+
+
+def first_step(train, params, tokens):
+    """``(the new state, the metrics)`` of one step of ``train`` from a new
+    state that holds copies of ``params``, on the batch ``tokens``."""
+    state = train.init(jax.random.PRNGKey(0))
+    state = state.replace(params=jax.tree.map(
+        lambda new, old: jax.device_put(
+            jnp.array(new, old.dtype, copy=True), old.sharding
+        ), params, state.params,
+    ))
+    batch = {"inputs": np.asarray(tokens[0]), "targets": np.asarray(tokens[1])}
+    return train.step(state, train_lib.shard_batch(batch, train))
+
+
+def digest(state):
+    """The train state's digest as the trainer takes it, one program a
+    tree (op by op a leaf's fold is a program for every shape)."""
+    from dlrover_tpu.trainer import state_digest
+
+    return int(jax.jit(state_digest._digest_tree)(state))
+
+
+@functools.cache
+def compile_event(cfg, seq, patches=()):
+    """The attributes of the ``compile`` event of an ``ElasticTrainer`` of
+    ``cfg`` on every host device, a sequence each; ``patches``: (object,
+    attribute, value) triples in force while it is built (the build cache
+    is emptied around such a build, and only around such a one)."""
+    from dlrover_tpu.trainer.elastic_trainer import (
+        ElasticTrainer, TrainerConfig,
+    )
+
+    recorder = telemetry.recorder()
+    was_enabled = recorder.enabled
+    recorder.configure(enabled=True)
+    try:
+        with pytest.MonkeyPatch.context() as patch, (
+            recorder.open_tap()
+        ) as tap:
+            for target, name, value in patches:
+                patch.setattr(target, name, value)
+            if patches:
+                # a patch changes the program and not the key it is kept by
+                train_lib.reset_build_cache()
+            ElasticTrainer(cfg, TrainerConfig(
+                global_batch_size=jax.device_count(), seq_len=seq,
+                optimizer="adafactor", warmup_compile=True, ckpt_every=1000,
+            ))
+            (event,) = [e for e in tap.take() if e[0] == "compile"]
+    finally:
+        recorder.configure(enabled=was_enabled)
+        if patches:
+            train_lib.reset_build_cache()
+    return event[-1]
+
+
+# -- a program against its reference -------------------------------------------
 
 
 def tokens(seed, batch, seq, vocab):
@@ -115,6 +271,14 @@ def _jitted(what, cfg):
     return jax.jit(functools.partial(fn, cfg))
 
 
+@functools.cache
+def _reference(fn, cfg, kw):
+    """``fn(cfg's fields, params, *tokens, **kw)`` of a reference module as
+    one program (op by op the glue between its layers, a slice of every
+    stacked leaf for every layer, is a program each)."""
+    return jax.jit(functools.partial(fn, dataclasses.asdict(cfg), **dict(kw)))
+
+
 class Harness:
     """One model's program against ``ref``, at that model's tolerances:
     ``loss_atol`` the loss, a gradient leaf within ``grad_atol + grad_rtol
@@ -155,34 +319,42 @@ class Harness:
         (loss, parts), grads = self._once("grads", cfg, params, tokens)
         return loss, parts, grads
 
+    def reference(self, what, cfg, params, tokens, **kw):
+        """The reference module's ``what`` (``forward``, ``token_nll``,
+        ``loss_and_grads``) under ``cfg``'s fields and whatever ``kw`` names
+        (a fault, a lowered precision: structure, so a program each), as
+        NumPy arrays; compiled once for a (``what``, ``cfg``, ``kw``)."""
+        fn = _reference(getattr(self.ref, what), cfg, tuple(sorted(kw.items())))
+        return jax.tree.map(np.asarray, fn(params, *tokens))
+
     def nll_gap(self, cfg, params, tokens, ref_cfg=None, **reference_kw):
         """The largest distance of a token's loss from the reference's,
         computed under ``ref_cfg`` (the program's by default) and whatever
         fault ``reference_kw`` names."""
-        want = self.ref.token_nll(
-            dataclasses.asdict(ref_cfg or cfg), params, *tokens,
-            **reference_kw,
+        want = self.reference(
+            "token_nll", ref_cfg or cfg, params, tokens, **reference_kw
         )
-        return float(jnp.abs(self.nll(cfg, params, tokens) - want).max())
+        got = np.asarray(self.nll(cfg, params, tokens))
+        return float(np.abs(got - want).max())
 
     def loss_and_every_gradient_match(self, cfg, params, tokens):
         got, _, got_grads = self.loss_and_grads(cfg, params, tokens)
-        # the reference's side as one program too (op by op it compiles
-        # every token-by-token piece of itself for each of its calls)
-        want, want_grads = jax.jit(functools.partial(
-            self.ref.loss_and_grads, dataclasses.asdict(cfg)
-        ))(params, *tokens)
+        want, want_grads = self.reference(
+            "loss_and_grads", cfg, params, tokens
+        )
         assert abs(float(got) - float(want)) <= self.loss_atol
         flat_got = jax.tree_util.tree_leaves_with_path(got_grads)
         flat_want = jax.tree_util.tree_leaves(want_grads)
         assert len(flat_got) == len(flat_want)
+        # compared on the host: a leaf's ``abs``, ``max`` and difference
+        # are a program each for every shape a tree has
         for (path, g), w in zip(flat_got, flat_want):
-            name = jax.tree_util.keystr(path)
-            top = float(jnp.abs(w).max())
+            name, g = jax.tree_util.keystr(path), np.asarray(g)
+            top = float(np.abs(w).max())
             bound = self.grad_atol + self.grad_rtol * top
-            assert float(jnp.abs(g - w).max()) <= bound, name
+            assert float(np.abs(g - w).max()) <= bound, name
             if any(part in name for part in self.no_gradient):
-                assert top == 0 and not jnp.asarray(g).any(), name
+                assert top == 0 and not g.any(), name
             elif not any(part in name for part in self.may_be_zero):
                 assert top > 0, name
 

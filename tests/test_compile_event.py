@@ -2,58 +2,31 @@
 ``ElasticTrainer``s of the benchmark's tiny presets: the flash backward's
 path and the classes of its blocks, the form the short convolutions took.
 A preset's trainer is built and compiled once, whichever case asks first,
-and its event kept."""
+and its event kept (``reference_harness.compile_event``)."""
 
 import dataclasses
-import functools
-import os
 
-import jax
 import pytest
 
-from dlrover_tpu.common import telemetry
+import reference_harness as harness
 from dlrover_tpu.ops import flash_attention
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def preset_model(preset, seq=None):
     """(the preset's model at ``seq`` tokens or its own, that length)."""
-    from benchmark import build
-
-    cfg = build.load_json(os.path.join(
-        REPO, "tests", "benchmark_suite", "presets", f"{preset}.json"
-    ))
-    seq = seq or cfg["run"]["seq_len"]
-    return build.transformer_config(build.model_group(cfg), seq), seq
+    return harness.preset(preset, seq)[:2]
 
 
-@functools.cache
 def compile_event(preset, seq=None, vmem_cap=None, xla_attention=False):
     """The attributes of the ``compile`` event of the preset's trainer,
     built with the flash kernels' VMEM bound at ``vmem_cap`` where given."""
-    from dlrover_tpu.trainer import train_lib
-    from dlrover_tpu.trainer.elastic_trainer import (
-        ElasticTrainer, TrainerConfig,
-    )
-
     model, seq = preset_model(preset, seq)
     if xla_attention:
         model = dataclasses.replace(model, attention_impl="xla", remat="none")
-    recorder = telemetry.recorder()
-    was_enabled = recorder.enabled
-    recorder.configure(enabled=True)
-    with pytest.MonkeyPatch.context() as patch, recorder.open_tap() as tap:
-        if vmem_cap is not None:
-            patch.setattr(flash_attention, "_VMEM_CAP", vmem_cap)
-        train_lib.reset_build_cache()
-        ElasticTrainer(model, TrainerConfig(
-            global_batch_size=jax.device_count(), seq_len=seq,
-            optimizer="adafactor", warmup_compile=True, ckpt_every=1000,
-        ))
-        (event,) = [e for e in tap.take() if e[0] == "compile"]
-    recorder.configure(enabled=was_enabled)
-    return event[-1]
+    patches = ()
+    if vmem_cap is not None:
+        patches = ((flash_attention, "_VMEM_CAP", vmem_cap),)
+    return harness.compile_event(model, seq, patches)
 
 
 @pytest.mark.parametrize("preset,blocks,vmem_cap,path,classes", [

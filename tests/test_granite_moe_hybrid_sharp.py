@@ -4,12 +4,9 @@ the tolerance held there (and the program's own switch, where it has one,
 makes the faulty reference agree again), and the reference computed in a
 lower precision is another result."""
 
-import dataclasses
-
-import jax.numpy as jnp
+import numpy as np
 import pytest
 
-from dlrover_tpu.models.references import granite_moe_hybrid as ref
 from test_granite_moe_hybrid_reference import (  # noqa: F401 (fixtures)
     CHECK, TOL, config, params, tokens,
 )
@@ -53,9 +50,10 @@ def test_the_check_is_sharp(wrong, params, tokens):
 
 
 def test_the_reference_computed_lower_is_another_result(params, tokens):
-    fields = dataclasses.asdict(config())
-    exact = ref.token_nll(fields, params, *tokens)
+    exact = CHECK.reference("token_nll", config(), params, tokens)
     for lowered, least in (("router", TOL / 10), ("ssm", TOL),
                            ("all", 100 * TOL)):
-        other = ref.token_nll(fields, params, *tokens, lowered)
-        assert float(jnp.abs(other - exact).mean()) > least, lowered
+        other = CHECK.reference(
+            "token_nll", config(), params, tokens, lowered=lowered
+        )
+        assert float(np.abs(other - exact).mean()) > least, lowered

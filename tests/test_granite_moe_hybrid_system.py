@@ -4,7 +4,7 @@ each (its numerics against the reference are
 ``ssm`` events of a fit say; the mixer's rescaled initialiser.  (What the
 configuration refuses is ``tests/test_granite_moe_hybrid_config.py``'s; the
 defaults that leave every other model's step as it was
-``tests/test_lowered_steps.py``'s.)"""
+``tests/test_step_scopes.py``'s.)"""
 
 import jax
 import numpy as np

@@ -87,7 +87,7 @@ def test_program_matches_the_reference_in_float32(case):
     cfg = config(**CASES[case])
     params = weights(cfg.experts_held)
     _, (main, _, extra), _ = CHECK.loss_and_grads(cfg, params, tokens())
-    want = ref.forward(dataclasses.asdict(cfg), params, *tokens())
+    want = CHECK.reference("forward", cfg, params, tokens())
     np.testing.assert_allclose(main, want["nll"], atol=TOL)
     np.testing.assert_allclose(extra, want["mtp_nll"], atol=TOL)
     CHECK.loss_and_every_gradient_match(cfg, params, tokens())
@@ -102,11 +102,12 @@ def test_program_matches_the_reference_in_float32(case):
 
 
 def test_the_reference_computed_lower_is_another_result():
-    fields = dataclasses.asdict(config())
-    exact = ref.token_nll(fields, weights(), *tokens())
+    exact = CHECK.reference("token_nll", config(), weights(), tokens())
     for lowered, least in (("router", TOL), ("all", 100 * TOL)):
-        other = ref.token_nll(fields, weights(), *tokens(), lowered)
-        assert float(jnp.abs(other - exact).mean()) > least, lowered
+        other = CHECK.reference(
+            "token_nll", config(), weights(), tokens(), lowered=lowered
+        )
+        assert float(np.abs(other - exact).mean()) > least, lowered
 
 
 def test_the_shares_add_up_to_the_uncut_layer():

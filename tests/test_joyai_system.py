@@ -2,12 +2,15 @@
 test each: the train step's loss, metrics and router-bias rule against the
 reference, the microbatch engine, the ``moe`` and
 ``mtp`` events; the parameter count and the latent attention's scopes (at
-the size of ``tests/test_joyai_reference.py``: ``numerics``).  (What the
-configuration refuses and the master's gauges are
-``tests/test_joyai_config.py``'s; the bias under the optimizers and a
-checkpoint, and the pipeline's dense prefix ``tests/test_joyai_state.py``'s.)"""
+the size of ``tests/test_joyai_reference.py``: ``numerics``); then the
+router bias as train state (no optimizer moves it, a Flash Checkpoint
+under FSDP keeps it) and the dense prefix ahead of a two-stage pipeline
+(``tests/test_joyai_state.py``'s cases until PR 48: they take this file's
+sizes and its builder, and a few-case file of two and a half minutes that
+starts last was the end of the whole run).  (What the configuration
+refuses and the master's gauges are ``tests/test_joyai_config.py``'s.)"""
 
-import dataclasses
+import os
 
 import jax
 import jax.numpy as jnp
@@ -20,8 +23,6 @@ from dlrover_tpu.models import moe as moe_lib
 from dlrover_tpu.models.joyai_llm_flash import joyai_llm_flash_config
 from dlrover_tpu.models.references import joyai_llm_flash as ref
 from dlrover_tpu.models.transformer import TransformerLM
-from dlrover_tpu.parallel import rules as lr
-from dlrover_tpu.runtime.mesh import ParallelConfig, build_mesh
 from dlrover_tpu.trainer import train_lib
 
 SEQ, BATCH, VOCAB = 32, 8, 256
@@ -46,13 +47,10 @@ def batches(n, seed=0):
 
 
 def build(cfg=None, devices=1, parallel=None, optimizer="sgd", **kw):
-    mesh = build_mesh(
-        parallel or ParallelConfig(data=-1), devices=jax.devices()[:devices]
-    )
-    return train_lib.build_sharded_train(
-        TransformerLM(cfg or config()),
-        train_lib.make_optimizer(optimizer, learning_rate=1e-2),
-        mesh, lr.DEFAULT_RULES, global_batch_size=BATCH, seq_len=SEQ, **kw,
+    """Kept for the process: the plain step is two cases'."""
+    return harness.built(
+        cfg or config(), batch=BATCH, seq=SEQ, devices=devices,
+        parallel=parallel, optimizer=optimizer, learning_rate=1e-2, **kw,
     )
 
 
@@ -77,11 +75,8 @@ def test_the_step_trains_the_sum_reports_the_parts_and_moves_the_bias():
         new_state, metrics = train.step(
             state, train_lib.shard_batch(batch, train)
         )
-    fields = dataclasses.asdict(cfg)
-    inputs, targets = jnp.asarray(batch["inputs"]), jnp.asarray(
-        batch["targets"]
-    )
-    want = ref.forward(fields, params, inputs, targets)
+    rows = (jnp.asarray(batch["inputs"]), jnp.asarray(batch["targets"]))
+    want = numerics.CHECK.reference("forward", cfg, params, rows)
     assert float(metrics["loss"]) == pytest.approx(
         float(want["nll"].mean()), abs=1e-4
     )
@@ -89,12 +84,12 @@ def test_the_step_trains_the_sum_reports_the_parts_and_moves_the_bias():
         float(want["mtp_nll"].mean()), abs=1e-4
     )
     assert float(metrics["aux_loss"]) == 0.0
-    _, grads = ref.loss_and_grads(fields, params, inputs, targets)
+    _, grads = numerics.CHECK.reference("loss_and_grads", cfg, params, rows)
     # plain SGD, clipped at global norm 1: the update is the gradient's
     # direction, the reference's
     norm = float(metrics["grad_norm"])
-    want_norm = float(jnp.sqrt(sum(
-        jnp.sum(g * g) for g in jax.tree.leaves(grads)
+    want_norm = float(np.sqrt(sum(
+        np.sum(g * g) for g in jax.tree.leaves(grads)
     )))
     assert norm == pytest.approx(want_norm, rel=1e-4)
     moved = biases(new_state.params)
@@ -244,3 +239,127 @@ def test_latent_attention_names_its_scopes_and_shares_one_rotary_key():
         assert scope in text, scope
     kv_a = params["dense_0"]["attn"]["kv_a"]["kernel"]
     assert kv_a.shape == (64, 32 + 8)    # one 8-wide rotary key, not 4
+
+
+# -- the bias as train state, and the dense prefix ahead of a pipeline ---------
+
+
+@pytest.mark.parametrize("optimizer", ["adafactor", "adamw"])
+def test_no_optimizer_moves_the_bias_only_the_rule_does(optimizer):
+    train = build(optimizer=optimizer)
+    state = train.init(jax.random.PRNGKey(0))
+    for i, batch in enumerate(batches(3), start=1):
+        state, metrics = train.step(
+            state, train_lib.shard_batch(batch, train)
+        )
+        for name, bias in biases(state.params).items():
+            # every entry has moved by whole steps of the rate, each way
+            steps = bias / 0.001
+            np.testing.assert_allclose(steps, np.rint(steps), atol=1e-3)
+            assert np.abs(steps).max() <= i + 1e-3, name
+        assert float(np.asarray(metrics[moe_lib.SHARE_STATS_NAME])[1]) == (
+            pytest.approx(0.001 * (i - 1), abs=1e-6)
+        )
+
+
+@pytest.mark.skipif(len(jax.devices()) < 2, reason="needs two host devices")
+def test_a_flash_checkpoint_keeps_the_router_bias(small_pieces):
+    """``b`` is train state no gradient moves: saved through the staged
+    path with the rest of it and restored from the arena alone."""
+    from dlrover_tpu.checkpoint import engine as ckpt_engine
+    from dlrover_tpu.checkpoint.shm_handler import (
+        SharedMemoryHandler,
+        assemble_tensor,
+    )
+
+    train = build(
+        devices=2, parallel=dict(data=1, fsdp=2),
+        optimizer="adafactor",
+    )
+    state = train.init(jax.random.PRNGKey(0))
+    for batch in batches(3):
+        state, _ = train.step(state, train_lib.shard_batch(batch, train))
+    saved, saved_bias = harness.digest(state), biases(state.params)
+    assert all(np.abs(b).max() > 0 for b in saved_bias.values())
+    name = f"joyai{os.getpid()}"
+    writer = SharedMemoryHandler(name)
+    try:
+        writer.save_state_dict(state, step=3)
+        writer.close()                       # the process is gone
+        reader = SharedMemoryHandler(name)
+        meta = reader.load_meta()
+        assert meta.step == 3
+        assert [t.path for t in meta.tensors if "router_bias" in str(t.path)]
+        arrays = {
+            t.path: assemble_tensor(t, lambda r: reader.load_block(meta, r))
+            for t in meta.tensors
+        }
+        restored = ckpt_engine.materialize_records(
+            arrays, meta, train.state_shardings,
+            jax.tree_util.tree_structure(state),
+        )
+        assert harness.digest(restored) == saved
+        for key, bias in biases(restored.params).items():
+            np.testing.assert_array_equal(bias, saved_bias[key])
+        batch = train_lib.shard_batch(batches(4)[3], train)
+        _, a = train.step(restored, batch)
+        assert np.isfinite(float(a["loss"]))
+    finally:
+        SharedMemoryHandler(name).close(unlink=True)
+
+
+@pytest.mark.skipif(len(jax.devices()) < 4, reason="needs four host devices")
+def test_the_dense_layer_runs_ahead_of_a_two_stage_pipeline():
+    """A dense trunk with latent attention and one leading layer outside
+    the stack: two pipeline stages over a real ``pipe`` axis give the loss
+    the plain scan gives on the same weights."""
+    dense = dict(
+        num_experts=0, experts_held=0, first_expert=0, router_bias=False,
+        router_scoring="softmax", num_shared_experts=0, moe_dispatch="einsum",
+        mtp_depth=0, num_layers=5, first_k_dense=1,
+    )
+    tokens = batches(1)[0]
+    losses, params1 = {}, None
+    for pp in (1, 2):
+        cfg = config(
+            pipeline_stages=pp, num_microbatches=2 if pp > 1 else 0, **dense
+        )
+        assert cfg.num_scan_units == 4
+        train = build(
+            cfg, devices=2 * pp,
+            parallel=dict(data=2, pipe=pp), optimizer="sgd",
+        )
+        state = train.init(jax.random.PRNGKey(0))
+        if pp == 1:
+            params1 = jax.tree.map(np.asarray, state.params)
+        else:
+            # the first stage's input is the dense layer's output: the
+            # layer lives outside the stage-stacked weights, whole
+            assert set(state.params) == set(params1)
+            stacked = jax.tree.map(
+                lambda leaf: leaf.reshape(2, 2, *leaf.shape[1:]),
+                params1["blocks"],
+            )
+            piped = dict(
+                params1, blocks={"ticks": {"stages": {"layers": stacked}}}
+            )
+            assert jax.tree.structure(piped) == jax.tree.structure(
+                jax.tree.map(np.asarray, state.params)
+            )
+            state = state.replace(params=jax.device_put(
+                piped, train.state_shardings.params
+            ))
+            spec = state.params["blocks"]["ticks"]["stages"]["layers"][
+                "attn"
+            ]["q_b"]["kernel"].sharding.spec
+            assert spec[0] == "pipe", spec
+            assert "pipe" not in str(
+                state.params["dense_0"]["attn"]["q_b"]["kernel"].sharding.spec
+            )
+        _, metrics = train.step(state, train_lib.shard_batch(tokens, train))
+        losses[pp] = float(metrics["loss"])
+    assert losses[2] == pytest.approx(losses[1], rel=1e-4)
+    with pytest.raises(NotImplementedError, match="num_experts=0"):
+        TransformerLM(config(pipeline_stages=2, num_layers=5)).init(
+            jax.random.PRNGKey(0), jnp.zeros((2, SEQ), jnp.int32)
+        )

@@ -11,6 +11,8 @@ e^40, so a product carries the rounding of two exponentials of arguments up
 to 40: 40 x 6e-8 relative), orders its sums differently from the recurrence
 and builds ``(I + A)^-1`` by products: 2e-5 of the largest entry."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -41,11 +43,25 @@ def rule_inputs(seed, length, dk, dv, near):
     return (q, k, v, g, beta), do
 
 
+@functools.cache
+def _jitted(rule):
+    """``rule`` and the gradients of its output against a cotangent, a
+    program each for a shape: the cases that differ in their numbers alone
+    (where the gate is centred) run what the first of them compiled."""
+    return jax.jit(rule), jax.jit(jax.grad(
+        lambda do, *a: (rule(*a) * do).sum(), argnums=(1, 2, 3, 4, 5),
+    ))
+
+
+@functools.cache
+def chunked(chunk):
+    return lambda *a: kda_lib.kda(*a, chunk=chunk)[0]
+
+
 def rule_and_grads(rule, args, do):
+    forward, grads = _jitted(rule)
     with jax.default_matmul_precision("highest"):
-        return jax.jit(jax.value_and_grad(
-            lambda *a: (rule(*a) * do).sum(), argnums=(0, 1, 2, 3, 4),
-        ))(*args)[1], jax.jit(rule)(*args)
+        return grads(do, *args), forward(*args)
 
 
 # (dk, dv): the widths decide the path (``plan``)
@@ -66,9 +82,7 @@ def test_chunked_rule_is_the_recurrence(path, length, chunk, near):
     if near == "zero":
         assert float(args[3].mean()) > -0.2
     want_grads, want = rule_and_grads(reference.kda_recurrence, args, do)
-    got_grads, got = rule_and_grads(
-        lambda *a: kda_lib.kda(*a, chunk=chunk)[0], args, do
-    )
+    got_grads, got = rule_and_grads(chunked(chunk), args, do)
     scale = float(jnp.abs(want).max())
     assert float(jnp.abs(got - want).max()) <= 2e-5 * scale
     for name, g, w in zip("q k v g beta".split(), got_grads, want_grads):

@@ -9,7 +9,6 @@ the train step, the events and the scopes ``tests/test_ling_flash_system.py``'s;
 what the configuration refuses and the benchmark's file
 ``tests/test_ling_flash_config.py``'s; the rule itself ``tests/test_kda.py``'s."""
 
-import dataclasses
 import functools
 
 import jax
@@ -128,7 +127,7 @@ def test_program_matches_the_reference_in_float32(case, tokens):
         assert CHECK.nll_gap(cfg, weights, tokens) <= TOL
         return
     _, (main, aux, _), _ = CHECK.loss_and_grads(cfg, weights, tokens)
-    want = ref.forward(dataclasses.asdict(cfg), weights, *tokens)
+    want = CHECK.reference("forward", cfg, weights, tokens)
     np.testing.assert_allclose(main, want["nll"], atol=TOL)
     assert float(aux) == 0.0
     CHECK.loss_and_every_gradient_match(cfg, weights, tokens)

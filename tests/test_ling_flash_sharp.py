@@ -5,7 +5,7 @@ the faulty reference agree again), and the reference computed in a lower
 precision is another result.  Then the group-limited choice on its own, and
 the shares of an expert layer under it."""
 
-import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -62,12 +62,13 @@ def test_the_check_is_sharp(wrong, params, tokens):
 
 
 def test_the_reference_computed_lower_is_another_result(params, tokens):
-    fields = dataclasses.asdict(config())
-    exact = ref.token_nll(fields, params, *tokens)
+    exact = CHECK.reference("token_nll", config(), params, tokens)
     for lowered, least in (("router", TOL), ("rule", 10 * TOL),
                            ("all", 100 * TOL)):
-        other = ref.token_nll(fields, params, *tokens, lowered)
-        assert float(jnp.abs(other - exact).mean()) > least, lowered
+        other = CHECK.reference(
+            "token_nll", config(), params, tokens, lowered=lowered
+        )
+        assert float(np.abs(other - exact).mean()) > least, lowered
 
 
 # -- the group-limited choice ----------------------------------------------------
@@ -111,12 +112,13 @@ def test_the_program_s_choice_is_the_reference_s_sort():
         num_experts=64, top_k=6, router_groups=8, router_topk_groups=3,
         routed_scaling_factor=2.5,
     )
+    # one program a side (op by op the two are fifty)
     with jax.default_matmul_precision("highest"):
-        want, counts = ref.router(fields, n, p)
-        vals, idx, _ = moe_lib._gate(
+        want, counts = jax.jit(functools.partial(ref.router, fields))(n, p)
+        vals, idx, _ = jax.jit(lambda n, p: moe_lib._gate(
             n @ p["router"]["kernel"], 6, True, "top1", "sigmoid",
             p["router_bias"], 2.5, 8, 3,
-        )
+        ))(n, p)
     got = (jax.nn.one_hot(idx, 64) * vals[..., None]).sum(-2)
     np.testing.assert_allclose(got, want, atol=1e-6)
     # every token's six come from three groups of eight
@@ -187,21 +189,21 @@ def test_tokens_here_counts_the_tokens_with_a_pair_here():
     )
     import flax.linen as nn
 
-    variables = nn.meta.unbox(layer.init(keys[1], n))
-    _, sown = layer.apply(variables, n, mutable=["intermediates"])
+    # the layer and the reference's router as a program each (op by op
+    # they are two hundred)
+    variables = nn.meta.unbox(jax.jit(layer.init)(keys[1], n))
+    _, sown = jax.jit(
+        lambda v, n: layer.apply(v, n, mutable=["intermediates"])
+    )(variables, n)
     pairs, _, tokens_here = np.asarray(
         sown["intermediates"][moe_lib.SHARE_STATS_NAME][0]
     )
     p = jax.tree.map(lambda a: a, variables["params"])
-    _, counts = ref.router(
+    gates, counts = jax.tree.map(np.asarray, jax.jit(functools.partial(
+        ref.router,
         dict(num_experts=64, top_k=4, router_groups=8, router_topk_groups=2),
-        n, p,
-    )
+    ))(n, p))
     assert pairs == pytest.approx(float(counts[8:16].sum() / counts.sum()))
-    gates, _ = ref.router(
-        dict(num_experts=64, top_k=4, router_groups=8, router_topk_groups=2),
-        n, p,
-    )
     want = float((gates[..., 8:16] > 0).any(-1).mean())
     assert tokens_here == pytest.approx(want)
     # a token has a pair here only if group 1 is one of its two of eight

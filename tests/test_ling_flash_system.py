@@ -6,14 +6,12 @@ the scopes the benchmark reads, what ``flash_only`` keeps, and that a model
 without a KDA layer imports and traces none of this.  (Sizes and weights
 are ``tests/test_ling_flash_reference.py``'s: ``numerics``.)"""
 
-import dataclasses
 import json
 import os
 import subprocess
 import sys
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -25,8 +23,6 @@ from dlrover_tpu.models.references import ling_flash as ref
 from dlrover_tpu.models.transformer import TransformerLM
 from dlrover_tpu.ops import kda as kda_lib
 from dlrover_tpu.ops import remat_policy
-from dlrover_tpu.parallel import rules as lr
-from dlrover_tpu.runtime.mesh import ParallelConfig, build_mesh
 from dlrover_tpu.trainer import train_lib
 from test_ling_flash_reference import config, params, tokens  # noqa: F401
 
@@ -57,26 +53,11 @@ def test_the_train_step_s_first_loss_and_bias_move_are_the_reference_s(
     over ALL the experts, in their groups."""
     cfg = config(attention_impl="flash", remat="flash_only",
                  flash_block_q=8, flash_block_kv=8)
-    train = train_lib.build_sharded_train(
-        TransformerLM(cfg),
-        train_lib.make_optimizer("adafactor", learning_rate=1e-3),
-        build_mesh(ParallelConfig(data=1), devices=jax.devices()[:1]),
-        lr.DEFAULT_RULES, global_batch_size=numerics.BATCH,
-        seq_len=numerics.SEQ,
-    )
-    state = train.init(jax.random.PRNGKey(0))
-    state = state.replace(params=jax.tree.map(
-        lambda new, old: jax.device_put(
-            jnp.array(new, old.dtype, copy=True), old.sharding
-        ), params, state.params,
-    ))
+    train = harness.built(cfg, batch=numerics.BATCH, seq=numerics.SEQ)
     before = biases(params)
-    batch = {"inputs": np.asarray(tokens[0]), "targets": np.asarray(tokens[1])}
     with jax.default_matmul_precision("highest"):
-        new_state, metrics = train.step(
-            state, train_lib.shard_batch(batch, train)
-        )
-    want = ref.forward(dataclasses.asdict(cfg), params, *tokens)
+        new_state, metrics = harness.first_step(train, params, tokens)
+    want = numerics.CHECK.reference("forward", cfg, params, tokens)
     assert abs(float(metrics["loss"]) - float(want["nll"].mean())) <= 1e-4
     assert float(metrics["aux_loss"]) == 0.0
     moved = biases(new_state.params)

@@ -9,6 +9,7 @@ EP>1 guard, the router statistics a step hands out, cache-key coverage of the Mo
 and the zero-retrace steady state.
 """
 
+import dataclasses
 import functools
 import json
 
@@ -17,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+import reference_harness as harness
 import trace_asserts
 
 from dlrover_tpu.models.llama import moe_llama_config
@@ -50,14 +52,18 @@ def _batches(n, batch=16, seq=16, vocab=256, seed=0):
     return out
 
 
-def _run(config, parallel=EP_MESH, n_steps=3, batch=16, seq=16, **build_kw):
-    mesh = build_mesh(parallel)
-    model = TransformerLM(config)
-    opt = train_lib.make_optimizer("sgd", learning_rate=1e-2)
-    train = train_lib.build_sharded_train(
-        model, opt, mesh, lr.DEFAULT_RULES,
-        global_batch_size=batch, seq_len=seq, **build_kw,
+def _sgd_step(config, parallel, batch=16, seq=16, **build_kw):
+    """``reference_harness.built``'s program of ``config`` under plain SGD
+    on the whole virtual mesh (kept for the process)."""
+    return harness.built(
+        config, batch=batch, seq=seq, devices=len(jax.devices()),
+        parallel=dataclasses.asdict(parallel), optimizer="sgd",
+        learning_rate=1e-2, **build_kw,
     )
+
+
+def _run(config, parallel=EP_MESH, n_steps=3, batch=16, seq=16, **build_kw):
+    train = _sgd_step(config, parallel, batch, seq, **build_kw)
     state = train.init(jax.random.PRNGKey(0))
     losses = []
     # Re-feed the same batch: loss must fall as the model memorizes it.
@@ -252,13 +258,8 @@ def _build(config, parallel=ONE_CHIP, **build_kw):
     """(model, train, its initial state), built once a configuration: two
     cases ask for the plain einsum and grouped steps each, and the state is
     not donated."""
-    model = TransformerLM(config)
-    train = train_lib.build_sharded_train(
-        model, train_lib.make_optimizer("sgd", learning_rate=1e-2),
-        build_mesh(parallel), lr.DEFAULT_RULES,
-        global_batch_size=16, seq_len=16, donate_state=False, **build_kw,
-    )
-    return model, train, train.init(jax.random.PRNGKey(0))
+    train = _sgd_step(config, parallel, donate_state=False, **build_kw)
+    return TransformerLM(config), train, train.init(jax.random.PRNGKey(0))
 
 
 @pytest.mark.parametrize(
@@ -390,7 +391,10 @@ def test_fit_books_one_moe_event_per_report_from_the_step_itself(
 
     monkeypatch.setenv("DLROVER_TPU_JOB", f"moe_{tmp_path.name}")
     monkeypatch.setenv("DLROVER_TPU_SOCKET_DIR", str(tmp_path / "socks"))
-    config = _moe_config("grouped")
+    # four experts, not the file's eight: the kernels' row budget (128 rows
+    # an expert a device, interpreted) is the cost of these twenty steps,
+    # and two events of every attribute ask for no more than top-2 of 4
+    config = _moe_config("grouped", num_experts=4)
     trainer = ElasticTrainer(
         config,
         TrainerConfig(

@@ -74,25 +74,10 @@ def test_the_bias_moves_by_the_rule_and_the_step_hands_out_ssm_stats(
     policy the cell runs; its first loss is the reference's, the router
     biases move by ``rate x sign(mean load - load)`` of that step's own
     counts, and ``ssm_stats`` leaves with the metrics."""
-    from dlrover_tpu.parallel import rules as lr
-    from dlrover_tpu.runtime.mesh import ParallelConfig, build_mesh
-
     cfg = config(**CASES["kernels"])
-    train = train_lib.build_sharded_train(
-        TransformerLM(cfg),
-        train_lib.make_optimizer("adafactor", learning_rate=1e-3),
-        build_mesh(ParallelConfig(data=1), devices=jax.devices()[:1]),
-        lr.DEFAULT_RULES, global_batch_size=BATCH, seq_len=SEQ,
-    )
-    state = train.init(jax.random.PRNGKey(0))
-    state = state.replace(params=jax.tree.map(
-        lambda new, old: jax.device_put(
-            jnp.array(new, old.dtype, copy=True), old.sharding
-        ), params, state.params,
-    ))
-    batch = {"inputs": np.asarray(tokens[0]), "targets": np.asarray(tokens[1])}
-    new_state, metrics = train.step(state, train_lib.shard_batch(batch, train))
-    out = ref.forward(cfg, params, *tokens)
+    train = harness.built(cfg, batch=BATCH, seq=SEQ)
+    new_state, metrics = harness.first_step(train, params, tokens)
+    out = CHECK.reference("forward", cfg, params, tokens)
     assert abs(float(metrics["loss"]) - float(out["nll"].mean())) <= TOL
     decay, dt, absmax = linear_attention.split_stats(
         np.asarray(metrics[mamba2.STATS_NAME])

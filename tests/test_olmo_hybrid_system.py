@@ -4,9 +4,8 @@ the patterned tree, a live relayout 4 -> 2, the ``linear_attn`` event;
 and, at the size of ``tests/test_olmo_hybrid_reference.py`` (``numerics``),
 the train step's first loss.  (What the configuration refuses and the
 master's gauges are ``tests/test_olmo_hybrid_config.py``'s; the earlier
-models' pinned steps ``tests/test_lowered_steps.py``'s.)"""
+models' pinned steps ``tests/test_step_scopes.py``'s.)"""
 
-import functools
 import os
 
 import jax
@@ -16,10 +15,8 @@ import pytest
 
 from dlrover_tpu.models import linear_attention
 from dlrover_tpu.models.olmo_hybrid import olmo_hybrid_config
-from dlrover_tpu.models.transformer import TransformerLM
-from dlrover_tpu.parallel import rules as lr
-from dlrover_tpu.runtime.mesh import ParallelConfig, build_mesh
 from dlrover_tpu.trainer import train_lib
+import reference_harness as harness
 import test_olmo_hybrid_reference as numerics
 from test_olmo_hybrid_reference import params, tokens  # noqa: F401
 
@@ -49,17 +46,9 @@ def batches(n, seed=0):
 def build(devices, parallel, **kw):
     """Built once for all the cases that ask for the same one (three ask
     for the ZeRO-1 step on four devices): the state is each case's own."""
-    return _built(devices, parallel, tuple(sorted(kw.items())))
-
-
-@functools.cache
-def _built(devices, parallel, kw):
-    mesh = build_mesh(parallel, devices=jax.devices()[:devices])
-    return train_lib.build_sharded_train(
-        TransformerLM(config()),
-        train_lib.make_optimizer("adafactor", learning_rate=1e-2),
-        mesh, lr.DEFAULT_RULES, global_batch_size=BATCH, seq_len=SEQ,
-        **dict(kw),
+    return harness.built(
+        config(), batch=BATCH, seq=SEQ, devices=devices, parallel=parallel,
+        learning_rate=1e-2, **kw,
     )
 
 
@@ -76,8 +65,8 @@ def run(train, steps=3):
 
 
 def test_zero1_on_four_devices_trains_to_the_single_device_losses():
-    one = build(1, ParallelConfig(data=1))
-    four = build(4, ParallelConfig(data=2, fsdp=2), zero1=True)
+    one = build(1, dict(data=1))
+    four = build(4, dict(data=2, fsdp=2), zero1=True)
     assert four.zero1
     _, want, want_stats = run(one)
     state, got, got_stats = run(four)
@@ -92,10 +81,7 @@ def test_zero1_on_four_devices_trains_to_the_single_device_losses():
     assert spec[1] == "fsdp", spec
 
 
-def digest(state):
-    from dlrover_tpu.trainer import state_digest
-
-    return int(state_digest._digest_tree(state))
+digest = harness.digest
 
 
 def test_a_flash_checkpoint_of_the_patterned_tree_restores_its_digest(
@@ -110,7 +96,7 @@ def test_a_flash_checkpoint_of_the_patterned_tree_restores_its_digest(
         assemble_tensor,
     )
 
-    train = build(4, ParallelConfig(data=2, fsdp=2), zero1=True)
+    train = build(4, dict(data=2, fsdp=2), zero1=True)
     state, _, _ = run(train, steps=2)
     saved = digest(state)
     name = f"hybrid{os.getpid()}"
@@ -145,8 +131,8 @@ def test_a_flash_checkpoint_of_the_patterned_tree_restores_its_digest(
 def test_relayout_state_four_to_two_keeps_every_leaf():
     from dlrover_tpu.runtime.virtual_mesh import relayout_state
 
-    four = build(4, ParallelConfig(data=2, fsdp=2), zero1=True)
-    two = build(2, ParallelConfig(data=1, fsdp=2))
+    four = build(4, dict(data=2, fsdp=2), zero1=True)
+    two = build(2, dict(data=1, fsdp=2))
     state, _, _ = run(four, steps=2)
     want = [np.asarray(x) for x in jax.tree.leaves(state)]
     moved = two.adopt(relayout_state(state, two.state_shardings))
@@ -226,30 +212,13 @@ def test_the_train_step_s_first_loss_is_the_reference_s(
     policy the cell runs (the rule's and the flash kernels' outputs kept);
     on one device, and with the batch over ``data`` and the heads over
     ``tensor``, where each device's kernels see its own rows and heads."""
-    from dlrover_tpu.models import linear_attention
-    from dlrover_tpu.parallel import rules as lr
-    from dlrover_tpu.runtime.mesh import ParallelConfig, build_mesh
-    from dlrover_tpu.trainer import train_lib
-
     cfg = numerics.config(attention_impl="flash", remat="flash_only")
-    train = train_lib.build_sharded_train(
-        TransformerLM(cfg),
-        train_lib.make_optimizer("adafactor", learning_rate=1e-3),
-        build_mesh(
-            ParallelConfig(**parallel), devices=jax.devices()[:devices]
-        ),
-        lr.DEFAULT_RULES, global_batch_size=numerics.BATCH,
-        seq_len=numerics.SEQ,
+    train = harness.built(
+        cfg, batch=numerics.BATCH, seq=numerics.SEQ, devices=devices,
+        parallel=parallel,
     )
-    state = train.init(jax.random.PRNGKey(0))
-    state = state.replace(params=jax.tree.map(
-        lambda new, old: jax.device_put(
-            jnp.array(new, old.dtype, copy=True), old.sharding
-        ), params, state.params,
-    ))
-    batch = {"inputs": np.asarray(tokens[0]), "targets": np.asarray(tokens[1])}
-    _, metrics = train.step(state, train_lib.shard_batch(batch, train))
-    want = numerics.reference.token_nll(cfg, params, *tokens).mean()
+    _, metrics = harness.first_step(train, params, tokens)
+    want = numerics.CHECK.reference("token_nll", cfg, params, tokens).mean()
     assert abs(float(metrics["loss"]) - float(want)) <= numerics.LOSS_ATOL
     alpha, beta, absmax = linear_attention.split_stats(
         np.asarray(metrics[linear_attention.STATS_NAME])

@@ -12,6 +12,7 @@ lies a factor of twenty or more from either.
 """
 
 import dataclasses
+import functools
 
 import flax.linen as nn
 import jax
@@ -96,25 +97,10 @@ def test_token_nll_matches_the_reference(attention_impl, params, tokens):
 
 def test_the_train_step_s_first_loss_is_the_reference_s(params, tokens):
     """The normal path: ``build_sharded_train``'s compiled step."""
-    from dlrover_tpu.parallel import rules as lr
-    from dlrover_tpu.runtime.mesh import ParallelConfig, build_mesh
-    from dlrover_tpu.trainer import train_lib
-
     cfg = config(attention_impl="flash", remat="flash_only")
-    train = train_lib.build_sharded_train(
-        TransformerLM(cfg),
-        train_lib.make_optimizer("adafactor", learning_rate=1e-3),
-        build_mesh(ParallelConfig(data=1), devices=jax.devices()[:1]),
-        lr.DEFAULT_RULES, global_batch_size=BATCH, seq_len=SEQ,
-    )
-    state = train.init(jax.random.PRNGKey(0))
-    state = state.replace(params=jax.tree.map(
-        lambda new, old: jnp.array(new, old.dtype, copy=True), params,
-        state.params,
-    ))
-    batch = {"inputs": np.asarray(tokens[0]), "targets": np.asarray(tokens[1])}
-    _, metrics = train.step(state, train_lib.shard_batch(batch, train))
-    want = reference.token_nll(cfg, params, *tokens).mean()
+    train = harness.built(cfg, batch=BATCH, seq=SEQ)
+    _, metrics = harness.first_step(train, params, tokens)
+    want = CHECK.reference("token_nll", cfg, params, tokens).mean()
     assert abs(float(metrics["loss"]) - float(want)) <= LOSS_ATOL
 
 
@@ -142,16 +128,19 @@ def test_the_check_is_sharp(undo, params, tokens, monkeypatch):
     if undo == "top1_balance_loss":
         cfg = config(moe_aux_form="top1")
         nll, aux, _ = CHECK.outputs(cfg, params, tokens)
-        want = reference.loss(cfg, params, *tokens)
+        want = CHECK.reference("loss", cfg, params, tokens)
         assert abs(float(nll.mean() + aux) - float(want)) > 100 * LOSS_ATOL
         return
     if undo == "one_dropped_pair":
         # the plan is patched under a configuration the other cases run:
-        # traced here and now, not taken from what they kept
+        # traced here and now (one program, and a new one), not taken from
+        # what they kept
         cfg = _drop_one_pair(monkeypatch)
-        got = harness.program_nll(cfg, params, *tokens)
-        want = reference.token_nll(cfg, params, *tokens)
-        assert float(jnp.abs(got - want).max()) > 10 * NLL_ATOL
+        got = jax.jit(functools.partial(harness.program_nll, cfg))(
+            params, *tokens
+        )
+        want = CHECK.reference("token_nll", cfg, params, tokens)
+        assert float(np.abs(got - want).max()) > 10 * NLL_ATOL
         return
     cfg = {
         "renormalised_gates": config(norm_topk_prob=True),
@@ -280,6 +269,39 @@ def test_earlier_models_keep_their_trees_and_losses(name):
     )
     np.testing.assert_allclose(float(got_nll.mean()), nll, rtol=1e-6)
     np.testing.assert_allclose(float(got_aux), aux, rtol=1e-6)
+
+
+def test_olmoe_keeps_its_tree_and_losses():
+    """GPT-2's and Mixtral's are held above; OLMoE's first loss and
+    auxiliary term at the parent commit 3e4dd89.  (From
+    ``tests/test_lowered_steps.py``, whose pinned step texts are
+    ``tests/test_step_scopes.py``'s since PR 48.)"""
+    cfg = olmoe_config(
+        vocab_size=256, num_layers=2, d_model=64, num_heads=4, d_ff=32,
+        num_experts=8, top_k=4, max_seq_len=32, dtype=jnp.float32,
+        param_dtype=jnp.float32,
+    )
+    assert cfg.layer_pattern == () and cfg.norm_placement == "pre"
+    assert cfg.norm_eps == 1e-5 and cfg.num_scan_units == 2
+    rng = np.random.default_rng(0)
+    rows = jnp.asarray(rng.integers(0, 256, (2, 33)), jnp.int32)
+    tree = nn.meta.unbox(
+        TransformerLM(cfg).init(jax.random.PRNGKey(0), rows[:, :-1])
+    )["params"]
+    found = sorted(
+        "/".join(k.key for k in path)
+        for path, _ in jax.tree_util.tree_leaves_with_path(tree["blocks"])
+    )
+    assert found == [
+        "attn/k_norm/scale", "attn/out/kernel", "attn/q_norm/scale",
+        "attn/qkv/kernel", "ln_attn/scale", "ln_mlp/scale",
+        "moe/router/kernel", "moe/wg", "moe/wi", "moe/wo",
+    ]
+    logits, aux = TransformerLM(cfg).apply({"params": tree}, rows[:, :-1])
+    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+    nll = -jnp.take_along_axis(logp, rows[:, 1:][..., None], -1)[..., 0]
+    np.testing.assert_allclose(float(nll.mean()), 6.050836563110352, rtol=1e-6)
+    np.testing.assert_allclose(float(aux), 0.0913332924246788, rtol=1e-6)
 
 
 def test_cache_key_covers_the_new_fields():

@@ -1,5 +1,5 @@
 """The step program's names, held against the next change that adds work to
-a step without one.
+a step without one, and the lowered text of the steps that were.
 
 The device trace names instructions only; the per-layer metrics of the step
 program (``benchmark/layer_metrics/{forward,recompute,backward,optimizer,
@@ -7,26 +7,29 @@ head_loss,step_unnamed}_ms.json``) read each instruction's ``op_name`` out of
 the compiled step's text.  So for every tiny preset the compiled step must
 carry ``train_lib.STEP_SCOPES`` where the preset has the mechanism, every
 matmul, convolution and custom call must lie under a layer's or a scope's
-name, and the four phases must not overlap."""
+name, and the four phases must not overlap.
+
+The pinned hashes of the presets' lowered steps are here as well (they
+were ``tests/test_lowered_steps.py``'s, and are unedited): they read the
+text of the lowering whose compiled form the cases above read, which
+``reference_harness.lowered`` traces once a preset."""
 
 import functools
+import hashlib
 import os
 import re
 import sys
 
-import jax
 import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-from benchmark import build, layers, trace_reduce  # noqa: E402
-from dlrover_tpu.models.transformer import TransformerLM  # noqa: E402
-from dlrover_tpu.parallel import rules as lr  # noqa: E402
-from dlrover_tpu.runtime.mesh import ParallelConfig, build_mesh  # noqa: E402
+import reference_harness as harness  # noqa: E402
+from benchmark import layers, trace_reduce  # noqa: E402
+from dlrover_tpu.models.transformer import TransformerConfig  # noqa: E402
 from dlrover_tpu.trainer import train_lib  # noqa: E402
 
-PRESETS = os.path.join(REPO, "tests", "benchmark_suite", "presets")
 PHASES = ("forward_ms", "recompute_ms", "backward_ms", "optimizer_ms")
 EVERY_STEP = {
     train_lib.OPTIMIZER_UPDATE, train_lib.OPTIMIZER_APPLY,
@@ -62,27 +65,27 @@ def pattern(metric):
     return re.compile(spec["params"]["match"])
 
 
-@functools.lru_cache(maxsize=None)
-def compiled_step(preset, devices, zero1, grad_accum, engine=()):
+def step_lowered(preset, devices=1, zero1=False, grad_accum=1, engine=()):
+    """The preset's step, lowered once a process; ``engine``: further
+    options of ``build_sharded_train``, as pairs."""
+    model, seq, per_chip = harness.preset(preset)
+    options = dict(engine)
+    if zero1:
+        options["zero1"] = True
+    if grad_accum > 1:
+        options["grad_accum"] = grad_accum
+    return harness.lowered(harness.built(
+        model, batch=per_chip * devices * grad_accum, seq=seq,
+        devices=devices, **options,
+    ))
+
+
+@functools.cache
+def compiled_step(*case):
     """``(compiled text, {instruction: op_name}, lowered text with its
-    locations)`` of the preset's step; ``engine``: further options of
-    ``build_sharded_train``, as pairs."""
-    cfg = build.load_json(os.path.join(PRESETS, f"{preset}.json"))
-    seq = cfg["run"]["seq_len"]
-    batch = cfg["run"]["sequences_per_chip"] * devices * grad_accum
-    mesh = build_mesh(
-        ParallelConfig(data=-1), devices=jax.devices()[:devices]
-    )
-    train = train_lib.build_sharded_train(
-        TransformerLM(build.transformer_config(build.model_group(cfg), seq)),
-        train_lib.make_optimizer("adafactor", learning_rate=1e-3),
-        mesh, lr.DEFAULT_RULES, global_batch_size=batch, seq_len=seq,
-        zero1=zero1, grad_accum=grad_accum, **dict(engine),
-    )
-    with train_lib.use_mesh(mesh):
-        state = jax.eval_shape(train.init_fn, train_lib._ABSTRACT_KEY)
-        lowered = train.step_fn.lower(state, train.batch_avals)
-        text = lowered.compile().as_text()
+    locations)`` of ``step_lowered(*case)``."""
+    lowered = step_lowered(*case)
+    text = lowered.compile().as_text()
     return (
         text, trace_reduce.scopes_from_hlo(text),
         lowered.as_text(debug_info=True),
@@ -172,3 +175,84 @@ def test_the_phases_are_exclusive(preset, devices, zero1, grad_accum):
             counts[p] += 1
     # every preset rematerialises (``flash_only``), so all four are met
     assert all(counts.values()), counts
+
+
+# -- the lowered text of the steps that were ----------------------------------
+
+# sha256 of the lowered step text (StableHLO; CPU; the benchmark's tiny
+# presets; ``@name_<n>`` counters normalised) at the parent commit 04ce0df
+# with PR 36's flash kernels, which every one of the four runs: no segment
+# compare and no all-masked-row guards without ids or padding, an exact
+# ``scale`` on the q tile, the forward of ONE kv block written straight out
+# (all four were recorded anew; the other modules lower to what they did).
+# A PR that changes these models' step on purpose records them anew.  PR 33
+# left all four as they were: the flash kernels at ``d_qk == d_v``, the
+# grouped GEMMs with every expert held (no dead blocks skipped), the router
+# statistics without a share and the trunk without a dense prefix or an MTP
+# module lower to what they lowered to.  PR 34 (the one-pass flash backward at
+# several kv blocks) left three as they were: their tiny presets run ONE kv
+# block (64 tokens in a block of 64), which lowers to the parent's kernel.
+# ``olmo-hybrid-7b``'s preset sets blocks of 16 for its 64 tokens, four kv
+# blocks: its two full layers' backward is now one kernel with a [64, 16]
+# float32 dq scratch where it was two, so its text is recorded anew.
+LOWERED_AT_PARENT = {
+    "gpt2-1.5b":
+        "3fb5f6338781894bc6418780c92ff0224b12abbaddadeef5d7c79a740c9f4f92",
+    "mixtral-8x7b":
+        "a610499e04164995118fff59e041ffb9f8a82901625a1fddb1ebe83edd4790bb",
+    "olmoe-1b-7b":
+        "0d7f87bc882696205ed45766b521452c70b9eab6848047132852914d5623fd02",
+    "olmo-hybrid-7b":
+        "f0d80527a4cb2792ec44b3c973ef822f3582b8ba5d6d058e9850cd56c1ed6ab5",
+}
+
+
+def lowered_step_text(preset):
+    text = step_lowered(preset).as_text()
+    return re.sub(r"@(\w+?)_\d+\b", r"@\1_N", text)
+
+
+@pytest.mark.parametrize("preset", sorted(LOWERED_AT_PARENT))
+def test_earlier_models_keep_their_lowered_step_text(preset):
+    text = lowered_step_text(preset)
+    assert hashlib.sha256(text.encode()).hexdigest() == (
+        LOWERED_AT_PARENT[preset]
+    )
+    if preset != "olmo-hybrid-7b":
+        assert "linear_attn" not in text and "delta" not in text
+    # and none of them has met the DeepSeek-V3 family's parts
+    for name in ("latent", "router_bias", "mtp", "moe_share_stats"):
+        assert name not in text, name
+
+
+# sha256 of the lowered step text of the two tiny presets that
+# the table above does not hold (its ``lowered_step_text``): JoyAI-LLM-Flash's
+# (latent attention, the sigmoid router, a share) and Nemotron's, whose text
+# holds its scan kernels' grids and index maps (one tile a group).  Both are
+# the texts since PR 45, which took the gather, its scatter and ``top_k``'s
+# sort out of the sigmoid router (``models/moe.py::_gate``): at the parent
+# 0ab4b77 they read 680dda30...835c48d (as at fed8b01: nothing else in the
+# step had moved) and 1ac0af4f...e16b47b4 (PR 40's scan kernels); the
+# presets that route by softmax or not at all, the four above and Granite's,
+# kept the parent's texts (CHANGES.md, PR 45).  Whoever edits the router or
+# the scan kernels next re-pins them.
+LATER_PRESETS_LOWERED = {
+    "joyai-llm-flash":
+        "63af0acfb70a6311d09581ecbce514fc91fea6dfa3d15f6c04ad2fa9706c9955",
+    "nemotron-3-nano-30b-a3b":
+        "80d960d277046e5ad1b5448296088ed0d3696374b89f651e741461bf439bf378",
+}
+
+
+@pytest.mark.parametrize("preset", sorted(LATER_PRESETS_LOWERED))
+def test_the_multipliers_and_the_tiles_default_to_nothing(preset):
+    """A config that names none of the four multipliers, and a scan whose
+    group is one grid step, lower to the step they lowered to (the other
+    four pinned texts are held above)."""
+    cfg = TransformerConfig()
+    assert (cfg.embed_scale, cfg.attention_scale, cfg.residual_scale,
+            cfg.logit_scale) == (1.0, 0.0, 1.0, 1.0)
+    text = lowered_step_text(preset)
+    assert hashlib.sha256(text.encode()).hexdigest() == (
+        LATER_PRESETS_LOWERED[preset]
+    )

@@ -475,8 +475,8 @@ class KimiDeltaAttention(nn.Module):
             o, state_absmax = _delta_rule_local(
                 q, k, v, g, beta, chunk=self.chunk, rule=kda_ops.kda
             )
-        # kept by ``flash_only`` as the scalar rule's output is
-        # (ops/remat_policy.py)
+        # kept by ``flash_only``, and beside it the kernel's chunk-start
+        # states (``kda_states``, ops/kda.py; ops/remat_policy.py says why)
         o = jax.ad_checkpoint.checkpoint_name(o, "kda_out")
         o = nn.with_logical_constraint(o, spec)
         alpha = jnp.exp(g)

@@ -50,7 +50,11 @@ adds, exact), the scaled keys, ``A'``, ``T``, ``W``, ``U``, ``M`` in VMEM.
 HBM sees, forward: the inputs, ``o``, each chunk's start state in the
 operands' dtype, the largest ``|S|``; backward: those and ``do`` in, dq,
 dk, dv, dg, dbeta out.  The custom VJP keeps the inputs and the start
-states; under a layer's remat the forward kernel runs again for them.
+states, and names the states ``kda_states``: a remat policy that keeps that
+name beside the mixer's ``kda_out`` (``flash_only``, ops/remat_policy.py)
+replays a forward kernel with no live output, so it runs once a layer and
+the backward reads the first run's states; a policy that keeps no names
+(``full``) runs it a second time for them.
 
 **Precision.**  Products take operands in the inputs' dtype and accumulate
 in float32; g, its running sums, ``T`` (built by halves in float32
@@ -75,6 +79,7 @@ import types
 from typing import Tuple
 
 import jax
+import jax.ad_checkpoint
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -399,6 +404,10 @@ def _backward(q, k, v, g, beta, starts, do):
 
 def _rule_fwd(q, k, v, g, beta):
     o, starts, top = _forward(q, k, v, g, beta)
+    # kept by ``flash_only`` beside the mixer's ``kda_out``: the replayed
+    # forward kernel then has no live output and the backward reads these
+    # (ops/remat_policy.py).  Under any other policy the name is a no-op.
+    starts = jax.ad_checkpoint.checkpoint_name(starts, "kda_states")
     return (o, top), (q, k, v, g, beta, starts)
 
 

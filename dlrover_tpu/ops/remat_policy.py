@@ -21,19 +21,36 @@ The saveable names are emitted by the model code via
 (models/linear_attention.py: the gated delta rule's output; a model
 without such a layer emits no such name, and its step is what it was),
 ``kda_out`` (the same file's ``KimiDeltaAttention``: the per-channel
-rule's output, kept as the scalar rule's is and for the same reason),
+rule's output) and ``kda_states`` (ops/kda.py custom_vjp fwd, the kernel
+path only: the state each chunk starts from, which the backward kernel
+reads),
 ``ssd_out`` (models/mamba2.py: the state-space scan's output, likewise
 only where a model has such a layer),
 ``latent_k`` / ``latent_v`` (models/attention.py ``LatentAttention``: the
 per-head keys and values rebuilt from the latent row; NO registered policy
 keeps them).
 
-What ``flash_only`` keeps of a linear layer is that output alone.  The
-rule's backward kernel also reads the state each chunk starts from, and
-the forward kernel writes those again under the remat: keeping them too
-(no second forward) was the slower step on the chip, because the compiler
-then made room by recomputing two projections, and keeping neither let it
-pick slower layouts around the rule (PERF.md §6, PR 32).
+What ``flash_only`` keeps of a linear layer differs by rule, and the chip
+chose each.  Both rules' backward kernels read the state each chunk starts
+from, which the forward kernel writes beside its output; keeping those
+states costs residency and no traffic, and spares the forward kernel's
+second run under the layer's remat.
+
+* The scalar rule (``ops/gated_delta_rule.py``, the hybrid): the output
+  alone (``delta_out``).  Keeping the states too (no second forward) was
+  the slower step on the chip, 1915.20 against 1907.73 ms, because the
+  compiler then made room by recomputing two projections, and keeping
+  neither let it pick slower layouts around the rule (PERF.md §6, PR 32).
+* The per-channel rule (``ops/kda.py``, Ling): the output AND the states
+  (``kda_out``, ``kda_states``; ``[B H, S / 128, dv, dk]``, 134 MB a layer
+  at 2 x 8192 tokens and 32 heads of 128 / 128).  That step holds 7.35 of
+  15.75 GiB, so nothing has to make room: the replayed forward kernel has
+  no live output and is dropped, one run of it a layer instead of two.
+  On the chip the step fell from 854.33 to 807.90 ms, six runs of 7.72 ms,
+  no other instruction moved by more than 0.03 ms and the peak of memory
+  stood where it was (PERF.md §6, PR 49).  Only a program with a KDA layer
+  on the kernel path emits the name; the ``jax.numpy`` form of the rule
+  (head widths that are no whole lane tiles) has no kernel to drop.
 
 What ``flash_only`` keeps of a state-space (Mamba-2) layer is likewise the
 scan's output alone (``ssd_out``, ``[B, S, H P]``): the scan's forward
@@ -128,12 +145,16 @@ register(RematPolicy(
     "flash_res", saved_names=("attn_out", "flash_out", "flash_lse"),
     hbm_act_per_token_layer=3.05, recompute_fraction=0.55,
 ))
-# The mixers' kernels' outputs and nothing else: the flash kernel's, and
-# where a layer pattern has gated-delta-rule or state-space layers, the
-# rule's and the scan's.
+# What the mixers' kernels wrote and their backward kernels read, as far as
+# the chip chose it (the module's text): the flash kernel's output and
+# log-sum-exp rows, the KDA rule's output and chunk-start states, and of the
+# scalar rule and the state-space scan the output alone.
 register(RematPolicy(
     "flash_only",
-    saved_names=("flash_out", "flash_lse", "delta_out", "kda_out", "ssd_out"),
+    saved_names=(
+        "flash_out", "flash_lse", "delta_out", "kda_out", "kda_states",
+        "ssd_out",
+    ),
     hbm_act_per_token_layer=2.05, recompute_fraction=0.7,
 ))
 

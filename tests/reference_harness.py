@@ -228,6 +228,19 @@ def held(params, cfg):
     return jax.tree_util.tree_map_with_path(cut, params)
 
 
+def pallas_calls(jaxpr):
+    """The ``name`` of every ``pallas_call`` equation of ``jaxpr``, those of
+    its nested programs too (a scanned body counts once).  Not a count of
+    its text: the printer writes a program two equations share once."""
+    names = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            names.append(eqn.params["name"])
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            names.extend(pallas_calls(sub))
+    return names
+
+
 def token_nll(logits, targets):
     logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
     return -jnp.take_along_axis(logp, targets[..., None], -1)[..., 0]

@@ -133,6 +133,23 @@ def test_state_absmax_is_the_largest_boundary_state(path):
     assert float(absmax) > 0
 
 
+@pytest.mark.parametrize("path", sorted(WIDTHS))
+def test_the_kernels_start_states_are_named_where_the_backward_reads_them(
+    path,
+):
+    """``kda_states`` is what ``flash_only`` keeps beside the mixer's
+    ``kda_out`` (ops/remat_policy.py): the forward kernel's chunk-start
+    states, named inside the custom VJP's forward rule.  The ``jax.numpy``
+    form has no kernel to drop and names nothing."""
+    dk, dv = WIDTHS[path]
+    (q, k, v, g, beta), do = rule_inputs(0, 64, dk, dv, "mixed")
+    text = str(jax.make_jaxpr(jax.grad(
+        lambda q: (kda_lib.kda(q, k, v, g, beta, chunk=32)[0] * do).sum()
+    ))(q))
+    assert ("name=kda_states" in text) == (path == "kernel")
+    assert ("kda_bwd" in text) == (path == "kernel")
+
+
 @pytest.mark.parametrize("chunk", [8, 24, 48])
 def test_a_chunk_that_is_no_whole_sub_chunks_raises_with_the_numbers(chunk):
     (q, k, v, g, beta), _ = rule_inputs(0, 32, 16, 16, "mixed")

@@ -6,6 +6,7 @@ the scopes the benchmark reads, what ``flash_only`` keeps, and that a model
 without a KDA layer imports and traces none of this.  (Sizes and weights
 are ``tests/test_ling_flash_reference.py``'s: ``numerics``.)"""
 
+import functools
 import json
 import os
 import subprocess
@@ -207,20 +208,67 @@ def test_the_scopes_the_benchmark_reads_reach_the_compiled_text(tokens):
     assert "top_k" not in text
 
 
-def test_flash_only_keeps_the_rule_s_output_under_its_own_name():
-    kept = remat_policy.resolve("flash_only").saved_names
-    assert "kda_out" in kept and "delta_out" in kept
-    cfg = config(attention_impl="flash", remat="flash_only",
-                 flash_block_q=8, flash_block_kv=8)
-    weights = numerics.share(cfg)
-    inputs, targets = numerics.seeded()[0]
+def traced_gradient(cfg, inputs, targets):
+    """The mean token loss's gradient, traced against the shapes of
+    ``cfg``'s own init (no weight is made)."""
+    model = TransformerLM(cfg)
+    weights = jax.eval_shape(model.init, jax.random.PRNGKey(0), inputs)
 
     def loss(p):
-        logits, _ = TransformerLM(cfg).apply({"params": p}, inputs)
-        return harness.token_nll(logits, targets).mean()
+        return harness.token_nll(model.apply(p, inputs)[0], targets).mean()
 
-    text = str(jax.make_jaxpr(jax.grad(loss))(weights))
+    return jax.make_jaxpr(jax.grad(loss))(weights)
+
+
+@functools.cache
+def gradient_program(remat, kernel_widths=True):
+    """``traced_gradient`` of the small model under ``remat``: with heads of
+    128 / 128, which take the kernels (a dense layer ahead of a trunk of
+    one: two KDA layers), or as it is (heads of 16: the ``jax.numpy``
+    form)."""
+    widths = numerics.CASES["kda_kernel_widths"] if kernel_widths else {}
+    cfg = config(**widths, attention_impl="flash", remat=remat,
+                 flash_block_q=8, flash_block_kv=8)
+    return traced_gradient(cfg, *numerics.seeded()[0])
+
+
+def test_flash_only_keeps_the_rule_s_output_under_its_own_name():
+    kept = remat_policy.resolve("flash_only").saved_names
+    assert {"kda_out", "kda_states", "delta_out"} <= set(kept)
+    text = str(gradient_program("flash_only", kernel_widths=False))
     assert "name=kda_out" in text and "name=flash_out" in text
+    # the states are the kernel's: heads of 16 run the ``jax.numpy`` form
+    assert "name=kda_states" not in text
+    text = str(gradient_program("flash_only"))
+    assert "name=kda_out" in text and "name=kda_states" in text
+
+
+@pytest.mark.parametrize("remat,runs", [("flash_only", 1), ("full", 2)])
+def test_the_forward_kernel_runs_once_a_layer_where_its_states_are_kept(
+    remat, runs
+):
+    """Under ``flash_only`` the layer's remat keeps both of the forward
+    kernel's outputs the backward reads, so the replayed kernel has no live
+    output and is dropped; a policy that keeps no names runs it twice."""
+    calls = harness.pallas_calls(gradient_program(remat).jaxpr)
+    assert calls.count("kda_bwd") == 2
+    assert calls.count("kda_fwd") == 2 * runs
+
+
+def test_a_model_with_the_scalar_rule_emits_neither_kda_name():
+    """Only a program with a KDA layer changes: the hybrid's step keeps
+    ``delta_out`` alone, as the chip chose for it (ops/remat_policy.py)."""
+    from dlrover_tpu.models.olmo_hybrid import olmo_hybrid_config
+
+    cfg = olmo_hybrid_config(
+        num_layers=4, d_model=32, num_heads=4, linear_num_heads=4,
+        linear_key_head_dim=8, linear_value_head_dim=16, vocab_size=128,
+        attention_impl="flash", remat="flash_only",
+        flash_block_q=8, flash_block_kv=8,
+    )
+    text = str(traced_gradient(cfg, *harness.tokens(0, 2, 16, 128)))
+    assert "name=delta_out" in text and "name=flash_out" in text
+    assert "kda_out" not in text and "kda_states" not in text
 
 
 def test_num_params_counts_what_is_held():

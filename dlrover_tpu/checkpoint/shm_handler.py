@@ -36,6 +36,7 @@ import numpy as np
 from dlrover_tpu.common import telemetry
 from dlrover_tpu.common.log import default_logger as logger
 from dlrover_tpu.common.multi_process import SharedMemory, attach_or_none
+from dlrover_tpu.runtime import compile_cache
 
 _HEADER = struct.Struct("<Q")
 
@@ -426,9 +427,10 @@ class SharedMemoryHandler:
                 groups.append((first, group))
                 first += sum(group)
             programs = {
-                group: jax.jit(
-                    functools.partial(_flat_pieces, sizes=group)
-                ).lower(block, np.int32(0)).compile()
+                group: compile_cache.staged_compile(
+                    jax.jit(functools.partial(_flat_pieces, sizes=group)),
+                    block, np.int32(0),
+                )
                 for group in sorted({group for _, group in groups})
             }
             plan = _StagedPlan(

@@ -17,14 +17,25 @@ Flash-Checkpoint story leaves on the table.  Two layers remove it:
    ``train_cache_key`` names the compiled program by everything that
    shapes it; ``trainer.train_lib.build_sharded_train`` memoizes on it so
    the second construction performs ZERO retraces.
+
+What is left of a start is booked where it happens: ``staged_compile``
+splits a compilation into tracing, lowering and the cache read or XLA
+compile (spans ``compile.trace``, ``compile.lower``, ``compile.backend``),
+and the module's one ``jax.monitoring`` listener books every executable
+the process builds as a timed ``jax.compile`` event under the span, step
+or restart it fell in.
 """
 
 from __future__ import annotations
 
 import collections
+import contextlib
 import os
-from typing import Dict, Optional
+import threading
+import time
+from typing import Any, Dict, Optional
 
+from dlrover_tpu.common import telemetry
 from dlrover_tpu.common.log import default_logger as logger
 
 # jax's own variable: read by jax at import, never written here.
@@ -41,25 +52,103 @@ ENV_COMPILE_CACHE_CPU_OK = "DLROVER_TPU_COMPILE_CACHE_CPU_OK"
 
 _enabled_dir: Optional[str] = None
 
-# Persistent-cache traffic of this process, counted from jax's monitoring
-# events: a hit is an executable read back, a miss is one compiled and
-# written.  What a restarted trainer reports to prove it compiled nothing.
-_EVENT_NAMES = {
-    "/jax/compilation_cache/cache_hits": "hits",
-    "/jax/compilation_cache/cache_misses": "misses",
+# What jax's monitoring says of the persistent cache: ``hits`` is an
+# executable read back from it, ``misses`` one compiled and written (what a
+# restarted trainer reports to prove it compiled nothing).
+_CACHE_SAID = {
+    "/jax/compilation_cache/cache_hits": "hit",
+    "/jax/compilation_cache/cache_misses": "miss",
 }
-_event_counts: collections.Counter = collections.Counter()
+_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+_counts: collections.Counter = collections.Counter()
+# jax calls a listener on the thread that compiles.  ``said``: what the
+# cache said on this thread since the thread's last executable, which is
+# the next one's; ``built``: the outcome of the last one.
+_thread = threading.local()
+_listening = False
 
 
-def _count_event(event: str, **_):
-    name = _EVENT_NAMES.get(event)
-    if name is not None:
-        _event_counts[name] += 1
+def _on_monitoring(event: str, seconds: float = 0.0, fun_name: str = "", **_):
+    """The one listener, for jax's events and its durations alike.  Every
+    executable the process builds is one timed ``jax.compile`` event, with
+    the ``parent`` and ``id`` of the span it fell in and what the
+    persistent cache did for it: ``cache`` (``hit``: read back, ``miss``:
+    compiled and written, ``off``: compiled and nothing kept, as without a
+    cache) and, on a hit, jax's own ``retrieval_s``.  jax's tracing and
+    lowering durations are not booked: inner ``jit``s nest and would count
+    twice, ``staged_compile``'s spans are the exclusive reading."""
+    if event in _CACHE_SAID:
+        _counts[_CACHE_SAID[event]] += 1
+        _thread.said = {"cache": _CACHE_SAID[event]}
+    elif event == _RETRIEVAL:
+        getattr(_thread, "said", {})["retrieval_s"] = round(seconds, 6)
+    elif event == _BACKEND_COMPILE:
+        _thread.built = _thread.__dict__.pop("said", None) or {"cache": "off"}
+        if telemetry.recorder().enabled:
+            telemetry.event(
+                "jax.compile", duration_s=seconds,
+                t_mono=time.monotonic() - seconds, fun_name=fun_name,
+                seconds=round(seconds, 6), **_thread.built,
+            )
+
+
+def listen():
+    """Register ``_on_monitoring`` with jax, once a process."""
+    global _listening
+    if _listening:
+        return
+    import jax
+
+    jax.monitoring.register_event_listener(_on_monitoring)
+    jax.monitoring.register_event_duration_secs_listener(_on_monitoring)
+    _listening = True
 
 
 def stats() -> Dict[str, int]:
-    """``{"hits", "misses"}`` of the persistent cache since ``enable()``."""
-    return {name: _event_counts[name] for name in _EVENT_NAMES.values()}
+    """``hits`` and ``misses`` of the persistent cache since ``listen()``."""
+    return {"hits": _counts["hit"], "misses": _counts["miss"]}
+
+
+@contextlib.contextmanager
+def stage(name: str, parts: Dict[str, Any], **attrs):
+    """One stage of a compilation: the span ``compile.<name>``, whose
+    attributes it yields (to no effect with telemetry off), and its seconds
+    under ``parts["<name>_s"]``."""
+    t0 = time.monotonic()
+    with telemetry.span(f"compile.{name}", **attrs) as span:
+        try:
+            yield attrs if span is None else span.attrs
+        finally:
+            parts[f"{name}_s"] = round(time.monotonic() - t0, 6)
+
+
+def compile_traced(traced, parts: Dict[str, Any], **attrs):
+    """Lower and compile a traced program (``jitted.trace(...)``) as the
+    spans ``compile.lower`` and ``compile.backend``; the latter closes
+    with the cache's outcome (``_on_monitoring``), which ``parts`` holds
+    too."""
+    listen()
+    with stage("lower", parts, **attrs):
+        lowered = traced.lower()
+    with stage("backend", parts, **attrs) as found:
+        _thread.built = None
+        compiled = lowered.compile()
+        outcome = _thread.built or {"cache": "off"}
+        found.update(outcome)
+        parts.update(outcome)
+    return compiled
+
+
+def staged_compile(jitted, *args):
+    """``jitted.lower(*args).compile()`` where the work happens: tracing,
+    lowering and the cache read or XLA compile are the spans
+    ``compile.trace``, ``compile.lower`` and ``compile.backend``, each with
+    ``fun_name``, children of whatever span is open on the thread."""
+    parts: Dict[str, Any] = {}
+    with stage("trace", parts, fun_name=jitted.__name__):
+        traced = jitted.trace(*args)
+    return compile_traced(traced, parts, fun_name=jitted.__name__)
 
 
 def default_cache_dir() -> str:
@@ -88,7 +177,7 @@ def enable() -> str:
         jax.config.update("jax_compilation_cache_dir", cache_dir)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    jax.monitoring.register_event_listener(_count_event)
+    listen()
     _enabled_dir = cache_dir
     logger.info("persistent compilation cache enabled at %s", cache_dir)
     return cache_dir
@@ -110,6 +199,8 @@ def maybe_enable() -> Optional[str]:
     """
     import jax
 
+    # whether or not the cache comes on: the CPU path never calls enable()
+    listen()
     if (
         os.environ.get(ENV_COMPILE_CACHE_CPU_OK, "") != "1"
         and jax.default_backend() == "cpu"

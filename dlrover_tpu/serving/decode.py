@@ -53,6 +53,7 @@ import jax
 import jax.numpy as jnp
 
 from dlrover_tpu.models.transformer import TransformerConfig, TransformerLM
+from dlrover_tpu.runtime import compile_cache
 from dlrover_tpu.runtime.compile_cache import serve_cache_key
 from dlrover_tpu.serving.tp import (
     SERVE_TP_RULES,
@@ -407,10 +408,11 @@ class ServePrograms:
                 key = ("prefill", bucket)
                 if key in self._aot:
                     continue
-                self._aot[key] = self._prefill.lower(
+                self._aot[key] = compile_cache.staged_compile(
+                    self._prefill,
                     params, jnp.zeros((1, bucket), jnp.int32),
                     jnp.int32(bucket), rng, one, one_k,
-                ).compile()
+                )
                 compiled_any = True
             if ("insert",) not in self._aot or ("decode",) not in self._aot:
                 cache = self.init_cache(params)
@@ -422,18 +424,19 @@ class ServePrograms:
                     cache,
                 )
                 row = self.place_row(row)
-                self._aot[("insert",)] = self._insert.lower(
-                    cache, row, jnp.int32(0)
-                ).compile()
+                self._aot[("insert",)] = compile_cache.staged_compile(
+                    self._insert, cache, row, jnp.int32(0),
+                )
                 compiled_any = True
             if ("decode",) not in self._aot:
                 s = self.slots
-                self._aot[("decode",)] = self._decode.lower(
+                self._aot[("decode",)] = compile_cache.staged_compile(
+                    self._decode,
                     params, cache,
                     jnp.zeros((s,), jnp.int32), jnp.zeros((s,), jnp.int32),
                     rng, jnp.ones((s,), jnp.float32),
                     jnp.zeros((s,), jnp.int32),
-                ).compile()
+                )
                 compiled_any = True
         return time.perf_counter() - t0 if compiled_any else 0.0
 
@@ -624,18 +627,19 @@ class SpecPrograms:
         with self.target._trace_ctx():
             if ("propose",) not in self._aot:
                 draft_pool = self.draft.init_cache(draft_params)
-                self._aot[("propose",)] = self._propose.lower(
-                    draft_params, draft_pool, tok, tok
-                ).compile()
+                self._aot[("propose",)] = compile_cache.staged_compile(
+                    self._propose, draft_params, draft_pool, tok, tok,
+                )
                 compiled_any = True
             if ("verify",) not in self._aot:
                 pool = self.target.init_cache(params)
-                self._aot[("verify",)] = self._verify.lower(
+                self._aot[("verify",)] = compile_cache.staged_compile(
+                    self._verify,
                     params, pool,
                     jnp.zeros((s, self.spec_tokens + 1), jnp.int32), tok,
                     jax.random.PRNGKey(0),
                     jnp.zeros((s,), jnp.float32), tok,
-                ).compile()
+                )
                 compiled_any = True
         return time.perf_counter() - t0 if compiled_any else 0.0
 

@@ -341,18 +341,25 @@ class ElasticTrainer:
         )
         if config.warmup_compile:
             before = compile_cache.stats()
-            compile_s = self.train.aot_compile()
+            # The stages close before the ``compile`` event is recorded
+            # (backdated, its duration what ``aot_compile`` returns), so
+            # they are told their restart.
+            t_compile = time.monotonic()
+            compile_s = self.train.aot_compile(restart_count=restart)
             after = compile_cache.stats()
             # 0.0 means the build cache handed back an already-compiled
             # program — a zero-cost restart, recorded as a cache hit.  The
             # persistent_* counts say what the step program did to the
             # cross-process cache: a restarted trainer hits, never misses.
+            # ``trace_s`` to ``analysis_s`` add up to ``seconds``;
+            # ``text_s`` lies beside them.
             detail = {
                 "seconds": round(compile_s, 6),
                 "restart": renv.restart_count() > 0,
                 "cached": compile_s == 0.0,
                 "persistent_hits": after["hits"] - before["hits"],
                 "persistent_misses": after["misses"] - before["misses"],
+                **(self.train.compile_parts if compile_s else {}),
                 "kernel_calls": self.train.kernel_calls,
                 **self._flash_facts(),
                 "ssm_scan": self._ssm_scan(),
@@ -364,8 +371,7 @@ class ElasticTrainer:
             }
             logger.info("compile warmup: %s", detail)
             telemetry.event(
-                "compile", duration_s=compile_s,
-                t_mono=time.monotonic() - compile_s,
+                "compile", duration_s=compile_s, t_mono=t_compile,
                 restart_count=restart, **detail,
             )
             if self.client is not None:

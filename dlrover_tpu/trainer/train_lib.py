@@ -28,6 +28,7 @@ from dlrover_tpu.models import linear_attention
 from dlrover_tpu.models import mamba2
 from dlrover_tpu.models import moe as moe_lib
 from dlrover_tpu.parallel import rules as lr
+from dlrover_tpu.runtime import compile_cache
 
 
 class TrainState(flax_train_state.TrainState):
@@ -307,6 +308,11 @@ class ShardedTrain:
     # optimized program: 0 wherever the kernels ran in interpret mode, so
     # a chip run can tell that the step it timed holds the kernel it names.
     kernel_calls: Optional[int] = None
+    # Where ``aot_compile``'s seconds went (``trace_s``, ``lower_s``,
+    # ``backend_s``, ``analysis_s``), the text pass beside them
+    # (``text_s``) and what the persistent cache did (``cache``, and on a
+    # hit ``retrieval_s``); empty until it has compiled.
+    compile_parts: Dict[str, Any] = dataclasses.field(default_factory=dict)
 
     def init(self, rng: jax.Array) -> TrainState:
         with use_mesh(self.mesh):
@@ -334,7 +340,7 @@ class ShardedTrain:
         ``op_name`` metadata); empty before ``aot_compile``."""
         return self._aot_step.as_text() if self._aot_step is not None else ""
 
-    def aot_compile(self) -> float:
+    def aot_compile(self, restart_count: Optional[int] = None) -> float:
         """``lower().compile()`` the train step before the first batch.
 
         Returns the wall seconds spent (the goodput ledger records it as
@@ -342,24 +348,44 @@ class ShardedTrain:
         the compiled executable directly, so the jit dispatch path never
         retraces — and with the persistent compilation cache enabled the
         XLA compile inside is a disk hit on a post-restart world.
+
+        The seconds are split where the work happens (``compile_parts``):
+        ``compile.trace`` (the abstract state and the step's tracing),
+        ``compile.lower``, ``compile.backend`` (the cache read or XLA's
+        compile) and ``compile.analysis``; after them, and outside the
+        seconds returned, ``compile.text`` walks the compiled text for
+        ``kernel_calls``.  A trainer books the whole afterwards as its
+        restart's ``compile`` event, whose seconds are the ones returned
+        and which no open span can give (the text pass would lie inside
+        it): with ``restart_count`` the spans, closed by then, carry that
+        ``parent`` and restart.
         """
         if self._aot_step is not None or self.batch_avals is None:
             return 0.0
-        t0 = time.perf_counter()
-        with use_mesh(self.mesh):
-            abstract_state = jax.eval_shape(self.init_fn, _ABSTRACT_KEY)
-            self._aot_step = self.step_fn.lower(
-                abstract_state, self.batch_avals
-            ).compile()
         from dlrover_tpu.utils import memory_profile
 
-        self.memory_analysis = memory_profile.compiled_memory_analysis(
-            self._aot_step
-        )
+        parts = self.compile_parts = {}
+        t0 = time.perf_counter()
+        span_attrs = {} if restart_count is None else {
+            "parent": "compile", "restart_count": restart_count,
+        }
+        named = dict(span_attrs, fun_name=self.step_fn.__name__)
+        with use_mesh(self.mesh):
+            with compile_cache.stage("trace", parts, **named):
+                abstract_state = jax.eval_shape(self.init_fn, _ABSTRACT_KEY)
+                traced = self.step_fn.trace(abstract_state, self.batch_avals)
+            self._aot_step = compile_cache.compile_traced(
+                traced, parts, **named
+            )
+        with compile_cache.stage("analysis", parts, **span_attrs):
+            self.memory_analysis = memory_profile.compiled_memory_analysis(
+                self._aot_step
+            )
         seconds = time.perf_counter() - t0
-        self.kernel_calls = self._aot_step.as_text().count(
-            'custom_call_target="tpu_custom_call"'
-        )
+        with compile_cache.stage("text", parts, **span_attrs):
+            self.kernel_calls = self._aot_step.as_text().count(
+                'custom_call_target="tpu_custom_call"'
+            )
         return seconds
 
 

@@ -25,7 +25,8 @@ the program by, so the cache holds it and nothing stands beside it.
 step once for every case that reads its text, ``first_step`` runs one step
 on given weights, ``digest`` folds a state as the trainer does,
 ``compile_event`` is what a trainer of the configuration says of its
-compiled step.  A file is one process: cases
+compiled step, ``compile_events`` everything it records while it is built.
+A file is one process: cases
 that read the same program belong in the same file (and when a file ends
 ``conftest.py`` drops every program jax compiled: what ``built`` keeps
 compiles anew if a later file of the same worker asks for it).
@@ -149,12 +150,22 @@ def digest(state):
     return int(jax.jit(state_digest._digest_tree)(state))
 
 
-@functools.cache
 def compile_event(cfg, seq, patches=()):
-    """The attributes of the ``compile`` event of an ``ElasticTrainer`` of
-    ``cfg`` on every host device, a sequence each; ``patches``: (object,
-    attribute, value) triples in force while it is built (the build cache
-    is emptied around such a build, and only around such a one)."""
+    """The attributes of the ``compile`` event of ``compile_events``'s
+    trainer."""
+    (event,) = [
+        e for e in compile_events(cfg, seq, patches) if e[0] == "compile"
+    ]
+    return event[-1]
+
+
+@functools.cache
+def compile_events(cfg, seq, patches=()):
+    """Every event an ``ElasticTrainer`` of ``cfg`` on every host device, a
+    sequence each, records while it is built: its start-up spans, the
+    ``compile`` event and its children; ``patches``: (object, attribute,
+    value) triples in force while it is built (the build cache is emptied
+    around such a build, and only around such a one)."""
     from dlrover_tpu.trainer.elastic_trainer import (
         ElasticTrainer, TrainerConfig,
     )
@@ -175,12 +186,11 @@ def compile_event(cfg, seq, patches=()):
                 global_batch_size=jax.device_count(), seq_len=seq,
                 optimizer="adafactor", warmup_compile=True, ckpt_every=1000,
             ))
-            (event,) = [e for e in tap.take() if e[0] == "compile"]
+            return tuple(tap.take())
     finally:
         recorder.configure(enabled=was_enabled)
         if patches:
             train_lib.reset_build_cache()
-    return event[-1]
 
 
 # -- a program against its reference -------------------------------------------

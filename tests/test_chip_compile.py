@@ -404,9 +404,7 @@ def test_compile_cache_has_one_placement_rule(tmp_path, monkeypatch):
     monkeypatch.setattr(
         jax.config, "update", lambda name, value: writes.append(name)
     )
-    monkeypatch.setattr(
-        jax.monitoring, "register_event_listener", lambda fn: None
-    )
+    monkeypatch.setattr(compile_cache, "_listening", True)
     # In two parts: the tree keeps one literal site of this option's name,
     # the one in compile_cache.enable().
     cache_option = "jax_compilation_" + "cache_dir"

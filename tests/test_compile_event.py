@@ -1,6 +1,7 @@
 """What the ``compile`` event says of the compiled step's kernels, read off
 ``ElasticTrainer``s of the benchmark's tiny presets: the flash backward's
-path and the classes of its blocks, the form the short convolutions took.
+path and the classes of its blocks, the form the short convolutions took;
+and where the compilation's own seconds went, by its children.
 A preset's trainer is built and compiled once, whichever case asks first,
 and its event kept (``reference_harness.compile_event``)."""
 
@@ -17,16 +18,22 @@ def preset_model(preset, seq=None):
     return harness.preset(preset, seq)[:2]
 
 
-def compile_event(preset, seq=None, vmem_cap=None, xla_attention=False):
-    """The attributes of the ``compile`` event of the preset's trainer,
-    built with the flash kernels' VMEM bound at ``vmem_cap`` where given."""
+def trainer_of(preset, seq=None, vmem_cap=None, xla_attention=False):
+    """What ``reference_harness`` keys the preset's trainer by: (model,
+    sequence length, patches), the flash kernels' VMEM bound at ``vmem_cap``
+    where given."""
     model, seq = preset_model(preset, seq)
     if xla_attention:
         model = dataclasses.replace(model, attention_impl="xla", remat="none")
     patches = ()
     if vmem_cap is not None:
         patches = ((flash_attention, "_VMEM_CAP", vmem_cap),)
-    return harness.compile_event(model, seq, patches)
+    return model, seq, patches
+
+
+def compile_event(preset, seq=None, **how):
+    """The attributes of the ``compile`` event of the preset's trainer."""
+    return harness.compile_event(*trainer_of(preset, seq, **how))
 
 
 @pytest.mark.parametrize("preset,blocks,vmem_cap,path,classes", [
@@ -73,3 +80,57 @@ def test_compile_event_names_the_short_conv(preset, seq, path):
     """Beside ``test_compile_event_names_the_flash_backward``: which form
     the step's convolutions took is a fact of the compiled step."""
     assert compile_event(preset, seq)["short_conv"] == path
+
+
+STAGES = ("trace", "lower", "backend", "analysis")
+
+
+@pytest.mark.parametrize("preset,seq", [
+    ("gpt2-1.5b", None),
+    ("olmo-hybrid-7b", None),
+    ("nemotron-3-nano-30b-a3b", 128),
+])
+def test_compile_event_names_its_parts(preset, seq):
+    """The event's seconds are split where the work happens: four children,
+    once each, that add up to them; the text pass beside them; and the one
+    executable built, booked under ``compile.backend``."""
+    events = harness.compile_events(*trainer_of(preset, seq))
+    (whole,) = [e for e in events if e[0] == "compile"]
+    attrs = whole[4]
+    assert whole[3] == pytest.approx(attrs["seconds"], abs=1e-5)
+    assert attrs["id"] == "restart:0" and "parent" not in attrs
+    children = {
+        e[0]: e for e in events if e[4].get("parent") == "compile"
+    }
+    assert sorted(children) == sorted(
+        f"compile.{stage}" for stage in (*STAGES, "text")
+    )
+    assert len([e for e in events if e[0] in children]) == 5   # once each
+    for name, (_, kind, _, seconds, said) in children.items():
+        assert kind == "span" and said["id"] == "restart:0"
+        stage = name.split(".")[1]
+        assert seconds == pytest.approx(attrs[f"{stage}_s"], abs=1e-3)
+        if stage in ("trace", "lower", "backend"):
+            assert said["fun_name"] == "_train_step"
+    in_seconds = sum(attrs[f"{stage}_s"] for stage in STAGES)
+    assert in_seconds == pytest.approx(
+        attrs["seconds"], abs=max(0.05, 0.01 * attrs["seconds"])
+    )
+    assert in_seconds <= attrs["seconds"] and attrs["text_s"] > 0
+    # the text pass comes after the seconds, not inside them
+    text = children["compile.text"]
+    assert text[2] >= whole[2] + whole[3] - 1e-3
+    # the CPU keeps no persistent cache: compiled, nothing written
+    assert attrs["cache"] == children["compile.backend"][4]["cache"] == "off"
+    (built,) = [
+        e for e in events if e[0] == "jax.compile"
+        and e[4].get("parent") == "compile.backend"
+    ]
+    assert built[4]["fun_name"] == "jit(_train_step)"
+    assert built[4]["id"] == "restart:0" and built[4]["cache"] == "off"
+    assert built[3] <= children["compile.backend"][3]
+    # start-up's own executables fall where they are built
+    assert [
+        e for e in events if e[0] == "jax.compile"
+        and e[4].get("parent") == "startup.init"
+    ]

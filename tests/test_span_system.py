@@ -2,6 +2,8 @@
 tap, and the spans placed where the save, the persist, the resume and the
 wait for a batch happen."""
 
+import importlib.util
+import json
 import logging
 import os
 import pickle
@@ -96,7 +98,9 @@ def _named(events, name, **attrs):
 # -- the span itself -----------------------------------------------------------
 
 
-def test_parent_and_id_survive_wire_timeline_and_chrome_export():
+def test_parent_and_id_survive_wire_timeline_and_chrome_export(
+    tap, tmp_path, monkeypatch
+):
     r = TelemetryRecorder(enabled=True, source="trainer")
     with r.span("checkpoint", step=7):
         with r.span("checkpoint.d2h") as d2h:
@@ -115,6 +119,20 @@ def test_parent_and_id_survive_wire_timeline_and_chrome_export():
     assert by_name["note"]["id"] == "step:7"
     assert by_name["restore.read"]["parent"] == "restore"
     assert by_name["restore.read"]["id"] == "restart:2"
+    # A program the job compiles late, here the digest at the first
+    # ``sdc_check_every``, is one ``jax.compile`` event of that step's.
+    trainer = _trainer(sdc_check_every=2)
+    tap.take()
+    trainer.fit(_batches(3), max_steps=2)
+    (late,) = [
+        e for e in _named(tap.take(), "jax.compile")
+        if e[4]["fun_name"] == "jit(_digest_tree)"
+    ]
+    assert late[1] == "span"
+    assert late[3] == pytest.approx(late[4]["seconds"], abs=1e-6)
+    assert late[4]["parent"] == "step" and late[4]["id"] == "step:2"
+    assert late[4]["cache"] == "off"
+    drained.append(late)
 
     timeline = JobTimeline()
     servicer = MasterServicer(timeline=timeline)
@@ -125,16 +143,36 @@ def test_parent_and_id_survive_wire_timeline_and_chrome_export():
     merged = {e[0]: e[4] for e in timeline.events(3)[3]}
     assert merged["checkpoint.d2h"] == by_name["checkpoint.d2h"]
     assert merged["restore.read"]["id"] == "restart:2"
+    assert merged["jax.compile"] == late[4]
 
+    # the Perfetto export, as ``tools/job_timeline.py`` writes it
+    dump, out = tmp_path / "events.json", tmp_path / "trace.json"
+    dump.write_text(json.dumps(timeline.events()))
+    spec = importlib.util.spec_from_file_location(
+        "_job_timeline", os.path.join(REPO, "tools", "job_timeline.py")
+    )
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    monkeypatch.setattr(sys, "argv", [
+        "job_timeline.py", "--input", str(dump), "--out", str(out),
+    ])
+    assert tool.main() == 0
+    exported = json.loads(out.read_text())
+    assert exported == json.loads(json.dumps(
+        events_to_chrome_trace(timeline.events())
+    ))
     rows = {
-        e["name"]: e["args"]
-        for e in events_to_chrome_trace(timeline.events())["traceEvents"]
+        e["name"]: e for e in exported["traceEvents"]
         if e["ph"] in ("X", "i")
     }
-    assert rows["checkpoint.d2h"]["parent"] == "checkpoint"
-    assert rows["checkpoint.d2h"]["id"] == "step:7"
-    assert rows["checkpoint.d2h"]["bytes"] == 12
-    assert rows["note"]["parent"] == "checkpoint.d2h"
+    assert rows["checkpoint.d2h"]["args"]["parent"] == "checkpoint"
+    assert rows["checkpoint.d2h"]["args"]["id"] == "step:7"
+    assert rows["checkpoint.d2h"]["args"]["bytes"] == 12
+    assert rows["note"]["args"]["parent"] == "checkpoint.d2h"
+    assert rows["jax.compile"]["ph"] == "X"
+    assert rows["jax.compile"]["args"]["id"] == "step:2"
+    assert rows["jax.compile"]["args"]["fun_name"] == "jit(_digest_tree)"
+    assert rows["jax.compile"]["dur"] == pytest.approx(late[3] * 1e6)
 
 
 def test_parent_is_the_span_open_on_the_same_thread():
@@ -319,6 +357,24 @@ def test_a_save_is_split_where_the_work_happens(tmp_path, tap, small_pieces):
     # new: its programs and pieces are the first save's.
     assert compiled_in[0] > 0 and compiled_in[1] == 0
     assert staged[0] and staged[1] == staged[0]
+    # The first save's staged programs are compiled inside its
+    # ``checkpoint.d2h``: three stages a program, one executable each.
+    programs = sum(len(plan.programs) for plan in staged[0].values() if plan)
+    for stage in ("trace", "lower", "backend"):
+        spans = _named(events, f"compile.{stage}")
+        assert len(spans) == programs > 0
+        assert all(e[4]["parent"] == "checkpoint.d2h"
+                   and e[4]["id"] == "step:2"
+                   and e[4]["fun_name"] == "_flat_pieces" for e in spans)
+    built = _named(events, "jax.compile", parent="compile.backend")
+    assert len(built) == programs
+    assert all(e[4]["id"] == "step:2" for e in built)
+    assert not _named(events, "jax.compile", id="step:4")
+    (first_d2h,) = _named(events, "checkpoint.d2h", id="step:2")
+    assert sum(
+        e[3] for e in events if e[0].startswith("compile.")
+        and e[4].get("parent") == "checkpoint.d2h"
+    ) <= first_d2h[3]
 
 
 def test_a_persist_has_its_four_children_and_says_persisted(tmp_path, tap):
@@ -529,6 +585,90 @@ def test_the_compiled_step_program_hands_out_its_text():
     trainer.train.aot_compile()
     text = trainer.train.compiled_step_text()
     assert text.startswith("HloModule") and "op_name=" in text
+
+
+def test_every_executable_is_booked_once_and_none_with_telemetry_off(tap):
+    """One listener a process, however many trainers start in it; with
+    telemetry off it books nothing and the staged compile gives the same
+    program."""
+    from dlrover_tpu.trainer import train_lib
+
+    compiled = []
+
+    def count(name, seconds, fun_name="", **_):
+        if name == "/jax/core/compile/backend_compile_duration":
+            compiled.append(fun_name)
+
+    jax.monitoring.register_event_duration_secs_listener(count)
+    recorder = telemetry.recorder()
+    starts = []
+    try:
+        for recording in (True, True, False):
+            train_lib.reset_build_cache()
+            recorder.configure(enabled=recording)
+            tap.take()
+            del compiled[:]
+            # every start from this one line: the compiled text names the
+            # lines of its callers
+            trainer = _trainer(warmup_compile=True)
+            starts.append((trainer, tap.take(), list(compiled)))
+    finally:
+        jax.monitoring.unregister_event_duration_listener(count)
+        recorder.configure(enabled=True)
+        train_lib.reset_build_cache()
+    # a second listener would book every executable of the second start twice
+    (first, *_), (second, events, built), (unseen, none, built_unseen) = starts
+    booked = [e[4]["fun_name"] for e in _named(events, "jax.compile")]
+    assert booked == built and booked.count("jit(_train_step)") == 1
+    (whole,) = _named(events, "compile")
+    assert whole[4]["cached"] is False and whole[4]["cache"] == "off"
+    # off: nothing is booked, and the parts are still the trainer's to log
+    assert none == [] and built_unseen.count("jit(_train_step)") == 1
+    assert unseen.train.compile_parts["cache"] == "off"
+    assert (
+        unseen.train.compiled_step_text()
+        == second.train.compiled_step_text()
+        == first.train.compiled_step_text()
+    )
+
+
+def test_the_cache_outcome_is_the_executable_s_own_thread_s(tap):
+    """What the chip says and the CPU cannot (its persistent cache stays
+    off): jax's own sequence for a read from the cache, with an executable
+    of another thread built in the middle of it."""
+    from dlrover_tpu.runtime import compile_cache
+
+    said = compile_cache._on_monitoring
+    built = "/jax/core/compile/backend_compile_duration"
+    before = compile_cache.stats()
+    tap.take()
+    said("/jax/compilation_cache/cache_hits")
+    other = threading.Thread(
+        target=said, args=(built, 0.25), kwargs={"fun_name": "jit(other)"}
+    )
+    other.start()
+    other.join()
+    said("/jax/compilation_cache/compile_time_saved_sec", 120.0)
+    said("/jax/compilation_cache/cache_retrieval_time_sec", 1.5)
+    said(built, 2.0, fun_name="jit(read_back)")
+    said("/jax/compilation_cache/cache_misses")
+    said(built, 3.0, fun_name="jit(written)")
+    said(built, 4.0, fun_name="jit(uncached)")
+    after = compile_cache.stats()
+    assert (after["hits"] - before["hits"],
+            after["misses"] - before["misses"]) == (1, 1)
+    booked = {
+        e[4]["fun_name"]: (e[3], {
+            k: v for k, v in e[4].items() if k in ("cache", "retrieval_s")
+        })
+        for e in _named(tap.take(), "jax.compile")
+    }
+    assert booked == {
+        "jit(other)": (0.25, {"cache": "off"}),
+        "jit(read_back)": (2.0, {"cache": "hit", "retrieval_s": 1.5}),
+        "jit(written)": (3.0, {"cache": "miss"}),
+        "jit(uncached)": (4.0, {"cache": "off"}),
+    }
 
 
 # -- the agent's failure path ---------------------------------------------------------

@@ -365,9 +365,7 @@ def test_persistent_compile_cache_configured(tmp_path, monkeypatch):
     # executables, which is the unsound reuse the CPU gate exists against.
     written = {}
     monkeypatch.setattr(jax.config, "update", written.__setitem__)
-    monkeypatch.setattr(
-        jax.monitoring, "register_event_listener", lambda fn: None
-    )
+    monkeypatch.setattr(compile_cache, "_listening", True)
     monkeypatch.setattr(compile_cache, "_enabled_dir", None)
     monkeypatch.setenv(compile_cache.ENV_JAX_CACHE_DIR, str(tmp_path))
     # On the CPU backend maybe_enable declines (cross-process reuse of

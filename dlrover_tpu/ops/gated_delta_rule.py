@@ -48,11 +48,27 @@ for it (PERF.md §6, PR 32).
 accumulate in float32; ``g``, its running sums, ``T`` and the state are
 float32.  ``T`` is built by halves (:func:`_unit_lower_inverse`), as
 stable as substitution, in float32 products of three bfloat16 passes (the
-operands split into a high and a low half: ``Precision.HIGH``).  The
+operands split into a high and a low half: ``Precision.HIGH``): twelve a
+128-token chunk, six with a left operand of 64 rows (the rows of a level
+that are not structural zeros) and six of 16 (the eight diagonal blocks
+folded onto one block's rows), where until PR 51 all twelve were whole
+128 x 128 x 128; the numbers are the same to the last bit.  The
 shorter product ``(I - A)(I + A^2)(I + A^4)...`` loses every digit on keys
 that resemble each other, as trained keys do, and one-pass products move
 the program's distance from its reference (PERF.md §6, PR 31): neither is
 here.  With float32 operands (the CPU tests) every product is float32.
+
+**Heads in lockstep.**  A head's chunk is a chain of products each of
+which waits for the last: twelve in the inverse, a handful around it, and
+the matrix unit answers one in about a hundred cycles whatever its size.
+The compiler schedules a kernel body in program order, so heads written
+one after another wait one after another: on the chip the forward kernel
+took the same 9.4 ms a call at the benchmark's shapes with a third fewer
+instructions, and 5.4 with the same instructions in another order
+(PERF.md §6, PR 51).  So a head's work is a generator that yields where it
+has asked for a product the next stage waits for, and a grid step
+advances its heads' generators in turn (:func:`_in_lockstep`): one head's
+wait is the others' work.  No number changes, only the program's order.
 
 The kernels want ``[B * H, S, d]``; the transposes from the call site's
 ``[B, S, H, d]`` are made here, inside the caller's scope.  The chunk is
@@ -116,27 +132,144 @@ def _mm_f32(x, y, dims=_NN):
     )
 
 
+def _alone(head):
+    """One head's stages (see :func:`_in_lockstep`) run to the end: what
+    the generator returns."""
+    try:
+        while True:
+            next(head)
+    except StopIteration as done:
+        return done.value
+
+
+def _in_lockstep(heads):
+    """Runs the heads of a grid step, each a generator that yields where
+    it has just asked the matrix unit for a product the next stage waits
+    for, a stage of every head in turn.  The order of the arithmetic
+    inside a head, and so every number, stays what it is; only the
+    program's order interleaves the heads' chains, which is the order the
+    compiler schedules in."""
+    heads = list(heads)
+    while heads:
+        for head in list(heads):
+            try:
+                next(head)
+            except StopIteration:
+                heads.remove(head)
+
+
+_FOLD = 16     # the diagonal blocks folded onto one block's rows below it
+
+
+def _lower_left(row, col, level):
+    """Where ``row``, ``col`` lie in the lower-left quarter of one diagonal
+    block of ``2 << level``."""
+    return (
+        ((row >> (level + 1)) == (col >> (level + 1)))
+        & (((row >> level) & 1) == 1) & (((col >> level) & 1) == 0)
+    )
+
+
+def _live(x, m):
+    """The rows of ``x`` [C, C] whose bit ``m`` is set, [C / 2, C]: aligned
+    slices of ``m`` rows."""
+    return jnp.concatenate(
+        [x[lo + m:lo + 2 * m] for lo in range(0, x.shape[0], 2 * m)], axis=0
+    )
+
+
+def _weave(x, live, m):
+    """``x`` [C, C] with ``live`` [C / 2, C] for its rows whose bit ``m``
+    is set."""
+    return jnp.concatenate([
+        part for lo in range(0, x.shape[0], 2 * m)
+        for part in (x[lo:lo + m], live[lo // 2:lo // 2 + m])
+    ], axis=0)
+
+
 def _unit_lower_inverse(a, row, col, exact):
     """``(I + a)^-1`` for strictly lower-triangular ``a`` [C, C], C a power
     of two, by halves: with the inverses ``P`` of the diagonal blocks of
     size m in hand, those of size 2m are ``P - P E P``, ``E`` the
     lower-left m x m block of each (``[[L1, 0], [E, L2]]^-1 = [[P1, 0],
-    [-P2 E P1, P2]]``).  From m = 1 (``P = I``) that is 2 (log2(C) - 1)
-    C x C products."""
+    [-P2 E P1, P2]]``), from m = 1 (``P = I``): 2 (log2(C) - 1) float32
+    products, none of them C x C x C.  ``P E P`` is zero outside the rows
+    whose bit m is set, and lands where ``P`` is zero, so a level multiplies
+    those rows alone, against ``-a`` whole and ``P``, and keeps of the
+    result the quarter blocks (what else ``-a`` put there never reaches
+    them: ``P`` is block-diagonal).  From m = 16 up the rows are aligned
+    slices, half the matrix.  Below, all is block-diagonal in blocks of
+    16, and the sum ``X'`` of a block-diagonal ``X``'s 16-row slices holds
+    all of it, with ``(X Y)' = X' Y``: the levels run on ``[16, C]`` and
+    unfold (tile, mask to the block diagonal) what they multiply by; a
+    chunk of 16 or less is its own fold.  ``-a`` is split into halves
+    once, and of ``P`` only the new rows.  The entries that are not zero
+    meet the arithmetic of the whole-matrix build, and the result is its
+    result bit for bit (tests/test_gated_delta_rule.py keeps that build).
+
+    A generator, one stage a product (:func:`_in_lockstep`): ``t = yield
+    from ...`` inside a head, :func:`_alone` around it elsewhere."""
     c = a.shape[-1]
-    inv = jnp.where(row == col, 1.0, 0.0) - jnp.where(
-        (row == col + 1) & ((col & 1) == 0), a, 0.0
-    )
-    level = 1
-    while (1 << level) < c:
-        lower_left = (
-            ((row >> (level + 1)) == (col >> (level + 1)))
-            & (((row >> level) & 1) == 1) & (((col >> level) & 1) == 0)
+    fold = min(c, _FOLD)
+    blocks, fold_bits = c // fold, fold.bit_length() - 1
+    same_block = (row >> fold_bits) == (col >> fold_bits)
+    neg = -a
+
+    def unfold(parts):
+        """[fold, C] -> [C, C], each part in its own dtype (the select in
+        float32: the vector unit has no other)."""
+        if blocks == 1:
+            return parts
+        return tuple(
+            jnp.where(
+                same_block,
+                jnp.concatenate([x.astype(F32)] * blocks, axis=0), 0.0,
+            ).astype(x.dtype)
+            for x in parts
         )
+
+    in_block = jnp.where(same_block, neg, 0.0) if blocks > 1 else neg
+    by_block = _halves(in_block, exact)
+    inv = in_block[:fold]
+    for b in range(1, blocks):
+        inv = inv + in_block[b * fold:(b + 1) * fold]
+    # (new iotas: Mosaic cannot slice one)
+    at = jax.lax.broadcasted_iota(jnp.int32, (fold, c), 0)
+    to = jax.lax.broadcasted_iota(jnp.int32, (fold, c), 1) & (fold - 1)
+    inv = jnp.where(
+        at == to, 1.0, jnp.where((at == to + 1) & ((to & 1) == 0), inv, 0.0)
+    )
+    for level in range(1, fold_bits):
         p = _halves(inv, exact)
-        e = _halves(jnp.where(lower_left, a, 0.0), exact)
-        inv = inv - _mm_f32(_halves(_mm_f32(p, e), exact), p)
-        level += 1
+        x = _halves(_mm_f32(p, by_block), exact)
+        yield
+        inv = jnp.where(
+            _lower_left(at, to, level), _mm_f32(x, unfold(p)), inv
+        )
+        yield
+    if fold == c:
+        return inv
+    whole = _halves(neg, exact)
+    p = unfold(_halves(inv, exact))
+    inv, = unfold((inv,))
+    at = jax.lax.broadcasted_iota(jnp.int32, (c // 2, c), 0)
+    to = jax.lax.broadcasted_iota(jnp.int32, (c // 2, c), 1)
+    for level in range(fold_bits, c.bit_length() - 1):
+        m = 1 << level
+        x = _halves(_mm_f32(tuple(_live(h, m) for h in p), whole), exact)
+        yield
+        # live row i is row 2m (i // m) + m + i % m
+        new = jnp.where(
+            ((to >> (level + 1)) == (at >> level))
+            & (((to >> level) & 1) == 0),
+            _mm_f32(x, p), _live(inv, m),
+        )
+        inv = _weave(inv, new, m)
+        yield
+        if 2 * m < c:
+            p = tuple(
+                _weave(old, h, m) for old, h in zip(p, _halves(new, exact))
+            )
     return inv
 
 
@@ -151,7 +284,8 @@ def _to_row(x_col, eye):
 
 def _chunk_tensors(q, k, v, g_row, beta_row, start):
     """What a chunk builds from its own tokens and the state it starts
-    from, forward and backward alike.  ``q``, ``k`` [C, dk], ``v``
+    from, forward and backward alike, in a head's stages (a generator:
+    :func:`_in_lockstep`).  ``q``, ``k`` [C, dk], ``v``
     [C, dv]; ``g_row``, ``beta_row`` [1, C] float32; ``start`` [dv, dk] in
     the operands' dtype.  A vector indexed by the token comes as a column
     [C, 1] where it scales rows and as a row [1, C] where it scales
@@ -169,7 +303,8 @@ def _chunk_tensors(q, k, v, g_row, beta_row, start):
     decay = jnp.exp(jnp.where(lower, b_col - b_row, -jnp.inf))
     beta_col = _to_col(beta_row, eye)
     kk = _dot(k, k, _NT)
-    t = _unit_lower_inverse(
+    yield
+    t = yield from _unit_lower_inverse(
         jnp.where(strict, kk * decay * beta_col, 0.0), row, col,
         exact=cd == F32,
     )
@@ -179,13 +314,16 @@ def _chunk_tensors(q, k, v, g_row, beta_row, start):
     t_w = (t * (beta_row * gamma_row)).astype(cd)
     t_u = (t * beta_row).astype(cd)
     w = _dot(t_w, k).astype(cd)
+    yield
     gamma_col, to_end = jnp.exp(b_col), jnp.exp(total - b_col)
     qk = _dot(q, k, _NT)
+    writes = (_dot(t_u, v) - _dot(w, start, _NT)).astype(cd)         # V'
+    yield
     return types.SimpleNamespace(
         lower=lower, strict=strict, eye=eye, decay=decay, kk=kk, t=t,
         beta_col=beta_col, gamma_row=gamma_row, gamma_col=gamma_col,
         gamma_end=jnp.exp(total), to_end=to_end, t_w=t_w, t_u=t_u, w=w,
-        writes=(_dot(t_u, v) - _dot(w, start, _NT)).astype(cd),      # V'
+        writes=writes,
         k_end=(k.astype(F32) * to_end).astype(cd),
         q_in=(q.astype(F32) * gamma_col).astype(cd),
         qk=qk, within=(qk * decay).astype(cd),                       # M
@@ -201,11 +339,13 @@ def _fwd_kernel(
         state[...] = jnp.zeros_like(state)
         top_ref[...] = jnp.zeros_like(top_ref)
 
-    for h in range(heads):
+    def head(h):
         q, k, v = q_ref[h], k_ref[h], v_ref[h]
         start = state[h].astype(v.dtype)                  # [dv, dk]
         start_ref[h, 0] = start
-        x = _chunk_tensors(q, k, v, g_ref[h, 0], beta_ref[h, 0], start)
+        x = yield from _chunk_tensors(
+            q, k, v, g_ref[h, 0], beta_ref[h, 0], start
+        )
         end = state[h] * x.gamma_end + _dot(x.writes, x.k_end, _TN)
         state[h] = end
         top = jnp.max(
@@ -216,6 +356,8 @@ def _fwd_kernel(
         o_ref[h] = (
             _dot(x.q_in, start, _NT) + _dot(x.within, x.writes)
         ).astype(o_ref.dtype)
+
+    _in_lockstep(head(h) for h in range(heads))
 
 
 def _bwd_kernel(
@@ -229,12 +371,14 @@ def _bwd_kernel(
     def _():
         d_state[...] = jnp.zeros_like(d_state)
 
-    for h in range(heads):
+    def head(h):
         q, k, v, do = q_ref[h], k_ref[h], v_ref[h], do_ref[h]
         cd = v.dtype
         beta_row = beta_ref[h, 0]
         start = start_ref[h, 0]                           # [dv, dk]
-        x = _chunk_tensors(q, k, v, g_ref[h, 0], beta_row, start)
+        x = yield from _chunk_tensors(
+            q, k, v, g_ref[h, 0], beta_row, start
+        )
         lower, decay, t = x.lower, x.decay, x.t
         w, writes, k_end, q_in = x.w, x.writes, x.k_end, x.q_in
         q32, k32 = q.astype(F32), k.astype(F32)
@@ -249,6 +393,7 @@ def _bwd_kernel(
         d_decay = d_within * x.qk
         d_q_in = _dot(do, start)
         d_k_end = _dot(writes, d_end_cd)
+        yield
         d_q = d_q_in * x.gamma_col + _dot(d_qk, k)
         d_k = _dot(d_qk, q, _TN) + d_k_end * x.to_end
         d_gamma_col = jnp.sum(d_q_in * q32, axis=1, keepdims=True)
@@ -263,6 +408,7 @@ def _bwd_kernel(
             - _dot(d_writes_cd, w, _TN)
         )
         d_w = (-_dot(d_writes_cd, start)).astype(cd)
+        yield
         # W = t_w K, U = t_u V
         d_t_w = _dot(d_w, k, _NT)
         d_t_u = _dot(d_writes_cd, v, _NT)
@@ -275,16 +421,17 @@ def _bwd_kernel(
             + d_scale_w * x.gamma_row
         )
         d_gamma_row = d_scale_w * beta_row
+        yield
         # T = (I + A)^-1:  dA = -T^T dT T^T
         exact = cd == F32
         t_halves = _halves(t, exact)
-        d_a = _mm_f32(
-            _halves(_mm_f32(
-                t_halves, _halves(d_t_w * scale_w + d_t_u * beta_row, exact),
-                _TN,
-            ), exact),
-            t_halves, _NT,
-        )
+        t_dt = _halves(_mm_f32(
+            t_halves, _halves(d_t_w * scale_w + d_t_u * beta_row, exact),
+            _TN,
+        ), exact)
+        yield
+        d_a = _mm_f32(t_dt, t_halves, _NT)
+        yield
         d_a = jnp.where(x.strict, -d_a, 0.0)
         # A = beta_t decay kk
         d_kk = (d_a * decay * x.beta_col).astype(cd)
@@ -314,6 +461,8 @@ def _bwd_kernel(
         dbeta_ref[h, 0] = d_beta_row + _to_row(d_beta_col, x.eye)
         dq_ref[h] = d_q.astype(dq_ref.dtype)
         dk_ref[h] = d_k.astype(dk_ref.dtype)
+
+    _in_lockstep(head(h) for h in range(heads))
 
 
 def _heads_per_step(heads: int) -> int:

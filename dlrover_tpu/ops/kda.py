@@ -58,18 +58,24 @@ the backward reads the first run's states; a policy that keeps no names
 
 **Precision.**  Products take operands in the inputs' dtype and accumulate
 in float32; g, its running sums, ``T`` (built by halves in float32
-products of three bfloat16 passes, ``gated_delta_rule._unit_lower_inverse``)
-and the state are float32.  With float32 operands every product is float32.
+products of three bfloat16 passes on the rows that are not structural
+zeros, ``gated_delta_rule._unit_lower_inverse``) and the state are float32.
+With float32 operands every product is float32.  A grid step advances its
+heads in lockstep, a stage of each in turn, so that one head's wait for the
+matrix unit is the others' work (``gated_delta_rule._in_lockstep``;
+:func:`_chunk_tensors` and a kernel's ``head`` are generators for that).
 
 :func:`kda` picks by shape (:func:`plan`): the kernels where both head
 widths are whole lane tiles, the chunked ``jax.numpy`` form (the same
 chunk mathematics under ``vmap`` and ``lax.scan``, differentiated by JAX)
 anywhere else.  The chunk is 128 tokens and a grid step holds four heads,
 chosen on the chip at 2 x 8192 tokens and 32 heads of 128 / 128 (PERF.md
-§6): forward 10.4 ms and forward with backward 23.8 ms a layer, against
-15.1 and 33.8 at chunks of 64 (whose 64 x 64 products fill a quarter of
-the matrix unit, though ``T``'s 2 (log2 C - 1) float32 products grow with
-C^3) and 11.4 and 25.3 at one head a step.
+§6, PR 44): forward 10.4 ms and forward with backward 23.8 ms a layer from
+``[B, S, H, d]``, against 15.1 and 33.8 at chunks of 64 (whose 64 x 64
+products fill a quarter of the matrix unit) and 11.4 and 25.3 at one head
+a step.  Since PR 51 (``T``'s twelve products on 64 and 16 rows where they
+were whole, the heads in lockstep) the kernels alone take 2.97 ms a forward
+call and 5.78 a backward call where they took 7.76 and 11.61 (PERF.md §6).
 """
 
 from __future__ import annotations
@@ -86,8 +92,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from dlrover_tpu.ops import backend
 from dlrover_tpu.ops.gated_delta_rule import (
-    _NT, _TN, _dot, _halves, _mm_f32, _to_col, _to_row,
-    _unit_lower_inverse,
+    _NT, _TN, _alone, _dot, _halves, _in_lockstep, _mm_f32, _to_col,
+    _to_row, _unit_lower_inverse,
 )
 from dlrover_tpu.ops.row_gather_sum import tile_rows
 
@@ -112,7 +118,8 @@ def _cumsum_rows(x, tok, roll):
 
 def _chunk_tensors(q, k, v, g, beta_row, start, roll):
     """What a chunk builds from its own tokens and the state it starts
-    from, forward and backward alike.  ``q``, ``k`` [C, dk], ``v`` [C, dv],
+    from, forward and backward alike, in a head's stages (a generator).
+    ``q``, ``k`` [C, dk], ``v`` [C, dv],
     ``g`` [C, dk] float32, ``beta_row`` [1, C] float32, ``start`` [dv, dk]
     in the operands' dtype."""
     cd = v.dtype
@@ -148,18 +155,24 @@ def _chunk_tensors(q, k, v, g, beta_row, start, roll):
     kk = jnp.where(strict, jnp.concatenate(bands_kk, axis=0), 0.0)   # A'
     qk = jnp.where(lower, jnp.concatenate(bands_qk, axis=0), 0.0)    # M
     beta_col = _to_col(beta_row, eye)
-    t = _unit_lower_inverse(kk * beta_col, row, col, exact=cd == F32)
+    yield
+    t = yield from _unit_lower_inverse(
+        kk * beta_col, row, col, exact=cd == F32
+    )
     gamma, e_end = jnp.exp(run), jnp.exp(total - run)
     k_g, q_g = (k32 * gamma).astype(cd), (q32 * gamma).astype(cd)
     t_b = (t * beta_row).astype(cd)
     w = _dot(t_b, k_g).astype(cd)
+    yield
+    writes = (_dot(t_b, v) - _dot(w, start, _NT)).astype(cd)         # U
+    yield
     return types.SimpleNamespace(
         lower=lower, strict=strict, eye=eye, tok=tok, q32=q32, k32=k32,
         e_row=e_row, k_r=k_r, q_r=q_r, e_cols=e_cols,
         k_cols=k_cols, kk=kk, within=qk.astype(cd), beta_col=beta_col, t=t,
         gamma=gamma, e_end=e_end, gamma_end=jnp.exp(total), k_g=k_g,
         q_g=q_g, t_b=t_b, w=w,
-        writes=(_dot(t_b, v) - _dot(w, start, _NT)).astype(cd),      # U
+        writes=writes,
         k_end=(k32 * e_end).astype(cd),
     )
 
@@ -189,11 +202,11 @@ def _fwd_kernel(
         state[...] = jnp.zeros_like(state)
         top_ref[...] = jnp.zeros_like(top_ref)
 
-    for h in range(heads):
+    def head(h):
         v = v_ref[h]
         start = state[h].astype(v.dtype)                   # [dv, dk]
         start_ref[h, 0] = start
-        x = _chunk_tensors(
+        x = yield from _chunk_tensors(
             q_ref[h], k_ref[h], v, g_ref[h], beta_ref[h, 0], start,
             _roll_rows,
         )
@@ -201,6 +214,8 @@ def _fwd_kernel(
         state[h] = end
         top_ref[h] = jnp.maximum(top_ref[h], _absmax(end))
         o_ref[h] = o.astype(o_ref.dtype)
+
+    _in_lockstep(head(h) for h in range(heads))
 
 
 def _bwd_kernel(
@@ -214,13 +229,13 @@ def _bwd_kernel(
     def _():
         d_state[...] = jnp.zeros_like(d_state)
 
-    for h in range(heads):
+    def head(h):
         v, do = v_ref[h], do_ref[h]
         cd = v.dtype
         exact = cd == F32
         beta_row = beta_ref[h, 0]
         start = start_ref[h, 0]                            # [dv, dk]
-        x = _chunk_tensors(
+        x = yield from _chunk_tensors(
             q_ref[h], k_ref[h], v, g_ref[h], beta_row, start, _roll_rows
         )
         c = v.shape[0]
@@ -234,6 +249,7 @@ def _bwd_kernel(
         d_qk = jnp.where(x.lower, _dot(do, x.writes, _NT), 0.0)
         d_q_g = _dot(do, start)
         d_k_end = _dot(x.writes, d_end_cd)
+        yield
         d_gamma_end = jnp.sum(
             d_end * start.astype(F32), axis=0, keepdims=True
         )
@@ -243,20 +259,21 @@ def _bwd_kernel(
             - _dot(d_u_cd, x.w, _TN)
         )
         d_w = (-_dot(d_u_cd, start)).astype(cd)
+        yield
         # W = t_b k_g with t_b = T * beta (columns)
         d_t_b = _dot(d_w, x.k_g, _NT) + _dot(d_u_cd, v, _NT)
         d_k_g = _dot(x.t_b, d_w, _TN)
         dv_ref[h] = _dot(x.t_b, d_u_cd, _TN).astype(dv_ref.dtype)
         d_beta_row = jnp.sum(d_t_b * x.t, axis=0, keepdims=True)
+        yield
         # T = (I + A)^-1:  dA = -T^T dT T^T
         t_halves = _halves(x.t, exact)
-        d_a = _mm_f32(
-            _halves(
-                _mm_f32(t_halves, _halves(d_t_b * beta_row, exact), _TN),
-                exact,
-            ),
-            t_halves, _NT,
+        t_dt = _halves(
+            _mm_f32(t_halves, _halves(d_t_b * beta_row, exact), _TN), exact
         )
+        yield
+        d_a = _mm_f32(t_dt, t_halves, _NT)
+        yield
         d_a = jnp.where(x.strict, -d_a, 0.0)
         # A = beta_t A'
         d_kk = d_a * x.beta_col
@@ -310,6 +327,8 @@ def _bwd_kernel(
         dbeta_ref[h, 0] = d_beta_row + _to_row(d_beta_col, x.eye)
         dq_ref[h] = (d_q_r + d_q_g).astype(dq_ref.dtype)
         dk_ref[h] = (d_k + d_k_r + d_k_g + d_k_end).astype(dk_ref.dtype)
+
+    _in_lockstep(head(h) for h in range(heads))
 
 
 def _heads_per_step(heads: int) -> int:
@@ -439,7 +458,7 @@ def _rule_xla(q, k, v, g, beta):
 
     def one_head(state, q, k, v, g, beta_row):
         start = state.astype(v.dtype)
-        x = _chunk_tensors(q, k, v, g, beta_row, start, roll)
+        x = _alone(_chunk_tensors(q, k, v, g, beta_row, start, roll))
         o, end = _chunk_forward(x, state, start)
         return o.astype(v.dtype), end
 

@@ -425,6 +425,16 @@ class MasterServicer:
                     logger.warning(
                         "unparseable ssm event from %d: %r", node, attrs,
                     )
+            elif self.speed_monitor is not None and name == "conv":
+                # Gated-short-convolution health snapshot (the gates' mean
+                # sizes, the core's largest output): feeds the ledger
+                # behind the dlrover_conv_* gauges.
+                try:
+                    self.speed_monitor.record_conv(node, **attrs)
+                except (TypeError, ValueError):
+                    logger.warning(
+                        "unparseable conv event from %d: %r", node, attrs,
+                    )
             elif self.speed_monitor is not None and name == "embed":
                 # Embedding-plane stats snapshot: feeds the embed ledger
                 # behind the dlrover_embed_* gauges (rows owned, cache

@@ -108,6 +108,10 @@ class SpeedMonitor:
         # "ssm" telemetry events: the same for a model's state-space
         # (Mamba-2) layers — the ``dlrover_ssm_*`` gauges.
         self._ssm_stats: Dict[int, Dict[str, float]] = {}
+        # "conv" telemetry events: the same for a model's gated short
+        # convolutions (no state: the core's largest output stands where
+        # the state's largest entry does) — the ``dlrover_conv_*`` gauges.
+        self._conv_stats: Dict[int, Dict[str, float]] = {}
 
     def collect_global_step(
         self, step: int, timestamp: Optional[float] = None, tokens: int = 0
@@ -425,7 +429,41 @@ class SpeedMonitor:
         """:meth:`linear_attn_ledger`'s aggregate of the ``ssm`` events."""
         return self._state_ledger(self._ssm_stats, ("mean_decay", "mean_dt"))
 
-    def _state_ledger(self, store, mean_keys) -> Dict[str, float]:
+    def record_conv(
+        self,
+        node_id: int = 0,
+        *,
+        step: float = 0.0,
+        layers: float = 0.0,
+        gate_absmean: float = 0.0,
+        out_gate_absmean: float = 0.0,
+        out_absmax: float = 0.0,
+        **_ignored,
+    ):
+        """A trainer's gated-short-convolution snapshot (its ``conv``
+        telemetry event).  Newest-wins per reporting node; unknown attrs
+        are ignored."""
+        with self._lock:
+            self._conv_stats[node_id] = {
+                "step": float(step),
+                "layers": float(layers),
+                "gate_absmean": float(gate_absmean),
+                "out_gate_absmean": float(out_gate_absmean),
+                "out_absmax": float(out_absmax),
+            }
+
+    def conv_ledger(self) -> Dict[str, float]:
+        """:meth:`linear_attn_ledger`'s aggregate of the ``conv`` events:
+        ``out_absmax`` stands where a recurrent mixer has its state's
+        largest entry."""
+        return self._state_ledger(
+            self._conv_stats, ("gate_absmean", "out_gate_absmean"),
+            max_keys=("out_absmax",),
+        )
+
+    def _state_ledger(
+        self, store, mean_keys, max_keys=("chunk", "state_absmax")
+    ) -> Dict[str, float]:
         with self._lock:
             stats = list(store.values())
         n = len(stats)
@@ -443,9 +481,8 @@ class SpeedMonitor:
             "reporters": float(n),
             "step": most("step"),
             "layers": most("layers"),
-            "chunk": most("chunk"),
-            "state_absmax": most("state_absmax"),
         }
+        out.update({key: most(key) for key in max_keys})
         out.update({key: mean(key) for key in mean_keys})
         return out
 

@@ -445,6 +445,17 @@ class JobTimeline:
                   "boundary (max of reporters; NaN/Inf = diverged)")
             gauge("dlrover_ssm_reporters", ssm["reporters"],
                   "trainers that have reported state-space snapshots")
+            conv = speed_monitor.conv_ledger()
+            gauge("dlrover_conv_gate_absmean", conv["gate_absmean"],
+                  "mean |B| of the gate before the convolution over "
+                  "tokens, channels and layers (mean of reporters)")
+            gauge("dlrover_conv_out_gate_absmean", conv["out_gate_absmean"],
+                  "mean |C| of the gate after the convolution")
+            gauge("dlrover_conv_out_absmax", conv["out_absmax"],
+                  "largest |C * conv(B * z)| entry of any layer (max of "
+                  "reporters; NaN/Inf = diverged)")
+            gauge("dlrover_conv_reporters", conv["reporters"],
+                  "trainers that have reported convolution snapshots")
             sdc = speed_monitor.sdc_ledger()
             gauge("dlrover_sdc_checks_total", sdc["checks"],
                   "cross-replica state-digest votes performed")

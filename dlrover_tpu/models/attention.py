@@ -26,7 +26,7 @@ TPU-first design notes:
 from __future__ import annotations
 
 import functools
-from typing import Callable, Optional
+from typing import Any, Callable, Optional
 
 import flax.linen as nn
 import jax
@@ -195,12 +195,16 @@ def _flash_local(q, k, v, segment_ids, *, block_q, block_kv, scale=None):
 
 
 class QKNorm(nn.Module):
-    """RMSNorm over a whole projection ``[..., H, hd]``: all heads jointly,
-    one ``[H * hd]`` scale in the published (head-major) order, float32
-    accumulation (OLMoE / OLMo-2 ``q_norm`` and ``k_norm``)."""
+    """RMSNorm of a projection ``[..., H, hd]`` before the rotation, float32
+    accumulation.  Jointly over all heads with one ``[H * hd]`` scale in the
+    published (head-major) order (OLMoE / OLMo-2 ``q_norm`` and ``k_norm``),
+    or ``per_head``: each head's ``hd`` columns by their own mean square
+    under ONE ``[hd]`` scale all heads share (the LFM2 family's
+    ``q_layernorm`` / ``k_layernorm``)."""
 
     epsilon: float = 1e-5
     param_dtype: layers.Dtype = jnp.float32
+    per_head: bool = False
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
@@ -210,13 +214,18 @@ class QKNorm(nn.Module):
             nn.with_logical_partitioning(
                 nn.initializers.ones_init(), (lr.NORM,)
             ),
-            (heads * head_dim,),
+            (head_dim if self.per_head else heads * head_dim,),
             self.param_dtype,
         )
         x32 = x.astype(jnp.float32)
-        var = jnp.mean(jnp.square(x32), axis=(-2, -1), keepdims=True)
+        var = jnp.mean(
+            jnp.square(x32), axis=-1 if self.per_head else (-2, -1),
+            keepdims=True,
+        )
         y = x32 * jax.lax.rsqrt(var + self.epsilon)
-        scale = scale.astype(jnp.float32).reshape(heads, head_dim)
+        scale = scale.astype(jnp.float32)
+        if not self.per_head:
+            scale = scale.reshape(heads, head_dim)
         return (y * scale).astype(x.dtype)
 
 
@@ -232,7 +241,7 @@ class Attention(nn.Module):
     dtype: layers.Dtype = jnp.bfloat16
     param_dtype: layers.Dtype = jnp.float32
     attention_impl: str = "xla"
-    qk_norm: bool = False
+    qk_norm: Any = False            # False | True (joint) | "per_head"
     norm_eps: float = 1e-5
     flash_block_q: int = 512
     flash_block_kv: int = 512
@@ -305,15 +314,19 @@ class Attention(nn.Module):
             )(x)
 
         if self.qk_norm:
-            # Train, prefill and cached decode all pass here.  The norm is
-            # over all heads of a projection jointly, so the fused kernel's
+            # Train, prefill and cached decode all pass here.  The joint
+            # norm is over all heads of a projection, so the fused kernel's
             # per-head [q | k | v] slices are normed over their two trailing
-            # axes and the one wide QKV matmul is kept.
+            # axes and the one wide QKV matmul is kept; "per_head" norms
+            # each head's columns alone.
+            per_head = self.qk_norm == "per_head"
             q = QKNorm(
-                self.norm_eps, param_dtype=self.param_dtype, name="q_norm"
+                self.norm_eps, param_dtype=self.param_dtype,
+                per_head=per_head, name="q_norm",
             )(q)
             k = QKNorm(
-                self.norm_eps, param_dtype=self.param_dtype, name="k_norm"
+                self.norm_eps, param_dtype=self.param_dtype,
+                per_head=per_head, name="k_norm",
             )(k)
 
         if self.use_rope:

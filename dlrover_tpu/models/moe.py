@@ -88,7 +88,8 @@ def group_limited(pick: jax.Array, groups: int, topk_group: int) -> jax.Array:
 
 def _gate(logits: jax.Array, k: int, norm_topk_prob: bool = True,
           aux_form: str = "top1", scoring: str = "softmax", bias=None,
-          scale: float = 1.0, groups: int = 1, topk_group: int = 1):
+          scale: float = 1.0, groups: int = 1, topk_group: int = 1,
+          norm_eps: float = 1e-20):
     """Shared top-k gate: (gate_vals, gate_idx, aux_loss), float32.
 
     ``norm_topk_prob`` renormalises the chosen gates to sum to one
@@ -102,7 +103,9 @@ def _gate(logits: jax.Array, k: int, norm_topk_prob: bool = True,
     (``topk_method: noaux_tc``): ``s = sigmoid(logits)``; the k experts are
     chosen on ``s + bias`` (``bias`` picks, it never weighs, and takes no
     gradient); the gates are the chosen ``s``, renormalised under
-    ``norm_topk_prob``, times ``scale``; there is no auxiliary loss (the
+    ``norm_topk_prob`` (divided by their sum + ``norm_eps``: 1e-20 in the
+    DeepSeek-V3 family's code, 1e-6 in LFM2's), times ``scale``; there is
+    no auxiliary loss (the
     bias is moved by :func:`bias_update` after each step instead).  With
     ``groups`` > 1 the choice is group-limited (:func:`group_limited`, on
     ``s + bias`` too): the k come from a token's ``topk_group`` best
@@ -128,7 +131,7 @@ def _gate(logits: jax.Array, k: int, norm_topk_prob: bool = True,
         gate_vals = scores_at(scores, gate_idx)
         if norm_topk_prob:
             gate_vals = gate_vals / (
-                jnp.sum(gate_vals, axis=-1, keepdims=True) + 1e-20
+                jnp.sum(gate_vals, axis=-1, keepdims=True) + norm_eps
             )
         return gate_vals * scale, gate_idx, jnp.zeros((), jnp.float32)
     if scoring != "softmax":
@@ -549,6 +552,7 @@ class MoEMlp(nn.Module):
     row_budget_multiple: float = 1.25
     router_groups: int = 1          # > 1: a group-limited choice
     router_topk_groups: int = 1
+    router_norm_eps: float = 1e-20  # beside the renormalising sum (sigmoid)
 
     @property
     def held(self) -> int:
@@ -873,7 +877,7 @@ class MoEMlp(nn.Module):
             gate_vals, gate_idx, aux_loss = _gate(
                 router_logits, k, self.norm_topk_prob, self.aux_form,
                 self.scoring, bias, self.routed_scale, self.router_groups,
-                self.router_topk_groups,
+                self.router_topk_groups, self.router_norm_eps,
             )
         # Tokens stay split over their batch and sequence axes (an MLP is
         # token-wise); the embed dim and the expert weights are whole on

@@ -25,7 +25,11 @@ bounded gate), latent attention without a q latent (``q_lora_rank`` 0) and
 with a head-wise output gate (``attention_gate``), a group-limited router
 (``router_groups`` / ``router_topk_groups``), and ``first_k_dense`` leading
 layers ahead of a PATTERNED trunk whose two-branch blocks carry the expert
-layer.
+layer.  LFM2's are a third two-branch kind, ``conv`` (a gated short
+convolution: two gates round a 3-tap causal depthwise convolution, no
+state, no softmax, no position; ``models/gated_conv.py``), a QK-norm PER
+HEAD (``qk_norm="per_head"``) and ``router_norm_eps`` beside the
+renormalising sum of a sigmoid router's gates.
 
 TPU-first structure:
   * layers are ``nn.scan``-stacked: one trace regardless of depth (fast
@@ -50,6 +54,7 @@ import jax.numpy as jnp
 
 from dlrover_tpu.models import layers
 from dlrover_tpu.models.attention import Attention, LatentAttention
+from dlrover_tpu.models import gated_conv
 from dlrover_tpu.models.linear_attention import (
     GatedDeltaNet,
     KimiDeltaAttention,
@@ -63,8 +68,10 @@ from dlrover_tpu.parallel import rules as lr
 
 FULL_ATTENTION = "full_attention"
 LINEAR_ATTENTION = "linear_attention"
-# Layers of TWO residual branches: a mixer, then an MLP (``Block``).
-TWO_BRANCH_KINDS = (FULL_ATTENTION, LINEAR_ATTENTION)
+CONV = "conv"
+# Layers of TWO residual branches: a mixer (softmax attention, a delta rule
+# or a gated short convolution), then an MLP (``Block``).
+TWO_BRANCH_KINDS = (FULL_ATTENTION, LINEAR_ATTENTION, CONV)
 # Layers that are ONE residual branch, ``x + f(Norm(x))`` (``BranchBlock``):
 # a state-space mixer, an attention, an expert layer, a dense MLP.
 SSM = "ssm"
@@ -148,6 +155,9 @@ class TransformerConfig:
     # The shared expert's own width (0 -> ``num_shared_experts`` x the
     # routed experts' width, the DeepSeek-V3 family's).
     shared_expert_d_ff: int = 0
+    # What a sigmoid router's renormalising sum is added to (the
+    # DeepSeek-V3 family's code: 1e-20; LFM2's: 1e-6).
+    router_norm_eps: float = 1e-20
     # Layers before the scanned trunk whose MLP is dense (``d_ff`` wide)
     # though the trunk's is sparse: ``dense_0`` .. of ``num_layers``.  Ahead
     # of a patterned trunk their mixers are the pattern's continued
@@ -160,8 +170,10 @@ class TransformerConfig:
     mtp_depth: int = 0
     mtp_weight: float = 0.3
     # RMSNorm over the whole q and k projections (all heads jointly, own
-    # scale each) before the head split and RoPE (OLMoE, OLMo-2).
-    qk_norm: bool = False
+    # scale each) before the head split and RoPE (OLMoE, OLMo-2);
+    # "per_head": each head's columns alone under ONE [head_dim] scale for
+    # q and one for k (the LFM2 family).
+    qk_norm: Any = False
     # Latent attention (models/attention.py ``LatentAttention``), on where
     # ``kv_lora_rank`` is set: q through a ``q_lora_rank`` latent, k and v
     # rebuilt from a ``kv_lora_rank`` latent, ``qk_rope_head_dim`` rotary
@@ -176,8 +188,8 @@ class TransformerConfig:
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
     attention_gate: str = ""
-    # One period of layer kinds (``LAYER_KINDS``: "full_attention" and
-    # "linear_attention" are a mixer AND an MLP; "ssm", "attention",
+    # One period of layer kinds (``LAYER_KINDS``: "full_attention",
+    # "linear_attention" and "conv" are a mixer AND an MLP; "ssm", "attention",
     # "experts" and "mlp" are that part alone on one residual branch),
     # repeated num_layers / len(layer_pattern) times; empty = every layer
     # full attention.  The trunk scans over PERIODS: a period applies its
@@ -219,6 +231,9 @@ class TransformerConfig:
     # ``g = linear_decay_bound x sigmoid(..)``, ``KimiDeltaAttention``).
     linear_rule: str = "delta"
     linear_decay_bound: float = -5.0
+    # Taps of the "conv" layers' gated short convolution
+    # (models/gated_conv.py; LFM2's ``conv_L_cache``).
+    conv_kernel: int = 3
     # "pre": x + f(Norm(x)) (GPT-2, Llama, Mixtral, OLMoE); "post":
     # x + Norm(f(x)), each branch's OUTPUT normalised before the residual
     # add (OLMo 2 and later).
@@ -307,6 +322,13 @@ class TransformerConfig:
         return self.num_layers_of(LINEAR_ATTENTION) + sum(
             self.layer_kind(i) == LINEAR_ATTENTION
             for i in range(self.first_k_dense)
+        )
+
+    @property
+    def num_conv_layers(self) -> int:
+        """As ``num_linear_layers``: the dense prefix's count too."""
+        return self.num_layers_of(CONV) + sum(
+            self.layer_kind(i) == CONV for i in range(self.first_k_dense)
         )
 
     @property
@@ -412,6 +434,19 @@ class TransformerConfig:
             )
         if SSM in pattern:
             self._check_ssm()
+        if CONV in pattern:
+            if self.conv_kernel < 2:
+                raise ValueError(
+                    f"a conv layer needs conv_kernel >= 2 taps, got "
+                    f"{self.conv_kernel}"
+                )
+            if self.decode:
+                raise ValueError(
+                    "decode=True with a conv layer: its convolution's last "
+                    f"{self.conv_kernel - 1} rows of B * z a sequence have "
+                    "no place beside the KV cache yet (serving/decode.py, "
+                    "serving/engine.py); this model trains only"
+                )
         if EXPERTS in pattern and not self.num_experts:
             raise ValueError(
                 "an 'experts' layer needs num_experts (and moe_d_ff, top_k)"
@@ -500,6 +535,11 @@ class TransformerConfig:
             raise ValueError(
                 "activation must be 'gelu', 'swiglu' or 'relu2', got "
                 f"{self.activation!r}"
+            )
+        if self.qk_norm not in (False, True, "per_head"):
+            raise ValueError(
+                "qk_norm must be False, True (all heads jointly) or "
+                f"'per_head', got {self.qk_norm!r}"
             )
         if self.router_scoring not in ("softmax", "sigmoid"):
             raise ValueError(
@@ -663,7 +703,9 @@ class TransformerConfig:
             ff = dense_ff
         embed = v * d + (0 if self.position != "learned" else self.max_seq_len * d)
         head = 0 if self.tie_embeddings else v * d
-        linear = self.num_linear_layers
+        if self.qk_norm == "per_head":
+            attn += 2 * self.resolved_head_dim
+        linear, conv = self.num_linear_layers, self.num_conv_layers
         dense = self.first_k_dense
         mtp = self.mtp_depth * (attn + ff + 2 * d * d + 3 * d)
         # layers that are one branch: none of them is a mixer AND an MLP
@@ -672,8 +714,9 @@ class TransformerConfig:
         )
         two = l - ssm - alone - experts - mlp
         return (
-            (two - linear + alone) * attn
+            (two - linear - conv + alone) * attn
             + linear * self._linear_mixer_params()
+            + conv * self._conv_mixer_params()
             + ssm * self._ssm_mixer_params()
             + (two - dense + experts) * ff + (dense + mlp) * dense_ff
             + embed + head + mtp
@@ -689,6 +732,12 @@ class TransformerConfig:
             self.d_model * (2 * inner + 2 * bc + h) + inner * self.d_model
             + (self.ssm_conv_kernel + 1) * (inner + 2 * bc) + 3 * h + inner
         )
+
+    def _conv_mixer_params(self) -> int:
+        """The ``[d, 3d]`` and ``[d, d]`` projections and the taps
+        (models/gated_conv.py)."""
+        d = self.d_model
+        return 3 * d * d + d * d + self.conv_kernel * d
 
     def _linear_mixer_params(self) -> int:
         """q, k, v, gate and output projections, the two gate
@@ -819,6 +868,7 @@ def _experts(cfg: TransformerConfig):
         row_budget_multiple=cfg.moe_row_budget,
         router_groups=cfg.router_groups,
         router_topk_groups=cfg.router_topk_groups,
+        router_norm_eps=cfg.router_norm_eps,
         name="moe",
     )
 
@@ -842,7 +892,8 @@ def _add_branch(cfg: TransformerConfig, x: jax.Array, y: jax.Array):
 
 class Block(nn.Module):
     """One layer: a token mixer of ``kind`` (softmax attention, plain or
-    latent, or the gated delta rule) and an MLP, each on its residual
+    latent, a delta rule or the gated short convolution) and an MLP, each
+    on its residual
     branch.  ``dense_mlp`` makes the MLP the dense one (``d_ff`` wide)
     though the model's trunk is sparse: a leading dense layer."""
 
@@ -889,6 +940,11 @@ class Block(nn.Module):
                 dtype=cfg.dtype,
                 param_dtype=cfg.param_dtype,
                 name="linear_attn",
+            )(y)
+        elif self.kind == CONV:
+            y = gated_conv.GatedShortConv(
+                conv_taps=cfg.conv_kernel, dtype=cfg.dtype,
+                param_dtype=cfg.param_dtype, name="conv",
             )(y)
         else:
             y = _attention(cfg)(y, positions, segment_ids)

@@ -652,3 +652,264 @@ def _vjp_bwd(offset, splits, l2_scales, res, dys):
 
 
 short_conv.defvjp(_vjp_fwd, _vjp_bwd)
+
+
+# -- the gated form: C * conv(B * z), no activation ---------------------------
+#
+# LFM2's mixer core (``models/gated_conv.py``).  ``x`` is ``[B, S, 3d]``, the
+# three d-wide column ranges ``B | C | z`` of ONE projection, read where
+# they lie as ``[B, 3, d, S]`` (the same transpose that moves nothing, then
+# a split of the channel axis): one block of a grid step holds a channel
+# tile of all three.  ``y[t] = C[t] sum_j taps[j] (B z)[t - (K - 1) + j]``;
+# the backward rebuilds ``u = conv(B z)`` from x, forms ``g = dy C`` on the
+# tile and the lane tile after it (float32 VMEM scratch), and writes the
+# THREE cotangents into one ``[B, 3, d, S]`` array: ``dC = dy u``, and with
+# ``dBz[t] = sum_j taps[j] g[t + (K - 1) - j]``, ``dB = z dBz`` and ``dz = B
+# dBz``; ``d_taps[j] = sum (B z)[t - (K - 1) + j] g[t]`` lands as the SiLU
+# form's does.  Layout, strips, lane rotations and the unrolled lane-tile
+# loop are the SiLU form's, whose code above is untouched by this.
+
+_GATED_TILE_CHANNELS = 256
+
+
+class GatedPlan(NamedTuple):
+    ts: int          # tokens a tile (lanes)
+    wc: int          # channels a tile (sublanes), of each of the three
+
+
+def plan_gated(
+    x_shape: Sequence[int], taps_shape: Sequence[int]
+) -> Optional[GatedPlan]:
+    """The tiling of ``x`` ``[B, S, 3d]`` under ``taps`` ``[K, d]``, or None
+    where the kernels cannot tile it: tokens whole lane tiles, ``d`` whole
+    row tiles."""
+    if len(x_shape) != 3 or len(taps_shape) != 2:
+        return None
+    k, d = taps_shape
+    seq = x_shape[1]
+    if x_shape[2] != 3 * d or not 2 <= k < LANES or d % STRIP:
+        return None
+    wc = next(
+        (t for t in range(_GATED_TILE_CHANNELS, 0, -STRIP) if d % t == 0),
+        None,
+    )
+    ts = min(_TILE_TOKENS, seq) // LANES * LANES
+    while ts > LANES and seq % ts:
+        ts -= LANES
+    if wc is None or ts < LANES or seq % ts:
+        return None
+    return GatedPlan(ts, wc)
+
+
+def _gated_fwd_kernel(x_ref, before_ref, par_ref, out_ref, *, p, k):
+    i = pl.program_id(1)
+    masks = _lane_masks(k)
+    tiles = p.ts // LANES
+
+    def strip(r, carry):
+        rows = _strip_rows(r)
+        taps, _ = _columns(par_ref, rows, k, False)
+
+        def tile(n, prev):
+            cols = _tile_cols(n)
+            cur = x_ref[0, 0, rows, cols].astype(F32) * x_ref[
+                0, 2, rows, cols
+            ].astype(F32)
+            u, _ = _u_of(taps, None, cur, prev, masks)
+            out_ref[0, rows, cols] = (
+                x_ref[0, 1, rows, cols].astype(F32) * u
+            ).astype(out_ref.dtype)
+            return cur
+
+        first = jnp.where(
+            i > 0,
+            before_ref[0, 0, rows, :].astype(F32)
+            * before_ref[0, 2, rows, :].astype(F32),
+            0.0,
+        )
+        _for_each(tiles, tile, first)
+        return carry
+
+    jax.lax.fori_loop(0, p.wc // STRIP, strip, 0)
+
+
+def _gated_bwd_kernel(
+    x_ref, before_ref, after_ref, dy_ref, dy_after, par_ref,
+    dx_ref, acc_ref, gs, *, p, k,
+):
+    b, i, c = (pl.program_id(a) for a in range(3))
+    last = i == pl.num_programs(1) - 1
+    tiles = p.ts // LANES
+    behind, ahead = _lane_masks(k), _lane_masks(k, ahead=True)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (STRIP, LANES), 1)
+    zero = jnp.zeros((STRIP, LANES), F32)
+
+    @pl.when((b == 0) & (i == 0) & (c == 0))
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def strip(r, carry):
+        rows = _strip_rows(r)
+        taps, _ = _columns(par_ref, rows, k, False)
+
+        # g = dy C on the tile (and dC = dy u, the taps' sums beside it) ...
+        def tile(n, carry):
+            prev, sums = carry
+            cols = _tile_cols(n)
+            cur = x_ref[0, 0, rows, cols].astype(F32) * x_ref[
+                0, 2, rows, cols
+            ].astype(F32)
+            dy = dy_ref[0, rows, cols].astype(F32)
+            u, shifted = _u_of(taps, None, cur, prev, behind)
+            g = dy * x_ref[0, 1, rows, cols].astype(F32)
+            gs[rows, cols] = g
+            dx_ref[0, 1, rows, cols] = (dy * u).astype(dx_ref.dtype)
+            return cur, [a + s * g for a, s in zip(sums, shifted)]
+
+        first = jnp.where(
+            i > 0,
+            before_ref[0, 0, rows, :].astype(F32)
+            * before_ref[0, 2, rows, :].astype(F32),
+            0.0,
+        )
+        _, sums = _for_each(tiles, tile, (first, [zero] * k))
+        # ... and on the lane tile after it, whose first K - 1 tokens the
+        # tile's last tokens of dBz take (zeros past a sequence's end)
+        gs[rows, _tile_cols(tiles)] = jnp.where(
+            ~last,
+            dy_after[0, rows, :].astype(F32)
+            * after_ref[0, 1, rows, :].astype(F32),
+            0.0,
+        )
+        found = zero
+        for j in range(k):
+            found = jnp.where(
+                lane == j, jnp.sum(sums[j], axis=1, keepdims=True), found
+            )
+        acc_ref[pl.ds(
+            pl.multiple_of(c * p.wc + r * STRIP, STRIP), STRIP
+        ), :] += found
+
+        def back(n, carry):
+            cols = _tile_cols(n)
+            here, after = gs[rows, cols], gs[rows, _tile_cols(n + 1)]
+            dbz = sum(
+                taps[j] * _shifted(here, after, k - 1 - j, ahead, True)
+                for j in range(k)
+            )
+            dx_ref[0, 0, rows, cols] = (
+                x_ref[0, 2, rows, cols].astype(F32) * dbz
+            ).astype(dx_ref.dtype)
+            dx_ref[0, 2, rows, cols] = (
+                x_ref[0, 0, rows, cols].astype(F32) * dbz
+            ).astype(dx_ref.dtype)
+            return carry
+
+        _for_each(tiles, back)
+        return carry
+
+    jax.lax.fori_loop(0, p.wc // STRIP, strip, 0)
+
+
+def _ranges_first(x):
+    """``[B, S, 3d]`` as ``[B, 3, d, S]``."""
+    batch, seq, width = x.shape
+    return x.transpose(0, 2, 1).reshape(batch, 3, width // 3, seq)
+
+
+def _gated_specs(p: GatedPlan, seq: int):
+    """Index maps of a step ``(b, i, c)`` on ``[B, 3, d, S]``: the tile,
+    the lane tiles before and after it; on ``[B, d, S]``: the tile and the
+    lane tile after it; a parameter's tile."""
+    per, blocks = p.ts // LANES, seq // LANES
+
+    def ahead(i):
+        # past the last tile: any block (the kernel zeroes it)
+        return jnp.minimum((i + 1) * per, blocks - 1)
+
+    return (
+        lambda b, i, c: (b, 0, c, i),
+        lambda b, i, c: (b, 0, c, jnp.maximum(i * per - 1, 0)),
+        lambda b, i, c: (b, 0, c, ahead(i)),
+        lambda b, i, c: (b, c, i),
+        lambda b, i, c: (b, c, ahead(i)),
+        lambda b, i, c: (c, 0),
+    )
+
+
+@jax.jit
+def _gated_forward(x, taps):
+    p = plan_gated(x.shape, taps.shape)
+    k = taps.shape[0]
+    x4, par = _ranges_first(x), _lane_params(taps, None)
+    batch, _, d, seq = x4.shape
+    tile, before, _, row_tile, _, param = _gated_specs(p, seq)
+    y = pl.pallas_call(
+        functools.partial(_gated_fwd_kernel, p=p, k=k),
+        grid=(batch, seq // p.ts, d // p.wc),
+        in_specs=[
+            pl.BlockSpec((1, 3, p.wc, p.ts), tile),
+            pl.BlockSpec((1, 3, p.wc, LANES), before),
+            pl.BlockSpec((p.wc, LANES), param),
+        ],
+        out_specs=pl.BlockSpec((1, p.wc, p.ts), row_tile),
+        out_shape=jax.ShapeDtypeStruct((batch, d, seq), x.dtype),
+        compiler_params=_compiler_params(("parallel",) * 3),
+        interpret=backend.interpret(), name="gated_conv_fwd",
+    )(x4, x4, par)
+    return y.transpose(0, 2, 1)
+
+
+@jax.jit
+def _gated_backward(x, taps, dy):
+    p = plan_gated(x.shape, taps.shape)
+    k = taps.shape[0]
+    x4, par = _ranges_first(x), _lane_params(taps, None)
+    dy = dy.transpose(0, 2, 1)
+    batch, _, d, seq = x4.shape
+    tile, before, after, row_tile, row_after, param = _gated_specs(p, seq)
+    dx, acc = pl.pallas_call(
+        functools.partial(_gated_bwd_kernel, p=p, k=k),
+        grid=(batch, seq // p.ts, d // p.wc),
+        in_specs=[
+            pl.BlockSpec((1, 3, p.wc, p.ts), tile),
+            pl.BlockSpec((1, 3, p.wc, LANES), before),
+            pl.BlockSpec((1, 3, p.wc, LANES), after),
+            pl.BlockSpec((1, p.wc, p.ts), row_tile),
+            pl.BlockSpec((1, p.wc, LANES), row_after),
+            pl.BlockSpec((p.wc, LANES), param),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, 3, p.wc, p.ts), tile),
+            # every channel's sums, one block that stays in VMEM
+            pl.BlockSpec((d, LANES), lambda b, i, c: (0, 0)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct(x4.shape, x.dtype),
+            jax.ShapeDtypeStruct((d, LANES), F32),
+        ],
+        scratch_shapes=[pltpu.VMEM((p.wc, p.ts + LANES), F32)],
+        compiler_params=_compiler_params(("arbitrary",) * 3),
+        interpret=backend.interpret(), name="gated_conv_bwd",
+    )(x4, x4, x4, dy, dy, par)
+    d_taps = acc[:, :k].T.astype(taps.dtype)
+    return dx.reshape(batch, 3 * d, seq).transpose(0, 2, 1), d_taps
+
+
+@jax.custom_vjp
+def gated_conv(x, taps):
+    """``C * conv(B * z)`` of ``x = [B | C | z]`` ``[batch, S, 3d]`` under
+    ``taps`` ``[K, d]`` (causal, depthwise, no bias, no activation) through
+    the kernels.  Only for a shape :func:`plan_gated` tiles."""
+    return _gated_forward(x, taps)
+
+
+def _gated_vjp_fwd(x, taps):
+    return _gated_forward(x, taps), (x, taps)
+
+
+def _gated_vjp_bwd(res, dy):
+    return _gated_backward(*res, dy)
+
+
+gated_conv.defvjp(_gated_vjp_fwd, _gated_vjp_bwd)

@@ -53,6 +53,8 @@ KV = "kv"                  # param per-head dim (a KDA head's dk decay
 LATENT = "latent"          # latent attention's low-rank dim (q 1536, kv 512+64)
 SSM_INNER = "ssm_inner"    # a state-space mixer's fused columns [z|x|B|C|dt]
 SSM_HEADS = "ssm_heads"    # its per-head scalars (A_log, D, dt_bias)
+CONV_INNER = "conv_inner"  # a gated short convolution's columns [B|C|z], its
+                           # taps' channels and its output projection's rows
 VOCAB = "vocab"            # param vocab dim (TP vocab split)
 EXPERT = "expert"          # param expert dim (EP shard dim)
 LAYERS = "layers"          # scanned layer dim (within one pipeline stage)
@@ -97,6 +99,11 @@ def make_rules(
         # the scan runs on each device's own batch rows.
         (SSM_INNER, None),
         (SSM_HEADS, None),
+        # A gated short convolution multiplies channel c of B, of C and of
+        # z, which lie d columns apart in ONE projection: no even split of
+        # its 3d columns keeps the three together, so they are whole on
+        # every device (the projections still split on embed).
+        (CONV_INNER, None),
         (NORM, None),
         (GATHERED, None),
     ]

@@ -30,6 +30,7 @@ import optax
 from dlrover_tpu.common import faults, telemetry
 from dlrover_tpu.common.log import default_logger as logger
 from dlrover_tpu.common.retry import RetryError, RetryPolicy
+from dlrover_tpu.models import gated_conv
 from dlrover_tpu.models import linear_attention
 from dlrover_tpu.models import mamba2
 from dlrover_tpu.models import moe as moe_lib
@@ -48,7 +49,7 @@ _NO_BATCH = object()
 # until a report reads them.
 _STATS_KEYS = (
     "moe_stats", moe_lib.SHARE_STATS_NAME, linear_attention.STATS_NAME,
-    mamba2.STATS_NAME,
+    mamba2.STATS_NAME, gated_conv.STATS_NAME,
 )
 
 _PROCESS_START_BOOKED = False
@@ -367,6 +368,7 @@ class ElasticTrainer:
                 "ssm_tiles_per_group": self._ssm_tiles_per_group(),
                 "short_conv": self._short_conv(),
                 "row_moves": self._row_moves(),
+                "conv_core": self._conv_core(),
                 "kda": self._kda(),
             }
             logger.info("compile warmup: %s", detail)
@@ -504,6 +506,21 @@ class ElasticTrainer:
         from dlrover_tpu.ops import kda
 
         return kda.plan(cfg.linear_key_head_dim, cfg.linear_value_head_dim)
+
+    def _conv_core(self) -> str:
+        """How the gated short convolutions' core ``C * conv(B * z)`` runs,
+        for the ``compile`` event: ``pallas`` (``ops/short_conv.py``'s gated
+        form) / ``xla`` (the written-out form), ``none`` for a model without
+        a ``conv`` layer.  Chosen at trace time from the shapes alone, so
+        this asks the function the dispatch asks."""
+        cfg = self.model_config
+        if not cfg.num_conv_layers:
+            return "none"
+        path = gated_conv.core_path(
+            (1, self.config.seq_len, 3 * cfg.d_model),
+            (cfg.conv_kernel, cfg.d_model),
+        )
+        return "pallas" if path == "kernel" else "xla"
 
     def _row_moves(self) -> str:
         """Which path a token's ``top_k`` rows take through the dropless
@@ -1373,6 +1390,7 @@ class ElasticTrainer:
                 self._report_ssm(metrics, step),
             ) if v is not None
         ]
+        self._report_conv(metrics, step)
         state_absmax = None if not absmaxes else (
             float("nan") if any(v != v for v in absmaxes) else max(absmaxes)
         )
@@ -1521,6 +1539,21 @@ class ElasticTrainer:
             groups=self.model_config.ssm_groups, **read,
         )
         return read["state_absmax"]
+
+    def _report_conv(self, metrics, step: int) -> None:
+        """The gated short convolutions' health, as a ``conv`` event: the
+        gates' mean sizes and the core's largest output over the layers
+        (no recurrent state: the vector is laid out as theirs)."""
+        read = self._state_stats(
+            metrics, step, gated_conv.STATS_NAME,
+            ("gate_absmean", "out_gate_absmean"),
+        )
+        if read is None:
+            return
+        telemetry.event(
+            "conv", step=step, layers=self.model_config.num_conv_layers,
+            out_absmax=read.pop("state_absmax"), **read,
+        )
 
     def _state_stats(
         self, metrics, step: int, stats_name: str, means

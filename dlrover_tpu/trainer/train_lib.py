@@ -24,6 +24,7 @@ from flax.training import train_state as flax_train_state
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from dlrover_tpu.common.log import default_logger as logger
+from dlrover_tpu.models import gated_conv
 from dlrover_tpu.models import linear_attention
 from dlrover_tpu.models import mamba2
 from dlrover_tpu.models import moe as moe_lib
@@ -481,8 +482,11 @@ def _sown_vectors(sown, name: str) -> Optional[jax.Array]:
 
 ROUTER_LOADS = "router_loads"
 # The recurrent mixers' sown vectors, one layout ([mean decay, mean write
-# strength or step, largest state entry]) and one fold.
-_STATE_STATS = (linear_attention.STATS_NAME, mamba2.STATS_NAME)
+# strength or step, largest state entry]) and one fold; the gated short
+# convolution's is laid out so too ([mean |B|, mean |C|, largest |C u|]).
+_STATE_STATS = (
+    linear_attention.STATS_NAME, mamba2.STATS_NAME, gated_conv.STATS_NAME,
+)
 
 
 def _router_loads(sown) -> Dict[Tuple[str, ...], jax.Array]:
@@ -793,7 +797,7 @@ def build_sharded_train(
     model_config = getattr(model, "config", None)
     sows_stats = bool(
         getattr(model_config, "num_experts", 0)
-        or {"linear_attention", "ssm"}
+        or {"linear_attention", "ssm", "conv"}
         & set(getattr(model_config, "layer_pattern", ()))
     )
     # The DeepSeek-V3 family: a multi-token-prediction module whose
@@ -810,7 +814,8 @@ def build_sharded_train(
     def _forward_sums(params, apply_fn, inputs, targets, weights):
         """One forward pass -> (weighted CE sum, token count, aux loss,
         layer statistics).  The last is ``_layer_stats`` of what the
-        layers sowed (``moe_stats``, ``linear_attn_stats``, ``ssm_stats``),
+        layers sowed (``moe_stats``, ``linear_attn_stats``, ``ssm_stats``,
+        ``conv_stats``),
         folded over
         layers and whatever axes the scan and the sow stack, under
         ``stop_gradient``; empty for a model that sows none.  A model with

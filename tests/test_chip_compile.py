@@ -182,6 +182,15 @@ def _short_conv(offset, splits, l2_scales, has_bias):
     )
 
 
+def _gated_conv():
+    from dlrover_tpu.ops.short_conv import gated_conv
+
+    def loss(x, taps):
+        return gated_conv(x, taps).astype(F32).sum()
+
+    return jax.value_and_grad(loss, argnums=(0, 1))
+
+
 def _quant_roundtrip():
     from dlrover_tpu.ops import quantization as qz
 
@@ -364,6 +373,27 @@ CASES = [
     ("short_conv_olmo_hybrid",
      lambda: _short_conv(0, (96, 96, 192), (96 ** -0.5, 1.0, None), False),
      [((2, 8192, 30, 576), BF16), ((4, 30, 384), BF16)], {}, 2),
+    # LFM2-8B-A1B's cell, 4 x 8192 tokens: 32 query heads over 8 key/value
+    # heads of 64 (half a lane group wide, eight kv blocks); its share's
+    # grouped GEMMs at the expert width 1,792 (14 lane tiles) over a budget
+    # of 42,112 rows for 8 of 32 experts, rows tiled in and out; a token's
+    # 4 live rows of 2,048 fetched and summed
+    ("flash_lfm2_gqa_32_over_8_of_64", lambda: _flash(1024),
+     [((4, 8192, 32, 64), BF16)] + [((4, 8192, 8, 64), BF16)] * 2, {}, 2),
+    ("grouped_matmul_lfm2_wi_rows_tiled",
+     lambda: _grouped_matmul(False, True),
+     [((42112, 16, 128), BF16), ((8, 2048, 1792), BF16), ((8,), I32)],
+     {}, 3),
+    ("grouped_matmul_lfm2_wo_out_tiled",
+     lambda: _grouped_matmul(True, True),
+     [((42112, 1792), BF16), ((8, 1792, 2048), BF16), ((8,), I32)], {}, 3),
+    ("row_gather_sum_lfm2_live_weighted", _row_gather_sum_live,
+     [((42112, 16, 128), BF16), ((32768, 4), I32), ((32768, 4), F32)],
+     {}, 1),
+    # its gated short convolution's core: B | C | z of one [4, 8192, 6144]
+    # projection, 3 taps, the forward kernel and the backward kernel
+    ("gated_conv_lfm2", _gated_conv,
+     [((4, 8192, 6144), BF16), ((3, 2048), BF16)], {}, 2),
     ("quantize_dequantize", _quant_roundtrip, [LEAF], {}, 2),
     ("q8_adam", lambda: _adam_update("q8_adam"), [LEAF, LEAF], {}, 1),
     ("q4_adam", lambda: _adam_update("q4_adam"), [LEAF, LEAF], {}, 1),

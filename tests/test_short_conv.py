@@ -262,3 +262,146 @@ def test_the_mixers_ask_for_their_own_layout():
     assert mamba2.conv_path(64, 4, 64, 16, 2, 4) == "xla"
     assert la.conv_path(64, 4, 12, 24, 4) == "xla"
     assert mamba2.conv_path(128, 4, 64, 16, 2, 4) == "kernel"
+
+
+# -- the SiLU form is what it was at fe404a6 ----------------------------------
+
+# the four configurations that share the SiLU form, at their cells' shapes:
+# x, taps, the first column, the outputs' widths and L2 norms, and the
+# tiling ``plan`` gave them at fe404a6 (PR 51)
+SIBLINGS = {
+    "nemotron-3-nano-30b-a3b": (
+        (2, 8192, 10304), (4, 6144), 4096, (4096, 1024, 1024), None,
+        (2048, 512, 8, 1, ((0, 8), (8, 10), (10, 12)), (None,) * 3),
+    ),
+    "granite-4.0-h-small": (
+        (2, 8192, 16768), (4, 8448), 8192, (8192, 128, 128), None,
+        (2048, 128, 64, 1, ((0, 64), (64, 65), (65, 66)), (None,) * 3),
+    ),
+    "olmo-hybrid-7b": (
+        (2, 8192, 30, 576), (4, 30, 384), 0, (96, 96, 192),
+        (96 ** -0.5, 1.0, None),
+        (2048, 96, 0, 30, ((0, 1), (1, 2), (2, 4)), (96 ** -0.5, 1.0, None)),
+    ),
+    "ling-3.0-flash-vl": (
+        (2, 8192, 32, 384), (4, 32, 384), 0, (128, 128, 128),
+        (128 ** -0.5, 1.0, None),
+        (2048, 128, 0, 32, ((0, 1), (1, 2), (2, 3)),
+         (128 ** -0.5, 1.0, None)),
+    ),
+}
+# sha256 of ``ops/short_conv.py`` as it stood at fe404a6
+SILU_FORM_AT_FE404A6 = (
+    "253db8c5e6eb4b3af23bea13e842b4ea62c85230d551d8d24480f93831c3e138"
+)
+GATED_FORM_STARTS = "\n\n# -- the gated form"
+
+
+@pytest.mark.parametrize("sibling", sorted(SIBLINGS))
+def test_the_silu_form_reads_bit_for_bit_what_it_read_at_fe404a6(
+    monkeypatch, sibling
+):
+    """LFM2's gated form was ADDED below the SiLU form (PR 52): every byte
+    of the module up to it is fe404a6's, so the same kernels run under the
+    same tiling and each sibling's ``short_conv_ms`` / ``ssm_conv_ms`` has
+    nothing to move by.  A PR that edits the SiLU form shows its outputs
+    bit for bit equal on these four shapes and records the new digest."""
+    import hashlib
+
+    with open(short_conv.__file__) as f:
+        text = f.read()
+    silu_form = text[: text.index(GATED_FORM_STARTS)]
+    assert hashlib.sha256(silu_form.encode()).hexdigest() == (
+        SILU_FORM_AT_FE404A6
+    )
+    # nothing of the gated form is named above it, and nothing there
+    # rebinds a name of the SiLU form
+    assert "gated_conv" not in silu_form and "plan_gated" not in silu_form
+    gated_form = text[text.index(GATED_FORM_STARTS):]
+    for name in ("_fwd_kernel", "_bwd_kernel", "_forward", "_backward",
+                 "short_conv", "plan", "_specs", "_shifted", "_u_of",
+                 "_lane_masks", "_columns", "_for_each", "_TILE_TOKENS",
+                 "_TILE_CHANNELS", "_UNROLL", "STRIP"):
+        assert f"\ndef {name}(" not in gated_form
+        assert f"\n{name} =" not in gated_form
+    # the published tile sizes (the fixture above cuts the tokens' for the
+    # interpreter's sake)
+    monkeypatch.setattr(short_conv, "_TILE_TOKENS", 2048)
+    x, taps, offset, splits, scales, want = SIBLINGS[sibling]
+    assert tuple(short_conv.plan(x, taps, offset, splits, scales)) == want
+
+
+# -- the gated form: C * conv(B * z) -------------------------------------------
+
+
+def gated_operands(k=3, d=48, tokens=384, dtype=F32, seed=0):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 3)
+    x = jax.random.normal(keys[0], (2, tokens, 3 * d), F32).astype(dtype)
+    taps = jax.random.uniform(keys[1], (k, d), F32, -0.5, 0.5).astype(dtype)
+    w = jax.random.normal(keys[2], (2, tokens, d), F32)
+    return x, taps, w
+
+
+@pytest.mark.parametrize("k", [3, 4])
+def test_the_gated_form_and_its_cotangents_match_the_xla_form(k):
+    """Forward, all three cotangents (in ONE ``[B, S, 3d]`` array) and
+    ``d_taps``, three token tiles a sequence: a tile's first rows read the
+    tile before, a sequence's first rows zeros."""
+    from dlrover_tpu.models import gated_conv
+
+    x, taps, w = gated_operands(k)
+    tiled = short_conv.plan_gated(x.shape, taps.shape)
+    assert tiled == (128, 48) and x.shape[1] // tiled.ts == 3
+    assert gated_conv.core_path(x.shape, taps.shape) == "kernel"
+    got = gated_conv.gated_conv(x, taps)
+    want = gated_conv.gated_conv_xla(x, taps)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=2e-6)
+
+    def grads(fn):
+        return jax.grad(
+            lambda x, taps: (fn(x, taps) * w).sum(), argnums=(0, 1)
+        )(x, taps)
+
+    (dx, d_taps), (dx_want, d_taps_want) = (
+        grads(gated_conv.gated_conv), grads(gated_conv.gated_conv_xla)
+    )
+    assert dx.shape == x.shape and d_taps.shape == taps.shape
+    np.testing.assert_allclose(dx, dx_want, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(d_taps, d_taps_want, rtol=1e-4, atol=1e-4)
+    # t < K - 1 of the second batch row reads zeros, not the first row's end
+    d = taps.shape[1]
+    by_hand = x[1, 0, d: 2 * d] * taps[-1] * x[1, 0, :d] * x[1, 0, 2 * d:]
+    np.testing.assert_allclose(got[1, 0], by_hand, rtol=1e-5, atol=1e-6)
+    # the tile edge: token 128 reads tokens 126 and 127 of the tile before
+    bz = x[0, 126:129, :d] * x[0, 126:129, 2 * d:]
+    edge = x[0, 128, d: 2 * d] * (taps[-3:] * bz).sum(0)
+    if k == 3:
+        np.testing.assert_allclose(got[0, 128], edge, rtol=1e-5, atol=1e-6)
+
+
+def test_the_gated_form_rounds_once_in_bfloat16():
+    from dlrover_tpu.models import gated_conv
+
+    x, taps, _ = gated_operands(dtype=jnp.bfloat16)
+    got = gated_conv.gated_conv(x, taps)
+    want = gated_conv.gated_conv_xla(x, taps)
+    assert got.dtype == jnp.bfloat16
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("x_shape,taps_shape,why", [
+    ((2, 100, 96), (3, 32), "tokens no whole lane tiles"),
+    ((2, 256, 120), (3, 40), "channels no whole row tiles"),
+    ((2, 256, 100), (3, 32), "not three ranges of the taps' width"),
+    ((2, 256, 4, 96), (3, 4, 32), "heads"),
+])
+def test_a_shape_the_gated_kernel_cannot_tile_takes_the_xla_form(
+    x_shape, taps_shape, why
+):
+    from dlrover_tpu.models import gated_conv
+
+    assert short_conv.plan_gated(x_shape, taps_shape) is None, why
+    if len(x_shape) == 3 and x_shape[2] == 3 * taps_shape[1]:
+        assert gated_conv.core_path(x_shape, taps_shape) == "xla"
+    # the cell's shape takes the kernel
+    assert gated_conv.core_path((4, 8192, 6144), (3, 2048)) == "kernel"

@@ -29,11 +29,28 @@ that view a row is whole native tiles, contiguous in HBM, which is the form
 ``ops/row_gather_sum.py`` can DMA single rows from.  The kernels take such
 blocks and reshape them in VMEM, so no relayout pass over HBM is needed on
 either side of a GEMM.
+
+What a forward or ``dx`` call costs in HBM traffic is decided by its tiles
+(:func:`plan_tiles`).  The grid is (output strip, row block, contraction
+step), so while the whole contraction is one step an expert's ``[K, tm]``
+strip of weights is fetched when the expert changes and stays in VMEM
+across that expert's row blocks: the weights cross HBM once an expert and
+strip, and the dot's result is the output block (no accumulator).  Once K
+is split the weight block changes at every grid step, and EVERY row block
+of 128 rows streams its expert's whole ``[K, tm]`` strip again: 7 MiB for
+4.8 us of MXU work at LFM2's 1792 x 2048, bound by HBM at half the MXU's
+rate whatever the body's schedule (on the chip 2.98 ms a call against 1.51
+resident, PERF.md section 6, PR 53; it costs less only where XLA happens to
+hold the whole expert matrix in VMEM itself, which no call can count on).
+K is therefore split only where the whole-K strip fits under no VMEM limit
+the kernel may ask for (``_VMEM_CAP``; Mixtral's 14336 x 2048 under
+``grouped``).
 """
 
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -44,11 +61,31 @@ from dlrover_tpu.ops import backend
 from dlrover_tpu.ops.row_gather_sum import LANES, tile_rows
 
 
-# VMEM one kernel may spend on its expert-weight tiles (double-buffered
-# blocks plus f32 accumulators); the row blocks of x / dy / out take the
-# rest of v5e's 16 MiB scoped VMEM.  A whole [K, M] expert block overflows
-# it at MoE widths (K=1600, M=3200), so the kernels tile K and M.
+# What a kernel's weight tiles may take of the DEFAULT scoped VMEM: the
+# double-buffered [tk, tm] block of one expert's weights (dw: the f32
+# accumulator and the double-buffered output tile), 8 of the 16 MiB a
+# kernel gets that asks for nothing; the row blocks of x / dy / out, the
+# f32 accumulator and Mosaic's temporaries take the rest.  16 MiB is that
+# default, not the chip's VMEM (a v5e core has 128 MiB): a forward or dx
+# call whose whole-K strip overflows this budget asks for more
+# (``_VMEM_CAP``) before it splits K, because a split K costs the weights
+# once a ROW BLOCK and not once an expert (the module docstring).  A whole
+# [K, M] expert block overflows the budget at MoE widths (K=1600, M=3200),
+# so the kernels tile M, and K where they must.
 _TILE_BYTES = 8 * 2**20
+
+# The most VMEM a forward/dx call may ask for (``vmem_limit_bytes``) to keep
+# a whole-K strip resident: a quarter of a v5e core's 128 MiB, twice the
+# default scoped limit.  ``_strip_vmem_bytes`` counts LFM2's [1792, 2048]
+# bf16 strip at 18.3 MiB (14.0 of them the strip twice; Mosaic accepts 16.4
+# for the described chip) and Nemotron's [2688, 1856] at 24.5 (19.7; 21.8);
+# Mixtral's [14336, 2048] would take 125.5 and keeps the K-split.  What a
+# call asks for XLA cannot use beside it: XLA keeps whole operands of a
+# kernel in VMEM where they fit (two of LFM2's 56 MiB expert matrices under
+# the default limit, one under 18.3 MiB: PERF.md section 6, PR 53), so the
+# call asks for what its plan needs and no more.  Nothing between 25 and
+# 125 MiB has been measured.
+_VMEM_CAP = 32 * 2**20
 
 
 def _lane_tile(dim: int, limit: int, quantum: int = LANES) -> int:
@@ -72,6 +109,81 @@ def _quantum(tiled: bool, dtype) -> int:
     return LANES * tile_rows(dtype) if tiled else LANES
 
 
+class Tiles(NamedTuple):
+    """A forward/dx call's weight block ``[tk, tm]`` and the scoped VMEM it
+    has to ask for (``None``: the default limit holds it)."""
+
+    tk: int
+    tm: int
+    vmem_limit_bytes: Optional[int]
+
+
+def _strip_vmem_bytes(k, tm, dtype, block_rows):
+    """VMEM a forward/dx call takes with the whole ``[k, tm]`` strip as its
+    weight block, as the array lies there (lanes padded to 128, sublanes to
+    the dtype's tile): the strip and the row blocks of x and out double-
+    buffered (one contraction step keeps no accumulator), and Mosaic's
+    temporaries: the x block once more (a row-tiled block's reshape) and
+    two f32 blocks of the output's shape (the dot's result and its cast's
+    operand)."""
+
+    def padded(n, tile):
+        return -(-n // tile) * tile
+
+    size = jnp.dtype(dtype).itemsize
+    k_lanes, tm_lanes = padded(k, LANES), padded(tm, LANES)
+    strip = 2 * padded(k, tile_rows(dtype)) * tm_lanes * size
+    x_blocks = 3 * block_rows * k_lanes * size
+    out_blocks = 2 * block_rows * tm_lanes * size
+    f32_blocks = 2 * block_rows * tm_lanes * 4
+    return strip + x_blocks + out_blocks + f32_blocks
+
+
+def plan_tiles(
+    k: int, m: int, x_tiled: bool, out_tiled: bool, dtype,
+    block_rows: int = 128,
+) -> Tiles:
+    """The tiles of a forward/dx call ``[N, k] @ [E, k, m]`` (rows in
+    row-tiled if ``x_tiled``, out if ``out_tiled``), which the kernel and
+    the ``compile`` event's ``gmm_strips`` both ask.
+
+    ``tm`` is the widest block of M whose double-buffered whole-K strip
+    fits ``_TILE_BYTES`` (at least one quantum: M itself where it is no
+    multiple of one).  Where that strip fits, or K cannot be cut, ``tk`` is
+    K under the default limit.  Where it overflows, the call asks for the
+    VMEM the whole strip needs while that is within ``_VMEM_CAP``, and only
+    beyond it splits K under the default limit."""
+    tile_elems = _TILE_BYTES // (2 * jnp.dtype(dtype).itemsize)
+    tm = _lane_tile(m, tile_elems // k, _quantum(out_tiled, dtype))
+    tk = _lane_tile(k, tile_elems // tm, _quantum(x_tiled, dtype))
+    if tk == k:
+        return Tiles(k, tm, None)
+    need = _strip_vmem_bytes(k, tm, dtype, block_rows)
+    if need <= _VMEM_CAP:
+        return Tiles(k, tm, need)
+    return Tiles(tk, tm, None)
+
+
+def expert_strips(
+    d_model: int, d_ff: int, gated: bool, rows_tiled: bool, dtype
+) -> str:
+    """For the ``compile`` event's ``gmm_strips``: of the distinct
+    forward/dx calls of one expert layer (``wi``, ``wg`` where ``gated``
+    and ``wo`` forward, and the ``dx`` of each; the replay repeats the
+    forward's) how many split K: ``resident`` where none does, else
+    ``split_k:<calls>/<of>``.  ``rows_tiled``: whether the d_model-wide
+    rows come and go row-tiled (``row_gather_sum.kernel_fits``, which the
+    layer asks)."""
+    # Two plans, each met by as many calls as the layer has matrices: wi /
+    # wg forward and wo's dx go INTO the expert width, wo forward and wi /
+    # wg's dx come OUT OF it.
+    into = plan_tiles(d_model, d_ff, rows_tiled, False, dtype)
+    out_of = plan_tiles(d_ff, d_model, False, rows_tiled, dtype)
+    each = 3 if gated else 2
+    split = each * ((into.tk < d_model) + (out_of.tk < d_ff))
+    return f"split_k:{split}/{2 * each}" if split else "resident"
+
+
 def _row_block(tiled, block_rows, width, index):
     """BlockSpec of ``block_rows`` rows by ``width`` columns at block
     ``index(*grid) -> (i, j)``, of a plain or a row-tiled array."""
@@ -89,8 +201,29 @@ def _plain(ref):
     return block.reshape(block.shape[0], -1) if block.ndim == 3 else block
 
 
-def _gmm_kernel(*refs, skip_dead):
+def _gmm_kernel(*refs, skip_dead, k_steps):
     live_blocks = refs[1] if skip_dead else None
+    if k_steps == 1:
+        # One contraction step: the dot's own result is the output block,
+        # and no float32 accumulator is zeroed, added into and read back.
+        x_ref, w_ref, out_ref = refs[-3:]
+
+        def product():
+            out_ref[...] = jax.lax.dot(
+                _plain(x_ref), w_ref[0], preferred_element_type=jnp.float32
+            ).astype(out_ref.dtype).reshape(out_ref.shape)
+
+        if skip_dead:
+            live = pl.program_id(1) < live_blocks[0]
+            pl.when(live)(product)
+
+            @pl.when(jnp.logical_not(live))
+            def _():
+                out_ref[...] = jnp.zeros_like(out_ref)
+        else:
+            product()
+        return
+
     x_ref, w_ref, out_ref, acc_ref = refs[-4:]
     kk = pl.program_id(2)
 
@@ -108,7 +241,7 @@ def _gmm_kernel(*refs, skip_dead):
     else:
         accumulate()
 
-    @pl.when(kk == pl.num_programs(2) - 1)
+    @pl.when(kk == k_steps - 1)
     def _():
         # a dead block writes the zeros its accumulator still holds
         out_ref[...] = acc_ref[:].astype(out_ref.dtype).reshape(out_ref.shape)
@@ -169,12 +302,13 @@ def _gmm_fwd_impl(x, w, group_sizes, block_rows, out_tiled, skip_dead):
     scalars = _block_plan(group_sizes, num_blocks, block_rows, skip_dead)
 
     # Output columns outermost, contraction innermost.  While the whole K
-    # fits (tk == k) an expert's [K, tm] strip stays resident across its
-    # consecutive row blocks; K is split only when M cannot be.
-    tile_elems = _TILE_BYTES // (2 * jnp.dtype(w.dtype).itemsize)
-    tm = _lane_tile(m, tile_elems // k, _quantum(out_tiled, x.dtype))
-    tk = _lane_tile(k, tile_elems // tm, _quantum(x.ndim == 3, x.dtype))
-    last_k = k // tk - 1
+    # is one step (tk == k) an expert's [K, tm] strip stays resident across
+    # its consecutive row blocks; split, every row block fetches it again.
+    tk, tm, vmem_limit = plan_tiles(
+        k, m, x.ndim == 3, out_tiled, x.dtype, block_rows
+    )
+    k_steps = k // tk
+    last_k = k_steps - 1
 
     def k_of(i, kk, s):
         # a dead block stays on the contraction tile the last live one ended on
@@ -182,7 +316,7 @@ def _gmm_fwd_impl(x, w, group_sizes, block_rows, out_tiled, skip_dead):
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
-        grid=(m // tm, num_blocks, k // tk),
+        grid=(m // tm, num_blocks, k_steps),
         in_specs=[
             _row_block(
                 x.ndim == 3, block_rows, tk,
@@ -196,13 +330,17 @@ def _gmm_fwd_impl(x, w, group_sizes, block_rows, out_tiled, skip_dead):
         out_specs=_row_block(
             out_tiled, block_rows, tm, lambda j, i, kk, *s: (i, j)
         ),
-        scratch_shapes=[pltpu.VMEM((block_rows, tm), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block_rows, tm), jnp.float32)]
+        if k_steps > 1 else [],
     )
     return pl.pallas_call(
-        functools.partial(_gmm_kernel, skip_dead=skip_dead),
+        functools.partial(_gmm_kernel, skip_dead=skip_dead, k_steps=k_steps),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(
             (n, m // LANES, LANES) if out_tiled else (n, m), x.dtype
+        ),
+        compiler_params=vmem_limit and pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit
         ),
         interpret=backend.interpret(),
     )(*scalars, x, w)

@@ -368,6 +368,7 @@ class ElasticTrainer:
                 "ssm_tiles_per_group": self._ssm_tiles_per_group(),
                 "short_conv": self._short_conv(),
                 "row_moves": self._row_moves(),
+                "gmm_strips": self._gmm_strips(),
                 "conv_core": self._conv_core(),
                 "kda": self._kda(),
             }
@@ -547,6 +548,25 @@ class ElasticTrainer:
             return "xla"
         share = cfg.resolved_experts_held < cfg.num_experts
         return ("kernel_live" if share else "kernel") + padded
+
+    def _gmm_strips(self) -> str:
+        """Whether the grouped experts' forward and ``dx`` GEMMs hold an
+        expert's whole-K strip of weights in VMEM across its row blocks,
+        for the ``compile`` event: ``resident``, or ``split_k:<n>/<of>``
+        where ``n`` of a layer's distinct forward/dx calls (six for gated
+        experts, four for ungated) split K and stream the weights once a
+        row block (``ops/grouped_matmul.py`` ``plan_tiles``, which the
+        kernel asks), ``none`` for a model without grouped experts."""
+        cfg = self.model_config
+        if not cfg.num_experts or cfg.moe_dispatch != "grouped":
+            return "none"
+        from dlrover_tpu.ops import grouped_matmul, row_gather_sum
+
+        return grouped_matmul.expert_strips(
+            cfg.d_model, cfg.resolved_moe_d_ff, cfg.activation == "swiglu",
+            row_gather_sum.kernel_fits(cfg.d_model, cfg.top_k, cfg.dtype),
+            cfg.dtype,
+        )
 
     def _short_conv(self) -> str:
         """How the step program's short convolutions run, for the

@@ -387,6 +387,15 @@ CASES = [
     ("grouped_matmul_lfm2_wo_out_tiled",
      lambda: _grouped_matmul(True, True),
      [((42112, 1792), BF16), ((8, 1792, 2048), BF16), ((8,), I32)], {}, 3),
+    # Nemotron-3-Nano's share: 16 held ungated experts of 1,856 (14.5 lane
+    # tiles: a block's full extent) at d_model 2,688, plain rows out of a
+    # budget of 17,536.  wi's [2688, 1856] strip, as LFM2's wo [1792, 2048]
+    # above, stays whole-K under a scoped-VMEM limit the call asks for
+    # (`plan_tiles`), which Mosaic holds it to here
+    ("grouped_matmul_nemotron_wi", lambda: _grouped_matmul(False, True),
+     [((17536, 2688), BF16), ((16, 2688, 1856), BF16), ((16,), I32)], {}, 3),
+    ("grouped_matmul_nemotron_wo", lambda: _grouped_matmul(False, True),
+     [((17536, 1856), BF16), ((16, 1856, 2688), BF16), ((16,), I32)], {}, 3),
     ("row_gather_sum_lfm2_live_weighted", _row_gather_sum_live,
      [((42112, 16, 128), BF16), ((32768, 4), I32), ((32768, 4), F32)],
      {}, 1),

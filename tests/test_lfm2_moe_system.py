@@ -110,6 +110,7 @@ def test_fit_books_the_conv_event_from_the_step_itself(monkeypatch, tmp_path):
     events = [e for e in taken if e[1] == "event"]
     (compiled,) = [e[-1] for e in taken if e[0] == "compile"]
     assert compiled["conv_core"] == "xla" and compiled["short_conv"] == "none"
+    assert compiled["gmm_strips"] == "resident"
     conv = [e[4] for e in events if e[0] == "conv"]
     moe = [e[4] for e in events if e[0] == "moe"]
     assert [e["step"] for e in conv] == [5, 10] == [e["step"] for e in moe]
@@ -159,6 +160,11 @@ def test_the_compile_event_says_how_the_core_runs():
     # rows of 2,048 are 16 lane tiles, 4 a token: the fetch-and-sum kernel
     # under a share of the experts
     assert ElasticTrainer._row_moves(stub(published, 8192)) == "kernel_live"
+    # wo's [1792, 2048] strip and the transposed wi's and wg's, which the
+    # default scoped VMEM would cut in two, stay whole under the limit the
+    # calls ask for: none of a layer's six forward/dx GEMMs splits K
+    assert ElasticTrainer._gmm_strips(stub(published, 8192)) == "resident"
+    assert ElasticTrainer._gmm_strips(stub(TransformerConfig())) == "none"
 
 
 def test_the_scopes_the_benchmark_reads_reach_the_compiled_text(tokens):

@@ -85,6 +85,26 @@ def test_a_model_without_such_a_layer_names_no_scan():
     assert ElasticTrainer._ssm_scan(stub) == "xla"
 
 
+@pytest.mark.parametrize("cap,said", [
+    # wi's [2688, 1856] strip (and the transposed wo's: its dx) overflows
+    # the default scoped VMEM's budget and asks for more ...
+    (None, "resident"),
+    # ... and with nothing more to ask for K is cut in three: two of the
+    # ungated layer's four forward/dx calls (the rule until PR 53)
+    (0, "split_k:2/4"),
+])
+def test_the_compile_event_names_the_gemm_strips(monkeypatch, cap, said):
+    from dlrover_tpu.ops import grouped_matmul
+    from dlrover_tpu.trainer.elastic_trainer import ElasticTrainer
+
+    if cap is not None:
+        monkeypatch.setattr(grouped_matmul, "_VMEM_CAP", cap)
+    stub = type("T", (), {
+        "model_config": nemotron_h_config(experts_held=16)
+    })()
+    assert ElasticTrainer._gmm_strips(stub) == said
+
+
 @pytest.mark.parametrize("overrides,rows", [
     # the cell: 16 of 128 experts here, six rows of 2,688 a token.  21 lane
     # tiles are no whole native tiles, so the rows stay plain between the

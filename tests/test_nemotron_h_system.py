@@ -178,3 +178,4 @@ def test_fit_books_one_ssm_event_per_report_from_the_step_itself(
     assert train_lib.trace_count("train_step") == 1
     (compiled,) = [e for e in taken if e[0] == "compile"]
     assert compiled[-1]["ssm_scan"] == "kernel"
+    assert compiled[-1]["gmm_strips"] == "resident"

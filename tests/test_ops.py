@@ -138,14 +138,24 @@ def test_grouped_matmul_fwd(rng):
     np.testing.assert_allclose(out, ref, atol=1e-4, rtol=1e-4)
 
 
-@pytest.mark.parametrize("k,m,tile_bytes", [
-    (64, 64, None),          # one tile per expert block
-    (256, 384, 256 * 1024),  # fwd/dx tile M then K, dw tiles M
-    (384, 200, 256 * 1024),  # M off the lane grid: only K can be split
+@pytest.mark.parametrize("k,m,tile_bytes,cap,fwd_tk,dx_tk", [
+    (64, 64, None, None, 64, 64),    # one tile per expert block
+    # no more VMEM to ask for: fwd/dx tile M then K, dw tiles M
+    (256, 384, 256 * 1024, 0, 256, 128),
+    # M off the lane grid: only K can be split, and without a cap it is
+    (384, 200, 256 * 1024, 0, 128, 200),
+    # the same under the cap: the strip the budget would cut in three
+    # stays whole, one contraction step
+    (384, 200, 256 * 1024, None, 384, 200),
 ])
-def test_grouped_matmul_grads(rng, monkeypatch, k, m, tile_bytes):
+def test_grouped_matmul_grads(rng, monkeypatch, k, m, tile_bytes, cap,
+                              fwd_tk, dx_tk):
     if tile_bytes is not None:
         monkeypatch.setattr(gmm, "_TILE_BYTES", tile_bytes)
+    if cap is not None:
+        monkeypatch.setattr(gmm, "_VMEM_CAP", cap)
+    assert gmm.plan_tiles(k, m, False, False, jnp.float32).tk == fwd_tk
+    assert gmm.plan_tiles(m, k, False, False, jnp.float32).tk == dx_tk
     e = 3
     sizes = jnp.asarray([128, 256, 128], jnp.int32)
     n = int(sizes.sum())
@@ -162,6 +172,64 @@ def test_grouped_matmul_grads(rng, monkeypatch, k, m, tile_bytes):
     gx_r, gw_r = jax.grad(loss_ref, argnums=(0, 1))(x, w)
     np.testing.assert_allclose(gx_k, gx_r, atol=1e-3, rtol=1e-3)
     np.testing.assert_allclose(gw_k, gw_r, atol=1e-3, rtol=1e-3)
+
+
+# (k, m, rows in tiled, rows out tiled) -> (tk, tm, asks for more VMEM), in
+# bfloat16.  A gated expert layer's six forward/dx calls are two plans: wi /
+# wg forward and wo's dx ("into" the expert width), wo forward and wi / wg's
+# dx ("out of" it).  The four cells above the line keep the tiles they had
+# before `plan_tiles` (their strips were whole); LFM2's and Nemotron's split
+# K until PR 53 ((896, 2048) and (896, 1856)); Mixtral's wo still does.
+_CELL_TILES = {
+    "olmoe_into": ((2048, 1024, True, False), (2048, 1024, False)),
+    "olmoe_out_of": ((1024, 2048, False, True), (1024, 2048, False)),
+    "joyai_into": ((2048, 768, True, False), (2048, 768, False)),
+    "joyai_out_of": ((768, 2048, False, True), (768, 2048, False)),
+    "ling_into": ((2560, 768, False, False), (2560, 768, False)),
+    "ling_out_of": ((768, 2560, False, False), (768, 2560, False)),
+    "granite_into": ((4096, 768, True, False), (4096, 384, False)),
+    "granite_out_of": ((768, 4096, False, True), (768, 2048, False)),
+    "lfm2_into": ((2048, 1792, True, False), (2048, 896, False)),
+    "nemotron_out_of": ((1856, 2688, False, False), (1856, 896, False)),
+    "mixtral_into": ((4096, 14336, True, False), (4096, 512, False)),
+    # ------------------------------------------------------------------
+    "lfm2_out_of": ((1792, 2048, False, True), (1792, 2048, True)),
+    "nemotron_into": ((2688, 1856, False, False), (2688, 1856, True)),
+    "mixtral_out_of": ((14336, 4096, False, True), (1024, 2048, False)),
+}
+
+
+@pytest.mark.parametrize("call", sorted(_CELL_TILES))
+def test_plan_tiles_at_the_cells_shapes(call):
+    (k, m, x_tiled, out_tiled), (tk, tm, asks) = _CELL_TILES[call]
+    plan = gmm.plan_tiles(k, m, x_tiled, out_tiled, jnp.bfloat16)
+    assert (plan.tk, plan.tm) == (tk, tm)
+    if not asks:
+        # the default scoped limit, and no compiler parameters at all
+        assert plan.vmem_limit_bytes is None
+    else:
+        # what the whole-K strip needs, twice over, and within the cap
+        assert 2 * k * tm * 2 < plan.vmem_limit_bytes <= gmm._VMEM_CAP
+
+
+@pytest.mark.parametrize("d,ff,gated,tiled,said", [
+    (2048, 1024, True, True, "resident"),       # OLMoE
+    (2048, 1792, True, True, "resident"),       # LFM2 (split_k:3/6 before)
+    (2688, 1856, False, False, "resident"),     # Nemotron (split_k:2/4)
+    (4096, 14336, True, True, "split_k:3/6"),   # Mixtral under `grouped`
+])
+def test_expert_strips_counts_a_layers_calls(d, ff, gated, tiled, said):
+    assert gmm.expert_strips(d, ff, gated, tiled, jnp.bfloat16) == said
+
+
+def test_expert_strips_without_the_cap_reads_the_old_rule(monkeypatch):
+    monkeypatch.setattr(gmm, "_VMEM_CAP", 0)
+    assert gmm.expert_strips(
+        2048, 1792, True, True, jnp.bfloat16
+    ) == "split_k:3/6"
+    assert gmm.expert_strips(
+        2688, 1856, False, False, jnp.bfloat16
+    ) == "split_k:2/4"
 
 
 def test_grouped_matmul_empty_expert_grad(rng):

@@ -200,14 +200,20 @@ def test_the_phases_are_exclusive(preset, devices, zero1, grad_accum):
 # step's heads in lockstep (``ops/gated_delta_rule.py``), which is another
 # kernel body and the same numbers; it read f0d80527...c1ed6ab5.  The other
 # five texts (three here, two below) standing unedited is the proof that no
-# other model's program moved.
+# other model's program moved.  PR 53 recorded ``olmoe-1b-7b``'s anew (and
+# the two below): a grouped GEMM's forward/dx call of ONE contraction step
+# writes the dot's result straight to its output block and keeps no float32
+# accumulator (``ops/grouped_matmul.py`` ``_gmm_kernel``), another kernel
+# body and the same numbers; it read 0d7f87bc...5623fd02.  With ``plan_tiles``
+# alone (the tiles and the VMEM limit, the accumulator still there) all six
+# texts stood unedited: no tiny preset's strip overflows the budget.
 LOWERED_AT_PARENT = {
     "gpt2-1.5b":
         "3fb5f6338781894bc6418780c92ff0224b12abbaddadeef5d7c79a740c9f4f92",
     "mixtral-8x7b":
         "a610499e04164995118fff59e041ffb9f8a82901625a1fddb1ebe83edd4790bb",
     "olmoe-1b-7b":
-        "0d7f87bc882696205ed45766b521452c70b9eab6848047132852914d5623fd02",
+        "e4a8149a8e2cf5234188b2af863582c6a92a856d1b82420555f3a2a16ffcaa9a",
     "olmo-hybrid-7b":
         "d31949ba8911676ff7e5da8cb178c47edcc51ec0f2fba1b5676e1d934fc6a86c",
 }
@@ -240,13 +246,15 @@ def test_earlier_models_keep_their_lowered_step_text(preset):
 # 0ab4b77 they read 680dda30...835c48d (as at fed8b01: nothing else in the
 # step had moved) and 1ac0af4f...e16b47b4 (PR 40's scan kernels); the
 # presets that route by softmax or not at all, the four above and Granite's,
-# kept the parent's texts (CHANGES.md, PR 45).  Whoever edits the router or
-# the scan kernels next re-pins them.
+# kept the parent's texts (CHANGES.md, PR 45).  PR 53 recorded both anew for
+# the grouped GEMMs' one-step body (the note above): they read
+# 63af0acf...9706c9955 and 80d960d2...39bf378.  Whoever edits the router, the
+# scan kernels or the grouped GEMMs next re-pins them.
 LATER_PRESETS_LOWERED = {
     "joyai-llm-flash":
-        "63af0acfb70a6311d09581ecbce514fc91fea6dfa3d15f6c04ad2fa9706c9955",
+        "c92d3a044315cdf4b513e023bed00f6fb542564c765d739e3971ef4b5ee5b0bf",
     "nemotron-3-nano-30b-a3b":
-        "80d960d277046e5ad1b5448296088ed0d3696374b89f651e741461bf439bf378",
+        "cedfb9810641b29ef3ef7d973511726f2eb95ded9dec29a377f2de3c46aea18c",
 }
 
 

@@ -435,6 +435,16 @@ class MasterServicer:
                     logger.warning(
                         "unparseable conv event from %d: %r", node, attrs,
                     )
+            elif self.speed_monitor is not None and name == "attn":
+                # Softmax-attention snapshot of a model with windowed
+                # layers (layers by kind, window, the score bounds): feeds
+                # the ledger behind the dlrover_attn_* gauges.
+                try:
+                    self.speed_monitor.record_attn(node, **attrs)
+                except (TypeError, ValueError):
+                    logger.warning(
+                        "unparseable attn event from %d: %r", node, attrs,
+                    )
             elif self.speed_monitor is not None and name == "embed":
                 # Embedding-plane stats snapshot: feeds the embed ledger
                 # behind the dlrover_embed_* gauges (rows owned, cache

@@ -112,6 +112,11 @@ class SpeedMonitor:
         # convolutions (no state: the core's largest output stands where
         # the state's largest entry does) — the ``dlrover_conv_*`` gauges.
         self._conv_stats: Dict[int, Dict[str, float]] = {}
+        # "attn" telemetry events: the softmax attentions of a model with
+        # windowed layers (the bound of each kind's largest score stands
+        # where a state's largest entry does) — the ``dlrover_attn_*``
+        # gauges.
+        self._attn_stats: Dict[int, Dict[str, float]] = {}
 
     def collect_global_step(
         self, step: int, timestamp: Optional[float] = None, tokens: int = 0
@@ -459,6 +464,43 @@ class SpeedMonitor:
         return self._state_ledger(
             self._conv_stats, ("gate_absmean", "out_gate_absmean"),
             max_keys=("out_absmax",),
+        )
+
+    def record_attn(
+        self,
+        node_id: int = 0,
+        *,
+        step: float = 0.0,
+        full_layers: float = 0.0,
+        sliding_layers: float = 0.0,
+        window: float = 0.0,
+        full_score_bound: float = 0.0,
+        sliding_score_bound: float = 0.0,
+        score_bound: float = 0.0,
+        **_ignored,
+    ):
+        """A trainer's softmax-attention snapshot (its ``attn`` telemetry
+        event).  Newest-wins per reporting node; unknown attrs are
+        ignored."""
+        with self._lock:
+            self._attn_stats[node_id] = {
+                "step": float(step),
+                "layers": float(full_layers) + float(sliding_layers),
+                "sliding_layers": float(sliding_layers),
+                "window": float(window),
+                "full_score_bound": float(full_score_bound),
+                "sliding_score_bound": float(sliding_score_bound),
+                "score_bound": float(score_bound),
+            }
+
+    def attn_ledger(self) -> Dict[str, float]:
+        """:meth:`linear_attn_ledger`'s aggregate of the ``attn`` events:
+        every entry the largest of the reporters'."""
+        return self._state_ledger(
+            self._attn_stats, (), max_keys=(
+                "sliding_layers", "window", "full_score_bound",
+                "sliding_score_bound", "score_bound",
+            ),
         )
 
     def _state_ledger(

@@ -456,6 +456,22 @@ class JobTimeline:
                   "reporters; NaN/Inf = diverged)")
             gauge("dlrover_conv_reporters", conv["reporters"],
                   "trainers that have reported convolution snapshots")
+            attn = speed_monitor.attn_ledger()
+            gauge("dlrover_attn_window", attn["window"],
+                  "keys a windowed attention layer's query sees")
+            gauge("dlrover_attn_sliding_layers", attn["sliding_layers"],
+                  "windowed attention layers of the model")
+            gauge("dlrover_attn_full_score_bound",
+                  attn["full_score_bound"],
+                  "UPPER BOUND, not an observed score: longest query "
+                  "row x longest key row x scale of a full attention "
+                  "layer's heads (max of reporters; a rotation's factor "
+                  "shows here; NaN/Inf = diverged)")
+            gauge("dlrover_attn_sliding_score_bound",
+                  attn["sliding_score_bound"],
+                  "the same of a windowed layer")
+            gauge("dlrover_attn_reporters", attn["reporters"],
+                  "trainers that have reported attention snapshots")
             sdc = speed_monitor.sdc_ledger()
             gauge("dlrover_sdc_checks_total", sdc["checks"],
                   "cross-replica state-digest votes performed")

@@ -65,6 +65,9 @@ def ulysses_attention(
     ``all_to_all`` swaps the shards to head-sharded ``[B, S, H/sp, D]``
     for the attention math, and back after.
 
+    ``attn_fn`` sees the whole sequence, so it may not be windowed by a
+    band that this function splits: ``Attention`` refuses a window here.
+
     Expressing the switch as annotations alone (``ACT_HEADS ->
     (seq, tensor)`` constraints) leaves the resharding decision to the
     SPMD partitioner, which falls back to "involuntary full
@@ -107,9 +110,12 @@ def xla_attention(
     causal: bool = True,
     segment_ids: Optional[jax.Array] = None,
     scale: Optional[float] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Reference einsum attention; fp32 softmax; shapes [B, S, H, D];
-    the scores times ``scale`` (default ``D ** -0.5``).
+    the scores times ``scale`` (default ``D ** -0.5``).  ``window`` (causal
+    only) keeps the band ``0 <= i - j < window``: a query sees itself and
+    the ``window - 1`` tokens before it.
 
     Supports GQA (H_kv dividing H_q) and packed-sequence masks via
     ``segment_ids`` — the capability match for the reference's GLM/pack mask
@@ -132,6 +138,10 @@ def xla_attention(
         qpos = jnp.arange(sq)[:, None]
         kpos = jnp.arange(sk)[None, :]
         mask = qpos >= kpos
+        if window is not None:
+            mask = jnp.logical_and(mask, qpos - kpos < window)
+    elif window is not None:
+        raise ValueError("a window needs causal attention")
     if segment_ids is not None:
         seg = segment_ids[:, :, None] == segment_ids[:, None, :]
         seg = seg[:, None, None, :, :]
@@ -152,7 +162,9 @@ def cached_attention(
 ) -> jax.Array:
     """Decode attention: queries at absolute ``q_positions`` [B, T]
     against the full KV cache [B, L, H_kv, D]; cache slots past a query's
-    position (unwritten, or future) are masked.  GQA via grouped q."""
+    position (unwritten, or future) are masked.  GQA via grouped q.  It
+    knows no window: a windowed layer would keep a ring of ``W`` rows, which
+    nothing in ``serving/decode.py`` holds yet (``Attention`` refuses)."""
     b, sq, hq, d = q.shape
     cache_len, hkv = k.shape[1], k.shape[2]
     group = hq // hkv
@@ -171,10 +183,13 @@ def cached_attention(
     return out.reshape(b, sq, hq, d)
 
 
-def _flash_local(q, k, v, segment_ids, *, block_q, block_kv, scale=None):
+def _flash_local(
+    q, k, v, segment_ids, *, block_q, block_kv, scale=None, window=None
+):
     """Causal flash attention on each device's own batch rows and heads
     (:func:`shard_local`): q/k/v stay sharded as the active rule table
-    lays out ``[batch, -, act_heads, kv]``, the sequence is whole."""
+    lays out ``[batch, -, act_heads, kv]``, the sequence is whole.  Under a
+    ``window`` the kernels skip what lies outside the band."""
     from dlrover_tpu.ops import flash_attention as fa
 
     qkv_spec = nn.logical_to_mesh_axes((lr.BATCH, None, lr.ACT_HEADS, lr.KV))
@@ -186,7 +201,7 @@ def _flash_local(q, k, v, segment_ids, *, block_q, block_kv, scale=None):
     def local(q, k, v, seg=None):
         return fa.mha(
             q, k, v, causal=True, segment_ids=seg, scale=scale,
-            block_q=block_q, block_kv=block_kv,
+            block_q=block_q, block_kv=block_kv, window=window,
         )
 
     return shard_local(
@@ -229,8 +244,29 @@ class QKNorm(nn.Module):
         return (y * scale).astype(x.dtype)
 
 
+STATS_NAME = "attn_stats"
+
+
+def score_bound(q: jax.Array, k: jax.Array, scale: float) -> jax.Array:
+    """An upper bound of the largest ``|q_i . k_j| * scale`` of any head,
+    ``[B, S, H, D]`` and ``[B, S, H_kv, D]`` in, a scalar out: the longest
+    query row of a key head's group times its longest key row
+    (Cauchy-Schwarz; ``S x d`` work, where the exact maximum is the ``S x
+    S`` scores again).  It moves with whatever scales q and k, a rotation's
+    factor included."""
+    b, _, hq, _ = q.shape
+    hkv = k.shape[2]
+    q_len = jnp.linalg.norm(q.astype(jnp.float32), axis=-1).max(axis=1)
+    k_len = jnp.linalg.norm(k.astype(jnp.float32), axis=-1).max(axis=1)
+    q_len = q_len.reshape(b, hkv, hq // hkv).max(axis=-1)
+    return (q_len * k_len).max() * scale
+
+
 class Attention(nn.Module):
-    """Causal self-attention block with RoPE/GQA and SP-aware shardings."""
+    """Causal self-attention block with RoPE/GQA and SP-aware shardings.
+    ``window`` keeps the band ``0 <= i - j < window`` of the causal mask
+    (a ``sliding_attention`` layer); ``rotation`` is the layer kind's rotary
+    embedding where it is more than ``rope_theta`` (YaRN)."""
 
     num_heads: int
     num_kv_heads: int
@@ -254,6 +290,14 @@ class Attention(nn.Module):
     # single decode token) against it.
     decode: bool = False
     cache_len: int = 0
+    window: int = 0                 # 0: the whole causal triangle
+    rotation: Optional[layers.Rotation] = None
+    # Sow ``STATS_NAME``: [the bound of a full layer's scores, of a windowed
+    # layer's] (:func:`score_bound`; the other entry 0).
+    score_stats: bool = False
+    # The spread the scaled scores are seeded with: query and key kernels
+    # at ``sqrt(init_score_std / features)``; 0: the default initialiser.
+    init_score_std: float = 0.0
 
     @nn.compact
     def __call__(
@@ -266,8 +310,26 @@ class Attention(nn.Module):
         scale = self.scale or None
         if positions is None:
             positions = jnp.arange(x.shape[1])[None, :]
+        window = self.window or None
+        if self.window and (
+            self.decode or self.attention_impl == "ring"
+            or mesh_axis_size(SEQ_AXIS) > 1
+        ):
+            raise ValueError(
+                f"a window of {self.window} keys runs on a whole sequence "
+                "under attention_impl 'flash' or 'xla': cached_attention "
+                "(decode=True) keeps no ring of window rows, and "
+                "ulysses_attention and ring attention split the sequence "
+                "the band runs along"
+            )
 
         if self.num_kv_heads == self.num_heads:
+            if self.init_score_std:
+                raise ValueError(
+                    "init_score_std seeds the separate query and key "
+                    "kernels of grouped-query attention; the fused qkv "
+                    "kernel has one initialiser"
+                )
             # This is the whole rule: without GQA the three projections are
             # equally wide and run as one kernel (param ``qkv``), with GQA
             # they stay three (``query``/``key``/``value``).
@@ -288,12 +350,18 @@ class Attention(nn.Module):
             k = qkv[..., self.head_dim: 2 * self.head_dim]
             v = qkv[..., 2 * self.head_dim:]
         else:
+            qk_init = layers.default_kernel_init
+            if self.init_score_std:
+                qk_init = nn.initializers.normal(
+                    (self.init_score_std / features) ** 0.5
+                )
             q = layers.DenseGeneral(
                 (self.num_heads, self.head_dim),
                 kernel_axes=(lr.EMBED, lr.HEADS, lr.KV),
                 use_bias=self.use_bias,
                 dtype=self.dtype,
                 param_dtype=self.param_dtype,
+                kernel_init=qk_init,
                 name="query",
             )(x)
             k = layers.DenseGeneral(
@@ -302,6 +370,7 @@ class Attention(nn.Module):
                 use_bias=self.use_bias,
                 dtype=self.dtype,
                 param_dtype=self.param_dtype,
+                kernel_init=qk_init,
                 name="key",
             )(x)
             v = layers.DenseGeneral(
@@ -330,7 +399,19 @@ class Attention(nn.Module):
             )(k)
 
         if self.use_rope:
-            q, k = layers.rotary_embedding(q, k, positions, self.rope_theta)
+            rotation = self.rotation or layers.Rotation(self.rope_theta)
+            q, k = layers.rotary_embedding(
+                q, k, positions, *rotation.table(self.head_dim)
+            )
+        if self.score_stats:
+            bound = jax.lax.stop_gradient(
+                score_bound(q, k, scale or self.head_dim ** -0.5)
+            )
+            zero = jnp.zeros_like(bound)
+            self.sow(
+                "intermediates", STATS_NAME,
+                jnp.stack([zero, bound] if self.window else [bound, zero]),
+            )
 
         if self.decode:
             b, t = x.shape[0], x.shape[1]
@@ -424,7 +505,9 @@ class Attention(nn.Module):
 
                 out = ulysses_attention(attn_fn, q, k, v, segment_ids)
             elif flash:
-                out = _flash_local(q, k, v, segment_ids, **blocks)
+                out = _flash_local(
+                    q, k, v, segment_ids, window=window, **blocks
+                )
             else:
                 attn_spec = (lr.BATCH, None, lr.ACT_HEADS, lr.KV)
                 q = nn.with_logical_constraint(q, attn_spec)
@@ -432,7 +515,7 @@ class Attention(nn.Module):
                 v = nn.with_logical_constraint(v, attn_spec)
                 out = xla_attention(
                     q, k, v, causal=True, segment_ids=segment_ids,
-                    scale=scale,
+                    scale=scale, window=window,
                 )
                 out = nn.with_logical_constraint(out, attn_spec)
         else:
@@ -559,7 +642,7 @@ class LatentAttention(nn.Module):
         with jax.named_scope("rope"):
             q_pe, k_pe = layers.rotary_embedding(
                 q[..., nope:], kv_row[..., None, self.kv_lora_rank:],
-                positions, self.rope_theta,
+                positions, layers.rope_frequencies(rope // 2, self.rope_theta),
             )
             q = jnp.concatenate([q[..., :nope], q_pe], axis=-1)
             k = jnp.concatenate([

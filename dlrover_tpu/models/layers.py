@@ -10,6 +10,8 @@ module class, so the same model code runs under any strategy.
 
 from __future__ import annotations
 
+import dataclasses
+import math
 from typing import Any, Callable, Iterable, Tuple, Union
 
 import flax.linen as nn
@@ -245,21 +247,99 @@ def make_norm(kind: str, dtype: Dtype, param_dtype: Dtype, name: str,
     raise ValueError(f"unknown norm kind {kind!r}")
 
 
+def rope_frequencies(half: int, rope_theta: float) -> jax.Array:
+    """Plain RoPE's inverse frequencies ``theta ** (-i / half)``, ``[half]``."""
+    return 1.0 / (
+        rope_theta ** (jnp.arange(0, half, dtype=jnp.float32) / half)
+    )
+
+
+def yarn_range(
+    head_dim: int, rope_theta: float, original_len: int, beta_fast: float,
+    beta_slow: float,
+) -> Tuple[int, int]:
+    """YaRN's ``(low, high)``: the columns that make ``beta_fast`` and
+    ``beta_slow`` turns over the original length, rounded out and kept
+    inside the head.  Columns up to ``low`` keep their frequency, those
+    from ``high`` on are interpolated, a linear ramp between."""
+    def column(beta):
+        return head_dim * math.log(
+            original_len / (2 * math.pi * beta)
+        ) / (2 * math.log(rope_theta))
+
+    low = max(math.floor(column(beta_fast)), 0)
+    high = min(math.ceil(column(beta_slow)), head_dim - 1)
+    return low, high
+
+
+@dataclasses.dataclass(frozen=True)
+class Rotation:
+    """A layer kind's rotary embedding: plain RoPE at ``theta``, or under
+    ``scaling`` ``"yarn"`` (arXiv:2309.00071) the frequencies interpolated
+    by ``factor`` past the columns that turn often enough over
+    ``original_len`` positions, and cos and sin both times
+    ``attention_factor`` (so that a score carries its square)."""
+
+    theta: float = 10000.0
+    scaling: str = ""
+    factor: float = 0.0
+    original_len: int = 0
+    beta_fast: float = 0.0
+    beta_slow: float = 0.0
+    attention_factor: float = 0.0
+
+    def __post_init__(self):
+        if self.scaling not in ("", "yarn"):
+            raise ValueError(
+                f"rope scaling must be '' or 'yarn', got {self.scaling!r}"
+            )
+        numbers = (
+            self.factor, self.original_len, self.beta_fast, self.beta_slow,
+            self.attention_factor,
+        )
+        if self.scaling and not all(n > 0 for n in numbers):
+            raise ValueError(
+                "yarn needs its five numbers (factor, original length, "
+                f"beta_fast, beta_slow, attention factor), got {numbers}"
+            )
+
+    def table(self, head_dim: int) -> Tuple[jax.Array, float]:
+        """``(inverse frequencies [head_dim / 2], factor on cos and sin)``
+        for :func:`rotary_embedding`."""
+        half = head_dim // 2
+        inv = rope_frequencies(half, self.theta)
+        if not self.scaling:
+            return inv, 1.0
+        low, high = yarn_range(
+            head_dim, self.theta, self.original_len, self.beta_fast,
+            self.beta_slow,
+        )
+        ramp = jnp.clip(
+            (jnp.arange(half, dtype=jnp.float32) - low)
+            / max(high - low, 1e-3), 0.0, 1.0,
+        )
+        return (
+            inv * (1.0 - ramp) + (inv / self.factor) * ramp,
+            self.attention_factor,
+        )
+
+
 def rotary_embedding(
     q: jax.Array,
     k: jax.Array,
     positions: jax.Array,
-    rope_theta: float = 10000.0,
+    inv_freq: jax.Array,
+    factor: float = 1.0,
 ) -> Tuple[jax.Array, jax.Array]:
-    """Apply rotary position embeddings to q/k of shape [B, S, H, D]."""
-    head_dim = q.shape[-1]
-    half = head_dim // 2
-    freqs = 1.0 / (
-        rope_theta ** (jnp.arange(0, half, dtype=jnp.float32) / half)
-    )
-    angles = positions[..., None].astype(jnp.float32) * freqs  # [B, S, half]
+    """Apply rotary position embeddings to q/k of shape [B, S, H, D] at the
+    inverse frequencies ``inv_freq`` ``[D / 2]`` (:func:`rope_frequencies`,
+    :meth:`Rotation.table`), cos and sin times ``factor``."""
+    half = q.shape[-1] // 2
+    angles = positions[..., None].astype(jnp.float32) * inv_freq  # [B, S, half]
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
 
     def rotate(x):
         x32 = x.astype(jnp.float32)

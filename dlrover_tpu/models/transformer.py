@@ -29,7 +29,11 @@ layer.  LFM2's are a third two-branch kind, ``conv`` (a gated short
 convolution: two gates round a 3-tap causal depthwise convolution, no
 state, no softmax, no position; ``models/gated_conv.py``), a QK-norm PER
 HEAD (``qk_norm="per_head"``) and ``router_norm_eps`` beside the
-renormalising sum of a sigmoid router's gates.
+renormalising sum of a sigmoid router's gates.  Mellum2's are a fourth,
+``sliding_attention``: the config's attention under a band of the causal
+mask (``sliding_window`` keys, ``ops/flash_attention.py``'s ``window``),
+each kind with its own rotation (plain RoPE under the window; the full
+layers' ``rope_scaling="yarn"`` and its five numbers, ``layers.Rotation``).
 
 TPU-first structure:
   * layers are ``nn.scan``-stacked: one trace regardless of depth (fast
@@ -45,7 +49,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Optional, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -69,9 +73,13 @@ from dlrover_tpu.parallel import rules as lr
 FULL_ATTENTION = "full_attention"
 LINEAR_ATTENTION = "linear_attention"
 CONV = "conv"
-# Layers of TWO residual branches: a mixer (softmax attention, a delta rule
-# or a gated short convolution), then an MLP (``Block``).
-TWO_BRANCH_KINDS = (FULL_ATTENTION, LINEAR_ATTENTION, CONV)
+SLIDING_ATTENTION = "sliding_attention"
+# Layers of TWO residual branches: a mixer (softmax attention over the
+# causal triangle or over a window of it, a delta rule or a gated short
+# convolution), then an MLP (``Block``).
+TWO_BRANCH_KINDS = (
+    FULL_ATTENTION, LINEAR_ATTENTION, CONV, SLIDING_ATTENTION
+)
 # Layers that are ONE residual branch, ``x + f(Norm(x))`` (``BranchBlock``):
 # a state-space mixer, an attention, an expert layer, a dense MLP.
 SSM = "ssm"
@@ -234,6 +242,29 @@ class TransformerConfig:
     # Taps of the "conv" layers' gated short convolution
     # (models/gated_conv.py; LFM2's ``conv_L_cache``).
     conv_kernel: int = 3
+    # The "sliding_attention" layers (Mellum2's): the config's attention
+    # under a band of the causal mask, a query seeing itself and the
+    # ``sliding_window - 1`` tokens before it, rotated by plain RoPE at
+    # ``rope_theta``.
+    sliding_window: int = 0
+    # The spread the scaled scores ``q k^T / sqrt(head_dim)`` are SEEDED
+    # with (0: the default initialisers): the query and key kernels are
+    # drawn at ``sqrt(attn_init_score_std / d_model)``.  The default
+    # initialiser counts the heads into its fan-in, so seeded scores spread
+    # by under 0.1, every softmax is flat, a layer adds the running mean of
+    # its values (one vector for all late tokens) and no comparison of
+    # losses can see a window or a rotation; a trained model's scores are
+    # peaked.  See ``benchmark/configs/mellum2-12b-a2.5b.json``.
+    attn_init_score_std: float = 0.0
+    # The rotation of the FULL attention layers where it is more than
+    # ``rope_theta``: ``"yarn"`` and its five numbers (``layers.Rotation``;
+    # the published ``rope_parameters.full_attention``).
+    rope_scaling: str = ""
+    rope_scaling_factor: float = 0.0
+    rope_original_max_position: int = 0
+    rope_beta_fast: float = 0.0
+    rope_beta_slow: float = 0.0
+    rope_attention_factor: float = 0.0
     # "pre": x + f(Norm(x)) (GPT-2, Llama, Mixtral, OLMoE); "post":
     # x + Norm(f(x)), each branch's OUTPUT normalised before the residual
     # add (OLMo 2 and later).
@@ -336,6 +367,35 @@ class TransformerConfig:
         return self.num_layers_of(SSM)
 
     @property
+    def num_sliding_layers(self) -> int:
+        """As ``num_linear_layers``: the dense prefix's count too."""
+        return self.num_layers_of(SLIDING_ATTENTION) + sum(
+            self.layer_kind(i) == SLIDING_ATTENTION
+            for i in range(self.first_k_dense)
+        )
+
+    @property
+    def num_full_layers(self) -> int:
+        """Layers of ``full_attention`` (every layer without a pattern)."""
+        if not self.layer_pattern:
+            return self.num_layers
+        return self.num_layers_of(FULL_ATTENTION) + sum(
+            self.layer_kind(i) == FULL_ATTENTION
+            for i in range(self.first_k_dense)
+        )
+
+    def rotation(self, kind: str = FULL_ATTENTION) -> layers.Rotation:
+        """``kind``'s rotary embedding: plain RoPE under the window, the
+        scaled one (where the config names one) on the full layers."""
+        if kind == SLIDING_ATTENTION:
+            return layers.Rotation(self.rope_theta)
+        return layers.Rotation(
+            self.rope_theta, self.rope_scaling, self.rope_scaling_factor,
+            self.rope_original_max_position, self.rope_beta_fast,
+            self.rope_beta_slow, self.rope_attention_factor,
+        )
+
+    @property
     def ssm_heads_per_step(self) -> int:
         """Heads one grid step of the scan kernels holds at these sizes
         (``ops/ssd.py``); 0 where the kernels do not hold them."""
@@ -358,6 +418,7 @@ class TransformerConfig:
         object.__setattr__(self, "layer_pattern", tuple(self.layer_pattern))
         self._check_pattern()
         self._check_family()
+        self.rotation()             # yarn without its five numbers raises
         if self.attention_impl not in ("xla", "flash", "ring"):
             raise ValueError(
                 f"attention_impl must be 'xla', 'flash' or 'ring', got "
@@ -446,6 +507,27 @@ class TransformerConfig:
                     f"{self.conv_kernel - 1} rows of B * z a sequence have "
                     "no place beside the KV cache yet (serving/decode.py, "
                     "serving/engine.py); this model trains only"
+                )
+        if SLIDING_ATTENTION in pattern:
+            if self.sliding_window < 1:
+                raise ValueError(
+                    "a sliding_attention layer needs sliding_window (the "
+                    "keys a query sees, itself among them), got "
+                    f"{self.sliding_window}"
+                )
+            if self.latent_attention or self.attention_impl == "ring":
+                raise ValueError(
+                    "a sliding_attention layer is the plain grouped-query "
+                    "attention under attention_impl 'flash' or 'xla': "
+                    "latent attention and ring attention know no window"
+                )
+            if self.decode:
+                raise ValueError(
+                    "decode=True with a sliding_attention layer: its ring "
+                    f"of {self.sliding_window} cached rows has no place "
+                    "beside the whole cache of the full layers yet "
+                    "(serving/decode.py, models/attention.cached_attention);"
+                    " this model trains only"
                 )
         if EXPERTS in pattern and not self.num_experts:
             raise ValueError(
@@ -805,8 +887,12 @@ def _norm(cfg: TransformerConfig, name: str):
     )
 
 
-def _attention(cfg: TransformerConfig):
-    """The config's softmax attention, latent or plain, named ``attn``."""
+def _attention(cfg: TransformerConfig, kind: str = FULL_ATTENTION):
+    """The config's softmax attention, latent or plain, named ``attn``;
+    ``kind`` ``sliding_attention`` under its window and its own rotation.
+    Only a model with windowed layers hands ``Attention`` a rotation or
+    asks for its score statistics: every other model's program is the one
+    it was."""
     if cfg.latent_attention:
         return LatentAttention(
             num_heads=cfg.num_heads,
@@ -843,7 +929,20 @@ def _attention(cfg: TransformerConfig):
         scale=cfg.attention_scale,
         decode=cfg.decode,
         cache_len=cfg.max_seq_len,
+        init_score_std=cfg.attn_init_score_std,
+        **_by_kind(cfg, kind),
         name="attn",
+    )
+
+
+def _by_kind(cfg: TransformerConfig, kind: str) -> Dict[str, Any]:
+    sliding = kind == SLIDING_ATTENTION
+    if not (sliding or cfg.rope_scaling or cfg.num_sliding_layers):
+        return {}
+    return dict(
+        window=cfg.sliding_window if sliding else 0,
+        rotation=cfg.rotation(kind),
+        score_stats=bool(cfg.num_sliding_layers),
     )
 
 
@@ -947,7 +1046,7 @@ class Block(nn.Module):
                 param_dtype=cfg.param_dtype, name="conv",
             )(y)
         else:
-            y = _attention(cfg)(y, positions, segment_ids)
+            y = _attention(cfg, self.kind)(y, positions, segment_ids)
         if post:
             y = norm("ln_attn", y)
         # Named checkpoint: under the "attn_out" remat policy the backward

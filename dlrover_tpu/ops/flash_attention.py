@@ -44,6 +44,26 @@ class's work:
 
 Non-causal attention has interior blocks only.
 
+A window.  ``window=W`` keeps a BAND of the causal triangle: row ``i`` sees
+column ``j`` iff ``0 <= i - j < W`` (itself and the ``W - 1`` before it).
+The band has a second edge, so a block is also **dead** when it lies wholly
+BELOW the band, and an edge block is one of three: crossed by the diagonal
+alone (worked as without a window, row strips and all), by the band's LOWER
+edge alone (the masked square under ``j > i - W``), or by both (``W`` no
+wider than a block).  A (batch, head) of 32,768 tokens at blocks of 1,024
+has 528 live blocks under the causal mask and 63 under a band of 1,024, so
+the grids do not walk the dead ones either: the kv-inner kernels make
+``kv_steps`` steps a q block (the most kv blocks the band gives a q block),
+step ``t`` naming kv block ``first live + t``, and the q-inner kernels
+``q_steps`` a kv block alike (:class:`BandClasses`; on a v5e at 32,768
+tokens the banded forward read 11.96 ms so against 15.13 with the grid over
+the whole sequence, the backward 18.43 against 24.79: PERF.md §6 PR 54).
+A step past a block's last live partner is dead and names that partner
+(``_last_live_kv`` / ``_first_live_q`` clamp on both sides:
+``_band_first_kv``, ``_band_last_q``).  The one-pass backward's dq rows are assigned at a q
+block's FIRST live kv block and written at its last.  Without a window
+every kernel lowers to the text it had before there was one.
+
 The segment compare is built only where ids were given or a length was
 padded (``segments``, a static fact of the call :func:`mha` works out);
 then every class keeps it, interior blocks too, and all-masked rows are
@@ -85,6 +105,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.ad_checkpoint
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -139,9 +160,42 @@ class BlockClasses(NamedTuple):
     strip: int
 
 
+class BandClasses(NamedTuple):
+    """:class:`BlockClasses` under a window: ``diagonal`` counts the blocks
+    the diagonal alone crosses, ``lower`` those the band's lower edge alone
+    crosses, ``both`` those that carry both edges; ``kv_steps`` / ``q_steps``
+    are the inner grid steps of the kv-inner / q-inner kernels."""
+
+    dead: int
+    interior: int
+    diagonal: int
+    strip: int
+    lower: int
+    both: int
+    kv_steps: int
+    q_steps: int
+
+
+def _pair_range(iq, ik, block_q, block_kv):
+    """The least and the most ``i - j`` over the pairs of block ``(iq,
+    ik)``."""
+    q_start, kv_start = iq * block_q, ik * block_kv
+    return q_start - (kv_start + block_kv - 1), q_start + block_q - 1 - kv_start
+
+
+def _block_edges(iq, ik, block_q, block_kv, window):
+    """``(live, diagonal, lower)`` of block ``(iq, ik)`` under a causal band
+    of ``window`` keys, Python, numpy or traced: whether any pair of it is
+    live, whether the diagonal crosses it, whether the band's lower edge
+    does.  A live block that neither crosses is interior."""
+    least, most = _pair_range(iq, ik, block_q, block_kv)
+    live = (most >= 0) & (least < window)
+    return live, live & (least < 0), live & (most >= window)
+
+
 def _block_class(iq, ik, block_q, block_kv, causal):
     """``(dead, interior)`` of block ``(iq, ik)``, Python or traced; a block
-    that is neither is diagonal."""
+    that is neither is diagonal.  Under a window: :func:`_block_edges`."""
     if not causal:
         return False, True
     q_start, kv_start = iq * block_q, ik * block_kv
@@ -162,13 +216,16 @@ def _strip_rows(block_q, block_kv) -> int:
     return rows
 
 
-def block_classes(sq, skv, block_q, block_kv, causal) -> BlockClasses:
+def block_classes(sq, skv, block_q, block_kv, causal, window=None):
     """The classes of an attention of these sizes, given as :func:`mha`'s
-    caller gives them (clamped and padded here as there)."""
+    caller gives them (clamped and padded here as there):
+    :class:`BlockClasses`, or :class:`BandClasses` under a ``window``."""
     block_q, block_kv, sq, skv = _blocks_and_padding(
         sq, skv, block_q, block_kv
     )
     nq, nk = sq // block_q, skv // block_kv
+    if window is not None:
+        return _band_classes(nq, nk, block_q, block_kv, window)
     if not causal:
         return BlockClasses(0, nq * nk, 0, 0)
     dead = interior = 0
@@ -182,24 +239,80 @@ def block_classes(sq, skv, block_q, block_kv, causal) -> BlockClasses:
     return BlockClasses(dead, interior, diagonal, strip)
 
 
-def _last_live_kv(iq, ik, block_q, block_kv, causal):
+def _band_classes(nq, nk, block_q, block_kv, window) -> BandClasses:
+    iq, ik = np.arange(nq)[:, None], np.arange(nk)[None, :]
+    live, diagonal, lower = _block_edges(iq, ik, block_q, block_kv, window)
+    both = diagonal & lower
+    edge = diagonal | lower
+    count = lambda blocks: int(blocks.sum())
+    return BandClasses(
+        dead=nq * nk - count(live), interior=count(live & ~edge),
+        diagonal=count(diagonal & ~both),
+        strip=_strip_rows(block_q, block_kv) if (diagonal & ~both).any()
+        else 0,
+        lower=count(lower & ~both), both=count(both),
+        kv_steps=max(1, int(live.sum(axis=1).max())),
+        q_steps=max(1, int(live.sum(axis=0).max())),
+    )
+
+
+def _band_first_kv(iq, block_q, block_kv, window):
+    """The first kv block a q block sees under a band: the one that holds
+    the first column its first row sees."""
+    return jnp.maximum(iq * block_q - (window - 1), 0) // block_kv
+
+
+def _band_last_q(ik, nq, block_q, block_kv, window):
+    """The last q block that sees a kv block under a band: the one that
+    holds the last row its last column is seen by."""
+    return jnp.minimum(
+        (ik * block_kv + block_kv - 1 + window - 1) // block_q, nq - 1
+    )
+
+
+def _last_live_kv(iq, ik, block_q, block_kv, causal, window=None):
     """The kv block a kv-inner grid step names: its own, or on a dead step
-    the q block's last live one, which is already in VMEM."""
+    the q block's last live one, which is already in VMEM (under a
+    ``window`` a step BELOW the band names the first live one)."""
     if not causal:
         return ik
-    return jnp.minimum(ik, (iq * block_q + block_q - 1) // block_kv)
+    ik = jnp.minimum(ik, (iq * block_q + block_q - 1) // block_kv)
+    if window is not None:
+        ik = jnp.maximum(ik, _band_first_kv(iq, block_q, block_kv, window))
+    return ik
 
 
-def _first_live_q(iq, ik, nq, block_q, block_kv, causal):
+def _first_live_q(iq, ik, nq, block_q, block_kv, causal, window=None):
     """The q block a q-inner grid step names: its own, or on a dead step
     the kv block's first live one, which the pipeline then fetches once and
     not for every dead step (JoyAI's layer 35.0 -> 31.2 ms on a v5e,
     PERF.md §6 PR 34).  A kv block past the last q row (more keys than
     queries) has no live q block: its steps stay on the last of the
-    ``nq``."""
+    ``nq``.  Under a ``window`` a step past the band names the kv block's
+    LAST live q block."""
     if not causal:
         return iq
-    return jnp.maximum(iq, jnp.minimum((ik * block_kv) // block_q, nq - 1))
+    iq = jnp.maximum(iq, jnp.minimum((ik * block_kv) // block_q, nq - 1))
+    if window is not None:
+        iq = jnp.minimum(
+            iq, _band_last_q(ik, nq, block_q, block_kv, window)
+        )
+    return iq
+
+
+def _kv_of_step(iq, step, block_q, block_kv, window):
+    """The kv block of a kv-inner grid step: without a window the step's
+    own number, under one the q block's first live kv block and on."""
+    if window is None:
+        return step
+    return _band_first_kv(iq, block_q, block_kv, window) + step
+
+
+def _q_of_step(ik, step, nq, block_q, block_kv, window):
+    """The q block of a q-inner grid step, as :func:`_kv_of_step`."""
+    if window is None:
+        return step
+    return jnp.minimum((ik * block_kv) // block_q, nq - 1) + step
 
 
 def _tiles(block_q, block_kv, strip):
@@ -213,15 +326,51 @@ def _tiles(block_q, block_kv, strip):
     ]
 
 
-def _for_live_class(iq, ik, compute, *, causal, block_q, block_kv, classes):
+def _for_live_class(
+    iq, ik, compute, *, causal, block_q, block_kv, classes, window=None,
+    q_blocks=0,
+):
     """Run ``compute(causal_offset, tiles)`` as block ``(iq, ik)``'s class
     asks: not at all (dead), over the whole block with no causal mask
     (interior, ``causal_offset`` None), or over a diagonal block's tiles,
     where row ``i`` of the block sees column ``j`` while ``i + causal_offset
-    >= j``.  A class the shapes do not hold gets no branch."""
+    >= j``.  A class the shapes do not hold gets no branch.  Under a
+    ``window``, ``compute(causal_offset, tiles, band_offset)``: an edge block
+    masks the edges that cross it, the lower one ``i + band_offset < j +
+    window``; ``q_blocks`` (the q-inner kernels give it) is where the q
+    blocks end, since a kv block's last steps may lie past the sequence."""
     whole = _tiles(block_q, block_kv, 0)
     if not causal:
         compute(None, whole)
+        return
+    if window is not None:
+        live, diagonal, lower = _block_edges(
+            iq, ik, block_q, block_kv, window
+        )
+        if q_blocks:
+            live, diagonal, lower = (
+                kind & (iq < q_blocks) for kind in (live, diagonal, lower)
+            )
+        offset = iq * block_q - ik * block_kv
+        # as below: blocks of one size meet the diagonal where iq == ik
+        on_diagonal = 0 if block_q == block_kv else offset
+        no = jnp.logical_not
+        if classes.interior:
+            pl.when(live & no(diagonal | lower))(
+                lambda: compute(None, whole)
+            )
+        if classes.diagonal:
+            pl.when(diagonal & no(lower))(lambda: compute(
+                on_diagonal, _tiles(block_q, block_kv, classes.strip)
+            ))
+        if classes.lower:
+            pl.when(lower & no(diagonal))(
+                lambda: compute(None, whole, offset)
+            )
+        if classes.both:
+            pl.when(lower & diagonal)(
+                lambda: compute(on_diagonal, whole, on_diagonal)
+            )
         return
     dead, interior = _block_class(iq, ik, block_q, block_kv, True)
     if classes.interior:
@@ -239,20 +388,32 @@ def _for_live_class(iq, ik, compute, *, causal, block_q, block_kv, classes):
 
 
 def _masked(x, fill, rows, cols, causal_offset, seg_q_ref, seg_kv_ref,
-            segments):
+            segments, band=None):
     """``x``, the scores or probabilities of tile ``(rows, cols)`` of a
     block, with ``fill`` where a row may not see a column; ``x`` itself
-    where nothing is masked."""
+    where nothing is masked.  ``band``: ``(band_offset, window)`` where the
+    band's lower edge crosses the block."""
     mask = None
-    if causal_offset is not None:
+    if causal_offset is not None or band is not None:
         shape = (rows.stop - rows.start, cols.stop - cols.start)
         row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
         col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    if causal_offset is not None:
         mask = row + (causal_offset + rows.start - cols.start) >= col
+    if band is not None:
+        offset, window = band
+        below = row + (offset + rows.start - cols.start - window) < col
+        mask = below if mask is None else jnp.logical_and(mask, below)
     if segments:
         seg = seg_q_ref[0, 0, rows][:, None] == seg_kv_ref[0, 0, cols][None, :]
         mask = seg if mask is None else jnp.logical_and(mask, seg)
     return x if mask is None else jnp.where(mask, x, fill)
+
+
+def _band(band_offset, window):
+    """``_masked``'s ``band`` of a block's tile: none where the lower edge
+    does not cross it."""
+    return None if band_offset is None else (band_offset, window)
 
 
 def _scores(q, k, scale):
@@ -277,13 +438,14 @@ def _fwd_kernel(
     seg_q_ref, seg_kv_ref, q_ref, k_ref, v_ref,
     o_ref, lse_ref, *state,
     causal: bool, scale: float, block_q: int, block_kv: int,
-    classes: BlockClasses, segments: bool,
+    classes: BlockClasses, segments: bool, window: Optional[int] = None,
 ):
     """``state`` is the running (m, l, acc) scratch of several kv blocks.
     With ONE kv block it is empty: every row is visited once, and its tile
     is normalised and written straight out."""
-    iq, ik = pl.program_id(2), pl.program_id(3)
+    iq, step = pl.program_id(2), pl.program_id(3)
     nk = pl.num_programs(3)
+    ik = _kv_of_step(iq, step, block_q, block_kv, window)
 
     def _write(rows, m, l, acc):
         safe_l = jnp.where(l == 0.0, 1.0, l)
@@ -294,19 +456,19 @@ def _fwd_kernel(
     if state:
         m_ref, l_ref, acc_ref = state
 
-        @pl.when(ik == 0)
+        @pl.when(step == 0)
         def _init():
             m_ref[:] = jnp.full_like(m_ref, NEG_INF)
             l_ref[:] = jnp.zeros_like(l_ref)
             acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    def _compute(causal_offset, tiles):
+    def _compute(causal_offset, tiles, band_offset=None):
         for rows, cols in tiles:
             v = v_ref[0, 0, cols, :]
             s = _scores(q_ref[0, 0, rows, :], k_ref[0, 0, cols, :], scale)
             s = _masked(
                 s, NEG_INF, rows, cols, causal_offset, seg_q_ref, seg_kv_ref,
-                segments,
+                segments, _band(band_offset, window),
             )
 
             m_new = jnp.max(s, axis=1)[:, None]  # [rows, 1]
@@ -314,9 +476,11 @@ def _fwd_kernel(
                 m_prev = m_ref[rows, 0][:, None]
                 m_new = jnp.maximum(m_prev, m_new)
             p = jnp.exp(s - m_new)
-            if segments:
+            if segments or band_offset is not None:
                 # All-masked rows keep m at NEG_INF; freeze them to avoid
-                # inf-inf.  Without segments column 0 is live for every row.
+                # inf-inf.  Without segments column 0 is live for every row
+                # (under a band, a row's first live block may hold none of
+                # the columns it sees).
                 p = jnp.where(m_new == NEG_INF, 0.0, p)
             l_new = jnp.sum(p, axis=1)[:, None]
             pv = jax.lax.dot(
@@ -335,11 +499,11 @@ def _fwd_kernel(
 
     _for_live_class(
         iq, ik, _compute, causal=causal, block_q=block_q, block_kv=block_kv,
-        classes=classes,
+        classes=classes, window=window,
     )
 
     if state:
-        @pl.when(ik == nk - 1)
+        @pl.when(step == nk - 1)
         def _finalize():
             _write(
                 slice(0, block_q), m_ref[:, 0][:, None], l_ref[:, 0][:, None],
@@ -349,7 +513,7 @@ def _fwd_kernel(
 
 def _flash_fwd(
     q, k, v, seg_q, seg_kv, *, causal, scale, block_q, block_kv,
-    segments,
+    segments, window=None,
 ):
     """q [B,Hq,S,D], k [B,Hkv,S,D], v [B,Hkv,S,Dv], seg [B,S] ->
     (o [B,Hq,S,Dv], lse).  ``Dv`` may differ from ``D`` (latent attention:
@@ -359,9 +523,15 @@ def _flash_fwd(
     hkv, skv, d_v = k.shape[1], k.shape[2], v.shape[3]
     group = hq // hkv
     nq, nk = sq // block_q, skv // block_kv
+    classes = block_classes(sq, skv, block_q, block_kv, causal, window)
+    if window is not None:
+        nk = classes.kv_steps
 
     def kv_block(iq, ik):
-        return _last_live_kv(iq, ik, block_q, block_kv, causal)
+        return _last_live_kv(
+            iq, _kv_of_step(iq, ik, block_q, block_kv, window),
+            block_q, block_kv, causal, window,
+        )
 
     def kv_rows(ib, ih, iq, ik):
         return (ib, ih // group, kv_block(iq, ik), 0)
@@ -369,9 +539,8 @@ def _flash_fwd(
     grid = (b, hq, nq, nk)
     kernel = functools.partial(
         _fwd_kernel, causal=causal, scale=scale,
-        block_q=block_q, block_kv=block_kv,
-        classes=block_classes(sq, skv, block_q, block_kv, causal),
-        segments=segments,
+        block_q=block_q, block_kv=block_kv, classes=classes,
+        segments=segments, window=window,
     )
     out_shape = [
         jax.ShapeDtypeStruct((b, hq, sq, d_v), q.dtype),
@@ -420,7 +589,7 @@ def _flash_fwd(
 def _recompute_p_ds(
     q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref,
     seg_q_ref, seg_kv_ref,
-    *, rows, cols, causal_offset, scale, segments,
+    *, rows, cols, causal_offset, scale, segments, band=None,
 ):
     """Shared backward math of one tile of a block (``_tiles``: the whole
     block, or a diagonal block's strip): probabilities p, score-grads ds,
@@ -446,7 +615,7 @@ def _recompute_p_ds(
     q = q_ref[0, 0, rows, :]
     p = _masked(
         jnp.exp(_scores(q, k, scale) - lse), 0.0,
-        rows, cols, causal_offset, seg_q_ref, seg_kv_ref, segments,
+        rows, cols, causal_offset, seg_q_ref, seg_kv_ref, segments, band,
     )
     dp = jax.lax.dot_general(
         do, v, (((1,), (1,)), ((), ())),
@@ -460,22 +629,24 @@ def _bwd_dq_kernel(
     seg_q_ref, seg_kv_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref,
     dq_ref, dq_acc_ref,
     *, causal: bool, scale: float, block_q: int, block_kv: int,
-    classes: BlockClasses, segments: bool,
+    classes: BlockClasses, segments: bool, window: Optional[int] = None,
 ):
-    iq, ik = pl.program_id(2), pl.program_id(3)
+    iq, step = pl.program_id(2), pl.program_id(3)
     nk = pl.num_programs(3)
+    ik = _kv_of_step(iq, step, block_q, block_kv, window)
 
-    @pl.when(ik == 0)
+    @pl.when(step == 0)
     def _init():
         dq_acc_ref[:] = jnp.zeros_like(dq_acc_ref)
 
-    def _compute(causal_offset, tiles):
+    def _compute(causal_offset, tiles, band_offset=None):
         for rows, cols in tiles:
             _, ds, _, k, _ = _recompute_p_ds(
                 q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref,
                 seg_q_ref, seg_kv_ref,
                 rows=rows, cols=cols, causal_offset=causal_offset,
                 scale=scale, segments=segments,
+                band=_band(band_offset, window),
             )
             dq_acc_ref[rows, :] += jax.lax.dot(
                 ds, k, preferred_element_type=jnp.float32
@@ -483,10 +654,10 @@ def _bwd_dq_kernel(
 
     _for_live_class(
         iq, ik, _compute, causal=causal, block_q=block_q, block_kv=block_kv,
-        classes=classes,
+        classes=classes, window=window,
     )
 
-    @pl.when(ik == nk - 1)
+    @pl.when(step == nk - 1)
     def _finalize():
         dq_ref[0, 0] = dq_acc_ref[:].astype(dq_ref.dtype)
 
@@ -506,32 +677,35 @@ def _bwd_dkv_kernel(
     seg_q_ref, seg_kv_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref,
     dk_ref, dv_ref, dk_acc_ref, dv_acc_ref,
     *, causal: bool, scale: float, block_q: int, block_kv: int,
-    classes: BlockClasses, segments: bool,
+    classes: BlockClasses, segments: bool, window: Optional[int] = None,
+    q_blocks: int = 0,
 ):
-    ik, iq = pl.program_id(2), pl.program_id(3)  # note: kv outer, q inner
+    ik, step = pl.program_id(2), pl.program_id(3)  # note: kv outer, q inner
     nq = pl.num_programs(3)
+    iq = _q_of_step(ik, step, q_blocks, block_q, block_kv, window)
 
-    @pl.when(iq == 0)
+    @pl.when(step == 0)
     def _init():
         dk_acc_ref[:] = jnp.zeros_like(dk_acc_ref)
         dv_acc_ref[:] = jnp.zeros_like(dv_acc_ref)
 
-    def _compute(causal_offset, tiles):
+    def _compute(causal_offset, tiles, band_offset=None):
         for rows, cols in tiles:
             p, ds, q, _, do = _recompute_p_ds(
                 q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref,
                 seg_q_ref, seg_kv_ref,
                 rows=rows, cols=cols, causal_offset=causal_offset,
                 scale=scale, segments=segments,
+                band=_band(band_offset, window),
             )
             _add_dk_dv(dk_acc_ref, dv_acc_ref, cols, p, ds, q, do)
 
     _for_live_class(
         iq, ik, _compute, causal=causal, block_q=block_q, block_kv=block_kv,
-        classes=classes,
+        classes=classes, window=window, q_blocks=q_blocks,
     )
 
-    @pl.when(iq == nq - 1)
+    @pl.when(step == nq - 1)
     def _finalize():
         dk_ref[0, 0] = dk_acc_ref[:].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_acc_ref[:].astype(dv_ref.dtype)
@@ -541,7 +715,8 @@ def _bwd_fused_kernel(
     seg_q_ref, seg_kv_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref,
     dq_ref, dk_ref, dv_ref, dk_acc_ref, dv_acc_ref, *dq_acc,
     causal: bool, scale: float, block_q: int, block_kv: int,
-    classes: BlockClasses, segments: bool,
+    classes: BlockClasses, segments: bool, window: Optional[int] = None,
+    q_blocks: int = 0,
 ):
     """One-pass backward: s/p computed once feed dq, dk AND dv.
 
@@ -562,10 +737,11 @@ def _bwd_fused_kernel(
     at the q block's last live kv block.  With ONE kv block (``dq_acc``
     empty) every dq block is visited once and written straight out.
     """
-    ik, iq = pl.program_id(2), pl.program_id(3)
+    ik, step = pl.program_id(2), pl.program_id(3)
     nq = pl.num_programs(3)
+    iq = _q_of_step(ik, step, q_blocks, block_q, block_kv, window)
 
-    @pl.when(iq == 0)
+    @pl.when(step == 0)
     def _init_kv():
         dk_acc_ref[:] = jnp.zeros_like(dk_acc_ref)
         dv_acc_ref[:] = jnp.zeros_like(dv_acc_ref)
@@ -573,14 +749,21 @@ def _bwd_fused_kernel(
     q_start = iq * block_q
 
     # ik == 0 always runs under causal (kv_start 0), so the dq init below
-    # is guaranteed to execute for every row of every q block.
-    def _compute(causal_offset, tiles):
+    # is guaranteed to execute for every row of every q block (under a
+    # window: the q block's first live kv block, whose every row is worked,
+    # masked or not).
+    first = 0
+    if window is not None:
+        first = _band_first_kv(iq, block_q, block_kv, window)
+
+    def _compute(causal_offset, tiles, band_offset=None):
         for rows, cols in tiles:
             p, ds, q, k, do = _recompute_p_ds(
                 q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref,
                 seg_q_ref, seg_kv_ref,
                 rows=rows, cols=cols, causal_offset=causal_offset,
                 scale=scale, segments=segments,
+                band=_band(band_offset, window),
             )
             _add_dk_dv(dk_acc_ref, dv_acc_ref, cols, p, ds, q, do)
             dq = jax.lax.dot(ds, k, preferred_element_type=jnp.float32)
@@ -593,11 +776,11 @@ def _bwd_fused_kernel(
         height = rows.stop - rows.start
         at = pl.ds(pl.multiple_of(q_start + rows.start, height), height)
 
-        @pl.when(ik == 0)
+        @pl.when(ik == first)
         def _first():
             dq_acc_ref[at, :] = dq
 
-        @pl.when(ik > 0)
+        @pl.when(ik > first)
         def _later():
             dq_acc_ref[at, :] += dq
 
@@ -611,10 +794,10 @@ def _bwd_fused_kernel(
 
     _for_live_class(
         iq, ik, _compute, causal=causal, block_q=block_q, block_kv=block_kv,
-        classes=classes,
+        classes=classes, window=window, q_blocks=q_blocks,
     )
 
-    @pl.when(iq == nq - 1)
+    @pl.when(step == nq - 1)
     def _finalize_kv():
         dk_ref[0, 0] = dk_acc_ref[:].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_acc_ref[:].astype(dv_ref.dtype)
@@ -642,17 +825,22 @@ def _fused_bwd_vmem_bytes(sq, d, d_v, block_q, block_kv, dtype) -> int:
 
 def _flash_bwd_fused(
     q, k, v, seg_q, seg_kv, o, lse, do,
-    *, causal, scale, block_q, block_kv, segments,
+    *, causal, scale, block_q, block_kv, segments, window=None,
 ):
     b, hq, sq, d = q.shape
     hkv, skv, d_v = k.shape[1], k.shape[2], v.shape[3]
     group = hq // hkv
     nq, nk = sq // block_q, skv // block_kv
+    classes = block_classes(sq, skv, block_q, block_kv, causal, window)
+    q_steps = nq if window is None else classes.q_steps
 
     lse_l = jnp.broadcast_to(lse[..., None], (*lse.shape, _STAT))
 
     def q_block(ik, iq):
-        return _first_live_q(iq, ik, nq, block_q, block_kv, causal)
+        return _first_live_q(
+            _q_of_step(ik, iq, nq, block_q, block_kv, window), ik, nq,
+            block_q, block_kv, causal, window,
+        )
 
     def q_rows(ib, ih, ik, iq):
         return (ib, ih, q_block(ik, iq), 0)
@@ -686,11 +874,10 @@ def _flash_bwd_fused(
     dq, dk, dv = pl.pallas_call(
         functools.partial(
             _bwd_fused_kernel, causal=causal, scale=scale,
-            block_q=block_q, block_kv=block_kv,
-            classes=block_classes(sq, skv, block_q, block_kv, causal),
-            segments=segments,
+            block_q=block_q, block_kv=block_kv, classes=classes,
+            segments=segments, window=window, q_blocks=nq,
         ),
-        grid=(b, hq, nk, nq),
+        grid=(b, hq, nk, q_steps),
         in_specs=[
             pl.BlockSpec(
                 (1, 1, block_q),
@@ -730,25 +917,31 @@ def _flash_bwd_fused(
 
 def _flash_bwd(
     q, k, v, seg_q, seg_kv, o, lse, do,
-    *, causal, scale, block_q, block_kv, segments,
+    *, causal, scale, block_q, block_kv, segments, window=None,
 ):
     b, hq, sq, d = q.shape
     hkv, skv, d_v = k.shape[1], k.shape[2], v.shape[3]
     group = hq // hkv
     nq, nk = sq // block_q, skv // block_kv
+    classes = block_classes(sq, skv, block_q, block_kv, causal, window)
+    kv_steps, q_steps = nk, nq
+    if window is not None:
+        kv_steps, q_steps = classes.kv_steps, classes.q_steps
 
     lse_l = jnp.broadcast_to(lse[..., None], (*lse.shape, _STAT))
 
     common_in = [seg_q, seg_kv, q, k, v, do, lse_l, o]
     static = dict(
         causal=causal, scale=scale, block_q=block_q, block_kv=block_kv,
-        classes=block_classes(sq, skv, block_q, block_kv, causal),
-        segments=segments,
+        classes=classes, segments=segments, window=window,
     )
 
     # dq: kv inner, so a dead step parks the kv side (as the forward's).
     def kv_block(iq, ik):
-        return _last_live_kv(iq, ik, block_q, block_kv, causal)
+        return _last_live_kv(
+            iq, _kv_of_step(iq, ik, block_q, block_kv, window),
+            block_q, block_kv, causal, window,
+        )
 
     def q_rows(ib, ih, iq, ik):
         return (ib, ih, iq, 0)
@@ -758,7 +951,7 @@ def _flash_bwd(
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, **static),
-        grid=(b, hq, nq, nk),
+        grid=(b, hq, nq, kv_steps),
         in_specs=[
             pl.BlockSpec((1, 1, block_q), lambda ib, ih, iq, ik: (ib, 0, iq)),
             pl.BlockSpec(
@@ -781,7 +974,10 @@ def _flash_bwd(
     # dk/dv: one pass per q-head; accumulated per kv head afterwards (GQA).
     # q inner, so a dead step parks the q side (as the one pass's).
     def q_block(ik, iq):
-        return _first_live_q(iq, ik, nq, block_q, block_kv, causal)
+        return _first_live_q(
+            _q_of_step(ik, iq, nq, block_q, block_kv, window), ik, nq,
+            block_q, block_kv, causal, window,
+        )
 
     def parked_q_rows(ib, ih, ik, iq):
         return (ib, ih, q_block(ik, iq), 0)
@@ -790,8 +986,8 @@ def _flash_bwd(
         return (ib, ih // group, ik, 0)
 
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, **static),
-        grid=(b, hq, nk, nq),
+        functools.partial(_bwd_dkv_kernel, q_blocks=nq, **static),
+        grid=(b, hq, nk, q_steps),
         in_specs=[
             pl.BlockSpec(
                 (1, 1, block_q),
@@ -850,24 +1046,28 @@ def backward_path(sq, skv, d, d_v, block_q, block_kv, dtype) -> str:
 
 
 @functools.partial(
-    jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9)
+    jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10)
 )
 def _flash_core(
-    q, k, v, seg_q, seg_kv, causal, scale, block_q, block_kv, segments
+    q, k, v, seg_q, seg_kv, causal, scale, block_q, block_kv, segments,
+    window=None,
 ):
     o, _ = _flash_fwd(
         q, k, v, seg_q, seg_kv, causal=causal, scale=scale,
         block_q=block_q, block_kv=block_kv, segments=segments,
+        window=window,
     )
     return o
 
 
 def _flash_core_fwd(
-    q, k, v, seg_q, seg_kv, causal, scale, block_q, block_kv, segments
+    q, k, v, seg_q, seg_kv, causal, scale, block_q, block_kv, segments,
+    window=None,
 ):
     o, lse = _flash_fwd(
         q, k, v, seg_q, seg_kv, causal=causal, scale=scale,
         block_q=block_q, block_kv=block_kv, segments=segments,
+        window=window,
     )
     # Named remat saveables: under the "flash_res" policy (models/transformer)
     # the first forward saves o+lse and the backward replay DCEs the whole
@@ -879,7 +1079,7 @@ def _flash_core_fwd(
 
 
 def _flash_core_bwd(
-    causal, scale, block_q, block_kv, segments, residuals, g
+    causal, scale, block_q, block_kv, segments, window, residuals, g
 ):
     q, k, v, seg_q, seg_kv, o, lse = residuals
     path = backward_path(
@@ -890,6 +1090,7 @@ def _flash_core_bwd(
     dq, dk, dv = impl(
         q, k, v, seg_q, seg_kv, o, lse, g, causal=causal, scale=scale,
         block_q=block_q, block_kv=block_kv, segments=segments,
+        window=window,
     )
     return dq, dk, dv, None, None
 
@@ -907,6 +1108,7 @@ def mha(
     block_q: int = 512,
     block_kv: int = 512,
     scale: Optional[float] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Flash attention on [B, S, H, D] tensors (layout of models/attention).
 
@@ -917,10 +1119,20 @@ def mha(
     ``segment_ids`` [B, S] activates packed-sequence masking: token i attends
     token j only if segment_ids[i] == segment_ids[j] (and j <= i when
     causal).  Pad positions use segment id -1 injected for padded tails.
+
+    ``window`` (causal self-attention only) keeps the band ``0 <= i - j <
+    window``: a query sees itself and the ``window - 1`` tokens before it,
+    and blocks outside the band are neither computed nor walked.
     """
     b, sq, hq, d = q.shape
     skv = k.shape[1]
     scale = d ** -0.5 if scale is None else scale
+    if window is not None and (not causal or sq != skv or window < 1):
+        raise ValueError(
+            "window needs causal self-attention (as many keys as queries) "
+            f"and at least one key, got causal={causal}, {sq} queries, "
+            f"{skv} keys, window={window}"
+        )
 
     block_q, block_kv, sq_p, skv_p = _blocks_and_padding(
         sq, skv, block_q, block_kv
@@ -944,5 +1156,6 @@ def mha(
     o = _flash_core(
         qt, kt, vt, seg_q[:, None, :], seg_kv[:, None, :],
         causal, scale, block_q, block_kv, segments,
+        None if window is None else int(window),
     )
     return o[:, :, :sq].transpose(0, 2, 1, 3)

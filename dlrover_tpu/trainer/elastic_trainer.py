@@ -30,6 +30,7 @@ import optax
 from dlrover_tpu.common import faults, telemetry
 from dlrover_tpu.common.log import default_logger as logger
 from dlrover_tpu.common.retry import RetryError, RetryPolicy
+from dlrover_tpu.models import attention as attention_lib
 from dlrover_tpu.models import gated_conv
 from dlrover_tpu.models import linear_attention
 from dlrover_tpu.models import mamba2
@@ -49,7 +50,7 @@ _NO_BATCH = object()
 # until a report reads them.
 _STATS_KEYS = (
     "moe_stats", moe_lib.SHARE_STATS_NAME, linear_attention.STATS_NAME,
-    mamba2.STATS_NAME, gated_conv.STATS_NAME,
+    mamba2.STATS_NAME, gated_conv.STATS_NAME, attention_lib.STATS_NAME,
 )
 
 _PROCESS_START_BOOKED = False
@@ -615,13 +616,47 @@ class ElasticTrainer:
             d_v = cfg.v_head_dim
         seq = self.config.seq_len
         blocks = (cfg.flash_block_q, cfg.flash_block_kv)
+        backward = flash_attention.backward_path(
+            seq, seq, d, d_v, *blocks, cfg.dtype
+        )
+        classes = flash_attention.block_classes(
+            seq, seq, *blocks, causal=True
+        )
+        if not cfg.num_sliding_layers:
+            return {
+                "flash_backward": backward,
+                "flash_blocks": classes._asdict(),
+            }
+        # A model with windowed layers: the counts of each kind, the steps
+        # its forward's grid really makes a (batch, head) (``grid``), and
+        # ``live_share``, the live steps among them.
+        band = flash_attention.block_classes(
+            seq, seq, *blocks, causal=True, window=cfg.sliding_window
+        )
+        block_q, _, padded, _ = flash_attention._blocks_and_padding(
+            seq, seq, *blocks
+        )
+
+        def facts(c, edge, grid):
+            live = c.interior + edge
+            return {
+                "live": live, "interior": c.interior, "edge": edge,
+                "dead": c.dead, "grid": grid, "strip": c.strip,
+                "live_share": live / grid, "backward": backward,
+            }
+
         return {
-            "flash_backward": flash_attention.backward_path(
-                seq, seq, d, d_v, *blocks, cfg.dtype
-            ),
-            "flash_blocks": flash_attention.block_classes(
-                seq, seq, *blocks, causal=True
-            )._asdict(),
+            "flash_backward": backward,
+            "flash_blocks": {
+                "full_attention": facts(
+                    classes, classes.diagonal,
+                    classes.dead + classes.interior + classes.diagonal,
+                ),
+                "sliding_attention": facts(
+                    band, band.diagonal + band.lower + band.both,
+                    padded // block_q * band.kv_steps,
+                ),
+            },
         }
 
     def _build_train(
@@ -1411,6 +1446,7 @@ class ElasticTrainer:
             ) if v is not None
         ]
         self._report_conv(metrics, step)
+        self._report_attn(metrics, step)
         state_absmax = None if not absmaxes else (
             float("nan") if any(v != v for v in absmaxes) else max(absmaxes)
         )
@@ -1573,6 +1609,33 @@ class ElasticTrainer:
         telemetry.event(
             "conv", step=step, layers=self.model_config.num_conv_layers,
             out_absmax=read.pop("state_absmax"), **read,
+        )
+
+    def _report_attn(self, metrics, step: int) -> None:
+        """The softmax attentions of a model with windowed layers, as an
+        ``attn`` event: the layers of each kind, the window, and
+        ``score_bound``: the bound of the largest ``|q k^T| * scale``
+        before the mask (``models/attention.score_bound``: the longest
+        query row times the longest key row of a head, which the exact
+        maximum cannot pass) over the layers of each kind and over both,
+        so that a rotation's factor on the full layers' scores shows."""
+        stats = metrics.get(attention_lib.STATS_NAME)
+        if stats is None or step % self.config.report_every:
+            return
+        with pipeline_counters().host_block(
+            attention_lib.STATS_NAME, steps=(step,)
+        ):
+            full, sliding = (
+                float(v) for v in np.asarray(jax.device_get(stats))
+            )
+        cfg = self.model_config
+        telemetry.event(
+            "attn", step=step, full_layers=cfg.num_full_layers,
+            sliding_layers=cfg.num_sliding_layers,
+            window=cfg.sliding_window, full_score_bound=full,
+            sliding_score_bound=sliding,
+            score_bound=float("nan") if full != full or sliding != sliding
+            else max(full, sliding),
         )
 
     def _state_stats(

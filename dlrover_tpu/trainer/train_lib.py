@@ -24,6 +24,7 @@ from flax.training import train_state as flax_train_state
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from dlrover_tpu.common.log import default_logger as logger
+from dlrover_tpu.models import attention as attention_lib
 from dlrover_tpu.models import gated_conv
 from dlrover_tpu.models import linear_attention
 from dlrover_tpu.models import mamba2
@@ -529,6 +530,10 @@ def _layer_stats(sown, router_loads: bool = False) -> Dict[str, Any]:
         vectors = _sown_vectors(sown, name)
         if vectors is not None:
             out[name] = linear_attention.fold_stats(vectors)
+    scores = _sown_vectors(sown, attention_lib.STATS_NAME)
+    if scores is not None:
+        # [a full layer's score bound, a windowed layer's]: the largest
+        out[attention_lib.STATS_NAME] = scores.max(axis=0)
     if router_loads:
         out[ROUTER_LOADS] = _router_loads(sown)
     return out
@@ -797,7 +802,7 @@ def build_sharded_train(
     model_config = getattr(model, "config", None)
     sows_stats = bool(
         getattr(model_config, "num_experts", 0)
-        or {"linear_attention", "ssm", "conv"}
+        or {"linear_attention", "ssm", "conv", "sliding_attention"}
         & set(getattr(model_config, "layer_pattern", ()))
     )
     # The DeepSeek-V3 family: a multi-token-prediction module whose
@@ -815,7 +820,7 @@ def build_sharded_train(
         """One forward pass -> (weighted CE sum, token count, aux loss,
         layer statistics).  The last is ``_layer_stats`` of what the
         layers sowed (``moe_stats``, ``linear_attn_stats``, ``ssm_stats``,
-        ``conv_stats``),
+        ``conv_stats``, ``attn_stats``),
         folded over
         layers and whatever axes the scan and the sow stack, under
         ``stop_gradient``; empty for a model that sows none.  A model with

@@ -68,17 +68,35 @@ def compile_for_chip(v5e, monkeypatch):
 BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
 
 
-def _flash(block):
+def _flash(block, window=None):
     from dlrover_tpu.ops import flash_attention as fa
 
     def loss(q, k, v, ids=None):
         out = fa.mha(
             q, k, v, causal=True, segment_ids=ids,
-            block_q=block, block_kv=block,
+            block_q=block, block_kv=block, window=window,
         )
         return out.astype(F32).sum()
 
     return jax.grad(loss, argnums=(0, 1, 2))
+
+
+def _flash_band_split(block, window):
+    """The banded forward and the SPLIT backward pair (a length past the
+    one-pass backward's bound would take it; here it is asked for)."""
+    from dlrover_tpu.ops import flash_attention as fa
+
+    def fn(q, k, v, do):
+        b, _, s, _ = q.shape
+        ids = jnp.zeros((b, 1, s), I32)
+        static = dict(
+            causal=True, scale=q.shape[-1] ** -0.5, block_q=block,
+            block_kv=block, segments=False, window=window,
+        )
+        o, lse = fa._flash_fwd(q, k, v, ids, ids, **static)
+        return fa._flash_bwd(q, k, v, ids, ids, o, lse, do, **static)
+
+    return fn
 
 
 def _norm_grad(name, with_bias):
@@ -224,6 +242,9 @@ def _flash_longest(seq, d, d_v=None):
     return [qk, qk, ((1, seq, 1, d_v or d), BF16)]
 
 
+MELLUM_QKV = (
+    [((1, 32768, 32, 128), BF16)] + [((1, 32768, 4, 128), BF16)] * 2
+)
 LEAF = ((1600, 6400), F32)                 # the 1.5B MLP wi kernel
 CACHE = ((65536, 128), F32)
 
@@ -403,6 +424,28 @@ CASES = [
     # projection, 3 taps, the forward kernel and the backward kernel
     ("gated_conv_lfm2", _gated_conv,
      [((4, 8192, 6144), BF16), ((3, 2048), BF16)], {}, 2),
+    # Mellum2-12B-A2.5B's cell, 1 x 32768 tokens, 32 query heads over 4
+    # key/value heads of 128: the full layers' kernels (32 kv blocks, the
+    # one-pass backward's dq scratch 32 MiB), the banded ones under a window
+    # of 1,024 keys at blocks of 1,024 and of 512 (two and three steps a
+    # block's inner grid) with both backward paths; its share's grouped
+    # GEMMs at the expert width 896 (7 lane tiles) and d_model 2,304 (18
+    # lane tiles: plain rows) over a budget of 84,096 rows for 16 of 64
+    # experts; a token's 8 rows of 2,304 padded to 24 lane tiles at the
+    # fetch-and-sum kernel's door
+    ("flash_mellum_full_32k", lambda: _flash(1024), MELLUM_QKV, {}, 2),
+    ("flash_mellum_band_1024", lambda: _flash(1024, 1024), MELLUM_QKV, {}, 2),
+    ("flash_mellum_band_512", lambda: _flash(512, 1024), MELLUM_QKV, {}, 2),
+    ("flash_mellum_band_split", lambda: _flash_band_split(1024, 1024),
+     [((1, 32, 32768, 128), BF16)] + [((1, 4, 32768, 128), BF16)] * 2
+     + [((1, 32, 32768, 128), BF16)], {}, 3),
+    ("grouped_matmul_mellum_wi", lambda: _grouped_matmul(False, True),
+     [((84096, 2304), BF16), ((16, 2304, 896), BF16), ((16,), I32)], {}, 3),
+    ("grouped_matmul_mellum_wo", lambda: _grouped_matmul(False, True),
+     [((84096, 896), BF16), ((16, 896, 2304), BF16), ((16,), I32)], {}, 3),
+    ("row_gather_sum_mellum_padded_live_weighted",
+     lambda: _row_gather_sum_padded(True),
+     [((84096, 2304), BF16), ((32768, 8), I32), ((32768, 8), F32)], {}, 1),
     ("quantize_dequantize", _quant_roundtrip, [LEAF], {}, 2),
     ("q8_adam", lambda: _adam_update("q8_adam"), [LEAF, LEAF], {}, 1),
     ("q4_adam", lambda: _adam_update("q4_adam"), [LEAF, LEAF], {}, 1),

@@ -45,6 +45,18 @@ hold the whole expert matrix in VMEM itself, which no call can count on).
 K is therefore split only where the whole-K strip fits under no VMEM limit
 the kernel may ask for (``_VMEM_CAP``; Mixtral's 14336 x 2048 under
 ``grouped``).
+
+The ``dW`` call asks for more before it tiles, too (:func:`plan_dw_tiles`).
+Its grid is (K tile, M tile, row block): an expert's ``[tk, tm]`` tile stays
+in a float32 accumulator across its row blocks, x crosses HBM once an M
+tile, dy once a K tile, and every (tile, row block) pair is a grid step of
+0.3-0.45 us before any work (on the chip: LFM2's 2048 x 1792 in seven, two
+and one tile 2.19, 1.58 and 1.48 ms a call; PERF.md section 6, PR 55).  The
+default budget cut Mellum2's 2304 x 896 into seven tiles of 128 lanes, 2.7
+ms of HBM time for 1.4 ms of MXU work (3.91 ms a call; whole 1.77), so the
+whole tile is taken wherever ``_VMEM_CAP`` holds it, and the fewest tiles
+where it does not.  The sum's order is the same whatever the tile, and so
+is every number of dW.
 """
 
 from __future__ import annotations
@@ -69,14 +81,19 @@ from dlrover_tpu.ops.row_gather_sum import LANES, tile_rows
 # default, not the chip's VMEM (a v5e core has 128 MiB): a forward or dx
 # call whose whole-K strip overflows this budget asks for more
 # (``_VMEM_CAP``) before it splits K, because a split K costs the weights
-# once a ROW BLOCK and not once an expert (the module docstring).  A whole
-# [K, M] expert block overflows the budget at MoE widths (K=1600, M=3200),
-# so the kernels tile M, and K where they must.
+# once a ROW BLOCK and not once an expert (the module docstring).  The dw
+# call asks for more before it tiles as well (``plan_dw_tiles``): its tiles
+# cost x and dy a pass over HBM each and the grid its steps; Mosaic accepts
+# 17.8 MiB for Mellum2's whole [2304, 896] tile (18.1 counted) and 30.3 for
+# LFM2's [2048, 1792] (30.8) for the described chip.  A whole [K, M] expert
+# block overflows the budget at MoE widths (K=1600, M=3200), so under the
+# default limit the kernels tile M, and K where they must.
 _TILE_BYTES = 8 * 2**20
 
-# The most VMEM a forward/dx call may ask for (``vmem_limit_bytes``) to keep
-# a whole-K strip resident: a quarter of a v5e core's 128 MiB, twice the
-# default scoped limit.  ``_strip_vmem_bytes`` counts LFM2's [1792, 2048]
+# The most VMEM a call may ask for (``vmem_limit_bytes``), a forward/dx call
+# to keep a whole-K strip resident, a dw call to keep its tile whole: a
+# quarter of a v5e core's 128 MiB, twice the default scoped limit.
+# ``_strip_vmem_bytes`` counts LFM2's [1792, 2048]
 # bf16 strip at 18.3 MiB (14.0 of them the strip twice; Mosaic accepts 16.4
 # for the described chip) and Nemotron's [2688, 1856] at 24.5 (19.7; 21.8);
 # Mixtral's [14336, 2048] would take 125.5 and keeps the K-split.  What a
@@ -88,19 +105,22 @@ _TILE_BYTES = 8 * 2**20
 _VMEM_CAP = 32 * 2**20
 
 
-def _lane_tile(dim: int, limit: int, quantum: int = LANES) -> int:
-    """Widest divisor of ``dim`` that is a multiple of ``quantum`` (128
-    lanes) and at most ``limit`` (at least one quantum); ``dim`` itself
-    when it is no multiple of it — such a dim can only be a block's full
-    extent."""
+def _lane_tiles(dim: int, quantum: int = LANES):
+    """Every block width ``dim`` can be cut into, narrowest first: its
+    divisors that are multiples of ``quantum`` (128 lanes), or ``dim`` alone
+    when it is no multiple of it (such a dim can only be a block's full
+    extent)."""
     if dim % quantum:
-        return dim
+        return [dim]
     groups = dim // quantum
-    fits = [
-        t for t in range(1, groups + 1)
-        if groups % t == 0 and t * quantum <= limit
-    ]
-    return quantum * max(fits, default=1)
+    return [quantum * t for t in range(1, groups + 1) if groups % t == 0]
+
+
+def _lane_tile(dim: int, limit: int, quantum: int = LANES) -> int:
+    """The widest of ``_lane_tiles`` that is at most ``limit`` (the
+    narrowest where none is)."""
+    widths = _lane_tiles(dim, quantum)
+    return max((t for t in widths if t <= limit), default=widths[0])
 
 
 def _quantum(tiled: bool, dtype) -> int:
@@ -110,12 +130,17 @@ def _quantum(tiled: bool, dtype) -> int:
 
 
 class Tiles(NamedTuple):
-    """A forward/dx call's weight block ``[tk, tm]`` and the scoped VMEM it
-    has to ask for (``None``: the default limit holds it)."""
+    """A call's weight block (dw: its tile of dw) ``[tk, tm]`` and the scoped
+    VMEM it has to ask for (``None``: the default limit holds it)."""
 
     tk: int
     tm: int
     vmem_limit_bytes: Optional[int]
+
+
+def _padded(n: int, tile: int) -> int:
+    """``n`` rounded up to whole ``tile``s, as an array's side lies in VMEM."""
+    return -(-n // tile) * tile
 
 
 def _strip_vmem_bytes(k, tm, dtype, block_rows):
@@ -127,12 +152,9 @@ def _strip_vmem_bytes(k, tm, dtype, block_rows):
     two f32 blocks of the output's shape (the dot's result and its cast's
     operand)."""
 
-    def padded(n, tile):
-        return -(-n // tile) * tile
-
     size = jnp.dtype(dtype).itemsize
-    k_lanes, tm_lanes = padded(k, LANES), padded(tm, LANES)
-    strip = 2 * padded(k, tile_rows(dtype)) * tm_lanes * size
+    k_lanes, tm_lanes = _padded(k, LANES), _padded(tm, LANES)
+    strip = 2 * _padded(k, tile_rows(dtype)) * tm_lanes * size
     x_blocks = 3 * block_rows * k_lanes * size
     out_blocks = 2 * block_rows * tm_lanes * size
     f32_blocks = 2 * block_rows * tm_lanes * 4
@@ -182,6 +204,76 @@ def expert_strips(
     each = 3 if gated else 2
     split = each * ((into.tk < d_model) + (out_of.tk < d_ff))
     return f"split_k:{split}/{2 * each}" if split else "resident"
+
+
+def _dw_vmem_bytes(tk, tm, dtype, block_rows):
+    """VMEM a dW call takes with ``[tk, tm]`` as its tile, as the arrays lie
+    there: the float32 accumulator, the double-buffered output tile, and
+    three of each row block of x and dy: two in flight and the body's own
+    (x transposed for the contraction over rows, a row-tiled block
+    reshaped).  The dot adds into the accumulator in place: Mosaic keeps no
+    second float32 tile.  Its refusal line for the described chip states
+    17.31 MiB for Mellum2's whole [2304, 896] before its own temporaries
+    (this count less the third blocks, to the byte) and it accepts a limit
+    of 17.8 (counted: 18.1); 30.3 for LFM2's [2048, 1792] (30.8), 17.9 for
+    OLMoE's [2048, 1024] (18.3), 13.6 for Nemotron's [896, 1856] (15.2)."""
+
+    size = jnp.dtype(dtype).itemsize
+    tk_lanes, tm_lanes = _padded(tk, LANES), _padded(tm, LANES)
+    acc = _padded(tk, 8) * tm_lanes * 4
+    out_tiles = 2 * _padded(tk, tile_rows(dtype)) * tm_lanes * size
+    row_blocks = 3 * block_rows * (tk_lanes + tm_lanes) * size
+    return acc + out_tiles + row_blocks
+
+
+def plan_dw_tiles(
+    k: int, m: int, x_tiled: bool, out_tiled: bool, dtype,
+    block_rows: int = 128,
+) -> Tiles:
+    """The ``[tk, tm]`` tile of a dW call ``x[N, k]^T @ dy[N, m]`` (x
+    row-tiled if ``x_tiled``, dy if ``out_tiled``), which the kernel and the
+    ``compile`` event's ``gmm_dw_tiles`` both ask.
+
+    The grid is (K tiles, M tiles, row blocks), so x crosses HBM once an M
+    tile and dy once a K tile, and every (tile, row block) pair is a grid
+    step.  Where the whole ``[k, m]`` tile fits ``_TILE_BYTES`` the call
+    asks for nothing.  Else it asks for what the fewest tiles need that fit
+    ``_VMEM_CAP`` (the whole tile where that does), cut along the side whose
+    re-read operand is the narrower; and where no tile fits the cap, it
+    keeps the tiles of the default limit."""
+    k_quantum = _quantum(x_tiled, dtype)
+    m_quantum = _quantum(out_tiled, dtype)
+    tile_elems = _TILE_BYTES // (4 + 2 * jnp.dtype(dtype).itemsize)
+    tm = _lane_tile(m, tile_elems // k, m_quantum)
+    tk = _lane_tile(k, tile_elems // tm, k_quantum)
+    if (tk, tm) == (k, m):
+        return Tiles(k, m, None)
+    fits = []
+    for a in _lane_tiles(k, k_quantum):
+        for b in _lane_tiles(m, m_quantum):
+            need = _dw_vmem_bytes(a, b, dtype, block_rows)
+            if need <= _VMEM_CAP:
+                # fewest grid steps, then fewest re-read bytes a row block
+                k_tiles, m_tiles = k // a, m // b
+                fits.append(
+                    (k_tiles * m_tiles, m_tiles * k + k_tiles * m, need, a, b)
+                )
+    if not fits:
+        return Tiles(tk, tm, None)
+    _, _, need, tk, tm = min(fits)
+    return Tiles(tk, tm, need)
+
+
+def expert_dw_tiles(d_model: int, d_ff: int, rows_tiled: bool, dtype) -> str:
+    """For the ``compile`` event's ``gmm_dw_tiles``: how many tiles the two
+    dW plans of one expert layer cut an expert's matrix into, ``into:<K
+    tiles>x<M tiles>`` for ``wi`` (and ``wg``), ``out_of:`` for ``wo``."""
+    into = plan_dw_tiles(d_model, d_ff, rows_tiled, False, dtype)
+    out_of = plan_dw_tiles(d_ff, d_model, False, rows_tiled, dtype)
+    return (
+        f"into:{d_model // into.tk}x{d_ff // into.tm} "
+        f"out_of:{d_ff // out_of.tk}x{d_model // out_of.tm}"
+    )
 
 
 def _row_block(tiled, block_rows, width, index):
@@ -403,9 +495,9 @@ def _gmm_bwd(block_rows, out_tiled, skip_dead, residuals, dy):
     # Tiles of dw outermost, row blocks innermost: the f32 accumulator and
     # the double-buffered output block hold one [tk, tm] tile across an
     # expert's consecutive row blocks.
-    tile_elems = _TILE_BYTES // (4 + 2 * jnp.dtype(w.dtype).itemsize)
-    tm = _lane_tile(m, tile_elems // k, _quantum(out_tiled, x.dtype))
-    tk = _lane_tile(k, tile_elems // tm, _quantum(x.ndim == 3, x.dtype))
+    tk, tm, vmem_limit = plan_dw_tiles(
+        k, m, x.ndim == 3, out_tiled, x.dtype, block_rows
+    )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
         grid=(k // tk, m // tm, num_blocks),
@@ -429,6 +521,9 @@ def _gmm_bwd(block_rows, out_tiled, skip_dead, residuals, dy):
         functools.partial(_gmm_dw_kernel, skip_dead=skip_dead),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((e, k, m), w.dtype),
+        compiler_params=vmem_limit and pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit
+        ),
         interpret=backend.interpret(),
     )(*scalars, x, dy)
     # An expert with no rows is never visited (or only by dead blocks) and

@@ -172,6 +172,17 @@ class TrainerConfig:
     world: int = 0
 
 
+def _grouped_rows_tiled(cfg):
+    """``None`` for a model without grouped experts; else whether their
+    d_model-wide rows come and go row-tiled between the row moves and the
+    grouped GEMMs (``row_gather_sum.kernel_fits``, which the layer asks)."""
+    if not cfg.num_experts or cfg.moe_dispatch != "grouped":
+        return None
+    from dlrover_tpu.ops import row_gather_sum
+
+    return row_gather_sum.kernel_fits(cfg.d_model, cfg.top_k, cfg.dtype)
+
+
 class TrainerCallback:
     """Hook surface of the fit loop (ref ``atorch_trainer.py`` callbacks /
     the HF TrainerCallback contract it implements).  Subclass and override;
@@ -370,6 +381,7 @@ class ElasticTrainer:
                 "short_conv": self._short_conv(),
                 "row_moves": self._row_moves(),
                 "gmm_strips": self._gmm_strips(),
+                "gmm_dw_tiles": self._gmm_dw_tiles(),
                 "conv_core": self._conv_core(),
                 "kda": self._kda(),
             }
@@ -559,14 +571,31 @@ class ElasticTrainer:
         row block (``ops/grouped_matmul.py`` ``plan_tiles``, which the
         kernel asks), ``none`` for a model without grouped experts."""
         cfg = self.model_config
-        if not cfg.num_experts or cfg.moe_dispatch != "grouped":
+        tiled = _grouped_rows_tiled(cfg)
+        if tiled is None:
             return "none"
-        from dlrover_tpu.ops import grouped_matmul, row_gather_sum
+        from dlrover_tpu.ops import grouped_matmul
 
         return grouped_matmul.expert_strips(
             cfg.d_model, cfg.resolved_moe_d_ff, cfg.activation == "swiglu",
-            row_gather_sum.kernel_fits(cfg.d_model, cfg.top_k, cfg.dtype),
-            cfg.dtype,
+            tiled, cfg.dtype,
+        )
+
+    def _gmm_dw_tiles(self) -> str:
+        """How many tiles the grouped experts' weight-gradient GEMMs cut an
+        expert's matrix into, for the ``compile`` event: ``into:<K tiles>x<M
+        tiles> out_of:<K>x<M>`` (``wi`` / ``wg`` and ``wo``; every M tile
+        reads the rows again, every K tile their cotangents:
+        ``ops/grouped_matmul.py`` ``plan_dw_tiles``, which the kernel asks),
+        ``none`` for a model without grouped experts."""
+        cfg = self.model_config
+        tiled = _grouped_rows_tiled(cfg)
+        if tiled is None:
+            return "none"
+        from dlrover_tpu.ops import grouped_matmul
+
+        return grouped_matmul.expert_dw_tiles(
+            cfg.d_model, cfg.resolved_moe_d_ff, tiled, cfg.dtype
         )
 
     def _short_conv(self) -> str:

@@ -111,6 +111,7 @@ def test_fit_books_the_conv_event_from_the_step_itself(monkeypatch, tmp_path):
     (compiled,) = [e[-1] for e in taken if e[0] == "compile"]
     assert compiled["conv_core"] == "xla" and compiled["short_conv"] == "none"
     assert compiled["gmm_strips"] == "resident"
+    assert compiled["gmm_dw_tiles"] == "into:1x1 out_of:1x1"
     conv = [e[4] for e in events if e[0] == "conv"]
     moe = [e[4] for e in events if e[0] == "moe"]
     assert [e["step"] for e in conv] == [5, 10] == [e["step"] for e in moe]
@@ -165,6 +166,12 @@ def test_the_compile_event_says_how_the_core_runs():
     # calls ask for: none of a layer's six forward/dx GEMMs splits K
     assert ElasticTrainer._gmm_strips(stub(published, 8192)) == "resident"
     assert ElasticTrainer._gmm_strips(stub(TransformerConfig())) == "none"
+    # the weight gradients' [2048, 1792] and [1792, 2048], seven tiles each
+    # under the default scoped VMEM, are one tile under the limit they ask
+    assert ElasticTrainer._gmm_dw_tiles(stub(published, 8192)) == (
+        "into:1x1 out_of:1x1"
+    )
+    assert ElasticTrainer._gmm_dw_tiles(stub(TransformerConfig())) == "none"
 
 
 def test_the_scopes_the_benchmark_reads_reach_the_compiled_text(tokens):

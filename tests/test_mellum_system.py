@@ -173,6 +173,12 @@ def test_a_model_without_windowed_layers_books_what_it_booked():
         "kernel_live_padded"
     )
     assert ElasticTrainer._gmm_strips(stub(published, 32768)) == "resident"
+    # ... and each weight gradient is ONE tile under the limit its call asks
+    # for (2304 x 896 was seven tiles of 128 lanes until PR 55, and read
+    # every row block seven times)
+    assert ElasticTrainer._gmm_dw_tiles(stub(published, 32768)) == (
+        "into:1x1 out_of:1x1"
+    )
 
 
 def test_the_scopes_the_benchmark_reads_reach_the_compiled_text(tokens):

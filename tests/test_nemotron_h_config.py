@@ -85,15 +85,19 @@ def test_a_model_without_such_a_layer_names_no_scan():
     assert ElasticTrainer._ssm_scan(stub) == "xla"
 
 
-@pytest.mark.parametrize("cap,said", [
+@pytest.mark.parametrize("cap,said,dw_said", [
     # wi's [2688, 1856] strip (and the transposed wo's: its dx) overflows
-    # the default scoped VMEM's budget and asks for more ...
-    (None, "resident"),
+    # the default scoped VMEM's budget and asks for more; the weight
+    # gradient's whole tile would take 40 MiB and is cut in three along the
+    # side that can be (1,856 is a block's full extent) ...
+    (None, "resident", "into:3x1 out_of:1x3"),
     # ... and with nothing more to ask for K is cut in three: two of the
-    # ungated layer's four forward/dx calls (the rule until PR 53)
-    (0, "split_k:2/4"),
+    # ungated layer's four forward/dx calls (the rule until PR 53), and the
+    # weight gradients in seven (until PR 55)
+    (0, "split_k:2/4", "into:7x1 out_of:1x7"),
 ])
-def test_the_compile_event_names_the_gemm_strips(monkeypatch, cap, said):
+def test_the_compile_event_names_the_gemm_strips(monkeypatch, cap, said,
+                                                 dw_said):
     from dlrover_tpu.ops import grouped_matmul
     from dlrover_tpu.trainer.elastic_trainer import ElasticTrainer
 
@@ -103,6 +107,7 @@ def test_the_compile_event_names_the_gemm_strips(monkeypatch, cap, said):
         "model_config": nemotron_h_config(experts_held=16)
     })()
     assert ElasticTrainer._gmm_strips(stub) == said
+    assert ElasticTrainer._gmm_dw_tiles(stub) == dw_said
 
 
 @pytest.mark.parametrize("overrides,rows", [

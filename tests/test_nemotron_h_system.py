@@ -179,3 +179,4 @@ def test_fit_books_one_ssm_event_per_report_from_the_step_itself(
     (compiled,) = [e for e in taken if e[0] == "compile"]
     assert compiled[-1]["ssm_scan"] == "kernel"
     assert compiled[-1]["gmm_strips"] == "resident"
+    assert compiled[-1]["gmm_dw_tiles"] == "into:1x1 out_of:1x1"

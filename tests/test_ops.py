@@ -138,24 +138,30 @@ def test_grouped_matmul_fwd(rng):
     np.testing.assert_allclose(out, ref, atol=1e-4, rtol=1e-4)
 
 
-@pytest.mark.parametrize("k,m,tile_bytes,cap,fwd_tk,dx_tk", [
-    (64, 64, None, None, 64, 64),    # one tile per expert block
-    # no more VMEM to ask for: fwd/dx tile M then K, dw tiles M
-    (256, 384, 256 * 1024, 0, 256, 128),
+@pytest.mark.parametrize("k,m,tile_bytes,cap,fwd_tk,dx_tk,dw_tile", [
+    (64, 64, None, None, 64, 64, (64, 64)),    # one tile per expert block
+    # no more VMEM to ask for: fwd/dx tile M then K, dw tiles M and K
+    (256, 384, 256 * 1024, 0, 256, 128, (128, 128)),
     # M off the lane grid: only K can be split, and without a cap it is
-    (384, 200, 256 * 1024, 0, 128, 200),
+    (384, 200, 256 * 1024, 0, 128, 200, (128, 200)),
     # the same under the cap: the strip the budget would cut in three
-    # stays whole, one contraction step
-    (384, 200, 256 * 1024, None, 384, 200),
+    # stays whole, one contraction step, and so does the dw tile
+    (384, 200, 256 * 1024, None, 384, 200, (384, 200)),
+    # a cap that holds half of dw: cut along K where dy (m wide) is the
+    # narrower operand to read twice ...
+    (512, 256, 256 * 1024, 1792 * 1024, 512, 256, (256, 256)),
+    # ... and along M where x (k wide) is
+    (256, 512, 256 * 1024, 1792 * 1024, 256, 512, (256, 256)),
 ])
 def test_grouped_matmul_grads(rng, monkeypatch, k, m, tile_bytes, cap,
-                              fwd_tk, dx_tk):
+                              fwd_tk, dx_tk, dw_tile):
     if tile_bytes is not None:
         monkeypatch.setattr(gmm, "_TILE_BYTES", tile_bytes)
     if cap is not None:
         monkeypatch.setattr(gmm, "_VMEM_CAP", cap)
     assert gmm.plan_tiles(k, m, False, False, jnp.float32).tk == fwd_tk
     assert gmm.plan_tiles(m, k, False, False, jnp.float32).tk == dx_tk
+    assert gmm.plan_dw_tiles(k, m, False, False, jnp.float32)[:2] == dw_tile
     e = 3
     sizes = jnp.asarray([128, 256, 128], jnp.int32)
     n = int(sizes.sum())
@@ -172,6 +178,36 @@ def test_grouped_matmul_grads(rng, monkeypatch, k, m, tile_bytes, cap,
     gx_r, gw_r = jax.grad(loss_ref, argnums=(0, 1))(x, w)
     np.testing.assert_allclose(gx_k, gx_r, atol=1e-3, rtol=1e-3)
     np.testing.assert_allclose(gw_k, gw_r, atol=1e-3, rtol=1e-3)
+
+
+def test_grouped_matmul_dw_is_the_same_numbers_whatever_the_tile(
+    rng, monkeypatch
+):
+    """Every element of dw is the float32 sum over its expert's row blocks
+    in the same order, rounded once: whole under the cap or in the six
+    tiles of the default budget's rule, bit for bit."""
+    k, m = 384, 256
+    sizes = jnp.asarray([256, 0, 384, 128], jnp.int32)
+    n = int(sizes.sum()) + 128     # a block of the budget's slack
+    x = jnp.asarray(rng.normal(size=(n, k)), jnp.bfloat16)
+    w = jnp.asarray(rng.normal(size=(4, k, m)) * 0.1, jnp.bfloat16)
+    dy = jnp.asarray(rng.normal(size=(n, m)), jnp.bfloat16)
+    monkeypatch.setattr(gmm, "_TILE_BYTES", 128 * 1024)
+
+    def dw():
+        _, vjp = jax.vjp(lambda w: gmm.grouped_matmul(x, w, sizes), w)
+        return vjp(dy)[0]
+
+    whole = gmm.plan_dw_tiles(k, m, False, False, jnp.bfloat16)
+    assert (whole.tk, whole.tm) == (k, m) and whole.vmem_limit_bytes
+    under_the_cap = dw()
+    monkeypatch.setattr(gmm, "_VMEM_CAP", 0)
+    assert gmm.plan_dw_tiles(k, m, False, False, jnp.bfloat16) == (
+        128, 128, None
+    )
+    np.testing.assert_array_equal(
+        np.asarray(under_the_cap, np.float32), np.asarray(dw(), np.float32)
+    )
 
 
 # (k, m, rows in tiled, rows out tiled) -> (tk, tm, asks for more VMEM), in
@@ -192,6 +228,9 @@ _CELL_TILES = {
     "lfm2_into": ((2048, 1792, True, False), (2048, 896, False)),
     "nemotron_out_of": ((1856, 2688, False, False), (1856, 896, False)),
     "mixtral_into": ((4096, 14336, True, False), (4096, 512, False)),
+    # Mellum2 (PR 54): rows of 2,304 = 18 lane tiles stay plain
+    "mellum_into": ((2304, 896, False, False), (2304, 896, False)),
+    "mellum_out_of": ((896, 2304, False, False), (896, 2304, False)),
     # ------------------------------------------------------------------
     "lfm2_out_of": ((1792, 2048, False, True), (1792, 2048, True)),
     "nemotron_into": ((2688, 1856, False, False), (2688, 1856, True)),
@@ -210,6 +249,66 @@ def test_plan_tiles_at_the_cells_shapes(call):
     else:
         # what the whole-K strip needs, twice over, and within the cap
         assert 2 * k * tm * 2 < plan.vmem_limit_bytes <= gmm._VMEM_CAP
+
+
+# (k, m, x row-tiled, dy row-tiled) -> (tk, tm, asks for more VMEM), in
+# bfloat16: the two dW plans of each grouped cell's expert layer, wi / wg's
+# ("into" the expert width) and wo's ("out of" it), and Mixtral's under
+# `grouped`.  Until PR 55 the default scoped VMEM's budget cut them into
+# the tiles on the right; every M tile reads x again, every K tile dy.
+_CELL_DW_TILES = {
+    "mellum_into": ((2304, 896, False, False), (2304, 896, True)),    # 1x7
+    "mellum_out_of": ((896, 2304, False, False), (896, 2304, True)),  # 1x2
+    "lfm2_into": ((2048, 1792, True, False), (2048, 1792, True)),     # 1x7
+    "lfm2_out_of": ((1792, 2048, False, True), (1792, 2048, True)),   # 7x1
+    # 2688 x 1856 whole would take 40 MiB: three tiles along the side that
+    # can be cut (1,856 is 14.5 lane tiles, a block's full extent)
+    "nemotron_into": ((2688, 1856, False, False), (896, 1856, True)),  # 7x1
+    "nemotron_out_of": ((1856, 2688, False, False), (1856, 896, True)),
+    "olmoe_into": ((2048, 1024, True, False), (2048, 1024, True)),    # 1x2
+    "olmoe_out_of": ((1024, 2048, False, True), (1024, 2048, True)),  # 2x1
+    "granite_into": ((4096, 768, True, False), (4096, 768, True)),    # 1x3
+    "granite_out_of": ((768, 4096, False, True), (768, 4096, True)),  # 2x2
+    "joyai_into": ((2048, 768, True, False), (2048, 768, True)),      # 1x2
+    "joyai_out_of": ((768, 2048, False, True), (768, 2048, True)),    # 2x1
+    "ling_into": ((2560, 768, False, False), (2560, 768, True)),      # 1x2
+    "ling_out_of": ((768, 2560, False, False), (768, 2560, True)),    # 1x2
+    # sixteen tiles under the cap where the budget cut 56: rows of 4,096
+    # row-tiled can be halved, and then the wider tile of M re-reads less
+    "mixtral_into": ((4096, 14336, True, False), (2048, 1792, True)),
+    "mixtral_out_of": ((14336, 4096, False, True), (1792, 2048, True)),
+    # a tile the default limit holds stays, and asks for nothing
+    "preset": ((256, 512, False, False), (256, 512, False)),
+}
+
+
+@pytest.mark.parametrize("call", sorted(_CELL_DW_TILES))
+def test_plan_dw_tiles_at_the_cells_shapes(call):
+    (k, m, x_tiled, out_tiled), (tk, tm, asks) = _CELL_DW_TILES[call]
+    plan = gmm.plan_dw_tiles(k, m, x_tiled, out_tiled, jnp.bfloat16)
+    assert (plan.tk, plan.tm) == (tk, tm)
+    if not asks:
+        assert plan.vmem_limit_bytes is None
+    else:
+        # over the float32 accumulator and the output tile twice, and
+        # within the cap
+        assert 8 * tk * tm < plan.vmem_limit_bytes <= gmm._VMEM_CAP
+
+
+@pytest.mark.parametrize("d,ff,tiled,cap,said", [
+    (2304, 896, False, None, "into:1x1 out_of:1x1"),      # Mellum2
+    (2304, 896, False, 0, "into:1x7 out_of:1x2"),         # ... until PR 55
+    (2048, 1792, True, None, "into:1x1 out_of:1x1"),      # LFM2
+    (2048, 1792, True, 0, "into:1x7 out_of:7x1"),
+    (2688, 1856, False, None, "into:3x1 out_of:1x3"),     # Nemotron
+    (2688, 1856, False, 0, "into:7x1 out_of:1x7"),
+    (4096, 14336, True, None, "into:2x8 out_of:8x2"),     # Mixtral, `grouped`
+])
+def test_expert_dw_tiles_counts_a_layers_tiles(monkeypatch, d, ff, tiled,
+                                               cap, said):
+    if cap is not None:
+        monkeypatch.setattr(gmm, "_VMEM_CAP", cap)
+    assert gmm.expert_dw_tiles(d, ff, tiled, jnp.bfloat16) == said
 
 
 @pytest.mark.parametrize("d,ff,gated,tiled,said", [

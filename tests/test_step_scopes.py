@@ -206,7 +206,11 @@ def test_the_phases_are_exclusive(preset, devices, zero1, grad_accum):
 # accumulator (``ops/grouped_matmul.py`` ``_gmm_kernel``), another kernel
 # body and the same numbers; it read 0d7f87bc...5623fd02.  With ``plan_tiles``
 # alone (the tiles and the VMEM limit, the accumulator still there) all six
-# texts stood unedited: no tiny preset's strip overflows the budget.
+# texts stood unedited: no tiny preset's strip overflows the budget.  PR 55
+# (the dW call's tile planned under a VMEM limit it asks for,
+# ``plan_dw_tiles``) recorded none anew: every tiny preset's whole dW tile
+# fits the default budget, keeps its tile and asks for nothing, so all six
+# texts stand unedited, which is the proof that no small program moved.
 LOWERED_AT_PARENT = {
     "gpt2-1.5b":
         "3fb5f6338781894bc6418780c92ff0224b12abbaddadeef5d7c79a740c9f4f92",

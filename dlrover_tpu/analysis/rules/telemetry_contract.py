@@ -30,9 +30,16 @@ Checks (all project-scope; each needs symbols from several modules):
 Routing detection keys on the repo convention that routing functions
 compare a variable literally named ``name`` against string constants
 (``name == "fault"``, ``name in KINDS``, ``KINDS`` a module-level
-dict/set literal).  When the tree has no routing function at all (single
+dict/set literal of the routing module or of the module it imports the
+name from).  When the tree has no routing function at all (single
 -file lints, fixtures), the emit-side checks stay silent rather than
 flagging every event in sight.
+
+A model family books its event under the name its declaration carries
+(``FAMILY = Family(event="ssm", ...)``, ``models/family.py``; the trainer
+emits ``telemetry.event(family.event, ...)``, a kind no literal at the call
+says), so a module-level ``Family(event="<kind>")`` counts as the emitter
+of that instant kind.
 """
 
 from __future__ import annotations
@@ -46,6 +53,8 @@ from dlrover_tpu.analysis.project import ModuleInfo, ProjectContext
 
 ROUTING_FUNCTIONS = {"_report_telemetry", "add_events"}
 RENDER_FUNCTIONS = {"render_metrics"}
+#: The record type whose ``event=`` keyword declares an emitted kind.
+FAMILY_RECORD = "Family"
 
 
 def _string_const(node: ast.AST) -> Optional[str]:
@@ -129,13 +138,15 @@ class TelemetryContract(ProjectRule):
                 for node in ast.walk(fn):
                     if not isinstance(node, ast.Compare):
                         continue
-                    for kind in self._kinds_of_compare(info, node):
+                    for kind in self._kinds_of_compare(
+                        project, info, node
+                    ):
                         out.setdefault(kind, (info, node))
         return out
 
     @staticmethod
     def _kinds_of_compare(
-        info: ModuleInfo, node: ast.Compare
+        project: ProjectContext, info: ModuleInfo, node: ast.Compare
     ) -> List[str]:
         sides = [node.left] + list(node.comparators)
         if not any(
@@ -154,6 +165,11 @@ class TelemetryContract(ProjectRule):
             elif isinstance(other, ast.Name) and other.id != "name":
                 if has_in:
                     const = info.constants.get(other.id)
+                    if const is None:
+                        # a table the routing module imports by name
+                        resolved = project.resolve(info.module, other.id)
+                        if resolved is not None:
+                            const = resolved[0].constants.get(resolved[1])
                     if const is not None:
                         out.extend(_literal_strings(const))
             elif has_in:
@@ -171,6 +187,18 @@ class TelemetryContract(ProjectRule):
         ] = {}
         for mod in sorted(project.modules):
             info = project.modules[mod]
+            for const in info.constants.values():
+                # a family's declaration: the kind its reports book
+                if not isinstance(const, ast.Call) or jaxast.call_name(
+                    const
+                ).rsplit(".", 1)[-1] != FAMILY_RECORD:
+                    continue
+                for kw in const.keywords:
+                    literal = _string_const(kw.value)
+                    if kw.arg == "event" and literal is not None:
+                        out.setdefault(literal, []).append(
+                            (info, "<module>", const, True)
+                        )
             for qual in sorted(info.functions):
                 fn = info.functions[qual]
                 for node in jaxast.body_nodes(fn):

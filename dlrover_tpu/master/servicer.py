@@ -19,6 +19,7 @@ import grpc
 
 from dlrover_tpu.common.log import default_logger as logger
 from dlrover_tpu.master import messages as msg
+from dlrover_tpu.master.speed_monitor import HEALTH_KINDS
 
 SERVICE = "dlrover_tpu.Master"
 REPORT = f"/{SERVICE}/report"
@@ -385,9 +386,9 @@ class MasterServicer:
                         node, attrs,
                     )
             elif self.speed_monitor is not None and name == "moe":
-                # Router-health snapshot (gate entropy, capacity drops,
-                # per-expert load): feeds the moe ledger behind the
-                # dlrover_moe_* gauges.
+                # Router-health snapshot: its scalars are the ``moe`` row
+                # of ``HEALTH_KINDS``, its per-expert load vector the
+                # labelled dlrover_moe_expert_load gauges.
                 try:
                     self.speed_monitor.record_moe(node, **attrs)
                 except (TypeError, ValueError):
@@ -395,55 +396,17 @@ class MasterServicer:
                         "unparseable moe event from %d: %r",
                         node, attrs,
                     )
-            elif self.speed_monitor is not None and name == "mtp":
-                # The multi-token-prediction module's loss: behind the
-                # dlrover_mtp_loss gauge.
+            elif self.speed_monitor is not None and name in HEALTH_KINDS:
+                # A model family's health snapshot (the MTP module's
+                # loss, the delta-rule, state-space, short-convolution and
+                # windowed-attention layers' statistics): kept and
+                # rendered as the kind's row of ``HEALTH_KINDS`` says.
                 try:
-                    self.speed_monitor.record_mtp(node, **attrs)
+                    self.speed_monitor.record_health(name, node, **attrs)
                 except (TypeError, ValueError):
                     logger.warning(
-                        "unparseable mtp event from %d: %r", node, attrs,
-                    )
-            elif self.speed_monitor is not None and name == "linear_attn":
-                # Linear-attention health snapshot (mean decay, mean
-                # write strength, the recurrent state's largest entry):
-                # feeds the ledger behind the dlrover_linear_attn_* gauges.
-                try:
-                    self.speed_monitor.record_linear_attn(node, **attrs)
-                except (TypeError, ValueError):
-                    logger.warning(
-                        "unparseable linear_attn event from %d: %r",
-                        node, attrs,
-                    )
-            elif self.speed_monitor is not None and name == "ssm":
-                # State-space health snapshot (mean decay, mean step, the
-                # recurrent state's largest entry): feeds the ledger behind
-                # the dlrover_ssm_* gauges.
-                try:
-                    self.speed_monitor.record_ssm(node, **attrs)
-                except (TypeError, ValueError):
-                    logger.warning(
-                        "unparseable ssm event from %d: %r", node, attrs,
-                    )
-            elif self.speed_monitor is not None and name == "conv":
-                # Gated-short-convolution health snapshot (the gates' mean
-                # sizes, the core's largest output): feeds the ledger
-                # behind the dlrover_conv_* gauges.
-                try:
-                    self.speed_monitor.record_conv(node, **attrs)
-                except (TypeError, ValueError):
-                    logger.warning(
-                        "unparseable conv event from %d: %r", node, attrs,
-                    )
-            elif self.speed_monitor is not None and name == "attn":
-                # Softmax-attention snapshot of a model with windowed
-                # layers (layers by kind, window, the score bounds): feeds
-                # the ledger behind the dlrover_attn_* gauges.
-                try:
-                    self.speed_monitor.record_attn(node, **attrs)
-                except (TypeError, ValueError):
-                    logger.warning(
-                        "unparseable attn event from %d: %r", node, attrs,
+                        "unparseable %s event from %d: %r",
+                        name, node, attrs,
                     )
             elif self.speed_monitor is not None and name == "embed":
                 # Embedding-plane stats snapshot: feeds the embed ledger

@@ -14,6 +14,178 @@ from collections import deque
 from typing import Any, Deque, Dict, List, Optional, Tuple
 
 
+#: The model families' health events (``models/family.py``: one event a
+#: family, booked by the trainer on the report cadence), by the event's
+#: name: the ONE place the master says what it keeps of each, how the
+#: reporters' newest snapshots aggregate, and which gauges render the
+#: aggregate.  ``MasterServicer._report_telemetry`` routes a kind that has
+#: a row here to :meth:`SpeedMonitor.record_health`,
+#: ``JobTimeline.render_metrics`` renders each row's ``gauges`` out of
+#: :meth:`SpeedMonitor.health_ledger`: a new family adds a row and edits no
+#: code.  ``mean`` / ``max`` / ``min``: the attributes kept, each with what
+#: it reads where an event (or every reporter) has none; the means average
+#: (each reporter books its own replica's batch), the geometry and the
+#: largest entries take the max, where one that is not a number wins (a
+#: non-finite entry on any replica must show).  ``or``: an attribute that
+#: reads another's value where the event says none.  ``gauges``:
+#: ``(attribute, gauge, HELP)`` in the order they render; ``reporters`` and
+#: ``step`` are kept of every kind.
+HEALTH_KINDS: Dict[str, Dict[str, Any]] = {
+    # Router health (gate entropy, capacity drops, the share of a chip
+    # that holds some of the experts); the per-expert load vector is
+    # ``record_moe``'s.
+    "moe": {
+        "mean": {
+            "entropy": 0.0, "drop_fraction": 0.0, "pad_share": 0.0,
+            "max_expert_load": 0.0, "pairs_here": 1.0, "tokens_here": 1.0,
+        },
+        "max": {
+            "experts": 0.0, "top_k": 0.0, "held": 0.0, "bias_absmax": 0.0,
+            "groups": 1.0,
+        },
+        # an older trainer's event (no share told): every expert held
+        "or": {"held": "experts"},
+        "gauges": (
+            ("entropy", "dlrover_moe_gate_entropy",
+             "mean per-token router entropy in nats (mean of "
+             "reporters; ln(E) = uniform routing, 0 = collapsed)"),
+            ("drop_fraction", "dlrover_moe_capacity_drop_fraction",
+             "fraction of token-choices dropped at expert capacity "
+             "(0 on the dropless grouped path)"),
+            ("pad_share", "dlrover_moe_pad_share",
+             "padding rows over the rows the expert matmuls run "
+             "(capacity slots, or the grouped GEMMs' row budget)"),
+            ("max_expert_load", "dlrover_moe_max_expert_load",
+             "busiest expert's routed rows over the mean expert's "
+             "(1 = perfectly balanced)"),
+            ("experts", "dlrover_moe_experts",
+             "expert count of the reported MoE model"),
+            ("top_k", "dlrover_moe_top_k",
+             "router choices per token (top-k)"),
+            ("reporters", "dlrover_moe_reporters",
+             "trainers that have reported router-health snapshots"),
+            ("held", "dlrover_moe_experts_held",
+             "experts of a layer that live on a reporter's chip "
+             "(= dlrover_moe_experts where none is told a share)"),
+            ("pairs_here", "dlrover_moe_pairs_here",
+             "share of the routed token-choices a reporter's own "
+             "experts computed (mean of reporters; 1 without a share)"),
+            ("tokens_here", "dlrover_moe_tokens_here",
+             "share of the tokens with at least one routed pair on a "
+             "reporter's chip: the rows an exchange would send it "
+             "(mean of reporters; 1 without a share)"),
+            ("groups", "dlrover_moe_router_groups",
+             "groups a group-limited router cuts the experts into "
+             "(1: no limit)"),
+            ("bias_absmax", "dlrover_moe_router_bias_absmax",
+             "largest |bias| of a bias-corrected router (max of "
+             "reporters; 0 where the router has none)"),
+        ),
+    },
+    # The multi-token-prediction module's own loss.
+    "mtp": {
+        "mean": {"mtp_loss": 0.0},
+        "gauges": (
+            ("mtp_loss", "dlrover_mtp_loss",
+             "multi-token-prediction module's cross-entropy (mean of "
+             "reporters' newest; 0 where the model has no module)"),
+        ),
+    },
+    # The delta-rule layers (mean decay, mean write strength, the
+    # recurrent state's largest entry; ``min_alpha`` is a per-channel
+    # rule's smallest mean decay of a channel, 1 where the event has none).
+    "linear_attn": {
+        "mean": {"mean_alpha": 0.0, "mean_beta": 0.0},
+        "max": {"layers": 0.0, "chunk": 0.0, "state_absmax": 0.0},
+        "min": {"min_alpha": 1.0},
+        "gauges": (
+            ("layers", "dlrover_linear_attn_layers",
+             "gated-delta-rule layers of the reported model"),
+            ("chunk", "dlrover_linear_attn_chunk",
+             "tokens a chunk of the chunked delta rule holds"),
+            ("mean_alpha", "dlrover_linear_attn_mean_alpha",
+             "mean state decay exp(g) over tokens, heads and layers "
+             "(mean of reporters; 1 = nothing forgotten)"),
+            ("mean_beta", "dlrover_linear_attn_mean_beta",
+             "mean write strength beta (0..1, or 0..2 where negative "
+             "eigenvalues are allowed)"),
+            ("state_absmax", "dlrover_linear_attn_state_absmax",
+             "largest |S| entry of a recurrent state at any chunk "
+             "boundary (max of reporters; NaN/Inf = diverged)"),
+            ("min_alpha", "dlrover_linear_attn_min_alpha",
+             "smallest mean decay of one channel of a per-channel "
+             "rule (min of reporters; 1 where no layer has one)"),
+            ("reporters", "dlrover_linear_attn_reporters",
+             "trainers that have reported linear-attention snapshots"),
+        ),
+    },
+    # The state-space (Mamba-2) layers: mean decay, mean step, the
+    # recurrent state's largest entry.
+    "ssm": {
+        "mean": {"mean_decay": 0.0, "mean_dt": 0.0},
+        "max": {"layers": 0.0, "chunk": 0.0, "state_absmax": 0.0},
+        "gauges": (
+            ("layers", "dlrover_ssm_layers",
+             "state-space (Mamba-2) layers of the reported model"),
+            ("chunk", "dlrover_ssm_chunk",
+             "tokens a chunk of the chunked scan holds"),
+            ("mean_decay", "dlrover_ssm_mean_decay",
+             "mean state decay exp(dt A) over tokens, heads and "
+             "layers (mean of reporters; 1 = nothing forgotten)"),
+            ("mean_dt", "dlrover_ssm_mean_dt",
+             "mean step dt after its softplus"),
+            ("state_absmax", "dlrover_ssm_state_absmax",
+             "largest |S| entry of a state-space state at any chunk "
+             "boundary (max of reporters; NaN/Inf = diverged)"),
+            ("reporters", "dlrover_ssm_reporters",
+             "trainers that have reported state-space snapshots"),
+        ),
+    },
+    # The gated short convolutions (no state: the core's largest output
+    # stands where the state's largest entry does).
+    "conv": {
+        "mean": {"gate_absmean": 0.0, "out_gate_absmean": 0.0},
+        "max": {"layers": 0.0, "out_absmax": 0.0},
+        "gauges": (
+            ("gate_absmean", "dlrover_conv_gate_absmean",
+             "mean |B| of the gate before the convolution over "
+             "tokens, channels and layers (mean of reporters)"),
+            ("out_gate_absmean", "dlrover_conv_out_gate_absmean",
+             "mean |C| of the gate after the convolution"),
+            ("out_absmax", "dlrover_conv_out_absmax",
+             "largest |C * conv(B * z)| entry of any layer (max of "
+             "reporters; NaN/Inf = diverged)"),
+            ("reporters", "dlrover_conv_reporters",
+             "trainers that have reported convolution snapshots"),
+        ),
+    },
+    # The softmax attentions of a model with windowed layers (the bound of
+    # each kind's largest score stands where a state's largest entry does).
+    "attn": {
+        "max": {
+            "full_layers": 0.0, "sliding_layers": 0.0, "window": 0.0,
+            "full_score_bound": 0.0, "sliding_score_bound": 0.0,
+            "score_bound": 0.0,
+        },
+        "gauges": (
+            ("window", "dlrover_attn_window",
+             "keys a windowed attention layer's query sees"),
+            ("sliding_layers", "dlrover_attn_sliding_layers",
+             "windowed attention layers of the model"),
+            ("full_score_bound", "dlrover_attn_full_score_bound",
+             "UPPER BOUND, not an observed score: longest query "
+             "row x longest key row x scale of a full attention "
+             "layer's heads (max of reporters; a rotation's factor "
+             "shows here; NaN/Inf = diverged)"),
+            ("sliding_score_bound", "dlrover_attn_sliding_score_bound",
+             "the same of a windowed layer"),
+            ("reporters", "dlrover_attn_reporters",
+             "trainers that have reported attention snapshots"),
+        ),
+    },
+}
+
+
 class SpeedMonitor:
     SAMPLE_WINDOW = 20
 
@@ -94,29 +266,16 @@ class SpeedMonitor:
         # ``dlrover_embed_*`` gauges read the aggregate.
         self._embed_stats: Dict[int, Dict[str, float]] = {}
         self._embed_events = 0
-        # "moe" telemetry events: each reporter's newest router-health
-        # snapshot (gate entropy, capacity-drop fraction, per-expert
-        # load) — the ``dlrover_moe_*`` gauges read the aggregate.
-        self._moe_stats: Dict[int, Dict[str, Any]] = {}
+        # The model families' health events (``HEALTH_KINDS``): each
+        # reporter's newest snapshot of each kind, the attributes its row
+        # keeps.
+        self._health: Dict[str, Dict[int, Dict[str, float]]] = {
+            kind: {} for kind in HEALTH_KINDS
+        }
+        # "moe" events: each reporter's newest per-expert load vector, and
+        # how many events came.
+        self._moe_load: Dict[int, List[float]] = {}
         self._moe_events = 0
-        # "mtp" events: each reporter's newest multi-token-prediction loss.
-        self._mtp_loss: Dict[int, float] = {}
-        # "linear_attn" telemetry events: each reporter's newest snapshot
-        # of its gated-delta-rule layers — the ``dlrover_linear_attn_*``
-        # gauges read the aggregate.
-        self._linear_attn_stats: Dict[int, Dict[str, float]] = {}
-        # "ssm" telemetry events: the same for a model's state-space
-        # (Mamba-2) layers — the ``dlrover_ssm_*`` gauges.
-        self._ssm_stats: Dict[int, Dict[str, float]] = {}
-        # "conv" telemetry events: the same for a model's gated short
-        # convolutions (no state: the core's largest output stands where
-        # the state's largest entry does) — the ``dlrover_conv_*`` gauges.
-        self._conv_stats: Dict[int, Dict[str, float]] = {}
-        # "attn" telemetry events: the softmax attentions of a model with
-        # windowed layers (the bound of each kind's largest score stands
-        # where a state's largest entry does) — the ``dlrover_attn_*``
-        # gauges.
-        self._attn_stats: Dict[int, Dict[str, float]] = {}
 
     def collect_global_step(
         self, step: int, timestamp: Optional[float] = None, tokens: int = 0
@@ -298,276 +457,80 @@ class SpeedMonitor:
                 "rows_per_s": float(rows_per_s),
             }
 
-    def record_moe(
-        self,
-        node_id: int = 0,
-        *,
-        step: float = 0.0,
-        entropy: float = 0.0,
-        drop_fraction: float = 0.0,
-        experts: float = 0.0,
-        top_k: float = 0.0,
-        load: Any = "[]",
-        pad_share: float = 0.0,
-        max_expert_load: float = 0.0,
-        held: float = 0.0,
-        pairs_here: float = 1.0,
-        bias_absmax: float = 0.0,
-        tokens_here: float = 1.0,
-        groups: float = 1.0,
-        **_ignored,
-    ):
-        """A trainer's router-health snapshot (its ``moe`` telemetry
-        event).  ``held`` of the ``experts`` live on the reporter's chip
-        (0: all), which computed ``pairs_here`` of the routed pairs;
-        ``bias_absmax`` is a bias-corrected router's largest bias;
-        ``tokens_here`` the share of the tokens with a pair here and
-        ``groups`` the groups a group-limited choice cuts the experts into
-        (1: no limit).  Newest-wins per reporting node; ``load`` arrives as a
-        JSON array string of per-expert load fractions (wire attrs stay
-        scalar-ish); unknown attrs are ignored so the trainer can grow
+    def record_health(self, kind: str, node_id: int = 0, **attrs):
+        """A trainer's health snapshot of one model family (its ``kind``
+        telemetry event): the attributes ``HEALTH_KINDS[kind]`` keeps, each
+        with its default where the event has none.  Newest-wins per
+        reporting node; other attrs are ignored, so the trainer can grow
         the event without breaking older masters."""
+        row = HEALTH_KINDS[kind]
+        kept = {"step": float(attrs.get("step", 0.0))}
+        for how in ("mean", "max", "min"):
+            for attr, default in row.get(how, {}).items():
+                kept[attr] = float(attrs.get(attr, default))
+        for attr, other in row.get("or", {}).items():
+            kept[attr] = kept[attr] or kept[other]
+        with self._lock:
+            self._health[kind][node_id] = kept
+
+    def health_ledger(self, kind: str) -> Dict[str, float]:
+        """The aggregate over reporters of one family's snapshots, as its
+        row of ``HEALTH_KINDS`` says; with no reporter every attribute
+        reads its default."""
+        row = HEALTH_KINDS[kind]
+        with self._lock:
+            stats = list(self._health[kind].values())
+
+        def most(attr, default=0.0):
+            values = [s[attr] for s in stats]
+            if any(v != v for v in values):
+                return float("nan")
+            return max(values, default=default)
+
+        out = {"reporters": float(len(stats)), "step": most("step")}
+        for attr, default in row.get("max", {}).items():
+            out[attr] = most(attr, default)
+        for attr, default in row.get("mean", {}).items():
+            out[attr] = (
+                sum(s[attr] for s in stats) / len(stats) if stats
+                else default
+            )
+        for attr, default in row.get("min", {}).items():
+            out[attr] = min((s[attr] for s in stats), default=default)
+        return out
+
+    def record_moe(self, node_id: int = 0, *, load: Any = "[]", **attrs):
+        """A trainer's ``moe`` telemetry event: its scalars are the
+        ``moe`` kind's health snapshot (:meth:`record_health`); ``load``,
+        the per-expert load fractions, arrives as a JSON array string (wire
+        attrs stay scalar-ish) and is kept here, newest-wins per reporting
+        node."""
         if isinstance(load, str):
             import json
 
             load = json.loads(load)
+        load = [float(v) for v in load]
+        self.record_health("moe", node_id, **attrs)
         with self._lock:
             self._moe_events += 1
-            self._moe_stats[node_id] = {
-                "step": float(step),
-                "entropy": float(entropy),
-                "drop_fraction": float(drop_fraction),
-                "experts": float(experts),
-                "top_k": float(top_k),
-                "load": [float(v) for v in load],
-                "pad_share": float(pad_share),
-                "max_expert_load": float(max_expert_load),
-                "held": float(held or experts),
-                "pairs_here": float(pairs_here),
-                "bias_absmax": float(bias_absmax),
-                "tokens_here": float(tokens_here),
-                "groups": float(groups),
-            }
-
-    def record_mtp(self, node_id: int = 0, *, step: float = 0.0,
-                   mtp_loss: float = 0.0, **_ignored):
-        """A trainer's multi-token-prediction loss (its ``mtp`` event);
-        newest wins per reporting node."""
-        with self._lock:
-            self._mtp_loss[node_id] = float(mtp_loss)
-
-    def mtp_loss(self) -> float:
-        """Mean over reporters of the newest MTP loss; 0 with none."""
-        with self._lock:
-            values = list(self._mtp_loss.values())
-        return sum(values) / len(values) if values else 0.0
-
-    def record_linear_attn(
-        self,
-        node_id: int = 0,
-        *,
-        step: float = 0.0,
-        layers: float = 0.0,
-        chunk: float = 0.0,
-        mean_alpha: float = 0.0,
-        mean_beta: float = 0.0,
-        state_absmax: float = 0.0,
-        min_alpha: float = 1.0,
-        **_ignored,
-    ):
-        """A trainer's linear-attention snapshot (its ``linear_attn``
-        telemetry event; ``min_alpha`` is a per-channel rule's smallest
-        mean decay of a channel, 1 where the event has none).  Newest-wins
-        per reporting node; unknown attrs are ignored so the trainer can
-        grow the event."""
-        with self._lock:
-            self._linear_attn_stats[node_id] = {
-                "step": float(step),
-                "layers": float(layers),
-                "chunk": float(chunk),
-                "mean_alpha": float(mean_alpha),
-                "mean_beta": float(mean_beta),
-                "state_absmax": float(state_absmax),
-                "min_alpha": float(min_alpha),
-            }
-
-    def linear_attn_ledger(self) -> Dict[str, float]:
-        """Aggregate over reporters: the means average (each books its own
-        replica's batch), the state's largest entry and the geometry take
-        the max (a non-finite entry on any replica must show), the
-        smallest channel decay the min."""
-        out = self._state_ledger(
-            self._linear_attn_stats, ("mean_alpha", "mean_beta")
-        )
-        with self._lock:
-            out["min_alpha"] = min(
-                (s["min_alpha"] for s in self._linear_attn_stats.values()),
-                default=1.0,
-            )
-        return out
-
-    def record_ssm(
-        self,
-        node_id: int = 0,
-        *,
-        step: float = 0.0,
-        layers: float = 0.0,
-        chunk: float = 0.0,
-        mean_decay: float = 0.0,
-        mean_dt: float = 0.0,
-        state_absmax: float = 0.0,
-        **_ignored,
-    ):
-        """A trainer's state-space snapshot (its ``ssm`` telemetry event).
-        Newest-wins per reporting node; unknown attrs are ignored."""
-        with self._lock:
-            self._ssm_stats[node_id] = {
-                "step": float(step),
-                "layers": float(layers),
-                "chunk": float(chunk),
-                "mean_decay": float(mean_decay),
-                "mean_dt": float(mean_dt),
-                "state_absmax": float(state_absmax),
-            }
-
-    def ssm_ledger(self) -> Dict[str, float]:
-        """:meth:`linear_attn_ledger`'s aggregate of the ``ssm`` events."""
-        return self._state_ledger(self._ssm_stats, ("mean_decay", "mean_dt"))
-
-    def record_conv(
-        self,
-        node_id: int = 0,
-        *,
-        step: float = 0.0,
-        layers: float = 0.0,
-        gate_absmean: float = 0.0,
-        out_gate_absmean: float = 0.0,
-        out_absmax: float = 0.0,
-        **_ignored,
-    ):
-        """A trainer's gated-short-convolution snapshot (its ``conv``
-        telemetry event).  Newest-wins per reporting node; unknown attrs
-        are ignored."""
-        with self._lock:
-            self._conv_stats[node_id] = {
-                "step": float(step),
-                "layers": float(layers),
-                "gate_absmean": float(gate_absmean),
-                "out_gate_absmean": float(out_gate_absmean),
-                "out_absmax": float(out_absmax),
-            }
-
-    def conv_ledger(self) -> Dict[str, float]:
-        """:meth:`linear_attn_ledger`'s aggregate of the ``conv`` events:
-        ``out_absmax`` stands where a recurrent mixer has its state's
-        largest entry."""
-        return self._state_ledger(
-            self._conv_stats, ("gate_absmean", "out_gate_absmean"),
-            max_keys=("out_absmax",),
-        )
-
-    def record_attn(
-        self,
-        node_id: int = 0,
-        *,
-        step: float = 0.0,
-        full_layers: float = 0.0,
-        sliding_layers: float = 0.0,
-        window: float = 0.0,
-        full_score_bound: float = 0.0,
-        sliding_score_bound: float = 0.0,
-        score_bound: float = 0.0,
-        **_ignored,
-    ):
-        """A trainer's softmax-attention snapshot (its ``attn`` telemetry
-        event).  Newest-wins per reporting node; unknown attrs are
-        ignored."""
-        with self._lock:
-            self._attn_stats[node_id] = {
-                "step": float(step),
-                "layers": float(full_layers) + float(sliding_layers),
-                "sliding_layers": float(sliding_layers),
-                "window": float(window),
-                "full_score_bound": float(full_score_bound),
-                "sliding_score_bound": float(sliding_score_bound),
-                "score_bound": float(score_bound),
-            }
-
-    def attn_ledger(self) -> Dict[str, float]:
-        """:meth:`linear_attn_ledger`'s aggregate of the ``attn`` events:
-        every entry the largest of the reporters'."""
-        return self._state_ledger(
-            self._attn_stats, (), max_keys=(
-                "sliding_layers", "window", "full_score_bound",
-                "sliding_score_bound", "score_bound",
-            ),
-        )
-
-    def _state_ledger(
-        self, store, mean_keys, max_keys=("chunk", "state_absmax")
-    ) -> Dict[str, float]:
-        with self._lock:
-            stats = list(store.values())
-        n = len(stats)
-
-        def mean(key):
-            return sum(s[key] for s in stats) / n if n else 0.0
-
-        def most(key):
-            values = [s[key] for s in stats]
-            if any(v != v for v in values):
-                return float("nan")
-            return max(values, default=0.0)
-
-        out = {
-            "reporters": float(n),
-            "step": most("step"),
-            "layers": most("layers"),
-        }
-        out.update({key: most(key) for key in max_keys})
-        out.update({key: mean(key) for key in mean_keys})
-        return out
+            self._moe_load[node_id] = load
 
     def moe_ledger(self) -> Dict[str, Any]:
-        """Router-health aggregate: entropy/drop/padding average over reporters
-        (each books its own replica's gate view), expert geometry takes
-        the max, and per-expert load averages elementwise across the
-        reporters that carry the full-width vector."""
+        """The ``moe`` kind's aggregate (:meth:`health_ledger`), the count
+        of its events and the per-expert load, averaged elementwise across
+        the reporters that carry the full-width vector."""
+        out: Dict[str, Any] = self.health_ledger("moe")
+        experts = int(out["experts"])
         with self._lock:
-            stats = list(self._moe_stats.values())
-            n = len(stats)
-            experts = max((s["experts"] for s in stats), default=0.0)
             loads = [
-                s["load"] for s in stats
-                if len(s["load"]) == int(experts) and experts
+                load for load in self._moe_load.values()
+                if len(load) == experts and experts
             ]
-            load = [
-                sum(vec[i] for vec in loads) / len(loads)
-                for i in range(int(experts))
-            ] if loads else []
-
-            def mean(key):
-                return sum(s[key] for s in stats) / n if n else 0.0
-
-            return {
-                "moe_events": float(self._moe_events),
-                "reporters": float(n),
-                "step": max((s["step"] for s in stats), default=0.0),
-                "entropy": mean("entropy"),
-                "drop_fraction": mean("drop_fraction"),
-                "pad_share": mean("pad_share"),
-                "max_expert_load": mean("max_expert_load"),
-                "experts": experts,
-                "top_k": max((s["top_k"] for s in stats), default=0.0),
-                "load": load,
-                "held": max((s["held"] for s in stats), default=0.0),
-                "pairs_here": mean("pairs_here") if n else 1.0,
-                "bias_absmax": max(
-                    (s["bias_absmax"] for s in stats), default=0.0
-                ),
-                "tokens_here": mean("tokens_here") if n else 1.0,
-                "groups": max((s["groups"] for s in stats), default=1.0),
-            }
+            out["moe_events"] = float(self._moe_events)
+        out["load"] = [
+            sum(vec[i] for vec in loads) / len(loads) for i in range(experts)
+        ] if loads else []
+        return out
 
     def embed_ledger(self) -> Dict[str, float]:
         """Embedding-plane aggregate.  Every reporter books the same

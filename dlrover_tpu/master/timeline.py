@@ -26,6 +26,7 @@ from collections import Counter, deque
 from typing import Any, Deque, Dict, List, Optional, Sequence
 
 from dlrover_tpu.common.telemetry import WireEvent, events_to_chrome_trace
+from dlrover_tpu.master.speed_monitor import HEALTH_KINDS
 
 
 def _quantile(sorted_values: Sequence[float], q: float) -> float:
@@ -359,119 +360,29 @@ class JobTimeline:
                   "cold rows spilled to host-disk tiers, in bytes")
             gauge("dlrover_embed_rows_per_s", embed["rows_per_s"],
                   "embedding rows served/s (newest reported snapshot)")
-            moe = speed_monitor.moe_ledger()
-            gauge("dlrover_moe_gate_entropy", moe["entropy"],
-                  "mean per-token router entropy in nats (mean of "
-                  "reporters; ln(E) = uniform routing, 0 = collapsed)")
-            gauge("dlrover_moe_capacity_drop_fraction",
-                  moe["drop_fraction"],
-                  "fraction of token-choices dropped at expert capacity "
-                  "(0 on the dropless grouped path)")
-            gauge("dlrover_moe_pad_share", moe["pad_share"],
-                  "padding rows over the rows the expert matmuls run "
-                  "(capacity slots, or the grouped GEMMs' row budget)")
-            gauge("dlrover_moe_max_expert_load", moe["max_expert_load"],
-                  "busiest expert's routed rows over the mean expert's "
-                  "(1 = perfectly balanced)")
-            gauge("dlrover_moe_experts", moe["experts"],
-                  "expert count of the reported MoE model")
-            gauge("dlrover_moe_top_k", moe["top_k"],
-                  "router choices per token (top-k)")
-            gauge("dlrover_moe_reporters", moe["reporters"],
-                  "trainers that have reported router-health snapshots")
-            gauge("dlrover_moe_experts_held", moe["held"],
-                  "experts of a layer that live on a reporter's chip "
-                  "(= dlrover_moe_experts where none is told a share)")
-            gauge("dlrover_moe_pairs_here", moe["pairs_here"],
-                  "share of the routed token-choices a reporter's own "
-                  "experts computed (mean of reporters; 1 without a share)")
-            gauge("dlrover_moe_tokens_here", moe["tokens_here"],
-                  "share of the tokens with at least one routed pair on a "
-                  "reporter's chip: the rows an exchange would send it "
-                  "(mean of reporters; 1 without a share)")
-            gauge("dlrover_moe_router_groups", moe["groups"],
-                  "groups a group-limited router cuts the experts into "
-                  "(1: no limit)")
-            gauge("dlrover_moe_router_bias_absmax", moe["bias_absmax"],
-                  "largest |bias| of a bias-corrected router (max of "
-                  "reporters; 0 where the router has none)")
-            gauge("dlrover_mtp_loss", speed_monitor.mtp_loss(),
-                  "multi-token-prediction module's cross-entropy (mean of "
-                  "reporters' newest; 0 where the model has no module)")
-            lines.append(
-                "# HELP dlrover_moe_expert_load fraction of kept "
-                "token-choices routed to each expert (mean of reporters; "
-                "1/E = perfectly balanced)"
-            )
-            lines.append("# TYPE dlrover_moe_expert_load gauge")
-            if moe["load"]:
-                for i, frac in enumerate(moe["load"]):
+            for kind, row in HEALTH_KINDS.items():
+                # a model family's health: its row's gauges, out of the
+                # aggregate of its reporters' newest snapshots
+                ledger = speed_monitor.health_ledger(kind)
+                for attr, name, help_text in row["gauges"]:
+                    gauge(name, ledger[attr], help_text)
+                if kind != "mtp":
+                    continue
+                # the routers' per-expert load, a labelled family, keeps
+                # its place in the text: after the routers' scalars and
+                # the MTP loss
+                lines.append(
+                    "# HELP dlrover_moe_expert_load fraction of kept "
+                    "token-choices routed to each expert (mean of "
+                    "reporters; 1/E = perfectly balanced)"
+                )
+                lines.append("# TYPE dlrover_moe_expert_load gauge")
+                load = speed_monitor.moe_ledger()["load"]
+                for i, frac in enumerate(load):
                     gauge("dlrover_moe_expert_load", frac,
                           labels=f'{{expert="{i}"}}')
-            else:
-                gauge("dlrover_moe_expert_load", 0)
-            linear = speed_monitor.linear_attn_ledger()
-            gauge("dlrover_linear_attn_layers", linear["layers"],
-                  "gated-delta-rule layers of the reported model")
-            gauge("dlrover_linear_attn_chunk", linear["chunk"],
-                  "tokens a chunk of the chunked delta rule holds")
-            gauge("dlrover_linear_attn_mean_alpha", linear["mean_alpha"],
-                  "mean state decay exp(g) over tokens, heads and layers "
-                  "(mean of reporters; 1 = nothing forgotten)")
-            gauge("dlrover_linear_attn_mean_beta", linear["mean_beta"],
-                  "mean write strength beta (0..1, or 0..2 where negative "
-                  "eigenvalues are allowed)")
-            gauge("dlrover_linear_attn_state_absmax",
-                  linear["state_absmax"],
-                  "largest |S| entry of a recurrent state at any chunk "
-                  "boundary (max of reporters; NaN/Inf = diverged)")
-            gauge("dlrover_linear_attn_min_alpha", linear["min_alpha"],
-                  "smallest mean decay of one channel of a per-channel "
-                  "rule (min of reporters; 1 where no layer has one)")
-            gauge("dlrover_linear_attn_reporters", linear["reporters"],
-                  "trainers that have reported linear-attention snapshots")
-            ssm = speed_monitor.ssm_ledger()
-            gauge("dlrover_ssm_layers", ssm["layers"],
-                  "state-space (Mamba-2) layers of the reported model")
-            gauge("dlrover_ssm_chunk", ssm["chunk"],
-                  "tokens a chunk of the chunked scan holds")
-            gauge("dlrover_ssm_mean_decay", ssm["mean_decay"],
-                  "mean state decay exp(dt A) over tokens, heads and "
-                  "layers (mean of reporters; 1 = nothing forgotten)")
-            gauge("dlrover_ssm_mean_dt", ssm["mean_dt"],
-                  "mean step dt after its softplus")
-            gauge("dlrover_ssm_state_absmax", ssm["state_absmax"],
-                  "largest |S| entry of a state-space state at any chunk "
-                  "boundary (max of reporters; NaN/Inf = diverged)")
-            gauge("dlrover_ssm_reporters", ssm["reporters"],
-                  "trainers that have reported state-space snapshots")
-            conv = speed_monitor.conv_ledger()
-            gauge("dlrover_conv_gate_absmean", conv["gate_absmean"],
-                  "mean |B| of the gate before the convolution over "
-                  "tokens, channels and layers (mean of reporters)")
-            gauge("dlrover_conv_out_gate_absmean", conv["out_gate_absmean"],
-                  "mean |C| of the gate after the convolution")
-            gauge("dlrover_conv_out_absmax", conv["out_absmax"],
-                  "largest |C * conv(B * z)| entry of any layer (max of "
-                  "reporters; NaN/Inf = diverged)")
-            gauge("dlrover_conv_reporters", conv["reporters"],
-                  "trainers that have reported convolution snapshots")
-            attn = speed_monitor.attn_ledger()
-            gauge("dlrover_attn_window", attn["window"],
-                  "keys a windowed attention layer's query sees")
-            gauge("dlrover_attn_sliding_layers", attn["sliding_layers"],
-                  "windowed attention layers of the model")
-            gauge("dlrover_attn_full_score_bound",
-                  attn["full_score_bound"],
-                  "UPPER BOUND, not an observed score: longest query "
-                  "row x longest key row x scale of a full attention "
-                  "layer's heads (max of reporters; a rotation's factor "
-                  "shows here; NaN/Inf = diverged)")
-            gauge("dlrover_attn_sliding_score_bound",
-                  attn["sliding_score_bound"],
-                  "the same of a windowed layer")
-            gauge("dlrover_attn_reporters", attn["reporters"],
-                  "trainers that have reported attention snapshots")
+                if not load:
+                    gauge("dlrover_moe_expert_load", 0)
             sdc = speed_monitor.sdc_ledger()
             gauge("dlrover_sdc_checks_total", sdc["checks"],
                   "cross-replica state-digest votes performed")

@@ -26,7 +26,7 @@ TPU-first design notes:
 from __future__ import annotations
 
 import functools
-from typing import Any, Callable, Optional
+from typing import Any, Callable, Dict, Optional
 
 import flax.linen as nn
 import jax
@@ -35,6 +35,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from dlrover_tpu.models import layers
+from dlrover_tpu.models.family import Family
 from dlrover_tpu.parallel import rules as lr
 from dlrover_tpu.runtime.mesh import (
     DATA_AXIS,
@@ -245,6 +246,9 @@ class QKNorm(nn.Module):
 
 
 STATS_NAME = "attn_stats"
+# The two kinds of softmax-attention layer a layer pattern names.
+FULL_ATTENTION = "full_attention"
+SLIDING_ATTENTION = "sliding_attention"
 
 
 def score_bound(q: jax.Array, k: jax.Array, scale: float) -> jax.Array:
@@ -684,3 +688,144 @@ class LatentAttention(nn.Module):
             kernel_axes=(lr.HEADS, lr.KV, lr.EMBED), use_bias=False,
             dtype=self.dtype, param_dtype=self.param_dtype, name="wo",
         )(out)
+
+
+def from_config(cfg, kind: str = FULL_ATTENTION, **kwargs):
+    """The config's softmax attention, latent or plain: the one place that
+    reads the config's fields into the layer's, for the blocks that run it
+    and for :func:`kernel_facts`.  ``kind`` ``sliding_attention`` under its
+    window and its own rotation.  Only a model with windowed layers hands
+    ``Attention`` a rotation or asks for its score statistics: every other
+    model's program is the one it was."""
+    if cfg.latent_attention:
+        return LatentAttention(
+            num_heads=cfg.num_heads,
+            q_lora_rank=cfg.q_lora_rank,
+            kv_lora_rank=cfg.kv_lora_rank,
+            qk_nope_head_dim=cfg.qk_nope_head_dim,
+            qk_rope_head_dim=cfg.qk_rope_head_dim,
+            v_head_dim=cfg.v_head_dim,
+            rope_theta=cfg.rope_theta,
+            norm_eps=cfg.norm_eps,
+            dtype=cfg.dtype,
+            param_dtype=cfg.param_dtype,
+            attention_impl=cfg.attention_impl,
+            flash_block_q=cfg.flash_block_q,
+            flash_block_kv=cfg.flash_block_kv,
+            scale=cfg.attention_scale,
+            gate=cfg.attention_gate,
+            **kwargs,
+        )
+    return Attention(
+        num_heads=cfg.num_heads,
+        num_kv_heads=cfg.resolved_kv_heads,
+        head_dim=cfg.resolved_head_dim,
+        use_rope=cfg.position == "rope",
+        rope_theta=cfg.rope_theta,
+        use_bias=cfg.use_bias,
+        dtype=cfg.dtype,
+        param_dtype=cfg.param_dtype,
+        attention_impl=cfg.attention_impl,
+        qk_norm=cfg.qk_norm,
+        norm_eps=cfg.norm_eps,
+        flash_block_q=cfg.flash_block_q,
+        flash_block_kv=cfg.flash_block_kv,
+        scale=cfg.attention_scale,
+        decode=cfg.decode,
+        cache_len=cfg.max_seq_len,
+        init_score_std=cfg.attn_init_score_std,
+        **_by_kind(cfg, kind),
+        **kwargs,
+    )
+
+
+def _by_kind(cfg, kind: str) -> Dict[str, Any]:
+    sliding = kind == SLIDING_ATTENTION
+    if not (sliding or cfg.rope_scaling or cfg.num_sliding_layers):
+        return {}
+    return dict(
+        window=cfg.sliding_window if sliding else 0,
+        rotation=cfg.rotation(kind),
+        score_stats=bool(cfg.num_sliding_layers),
+    )
+
+
+def _read(cfg, vec) -> Dict[str, Any]:
+    """The ``attn`` event of the step's folded vector: the layers of each
+    kind, the window, and ``score_bound``: the bound of the largest ``|q
+    k^T| * scale`` before the mask (:func:`score_bound`, which the exact
+    maximum cannot pass) over the layers of each kind and over both, so
+    that a rotation's factor on the full layers' scores shows."""
+    full, sliding = (float(v) for v in vec)
+    return dict(
+        full_layers=cfg.num_full_layers,
+        sliding_layers=cfg.num_sliding_layers,
+        window=cfg.sliding_window, full_score_bound=full,
+        sliding_score_bound=sliding,
+        score_bound=float("nan") if full != full or sliding != sliding
+        else max(full, sliding),
+    )
+
+
+def kernel_facts(cfg, seq_len: int) -> Dict[str, Any]:
+    """What the step program's flash-attention kernels are:
+    ``flash_backward``, the backward it holds (``fused``: one pass,
+    ``split``: dq, then dk / dv, ``none``: no flash kernel), and
+    ``flash_blocks``, how many causal blocks of each class one (batch,
+    head) holds and the rows of a diagonal block's strips (0: the masked
+    square).  Both are chosen at trace time from the shapes alone, so this
+    asks the functions the dispatch asks, with the layer's own fields; the
+    sequence is whole inside attention under every rule table."""
+    from dlrover_tpu.ops import flash_attention as fa
+
+    layer = from_config(cfg)
+    if layer.attention_impl != "flash":
+        return {"flash_backward": "none", "flash_blocks": None}
+    if cfg.latent_attention:
+        d = layer.qk_nope_head_dim + layer.qk_rope_head_dim
+        d_v = layer.v_head_dim
+    else:
+        d = d_v = layer.head_dim
+    sizes = (seq_len, seq_len, layer.flash_block_q, layer.flash_block_kv)
+    backward = fa.backward_path(*sizes[:2], d, d_v, *sizes[2:], layer.dtype)
+    classes = fa.block_classes(*sizes, causal=True)
+    if not cfg.num_sliding_layers:
+        return {
+            "flash_backward": backward, "flash_blocks": classes._asdict(),
+        }
+    # A model with windowed layers: the counts of each kind, the steps its
+    # forward's grid really makes a (batch, head) (``grid``), and
+    # ``live_share``, the live steps among them.
+    window = from_config(cfg, SLIDING_ATTENTION).window
+    band = fa.block_classes(*sizes, causal=True, window=window)
+
+    def facts(c, edge, grid):
+        live = c.interior + edge
+        return {
+            "live": live, "interior": c.interior, "edge": edge,
+            "dead": c.dead, "grid": grid, "strip": c.strip,
+            "live_share": live / grid, "backward": backward,
+        }
+
+    return {
+        "flash_backward": backward,
+        "flash_blocks": {
+            FULL_ATTENTION: facts(
+                classes, classes.diagonal, fa.forward_grid_steps(*sizes)
+            ),
+            SLIDING_ATTENTION: facts(
+                band, band.diagonal + band.lower + band.both,
+                fa.forward_grid_steps(*sizes, window),
+            ),
+        },
+    }
+
+
+FAMILY = Family(
+    event="attn",
+    # [a full layer's score bound, a windowed layer's]: the largest
+    stats={STATS_NAME: lambda stacked: stacked.max(axis=0)},
+    has=lambda cfg: cfg.num_sliding_layers,
+    read=_read,
+    kernel_facts=kernel_facts,
+)

@@ -42,7 +42,7 @@ transposed ops alike, where the benchmark reads them.
 
 from __future__ import annotations
 
-from typing import Any, Sequence
+from typing import Any, Dict, Sequence
 
 import flax.linen as nn
 import jax
@@ -50,10 +50,13 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from dlrover_tpu.models import layers
+from dlrover_tpu.models.family import Family
 from dlrover_tpu.models.linear_attention import (
     _conv_bwd,
     _shifted_sum,
     conv_init,
+    fold_stats,
+    read_stats,
 )
 from dlrover_tpu.ops import short_conv
 from dlrover_tpu.parallel import rules as lr
@@ -160,3 +163,45 @@ class GatedShortConv(nn.Module):
             dtype=self.dtype, param_dtype=self.param_dtype,
             name="out_proj",
         )(y)
+
+
+def from_config(cfg, **kwargs) -> GatedShortConv:
+    """The config's ``conv`` mixer: the one place that reads the config's
+    fields into the layer's, for the block that runs it and for
+    :func:`kernel_facts`."""
+    return GatedShortConv(
+        conv_taps=cfg.conv_kernel, dtype=cfg.dtype,
+        param_dtype=cfg.param_dtype, **kwargs,
+    )
+
+
+def _read(cfg, vec) -> Dict[str, Any]:
+    """The ``conv`` event of the step's folded vector: the gates' mean
+    sizes and the core's largest output over the layers (no recurrent
+    state: the vector is laid out as the delta-rule mixers')."""
+    return dict(
+        layers=cfg.num_conv_layers,
+        **read_stats(vec, "gate_absmean", "out_gate_absmean", "out_absmax"),
+    )
+
+
+def kernel_facts(cfg, seq_len: int) -> Dict[str, str]:
+    """``conv_core``: how the core ``C * conv(B * z)`` runs on ``seq_len``
+    tokens, ``pallas`` (``ops/short_conv.py``'s gated form) / ``xla`` (the
+    written-out form), chosen at trace time from the shapes alone
+    (:func:`core_path`, of the projection the layer makes of a ``d_model``
+    wide input); ``none`` for a model without a ``conv`` layer."""
+    if not cfg.num_conv_layers:
+        return {"conv_core": "none"}
+    d = cfg.d_model
+    path = core_path((1, seq_len, 3 * d), (from_config(cfg).conv_taps, d))
+    return {"conv_core": "pallas" if path == "kernel" else "xla"}
+
+
+FAMILY = Family(
+    event="conv",
+    stats={STATS_NAME: fold_stats},
+    has=lambda cfg: cfg.num_conv_layers,
+    read=_read,
+    kernel_facts=kernel_facts,
+)

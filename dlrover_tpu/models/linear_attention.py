@@ -59,15 +59,17 @@ the compiled text as ``linear_attn/qkv``, ``/conv``, ``/gates``,
 from __future__ import annotations
 
 import math
-from typing import Any, Optional, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.ad_checkpoint
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from dlrover_tpu.models import layers
+from dlrover_tpu.models.family import Family
 from dlrover_tpu.ops import short_conv
 from dlrover_tpu.ops.gated_delta_rule import gated_delta_rule
 from dlrover_tpu.parallel import rules as lr
@@ -91,6 +93,22 @@ def fold_stats(stacked: jax.Array) -> jax.Array:
     if stacked.shape[1] > 3:
         folded.append(stacked[:, 3:].min(axis=0))
     return jnp.concatenate(folded)
+
+
+def read_stats(vec, first: str, second: str, largest: str = "state_absmax"):
+    """A fetched vector of this layout by name, for a family's event: the
+    two means as ``first`` and ``second``, the largest entry as ``largest``
+    and, of a per-channel rule's ``[4]``, ``min_alpha``: the smallest mean
+    decay of a channel."""
+    vec = np.asarray(vec, np.float64)
+    mean_first, mean_second, absmax = split_stats(vec)
+    read = {
+        first: float(mean_first), second: float(mean_second),
+        largest: float(absmax),
+    }
+    if vec.size > 3:
+        read["min_alpha"] = float(vec[3])
+    return read
 
 
 def _a_log_init(key, shape, dtype):
@@ -513,3 +531,67 @@ class KimiDeltaAttention(nn.Module):
             dtype=self.dtype, param_dtype=self.param_dtype,
             kernel_init=proj_init, name="wo",
         )(y)
+
+
+def from_config(cfg, **kwargs):
+    """The config's ``linear_attention`` mixer, the scalar rule's or the
+    per-channel one's: the one place that reads the config's fields into
+    the layer's, for the block that runs it and for :func:`kernel_facts`."""
+    widths = dict(
+        num_heads=cfg.resolved_linear_heads,
+        key_dim=cfg.linear_key_head_dim,
+        value_dim=cfg.linear_value_head_dim,
+        conv_taps=cfg.linear_conv_kernel,
+        norm_eps=cfg.norm_eps,
+        dtype=cfg.dtype,
+        param_dtype=cfg.param_dtype,
+        **kwargs,
+    )
+    if cfg.linear_rule == "kda":
+        return KimiDeltaAttention(
+            decay_bound=cfg.linear_decay_bound, **widths
+        )
+    return GatedDeltaNet(
+        allow_neg_eigval=cfg.linear_allow_neg_eigval, **widths
+    )
+
+
+def _read(cfg, vec) -> Dict[str, Any]:
+    """The ``linear_attn`` event of the step's folded vector."""
+    return dict(
+        layers=cfg.num_linear_layers, chunk=from_config(cfg).chunk,
+        rule=cfg.linear_rule, **read_stats(vec, "mean_alpha", "mean_beta"),
+    )
+
+
+def kernel_facts(cfg, seq_len: int) -> Dict[str, str]:
+    """``short_conv``: how the mixers' convolution runs on ``seq_len``
+    tokens, ``kernel`` (``ops/short_conv.py``) / ``xla`` (the written-out
+    form), chosen at trace time from the shapes alone (:func:`conv_path`).
+    ``kda``: how the per-channel rule runs, ``kernel`` / ``xla``
+    (``ops/kda.py`` ``plan``, which the rule asks).  Each ``none`` for a
+    model without such a layer."""
+    if not cfg.num_linear_layers:
+        return {"short_conv": "none", "kda": "none"}
+    from dlrover_tpu.ops import kda
+
+    mixer = from_config(cfg)
+    per_channel = isinstance(mixer, KimiDeltaAttention)
+    return {
+        "short_conv": conv_path(
+            seq_len, mixer.num_heads, mixer.key_dim, mixer.value_dim,
+            mixer.conv_taps, gate_in_row=not per_channel,
+        ),
+        "kda": kda.plan(mixer.key_dim, mixer.value_dim)
+        if per_channel else "none",
+    }
+
+
+FAMILY = Family(
+    event="linear_attn",
+    stats={STATS_NAME: fold_stats},
+    has=lambda cfg: cfg.num_linear_layers,
+    read=_read,
+    kernel_facts=kernel_facts,
+    absmax="state_absmax",
+)

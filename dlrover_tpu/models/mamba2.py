@@ -45,7 +45,7 @@ them.
 from __future__ import annotations
 
 import math
-from typing import Any
+from typing import Any, Dict
 
 import flax.linen as nn
 import jax
@@ -53,11 +53,15 @@ import jax.ad_checkpoint
 import jax.numpy as jnp
 
 from dlrover_tpu.models import layers
+from dlrover_tpu.models.family import Family
 from dlrover_tpu.models.linear_attention import (
     causal_depthwise_conv,
     conv_init,
+    fold_stats,
+    read_stats,
     short_conv_path,
 )
+from dlrover_tpu.ops import ssd as ssd_ops
 from dlrover_tpu.ops.ssd import ssd
 from dlrover_tpu.parallel import rules as lr
 from dlrover_tpu.runtime.mesh import shard_local
@@ -250,3 +254,81 @@ class Mamba2(nn.Module):
             ),
             name="out_proj",
         )(y)
+
+
+def from_config(cfg, **kwargs) -> Mamba2:
+    """The config's ``ssm`` mixer: the one place that reads the config's
+    fields into the layer's, for the block that runs it and for
+    :func:`kernel_facts`."""
+    return Mamba2(
+        num_heads=cfg.ssm_num_heads,
+        head_dim=cfg.ssm_head_dim,
+        state_size=cfg.ssm_state_size,
+        num_groups=cfg.ssm_groups,
+        conv_taps=cfg.ssm_conv_kernel,
+        chunk=cfg.ssm_chunk,
+        dt_min=cfg.ssm_dt_min,
+        dt_max=cfg.ssm_dt_max,
+        dt_floor=cfg.ssm_dt_floor,
+        norm_eps=cfg.norm_eps,
+        out_init_scale=cfg.ssm_out_init_scale,
+        impl=cfg.ssm_impl,
+        dtype=cfg.dtype,
+        param_dtype=cfg.param_dtype,
+        **kwargs,
+    )
+
+
+def _read(cfg, vec) -> Dict[str, Any]:
+    """The ``ssm`` event of the step's folded vector (laid out as the
+    delta-rule mixers')."""
+    mixer = from_config(cfg)
+    return dict(
+        layers=cfg.num_ssm_layers, chunk=mixer.chunk, heads=mixer.num_heads,
+        groups=mixer.num_groups, **read_stats(vec, "mean_decay", "mean_dt"),
+    )
+
+
+def kernel_facts(cfg, seq_len: int) -> Dict[str, Any]:
+    """``ssm_scan``: how the scan runs, ``kernel`` / ``xla``
+    (``ops/ssd.py``).  ``ssm_heads_per_step``: the heads one grid step of
+    the scan kernels holds (``heads_per_step``, which the kernels ask), and
+    ``ssm_tiles_per_group``: the grid steps that share one group's B and C
+    (the kernels' innermost grid axis: Nemotron-H 1, Granite-4.0-H 16;
+    where it is more than 1 the group's state stays in VMEM for all of
+    them, ``C B^T`` is formed at the first and dB, dC are written at the
+    last); both ``None`` where no kernel runs the scan.  ``short_conv``:
+    how the mixer's convolution runs on ``seq_len`` tokens
+    (:func:`conv_path`).  ``none`` for a model without such a layer."""
+    if not cfg.num_ssm_layers:
+        return {
+            "ssm_scan": "none", "ssm_heads_per_step": None,
+            "ssm_tiles_per_group": None, "short_conv": "none",
+        }
+    mixer = from_config(cfg)
+    per_step = None
+    if mixer.impl == "kernel":
+        per_step = ssd_ops.heads_per_step(
+            mixer.num_heads, mixer.head_dim, mixer.num_groups,
+            mixer.state_size, mixer.chunk, mixer.dtype,
+        )
+    return {
+        "ssm_scan": mixer.impl,
+        "ssm_heads_per_step": per_step,
+        "ssm_tiles_per_group": mixer.num_heads // mixer.num_groups
+        // per_step if per_step else None,
+        "short_conv": conv_path(
+            seq_len, mixer.num_heads, mixer.head_dim, mixer.state_size,
+            mixer.num_groups, mixer.conv_taps,
+        ),
+    }
+
+
+FAMILY = Family(
+    event="ssm",
+    stats={STATS_NAME: fold_stats},
+    has=lambda cfg: cfg.num_ssm_layers,
+    read=_read,
+    kernel_facts=kernel_facts,
+    absmax="state_absmax",
+)

@@ -16,13 +16,16 @@ matmuls at larger expert counts.
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+import json
+from typing import Any, Dict, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from dlrover_tpu.models import layers
+from dlrover_tpu.models.family import Family
 from dlrover_tpu.ops import row_gather_sum
 from dlrover_tpu.parallel import rules as lr
 
@@ -236,6 +239,7 @@ def _router_entropy(router_logits: jax.Array,
 # fewer than a binomial share of every token's k would give, because a
 # token whose best groups leave this chip's experts out sends nothing;
 # without a limit it is that binomial share and is not sown).
+STATS_NAME = "moe_stats"
 SHARE_STATS_NAME = "moe_share_stats"
 
 
@@ -384,6 +388,16 @@ def _zero_row(x):
     """``x`` with one row of zeros after its last: what a padding row's
     index (one past the end) gathers, so no pass has to mask them."""
     return jnp.concatenate([x, jnp.zeros((1,) + x.shape[1:], x.dtype)])
+
+
+def _row_path(d: int, k: int, dtype) -> Tuple[bool, int]:
+    """How ``d``-wide rows of ``dtype``, ``k`` a token, move through the
+    dropless dispatch: whether they live row-tiled between the gathers and
+    the GEMMs (``row_gather_sum.kernel_fits``), and the width the
+    fetch-and-sum kernel fetches of them (``d``, else the padded view's,
+    else 0: XLA's gather and reduction)."""
+    tiled = row_gather_sum.kernel_fits(d, k, dtype)
+    return tiled, d if tiled else row_gather_sum.padded_width(d, k, dtype)
 
 
 def _rows_for(x, pair_token, tiled: bool):
@@ -850,7 +864,7 @@ class MoEMlp(nn.Module):
         pad_share = share_missing(rows_run)
         max_load = load.max() * routed.shape[0]
         self.sow(
-            "intermediates", "moe_stats",
+            "intermediates", STATS_NAME,
             jnp.concatenate([
                 jnp.stack([entropy, drop]), load,
                 jnp.stack([pad_share, max_load]),
@@ -929,10 +943,7 @@ class MoEMlp(nn.Module):
             # the GEMMs wherever the fetch-and-sum kernel can read them;
             # rows it reads only padded stay plain, and ``_k_rows_summed``
             # pads them at the kernel's door.
-            tiled = row_gather_sum.kernel_fits(d, k, self.dtype)
-            fetched = d if tiled else row_gather_sum.padded_width(
-                d, k, self.dtype
-            )
+            tiled, fetched = _row_path(d, k, self.dtype)
             if share and fetched:
                 # most of a token's pairs have no row here: the kernel is
                 # handed the ones that have, once for its two calls (its
@@ -1039,3 +1050,149 @@ def check_share(num_experts: int, held: int, first: int, dispatch: str):
             "capacity dispatches build their [B, S, E, C] tensors over "
             "every expert"
         )
+
+
+def from_config(cfg, **kwargs) -> MoEMlp:
+    """The config's expert layer: the one place that reads the config's
+    fields into the layer's, for the blocks that run it and for
+    :func:`kernel_facts`."""
+    return MoEMlp(
+        num_experts=cfg.num_experts,
+        d_ff=cfg.resolved_moe_d_ff,
+        top_k=cfg.top_k,
+        capacity_factor=cfg.capacity_factor,
+        activation=cfg.activation,
+        dtype=cfg.dtype,
+        param_dtype=cfg.param_dtype,
+        dispatch=cfg.moe_dispatch,
+        norm_topk_prob=cfg.norm_topk_prob,
+        aux_form=cfg.moe_aux_form,
+        scoring=cfg.router_scoring,
+        router_bias=cfg.router_bias,
+        routed_scale=cfg.routed_scaling_factor,
+        experts_held=cfg.experts_held,
+        first_expert=cfg.first_expert,
+        shared_d_ff=cfg.resolved_shared_d_ff,
+        row_budget_multiple=cfg.moe_row_budget,
+        router_groups=cfg.router_groups,
+        router_topk_groups=cfg.router_topk_groups,
+        router_norm_eps=cfg.router_norm_eps,
+        **kwargs,
+    )
+
+
+def _grouped_rows(cfg):
+    """``(the layer, tiled, fetched)`` of a model with grouped experts:
+    :func:`_row_path` of a ``d_model`` wide input, which the layer asks;
+    ``None`` for any other model."""
+    if not cfg.num_experts or cfg.moe_dispatch != "grouped":
+        return None
+    layer = from_config(cfg)
+    return (layer,) + _row_path(cfg.d_model, layer.top_k, layer.dtype)
+
+
+def row_moves(cfg) -> str:
+    """Which path a token's ``top_k`` rows take through the dropless
+    dispatch's combine and the scatter's transpose: ``kernel``
+    (``ops/row_gather_sum.py``: fetched and summed in one pass) /
+    ``kernel_live`` (the same under a share of the experts: only the pairs
+    that have a row here are fetched and added) / ``kernel_padded`` and
+    ``kernel_live_padded`` (the same two for rows of whole lanes that are
+    whole tiles only padded: plain between the gathers and the GEMMs,
+    padded at the kernel's door) / ``xla`` (a gather, then a reduction),
+    ``none`` for a model without grouped experts."""
+    grouped = _grouped_rows(cfg)
+    if grouped is None:
+        return "none"
+    layer, tiled, fetched = grouped
+    if not fetched:
+        return "xla"
+    live = "_live" if layer.held < layer.num_experts else ""
+    return "kernel" + live + ("" if tiled else "_padded")
+
+
+def kernel_facts(cfg, seq_len: int) -> Dict[str, str]:
+    """``row_moves`` (:func:`row_moves`).  ``gmm_strips``: whether the
+    grouped experts' forward and ``dx`` GEMMs hold an expert's whole-K
+    strip of weights in VMEM across its row blocks, ``resident``, or
+    ``split_k:<n>/<of>`` where ``n`` of a layer's distinct forward/dx calls
+    (six for gated experts, four for ungated) split K and stream the
+    weights once a row block (``ops/grouped_matmul.py`` ``plan_tiles``,
+    which the kernel asks).  ``gmm_dw_tiles``: how many tiles the
+    weight-gradient GEMMs cut an expert's matrix into, ``into:<K tiles>x<M
+    tiles> out_of:<K>x<M>`` (``wi`` / ``wg`` and ``wo``; every M tile reads
+    the rows again, every K tile their cotangents: ``plan_dw_tiles``, which
+    the kernel asks).  Each ``none`` for a model without grouped experts."""
+    del seq_len  # a token's rows and an expert's matrix: no sequence in it
+    grouped = _grouped_rows(cfg)
+    if grouped is None:
+        return dict.fromkeys(
+            ("row_moves", "gmm_strips", "gmm_dw_tiles"), "none"
+        )
+    from dlrover_tpu.ops import grouped_matmul
+
+    layer, tiled, _ = grouped
+    widths = (cfg.d_model, layer.d_ff)
+    return {
+        "row_moves": row_moves(cfg),
+        "gmm_strips": grouped_matmul.expert_strips(
+            *widths, layer.activation == "swiglu", tiled, layer.dtype
+        ),
+        "gmm_dw_tiles": grouped_matmul.expert_dw_tiles(
+            *widths, tiled, layer.dtype
+        ),
+    }
+
+
+def _read(cfg, vec, share=None) -> Dict[str, Any]:
+    """The ``moe`` event of the step's folded vectors: router health of
+    the parameters the step routed with (layout: :func:`split_stats`).  A
+    layer told its share of the experts (or a router bias) hands
+    ``[pairs_here, bias_absmax]`` out beside the vector; any other computes
+    every pair it routes and has no bias."""
+    vec = np.asarray(vec, np.float64)
+    entropy, drop, load, pad_share, max_load = split_stats(vec)
+    share = (1.0, 0.0) if share is None else np.asarray(share, np.float64)
+    pairs_here, bias_absmax = share[:2]
+    # a group-limited router's layers also count the tokens with a pair
+    # here (``SHARE_STATS_NAME``)
+    grouped = {} if len(share) < 3 else {"tokens_here": float(share[2])}
+    # Of a token's top_k row fetches, the share that is issued: all of
+    # them, but where the live-only kernel runs those of the pairs the
+    # plan kept (routed here, less the dropped ones).  From the two means
+    # the step already returns: the layers' mean of kept / pairs to the
+    # digit while no layer drops a pair, the product of two means (not the
+    # mean of the layers' products) once one does.
+    row_fetch_share = 1.0
+    if row_moves(cfg).startswith("kernel_live"):
+        row_fetch_share = float(pairs_here) * (1.0 - float(drop))
+    layer = from_config(cfg)
+    return dict(
+        entropy=float(entropy),
+        drop_fraction=float(drop),
+        experts=int(load.size),
+        top_k=int(layer.top_k),
+        load=json.dumps([round(float(v), 6) for v in load]),
+        pad_share=float(pad_share),
+        max_expert_load=float(max_load),
+        experts_total=int(load.size),
+        held=int(layer.held),
+        pairs_here=float(pairs_here),
+        bias_absmax=float(bias_absmax),
+        row_fetch_share=row_fetch_share,
+        groups=int(layer.router_groups),
+        topk_group=int(layer.router_topk_groups),
+        **grouped,
+    )
+
+
+FAMILY = Family(
+    event="moe",
+    stats={
+        STATS_NAME: lambda stacked: stacked.mean(axis=0),
+        SHARE_STATS_NAME: fold_share_stats,
+    },
+    has=lambda cfg: cfg.num_experts,
+    read=_read,
+    kernel_facts=kernel_facts,
+)

@@ -56,24 +56,22 @@ import jax
 import jax.ad_checkpoint
 import jax.numpy as jnp
 
-from dlrover_tpu.models import layers
-from dlrover_tpu.models.attention import Attention, LatentAttention
+from dlrover_tpu.models import attention as attention_lib
 from dlrover_tpu.models import gated_conv
-from dlrover_tpu.models.linear_attention import (
-    GatedDeltaNet,
-    KimiDeltaAttention,
-)
-from dlrover_tpu.models.mamba2 import Mamba2
-from dlrover_tpu.models.moe import MoEMlp, check_share, ungated
+from dlrover_tpu.models import layers
+from dlrover_tpu.models import linear_attention
+from dlrover_tpu.models import mamba2
+from dlrover_tpu.models import moe as moe_lib
+from dlrover_tpu.models.attention import FULL_ATTENTION, SLIDING_ATTENTION
+from dlrover_tpu.models.family import Family
+from dlrover_tpu.models.moe import check_share, ungated
 from dlrover_tpu.ops import remat_policy as remat_policies
 from dlrover_tpu.ops import ssd
 from dlrover_tpu.parallel import rules as lr
 
 
-FULL_ATTENTION = "full_attention"
 LINEAR_ATTENTION = "linear_attention"
 CONV = "conv"
-SLIDING_ATTENTION = "sliding_attention"
 # Layers of TWO residual branches: a mixer (softmax attention over the
 # causal triangle or over a window of it, a delta rule or a gated short
 # convolution), then an MLP (``Block``).
@@ -887,91 +885,6 @@ def _norm(cfg: TransformerConfig, name: str):
     )
 
 
-def _attention(cfg: TransformerConfig, kind: str = FULL_ATTENTION):
-    """The config's softmax attention, latent or plain, named ``attn``;
-    ``kind`` ``sliding_attention`` under its window and its own rotation.
-    Only a model with windowed layers hands ``Attention`` a rotation or
-    asks for its score statistics: every other model's program is the one
-    it was."""
-    if cfg.latent_attention:
-        return LatentAttention(
-            num_heads=cfg.num_heads,
-            q_lora_rank=cfg.q_lora_rank,
-            kv_lora_rank=cfg.kv_lora_rank,
-            qk_nope_head_dim=cfg.qk_nope_head_dim,
-            qk_rope_head_dim=cfg.qk_rope_head_dim,
-            v_head_dim=cfg.v_head_dim,
-            rope_theta=cfg.rope_theta,
-            norm_eps=cfg.norm_eps,
-            dtype=cfg.dtype,
-            param_dtype=cfg.param_dtype,
-            attention_impl=cfg.attention_impl,
-            flash_block_q=cfg.flash_block_q,
-            flash_block_kv=cfg.flash_block_kv,
-            scale=cfg.attention_scale,
-            gate=cfg.attention_gate,
-            name="attn",
-        )
-    return Attention(
-        num_heads=cfg.num_heads,
-        num_kv_heads=cfg.resolved_kv_heads,
-        head_dim=cfg.resolved_head_dim,
-        use_rope=cfg.position == "rope",
-        rope_theta=cfg.rope_theta,
-        use_bias=cfg.use_bias,
-        dtype=cfg.dtype,
-        param_dtype=cfg.param_dtype,
-        attention_impl=cfg.attention_impl,
-        qk_norm=cfg.qk_norm,
-        norm_eps=cfg.norm_eps,
-        flash_block_q=cfg.flash_block_q,
-        flash_block_kv=cfg.flash_block_kv,
-        scale=cfg.attention_scale,
-        decode=cfg.decode,
-        cache_len=cfg.max_seq_len,
-        init_score_std=cfg.attn_init_score_std,
-        **_by_kind(cfg, kind),
-        name="attn",
-    )
-
-
-def _by_kind(cfg: TransformerConfig, kind: str) -> Dict[str, Any]:
-    sliding = kind == SLIDING_ATTENTION
-    if not (sliding or cfg.rope_scaling or cfg.num_sliding_layers):
-        return {}
-    return dict(
-        window=cfg.sliding_window if sliding else 0,
-        rotation=cfg.rotation(kind),
-        score_stats=bool(cfg.num_sliding_layers),
-    )
-
-
-def _experts(cfg: TransformerConfig):
-    return MoEMlp(
-        num_experts=cfg.num_experts,
-        d_ff=cfg.resolved_moe_d_ff,
-        top_k=cfg.top_k,
-        capacity_factor=cfg.capacity_factor,
-        activation=cfg.activation,
-        dtype=cfg.dtype,
-        param_dtype=cfg.param_dtype,
-        dispatch=cfg.moe_dispatch,
-        norm_topk_prob=cfg.norm_topk_prob,
-        aux_form=cfg.moe_aux_form,
-        scoring=cfg.router_scoring,
-        router_bias=cfg.router_bias,
-        routed_scale=cfg.routed_scaling_factor,
-        experts_held=cfg.experts_held,
-        first_expert=cfg.first_expert,
-        shared_d_ff=cfg.resolved_shared_d_ff,
-        row_budget_multiple=cfg.moe_row_budget,
-        router_groups=cfg.router_groups,
-        router_topk_groups=cfg.router_topk_groups,
-        router_norm_eps=cfg.router_norm_eps,
-        name="moe",
-    )
-
-
 def _dense_mlp(cfg: TransformerConfig):
     return Mlp(
         d_ff=cfg.resolved_d_ff,
@@ -1016,37 +929,14 @@ class Block(nn.Module):
             return _norm(cfg, name)(y)
 
         y = x if post else norm("ln_attn", x)
-        if self.kind == LINEAR_ATTENTION and cfg.linear_rule == "kda":
-            y = KimiDeltaAttention(
-                num_heads=cfg.resolved_linear_heads,
-                key_dim=cfg.linear_key_head_dim,
-                value_dim=cfg.linear_value_head_dim,
-                conv_taps=cfg.linear_conv_kernel,
-                decay_bound=cfg.linear_decay_bound,
-                norm_eps=cfg.norm_eps,
-                dtype=cfg.dtype,
-                param_dtype=cfg.param_dtype,
-                name="linear_attn",
-            )(y)
-        elif self.kind == LINEAR_ATTENTION:
-            y = GatedDeltaNet(
-                num_heads=cfg.resolved_linear_heads,
-                key_dim=cfg.linear_key_head_dim,
-                value_dim=cfg.linear_value_head_dim,
-                conv_taps=cfg.linear_conv_kernel,
-                allow_neg_eigval=cfg.linear_allow_neg_eigval,
-                norm_eps=cfg.norm_eps,
-                dtype=cfg.dtype,
-                param_dtype=cfg.param_dtype,
-                name="linear_attn",
-            )(y)
+        if self.kind == LINEAR_ATTENTION:
+            y = linear_attention.from_config(cfg, name="linear_attn")(y)
         elif self.kind == CONV:
-            y = gated_conv.GatedShortConv(
-                conv_taps=cfg.conv_kernel, dtype=cfg.dtype,
-                param_dtype=cfg.param_dtype, name="conv",
-            )(y)
+            y = gated_conv.from_config(cfg, name="conv")(y)
         else:
-            y = _attention(cfg, self.kind)(y, positions, segment_ids)
+            y = attention_lib.from_config(cfg, self.kind, name="attn")(
+                y, positions, segment_ids
+            )
         if post:
             y = norm("ln_attn", y)
         # Named checkpoint: under the "attn_out" remat policy the backward
@@ -1056,7 +946,7 @@ class Block(nn.Module):
         x = _add_branch(cfg, x, y)
         y = x if post else norm("ln_mlp", x)
         if cfg.num_experts and not self.dense_mlp:
-            y, layer_aux = _experts(cfg)(y)
+            y, layer_aux = moe_lib.from_config(cfg, name="moe")(y)
             aux = aux + layer_aux
         else:
             y = _dense_mlp(cfg)(y)
@@ -1095,27 +985,13 @@ class BranchBlock(nn.Module):
         norm = _norm(cfg, "ln")
         y = x if post else norm(x)
         if self.kind == SSM:
-            y = Mamba2(
-                num_heads=cfg.ssm_num_heads,
-                head_dim=cfg.ssm_head_dim,
-                state_size=cfg.ssm_state_size,
-                num_groups=cfg.ssm_groups,
-                conv_taps=cfg.ssm_conv_kernel,
-                chunk=cfg.ssm_chunk,
-                dt_min=cfg.ssm_dt_min,
-                dt_max=cfg.ssm_dt_max,
-                dt_floor=cfg.ssm_dt_floor,
-                norm_eps=cfg.norm_eps,
-                out_init_scale=cfg.ssm_out_init_scale,
-                impl=cfg.ssm_impl,
-                dtype=cfg.dtype,
-                param_dtype=cfg.param_dtype,
-                name="ssm",
-            )(y)
+            y = mamba2.from_config(cfg, name="ssm")(y)
         elif self.kind == ATTENTION:
-            y = _attention(cfg)(y, positions, segment_ids)
+            y = attention_lib.from_config(cfg, name="attn")(
+                y, positions, segment_ids
+            )
         elif self.kind == EXPERTS:
-            y, layer_aux = _experts(cfg)(y)
+            y, layer_aux = moe_lib.from_config(cfg, name="moe")(y)
             aux = aux + layer_aux
         else:
             y = _dense_mlp(cfg)(y)
@@ -1216,6 +1092,48 @@ class MTPModule(nn.Module):
             cfg, FULL_ATTENTION, name="block"
         )((x, jnp.zeros((), jnp.float32)), positions, segment_ids)
         return norm("norm", x), aux
+
+
+# The multi-token-prediction module's own cross-entropy (token i + 2 from
+# position i), beside the main loss it trains with: a scalar the step
+# computes itself (``trainer/train_lib.py``), no sown vector and no kernel.
+MTP_FAMILY = Family(
+    event="mtp",
+    stats={"mtp_loss": None},
+    has=lambda cfg: cfg.mtp_depth,
+    read=lambda cfg, loss: dict(
+        mtp_loss=float(loss), weight=float(cfg.mtp_weight)
+    ),
+    kernel_facts=lambda cfg, seq_len: {},
+)
+
+# Every family of layers that sows statistics or chooses kernels, in the
+# order the step folds their vectors and a report books their events.
+FAMILIES = (
+    moe_lib.FAMILY, MTP_FAMILY, linear_attention.FAMILY, mamba2.FAMILY,
+    gated_conv.FAMILY, attention_lib.FAMILY,
+)
+
+
+def families(cfg: TransformerConfig) -> Tuple[Family, ...]:
+    """The families ``cfg`` has layers of, in ``FAMILIES``' order."""
+    return tuple(family for family in FAMILIES if family.has(cfg))
+
+
+def kernel_facts(cfg: TransformerConfig, seq_len: int) -> Dict[str, Any]:
+    """How each kernel family runs in ``cfg``'s step program on sequences
+    of ``seq_len`` tokens, for the ``compile`` event: every family's keys,
+    ``none`` where ``cfg`` has no such layer.  ``short_conv`` is said by
+    both families that run the short convolution: the paths of both,
+    joined."""
+    facts: Dict[str, Any] = {}
+    for family in FAMILIES:
+        for key, said in family.kernel_facts(cfg, seq_len).items():
+            if key in facts:
+                paths = {facts[key], said} - {"none"}
+                said = "+".join(sorted(paths)) or "none"
+            facts[key] = said
+    return facts
 
 
 class TransformerLM(nn.Module):

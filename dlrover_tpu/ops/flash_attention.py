@@ -1159,3 +1159,16 @@ def mha(
         None if window is None else int(window),
     )
     return o[:, :, :sq].transpose(0, 2, 1, 3)
+
+
+def forward_grid_steps(sq, skv, block_q, block_kv, window=None) -> int:
+    """The steps the forward's kv-inner grid makes a (batch, head) at these
+    sizes, given as :func:`mha`'s caller gives them: every (q block, kv
+    block) pair, under a ``window`` the band's ``kv_steps`` a q block."""
+    block_q, block_kv, sq, skv = _blocks_and_padding(
+        sq, skv, block_q, block_kv
+    )
+    nq, nk = sq // block_q, skv // block_kv
+    if window is None:
+        return nq * nk
+    return nq * _band_classes(nq, nk, block_q, block_kv, window).kv_steps

@@ -30,11 +30,7 @@ import optax
 from dlrover_tpu.common import faults, telemetry
 from dlrover_tpu.common.log import default_logger as logger
 from dlrover_tpu.common.retry import RetryError, RetryPolicy
-from dlrover_tpu.models import attention as attention_lib
-from dlrover_tpu.models import gated_conv
-from dlrover_tpu.models import linear_attention
-from dlrover_tpu.models import mamba2
-from dlrover_tpu.models import moe as moe_lib
+from dlrover_tpu.models import transformer
 from dlrover_tpu.models.transformer import TransformerConfig, TransformerLM
 from dlrover_tpu.parallel import rules as lr
 from dlrover_tpu.runtime import compile_cache, env as renv
@@ -46,11 +42,11 @@ from dlrover_tpu.utils.profiler import pipeline_counters
 
 # End-of-source sentinel of ``ElasticTrainer._waited``.
 _NO_BATCH = object()
-# The step metrics that are vectors, not scalars: they stay on the device
-# until a report reads them.
-_STATS_KEYS = (
-    "moe_stats", moe_lib.SHARE_STATS_NAME, linear_attention.STATS_NAME,
-    mamba2.STATS_NAME, gated_conv.STATS_NAME, attention_lib.STATS_NAME,
+# The step metrics that are vectors, not scalars (the families' sown
+# statistics): they stay on the device until a report reads them.
+_STATS_KEYS = tuple(
+    name for family in transformer.FAMILIES
+    for name, fold in family.stats.items() if fold is not None
 )
 
 _PROCESS_START_BOOKED = False
@@ -170,17 +166,6 @@ class TrainerConfig:
     # logical submeshes onto this many members; ``apply_world_change``
     # moves it live without recompiling or restoring from storage.
     world: int = 0
-
-
-def _grouped_rows_tiled(cfg):
-    """``None`` for a model without grouped experts; else whether their
-    d_model-wide rows come and go row-tiled between the row moves and the
-    grouped GEMMs (``row_gather_sum.kernel_fits``, which the layer asks)."""
-    if not cfg.num_experts or cfg.moe_dispatch != "grouped":
-        return None
-    from dlrover_tpu.ops import row_gather_sum
-
-    return row_gather_sum.kernel_fits(cfg.d_model, cfg.top_k, cfg.dtype)
 
 
 class TrainerCallback:
@@ -374,16 +359,7 @@ class ElasticTrainer:
                 "persistent_misses": after["misses"] - before["misses"],
                 **(self.train.compile_parts if compile_s else {}),
                 "kernel_calls": self.train.kernel_calls,
-                **self._flash_facts(),
-                "ssm_scan": self._ssm_scan(),
-                "ssm_heads_per_step": self._ssm_heads_per_step(),
-                "ssm_tiles_per_group": self._ssm_tiles_per_group(),
-                "short_conv": self._short_conv(),
-                "row_moves": self._row_moves(),
-                "gmm_strips": self._gmm_strips(),
-                "gmm_dw_tiles": self._gmm_dw_tiles(),
-                "conv_core": self._conv_core(),
-                "kda": self._kda(),
+                **transformer.kernel_facts(model_config, config.seq_len),
             }
             logger.info("compile warmup: %s", detail)
             telemetry.event(
@@ -479,214 +455,6 @@ class ElasticTrainer:
             self._ref_accum, self.config.global_batch_size,
             self._dp_shards(),
         )
-
-    def _ssm_scan(self) -> str:
-        """How the step program's state-space scan runs, for the
-        ``compile`` event: ``kernel`` / ``xla`` (``ops/ssd.py``), ``none``
-        for a model without such a layer."""
-        cfg = self.model_config
-        return cfg.ssm_impl if cfg.num_ssm_layers else "none"
-
-    def _ssm_heads_per_step(self) -> Optional[int]:
-        """How the scan kernels' grid is cut, beside ``ssm_scan``: the
-        heads one grid step holds (``ops/ssd.py`` ``heads_per_step``, which
-        the kernels ask; a group of more heads runs as several tiles:
-        ``ssm_tiles_per_group``), ``None`` where no kernel runs the
-        scan."""
-        if self._ssm_scan() != "kernel":
-            return None
-        return self.model_config.ssm_heads_per_step
-
-    def _ssm_tiles_per_group(self) -> Optional[int]:
-        """Beside ``ssm_heads_per_step``: the grid steps that share one
-        group's B and C (the kernels' innermost grid axis: Nemotron-H 1,
-        Granite-4.0-H 16; where it is more than 1 the group's state stays
-        in VMEM for all of them, ``C B^T`` is formed at the first and dB,
-        dC are written at the last), ``None`` where no kernel runs the
-        scan."""
-        per_step = self._ssm_heads_per_step()
-        if not per_step:
-            return None
-        cfg = self.model_config
-        return cfg.ssm_num_heads // cfg.ssm_groups // per_step
-
-    def _kda(self) -> str:
-        """How the step program's per-channel delta rule runs, for the
-        ``compile`` event: ``kernel`` / ``xla`` (``ops/kda.py`` ``plan``,
-        which the rule asks), ``none`` for a model without a KDA layer."""
-        cfg = self.model_config
-        if not cfg.num_linear_layers or cfg.linear_rule != "kda":
-            return "none"
-        from dlrover_tpu.ops import kda
-
-        return kda.plan(cfg.linear_key_head_dim, cfg.linear_value_head_dim)
-
-    def _conv_core(self) -> str:
-        """How the gated short convolutions' core ``C * conv(B * z)`` runs,
-        for the ``compile`` event: ``pallas`` (``ops/short_conv.py``'s gated
-        form) / ``xla`` (the written-out form), ``none`` for a model without
-        a ``conv`` layer.  Chosen at trace time from the shapes alone, so
-        this asks the function the dispatch asks."""
-        cfg = self.model_config
-        if not cfg.num_conv_layers:
-            return "none"
-        path = gated_conv.core_path(
-            (1, self.config.seq_len, 3 * cfg.d_model),
-            (cfg.conv_kernel, cfg.d_model),
-        )
-        return "pallas" if path == "kernel" else "xla"
-
-    def _row_moves(self) -> str:
-        """Which path a token's ``top_k`` rows take through the dropless
-        dispatch's combine and the scatter's transpose, for the ``compile``
-        event: ``kernel`` (``ops/row_gather_sum.py``: fetched and summed in
-        one pass) / ``kernel_live`` (the same under a share of the experts:
-        only the pairs that have a row here are fetched and added) /
-        ``kernel_padded`` and ``kernel_live_padded`` (the same two for rows
-        of whole lanes that are whole tiles only padded: plain between the
-        gathers and the GEMMs, padded at the kernel's door) /
-        ``xla`` (a gather, then a reduction), ``none`` for a model without
-        grouped experts.  Asks the functions the layer asks."""
-        cfg = self.model_config
-        if not cfg.num_experts or cfg.moe_dispatch != "grouped":
-            return "none"
-        from dlrover_tpu.ops import row_gather_sum
-
-        row = (cfg.d_model, cfg.top_k, cfg.dtype)
-        if row_gather_sum.kernel_fits(*row):
-            padded = ""
-        elif row_gather_sum.padded_width(*row):
-            padded = "_padded"
-        else:
-            return "xla"
-        share = cfg.resolved_experts_held < cfg.num_experts
-        return ("kernel_live" if share else "kernel") + padded
-
-    def _gmm_strips(self) -> str:
-        """Whether the grouped experts' forward and ``dx`` GEMMs hold an
-        expert's whole-K strip of weights in VMEM across its row blocks,
-        for the ``compile`` event: ``resident``, or ``split_k:<n>/<of>``
-        where ``n`` of a layer's distinct forward/dx calls (six for gated
-        experts, four for ungated) split K and stream the weights once a
-        row block (``ops/grouped_matmul.py`` ``plan_tiles``, which the
-        kernel asks), ``none`` for a model without grouped experts."""
-        cfg = self.model_config
-        tiled = _grouped_rows_tiled(cfg)
-        if tiled is None:
-            return "none"
-        from dlrover_tpu.ops import grouped_matmul
-
-        return grouped_matmul.expert_strips(
-            cfg.d_model, cfg.resolved_moe_d_ff, cfg.activation == "swiglu",
-            tiled, cfg.dtype,
-        )
-
-    def _gmm_dw_tiles(self) -> str:
-        """How many tiles the grouped experts' weight-gradient GEMMs cut an
-        expert's matrix into, for the ``compile`` event: ``into:<K tiles>x<M
-        tiles> out_of:<K>x<M>`` (``wi`` / ``wg`` and ``wo``; every M tile
-        reads the rows again, every K tile their cotangents:
-        ``ops/grouped_matmul.py`` ``plan_dw_tiles``, which the kernel asks),
-        ``none`` for a model without grouped experts."""
-        cfg = self.model_config
-        tiled = _grouped_rows_tiled(cfg)
-        if tiled is None:
-            return "none"
-        from dlrover_tpu.ops import grouped_matmul
-
-        return grouped_matmul.expert_dw_tiles(
-            cfg.d_model, cfg.resolved_moe_d_ff, tiled, cfg.dtype
-        )
-
-    def _short_conv(self) -> str:
-        """How the step program's short convolutions run, for the
-        ``compile`` event: ``kernel`` (``ops/short_conv.py``) / ``xla``
-        (the written-out form), ``none`` for a model without a state-space
-        or linear-attention layer.  Chosen at trace time from the shapes
-        alone, so this asks the function the dispatch asks."""
-        cfg = self.model_config
-        seq = self.config.seq_len
-        paths = set()
-        if cfg.num_ssm_layers:
-            from dlrover_tpu.models import mamba2
-
-            paths.add(mamba2.conv_path(
-                seq, cfg.ssm_num_heads, cfg.ssm_head_dim,
-                cfg.ssm_state_size, cfg.ssm_groups, cfg.ssm_conv_kernel,
-            ))
-        if cfg.num_linear_layers:
-            from dlrover_tpu.models import linear_attention
-
-            paths.add(linear_attention.conv_path(
-                seq, cfg.resolved_linear_heads, cfg.linear_key_head_dim,
-                cfg.linear_value_head_dim, cfg.linear_conv_kernel,
-                gate_in_row=cfg.linear_rule != "kda",
-            ))
-        return "+".join(sorted(paths)) or "none"
-
-    def _flash_facts(self) -> Dict[str, Any]:
-        """What the step program's flash-attention kernels are, for the
-        ``compile`` event: ``flash_backward``, the backward it holds
-        (``fused``: one pass, ``split``: dq, then dk / dv, ``none``: no
-        flash kernel), and ``flash_blocks``, how many causal blocks of
-        each class one (batch, head) holds and the rows of a diagonal
-        block's strips (0: the masked square).  Both are chosen at trace
-        time from the shapes alone, so this asks the functions the dispatch
-        asks; the sequence is whole inside attention under every rule table
-        (``models/attention.py``)."""
-        cfg = self.model_config
-        if cfg.attention_impl != "flash":
-            return {"flash_backward": "none", "flash_blocks": None}
-        from dlrover_tpu.ops import flash_attention
-
-        d = d_v = cfg.resolved_head_dim
-        if cfg.latent_attention:
-            d = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
-            d_v = cfg.v_head_dim
-        seq = self.config.seq_len
-        blocks = (cfg.flash_block_q, cfg.flash_block_kv)
-        backward = flash_attention.backward_path(
-            seq, seq, d, d_v, *blocks, cfg.dtype
-        )
-        classes = flash_attention.block_classes(
-            seq, seq, *blocks, causal=True
-        )
-        if not cfg.num_sliding_layers:
-            return {
-                "flash_backward": backward,
-                "flash_blocks": classes._asdict(),
-            }
-        # A model with windowed layers: the counts of each kind, the steps
-        # its forward's grid really makes a (batch, head) (``grid``), and
-        # ``live_share``, the live steps among them.
-        band = flash_attention.block_classes(
-            seq, seq, *blocks, causal=True, window=cfg.sliding_window
-        )
-        block_q, _, padded, _ = flash_attention._blocks_and_padding(
-            seq, seq, *blocks
-        )
-
-        def facts(c, edge, grid):
-            live = c.interior + edge
-            return {
-                "live": live, "interior": c.interior, "edge": edge,
-                "dead": c.dead, "grid": grid, "strip": c.strip,
-                "live_share": live / grid, "backward": backward,
-            }
-
-        return {
-            "flash_backward": backward,
-            "flash_blocks": {
-                "full_attention": facts(
-                    classes, classes.diagonal,
-                    classes.dead + classes.interior + classes.diagonal,
-                ),
-                "sliding_attention": facts(
-                    band, band.diagonal + band.lower + band.both,
-                    padded // block_q * band.kv_steps,
-                ),
-            },
-        }
 
     def _build_train(
         self, grad_accum: Optional[int] = None
@@ -1466,16 +1234,15 @@ class ElasticTrainer:
         logger.info(
             "step %d loss %.4f lr %.3g", step, loss, self.current_lr(step)
         )
-        # the largest recurrent-state entry of either kind of layer; one
-        # that is not a number stays so
+        # Each family's health on the report cadence, as its own event; the
+        # largest recurrent-state entry of any of them feeds the numeric
+        # check, and one that is not a number stays so.
         absmaxes = [
             v for v in (
-                self._report_linear_attn(metrics, step),
-                self._report_ssm(metrics, step),
+                self._report_family(family, metrics, step)
+                for family in transformer.families(self.model_config)
             ) if v is not None
         ]
-        self._report_conv(metrics, step)
-        self._report_attn(metrics, step)
         state_absmax = None if not absmaxes else (
             float("nan") if any(v != v for v in absmaxes) else max(absmaxes)
         )
@@ -1496,66 +1263,6 @@ class ElasticTrainer:
             # drain RPC.  Off path (memory_report=False) this branch is
             # the one attribute read.
             self._emit_memory_event(step)
-        moe_stats = metrics.get("moe_stats")
-        if moe_stats is not None and step % cfg.report_every == 0:
-            # Router health on the report cadence (queued before the ring
-            # ships below): the vector this step's program returned, so
-            # of the parameters the step routed with, ready when its loss
-            # is.  Layout: models/moe.py ``split_stats``.
-            # A layer told its share of the experts (or a router bias)
-            # hands [pairs_here, bias_absmax] out beside the vector; any
-            # other computes every pair it routes and has no bias.
-            fetch = [moe_stats, metrics.get(moe_lib.SHARE_STATS_NAME)]
-            with pipeline_counters().host_block("moe_stats", steps=(step,)):
-                vec, share = jax.device_get(fetch)
-            vec = np.asarray(vec, np.float64)
-            entropy, drop, load, pad_share, max_load = (
-                moe_lib.split_stats(vec)
-            )
-            share = (1.0, 0.0) if share is None else np.asarray(
-                share, np.float64
-            )
-            pairs_here, bias_absmax = share[:2]
-            # a group-limited router's layers also count the tokens with a
-            # pair here (models/moe.py ``SHARE_STATS_NAME``)
-            grouped = {} if len(share) < 3 else {
-                "tokens_here": float(share[2])
-            }
-            # Of a token's top_k row fetches, the share that is issued: all
-            # of them, but where the live-only kernel runs those of the
-            # pairs the plan kept (routed here, less the dropped ones).
-            # From the two means the step already returns: the layers' mean
-            # of kept / pairs to the digit while no layer drops a pair,
-            # the product of two means (not the mean of the layers'
-            # products) once one does.
-            row_fetch_share = 1.0
-            if self._row_moves().startswith("kernel_live"):
-                row_fetch_share = float(pairs_here) * (1.0 - float(drop))
-            telemetry.event(
-                "moe", step=step,
-                entropy=float(entropy),
-                drop_fraction=float(drop),
-                experts=int(load.size),
-                top_k=int(getattr(self.model_config, "top_k", 0)),
-                load=json.dumps([round(float(v), 6) for v in load]),
-                pad_share=float(pad_share),
-                max_expert_load=float(max_load),
-                experts_total=int(load.size),
-                held=int(self.model_config.resolved_experts_held),
-                pairs_here=float(pairs_here),
-                bias_absmax=float(bias_absmax),
-                row_fetch_share=row_fetch_share,
-                groups=int(self.model_config.router_groups),
-                topk_group=int(self.model_config.router_topk_groups),
-                **grouped,
-            )
-        if "mtp_loss" in metrics and step % cfg.report_every == 0:
-            # The multi-token-prediction module's own cross-entropy (token
-            # i + 2 from position i), beside the main loss it trains with.
-            telemetry.event(
-                "mtp", step=step, mtp_loss=float(metrics["mtp_loss"]),
-                weight=float(self.model_config.mtp_weight),
-            )
         if self.client is not None:
             self.client.report_step(
                 step,
@@ -1586,107 +1293,25 @@ class ElasticTrainer:
 
         write_device_metrics()
 
-    def _report_linear_attn(self, metrics, step: int) -> Optional[float]:
-        """Linear-attention health on the report cadence: the vector this
-        step's program returned, as a ``linear_attn`` event.  Returns its
-        ``state_absmax`` for the numeric check, ``None`` where the step
-        handed none out or no report is due."""
-        read = self._state_stats(
-            metrics, step, linear_attention.STATS_NAME,
-            ("mean_alpha", "mean_beta"),
-        )
-        if read is None:
+    def _report_family(self, family, metrics, step: int) -> Optional[float]:
+        """``family``'s statistics of this step, if a report is due and the
+        step handed them out, as the family's event (``models/family.py``:
+        the declaration reads the vector and adds the layers' geometry).
+        A sown vector is the one this step's program returned, so of the
+        parameters the step ran with, ready when its loss is; it is fetched
+        here, under the ``host_block`` of its metric's name.  Returns the
+        attribute the family feeds the numeric check with, ``None`` where
+        it names none or nothing was booked."""
+        names = tuple(family.stats)
+        values = [metrics.get(name) for name in names]
+        if values[0] is None or step % self.config.report_every:
             return None
-        rule = self.model_config.linear_rule
-        mixer = (
-            linear_attention.KimiDeltaAttention if rule == "kda"
-            else linear_attention.GatedDeltaNet
-        )
-        telemetry.event(
-            "linear_attn", step=step,
-            layers=self.model_config.num_linear_layers,
-            chunk=mixer.chunk, rule=rule, **read,
-        )
-        return read["state_absmax"]
-
-    def _report_ssm(self, metrics, step: int) -> Optional[float]:
-        """The same for the state-space layers, as an ``ssm`` event (their
-        vector is laid out as the linear layers')."""
-        read = self._state_stats(
-            metrics, step, mamba2.STATS_NAME, ("mean_decay", "mean_dt")
-        )
-        if read is None:
-            return None
-        telemetry.event(
-            "ssm", step=step, layers=self.model_config.num_ssm_layers,
-            chunk=self.model_config.ssm_chunk,
-            heads=self.model_config.ssm_num_heads,
-            groups=self.model_config.ssm_groups, **read,
-        )
-        return read["state_absmax"]
-
-    def _report_conv(self, metrics, step: int) -> None:
-        """The gated short convolutions' health, as a ``conv`` event: the
-        gates' mean sizes and the core's largest output over the layers
-        (no recurrent state: the vector is laid out as theirs)."""
-        read = self._state_stats(
-            metrics, step, gated_conv.STATS_NAME,
-            ("gate_absmean", "out_gate_absmean"),
-        )
-        if read is None:
-            return
-        telemetry.event(
-            "conv", step=step, layers=self.model_config.num_conv_layers,
-            out_absmax=read.pop("state_absmax"), **read,
-        )
-
-    def _report_attn(self, metrics, step: int) -> None:
-        """The softmax attentions of a model with windowed layers, as an
-        ``attn`` event: the layers of each kind, the window, and
-        ``score_bound``: the bound of the largest ``|q k^T| * scale``
-        before the mask (``models/attention.score_bound``: the longest
-        query row times the longest key row of a head, which the exact
-        maximum cannot pass) over the layers of each kind and over both,
-        so that a rotation's factor on the full layers' scores shows."""
-        stats = metrics.get(attention_lib.STATS_NAME)
-        if stats is None or step % self.config.report_every:
-            return
-        with pipeline_counters().host_block(
-            attention_lib.STATS_NAME, steps=(step,)
-        ):
-            full, sliding = (
-                float(v) for v in np.asarray(jax.device_get(stats))
-            )
-        cfg = self.model_config
-        telemetry.event(
-            "attn", step=step, full_layers=cfg.num_full_layers,
-            sliding_layers=cfg.num_sliding_layers,
-            window=cfg.sliding_window, full_score_bound=full,
-            sliding_score_bound=sliding,
-            score_bound=float("nan") if full != full or sliding != sliding
-            else max(full, sliding),
-        )
-
-    def _state_stats(
-        self, metrics, step: int, stats_name: str, means
-    ) -> Optional[Dict[str, float]]:
-        """A recurrent mixer's sown vector of this step, if a report is due
-        (``linear_attention.split_stats``: two means, named ``means``, and
-        ``state_absmax``, the largest state entry)."""
-        stats = metrics.get(stats_name)
-        if stats is None or step % self.config.report_every:
-            return None
-        with pipeline_counters().host_block(stats_name, steps=(step,)):
-            vec = np.asarray(jax.device_get(stats), np.float64)
-        first, second, absmax = linear_attention.split_stats(vec)
-        read = {
-            means[0]: float(first), means[1]: float(second),
-            "state_absmax": float(absmax),
-        }
-        if vec.size > 3:
-            # a per-channel rule's smallest mean decay of a channel
-            read["min_alpha"] = float(vec[3])
-        return read
+        if family.stats[names[0]] is not None:
+            with pipeline_counters().host_block(names[0], steps=(step,)):
+                values = jax.device_get(values)
+        attrs = family.read(self.model_config, *values)
+        telemetry.event(family.event, step=step, **attrs)
+        return attrs.get(family.absmax)
 
     def _emit_memory_event(self, step: int):
         """One flat-attr ``memory`` event: allocator truth + classified

@@ -24,11 +24,8 @@ from flax.training import train_state as flax_train_state
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from dlrover_tpu.common.log import default_logger as logger
-from dlrover_tpu.models import attention as attention_lib
-from dlrover_tpu.models import gated_conv
-from dlrover_tpu.models import linear_attention
-from dlrover_tpu.models import mamba2
 from dlrover_tpu.models import moe as moe_lib
+from dlrover_tpu.models import transformer
 from dlrover_tpu.parallel import rules as lr
 from dlrover_tpu.runtime import compile_cache
 
@@ -482,12 +479,6 @@ def _sown_vectors(sown, name: str) -> Optional[jax.Array]:
 
 
 ROUTER_LOADS = "router_loads"
-# The recurrent mixers' sown vectors, one layout ([mean decay, mean write
-# strength or step, largest state entry]) and one fold; the gated short
-# convolution's is laid out so too ([mean |B|, mean |C|, largest |C u|]).
-_STATE_STATS = (
-    linear_attention.STATS_NAME, mamba2.STATS_NAME, gated_conv.STATS_NAME,
-)
 
 
 def _router_loads(sown) -> Dict[Tuple[str, ...], jax.Array]:
@@ -510,30 +501,27 @@ def _router_loads(sown) -> Dict[Tuple[str, ...], jax.Array]:
     return out
 
 
-def _layer_stats(sown, router_loads: bool = False) -> Dict[str, Any]:
-    """What a step hands out of the layers' sown vectors, by metric name:
-    ``moe_stats`` (mean over the layers), ``moe_share_stats`` (mean share
-    of the routed pairs computed here, largest router-bias entry) and
-    ``linear_attn_stats`` and ``ssm_stats`` (``linear_attention.fold_stats``:
-    means, and the largest state entry).  Empty for a model whose layers sow
-    none.
+def _folded(families, vectors_of) -> Dict[str, Any]:
+    """Each sown statistic of the ``families`` that ``vectors_of(name)``
+    holds as ``[n, width]``, folded to the one vector a step hands out by
+    its family's own fold (``models/family.py``), by metric name."""
+    out = {}
+    for family in families:
+        for name, fold in family.stats.items():
+            vectors = vectors_of(name) if fold is not None else None
+            if vectors is not None:
+                out[name] = fold(vectors)
+    return out
+
+
+def _layer_stats(families, sown, router_loads: bool = False) -> Dict[str, Any]:
+    """What a step hands out of the vectors the layers of ``families``
+    sowed, by metric name, each folded over the layers (``moe_stats``: the
+    mean; ``linear_attn_stats``, ``ssm_stats``, ``conv_stats``: means, and
+    the largest entry; ...).  Empty for a model whose layers sow none.
     ``router_loads`` adds each expert layer's own loads (``ROUTER_LOADS``,
     by module path) for the router-bias rule."""
-    out = {}
-    moe = _sown_vectors(sown, "moe_stats")
-    if moe is not None:
-        out["moe_stats"] = jnp.mean(moe, axis=0)
-    share = _sown_vectors(sown, moe_lib.SHARE_STATS_NAME)
-    if share is not None:
-        out[moe_lib.SHARE_STATS_NAME] = moe_lib.fold_share_stats(share)
-    for name in _STATE_STATS:
-        vectors = _sown_vectors(sown, name)
-        if vectors is not None:
-            out[name] = linear_attention.fold_stats(vectors)
-    scores = _sown_vectors(sown, attention_lib.STATS_NAME)
-    if scores is not None:
-        # [a full layer's score bound, a windowed layer's]: the largest
-        out[attention_lib.STATS_NAME] = scores.max(axis=0)
+    out = _folded(families, functools.partial(_sown_vectors, sown))
     if router_loads:
         out[ROUTER_LOADS] = _router_loads(sown)
     return out
@@ -800,10 +788,13 @@ def build_sharded_train(
     # each layer's sown stats vector out of the step that computes it; any
     # other model's step is applied as ever.
     model_config = getattr(model, "config", None)
-    sows_stats = bool(
-        getattr(model_config, "num_experts", 0)
-        or {"linear_attention", "ssm", "conv", "sliding_attention"}
-        & set(getattr(model_config, "layer_pattern", ()))
+    families = (
+        transformer.families(model_config)
+        if isinstance(model_config, transformer.TransformerConfig) else ()
+    )
+    sows_stats = any(
+        fold is not None
+        for family in families for fold in family.stats.values()
     )
     # The DeepSeek-V3 family: a multi-token-prediction module whose
     # cross-entropy joins the loss, and router biases the step moves.
@@ -836,7 +827,9 @@ def build_sharded_train(
             outs, sown = apply_fn(
                 variables, inputs, mutable=["intermediates"], **kwargs
             )
-            stats = _layer_stats(sown, router_loads=bool(bias_rate))
+            stats = _layer_stats(
+                families, sown, router_loads=bool(bias_rate)
+            )
         else:
             outs = apply_fn(variables, inputs, **kwargs)
             stats = {}
@@ -1218,17 +1211,10 @@ def build_sharded_train(
             "grad_norm": _grad_norm(grads),
             "step": new_state.step,
         }
-        if "moe_stats" in stats:
-            metrics["moe_stats"] = stats["moe_stats"].mean(axis=0)
-        if moe_lib.SHARE_STATS_NAME in stats:
-            metrics[moe_lib.SHARE_STATS_NAME] = moe_lib.fold_share_stats(
-                stats[moe_lib.SHARE_STATS_NAME]
-            )
+        # the families' vectors of the microbatches, folded as the layers'
+        metrics.update(_folded(families, stats.get))
         if "mtp_ce_sum" in stats:
             metrics["mtp_loss"] = stats["mtp_ce_sum"].sum() / mtp_total
-        for name in _STATE_STATS:
-            if name in stats:
-                metrics[name] = linear_attention.fold_stats(stats[name])
         return new_state, metrics
 
     if grad_accum > 1:

@@ -71,15 +71,11 @@ def test_the_published_widths_count_what_the_issue_counts():
 def test_the_compile_event_asks_what_the_dispatch_asks(
     model, scan, heads, tiles, rows
 ):
-    from dlrover_tpu.trainer.elastic_trainer import ElasticTrainer
+    from dlrover_tpu.models import transformer
 
-    class Stub:
-        model_config = model()
-        _ssm_scan = ElasticTrainer._ssm_scan
-        _ssm_heads_per_step = ElasticTrainer._ssm_heads_per_step
-
-    stub = Stub()
-    assert ElasticTrainer._ssm_scan(stub) == scan
-    assert ElasticTrainer._ssm_heads_per_step(stub) == heads
-    assert ElasticTrainer._ssm_tiles_per_group(stub) == tiles
-    assert ElasticTrainer._row_moves(stub) == rows
+    # none of the four reads the sequence's length
+    facts = transformer.kernel_facts(model(), 4096)
+    assert facts["ssm_scan"] == scan
+    assert facts["ssm_heads_per_step"] == heads
+    assert facts["ssm_tiles_per_group"] == tiles
+    assert facts["row_moves"] == rows

@@ -46,13 +46,13 @@ def test_the_master_renders_the_share_the_bias_and_the_mtp_loss_as_gauges():
                        bias_absmax=0.004, later_attr="ignored", **common)
     monitor.record_moe(1, step=5, held=32, pairs_here=0.126,
                        bias_absmax=0.006, **common)
-    monitor.record_mtp(0, step=5, mtp_loss=10.0, weight=0.3)
-    monitor.record_mtp(1, step=5, mtp_loss=10.5)
+    monitor.record_health("mtp", 0, step=5, mtp_loss=10.0, weight=0.3)
+    monitor.record_health("mtp", 1, step=5, mtp_loss=10.5)
     ledger = monitor.moe_ledger()
     assert ledger["held"] == 32 and ledger["experts"] == 256
     assert ledger["pairs_here"] == pytest.approx(0.125)
     assert ledger["bias_absmax"] == 0.006         # the largest replica's
-    assert monitor.mtp_loss() == pytest.approx(10.25)
+    assert monitor.health_ledger("mtp")["mtp_loss"] == pytest.approx(10.25)
     text = JobTimeline().render_metrics(speed_monitor=monitor)
     for name, value in (
         ("dlrover_moe_experts_held", "32"),
@@ -70,4 +70,4 @@ def test_the_master_renders_the_share_the_bias_and_the_mtp_loss_as_gauges():
     older.record_moe(0, step=1, **common)
     assert older.moe_ledger()["held"] == 256
     assert older.moe_ledger()["pairs_here"] == 1.0
-    assert older.mtp_loss() == 0.0
+    assert older.health_ledger("mtp")["mtp_loss"] == 0.0

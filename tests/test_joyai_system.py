@@ -154,13 +154,9 @@ def test_fit_books_the_share_and_the_mtp_loss_on_the_report_cadence(
     monkeypatch.setenv("DLROVER_TPU_SOCKET_DIR", str(tmp_path / "socks"))
     # rows of 64 go through XLA's gather (``row_moves: xla``); the second
     # case reports as a trainer whose rows fit the live-only kernel does
-    assert ElasticTrainer._row_moves(
-        type("Stub", (), {"model_config": config()})()
-    ) == "xla"
+    assert moe_lib.row_moves(config()) == "xla"
     if metrics_lag:
-        monkeypatch.setattr(
-            ElasticTrainer, "_row_moves", lambda self: "kernel_live"
-        )
+        monkeypatch.setattr(moe_lib, "row_moves", lambda cfg: "kernel_live")
     trainer = ElasticTrainer(
         config(),
         TrainerConfig(

@@ -172,15 +172,15 @@ def test_the_master_renders_the_conv_event_as_gauges():
     from dlrover_tpu.master.timeline import JobTimeline
 
     monitor = SpeedMonitor()
-    monitor.record_conv(
-        0, step=5, layers=13, gate_absmean=0.5,
+    monitor.record_health(
+        "conv", 0, step=5, layers=13, gate_absmean=0.5,
         out_gate_absmean=0.75, out_absmax=2.5,
     )
-    monitor.record_conv(
-        1, step=5, layers=13, gate_absmean=0.25,
+    monitor.record_health(
+        "conv", 1, step=5, layers=13, gate_absmean=0.25,
         out_gate_absmean=0.75, out_absmax=7.5, later_field=1,
     )
-    ledger = monitor.conv_ledger()
+    ledger = monitor.health_ledger("conv")
     assert ledger["out_absmax"] == 7.5 and ledger["gate_absmean"] == 0.375
     assert ledger["layers"] == 13 and ledger["reporters"] == 2
     assert "chunk" not in ledger and "state_absmax" not in ledger
@@ -196,9 +196,9 @@ def test_the_master_renders_the_conv_event_as_gauges():
             for line in text.splitlines()
         ), name
     # a value that is not a number on one replica must show
-    monitor.record_conv(1, step=6, layers=13, out_absmax=float("nan"))
-    assert monitor.conv_ledger()["out_absmax"] != (
-        monitor.conv_ledger()["out_absmax"]
+    monitor.record_health("conv", 1, step=6, layers=13, out_absmax=float("nan"))
+    assert monitor.health_ledger("conv")["out_absmax"] != (
+        monitor.health_ledger("conv")["out_absmax"]
     )
     # no reporter: the gauges read neutral
-    assert SpeedMonitor().conv_ledger()["out_absmax"] == 0.0
+    assert SpeedMonitor().health_ledger("conv")["out_absmax"] == 0.0

@@ -132,46 +132,44 @@ def test_fit_books_the_conv_event_from_the_step_itself(monkeypatch, tmp_path):
     # the event as it is shipped (unknown attributes and all) is what the
     # master's ledger takes
     monitor = SpeedMonitor()
-    monitor.record_conv(0, **conv[-1])
-    assert monitor.conv_ledger()["out_absmax"] == conv[-1]["out_absmax"]
-    assert monitor.conv_ledger()["layers"] == 4
+    monitor.record_health("conv", 0, **conv[-1])
+    ledger = monitor.health_ledger("conv")
+    assert ledger["out_absmax"] == conv[-1]["out_absmax"]
+    assert ledger["layers"] == 4
 
 
 def test_the_compile_event_says_how_the_core_runs():
+    from dlrover_tpu.models import transformer
     from dlrover_tpu.models.lfm2_moe import lfm2_moe_config
     from dlrover_tpu.models.transformer import TransformerConfig
-    from dlrover_tpu.trainer.elastic_trainer import ElasticTrainer
 
-    def stub(cfg, seq=SEQ):
-        return type("Stub", (), {
-            "model_config": cfg,
-            "config": type("C", (), {"seq_len": seq})(),
-        })()
+    def facts(cfg, seq=SEQ):
+        return transformer.kernel_facts(cfg, seq)
 
-    assert ElasticTrainer._conv_core(stub(config())) == "xla"
-    assert ElasticTrainer._conv_core(stub(TransformerConfig())) == "none"
+    assert facts(config())["conv_core"] == "xla"
+    assert facts(TransformerConfig())["conv_core"] == "none"
     published = lfm2_moe_config(
         num_layers=17, first_k_dense=1, experts_held=8, vocab_size=16384
     )
-    assert ElasticTrainer._short_conv(stub(published, 8192)) == "none"
+    assert facts(published, 8192)["short_conv"] == "none"
     # tokens whole lane tiles and d whole row tiles: the Pallas form
-    assert ElasticTrainer._conv_core(stub(published, 8192)) == "pallas"
+    assert facts(published, 8192)["conv_core"] == "pallas"
     # a length that is no whole lane tiles: the written-out form
-    assert ElasticTrainer._conv_core(stub(published, 8000)) == "xla"
+    assert facts(published, 8000)["conv_core"] == "xla"
     # rows of 2,048 are 16 lane tiles, 4 a token: the fetch-and-sum kernel
     # under a share of the experts
-    assert ElasticTrainer._row_moves(stub(published, 8192)) == "kernel_live"
+    assert facts(published, 8192)["row_moves"] == "kernel_live"
     # wo's [1792, 2048] strip and the transposed wi's and wg's, which the
     # default scoped VMEM would cut in two, stay whole under the limit the
     # calls ask for: none of a layer's six forward/dx GEMMs splits K
-    assert ElasticTrainer._gmm_strips(stub(published, 8192)) == "resident"
-    assert ElasticTrainer._gmm_strips(stub(TransformerConfig())) == "none"
+    assert facts(published, 8192)["gmm_strips"] == "resident"
+    assert facts(TransformerConfig())["gmm_strips"] == "none"
     # the weight gradients' [2048, 1792] and [1792, 2048], seven tiles each
     # under the default scoped VMEM, are one tile under the limit they ask
-    assert ElasticTrainer._gmm_dw_tiles(stub(published, 8192)) == (
+    assert facts(published, 8192)["gmm_dw_tiles"] == (
         "into:1x1 out_of:1x1"
     )
-    assert ElasticTrainer._gmm_dw_tiles(stub(TransformerConfig())) == "none"
+    assert facts(TransformerConfig())["gmm_dw_tiles"] == "none"
 
 
 def test_the_scopes_the_benchmark_reads_reach_the_compiled_text(tokens):

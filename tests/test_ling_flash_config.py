@@ -131,15 +131,15 @@ def test_the_master_renders_the_new_fields_as_gauges():
     from dlrover_tpu.master.timeline import JobTimeline
 
     monitor = SpeedMonitor()
-    monitor.record_linear_attn(
-        0, step=5, layers=6, chunk=128, mean_alpha=0.8, mean_beta=0.5,
+    monitor.record_health(
+        "linear_attn", 0, step=5, layers=6, chunk=128, mean_alpha=0.8, mean_beta=0.5,
         state_absmax=2.5, rule="kda", min_alpha=0.25,
     )
-    monitor.record_linear_attn(
-        1, step=5, layers=6, chunk=128, mean_alpha=0.6, mean_beta=0.5,
+    monitor.record_health(
+        "linear_attn", 1, step=5, layers=6, chunk=128, mean_alpha=0.6, mean_beta=0.5,
         state_absmax=7.5, rule="kda", min_alpha=0.125,
     )
-    assert monitor.linear_attn_ledger()["min_alpha"] == 0.125
+    assert monitor.health_ledger("linear_attn")["min_alpha"] == 0.125
     monitor.record_moe(
         0, step=5, experts=512, top_k=8, held=32, pairs_here=0.0625,
         tokens_here=0.25, groups=8, topk_group=4, load="[]",
@@ -159,7 +159,7 @@ def test_the_master_renders_the_new_fields_as_gauges():
         ), name
     # an older trainer's events carry neither: the gauges read neutral
     older = SpeedMonitor()
-    older.record_linear_attn(0, step=1, layers=6, chunk=128)
+    older.record_health("linear_attn", 0, step=1, layers=6, chunk=128)
     older.record_moe(0, step=1, experts=8, top_k=2, load="[]")
-    assert older.linear_attn_ledger()["min_alpha"] == 1.0
+    assert older.health_ledger("linear_attn")["min_alpha"] == 1.0
     assert older.moe_ledger()["tokens_here"] == 1.0

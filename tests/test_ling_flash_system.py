@@ -150,40 +150,37 @@ def test_fit_books_the_new_fields_from_the_step_itself(
     assert train_lib.trace_count("train_step") == 1
 
 
-def _stub(cfg, seq=SEQ):
-    return type("Stub", (), {
-        "model_config": cfg, "config": type("C", (), {"seq_len": seq})(),
-    })()
-
-
 def test_the_compile_event_says_which_rule_runs():
+    from dlrover_tpu.models import transformer
     from dlrover_tpu.models.olmo_hybrid import olmo_hybrid_config
     from dlrover_tpu.models.transformer import TransformerConfig
-    from dlrover_tpu.trainer.elastic_trainer import ElasticTrainer
 
-    assert ElasticTrainer._kda(_stub(config())) == "xla"
+    def facts(cfg, seq=SEQ):
+        return transformer.kernel_facts(cfg, seq)
+
+    assert facts(config())["kda"] == "xla"
     wide = config(
         linear_num_heads=2, linear_key_head_dim=128, linear_value_head_dim=128
     )
-    assert ElasticTrainer._kda(_stub(wide)) == "kernel"
+    assert facts(wide)["kda"] == "kernel"
     # the scalar rule's layers and a model without linear layers: none
     hybrid = olmo_hybrid_config(
         num_layers=4, d_model=32, num_heads=4, linear_num_heads=4,
         linear_key_head_dim=8, linear_value_head_dim=16, vocab_size=128,
     )
-    assert ElasticTrainer._kda(_stub(hybrid)) == "none"
-    assert ElasticTrainer._kda(_stub(TransformerConfig())) == "none"
+    assert facts(hybrid)["kda"] == "none"
+    assert facts(TransformerConfig())["kda"] == "none"
     # the convolution's row is [q | k | v] alone: at the published widths
     # on 8192 tokens it runs as the kernel, as the hybrid's does
     published = numerics.ling_flash_config(
         num_layers=7, first_k_dense=1, experts_held=32
     )
-    assert ElasticTrainer._short_conv(_stub(published, 8192)) == "kernel"
-    assert ElasticTrainer._kda(_stub(published, 8192)) == "kernel"
+    assert facts(published, 8192)["short_conv"] == "kernel"
+    assert facts(published, 8192)["kda"] == "kernel"
     # rows of 2,560 are 20 lane tiles: padded to 24 at the fetch-and-sum
     # kernel's door, and under the cell's share (32 of 512 experts) only
     # the pairs that have a row here are fetched
-    assert ElasticTrainer._row_moves(_stub(published, 8192)) == (
+    assert facts(published, 8192)["row_moves"] == (
         "kernel_live_padded"
     )
 

@@ -63,7 +63,7 @@ def test_bad_values_of_the_new_fields_raise(overrides, message):
 
 
 def test_the_defaults_leave_every_other_model_as_it_was():
-    from dlrover_tpu.models import transformer
+    from dlrover_tpu.models import attention
 
     plain = TransformerConfig()
     assert plain.sliding_window == 0 and plain.rope_scaling == ""
@@ -72,13 +72,13 @@ def test_the_defaults_leave_every_other_model_as_it_was():
     assert SLIDING_ATTENTION in TWO_BRANCH_KINDS
     assert plain.rotation() == layers.Rotation(10000.0)
     # no window, no rotation, no statistics handed to Attention
-    assert transformer._by_kind(plain, FULL_ATTENTION) == {}
+    assert attention._by_kind(plain, FULL_ATTENTION) == {}
     yarn_only = TransformerConfig(
         position="rope", rope_scaling="yarn", rope_scaling_factor=4.0,
         rope_original_max_position=64, rope_beta_fast=32.0,
         rope_beta_slow=1.0, rope_attention_factor=1.1,
     )
-    assert transformer._by_kind(yarn_only, FULL_ATTENTION) == dict(
+    assert attention._by_kind(yarn_only, FULL_ATTENTION) == dict(
         window=0, rotation=yarn_only.rotation(), score_stats=False
     )
 
@@ -247,19 +247,20 @@ def test_the_master_renders_the_attn_event_as_gauges():
     from dlrover_tpu.master.timeline import JobTimeline
 
     monitor = SpeedMonitor()
-    monitor.record_attn(
-        0, step=5, full_layers=2, sliding_layers=6, window=1024,
+    monitor.record_health(
+        "attn", 0, step=5, full_layers=2, sliding_layers=6, window=1024,
         full_score_bound=12.5, sliding_score_bound=7.5, score_bound=12.5,
     )
-    monitor.record_attn(
-        1, step=5, full_layers=2, sliding_layers=6, window=1024,
+    monitor.record_health(
+        "attn", 1, step=5, full_layers=2, sliding_layers=6, window=1024,
         full_score_bound=14.5, sliding_score_bound=6.5, score_bound=14.5,
         later_field=1,
     )
-    ledger = monitor.attn_ledger()
+    ledger = monitor.health_ledger("attn")
     assert ledger["full_score_bound"] == 14.5
     assert ledger["sliding_score_bound"] == 7.5
-    assert ledger["layers"] == 8 and ledger["reporters"] == 2
+    assert ledger["full_layers"] + ledger["sliding_layers"] == 8
+    assert ledger["reporters"] == 2
     assert ledger["window"] == 1024 and "state_absmax" not in ledger
     text = JobTimeline().render_metrics(speed_monitor=monitor)
     for name, value in (
@@ -273,8 +274,8 @@ def test_the_master_renders_the_attn_event_as_gauges():
             line.startswith(name + " ") and line.split()[1].startswith(value)
             for line in text.splitlines()
         ), name
-    monitor.record_attn(1, step=6, full_score_bound=float("nan"))
-    assert monitor.attn_ledger()["full_score_bound"] != (
-        monitor.attn_ledger()["full_score_bound"]
+    monitor.record_health("attn", 1, step=6, full_score_bound=float("nan"))
+    assert monitor.health_ledger("attn")["full_score_bound"] != (
+        monitor.health_ledger("attn")["full_score_bound"]
     )
-    assert SpeedMonitor().attn_ledger()["score_bound"] == 0.0
+    assert SpeedMonitor().health_ledger("attn")["score_bound"] == 0.0

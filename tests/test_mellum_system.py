@@ -27,9 +27,9 @@ SEQ, BATCH, VOCAB = 32, 8, numerics.VOCAB
 ONE_PERIOD = dict(num_layers=4)
 
 
-def batches(n, seed=0):
+def batches(n, seed=0, batch=BATCH):
     rng = np.random.default_rng(seed)
-    rows = rng.integers(0, VOCAB, (n, BATCH, SEQ + 1), dtype=np.int32)
+    rows = rng.integers(0, VOCAB, (n, batch, SEQ + 1), dtype=np.int32)
     return [{"inputs": r[:, :-1], "targets": r[:, 1:]} for r in rows]
 
 
@@ -67,19 +67,16 @@ def test_the_score_bound_bounds_the_scores_and_carries_the_factor(rng):
     )
 
 
-def test_fit_books_the_attn_event_from_the_step_itself(monkeypatch, tmp_path):
-    """Ten steps at ``report_every=5``: two ``attn`` and two ``moe`` events
-    carrying the step's own numbers, one ``compile`` event that counts each
-    kind's blocks; the servicer hands the ``attn`` event to the master's
-    ledger."""
+def fit_ten_steps(monkeypatch, tmp_path, batch=BATCH, **options):
+    """``(the trainer, what the recorder took, the metrics of each step)``
+    of ten steps of one period on ``batch`` sequences at
+    ``report_every=5``; ``options``: further fields of ``TrainerConfig``."""
     from dlrover_tpu.common import telemetry
-    from dlrover_tpu.master.speed_monitor import SpeedMonitor
     from dlrover_tpu.trainer.elastic_trainer import (
         ElasticTrainer,
         TrainerConfig,
     )
 
-    train_lib.reset_trace_counts()
     monkeypatch.setenv("DLROVER_TPU_JOB", f"mellum_{tmp_path.name}")
     monkeypatch.setenv("DLROVER_TPU_SOCKET_DIR", str(tmp_path / "socks"))
     cfg = config(max_seq_len=SEQ, attention_impl="flash", flash_block_q=8,
@@ -89,17 +86,48 @@ def test_fit_books_the_attn_event_from_the_step_itself(monkeypatch, tmp_path):
         trainer = ElasticTrainer(
             cfg,
             TrainerConfig(
-                global_batch_size=BATCH, seq_len=SEQ, learning_rate=1e-2,
+                global_batch_size=batch, seq_len=SEQ, learning_rate=1e-2,
                 optimizer="adafactor", ckpt_every=1000, report_every=5,
-                metrics_lag=4, warmup_compile=True,
+                metrics_lag=4, warmup_compile=True, **options,
             ),
             client=None,
         )
         trainer.fit(
-            batches(10), max_steps=10,
+            batches(10, batch=batch), max_steps=10,
             on_step=lambda step, metrics: seen.update({step: metrics}),
         )
-        taken = tap.take()
+        return trainer, tap.take(), seen
+
+
+def test_fit_books_the_attn_event_under_accumulation(monkeypatch, tmp_path):
+    """Two microbatches a step: the score bounds are folded over them as
+    over the layers, and the ``attn`` event is booked as at one (until PR
+    56 the accumulating step dropped ``attn_stats``, and none was)."""
+    # two rows a device, so that a step can be two microbatches
+    trainer, taken, seen = fit_ten_steps(
+        monkeypatch, tmp_path, batch=2 * BATCH, grad_accum=2
+    )
+    assert trainer.grad_accum == 2
+    attn = [e[4] for e in taken if e[:2] == ("attn", "event")]
+    assert [e["step"] for e in attn] == [5, 10]
+    for event in attn:
+        vec = np.asarray(
+            seen[event["step"]][attention_lib.STATS_NAME], np.float64
+        )
+        assert np.isfinite(event["score_bound"])
+        assert event["score_bound"] == pytest.approx(float(vec.max()))
+        assert min(vec) > 0
+
+
+def test_fit_books_the_attn_event_from_the_step_itself(monkeypatch, tmp_path):
+    """Ten steps at ``report_every=5``: two ``attn`` and two ``moe`` events
+    carrying the step's own numbers, one ``compile`` event that counts each
+    kind's blocks; the servicer hands the ``attn`` event to the master's
+    ledger."""
+    from dlrover_tpu.master.speed_monitor import SpeedMonitor
+
+    train_lib.reset_trace_counts()
+    _, taken, seen = fit_ten_steps(monkeypatch, tmp_path)
     events = [e for e in taken if e[1] == "event"]
     (compiled,) = [e[-1] for e in taken if e[0] == "compile"]
     blocks = compiled["flash_blocks"]
@@ -133,50 +161,43 @@ def test_fit_books_the_attn_event_from_the_step_itself(monkeypatch, tmp_path):
     assert train_lib.trace_count("train_step") == 1
     # the event as it is shipped is what the master's ledger takes
     monitor = SpeedMonitor()
-    monitor.record_attn(0, **attn[-1])
-    assert monitor.attn_ledger()["score_bound"] == attn[-1]["score_bound"]
-    assert monitor.attn_ledger()["layers"] == 4
+    monitor.record_health("attn", 0, **attn[-1])
+    ledger = monitor.health_ledger("attn")
+    assert ledger["score_bound"] == attn[-1]["score_bound"]
+    assert ledger["full_layers"] + ledger["sliding_layers"] == 4
 
 
 def test_a_model_without_windowed_layers_books_what_it_booked():
     """The ``compile`` event's ``flash_blocks`` keeps its four counts, and
     no ``attn_stats`` is sown."""
-    from dlrover_tpu.trainer.elastic_trainer import ElasticTrainer
-
-    def stub(cfg, seq=SEQ):
-        return type("Stub", (), {
-            "model_config": cfg,
-            "config": type("C", (), {"seq_len": seq})(),
-        })()
-
     from dlrover_tpu.models.transformer import TransformerConfig
 
+    facts = transformer_lib.kernel_facts
     plain = TransformerConfig(attention_impl="flash")
-    facts = ElasticTrainer._flash_facts(stub(plain, 4096))
-    assert facts["flash_blocks"] == dict(
+    assert facts(plain, 4096)["flash_blocks"] == dict(
         dead=6, interior=6, diagonal=4, strip=256
     )
     published = numerics.mellum_config(
         num_layers=8, experts_held=16, vocab_size=24576,
         attention_impl="flash",
     )
-    facts = ElasticTrainer._flash_facts(stub(published, 32768))["flash_blocks"]
-    assert facts["full_attention"]["live"] == 528
-    band = facts["sliding_attention"]
+    blocks = facts(published, 32768)["flash_blocks"]
+    assert blocks["full_attention"]["live"] == 528
+    band = blocks["sliding_attention"]
     assert (band["live"], band["grid"], band["backward"]) == (
         63, 64, "fused"
     )
     assert band["live_share"] == 63 / 64
     # rows of 2,304 are 18 lane tiles: padded at the fetch-and-sum kernel's
     # door; the 896-wide strips stay whole-K
-    assert ElasticTrainer._row_moves(stub(published, 32768)) == (
+    assert facts(published, 32768)["row_moves"] == (
         "kernel_live_padded"
     )
-    assert ElasticTrainer._gmm_strips(stub(published, 32768)) == "resident"
+    assert facts(published, 32768)["gmm_strips"] == "resident"
     # ... and each weight gradient is ONE tile under the limit its call asks
     # for (2304 x 896 was seven tiles of 128 lanes until PR 55, and read
     # every row block seven times)
-    assert ElasticTrainer._gmm_dw_tiles(stub(published, 32768)) == (
+    assert facts(published, 32768)["gmm_dw_tiles"] == (
         "into:1x1 out_of:1x1"
     )
 
@@ -245,7 +266,7 @@ def test_the_seeded_scores_spread_as_the_config_says(score_std):
                 init_score_std=score_std,
             ).init(jax.random.PRNGKey(0), jnp.zeros((1, 8, 32)))
     cfg = config(attn_init_score_std=score_std)
-    assert transformer_lib._attention(cfg).init_score_std == score_std
+    assert attention_lib.from_config(cfg).init_score_std == score_std
 
 
 def test_num_params_counts_what_is_held():

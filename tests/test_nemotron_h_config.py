@@ -77,12 +77,11 @@ def test_the_published_widths_count_what_the_issue_counts():
 
 
 def test_a_model_without_such_a_layer_names_no_scan():
-    from dlrover_tpu.trainer.elastic_trainer import ElasticTrainer
+    from dlrover_tpu.models import transformer
 
-    stub = type("T", (), {"model_config": TransformerConfig()})()
-    assert ElasticTrainer._ssm_scan(stub) == "none"
-    stub.model_config = config()
-    assert ElasticTrainer._ssm_scan(stub) == "xla"
+    facts = transformer.kernel_facts(TransformerConfig(), 64)
+    assert facts["ssm_scan"] == "none"
+    assert transformer.kernel_facts(config(), 64)["ssm_scan"] == "xla"
 
 
 @pytest.mark.parametrize("cap,said,dw_said", [
@@ -98,16 +97,14 @@ def test_a_model_without_such_a_layer_names_no_scan():
 ])
 def test_the_compile_event_names_the_gemm_strips(monkeypatch, cap, said,
                                                  dw_said):
+    from dlrover_tpu.models import transformer
     from dlrover_tpu.ops import grouped_matmul
-    from dlrover_tpu.trainer.elastic_trainer import ElasticTrainer
 
     if cap is not None:
         monkeypatch.setattr(grouped_matmul, "_VMEM_CAP", cap)
-    stub = type("T", (), {
-        "model_config": nemotron_h_config(experts_held=16)
-    })()
-    assert ElasticTrainer._gmm_strips(stub) == said
-    assert ElasticTrainer._gmm_dw_tiles(stub) == dw_said
+    facts = transformer.kernel_facts(nemotron_h_config(experts_held=16), 8192)
+    assert facts["gmm_strips"] == said
+    assert facts["gmm_dw_tiles"] == dw_said
 
 
 @pytest.mark.parametrize("overrides,rows", [
@@ -124,12 +121,11 @@ def test_the_compile_event_names_the_gemm_strips(monkeypatch, cap, said,
     (None, "xla"),
 ])
 def test_the_compile_event_names_the_row_moves(overrides, rows):
+    from dlrover_tpu.models import transformer
     from dlrover_tpu.ops import row_gather_sum
-    from dlrover_tpu.trainer.elastic_trainer import ElasticTrainer
 
     cfg = config() if overrides is None else nemotron_h_config(**overrides)
-    stub = type("T", (), {"model_config": cfg})()
-    assert ElasticTrainer._row_moves(stub) == rows
+    assert transformer.kernel_facts(cfg, 8192)["row_moves"] == rows
     row = (cfg.d_model, cfg.top_k, cfg.dtype)
     # rows of whole tiles go row-tiled THROUGH THE GEMMS: these never do
     assert not row_gather_sum.kernel_fits(*row)
@@ -143,15 +139,15 @@ def test_the_master_renders_the_events_as_gauges():
     from dlrover_tpu.master.timeline import JobTimeline
 
     monitor = SpeedMonitor()
-    monitor.record_ssm(
-        0, step=5, layers=8, chunk=128, mean_decay=0.8, mean_dt=0.02,
+    monitor.record_health(
+        "ssm", 0, step=5, layers=8, chunk=128, mean_decay=0.8, mean_dt=0.02,
         state_absmax=2.5, later_attr="ignored",
     )
-    monitor.record_ssm(
-        1, step=5, layers=8, chunk=128, mean_decay=0.6, mean_dt=0.04,
+    monitor.record_health(
+        "ssm", 1, step=5, layers=8, chunk=128, mean_decay=0.6, mean_dt=0.04,
         state_absmax=7.5,
     )
-    ledger = monitor.ssm_ledger()
+    ledger = monitor.health_ledger("ssm")
     assert ledger["reporters"] == 2 and ledger["layers"] == 8
     assert ledger["mean_decay"] == pytest.approx(0.7)
     assert ledger["state_absmax"] == 7.5          # the worst replica's
@@ -171,15 +167,29 @@ def test_the_master_renders_the_events_as_gauges():
         ), name
     # a state that diverged on one replica shows as such, and the linear
     # layers' ledger is its own
-    monitor.record_ssm(1, step=10, state_absmax=float("nan"))
-    assert np.isnan(monitor.ssm_ledger()["state_absmax"])
-    assert monitor.linear_attn_ledger()["reporters"] == 0
+    monitor.record_health("ssm", 1, step=10, state_absmax=float("nan"))
+    assert np.isnan(monitor.health_ledger("ssm")["state_absmax"])
+    assert monitor.health_ledger("linear_attn")["reporters"] == 0
 
 
 def test_the_servicer_routes_the_event_to_the_ledger():
-    import inspect
+    import pickle
 
-    from dlrover_tpu.master import servicer
+    from dlrover_tpu.master import messages as msg
+    from dlrover_tpu.master.servicer import MasterServicer
+    from dlrover_tpu.master.speed_monitor import HEALTH_KINDS, SpeedMonitor
+    from dlrover_tpu.master.timeline import JobTimeline
 
-    source = inspect.getsource(servicer)
-    assert 'name == "ssm"' in source and "record_ssm(node, **attrs)" in source
+    # by the kind's row of the one table, not by a branch of its own
+    assert "ssm" in HEALTH_KINDS
+    monitor = SpeedMonitor()
+    servicer = MasterServicer(speed_monitor=monitor, timeline=JobTimeline())
+    attrs = dict(step=5, layers=8, chunk=128, mean_decay=0.8, mean_dt=0.02,
+                 state_absmax=2.5, heads=64, groups=8)
+    wire = pickle.dumps(msg.Envelope(
+        node_id=3,
+        payload=msg.TelemetryEvents(3, (("ssm", "event", 0.0, 0.0, attrs),)),
+    ))
+    assert servicer.report(msg.safe_loads(wire)).success
+    ledger = monitor.health_ledger("ssm")
+    assert ledger["reporters"] == 1 and ledger["state_absmax"] == 2.5

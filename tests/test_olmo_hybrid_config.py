@@ -86,15 +86,15 @@ def test_the_master_renders_the_events_as_gauges():
     from dlrover_tpu.master.timeline import JobTimeline
 
     monitor = SpeedMonitor()
-    monitor.record_linear_attn(
-        0, step=5, layers=6, chunk=64, mean_alpha=0.8, mean_beta=1.0,
+    monitor.record_health(
+        "linear_attn", 0, step=5, layers=6, chunk=64, mean_alpha=0.8, mean_beta=1.0,
         state_absmax=2.5, later_attr="ignored",
     )
-    monitor.record_linear_attn(
-        1, step=5, layers=6, chunk=64, mean_alpha=0.6, mean_beta=1.2,
+    monitor.record_health(
+        "linear_attn", 1, step=5, layers=6, chunk=64, mean_alpha=0.6, mean_beta=1.2,
         state_absmax=7.5,
     )
-    ledger = monitor.linear_attn_ledger()
+    ledger = monitor.health_ledger("linear_attn")
     assert ledger["reporters"] == 2 and ledger["layers"] == 6
     assert ledger["mean_alpha"] == pytest.approx(0.7)
     assert ledger["state_absmax"] == 7.5          # the worst replica's
@@ -112,8 +112,8 @@ def test_the_master_renders_the_events_as_gauges():
             for line in text.splitlines()
         ), name
     # a state that diverged on one replica shows as such
-    monitor.record_linear_attn(1, step=10, state_absmax=float("nan"))
-    assert np.isnan(monitor.linear_attn_ledger()["state_absmax"])
+    monitor.record_health("linear_attn", 1, step=10, state_absmax=float("nan"))
+    assert np.isnan(monitor.health_ledger("linear_attn")["state_absmax"])
 
 
 def test_a_state_that_is_not_finite_is_the_anomaly_a_loss_would_be():

@@ -253,12 +253,28 @@ def test_earlier_models_keep_their_lowered_step_text(preset):
 # kept the parent's texts (CHANGES.md, PR 45).  PR 53 recorded both anew for
 # the grouped GEMMs' one-step body (the note above): they read
 # 63af0acf...9706c9955 and 80d960d2...39bf378.  Whoever edits the router, the
-# scan kernels or the grouped GEMMs next re-pins them.
+# scan kernels or the grouped GEMMs next re-pins them.  PR 56 recorded the
+# other four presets' texts at its parent ebf9455, BEFORE it moved the fold of
+# the layers' sown statistics and the layers' construction behind each
+# family's declaration (``models/family.py``): Granite's (the scan kernels at
+# sixteen tiles a group, the four multipliers), Ling's (the per-channel delta
+# rule, the group-limited router), LFM2's (the gated short convolution) and
+# Mellum2's (the banded flash kernels, two rotations, ``attn_stats``).  All
+# ten texts standing unedited after it is the CPU's certificate that the chip
+# runs the programs it ran.
 LATER_PRESETS_LOWERED = {
     "joyai-llm-flash":
         "c92d3a044315cdf4b513e023bed00f6fb542564c765d739e3971ef4b5ee5b0bf",
     "nemotron-3-nano-30b-a3b":
         "cedfb9810641b29ef3ef7d973511726f2eb95ded9dec29a377f2de3c46aea18c",
+    "granite-4.0-h-small":
+        "f5b5219e173325e666075902a50e7d675076e9d75f6792f38ec208ace70a5ec3",
+    "ling-3.0-flash-vl":
+        "32e2f1afb936167fe50c9c8145e1a6899033877334ad15a3c81e8d1968cd8df0",
+    "lfm2-8b-a1b":
+        "e7bc689f6c5100b1ca8b4f1ba6b06b0b5e8e6b2b3ad6251b7f902de9d9ef0364",
+    "mellum2-12b-a2.5b":
+        "9a4487fbfeccc099066a58a2f909ef0c77e5c58a72bb9845006caa9d2b8e8b67",
 }
 
 
@@ -266,7 +282,8 @@ LATER_PRESETS_LOWERED = {
 def test_the_multipliers_and_the_tiles_default_to_nothing(preset):
     """A config that names none of the four multipliers, and a scan whose
     group is one grid step, lower to the step they lowered to (the other
-    four pinned texts are held above)."""
+    four pinned texts are held above); so do the four presets recorded
+    since."""
     cfg = TransformerConfig()
     assert (cfg.embed_scale, cfg.attention_scale, cfg.residual_scale,
             cfg.logit_scale) == (1.0, 0.0, 1.0, 1.0)

@@ -1194,6 +1194,59 @@ def test_tel001_dead_route_fires(tmp_path):
     assert "route::ghost" in symbols
 
 
+TEL_FAMILY_TABLE = """\
+HEALTH_KINDS = {"ssm": {"gauges": ()}, "ghost": {"gauges": ()}}
+"""
+
+TEL_FAMILY_MASTER = """\
+from pkg.table import HEALTH_KINDS
+
+class SpeedMonitor:
+    def record_health(self, kind, node, **attrs):
+        pass
+
+class Servicer:
+    def _report_telemetry(self, events):
+        for name, duration_s, attrs in events:
+            if name in HEALTH_KINDS:
+                self.speed_monitor.record_health(name, 0, **attrs)
+"""
+
+TEL_FAMILY_MODELS = """\
+from pkg import telemetry
+
+class Family:
+    def __init__(self, event, read):
+        self.event, self.read = event, read
+
+FAMILY = Family(event="ssm", read=dict)
+UNROUTED = Family(event="conv", read=dict)
+
+def report(family, vec):
+    telemetry.event(family.event, **family.read(vec))
+"""
+
+
+@pytest.mark.parametrize("symbol,fires", [
+    # a kind routed by a table the routing module imports, and emitted by
+    # a family's declaration, is neither dead nor unrouted ...
+    ("route::ssm", False), ("event::ssm", False),
+    # ... a row no family declares is a dead route, a family no row routes
+    # an unrouted event
+    ("route::ghost", True), ("event::conv", True),
+])
+def test_tel001_reads_the_family_table_and_declarations(
+    tmp_path, symbol, fires
+):
+    report = lint_files(tmp_path, {
+        "pkg/telemetry.py": TEL_TELEMETRY,
+        "pkg/table.py": TEL_FAMILY_TABLE,
+        "pkg/master.py": TEL_FAMILY_MASTER,
+        "pkg/models.py": TEL_FAMILY_MODELS,
+    }, select=["TEL001"])
+    assert (symbol in {f.symbol for f in report.findings}) == fires
+
+
 def test_tel001_silent_without_routing_functions(tmp_path):
     """Single-file fixtures with no master in sight emit freely."""
     report = lint_files(tmp_path, {

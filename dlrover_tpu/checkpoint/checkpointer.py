@@ -38,10 +38,14 @@ class Checkpointer:
     SIGKILL after it finds that step there; the persist runs behind the
     training loop.  What the call blocks for is the device-to-host path:
     measured on a TPU v5e at 3.37 GB of state (PERF.md §5), half a second
-    (6.7 GB/s) once the arena's pages are resident, some ten seconds for
-    a job's first save, which touches them for the first time, and 6-7 s
-    where a save falls back to the per-shard copy (0.5 GB/s; event
-    ``checkpoint.d2h_fallback``).
+    (6.7 GB/s) once the arena's pages are resident, and 6-7 s where a save
+    falls back to the per-shard copy (0.5 GB/s; event
+    ``checkpoint.d2h_fallback``).  Making the arena, touching its pages for
+    the first time (8-18 s for those 3.37 GB) and compiling the staged
+    programs (6.5 s on an empty compile cache) are a job's first save's
+    only where nobody did them before it: ``prepare``, which a trainer
+    calls at its start with the state's description, does them on a thread
+    beside the step program's compile (PERF.md §6, PR 57).
     """
 
     def __init__(
@@ -59,6 +63,19 @@ class Checkpointer:
             num_hosts=num_hosts,
             local_saver=local_saver,
         )
+
+    def prepare(
+        self, state: Any, extra: Optional[Dict[str, Any]] = None, **ids
+    ):
+        """Start a job's first save's one-time work now, on a thread:
+        ``state`` describes what will be saved (``ShapeDtypeStruct``
+        leaves under their shardings; ``CheckpointEngine.prepare``)."""
+        self._engine.prepare(state, extra, **ids)
+
+    def take_arena_wait(self) -> Optional[float]:
+        """Seconds the first save was blocked for ``prepare``'s thread
+        (0.0: the work was hidden), once; None otherwise."""
+        return self._engine.take_arena_wait()
 
     def save_checkpoint(
         self,

@@ -26,6 +26,7 @@ from __future__ import annotations
 import dataclasses
 import os
 import pickle
+import threading
 import time
 import zlib
 from enum import Enum
@@ -490,6 +491,81 @@ class CheckpointEngine(StorageStepReader):
         self._status = SharedDict(status_name(self.host_index), create=False)
         self._latest_memory_step = -1
         self._latest_storage_step = -1
+        # ``prepare``'s thread, the instant its arena is mapped, and the
+        # seconds the first save (or ``close``) was blocked for it.
+        self._preparing: Optional[threading.Thread] = None
+        self._arena_mapped = threading.Event()
+        self._arena_wait_s: Optional[float] = None
+
+    # -- the first save's one-time work, ahead of it --------------------------------
+
+    def prepare(
+        self, state: Any, extra: Optional[Dict[str, Any]] = None, **ids
+    ):
+        """Start, on a daemon thread, what this host's first save of a
+        state like ``state`` (its leaves described: ``ShapeDtypeStruct``s
+        under their shardings) would otherwise do inside the training
+        loop: ``SharedMemoryHandler.prepare``.  ``ids`` (a trainer's
+        ``restart_count``) go on the thread's span.
+
+        ``save_to_memory`` and ``close`` wait for the thread; ``load``
+        waits until the arena is mapped, which is all a reader touches.
+        The thread holds the arena's lock as a save does; where the saver
+        holds it (a restart in place while the last save is persisted) or
+        the work raises, ``checkpoint.prepare_skipped`` says so (``reason``
+        ``shm_busy`` / ``error``) and the first save does what is missing,
+        as it always did."""
+        self._preparing = threading.Thread(
+            target=self._prepare, args=(state, extra, ids),
+            name="checkpoint-prepare", daemon=True,
+        )
+        self._preparing.start()
+
+    def _prepare(self, state, extra, ids):
+        try:
+            if not self._lock.acquire(blocking=False):
+                logger.info("shm busy (saver persisting); arena not prepared")
+                telemetry.event(
+                    "checkpoint.prepare_skipped", reason="shm_busy", **ids
+                )
+                return
+            try:
+                t0 = time.monotonic()
+                found = self._shm.prepare(
+                    state, extra, on_mapped=self._arena_mapped.set, **ids
+                )
+                logger.info(
+                    "arena prepared ahead of the first save in %.3fs: %s",
+                    time.monotonic() - t0, found,
+                )
+            finally:
+                self._lock.release()
+        except Exception as e:  # noqa: BLE001 - the first save does the work
+            logger.warning("arena not prepared, the first save will: %s", e)
+            telemetry.event(
+                "checkpoint.prepare_skipped", reason="error",
+                error=type(e).__name__, **ids,
+            )
+        finally:
+            self._arena_mapped.set()
+
+    def _await_prepared(self):
+        """Block until ``prepare``'s thread is done; the first wait books
+        its seconds (0.0 where the work was hidden)."""
+        thread, self._preparing = self._preparing, None
+        if thread is not None:
+            t0 = time.monotonic()
+            thread.join()
+            self._arena_wait_s = time.monotonic() - t0
+            logger.info(
+                "waited %.3fs for the arena's preparation", self._arena_wait_s
+            )
+
+    def take_arena_wait(self) -> Optional[float]:
+        """Seconds a caller was blocked for ``prepare``, once: None before
+        the wait, after it was taken, and where nothing was prepared."""
+        waited, self._arena_wait_s = self._arena_wait_s, None
+        return waited
 
     # -- save -----------------------------------------------------------------
 
@@ -498,6 +574,7 @@ class CheckpointEngine(StorageStepReader):
     ) -> bool:
         """Pack ``state`` into shm.  Skips (returns False) if the saver is
         mid-persist — never blocks training on storage I/O."""
+        self._await_prepared()
         if not self._lock.acquire(blocking=False):
             logger.info(
                 "step %d: shm busy (saver persisting); skip memory save", step
@@ -550,6 +627,8 @@ class CheckpointEngine(StorageStepReader):
         ``treedef`` (or a flat ``{path: array}`` dict when no treedef) with
         leaves ``device_put`` under ``shardings`` when given.
         """
+        if self._preparing is not None:
+            self._arena_mapped.wait()
         meta = self._shm.load_meta()
         shm_ok = meta is not None and self._all_local(meta)
         shm_step = meta.step if shm_ok else -1
@@ -672,6 +751,7 @@ class CheckpointEngine(StorageStepReader):
         return self._latest_memory_step
 
     def close(self):
+        self._await_prepared()
         if self._saver is not None:
             self._saver.stop()
         self._shm.close()

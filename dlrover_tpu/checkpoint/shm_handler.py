@@ -22,6 +22,7 @@ from __future__ import annotations
 import collections
 import dataclasses
 import functools
+import math
 import mmap
 import os
 import pickle
@@ -94,23 +95,71 @@ def _slices_to_index(
     return tuple(out)
 
 
+def _local_replica_zero(shape, sharding) -> List[Tuple[Tuple, Any]]:
+    """``(index, device)`` of every block of a leaf of ``shape`` under
+    ``sharding`` that this host stores: the addressable devices that hold
+    replica 0 of their index, in the sharding's device order.  The
+    numbering is the one jax gives a shard's ``replica_id`` (devices that
+    hold one index count up in that order), read from the sharding alone,
+    so a leaf that does not exist yet gives the records its array will."""
+    copies: collections.Counter = collections.Counter()
+    local, owned = [], []
+    for device, slices in sharding.devices_indices_map(shape).items():
+        index = _slices_to_index(slices, shape)
+        replica, copies[index] = copies[index], copies[index] + 1
+        if device in sharding.addressable_devices:
+            local.append((index, device))
+            if replica == 0:
+                owned.append((index, device))
+    # All local replicas are duplicates owned elsewhere: keep one so
+    # single-host restore still works (harmless duplicate on disk).
+    return owned or local[:1]
+
+
 def _select_shards(leaf) -> Tuple[Tuple[int, ...], str, List[Tuple[Tuple, Any]]]:
-    """Return (global_shape, dtype, [(index, device_or_np_block)]) — no D2H."""
-    if isinstance(leaf, jax.Array) and hasattr(leaf, "addressable_shards"):
-        shards = []
-        for shard in leaf.addressable_shards:
-            if shard.replica_id != 0:
-                continue
-            shards.append((_slices_to_index(shard.index, leaf.shape), shard.data))
-        if not shards and leaf.addressable_shards:
-            # All local replicas are duplicates owned elsewhere; keep one so
-            # single-host restore still works (harmless duplicate on disk).
-            shard = leaf.addressable_shards[0]
-            shards.append((_slices_to_index(shard.index, leaf.shape), shard.data))
-        return tuple(leaf.shape), np.dtype(leaf.dtype).name, shards
-    block = np.ascontiguousarray(leaf)
-    index = tuple((0, d) for d in np.shape(leaf))
-    return tuple(np.shape(leaf)), block.dtype.name, [(index, block)]
+    """Return (global_shape, dtype, [(index, block)]) — no D2H.
+
+    A block is a single-device ``jax.Array``, a host array or, for a leaf
+    that is only described (a ``jax.ShapeDtypeStruct``, with or without a
+    sharding), the description of that block: what ``prepare`` plans a
+    first save from.  Either kind of leaf is read by its shape, dtype and
+    sharding, so both give the same records."""
+    shape = tuple(np.shape(leaf))
+    abstract = isinstance(leaf, jax.ShapeDtypeStruct)
+    sharding = getattr(leaf, "sharding", None)
+    if sharding is None or not (abstract or isinstance(leaf, jax.Array)):
+        block = leaf if abstract else np.ascontiguousarray(leaf)
+        index = tuple((0, d) for d in shape)
+        return shape, np.dtype(block.dtype).name, [(index, block)]
+    if abstract:
+        def block_on(index, device):
+            return jax.ShapeDtypeStruct(
+                tuple(stop - start for start, stop in index), leaf.dtype,
+                sharding=jax.sharding.SingleDeviceSharding(device),
+            )
+    else:
+        data = {s.device: s.data for s in leaf.addressable_shards}
+
+        def block_on(index, device):
+            return data[device]
+    shards = [
+        (index, block_on(index, device))
+        for index, device in _local_replica_zero(shape, sharding)
+    ]
+    return shape, np.dtype(leaf.dtype).name, shards
+
+
+def _nbytes(block) -> int:
+    """Bytes of a block, present or described."""
+    return math.prod(block.shape) * np.dtype(block.dtype).itemsize
+
+
+def _device_of(block):
+    """The device of a block, or None for one on the host."""
+    if isinstance(block, jax.Array):
+        return block.device
+    sharding = getattr(block, "sharding", None)
+    return None if sharding is None else next(iter(sharding.device_set))
 
 
 def _plan_pytree(
@@ -118,7 +167,8 @@ def _plan_pytree(
 ) -> Tuple[CheckpointMeta, List[Any]]:
     """The meta of a save and, in the order of its records, the block each
     record's bytes come from (a single-device ``jax.Array`` or a host
-    array).  Nothing is copied: a record's size is its block's."""
+    array; its description where ``state`` is described).  Nothing is
+    copied: a record's size is its block's."""
     tensors: List[TensorMeta] = []
     sources: List[Any] = []
     offset = 0
@@ -126,17 +176,18 @@ def _plan_pytree(
         global_shape, dtype, shards = _select_shards(leaf)
         records = []
         for index, block in shards:
+            nbytes = _nbytes(block)
             records.append(
                 ShardRecord(
                     index=index,
                     offset=offset,
-                    nbytes=block.nbytes,
+                    nbytes=nbytes,
                     # A scalar's block is stored as one element, (1,).
                     shape=tuple(block.shape) or (1,),
                 )
             )
             sources.append(block)
-            offset += block.nbytes
+            offset += nbytes
         tensors.append(
             TensorMeta(
                 path=tuple(jax.tree_util.keystr([k]) for k in path),
@@ -154,9 +205,10 @@ def _plan_pytree(
     return meta, sources
 
 
-def _minor_faults() -> int:
-    """Pages this process has touched for the first time, so far."""
-    return resource.getrusage(resource.RUSAGE_SELF).ru_minflt
+def _minor_faults(who: int = resource.RUSAGE_SELF) -> int:
+    """Pages this process (``RUSAGE_THREAD``: the calling thread) has
+    touched for the first time, so far."""
+    return resource.getrusage(who).ru_minflt
 
 
 def _as_bytes(block: np.ndarray) -> np.ndarray:
@@ -299,8 +351,9 @@ class SharedMemoryHandler:
         tag = f"{job}_" if job else ""
         self.name = f"dlrover_tpu_ckpt_{tag}{name}".replace("/", "_")
         self._shm: Optional[SharedMemory] = None
-        # Staged plans by (shape, dtype, device): compiled at the first
-        # save that meets a block of that kind, and never again.
+        # Staged plans by (shape, dtype, device): compiled by ``prepare``,
+        # else at the first save that meets a block of that kind, and
+        # never again.
         self._staged: Dict[Tuple, Optional[_StagedPlan]] = {}
         #: Path the last save took and the bytes a second it moved from
         #: the device into the arena.
@@ -329,7 +382,7 @@ class SharedMemoryHandler:
         meta_bytes = pickle.dumps(meta)
         data_offset = _HEADER.size + len(meta_bytes)
         size = sum(r.nbytes for t in meta.tensors for r in t.shards)
-        fresh = self._ensure_capacity(data_offset + size)
+        mapped = self._ensure_capacity(data_offset + size)
         buf = self._shm.buf
         # Crash-consistency ordering: invalidate the header first, then write
         # data + meta, then publish the header *last*.  A trainer SIGKILLed
@@ -357,13 +410,86 @@ class SharedMemoryHandler:
             del arena
             buf[_HEADER.size : data_offset] = meta_bytes
             buf[: _HEADER.size] = _HEADER.pack(len(meta_bytes))
-        if fresh:
+        if mapped:
             self._settle(data_offset + size)
         return meta
 
+    def prepare(
+        self, state: Any, extra: Optional[Dict[str, Any]] = None,
+        on_mapped=None, **ids,
+    ) -> Dict[str, Any]:
+        """Do a job's first save's one-time work for a state that does not
+        exist yet, so that the first save is a later save.
+
+        ``state`` describes what will be saved: a pytree whose leaves are
+        ``jax.ShapeDtypeStruct``s under their shardings (and ``extra`` the
+        sidecar a save will carry).  Its records are ``_plan_pytree``'s,
+        so the bytes reckoned here are the bytes the save writes.  Then,
+        on the paths the first save would take (``_ensure_capacity``,
+        ``_settle``, ``_staged_plan``):
+
+        * the arena is mapped; ``on_mapped()`` is called once it is, after
+          which a reader (``load_meta``) may use this handler;
+        * a mapping this call CREATED is written once, with zeros (its
+          header then says "no checkpoint", which is true), and settled:
+          on the v5e machines those are the two slow passes of a new
+          mapping (``_settle``), and the first save's write is the third;
+        * an arena that was THERE (a restart in place) holds the only copy
+          of the last acknowledged save: it is attached and read a byte a
+          page, never written, and one too small for ``state`` is left
+          alone (the restore may still want it; the first save makes the
+          larger one);
+        * the staged programs of every kind of block the state has are
+          compiled, so that ``_staged`` is full before the first save.
+
+        Returns the attributes of its span, ``checkpoint.prepare`` (which
+        also takes ``ids``, a trainer's ``restart_count``).  The caller
+        holds the arena's lock as a save does.  A state that turns
+        out larger than described costs nothing but this work: the save
+        recreates the arena as it always did.
+        """
+        with telemetry.span("checkpoint.prepare", **ids) as span:
+            faults = _minor_faults(resource.RUSAGE_THREAD)
+            meta, sources = _plan_pytree(state, 0, extra)
+            total = _HEADER.size + len(pickle.dumps(meta)) + sum(
+                r.nbytes for t in meta.tensors for r in t.shards
+            )
+            try:
+                mapped = self._ensure_capacity(total, ahead=True)
+            finally:
+                if on_mapped is not None:
+                    on_mapped()
+            write_s = 0.0
+            if mapped == "created":
+                # numpy fills without the GIL: a trainer's tracing, on
+                # another thread, is not held up (PERF.md section 6, PR 57).
+                t0 = time.monotonic()
+                np.frombuffer(self._shm.buf, np.uint8, count=total).fill(0)
+                write_s = time.monotonic() - t0
+            if mapped:
+                self._settle(total)
+            for block in sources:
+                device = _device_of(block)
+                if device is not None:
+                    self._staged_plan(block.shape, block.dtype, device)
+            found = {
+                "bytes": total, "created": mapped == "created",
+                # the zeros' pass: the mapping's first (0.0: none made)
+                "write_s": round(write_s, 6),
+                # what the first save finds compiled
+                "programs": sum(
+                    len(plan.programs) for plan in self._staged.values()
+                    if plan is not None
+                ),
+                "minflt": _minor_faults(resource.RUSAGE_THREAD) - faults,
+            }
+            if span is not None:
+                span.attrs.update(found)
+        return found
+
     def _settle(self, total: int):
-        """Read a byte of every page this save has just written for the
-        first time.
+        """Read a byte of every page of a mapping that has just been
+        written for the first time.
 
         On the v5e machines a new mapping is at full speed only from its
         third pass on: 3.37 GB are written in 8.2-10.0 s, then in 1.5-1.8 s,
@@ -371,7 +497,8 @@ class SharedMemoryHandler:
         byte a page (PERF.md section 6, PR 26).  Left alone it falls on the
         job's next save, whose stall then swings with the host's memory
         from run to run (1.32-1.82 s against 0.46-0.50 s for every later
-        save); here the save that made the mapping pays for all of it.
+        save); here whoever made the mapping pays for all of it: ``prepare``
+        at a trainer's start, else the save that found none.
         """
         with telemetry.span("checkpoint.arena_settle", bytes=total):
             pages = np.frombuffer(self._shm.buf, dtype=np.uint8, count=total)
@@ -390,7 +517,10 @@ class SharedMemoryHandler:
             if isinstance(block, jax.Array)
         ]
         try:
-            plans = [self._staged_plan(block) for _, block in device]
+            plans = [
+                self._staged_plan(block.shape, block.dtype, block.device)
+                for _, block in device
+            ]
             reason = self._refusal(device, plans)
             if reason is None:
                 return host, self._stage(device, plans, arena)
@@ -408,24 +538,31 @@ class SharedMemoryHandler:
         left = host + [(o, b) for (o, _), b in zip(device, blocks)]
         return left, {"path": "per_shard", "groups": 0, "launch_s": launch_s}
 
-    def _staged_plan(self, block) -> Optional[_StagedPlan]:
-        """The plan for blocks like ``block`` (None: per-shard copy)."""
-        key = (block.shape, block.dtype.name, block.device.id)
+    def _staged_plan(self, shape, dtype, device) -> Optional[_StagedPlan]:
+        """The plan for blocks of ``shape`` and ``dtype`` on ``device``
+        (None: per-shard copy), compiled for a described block, so that
+        it can be made before any block exists."""
+        dtype = np.dtype(dtype)
+        key = (tuple(shape), dtype.name, device.id)
         if key in self._staged:
             return self._staged[key]
         plan = None
+        size = math.prod(shape)
         # (a flat block has nothing to undo)
-        if block.ndim >= 2 and block.nbytes >= _STAGED_MIN_BYTES:
-            most = _PIECE_BYTES // block.dtype.itemsize
+        if len(shape) >= 2 and size * dtype.itemsize >= _STAGED_MIN_BYTES:
+            most = _PIECE_BYTES // dtype.itemsize
             sizes = [
-                min(most, block.size - first)
-                for first in range(0, block.size, most)
+                min(most, size - first) for first in range(0, size, most)
             ]
             groups, first = [], 0
             for i in range(0, len(sizes), _GROUP_PIECES):
                 group = tuple(sizes[i : i + _GROUP_PIECES])
                 groups.append((first, group))
                 first += sum(group)
+            block = jax.ShapeDtypeStruct(
+                tuple(shape), dtype,
+                sharding=jax.sharding.SingleDeviceSharding(device),
+            )
             programs = {
                 group: compile_cache.staged_compile(
                     jax.jit(functools.partial(_flat_pieces, sizes=group)),
@@ -501,23 +638,32 @@ class SharedMemoryHandler:
             "launch_s": time.monotonic() - started - landing_s,
         }
 
-    def _ensure_capacity(self, total: int) -> bool:
-        """Whether this call mapped the arena anew."""
+    def _ensure_capacity(self, total: int, ahead: bool = False) -> str:
+        """How this call mapped the arena: ``"created"``, ``"attached"``
+        or, where the mapping it has is large enough, ``""``."""
         if self._shm is not None and self._shm.size >= total:
-            return False
-        # Only a save that creates, grows or re-attaches the arena comes
-        # here.  A new mapping's pages are not touched yet: the first write
-        # into them (``checkpoint.d2h``) pays for that, and ``_settle`` for
-        # their second pass.
-        with telemetry.span("checkpoint.arena", bytes=total) as span:
-            created = self._open_arena(total)
+            return ""
+        # The one place the arena is created, grown or re-attached:
+        # ``prepare`` comes here at a trainer's start (``ahead``), a save
+        # only where that was skipped or the state outgrew it.  A new
+        # mapping's pages are not touched yet: the first write into them
+        # pays for that (``prepare``'s zeros; else ``checkpoint.d2h``), and
+        # ``_settle`` for their second pass.
+        with telemetry.span(
+            "checkpoint.arena", bytes=total, ahead=ahead
+        ) as span:
+            mapped = self._open_arena(total, replace=not ahead)
             if span is not None:
-                span.attrs["created"] = created
-        return True
+                span.attrs["created"] = mapped == "created"
+        return mapped
 
-    def _open_arena(self, total: int) -> bool:
-        """Attach the arena if one of this name is large enough, else
-        (re)create it; returns whether it was created."""
+    def _open_arena(self, total: int, replace: bool = True) -> str:
+        """Attach the arena if one of this name is large enough
+        (``"attached"``), else (re)create it (``"created"``).  Without
+        ``replace`` an arena that is there and too small is left as it is
+        (``""``): ahead of a restore it may hold the only copy of the
+        last acknowledged save, and the save that needs the room
+        replaces it, as it always did."""
         if self._shm is not None:
             self._shm.close()
             self._shm.unlink()
@@ -529,11 +675,13 @@ class SharedMemoryHandler:
         if existing is not None:
             if existing.size >= total:
                 self._shm = existing
-                return False
+                return "attached"
             existing.close()
+            if not replace:
+                return ""
             existing.unlink()
         self._shm = SharedMemory(self.name, create=True, size=size)
-        return True
+        return "created"
 
     # -- reader side (agent or restarted trainer) -----------------------------
 

@@ -38,6 +38,7 @@ _COUNTER_KINDS: Dict[str, str] = {
     "worker_start": "worker_starts",
     "checkpoint.skip": "checkpoint_skipped",
     "checkpoint.d2h_fallback": "checkpoint_d2h_fallback",
+    "checkpoint.prepare_skipped": "checkpoint_prepare_skipped",
 }
 
 

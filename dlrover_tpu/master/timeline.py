@@ -429,6 +429,9 @@ class JobTimeline:
             worker_starts = self._counters.get("worker_starts", 0)
             checkpoint_skipped = self._counters.get("checkpoint_skipped", 0)
             d2h_fallbacks = self._counters.get("checkpoint_d2h_fallback", 0)
+            prepare_skipped = self._counters.get(
+                "checkpoint_prepare_skipped", 0
+            )
             persisted_steps = dict(self._persisted_steps)
         gauge("dlrover_telemetry_dropped_total", dropped,
               "events the node telemetry rings overwrote before a drain")
@@ -450,6 +453,9 @@ class JobTimeline:
         gauge("dlrover_checkpoint_d2h_fallback_total", d2h_fallbacks,
               "saves that left the staged device-to-host path for the "
               "per-shard copy (too little free HBM, or a device error)")
+        gauge("dlrover_checkpoint_prepare_skipped_total", prepare_skipped,
+              "trainer starts that left the arena and the staged programs "
+              "to the first save (arena busy with a persist, or an error)")
         if persisted_steps:
             lines.append(
                 "# HELP dlrover_persisted_step newest step the node's "

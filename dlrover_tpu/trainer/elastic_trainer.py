@@ -332,6 +332,21 @@ class ElasticTrainer:
         # set could close over anything, so either one opts out.
         self._cacheable = optimizer is None and rules is None
         self.train = self._build_train()
+        self._ckpt = None
+        if config.checkpoint_dir:
+            from dlrover_tpu.checkpoint import Checkpointer
+
+            self._ckpt = Checkpointer(
+                config.checkpoint_dir, local_saver=not renv.under_agent()
+            )
+            # The first save's one-time work (the arena made and its pages
+            # touched, the staged programs compiled) needs only what the
+            # state WILL be: on a thread from here, beside the step
+            # program's compile, so that the first save is a later save.
+            self._ckpt.prepare(
+                self.train.abstract_state(), extra=self._accum_extra(),
+                restart_count=restart,
+            )
         # Model, optimizer and ``build_sharded_train``, up to ``compile``.
         telemetry.event(
             "startup.build", duration_s=time.monotonic() - t_build,
@@ -391,13 +406,7 @@ class ElasticTrainer:
             )
         self.step = 0
         self._last_saved = 0
-        self._ckpt = None
-        if config.checkpoint_dir:
-            from dlrover_tpu.checkpoint import Checkpointer
-
-            self._ckpt = Checkpointer(
-                config.checkpoint_dir, local_saver=not renv.under_agent()
-            )
+        if self._ckpt is not None:
             with telemetry.span("restore", restart_count=restart):
                 restored_step, restored = self._ckpt.load_checkpoint(
                     shardings=self.train.state_shardings,
@@ -1338,7 +1347,7 @@ class ElasticTrainer:
 
         # The span is everything the training loop is blocked for: the
         # drain of what the device still had in flight, then the save.
-        with telemetry.span("checkpoint", step=self.step):
+        with telemetry.span("checkpoint", step=self.step) as span:
             with telemetry.span("checkpoint.drain"):
                 # Checkpoint barrier: drain deferred metrics first, so (a)
                 # every step committed by this save has already been
@@ -1360,6 +1369,12 @@ class ElasticTrainer:
                 self.step, self.state, StorageType.DISK,
                 extra=self._accum_extra(),
             )
+            # The first save says how long it was blocked for the arena's
+            # preparation (``Checkpointer.prepare``): 0.0 where that work
+            # was hidden behind the start.
+            waited = self._ckpt.take_arena_wait()
+            if span is not None and waited is not None:
+                span.attrs["arena_wait_s"] = waited
         if self._embed_plane is not None and self._embed_dir is not None:
             # The plane's delta leg rides every dense checkpoint: rows
             # touched since the last export land under the integrity

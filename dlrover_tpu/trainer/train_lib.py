@@ -317,6 +317,23 @@ class ShardedTrain:
         with use_mesh(self.mesh):
             return self.init_fn(rng)
 
+    def abstract_state(self) -> TrainState:
+        """The state ``init`` will give, described: every leaf a
+        ``jax.ShapeDtypeStruct`` under its ``state_shardings`` sharding.
+        No array is made (``aot_compile`` traces the same ``init_fn``,
+        so one of the two finds the other's trace)."""
+        with use_mesh(self.mesh):
+            shapes = jax.eval_shape(self.init_fn, _ABSTRACT_KEY)
+        leaves, treedef = jax.tree_util.tree_flatten(shapes)
+        shardings = jax.tree_util.tree_leaves(
+            self.state_shardings,
+            is_leaf=lambda x: isinstance(x, jax.sharding.Sharding),
+        )
+        return jax.tree_util.tree_unflatten(treedef, [
+            jax.ShapeDtypeStruct(leaf.shape, leaf.dtype, sharding=sharding)
+            for leaf, sharding in zip(leaves, shardings, strict=True)
+        ])
+
     def adopt(self, state: TrainState) -> TrainState:
         """Rebind a state's static metadata (apply_fn/tx) to the identities
         this program was compiled with; array leaves are untouched."""

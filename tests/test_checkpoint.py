@@ -326,6 +326,344 @@ def test_a_device_error_in_the_pipeline_falls_back(small_pieces, tap,
         handler.close(unlink=True)
 
 
+# -- the first save's one-time work, ahead of it ---------------------------------
+
+
+def _described(state):
+    """``state`` as a trainer knows it before it exists: every leaf a
+    ``ShapeDtypeStruct`` under its sharding (a host leaf under none)."""
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(
+            np.shape(x), x.dtype, sharding=getattr(x, "sharding", None)
+        ),
+        state,
+    )
+
+
+def _mixed_state(k=1):
+    """Replicated, sharded, sharded-and-replicated and scalar leaves."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    mesh = Mesh(np.asarray(jax.devices()[:4]).reshape(2, 2), ("x", "y"))
+    put = lambda x, *spec: jax.device_put(  # noqa: E731
+        x, NamedSharding(mesh, P(*spec))
+    )
+    rows = np.arange(64 * 50).reshape(64, 50) % 97 + k
+    return {
+        "replicated": put(jnp.asarray(rows, jnp.bfloat16)),
+        "sharded": put(jnp.asarray(rows, jnp.float32), "x", "y"),
+        "sharded_replicated": put(jnp.asarray(rows, jnp.float32), "x", None),
+        "columns": put(jnp.asarray(rows, jnp.int32), None, "y"),
+        "scalar": put(jnp.int32(k)),
+        "one_device": jnp.full((6, 40, 50), k, jnp.bfloat16),
+        "host": np.arange(5, dtype=np.int16),
+    }
+
+
+def _join(thread):
+    thread.join(timeout=60)
+    assert not thread.is_alive()
+
+
+def _compile_counter():
+    compiles = []
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda name, secs, **_: compiles.append(name)
+        if name == "/jax/core/compile/backend_compile_duration" else None
+    )
+    return compiles
+
+
+def _restored(handler, meta):
+    return {
+        t.path[0][2:-2]: assemble_tensor(
+            t, lambda r: handler.load_block(meta, r)
+        )
+        for t in meta.tensors
+    }
+
+
+def _assert_restores(handler, meta, state):
+    for name, out in _restored(handler, meta).items():
+        assert out.dtype == state[name].dtype
+        assert out.tobytes() == np.asarray(state[name]).tobytes(), name
+
+
+def test_a_described_state_plans_the_records_of_the_state_itself():
+    """``prepare`` reckons a first save from ``ShapeDtypeStruct``s: the
+    records are the concrete state's, and jax's own ``replica_id`` picks
+    the same shards."""
+    import pickle
+
+    from dlrover_tpu.checkpoint import shm_handler
+
+    state = _mixed_state()
+    meta, blocks = shm_handler._plan_pytree(state, 0, {"k": 1})
+    described, abstract = shm_handler._plan_pytree(
+        _described(state), 0, {"k": 1}
+    )
+    assert described.tensors == meta.tensors
+    assert [
+        (b.shape, np.dtype(b.dtype), shm_handler._device_of(b))
+        for b in abstract
+    ] == [
+        (b.shape, np.dtype(b.dtype), shm_handler._device_of(b))
+        for b in blocks
+    ]
+    for tensor, (name, leaf) in zip(meta.tensors, sorted(state.items())):
+        if not isinstance(leaf, jax.Array):
+            continue
+        owned = [s for s in leaf.addressable_shards if s.replica_id == 0]
+        assert [r.index for r in tensor.shards] == [
+            shm_handler._slices_to_index(s.index, leaf.shape) for s in owned
+        ], name
+        assert [r.nbytes for r in tensor.shards] == [
+            s.data.nbytes for s in owned
+        ]
+    assert [len(t.shards) for t in meta.tensors] == [2, 1, 1, 1, 1, 4, 2]
+    handler = SharedMemoryHandler(f"pl{os.getpid()}")
+    try:
+        found = handler.prepare(_described(state), {"k": 1})
+        # (equal metas pickle to within a few bytes: strings one of them
+        # shares between records the other may hold twice)
+        assert found["bytes"] == pytest.approx(
+            8 + len(pickle.dumps(meta)) + sum(
+                r.nbytes for t in meta.tensors for r in t.shards
+            ), abs=64,
+        )
+    finally:
+        handler.close(unlink=True)
+
+
+@pytest.mark.parametrize("zero1", [False, True])
+def test_a_step_program_describes_the_state_its_init_gives(zero1):
+    """What a trainer hands ``prepare`` before any state exists
+    (``ShardedTrain.abstract_state``) plans the records its first save
+    will write, the ZeRO-1 optimizer state's too."""
+    from dlrover_tpu.checkpoint import shm_handler
+    from dlrover_tpu.models.gpt2 import gpt2_config
+    from dlrover_tpu.models.transformer import TransformerLM
+    from dlrover_tpu.parallel import rules as lr
+    from dlrover_tpu.runtime.mesh import ParallelConfig, build_mesh
+    from dlrover_tpu.trainer import train_lib
+
+    model = TransformerLM(gpt2_config(
+        "124m", num_layers=2, d_model=64, num_heads=4, vocab_size=256,
+        max_seq_len=64,
+    ))
+    train = train_lib.build_sharded_train(
+        model, train_lib.make_optimizer("adamw", learning_rate=1e-2),
+        build_mesh(ParallelConfig(data=4, fsdp=2)), lr.DEFAULT_RULES,
+        global_batch_size=32, seq_len=16, zero1=zero1,
+    )
+    assert train.zero1 == zero1
+    described, _ = shm_handler._plan_pytree(train.abstract_state(), 0, None)
+    state = train.init(jax.random.PRNGKey(0))
+    meta, blocks = shm_handler._plan_pytree(state, 0, None)
+    assert described.tensors == meta.tensors
+    assert any(len(t.shards) > 1 for t in meta.tensors)
+    assert sum(r.nbytes for t in meta.tensors for r in t.shards) == sum(
+        b.nbytes for b in blocks
+    )
+
+
+def test_a_prepared_first_save_is_a_later_save(small_pieces, tap):
+    """After ``prepare`` the first save maps nothing, settles nothing and
+    compiles nothing: it writes into pages that are there, through
+    programs that are there."""
+    compiles = _compile_counter()
+    state = _mixed_state()
+    jax.block_until_ready(state)
+    handler = SharedMemoryHandler(f"pf{os.getpid()}")
+    try:
+        found = handler.prepare(_described(state), restart_count=0)
+        events = tap.take()
+        (prepare,) = _named(events, "checkpoint.prepare")
+        assert prepare[4] == dict(prepare[4], **found)
+        assert found["created"] is True and found["minflt"] >= 0
+        assert prepare[4]["id"] == "restart:0"
+        (arena,) = _named(events, "checkpoint.arena")
+        assert arena[4] == dict(
+            arena[4], ahead=True, created=True, bytes=found["bytes"],
+            parent="checkpoint.prepare",
+        )
+        (settle,) = _named(events, "checkpoint.arena_settle")
+        assert settle[4]["bytes"] == found["bytes"]
+        assert settle[4]["parent"] == "checkpoint.prepare"
+        # a plan for every kind of staged block, on every device that
+        # holds one; the scalar and the host leaf have none to make
+        plans = dict(handler._staged)
+        assert found["programs"] == sum(
+            len(p.programs) for p in plans.values() if p
+        ) == len(_named(events, "compile.backend")) > 0
+        assert all(
+            e[4]["parent"] == "checkpoint.prepare"
+            for e in _named(events, "compile.trace")
+        )
+        # the arena says "no checkpoint" until a save has published one
+        assert handler.load_meta() is None
+        assert not np.frombuffer(
+            handler._shm.buf, np.uint8, count=found["bytes"]
+        ).any()
+        before = len(compiles)
+        meta = handler.save_state_dict(state, step=1)
+        events = tap.take()
+        assert len(compiles) == before and handler._staged == plans
+        assert not _named(events, "checkpoint.arena")
+        assert not _named(events, "checkpoint.arena_settle")
+        assert not [e for e in events if e[0].startswith("compile.")]
+        (d2h,) = _named(events, "checkpoint.d2h")
+        assert d2h[4]["path"] == "staged" and d2h[4]["groups"] > 0
+        assert handler.load_meta().step == 1
+        _assert_restores(handler, meta, state)
+    finally:
+        handler.close(unlink=True)
+
+
+def test_preparing_over_a_published_checkpoint_only_reads_it(
+    small_pieces, tap, tmp_path
+):
+    """A restart in place: the arena holds the only copy of the last
+    acknowledged save, so the preparation attaches it, reads it, and
+    leaves every byte and the header as they were; ``load`` restores it."""
+    ckpt_dir = str(tmp_path / "ckpt")
+    state = {k: v for k, v in _mixed_state().items() if k != "host"}
+    dead = Checkpointer(ckpt_dir, host_index=0, num_hosts=1, local_saver=True)
+    assert dead.save_checkpoint(5, state, StorageType.MEMORY)
+    arena = dead._engine._shm._shm
+    before = bytes(arena.buf)
+    tap.take()
+    # the trainer the agent starts in its place (same saver, same arena)
+    ckpt = Checkpointer(ckpt_dir, host_index=0, num_hosts=1)
+    try:
+        ckpt.prepare(_described(state), restart_count=1)
+        step, loaded = ckpt.load_checkpoint(state_template=state)
+        _join(ckpt._engine._preparing)
+        events = tap.take()
+        assert step == 5
+        for name, leaf in state.items():
+            assert np.asarray(loaded[name]).tobytes() \
+                == np.asarray(leaf).tobytes()
+        assert bytes(arena.buf) == before
+        (prepare,) = _named(events, "checkpoint.prepare")
+        assert prepare[4]["created"] is False
+        assert prepare[4]["id"] == "restart:1" and prepare[4]["programs"] > 0
+        (mapped,) = _named(events, "checkpoint.arena")
+        assert mapped[4] == dict(mapped[4], ahead=True, created=False)
+        assert len(_named(events, "checkpoint.arena_settle")) == 1
+        # ... and the next save goes into the arena that was attached
+        state["scalar"] = state["scalar"] + 1
+        assert ckpt.save_checkpoint(6, state, StorageType.MEMORY)
+        assert ckpt.take_arena_wait() is not None
+        assert ckpt.take_arena_wait() is None
+        assert not _named(tap.take(), "checkpoint.arena")
+        assert dead._engine._shm.load_meta().step == 6
+    finally:
+        ckpt.close()
+        dead._engine._shm.close(unlink=True)
+        dead.close()
+
+
+@pytest.mark.parametrize("too_small", ["what_was_prepared", "the_arena_there"])
+def test_a_state_that_needs_more_room_still_saves(too_small, small_pieces, tap):
+    """The save that needs a larger arena makes it, as it always did: after
+    a preparation for a smaller state, and after one that found an arena
+    too small for its state and left it alone, since the restore may
+    still want the checkpoint it holds."""
+    name = f"pg{os.getpid()}{too_small}"
+    small = {"w": jnp.ones((40, 50), jnp.float32)}
+    grown = {"w": jnp.ones((1 << 10, 1 << 9), jnp.float32)}
+    handler, dead = SharedMemoryHandler(name), SharedMemoryHandler(name)
+    try:
+        if too_small == "what_was_prepared":
+            assert handler.prepare(_described(small))["created"] is True
+        else:
+            dead.save_state_dict(small, step=4)
+            before = bytes(dead._shm.buf)
+            tap.take()
+            found = handler.prepare(_described(grown))
+            assert found["created"] is False and found["programs"] > 0
+            assert handler._shm is None and bytes(dead._shm.buf) == before
+            (arena,) = _named(tap.take(), "checkpoint.arena")
+            assert arena[4] == dict(arena[4], ahead=True, created=False)
+            meta = handler.load_meta()
+            assert meta.step == 4
+            _assert_restores(handler, meta, small)
+        tap.take()
+        meta = handler.save_state_dict(grown, step=5)
+        events = tap.take()
+        (arena,) = _named(events, "checkpoint.arena")
+        assert arena[4] == dict(arena[4], ahead=False, created=True)
+        assert len(_named(events, "checkpoint.arena_settle")) == 1
+        _assert_restores(handler, meta, grown)
+    finally:
+        dead.close()
+        handler.close(unlink=True)
+
+
+@pytest.mark.parametrize("why", ["error", "shm_busy"])
+def test_a_skipped_preparation_leaves_the_first_save_as_it_was(
+    why, small_pieces, tap, tmp_path, monkeypatch
+):
+    """No room in /dev/shm, a compile error, or the saver persisting the
+    last save of the trainer before this one: the preparation says so and
+    the first save makes the arena and its programs, as it always did."""
+    compiles = _compile_counter()
+    state = {k: v for k, v in _mixed_state().items() if k != "host"}
+    jax.block_until_ready(state)
+    ckpt = Checkpointer(
+        str(tmp_path / "ckpt"), host_index=0, num_hosts=1, local_saver=True
+    )
+    engine = ckpt._engine
+    held, leave = threading.Event(), threading.Event()
+
+    def hold():
+        # (as the saver does while it persists a step)
+        engine._lock.acquire()
+        held.set()
+        leave.wait()
+        engine._lock.release()
+
+    holder = threading.Thread(target=hold)
+    try:
+        with monkeypatch.context() as patch:
+            if why == "error":
+                def no_room(total):
+                    raise OSError(28, "No space left on device")
+
+                patch.setattr(engine._shm, "_open_arena", no_room)
+            else:
+                holder.start()
+                assert held.wait(timeout=60)
+            ckpt.prepare(_described(state), restart_count=0)
+            _join(engine._preparing)
+            leave.set()
+        events = tap.take()
+        (skipped,) = _named(events, "checkpoint.prepare_skipped")
+        assert skipped[1] == "event"
+        assert skipped[4] == dict(skipped[4], reason=why, id="restart:0")
+        assert not _named(events, "checkpoint.arena_settle")
+        assert not engine._shm._staged and engine._shm._shm is None
+        if why == "shm_busy":
+            _join(holder)
+        before = len(compiles)
+        assert ckpt.save_checkpoint(1, state, StorageType.MEMORY)
+        events = tap.take()
+        assert len(compiles) > before
+        (arena,) = _named(events, "checkpoint.arena")
+        assert arena[4] == dict(arena[4], ahead=False, created=True)
+        assert len(_named(events, "checkpoint.arena_settle")) == 1
+        assert ckpt.take_arena_wait() == pytest.approx(0.0, abs=0.05)
+        step, loaded = ckpt.load_checkpoint(state_template=state)
+        assert step == 1
+        for name, leaf in state.items():
+            assert np.asarray(loaded[name]).tobytes() \
+                == np.asarray(leaf).tobytes()
+    finally:
+        engine._shm.close(unlink=True)
+        ckpt.close()
+
+
 def test_checkpointer_memory_and_disk_cycle(tmp_path):
     ckpt_dir = str(tmp_path / "ckpt")
     ckpt = Checkpointer(ckpt_dir, host_index=0, num_hosts=1, local_saver=True)
@@ -456,19 +794,58 @@ def test_the_save_that_maps_the_arena_settles_its_pages(
         writer.close(unlink=True)
 
 
-def test_torn_write_is_invisible():
+class _Killed(BaseException):
+    """Stands for a SIGKILL at the line that raises it."""
+
+
+@pytest.mark.parametrize("killed_in", [
+    "save", "preparation_of_a_new_arena", "preparation_of_an_arena_there",
+])
+def test_torn_write_is_invisible(killed_in, monkeypatch):
     """A crash mid-save must not leave a valid-looking checkpoint: the
-    header is zeroed during the write and only published at the end."""
-    handler = SharedMemoryHandler(f"torn{os.getpid()}")
-    handler.save_state_dict({"w": np.ones(4, np.float32)}, step=1)
+    header is zeroed during the write and only published at the end.  A
+    crash inside the preparation leaves "no checkpoint" in an arena it
+    made, and an arena that was there as it was."""
+    name = f"torn{os.getpid()}{killed_in}"
+    state = {"w": np.arange(5000, dtype=np.float32)}
+    handler = SharedMemoryHandler(name)
+    try:
+        if killed_in != "preparation_of_a_new_arena":
+            handler.save_state_dict(state, step=1)
+        if killed_in == "save":
+            # Simulate death mid-write: corrupt by zeroing the header the
+            # way save_state_dict does before copying blocks.
+            import struct
 
-    # Simulate death mid-write: corrupt by zeroing the header the way
-    # save_state_dict does before copying blocks.
-    import struct
+            handler._shm.buf[:8] = struct.pack("<Q", 0)
+            assert handler.load_meta() is None
+            return
+        before = None if handler._shm is None else bytes(handler._shm.buf)
 
-    handler._shm.buf[:8] = struct.pack("<Q", 0)
-    assert handler.load_meta() is None
-    handler.close(unlink=True)
+        def killed(total):
+            raise _Killed
+
+        # (the pages are written, where they are, before they are settled)
+        restarted = SharedMemoryHandler(name)
+        monkeypatch.setattr(restarted, "_settle", killed)
+        with pytest.raises(_Killed):
+            restarted.prepare(_described(state))
+        reader = SharedMemoryHandler(name)
+        if before is None:
+            assert reader.attach() and reader.load_meta() is None
+        else:
+            meta = reader.load_meta()
+            assert meta.step == 1
+            assert bytes(reader._shm.buf) == before
+            assert reader.load_block(meta, meta.tensors[0].shards[0]) \
+                .tobytes() == state["w"].tobytes()
+        reader.close()
+        restarted.close()
+    finally:
+        handler.close()
+        last = SharedMemoryHandler(name)
+        last.attach()
+        last.close(unlink=True)
 
 
 def test_saver_sigterm_persist_path(tmp_path):

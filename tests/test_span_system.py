@@ -318,6 +318,10 @@ def test_a_save_is_split_where_the_work_happens(tmp_path, tap, small_pieces):
         assert trainer._ckpt.wait(timeout=60)
 
     trainer.save_checkpoint = save_then_wait_for_the_persist
+    # (a job's start hides the preparation; this one is too short to)
+    preparing = trainer._ckpt._engine._preparing
+    preparing.join(timeout=60)
+    assert not preparing.is_alive()
     trainer.fit(_batches(6), max_steps=4)
     trainer.close()
     events = tap.take()
@@ -331,11 +335,11 @@ def test_a_save_is_split_where_the_work_happens(tmp_path, tap, small_pieces):
             if e[4].get("parent") == "checkpoint" and e[4].get("id") == group
         ]
         names = sorted(e[0] for e in children)
-        first = save[4]["step"] == 2
-        # The save that maps the arena also settles its pages.
-        assert names == (
-            ["checkpoint.arena", "checkpoint.arena_settle"] if first else []
-        ) + ["checkpoint.d2h", "checkpoint.drain", "checkpoint.shm_write"]
+        # No save maps the arena or settles its pages: the trainer's
+        # start did (``checkpoint.prepare``, below).
+        assert names == [
+            "checkpoint.d2h", "checkpoint.drain", "checkpoint.shm_write"
+        ]
         assert sum(e[3] for e in children) <= save[3]
         (d2h,) = _named(children, "checkpoint.d2h")
         assert d2h[4]["bytes"] == size and d2h[4]["shards"] > 0
@@ -347,34 +351,49 @@ def test_a_save_is_split_where_the_work_happens(tmp_path, tap, small_pieces):
         # what the device still had in flight is read inside the drain
         assert _named(events, "metrics-flush", parent="checkpoint.drain",
                       id=group)
+    # The first save alone says how long it waited for the preparation.
+    assert [("arena_wait_s" in e[4]) for e in saves] == [True, False]
+    assert 0.0 <= saves[0][4]["arena_wait_s"] < 0.05
+    # The arena is made, written once and settled at the trainer's start,
+    # on the path a save would take, and the span says so.
+    (prepare,) = _named(events, "checkpoint.prepare")
+    assert prepare[4]["id"] == "restart:0" and "parent" not in prepare[4]
+    assert prepare[4]["created"] is True and prepare[4]["minflt"] >= 0
     (arena_span,) = _named(events, "checkpoint.arena")
-    assert arena_span[4]["created"] is True and arena_span[4]["bytes"] > size
+    assert arena_span[4]["created"] is True and arena_span[4]["ahead"] is True
+    assert arena_span[4]["bytes"] == prepare[4]["bytes"] > size
     (settle,) = _named(events, "checkpoint.arena_settle")
     assert settle[4]["bytes"] == arena_span[4]["bytes"]
+    for span in (arena_span, settle):
+        assert span[4]["parent"] == "checkpoint.prepare"
+        assert span[4]["id"] == "restart:0"
+    assert not _named(events, "checkpoint.prepare_skipped")
     assert not _named(events, "checkpoint.skip")
     assert not _named(events, "checkpoint.d2h_fallback")
-    # The second save of the same state compiles nothing and plans nothing
-    # new: its programs and pieces are the first save's.
-    assert compiled_in[0] > 0 and compiled_in[1] == 0
+    # No save compiles or plans anything: the programs and pieces are the
+    # preparation's.
+    assert compiled_in == [0, 0]
     assert staged[0] and staged[1] == staged[0]
-    # The first save's staged programs are compiled inside its
-    # ``checkpoint.d2h``: three stages a program, one executable each.
+    # The staged programs are compiled inside ``checkpoint.prepare``:
+    # three stages a program, one executable each.
     programs = sum(len(plan.programs) for plan in staged[0].values() if plan)
+    assert prepare[4]["programs"] == programs
     for stage in ("trace", "lower", "backend"):
-        spans = _named(events, f"compile.{stage}")
+        spans = _named(events, f"compile.{stage}", fun_name="_flat_pieces")
         assert len(spans) == programs > 0
-        assert all(e[4]["parent"] == "checkpoint.d2h"
-                   and e[4]["id"] == "step:2"
-                   and e[4]["fun_name"] == "_flat_pieces" for e in spans)
-    built = _named(events, "jax.compile", parent="compile.backend")
-    assert len(built) == programs
-    assert all(e[4]["id"] == "step:2" for e in built)
+        assert all(e[4]["parent"] == "checkpoint.prepare"
+                   and e[4]["id"] == "restart:0" for e in spans)
+    built = [
+        e for e in _named(events, "jax.compile", parent="compile.backend")
+        if e[2] >= prepare[2] and e[2] + e[3] <= prepare[2] + prepare[3]
+        and e[4]["id"] == "restart:0"
+    ]
+    assert len(built) >= programs
+    assert not _named(events, "jax.compile", id="step:2")
     assert not _named(events, "jax.compile", id="step:4")
-    (first_d2h,) = _named(events, "checkpoint.d2h", id="step:2")
     assert sum(
-        e[3] for e in events if e[0].startswith("compile.")
-        and e[4].get("parent") == "checkpoint.d2h"
-    ) <= first_d2h[3]
+        e[3] for e in events if e[4].get("parent") == "checkpoint.prepare"
+    ) <= prepare[3]
 
 
 def test_a_persist_has_its_four_children_and_says_persisted(tmp_path, tap):
@@ -474,6 +493,8 @@ def test_skips_and_persisted_steps_reach_the_master_gauges():
         ("persisted", "event", 0.0, 0.0, {"step": 14}),
         ("checkpoint.d2h_fallback", "event", 0.0, 0.0,
          {"step": 21, "reason": "hbm_headroom"}),
+        ("checkpoint.prepare_skipped", "event", 0.0, 0.0,
+         {"reason": "shm_busy", "restart_count": 1}),
     )
     wire = pickle.dumps(msg.Envelope(
         node_id=2, payload=msg.TelemetryEvents(2, events),
@@ -482,6 +503,7 @@ def test_skips_and_persisted_steps_reach_the_master_gauges():
     text = timeline.render_metrics()
     assert "dlrover_checkpoint_skipped_total 1" in text
     assert "dlrover_checkpoint_d2h_fallback_total 1" in text
+    assert "dlrover_checkpoint_prepare_skipped_total 1" in text
     assert 'dlrover_persisted_step{node="2"} 14' in text
     assert "# HELP dlrover_persisted_step " in text
 

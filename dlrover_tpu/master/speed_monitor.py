@@ -41,8 +41,10 @@ HEALTH_KINDS: Dict[str, Dict[str, Any]] = {
         },
         "max": {
             "experts": 0.0, "top_k": 0.0, "held": 0.0, "bias_absmax": 0.0,
-            "groups": 1.0,
+            "groups": 1.0, "shared_held": 0.0, "shared_published": 0.0,
         },
+        # an older trainer's event says no scale: the shared experts summed
+        "min": {"shared_scale": 1.0},
         # an older trainer's event (no share told): every expert held
         "or": {"held": "experts"},
         "gauges": (
@@ -80,6 +82,13 @@ HEALTH_KINDS: Dict[str, Dict[str, Any]] = {
             ("bias_absmax", "dlrover_moe_router_bias_absmax",
              "largest |bias| of a bias-corrected router (max of "
              "reporters; 0 where the router has none)"),
+            ("shared_held", "dlrover_moe_shared_experts_held",
+             "shared experts of a layer that live on a reporter's chip"),
+            ("shared_published", "dlrover_moe_shared_experts",
+             "shared experts of a layer of the reported model"),
+            ("shared_scale", "dlrover_moe_shared_expert_scale",
+             "what the shared experts' summed output is multiplied by "
+             "(1: summed; 1 / their count: averaged)"),
         ),
     },
     # The multi-token-prediction module's own loss.
@@ -165,13 +174,16 @@ HEALTH_KINDS: Dict[str, Dict[str, Any]] = {
         "max": {
             "full_layers": 0.0, "sliding_layers": 0.0, "window": 0.0,
             "full_score_bound": 0.0, "sliding_score_bound": 0.0,
-            "score_bound": 0.0,
+            "score_bound": 0.0, "rotated_layers": 0.0,
         },
         "gauges": (
             ("window", "dlrover_attn_window",
              "keys a windowed attention layer's query sees"),
             ("sliding_layers", "dlrover_attn_sliding_layers",
              "windowed attention layers of the model"),
+            ("rotated_layers", "dlrover_attn_rotated_layers",
+             "attention layers that rotate q and k (RoPE or YaRN); the "
+             "model's other attention layers see no position"),
             ("full_score_bound", "dlrover_attn_full_score_bound",
              "UPPER BOUND, not an observed score: longest query "
              "row x longest key row x scale of a full attention "

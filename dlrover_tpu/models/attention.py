@@ -696,7 +696,9 @@ def from_config(cfg, kind: str = FULL_ATTENTION, **kwargs):
     and for :func:`kernel_facts`.  ``kind`` ``sliding_attention`` under its
     window and its own rotation.  Only a model with windowed layers hands
     ``Attention`` a rotation or asks for its score statistics: every other
-    model's program is the one it was."""
+    model's program is the one it was.  A kind the config gives no rotation
+    (``cfg.rotation(kind)`` ``None``: full layers without positions) does
+    not rotate."""
     if cfg.latent_attention:
         return LatentAttention(
             num_heads=cfg.num_heads,
@@ -720,7 +722,7 @@ def from_config(cfg, kind: str = FULL_ATTENTION, **kwargs):
         num_heads=cfg.num_heads,
         num_kv_heads=cfg.resolved_kv_heads,
         head_dim=cfg.resolved_head_dim,
-        use_rope=cfg.position == "rope",
+        use_rope=rotation_of(cfg, kind) != "none",
         rope_theta=cfg.rope_theta,
         use_bias=cfg.use_bias,
         dtype=cfg.dtype,
@@ -739,6 +741,14 @@ def from_config(cfg, kind: str = FULL_ATTENTION, **kwargs):
     )
 
 
+def rotation_of(cfg, kind: str) -> str:
+    """How ``kind``'s layers rotate q and k: ``none`` | ``rope`` | ``yarn``."""
+    rotation = cfg.rotation(kind)
+    if cfg.position != "rope" or rotation is None:
+        return "none"
+    return rotation.scaling or "rope"
+
+
 def _by_kind(cfg, kind: str) -> Dict[str, Any]:
     sliding = kind == SLIDING_ATTENTION
     if not (sliding or cfg.rope_scaling or cfg.num_sliding_layers):
@@ -752,14 +762,26 @@ def _by_kind(cfg, kind: str) -> Dict[str, Any]:
 
 def _read(cfg, vec) -> Dict[str, Any]:
     """The ``attn`` event of the step's folded vector: the layers of each
-    kind, the window, and ``score_bound``: the bound of the largest ``|q
+    kind, the window, each kind's rotation (:func:`rotation_of`; and
+    ``rotated_layers``, the layers that rotate at all: the others see no
+    position) and ``score_bound``: the bound of the largest ``|q
     k^T| * scale`` before the mask (:func:`score_bound`, which the exact
     maximum cannot pass) over the layers of each kind and over both, so
     that a rotation's factor on the full layers' scores shows."""
     full, sliding = (float(v) for v in vec)
+    rotation = {
+        kind: rotation_of(cfg, kind)
+        for kind in (FULL_ATTENTION, SLIDING_ATTENTION)
+    }
     return dict(
         full_layers=cfg.num_full_layers,
         sliding_layers=cfg.num_sliding_layers,
+        full_rotation=rotation[FULL_ATTENTION],
+        sliding_rotation=rotation[SLIDING_ATTENTION],
+        rotated_layers=(
+            cfg.num_full_layers * (rotation[FULL_ATTENTION] != "none")
+            + cfg.num_sliding_layers * (rotation[SLIDING_ATTENTION] != "none")
+        ),
         window=cfg.sliding_window, full_score_bound=full,
         sliding_score_bound=sliding,
         score_bound=float("nan") if full != full or sliding != sliding

@@ -237,13 +237,16 @@ class LayerNorm(nn.Module):
 
 def make_norm(kind: str, dtype: Dtype, param_dtype: Dtype, name: str,
               fused_backward: bool = False,
-              epsilon: float = 1e-5) -> nn.Module:
+              epsilon: float = 1e-5, use_bias: bool = True) -> nn.Module:
+    """``use_bias`` is a LayerNorm's (GPT-2's has one, the ``cohere2``
+    family's none); an RMSNorm has a scale alone."""
     if kind == "rmsnorm":
         return RMSNorm(dtype=dtype, param_dtype=param_dtype, name=name,
                        fused_backward=fused_backward, epsilon=epsilon)
     if kind == "layernorm":
         return LayerNorm(dtype=dtype, param_dtype=param_dtype, name=name,
-                         fused_backward=fused_backward, epsilon=epsilon)
+                         fused_backward=fused_backward, epsilon=epsilon,
+                         use_bias=use_bias)
     raise ValueError(f"unknown norm kind {kind!r}")
 
 

@@ -528,7 +528,10 @@ class MoEMlp(nn.Module):
     parameter no gradient reaches, moved by :func:`bias_update` in the
     train step.  ``shared_d_ff`` adds a shared expert (a plain MLP of the
     layer's ``activation`` and that width, ``shared``) every token passes
-    through.  ``router_groups`` > 1 makes the choice group-limited
+    through, its output times ``shared_scale``: several shared experts
+    SUMMED are one MLP as wide as all of them (scale 1), AVERAGED that sum
+    over their published count, and a chip's share of them the same MLP
+    narrower, under the same scale.  ``router_groups`` > 1 makes the choice group-limited
     (``router_topk_groups`` of the groups a token, :func:`group_limited`);
     under a share the pairs that land here are then no binomial thinning of
     every token's k (a token whose best groups leave this chip's out sends
@@ -563,6 +566,10 @@ class MoEMlp(nn.Module):
     experts_held: int = 0           # 0 -> all num_experts live here
     first_expert: int = 0
     shared_d_ff: int = 0            # 0 -> no shared expert
+    # What the shared MLP's output is multiplied by before it joins (1 / the
+    # published count where the shared experts are AVERAGED, whether the MLP
+    # holds all of them or a chip's share).
+    shared_scale: float = 1.0
     row_budget_multiple: float = 1.25
     router_groups: int = 1          # > 1: a group-limited choice
     router_topk_groups: int = 1
@@ -637,6 +644,8 @@ class MoEMlp(nn.Module):
                 use_bias=False, dtype=self.dtype,
                 param_dtype=self.param_dtype, name="shared",
             )(x)
+            if self.shared_scale != 1.0:
+                shared = shared * jnp.asarray(self.shared_scale, shared.dtype)
         out, aux = self._routed(x, router_logits, bias, wi, wg, wo)
         return (out if shared is None else out + shared), aux
 
@@ -1073,6 +1082,7 @@ def from_config(cfg, **kwargs) -> MoEMlp:
         experts_held=cfg.experts_held,
         first_expert=cfg.first_expert,
         shared_d_ff=cfg.resolved_shared_d_ff,
+        shared_scale=cfg.shared_expert_scale,
         row_budget_multiple=cfg.moe_row_budget,
         router_groups=cfg.router_groups,
         router_topk_groups=cfg.router_topk_groups,
@@ -1182,6 +1192,11 @@ def _read(cfg, vec, share=None) -> Dict[str, Any]:
         row_fetch_share=row_fetch_share,
         groups=int(layer.router_groups),
         topk_group=int(layer.router_topk_groups),
+        # the shared experts: held here, published, and what their summed
+        # output is multiplied by (1 / published where they are averaged)
+        shared_held=int(cfg.resolved_shared_held),
+        shared_published=int(cfg.num_shared_experts),
+        shared_scale=float(layer.shared_scale),
         **grouped,
     )
 

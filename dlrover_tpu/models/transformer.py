@@ -34,6 +34,12 @@ renormalising sum of a sigmoid router's gates.  Mellum2's are a fourth,
 mask (``sliding_window`` keys, ``ops/flash_attention.py``'s ``window``),
 each kind with its own rotation (plain RoPE under the window; the full
 layers' ``rope_scaling="yarn"`` and its five numbers, ``layers.Rotation``).
+Command A+'s are the PARALLEL form of the two-branch block
+(``parallel_block``: one norm feeds the mixer and the MLP, ``x + Mixer(n) +
+MLP(n)``), a LayerNorm without a bias (``norm_use_bias``), full layers
+WITHOUT positions beside windowed layers that rotate (``full_rope``), and
+shared experts that are AVERAGED over their published count, of which a chip
+may hold a share (``shared_expert_combine``, ``shared_experts_held``).
 
 TPU-first structure:
   * layers are ``nn.scan``-stacked: one trace regardless of depth (fast
@@ -161,6 +167,14 @@ class TransformerConfig:
     # The shared expert's own width (0 -> ``num_shared_experts`` x the
     # routed experts' width, the DeepSeek-V3 family's).
     shared_expert_d_ff: int = 0
+    # How the ``num_shared_experts`` shared experts join: "sum" (the
+    # DeepSeek-V3 family's: one MLP as wide as all of them) or "average"
+    # (Command A+'s: their sum over the PUBLISHED count).  A chip may hold
+    # ``shared_experts_held`` (0 = all) of them whole, as it holds a share
+    # of the routed experts: what the others would add is left out, and the
+    # divisor stays the published count.
+    shared_expert_combine: str = "sum"
+    shared_experts_held: int = 0
     # What a sigmoid router's renormalising sum is added to (the
     # DeepSeek-V3 family's code: 1e-20; LFM2's: 1e-6).
     router_norm_eps: float = 1e-20
@@ -263,11 +277,22 @@ class TransformerConfig:
     rope_beta_fast: float = 0.0
     rope_beta_slow: float = 0.0
     rope_attention_factor: float = 0.0
+    # Whether the FULL attention layers rotate q and k.  False beside
+    # windowed layers that do (Command A+'s "global NoPE"): a full layer
+    # sees the order only through what the windowed layers wrote.
+    full_rope: bool = True
     # "pre": x + f(Norm(x)) (GPT-2, Llama, Mixtral, OLMoE); "post":
     # x + Norm(f(x)), each branch's OUTPUT normalised before the residual
     # add (OLMo 2 and later).
     norm_placement: str = "pre"
+    # The two-branch layers' PARALLEL form (Command A+'s
+    # ``use_parallel_block``): ``n = Norm(x); x' = x + Mixer(n) + MLP(n)``,
+    # ONE norm a layer (``ln``), neither branch sees the other's output.
+    parallel_block: bool = False
     norm_eps: float = 1e-5         # every RMSNorm / LayerNorm / QK-norm
+    # A LayerNorm's bias (GPT-2 has one; the ``cohere2`` family's subtracts
+    # the mean, scales, and has none).  An RMSNorm never has one.
+    norm_use_bias: bool = True
     # numerics / execution
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
@@ -336,10 +361,29 @@ class TransformerConfig:
         return self.experts_held or self.num_experts
 
     @property
+    def resolved_shared_held(self) -> int:
+        return self.shared_experts_held or self.num_shared_experts
+
+    @property
     def resolved_shared_d_ff(self) -> int:
+        """The width of the ONE MLP that is the shared experts held here."""
         return self.shared_expert_d_ff or (
-            self.num_shared_experts * self.resolved_moe_d_ff
+            self.resolved_shared_held * self.resolved_moe_d_ff
         )
+
+    @property
+    def shared_expert_scale(self) -> float:
+        """What the shared MLP's output is multiplied by: 1 over the
+        PUBLISHED count where the shared experts are averaged."""
+        if self.shared_expert_combine == "average":
+            return 1.0 / self.num_shared_experts
+        return 1.0
+
+    @property
+    def norms_per_layer(self) -> int:
+        """Norms of a two-branch layer: one feeds both branches of the
+        parallel form."""
+        return 1 if self.parallel_block else 2
 
     def num_layers_of(self, kind: str) -> int:
         """Layers of ``kind`` in the scanned trunk."""
@@ -382,11 +426,16 @@ class TransformerConfig:
             for i in range(self.first_k_dense)
         )
 
-    def rotation(self, kind: str = FULL_ATTENTION) -> layers.Rotation:
+    def rotation(
+        self, kind: str = FULL_ATTENTION
+    ) -> Optional[layers.Rotation]:
         """``kind``'s rotary embedding: plain RoPE under the window, the
-        scaled one (where the config names one) on the full layers."""
+        scaled one (where the config names one) on the full layers, ``None``
+        for full layers without positions (``full_rope`` False)."""
         if kind == SLIDING_ATTENTION:
             return layers.Rotation(self.rope_theta)
+        if not self.full_rope:
+            return None
         return layers.Rotation(
             self.rope_theta, self.rope_scaling, self.rope_scaling_factor,
             self.rope_original_max_position, self.rope_beta_fast,
@@ -416,6 +465,7 @@ class TransformerConfig:
         object.__setattr__(self, "layer_pattern", tuple(self.layer_pattern))
         self._check_pattern()
         self._check_family()
+        self._check_block()
         self.rotation()             # yarn without its five numbers raises
         if self.attention_impl not in ("xla", "flash", "ring"):
             raise ValueError(
@@ -560,6 +610,60 @@ class TransformerConfig:
                     "(serving/decode.py, serving/engine.py); this model "
                     "trains only"
                 )
+
+    def _check_block(self):
+        """The parallel block, the bias-free LayerNorm, full layers without
+        positions and the shared experts' mean and share."""
+        if self.parallel_block:
+            one_branch = sorted(set(self.layer_pattern) & set(BRANCH_KINDS))
+            if self.norm_placement != "pre" or one_branch or self.mtp_depth:
+                raise ValueError(
+                    "parallel_block is the pre-norm two-branch layer with "
+                    "ONE norm, x + Mixer(Norm(x)) + MLP(Norm(x)): "
+                    "norm_placement='post' norms each branch's output by "
+                    "itself, a one-branch kind has no second branch, and the "
+                    "MTP module's layer is the serial one; got norm_placement"
+                    f"={self.norm_placement!r}, one-branch kinds {one_branch}"
+                    f", mtp_depth={self.mtp_depth}"
+                )
+        if not self.norm_use_bias and self.norm != "layernorm":
+            raise ValueError(
+                "norm_use_bias=False leaves a LayerNorm's bias out; "
+                f"norm={self.norm!r} has none"
+            )
+        if not self.full_rope and (
+            self.position != "rope" or self.rope_scaling
+            or not self.num_sliding_layers
+        ):
+            raise ValueError(
+                "full_rope=False takes the rotation off the full attention "
+                "layers BESIDE sliding_attention layers that keep it "
+                "(position='rope'; rope_scaling is the full layers' rotation "
+                "and has nothing to scale); a model with no positions at all "
+                f"is position='none'; got position={self.position!r}, "
+                f"rope_scaling={self.rope_scaling!r}, "
+                f"{self.num_sliding_layers} sliding layer(s)"
+            )
+        if self.shared_expert_combine not in ("sum", "average") or (
+            self.shared_expert_combine == "average"
+            and not self.num_shared_experts
+        ):
+            raise ValueError(
+                "shared_expert_combine must be 'sum' or 'average' (of "
+                "num_shared_experts), got "
+                f"{self.shared_expert_combine!r} with "
+                f"{self.num_shared_experts} shared expert(s)"
+            )
+        held, shared = self.shared_experts_held, self.num_shared_experts
+        if held and (
+            held < 0 or not shared or shared % held or self.shared_expert_d_ff
+        ):
+            raise ValueError(
+                f"shared_experts_held {held} must divide num_shared_experts "
+                f"{shared}: a chip holds whole shared experts of the routed "
+                "experts' width (no shared_expert_d_ff), one of "
+                "num_shared_experts / held equal shares"
+            )
 
     def _check_ssm(self):
         h, p, n, g = (
@@ -878,10 +982,14 @@ class Mlp(nn.Module):
         )(h)
 
 
-def _norm(cfg: TransformerConfig, name: str):
+def _norm(cfg: TransformerConfig, name: str, fused: bool = True):
+    """The config's norm under ``name``; ``fused``: whether ``fused_ln``'s
+    one-pass backward applies (the layers' norms; not the final norm's or
+    the MTP module's)."""
     return layers.make_norm(
         cfg.norm, cfg.dtype, cfg.param_dtype, name,
-        fused_backward=cfg.fused_ln, epsilon=cfg.norm_eps,
+        fused_backward=fused and cfg.fused_ln,
+        epsilon=cfg.norm_eps, use_bias=cfg.norm_use_bias,
     )
 
 
@@ -907,7 +1015,9 @@ class Block(nn.Module):
     latent, a delta rule or the gated short convolution) and an MLP, each
     on its residual
     branch.  ``dense_mlp`` makes the MLP the dense one (``d_ff`` wide)
-    though the model's trunk is sparse: a leading dense layer."""
+    though the model's trunk is sparse: a leading dense layer.  Under
+    ``parallel_block`` both branches read ONE norm of the layer's input
+    (``ln``) and join the stream in one add: ``x + Mixer(n) + MLP(n)``."""
 
     config: TransformerConfig
     kind: str = FULL_ATTENTION
@@ -928,15 +1038,35 @@ class Block(nn.Module):
         def norm(name, y):
             return _norm(cfg, name)(y)
 
-        y = x if post else norm("ln_attn", x)
-        if self.kind == LINEAR_ATTENTION:
-            y = linear_attention.from_config(cfg, name="linear_attn")(y)
-        elif self.kind == CONV:
-            y = gated_conv.from_config(cfg, name="conv")(y)
-        else:
-            y = attention_lib.from_config(cfg, self.kind, name="attn")(
+        def mixer(y):
+            if self.kind == LINEAR_ATTENTION:
+                return linear_attention.from_config(cfg, name="linear_attn")(y)
+            if self.kind == CONV:
+                return gated_conv.from_config(cfg, name="conv")(y)
+            return attention_lib.from_config(cfg, self.kind, name="attn")(
                 y, positions, segment_ids
             )
+
+        def mlp(y):
+            if cfg.num_experts and not self.dense_mlp:
+                return moe_lib.from_config(cfg, name="moe")(y)
+            return _dense_mlp(cfg)(y), None
+
+        if cfg.parallel_block:
+            n = norm("ln", x)
+            # the names the serial form's branches carry, so that a remat
+            # policy keeps here what it keeps there
+            y = jax.ad_checkpoint.checkpoint_name(mixer(n), "attn_out")
+            z, layer_aux = mlp(n)
+            z = jax.ad_checkpoint.checkpoint_name(z, "mlp_out")
+            if layer_aux is not None:
+                aux = aux + layer_aux
+            x = _add_branch(cfg, x, y + z)
+            x = nn.with_logical_constraint(
+                x, (lr.BATCH, lr.ACT_SEQ, lr.ACT_EMBED)
+            )
+            return (x, aux), None
+        y = mixer(x if post else norm("ln_attn", x))
         if post:
             y = norm("ln_attn", y)
         # Named checkpoint: under the "attn_out" remat policy the backward
@@ -944,12 +1074,9 @@ class Block(nn.Module):
         # recompute) at b*s*d bf16 per layer of extra HBM.
         y = jax.ad_checkpoint.checkpoint_name(y, "attn_out")
         x = _add_branch(cfg, x, y)
-        y = x if post else norm("ln_mlp", x)
-        if cfg.num_experts and not self.dense_mlp:
-            y, layer_aux = moe_lib.from_config(cfg, name="moe")(y)
+        y, layer_aux = mlp(x if post else norm("ln_mlp", x))
+        if layer_aux is not None:
             aux = aux + layer_aux
-        else:
-            y = _dense_mlp(cfg)(y)
         if post:
             y = norm("ln_mlp", y)
         # Under the "branch_out" policy the backward rebuilds the residual
@@ -1073,10 +1200,7 @@ class MTPModule(nn.Module):
         cfg = self.config
 
         def norm(name, y):
-            return layers.make_norm(
-                cfg.norm, cfg.dtype, cfg.param_dtype, name,
-                epsilon=cfg.norm_eps,
-            )(y)
+            return _norm(cfg, name, fused=False)(y)
 
         x = layers.DenseGeneral(
             cfg.d_model,
@@ -1125,8 +1249,14 @@ def kernel_facts(cfg: TransformerConfig, seq_len: int) -> Dict[str, Any]:
     of ``seq_len`` tokens, for the ``compile`` event: every family's keys,
     ``none`` where ``cfg`` has no such layer.  ``short_conv`` is said by
     both families that run the short convolution: the paths of both,
-    joined."""
-    facts: Dict[str, Any] = {}
+    joined.  Ahead of them what no family says, the two-branch layers'
+    form: ``block_form`` (``parallel``: one norm feeds both branches, which
+    do not depend on each other inside a layer; ``serial``) and
+    ``block_norms``, the norms a layer."""
+    facts: Dict[str, Any] = {
+        "block_form": "parallel" if cfg.parallel_block else "serial",
+        "block_norms": cfg.norms_per_layer,
+    }
     for family in FAMILIES:
         for key, said in family.kernel_facts(cfg, seq_len).items():
             if key in facts:
@@ -1254,10 +1384,7 @@ class TransformerLM(nn.Module):
             if next_tokens is None:
                 mtp_hidden = None
 
-        x = layers.make_norm(
-            cfg.norm, cfg.dtype, cfg.param_dtype, "ln_final",
-            epsilon=cfg.norm_eps,
-        )(x)
+        x = _norm(cfg, "ln_final", fused=False)(x)
         if return_hidden:
             # Caller computes the loss head itself (chunked CE path) — the
             # [B, S, V] logits tensor is never materialized.  The µP logit

@@ -245,6 +245,9 @@ def _flash_longest(seq, d, d_v=None):
 MELLUM_QKV = (
     [((1, 32768, 32, 128), BF16)] + [((1, 32768, 4, 128), BF16)] * 2
 )
+COMMAND_A_QKV = (
+    [((1, 16384, 32, 128), BF16)] + [((1, 16384, 2, 128), BF16)] * 2
+)
 LEAF = ((1600, 6400), F32)                 # the 1.5B MLP wi kernel
 CACHE = ((65536, 128), F32)
 
@@ -446,6 +449,26 @@ CASES = [
     ("row_gather_sum_mellum_padded_live_weighted",
      lambda: _row_gather_sum_padded(True),
      [((84096, 2304), BF16), ((32768, 8), I32), ((32768, 8), F32)], {}, 1),
+    # Command A+'s cell, 1 x 16384 tokens, 32 query heads over 2 key/value
+    # heads of 128 (a group of 16): the full layer's kernels, the banded
+    # ones under a window of 4,096 keys at blocks of 1,024 (a band five
+    # blocks wide) with both backward paths; its share's grouped GEMMs at 8
+    # experts of 4,096 x 4,096 over a budget of 11,392 rows, rows of 32 lane
+    # tiles tiled in (wi) and out (wo): forward, dx and dW each way, under
+    # the tiles ``test_the_plans_at_4096_by_4096`` holds
+    ("flash_command_a_full_16k", lambda: _flash(1024), COMMAND_A_QKV, {}, 2),
+    ("flash_command_a_band_4096", lambda: _flash(1024, 4096), COMMAND_A_QKV,
+     {}, 2),
+    ("flash_command_a_band_split", lambda: _flash_band_split(1024, 4096),
+     [((1, 32, 16384, 128), BF16)] + [((1, 2, 16384, 128), BF16)] * 2
+     + [((1, 32, 16384, 128), BF16)], {}, 3),
+    ("grouped_matmul_command_a_wi_rows_tiled",
+     lambda: _grouped_matmul(False, True),
+     [((11392, 32, 128), BF16), ((8, 4096, 4096), BF16), ((8,), I32)],
+     {}, 3),
+    ("grouped_matmul_command_a_wo_out_tiled",
+     lambda: _grouped_matmul(True, True),
+     [((11392, 4096), BF16), ((8, 4096, 4096), BF16), ((8,), I32)], {}, 3),
     ("quantize_dequantize", _quant_roundtrip, [LEAF], {}, 2),
     ("q8_adam", lambda: _adam_update("q8_adam"), [LEAF, LEAF], {}, 1),
     ("q4_adam", lambda: _adam_update("q4_adam"), [LEAF, LEAF], {}, 1),
@@ -464,6 +487,31 @@ def test_kernel_compiles_for_v5e(compile_for_chip, build, avals, static,
                                  kernels):
     text = compile_for_chip(build(), *avals, **static)
     assert text.count('custom_call_target="tpu_custom_call"') == kernels
+
+
+def test_the_plans_at_4096_by_4096():
+    """What the grouped GEMMs' plans choose for Command A+'s experts (bf16,
+    rows of 32 lane tiles row-tiled): INTO the expert width whole-K strips
+    of 512 columns, resident; OUT OF it K is SPLIT in four (a tiled
+    output's narrowest block is 2,048 columns, whose whole-K strip is past
+    the cap); each weight gradient eight tiles under a limit it asks for."""
+    from dlrover_tpu.ops import grouped_matmul as gmm
+
+    into = gmm.plan_tiles(4096, 4096, True, False, BF16)
+    out_of = gmm.plan_tiles(4096, 4096, False, True, BF16)
+    assert (into.tk, into.tm, into.vmem_limit_bytes) == (4096, 512, None)
+    assert (out_of.tk, out_of.tm, out_of.vmem_limit_bytes) == (
+        1024, 2048, None
+    )
+    assert gmm.expert_strips(4096, 4096, True, True, BF16) == "split_k:3/6"
+    dw_into = gmm.plan_dw_tiles(4096, 4096, True, False, BF16)
+    dw_out_of = gmm.plan_dw_tiles(4096, 4096, False, True, BF16)
+    assert (dw_into.tk, dw_into.tm) == (2048, 1024)
+    assert (dw_out_of.tk, dw_out_of.tm) == (1024, 2048)
+    assert dw_into.vmem_limit_bytes == dw_out_of.vmem_limit_bytes == 19136512
+    assert gmm.expert_dw_tiles(4096, 4096, True, BF16) == (
+        "into:2x4 out_of:4x2"
+    )
 
 
 def test_chip_smoke_refuses_cpu(cpu_child_env):

@@ -70,6 +70,16 @@ GAUGES_BEFORE_THE_TABLE = {
         "dlrover_attn_reporters",
     },
 }
+# ... and the ones a family's row has gained since, by the PR that added them.
+GAUGES_SINCE = {
+    # PR 58: the shared experts held, published and their scale; the layers
+    # that rotate at all
+    "moe": {
+        "dlrover_moe_shared_experts_held", "dlrover_moe_shared_experts",
+        "dlrover_moe_shared_expert_scale",
+    },
+    "attn": {"dlrover_attn_rotated_layers"},
+}
 
 
 def test_every_family_has_a_row_and_every_row_a_family():
@@ -102,7 +112,9 @@ def test_a_row_keeps_what_the_family_emits_and_renders_its_gauges(kind):
             name, value = line.rsplit(" ", 1)
             rendered[name] = float(value)
     gauges = {name: attr for attr, name, _ in row["gauges"]}
-    assert set(gauges) == GAUGES_BEFORE_THE_TABLE[kind]
+    assert set(gauges) == GAUGES_BEFORE_THE_TABLE[kind] | GAUGES_SINCE.get(
+        kind, set()
+    )
     for name, attr in gauges.items():
         assert f"# TYPE {name} gauge" in text
         want = 1.0 if attr == "reporters" else float(attrs[attr])

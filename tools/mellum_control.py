@@ -53,22 +53,29 @@ class StandIn:
             self.model, params, inputs, lowered=self.lowered,
             wrong=self.wrong,
         )["hidden"]
-        dtype = hidden.dtype
         with jax.default_matmul_precision("highest"):
-            logits = self.reference.rms_norm(
-                hidden, params["ln_final"]["scale"],
-                float(self.model["norm_eps"]), dtype,
-            ) @ params["lm_head"]["kernel"].astype(dtype)
-        return logits, None
+            return self.head(params, hidden, hidden.dtype), None
+
+    def head(self, params, hidden, dtype):
+        """The final norm and the head, in ``dtype``: this model's RMSNorm
+        and untied head (a sibling's script overrides it)."""
+        return self.reference.rms_norm(
+            hidden, params["ln_final"]["scale"],
+            float(self.model["norm_eps"]), dtype,
+        ) @ params["lm_head"]["kernel"].astype(dtype)
 
 
-def main(argv=None) -> int:
-    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+def main(argv=None, cell=CELL, stand_in=StandIn,
+         faults=("no_window", "no_yarn"), out="mellum_control.json",
+         doc=__doc__) -> int:
+    """``cell``, ``stand_in``, the default ``faults`` and ``out``: a sibling
+    configuration's script (``tools/command_a_control.py``) hands its own."""
+    ap = argparse.ArgumentParser(description=doc.splitlines()[0])
     ap.add_argument("--seeds", type=int, nargs="+", required=True)
     ap.add_argument("--lowered", nargs="*", default=list(LOWERED))
-    ap.add_argument("--faults", nargs="*", default=["no_window", "no_yarn"])
+    ap.add_argument("--faults", nargs="*", default=list(faults))
     ap.add_argument("--float32", action="store_true")
-    ap.add_argument("--out", default="mellum_control.json")
+    ap.add_argument("--out", default=out)
     ap.add_argument(
         "--program", nargs="*", default=[], metavar="FIELD=NUMBER",
         help="the configuration's program fields to read another way "
@@ -82,7 +89,7 @@ def main(argv=None) -> int:
     from benchmark.scenarios import train_steady_own_ref
 
     manifest = build.manifest()
-    cell = {w["name"]: w for w in manifest["workloads"]}[CELL]
+    cell = {w["name"]: w for w in manifest["workloads"]}[cell]
     file = {c["name"]: c for c in manifest["configs"]}[cell["config"]]["file"]
     root = os.path.dirname(build.ROOT)
     config = build.load_json(os.path.join(root, file))
@@ -120,7 +127,7 @@ def main(argv=None) -> int:
         ]
         for name, kw in stand_ins:
             with lowered_control.standing_in(
-                worker, StandIn(reference, worker.model, **kw)
+                worker, stand_in(reference, worker.model, **kw)
             ):
                 checks[name] = worker.check_reference()
         if args.float32:

@@ -817,7 +817,11 @@ def kernel_facts(cfg, seq_len: int) -> Dict[str, Any]:
         }
     # A model with windowed layers: the counts of each kind, the steps its
     # forward's grid really makes a (batch, head) (``grid``), and
-    # ``live_share``, the live steps among them.
+    # ``live_share``, the live steps among them.  The banded kernels say
+    # three facts more, of theirs alone: ``lower_strip``, the rows of a
+    # lower-edge block's strips (0: the masked square), ``tile_live_share``,
+    # the band's live pairs among the pairs their tiles work, and
+    # ``lockstep``, that a block's strips take their stages in turn.
     window = from_config(cfg, SLIDING_ATTENTION).window
     band = fa.block_classes(*sizes, causal=True, window=window)
 
@@ -835,9 +839,14 @@ def kernel_facts(cfg, seq_len: int) -> Dict[str, Any]:
             FULL_ATTENTION: facts(
                 classes, classes.diagonal, fa.forward_grid_steps(*sizes)
             ),
-            SLIDING_ATTENTION: facts(
-                band, band.diagonal + band.lower + band.both,
-                fa.forward_grid_steps(*sizes, window),
+            SLIDING_ATTENTION: dict(
+                facts(
+                    band, band.diagonal + band.lower + band.both,
+                    fa.forward_grid_steps(*sizes, window),
+                ),
+                lower_strip=band.lower_strip,
+                tile_live_share=fa.band_tile_live_share(*sizes, window),
+                lockstep=True,
             ),
         },
     }

@@ -49,8 +49,21 @@ column ``j`` iff ``0 <= i - j < W`` (itself and the ``W - 1`` before it).
 The band has a second edge, so a block is also **dead** when it lies wholly
 BELOW the band, and an edge block is one of three: crossed by the diagonal
 alone (worked as without a window, row strips and all), by the band's LOWER
-edge alone (the masked square under ``j > i - W``), or by both (``W`` no
-wider than a block).  A (batch, head) of 32,768 tokens at blocks of 1,024
+edge alone, or by both (``W`` no wider than a block: the masked square).
+Where ``W`` is whole blocks of one size that hold strips, a lower-edge
+block lies at ``iq - ik == W // block`` and its edge is its own diagonal,
+row ``i`` seeing ``j > i``: it is worked as the diagonal block's MIRROR,
+strip ``r`` taking ``q[r h : (r + 1) h]`` against columns ``[r h, block)``
+(``_tiles(lower=True)``, ``BandClasses.lower_strip``), so that a q block of
+one diagonal and one lower-edge block does 1.25 blocks' work for 1.0 live
+where the masked square did 1.625; any other window's lower edge crosses
+two blocks a q block at traced offsets and keeps the masked square under
+``j > i - W``.  Under a band every block of a q block may be an edge block
+and every tile a strip, so the forward and the one-pass backward advance a
+block's strips IN LOCKSTEP (``_advance``: a strip is a generator that
+yields where it has just asked the matrix unit for a product, and the
+strips take their stages in turn; the numbers are the loop's, bit for
+bit).  A (batch, head) of 32,768 tokens at blocks of 1,024
 has 528 live blocks under the causal mask and 63 under a band of 1,024, so
 the grids do not walk the dead ones either: the kv-inner kernels make
 ``kv_steps`` steps a q block (the most kv blocks the band gives a q block),
@@ -110,6 +123,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from dlrover_tpu.ops import backend
+from dlrover_tpu.ops.gated_delta_rule import _in_lockstep
 
 NEG_INF = -1e30
 _LANE = 128
@@ -164,7 +178,9 @@ class BandClasses(NamedTuple):
     """:class:`BlockClasses` under a window: ``diagonal`` counts the blocks
     the diagonal alone crosses, ``lower`` those the band's lower edge alone
     crosses, ``both`` those that carry both edges; ``kv_steps`` / ``q_steps``
-    are the inner grid steps of the kv-inner / q-inner kernels."""
+    are the inner grid steps of the kv-inner / q-inner kernels;
+    ``lower_strip`` the rows of a lower-edge block's strips (0: the masked
+    square)."""
 
     dead: int
     interior: int
@@ -174,6 +190,7 @@ class BandClasses(NamedTuple):
     both: int
     kv_steps: int
     q_steps: int
+    lower_strip: int
 
 
 def _pair_range(iq, ik, block_q, block_kv):
@@ -245,14 +262,21 @@ def _band_classes(nq, nk, block_q, block_kv, window) -> BandClasses:
     both = diagonal & lower
     edge = diagonal | lower
     count = lambda blocks: int(blocks.sum())
+    strip = _strip_rows(block_q, block_kv)
+    # A window of whole blocks puts the lower edge on the diagonal of the
+    # blocks it crosses (``iq - ik == window // block_kv``: row i of one
+    # sees column j iff j > i), where strips can follow it; any other
+    # window's edge crosses two blocks a q block at offsets of its own.
+    on_block_diagonal = window % block_kv == 0
     return BandClasses(
         dead=nq * nk - count(live), interior=count(live & ~edge),
         diagonal=count(diagonal & ~both),
-        strip=_strip_rows(block_q, block_kv) if (diagonal & ~both).any()
-        else 0,
+        strip=strip if (diagonal & ~both).any() else 0,
         lower=count(lower & ~both), both=count(both),
         kv_steps=max(1, int(live.sum(axis=1).max())),
         q_steps=max(1, int(live.sum(axis=0).max())),
+        lower_strip=strip if on_block_diagonal and (lower & ~both).any()
+        else 0,
     )
 
 
@@ -315,15 +339,43 @@ def _q_of_step(ik, step, nq, block_q, block_kv, window):
     return jnp.minimum((ik * block_kv) // block_q, nq - 1) + step
 
 
-def _tiles(block_q, block_kv, strip):
+def _tiles(block_q, block_kv, strip, lower=False):
     """Static ``(rows, cols)`` slices that cover a block's live part: the
-    whole block, or the strips of a diagonal one."""
+    whole block, or the strips of a diagonal one (each ends at the
+    diagonal), or of a ``lower`` one, the band's lower edge on its own
+    diagonal (each starts there)."""
     if not strip:
         return [(slice(0, block_q), slice(0, block_kv))]
     return [
-        (slice(r * strip, (r + 1) * strip), slice(0, (r + 1) * strip))
+        (
+            slice(r * strip, (r + 1) * strip),
+            slice(r * strip, block_kv) if lower
+            else slice(0, (r + 1) * strip),
+        )
         for r in range(block_q // strip)
     ]
+
+
+def _one_by_one(tiles):
+    """Runs a block's tiles, each a generator of one tile's stages (it
+    yields where it has just asked the matrix unit for a product the next
+    stage waits for), each to its end before the next one's first stage:
+    the program order of a plain loop over the tiles."""
+    for tile in tiles:
+        for _ in tile:
+            pass
+
+
+def _advance(window):
+    """How a kernel that can keep several tiles in flight runs a block's
+    tiles: under a ``window`` every block of a q block is an edge block
+    and every tile a strip, an independent chain (its rows of m, l, acc
+    and dq are its own, and every strip adds to dk / dv at the same
+    stage, so the adds keep the loop's order), so the strips take their
+    stages in turn (``gated_delta_rule._in_lockstep``: only the program's
+    order changes, every number is the loop's).  Without a window the
+    loop, and the kernel's text, are what they were."""
+    return _one_by_one if window is None else _in_lockstep
 
 
 def _for_live_class(
@@ -364,9 +416,13 @@ def _for_live_class(
                 on_diagonal, _tiles(block_q, block_kv, classes.strip)
             ))
         if classes.lower:
-            pl.when(lower & no(diagonal))(
-                lambda: compute(None, whole, offset)
-            )
+            # in strips the edge lies on the block's own diagonal: the
+            # offset is the window, static, and the mask a constant
+            pl.when(lower & no(diagonal))(lambda: compute(
+                None,
+                _tiles(block_q, block_kv, classes.lower_strip, lower=True),
+                window if classes.lower_strip else offset,
+            ))
         if classes.both:
             pl.when(lower & diagonal)(
                 lambda: compute(on_diagonal, whole, on_diagonal)
@@ -462,40 +518,47 @@ def _fwd_kernel(
             l_ref[:] = jnp.zeros_like(l_ref)
             acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    def _compute(causal_offset, tiles, band_offset=None):
-        for rows, cols in tiles:
-            v = v_ref[0, 0, cols, :]
-            s = _scores(q_ref[0, 0, rows, :], k_ref[0, 0, cols, :], scale)
-            s = _masked(
-                s, NEG_INF, rows, cols, causal_offset, seg_q_ref, seg_kv_ref,
-                segments, _band(band_offset, window),
-            )
+    def _tile(rows, cols, causal_offset, band_offset):
+        v = v_ref[0, 0, cols, :]
+        s = _scores(q_ref[0, 0, rows, :], k_ref[0, 0, cols, :], scale)
+        yield
+        s = _masked(
+            s, NEG_INF, rows, cols, causal_offset, seg_q_ref, seg_kv_ref,
+            segments, _band(band_offset, window),
+        )
 
-            m_new = jnp.max(s, axis=1)[:, None]  # [rows, 1]
-            if state:
-                m_prev = m_ref[rows, 0][:, None]
-                m_new = jnp.maximum(m_prev, m_new)
-            p = jnp.exp(s - m_new)
-            if segments or band_offset is not None:
-                # All-masked rows keep m at NEG_INF; freeze them to avoid
-                # inf-inf.  Without segments column 0 is live for every row
-                # (under a band, a row's first live block may hold none of
-                # the columns it sees).
-                p = jnp.where(m_new == NEG_INF, 0.0, p)
-            l_new = jnp.sum(p, axis=1)[:, None]
-            pv = jax.lax.dot(
-                p.astype(v.dtype), v, preferred_element_type=jnp.float32
-            )
-            if not state:
-                _write(rows, m_new, l_new, pv)
-                continue
-            correction = jnp.exp(m_prev - m_new)
-            if segments:
-                correction = jnp.where(m_prev == NEG_INF, 0.0, correction)
-            l_new += correction * l_ref[rows, 0][:, None]
-            acc_ref[rows, :] = acc_ref[rows, :] * correction + pv
-            m_ref[rows, :] = jnp.broadcast_to(m_new, (m_new.shape[0], _LANE))
-            l_ref[rows, :] = jnp.broadcast_to(l_new, (l_new.shape[0], _LANE))
+        m_new = jnp.max(s, axis=1)[:, None]  # [rows, 1]
+        if state:
+            m_prev = m_ref[rows, 0][:, None]
+            m_new = jnp.maximum(m_prev, m_new)
+        p = jnp.exp(s - m_new)
+        if segments or band_offset is not None:
+            # All-masked rows keep m at NEG_INF; freeze them to avoid
+            # inf-inf.  Without segments column 0 is live for every row
+            # (under a band, a row's first live block may hold none of
+            # the columns it sees).
+            p = jnp.where(m_new == NEG_INF, 0.0, p)
+        l_new = jnp.sum(p, axis=1)[:, None]
+        pv = jax.lax.dot(
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32
+        )
+        yield
+        if not state:
+            _write(rows, m_new, l_new, pv)
+            return
+        correction = jnp.exp(m_prev - m_new)
+        if segments:
+            correction = jnp.where(m_prev == NEG_INF, 0.0, correction)
+        l_new += correction * l_ref[rows, 0][:, None]
+        acc_ref[rows, :] = acc_ref[rows, :] * correction + pv
+        m_ref[rows, :] = jnp.broadcast_to(m_new, (m_new.shape[0], _LANE))
+        l_ref[rows, :] = jnp.broadcast_to(l_new, (l_new.shape[0], _LANE))
+
+    def _compute(causal_offset, tiles, band_offset=None):
+        _advance(window)(
+            _tile(rows, cols, causal_offset, band_offset)
+            for rows, cols in tiles
+        )
 
     _for_live_class(
         iq, ik, _compute, causal=causal, block_q=block_q, block_kv=block_kv,
@@ -603,6 +666,9 @@ def _recompute_p_ds(
     head dim is whole per block, so the row sum is exact) instead of in a
     separate XLA fusion — that fusion plus the padded [B,H,S,STAT] delta
     array cost ~1 ms/layer of pure HBM traffic at bench shapes.
+
+    A generator of the tile's first stages (``_one_by_one``): it yields
+    after each of its two products and RETURNS the five (``yield from``).
     """
     k = k_ref[0, 0, cols, :]
     v = v_ref[0, 0, cols, :]
@@ -613,14 +679,17 @@ def _recompute_p_ds(
         axis=1, keepdims=True,
     )
     q = q_ref[0, 0, rows, :]
+    s = _scores(q, k, scale)
+    yield
     p = _masked(
-        jnp.exp(_scores(q, k, scale) - lse), 0.0,
+        jnp.exp(s - lse), 0.0,
         rows, cols, causal_offset, seg_q_ref, seg_kv_ref, segments, band,
     )
     dp = jax.lax.dot_general(
         do, v, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
+    yield
     ds = p * (dp - delta) * scale
     return p, ds.astype(q.dtype), q, k, do
 
@@ -639,18 +708,23 @@ def _bwd_dq_kernel(
     def _init():
         dq_acc_ref[:] = jnp.zeros_like(dq_acc_ref)
 
+    def _tile(rows, cols, causal_offset, band_offset):
+        _, ds, _, k, _ = yield from _recompute_p_ds(
+            q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref,
+            seg_q_ref, seg_kv_ref,
+            rows=rows, cols=cols, causal_offset=causal_offset,
+            scale=scale, segments=segments,
+            band=_band(band_offset, window),
+        )
+        dq_acc_ref[rows, :] += jax.lax.dot(
+            ds, k, preferred_element_type=jnp.float32
+        )
+
     def _compute(causal_offset, tiles, band_offset=None):
-        for rows, cols in tiles:
-            _, ds, _, k, _ = _recompute_p_ds(
-                q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref,
-                seg_q_ref, seg_kv_ref,
-                rows=rows, cols=cols, causal_offset=causal_offset,
-                scale=scale, segments=segments,
-                band=_band(band_offset, window),
-            )
-            dq_acc_ref[rows, :] += jax.lax.dot(
-                ds, k, preferred_element_type=jnp.float32
-            )
+        _one_by_one(
+            _tile(rows, cols, causal_offset, band_offset)
+            for rows, cols in tiles
+        )
 
     _for_live_class(
         iq, ik, _compute, causal=causal, block_q=block_q, block_kv=block_kv,
@@ -689,16 +763,21 @@ def _bwd_dkv_kernel(
         dk_acc_ref[:] = jnp.zeros_like(dk_acc_ref)
         dv_acc_ref[:] = jnp.zeros_like(dv_acc_ref)
 
+    def _tile(rows, cols, causal_offset, band_offset):
+        p, ds, q, _, do = yield from _recompute_p_ds(
+            q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref,
+            seg_q_ref, seg_kv_ref,
+            rows=rows, cols=cols, causal_offset=causal_offset,
+            scale=scale, segments=segments,
+            band=_band(band_offset, window),
+        )
+        _add_dk_dv(dk_acc_ref, dv_acc_ref, cols, p, ds, q, do)
+
     def _compute(causal_offset, tiles, band_offset=None):
-        for rows, cols in tiles:
-            p, ds, q, _, do = _recompute_p_ds(
-                q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref,
-                seg_q_ref, seg_kv_ref,
-                rows=rows, cols=cols, causal_offset=causal_offset,
-                scale=scale, segments=segments,
-                band=_band(band_offset, window),
-            )
-            _add_dk_dv(dk_acc_ref, dv_acc_ref, cols, p, ds, q, do)
+        _one_by_one(
+            _tile(rows, cols, causal_offset, band_offset)
+            for rows, cols in tiles
+        )
 
     _for_live_class(
         iq, ik, _compute, causal=causal, block_q=block_q, block_kv=block_kv,
@@ -756,21 +835,27 @@ def _bwd_fused_kernel(
     if window is not None:
         first = _band_first_kv(iq, block_q, block_kv, window)
 
+    def _tile(rows, cols, causal_offset, band_offset):
+        p, ds, q, k, do = yield from _recompute_p_ds(
+            q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref,
+            seg_q_ref, seg_kv_ref,
+            rows=rows, cols=cols, causal_offset=causal_offset,
+            scale=scale, segments=segments,
+            band=_band(band_offset, window),
+        )
+        _add_dk_dv(dk_acc_ref, dv_acc_ref, cols, p, ds, q, do)
+        dq = jax.lax.dot(ds, k, preferred_element_type=jnp.float32)
+        yield
+        if not dq_acc:
+            dq_ref[0, 0, rows, :] = dq.astype(dq_ref.dtype)
+            return
+        _add_dq(dq, rows, *dq_acc)
+
     def _compute(causal_offset, tiles, band_offset=None):
-        for rows, cols in tiles:
-            p, ds, q, k, do = _recompute_p_ds(
-                q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref,
-                seg_q_ref, seg_kv_ref,
-                rows=rows, cols=cols, causal_offset=causal_offset,
-                scale=scale, segments=segments,
-                band=_band(band_offset, window),
-            )
-            _add_dk_dv(dk_acc_ref, dv_acc_ref, cols, p, ds, q, do)
-            dq = jax.lax.dot(ds, k, preferred_element_type=jnp.float32)
-            if not dq_acc:
-                dq_ref[0, 0, rows, :] = dq.astype(dq_ref.dtype)
-                continue
-            _add_dq(dq, rows, *dq_acc)
+        _advance(window)(
+            _tile(rows, cols, causal_offset, band_offset)
+            for rows, cols in tiles
+        )
 
     def _add_dq(dq, rows, dq_acc_ref):
         height = rows.stop - rows.start
@@ -1172,3 +1257,31 @@ def forward_grid_steps(sq, skv, block_q, block_kv, window=None) -> int:
     if window is None:
         return nq * nk
     return nq * _band_classes(nq, nk, block_q, block_kv, window).kv_steps
+
+
+def band_tile_live_share(sq, skv, block_q, block_kv, window) -> float:
+    """The band's live pairs over the pairs the tiles of a banded kernel
+    work, one (batch, head) at these sizes, given as :func:`mha`'s caller
+    gives them: an interior block is all live, an edge block's tiles are
+    its strips or the masked square (``_tiles``, from the classes alone)."""
+    block_q, block_kv, sq, skv = _blocks_and_padding(
+        sq, skv, block_q, block_kv
+    )
+    classes = _band_classes(
+        sq // block_q, skv // block_kv, block_q, block_kv, window
+    )
+
+    def pairs(strip, lower=False):
+        return sum(
+            (rows.stop - rows.start) * (cols.stop - cols.start)
+            for rows, cols in _tiles(block_q, block_kv, strip, lower)
+        )
+
+    worked = (
+        (classes.interior + classes.both) * pairs(0)
+        + classes.diagonal * pairs(classes.strip)
+        + classes.lower * pairs(classes.lower_strip, lower=True)
+    )
+    ramp = min(window, sq)      # rows that see fewer than ``window`` keys
+    live = ramp * (ramp + 1) // 2 + (sq - ramp) * window
+    return live / worked

@@ -252,6 +252,12 @@ def test_to_program_maps_to_fields_that_exist():
     assert cfg.shared_expert_scale == 0.25 and cfg.resolved_shared_d_ff == 4096
     facts = kernel_facts(cfg, 16384)
     assert (facts["block_form"], facts["block_norms"]) == ("parallel", 1)
+    # a window of four blocks: 16 diagonal and 12 lower-edge blocks in
+    # strips, 42 interior: 56 blocks' live pairs of 59.5 worked (of 64)
+    band = facts["flash_blocks"]["sliding_attention"]
+    assert (band["strip"], band["lower_strip"]) == (256, 256)
+    assert band["tile_live_share"] == pytest.approx(56 / 59.5, abs=1e-4)
+    assert "lower_strip" not in facts["flash_blocks"]["full_attention"]
 
 
 def test_cache_key_covers_the_new_fields():

@@ -68,6 +68,33 @@ def test_compile_event_names_the_flash_backward(
         assert flash_facts(xla_attention=True) == ("none", None)
 
 
+@pytest.mark.parametrize("preset", [
+    "mellum2-12b-a2.5b", "command-a-plus-05-2026",
+])
+def test_compile_event_names_the_banded_kernels_tiles(preset):
+    """A model with windowed layers says three more facts of its BANDED
+    kernels, under ``sliding_attention`` and nowhere else (the full layers'
+    dict and a plain model's four counts above keep their keys): the rows
+    of a lower-edge block's strips, the live pairs among the pairs the
+    kernels' tiles work, and that a block's strips run in lockstep."""
+    model, seq = preset_model(preset)
+    blocks = compile_event(preset)["flash_blocks"]
+    band, full = blocks["sliding_attention"], blocks["full_attention"]
+    assert set(band) - set(full) == {
+        "lower_strip", "tile_live_share", "lockstep"
+    }
+    assert not set(full) - set(band)
+    block, window = model.flash_block_kv, model.sliding_window
+    assert (seq, block, window) == (64, 16, 24)
+    # blocks of 16 hold no strip: every live block is worked whole
+    assert (band["strip"], band["lower_strip"]) == (0, 0)
+    live_pairs = sum(min(i + 1, window) for i in range(seq))
+    assert band["tile_live_share"] == live_pairs / (
+        band["live"] * block * block
+    )
+    assert band["lockstep"] is True
+
+
 @pytest.mark.parametrize("preset,seq,path", [
     # Nemotron-like: the tiny preset's layers on one whole lane tile of
     # tokens (x | B | C = 256 | 32 | 32 channels: whole row tiles)

@@ -141,6 +141,10 @@ def test_fit_books_the_attn_event_from_the_step_itself(monkeypatch, tmp_path):
     band = blocks["sliding_attention"]
     assert (band["live"], band["dead"], band["grid"]) == (9, 7, 12)
     assert band["live_share"] == 0.75 and band["interior"] == 0
+    # ... and, of the banded kernels alone: blocks of 8 hold no strip, so
+    # the 318 live pairs of the band are worked as nine whole blocks
+    assert (band["lower_strip"], band["lockstep"]) == (0, True)
+    assert band["tile_live_share"] == 318 / (9 * 64)
     assert compiled["flash_backward"] == "fused"
     attn = [e[4] for e in events if e[0] == "attn"]
     moe = [e[4] for e in events if e[0] == "moe"]
@@ -188,6 +192,15 @@ def test_a_model_without_windowed_layers_books_what_it_booked():
         63, 64, "fused"
     )
     assert band["live_share"] == 63 / 64
+    # the lower-edge blocks are strips as the diagonal ones: 10 of 16
+    # sub-tiles each, 31.5 blocks' live pairs of 39.4 worked (of 51 while
+    # the lower edge was a masked square); said of the banded kernels only
+    assert (band["strip"], band["lower_strip"]) == (256, 256)
+    assert band["tile_live_share"] == pytest.approx(0.8, abs=1e-4)
+    assert band["lockstep"] is True
+    assert set(band) - set(blocks["full_attention"]) == {
+        "lower_strip", "tile_live_share", "lockstep"
+    }
     # rows of 2,304 are 18 lane tiles: padded at the fetch-and-sum kernel's
     # door; the 896-wide strips stay whole-K
     assert facts(published, 32768)["row_moves"] == (

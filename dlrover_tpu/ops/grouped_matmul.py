@@ -44,7 +44,16 @@ resident, PERF.md section 6, PR 53; it costs less only where XLA happens to
 hold the whole expert matrix in VMEM itself, which no call can count on).
 K is therefore split only where the whole-K strip fits under no VMEM limit
 the kernel may ask for (``_VMEM_CAP``; Mixtral's 14336 x 2048 under
-``grouped``).
+``grouped``).  Command A+'s 4096 x 4096 was such a call under a cap of 32
+MiB: rows leave the expert width row-tiled, a row-tiled block's narrowest
+width in bf16 is 2,048 columns, the whole-K strip ``[4096, 2048]`` counts
+38.0 MiB, and split in four every row block streamed 16 MiB again.  On the
+chip, at ``[11392, 4096]`` rows, eight experts, 68 live row blocks
+(PERF.md section 6, PR 61): split 3.417 ms a call, resident under the 38.0
+MiB it asks for 1.841 (the MXU needs 1.48); the dx call of the same plan,
+XLA's transpose of the weights included, 4.168 against 2.759; the calls
+INTO the width, whose strips of 512 columns were always whole, 2.020 on
+both sides.
 
 The ``dW`` call asks for more before it tiles, too (:func:`plan_dw_tiles`).
 Its grid is (K tile, M tile, row block): an expert's ``[tk, tm]`` tile stays
@@ -55,8 +64,10 @@ and one tile 2.19, 1.58 and 1.48 ms a call; PERF.md section 6, PR 55).  The
 default budget cut Mellum2's 2304 x 896 into seven tiles of 128 lanes, 2.7
 ms of HBM time for 1.4 ms of MXU work (3.91 ms a call; whole 1.77), so the
 whole tile is taken wherever ``_VMEM_CAP`` holds it, and the fewest tiles
-where it does not.  The sum's order is the same whatever the tile, and so
-is every number of dW.
+where it does not (Command A+'s 4096 x 4096 in four tiles of ``[2048,
+2048]`` under 35.0 MiB where 32 MiB held eight: 2.888 -> 2.773 ms a call,
+PR 61).  The sum's order is the same whatever the tile, and so is every
+number of dW.
 """
 
 from __future__ import annotations
@@ -84,25 +95,33 @@ from dlrover_tpu.ops.row_gather_sum import LANES, tile_rows
 # once a ROW BLOCK and not once an expert (the module docstring).  The dw
 # call asks for more before it tiles as well (``plan_dw_tiles``): its tiles
 # cost x and dy a pass over HBM each and the grid its steps; Mosaic accepts
-# 17.8 MiB for Mellum2's whole [2304, 896] tile (18.1 counted) and 30.3 for
-# LFM2's [2048, 1792] (30.8) for the described chip.  A whole [K, M] expert
+# 17.8 MiB for Mellum2's whole [2304, 896] tile (18.1 counted), 30.3 for
+# LFM2's [2048, 1792] (30.8) and 34.4 for a [2048, 2048] quarter of Command
+# A+'s (35.0) for the described chip.  A whole [K, M] expert
 # block overflows the budget at MoE widths (K=1600, M=3200), so under the
 # default limit the kernels tile M, and K where they must.
 _TILE_BYTES = 8 * 2**20
 
 # The most VMEM a call may ask for (``vmem_limit_bytes``), a forward/dx call
-# to keep a whole-K strip resident, a dw call to keep its tile whole: a
-# quarter of a v5e core's 128 MiB, twice the default scoped limit.
-# ``_strip_vmem_bytes`` counts LFM2's [1792, 2048]
-# bf16 strip at 18.3 MiB (14.0 of them the strip twice; Mosaic accepts 16.4
-# for the described chip) and Nemotron's [2688, 1856] at 24.5 (19.7; 21.8);
-# Mixtral's [14336, 2048] would take 125.5 and keeps the K-split.  What a
-# call asks for XLA cannot use beside it: XLA keeps whole operands of a
-# kernel in VMEM where they fit (two of LFM2's 56 MiB expert matrices under
-# the default limit, one under 18.3 MiB: PERF.md section 6, PR 53), so the
-# call asks for what its plan needs and no more.  Nothing between 25 and
-# 125 MiB has been measured.
-_VMEM_CAP = 32 * 2**20
+# to keep a whole-K strip resident, a dw call to keep its tile whole: the
+# least round value that holds Command A+'s [4096, 2048] bf16 strip (38.0
+# MiB counted, 32 of them the strip twice; Mosaic accepts 36.0 for the
+# described chip), of a v5e core's 128 MiB.  ``_strip_vmem_bytes`` counts
+# LFM2's [1792, 2048] at 18.3 MiB (14.0 the strip twice; Mosaic accepts
+# 16.4) and Nemotron's [2688, 1856] at 24.5 (19.7; 21.8); Mixtral's [14336,
+# 2048] would take 125.5 and keeps the K-split.  What a call asks for XLA
+# cannot use beside it: XLA keeps whole operands of a kernel in VMEM where
+# they fit (two of LFM2's 56 MiB expert matrices under the default limit,
+# one under 18.3 MiB: PERF.md section 6, PR 53), so the call asks for what
+# its plan needs and no more.  What the chip read between 25 and 40 MiB
+# (PERF.md section 6, PR 61; Command A+'s shapes): the resident strip and
+# the four dW tiles are the faster forms (the module docstring has the
+# calls' times), Mosaic takes the limit alone and in the cell's step, and
+# the compiled step holds the same large operands in VMEM (``S(1)``) under
+# either cap, the shared expert's 32 MiB matrices and the replayed ``wo``
+# call's 89 MiB of rows among them.  From 48 MiB on Nemotron's whole dW
+# tile (42.8) would fit too: not measured, nor anything up to 125.
+_VMEM_CAP = 40 * 2**20
 
 
 def _lane_tiles(dim: int, quantum: int = LANES):

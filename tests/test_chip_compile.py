@@ -492,25 +492,31 @@ def test_kernel_compiles_for_v5e(compile_for_chip, build, avals, static,
 def test_the_plans_at_4096_by_4096():
     """What the grouped GEMMs' plans choose for Command A+'s experts (bf16,
     rows of 32 lane tiles row-tiled): INTO the expert width whole-K strips
-    of 512 columns, resident; OUT OF it K is SPLIT in four (a tiled
-    output's narrowest block is 2,048 columns, whose whole-K strip is past
-    the cap); each weight gradient eight tiles under a limit it asks for."""
+    of 512 columns, resident under the default limit; OUT OF it a tiled
+    output's narrowest block is 2,048 columns, whose whole-K strip counts
+    39,845,888 bytes (38.0 MiB): resident too, under a limit the call asks
+    for, since ``_VMEM_CAP`` is 40 MiB (PR 61).  Under 32 MiB K was split in
+    four (``tk`` 1024) and every row block of 128 rows streamed its expert's
+    16 MiB strip again, ``split_k:3/6``.  Each weight gradient is four tiles
+    of ``[2048, 2048]`` asking 36,700,160 where 32 MiB held eight
+    (``[2048, 1024]`` / ``[1024, 2048]``, 19,136,512)."""
     from dlrover_tpu.ops import grouped_matmul as gmm
 
     into = gmm.plan_tiles(4096, 4096, True, False, BF16)
     out_of = gmm.plan_tiles(4096, 4096, False, True, BF16)
     assert (into.tk, into.tm, into.vmem_limit_bytes) == (4096, 512, None)
     assert (out_of.tk, out_of.tm, out_of.vmem_limit_bytes) == (
-        1024, 2048, None
+        4096, 2048, 39845888
     )
-    assert gmm.expert_strips(4096, 4096, True, True, BF16) == "split_k:3/6"
+    assert out_of.vmem_limit_bytes <= gmm._VMEM_CAP == 40 * 2**20
+    assert gmm.expert_strips(4096, 4096, True, True, BF16) == "resident"
     dw_into = gmm.plan_dw_tiles(4096, 4096, True, False, BF16)
     dw_out_of = gmm.plan_dw_tiles(4096, 4096, False, True, BF16)
-    assert (dw_into.tk, dw_into.tm) == (2048, 1024)
-    assert (dw_out_of.tk, dw_out_of.tm) == (1024, 2048)
-    assert dw_into.vmem_limit_bytes == dw_out_of.vmem_limit_bytes == 19136512
+    assert (dw_into.tk, dw_into.tm) == (2048, 2048)
+    assert (dw_out_of.tk, dw_out_of.tm) == (2048, 2048)
+    assert dw_into.vmem_limit_bytes == dw_out_of.vmem_limit_bytes == 36700160
     assert gmm.expert_dw_tiles(4096, 4096, True, BF16) == (
-        "into:2x4 out_of:4x2"
+        "into:2x2 out_of:2x2"
     )
 
 

@@ -176,6 +176,29 @@ def test_the_published_widths_count_what_the_file_counts():
     assert "mlp" not in tree["blocks"]["full_3"]
 
 
+def test_the_cells_kernels_at_16k_keep_every_strip_resident():
+    """What the ``compile`` event says of the cell's kernels at 1 x 16,384
+    tokens.  Since PR 61 the three grouped GEMMs OUT OF the expert width
+    keep their whole-K strip resident under a limit they ask for (40 MiB
+    holds its 38.0; ``split_k:3/6`` under 32), and each weight gradient is
+    four tiles (eight).  ``tests/benchmark_suite``'s pinned reading of the
+    same facts still holds PR 58's two strings (only a ``benchmark`` issue
+    may re-pin them) and stops there, so the facts after them are held
+    here: the parallel block's one norm and the two attention grids."""
+    from benchmark import build
+
+    cut = build.transformer_config(build.model_group(cell_file()), 16384)
+    facts = kernel_facts(cut, 16384)
+    assert facts["row_moves"] == "kernel_live"
+    assert facts["gmm_strips"] == "resident"
+    assert facts["gmm_dw_tiles"] == "into:2x2 out_of:2x2"
+    assert (facts["block_form"], facts["block_norms"]) == ("parallel", 1)
+    # a band five blocks of 1,024 wide: 70 live steps of a grid of 80
+    band = facts["flash_blocks"]["sliding_attention"]
+    assert (band["live"], band["grid"], band["live_share"]) == (70, 80, 0.875)
+    assert facts["flash_blocks"]["full_attention"]["live"] == 136
+
+
 def test_the_file_holds_every_key_of_the_catalog_s_config():
     if not os.path.exists(CATALOG):
         pytest.skip("no catalog beside the model-configs guide here")

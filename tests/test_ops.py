@@ -180,6 +180,50 @@ def test_grouped_matmul_grads(rng, monkeypatch, k, m, tile_bytes, cap,
     np.testing.assert_allclose(gw_k, gw_r, atol=1e-3, rtol=1e-3)
 
 
+@pytest.mark.parametrize("call", ["wo_forward", "wi_dx"])
+def test_grouped_matmul_out_of_the_width_under_the_limit_it_asks_for(
+    rng, monkeypatch, call
+):
+    """Command A+'s case in small: rows leave the expert width row-tiled,
+    so the narrowest block of M is a native tile's 2,048 columns and the
+    whole-K strip overflows the budget.  A cap that holds it to the byte
+    keeps K whole (one contraction step, the limit asked for); one byte
+    less splits K in two.  Both are the reference's numbers, forward and
+    gradients, whether the call is ``wo``'s forward or the dx of ``wi``."""
+    d, ff, e = 2048, 256, 3
+    dtype = jnp.bfloat16
+    monkeypatch.setattr(gmm, "_TILE_BYTES", 512 * 1024)
+    need = gmm._strip_vmem_bytes(ff, d, dtype, 128)
+    sizes = jnp.asarray([128, 256, 0], jnp.int32)
+    n = int(sizes.sum()) + 128         # a dead block of the budget's slack
+    if call == "wo_forward":
+        k, m, tiled_in, tiled_out = ff, d, False, True
+    else:
+        k, m, tiled_in, tiled_out = d, ff, True, False
+    x = jnp.asarray(rng.normal(size=(n, k)), dtype)
+    w = jnp.asarray(rng.normal(size=(e, k, m)) * 0.05, dtype)
+    dy = jnp.asarray(rng.normal(size=(n, m)), dtype)
+
+    def kernel(x, w):
+        rows = x.reshape(n, k // 128, 128) if tiled_in else x
+        out = gmm.grouped_matmul(rows, w, sizes, 128, tiled_out, True)
+        return out.reshape(n, m)
+
+    want, ref_vjp = jax.vjp(
+        lambda x, w: gmm.grouped_matmul_ref(x, w, sizes), x, w
+    )
+    for cap, steps in ((need, 1), (need - 1, 2)):
+        monkeypatch.setattr(gmm, "_VMEM_CAP", cap)
+        out_of = gmm.plan_tiles(ff, d, False, True, dtype)
+        assert out_of == (ff // steps, d, need if steps == 1 else None)
+        got, vjp = jax.vjp(kernel, x, w)
+        for a, b in zip((got,) + vjp(dy), (want,) + ref_vjp(dy)):
+            np.testing.assert_allclose(
+                np.asarray(a, np.float32), np.asarray(b, np.float32),
+                atol=0.05, rtol=0.02,
+            )
+
+
 def test_grouped_matmul_dw_is_the_same_numbers_whatever_the_tile(
     rng, monkeypatch
 ):
@@ -235,6 +279,12 @@ _CELL_TILES = {
     "lfm2_out_of": ((1792, 2048, False, True), (1792, 2048, True)),
     "nemotron_into": ((2688, 1856, False, False), (2688, 1856, True)),
     "mixtral_out_of": ((14336, 4096, False, True), (1024, 2048, False)),
+    # Command A+ (PR 58): into the width strips of 512 columns were always
+    # whole; out of it a row-tiled block's narrowest width is 2,048 columns,
+    # whose whole-K strip counts 38.0 MiB: K split in four (1024, 2048)
+    # until the cap went from 32 to 40 MiB (PR 61)
+    "command_a_into": ((4096, 4096, True, False), (4096, 512, False)),
+    "command_a_out_of": ((4096, 4096, False, True), (4096, 2048, True)),
 }
 
 
@@ -273,10 +323,15 @@ _CELL_DW_TILES = {
     "joyai_out_of": ((768, 2048, False, True), (768, 2048, True)),    # 2x1
     "ling_into": ((2560, 768, False, False), (2560, 768, True)),      # 1x2
     "ling_out_of": ((768, 2560, False, False), (768, 2560, True)),    # 1x2
-    # sixteen tiles under the cap where the budget cut 56: rows of 4,096
-    # row-tiled can be halved, and then the wider tile of M re-reads less
-    "mixtral_into": ((4096, 14336, True, False), (2048, 1792, True)),
-    "mixtral_out_of": ((14336, 4096, False, True), (1792, 2048, True)),
+    # fourteen tiles under the cap where the budget cut 56 (sixteen of
+    # 2048 x 1792 under 32 MiB, until PR 61): rows of 4,096 row-tiled can
+    # be halved, and then the wider tile of M re-reads less
+    "mixtral_into": ((4096, 14336, True, False), (2048, 2048, True)),
+    "mixtral_out_of": ((14336, 4096, False, True), (2048, 2048, True)),
+    # Command A+ (PR 61): four tiles of 35.0 MiB where 32 MiB held eight
+    # ([2048, 1024] / [1024, 2048]); the whole tile would count 100 MiB
+    "command_a_into": ((4096, 4096, True, False), (2048, 2048, True)),
+    "command_a_out_of": ((4096, 4096, False, True), (2048, 2048, True)),
     # a tile the default limit holds stays, and asks for nothing
     "preset": ((256, 512, False, False), (256, 512, False)),
 }
@@ -302,7 +357,9 @@ def test_plan_dw_tiles_at_the_cells_shapes(call):
     (2048, 1792, True, 0, "into:1x7 out_of:7x1"),
     (2688, 1856, False, None, "into:3x1 out_of:1x3"),     # Nemotron
     (2688, 1856, False, 0, "into:7x1 out_of:1x7"),
-    (4096, 14336, True, None, "into:2x8 out_of:8x2"),     # Mixtral, `grouped`
+    (4096, 14336, True, None, "into:2x7 out_of:7x2"),     # Mixtral, `grouped`
+    (4096, 4096, True, None, "into:2x2 out_of:2x2"),      # Command A+
+    (4096, 4096, True, 32 << 20, "into:2x4 out_of:4x2"),  # ... until PR 61
 ])
 def test_expert_dw_tiles_counts_a_layers_tiles(monkeypatch, d, ff, tiled,
                                                cap, said):
@@ -316,9 +373,22 @@ def test_expert_dw_tiles_counts_a_layers_tiles(monkeypatch, d, ff, tiled,
     (2048, 1792, True, True, "resident"),       # LFM2 (split_k:3/6 before)
     (2688, 1856, False, False, "resident"),     # Nemotron (split_k:2/4)
     (4096, 14336, True, True, "split_k:3/6"),   # Mixtral under `grouped`
+    (4096, 4096, True, True, "resident"),       # Command A+ (split_k:3/6)
 ])
 def test_expert_strips_counts_a_layers_calls(d, ff, gated, tiled, said):
     assert gmm.expert_strips(d, ff, gated, tiled, jnp.bfloat16) == said
+
+
+def test_expert_strips_under_32_mib_split_command_a_plus(monkeypatch):
+    """The cap PR 53 chose held every cell's strip but the one a row-tiled
+    output makes 2,048 columns wide at K 4,096 (38.0 MiB counted)."""
+    monkeypatch.setattr(gmm, "_VMEM_CAP", 32 << 20)
+    assert gmm.plan_tiles(4096, 4096, False, True, jnp.bfloat16) == (
+        1024, 2048, None
+    )
+    assert gmm.expert_strips(
+        4096, 4096, True, True, jnp.bfloat16
+    ) == "split_k:3/6"
 
 
 def test_expert_strips_without_the_cap_reads_the_old_rule(monkeypatch):

@@ -195,6 +195,37 @@ HEALTH_KINDS: Dict[str, Dict[str, Any]] = {
              "trainers that have reported attention snapshots"),
         ),
     },
+    # The sparse attention layers' indexers (the largest index score stands
+    # where a state's largest entry does).
+    "index": {
+        "mean": {"selected_share": 0.0, "kl": 0.0},
+        "max": {
+            "index_layers": 0.0, "shared_layers": 0.0, "topk": 0.0,
+            "score_absmax": 0.0,
+        },
+        "gauges": (
+            ("index_layers", "dlrover_index_layers",
+             "attention layers that hold an indexer and choose a query's "
+             "keys"),
+            ("shared_layers", "dlrover_index_shared_layers",
+             "attention layers served by an earlier layer's choice "
+             "(IndexShare)"),
+            ("topk", "dlrover_index_topk",
+             "keys an indexer keeps for a query"),
+            ("selected_share", "dlrover_index_selected_share",
+             "chosen (query, key) pairs over the pairs a query may see "
+             "(mean of reporters; 1 = nothing left out)"),
+            ("kl", "dlrover_index_kl",
+             "mean over the choosing layers of KL(attention || softmax "
+             "of the index scores) over the chosen keys, the term that "
+             "trains the indexers (mean of reporters)"),
+            ("score_absmax", "dlrover_index_score_absmax",
+             "largest |index score| of a pair a query may see (max of "
+             "reporters; NaN/Inf = diverged)"),
+            ("reporters", "dlrover_index_reporters",
+             "trainers that have reported indexer snapshots"),
+        ),
+    },
 }
 
 

@@ -246,9 +246,15 @@ class QKNorm(nn.Module):
 
 
 STATS_NAME = "attn_stats"
-# The two kinds of softmax-attention layer a layer pattern names.
+# The kinds of softmax-attention layer a layer pattern names: the causal
+# triangle, a band of it, and (latent attention only, DeepSeek-V3.2's DSA)
+# a set of keys a query, which a layer's own indexer CHOOSES
+# (``index_attention``) or the nearest such layer before it hands over
+# (``reuse_attention``); ``models/sparse_attention.py``.
 FULL_ATTENTION = "full_attention"
 SLIDING_ATTENTION = "sliding_attention"
+INDEX_ATTENTION = "index_attention"
+REUSE_ATTENTION = "reuse_attention"
 
 
 def score_bound(q: jax.Array, k: jax.Array, scale: float) -> jax.Array:
@@ -566,7 +572,14 @@ class LatentAttention(nn.Module):
     head's q and of the ``kv_a`` row (``rope_interleave`` in the published
     config is a fixed permutation of those columns).
 
-    There is no decode path: a latent cache would hold the normed
+    A sparse layer (``models/sparse_attention.py``, DeepSeek-V3.2's DSA)
+    is this layer with ONE change: query ``t`` attends to a chosen set
+    ``S_t`` of the keys before it (the softmax runs over ``S_t``), which
+    an indexer that reads ``n`` and ``c_q`` picks or an earlier layer
+    hands over; it builds on ``latent_qkv`` and ``latent_output`` below.
+
+    There is no decode path, with a chosen set or without (``decode=True``
+    is refused by ``TransformerConfig``): a latent cache would hold the normed
     ``c_kv`` row and the rotated ``k_pe`` (``kv_lora_rank + rope`` numbers
     a token, not ``2 H hd``) in ``serving/decode.py``'s cache pool, with
     ``W_kvb`` absorbed into the query and output sides; nothing there can
@@ -588,6 +601,11 @@ class LatentAttention(nn.Module):
     flash_block_kv: int = 512
     scale: float = 0.0             # 0 -> (nope + rope) ** -0.5
     gate: str = ""                 # "" | "head_wise"
+    # The spread the scaled scores are seeded with (0: the default
+    # initialisers, which count the heads into the fan-in and spread them
+    # by about 0.1): ``q_b`` and ``kv_b`` drawn so that a head's query
+    # entries have this variance squared and its keys' one.
+    init_score_std: float = 0.0
 
     @nn.compact
     def __call__(
@@ -605,62 +623,7 @@ class LatentAttention(nn.Module):
             raise ValueError(
                 f"gate must be '' or 'head_wise', got {self.gate!r}"
             )
-        features = x.shape[-1]
-        nope, rope = self.qk_nope_head_dim, self.qk_rope_head_dim
-        if positions is None:
-            positions = jnp.arange(x.shape[1])[None, :]
-
-        def dense(width, axes, name):
-            return layers.DenseGeneral(
-                width, kernel_axes=axes, use_bias=False, dtype=self.dtype,
-                param_dtype=self.param_dtype, name=name,
-            )
-
-        def norm(name):
-            return layers.make_norm(
-                "rmsnorm", self.dtype, self.param_dtype, name,
-                epsilon=self.norm_eps,
-            )
-
-        if self.q_lora_rank:
-            c_q = norm("q_norm")(
-                dense(self.q_lora_rank, (lr.EMBED, lr.LATENT), "q_a")(x)
-            )
-            q = dense(
-                (self.num_heads, nope + rope), (lr.LATENT, lr.HEADS, lr.KV),
-                "q_b",
-            )(c_q)
-        else:
-            q = dense(
-                (self.num_heads, nope + rope), (lr.EMBED, lr.HEADS, lr.KV),
-                "q_b",
-            )(x)
-        kv_row = dense(
-            self.kv_lora_rank + rope, (lr.EMBED, lr.LATENT), "kv_a"
-        )(x)
-        c_kv = norm("kv_norm")(kv_row[..., : self.kv_lora_rank])
-        kv = dense(
-            (self.num_heads, nope + self.v_head_dim),
-            (lr.LATENT, lr.HEADS, lr.KV), "kv_b",
-        )(c_kv)
-        with jax.named_scope("rope"):
-            q_pe, k_pe = layers.rotary_embedding(
-                q[..., nope:], kv_row[..., None, self.kv_lora_rank:],
-                positions, layers.rope_frequencies(rope // 2, self.rope_theta),
-            )
-            q = jnp.concatenate([q[..., :nope], q_pe], axis=-1)
-            k = jnp.concatenate([
-                kv[..., :nope],
-                jnp.broadcast_to(k_pe, (*kv.shape[:-1], rope)),
-            ], axis=-1)
-            v = kv[..., nope:]
-        # Names for a remat policy that would KEEP the per-head keys and
-        # values (``flash_only`` does not: the backward rebuilds them from
-        # the latent row, which lost nothing measurable on the chip and
-        # saves 2 x B x S x H x (192 + 128) bytes a layer; PERF.md §6).
-        k = jax.ad_checkpoint.checkpoint_name(k, "latent_k")
-        v = jax.ad_checkpoint.checkpoint_name(v, "latent_v")
-
+        q, k, v, _ = latent_qkv(self, x, positions)
         if self.attention_impl == "flash":
             out = _flash_local(
                 q, k, v, segment_ids, block_q=self.flash_block_q,
@@ -676,18 +639,105 @@ class LatentAttention(nn.Module):
                 scale=self.scale or None,
             )
             out = nn.with_logical_constraint(out, attn_spec)
-        if self.gate:
-            head_gate = dense(self.num_heads, (lr.EMBED, lr.HEADS), "gate")(x)
-            with jax.named_scope("gate"):
-                out = (
-                    out.astype(jnp.float32)
-                    * jax.nn.sigmoid(head_gate.astype(jnp.float32))[..., None]
-                ).astype(self.dtype)
-        return layers.DenseGeneral(
-            features, axis=(-2, -1),
-            kernel_axes=(lr.HEADS, lr.KV, lr.EMBED), use_bias=False,
-            dtype=self.dtype, param_dtype=self.param_dtype, name="wo",
-        )(out)
+        return latent_output(self, out, x)
+
+
+def _latent_dense(layer, width, axes, name, **kwargs):
+    return layers.DenseGeneral(
+        width, kernel_axes=axes, use_bias=False, dtype=layer.dtype,
+        param_dtype=layer.param_dtype, name=name, **kwargs,
+    )
+
+
+def latent_qkv(layer, x: jax.Array, positions: Optional[jax.Array]):
+    """``layer`` is a :class:`LatentAttention` inside its ``__call__`` (plain
+    functions, not methods: a method of a module is a named scope of its
+    own, and the projections' scopes are what they were).  The five
+    projections but ``wo`` and the rotation: ``(q, k, v)``
+    per head, and the normed q latent ``c_q`` (``None`` without one),
+    which a sparse layer's indexer reads too."""
+    nope, rope = layer.qk_nope_head_dim, layer.qk_rope_head_dim
+    if positions is None:
+        positions = jnp.arange(x.shape[1])[None, :]
+    dense = functools.partial(_latent_dense, layer)
+
+    def norm(name):
+        return layers.make_norm(
+            "rmsnorm", layer.dtype, layer.param_dtype, name,
+            epsilon=layer.norm_eps,
+        )
+
+    c_q = None
+    if layer.q_lora_rank:
+        c_q = norm("q_norm")(
+            dense(layer.q_lora_rank, (lr.EMBED, lr.LATENT), "q_a")(x)
+        )
+        q = dense(
+            (layer.num_heads, nope + rope), (lr.LATENT, lr.HEADS, lr.KV),
+            "q_b", **_head_init(layer, layer.init_score_std ** 2),
+        )(c_q)
+    else:
+        q = dense(
+            (layer.num_heads, nope + rope), (lr.EMBED, lr.HEADS, lr.KV),
+            "q_b",
+        )(x)
+    kv_row = dense(
+        layer.kv_lora_rank + rope, (lr.EMBED, lr.LATENT), "kv_a"
+    )(x)
+    c_kv = norm("kv_norm")(kv_row[..., : layer.kv_lora_rank])
+    kv = dense(
+        (layer.num_heads, nope + layer.v_head_dim),
+        (lr.LATENT, lr.HEADS, lr.KV), "kv_b", **_head_init(layer, 1.0),
+    )(c_kv)
+    with jax.named_scope("rope"):
+        q_pe, k_pe = layers.rotary_embedding(
+            q[..., nope:], kv_row[..., None, layer.kv_lora_rank:],
+            positions, layers.rope_frequencies(rope // 2, layer.rope_theta),
+        )
+        q = jnp.concatenate([q[..., :nope], q_pe], axis=-1)
+        k = jnp.concatenate([
+            kv[..., :nope],
+            jnp.broadcast_to(k_pe, (*kv.shape[:-1], rope)),
+        ], axis=-1)
+        v = kv[..., nope:]
+    # Names for a remat policy that would KEEP the per-head keys and
+    # values (``flash_only`` does not: the backward rebuilds them from
+    # the latent row, which lost nothing measurable on the chip and
+    # saves 2 x B x S x H x (192 + 128) bytes a layer; PERF.md §6).
+    k = jax.ad_checkpoint.checkpoint_name(k, "latent_k")
+    v = jax.ad_checkpoint.checkpoint_name(v, "latent_v")
+    return q, k, v, c_q
+
+
+def _head_init(layer, variance: float) -> Dict[str, Any]:
+    """The initialiser of a projection from a normed latent to the
+    heads where the scores' spread is seeded (``init_score_std``): a
+    head's entries at ``variance``, the heads not counted into the
+    fan-in, so that a scaled score spreads by ``init_score_std``."""
+    if not layer.init_score_std:
+        return {}
+    return {"kernel_init": nn.initializers.variance_scaling(
+        variance, "fan_in", "normal", in_axis=0, out_axis=(1, 2),
+    )}
+
+
+def latent_output(layer, out: jax.Array, x: jax.Array) -> jax.Array:
+    """The heads' outputs ``[B, S, H, v]`` under the gate, through
+    ``wo``."""
+    if layer.gate:
+        head_gate = _latent_dense(
+            layer, layer.num_heads, (lr.EMBED, lr.HEADS), "gate"
+        )(x)
+        with jax.named_scope("gate"):
+            out = (
+                out.astype(jnp.float32)
+                * jax.nn.sigmoid(head_gate.astype(jnp.float32))[..., None]
+            ).astype(layer.dtype)
+    return layers.DenseGeneral(
+        x.shape[-1], axis=(-2, -1),
+        kernel_axes=(lr.HEADS, lr.KV, lr.EMBED), use_bias=False,
+        dtype=layer.dtype, param_dtype=layer.param_dtype, name="wo",
+    )(out)
 
 
 def from_config(cfg, kind: str = FULL_ATTENTION, **kwargs):
@@ -716,6 +766,7 @@ def from_config(cfg, kind: str = FULL_ATTENTION, **kwargs):
             flash_block_kv=cfg.flash_block_kv,
             scale=cfg.attention_scale,
             gate=cfg.attention_gate,
+            init_score_std=cfg.attn_init_score_std,
             **kwargs,
         )
     return Attention(

@@ -68,7 +68,12 @@ from dlrover_tpu.models import layers
 from dlrover_tpu.models import linear_attention
 from dlrover_tpu.models import mamba2
 from dlrover_tpu.models import moe as moe_lib
-from dlrover_tpu.models.attention import FULL_ATTENTION, SLIDING_ATTENTION
+from dlrover_tpu.models.attention import (
+    FULL_ATTENTION,
+    INDEX_ATTENTION,
+    REUSE_ATTENTION,
+    SLIDING_ATTENTION,
+)
 from dlrover_tpu.models.family import Family
 from dlrover_tpu.models.moe import check_share, ungated
 from dlrover_tpu.ops import remat_policy as remat_policies
@@ -79,11 +84,16 @@ from dlrover_tpu.parallel import rules as lr
 LINEAR_ATTENTION = "linear_attention"
 CONV = "conv"
 # Layers of TWO residual branches: a mixer (softmax attention over the
-# causal triangle or over a window of it, a delta rule or a gated short
-# convolution), then an MLP (``Block``).
+# causal triangle, over a window of it or over a set of keys that the layer
+# chooses or is handed, a delta rule or a gated short convolution), then an
+# MLP (``Block``).
 TWO_BRANCH_KINDS = (
-    FULL_ATTENTION, LINEAR_ATTENTION, CONV, SLIDING_ATTENTION
+    FULL_ATTENTION, LINEAR_ATTENTION, CONV, SLIDING_ATTENTION,
+    INDEX_ATTENTION, REUSE_ATTENTION,
 )
+# The sparse attention layers (models/sparse_attention.py): the choice of
+# keys rides beside the residual stream through every layer of such a model.
+INDEX_KINDS = (INDEX_ATTENTION, REUSE_ATTENTION)
 # Layers that are ONE residual branch, ``x + f(Norm(x))`` (``BranchBlock``):
 # a state-space mixer, an attention, an expert layer, a dense MLP.
 SSM = "ssm"
@@ -189,6 +199,10 @@ class TransformerConfig:
     # train step adds ``mtp_weight`` x its cross-entropy to the loss.
     mtp_depth: int = 0
     mtp_weight: float = 0.3
+    # The kind of the MTP module's layer beside a ``layer_pattern`` (a
+    # two-branch kind; without a pattern every layer is full attention and
+    # so is the module's).
+    mtp_layer_kind: str = ""
     # RMSNorm over the whole q and k projections (all heads jointly, own
     # scale each) before the head split and RoPE (OLMoE, OLMo-2);
     # "per_head": each head's columns alone under ONE [head_dim] scale for
@@ -208,6 +222,19 @@ class TransformerConfig:
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
     attention_gate: str = ""
+    # Sparse attention (models/sparse_attention.py; DeepSeek-V3.2's DSA):
+    # an ``index_attention`` layer's indexer (``index_n_heads`` heads of
+    # ``index_head_dim`` over the q latent) picks ``index_topk`` keys a
+    # query for latent attention, a ``reuse_attention`` layer takes the
+    # nearest earlier choice; the choosing layers' KL terms join the loss
+    # and train the indexers alone (the terms share no parameter with the
+    # rest of the loss, so a weight would only scale the indexers' step);
+    # ``index_init_score_std`` seeds the indexer's scores' spread (0: the
+    # default initialiser).
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    index_init_score_std: float = 0.0
     # One period of layer kinds (``LAYER_KINDS``: "full_attention",
     # "linear_attention" and "conv" are a mixer AND an MLP; "ssm", "attention",
     # "experts" and "mlp" are that part alone on one residual branch),
@@ -416,6 +443,23 @@ class TransformerConfig:
             for i in range(self.first_k_dense)
         )
 
+    def _layers_of(self, kind: str) -> int:
+        """Layers of ``kind``: the trunk's, the dense prefix's and the MTP
+        module's."""
+        return self.num_layers_of(kind) + sum(
+            self.layer_kind(i) == kind for i in range(self.first_k_dense)
+        ) + self.mtp_depth * (self.mtp_layer_kind == kind)
+
+    @property
+    def num_index_layers(self) -> int:
+        """Layers that CHOOSE a query's keys (each holds an indexer)."""
+        return self._layers_of(INDEX_ATTENTION)
+
+    @property
+    def num_reuse_layers(self) -> int:
+        """Layers that attend over an earlier layer's choice."""
+        return self._layers_of(REUSE_ATTENTION)
+
     @property
     def num_full_layers(self) -> int:
         """Layers of ``full_attention`` (every layer without a pattern)."""
@@ -577,6 +621,10 @@ class TransformerConfig:
                     "(serving/decode.py, models/attention.cached_attention);"
                     " this model trains only"
                 )
+        if set(pattern) & set(INDEX_KINDS) or self.mtp_layer_kind in (
+            INDEX_KINDS
+        ):
+            self._check_index()
         if EXPERTS in pattern and not self.num_experts:
             raise ValueError(
                 "an 'experts' layer needs num_experts (and moe_d_ff, top_k)"
@@ -610,6 +658,42 @@ class TransformerConfig:
                     "(serving/decode.py, serving/engine.py); this model "
                     "trains only"
                 )
+
+    def _check_index(self):
+        """Sparse attention: latent attention's layers, an indexer's three
+        sizes, a first layer that chooses."""
+        if not (
+            self.latent_attention and self.q_lora_rank and self.index_n_heads
+            and self.index_head_dim and self.index_topk > 0
+        ):
+            raise ValueError(
+                "an index_attention / reuse_attention layer is latent "
+                "attention with a q latent (the indexer reads it) over "
+                "index_topk keys chosen by index_n_heads heads of "
+                f"index_head_dim, got q_lora_rank={self.q_lora_rank}, "
+                f"kv_lora_rank={self.kv_lora_rank}, {self.index_n_heads} x "
+                f"{self.index_head_dim}, index_topk={self.index_topk}"
+            )
+        if self.index_head_dim < self.qk_rope_head_dim:
+            raise ValueError(
+                f"the indexer rotates qk_rope_head_dim {self.qk_rope_head_dim}"
+                f" columns of its index_head_dim {self.index_head_dim}"
+            )
+        others = sorted(set(self.layer_pattern) - set(INDEX_KINDS))
+        if others or self.layer_kind(0) != INDEX_ATTENTION:
+            raise ValueError(
+                "a model with sparse attention layers has no other kind "
+                "(every layer carries the choice) and its first layer "
+                f"chooses: layer 0 is {self.layer_kind(0)!r}, other kinds "
+                f"{others}"
+            )
+        if self.pipeline_stages > 1:
+            raise ValueError(
+                "pipeline_stages > 1 with sparse attention layers: the "
+                "choice a layer hands on would have to cross a stage's "
+                "boundary beside the residual stream, and "
+                "parallel/pipeline.py's carry holds the stream alone"
+            )
 
     def _check_block(self):
         """The parallel block, the bias-free LayerNorm, full layers without
@@ -784,10 +868,19 @@ class TransformerConfig:
             raise ValueError(
                 f"mtp_depth must be 0 or 1 (one module), got {self.mtp_depth}"
             )
-        if self.mtp_depth and self.layer_pattern:
+        if self.mtp_layer_kind and (
+            self.mtp_layer_kind not in TWO_BRANCH_KINDS or not self.mtp_depth
+            or self.mtp_layer_kind in (LINEAR_ATTENTION, CONV)
+        ):
+            raise ValueError(
+                "mtp_layer_kind is the attention kind of the MTP module's "
+                f"layer (mtp_depth 1), got {self.mtp_layer_kind!r} with "
+                f"mtp_depth={self.mtp_depth}"
+            )
+        if self.mtp_depth and self.layer_pattern and not self.mtp_layer_kind:
             raise ValueError(
                 "mtp_depth with a layer_pattern: the module's layer has no "
-                "kind to take"
+                "kind to take; state it (mtp_layer_kind)"
             )
         latent = (
             self.kv_lora_rank, self.qk_nope_head_dim,
@@ -892,6 +985,13 @@ class TransformerConfig:
         linear, conv = self.num_linear_layers, self.num_conv_layers
         dense = self.first_k_dense
         mtp = self.mtp_depth * (attn + ff + 2 * d * d + 3 * d)
+        # an indexer in the layers that choose only: wq_b, wk, its key's
+        # LayerNorm, weights_proj
+        indexers = self.num_index_layers * (
+            self.q_lora_rank * self.index_n_heads * self.index_head_dim
+            + d * self.index_head_dim + 2 * self.index_head_dim
+            + d * self.index_n_heads
+        )
         # layers that are one branch: none of them is a mixer AND an MLP
         ssm, alone, experts, mlp = (
             self.num_layers_of(kind) for kind in BRANCH_KINDS
@@ -903,7 +1003,7 @@ class TransformerConfig:
             + conv * self._conv_mixer_params()
             + ssm * self._ssm_mixer_params()
             + (two - dense + experts) * ff + (dense + mlp) * dense_ff
-            + embed + head + mtp
+            + embed + head + mtp + indexers
         )
 
     def _ssm_mixer_params(self) -> int:
@@ -1017,7 +1117,11 @@ class Block(nn.Module):
     branch.  ``dense_mlp`` makes the MLP the dense one (``d_ff`` wide)
     though the model's trunk is sparse: a leading dense layer.  Under
     ``parallel_block`` both branches read ONE norm of the layer's input
-    (``ln``) and join the stream in one add: ``x + Mixer(n) + MLP(n)``."""
+    (``ln``) and join the stream in one add: ``x + Mixer(n) + MLP(n)``.
+    In a model with sparse attention layers the carry has a third entry,
+    the choice of keys (``sparse_attention.Index``), which an
+    ``index_attention`` layer replaces and a ``reuse_attention`` layer
+    reads."""
 
     config: TransformerConfig
     kind: str = FULL_ATTENTION
@@ -1031,7 +1135,7 @@ class Block(nn.Module):
         segment_ids: Optional[jax.Array] = None,
     ) -> Tuple[Tuple[jax.Array, jax.Array], None]:
         cfg = self.config
-        x, aux = carry
+        x, aux, *index = carry
         x = nn.with_logical_constraint(x, (lr.BATCH, lr.ACT_SEQ, lr.ACT_EMBED))
         post = cfg.norm_placement == "post"
 
@@ -1039,6 +1143,13 @@ class Block(nn.Module):
             return _norm(cfg, name)(y)
 
         def mixer(y):
+            if self.kind in INDEX_KINDS:
+                from dlrover_tpu.models import sparse_attention
+
+                y, index[0] = sparse_attention.from_config(
+                    cfg, self.kind, name="attn"
+                )(y, positions, segment_ids, index[0])
+                return y
             if self.kind == LINEAR_ATTENTION:
                 return linear_attention.from_config(cfg, name="linear_attn")(y)
             if self.kind == CONV:
@@ -1065,7 +1176,7 @@ class Block(nn.Module):
             x = nn.with_logical_constraint(
                 x, (lr.BATCH, lr.ACT_SEQ, lr.ACT_EMBED)
             )
-            return (x, aux), None
+            return (x, aux, *index), None
         y = mixer(x if post else norm("ln_attn", x))
         if post:
             y = norm("ln_attn", y)
@@ -1085,7 +1196,7 @@ class Block(nn.Module):
         y = jax.ad_checkpoint.checkpoint_name(y, "mlp_out")
         x = _add_branch(cfg, x, y)
         x = nn.with_logical_constraint(x, (lr.BATCH, lr.ACT_SEQ, lr.ACT_EMBED))
-        return (x, aux), None
+        return (x, aux, *index), None
 
 
 class BranchBlock(nn.Module):
@@ -1191,12 +1302,15 @@ class MTPModule(nn.Module):
 
     ``h`` is the trunk's output BEFORE its final norm, the embedding the
     model's own; the caller puts the model's shared head on the result,
-    which predicts token ``i + 2``.  Returns ``(hidden, aux)``."""
+    which predicts token ``i + 2``.  Returns ``(hidden, aux)``; in a model
+    with sparse attention layers the module's layer is handed the trunk's
+    last choice (``index``), and the choice it leaves (its own ``L^I``
+    added, where it chooses) is returned third."""
 
     config: TransformerConfig
 
     @nn.compact
-    def __call__(self, hidden, next_embed, positions, segment_ids):
+    def __call__(self, hidden, next_embed, positions, segment_ids, *index):
         cfg = self.config
 
         def norm(name, y):
@@ -1212,10 +1326,10 @@ class MTPModule(nn.Module):
         )(jnp.concatenate(
             [norm("hnorm", hidden), norm("enorm", next_embed)], axis=-1
         ))
-        (x, aux), _ = block_class(cfg, prevent_cse=True)(
-            cfg, FULL_ATTENTION, name="block"
-        )((x, jnp.zeros((), jnp.float32)), positions, segment_ids)
-        return norm("norm", x), aux
+        (x, aux, *index), _ = block_class(cfg, prevent_cse=True)(
+            cfg, cfg.mtp_layer_kind or FULL_ATTENTION, name="block"
+        )((x, jnp.zeros((), jnp.float32), *index), positions, segment_ids)
+        return (norm("norm", x), aux, *index)
 
 
 # The multi-token-prediction module's own cross-entropy (token i + 2 from
@@ -1231,11 +1345,44 @@ MTP_FAMILY = Family(
     kernel_facts=lambda cfg, seq_len: {},
 )
 
+
+
+def _of_sparse_attention(name: str):
+    """``models/sparse_attention.py``'s ``name``, imported when it is first
+    called: a model without such a layer imports none of it."""
+    def call(*args):
+        from dlrover_tpu.models import sparse_attention
+
+        return getattr(sparse_attention, name)(*args)
+    return call
+
+
+def _index_kernel_facts(cfg: TransformerConfig, seq_len: int):
+    if not cfg.num_index_layers:
+        return {
+            "sparse_attention": "none", "sparse_block": None,
+            "index_select": "none", "index_mask_bytes": None,
+        }
+    return _of_sparse_attention("kernel_facts")(cfg, seq_len)
+
+
+# The sparse attention layers' indexers (models/sparse_attention.py): per
+# choosing layer the pairs chosen and seen, the largest index score and the
+# layer's KL term; ``index_stats`` is that module's ``STATS_NAME``.
+INDEX_FAMILY = Family(
+    event="index",
+    stats={"index_stats": _of_sparse_attention("fold_stats")},
+    has=lambda cfg: cfg.num_index_layers,
+    read=_of_sparse_attention("read"),
+    kernel_facts=_index_kernel_facts,
+    absmax="score_absmax",
+)
+
 # Every family of layers that sows statistics or chooses kernels, in the
 # order the step folds their vectors and a report books their events.
 FAMILIES = (
     moe_lib.FAMILY, MTP_FAMILY, linear_attention.FAMILY, mamba2.FAMILY,
-    gated_conv.FAMILY, attention_lib.FAMILY,
+    gated_conv.FAMILY, attention_lib.FAMILY, INDEX_FAMILY,
 )
 
 
@@ -1334,14 +1481,20 @@ class TransformerLM(nn.Module):
         # parameters, stacked over the periods).
         unit_cls = Period if cfg.layer_pattern else block_cls
         aux0 = jnp.zeros((), jnp.float32)
+        # the choice of keys beside the stream, where layers choose
+        index = ()
+        if cfg.num_index_layers:
+            from dlrover_tpu.models import sparse_attention
+
+            index = (sparse_attention.empty_index(*tokens.shape[:2]),)
         if cfg.first_k_dense:
             prefix_cls = block_class(cfg, prevent_cse=True)
-            carry = (x, aux0)
+            carry = (x, aux0, *index)
             for i in range(cfg.first_k_dense):
                 carry, _ = prefix_cls(
                     cfg, cfg.layer_kind(i), True, name=f"dense_{i}"
                 )(carry, positions, segment_ids)
-            x, aux0 = carry
+            x, aux0, *index = carry
         if cfg.pipeline_stages > 1:
             from dlrover_tpu.parallel.pipeline import PipelinedBlocks
 
@@ -1361,28 +1514,33 @@ class TransformerLM(nn.Module):
                 length=cfg.num_scan_units,
                 metadata_params={nn.PARTITION_NAME: lr.LAYERS},
             )(cfg, name="blocks")
-            (x, aux), _ = stack((x, aux0), positions, segment_ids)
+            (x, aux, *index), _ = stack(
+                (x, aux0, *index), positions, segment_ids
+            )
         else:
-            carry = (x, aux0)
+            carry = (x, aux0, *index)
             for i in range(cfg.first_k_dense, cfg.num_layers):
                 kind = cfg.layer_kind(i)
                 carry, _ = block_class(cfg, prevent_cse=True, kind=kind)(
                     cfg, kind, name=f"block_{i}"
                 )(carry, positions, segment_ids)
-            x, aux = carry
+            x, aux, *index = carry
 
         mtp_hidden = None
         if cfg.mtp_depth and (
             next_tokens is not None or self.is_initializing()
         ):
             # the module's parameters are made at init whoever calls
-            mtp_hidden, mtp_aux = MTPModule(cfg, name="mtp")(
+            mtp_hidden, mtp_aux, *mtp_index = MTPModule(cfg, name="mtp")(
                 x, embedded(tokens if next_tokens is None else next_tokens),
-                positions, segment_ids,
+                positions, segment_ids, *index,
             )
-            aux = aux + mtp_aux
             if next_tokens is None:
                 mtp_hidden = None
+            else:
+                # the module trains (its expert layer's term, its
+                # indexer's) only where it is asked for
+                aux, index = aux + mtp_aux, mtp_index
 
         x = _norm(cfg, "ln_final", fused=False)(x)
         if return_hidden:
@@ -1395,8 +1553,8 @@ class TransformerLM(nn.Module):
                 if mtp_hidden is not None:
                     mtp_hidden = mtp_hidden * cfg.logit_scale
             if mtp_hidden is not None:
-                return x, aux * cfg.moe_aux_weight, mtp_hidden
-            return x, aux * cfg.moe_aux_weight
+                return x, self._aux_term(aux, index), mtp_hidden
+            return x, self._aux_term(aux, index)
         if cfg.tie_embeddings:
             head = embed.attend
         else:
@@ -1420,5 +1578,13 @@ class TransformerLM(nn.Module):
         if mtp_hidden is not None:
             with jax.named_scope("mtp/head"):
                 mtp_logits = logits_of(mtp_hidden)
-            return logits_of(x), aux * cfg.moe_aux_weight, mtp_logits
-        return logits_of(x), aux * cfg.moe_aux_weight
+            return logits_of(x), self._aux_term(aux, index), mtp_logits
+        return logits_of(x), self._aux_term(aux, index)
+
+    def _aux_term(self, aux, index):
+        """What joins the loss beside the cross-entropies: the expert
+        layers' balance term, weighed, and the indexers' KL terms."""
+        aux = aux * self.config.moe_aux_weight
+        if index:
+            aux = aux + index[0].kl
+        return aux

@@ -26,9 +26,20 @@ path only: the state each chunk starts from, which the backward kernel
 reads),
 ``ssd_out`` (models/mamba2.py: the state-space scan's output, likewise
 only where a model has such a layer),
+``index_choice`` / ``index_kl_grads`` (models/sparse_attention.py),
 ``latent_k`` / ``latent_v`` (models/attention.py ``LatentAttention``: the
 per-head keys and values rebuilt from the latent row; NO registered policy
 keeps them).
+
+What ``flash_only`` keeps of a sparse attention layer
+(``models/sparse_attention.py``): the sparse kernels' output and rows under
+the flash kernels' names, a choosing layer's choice (``index_choice``, the
+int8 mask ``[B, T, T]``: the backward neither scores nor selects a second
+time, and the layers that reuse the choice take it as their input) and the
+gradient of its KL term, which the term's forward has already computed
+(``index_kl_grads``: the indexer's ``q``, ``k`` and weights' cotangents,
+``[B, T, 32 x 128]`` and less; the backward scales them).  A model without
+such a layer emits neither name.
 
 What ``flash_only`` keeps of a linear layer differs by rule, and the chip
 chose each.  Both rules' backward kernels read the state each chunk starts
@@ -153,7 +164,7 @@ register(RematPolicy(
     "flash_only",
     saved_names=(
         "flash_out", "flash_lse", "delta_out", "kda_out", "kda_states",
-        "ssd_out",
+        "ssd_out", "index_choice", "index_kl_grads",
     ),
     hbm_act_per_token_layer=2.05, recompute_fraction=0.7,
 ))

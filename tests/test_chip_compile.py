@@ -81,6 +81,30 @@ def _flash(block, window=None):
     return jax.grad(loss, argnums=(0, 1, 2))
 
 
+def _sparse_flash(block):
+    """Attention over a choice (ops/sparse_flash_attention.py): the forward
+    and the split backward pair under the int8 mask."""
+    from dlrover_tpu.ops import sparse_flash_attention as sfa
+
+    def loss(q, k, v, mask):
+        out, _ = sfa.mha(
+            q, k, v, mask, scale=q.shape[-1] ** -0.5, block=block
+        )
+        return out.astype(F32).sum()
+
+    return jax.grad(loss, argnums=(0, 1, 2))
+
+
+def _index_choice(topk, rows):
+    """The indexer's scores and the choice by counting, a block of query
+    rows at a time (ops/index_select.py): plain XLA, no kernel."""
+    from dlrover_tpu.ops import index_select
+
+    return lambda q, k, w: index_select.choose_blocked(
+        q, k, w, None, topk, rows
+    )
+
+
 def _flash_band_split(block, window):
     """The banded forward and the SPLIT backward pair (a length past the
     one-pass backward's bound would take it; here it is asked for)."""
@@ -469,6 +493,24 @@ CASES = [
     ("grouped_matmul_command_a_wo_out_tiled",
      lambda: _grouped_matmul(True, True),
      [((11392, 4096), BF16), ((8, 4096, 4096), BF16), ((8,), I32)], {}, 3),
+    # GLM-5.2's cell: one sequence of 16,384 tokens, 16 heads of 256 / 256
+    # under the int8 choice (forward, dq, dk / dv); the choice itself, 32
+    # indexer heads of 128, 2,048 keys a query; the share's grouped GEMMs at
+    # 8 experts of 6,144 x 2,048 over a budget of 7,296 rows (1.5 x the
+    # expected 4,096 + a block an expert + the zero block)
+    ("sparse_flash_glm_16k", lambda: _sparse_flash(512),
+     [((1, 16384, 16, 256), BF16)] * 3 + [((1, 16384, 16384), jnp.int8)],
+     {}, 3),
+    ("index_choice_glm_16k", lambda: _index_choice(2048, 128),
+     [((1, 16384, 32, 128), BF16), ((1, 16384, 128), BF16),
+      ((1, 16384, 32), F32)], {}, 0),
+    ("grouped_matmul_glm_wi_rows_tiled",
+     lambda: _grouped_matmul(False, True),
+     [((7296, 48, 128), BF16), ((8, 6144, 2048), BF16), ((8,), I32)],
+     {}, 3),
+    ("grouped_matmul_glm_wo_out_tiled",
+     lambda: _grouped_matmul(True, True),
+     [((7296, 2048), BF16), ((8, 2048, 6144), BF16), ((8,), I32)], {}, 3),
     ("quantize_dequantize", _quant_roundtrip, [LEAF], {}, 2),
     ("q8_adam", lambda: _adam_update("q8_adam"), [LEAF, LEAF], {}, 1),
     ("q4_adam", lambda: _adam_update("q4_adam"), [LEAF, LEAF], {}, 1),

@@ -37,6 +37,10 @@ CASES = {
     "attn": ("mellum2-12b-a2.5b", lambda cfg: [
         np.array([12.5, 7.5], np.float32)
     ]),
+    # [chosen pairs, pairs seen, largest score, the layers' KL terms summed]
+    "index": ("glm-5.2", lambda cfg: [
+        np.array([1536.0, 6240.0, 9.5, 4.5], np.float32)
+    ]),
 }
 # The gauges ``render_metrics`` wrote out for each family at PR 55, by hand.
 GAUGES_BEFORE_THE_TABLE = {
@@ -69,6 +73,7 @@ GAUGES_BEFORE_THE_TABLE = {
         "dlrover_attn_full_score_bound", "dlrover_attn_sliding_score_bound",
         "dlrover_attn_reporters",
     },
+    "index": set(),
 }
 # ... and the ones a family's row has gained since, by the PR that added them.
 GAUGES_SINCE = {
@@ -79,6 +84,13 @@ GAUGES_SINCE = {
         "dlrover_moe_shared_expert_scale",
     },
     "attn": {"dlrover_attn_rotated_layers"},
+    # PR 62: the family itself
+    "index": {
+        "dlrover_index_layers", "dlrover_index_shared_layers",
+        "dlrover_index_topk", "dlrover_index_selected_share",
+        "dlrover_index_kl", "dlrover_index_score_absmax",
+        "dlrover_index_reporters",
+    },
 }
 
 

@@ -278,6 +278,30 @@ LATER_PRESETS_LOWERED = {
 }
 
 
+# PR 62 recorded GLM-5.2's (sparse attention over an indexer's choice: the
+# count of 32 bits, the masked ``jax.numpy`` form at the preset's 64 tokens,
+# the KL term's scan, the choice carried through the dense prefix, the
+# period and the MTP module) and no other: the ten texts above stand unedited
+# after ``LatentAttention``'s projections moved into two plain functions
+# (``models/attention.py`` ``latent_qkv`` / ``latent_output``), which is the
+# CPU's certificate that JoyAI's and Ling's chips run the programs they ran.
+SPARSE_PRESETS_LOWERED = {
+    "glm-5.2":
+        "5c816a90aace175b5cd365b668699bcd9414ba5a6129bef595100d632848d2b5",
+}
+
+
+@pytest.mark.parametrize("preset", sorted(SPARSE_PRESETS_LOWERED))
+def test_the_sparse_attention_preset_keeps_its_lowered_step_text(preset):
+    text = lowered_step_text(preset)
+    assert hashlib.sha256(text.encode()).hexdigest() == (
+        SPARSE_PRESETS_LOWERED[preset]
+    )
+    # the names only this family's programs hold
+    for name in ("index_stats", "select", "index_kl"):
+        assert name in step_lowered(preset).as_text(debug_info=True), name
+
+
 @pytest.mark.parametrize("preset", sorted(LATER_PRESETS_LOWERED))
 def test_the_multipliers_and_the_tiles_default_to_nothing(preset):
     """A config that names none of the four multipliers, and a scan whose

@@ -67,12 +67,14 @@ class StandIn:
 
 def main(argv=None, cell=CELL, stand_in=StandIn,
          faults=("no_window", "no_yarn"), out="mellum_control.json",
-         doc=__doc__) -> int:
-    """``cell``, ``stand_in``, the default ``faults`` and ``out``: a sibling
-    configuration's script (``tools/command_a_control.py``) hands its own."""
+         doc=__doc__, lowered=LOWERED, readings=None) -> int:
+    """``cell``, ``stand_in``, the default ``faults``, ``lowered`` modes and
+    ``out``: a sibling configuration's script (``tools/command_a_control.py``)
+    hands its own; ``readings(worker, reference, lowered modes)``: what
+    more a sibling reads of a seed's weights, added to the seed's line."""
     ap = argparse.ArgumentParser(description=doc.splitlines()[0])
     ap.add_argument("--seeds", type=int, nargs="+", required=True)
-    ap.add_argument("--lowered", nargs="*", default=list(LOWERED))
+    ap.add_argument("--lowered", nargs="*", default=list(lowered))
     ap.add_argument("--faults", nargs="*", default=list(faults))
     ap.add_argument("--float32", action="store_true")
     ap.add_argument("--out", default=out)
@@ -156,6 +158,12 @@ def main(argv=None, cell=CELL, stand_in=StandIn,
             name: {k: check[k] for k in keep}
             for name, check in checks.items()
         })
+        if readings is not None:
+            # a seed's checks are not lost to what is read beside them
+            try:
+                line["readings"] = readings(worker, reference, args.lowered)
+            except Exception as e:  # noqa: BLE001
+                line["readings_error"] = repr(e)[:400]
         print(json.dumps(line), flush=True)
         lines.append(line)
     out = os.path.join(root, "chiprun_out")

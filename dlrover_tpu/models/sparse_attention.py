@@ -28,7 +28,10 @@ mask ``[B, T, T]`` (1: chosen) and the sum of the ``L^I`` so far.  Scopes
 under a layer's ``attn/``: ``indexer/`` (three projections, norm, rotation),
 ``select/`` (scores and choice, ``ops/index_select.py``), ``sparse/`` (the
 attention over the choice: ``ops/sparse_flash_attention.py``'s kernels
-under ``attention_impl="flash"`` where the sequence is whole blocks, the
+under ``attention_impl="flash"`` where the sequence is whole blocks, a
+forward call and, by the shapes, ONE backward call where dq over the
+sequence fits in VMEM or the split pair where it does not
+(``sparse_flash_attention.backward_path``; ``kernel_facts`` says which); the
 masked ``jax.numpy`` form otherwise), ``index_kl/`` (the term AND its
 gradient: both are computed in the forward, row block by row block, and
 the backward only scales them, so nothing ``[T, T]`` is kept).
@@ -395,15 +398,27 @@ def read(cfg, vec) -> Dict[str, Any]:
 def kernel_facts(cfg, seq_len: int) -> Dict[str, Any]:
     """``sparse_attention``: the form attention over a choice runs in
     (``masked_kernel``: the Pallas kernels over the causal triangle under
-    the mask; ``masked_xla``), its block, ``index_select`` (how the choice
-    is found) and ``index_mask_bytes`` (the choice a layer hands on, for a
-    sequence), for a model with such layers (``models/transformer.py``
-    says ``none`` for the others without importing this module)."""
+    the mask; ``masked_xla``), its block, ``sparse_backward`` (the kernels'
+    backward at these shapes, ``one_pass`` or ``split``: what their own
+    ``backward_path`` answers; ``None`` without kernels), ``index_select``
+    (how the choice is found) and ``index_mask_bytes`` (the choice a layer
+    hands on, for a sequence), for a model with such layers
+    (``models/transformer.py`` says ``none`` for the others without
+    importing this module)."""
     path, block = sparse_path(cfg.attention_impl, seq_len)
+    backward = None
+    if path == "kernel":
+        from dlrover_tpu.ops import sparse_flash_attention as sfa
+
+        backward = sfa.backward_path(
+            seq_len, cfg.qk_nope_head_dim + cfg.qk_rope_head_dim,
+            cfg.v_head_dim, block, cfg.dtype,
+        )
     return {
         "sparse_attention": "masked_kernel" if path == "kernel"
         else "masked_xla",
         "sparse_block": block or None,
+        "sparse_backward": backward,
         "index_select": f"count32_rows{index_select.row_block(seq_len, BLOCK_ROWS)}",
         "index_mask_bytes": seq_len * seq_len,
     }

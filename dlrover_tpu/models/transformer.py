@@ -1361,7 +1361,8 @@ def _index_kernel_facts(cfg: TransformerConfig, seq_len: int):
     if not cfg.num_index_layers:
         return {
             "sparse_attention": "none", "sparse_block": None,
-            "index_select": "none", "index_mask_bytes": None,
+            "sparse_backward": None, "index_select": "none",
+            "index_mask_bytes": None,
         }
     return _of_sparse_attention("kernel_facts")(cfg, seq_len)
 

@@ -21,10 +21,19 @@ probabilities are zeroed by the mask, not by the fill.
 
 Forward: grid ``(batch, head, q block, kv block)``, kv innermost, the
 running ``(m, l, acc)`` in VMEM scratch; it writes the output and the rows'
-log-sum-exp, which the backward and the indexer's KL term read.  Backward:
-the split pair (dq with kv inner, then dk / dv with q inner), each
-recomputing a block's probabilities from the log-sum-exp.  Keys may be
-wider than values (latent attention's 256 and 256, or 192 and 128).
+log-sum-exp, which the backward and the indexer's KL term read.
+
+Backward, by the shapes alone (:func:`backward_path`): ONE pass
+(``_bwd_one_pass_kernel``: kv outer, q inner; a live block's probabilities
+and score gradients recomputed once from the log-sum-exp feed dq, dk AND dv,
+five matmuls a block) wherever dq over the whole sequence of a (batch, head)
+fits in VMEM beside the blocks (:func:`_one_pass_vmem_bytes` within
+``_VMEM_LIMIT``: 16,384 tokens at 256-wide keys do, 41.5 MiB), and with one
+kv block (nothing to hold); the split pair otherwise (dq with kv inner, then
+dk / dv with q inner, each recomputing the block: nine matmuls; 32,768
+tokens at 256-wide keys).  Both sum the same terms in the same precision and
+order.  Keys may be wider than values (latent attention's 256 and 256, or
+192 and 128).
 """
 
 from __future__ import annotations
@@ -107,9 +116,11 @@ def _fwd_kernel(mask_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         lse_ref[0, 0] = jnp.broadcast_to(lse, (lse.shape[0], _STAT))
 
 
-def _params():
+def _params(outer: str = "parallel"):
+    """``outer``: the third grid axis; ``arbitrary`` where a scratch is
+    carried across its steps (the one pass's dq)."""
     return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+        dimension_semantics=("parallel", "parallel", outer, "arbitrary"),
         vmem_limit_bytes=_VMEM_LIMIT,
     )
 
@@ -177,6 +188,18 @@ def _p_ds(mask_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref, scale):
     return p, ds, q, k, do
 
 
+def _add_dk_dv(dk_acc_ref, dv_acc_ref, p, ds, q, do):
+    """A block's terms of dk and dv, as both q-inner kernels add them."""
+    dv_acc_ref[:] += jax.lax.dot_general(
+        p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    dk_acc_ref[:] += jax.lax.dot_general(
+        ds, q, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+
+
 def _bwd_dq_kernel(mask_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref,
                    dq_ref, dq_acc_ref, *, scale: float):
     iq, ik = pl.program_id(2), pl.program_id(3)
@@ -213,19 +236,158 @@ def _bwd_dkv_kernel(mask_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref,
         p, ds, q, _, do = _p_ds(
             mask_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref, scale
         )
-        dv_acc_ref[:] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dk_acc_ref[:] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        _add_dk_dv(dk_acc_ref, dv_acc_ref, p, ds, q, do)
 
     @pl.when(iq == pl.num_programs(3) - 1)
     def _finalize():
         dk_ref[0, 0] = dk_acc_ref[:].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_acc_ref[:].astype(dv_ref.dtype)
+
+
+def _bwd_one_pass_kernel(mask_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                         o_ref, dq_ref, dk_ref, dv_ref, dk_acc_ref,
+                         dv_acc_ref, *dq_acc, scale: float):
+    """dq, dk and dv from ONE walk of the triangle: kv outer, q inner, dk /
+    dv as ``_bwd_dkv_kernel`` has them.  A dq block takes one term from each
+    kv block up to its own, and with kv outer its visits are not consecutive:
+    an output block may not be revisited that way, a scratch may.  So with
+    several kv blocks ``dq_ref`` is the whole sequence of one (batch, head),
+    resident until the head changes, and ``dq_acc`` one float32 scratch of
+    that extent: a q block's rows are assigned at kv block 0, added to in
+    ascending kv order (``_bwd_dq_kernel``'s order and precision) and cast
+    into ``dq_ref`` at the diagonal, the rows' last block.  With ONE kv block
+    (``dq_acc`` empty) the only dq block is written straight out."""
+    ik, iq = pl.program_id(2), pl.program_id(3)  # kv outer, q inner
+
+    @pl.when(iq == 0)
+    def _init():
+        dk_acc_ref[:] = jnp.zeros_like(dk_acc_ref)
+        dv_acc_ref[:] = jnp.zeros_like(dv_acc_ref)
+
+    @pl.when(iq >= ik)
+    def _live():
+        p, ds, q, k, do = _p_ds(
+            mask_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref, scale
+        )
+        _add_dk_dv(dk_acc_ref, dv_acc_ref, p, ds, q, do)
+        dq = jax.lax.dot(ds, k, preferred_element_type=jnp.float32)
+        if not dq_acc:
+            dq_ref[0, 0] = dq.astype(dq_ref.dtype)
+            return
+        (dq_acc_ref,) = dq_acc
+        block = dq.shape[0]
+        rows = pl.ds(pl.multiple_of(iq * block, block), block)
+
+        @pl.when(ik == 0)
+        def _first():
+            dq_acc_ref[rows, :] = dq
+
+        @pl.when(ik > 0)
+        def _later():
+            dq_acc_ref[rows, :] += dq
+
+        @pl.when(ik == iq)
+        def _write():
+            dq_ref[0, 0, rows, :] = dq_acc_ref[rows, :].astype(dq_ref.dtype)
+
+    @pl.when(iq == pl.num_programs(3) - 1)
+    def _finalize():
+        dk_ref[0, 0] = dk_acc_ref[:].astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv_acc_ref[:].astype(dv_ref.dtype)
+
+
+def _one_pass_vmem_bytes(seq_len, d, d_v, block, dtype) -> int:
+    """VMEM the one pass needs at several kv blocks, from its shapes alone
+    as ``flash_attention._fused_bwd_vmem_bytes`` counts it (a last dimension
+    occupies whole 128-lane tiles): the pipeline's two buffers of every block
+    in and out, the mask's among them, the log-sum-exp block at 128 lanes,
+    the dk / dv accumulators, four float32 ``[block, block]`` temporaries,
+    and dq over the WHOLE sequence: the float32 scratch and two buffers of
+    the output.  At 16,384 x 256 / 256 in bfloat16 and blocks of 512: 4.5 +
+    1 + 4 + 32 = 41.5 MiB."""
+    item = jnp.dtype(dtype).itemsize
+    d, d_v = (-(-width // _LANE) * _LANE for width in (d, d_v))
+    q_side = block * (d + 2 * d_v)          # q, do, o
+    kv_side = block * (d + d_v)             # k, v in; dk, dv out
+    blocks = (
+        2 * item * (q_side + 2 * kv_side) + 2 * 4 * block * _LANE
+        + 2 * block * block
+    )
+    temporaries = 4 * 4 * block * block
+    dq = seq_len * d * (4 + 2 * item)
+    return blocks + 4 * kv_side + temporaries + dq
+
+
+def backward_path(seq_len, d, d_v, block, dtype) -> str:
+    """``"one_pass"`` or ``"split"``: the backward :func:`mha` runs at these
+    sizes.  One kv block holds no dq across blocks and always takes the one
+    pass; several do while :func:`_one_pass_vmem_bytes` is within
+    ``_VMEM_LIMIT``."""
+    if seq_len == block:
+        return "one_pass"
+    need = _one_pass_vmem_bytes(seq_len, d, d_v, block, dtype)
+    return "one_pass" if need <= _VMEM_LIMIT else "split"
+
+
+def _kv_outer_specs(block, d, d_v):
+    """Block specs of a grid ``(batch, head, kv block, q block)``, q inner:
+    the operands' ``(mask, q, k, v, do, lse, o)`` and the outputs' ``(dk,
+    dv)``.  A dead step (a q block before the kv block) parks the q side."""
+    def parked_q_rows(ib, ih, ik, iq):
+        return (ib, ih, jnp.maximum(iq, ik), 0)
+
+    def kv_rows(ib, ih, ik, iq):
+        return (ib, ih, ik, 0)
+
+    in_specs = [
+        pl.BlockSpec(
+            (1, block, block),
+            lambda ib, ih, ik, iq: (ib, jnp.maximum(iq, ik), ik),
+        ),
+        pl.BlockSpec((1, 1, block, d), parked_q_rows),
+        pl.BlockSpec((1, 1, block, d), kv_rows),
+        pl.BlockSpec((1, 1, block, d_v), kv_rows),
+        pl.BlockSpec((1, 1, block, d_v), parked_q_rows),
+        pl.BlockSpec((1, 1, block, _STAT), parked_q_rows),
+        pl.BlockSpec((1, 1, block, d_v), parked_q_rows),
+    ]
+    out_specs = [
+        pl.BlockSpec((1, 1, block, d), kv_rows),
+        pl.BlockSpec((1, 1, block, d_v), kv_rows),
+    ]
+    return in_specs, out_specs
+
+
+def _flash_bwd_one_pass(q, k, v, mask, o, lse, do, *, scale, block):
+    b, h, t, d = q.shape
+    d_v = v.shape[3]
+    n = t // block
+    lse_l = jnp.broadcast_to(lse[..., None], (*lse.shape, _STAT))
+    in_specs, dkv_specs = _kv_outer_specs(block, d, d_v)
+    dq_spec = in_specs[1]       # one kv block: the q block's own
+    scratch = [
+        pltpu.VMEM((block, d), jnp.float32),
+        pltpu.VMEM((block, d_v), jnp.float32),
+    ]
+    if n > 1:
+        dq_spec = pl.BlockSpec(
+            (1, 1, t, d), lambda ib, ih, ik, iq: (ib, ih, 0, 0)
+        )
+        scratch.append(pltpu.VMEM((t, d), jnp.float32))
+    return pl.pallas_call(
+        functools.partial(_bwd_one_pass_kernel, scale=scale),
+        grid=(b, h, n, n),
+        in_specs=in_specs,
+        out_specs=[dq_spec, *dkv_specs],
+        scratch_shapes=scratch,
+        out_shape=[
+            jax.ShapeDtypeStruct((b, h, t, d), q.dtype),
+            jax.ShapeDtypeStruct((b, h, t, d), k.dtype),
+            jax.ShapeDtypeStruct((b, h, t, d_v), v.dtype),
+        ],
+        compiler_params=_params(outer="arbitrary"),
+        interpret=backend.interpret(),
+    )(mask, q, k, v, do, lse_l, o)
 
 
 def _flash_bwd(q, k, v, mask, o, lse, do, *, scale, block):
@@ -263,31 +425,12 @@ def _flash_bwd(q, k, v, mask, o, lse, do, *, scale, block):
         interpret=backend.interpret(),
     )(*operands)
 
-    def parked_q_rows(ib, ih, ik, iq):
-        return (ib, ih, jnp.maximum(iq, ik), 0)
-
-    def kv_rows(ib, ih, ik, iq):
-        return (ib, ih, ik, 0)
-
+    in_specs, out_specs = _kv_outer_specs(block, d, d_v)
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale),
         grid=(b, h, n, n),
-        in_specs=[
-            pl.BlockSpec(
-                (1, block, block),
-                lambda ib, ih, ik, iq: (ib, jnp.maximum(iq, ik), ik),
-            ),
-            pl.BlockSpec((1, 1, block, d), parked_q_rows),
-            pl.BlockSpec((1, 1, block, d), kv_rows),
-            pl.BlockSpec((1, 1, block, d_v), kv_rows),
-            pl.BlockSpec((1, 1, block, d_v), parked_q_rows),
-            pl.BlockSpec((1, 1, block, _STAT), parked_q_rows),
-            pl.BlockSpec((1, 1, block, d_v), parked_q_rows),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, block, d), kv_rows),
-            pl.BlockSpec((1, 1, block, d_v), kv_rows),
-        ],
+        in_specs=in_specs,
+        out_specs=out_specs,
         scratch_shapes=[
             pltpu.VMEM((block, d), jnp.float32),
             pltpu.VMEM((block, d_v), jnp.float32),
@@ -319,9 +462,9 @@ def _core_fwd(q, k, v, mask, scale, block):
 def _core_bwd(scale, block, residuals, cotangents):
     q, k, v, mask, o, lse = residuals
     do, _ = cotangents      # the rows' log-sum-exp feeds detached terms only
-    dq, dk, dv = _flash_bwd(
-        q, k, v, mask, o, lse, do, scale=scale, block=block
-    )
+    path = backward_path(q.shape[2], q.shape[3], v.shape[3], block, q.dtype)
+    impl = _flash_bwd_one_pass if path == "one_pass" else _flash_bwd
+    dq, dk, dv = impl(q, k, v, mask, o, lse, do, scale=scale, block=block)
     return dq, dk, dv, None
 
 

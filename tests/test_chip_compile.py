@@ -83,7 +83,9 @@ def _flash(block, window=None):
 
 def _sparse_flash(block):
     """Attention over a choice (ops/sparse_flash_attention.py): the forward
-    and the split backward pair under the int8 mask."""
+    and the backward its shapes take under the int8 mask (``backward_path``:
+    one pass where dq over the sequence fits in VMEM, the split pair past
+    it)."""
     from dlrover_tpu.ops import sparse_flash_attention as sfa
 
     def loss(q, k, v, mask):
@@ -494,12 +496,17 @@ CASES = [
      lambda: _grouped_matmul(True, True),
      [((11392, 4096), BF16), ((8, 4096, 4096), BF16), ((8,), I32)], {}, 3),
     # GLM-5.2's cell: one sequence of 16,384 tokens, 16 heads of 256 / 256
-    # under the int8 choice (forward, dq, dk / dv); the choice itself, 32
+    # under the int8 choice (forward and the ONE-PASS backward: 41.5 MiB of
+    # VMEM by its own count, dq over the sequence resident; twice the tokens
+    # keep the split pair: forward, dq, dk / dv); the choice itself, 32
     # indexer heads of 128, 2,048 keys a query; the share's grouped GEMMs at
     # 8 experts of 6,144 x 2,048 over a budget of 7,296 rows (1.5 x the
     # expected 4,096 + a block an expert + the zero block)
     ("sparse_flash_glm_16k", lambda: _sparse_flash(512),
      [((1, 16384, 16, 256), BF16)] * 3 + [((1, 16384, 16384), jnp.int8)],
+     {}, 2),
+    ("sparse_flash_glm_32k_split", lambda: _sparse_flash(512),
+     [((1, 32768, 2, 256), BF16)] * 3 + [((1, 32768, 32768), jnp.int8)],
      {}, 3),
     ("index_choice_glm_16k", lambda: _index_choice(2048, 128),
      [((1, 16384, 32, 128), BF16), ((1, 16384, 128), BF16),

@@ -83,6 +83,7 @@ def test_the_defaults_leave_every_other_model_as_it_was():
     assert "index" not in [f.event for f in families(joyai)]
     facts = kernel_facts(joyai, 8192)
     assert facts["sparse_attention"] == "none"
+    assert facts["sparse_backward"] is None
     assert facts["index_select"] == "none"
     assert "index" in [f.event for f in families(config())]
     # an MTP module beside a pattern is legal once its kind is stated
@@ -218,6 +219,9 @@ def test_to_program_maps_to_fields_that_exist():
     facts = kernel_facts(cfg, 16384)
     assert facts["sparse_attention"] == "masked_kernel"
     assert facts["sparse_block"] == 512
+    # dq over the 16,384 tokens fits in VMEM: one backward call, not two
+    assert facts["sparse_backward"] == "one_pass"
+    assert kernel_facts(cfg, 32768)["sparse_backward"] == "split"
     assert facts["index_select"] == "count32_rows128"
     assert facts["index_mask_bytes"] == 16384 * 16384
     # the grouped GEMMs at K = 6,144 keep the whole-K strip resident

@@ -452,6 +452,105 @@ def test_the_sparse_kernels_are_the_masked_softmax(d, d_v):
     assert sfa.block_size(640, 512) == 128 and sfa.block_size(96, 512) == 0
 
 
+def _late_mask(t, keys):
+    """Each row's last ``keys`` keys: a row past the first block has no
+    chosen key in the first blocks of its walk."""
+    rows, cols = np.arange(t)[:, None], np.arange(t)[None, :]
+    seen = (cols <= rows) & (cols > rows - keys)
+    return jnp.broadcast_to(jnp.asarray(seen, jnp.int8), (BATCH, t, t))
+
+
+@pytest.mark.parametrize("d,d_v,t,choice", [
+    (64, 64, 384, "indexer"), (48, 32, 384, "indexer"),
+    (64, 64, 384, "late"), (48, 32, 384, "late"),
+    (64, 64, 128, "indexer"),       # one kv block: no dq scratch
+])
+def test_both_backward_paths_give_the_masked_softmax_gradients(
+    d, d_v, t, choice
+):
+    """The one pass and the split pair on the same ``q, k, v, mask, do``
+    (three kv blocks of 128, or one): dq, dk and dv of the one pass are the
+    pair's, term for term, and both are the ``jax.numpy`` form's."""
+    heads, topk, block = 2, 40, 128
+    keys = jax.random.split(jax.random.PRNGKey(21), 7)
+    q = jax.random.normal(keys[0], (BATCH, t, heads, d))
+    k = jax.random.normal(keys[1], (BATCH, t, heads, d))
+    v = jax.random.normal(keys[2], (BATCH, t, heads, d_v))
+    do = jax.random.normal(keys[3], (BATCH, t, heads, d_v))
+    if choice == "late":
+        mask = _late_mask(t, topk)
+    else:
+        mask, _ = index_select.choose_blocked(
+            jax.random.normal(keys[4], (BATCH, t, 3, 8)),
+            jax.random.normal(keys[5], (BATCH, t, 8)),
+            jax.random.normal(keys[6], (BATCH, t, 3)), None, topk, 64,
+        )
+    scale = d ** -0.5
+    by_head = [x.transpose(0, 2, 1, 3) for x in (q, k, v, do)]
+    with jax.default_matmul_precision("highest"):
+        o, lse = sfa._flash_fwd(
+            *by_head[:3], mask, scale=scale, block=block
+        )
+        one_pass, split = (
+            impl(
+                *by_head[:3], mask, o, lse, by_head[3], scale=scale,
+                block=block,
+            )
+            for impl in (sfa._flash_bwd_one_pass, sfa._flash_bwd)
+        )
+        (_, lse_w), vjp = jax.vjp(
+            functools.partial(
+                sparse_attention.masked_attention, mask=mask, scale=scale
+            ),
+            q, k, v,
+        )
+        want = vjp((do, jnp.zeros_like(lse_w)))
+    for one, pair, w in zip(one_pass, split, want):
+        np.testing.assert_array_equal(one, pair)
+        np.testing.assert_allclose(
+            one.transpose(0, 2, 1, 3), w, atol=2e-5
+        )
+    # which of the two ``mha`` runs is the shapes' alone, and a step's
+    # program holds the forward's call and that path's
+    assert sfa.backward_path(t, d, d_v, block, q.dtype) == "one_pass"
+    program = str(jax.make_jaxpr(jax.grad(
+        lambda q: sfa.mha(q, k, v, mask, scale=scale, block=block)[0].sum()
+    ))(q))
+    assert program.count("pallas_call") == 2
+
+
+def test_the_sparse_backward_path_on_either_side_of_its_bound(monkeypatch):
+    """The bound is the VMEM the module asks for, 64 MiB: at 256-wide keys
+    and values in bfloat16 and blocks of 512 the cell's 16,384 tokens take
+    the one pass (41.5 MiB), twice that keep the pair (73.5)."""
+    def path(seq, d=256, d_v=256, block=512, dtype=jnp.bfloat16):
+        return sfa.backward_path(seq, d, d_v, block, dtype)
+
+    assert path(16384) == "one_pass" and path(32768) == "split"
+    assert sfa._one_pass_vmem_bytes(
+        16384, 256, 256, 512, jnp.bfloat16
+    ) == 83 << 19 <= sfa._VMEM_LIMIT
+    # the longest that fits: dq takes 2 KiB a token beside 9.5 MiB
+    assert path(27648) == "one_pass" and path(28160) == "split"
+    # narrower keys and values (192 / 128 occupy 256 / 128 lanes) go further
+    assert path(32768, 192, 128) == "split" != path(24576, 192, 128)
+    # float32 blocks and a float32 dq output: 3 KiB a token beside 13 MiB
+    assert path(16384, dtype=jnp.float32) == "one_pass"
+    assert path(20480, dtype=jnp.float32) == "split"
+    # one kv block keeps no dq across blocks: the one pass at any length
+    assert path(1 << 20, block=1 << 20) == "one_pass"
+    # past the bound ``mha`` runs the pair: three calls in the program
+    monkeypatch.setattr(sfa, "_VMEM_LIMIT", 1 << 16)
+    x = jnp.zeros((1, 256, 1, 32))
+    program = str(jax.make_jaxpr(jax.grad(
+        lambda q: sfa.mha(
+            q, x, x, jnp.ones((1, 256, 256), jnp.int8), scale=1.0, block=128
+        )[0].sum()
+    ))(x))
+    assert sfa.backward_path(256, 32, 32, 128, x.dtype) == "split"
+    assert program.count("pallas_call") == 3
+
+
 # -- the shares add up ----------------------------------------------------------
 
 

@@ -132,6 +132,7 @@ def test_fit_books_the_index_event_from_the_step_itself(fitted):
     (compiled,) = [e[-1] for e in taken if e[0] == "compile"]
     assert compiled["sparse_attention"] == "masked_kernel"
     assert compiled["sparse_block"] == 128
+    assert compiled["sparse_backward"] == "one_pass"
     assert compiled["index_select"] == "count32_rows128"
     assert compiled["index_mask_bytes"] == SEQ * SEQ
     events = [e[4] for e in taken if e[1] == "event" and e[0] == "index"]

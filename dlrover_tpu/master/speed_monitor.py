@@ -102,11 +102,17 @@ HEALTH_KINDS: Dict[str, Dict[str, Any]] = {
     },
     # The delta-rule layers (mean decay, mean write strength, the
     # recurrent state's largest entry; ``min_alpha`` is a per-channel
-    # rule's smallest mean decay of a channel, 1 where the event has none).
+    # rule's smallest mean decay of a channel, 1 where the event has none;
+    # ``g_min`` and ``past_bound_share`` are a gate's without a lower bound:
+    # the most negative log decay of a token and channel, and the share of
+    # them under the split form's floor, 0 where the event has neither).
     "linear_attn": {
         "mean": {"mean_alpha": 0.0, "mean_beta": 0.0},
-        "max": {"layers": 0.0, "chunk": 0.0, "state_absmax": 0.0},
-        "min": {"min_alpha": 1.0},
+        "max": {
+            "layers": 0.0, "chunk": 0.0, "state_absmax": 0.0,
+            "past_bound_share": 0.0,
+        },
+        "min": {"min_alpha": 1.0, "g_min": 0.0},
         "gauges": (
             ("layers", "dlrover_linear_attn_layers",
              "gated-delta-rule layers of the reported model"),
@@ -124,6 +130,14 @@ HEALTH_KINDS: Dict[str, Dict[str, Any]] = {
             ("min_alpha", "dlrover_linear_attn_min_alpha",
              "smallest mean decay of one channel of a per-channel "
              "rule (min of reporters; 1 where no layer has one)"),
+            ("g_min", "dlrover_linear_attn_g_min",
+             "most negative log decay of one token and channel under a "
+             "gate without a lower bound (min of reporters; 0 where no "
+             "layer has one)"),
+            ("past_bound_share", "dlrover_linear_attn_past_bound_share",
+             "share of (token, head, channel) decays below -88 / 16, "
+             "where the rule's split form would overflow (max of "
+             "reporters)"),
             ("reporters", "dlrover_linear_attn_reporters",
              "trainers that have reported linear-attention snapshots"),
         ),

@@ -308,6 +308,11 @@ class Attention(nn.Module):
     # The spread the scaled scores are seeded with: query and key kernels
     # at ``sqrt(init_score_std / features)``; 0: the default initialiser.
     init_score_std: float = 0.0
+    # The output gate (Gated Attention, arXiv:2505.06708): the heads'
+    # outputs times ``sigmoid(n W_gate)`` before the output projection,
+    # ``W_gate`` ``[d, H]`` ("head_wise": one gate a head) or ``[d, H, hd]``
+    # ("elementwise": one a channel); param and scope ``attn/gate``.
+    gate: str = ""
 
     @nn.compact
     def __call__(
@@ -532,6 +537,21 @@ class Attention(nn.Module):
             raise ValueError(
                 f"unknown attention_impl {self.attention_impl!r}"
             )
+        if self.gate:
+            by_head = self.gate == "head_wise"
+            gate = layers.DenseGeneral(
+                self.num_heads if by_head
+                else (self.num_heads, self.head_dim),
+                kernel_axes=(lr.EMBED, lr.HEADS) + (() if by_head else (lr.KV,)),
+                use_bias=False, dtype=self.dtype,
+                param_dtype=self.param_dtype, name="gate",
+            )(x)
+            with jax.named_scope("gate"):
+                gate = jax.nn.sigmoid(gate.astype(jnp.float32))
+                out = (
+                    out.astype(jnp.float32)
+                    * (gate[..., None] if by_head else gate)
+                ).astype(self.dtype)
         out = layers.DenseGeneral(
             features,
             axis=(-2, -1),
@@ -787,6 +807,7 @@ def from_config(cfg, kind: str = FULL_ATTENTION, **kwargs):
         decode=cfg.decode,
         cache_len=cfg.max_seq_len,
         init_score_std=cfg.attn_init_score_std,
+        gate=cfg.attention_gate,
         **_by_kind(cfg, kind),
         **kwargs,
     )

@@ -33,22 +33,40 @@ largest |S| entry at a chunk boundary]`` (:func:`split_stats`) into
 ``"intermediates"``: a no-op unless the caller applies with that
 collection mutable, as the train step does.
 
-:class:`KimiDeltaAttention` is Kimi Linear's mixer (arXiv:2510.26692) as
-Ling-3.0-flash runs it: the same convolution and norms, then::
+:class:`KimiDeltaAttention` is Kimi Linear's mixer (arXiv:2510.26692): the
+same convolution and norms, then::
 
-    beta = sigmoid(W_b n)                                 (never doubled)
-    g    = bound * sigmoid(exp(A_log_h) * (W_f n + dt_bias))   [H, dk]
-           (the safe gate: ``bound`` = ``kda_lower_bound`` -5 < g < 0;
-           W_f full rank [d, H, dk], float32 accumulation)
+    beta = sigmoid(W_b n)     (x 2 with ``allow_neg_eigval``: 0 < beta < 2)
+    g    = bound * sigmoid(exp(A_log_h) * (f + dt_bias))        [H, dk]
+           (the SAFE gate, ``decay_bound`` = ``kda_lower_bound`` -5 < g < 0,
+           as Ling-3.0-flash runs it), or
+           -exp(A_log_h) * softplus(f + dt_bias)
+           (the PUBLISHED gate, ``decay_bound`` 0: g < 0 with no lower
+           bound, as Solar-Open2 runs it)
+    f    = W_f n, W_f full rank [d, H, dk] (``gate_rank`` 0), or
+           (n W_f_down) W_f_up through ``gate_rank`` columns
+           (``kda_use_full_proj`` false); float32 accumulation
     o    = the rule with a decay PER CHANNEL over (q, k, v, g, beta)
            (ops/kda: S_t = (I - beta k k^T) Diag(exp g) S_{t-1} + beta k v^T)
     y    = RMSNorm_dv(o; one [dv] scale) * sigmoid(W_g n)
+           (W_g full rank, or its own low-rank pair)
     out  = W_o y
 
+The gate decides the rule's form: under the safe gate a 16-token
+sub-chunk's total decay stays inside float32 and ``ops/kda`` splits every
+pair's decay around the sub-chunk's middle; the published gate has no
+floor, so its layers ask for the form that is exact for any ``g <= 0``
+(``exact``; ``kernel_facts`` says which a model's layers run).
+
 Its scopes are ``linear_attn/qkv``, ``/conv``, ``/gates`` (the decay
-projection, beta and the safe gate), ``/kda``, ``/out_norm`` and ``/wo``;
-its sown vector has a fourth entry, the smallest mean decay of a channel
-(``min_alpha``: a channel that forgets everything reads near exp(bound)).
+projection, beta and the gate; the output gate's low-rank pair where it
+has one), ``/kda``, ``/out_norm`` and ``/wo``; its sown vector has a fourth
+entry, the smallest mean decay of a channel (``min_alpha``: a channel that
+forgets everything reads near exp(bound)), and under the published gate a
+fifth and a sixth: the most negative log decay of a token and channel
+(``g_min``) and the share of (token, head, channel) triples below
+``ops/kda.SPLIT_FLOOR`` = -88 / 16, where the split form would overflow
+(``past_bound_share``).
 
 The block names this module ``linear_attn``, so its ``named_scope``s reach
 the compiled text as ``linear_attn/qkv``, ``/conv``, ``/gates``,
@@ -58,6 +76,7 @@ the compiled text as ``linear_attn/qkv``, ``/conv``, ``/gates``,
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Dict, Optional, Tuple
 
@@ -88,10 +107,14 @@ def split_stats(vec):
 def fold_stats(stacked: jax.Array) -> jax.Array:
     """One vector out of the layers' (and microbatches') ``[n, 3]``: the
     means of the means, the largest of the largest; of a KDA layer's
-    ``[n, 4]`` also the smallest of the smallest decays."""
+    ``[n, 4]`` also the smallest of the smallest decays; of its ``[n, 6]``
+    under a gate without a bound the most negative log decay and the mean
+    of the shares past the split form's floor."""
     folded = [stacked[:, :2].mean(axis=0), stacked[:, 2:3].max(axis=0)]
     if stacked.shape[1] > 3:
-        folded.append(stacked[:, 3:].min(axis=0))
+        folded.append(stacked[:, 3:5].min(axis=0))
+    if stacked.shape[1] > 5:
+        folded.append(stacked[:, 5:].mean(axis=0))
     return jnp.concatenate(folded)
 
 
@@ -99,7 +122,8 @@ def read_stats(vec, first: str, second: str, largest: str = "state_absmax"):
     """A fetched vector of this layout by name, for a family's event: the
     two means as ``first`` and ``second``, the largest entry as ``largest``
     and, of a per-channel rule's ``[4]``, ``min_alpha``: the smallest mean
-    decay of a channel."""
+    decay of a channel; of its ``[6]``, ``g_min`` and ``past_bound_share``
+    (the module's text)."""
     vec = np.asarray(vec, np.float64)
     mean_first, mean_second, absmax = split_stats(vec)
     read = {
@@ -108,6 +132,9 @@ def read_stats(vec, first: str, second: str, largest: str = "state_absmax"):
     }
     if vec.size > 3:
         read["min_alpha"] = float(vec[3])
+    if vec.size > 5:
+        read["g_min"] = float(vec[4])
+        read["past_bound_share"] = float(vec[5])
     return read
 
 
@@ -127,6 +154,22 @@ def _dt_bias_init(key, shape, dtype):
     )
     dt = jnp.maximum(dt, 1e-4)
     return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
+
+
+def _dt_bias_spread_init(std: float):
+    """``dt_bias`` normal about the middle of the default's range (where
+    ``softplus(dt_bias)`` is 1e-2) with ``std`` between CHANNELS: each
+    channel's decay is its own and near constant over the tokens, from
+    channels that keep a write for thousands of tokens to channels that
+    forget it within one (a gate without a bound, ``g`` well below
+    ``ops/kda.SPLIT_FLOOR``)."""
+    def init(key, shape, dtype):
+        return (
+            math.log(math.expm1(1e-2))
+            + std * jax.random.normal(key, shape, F32)
+        ).astype(dtype)
+
+    return init
 
 
 def conv_init(key, shape, dtype):
@@ -403,20 +446,53 @@ class GatedDeltaNet(nn.Module):
 
 class KimiDeltaAttention(nn.Module):
     """Kimi Delta Attention (the module's text): parameters ``qkv``
-    ``[d, H, 2 dk + dv]``, ``conv_kernel``, ``f_kernel`` ``[d, H, dk]`` (the
-    decay projection, full rank), ``b_kernel`` ``[d, H]``, ``A_log`` ``[H]``,
-    ``dt_bias`` ``[H, dk]``, ``g_proj`` ``[d, H, dv]`` (the output gate),
+    ``[d, H, 2 dk + dv]``, ``conv_kernel``, the decay projection
+    (``f_kernel`` ``[d, H, dk]`` at full rank, ``f_down`` ``[d, r]`` and
+    ``f_up`` ``[r, H, dk]`` at ``gate_rank`` r), ``b_kernel`` ``[d, H]``,
+    ``A_log`` ``[H]``, ``dt_bias`` ``[H, dk]``, the output gate (``g_proj``
+    ``[d, H, dv]``, or ``g_down`` ``[d, r]`` and ``g_up`` ``[r, H, dv]``),
     ``out_norm_scale`` ``[dv]``, ``wo``."""
 
     num_heads: int
     key_dim: int
     value_dim: int
     conv_taps: int = 4
-    decay_bound: float = -5.0
+    decay_bound: float = -5.0      # 0: no bound, the published softplus gate
+    gate_rank: int = 0             # 0: the two gate projections full rank
+    allow_neg_eigval: bool = False
+    # the spread of the decay's pre-activation between channels that
+    # ``dt_bias`` is seeded with; 0: the default initialiser
+    decay_init_std: float = 0.0
     norm_eps: float = 1e-5
     chunk: int = 128               # ops/kda.py CHUNK
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
+
+    @property
+    def exact(self) -> bool:
+        """Whether the rule runs in the form that is exact for any ``g <=
+        0`` (ops/kda.py): a gate without a bound needs it."""
+        return not self.decay_bound
+
+    def _low_rank(self, x, name, width, proj_init):
+        """``(x W_down) W_up`` through ``gate_rank`` columns, float32
+        accumulation: ``[B, S, H, width]`` float32."""
+        h = self.num_heads
+        down = self.param(
+            f"{name}_down",
+            nn.with_logical_partitioning(proj_init, (lr.EMBED, None)),
+            (x.shape[-1], self.gate_rank), self.param_dtype,
+        )
+        up = self.param(
+            f"{name}_up",
+            nn.with_logical_partitioning(proj_init, (None, lr.HEADS, lr.KV)),
+            (self.gate_rank, h, width), self.param_dtype,
+        )
+        low = jnp.einsum("bsd,dr->bsr", x, down.astype(self.dtype))
+        return jnp.einsum(
+            "bsr,rhk->bshk", low, up.astype(self.dtype),
+            preferred_element_type=F32,
+        )
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
@@ -443,13 +519,22 @@ class KimiDeltaAttention(nn.Module):
                 l2_scales=(dk ** -0.5, 1.0, None),
             )
         with jax.named_scope("gates"):
-            f_kernel = self.param(
-                "f_kernel",
-                nn.with_logical_partitioning(
-                    proj_init, (lr.EMBED, lr.HEADS, lr.KV)
-                ),
-                (features, h, dk), self.param_dtype,
-            )
+            xc = x.astype(self.dtype)
+            if self.gate_rank:
+                f = self._low_rank(xc, "f", dk, proj_init)
+                out_gate = self._low_rank(xc, "g", dv, proj_init)
+            else:
+                f_kernel = self.param(
+                    "f_kernel",
+                    nn.with_logical_partitioning(
+                        proj_init, (lr.EMBED, lr.HEADS, lr.KV)
+                    ),
+                    (features, h, dk), self.param_dtype,
+                )
+                f = jnp.einsum(
+                    "bsd,dhk->bshk", xc, f_kernel.astype(self.dtype),
+                    preferred_element_type=F32,
+                )
             b_kernel = self.param(
                 "b_kernel",
                 nn.with_logical_partitioning(proj_init, (lr.EMBED, lr.HEADS)),
@@ -463,25 +548,28 @@ class KimiDeltaAttention(nn.Module):
             dt_bias = self.param(
                 "dt_bias",
                 nn.with_logical_partitioning(
-                    _dt_bias_init, (lr.HEADS, lr.KV)
+                    _dt_bias_spread_init(self.decay_init_std)
+                    if self.decay_init_std else _dt_bias_init,
+                    (lr.HEADS, lr.KV),
                 ),
                 (h, dk), F32,
             )
-            xc = x.astype(self.dtype)
-            f = jnp.einsum(
-                "bsd,dhk->bshk", xc, f_kernel.astype(self.dtype),
-                preferred_element_type=F32,
-            )
-            # the safe gate: bounded below, so a sub-chunk's total decay
-            # stays inside float32 (ops/kda.py)
-            g = self.decay_bound * jax.nn.sigmoid(
-                jnp.exp(a_log.astype(F32))[:, None]
-                * (f + dt_bias.astype(F32))
-            )
+            a = jnp.exp(a_log.astype(F32))[:, None]
+            if self.decay_bound:
+                # the safe gate: bounded below, so a sub-chunk's total
+                # decay stays inside float32 (ops/kda.py)
+                g = self.decay_bound * jax.nn.sigmoid(
+                    a * (f + dt_bias.astype(F32))
+                )
+            else:
+                # the published gate: no floor, the rule's exact form
+                g = -a * jax.nn.softplus(f + dt_bias.astype(F32))
             beta = jax.nn.sigmoid(jnp.einsum(
                 "bsd,dh->bsh", xc, b_kernel.astype(self.dtype),
                 preferred_element_type=F32,
             ))
+            if self.allow_neg_eigval:
+                beta = 2.0 * beta
         spec = (lr.BATCH, None, lr.ACT_HEADS, lr.KV)
         q, k, v, g = (
             nn.with_logical_constraint(a, spec) for a in (q, k, v, g)
@@ -490,20 +578,28 @@ class KimiDeltaAttention(nn.Module):
             # imported where a model has such a layer, and by no other
             from dlrover_tpu.ops import kda as kda_ops
 
+            rule = kda_ops.kda
+            if self.exact:
+                rule = functools.partial(rule, exact=True)
             o, state_absmax = _delta_rule_local(
-                q, k, v, g, beta, chunk=self.chunk, rule=kda_ops.kda
+                q, k, v, g, beta, chunk=self.chunk, rule=rule
             )
         # kept by ``flash_only``, and beside it the kernel's chunk-start
         # states (``kda_states``, ops/kda.py; ops/remat_policy.py says why)
         o = jax.ad_checkpoint.checkpoint_name(o, "kda_out")
         o = nn.with_logical_constraint(o, spec)
         alpha = jnp.exp(g)
+        stats = [
+            alpha.mean(), beta.mean(), state_absmax,
+            alpha.mean(axis=(0, 1)).min(),
+        ]
+        if self.exact:
+            stats += [
+                g.min(), (g < kda_ops.SPLIT_FLOOR).astype(F32).mean(),
+            ]
         self.sow(
             "intermediates", STATS_NAME,
-            jax.lax.stop_gradient(jnp.stack([
-                alpha.mean(), beta.mean(), state_absmax,
-                alpha.mean(axis=(0, 1)).min(),
-            ])),
+            jax.lax.stop_gradient(jnp.stack(stats)),
         )
         with jax.named_scope("out_norm"):
             scale = self.param(
@@ -513,17 +609,18 @@ class KimiDeltaAttention(nn.Module):
                 ),
                 (dv,), self.param_dtype,
             )
-            gate = layers.DenseGeneral(
-                (h, dv), kernel_axes=(lr.EMBED, lr.HEADS, lr.KV),
-                dtype=self.dtype, param_dtype=self.param_dtype,
-                kernel_init=proj_init, name="g_proj",
-            )(x)
+            if not self.gate_rank:
+                out_gate = layers.DenseGeneral(
+                    (h, dv), kernel_axes=(lr.EMBED, lr.HEADS, lr.KV),
+                    dtype=self.dtype, param_dtype=self.param_dtype,
+                    kernel_init=proj_init, name="g_proj",
+                )(x)
             o32 = o.astype(F32)
             y = o32 * jax.lax.rsqrt(
                 jnp.mean(o32 * o32, axis=-1, keepdims=True) + self.norm_eps
             )
             y = (
-                y * scale.astype(F32) * jax.nn.sigmoid(gate.astype(F32))
+                y * scale.astype(F32) * jax.nn.sigmoid(out_gate.astype(F32))
             ).astype(self.dtype)
         return layers.DenseGeneral(
             features, axis=(-2, -1),
@@ -549,7 +646,10 @@ def from_config(cfg, **kwargs):
     )
     if cfg.linear_rule == "kda":
         return KimiDeltaAttention(
-            decay_bound=cfg.linear_decay_bound, **widths
+            decay_bound=cfg.linear_decay_bound,
+            gate_rank=cfg.linear_gate_rank,
+            allow_neg_eigval=cfg.linear_allow_neg_eigval,
+            decay_init_std=cfg.linear_decay_init_std, **widths
         )
     return GatedDeltaNet(
         allow_neg_eigval=cfg.linear_allow_neg_eigval, **widths
@@ -568,9 +668,10 @@ def kernel_facts(cfg, seq_len: int) -> Dict[str, str]:
     """``short_conv``: how the mixers' convolution runs on ``seq_len``
     tokens, ``kernel`` (``ops/short_conv.py``) / ``xla`` (the written-out
     form), chosen at trace time from the shapes alone (:func:`conv_path`).
-    ``kda``: how the per-channel rule runs, ``kernel`` / ``xla``
-    (``ops/kda.py`` ``plan``, which the rule asks).  Each ``none`` for a
-    model without such a layer."""
+    ``kda``: how the per-channel rule runs, ``kernel`` / ``xla`` under a
+    bounded gate, ``kernel_exact`` / ``xla_exact`` in the form for any ``g
+    <= 0`` (``ops/kda.py`` ``plan``, which the rule asks).  Each ``none``
+    for a model without such a layer."""
     if not cfg.num_linear_layers:
         return {"short_conv": "none", "kda": "none"}
     from dlrover_tpu.ops import kda
@@ -582,7 +683,7 @@ def kernel_facts(cfg, seq_len: int) -> Dict[str, str]:
             seq_len, mixer.num_heads, mixer.key_dim, mixer.value_dim,
             mixer.conv_taps, gate_in_row=not per_channel,
         ),
-        "kda": kda.plan(mixer.key_dim, mixer.value_dim)
+        "kda": kda.plan(mixer.key_dim, mixer.value_dim, mixer.exact)
         if per_channel else "none",
     }
 

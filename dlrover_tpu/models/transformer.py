@@ -215,7 +215,9 @@ class TransformerConfig:
     # qk_rope_head_dim`` wide, values ``v_head_dim``.
     # ``q_lora_rank`` 0 beside the other four: q straight from the stream
     # (no latent, no q norm).  ``attention_gate`` "head_wise": each head's
-    # output times ``sigmoid(n W_gate)_h`` before ``wo`` (latent only).
+    # output times ``sigmoid(n W_gate)_h`` before the output projection;
+    # "elementwise": every channel of every head under its own gate
+    # (``W_gate`` [d, H, hd]; grouped-query attention only).
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
@@ -275,9 +277,25 @@ class TransformerConfig:
     linear_allow_neg_eigval: bool = False
     # The linear layers' rule: "delta" (Gated DeltaNet: one decay a head,
     # ``GatedDeltaNet``) or "kda" (Kimi Delta Attention: a decay a channel,
-    # ``g = linear_decay_bound x sigmoid(..)``, ``KimiDeltaAttention``).
+    # ``KimiDeltaAttention``): under the safe gate ``g = linear_decay_bound
+    # x sigmoid(..)`` (Ling's ``kda_lower_bound``), or with
+    # ``linear_decay_bound`` 0 under the published gate ``-exp(A_log) x
+    # softplus(..)``, which has no bound and takes the rule's exact form
+    # (ops/kda.py).  ``linear_gate_rank``: the columns the decay's and the
+    # output gate's projections pass through (Kimi Linear's
+    # ``kda_use_full_proj`` false: the head width); 0: full rank.  Under
+    # "kda" too ``linear_allow_neg_eigval`` doubles beta.
     linear_rule: str = "delta"
     linear_decay_bound: float = -5.0
+    linear_gate_rank: int = 0
+    # The spread BETWEEN CHANNELS the KDA decay's pre-activation is SEEDED
+    # with (``dt_bias`` normal about the default's middle; 0: the default
+    # initialiser, under which no token decays a channel by more than
+    # e^-2): a trained gate has channels that keep a write for thousands of
+    # tokens and channels that close, and only such a gate shows whether
+    # the rule holds without a bound and whether its float32 state matters.
+    # See ``benchmark/configs/solar-open2-250b.json``.
+    linear_decay_init_std: float = 0.0
     # Taps of the "conv" layers' gated short convolution
     # (models/gated_conv.py; LFM2's ``conv_L_cache``).
     conv_kernel: int = 3
@@ -642,13 +660,22 @@ class TransformerConfig:
                     f"{self.linear_rule!r}"
                 )
             if self.linear_rule == "kda" and not (
-                -88.0 / 16 < self.linear_decay_bound < 0
+                -88.0 / 16 < self.linear_decay_bound <= 0
             ):
                 raise ValueError(
                     "linear_decay_bound bounds a token's log decay from "
                     "below so that a 16-token sub-chunk's stays inside "
-                    "float32 (ops/kda.py): it must lie in (-5.5, 0), got "
+                    "float32 (ops/kda.py), or is 0 for the gate without a "
+                    "bound: it must lie in (-5.5, 0), got "
                     f"{self.linear_decay_bound}"
+                )
+            if self.linear_gate_rank < 0 or (
+                self.linear_gate_rank and self.linear_rule != "kda"
+            ):
+                raise ValueError(
+                    "linear_gate_rank is the per-channel rule's "
+                    "(linear_rule 'kda'), 0 or a number of columns, got "
+                    f"{self.linear_gate_rank} under {self.linear_rule!r}"
                 )
             if self.decode:
                 raise ValueError(
@@ -892,12 +919,14 @@ class TransformerConfig:
                 "qk_rope_head_dim and v_head_dim together (q_lora_rank 0: q "
                 f"straight from the stream), got {latent}"
             )
-        if self.attention_gate not in ("", "head_wise") or (
-            self.attention_gate and not self.latent_attention
+        if self.attention_gate not in ("", "head_wise", "elementwise") or (
+            self.attention_gate == "elementwise" and self.latent_attention
         ):
             raise ValueError(
-                "attention_gate is '' or 'head_wise', and latent "
-                f"attention's, got {self.attention_gate!r}"
+                "attention_gate is '' or 'head_wise' (latent attention's "
+                "gate and grouped-query attention's) or 'elementwise' "
+                "(grouped-query attention's alone), got "
+                f"{self.attention_gate!r}"
             )
         if not self.latent_attention:
             return
@@ -966,7 +995,9 @@ class TransformerConfig:
         else:
             h = self.resolved_head_dim * self.num_heads
             hkv = self.resolved_head_dim * self.resolved_kv_heads
-            attn = d * h + 2 * d * hkv + h * d
+            attn = d * h + 2 * d * hkv + h * d + {
+                "": 0, "head_wise": d * self.num_heads, "elementwise": d * h,
+            }[self.attention_gate]
         dense_ff = swiglu * d * self.resolved_d_ff
         if self.num_experts:
             one = swiglu * d * self.resolved_moe_d_ff
@@ -1027,12 +1058,18 @@ class TransformerConfig:
         """q, k, v, gate and output projections, the two gate
         projections, the convolution's taps, A_log, dt_bias and the output
         norm's scale (models/linear_attention.py); under ``kda`` the decay
-        projection is full rank ([d, H dk]) and dt_bias a channel's."""
+        projection is a channel's ([d, H dk], or through
+        ``linear_gate_rank`` columns as the output gate's then is) and
+        dt_bias a channel's."""
         d, h = self.d_model, self.resolved_linear_heads
         dk, dv = self.linear_key_head_dim, self.linear_value_head_dim
         if self.linear_rule == "kda":
+            r = self.linear_gate_rank
+            gates = (d + h * dk) * r + (d + h * dv) * r if r else (
+                d * h * dk + d * h * dv
+            )
             return (
-                2 * d * h * dk + 3 * d * h * dv + d * h * dk + d * h
+                2 * d * h * dk + 2 * d * h * dv + gates + d * h
                 + self.linear_conv_kernel * h * (2 * dk + dv)
                 + h + h * dk + dv
             )

@@ -192,11 +192,11 @@ def _delta_rule():
     return jax.grad(loss, argnums=(0, 1, 2, 3, 4))
 
 
-def _kda():
+def _kda(exact=False):
     from dlrover_tpu.ops.kda import kda
 
     def loss(q, k, v, g, beta):
-        return kda(q, k, v, g, beta)[0].astype(F32).sum()
+        return kda(q, k, v, g, beta, exact=exact)[0].astype(F32).sum()
 
     return jax.grad(loss, argnums=(0, 1, 2, 3, 4))
 
@@ -362,6 +362,12 @@ CASES = [
     ("kda_ling_flash", _kda,
      [((2, 8192, 32, 128), BF16)] * 3 + [((2, 8192, 32, 128), F32)]
      + [((2, 8192, 32), F32)], {}, 2),
+    # Solar-Open2's KDA layers: 1 x 16384 tokens, 64 heads of 128 / 128 under
+    # a gate with no lower bound: the kernels in the form that is exact for
+    # any g <= 0 (the pairs' decays cut by halves, ops/kda.py)
+    ("kda_solar_open_exact", lambda: _kda(exact=True),
+     [((1, 16384, 64, 128), BF16)] * 3 + [((1, 16384, 64, 128), F32)]
+     + [((1, 16384, 64), F32)], {}, 2),
     # Nemotron-3-Nano's Mamba-2 layers: 2 x 8192 tokens, 64 heads of 64, a
     # 128-wide state, 8 groups: the forward kernel and the backward kernel
     ("ssd_nemotron_h", _ssd,

@@ -24,9 +24,11 @@ CASES = {
         np.array([0.25, 0.03125, 0.75]),
     ]),
     "mtp": ("joyai-llm-flash", lambda cfg: [np.float32(9.5)]),
-    # the per-channel rule: the fourth entry, ``min_alpha``
-    "linear_attn": ("ling-3.0-flash-vl", lambda cfg: [
-        np.array([0.875, 0.5, 3.5, 0.125], np.float32)
+    # the per-channel rule under a gate without a bound: the fourth entry,
+    # ``min_alpha``, the fifth and the sixth, ``g_min`` and
+    # ``past_bound_share``
+    "linear_attn": ("solar-open2-250b", lambda cfg: [
+        np.array([0.875, 1.0, 3.5, 0.125, -96.0, 0.03125], np.float32)
     ]),
     "ssm": ("nemotron-3-nano-30b-a3b", lambda cfg: [
         np.array([0.75, 0.03125, 6.5], np.float32)
@@ -84,6 +86,10 @@ GAUGES_SINCE = {
         "dlrover_moe_shared_expert_scale",
     },
     "attn": {"dlrover_attn_rotated_layers"},
+    # PR 64: a gate without a lower bound
+    "linear_attn": {
+        "dlrover_linear_attn_g_min", "dlrover_linear_attn_past_bound_share",
+    },
     # PR 62: the family itself
     "index": {
         "dlrover_index_layers", "dlrover_index_shared_layers",

@@ -53,9 +53,12 @@ def test_bad_combinations_of_the_new_fields_raise(overrides, message):
         config(**overrides)
 
 
-def test_an_attention_gate_without_latent_attention_raises():
+def test_an_elementwise_gate_on_latent_attention_raises():
+    """Latent attention's gate is a head's; since PR 64 plain grouped-query
+    attention takes either granularity (tests/test_solar_open_config.py)."""
     with pytest.raises(ValueError, match="latent attention's"):
-        TransformerConfig(attention_gate="head_wise")
+        config(attention_gate="elementwise")
+    assert TransformerConfig(attention_gate="head_wise").attention_gate
 
 
 def test_the_defaults_leave_every_other_model_as_it_was():

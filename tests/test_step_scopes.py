@@ -302,6 +302,34 @@ def test_the_sparse_attention_preset_keeps_its_lowered_step_text(preset):
         assert name in step_lowered(preset).as_text(debug_info=True), name
 
 
+# PR 64 recorded Solar-Open2's (Kimi Delta Attention under the published
+# gate, which has no lower bound: the rule's exact form, the triangle cut by
+# halves, here as the chunked ``jax.numpy`` form at the preset's heads of
+# 16; the two low-rank pairs under ``gates``; the element-wise gate on
+# position-free grouped-query attention) and no other.  Ling's text above
+# stands unedited after ``ops/kda.py``'s pair products moved into two
+# functions a form and ``KimiDeltaAttention`` learnt the published gate,
+# which is the CPU's certificate that Ling's chip runs the program it ran.
+FREE_GATE_PRESETS_LOWERED = {
+    "solar-open2-250b":
+        "58c62fc0451fddaa117952e86cc385e9b0a197fdccc9a96a0eb4527daeb9bd23",
+}
+
+
+@pytest.mark.parametrize("preset", sorted(FREE_GATE_PRESETS_LOWERED))
+def test_the_free_gate_preset_keeps_its_lowered_step_text(preset):
+    text = lowered_step_text(preset)
+    assert hashlib.sha256(text.encode()).hexdigest() == (
+        FREE_GATE_PRESETS_LOWERED[preset]
+    )
+    # the names only this model's programs hold
+    named = step_lowered(preset).as_text(debug_info=True)
+    for name in ("linear_attn/gates", "attn/gate", "f_down", "g_up",
+                 "softplus"):
+        assert name in named, name
+    assert "rope" not in named and "router_bias" not in named
+
+
 @pytest.mark.parametrize("preset", sorted(LATER_PRESETS_LOWERED))
 def test_the_multipliers_and_the_tiles_default_to_nothing(preset):
     """A config that names none of the four multipliers, and a scan whose

@@ -24,8 +24,32 @@ for name, value in (
         flags += f" --{name}={value}"
 os.environ["XLA_FLAGS"] = flags.strip()
 
+import contextlib  # noqa: E402
+import signal  # noqa: E402
+import threading  # noqa: E402
+
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
+
+
+# ``--dist loadfile`` hands a worker the next FILE in collection order, so a
+# file of minutes that sorts late (``test_step_scopes.py`` is the 106th of
+# 116) starts when the others end and is the run's tail: the run then takes
+# its start plus its length, whatever the total.  The three files that take
+# five minutes and more of a worker (junit case-seconds of a whole run,
+# ROADMAP D12) start first; the rest keep their order (sixteen files first,
+# longest first, read the same wall: 1,116 s beside 1,119).  A name that
+# is gone only costs order, never a case.
+STARTS_FIRST = (
+    "test_benchmark_program_spans.py", "test_benchmark_rehearsal.py",
+    "test_step_scopes.py",
+)
+
+
+def pytest_collection_modifyitems(items):
+    place = {name: at for at, name in enumerate(STARTS_FIRST)}
+    # (stable: a file's cases keep their order)
+    items.sort(key=lambda item: place.get(item.path.name, len(place)))
 
 
 def _cpu_child_env(base=None):
@@ -61,6 +85,42 @@ def compiled_programs_end_with_their_file():
 
     jax.clear_caches()
     gc.collect()
+
+
+CASE_LIMIT_S = 300
+
+
+@contextlib.contextmanager
+def limited(name):
+    """Raises ``TimeoutError`` naming ``name`` in the main thread once
+    ``CASE_LIMIT_S`` seconds have passed inside; on the way out the alarm is
+    off and the handler the one it found.  Off the main thread nothing is
+    armed."""
+    if threading.current_thread() is not threading.main_thread():
+        yield
+        return
+    seconds = CASE_LIMIT_S
+
+    def late(signum, frame):
+        raise TimeoutError(f"{name} waited past {seconds} s")
+
+    before = signal.signal(signal.SIGALRM, late)
+    signal.setitimer(signal.ITIMER_REAL, seconds)
+    try:
+        yield
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, before)
+
+
+@pytest.fixture(autouse=True)
+def a_case_that_waits_fails_alone(request):
+    """A case that waits past ``CASE_LIMIT_S`` (the longest takes 97 s under
+    load) fails by name and the run goes on: without it a hung case holds
+    its worker until the run's own limit cuts every case after it.  (xdist
+    runs a case on its worker's main thread.)"""
+    with limited(request.node.nodeid):
+        yield
 
 
 @pytest.fixture(scope="session")

@@ -24,8 +24,9 @@ the program by, so the cache holds it and nothing stands beside it.
 ``preset`` reads a tiny preset of the benchmark, ``lowered`` lowers a built
 step once for every case that reads its text, ``first_step`` runs one step
 on given weights, ``digest`` folds a state as the trainer does,
-``compile_event`` is what a trainer of the configuration says of its
-compiled step, ``compile_events`` everything it records while it is built.
+``trained`` is a trainer of the configuration on one device: everything it
+records while it is built and the step it compiled, ``compile_event`` what
+it says of that step; ``fit`` a trainer on every device, trained.
 A file is one process: cases
 that read the same program belong in the same file (and when a file ends
 ``conftest.py`` drops every program jax compiled: what ``built`` keeps
@@ -40,6 +41,7 @@ one each time, copied from the weights they are given, so the step's
 donation of its state takes nothing of another case's.
 """
 
+import contextlib
 import dataclasses
 import functools
 import os
@@ -142,6 +144,15 @@ def first_step(train, params, tokens):
     return train.step(state, train_lib.shard_batch(batch, train))
 
 
+def router_biases(tree):
+    """{path: the router bias there, on the host} of a parameter tree."""
+    return {
+        "/".join(k.key for k in path): np.asarray(leaf)
+        for path, leaf in jax.tree_util.tree_leaves_with_path(tree)
+        if path[-1].key == "router_bias"
+    }
+
+
 def digest(state):
     """The train state's digest as the trainer takes it, one program a
     tree (op by op a leaf's fold is a program for every shape)."""
@@ -150,47 +161,141 @@ def digest(state):
     return int(jax.jit(state_digest._digest_tree)(state))
 
 
-def compile_event(cfg, seq, patches=()):
-    """The attributes of the ``compile`` event of ``compile_events``'s
-    trainer."""
-    (event,) = [
-        e for e in compile_events(cfg, seq, patches) if e[0] == "compile"
-    ]
-    return event[-1]
-
-
-@functools.cache
-def compile_events(cfg, seq, patches=()):
-    """Every event an ``ElasticTrainer`` of ``cfg`` on every host device, a
-    sequence each, records while it is built: its start-up spans, the
-    ``compile`` event and its children; ``patches``: (object, attribute,
-    value) triples in force while it is built (the build cache is emptied
-    around such a build, and only around such a one)."""
-    from dlrover_tpu.trainer.elastic_trainer import (
-        ElasticTrainer, TrainerConfig,
-    )
-
+@contextlib.contextmanager
+def _recording():
+    """The tap of everything the process-wide recorder records inside."""
     recorder = telemetry.recorder()
     was_enabled = recorder.enabled
     recorder.configure(enabled=True)
     try:
-        with pytest.MonkeyPatch.context() as patch, (
-            recorder.open_tap()
-        ) as tap:
+        with recorder.open_tap() as tap:
+            yield tap
+    finally:
+        recorder.configure(enabled=was_enabled)
+
+
+def compile_event(cfg, seq, batch=1, patches=()):
+    """The attributes of the ``compile`` event of ``trained``'s trainer."""
+    (event,) = [
+        e for e in trained(cfg, seq, batch, patches)[0] if e[0] == "compile"
+    ]
+    return event[-1]
+
+
+def trained(cfg, seq, batch=1, patches=()):
+    """``(events, train)`` of an ``ElasticTrainer`` of ``cfg`` on the FIRST
+    host device, ``batch`` sequences a step: every event it records while
+    it is built (its start-up spans, the ``compile`` event and its
+    children) and its compiled ``ShardedTrain``, which is the program
+    ``built(cfg, batch=batch, seq=seq)`` would build (the same lowered
+    text: the pinned hashes of ``tests/test_step_scopes.py`` read either),
+    so a case that wants a preset's event AND its step walks the model
+    once.  ``patches``: (object, attribute, value) triples in force while
+    it is built (the build cache is emptied around such a build, and only
+    around such a one)."""
+    return _trained(cfg, seq, batch, tuple(patches))
+
+
+@functools.cache
+def _trained(cfg, seq, batch, patches):
+    from dlrover_tpu.trainer import elastic_trainer
+
+    try:
+        with pytest.MonkeyPatch.context() as patch, _recording() as tap:
+            # the trainer takes every device there is: hand it one
+            patch.setattr(
+                elastic_trainer, "build_mesh",
+                lambda parallel: build_mesh(
+                    parallel, devices=jax.devices()[:1]
+                ),
+            )
             for target, name, value in patches:
                 patch.setattr(target, name, value)
             if patches:
                 # a patch changes the program and not the key it is kept by
                 train_lib.reset_build_cache()
-            ElasticTrainer(cfg, TrainerConfig(
-                global_batch_size=jax.device_count(), seq_len=seq,
-                optimizer="adafactor", warmup_compile=True, ckpt_every=1000,
-            ))
-            return tuple(tap.take())
+            trainer = elastic_trainer.ElasticTrainer(
+                cfg, elastic_trainer.TrainerConfig(
+                    global_batch_size=batch, seq_len=seq, world=1,
+                    optimizer="adafactor", warmup_compile=True,
+                    ckpt_every=1000,
+                ), client=None,
+            )
+            return tuple(tap.take()), trainer.train
     finally:
-        recorder.configure(enabled=was_enabled)
         if patches:
             train_lib.reset_build_cache()
+
+
+def batches(n, batch, seq, vocab, seed=0):
+    """``n`` seeded batches of ``batch`` rows of ``seq`` tokens and their
+    next tokens."""
+    rows = np.random.default_rng(seed).integers(
+        0, vocab, (n, batch, seq + 1), dtype=np.int32
+    )
+    return [{"inputs": r[:, :-1], "targets": r[:, 1:]} for r in rows]
+
+
+def fit(cfg, directory, *, seq, steps=10, batch=None, seed=0, **options):
+    """An ``ElasticTrainer`` of ``cfg`` on every host device, trained to step
+    ``steps`` on seeded batches (``batch`` rows, one a device by default)
+    under the trainer options a system file runs (Adafactor at 1e-2, a
+    report every 5 steps read 4 steps late, the step compiled before the
+    first batch, no save) and whatever ``options`` name; with ``ckpt_every``
+    among them it saves under ``directory`` and starts from what it finds
+    there.  Returns, after the trainer is closed: ``train`` (its
+    ``ShardedTrain``: a case that wants the step program runs or lowers THIS
+    one, and a later trainer of the same key is handed it by the build
+    cache), ``grad_accum``, ``taken`` (everything the recorder took),
+    ``seen`` (step: its metrics), ``began`` and ``ended`` ((step, the state's
+    digest) before the first step and after the last), ``params`` (the
+    weights before and after, on the host) and ``traces`` (how often
+    ``train_step`` was traced meanwhile: 0 where the build cache had the
+    program)."""
+    from dlrover_tpu.trainer.elastic_trainer import (
+        ElasticTrainer, TrainerConfig,
+    )
+
+    batch = batch or jax.device_count()
+    if "ckpt_every" in options:
+        options["checkpoint_dir"] = os.path.join(directory, "ckpt")
+    config = TrainerConfig(**{**dict(
+        global_batch_size=batch, seq_len=seq, learning_rate=1e-2,
+        optimizer="adafactor", ckpt_every=1000, report_every=5,
+        metrics_lag=4, warmup_compile=True,
+    ), **options})
+    seen, traces = {}, train_lib.trace_count("train_step")
+    with pytest.MonkeyPatch.context() as patch, _recording() as tap:
+        # the arena outlives processes and is named by the job tag: a tag
+        # of this directory's own, or an earlier run's arena is what
+        # restores
+        patch.setenv(
+            "DLROVER_TPU_JOB",
+            f"fit{os.getpid()}_{os.path.basename(directory)}",
+        )
+        patch.setenv(
+            "DLROVER_TPU_SOCKET_DIR", os.path.join(directory, "socks")
+        )
+        trainer = ElasticTrainer(cfg, config, client=None)
+        try:
+            began = (trainer.step, digest(trainer.state))
+            first = jax.tree.map(np.asarray, trainer.state.params)
+            trainer.fit(
+                batches(steps, batch, seq, cfg.vocab_size, seed),
+                max_steps=steps,
+                on_step=lambda step, metrics: seen.update({step: metrics}),
+            )
+            return dict(
+                train=trainer.train, grad_accum=trainer.grad_accum,
+                taken=tap.take(), seen=seen, began=began,
+                ended=(trainer.step, digest(trainer.state)),
+                params=(
+                    first, jax.tree.map(np.asarray, trainer.state.params)
+                ),
+                traces=train_lib.trace_count("train_step") - traces,
+            )
+        finally:
+            trainer.close()
 
 
 # -- a program against its reference -------------------------------------------
@@ -294,11 +399,26 @@ def _jitted(what, cfg):
     return jax.jit(functools.partial(fn, cfg))
 
 
-@functools.cache
+# The fields that say HOW the program computes and not what: no reference
+# module reads them (it has one way), so two configurations that differ in
+# them alone (a model's ``share`` and ``flash`` cases) share the reference's
+# programs.
+_HOW = (
+    "attention_impl", "flash_block_q", "flash_block_kv", "remat", "ssm_impl",
+    "fused_ln",
+)
+
+
 def _reference(fn, cfg, kw):
     """``fn(cfg's fields, params, *tokens, **kw)`` of a reference module as
     one program (op by op the glue between its layers, a slice of every
     stacked leaf for every layer, is a program each)."""
+    how = {f.name: f.default for f in dataclasses.fields(cfg) if f.name in _HOW}
+    return _reference_program(fn, dataclasses.replace(cfg, **how), kw)
+
+
+@functools.cache
+def _reference_program(fn, cfg, kw):
     return jax.jit(functools.partial(fn, dataclasses.asdict(cfg), **dict(kw)))
 
 
@@ -342,6 +462,11 @@ class Harness:
         (loss, parts), grads = self._once("grads", cfg, params, tokens)
         return loss, parts, grads
 
+    def gradient_program(self, cfg, params, tokens):
+        """The jaxpr of the loss-and-gradients program (the tracing
+        ``loss_and_grads`` made, where it has run)."""
+        return _jitted("grads", cfg).trace(params, *tokens).jaxpr
+
     def reference(self, what, cfg, params, tokens, **kw):
         """The reference module's ``what`` (``forward``, ``token_nll``,
         ``loss_and_grads``) under ``cfg``'s fields and whatever ``kw`` names
@@ -382,6 +507,31 @@ class Harness:
                 assert top > 0, name
 
 
+def share_program(layer_of):
+    """``apply(part, n, first)``, the ``((output, aux), sown)`` of the share
+    ``layer_of(first)`` of an expert layer on the weights ``part``: ONE
+    program for every share, the share's place an argument of it (a
+    constant of it, the layer is walked once a share: 32 walks of GLM-5.2's
+    layer).  ``check_share`` reads the place as a Python number, so it is
+    asked before the program and not inside it."""
+    check = moe_lib.check_share
+
+    @jax.jit
+    def traced(part, n, first):
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setattr(moe_lib, "check_share", lambda *args: None)
+            return layer_of(first).apply(
+                {"params": part}, n, mutable=["intermediates"]
+            )
+
+    def apply(part, n, first):
+        layer = layer_of(first)
+        check(layer.num_experts, layer.experts_held, first, layer.dispatch)
+        return traced(part, n, first)
+
+    return apply
+
+
 def shares_add_up(ref, fields, n, whole, held_here, layer_of, shared, atol,
                   balance=None):
     """The routed parts of all the shares of ``held_here`` experts of one
@@ -391,18 +541,16 @@ def shares_add_up(ref, fields, n, whole, held_here, layer_of, shared, atol,
     layer; nothing is dropped, each share's routed part is the reference's
     own partial sum, and the shares' pairs add up to all of them.
     ``balance(aux, reference's term)`` checks a share's auxiliary term."""
+    apply = share_program(layer_of)
     with jax.default_matmul_precision("highest"):
         want, _ = ref.expert_layer(fields, n, whole)
         got, seen = shared, 0.0
         for first in range(0, fields["num_experts"], held_here):
-            layer = layer_of(first)
             part = dict(whole, **{
                 w: whole[w][first:first + held_here]
                 for w in ("wi", "wg", "wo") if w in whole
             })
-            (out, aux), sown = jax.jit(lambda part, n: layer.apply(
-                {"params": part}, n, mutable=["intermediates"]
-            ))(part, n)
+            (out, aux), sown = apply(part, n, first)
             stats = sown["intermediates"]
             assert float(moe_lib.split_stats(stats["moe_stats"][0])[1]) == 0.0
             seen += float(stats[moe_lib.SHARE_STATS_NAME][0][0])

@@ -85,7 +85,9 @@ def test_sigkill_one_of_two_agents_survivor_recovers(tmp_path, cpu_child_env):
         deadline = time.monotonic() + 240
         while layout.latest_step(storage) < 4:
             assert time.monotonic() < deadline, "no checkpoint within 240s"
-            assert procs[0].poll() is None, procs[0].communicate()[0][-3000:]
+            assert procs[0].poll() is None, (
+                procs[0].communicate(timeout=120)[0][-3000:]
+            )
             assert procs[1].poll() is None, "agent 1 died prematurely"
             time.sleep(0.5)
         os.killpg(os.getpgid(procs[1].pid), signal.SIGKILL)

@@ -285,6 +285,19 @@ def test_the_sixteen_chips_shares_add_up_to_the_uncut_layer():
                 "value": {"kernel": p["value"]["kernel"][:, g]},
                 "out": {"kernel": p["out"]["kernel"][h]},
             }}, n_here)
+        # a chip's layer with a shared expert and without, a program each
+        # for all the chips of its form
+        apply = {
+            has_shared: harness.share_program(
+                lambda first, has_shared=has_shared: moe_lib.MoEMlp(
+                    num_experts=experts, d_ff=width, top_k=top_k,
+                    dispatch="grouped", scoring="sigmoid", experts_held=2,
+                    first_expert=first, row_budget_multiple=16.0,
+                    shared_d_ff=width if has_shared else 0,
+                    shared_scale=0.25, dtype=jnp.float32, gmm_block_rows=8,
+                )
+            ) for has_shared in (True, False)
+        }
         pairs = 0.0
         for chip in range(16):                  # routed over 16
             first, cols = 2 * chip, slice(chip * width, (chip + 1) * width)
@@ -301,16 +314,7 @@ def test_the_sixteen_chips_shares_add_up_to_the_uncut_layer():
                     "wg": {"kernel": shared["wg"]["kernel"][:, cols]},
                     "wo": {"kernel": shared["wo"]["kernel"][cols]},
                 }
-            layer = moe_lib.MoEMlp(
-                num_experts=experts, d_ff=width, top_k=top_k,
-                dispatch="grouped", scoring="sigmoid", experts_held=2,
-                first_expert=first, row_budget_multiple=16.0,
-                shared_d_ff=width if has_shared else 0, shared_scale=0.25,
-                dtype=jnp.float32, gmm_block_rows=8,
-            )
-            (out, aux), sown = jax.jit(lambda part, n: layer.apply(
-                {"params": part}, n, mutable=["intermediates"]
-            ))(part, n_here)
+            (out, aux), sown = apply[has_shared](part, n_here, first)
             stats = sown["intermediates"]
             assert float(moe_lib.split_stats(stats["moe_stats"][0])[1]) == 0.0
             pairs += float(stats[moe_lib.SHARE_STATS_NAME][0][0])

@@ -9,7 +9,6 @@ benchmark reads; that the parameters held are the parameters counted.
 ``numerics``.)"""
 
 import json
-import os
 import re
 
 import jax
@@ -27,22 +26,36 @@ from test_command_a_reference import config, share, tokens  # noqa: F401
 SEQ, BATCH, VOCAB = 32, 8, numerics.VOCAB
 FLASH = dict(attention_impl="flash", flash_block_q=8, flash_block_kv=8)
 
-
-def batches(n, seed=0, batch=BATCH):
-    rng = np.random.default_rng(seed)
-    rows = rng.integers(0, VOCAB, (n, batch, SEQ + 1), dtype=np.int32)
-    return [{"inputs": r[:, :-1], "targets": r[:, 1:]} for r in rows]
+# ONE step program for the file: the trainer's, which the first-loss case
+# runs on the trainer's batch (``train_step`` is traced once)
+pytestmark = pytest.mark.usefixtures("one_step_program")
 
 
-def test_the_train_step_s_first_loss_is_the_reference_s(tokens):
-    """The normal path: ``build_sharded_train``'s compiled step under the
-    policy the cell runs, both kinds through the flash kernels, both
-    branches of the parallel block under ``flash_only``."""
-    cfg = config(remat="flash_only", **FLASH)
+@pytest.fixture(scope="module")
+def fitted(tmp_path_factory):
+    """Ten steps of the preset's period under the policy the cell runs at
+    ``report_every=5``, a checkpoint every 5; then a second trainer, as a
+    restarted process builds it (the normal path's restore), that trains
+    on to step 12."""
+    directory = str(tmp_path_factory.mktemp("command_a"))
+    cfg = config(max_seq_len=SEQ, remat="flash_only", **FLASH)
+    first = harness.fit(cfg, directory, seq=SEQ, batch=BATCH, ckpt_every=5)
+    second = harness.fit(
+        cfg, directory, seq=SEQ, batch=BATCH, ckpt_every=5, steps=12, seed=1
+    )
+    return dict(
+        first, cfg=cfg, restored=second["began"], final=second["ended"][0]
+    )
+
+
+def test_the_train_step_s_first_loss_is_the_reference_s(fitted):
+    """The normal path: the trainer's compiled step under the policy the
+    cell runs, both kinds through the flash kernels, both branches of the
+    parallel block under ``flash_only``."""
+    cfg = fitted["cfg"]
     params = share(cfg)
-    train = harness.built(cfg, batch=numerics.BATCH, seq=numerics.SEQ)
-    with jax.default_matmul_precision("highest"):
-        _, metrics = harness.first_step(train, params, tokens)
+    tokens = harness.tokens(1, BATCH, SEQ, VOCAB)
+    _, metrics = harness.first_step(fitted["train"], params, tokens)
     want = numerics.CHECK.reference("forward", cfg, params, tokens)
     assert abs(float(metrics["loss"]) - float(want["nll"].mean())) <= 1e-4
     assert float(metrics["aux_loss"]) == 0.0
@@ -52,60 +65,11 @@ def test_the_train_step_s_first_loss_is_the_reference_s(tokens):
     assert full > 0 and sliding > 0
 
 
-@pytest.fixture(scope="module")
-def fitted(tmp_path_factory):
-    """``(the trainer's configuration, what the recorder took, the metrics
-    of each step, the digest of the state saved at step 10)`` of ten steps
-    of the preset's period at ``report_every=5``, a checkpoint every 5."""
-    from dlrover_tpu.common import telemetry
-    from dlrover_tpu.trainer.elastic_trainer import (
-        ElasticTrainer,
-        TrainerConfig,
-    )
-
-    tmp_path = tmp_path_factory.mktemp("command_a")
-    with pytest.MonkeyPatch.context() as patch:
-        # the arena outlives processes and is named by the job tag: a tag
-        # of this process's own, or an earlier run's arena is what restores
-        patch.setenv(
-            "DLROVER_TPU_JOB", f"command_a{os.getpid()}_{tmp_path.name}"
-        )
-        patch.setenv("DLROVER_TPU_SOCKET_DIR", str(tmp_path / "socks"))
-        cfg = config(max_seq_len=SEQ, **FLASH)
-        trainer_config = TrainerConfig(
-            global_batch_size=BATCH, seq_len=SEQ, learning_rate=1e-2,
-            optimizer="adafactor", checkpoint_dir=str(tmp_path / "ckpt"),
-            ckpt_every=5, report_every=5, metrics_lag=4,
-            warmup_compile=True,
-        )
-        seen = {}
-        train_lib.reset_trace_counts()
-        with telemetry.recorder().open_tap() as tap:
-            trainer = ElasticTrainer(cfg, trainer_config, client=None)
-            trainer.fit(
-                batches(10), max_steps=10,
-                on_step=lambda step, metrics: seen.update({step: metrics}),
-            )
-            taken = tap.take()
-        saved = harness.digest(trainer.state)
-        trainer.close()
-        traces = train_lib.trace_count("train_step")
-        # a second trainer, as a restarted process builds it: the normal
-        # path's restore
-        second = ElasticTrainer(cfg, trainer_config, client=None)
-        restored = (second.step, harness.digest(second.state))
-        final = second.fit(batches(12, seed=1), max_steps=12)
-        second.close()
-        return dict(
-            taken=taken, seen=seen, saved=saved, traces=traces,
-            restored=restored, final=final,
-        )
-
-
 def test_a_save_is_restored_by_the_next_trainer(fitted):
     """``ElasticTrainer`` with a checkpoint directory: the state saved at
     step 10 is the state the next trainer starts from, and it trains on."""
-    assert fitted["restored"] == (10, fitted["saved"])
+    assert fitted["restored"] == fitted["ended"]
+    assert fitted["ended"][0] == 10
     assert fitted["final"] == 12
     saves = [e for e in fitted["taken"] if e[0] == "checkpoint"]
     assert [e[4]["step"] for e in saves] == [5, 10]
@@ -151,7 +115,7 @@ def test_fit_books_the_new_facts_from_the_step_itself(fitted):
         assert event["drop_fraction"] == 0.0
         assert 0.1 < event["pairs_here"] < 0.5
         assert len(json.loads(event["load"])) == 16
-    assert fitted["traces"] == 1
+    assert train_lib.trace_count("train_step") == 1
     # the events as they are shipped are what the master's ledger takes
     monitor = SpeedMonitor()
     monitor.record_health("attn", 0, **attn[-1])

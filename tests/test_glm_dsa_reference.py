@@ -74,9 +74,24 @@ def tokens(seq=SEQ):
 @functools.cache
 def weights(**overrides):
     """Seeded weights of ``config(**overrides)``'s tree (which share of the
-    experts a config holds changes no shape)."""
+    experts a config holds changes no shape or value, nor does how many
+    keys are kept or how long a sequence may be: ``_tree_of``).  Drawn once
+    a trunk: a tree that differs from the default one in the MTP module
+    alone is that tree without the module, or without the module's
+    indexer."""
     cfg = config(**overrides)
-    return harness.init(cfg, tokens(cfg.max_seq_len)[0], move=move)
+    if overrides and set(overrides) <= {"mtp_depth", "mtp_layer_kind"}:
+        tree = dict(weights())
+        module = tree.pop("mtp")
+        if cfg.mtp_depth:
+            assert cfg.mtp_layer_kind == REUSE_ATTENTION
+            attn = {
+                k: v for k, v in module["block"]["attn"].items()
+                if k != "indexer"
+            }
+            tree["mtp"] = dict(module, block=dict(module["block"], attn=attn))
+        return tree
+    return harness.init(cfg, tokens()[0], move=move)
 
 
 @functools.cache
@@ -113,7 +128,7 @@ def _tree_of(cfg):
     return {
         k: getattr(cfg, k) for k in (
             "num_layers", "mtp_depth", "mtp_layer_kind", "scan_layers",
-            "max_seq_len", "layer_pattern", "index_topk",
+            "layer_pattern",
         ) if getattr(cfg, k) != getattr(config(), k)
     }
 
@@ -142,11 +157,23 @@ def _layers(sown, periods):
 
 # -- the whole model against the reference ------------------------------------
 
+# A case is a whole model walked forward and backward, so a configuration
+# is there for a property no other has, and TWO models carry the four
+# names: the choice shared across the dense prefix and across two periods
+# into a module that chooses (nine layers: what ``share`` names holds in
+# the first period of it), and the module that reuses a choice under the
+# kernels (a kernel runs over a choice whoever made it; the module that
+# chooses under the kernels is ``tests/test_glm_dsa_system.py``'s step).
+_TWO_PERIODS = dict(num_layers=9)
+_KERNELS_AND_REUSE = dict(
+    mtp_layer_kind=REUSE_ATTENTION, attention_impl="flash", max_seq_len=128
+)
+NO_MTP = dict(mtp_depth=0, mtp_layer_kind="")
 CASES = {
-    "share": {},
-    "two_periods": dict(num_layers=9),
-    "mtp_reuses": dict(mtp_layer_kind=REUSE_ATTENTION),
-    "kernels": dict(attention_impl="flash", max_seq_len=128),
+    "share": _TWO_PERIODS,
+    "two_periods": _TWO_PERIODS,
+    "mtp_reuses": _KERNELS_AND_REUSE,
+    "kernels": _KERNELS_AND_REUSE,
 }
 
 
@@ -171,9 +198,9 @@ def test_program_matches_the_reference_in_float32(case):
     )
     assert all(float(kl) > 1e-3 for kl in want["index_kl"])
     if case == "kernels":
-        calls = harness.pallas_calls(jax.make_jaxpr(jax.grad(
-            lambda p: harness.program_loss(cfg, p, *toks)[0]
-        ))(params).jaxpr)
+        calls = harness.pallas_calls(
+            CHECK.gradient_program(cfg, params, toks).jaxpr
+        )
         # forward, dq and dk / dv of every attention layer (one body for
         # the scanned period's four)
         assert len(calls) >= 3 * 3
@@ -189,7 +216,12 @@ def test_the_blocked_passes_walk_runs_of_rows_to_their_own_keys(monkeypatch):
     ]
     assert index_select.key_runs(16384, 128)[0] == (0, 32, 4096)
     assert index_select.key_runs(96, 32) == [(0, 3, 96)]
-    cfg = config(index_topk=6)      # a program no other case has compiled
+    # a program no other case has compiled, of the layers that walk: a
+    # dense and an expert layer, both choosing
+    cfg = config(
+        index_topk=6, num_layers=2, layer_pattern=(INDEX_ATTENTION,),
+        **NO_MTP,
+    )
     params = weights(**_tree_of(cfg))
     CHECK.loss_and_every_gradient_match(cfg, params, tokens())
     masks, _ = choices(cfg)
@@ -203,9 +235,10 @@ def test_the_chosen_sets_are_the_reference_s_and_are_shared(case):
     the nearest choosing layer's before it, across the prefix-to-pattern
     boundary, across two periods and into the MTP module."""
     cfg = config(**CASES[case])
-    masks, stats = choices(cfg)
+    seq = cfg.max_seq_len
+    masks, stats = choices(cfg, seq)
     want = CHECK.reference(
-        "forward", cfg, weights(**_tree_of(cfg)), tokens()
+        "forward", cfg, weights(**_tree_of(cfg)), tokens(seq)
     )["masks"]
     assert len(masks) == len(want) == cfg.num_layers + cfg.mtp_depth
     kinds = [cfg.layer_kind(i) for i in range(cfg.num_layers)] + (
@@ -223,19 +256,19 @@ def test_the_chosen_sets_are_the_reference_s_and_are_shared(case):
             assert last is None or (ours != last).any()
         last = ours
     # every query keeps min(t + 1, topk) keys, itself or earlier
-    rows = np.minimum(np.arange(SEQ) + 1, TOPK)
+    rows = np.minimum(np.arange(seq) + 1, TOPK)
     for ours in masks:
         assert (ours.sum(-1) == rows).all()
         assert not np.triu(ours, 1).any()
     for chosen, seen, _, _ in stats:
         assert chosen == BATCH * rows.sum()
-        assert seen == BATCH * SEQ * (SEQ + 1) // 2
+        assert seen == BATCH * seq * (seq + 1) // 2
 
 
 def test_a_planted_tie_goes_to_the_lower_key():
     """An indexer whose weights' projection is zero scores every key 0:
     program and reference both keep the FIRST ``topk`` keys of a row."""
-    cfg = config(mtp_depth=0, mtp_layer_kind="")
+    cfg = config(**NO_MTP)
     params = jax.tree_util.tree_map_with_path(
         lambda path, leaf: jnp.zeros_like(leaf)
         if "weights_proj" in jax.tree_util.keystr(path) else leaf,
@@ -358,17 +391,18 @@ def test_where_every_key_is_kept_the_layer_is_dense_latent_attention():
     cfg = config()
     n = jax.random.normal(jax.random.PRNGKey(7), (BATCH, SEQ, cfg.d_model))
     sparse = sparse_attention.from_config(cfg, INDEX_ATTENTION)
-    variables = sparse.init(jax.random.PRNGKey(8), n)
+    dense = LatentAttention(**{
+        f.name: getattr(sparse, f.name)
+        for f in dataclasses.fields(LatentAttention)
+        if f.name not in ("parent", "name")
+    })
+    # (a program each: op by op a layer is a compile for every operation)
+    variables = jax.jit(sparse.init)(jax.random.PRNGKey(8), n)
     with jax.default_matmul_precision("highest"):
-        got, index = sparse.apply(variables, n)
-        dense = LatentAttention(**{
-            f.name: getattr(sparse, f.name)
-            for f in dataclasses.fields(LatentAttention)
-            if f.name not in ("parent", "name")
-        })
+        got, index = jax.jit(sparse.apply)(variables, n)
         params = dict(nn.meta.unbox(variables["params"]))
         params.pop("indexer")
-        want = dense.apply({"params": params}, n)
+        want = jax.jit(dense.apply)({"params": params}, n)
     np.testing.assert_allclose(got[:, :TOPK], want[:, :TOPK], atol=1e-5)
     assert float(jnp.abs(got[:, TOPK:] - want[:, TOPK:]).max()) > 1e-2
     assert float(index.kl) > 0
@@ -376,14 +410,16 @@ def test_where_every_key_is_kept_the_layer_is_dense_latent_attention():
     params = weights(**_tree_of(whole))
     fields = dataclasses.asdict(whole)
     with jax.default_matmul_precision("highest"):
-        sibling = joyai_ref.forward(fields, params, *tokens())
+        sibling = jax.jit(functools.partial(joyai_ref.forward, fields))(
+            params, *tokens()
+        )
     nll, _, mtp = CHECK.outputs(whole, params, tokens())
     np.testing.assert_allclose(nll, sibling["nll"], atol=TOL)
     np.testing.assert_allclose(mtp, sibling["mtp_nll"], atol=TOL)
 
 
 def test_no_key_of_another_document_is_chosen():
-    cfg = config(mtp_depth=0, mtp_layer_kind="")
+    cfg = config(**NO_MTP)
     masks, stats = choices(cfg, segments=True)
     seg = np.asarray(_segments(SEQ))
     same = seg[:, :, None] == seg[:, None, :]
@@ -395,9 +431,9 @@ def test_no_key_of_another_document_is_chosen():
     # the reference's choice on a layer's own inputs, with the ids
     n = jax.random.normal(jax.random.PRNGKey(9), (BATCH, SEQ, cfg.d_model))
     layer = sparse_attention.from_config(cfg, INDEX_ATTENTION)
-    variables = layer.init(jax.random.PRNGKey(10), n)
+    variables = jax.jit(layer.init)(jax.random.PRNGKey(10), n)
     with jax.default_matmul_precision("highest"):
-        _, index = layer.apply(variables, n, None, jnp.asarray(seg))
+        _, index = jax.jit(layer.apply)(variables, n, None, jnp.asarray(seg))
         p = nn.meta.unbox(variables["params"])
         _, _, _, c_q = ref.latent_qkv(dataclasses.asdict(cfg), n, p)
         want = ref.selection(
@@ -565,7 +601,7 @@ def test_the_head_shares_add_up_to_the_uncut_layer():
     n = jax.random.normal(jax.random.PRNGKey(12), (BATCH, SEQ, cfg.d_model))
     whole_layer = sparse_attention.from_config(cfg, INDEX_ATTENTION)
     whole = nn.meta.unbox(
-        whole_layer.init(jax.random.PRNGKey(13), n)["params"]
+        jax.jit(whole_layer.init)(jax.random.PRNGKey(13), n)["params"]
     )
 
     def cut(first):
@@ -636,8 +672,6 @@ def test_the_expert_shares_add_up_to_the_uncut_layer():
         shared = ref.swiglu(n, whole["shared"])
     harness.shares_add_up(
         ref, fields, n, whole, held,
-        lambda first: moe_lib.from_config(
-            dataclasses.replace(cfg, first_expert=first)
-        ),
+        lambda first: moe_lib.from_config(cfg).clone(first_expert=first),
         shared, atol=1e-5,
     )

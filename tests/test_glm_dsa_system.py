@@ -19,28 +19,36 @@ import reference_harness as harness
 import test_glm_dsa_reference as numerics
 from dlrover_tpu.models import sparse_attention, transformer
 from dlrover_tpu.trainer import train_lib
-from test_glm_dsa_reference import config, tokens, weights
+from test_glm_dsa_reference import config, weights
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SEQ, BATCH, VOCAB = 128, 8, numerics.VOCAB
 KERNELS = dict(attention_impl="flash", max_seq_len=SEQ, remat="flash_only")
 
-
-def batches(n, seed=0, batch=BATCH):
-    rng = np.random.default_rng(seed)
-    rows = rng.integers(0, VOCAB, (n, batch, SEQ + 1), dtype=np.int32)
-    return [{"inputs": r[:, :-1], "targets": r[:, 1:]} for r in rows]
+# ONE step program for the file: the trainer's, which the first-loss case
+# runs and the scopes' case lowers (``train_step`` is traced once)
+pytestmark = pytest.mark.usefixtures("one_step_program")
 
 
-def test_the_train_step_s_first_losses_are_the_reference_s():
-    """The normal path: ``build_sharded_train``'s compiled step under the
-    policy the cell runs; ``aux_loss`` is the indexers' terms alone."""
+@pytest.fixture(scope="module")
+def fitted(tmp_path_factory):
+    """Ten steps of the small model at ``report_every=5``, a checkpoint
+    every 5, then a second trainer that restores."""
+    directory = str(tmp_path_factory.mktemp("glm_dsa"))
     cfg = config(**KERNELS)
-    toks = tokens(SEQ)
-    params = weights(max_seq_len=SEQ)
-    train = harness.built(cfg, batch=numerics.BATCH, seq=SEQ)
-    with jax.default_matmul_precision("highest"):
-        _, metrics = harness.first_step(train, params, toks)
+    first = harness.fit(cfg, directory, seq=SEQ, batch=BATCH, ckpt_every=5)
+    second = harness.fit(cfg, directory, seq=SEQ, batch=BATCH, ckpt_every=5)
+    return dict(first, cfg=cfg, restored=second["began"])
+
+
+def test_the_train_step_s_first_losses_are_the_reference_s(fitted):
+    """The normal path: the trainer's compiled step under the policy the
+    cell runs, on the trainer's batch; ``aux_loss`` is the indexers' terms
+    alone."""
+    cfg = fitted["cfg"]
+    toks = harness.tokens(1, BATCH, SEQ, VOCAB)
+    params = weights()
+    _, metrics = harness.first_step(fitted["train"], params, toks)
     want = numerics.CHECK.reference("forward", cfg, params, toks)
     assert abs(float(metrics["loss"]) - float(want["nll"].mean())) <= 1e-4
     assert abs(
@@ -53,66 +61,23 @@ def test_the_train_step_s_first_losses_are_the_reference_s():
         metrics[sparse_attention.STATS_NAME]
     )
     rows = np.minimum(np.arange(SEQ) + 1, cfg.index_topk).sum()
-    assert chosen == 3 * numerics.BATCH * rows
-    assert seen == 3 * numerics.BATCH * SEQ * (SEQ + 1) // 2
+    assert chosen == 3 * BATCH * rows
+    assert seen == 3 * BATCH * SEQ * (SEQ + 1) // 2
     assert kl == pytest.approx(float(sum(want["index_kl"])), abs=1e-4)
     assert absmax > 0
 
 
-@pytest.fixture(scope="module")
-def fitted(tmp_path_factory):
-    """Ten steps of the small model at ``report_every=5``, a checkpoint
-    every 5, then a second trainer that restores."""
-    from dlrover_tpu.common import telemetry
-    from dlrover_tpu.trainer.elastic_trainer import (
-        ElasticTrainer,
-        TrainerConfig,
-    )
-
-    tmp_path = tmp_path_factory.mktemp("glm_dsa")
-    with pytest.MonkeyPatch.context() as patch:
-        patch.setenv("DLROVER_TPU_JOB", f"glm{os.getpid()}_{tmp_path.name}")
-        patch.setenv("DLROVER_TPU_SOCKET_DIR", str(tmp_path / "socks"))
-        cfg = config(**KERNELS)
-        trainer_config = TrainerConfig(
-            global_batch_size=BATCH, seq_len=SEQ, learning_rate=1e-2,
-            optimizer="adafactor", checkpoint_dir=str(tmp_path / "ckpt"),
-            ckpt_every=5, report_every=5, metrics_lag=4,
-            warmup_compile=True,
-        )
-        seen = {}
-        train_lib.reset_trace_counts()
-        with telemetry.recorder().open_tap() as tap:
-            trainer = ElasticTrainer(cfg, trainer_config, client=None)
-            first = jax.tree.map(np.asarray, trainer.state.params)
-            trainer.fit(
-                batches(10), max_steps=10,
-                on_step=lambda step, metrics: seen.update({step: metrics}),
-            )
-            taken = tap.take()
-        last = jax.tree.map(np.asarray, trainer.state.params)
-        saved = harness.digest(trainer.state)
-        trainer.close()
-        second = ElasticTrainer(cfg, trainer_config, client=None)
-        restored = (second.step, harness.digest(second.state))
-        second.close()
-        return dict(
-            cfg=cfg, taken=taken, seen=seen, saved=saved, restored=restored,
-            first=first, last=last,
-            traces=train_lib.trace_count("train_step"),
-        )
-
-
 def test_the_trainer_trains_saves_and_restores_it(fitted):
-    assert fitted["restored"] == (10, fitted["saved"])
-    assert fitted["traces"] == 1
+    assert fitted["restored"] == fitted["ended"]
+    assert fitted["ended"][0] == 10
+    assert train_lib.trace_count("train_step") == 1
     losses = [float(fitted["seen"][s]["loss"]) for s in sorted(fitted["seen"])]
     assert all(np.isfinite(losses)) and len(losses) == 10
     # the indexers' leaves are ordinary leaves: saved, restored, and moved
     # by their own term
     moved = jax.tree_util.tree_map_with_path(
         lambda path, a, b: float(np.abs(a - b).max()),
-        fitted["first"], fitted["last"],
+        *fitted["params"],
     )
     flat = {
         jax.tree_util.keystr(p): v
@@ -158,13 +123,11 @@ def test_fit_books_the_index_event_from_the_step_itself(fitted):
     assert ledger["shared_layers"] == 3.0 and ledger["kl"] > 0
 
 
-def test_the_scopes_the_benchmark_reads_reach_the_lowered_text():
+def test_the_scopes_the_benchmark_reads_reach_the_lowered_text(fitted):
     from benchmark import layers
 
-    cfg = config(**KERNELS)
-    text = harness.lowered(
-        harness.built(cfg, batch=numerics.BATCH, seq=SEQ)
-    ).as_text(debug_info=True)
+    text = harness.lowered(fitted["train"]).as_text(debug_info=True)
+    assert train_lib.trace_count("train_step") == 1
     for scope in (
         "dense_0/attn/indexer/wq_b", "dense_0/attn/indexer/wk",
         "dense_0/attn/indexer/k_norm", "dense_0/attn/indexer/weights_proj",
@@ -204,7 +167,10 @@ def test_a_model_without_an_indexer_imports_none_of_it():
         " moe_d_ff=16, vocab_size=128, dtype=jnp.float32)\n"
         "tokens = jnp.zeros((1, 16), jnp.int32)\n"
         "m = TransformerLM(cfg)\n"
-        "m.apply(m.init(jax.random.PRNGKey(0), tokens), tokens)\n"
+        # traced, not run: what a walk of the model imports is the
+        # property, and op by op the walk is twenty seconds of compiling
+        "jax.eval_shape(lambda: m.apply("
+        "m.init(jax.random.PRNGKey(0), tokens), tokens))\n"
         "assert kernel_facts(cfg, 16)['sparse_attention'] == 'none'\n"
         "bad = [n for n in sys.modules if n.endswith(('sparse_attention',"
         " 'ops.index_select', 'ops.sparse_flash_attention',"

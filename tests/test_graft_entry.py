@@ -23,6 +23,16 @@ def test_dryrun_multichip_2():
 
 def test_entry_traces():
     """entry()'s fn must be jit-traceable (full compile check runs on TPU)."""
-    fn, args = graft.entry()
-    out = jax.eval_shape(fn, *args)
+    kept = []
+
+    def arguments():
+        # ``entry()`` draws 355M weights with an unjitted ``model.init``:
+        # called under ``eval_shape`` it is traced and never run (run, XLA's
+        # threads go on computing it for a minute after the case returned)
+        fn, args = graft.entry()
+        kept.append(fn)
+        return args
+
+    args = jax.eval_shape(arguments)
+    out = jax.eval_shape(kept[0], *args)
     assert out.shape == ()

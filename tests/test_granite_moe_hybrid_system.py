@@ -6,58 +6,31 @@ configuration refuses is ``tests/test_granite_moe_hybrid_config.py``'s; the
 defaults that leave every other model's step as it was
 ``tests/test_step_scopes.py``'s.)"""
 
-import jax
 import numpy as np
 import pytest
 
 import reference_harness as harness
 from dlrover_tpu.models.transformer import TransformerConfig
-from test_granite_moe_hybrid_reference import VOCAB, config, params, tokens
+from test_granite_moe_hybrid_reference import config, params, tokens
 
 
-def test_fit_books_the_scan_cut_and_the_row_moves(monkeypatch, tmp_path):
+def test_fit_books_the_scan_cut_and_the_row_moves(tmp_path, one_step_program):
     """Five steps at ``report_every=5`` through ``ElasticTrainer``: the
     ``compile`` event says the scan is the kernel's, eight heads a grid
     step, and which path a token's rows take; the ``ssm`` event carries
     the heads and the one group; a ``moe`` event beside it with nothing
     dropped; the loss is finite and the step traced once."""
-    from dlrover_tpu.common import telemetry
     from dlrover_tpu.trainer import train_lib
-    from dlrover_tpu.trainer.elastic_trainer import (
-        ElasticTrainer,
-        TrainerConfig,
-    )
 
-    monkeypatch.setenv("DLROVER_TPU_JOB", f"granite_{tmp_path.name}")
-    monkeypatch.setenv("DLROVER_TPU_SOCKET_DIR", str(tmp_path / "socks"))
-    train_lib.reset_build_cache()
-    train_lib.reset_trace_counts()
-    batch = jax.device_count()
-    rng = np.random.default_rng(0)
-    rows = rng.integers(0, VOCAB, (5, batch, 32 + 1), dtype=np.int32)
-    with telemetry.recorder().open_tap() as tap:
-        was_enabled = telemetry.recorder().enabled
-        telemetry.recorder().configure(enabled=True)
-        trainer = ElasticTrainer(
-            config(
-                ssm_impl="kernel", num_layers=6, experts_held=4,
-                moe_row_budget=4.0,
-            ),
-            TrainerConfig(
-                global_batch_size=batch, seq_len=32, learning_rate=1e-2,
-                optimizer="adafactor", ckpt_every=1000, report_every=5,
-                warmup_compile=True,
-            ),
-            client=None,
-        )
-        losses = {}
-        trainer.fit(
-            [{"inputs": r[:, :-1], "targets": r[:, 1:]} for r in rows],
-            max_steps=5,
-            on_step=lambda step, m: losses.update({step: float(m["loss"])}),
-        )
-        taken = tap.take()
-        telemetry.recorder().configure(enabled=was_enabled)
+    fit = harness.fit(
+        config(
+            ssm_impl="kernel", num_layers=6, experts_held=4,
+            moe_row_budget=4.0,
+        ),
+        str(tmp_path), seq=32, steps=5, metrics_lag=0,
+    )
+    taken = fit["taken"]
+    losses = {step: float(m["loss"]) for step, m in fit["seen"].items()}
     assert sorted(losses) == [1, 2, 3, 4, 5]
     assert all(np.isfinite(v) for v in losses.values())
     assert train_lib.trace_count("train_step") == 1

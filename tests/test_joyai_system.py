@@ -8,7 +8,13 @@ under FSDP keeps it) and the dense prefix ahead of a two-stage pipeline
 (``tests/test_joyai_state.py``'s cases until PR 48: they take this file's
 sizes and its builder, and a few-case file of two and a half minutes that
 starts last was the end of the whole run).  (What the configuration
-refuses and the master's gauges are ``tests/test_joyai_config.py``'s.)"""
+refuses and the master's gauges are ``tests/test_joyai_config.py``'s.)
+
+The plain step of the small model is the trainer's (``fitted``), traced
+once.  A step program of its own have: two microbatches (the engine is
+another step), AdamW (another optimizer's state), FSDP over two devices
+(the staged save path wants sharded leaves) and the dense trunk on one and
+two pipeline stages (another model)."""
 
 import os
 
@@ -26,6 +32,7 @@ from dlrover_tpu.models.transformer import TransformerLM
 from dlrover_tpu.trainer import train_lib
 
 SEQ, BATCH, VOCAB = 32, 8, 256
+biases = harness.router_biases
 
 SMALL = dict(
     vocab_size=VOCAB, num_layers=3, d_model=64, num_heads=4, d_ff=96,
@@ -40,41 +47,44 @@ def config(**overrides):
     return joyai_llm_flash_config(**{**SMALL, **overrides})
 
 
-def batches(n, seed=0):
-    rng = np.random.default_rng(seed)
-    rows = rng.integers(0, VOCAB, (n, BATCH, SEQ + 1), dtype=np.int32)
-    return [{"inputs": r[:, :-1], "targets": r[:, 1:]} for r in rows]
+def batches(n):
+    return harness.batches(n, BATCH, SEQ, VOCAB)
 
 
-def build(cfg=None, devices=1, parallel=None, optimizer="sgd", **kw):
-    """Kept for the process: the plain step is two cases'."""
+def build(cfg=None, devices=1, parallel=None, optimizer="adafactor", **kw):
     return harness.built(
         cfg or config(), batch=BATCH, seq=SEQ, devices=devices,
         parallel=parallel, optimizer=optimizer, learning_rate=1e-2, **kw,
     )
 
 
-def biases(params):
-    return {
-        "/".join(k.key for k in path): np.asarray(leaf)
-        for path, leaf in jax.tree_util.tree_leaves_with_path(params)
-        if path[-1].key == "router_bias"
-    }
+@pytest.fixture(scope="module")
+def fitted(tmp_path_factory, one_step_program):
+    """Ten steps of the small model at ``report_every=5``, each report
+    read as its step ends, the step compiled by its first call.  Its step
+    program is the file's plain step: the cases that want one step of the
+    small model on a batch of eight run THIS one from a state of their
+    own."""
+    return harness.fit(
+        config(), str(tmp_path_factory.mktemp("joy")), seq=SEQ, batch=BATCH,
+        metrics_lag=0, warmup_compile=False,
+    )
 
 
-def test_the_step_trains_the_sum_reports_the_parts_and_moves_the_bias():
+def test_the_step_trains_the_sum_reports_the_parts_and_moves_the_bias(fitted):
     """One step against the reference: ``loss`` is the main cross-entropy,
     ``mtp_loss`` the module's, the gradient is of their weighted sum, and
     each layer's bias moves by the rule on that layer's own counts."""
     cfg = config()
-    train = build(cfg)
+    train = fitted["train"]
     state = train.init(jax.random.PRNGKey(0))
     params = jax.tree.map(np.asarray, state.params)
     batch = batches(1)[0]
-    with jax.default_matmul_precision("highest"):
-        new_state, metrics = train.step(
-            state, train_lib.shard_batch(batch, train)
-        )
+    traces = train_lib.trace_count("train_step")
+    new_state, metrics = train.step(
+        state, train_lib.shard_batch(batch, train)
+    )
+    assert train_lib.trace_count("train_step") == traces
     rows = (jnp.asarray(batch["inputs"]), jnp.asarray(batch["targets"]))
     want = numerics.CHECK.reference("forward", cfg, params, rows)
     assert float(metrics["loss"]) == pytest.approx(
@@ -85,8 +95,7 @@ def test_the_step_trains_the_sum_reports_the_parts_and_moves_the_bias():
     )
     assert float(metrics["aux_loss"]) == 0.0
     _, grads = numerics.CHECK.reference("loss_and_grads", cfg, params, rows)
-    # plain SGD, clipped at global norm 1: the update is the gradient's
-    # direction, the reference's
+    # the norm the step clips by is the reference gradient's
     norm = float(metrics["grad_norm"])
     want_norm = float(np.sqrt(sum(
         np.sum(g * g) for g in jax.tree.leaves(grads)
@@ -116,8 +125,8 @@ def test_the_step_trains_the_sum_reports_the_parts_and_moves_the_bias():
     assert drop == 0.0 and load.shape == (16,)
 
 
-def test_the_microbatch_engine_trains_the_same_step():
-    one, two = build(), build(grad_accum=2)
+def test_the_microbatch_engine_trains_the_same_step(fitted):
+    one, two = fitted["train"], build(grad_accum=2)
     batch = batches(1)[0]
     results = []
     for train in (one, two):
@@ -142,37 +151,20 @@ def test_the_microbatch_engine_trains_the_same_step():
 
 @pytest.mark.parametrize("metrics_lag", [0, 4])
 def test_fit_books_the_share_and_the_mtp_loss_on_the_report_cadence(
-    metrics_lag, monkeypatch, tmp_path, one_step_program
+    metrics_lag, monkeypatch, tmp_path, fitted
 ):
-    from dlrover_tpu.common import telemetry
-    from dlrover_tpu.trainer.elastic_trainer import (
-        ElasticTrainer,
-        TrainerConfig,
-    )
-
-    monkeypatch.setenv("DLROVER_TPU_JOB", f"joy_{tmp_path.name}")
-    monkeypatch.setenv("DLROVER_TPU_SOCKET_DIR", str(tmp_path / "socks"))
     # rows of 64 go through XLA's gather (``row_moves: xla``); the second
     # case reports as a trainer whose rows fit the live-only kernel does
     assert moe_lib.row_moves(config()) == "xla"
+    fit = fitted
     if metrics_lag:
         monkeypatch.setattr(moe_lib, "row_moves", lambda cfg: "kernel_live")
-    trainer = ElasticTrainer(
-        config(),
-        TrainerConfig(
-            global_batch_size=BATCH, seq_len=SEQ, learning_rate=1e-2,
-            optimizer="adafactor", ckpt_every=1000, report_every=5,
-            metrics_lag=metrics_lag,
-        ),
-        client=None,
-    )
-    seen = {}
-    with telemetry.recorder().open_tap() as tap:
-        trainer.fit(
-            batches(10), max_steps=10,
-            on_step=lambda step, metrics: seen.update({step: metrics}),
+        fit = harness.fit(
+            config(), str(tmp_path), seq=SEQ, batch=BATCH,
+            metrics_lag=metrics_lag, warmup_compile=False,
         )
-        events = [e for e in tap.take() if e[1] == "event"]
+    seen = fit["seen"]
+    events = [e for e in fit["taken"] if e[1] == "event"]
     moe = [e[4] for e in events if e[0] == "moe"]
     mtp = [e[4] for e in events if e[0] == "mtp"]
     assert [e["step"] for e in moe] == [5, 10] == [e["step"] for e in mtp]
@@ -199,7 +191,8 @@ def test_fit_books_the_share_and_the_mtp_loss_on_the_report_cadence(
         )
         assert event["weight"] == pytest.approx(0.3)
         assert 4.0 < event["mtp_loss"] < 7.0
-    assert train_lib.trace_count("train_step") == 1
+    # the first trainer traced the plain step, the second was handed it
+    assert fit["traces"] == (0 if metrics_lag else 1)
 
 
 def test_num_params_counts_what_is_held():
@@ -241,8 +234,11 @@ def test_latent_attention_names_its_scopes_and_shares_one_rotary_key():
 
 
 @pytest.mark.parametrize("optimizer", ["adafactor", "adamw"])
-def test_no_optimizer_moves_the_bias_only_the_rule_does(optimizer):
-    train = build(optimizer=optimizer)
+def test_no_optimizer_moves_the_bias_only_the_rule_does(optimizer, fitted):
+    train = (
+        fitted["train"] if optimizer == "adafactor"
+        else build(optimizer=optimizer)
+    )
     state = train.init(jax.random.PRNGKey(0))
     for i, batch in enumerate(batches(3), start=1):
         state, metrics = train.step(
@@ -268,10 +264,7 @@ def test_a_flash_checkpoint_keeps_the_router_bias(small_pieces):
         assemble_tensor,
     )
 
-    train = build(
-        devices=2, parallel=dict(data=1, fsdp=2),
-        optimizer="adafactor",
-    )
+    train = build(devices=2, parallel=dict(data=1, fsdp=2))
     state = train.init(jax.random.PRNGKey(0))
     for batch in batches(3):
         state, _ = train.step(state, train_lib.shard_batch(batch, train))

@@ -6,6 +6,7 @@ benchmark reads, and that the parameters held are the parameters counted.
 (Sizes and weights are ``tests/test_lfm2_moe_reference.py``'s:
 ``numerics``.)"""
 
+import functools
 import json
 
 import jax
@@ -23,34 +24,49 @@ from test_lfm2_moe_reference import config, params, tokens  # noqa: F401
 
 SEQ, BATCH, VOCAB = 32, 8, numerics.VOCAB
 SLOTS = ("full_0", "conv_1", "conv_2", "conv_3")
+biases = harness.router_biases
 
 
-def batches(n, seed=0):
-    rng = np.random.default_rng(seed)
-    rows = rng.integers(0, VOCAB, (n, BATCH, SEQ + 1), dtype=np.int32)
-    return [{"inputs": r[:, :-1], "targets": r[:, 1:]} for r in rows]
+# ONE step program for the file: the trainer's, which the first-loss case
+# runs on the trainer's batch (``train_step`` is traced once)
+pytestmark = pytest.mark.usefixtures("one_step_program")
 
 
-def biases(tree):
-    return {
-        "/".join(k.key for k in path): np.asarray(leaf)
-        for path, leaf in jax.tree_util.tree_leaves_with_path(tree)
-        if path[-1].key == "router_bias"
-    }
+def cell_config():
+    """One period (a dense layer ahead of it) under the policy the cell
+    runs."""
+    return config(
+        max_seq_len=SEQ, num_layers=5, attention_impl="flash",
+        remat="flash_only", flash_block_q=8, flash_block_kv=8,
+    )
+
+
+@functools.cache
+def one_period(seed=2):
+    """Seeded weights of the one-period tree, the biases moved."""
+    inputs = harness.tokens(1, BATCH, SEQ, VOCAB)[0]
+    return harness.init(cell_config(), inputs, seed=seed, move=numerics.move)
+
+
+@pytest.fixture(scope="module")
+def fitted(tmp_path_factory):
+    """Ten steps at ``report_every=5``."""
+    return harness.fit(
+        cell_config(), str(tmp_path_factory.mktemp("lfm2")), seq=SEQ,
+        batch=BATCH,
+    )
 
 
 def test_the_train_step_s_first_loss_and_bias_move_are_the_reference_s(
-    params, tokens
+    fitted
 ):
-    """The normal path: ``build_sharded_train``'s compiled step under the
-    policy the cell runs.  Each expert layer's bias moves by the unchanged
-    rule on that layer's own counts over ALL the experts."""
-    cfg = config(attention_impl="flash", remat="flash_only",
-                 flash_block_q=8, flash_block_kv=8)
-    train = harness.built(cfg, batch=numerics.BATCH, seq=numerics.SEQ)
+    """The normal path: the trainer's compiled step under the policy the
+    cell runs, on the trainer's batch.  Each expert layer's bias moves by
+    the unchanged rule on that layer's own counts over ALL the experts."""
+    cfg, params = cell_config(), one_period()
+    tokens = harness.tokens(1, BATCH, SEQ, VOCAB)
     before = biases(params)
-    with jax.default_matmul_precision("highest"):
-        new_state, metrics = harness.first_step(train, params, tokens)
+    new_state, metrics = harness.first_step(fitted["train"], params, tokens)
     want = numerics.CHECK.reference("forward", cfg, params, tokens)
     assert abs(float(metrics["loss"]) - float(want["nll"].mean())) <= 1e-4
     assert float(metrics["aux_loss"]) == 0.0
@@ -75,38 +91,14 @@ def test_the_train_step_s_first_loss_and_bias_move_are_the_reference_s(
     assert 0 < gate < 10 and 0 < out_gate < 10 and 0 < absmax < 1e3
 
 
-def test_fit_books_the_conv_event_from_the_step_itself(monkeypatch, tmp_path):
+def test_fit_books_the_conv_event_from_the_step_itself(fitted):
     """Ten steps at ``report_every=5``: two ``conv`` and two ``moe`` events
     carrying the step's own numbers, one ``compile`` event that says how
     the core runs; the servicer hands the ``conv`` event to the master's
     ledger."""
-    from dlrover_tpu.common import telemetry
     from dlrover_tpu.master.speed_monitor import SpeedMonitor
-    from dlrover_tpu.trainer.elastic_trainer import (
-        ElasticTrainer,
-        TrainerConfig,
-    )
 
-    train_lib.reset_trace_counts()
-    monkeypatch.setenv("DLROVER_TPU_JOB", f"lfm2_{tmp_path.name}")
-    monkeypatch.setenv("DLROVER_TPU_SOCKET_DIR", str(tmp_path / "socks"))
-    cfg = config(max_seq_len=SEQ, num_layers=5)
-    seen = {}
-    with telemetry.recorder().open_tap() as tap:
-        trainer = ElasticTrainer(
-            cfg,
-            TrainerConfig(
-                global_batch_size=BATCH, seq_len=SEQ, learning_rate=1e-2,
-                optimizer="adafactor", ckpt_every=1000, report_every=5,
-                metrics_lag=4, warmup_compile=True,
-            ),
-            client=None,
-        )
-        trainer.fit(
-            batches(10), max_steps=10,
-            on_step=lambda step, metrics: seen.update({step: metrics}),
-        )
-        taken = tap.take()
+    taken, seen = fitted["taken"], fitted["seen"]
     events = [e for e in taken if e[1] == "event"]
     (compiled,) = [e[-1] for e in taken if e[0] == "compile"]
     assert compiled["conv_core"] == "xla" and compiled["short_conv"] == "none"
@@ -174,7 +166,7 @@ def test_the_compile_event_says_how_the_core_runs():
 
 def test_the_scopes_the_benchmark_reads_reach_the_compiled_text(tokens):
     cfg = config(num_layers=5)
-    weights = harness.init(cfg, tokens[0], seed=2, move=numerics.move)
+    weights = one_period()
     text = jax.jit(
         lambda p, t: TransformerLM(cfg).apply({"params": p}, t)[0]
     ).lower(weights, tokens[0]).as_text(debug_info=True)

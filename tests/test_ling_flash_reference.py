@@ -90,20 +90,29 @@ def params():
     return share(config())
 
 
+KDA_KERNEL_WIDTHS = dict(
+    linear_num_heads=2, linear_key_head_dim=128, linear_value_head_dim=128,
+    layer_pattern=(LINEAR_ATTENTION,), num_layers=2,
+)
+_DEEP = dict(
+    num_layers=14, first_k_dense=2, layer_pattern=ling_flash.TRUNK_PATTERN
+)
 CASES = {
     "share": {},
     "whole": dict(experts_held=0, first_expert=0),
     "last_share": dict(first_expert=24),
     "flash": dict(attention_impl="flash", flash_block_q=8, flash_block_kv=8),
-    "two_dense_two_periods": dict(num_layers=8, first_k_dense=2),
-    "published_period": dict(
-        num_layers=7, layer_pattern=ling_flash.TRUNK_PATTERN
-    ),
+    # two dense layers ahead of two periods of the published six kinds: ONE
+    # model under both names (each is a depth and a period's length, held to
+    # every token's loss; apart they were two trees and four programs)
+    "two_dense_two_periods": _DEEP,
+    "published_period": _DEEP,
     # heads of 128 / 128 take the Pallas kernels (interpreted here): one
-    # dense and one expert layer, both KDA
+    # dense and one expert layer, both KDA, under the policy the cell runs
+    # (the program ``test_keeping_the_kda_states_changes_no_gradient`` holds
+    # ``full``'s to)
     "kda_kernel_widths": dict(
-        linear_num_heads=2, linear_key_head_dim=128, linear_value_head_dim=128,
-        layer_pattern=(LINEAR_ATTENTION,), num_layers=2,
+        KDA_KERNEL_WIDTHS, attention_impl="flash", remat="flash_only"
     ),
 }
 
@@ -114,6 +123,13 @@ CASES = {
 GRADIENTS = ("share", "flash", "kda_kernel_widths")
 
 
+@functools.cache
+def drawn(cfg):
+    """Seeded weights of ``cfg``'s own tree, drawn once for the cases that
+    read the same model."""
+    return harness.init(cfg, seeded()[0][0], seed=2, move=move)
+
+
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_program_matches_the_reference_in_float32(case, tokens):
     cfg = config(**CASES[case])
@@ -122,7 +138,7 @@ def test_program_matches_the_reference_in_float32(case, tokens):
     ):
         weights = share(cfg)
     else:
-        weights = harness.init(cfg, tokens[0], seed=2, move=move)
+        weights = drawn(cfg)
     if case not in GRADIENTS:
         assert CHECK.nll_gap(cfg, weights, tokens) <= TOL
         return
@@ -131,6 +147,31 @@ def test_program_matches_the_reference_in_float32(case, tokens):
     np.testing.assert_allclose(main, want["nll"], atol=TOL)
     assert float(aux) == 0.0
     CHECK.loss_and_every_gradient_match(cfg, weights, tokens)
+
+
+def test_keeping_the_kda_states_changes_no_gradient(tokens):
+    """``flash_only`` keeps the KDA forward kernel's chunk-start states
+    beside its output (``kda_states``, ``kda_out``), so the backward kernel
+    reads the first run's where ``full`` runs the kernel again: the same
+    kernels on the same inputs, held to what ``flash_only`` is held to in
+    ``tests/test_remat_policies.py`` (whose case this was until PR 65: it
+    reads the ``kda_kernel_widths`` case's program, so it lives beside it,
+    and ``full``'s is the one program it adds)."""
+    weights = drawn(config(**CASES["kda_kernel_widths"]))
+    loss, grads = {}, {}
+    for remat in ("flash_only", "full"):
+        cfg = config(**{**CASES["kda_kernel_widths"], "remat": remat})
+        loss[remat], _, tree = CHECK.loss_and_grads(cfg, weights, tokens)
+        grads[remat] = jax.tree_util.tree_leaves(tree)
+    np.testing.assert_allclose(
+        float(loss["flash_only"]), float(loss["full"]), rtol=1e-5
+    )
+    assert len(grads["flash_only"]) == len(grads["full"])
+    for a, b in zip(grads["flash_only"], grads["full"]):
+        np.testing.assert_allclose(
+            np.asarray(a, np.float64), np.asarray(b, np.float64),
+            rtol=2e-4, atol=2e-6,
+        )
 
 
 def test_the_unrolled_trunk_is_the_scanned_one(tokens):

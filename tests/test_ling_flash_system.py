@@ -29,35 +29,44 @@ from test_ling_flash_reference import config, params, tokens  # noqa: F401
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SEQ, BATCH, VOCAB = 32, 8, numerics.VOCAB
+biases = harness.router_biases
 
 
-def batches(n, seed=0):
-    rng = np.random.default_rng(seed)
-    rows = rng.integers(0, VOCAB, (n, BATCH, SEQ + 1), dtype=np.int32)
-    return [{"inputs": r[:, :-1], "targets": r[:, 1:]} for r in rows]
+# ONE step program for the file: the trainer's, which the first-loss case
+# runs on the trainer's batch (``train_step`` is traced once)
+pytestmark = pytest.mark.usefixtures("one_step_program")
 
 
-def biases(tree):
-    return {
-        "/".join(k.key for k in path): np.asarray(leaf)
-        for path, leaf in jax.tree_util.tree_leaves_with_path(tree)
-        if path[-1].key == "router_bias"
-    }
+def cell_config():
+    """The small model under the policy the cell runs (the flash and KDA
+    outputs kept)."""
+    return config(
+        max_seq_len=SEQ, attention_impl="flash", remat="flash_only",
+        flash_block_q=8, flash_block_kv=8,
+    )
+
+
+@pytest.fixture(scope="module")
+def fitted(tmp_path_factory):
+    """Ten steps at ``report_every=5``, each report read as its step ends
+    (``metrics_lag=0``)."""
+    return harness.fit(
+        cell_config(), str(tmp_path_factory.mktemp("ling")), seq=SEQ,
+        batch=BATCH, metrics_lag=0,
+    )
 
 
 def test_the_train_step_s_first_loss_and_bias_move_are_the_reference_s(
-    params, tokens
+    params, fitted
 ):
-    """The normal path: ``build_sharded_train``'s compiled step under the
-    policy the cell runs (the flash and KDA outputs kept).  Each expert
-    layer's bias moves by the unchanged rule on that layer's own counts
-    over ALL the experts, in their groups."""
-    cfg = config(attention_impl="flash", remat="flash_only",
-                 flash_block_q=8, flash_block_kv=8)
-    train = harness.built(cfg, batch=numerics.BATCH, seq=numerics.SEQ)
+    """The normal path: the trainer's compiled step under the policy the
+    cell runs, on the trainer's batch.  Each expert layer's bias moves by
+    the unchanged rule on that layer's own counts over ALL the experts, in
+    their groups."""
+    cfg = cell_config()
+    tokens = harness.tokens(1, BATCH, SEQ, VOCAB)
     before = biases(params)
-    with jax.default_matmul_precision("highest"):
-        new_state, metrics = harness.first_step(train, params, tokens)
+    new_state, metrics = harness.first_step(fitted["train"], params, tokens)
     want = numerics.CHECK.reference("forward", cfg, params, tokens)
     assert abs(float(metrics["loss"]) - float(want["nll"].mean())) <= 1e-4
     assert float(metrics["aux_loss"]) == 0.0
@@ -89,36 +98,16 @@ def test_the_train_step_s_first_loss_and_bias_move_are_the_reference_s(
 
 @pytest.mark.parametrize("metrics_lag", [0, 4])
 def test_fit_books_the_new_fields_from_the_step_itself(
-    metrics_lag, monkeypatch, tmp_path, one_step_program
+    metrics_lag, tmp_path, fitted
 ):
     """Ten steps at ``report_every=5``: two ``linear_attn`` and two ``moe``
     events carrying the step's own numbers, one ``compile`` event that
     says how the rule runs; one trace of the step and no second forward."""
-    from dlrover_tpu.common import telemetry
-    from dlrover_tpu.trainer.elastic_trainer import (
-        ElasticTrainer,
-        TrainerConfig,
+    fit = fitted if not metrics_lag else harness.fit(
+        cell_config(), str(tmp_path), seq=SEQ, batch=BATCH,
+        metrics_lag=metrics_lag,
     )
-
-    monkeypatch.setenv("DLROVER_TPU_JOB", f"ling_{tmp_path.name}")
-    monkeypatch.setenv("DLROVER_TPU_SOCKET_DIR", str(tmp_path / "socks"))
-    cfg = config(max_seq_len=SEQ)
-    seen = {}
-    with telemetry.recorder().open_tap() as tap:
-        trainer = ElasticTrainer(
-            cfg,
-            TrainerConfig(
-                global_batch_size=BATCH, seq_len=SEQ, learning_rate=1e-2,
-                optimizer="adafactor", ckpt_every=1000, report_every=5,
-                metrics_lag=metrics_lag, warmup_compile=True,
-            ),
-            client=None,
-        )
-        trainer.fit(
-            batches(10), max_steps=10,
-            on_step=lambda step, metrics: seen.update({step: metrics}),
-        )
-        taken = tap.take()
+    taken, seen = fit["taken"], fit["seen"]
     events = [e for e in taken if e[1] == "event"]
     compiled = [e[-1] for e in taken if e[0] == "compile"]
     if not metrics_lag:
@@ -223,7 +212,7 @@ def gradient_program(remat, kernel_widths=True):
     128 / 128, which take the kernels (a dense layer ahead of a trunk of
     one: two KDA layers), or as it is (heads of 16: the ``jax.numpy``
     form)."""
-    widths = numerics.CASES["kda_kernel_widths"] if kernel_widths else {}
+    widths = numerics.KDA_KERNEL_WIDTHS if kernel_widths else {}
     cfg = config(**widths, attention_impl="flash", remat=remat,
                  flash_block_q=8, flash_block_kv=8)
     return traced_gradient(cfg, *numerics.seeded()[0])
@@ -302,7 +291,10 @@ def test_a_model_without_a_kda_layer_imports_none_of_it():
         " linear_value_head_dim=16, vocab_size=128, dtype=jnp.float32)\n"
         "tokens = jnp.zeros((1, 16), jnp.int32)\n"
         "m = TransformerLM(cfg)\n"
-        "m.apply(m.init(jax.random.PRNGKey(0), tokens), tokens)\n"
+        # traced, not run: what a walk of the model imports is the
+        # property, and op by op the walk is twenty seconds of compiling
+        "jax.eval_shape(lambda: m.apply("
+        "m.init(jax.random.PRNGKey(0), tokens), tokens))\n"
         "bad = [n for n in sys.modules if n.endswith(('ops.kda',"
         " 'models.ling_flash', 'references.ling_flash'))]\n"
         "assert not bad, bad\n"

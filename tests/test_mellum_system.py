@@ -4,7 +4,11 @@ cell runs, the ``attn``, ``moe`` and ``compile`` events' fields from the
 step's own sown stats through the servicer to the master's ledger, the
 scopes the benchmark reads, what ``Attention`` refuses under a window, and
 that the parameters held are the parameters counted.  (Sizes and weights
-are ``tests/test_mellum_reference.py``'s: ``numerics``.)"""
+are ``tests/test_mellum_reference.py``'s: ``numerics``.)
+
+The file's step program is the trainer's, traced once; its last case,
+``test_fit_books_the_attn_event_under_accumulation``, needs the one other:
+two microbatches a step are another step program."""
 
 import json
 
@@ -26,22 +30,35 @@ from test_mellum_reference import config, share, tokens  # noqa: F401
 SEQ, BATCH, VOCAB = 32, 8, numerics.VOCAB
 ONE_PERIOD = dict(num_layers=4)
 
-
-def batches(n, seed=0, batch=BATCH):
-    rng = np.random.default_rng(seed)
-    rows = rng.integers(0, VOCAB, (n, batch, SEQ + 1), dtype=np.int32)
-    return [{"inputs": r[:, :-1], "targets": r[:, 1:]} for r in rows]
+# ONE step program for the file's cases, the trainer's, but for the case
+# under accumulation (the file's last): two microbatches are another step
+pytestmark = pytest.mark.usefixtures("one_step_program")
 
 
-def test_the_train_step_s_first_loss_is_the_reference_s(tokens):
-    """The normal path: ``build_sharded_train``'s compiled step under the
-    policy the cell runs, both kinds through the flash kernels."""
-    cfg = config(attention_impl="flash", remat="flash_only",
-                 flash_block_q=8, flash_block_kv=8, **ONE_PERIOD)
+def cell_config():
+    """One period under the policy the cell runs, both kinds through the
+    flash kernels."""
+    return config(
+        max_seq_len=SEQ, attention_impl="flash", remat="flash_only",
+        flash_block_q=8, flash_block_kv=8, **ONE_PERIOD
+    )
+
+
+@pytest.fixture(scope="module")
+def fitted(tmp_path_factory):
+    """Ten steps of one period at ``report_every=5``."""
+    return harness.fit(
+        cell_config(), str(tmp_path_factory.mktemp("mellum")), seq=SEQ,
+        batch=BATCH,
+    )
+
+
+def test_the_train_step_s_first_loss_is_the_reference_s(fitted):
+    """The normal path: the trainer's compiled step, on its batch."""
+    cfg = cell_config()
     params = share(cfg)
-    train = harness.built(cfg, batch=numerics.BATCH, seq=numerics.SEQ)
-    with jax.default_matmul_precision("highest"):
-        _, metrics = harness.first_step(train, params, tokens)
+    tokens = harness.tokens(1, BATCH, SEQ, VOCAB)
+    _, metrics = harness.first_step(fitted["train"], params, tokens)
     want = numerics.CHECK.reference("forward", cfg, params, tokens)
     assert abs(float(metrics["loss"]) - float(want["nll"].mean())) <= 1e-4
     assert float(metrics["aux_loss"]) == pytest.approx(
@@ -67,67 +84,14 @@ def test_the_score_bound_bounds_the_scores_and_carries_the_factor(rng):
     )
 
 
-def fit_ten_steps(monkeypatch, tmp_path, batch=BATCH, **options):
-    """``(the trainer, what the recorder took, the metrics of each step)``
-    of ten steps of one period on ``batch`` sequences at
-    ``report_every=5``; ``options``: further fields of ``TrainerConfig``."""
-    from dlrover_tpu.common import telemetry
-    from dlrover_tpu.trainer.elastic_trainer import (
-        ElasticTrainer,
-        TrainerConfig,
-    )
-
-    monkeypatch.setenv("DLROVER_TPU_JOB", f"mellum_{tmp_path.name}")
-    monkeypatch.setenv("DLROVER_TPU_SOCKET_DIR", str(tmp_path / "socks"))
-    cfg = config(max_seq_len=SEQ, attention_impl="flash", flash_block_q=8,
-                 flash_block_kv=8, **ONE_PERIOD)
-    seen = {}
-    with telemetry.recorder().open_tap() as tap:
-        trainer = ElasticTrainer(
-            cfg,
-            TrainerConfig(
-                global_batch_size=batch, seq_len=SEQ, learning_rate=1e-2,
-                optimizer="adafactor", ckpt_every=1000, report_every=5,
-                metrics_lag=4, warmup_compile=True, **options,
-            ),
-            client=None,
-        )
-        trainer.fit(
-            batches(10, batch=batch), max_steps=10,
-            on_step=lambda step, metrics: seen.update({step: metrics}),
-        )
-        return trainer, tap.take(), seen
-
-
-def test_fit_books_the_attn_event_under_accumulation(monkeypatch, tmp_path):
-    """Two microbatches a step: the score bounds are folded over them as
-    over the layers, and the ``attn`` event is booked as at one (until PR
-    56 the accumulating step dropped ``attn_stats``, and none was)."""
-    # two rows a device, so that a step can be two microbatches
-    trainer, taken, seen = fit_ten_steps(
-        monkeypatch, tmp_path, batch=2 * BATCH, grad_accum=2
-    )
-    assert trainer.grad_accum == 2
-    attn = [e[4] for e in taken if e[:2] == ("attn", "event")]
-    assert [e["step"] for e in attn] == [5, 10]
-    for event in attn:
-        vec = np.asarray(
-            seen[event["step"]][attention_lib.STATS_NAME], np.float64
-        )
-        assert np.isfinite(event["score_bound"])
-        assert event["score_bound"] == pytest.approx(float(vec.max()))
-        assert min(vec) > 0
-
-
-def test_fit_books_the_attn_event_from_the_step_itself(monkeypatch, tmp_path):
+def test_fit_books_the_attn_event_from_the_step_itself(fitted):
     """Ten steps at ``report_every=5``: two ``attn`` and two ``moe`` events
     carrying the step's own numbers, one ``compile`` event that counts each
     kind's blocks; the servicer hands the ``attn`` event to the master's
     ledger."""
     from dlrover_tpu.master.speed_monitor import SpeedMonitor
 
-    train_lib.reset_trace_counts()
-    _, taken, seen = fit_ten_steps(monkeypatch, tmp_path)
+    taken, seen = fitted["taken"], fitted["seen"]
     events = [e for e in taken if e[1] == "event"]
     (compiled,) = [e[-1] for e in taken if e[0] == "compile"]
     blocks = compiled["flash_blocks"]
@@ -293,3 +257,26 @@ def test_num_params_counts_what_is_held():
     )
     assert cfg.num_params() == held - norms
     assert (cfg.num_sliding_layers, cfg.num_full_layers) == (6, 2)
+
+
+def test_fit_books_the_attn_event_under_accumulation(tmp_path):
+    """Two microbatches a step: the score bounds are folded over them as
+    over the layers, and the ``attn`` event is booked as at one (until PR
+    56 the accumulating step dropped ``attn_stats``, and none was).  The
+    file's second step program: the microbatch engine is another step."""
+    # two rows a device, so that a step can be two microbatches
+    fit = harness.fit(
+        cell_config(), str(tmp_path), seq=SEQ, batch=2 * BATCH, grad_accum=2
+    )
+    assert fit["grad_accum"] == 2
+    taken, seen = fit["taken"], fit["seen"]
+    attn = [e[4] for e in taken if e[:2] == ("attn", "event")]
+    assert [e["step"] for e in attn] == [5, 10]
+    for event in attn:
+        vec = np.asarray(
+            seen[event["step"]][attention_lib.STATS_NAME], np.float64
+        )
+        assert np.isfinite(event["score_bound"])
+        assert event["score_bound"] == pytest.approx(float(vec.max()))
+        assert min(vec) > 0
+    assert train_lib.trace_count("train_step") == 2

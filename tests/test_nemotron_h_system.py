@@ -23,6 +23,26 @@ from test_nemotron_h_reference import (  # noqa: F401 (fixtures)
 )
 
 
+# ONE step program for the file: the trainer's, which the step's case runs
+# on the trainer's batch (``train_step`` is traced once)
+pytestmark = pytest.mark.usefixtures("one_step_program")
+
+
+def cell_config():
+    """One period under the policy the cell runs."""
+    return config(num_layers=9, **CASES["kernels"])
+
+
+@pytest.fixture(scope="module")
+def fitted(tmp_path_factory):
+    """Ten steps of 32 tokens at ``report_every=5``, each report read as
+    its step ends (``metrics_lag=0``)."""
+    return harness.fit(
+        cell_config(), str(tmp_path_factory.mktemp("ssm")), seq=32,
+        metrics_lag=0,
+    )
+
+
 def test_the_ungated_grouped_path_is_two_grouped_gemms(params, tokens):
     """``gmm_wi`` and ``gmm_wo`` and no ``gmm_wg``; the names reach the
     lowered text with the ``ssm/`` scopes."""
@@ -68,15 +88,19 @@ def test_a_pipelined_trunk_of_one_branch_layers_is_the_scanned_one(tokens):
 
 
 def test_the_bias_moves_by_the_rule_and_the_step_hands_out_ssm_stats(
-    params, tokens
+    params, fitted
 ):
-    """The normal path: ``build_sharded_train``'s compiled step under the
-    policy the cell runs; its first loss is the reference's, the router
-    biases move by ``rate x sign(mean load - load)`` of that step's own
-    counts, and ``ssm_stats`` leaves with the metrics."""
-    cfg = config(**CASES["kernels"])
-    train = harness.built(cfg, batch=BATCH, seq=SEQ)
-    new_state, metrics = harness.first_step(train, params, tokens)
+    """The normal path: the trainer's compiled step under the policy the
+    cell runs, on the trainer's batch and the first period of the seeded
+    weights; its first loss is the reference's, the router biases move by
+    ``rate x sign(mean load - load)`` of that step's own counts, and
+    ``ssm_stats`` leaves with the metrics."""
+    cfg = cell_config()
+    params = dict(
+        params, blocks=jax.tree.map(lambda a: a[:1], params["blocks"])
+    )
+    tokens = harness.tokens(1, jax.device_count(), 32, VOCAB)
+    new_state, metrics = harness.first_step(fitted["train"], params, tokens)
     out = CHECK.reference("forward", cfg, params, tokens)
     assert abs(float(metrics["loss"]) - float(out["nll"].mean())) <= TOL
     decay, dt, absmax = linear_attention.split_stats(
@@ -114,50 +138,22 @@ def test_the_initialisers_are_mamba_2s():
 # -- the ``ssm`` event and its gauges -------------------------------------------
 
 
-def batches(n, batch, seed=0):
-    rng = np.random.default_rng(seed)
-    rows = rng.integers(0, VOCAB, (n, batch, 32 + 1), dtype=np.int32)
-    return [{"inputs": r[:, :-1], "targets": r[:, 1:]} for r in rows]
-
-
 @pytest.mark.parametrize("metrics_lag", [0, 4])
 def test_fit_books_one_ssm_event_per_report_from_the_step_itself(
-    metrics_lag, monkeypatch, tmp_path, one_step_program
+    metrics_lag, tmp_path, fitted
 ):
     """Ten steps at ``report_every=5``: exactly two ``ssm`` events, of
     steps 5 and 10, carrying the step's own numbers; a ``moe`` event beside
     each; one trace of the step program, whatever the lag; the ``compile``
     event names the scan."""
-    from dlrover_tpu.common import telemetry
-    from dlrover_tpu.trainer.elastic_trainer import (
-        ElasticTrainer,
-        TrainerConfig,
+    fit = fitted if not metrics_lag else harness.fit(
+        cell_config(), str(tmp_path), seq=32, metrics_lag=metrics_lag
     )
-
-    monkeypatch.setenv("DLROVER_TPU_JOB", f"ssm_{tmp_path.name}")
-    monkeypatch.setenv("DLROVER_TPU_SOCKET_DIR", str(tmp_path / "socks"))
-    batch = jax.device_count()
-    with telemetry.recorder().open_tap() as tap:
-        was_enabled = telemetry.recorder().enabled
-        telemetry.recorder().configure(enabled=True)
-        trainer = ElasticTrainer(
-            config(ssm_impl="kernel", num_layers=9),
-            TrainerConfig(
-                global_batch_size=batch, seq_len=32, learning_rate=1e-2,
-                optimizer="adafactor", ckpt_every=1000, report_every=5,
-                metrics_lag=metrics_lag, warmup_compile=True,
-            ),
-            client=None,
-        )
-        seen = {}
-        trainer.fit(
-            batches(10, batch), max_steps=10,
-            on_step=lambda step, metrics: seen.update({
-                step: metrics[mamba2.STATS_NAME]
-            }),
-        )
-        taken = tap.take()
-        telemetry.recorder().configure(enabled=was_enabled)
+    taken = fit["taken"]
+    seen = {
+        step: metrics[mamba2.STATS_NAME]
+        for step, metrics in fit["seen"].items()
+    }
     events = [e for e in taken if e[0] == "ssm" and e[1] == "event"]
     assert sorted(seen) == list(range(1, 11))
     assert [e[4]["step"] for e in events] == [5, 10]
@@ -176,7 +172,8 @@ def test_fit_books_one_ssm_event_per_report_from_the_step_itself(
         assert 0 < attrs["mean_decay"] < 1 and 0 < attrs["mean_dt"] < 1
         assert 0 < attrs["state_absmax"] < 1e3
     assert train_lib.trace_count("train_step") == 1
-    (compiled,) = [e for e in taken if e[0] == "compile"]
+    # (the second case is handed the first's program: nothing compiles)
+    (compiled,) = [e for e in fitted["taken"] if e[0] == "compile"]
     assert compiled[-1]["ssm_scan"] == "kernel"
     assert compiled[-1]["gmm_strips"] == "resident"
     assert compiled[-1]["gmm_dw_tiles"] == "into:1x1 out_of:1x1"

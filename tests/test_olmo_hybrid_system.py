@@ -4,7 +4,13 @@ the patterned tree, a live relayout 4 -> 2, the ``linear_attn`` event;
 and, at the size of ``tests/test_olmo_hybrid_reference.py`` (``numerics``),
 the train step's first loss.  (What the configuration refuses and the
 master's gauges are ``tests/test_olmo_hybrid_config.py``'s; the earlier
-models' pinned steps ``tests/test_step_scopes.py``'s.)"""
+models' pinned steps ``tests/test_step_scopes.py``'s.)
+
+The file's step programs are each a property's own and stay apart: the
+small model on one device, on four under ZeRO-1 (three cases share it) and
+on two (the relayout's target), the trainer's on eight, and the reference's
+size (144 tokens, where the rule's kernel runs) on one device and over
+``data`` x ``tensor``, where each device's kernels see its own heads."""
 
 import os
 
@@ -37,10 +43,8 @@ def config(**overrides):
     return olmo_hybrid_config(**base)
 
 
-def batches(n, seed=0):
-    rng = np.random.default_rng(seed)
-    rows = rng.integers(0, VOCAB, (n, BATCH, SEQ + 1), dtype=np.int32)
-    return [{"inputs": r[:, :-1], "targets": r[:, 1:]} for r in rows]
+def batches(n):
+    return harness.batches(n, BATCH, SEQ, VOCAB)
 
 
 def build(devices, parallel, **kw):
@@ -149,40 +153,22 @@ def test_relayout_state_four_to_two_keeps_every_leaf():
 
 @pytest.mark.parametrize("metrics_lag", [0, 4])
 def test_fit_books_one_linear_attn_event_per_report_from_the_step_itself(
-    metrics_lag, monkeypatch, tmp_path, one_step_program
+    metrics_lag, tmp_path, one_step_program
 ):
     """Ten steps at ``report_every=5``: exactly two ``linear_attn`` events,
     of steps 5 and 10, carrying the step's own numbers; one trace of the
     step program and no second program beside it."""
-    from dlrover_tpu.common import telemetry
-    from dlrover_tpu.trainer.elastic_trainer import (
-        ElasticTrainer,
-        TrainerConfig,
+    fit = harness.fit(
+        config(), str(tmp_path), seq=SEQ, batch=BATCH,
+        metrics_lag=metrics_lag, warmup_compile=False,
     )
-
-    monkeypatch.setenv("DLROVER_TPU_JOB", f"la_{tmp_path.name}")
-    monkeypatch.setenv("DLROVER_TPU_SOCKET_DIR", str(tmp_path / "socks"))
-    trainer = ElasticTrainer(
-        config(),
-        TrainerConfig(
-            global_batch_size=BATCH, seq_len=SEQ, learning_rate=1e-2,
-            optimizer="adafactor", ckpt_every=1000, report_every=5,
-            metrics_lag=metrics_lag,
-        ),
-        client=None,
-    )
-    seen = {}
-    with telemetry.recorder().open_tap() as tap:
-        trainer.fit(
-            batches(10), max_steps=10,
-            on_step=lambda step, metrics: seen.update({
-                step: metrics[linear_attention.STATS_NAME]
-            }),
-        )
-        events = [
-            e for e in tap.take()
-            if e[0] == "linear_attn" and e[1] == "event"
-        ]
+    seen = {
+        step: metrics[linear_attention.STATS_NAME]
+        for step, metrics in fit["seen"].items()
+    }
+    events = [
+        e for e in fit["taken"] if e[0] == "linear_attn" and e[1] == "event"
+    ]
     assert sorted(seen) == list(range(1, 11))
     assert [e[4]["step"] for e in events] == [5, 10]
     for event in events:

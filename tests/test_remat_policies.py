@@ -100,38 +100,6 @@ def test_every_registered_policy_matches_none_grads(remat):
         )
 
 
-def test_keeping_the_kda_states_changes_no_gradient():
-    """``flash_only`` keeps the KDA forward kernel's chunk-start states
-    beside its output (``kda_states``, ``kda_out``), so the backward kernel
-    reads the first run's where ``full`` runs the kernel again: the same
-    kernels on the same inputs, held to what ``flash_only`` is held to
-    above.  (Seeded float32 weights, heads of 128 / 128 so that the kernels
-    run, interpreted: ``tests/test_ling_flash_reference.py``'s model.)"""
-    import reference_harness as harness
-    import test_ling_flash_reference as ling
-
-    tokens = ling.seeded()[0]
-    cfgs = {
-        remat: ling.config(**ling.CASES["kda_kernel_widths"],
-                           attention_impl="flash", remat=remat)
-        for remat in ("flash_only", "full")
-    }
-    weights = harness.init(cfgs["full"], tokens[0], seed=2, move=ling.move)
-    loss, grads = {}, {}
-    for remat, cfg in cfgs.items():
-        loss[remat], _, tree = ling.CHECK.loss_and_grads(cfg, weights, tokens)
-        grads[remat] = jax.tree_util.tree_leaves(tree)
-    np.testing.assert_allclose(
-        float(loss["flash_only"]), float(loss["full"]), rtol=1e-5
-    )
-    assert len(grads["flash_only"]) == len(grads["full"])
-    for a, b in zip(grads["flash_only"], grads["full"]):
-        np.testing.assert_allclose(
-            np.asarray(a, np.float64), np.asarray(b, np.float64),
-            rtol=2e-4, atol=2e-6,
-        )
-
-
 def test_registry_resolves_and_canonicalizes():
     assert len(rp.available()) == 8
     for name in rp.available():

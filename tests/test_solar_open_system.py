@@ -29,20 +29,36 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SEQ, BATCH, VOCAB = 32, 8, numerics.VOCAB
 
 
-def batches(n, seed=0):
-    rng = np.random.default_rng(seed)
-    rows = rng.integers(0, VOCAB, (n, BATCH, SEQ + 1), dtype=np.int32)
-    return [{"inputs": r[:, :-1], "targets": r[:, 1:]} for r in rows]
+# ONE step program for the file: the trainer's, which the first-loss case
+# runs on the trainer's batch (``train_step`` is traced once)
+pytestmark = pytest.mark.usefixtures("one_step_program")
 
 
-def test_the_train_step_s_first_loss_is_the_reference_s(params, tokens):
-    """The normal path: ``build_sharded_train``'s compiled step under the
-    policy the cell runs (the flash and KDA outputs kept)."""
-    cfg = config(attention_impl="flash", remat="flash_only",
-                 flash_block_q=8, flash_block_kv=8)
-    train = harness.built(cfg, batch=numerics.BATCH, seq=numerics.SEQ)
-    with jax.default_matmul_precision("highest"):
-        _, metrics = harness.first_step(train, params, tokens)
+def cell_config():
+    """The small model under the policy the cell runs (the flash and KDA
+    outputs kept); its own init spreads the decay's bias past the split
+    form's floor (the field shapes the init alone, not the step)."""
+    return config(
+        max_seq_len=SEQ, linear_decay_init_std=8.0, attention_impl="flash",
+        remat="flash_only", flash_block_q=8, flash_block_kv=8,
+    )
+
+
+@pytest.fixture(scope="module")
+def fitted(tmp_path_factory):
+    """Ten steps at ``report_every=5``."""
+    return harness.fit(
+        cell_config(), str(tmp_path_factory.mktemp("solar")), seq=SEQ,
+        batch=BATCH,
+    )
+
+
+def test_the_train_step_s_first_loss_is_the_reference_s(params, fitted):
+    """The normal path: the trainer's compiled step under the policy the
+    cell runs, on the trainer's batch and the reference file's weights."""
+    cfg = cell_config()
+    tokens = harness.tokens(1, BATCH, SEQ, VOCAB)
+    _, metrics = harness.first_step(fitted["train"], params, tokens)
     want = numerics.CHECK.reference("forward", cfg, params, tokens)
     assert abs(float(metrics["loss"]) - float(want["nll"].mean())) <= 1e-4
     assert float(metrics["aux_loss"]) == 0.0
@@ -58,38 +74,11 @@ def test_the_train_step_s_first_loss_is_the_reference_s(params, tokens):
     assert g_min < 2 * kda_lib.SPLIT_FLOOR and 0.005 < past < 1
 
 
-def test_fit_books_the_new_fields_from_the_step_itself(
-    monkeypatch, tmp_path
-):
+def test_fit_books_the_new_fields_from_the_step_itself(fitted):
     """Ten steps at ``report_every=5``: two ``linear_attn`` events carrying
     the step's own numbers, one ``compile`` event that says which form of
     the rule runs; one trace of the step."""
-    from dlrover_tpu.common import telemetry
-    from dlrover_tpu.trainer.elastic_trainer import (
-        ElasticTrainer,
-        TrainerConfig,
-    )
-
-    monkeypatch.setenv("DLROVER_TPU_JOB", f"solar_{tmp_path.name}")
-    monkeypatch.setenv("DLROVER_TPU_SOCKET_DIR", str(tmp_path / "socks"))
-    cfg = config(max_seq_len=SEQ, linear_decay_init_std=8.0)
-    seen = {}
-    traces = train_lib.trace_count("train_step")
-    with telemetry.recorder().open_tap() as tap:
-        trainer = ElasticTrainer(
-            cfg,
-            TrainerConfig(
-                global_batch_size=BATCH, seq_len=SEQ, learning_rate=1e-2,
-                optimizer="adafactor", ckpt_every=1000, report_every=5,
-                metrics_lag=4, warmup_compile=True,
-            ),
-            client=None,
-        )
-        trainer.fit(
-            batches(10), max_steps=10,
-            on_step=lambda step, metrics: seen.update({step: metrics}),
-        )
-        taken = tap.take()
+    taken, seen = fitted["taken"], fitted["seen"]
     events = [e for e in taken if e[1] == "event"]
     compiled = [e[-1] for e in taken if e[0] == "compile"]
     assert [e["kda"] for e in compiled] == ["xla_exact"]
@@ -108,7 +97,7 @@ def test_fit_books_the_new_fields_from_the_step_itself(
         assert 0 < event["past_bound_share"] < 1
         assert 0.5 < event["mean_beta"] < 1.5
         assert 0 < event["state_absmax"] < 1e3
-    assert train_lib.trace_count("train_step") == traces + 1
+    assert train_lib.trace_count("train_step") == 1
 
 
 def test_the_compile_event_says_which_form_of_the_rule_runs():

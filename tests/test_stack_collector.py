@@ -43,13 +43,13 @@ def test_collect_stacks_from_live_process(tmp_path):
         assert "deep_in_training_step" in stacks2
     finally:
         child.kill()
-        child.wait()
+        child.wait(timeout=120)
 
 
 def test_collect_stacks_dead_process(tmp_path):
     path = str(tmp_path / "stacks.txt")
     child = subprocess.Popen([sys.executable, "-c", "pass"])
-    child.wait()
+    child.wait(timeout=120)
     assert collect_stacks(child.pid, path, timeout_s=0.5) == ""
 
 
